@@ -821,7 +821,7 @@ def main():
                     help="payload MB (~0.8B-param DiLoCo fragment at 30)")
     ap.add_argument("--iters", type=int, default=3)
     ap.add_argument("--md", action="store_true",
-                    help="print a markdown table row block for RESULTS.md")
+                    help="print a markdown table row block")
     ap.add_argument("--no-striped", action="store_true",
                     help="skip the 3-replica striped-heal phase")
     ap.add_argument("--no-hier", action="store_true",

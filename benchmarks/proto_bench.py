@@ -9,7 +9,7 @@ connections (the reference's fast-quorum path is one round trip,
 
 This measures the full stack (Manager → ManagerServer → Lighthouse, all
 localhost) with no model attached, i.e. the pure protocol tax a train
-step pays.  Round-2 target (VERDICT item 7): < 10 ms/step.
+step pays.  Target: < 10 ms/step.
 
 Usage: python benchmarks/proto_bench.py [--steps N] [--sync-quorum]
 """

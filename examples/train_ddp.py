@@ -88,6 +88,9 @@ def main() -> None:
 
     if args.platform:
         jax.config.update("jax_platforms", args.platform)
+    from torchft_tpu.utils.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
 
     x, y = synthetic_cifar()
     model = SimpleCNN(num_classes=10)

@@ -80,6 +80,9 @@ def main() -> None:
     args = parser.parse_args()
     if args.platform:
         jax.config.update("jax_platforms", args.platform)
+    from torchft_tpu.utils.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
 
     rng = np.random.default_rng(0)
     x = rng.normal(size=(4096, 64)).astype(np.float32)

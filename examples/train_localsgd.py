@@ -53,6 +53,9 @@ def main() -> None:
     args = parser.parse_args()
     if args.platform:
         jax.config.update("jax_platforms", args.platform)
+    from torchft_tpu.utils.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
 
     rng = np.random.default_rng(args.replica_group_id)
     x = rng.normal(size=(1024, 32, 32, 3)).astype(np.float32)

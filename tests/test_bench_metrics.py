@@ -414,50 +414,3 @@ class TestDilocoStreamingLeg:
         assert out["sync_overhead_s_streaming"] == 0.01
         assert "stream_overlap_ratio" not in out
         assert out["sync_overhead_frac"] == 0.02
-
-
-class TestPhaseACaptureGuards:
-    """capture_phase_a_subprocess (shared by the mid-run recovery and
-    scripts/tpu_watch.py) must never pass off a stale or CPU artifact as a
-    TPU capture."""
-
-    def _capture(self, monkeypatch, tmp_path, artifact, write=True):
-        import json as _json
-        import subprocess as _sp
-
-        out_path = str(tmp_path / "phase_a.json")
-
-        def fake_run(cmd, **kw):
-            if write:
-                with open(kw["env"]["TPUFT_BENCH_OUT"], "w") as f:
-                    _json.dump(artifact, f)
-            return _sp.CompletedProcess(cmd, 0)
-
-        # capture_phase_a_subprocess does `import subprocess` at call time,
-        # so patching the global module object covers it
-        import subprocess
-
-        monkeypatch.setattr(subprocess, "run", fake_run)
-        return bench.capture_phase_a_subprocess(60.0, out_path=out_path)
-
-    def test_accepts_tpu_artifact(self, monkeypatch, tmp_path):
-        art = {"cpu_fallback": False, "single": {"platform": "tpu", "mfu": 0.5}}
-        got = self._capture(monkeypatch, tmp_path, art)
-        assert got is not None and got["single"]["mfu"] == 0.5
-
-    def test_rejects_cpu_platform_even_without_fallback_flag(
-        self, monkeypatch, tmp_path
-    ):
-        art = {"cpu_fallback": False, "single": {"platform": "cpu"}}
-        assert self._capture(monkeypatch, tmp_path, art) is None
-
-    def test_stale_artifact_removed_before_capture(self, monkeypatch, tmp_path):
-        stale = tmp_path / "phase_a.json"
-        stale.write_text('{"single": {"platform": "tpu"}, "cpu_fallback": false}')
-        # subprocess dies before writing: the stale file must NOT be read
-        assert (
-            self._capture(
-                monkeypatch, tmp_path, artifact=None, write=False
-            )
-            is None
-        )

@@ -232,7 +232,7 @@ class TestHTTPTransport:
 
 def test_chunked_fetch_error_not_masked_as_timeout(monkeypatch) -> None:
     """A real fetch failure (connection refused) in a chunk thread must
-    surface as that error, under one shared deadline (ADVICE r1)."""
+    surface as that error, under one shared deadline."""
     import time as _time
 
     import torchft_tpu.checkpointing.http_transport as ht

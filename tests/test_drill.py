@@ -1,14 +1,17 @@
 """Joint FT x SPMD kill/heal: real TCP replicas, real HSDP meshes.
 
-VERDICT r1 weak #2 / next-#3: the composition of a real DCN-tier
+The composition of a real DCN-tier
 communicator with compiled mesh parallelism, including a whole-replica
 death and live heal, validated in one run.
 """
+
+import dataclasses
 
 import jax
 import pytest
 
 from torchft_tpu.drill import joint_ft_spmd_drill
+from torchft_tpu.models.llama import llama_debug
 
 
 def test_joint_ft_spmd_kill_heal() -> None:
@@ -24,7 +27,9 @@ def test_joint_ft_spmd_kill_heal() -> None:
 def test_joint_ft_spmd_quantized_outer_ring() -> None:
     """HSDP with the int8 outer ring (quantize_outer=True): every replica
     applies the identical requantized averaged stream, so sharded state
-    stays bit-identical across replicas — the assertion inside the drill."""
+    stays bit-identical across replicas — the assertion inside the drill.
+    The model and the heartbeat bound are the caller's here, as a run at a
+    real width passes them."""
     if len(jax.devices()) < 8:
         pytest.skip("needs 8 (virtual) devices")
     facts = joint_ft_spmd_drill(
@@ -33,6 +38,8 @@ def test_joint_ft_spmd_quantized_outer_ring() -> None:
         num_steps=5,
         kill_replica=None,
         quantize_outer=True,
+        config=dataclasses.replace(llama_debug(), n_layers=1, vocab_size=384),
+        heartbeat_timeout_ms=3000,
     )
     assert facts["restarts"] == 0
 

@@ -306,7 +306,7 @@ class TestAllreduce:
 
     def test_should_commit_fences_inflight_collectives(self) -> None:
         """A collective failure landing after the vote must not let this
-        replica commit (ADVICE r1: analog of the reference's stream sync,
+        replica commit (analog of the reference's stream sync,
         ``manager.py:888-893``)."""
         import threading as _threading
         import time as _time

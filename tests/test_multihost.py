@@ -2,7 +2,7 @@
 
 Each replica group is a real 2-process ``jax.distributed`` job over a
 4-device CPU mesh, so arrays are genuinely non-fully-addressable — the
-code path a v5p-64 replica group exercises (VERDICT r1 missing #2).
+code path a v5p-64 replica group exercises.
 Covers: shard-local gradient rings per host, whole-group SIGKILL-class
 death, respawn, rank-to-rank heal of ``ShardedHostArray`` bundles, and
 rank-wise state equality across groups at the end.

@@ -255,9 +255,9 @@ class TestDeviceQuantizedGradientPath:
         wire_dtypes = []
         orig = manager.allreduce_prequantized
 
-        def spy(q, scales, n):
+        def spy(q, scales, n, device=None):
             wire_dtypes.append(q.dtype)
-            return orig(q, scales, n)
+            return orig(q, scales, n, device=device)
 
         monkeypatch.setattr(manager, "allreduce_prequantized", spy)
         tree = {"w": jnp.full((64, 32), 3.0, dtype=jnp.float32)}
@@ -319,3 +319,146 @@ class TestDeviceQuantizedGradientPath:
         )
         # shardings preserved
         assert out["w"].sharding == tree["w"].sharding
+
+
+class TestDeviceQuantizedWireIsMeshIndependent:
+    """Degraded mode runs a wounded replica on a smaller mesh than its
+    peers.  The device-quantized stream must therefore be a function of the
+    leaf shapes alone: quantized shard by shard it lined up only between
+    replicas on identical meshes, and the ring summed misaligned rows (or,
+    where the padded lengths differed, never committed)."""
+
+    # (mesh axes, the spec of the 2-D leaves): a healthy 2x2 replica, the same
+    # replica re-lowered onto two chips, and one on a single chip
+    LAYOUTS = (
+        (dict(fsdp=2, tp=2), ("fsdp", "tp")),
+        (dict(fsdp=2), ("fsdp", None)),
+        (dict(fsdp=1), (None, None)),
+    )
+
+    @staticmethod
+    def _tree(values, axes, spec, devices):
+        import jax
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        from torchft_tpu.parallel.mesh import make_mesh
+
+        mesh = make_mesh(devices=devices, **axes)
+        specs = {"w": P(*spec), "odd": P(*spec), "bias": P()}
+        return {
+            k: jax.device_put(v, NamedSharding(mesh, specs[k]))
+            for k, v in values.items()
+        }
+
+    @staticmethod
+    def _values(seed):
+        rng = np.random.default_rng(seed)
+        return {
+            "w": rng.normal(size=(64, 4096)).astype(np.float32),
+            # neither dimension is a multiple of the 1024-element row
+            "odd": rng.normal(size=(6, 1000)).astype(np.float32),
+            "bias": rng.normal(size=(100,)).astype(jnp.bfloat16),
+        }
+
+    def test_stream_is_the_same_under_every_mesh(self, monkeypatch) -> None:
+        import jax
+
+        from torchft_tpu import ddp
+
+        class Spy:
+            def allreduce_prequantized(self, q, scales, n, device=None):
+                self.stream = (q, scales, n)
+                self.device = device
+                raise RuntimeError("only the stream is wanted")
+
+            def report_error(self, e):
+                pass
+
+        values = self._values(0)
+        streams = []
+        for axes, spec in self.LAYOUTS:
+            n = int(np.prod(list(axes.values())))
+            tree = self._tree(values, axes, spec, jax.devices()[4 : 4 + n])
+            leaves, treedef = jax.tree_util.tree_flatten(tree)
+            spy = Spy()
+            ddp._allreduce_pytree_device_quantized(spy, leaves, treedef)
+            streams.append(spy.stream)
+            # the collective's reduce is sent to one of the replica's own chips
+            assert spy.device in jax.devices()[4 : 4 + n]
+        for q, scales, n in streams[1:]:
+            assert n == streams[0][2]
+            np.testing.assert_array_equal(q, streams[0][0])
+            np.testing.assert_array_equal(scales, streams[0][1])
+
+    def test_two_replicas_on_different_meshes_average(self) -> None:
+        import threading
+
+        import jax
+
+        from torchft_tpu.communicator import TCPCommunicator
+        from torchft_tpu.lighthouse import LighthouseServer
+
+        lighthouse = LighthouseServer(
+            bind="127.0.0.1:0",
+            min_replicas=2,
+            join_timeout_ms=100,
+            quorum_tick_ms=20,
+            heartbeat_timeout_ms=1000,
+        )
+        values = [self._values(seed) for seed in (0, 1)]
+        layouts = [
+            (*self.LAYOUTS[0], jax.devices()[:4]),
+            (*self.LAYOUTS[1], jax.devices()[4:6]),
+        ]
+        results, errors, managers = [None, None], [], []
+
+        def replica(idx: int) -> None:
+            try:
+                axes, spec, devices = layouts[idx]
+                tree = self._tree(values[idx], axes, spec, devices)
+                manager = Manager(
+                    comm=TCPCommunicator(timeout_s=20.0),
+                    load_state_dict=None,
+                    state_dict=None,
+                    min_replica_size=2,
+                    init_sync=False,  # no state to heal: one gradient only
+                    replica_id=f"mesh_{idx}",
+                    lighthouse_addr=lighthouse.local_address(),
+                    timeout=20.0,
+                    quorum_timeout=20.0,
+                    connect_timeout=20.0,
+                )
+                managers.append(manager)
+                manager.start_quorum()
+                out = ft_allreduce(manager, tree, should_quantize=True)
+                assert manager.errored() is None, manager.errored()
+                assert manager.should_commit()
+                for k in tree:
+                    assert out[k].sharding == tree[k].sharding
+                    assert out[k].dtype == tree[k].dtype
+                results[idx] = {k: np.asarray(v) for k, v in out.items()}
+            except BaseException as e:  # noqa: BLE001 — raised by the test
+                errors.append(e)
+
+        threads = [threading.Thread(target=replica, args=(i,)) for i in range(2)]
+        try:
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=120.0)
+        finally:
+            for m in managers:
+                m.shutdown()
+            lighthouse.shutdown()
+        if errors:
+            raise errors[0]
+        for k in values[0]:
+            mean = (
+                values[0][k].astype(np.float32) + values[1][k].astype(np.float32)
+            ) / 2
+            # two int8 roundings of values within +-5: a misaligned row is
+            # off by whole units
+            np.testing.assert_allclose(
+                results[0][k].astype(np.float32), mean, atol=0.08
+            )
+            np.testing.assert_array_equal(results[0][k], results[1][k])

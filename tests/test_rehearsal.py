@@ -103,7 +103,7 @@ class TestQuantKernelLowering:
         fused reduce / dequantize) x every wire kind must TPU-lower — a
         Mosaic-inexpressible program fails here in CI, not at cluster
         bring-up.  Per-generation compile still needs metal (covered at
-        runtime by pallas_quant._pallas_kind_ok)."""
+        runtime by pallas_quant.pallas_verdict)."""
         from torchft_tpu.parallel.rehearsal import quant_kernel_reports
 
         rows = quant_kernel_reports()

@@ -86,7 +86,7 @@ def test_concurrent_stress() -> None:
 
 def test_timed_out_wait_rechecks_predicate() -> None:
     """A notify racing the deadline must not produce a spurious
-    TimeoutError when the lock became available (ADVICE r1)."""
+    TimeoutError when the lock became available."""
     lock = RWLock(timeout=5.0)
     lock.r_lock()  # predicate blocked for a writer
 

@@ -33,7 +33,7 @@ import os
 import threading
 import time
 from concurrent.futures import Future
-from typing import Callable, List, Optional, Tuple, Union
+from typing import Any, Callable, List, Optional, Tuple, Union
 
 import numpy as np
 
@@ -156,12 +156,18 @@ def _unpack(
 
 
 def _reduce_shards(
-    qs: np.ndarray, scs: np.ndarray, kind: str
+    qs: np.ndarray, scs: np.ndarray, kind: str, device: Optional[Any] = None
 ) -> Tuple[np.ndarray, np.ndarray]:
     """Dequant-sum-requant ``w`` shards; on a TPU both wire kinds run as
     the fused Pallas kernel so only 1-byte payloads cross HBM (fp8 falls
     back to XLA-compiled jnp on chips whose Mosaic can't lower the dtype —
-    see ``pallas_quant._pallas_kind_ok``)."""
+    see ``pallas_quant.pallas_verdict``).  The kernel runs on ``device``:
+    a caller whose data lives on a chip names that chip, so replicas
+    sharing a process never pile onto one.  Only the device-quantized
+    gradient path (``allreduce_prequantized``) has a chip to name; the
+    callers whose input is host memory (``allreduce_quantized``,
+    ``reduce_scatter_quantized``) pass None and get the process's first
+    local device."""
     if _use_device_reduce(qs[0].nbytes):
         import jax
 
@@ -174,8 +180,11 @@ def _reduce_shards(
                 [qs, np.zeros((w, pad, row_size), qs.dtype)], axis=1
             )
             scs = np.concatenate([scs, np.zeros((w, pad), np.float32)], axis=1)
+        device = device or jax.local_devices()[0]
         q_dev, s_dev = reduce_quantized_device(
-            jax.numpy.asarray(qs), jax.numpy.asarray(scs)[:, :, None], kind=kind
+            jax.device_put(qs, device),
+            jax.device_put(scs[:, :, None], device),
+            kind=kind,
         )
         q_host = np.asarray(q_dev)[:rows]
         s_host = np.asarray(s_dev).reshape(-1)[:rows]
@@ -285,6 +294,7 @@ def _allreduce_pipelined_sync(
     scales: np.ndarray,
     n: int,
     tag_base: int,
+    device: Optional[Any] = None,
 ) -> np.ndarray:
     """SUM-allreduce of quantized rows with window-level overlap.
 
@@ -395,7 +405,9 @@ def _allreduce_pipelined_sync(
                         for g in gathered
                     )
                 )
-                q_red, s_red = _reduce_shards(np.stack(qs), np.stack(scs), kind)
+                q_red, s_red = _reduce_shards(
+                    np.stack(qs), np.stack(scs), kind, device
+                )
             except BaseException as e:  # noqa: BLE001
                 err = err or e
                 gathered = None
@@ -805,6 +817,7 @@ def _hier_allreduce_quantized_sync(
     row_size: int,
     kind: str,
     tag_base: int,
+    device: Optional[Any] = None,
 ) -> np.ndarray:
     """Topology-aware quantized SUM-allreduce: reduce float32 once per host
     over shared memory, quantize ONCE PER HOST, run the windowed pipeline
@@ -836,7 +849,7 @@ def _hier_allreduce_quantized_sync(
             lead = comm.leader_comm()  # type: ignore[attr-defined]
             if lead.size() > 1:
                 out = _allreduce_pipelined_sync(
-                    lead, q, scales, flat.size, tag_base=tag_base
+                    lead, q, scales, flat.size, tag_base=tag_base, device=device
                 )
             else:
                 # single host: the wire round-trip degenerates but the
@@ -886,11 +899,14 @@ def allreduce_prequantized(
     q: np.ndarray,
     scales: np.ndarray,
     n: int,
+    device: Optional[Any] = None,
 ) -> np.ndarray:
     """SUM-allreduce of an already-quantized stream (1-byte rows + f32
     rowwise scales, e.g. produced on device by ``ops.pallas_quant``);
-    returns the dequantized float32 sum of length ``n``.  Synchronous —
-    callers layer Work/threading on top (``Manager.allreduce_prequantized``)."""
+    returns the dequantized float32 sum of length ``n``.  ``device`` is the
+    chip the stream was quantized on: the windowed reduce kernels run there
+    (see :func:`_reduce_shards`).  Synchronous — callers layer
+    Work/threading on top (``Manager.allreduce_prequantized``)."""
     scales = np.asarray(scales).reshape(-1)
     if comm.size() == 1 or getattr(comm, "is_passthrough", False):
         return dequantize_rowwise(q, scales, n, np.float32)
@@ -902,10 +918,11 @@ def allreduce_prequantized(
         flat = dequantize_rowwise(q, scales, n, np.float32)
         return _hier_allreduce_quantized_sync(
             comm, topo, flat, q.shape[1], _kind_of(q),
-            tag_base=DEVICE_QUANT_PIPELINE_TAG_BASE,
+            tag_base=DEVICE_QUANT_PIPELINE_TAG_BASE, device=device,
         )
     return _allreduce_pipelined_sync(
-        comm, q, scales, n, tag_base=DEVICE_QUANT_PIPELINE_TAG_BASE
+        comm, q, scales, n, tag_base=DEVICE_QUANT_PIPELINE_TAG_BASE,
+        device=device,
     )
 
 

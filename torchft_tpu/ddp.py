@@ -196,10 +196,7 @@ def allreduce_pytree(
     # overlaps the bucket assembly and the first ring.
     for leaf in leaves:
         if isinstance(leaf, jax.Array):
-            try:
-                leaf.copy_to_host_async()
-            except (AttributeError, RuntimeError):
-                pass
+            leaf.copy_to_host_async()
 
     # Bucket by dtype (each dtype needs its own ring), then split large
     # buckets at ``bucket_cap`` bytes and submit each as its own collective:
@@ -302,9 +299,39 @@ def allreduce_pytree(
     return out
 
 
-@jax.jit
-def _flatten_f32(leaves: Any) -> jax.Array:
-    return jnp.concatenate([l.astype(jnp.float32).reshape(-1) for l in leaves])
+@functools.partial(jax.jit, static_argnames=("kind",))
+def _quantize_leaf(leaf: jax.Array, kind: str) -> Tuple[jax.Array, jax.Array]:
+    from torchft_tpu.ops.pallas_quant import quantize_rowwise_device
+
+    return quantize_rowwise_device(leaf.reshape(-1), kind=kind)
+
+
+@functools.partial(jax.jit, static_argnames=("shape", "starts"))
+def _stitch(blocks: List[jax.Array], shape: Tuple, starts: Tuple) -> jax.Array:
+    out = jnp.zeros(shape, blocks[0].dtype)
+    for block, start in zip(blocks, starts):
+        out = jax.lax.dynamic_update_slice(out, block, start)
+    return out
+
+
+def _on_one_device(leaf: jax.Array, turn: int) -> jax.Array:
+    """``leaf`` whole, as a single-device array on one of ITS OWN devices.
+    A leaf that one device already holds whole (one chip, or replicated) is
+    that device's buffer, no copy.  The blocks of a leaf sharded across the
+    replica's chips are copied device to device onto one of them, ``turn``
+    rotating which (so neither the copies nor the quantizers pile onto the
+    first chip), and stitched there; nothing passes through the host."""
+    shards = _unique_local_shards(leaf)  # they tile a fully-addressable leaf
+    if len(shards) == 1:
+        (only,) = shards.values()
+        return only.data
+    devices = sorted(leaf.sharding.addressable_devices, key=lambda d: d.id)
+    target = devices[turn % len(devices)]
+    return _stitch(
+        [jax.device_put(s.data, target) for s in shards.values()],
+        shape=leaf.shape,
+        starts=tuple(tuple(dim[0] for dim in key) for key in shards),
+    )
 
 
 def _allreduce_pytree_device_quantized(
@@ -312,35 +339,70 @@ def _allreduce_pytree_device_quantized(
 ) -> Work:
     """Device quantize → Manager-orchestrated wire pipeline → device put.
 
+    The wire stream is WHOLE leaves in tree order, each quantized by itself
+    (its rows padded to the kernel's block), so the stream is a function of
+    the leaf shapes alone and never of the replica's mesh: a wounded replica
+    re-lowered onto fewer chips (degraded mode) still lines up row for row
+    with its healthy peers, as the host path's whole-leaf buckets do.  That
+    rules out quantizing shard by shard (a shard's 1024-element rows are not
+    the leaf's).  Nor may the kernel see a sharded leaf: a bare
+    ``pallas_call`` is not SPMD-partitionable and would gather the leaf onto
+    EVERY chip.  So each leaf is brought whole onto one of the replica's own
+    devices (:func:`_on_one_device`) and quantized there.  No float32 copy
+    of the whole gradient is made: the quantizer's float temporaries are one
+    leaf at a time, and the gathered leaves are at worst one more copy of
+    the gradient, spread over the replica's chips.
+
     The fault-tolerance orchestration (quorum wait, participation zeroing,
     normalization, error funnel) lives in ``Manager.allreduce_prequantized``
     — this function only handles device-side quantization and pytree
     reassembly.  Returns a pending Work (the wire pipeline runs off-thread).
     """
-    from torchft_tpu.ops.pallas_quant import quantize_rowwise_device
+    from torchft_tpu.ops.pallas_quant import ROW_SIZE
     from torchft_tpu.quantization import quant_kind
 
     try:
-        flat = _flatten_f32(leaves)
         # wire kind (int8 / fp8) from TORCHFT_QUANT_KIND; everything
         # downstream — the pipelined ring, the reduce kernels, the
         # dequantize — dispatches on the payload dtype
-        q, scales = quantize_rowwise_device(flat, kind=quant_kind())
+        kind = quant_kind()
+        # dispatch every quantizer before fetching any result: the kernels
+        # queue on their devices while the host copies drain in order
+        quantized = [
+            _quantize_leaf(_on_one_device(leaf, i), kind)
+            for i, leaf in enumerate(leaves)
+        ]
+        # stream offset (in elements) of every leaf, from its padded rows
+        offsets = [0]
+        for q, _s in quantized:
+            offsets.append(offsets[-1] + q.shape[0] * ROW_SIZE)
         # the only HBM→host bytes: 1-byte payload + f32 rowwise scales
-        q_np, s_np = np.asarray(q), np.asarray(scales)
-        work = manager.allreduce_prequantized(q_np, s_np, int(flat.shape[0]))
+        q_np = np.concatenate([np.asarray(q) for q, _s in quantized])
+        s_np = np.concatenate([np.asarray(s).reshape(-1) for _q, s in quantized])
+        # the collective's device-side reduce runs where this replica's
+        # gradients live, not on the process-wide default device
+        (device,) = quantized[0][0].devices()
+        work = manager.allreduce_prequantized(
+            q_np, s_np, offsets[-1], device=device
+        )
     except Exception as e:  # noqa: BLE001 — errors never reach the train loop
         manager.report_error(e)
         return DummyWork(jax.tree_util.tree_unflatten(treedef, leaves))
 
     def _reassemble(avg: np.ndarray) -> Any:
         out = []
-        off = 0
-        for leaf in leaves:
-            n = leaf.size
-            host_val = avg[off : off + n].reshape(leaf.shape)
-            out.append(jax.device_put(host_val.astype(leaf.dtype), leaf.sharding))
-            off += n
+        for leaf, off in zip(leaves, offsets):
+            whole = avg[off : off + leaf.size].reshape(leaf.shape)
+            # each chip receives only its own block of the leaf
+            out.append(
+                _assemble_sharded(
+                    leaf.shape,
+                    leaf.sharding,
+                    leaf.dtype,
+                    leaf.addressable_shards,
+                    lambda _key, s, whole=whole: whole[s.index],
+                )
+            )
         return jax.tree_util.tree_unflatten(treedef, out)
 
     out = manager.wrap_work(

@@ -1,6 +1,6 @@
 """FT x SPMD composition drill: real replicas driving real meshes.
 
-The round-1 gap (VERDICT weak #2): every mesh-parallel validation mocked the
+The round-1 gap: every mesh-parallel validation mocked the
 replica dimension with a DummyCommunicator, so the *composition* — a real
 DCN-tier communicator ringing gradients between replica groups that each
 drive a compiled HSDP mesh, plus kill/heal across that boundary — was never
@@ -1503,8 +1503,15 @@ def joint_ft_spmd_drill(
     timeout_s: float = 30.0,
     quantize_outer: bool = False,
     heal_source_chaos: bool = False,
+    config: Optional[Any] = None,
+    heartbeat_timeout_ms: int = 1000,
 ) -> Dict[str, Any]:
     """Run the drill and return summary facts (asserts internally).
+
+    ``config`` is the :class:`~torchft_tpu.models.llama.LlamaConfig` each
+    replica trains (default ``llama_debug()``).  ``timeout_s`` and
+    ``heartbeat_timeout_ms`` are sized for that toy: at a real width a cold
+    compile, a multi-GB ring and the heal all sit inside them.
 
     ``heal_source_chaos`` (requires ``num_replicas >= 3`` so the rejoiner
     has 2+ striped heal sources) arms one SURVIVOR's checkpoint transport
@@ -1547,7 +1554,7 @@ def joint_ft_spmd_drill(
         # window before a partial quorum is issued
         join_timeout_ms=1500 if heal_source_chaos else 200,
         quorum_tick_ms=20,
-        heartbeat_timeout_ms=1000,
+        heartbeat_timeout_ms=heartbeat_timeout_ms,
     )
     restarts = [0]
     healed = [False]
@@ -1579,7 +1586,7 @@ def joint_ft_spmd_drill(
             tp=tp,
             devices=devices[idx * per_replica : (idx + 1) * per_replica],
         )
-        model = Llama(llama_debug(), mesh=mesh)
+        model = Llama(config or llama_debug(), mesh=mesh)
         first_life = True
         while True:
             transport = None
@@ -1712,7 +1719,7 @@ def joint_ft_spmd_drill(
             futures = [
                 pool.submit(replica_main, i) for i in range(num_replicas)
             ]
-            states = [f.result(timeout=300.0) for f in futures]
+            states = [f.result(timeout=max(300.0, 10 * timeout_s)) for f in futures]
     finally:
         for m in zombies:
             try:

@@ -299,24 +299,12 @@ _k("TPUFT_BENCH_OBS_STEPS", "int", "40",
    "Measured steps per leg of the observability-overhead phase", "bench")
 _k("TPUFT_BENCH_COORD_REPLICAS", "int", "120 cpu / 500 tpu",
    "Simulated replicas driven by the coordination scale phase", "bench")
-_k("TPUFT_BENCH_PROBE_TIMEOUT_S", "float", "180",
-   "Backend-executes probe deadline", "bench")
-_k("TPUFT_BENCH_PROBE_WINDOW_S", "float", "900",
-   "Total window spent re-probing a wedged backend at startup", "bench")
-_k("TPUFT_BENCH_REPROBE_WINDOW_S", "float", "60",
-   "Mid-run recovery: window spent re-probing after a wedge", "bench")
-_k("TPUFT_BENCH_REPROBE_BUDGET_S", "float", "1500",
-   "Mid-run recovery: budget for the phase-A recapture subprocess", "bench")
-_k("TPUFT_BENCH_PHASE_FLOOR_S", "float", "1500",
-   "Minimum per-phase share of the remaining budget", "bench")
 _k("TPUFT_BENCH_TOTAL_BUDGET_S", "float", "2100",
    "Soft wall-clock budget for the whole bench run", "bench")
-_k("TPUFT_BENCH_HARD_DEADLINE_S", "float", "budget+1200",
+_k("TPUFT_BENCH_HARD_DEADLINE_S", "float", "budget+420",
    "Hard watchdog: emit a partial artifact and exit 0 at this age", "bench")
 _k("TPUFT_PEAK_TFLOPS", "float", "auto",
    "Override the per-chip peak TFLOP/s used for MFU math", "bench")
-_k("TPUFT_SWEEP_OUT", "str", "unset",
-   "mfu_sweep artifact output path", "bench")
 
 
 def _parse_error(name: str, raw: str, expected: str) -> ValueError:

@@ -101,6 +101,13 @@ class ReplicaSupervisor:
     (``WorldSizeMode.FIXED_WITH_SPARES``, ``torchft/manager.py:123-139``).
     Workers that ignore the env var simply run twice, so only enable it for
     standby-aware commands.
+
+    Premise: the spare can initialize its backend WHILE the active process
+    runs.  A TPU chip belongs to one process, so on a host whose chips the
+    active process holds, the spare fails or hangs at its backend start and
+    is re-warmed for ever.  Warm standbys are for CPU fleets and for hosts
+    with chips to spare (``docs/operations.md`` §4); inside one host's
+    chips, replicas are threads of one process.
     """
 
     def __init__(

@@ -32,7 +32,7 @@ import threading
 import time
 import uuid
 from enum import Enum
-from typing import Callable, Dict, List, Optional, Tuple, TypeVar, Union, cast
+from typing import Any, Callable, Dict, List, Optional, Tuple, TypeVar, Union, cast
 
 import numpy as np
 
@@ -1355,11 +1355,17 @@ class Manager:
             return _failed_fast(DummyWork(data))
 
     def allreduce_prequantized(
-        self, q: np.ndarray, scales: np.ndarray, n: int
+        self,
+        q: np.ndarray,
+        scales: np.ndarray,
+        n: int,
+        device: Optional[Any] = None,
     ) -> Work:
         """Fault-tolerant SUM-allreduce of an already-quantized stream (int8
         rows + rowwise f32 scales, e.g. quantized on device by
         ``ops.pallas_quant``), normalized by ``num_participants()``.
+        ``device`` is where the collective's device-side reduce runs (the
+        caller's own chip; see ``collectives.allreduce_prequantized``).
 
         Same orchestration contract as :meth:`allreduce`: waits the quorum,
         zeroes the contribution of non-participants, swallows errors into a
@@ -1398,7 +1404,9 @@ class Manager:
 
         def _run() -> None:
             try:
-                summed = allreduce_prequantized(self._comm, q_in, s_in, n)
+                summed = allreduce_prequantized(
+                    self._comm, q_in, s_in, n, device=device
+                )
                 fut.set_result(summed / num_participants)
             except Exception as e:  # noqa: BLE001 — funnel, never raise
                 self.report_error(e)

@@ -23,6 +23,7 @@ Design choices for the TPU/XLA compilation model:
 from __future__ import annotations
 
 import functools
+import logging
 import os
 from dataclasses import dataclass
 from typing import Any, Dict, Optional, Tuple
@@ -31,6 +32,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
+
+logger = logging.getLogger(__name__)
 
 
 @dataclass(frozen=True)
@@ -103,12 +106,22 @@ def llama_debug(sp_axis: Optional[str] = None) -> LlamaConfig:
     )
 
 
+# presets by name, for entry points that take the model as an argument
+CONFIGS = {
+    "llama_debug": llama_debug,
+    "llama3_8b": llama3_8b,
+    "llama3_70b": llama3_70b,
+}
+
+
 class Llama:
     def __init__(self, config: LlamaConfig, mesh: Optional[Any] = None) -> None:
         """``mesh`` is required when ``config.sp_axis`` is set: the ring
         attention shard_map needs the concrete mesh object."""
         self.config = config
         self.mesh = mesh
+        # set when attention is traced: "flash", "ring" or "naive: <why>"
+        self.attention_path: Optional[str] = None
 
     # ------------------------------------------------------------------
     # params
@@ -248,36 +261,53 @@ class Llama:
             min(seq, _env("TORCHFT_FLASH_BLOCK_K")),
         )
 
-    def _use_flash(self, seq: int) -> bool:
-        """Dispatch to the fused Pallas kernel (``ops/flash_attention.py``)
-        when it applies: TPU backend (or forced), flash-friendly shapes, no
-        ring attention.  ``TORCHFT_FLASH`` = 1 forces on (interpret mode off
-        TPU), 0 kills it, unset = auto."""
+    def _flash_refusal(self, seq: int) -> Optional[str]:
+        """Why the fused Pallas kernel (``ops/flash_attention.py``) does NOT
+        apply at this sequence length, or None when it does: TPU backend (or
+        forced), flash-friendly shapes, no ring attention.  ``TORCHFT_FLASH``
+        = 1 forces on (interpret mode off TPU), 0 kills it, unset = auto."""
         cfg = self.config
         if cfg.sp_axis is not None:
-            return False
+            return f"sp_axis={cfg.sp_axis!r} routes to ring attention"
         env = os.environ.get("TORCHFT_FLASH", "")
         if env == "0":
-            return False
+            return "TORCHFT_FLASH=0"
         # seq % 8: Mosaic requires 8-divisible sublane dims — a 130-long seq
         # in [128, 512) would otherwise pick block_q=seq and fail to lower.
         # the divisibility gate uses the RESOLVED block sizes, so an env
-        # override that doesn't divide seq falls back to the naive path
-        # instead of crashing the trace
+        # override that doesn't divide seq takes the naive path instead of
+        # crashing the trace
         block_q, block_k = self._flash_blocks(seq)
         if seq < 128 or seq % 8 or seq % block_q or seq % block_k:
-            return False
+            return (
+                f"seq={seq} is under 128 or not divisible by 8 and the "
+                f"blocks ({block_q}, {block_k})"
+            )
         if getattr(self, "_disable_flash", False):
-            return False
+            return "disabled by the pipeline wrapper"
         if env == "1":
-            return True
+            return None
         # auto: single-device programs use the bare kernel; multi-device
         # needs a mesh for the shard_map variant (a bare pallas_call is not
         # SPMD-partitionable — inside a tp/fsdp-sharded jit it would force
         # operand replication)
-        if self._assumed_backend() != "tpu":
-            return False
-        return jax.device_count() == 1 or self._flash_mesh() is not None
+        backend = self._assumed_backend()
+        if backend != "tpu":
+            return f"backend is {backend}, not tpu"
+        if jax.device_count() > 1 and self._flash_mesh() is None:
+            return (
+                f"{jax.device_count()} devices and no (dp, tp) mesh on the "
+                "model for the shard_map variant"
+            )
+        return None
+
+    def _use_flash(self, seq: int) -> bool:
+        return self._flash_refusal(seq) is None
+
+    def _record_attention_path(self, path: str) -> None:
+        if path != self.attention_path:
+            logger.info("attention path: %s", path)
+        self.attention_path = path
 
     def _flash_mesh(self) -> Optional[Any]:
         """The mesh for ``flash_attention_sharded``, if attention under it
@@ -301,7 +331,10 @@ class Llama:
         """Causal GQA attention. q: [B,S,H,D], k/v: [B,S,KV,D]."""
         cfg = self.config
 
-        if self._use_flash(q.shape[1]):
+        # trace-time record of the path taken ("flash", or "naive: <why>"):
+        # a silent naive path costs the [B, H, S, S] score matrix in HBM
+        refusal = self._flash_refusal(q.shape[1])
+        if refusal is None:
             from torchft_tpu.ops.flash_attention import (
                 flash_attention,
                 flash_attention_sharded,
@@ -318,21 +351,30 @@ class Llama:
             if mesh_size == 1:
                 # bare kernel: single-device programs, or forced via env
                 # without a mesh (then operands replicate — caller's call)
+                self._record_attention_path("flash")
                 return flash_attention(
                     q, k, v, causal=True, interpret=interpret,
                     block_q=block_q, block_k=block_k,
                 )
             bp = mesh.shape["dp"] * mesh.shape.get("fsdp", 1)
+            tp = mesh.shape["tp"]
             if (
                 B % bp == 0  # batch shards over (dp, fsdp)
-                and H % mesh.shape["tp"] == 0
-                and cfg.n_kv_heads % mesh.shape["tp"] == 0
+                and H % tp == 0
+                and cfg.n_kv_heads % tp == 0
             ):
+                self._record_attention_path("flash")
                 return flash_attention_sharded(
                     q, k, v, mesh=mesh, causal=True, interpret=interpret,
                     block_q=block_q, block_k=block_k,
                 )
-            # mesh present but shapes don't shard evenly: naive path below
+            refusal = (
+                f"batch={B} heads={H}/{cfg.n_kv_heads} do not divide the "
+                f"mesh (dp*fsdp={bp}, tp={tp})"
+            )
+        self._record_attention_path(
+            "ring" if cfg.sp_axis is not None else f"naive: {refusal}"
+        )
 
         if cfg.sp_axis is not None:
             # the ring ships GQA K/V un-repeated (group-factor fewer

@@ -7,16 +7,21 @@ wire protocol of the Python implementations, so the Python clients
 (``RpcClient`` subclasses) work against either — the classes here mirror the
 Python servers' construction surface and are drop-in replacements.
 
-The library is built on demand with ``make`` (g++ -O3); if the toolchain or
-build fails, ``available()`` returns False and callers fall back to the
-pure-Python implementations.
+The library is built on demand with ``make`` (g++ -O3) and stamped with a
+hash of its sources, recipe and the host CPU's features; a binary whose
+stamp does not match is rebuilt, never loaded.  If the toolchain or build
+fails, ``available()`` returns False (``load_error()`` says why) and
+``TORCHFT_TIER=auto`` callers fall back to the pure-Python implementations.
 """
 
 from __future__ import annotations
 
 import ctypes
+import fcntl
+import hashlib
 import logging
 import os
+import platform
 import queue
 import subprocess
 import threading
@@ -77,23 +82,77 @@ _DTYPE_CODES = {
 _OP_CODES = {ReduceOp.SUM: 0, ReduceOp.AVG: 0, ReduceOp.MAX: 1, ReduceOp.MIN: 2}
 
 
+def _build_stamp(native_dir: str) -> str:
+    """Hash of everything the binary depends on: the sources, the build
+    recipe (Makefile plus the CXX/CXXFLAGS/LDFLAGS overrides it honours)
+    and — because the recipe says ``-march=native`` — this host's CPU
+    feature flags.  mtimes do not survive a copy of the tree, so freshness
+    is decided by content."""
+    digest = hashlib.sha256()
+    for name in sorted(os.listdir(native_dir)):
+        if name.endswith((".cc", ".h")) or name == "Makefile":
+            digest.update(name.encode())
+            with open(os.path.join(native_dir, name), "rb") as f:
+                digest.update(f.read())
+    for var in ("CXX", "CXXFLAGS", "LDFLAGS"):
+        digest.update(f"{var}={os.environ.get(var, '')}".encode())
+    try:
+        with open("/proc/cpuinfo") as f:
+            cpu = next(
+                (l for l in f if l.startswith(("flags", "Features"))), ""
+            )
+    except OSError:
+        cpu = ""
+    digest.update(platform.machine().encode() + cpu.encode())
+    return digest.hexdigest()
+
+
+def _stamped(lib_path: str, stamp: str) -> bool:
+    try:
+        with open(lib_path + ".stamp") as f:
+            return os.path.exists(lib_path) and f.read() == stamp
+    except OSError:
+        return False
+
+
 def _build_lib(native_dir: str, lib_path: str) -> None:
-    sources = [
-        os.path.join(native_dir, f)
-        for f in os.listdir(native_dir)
-        if f.endswith((".cc", ".h"))
-    ]
-    if os.path.exists(lib_path):
-        lib_mtime = os.path.getmtime(lib_path)
-        if all(os.path.getmtime(s) <= lib_mtime for s in sources):
+    """Make ``lib_path`` a build of THESE sources for THIS machine: a binary
+    without a matching stamp (copied from another host, left over from an
+    older checkout) is rebuilt, never loaded.  A binary whose stamp matches
+    needs nothing written, so a read-only install loads it.  The lock file
+    serializes concurrent first builds (a launcher starts its replicas
+    together)."""
+    stamp = _build_stamp(native_dir)
+    if _stamped(lib_path, stamp):
+        return
+    try:
+        lock = open(lib_path + ".lock", "w")
+    except OSError as e:
+        raise RuntimeError(
+            f"{lib_path} is missing or was not built here from these "
+            f"sources, and {native_dir} cannot be written ({e}); run `make "
+            "-C native` as a user who can, or point TORCHFT_NATIVE_DIR at a "
+            "writable copy of native/"
+        ) from e
+    with lock:
+        fcntl.flock(lock, fcntl.LOCK_EX)
+        if _stamped(lib_path, stamp):  # a peer built it while we waited
             return
-    logger.info("building native runtime (make -C %s)", native_dir)
-    subprocess.run(
-        ["make", "-C", native_dir],
-        check=True,
-        capture_output=True,
-        timeout=300,
-    )
+        logger.info("building native runtime (make -B -C %s)", native_dir)
+        try:
+            subprocess.run(
+                ["make", "-B", "-C", native_dir, "libtpuft.so"],
+                check=True,
+                capture_output=True,
+                text=True,
+                timeout=300,
+            )
+        except subprocess.CalledProcessError as e:
+            raise RuntimeError(
+                f"native build failed (rc {e.returncode}):\n{e.stderr}"
+            ) from e
+        with open(lib_path + ".stamp", "w") as f:
+            f.write(stamp)
 
 
 def _load() -> Optional[ctypes.CDLL]:
@@ -274,6 +333,13 @@ def _load() -> Optional[ctypes.CDLL]:
 
 def available() -> bool:
     return _load() is not None
+
+
+def load_error() -> Optional[str]:
+    """Why the native runtime did not build or load (the compiler's or the
+    loader's message), or None when it did."""
+    _load()
+    return _lib_error
 
 
 # ---------------------------------------------------------------------------

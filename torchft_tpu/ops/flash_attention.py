@@ -533,8 +533,6 @@ def flash_attention_sharded(
     """
     from jax.sharding import PartitionSpec as P
 
-    from torchft_tpu.parallel._compat import shard_map as _smap
-
     B, S, H, D = q.shape
     KV = k.shape[2]
     dp = mesh.shape[dp_axis]
@@ -557,10 +555,11 @@ def flash_attention_sharded(
         block_k=block_k,
         interpret=interpret,
     )
-    fn = _smap(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,
+        check_vma=False,
     )
     return fn(q, k, v)

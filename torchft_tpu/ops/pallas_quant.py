@@ -21,19 +21,22 @@ blocks of 32 sublanes to satisfy 1-byte tiling ((32, 128) min tile).
 Off-TPU the same math runs as plain jnp (still jittable) — Pallas on CPU is
 interpreter-only, so tests exercise the jnp path plus ``interpret=True``
 equivalence on tiny shapes.  On TPU, fp8 Mosaic support depends on the
-chip generation; a one-shot compile probe (:func:`_pallas_kind_ok`) falls
-back to the jnp path (still fused device code, XLA-compiled) when the
-kernel can't lower.
+chip generation; a one-shot compile probe (:func:`pallas_verdict`) sends
+fp8 to the jnp path (still fused device code, XLA-compiled) when the kernel
+can't lower, and says so once at WARNING.
 """
 
 from __future__ import annotations
 
 import functools
+import logging
 import threading
-from typing import Dict, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+
+logger = logging.getLogger(__name__)
 
 ROW_SIZE = 1024  # multiple of the 128-lane width
 BLOCK_ROWS = 32  # 1-byte min tile sublane count
@@ -91,49 +94,75 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-_KIND_OK: Dict[str, bool] = {}
-_KIND_OK_LOCK = threading.Lock()
+# kind -> None when Mosaic compiles all three kernels on this chip, else the
+# compiler's message.  Filled once per process by :func:`pallas_verdict`.
+_VERDICTS: Dict[str, Optional[str]] = {}
+_VERDICT_LOCK = threading.Lock()
 
 
-def _pallas_kind_ok(kind: str) -> bool:
-    """One-shot probe: can this chip's Mosaic lower the wire dtype?  int8 is
-    universal; fp8 conversion support varies by TPU generation.  Probes ALL
-    THREE kernels gated on it — the quantize store, the structurally
-    different reduce ([w, rows, R] fp8 loads + multiply), and the dequant
-    load-with-multiply — because each can fail independently and
-    :func:`dequantize_rowwise_device` dispatches on this same verdict.  The
-    verdict is published only AFTER every probe finishes (under a lock):
-    concurrent collectives must never see a provisional True and take an
-    un-lowerable Pallas branch."""
-    if kind == INT8:
-        return True
-    with _KIND_OK_LOCK:
-        if kind in _KIND_OK:
-            return _KIND_OK[kind]
-        try:
-            x = jnp.ones((BLOCK_ROWS * ROW_SIZE,), jnp.float32)
-            jax.jit(
-                functools.partial(
-                    _pallas_quantize,
-                    row_size=ROW_SIZE,
-                    kind=kind,
-                    interpret=False,
+def _probe_compile(kind: str) -> Optional[str]:
+    """Compile the quantize store, the structurally different reduce
+    ([w, rows, R] wire loads + multiply) and the dequant load-with-multiply
+    for ``kind``: each can fail independently, and all three dispatchers
+    share the verdict."""
+    wire = _wire_jnp_dtype(kind)
+    x = jax.ShapeDtypeStruct((BLOCK_ROWS * ROW_SIZE,), jnp.float32)
+    qs = jax.ShapeDtypeStruct((2, BLOCK_ROWS, ROW_SIZE), wire)
+    sc = jax.ShapeDtypeStruct((2, BLOCK_ROWS, 1), jnp.float32)
+    try:
+        jax.jit(
+            functools.partial(
+                _pallas_quantize, row_size=ROW_SIZE, kind=kind, interpret=False
+            )
+        ).lower(x).compile()
+        jax.jit(
+            functools.partial(_pallas_reduce, kind=kind, interpret=False)
+        ).lower(qs, sc).compile()
+        jax.jit(functools.partial(_pallas_dequant, interpret=False)).lower(
+            jax.ShapeDtypeStruct(qs.shape[1:], wire),
+            jax.ShapeDtypeStruct(sc.shape[1:], jnp.float32),
+        ).compile()
+    except Exception as e:  # noqa: BLE001 — the verdict carries the message
+        return f"{type(e).__name__}: {e}"
+    return None
+
+
+def pallas_verdict(kind: str) -> Optional[str]:
+    """Can this chip's Mosaic compile the ``kind`` kernels?  None when it
+    can, else the compiler's message (fp8 conversion support varies by TPU
+    generation).  Probed once per process on a TPU backend and logged once
+    at WARNING when negative; published only AFTER every probe finishes
+    (under a lock), so concurrent collectives never see a provisional
+    answer."""
+    with _VERDICT_LOCK:
+        if kind not in _VERDICTS:
+            _VERDICTS[kind] = message = _probe_compile(kind)
+            if message is not None:
+                logger.warning(
+                    "Pallas %s quantization kernels do not compile on %s: %s",
+                    kind,
+                    jax.devices()[0].device_kind,
+                    message,
                 )
-            ).lower(x).compile()
-            qs = jnp.zeros((2, BLOCK_ROWS, ROW_SIZE), _wire_jnp_dtype(kind))
-            sc = jnp.ones((2, BLOCK_ROWS, 1), jnp.float32)
-            jax.jit(
-                functools.partial(_pallas_reduce, kind=kind, interpret=False)
-            ).lower(qs, sc).compile()
-            q1 = jnp.zeros((BLOCK_ROWS, ROW_SIZE), _wire_jnp_dtype(kind))
-            s1 = jnp.ones((BLOCK_ROWS, 1), jnp.float32)
-            jax.jit(
-                functools.partial(_pallas_dequant, interpret=False)
-            ).lower(q1, s1).compile()
-            _KIND_OK[kind] = True
-        except Exception:  # noqa: BLE001 — any lowering failure → jnp fallback
-            _KIND_OK[kind] = False
-        return _KIND_OK[kind]
+        return _VERDICTS[kind]
+
+
+def _use_pallas(kind: str, interpret: bool) -> bool:
+    """Dispatch shared by the three public entry points.  Off TPU the jnp
+    math is the only compiled path the backend has.  On TPU a negative
+    verdict sends fp8 to XLA-compiled jnp (logged by :func:`pallas_verdict`
+    — an older chip generation, not a bug); int8 lowers on every generation,
+    so a negative int8 verdict is a kernel bug and the caller fails."""
+    if interpret:
+        return True
+    if not _on_tpu():
+        return False
+    message = pallas_verdict(kind)
+    if message is not None and kind == INT8:
+        raise RuntimeError(
+            f"Pallas int8 quantization kernels failed to compile: {message}"
+        )
+    return message is None
 
 
 def _pallas_quantize(
@@ -180,7 +209,7 @@ def quantize_rowwise_device(
     scales write), elsewhere — or when the chip can't lower the wire dtype
     — as plain jnp.
     """
-    if not (interpret or (_on_tpu() and _pallas_kind_ok(kind))):
+    if not _use_pallas(kind, interpret):
         x, _rows = _pad_to_rows(flat, row_size)
         return _quant_math(x, kind)
     return _pallas_quantize(flat, row_size, kind, interpret)
@@ -252,7 +281,7 @@ def reduce_quantized_device(
     """
     if scales.ndim == 2:
         scales = scales[:, :, None]
-    if not (interpret or (_on_tpu() and _pallas_kind_ok(kind))):
+    if not _use_pallas(kind, interpret):
         total = jnp.sum(qs.astype(jnp.float32) * scales, axis=0)
         return _quant_math(total, kind)
     return _pallas_reduce(qs, scales, kind, interpret)
@@ -290,7 +319,7 @@ def dequantize_rowwise_device(
     """(wire [rows, row_size], f32 [rows, 1]) → float32 [n].  The wire kind
     is carried by ``q.dtype``."""
     kind = INT8 if q.dtype == jnp.int8 else FP8
-    if not (interpret or (_on_tpu() and _pallas_kind_ok(kind))):
+    if not _use_pallas(kind, interpret):
         out = q.astype(jnp.float32) * scales
         return out.reshape(-1)[:n]
     out = _pallas_dequant(q, scales, interpret)

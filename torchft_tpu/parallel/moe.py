@@ -29,7 +29,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from torchft_tpu.parallel._compat import shard_map as _shard_map
 
 
 @dataclass(frozen=True)
@@ -159,7 +158,7 @@ class MoE:
         if self.mesh is None:
             out = self._apply_dense(params, flat)
         else:
-            fn = _shard_map(
+            fn = jax.shard_map(
                 partial(self._apply_ep_local),
                 mesh=self.mesh,
                 in_specs=(

@@ -34,7 +34,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from torchft_tpu.models.llama import Llama, LlamaConfig
 
-from torchft_tpu.parallel._compat import shard_map as _shard_map
 
 
 def _pipeline_local(
@@ -133,7 +132,7 @@ def pipeline_spmd(
     # x_mb is [M, mb, S, D]: seq (dim 2) shards over sp inside the manual
     # region; everything else about the schedule is sp-oblivious
     x_spec = P(None, None, sp_axis, None) if sp_axis else P()
-    out_mb = _shard_map(
+    out_mb = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(axis), x_spec),

@@ -16,15 +16,33 @@ diagonal block is masked triangularly, earlier blocks attend fully.
 
 from __future__ import annotations
 
+import logging
 import os
 from functools import partial
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from torchft_tpu.parallel._compat import shard_map as _shard_map
+
+logger = logging.getLogger(__name__)
+
+
+def _block_flash_refusal(S: int) -> Optional[str]:
+    """Why the per-block math is NOT the Pallas kernel at local block length
+    ``S``, or None when it is (``TORCHFT_FLASH`` forces/kills; interpret
+    mode off TPU) — the same gates as ``Llama._flash_refusal``."""
+    env = os.environ.get("TORCHFT_FLASH", "")
+    if env == "0":
+        return "TORCHFT_FLASH=0"
+    # S % 8: Mosaic sublane-divisibility
+    if S < 128 or S % 8 or S % min(512, S):
+        return f"block length {S} is under 128 or not divisible by 8 and 512"
+    if env != "1" and jax.default_backend() != "tpu":
+        return f"backend is {jax.default_backend()}, not tpu"
+    return None
 
 
 def _ring_attention_local(
@@ -49,17 +67,12 @@ def _ring_attention_local(
     scale = 1.0 / np.sqrt(D)
 
     # per-block flash: the Pallas kernel replaces the einsum-softmax block
-    # math when block shapes qualify (trace-time decision; TORCHFT_FLASH
-    # env forces/kills, interpret off-TPU)
-    env = os.environ.get("TORCHFT_FLASH", "")
-    if (
-        env != "0"
-        and S >= 128
-        and S % 8 == 0  # Mosaic sublane-divisibility, same gate as _use_flash
-        and S % min(512, S) == 0
-        and (env == "1" or jax.default_backend() == "tpu")
-    ):
+    # math when block shapes qualify (trace-time decision, logged)
+    refusal = _block_flash_refusal(S)
+    if refusal is None:
+        logger.info("ring attention block math: flash")
         return _ring_attention_flash(q, k, v, axis_name, n, my_idx)
+    logger.info("ring attention block math: naive: %s", refusal)
 
     q32 = q.astype(jnp.float32)
     # accumulators: running output (unnormalized), row max, denominator
@@ -201,7 +214,7 @@ def ring_attention_sharded(
     """
     batch_entry = ("dp", "fsdp") if "fsdp" in mesh.shape else "dp"
     spec = P(batch_entry, sp_axis, "tp", None)
-    fn = _shard_map(
+    fn = jax.shard_map(
         partial(_ring_attention_local, axis_name=sp_axis),
         mesh=mesh,
         in_specs=(spec, spec, spec),
