@@ -1,0 +1,6 @@
+"""``device_idle_pct`` in the cells with two replica groups, where the end-to-end
+metric is ``ddp_tokens_per_s_per_chip``."""
+
+from ftbench.sources import split_for
+
+META, read = split_for("device_idle_pct", "ddp_tokens_per_s_per_chip")

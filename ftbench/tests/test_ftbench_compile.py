@@ -1,0 +1,102 @@
+"""Each cell's step compiled at its real size for a described v5e:2x2.
+
+No chip is needed: the TPU's compiler is installed and compiles for a chip
+that is described and not attached.  The topology is described inside a
+fixture (only the worker that runs this file loads libtpu), and nothing here
+is a chip run: it shows that the compiler takes the programs and what it
+says they need, not how fast they are.
+"""
+
+import json
+import os
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+HBM_BYTES = 16e9
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure to describe is a skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture()
+def no_compile_cache():
+    # a compile for a described chip is written to the cache but cannot be
+    # read back without the chip; keep these out of it
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+def _config(config_name):
+    """A configuration's file, or ``2x2``: the four-chip layout a later PR
+    adds (PERF.md section 7), which is the two-replica file at depth 2 in
+    two groups of two chips."""
+    name = "mistral-7b-v0.3-2on1" if config_name == "2x2" else config_name
+    with open(os.path.join(ROOT, "ftbench", "configs", name + ".json")) as f:
+        config = json.load(f)
+    if config_name == "2x2":
+        config.update(num_hidden_layers=2,
+                      layout=dict(chips_per_group=2, groups_share_chip=False, fsdp=2))
+    return config
+
+
+def _compile_step(topo, config_name, seq, monkeypatch):
+    import jax
+    import jax.numpy as jnp
+    import optax
+
+    from ftbench.harness import llama_config
+    from torchft_tpu.models.llama import Llama
+    from torchft_tpu.parallel.hsdp import fsdp_shardings, make_grad_step, make_update_step
+    from torchft_tpu.parallel.mesh import make_mesh
+
+    # the model asks jax.default_backend(), which is the CPU here
+    monkeypatch.setenv("TORCHFT_FLASH_PLATFORM", "tpu")
+    config = _config(config_name)
+    per_group = config["layout"]["chips_per_group"]
+    mesh = make_mesh(fsdp=per_group, devices=list(topo.devices)[:per_group])
+    model = Llama(llama_config(config))
+    params_sh, batch_sh = fsdp_shardings(model, mesh)
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    params = jax.tree_util.tree_map(
+        lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh), shapes, params_sh
+    )
+    batch = tuple(jax.ShapeDtypeStruct((per_group, seq), jnp.int32, sharding=sh) for sh in batch_sh)
+    grad = make_grad_step(model, mesh).lower(params, batch).compile()
+    assert model.attention_path == "flash"
+    assert "tpu_custom_call" in grad.as_text()
+    tx = optax.adamw(config["assumed"]["learning_rate"])
+    opt_shapes = jax.eval_shape(tx.init, shapes)
+    update = make_update_step(model, tx, mesh).lower(params, opt_shapes, params).compile()
+    return grad, update, per_group
+
+
+@pytest.mark.parametrize(
+    "config_name",
+    ["mistral-7b-v0.3-1x1", "mistral-7b-v0.3-2on1", "2x2"],
+)
+def test_step_compiles_for_v5e(topo, no_compile_cache, monkeypatch, config_name):
+    grad, update, per_group = _compile_step(topo, config_name, 2048, monkeypatch)
+    for program in (grad, update):
+        need = program.memory_analysis()
+        total = need.argument_size_in_bytes + need.output_size_in_bytes + need.temp_size_in_bytes
+        assert total < HBM_BYTES, f"{config_name}: {total / 1e9:.1f} GB on a chip"
+    if per_group > 1:
+        # FSDP over ICI: the compiler put collectives into the step
+        text = grad.as_text()
+        assert "all-gather" in text or "all-reduce" in text or "reduce-scatter" in text

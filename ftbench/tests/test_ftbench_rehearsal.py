@@ -1,0 +1,122 @@
+"""The runner's control flow, walked on the CPU at toy widths: every cell,
+a traced run, the kill and the heal.  No number of these runs is a device's,
+and the runner prints none."""
+
+import json
+import os
+import shutil
+import subprocess
+import sys
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+
+def _run(args, cwd=ROOT, devices=4):
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}")
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    return subprocess.run(
+        [sys.executable, os.path.join("ftbench", "run.py"), *args],
+        cwd=cwd, env=env, capture_output=True, text=True, timeout=600,
+    )
+
+
+def _lines(stdout):
+    return [json.loads(l[len("ftbench: "):]) for l in stdout.splitlines() if l.startswith("ftbench: ")]
+
+
+@pytest.mark.parametrize(
+    "cell,trace,devices,expects",
+    [
+        ("mistral7b-ddp2-steady", 0, 2, {"ddp_tokens_per_s_per_chip", "setup_s"}),
+        ("mistral7b-ws1-steady", 0, 2, {"tokens_per_s_per_chip", "setup_s"}),
+        ("mistral7b-ddp2-kill", 0, 2, {"resume_s", "setup_s"}),
+        ("mistral7b-ddp2-steady", 1, 2, {"quorum_ms.ddp", "commit_vote_ms.ddp", "ring_ms",
+                                         "ring_tx_mbytes_per_step", "grad_mbytes_per_step"}),
+        ("mistral7b-ddp2-kill", 1, 2, {"detect_ms", "heal_ms", "heal_mbytes", "rejoin_first_step_ms",
+                                       "survivor_stall_s"}),
+    ],
+)
+def test_rehearsal_walks_the_cell(cell, trace, devices, expects):
+    done = _run(["--workload", cell, "--seed", "3000000019", "--seconds", "2",
+                 "--trace", str(trace), "--rehearse"], devices=devices)
+    assert done.returncode == 0, done.stderr[-3000:]
+    lines = _lines(done.stdout)
+    last = lines[-1]
+    assert last["rehearsal"] is True and last["platform"] == "cpu" and last["correct"] is True
+    assert last["failed"] == 0 and last["attempted"] > 0
+    # host-side readers have something to read on the CPU; device readers do not
+    assert set(last["would_report"]) == expects
+    # no result line, so no metric under a device's name
+    assert not any("metrics" in l for l in lines)
+    assert not done.stdout.rstrip().splitlines()[-1].startswith("{")
+    window = next(l for l in lines if "steps_in_window" in l)
+    assert window["compiles_in_window"] == 0 and window["steps_in_window"] >= 1
+    checks = next(l for l in lines if "checks" in l)
+    assert len(set(checks["digests"])) == 1
+
+
+def _root_with_the_four_chip_cell(tmp_path):
+    """What the PR that proves hsdp2x2 on the chip adds: a configuration
+    file (two groups of two chips, ``fsdp=2``, depth 2: PERF.md section 7),
+    two entries and the cell's name in the metrics' lists, and no edit to a
+    file under ftbench/."""
+    shutil.copytree(os.path.join(ROOT, "ftbench"), tmp_path / "ftbench",
+                    ignore=shutil.ignore_patterns("out", "__pycache__", "tests"))
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    with open(os.path.join(ROOT, "ftbench", "configs", "mistral-7b-v0.3-2on1.json")) as f:
+        config = json.load(f)
+    config.update(name="mistral-7b-v0.3-2x2", num_hidden_layers=2,
+                  layout=dict(chips_per_group=2, groups_share_chip=False, fsdp=2))
+    with open(tmp_path / "ftbench" / "configs" / "mistral-7b-v0.3-2x2.json", "w") as f:
+        json.dump(config, f)
+    entry = next(c for c in bench["configs"] if c["name"] == "mistral-7b-v0.3-2on1")
+    bench["configs"].append(dict(entry, name="mistral-7b-v0.3-2x2",
+                                 file="ftbench/configs/mistral-7b-v0.3-2x2.json"))
+    bench["workloads"].append(dict(name="mistral7b-hsdp2x2-steady", config="mistral-7b-v0.3-2x2",
+                                   traffic="ddp2-steady", chips=4, why="2 groups x 2 chips"))
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        if "mistral7b-ddp2-steady" in m.get("workloads", []):
+            m["workloads"].append("mistral7b-hsdp2x2-steady")
+    with open(tmp_path / "BENCHMARK.json", "w") as f:
+        json.dump(bench, f)
+    return str(tmp_path)
+
+
+def test_rehearsal_walks_the_four_chip_cell_a_later_pr_adds(tmp_path):
+    root = _root_with_the_four_chip_cell(tmp_path)
+    args = ["--workload", "mistral7b-hsdp2x2-steady", "--seed", "7", "--seconds", "2",
+            "--trace", "0", "--rehearse"]
+    # the program is not in that root: it comes from the repo
+    os.environ["PYTHONPATH"] = ROOT + os.pathsep + os.environ.get("PYTHONPATH", "")
+    try:
+        done = _run(args, cwd=root, devices=4)
+        short = _run(args, cwd=root, devices=2)
+    finally:
+        os.environ["PYTHONPATH"] = os.environ["PYTHONPATH"].split(os.pathsep, 1)[1]
+    assert done.returncode == 0, done.stderr[-3000:]
+    last = _lines(done.stdout)[-1]
+    assert last["rehearsal"] is True and last["correct"] is True
+    assert set(last["would_report"]) == {"ddp_tokens_per_s_per_chip", "setup_s"}
+    # two groups of two chips do not fit two devices
+    assert short.returncode == 1 and short.stdout.strip() == ""
+
+
+def test_no_chip_is_exit_1_and_no_result():
+    done = _run(["--workload", "mistral7b-ws1-steady", "--seed", "1", "--seconds", "1", "--trace", "0"])
+    assert done.returncode == 1
+    assert done.stdout.strip() == ""
+    assert "no TPU" in done.stderr
+
+
+def test_benchmark_alone_without_the_program_fails(tmp_path):
+    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), tmp_path)
+    shutil.copytree(os.path.join(ROOT, "ftbench"), tmp_path / "ftbench",
+                    ignore=shutil.ignore_patterns("out", "__pycache__"))
+    done = _run(["--workload", "mistral7b-ws1-steady", "--seed", "1", "--seconds", "1",
+                 "--trace", "0", "--rehearse"], cwd=str(tmp_path))
+    assert done.returncode != 0
+    assert not any(l.startswith("{") for l in done.stdout.splitlines())
