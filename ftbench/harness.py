@@ -31,15 +31,33 @@ from ftbench.spec import Cell, load_metric
 BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 
-# the float32 reference against the system's own forward pass, on one
-# seeded batch after the window: the greatest ABSOLUTE difference of the two
-# mean losses (10-13 at these sizes) that still reads correct.  bfloat16:
-# weights and activations carry 8 bits of mantissa, so single logits are off
-# by parts in a thousand and the mean over thousands of positions by less;
-# the chip read 3e-5 to 3.3e-3 (PERF.md), and the bound is three times the
-# worst.  An 8-bit float path would be off by some 5e-2 and fails.  float32
-# (the CPU rehearsal) differs only by summation order.
-REFERENCE_TOLERANCE_ABS = {"bfloat16": 1e-2, "float32": 2e-4}
+# the program's forward pass against the float32 reference, after the window
+# (``forward_passes``, ``reference_verdict``; README.md, "How `correct` is
+# decided").  Compared: the cross-entropy of every position of one seeded
+# batch at weights made anew from the seed, by the root mean square of the
+# differences.  The yardstick is made in the same call: the same program on
+# the same batch with its matrices through float8_e4m3fn and back
+# (``coarse_copy``), and the program's differences have to stay
+# COARSE_RATIO_K times under that copy's.  At seeded weights the logits are
+# of order one, and the ratio read 11.89 to 13.07 on the chip over 20 seeds
+# of each configuration.  The control, the same program on an int8 copy with
+# a scale a channel (the finest 8-bit path), read 2.75 to 2.97, the plain
+# reference on that copy 2.85 to 3.05, and the e4m3 copy itself reads 1
+# (PERF.md section 6, PR 25).  K keeps the worst sound seed three times in,
+# as ISSUE 25 asks (K <= 11.89 / 3), and every control out.  At the weights a
+# window ENDS with (one batch overfitted, logits of tens) the program's own
+# rounding of its logits to bfloat16 grows with them and the ratio fell to
+# 5.4: no K held there, which is why the state compared is the seeded one.
+COARSE_RATIO_K = 3.9
+# ``model.loss`` is what a training step differentiates, ``model.apply`` what
+# the positions are read from: the verdict needs the mean over the positions
+# to BE the program's loss, to float32's summation order (1.7e-6 at most in
+# 53 chip runs), so that a change to ``Llama.loss`` is never judged by a
+# stale copy of it
+LOSS_TIE_ABS = 2e-5
+# float32 (the CPU rehearsal) has no 8-bit neighbour to be told from: the
+# two mean losses differ only by summation order
+REFERENCE_TOLERANCE_ABS_FLOAT32 = 2e-4
 
 TOY_WIDTHS = dict(
     hidden_size=64,
@@ -177,6 +195,146 @@ def digest(host_leaves: List[Any]) -> str:
     for leaf in host_leaves:
         sha.update(np.ascontiguousarray(leaf).reshape(-1).view(np.uint8))
     return sha.hexdigest()[:16]
+
+
+E4M3_MAX = 448.0  # float8_e4m3fn: 4 bits of exponent, 3 of mantissa, no infinity
+
+
+def _is_matrix(x: Any) -> bool:
+    import jax.numpy as jnp
+
+    return x.dtype == jnp.bfloat16 and x.ndim >= 2
+
+
+def _e4m3_scale(x: Any) -> Any:
+    """What brings each matrix of ``x`` (a leaf's last two axes: a stacked
+    leaf is one matrix a layer) to float8_e4m3fn's range."""
+    import jax.numpy as jnp
+
+    amax = jnp.max(jnp.abs(x.astype(jnp.float32)), axis=(-2, -1), keepdims=True)
+    return jnp.where(amax > 0, E4M3_MAX / amax, 1.0)
+
+
+def to_e4m3(params: Any) -> Any:
+    import jax
+    import jax.numpy as jnp
+
+    return jax.tree_util.tree_map(
+        lambda x: (x.astype(jnp.float32) * _e4m3_scale(x)).astype(jnp.float8_e4m3fn)
+        if _is_matrix(x) else x,
+        params,
+    )
+
+
+def from_e4m3(params8: Any, params: Any) -> Any:
+    import jax
+    import jax.numpy as jnp
+
+    return jax.tree_util.tree_map(
+        lambda q, x: (q.astype(jnp.float32) / _e4m3_scale(x)).astype(x.dtype)
+        if _is_matrix(x) else x,
+        params8, params,
+    )
+
+
+def coarse_copy(params: Any, shardings: Any = None) -> Any:
+    """``params`` as an 8-bit float path would hold them: every bfloat16
+    leaf of two or more dimensions through ``float8_e4m3fn`` and back, each
+    matrix scaled so that its largest magnitude is the format's largest.
+    Norms (float32) and vectors stay as they are.  Two programs with the
+    float8 leaves in memory between them: inside ONE fusion the v5e's
+    compiler keeps a float8 intermediate at full width, and a cast there and
+    back rounds nothing (PERF.md section 6, PR 25)."""
+    import jax
+
+    return jax.jit(from_e4m3, out_shardings=shardings)(jax.jit(to_e4m3)(params), params)
+
+
+def system_token_nll(model: Any, params: Any, batch: Tuple[Any, Any]) -> Any:
+    """The cross-entropy of every position, [B, S], by the program's own
+    forward pass (``model.apply``)."""
+    import jax
+    import jax.numpy as jnp
+
+    tokens, targets = batch
+    logp = jax.nn.log_softmax(model.apply(params, tokens), axis=-1)
+    return -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
+
+
+def forward_passes(
+    model: Any, mesh: Any, config: Dict[str, Any], seed: int, rows: int, seq: int,
+    copies: Dict[str, Any],
+) -> Tuple[float, Dict[str, Any]]:
+    """``model.loss`` and the cross-entropy of every position, on one batch
+    and at weights that both come from ``seed`` alone: by the program
+    (``system``), by the program on each copy of the weights that ``copies``
+    names (name to ``f(params, shardings)``), and by the plain reference in
+    float32 (``reference``).  Nothing a window has trained is read."""
+    import jax
+    import numpy as np
+
+    from ftbench import reference
+    from torchft_tpu.parallel.hsdp import fsdp_shardings
+
+    params_sh, batch_sh = fsdp_shardings(model, mesh)
+    tokens, targets, batch = seeded_batch(
+        key_int(seed, 7777), config["vocab_size"], rows, seq, batch_sh
+    )
+    nll_fn = jax.jit(lambda p, b: system_token_nll(model, p, b))
+    nll: Dict[str, Any] = {}
+    with mesh:
+        params = jax.jit(model.init, out_shardings=params_sh)(
+            jax.random.PRNGKey(key_int(seed, 8888))
+        )
+        system_loss = float(jax.jit(model.loss)(params, batch))
+        nll["system"] = np.asarray(nll_fn(params, batch))
+        for name, copy in copies.items():
+            nll[name] = np.asarray(nll_fn(copy(params, params_sh), batch))
+    host = jax.tree_util.tree_map(np.asarray, params)
+    del params
+    with jax.default_device(mesh.devices.flat[0]):
+        nll["reference"] = np.asarray(reference.token_nll(host, tokens, targets, shapes_of(config)))
+    return system_loss, nll
+
+
+def reference_verdict(
+    system: Any, reference: Any, coarse: Optional[Any], system_loss: float
+) -> Dict[str, Any]:
+    """The arm by which ``reference_agrees`` holds (None: it does not), and
+    every number behind it: from the cross-entropy of every position by the
+    program, by the reference and by the program on the coarse copy, and from
+    the program's own mean loss.  ``coarse`` is None where no copy was made
+    (float32): the absolute arm on the means."""
+    import numpy as np
+
+    s, r = (np.asarray(a, np.float64).ravel() for a in (system, reference))
+    out: Dict[str, Any] = dict(
+        reference_arm=None,
+        system_loss=float(system_loss),
+        reference_loss=float(r.mean()),
+        loss_tie=float(abs(system_loss - s.mean())),
+        loss_tie_abs=LOSS_TIE_ABS,
+        token_rms=float(np.sqrt(np.mean((s - r) ** 2))),
+    )
+    # a NaN on any side compares false
+    tied = out["loss_tie"] <= LOSS_TIE_ABS
+    if coarse is None:
+        out["reference_diff"] = float(abs(system_loss - r.mean()))
+        out["reference_tolerance_abs"] = REFERENCE_TOLERANCE_ABS_FLOAT32
+        if tied and out["reference_diff"] <= REFERENCE_TOLERANCE_ABS_FLOAT32:
+            out["reference_arm"] = "absolute"
+        return out
+    c = np.asarray(coarse, np.float64).ravel()
+    out.update(
+        coarse_token_rms=float(np.sqrt(np.mean((c - r) ** 2))),
+        coarse_ratio_k=COARSE_RATIO_K,
+    )
+    if out["token_rms"] > 0:
+        out["coarse_ratio"] = out["coarse_token_rms"] / out["token_rms"]
+    # a yardstick that equals the reference measures nothing (weights of zero)
+    if tied and out["coarse_token_rms"] > 0 and out["token_rms"] <= out["coarse_token_rms"] / COARSE_RATIO_K:
+        out["reference_arm"] = "coarse"
+    return out
 
 
 def _tx_bytes(comm: Any) -> int:
@@ -583,23 +741,21 @@ class Fleet:
         self.after["grad_bytes"] = sum(
             int(x.nbytes) for x in jax.tree_util.tree_leaves(host[0])
         )
-        # the system's forward pass against the plain reference
-        from ftbench import reference
-
+        # the program's forward pass against the plain reference, with the
+        # 8-bit yardstick: made after memory_stats was read, dropped inside
         fin = self.final[0]
-        trainer, model = fin["trainer"], fin["model"]
         rows = len(self.groups[0]) * self.traffic["sequences_per_chip"]
-        from torchft_tpu.parallel.hsdp import fsdp_shardings
-
-        tokens, targets, batch = seeded_batch(
-            key_int(self.seed, 7777), self.config["vocab_size"], rows, self.seq,
-            fsdp_shardings(model, trainer.mesh)[1],
+        copies = {"coarse": coarse_copy} if self.config["torch_dtype"] == "bfloat16" else {}
+        system_loss, nll = forward_passes(
+            fin["model"], fin["trainer"].mesh, self.config, self.seed, rows, self.seq, copies
         )
-        with trainer.mesh:
-            system_loss = float(jax.jit(model.loss)(trainer.holder["params"], batch))
-        with jax.default_device(self.groups[0][0]):
-            ref_loss = reference.loss(host[0], tokens, targets, shapes_of(self.config))
-        self.after["system_loss"], self.after["reference_loss"] = system_loss, ref_loss
+        self.after.update(
+            reference_verdict=reference_verdict(
+                nll["system"], nll["reference"], nll.get("coarse"), system_loss
+            ),
+            # every position's numbers, for the series file
+            token_nll={k: v.ravel().tolist() for k, v in nll.items()},
+        )
 
     # -- the result --------------------------------------------------------
 
@@ -623,15 +779,14 @@ class Fleet:
         in_window_compiles = (
             ctl.marks["close"]["compiles"] - ctl.marks["open"]["compiles"] - in_window_hits
         )
-        tol = REFERENCE_TOLERANCE_ABS[self.config["torch_dtype"]]
-        diff = abs(self.after["system_loss"] - self.after["reference_loss"])
+        verdict = self.after["reference_verdict"]
         checks = {
             "losses_finite": all(
                 r[5] == r[5] and abs(r[5]) != float("inf") for recs in self.records for r in recs
             ),
             "digests_equal": len(set(self.after["digests"])) == 1,
             "no_compile_in_window": in_window_compiles == 0,
-            "reference_agrees": diff <= tol,
+            "reference_agrees": verdict["reference_arm"] is not None,
             "no_manager_error": all(f["errored"] is None for f in self.final),
             "attention_flash": self.rehearse or all(f["attention_path"] == "flash" for f in self.final),
             "devices_as_laid_out": all(
@@ -744,8 +899,7 @@ class Fleet:
         )
         say(
             checks=checks, digests=self.after["digests"],
-            system_loss=self.after["system_loss"], reference_loss=self.after["reference_loss"],
-            reference_diff=diff, reference_tolerance_abs=tol, tier=self.tier,
+            **verdict, tier=self.tier,
             params_M=flops.num_params(shapes) / 1e6,
             attention=[f["attention_path"] for f in self.final],
         )

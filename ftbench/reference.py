@@ -1,4 +1,5 @@
-"""The plain reference: Mistral-7B-v0.3's forward pass and loss.
+"""The plain reference: Mistral-7B-v0.3's forward pass and the
+cross-entropy of every position.
 
 Straightforward ``jax.numpy`` in float32 under
 ``jax.default_matmul_precision("highest")``: no kernels, no scan, no
@@ -17,10 +18,10 @@ from typing import Any, Dict
 import numpy as np
 
 
-def loss(params: Dict[str, Any], tokens: np.ndarray, targets: np.ndarray, cfg: Dict) -> float:
-    """Mean next-token cross-entropy of ``tokens`` [B, S] against
-    ``targets`` [B, S].  ``params`` may hold host or device arrays of any
-    float type; ``cfg`` has dim, n_heads, n_kv_heads, rope_theta, norm_eps."""
+def token_nll(params: Dict[str, Any], tokens: np.ndarray, targets: np.ndarray, cfg: Dict) -> Any:
+    """Next-token cross-entropy of every position, [B, S] in float32 on
+    the device.  ``params`` may hold host or device arrays of any float
+    type; ``cfg`` has dim, n_heads, n_kv_heads, rope_theta, norm_eps."""
     import jax
     import jax.numpy as jnp
 
@@ -62,5 +63,4 @@ def loss(params: Dict[str, Any], tokens: np.ndarray, targets: np.ndarray, cfg: D
             x = x + (jax.nn.silu(h @ w["w_gate"]) * (h @ w["w_up"])) @ w["w_down"]
         logits = rms_norm(x, f32(params["final_norm"])) @ f32(params["lm_head"])
         logp = jax.nn.log_softmax(logits, axis=-1)
-        nll = -jnp.take_along_axis(logp, jnp.asarray(targets)[..., None], axis=-1)
-        return float(jnp.mean(nll))
+        return -jnp.take_along_axis(logp, jnp.asarray(targets)[..., None], axis=-1)[..., 0]

@@ -100,3 +100,56 @@ def test_step_compiles_for_v5e(topo, no_compile_cache, monkeypatch, config_name)
         # FSDP over ICI: the compiler put collectives into the step
         text = grad.as_text()
         assert "all-gather" in text or "all-reduce" in text or "reduce-scatter" in text
+
+
+@pytest.mark.parametrize(
+    "config_name",
+    ["mistral-7b-v0.3-1x1", "mistral-7b-v0.3-2on1", "2x2"],
+)
+def test_forward_check_compiles_for_v5e(topo, no_compile_cache, monkeypatch, config_name):
+    """What runs after the window: weights from the seed, the coarse copy
+    (to float8_e4m3fn in one program, back in another, laid out as the
+    parameters are) and the forward pass that the program and the copy are
+    both judged by."""
+    import jax
+    import jax.numpy as jnp
+
+    from ftbench.harness import from_e4m3, llama_config, system_token_nll, to_e4m3
+    from torchft_tpu.models.llama import Llama
+    from torchft_tpu.parallel.hsdp import fsdp_shardings
+    from torchft_tpu.parallel.mesh import make_mesh
+
+    monkeypatch.setenv("TORCHFT_FLASH_PLATFORM", "tpu")
+    config = _config(config_name)
+    per_group = config["layout"]["chips_per_group"]
+    mesh = make_mesh(fsdp=per_group, devices=list(topo.devices)[:per_group])
+    model = Llama(llama_config(config))
+    params_sh, batch_sh = fsdp_shardings(model, mesh)
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    params = jax.tree_util.tree_map(
+        lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh), shapes, params_sh
+    )
+    batch = tuple(jax.ShapeDtypeStruct((per_group, 2048), jnp.int32, sharding=sh) for sh in batch_sh)
+    with mesh:
+        init = jax.jit(model.init, out_shardings=params_sh).lower(jax.random.PRNGKey(0)).compile()
+        down = jax.jit(to_e4m3).lower(params).compile()
+        params8 = jax.tree_util.tree_map(
+            lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+            jax.eval_shape(to_e4m3, shapes), params_sh,
+        )
+        up = jax.jit(from_e4m3, out_shardings=params_sh).lower(params8, params).compile()
+        loss = jax.jit(model.loss).lower(params, batch).compile()
+        nll = jax.jit(lambda p, b: system_token_nll(model, p, b)).lower(params, batch).compile()
+    assert model.attention_path == "flash"
+    # the float8 leaves lie in memory between the two programs: a cast there
+    # and back inside one fusion rounds nothing on this chip
+    assert any(x.dtype == jnp.float8_e4m3fn for x in jax.tree_util.tree_leaves(params8))
+    weights = up.memory_analysis().output_size_in_bytes
+    assert down.memory_analysis().output_size_in_bytes < 0.51 * weights
+    # the state a window leaves (parameters and two bf16 moments, twice where
+    # two replicas share the chip), the seeded weights, the float8 leaves,
+    # the copy and the pass fit
+    state = 3 * weights * (2 if config["layout"]["groups_share_chip"] else 1)
+    for program in (init, down, up, loss, nll):
+        need = program.memory_analysis()
+        assert state + 2.5 * weights + need.temp_size_in_bytes < HBM_BYTES

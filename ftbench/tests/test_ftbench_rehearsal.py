@@ -56,6 +56,11 @@ def test_rehearsal_walks_the_cell(cell, trace, devices, expects):
     assert window["compiles_in_window"] == 0 and window["steps_in_window"] >= 1
     checks = next(l for l in lines if "checks" in l)
     assert len(set(checks["digests"])) == 1
+    # float32 at toy widths: the absolute arm alone, and no coarse copy is made
+    assert checks["reference_arm"] == "absolute" and checks["reference_tolerance_abs"] == 2e-4
+    assert "coarse_token_rms" not in checks and "coarse_ratio" not in checks
+    assert checks["loss_tie"] <= checks["loss_tie_abs"] == 2e-5
+    assert checks["token_rms"] < 1e-4
 
 
 def _root_with_the_four_chip_cell(tmp_path):
