@@ -1302,20 +1302,20 @@ def _run_obs_phase() -> Dict[str, Any]:
         span = obs_spans.span
         rec.set_context(step=i, quorum_id=1)
         rec.record(FlightEvent.QUORUM_START, step=i)
-        with span("manager::quorum_rpc", step=i):
+        with span("tpuft/manager/quorum", step=i):
             rec.record(FlightEvent.QUORUM_ADOPT, step=i, world=3)
-        with span("comm::op", epoch=1):
+        with span("tpuft/comm/op", epoch=1):
             for lane in range(4):
-                with span("comm::lane_window", lane=lane):
+                with span("tpuft/comm/lane_window", lane=lane):
                     rec.record(
                         FlightEvent.COMM_CONFIGURE, rank=0, world=3, lanes=4
                     )
-        with span("manager::fence", step=i):
+        with span("tpuft/manager/fence", step=i):
             rec.record(FlightEvent.COMMIT_FENCE, step=i)
         for _ in range(6):  # heal/lane/chaos-shaped background events
             rec.record(FlightEvent.LANE_RECONNECT, peer=1, lane=0)
         rec.record(FlightEvent.COMMIT_VOTE, step=i, local=True)
-        with span("manager::should_commit", step=i):
+        with span("tpuft/manager/should_commit", step=i):
             rec.record(FlightEvent.COMMIT_RESULT, step=i, committed=True)
 
     def measure_pattern(rec: FlightRecorder, spans_on: bool, reps: int) -> float:

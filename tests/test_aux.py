@@ -11,11 +11,7 @@ import pytest
 
 from torchft_tpu.launcher import ReplicaSpec, ReplicaSupervisor
 from torchft_tpu.lighthouse import LighthouseClient, LighthouseServer
-from torchft_tpu.observability import (
-    _JsonLinesFormatter,
-    record_function,
-    traced,
-)
+from torchft_tpu.observability import _JsonLinesFormatter
 from torchft_tpu.parameter_server import ParameterServer, ParameterServerClient
 
 
@@ -68,15 +64,27 @@ class TestObservability:
             logging.getLogger(name).propagate = True
         monkeypatch.setattr(obs, "_initialized", False)
 
-    def test_record_function_and_traced(self) -> None:
-        with record_function("test::span"):
-            pass
+    def test_span_is_the_one_protocol_phase_annotation(self) -> None:
+        """What ``record_function`` / ``traced`` were for: a protocol phase
+        is one ``obs.spans.span``, usable bare (nobody bound, no buffer, no
+        trace) around any code, and it times what it wraps."""
+        from torchft_tpu.obs import spans
 
-        @traced("test::fn")
+        spans.bind(None)
+        with spans.span("tpuft/test/span") as sp:
+            time.sleep(0.002)
+        assert sp.attrs == {"r": ""} and sp.duration_s >= 0.002
+
         def fn(x):
-            return x + 1
+            with spans.span("tpuft/test/fn", x=x):
+                return x + 1
 
         assert fn(1) == 2
+        import torchft_tpu.observability as obs
+
+        # the old stack is gone, not aliased
+        for gone in ("record_function", "traced", "QuorumTracer", "TRACE_DIR_ENV"):
+            assert not hasattr(obs, gone), gone
 
 
 class TestLauncher:

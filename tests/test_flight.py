@@ -229,10 +229,12 @@ class TestSpans:
     def setup_method(self):
         spans_mod.configure(True)
         spans_mod.clear()
+        spans_mod.bind(None)
 
     def teardown_method(self):
         spans_mod.configure(None)
         spans_mod.clear()
+        spans_mod.bind(None)
 
     def test_nested_spans_record(self):
         with spans_mod.span("outer", step=1):
@@ -242,16 +244,15 @@ class TestSpans:
         names = [r["name"] for r in recs]
         assert names == ["inner", "outer"]  # completion order
         outer = recs[1]
-        assert outer["attrs"] == {"step": 1}
+        # a span on a thread nobody bound says r=""
+        assert outer["attrs"] == {"step": 1, "r": ""}
         assert outer["dur"] >= recs[0]["dur"]
 
-    def test_disabled_is_shared_noop(self):
+    def test_disabled_keeps_nothing_in_the_buffer(self):
         spans_mod.configure(False)
-        s1 = spans_mod.span("a")
-        s2 = spans_mod.span("b")
-        assert s1 is s2  # the shared null context
-        with s1:
+        with spans_mod.span("a") as s1:
             pass
+        assert s1.duration_s >= 0.0  # the span itself still ran
         assert spans_mod.snapshot() == []
 
     def test_chrome_trace_export(self, tmp_path):
@@ -268,7 +269,163 @@ class TestSpans:
         assert len(xs) == 1
         assert xs[0]["name"] == "step"
         assert xs[0]["ts"] > 0 and xs[0]["dur"] >= 0
-        assert xs[0]["args"] == {"step": 3}
+        assert xs[0]["args"] == {"step": 3, "r": ""}
+
+    def test_bound_thread_stamps_replica_and_sticky_step(self):
+        rec = FlightRecorder("rep_a:uuid/0", cap=16)
+        rec.set_context(step=7)
+        spans_mod.bind(rec)
+        with spans_mod.span("tpuft/x/bound"):
+            pass
+        with spans_mod.span("tpuft/x/explicit", step=9):
+            pass
+        seen = {}
+
+        def helper():
+            with spans_mod.span("tpuft/x/helper"):
+                pass
+            spans_mod.bind(rec)  # a helper says whom it works for
+            with spans_mod.span("tpuft/x/helper_bound"):
+                pass
+            seen["bound"] = spans_mod.bound()
+
+        t = threading.Thread(target=helper)
+        t.start()
+        t.join()
+        attrs = {r["name"]: r["attrs"] for r in spans_mod.snapshot()}
+        assert attrs["tpuft/x/bound"] == {"r": "rep_a:uuid/0", "step": 7}
+        assert attrs["tpuft/x/explicit"] == {"r": "rep_a:uuid/0", "step": 9}
+        assert attrs["tpuft/x/helper"] == {"r": ""}  # binding is per thread
+        assert attrs["tpuft/x/helper_bound"] == {"r": "rep_a:uuid/0", "step": 7}
+        assert seen["bound"] is rec
+        assert len(rec) == 0  # no flight= anywhere: the ring stays empty
+
+    def test_flight_writes_exactly_one_event_with_t0_and_duration(self):
+        rec = FlightRecorder("rep_a", cap=16)
+        rec.set_context(step=4)
+        spans_mod.bind(rec)
+        before = time.monotonic()
+        with spans_mod.span(
+            "tpuft/x/boundary", flight=FlightEvent.DDP_SYNC, buckets=3
+        ) as sp:
+            time.sleep(0.01)
+            sp.set(bytes=12)
+        (event,) = rec.snapshot()
+        assert event["name"] == "DDP_SYNC" and event["ev"] == 29
+        assert event["step"] == 4
+        assert before <= event["t0"] <= event["t"]
+        assert event["duration_s"] == pytest.approx(sp.duration_s, abs=1e-5)
+        assert event["duration_s"] >= 0.01
+        assert event["t0"] + event["duration_s"] == pytest.approx(event["t"], abs=1e-3)
+        assert event["buckets"] == 3 and event["bytes"] == 12
+        assert "r" not in event  # the ring knows its replica already
+
+    def test_begin_marks_entry_and_into_stores_seconds(self):
+        rec = FlightRecorder("rep_a", cap=16)
+        spans_mod.bind(rec)
+        timings = {}
+        with spans_mod.span(
+            "tpuft/heal/fetch",
+            step=5,
+            begin=FlightEvent.HEAL_RECV_BEGIN,
+            flight=FlightEvent.HEAL_RECV_END,
+            into=timings,
+            key="heal_recv_s",
+        ) as sp:
+            assert [e["name"] for e in rec.snapshot()] == ["HEAL_RECV_BEGIN"]
+        assert [e["name"] for e in rec.snapshot()] == [
+            "HEAL_RECV_BEGIN", "HEAL_RECV_END",
+        ]
+        assert timings == {"heal_recv_s": sp.duration_s}
+        assert all(e["step"] == 5 for e in rec.snapshot())
+
+    def test_no_annotation_is_made_while_no_profiler_session_is_on(self):
+        from jax.profiler import TraceAnnotation
+
+        assert not TraceAnnotation.is_enabled()
+        with spans_mod.span("tpuft/test/quiet") as sp:
+            assert sp._annotation is None
+        assert sp.duration_s >= 0.0 and len(spans_mod.snapshot()) == 1
+
+    def test_no_session_calls_nothing_of_the_profiler(self, monkeypatch):
+        # not even is_enabled(): whether a session is on is read from jax's
+        # own Python record of it
+        class Untouchable:
+            def __init__(self, *a, **kw):
+                raise AssertionError("an annotation with no session on")
+
+            @staticmethod
+            def is_enabled():
+                raise AssertionError("a call into the profiler's module")
+
+        spans_mod._session_on()  # loads the real class and jax's record
+        assert spans_mod._profile_state is not None
+        monkeypatch.setattr(spans_mod, "_annotation_cls", Untouchable)
+        rec = FlightRecorder("rep_a", cap=16)
+        spans_mod.bind(rec)
+        timings = {}
+        with spans_mod.span(
+            "tpuft/test/quiet", flight=FlightEvent.DDP_SYNC, into=timings, key="s"
+        ) as sp:
+            sp.detach()
+            sp.attach()
+        assert timings == {"s": sp.duration_s} and len(rec.snapshot()) == 1
+
+    def test_unbound_boundary_span_records_nowhere(self):
+        with spans_mod.span("tpuft/x/nobody", flight=FlightEvent.DDP_SYNC) as sp:
+            pass
+        assert sp.duration_s >= 0.0
+
+    def test_span_crossing_threads_is_two_pieces_of_one_name(self):
+        rec = FlightRecorder("rep_a", cap=16)
+        rec.set_context(step=2)
+        spans_mod.bind(rec)
+        sp = spans_mod.span("tpuft/x/cross", flight=FlightEvent.DDP_SYNC)
+        sp.__enter__()
+        sp.detach()
+
+        def closer():
+            spans_mod.bind(rec)
+            sp.attach()
+            time.sleep(0.005)
+            sp.__exit__(None, None, None)
+
+        t = threading.Thread(target=closer)
+        t.start()
+        t.join()
+        (event,) = rec.snapshot()
+        assert event["duration_s"] >= 0.005
+        (kept,) = spans_mod.snapshot()
+        assert kept["tid"] == t.ident  # closed by the other thread
+
+    def test_span_lands_in_a_profiler_trace_with_r_and_step(self, tmp_path):
+        import glob
+
+        import jax
+        from jax.profiler import ProfileData
+
+        rec = FlightRecorder("rep_a:uuid/0", cap=16)
+        rec.set_context(step=11)
+        spans_mod.bind(rec)
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with spans_mod.span("tpuft/test/traced", k=2):
+                jax.numpy.ones(8).block_until_ready()
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = glob.glob(
+            str(tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb")
+        )
+        found = [
+            (ev.name, dict(ev.stats))
+            for plane in ProfileData.from_file(path).planes
+            for line in plane.lines
+            for ev in line.events
+            if ev.name == "tpuft/test/traced"
+        ]
+        assert found == [
+            ("tpuft/test/traced", {"k": 2, "r": "rep_a:uuid/0", "step": 11})
+        ]
 
 
 class TestFlightMerge:
@@ -337,3 +494,251 @@ class TestFlightMerge:
         )
         assert chain is not None and len(chain) == 3
         assert flight_merge.find_chain(events, ["COMM_POISON", "CHAOS_INJECT"]) is None
+
+
+# -- the span API across a real two-replica fleet (threads of this process) ----
+
+
+class _Drill:
+    """Two replica groups as threads, each a Manager over the TCP tier and
+    an HTTP transport, averaging a gradient pytree of a few buckets a step;
+    replica 1 is killed once (a dead process: its Manager shut down, a new
+    one with other weights) and heals live from replica 0."""
+
+    STEPS = 46
+    KILL_AT = 3
+    LEAF = 96 * 1024  # float32 elements a leaf: three leaves, a bucket each
+
+    def __init__(self, lighthouse_addr):
+        self.lighthouse_addr = lighthouse_addr
+        self.managers = {0: [], 1: []}  # every life, oldest first
+        self.timings = {0: [], 1: []}  # last_quorum_timings after each round
+        self.heal_metrics = None
+        self.state_nbytes = 0
+        self.errors = []
+
+    def run(self):
+        threads = [
+            threading.Thread(target=self._guarded, args=(i,), name=f"drill_{i}")
+            for i in range(2)
+        ]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=180.0)
+        assert not any(t.is_alive() for t in threads), "drill hung"
+        assert not self.errors, self.errors
+
+    def shutdown(self):
+        for lives in self.managers.values():
+            for m in lives:
+                try:
+                    m.shutdown()
+                except Exception:  # noqa: BLE001 — best-effort teardown
+                    pass
+
+    def _guarded(self, idx):
+        try:
+            self._replica(idx)
+        except BaseException as e:  # noqa: BLE001 — raised again by run()
+            self.errors.append(e)
+
+    def _replica(self, idx):
+        import jax.numpy as jnp
+        import numpy as np
+
+        from torchft_tpu.checkpointing.http_transport import HTTPTransport
+        from torchft_tpu.communicator import TCPCommunicator
+        from torchft_tpu.ddp import ft_allreduce
+        from torchft_tpu.manager import Manager
+
+        killed = False
+        while True:
+            life = len(self.managers[idx])
+            holder = {
+                "params": {
+                    name: jnp.full((self.LEAF,), float(life + 1), jnp.float32)
+                    for name in ("a", "b", "c")
+                }
+            }
+            transport = HTTPTransport(timeout=20.0)
+            manager = Manager(
+                comm=TCPCommunicator(timeout_s=20.0),
+                checkpoint_transport=transport,
+                load_state_dict=holder.update,
+                state_dict=lambda holder=holder: dict(holder),
+                min_replica_size=1,
+                replica_id=f"drill_{idx}",
+                lighthouse_addr=self.lighthouse_addr,
+                timeout=20.0,
+                quorum_timeout=20.0,
+                connect_timeout=20.0,
+            )
+            self.managers[idx].append(manager)
+            while manager.current_step() < self.STEPS:
+                if idx == 1 and not killed and manager.current_step() == self.KILL_AT:
+                    killed = True
+                    manager.shutdown()
+                    break
+                time.sleep(0.01)
+                manager.start_quorum()
+                grads = {k: jnp.ones_like(v) for k, v in holder["params"].items()}
+                grads = ft_allreduce(manager, grads)
+                manager.wait_quorum()
+                self.timings[idx].append(dict(manager.last_quorum_timings))
+                if manager.should_commit():
+                    holder["params"] = {
+                        k: v - 0.01 * grads[k] for k, v in holder["params"].items()
+                    }
+                if life and transport.last_heal_metrics is not None and self.heal_metrics is None:
+                    # the restarted life's heal (a first life's init_sync heals too)
+                    self.heal_metrics = transport.last_heal_metrics
+                    self.state_nbytes = sum(
+                        int(np.asarray(v).nbytes) for v in holder["params"].values()
+                    )
+            else:
+                return
+
+
+@pytest.fixture(scope="module")
+def drill():
+    from torchft_tpu.lighthouse import LighthouseServer
+
+    lighthouse = LighthouseServer(
+        bind="127.0.0.1:0",
+        min_replicas=1,
+        join_timeout_ms=100,
+        quorum_tick_ms=20,
+        heartbeat_timeout_ms=1000,
+    )
+    saved_cap = os.environ.get("TORCHFT_BUCKET_CAP_MB")
+    os.environ["TORCHFT_BUCKET_CAP_MB"] = "0.25"  # a leaf a bucket
+    spans_mod.configure(True, cap=65536)
+    spans_mod.clear()
+    fleet = _Drill(lighthouse.local_address())
+    try:
+        fleet.run()
+        fleet.spans = spans_mod.snapshot()
+        yield fleet
+    finally:
+        fleet.shutdown()
+        lighthouse.shutdown()
+        spans_mod.configure(None, cap=8192)
+        spans_mod.clear()
+        if saved_cap is None:
+            os.environ.pop("TORCHFT_BUCKET_CAP_MB", None)
+        else:
+            os.environ["TORCHFT_BUCKET_CAP_MB"] = saved_cap
+
+
+def _union(intervals):
+    total, reach = 0.0, float("-inf")
+    for a, b in sorted(intervals):
+        if b > reach:
+            total += b - max(a, reach)
+            reach = b
+    return total
+
+
+class TestSpansAcrossAFleet:
+    def test_ddp_children_share_the_parents_step_and_replica_and_tile_it(self, drill):
+        ddp = [s for s in drill.spans if s["name"].startswith(("tpuft/ddp/", "tpuft/comm/op"))]
+        parents = [s for s in ddp if s["name"] == "tpuft/ddp/allreduce_pytree"]
+        # every two-member step made one round trip a replica
+        assert len(parents) >= 2 * (drill.STEPS - drill.KILL_AT - 8)
+        covered = []
+        for p in parents:
+            r, step = p["attrs"]["r"], p["attrs"]["step"]
+            assert r.startswith("drill_") and step >= 0
+            t0, t1 = p["t"], p["t"] + p["dur"]
+            kids = [
+                s for s in ddp
+                if s is not p and s["attrs"]["r"] == r and s["attrs"]["step"] == step
+                and s["t"] >= t0 - 1e-4 and s["t"] + s["dur"] <= t1 + 1e-4
+            ]
+            names = {s["name"] for s in kids}
+            assert {
+                "tpuft/ddp/plan", "tpuft/ddp/d2h", "tpuft/ddp/pack", "tpuft/ddp/submit",
+                "tpuft/comm/op", "tpuft/ddp/ring_wait", "tpuft/ddp/h2d",
+            } <= names, (step, names)
+            # a span a bucket for the per-bucket stages, none for the parent
+            for stage in ("d2h", "pack", "ring_wait", "h2d"):
+                assert sum(s["name"] == f"tpuft/ddp/{stage}" for s in kids) == 3
+            assert len({s["tid"] for s in kids}) >= 3  # train, op and gather threads
+            union = _union(
+                (max(s["t"], t0), min(s["t"] + s["dur"], t1)) for s in kids
+            )
+            covered.append(union / p["dur"])
+        covered.sort()
+        # the children tile the parent: 90 % or more (a loaded CI host delays a
+        # thread's start now and then, so the median is held, and the worst loosely)
+        assert covered[len(covered) // 2] >= 0.9, covered[:5]
+        assert covered[0] >= 0.5, covered[:5]
+
+    def test_comm_ops_count_from_zero_in_every_step(self, drill):
+        ops = [s for s in drill.spans if s["name"] == "tpuft/comm/op"]
+        by_step = {}
+        for s in ops:
+            by_step.setdefault((s["attrs"]["r"], s["attrs"]["step"]), []).append(s["attrs"]["k"])
+        full = [ks for ks in by_step.values() if len(ks) >= 3]
+        assert full and all(sorted(ks)[:3] == [0, 1, 2] for ks in full)
+
+    def test_ddp_sync_is_one_flight_event_a_round_trip_with_stage_seconds(self, drill):
+        survivor = drill.managers[0][0]._flight.snapshot()
+        syncs = [e for e in survivor if e["name"] == "DDP_SYNC"]
+        assert len(syncs) >= drill.STEPS - drill.KILL_AT - 8
+        assert len({e["step"] for e in syncs}) == len(syncs)  # one a step
+        for e in syncs:
+            assert e["buckets"] == 3 and e["bytes"] == 3 * 4 * drill.LEAF
+            stages = [e[k] for k in ("plan_s", "d2h_s", "pack_s", "ring_wait_s", "h2d_s")]
+            assert all(v >= 0.0 for v in stages)
+            assert sum(stages) <= e["duration_s"] + 1e-3
+            assert e["t0"] + e["duration_s"] == pytest.approx(e["t"], abs=1e-3)
+
+    def test_into_fills_last_quorum_timings_with_todays_keys(self, drill):
+        # every round stamps the RPC; a reconfiguring round the configure;
+        # the heal round its send (survivor) and its receive (new life)
+        assert all("quorum_rpc_s" in t for ts in drill.timings.values() for t in ts)
+        assert any("configure_s" in t for t in drill.timings[0])
+        sends = [t for t in drill.timings[0] if "heal_send_s" in t]
+        recvs = [t for t in drill.timings[1] if "heal_recv_s" in t]
+        assert sends and recvs
+        healed = recvs[-1]  # the restarted life's (the first life's init_sync heals too)
+        assert healed["heal_recv_s"] > 0.0 and healed["quorum_rpc_s"] > 0.0
+        assert healed["heal_bytes"] == drill.heal_metrics.bytes_total
+        assert healed["heal_num_sources"] == 1.0
+
+    def test_one_source_http_heal_sets_last_heal_metrics_to_the_wire_bytes(self, drill):
+        metrics = drill.heal_metrics
+        assert metrics is not None and metrics.num_sources == 1
+        assert metrics.duration_s > 0.0 and 0.0 < metrics.read_s <= metrics.duration_s
+        # the state's arrays plus the stream's framing (a header and 8 bytes a leaf)
+        assert drill.state_nbytes == 3 * 4 * drill.LEAF
+        assert drill.state_nbytes < metrics.bytes_total < drill.state_nbytes + 4096
+        new_life = drill.managers[1][1]._flight.snapshot()
+        (recv_end,) = [e for e in new_life if e["name"] == "HEAL_RECV_END"]
+        assert recv_end["bytes"] == metrics.bytes_total
+        assert recv_end["read_s"] == pytest.approx(metrics.read_s, abs=1e-5)
+        assert recv_end["duration_s"] >= metrics.duration_s
+        (applied,) = [e for e in new_life if e["name"] == "HEAL_APPLY"]
+        assert applied["duration_s"] >= 0.0 and applied["t0"] >= recv_end["t0"]
+        # the survivor served exactly those bytes
+        survivor = drill.managers[0][0]._flight.snapshot()
+        # (its first response was the other replica's init_sync at step 0)
+        served = [e for e in survivor if e["name"] == "HEAL_SERVE_END"][-1]
+        assert served["step"] == recv_end["step"]
+        assert served["bytes"] == metrics.bytes_total and served["part"] == "full"
+        assert served["d2h_s"] >= 0.0 and served["write_s"] >= 0.0
+        assert served["d2h_s"] + served["write_s"] <= served["duration_s"] + 1e-3
+
+    def test_survivor_ring_keeps_the_kill_after_forty_more_steps(self, drill):
+        survivor = drill.managers[0][0]
+        assert survivor.current_step() >= drill.KILL_AT + 40
+        assert survivor._flight._cap == 4096  # the default TORCHFT_FLIGHT_EVENTS
+        events = survivor._flight.snapshot()
+        names = [e["name"] for e in events]
+        for kept in ("QUORUM_ADOPT", "HEAL_SEND_BEGIN", "HEAL_SEND_END", "HEAL_SERVE_END"):
+            assert kept in names, kept
+        # boundaries only: no per-bucket span reaches the ring
+        assert len(events) <= 10 * survivor.current_step()
+        assert names.index("HEAL_SEND_BEGIN") < names.index("HEAL_SEND_END")

@@ -143,6 +143,7 @@ class CommTransport(CheckpointTransport[T]):
         into: Optional[T] = None,
     ) -> T:
         base = self._tags(step)
+        t0 = time.monotonic()
         meta_blob = self._comm.recv_bytes(src_rank, tag=base).wait(timeout=timeout)
         skeleton, array_meta = pickle.loads(meta_blob)
 
@@ -184,6 +185,14 @@ class CommTransport(CheckpointTransport[T]):
                 )
                 as_byte_view(target)[:] = blob
             arrays.append(target)
+        nbytes = len(meta_blob) + sum(int(a.nbytes) for a in arrays)
+        self.last_heal_metrics = HealMetrics(
+            step=step,
+            num_sources=1,
+            bytes_total=nbytes,
+            duration_s=time.monotonic() - t0,
+            per_source_bytes={src_rank: nbytes},
+        )
         logger.info(
             "received checkpoint step=%d (%d arrays) from rank %d",
             step,

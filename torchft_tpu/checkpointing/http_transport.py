@@ -40,6 +40,9 @@ from torchft_tpu.checkpointing.serialization import (
     plan_pytree,
 )
 from torchft_tpu.checkpointing.transport import CheckpointTransport
+from torchft_tpu.obs import spans as obs_spans
+from torchft_tpu.obs.flight import FlightEvent
+from torchft_tpu.obs.spans import span as obs_span
 from torchft_tpu.observability import HealMetrics
 
 logger = logging.getLogger(__name__)
@@ -69,11 +72,15 @@ def _read_stream_into(resp, view: memoryview) -> None:
 
 
 class _RawSocketWriter(RawIOBase):
-    """Adapts the handler's socket file to io.BufferedWriter."""
+    """Adapts the handler's socket file to io.BufferedWriter, and counts the
+    bytes it wrote and the seconds it was blocked writing them (a chunk is
+    too small for a span each: ``tpuft/heal/serve`` carries the sums)."""
 
     def __init__(self, wfile) -> None:
         super().__init__()
         self._wfile = wfile
+        self.write_s = 0.0
+        self.bytes = 0
 
     def writable(self) -> bool:
         return True
@@ -81,7 +88,35 @@ class _RawSocketWriter(RawIOBase):
     def write(self, b) -> int:
         # honor the RawIOBase short-write contract: BufferedWriter retries
         # any remainder only if we report what was actually written
-        return self._wfile.write(b)
+        t0 = time.monotonic()
+        n = self._wfile.write(b)
+        self.write_s += time.monotonic() - t0
+        self.bytes += n or 0
+        return n
+
+
+class _TimedReader:
+    """A response as ``load_pytree`` reads it, counting the bytes read and
+    the seconds blocked reading them (``tpuft/heal/fetch``'s ``read_s``)."""
+
+    def __init__(self, resp) -> None:
+        self._resp = resp
+        self.read_s = 0.0
+        self.bytes = 0
+
+    def read(self, n: int = -1) -> bytes:
+        t0 = time.monotonic()
+        out = self._resp.read(n)
+        self.read_s += time.monotonic() - t0
+        self.bytes += len(out)
+        return out
+
+    def readinto(self, view) -> int:
+        t0 = time.monotonic()
+        n = self._resp.readinto(view)
+        self.read_s += time.monotonic() - t0
+        self.bytes += n or 0
+        return n
 
 
 class _ChaosWriter(RawIOBase):
@@ -245,14 +280,29 @@ class HTTPTransport(CheckpointTransport[T]):
                 # unbuffered socket writer; batching the plan's small frame
                 # headers with the payloads into 1 MB writes avoids
                 # per-frame syscalls
-                raw = _RawSocketWriter(self.wfile)
+                socket_writer = raw = _RawSocketWriter(self.wfile)
                 if transport.chaos_serve_hook is not None and (
                     not transport.chaos_striped_only or parts[2] == "range"
                 ):
                     raw = _ChaosWriter(raw, transport)
                 buffered = BufferedWriter(raw, buffer_size=1 << 20)
-                plan.write_range(start, stop, buffered)
-                buffered.flush()
+                # this handler thread works for the transport's replica
+                obs_spans.bind(transport.flight)
+                with obs_span(
+                    "tpuft/heal/serve",
+                    step=step,
+                    flight=FlightEvent.HEAL_SERVE_END,
+                    part=parts[2],
+                ) as serve_span:
+                    try:
+                        d2h_s = plan.write_range(start, stop, buffered)
+                        buffered.flush()
+                    finally:
+                        serve_span.set(
+                            bytes=socket_writer.bytes,
+                            write_s=round(socket_writer.write_s, 6),
+                        )
+                    serve_span.set(d2h_s=round(d2h_s, 6))
 
         class _Server(ThreadingHTTPServer):
             daemon_threads = True
@@ -322,9 +372,13 @@ class HTTPTransport(CheckpointTransport[T]):
         (e.g. a ``jax.device_put`` with the healing replica's sharding) maps
         each leaf on arrival so its host copy dies immediately."""
         base = f"{metadata}/checkpoint/{step}"
+        t0 = time.monotonic()
         if self._num_chunks == 0:
             with urlopen(f"{base}/full", timeout=timeout) as resp:
-                return load_pytree(resp, leaf_hook=leaf_hook)  # type: ignore[return-value]
+                reader = _TimedReader(resp)
+                state = load_pytree(reader, leaf_hook=leaf_hook)
+            self._note_one_source(step, metadata, reader.bytes, t0, reader.read_s)
+            return state  # type: ignore[return-value]
 
         # chunked mode: parallel range fetches landing in one preallocated
         # buffer (no per-chunk bytes objects, no join copy)
@@ -364,7 +418,22 @@ class HTTPTransport(CheckpointTransport[T]):
             raise errors[0]
         if not all(done):
             raise TimeoutError("chunked checkpoint fetch timed out")
+        self._note_one_source(step, metadata, total_len, t0)
         return load_pytree(_ViewReader(view), leaf_hook=leaf_hook)  # type: ignore[return-value]
+
+    def _note_one_source(
+        self, step: int, source: str, nbytes: int, t0: float, read_s: float = 0.0
+    ) -> None:
+        """``last_heal_metrics`` of a fetch from one source: the bytes read
+        off the wire, as the striped path counts its own."""
+        self.last_heal_metrics = HealMetrics(
+            step=step,
+            num_sources=1,
+            bytes_total=nbytes,
+            duration_s=time.monotonic() - t0,
+            per_source_bytes={source: nbytes},
+            read_s=read_s,
+        )
 
     def recv_checkpoint_striped(
         self,

@@ -28,6 +28,7 @@ import os
 import pickle
 import struct
 import threading
+import time
 from dataclasses import dataclass, field
 from typing import Any, BinaryIO, List, Optional, Tuple
 
@@ -299,10 +300,12 @@ class PytreePlan:
             self._memo = (index, arr)
         return arr
 
-    def write_range(self, start: int, stop: int, stream: BinaryIO) -> None:
+    def write_range(self, start: int, stop: int, stream: BinaryIO) -> float:
         """Stream bytes [start, stop) of the serialized form, materializing
-        only the leaves that overlap the range (chunked HTTP fetches)."""
+        only the leaves that overlap the range (chunked HTTP fetches).
+        Returns the seconds spent materializing leaves (device to host)."""
         off = 0
+        d2h_s = 0.0
 
         def _emit(chunk) -> None:
             nonlocal off
@@ -323,7 +326,11 @@ class PytreePlan:
             if off + nbytes <= start:
                 off += nbytes
                 continue
-            _emit(as_byte_view(self._materialize(i)))
+            t0 = time.monotonic()
+            leaf = self._materialize(i)
+            d2h_s += time.monotonic() - t0
+            _emit(as_byte_view(leaf))
+        return d2h_s
 
 
 def _snapshot_leaf(leaf: Any) -> Any:

@@ -19,6 +19,10 @@ class CheckpointTransport(ABC, Generic[T]):
     to recovering replicas and fetch a peer's when healing
     (``torchft/checkpointing/transport.py:14-68``)."""
 
+    # the owning Manager's FlightRecorder (a plain attribute the Manager
+    # sets, as on the communicator): serving threads bind their spans to it
+    flight = None
+
     @abstractmethod
     def metadata(self) -> str:
         """Opaque metadata handed to recovering peers (e.g. a URL)."""

@@ -606,7 +606,7 @@ def outer_sharded_sync(
         try:
             if contrib is None:
                 raise err or CommunicatorError("intra-host reduce failed")
-            with obs_span("outer_shard::pipeline"):
+            with obs_span("tpuft/outer_shard/pipeline"):
                 delta_full = _outer_sharded_pipeline(
                     group,
                     contrib,
@@ -751,7 +751,7 @@ def _outer_sharded_pipeline(
                     acc = np.sum(np.stack(gathered), axis=0)
                 acc *= inv
                 t0 = time.perf_counter()
-                with obs_span("outer_shard::chunk_update", chunk=ci):
+                with obs_span("tpuft/outer_shard/chunk_update", chunk=ci):
                     delta = np.asarray(
                         update_cb(my_base + c0, my_base + c1, acc),
                         dtype=np.float32,

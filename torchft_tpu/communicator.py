@@ -51,7 +51,8 @@ import numpy as np
 
 from torchft_tpu.futures import TimerHandle, schedule_timeout
 from torchft_tpu.obs.flight import FlightEvent, FlightRecorder
-from torchft_tpu.obs.spans import span as obs_span, spans_enabled
+from torchft_tpu.obs import spans as obs_spans
+from torchft_tpu.obs.spans import span as obs_span
 from torchft_tpu.store import create_store_client
 from torchft_tpu import wire as wire_tags
 from torchft_tpu.wire import create_listener
@@ -61,15 +62,12 @@ logger = logging.getLogger(__name__)
 
 
 def _spanned(name: str):
-    """Wrap a hot method in an obs trace span — one truthiness check when
-    spans are disabled, a recorded wall-clock window when enabled."""
+    """Wrap a hot method in an obs trace span."""
     import functools
 
     def deco(fn):
         @functools.wraps(fn)
         def inner(*args, **kwargs):
-            if not spans_enabled():
-                return fn(*args, **kwargs)
             with obs_span(name):
                 return fn(*args, **kwargs)
 
@@ -1603,7 +1601,7 @@ class _TcpMesh:
             off += _recv_some(memoryview(buf)[off:])
         return bytes(buf)
 
-    @_spanned("comm::lane_window")
+    @_spanned("tpuft/comm/lane_window")
     def exchange(
         self,
         sends: List[Tuple[int, int, memoryview]],
@@ -2652,7 +2650,7 @@ class TCPCommunicator(Communicator):
 
         mesh: Optional[_TcpMesh] = None
         if world_size > 1:
-            with obs_span("comm::rendezvous", epoch=epoch):
+            with obs_span("tpuft/comm/rendezvous", epoch=epoch):
                 mesh = _TcpMesh(
                     store_addr,
                     rank,
@@ -2958,6 +2956,8 @@ class TCPCommunicator(Communicator):
         ops: "queue.Queue[Optional[Tuple[Callable[[], object], Future, bool, Optional[float]]]]",
         epoch: int,
     ) -> None:
+        # k: this op is the k-th of its step (the peer's k-th is its twin)
+        op_step, k = None, 0
         while True:
             item = ops.get()
             if item is None:
@@ -2976,9 +2976,13 @@ class TCPCommunicator(Communicator):
                     epoch, f"op timed out after {timeout_s}s"
                 ),
             )
+            flight = self.flight
+            obs_spans.bind(flight)  # this thread works for the replica
+            step = flight.step if flight is not None else None
+            op_step, k = step, (k + 1 if step == op_step else 0)
             self._op_started()
             try:
-                with obs_span("comm::op", epoch=epoch):
+                with obs_span("tpuft/comm/op", epoch=epoch, k=k):
                     result = fn()
             except BaseException as e:  # noqa: BLE001
                 # A fail-stop PEER death on a point-to-point byte op (dead

@@ -26,6 +26,9 @@ from torchft_tpu.checkpointing.serialization import (
     shard_key as _shard_key,
 )
 from torchft_tpu.manager import Manager
+from torchft_tpu.obs import spans as obs_spans
+from torchft_tpu.obs.flight import FlightEvent
+from torchft_tpu.obs.spans import span as obs_span
 from torchft_tpu.work import DummyWork, Work
 
 # Split gradient buckets at this size (reference: TORCHFT_USE_BUCKETIZATION /
@@ -191,97 +194,135 @@ def allreduce_pytree(
         return _allreduce_pytree_device_quantized(manager, leaves, treedef)
 
     original = list(leaves)
+    # The round trip as ONE span, opened here on the train thread and closed
+    # by the gather thread when the composite work is done; its stages are
+    # child spans on three threads (this one, the communicator's op thread,
+    # the gather thread) that share its ``r`` and ``step``.  DDP_SYNC, the
+    # flight event of its exit, carries the summed seconds of each stage.
+    recorder = manager._flight
+    obs_spans.bind(recorder)  # the caller is this replica's train thread
+    sync_span = obs_span("tpuft/ddp/allreduce_pytree", flight=FlightEvent.DDP_SYNC)
+    sync_span.__enter__()
+    stage_s = {"plan_s": 0.0, "d2h_s": 0.0, "pack_s": 0.0, "ring_wait_s": 0.0, "h2d_s": 0.0}
 
-    # Kick off every device→host transfer asynchronously up front so DMA
-    # overlaps the bucket assembly and the first ring.
-    for leaf in leaves:
-        if isinstance(leaf, jax.Array):
-            leaf.copy_to_host_async()
+    def _plan() -> Tuple[List[List[int]], List[int]]:
+        # Kick off every device→host transfer asynchronously up front so DMA
+        # overlaps the bucket assembly and the first ring.
+        for leaf in leaves:
+            if isinstance(leaf, jax.Array):
+                leaf.copy_to_host_async()
 
-    # Bucket by dtype (each dtype needs its own ring), then split large
-    # buckets at ``bucket_cap`` bytes and submit each as its own collective:
-    # the op thread rings bucket k while we fetch/assemble bucket k+1 —
-    # transfer/communication pipelining, the reference's bucket_cap_mb
-    # (``local_sgd.py:28,477-566``) in jax form.
-    bucket_cap = _bucket_cap_bytes()
-    order: Dict[str, List[int]] = {}
-    leaf_bytes: List[int] = []
-    for i, leaf in enumerate(leaves):
-        if isinstance(leaf, jax.Array) and not leaf.is_fully_addressable:
-            # bucket by what actually crosses the wire: this host's unique
-            # shard bytes (identical on twin hosts, so bucket boundaries —
-            # and therefore ring frame sizes — stay uniform)
-            dtype_name = leaf.dtype.name
-            nbytes = sum(
-                int(s.data.nbytes) for s in _unique_local_shards(leaf).values()
-            )
-        elif hasattr(leaf, "dtype") and hasattr(leaf, "nbytes"):
-            dtype_name, nbytes = leaf.dtype.name, int(leaf.nbytes)
-        else:
-            arr = np.asarray(leaf)
-            dtype_name, nbytes = arr.dtype.name, int(arr.nbytes)
-        leaf_bytes.append(nbytes)
-        order.setdefault(dtype_name, []).append(i)
+        # Bucket by dtype (each dtype needs its own ring), then split large
+        # buckets at ``bucket_cap`` bytes and submit each as its own collective:
+        # the op thread rings bucket k while we fetch/assemble bucket k+1 —
+        # transfer/communication pipelining, the reference's bucket_cap_mb
+        # (``local_sgd.py:28,477-566``) in jax form.
+        bucket_cap = _bucket_cap_bytes()
+        order: Dict[str, List[int]] = {}
+        leaf_bytes: List[int] = []
+        for i, leaf in enumerate(leaves):
+            if isinstance(leaf, jax.Array) and not leaf.is_fully_addressable:
+                # bucket by what actually crosses the wire: this host's unique
+                # shard bytes (identical on twin hosts, so bucket boundaries —
+                # and therefore ring frame sizes — stay uniform)
+                dtype_name = leaf.dtype.name
+                nbytes = sum(
+                    int(s.data.nbytes) for s in _unique_local_shards(leaf).values()
+                )
+            elif hasattr(leaf, "dtype") and hasattr(leaf, "nbytes"):
+                dtype_name, nbytes = leaf.dtype.name, int(leaf.nbytes)
+            else:
+                arr = np.asarray(leaf)
+                dtype_name, nbytes = arr.dtype.name, int(arr.nbytes)
+            leaf_bytes.append(nbytes)
+            order.setdefault(dtype_name, []).append(i)
+
+        groups: List[List[int]] = []
+        for _dtype_name, idxs in order.items():
+            group: List[int] = []
+            group_bytes = 0
+            for i in idxs:
+                if group and group_bytes + leaf_bytes[i] > bucket_cap:
+                    groups.append(group)
+                    group, group_bytes = [], 0
+                group.append(i)
+                group_bytes += leaf_bytes[i]
+            if group:
+                groups.append(group)
+        return groups, leaf_bytes
 
     works: List[Work] = []
     bucket_layouts: List[List[Tuple[int, int, int, tuple]]] = []
-    for _dtype_name, idxs in order.items():
-        group: List[int] = []
-        group_bytes = 0
-        groups: List[List[int]] = []
-        for i in idxs:
-            if group and group_bytes + leaf_bytes[i] > bucket_cap:
-                groups.append(group)
-                group, group_bytes = [], 0
-            group.append(i)
-            group_bytes += leaf_bytes[i]
-        if group:
-            groups.append(group)
-
-        for group in groups:
+    try:
+        with obs_span("tpuft/ddp/plan") as stage:
+            groups, leaf_bytes = _plan()
+        stage_s["plan_s"] = stage.duration_s
+        for bucket, group in enumerate(groups):
             # waits async copies; sharded leaves contribute local shards only
-            contribs = [_host_contribution(leaves[i]) for i in group]
-            total = sum(c[0].size for c in contribs)
-            flat = np.empty(total, dtype=contribs[0][0].dtype)
-            layout = []
-            off = 0
-            for i, (arr, restore) in zip(group, contribs):
-                n = arr.size
-                flat[off : off + n] = arr
-                layout.append((i, off, n, restore))
-                off += n
+            with obs_span("tpuft/ddp/d2h", bucket=bucket) as stage:
+                contribs = [_host_contribution(leaves[i]) for i in group]
+            stage_s["d2h_s"] += stage.duration_s
+            with obs_span("tpuft/ddp/pack", bucket=bucket) as stage:
+                total = sum(c[0].size for c in contribs)
+                flat = np.empty(total, dtype=contribs[0][0].dtype)
+                layout = []
+                off = 0
+                for i, (arr, restore) in zip(group, contribs):
+                    n = arr.size
+                    flat[off : off + n] = arr
+                    layout.append((i, off, n, restore))
+                    off += n
+            stage_s["pack_s"] += stage.duration_s
             # submit immediately: this bucket's ring overlaps the next
             # bucket's fetch/assembly; in_place — the bucket is ours and
             # discarded after the restore, so the ring reduces straight into
             # it (no defensive copy; on this host class that copy costs as
             # much as half the ring itself)
-            works.append(
-                manager.allreduce(
-                    flat,
-                    should_quantize=should_quantize,
-                    in_place=True,
-                    register_pending=stream is None,
+            with obs_span("tpuft/ddp/submit", bucket=bucket):
+                works.append(
+                    manager.allreduce(
+                        flat,
+                        should_quantize=should_quantize,
+                        in_place=True,
+                        register_pending=stream is None,
+                    )
                 )
-            )
             bucket_layouts.append(layout)
+    except BaseException:
+        sync_span.__exit__()
+        raise
 
     def _gather() -> Any:
         out = list(original)
-        for work, layout in zip(works, bucket_layouts):
-            flat = work.wait()
-            for i, off, n, restore in layout:
-                out[i] = restore(flat[off : off + n])
+        for bucket, (work, layout) in enumerate(zip(works, bucket_layouts)):
+            with obs_span("tpuft/ddp/ring_wait", bucket=bucket) as stage:
+                flat = work.wait()
+            stage_s["ring_wait_s"] += stage.duration_s
+            with obs_span("tpuft/ddp/h2d", bucket=bucket) as stage:
+                for i, off, n, restore in layout:
+                    out[i] = restore(flat[off : off + n])
+            stage_s["h2d_s"] += stage.duration_s
         return jax.tree_util.tree_unflatten(treedef, out)
 
     fut: "Future[Any]" = Future()
 
     def _finish() -> None:
+        obs_spans.bind(recorder)
+        sync_span.attach()
         try:
-            fut.set_result(_gather())
+            value = _gather()
         except Exception as e:  # noqa: BLE001 — funnel, never raise
             manager.report_error(e)
-            fut.set_result(jax.tree_util.tree_unflatten(treedef, original))
+            value = jax.tree_util.tree_unflatten(treedef, original)
+        sync_span.set(
+            buckets=len(works),
+            bytes=sum(leaf_bytes),
+            **{k: round(v, 6) for k, v in stage_s.items()},
+        )
+        sync_span.__exit__()
+        fut.set_result(value)
 
+    sync_span.detach()  # the gather thread carries the span from here
     threading.Thread(
         target=_finish, name="tpuft_ddp_gather", daemon=True
     ).start()

@@ -1435,12 +1435,12 @@ def _stream_drill(
                 "abort — the fleet never resumed streaming"
             )
         # per-fragment trace spans: every streamed round records a
-        # stream::submit / stream::barrier pair tagged with its fragment
+        # tpuft/stream/submit / tpuft/stream/barrier pair tagged with its fragment
         # index (both fragments of the two-leaf model must appear) — the
         # span side of the same lifecycle the FRAG_* events pin above
         span_frags: Dict[str, set] = {
-            "stream::submit": set(),
-            "stream::barrier": set(),
+            "tpuft/stream/submit": set(),
+            "tpuft/stream/barrier": set(),
         }
         for rec in obs_spans.snapshot():
             if rec["name"] in span_frags:

@@ -99,8 +99,6 @@ _k("TORCHFT_USE_OTEL", "bool", "0",
    "Opt into the OpenTelemetry metrics exporter when the SDK is installed")
 _k("TORCHFT_LOG_DIR", "str", "unset",
    "Directory for JSONL metrics logs (torchft_quorums / torchft_heals); enables logging when set")
-_k("TORCHFT_TRACE_DIR", "str", "unset",
-   "Directory for per-epoch chrome-trace dumps (off when unset)")
 _k("TORCHFT_FLIGHT_EVENTS", "int", "4096",
    "Flight-recorder ring capacity (typed events per replica); 0 disables recording entirely")
 _k("TORCHFT_FLIGHT_DIR", "str", "unset",

@@ -433,7 +433,7 @@ class LocalSGD:
         self._local_step = 0
         if self._stream_stall > 0:
             self._manager.start_quorum()
-            with obs_span("stream::submit", frag=0):
+            with obs_span("tpuft/stream/submit", frag=0):
                 # stream=0 registers the composite work in the Manager's
                 # stream-fence registry (FRAG_SUBMIT rides it)
                 self._stream_work = allreduce_pytree(
@@ -447,7 +447,7 @@ class LocalSGD:
         the (by now usually drained) collective, vote, and adopt the
         committed average."""
         work, self._stream_work = self._stream_work, None
-        with obs_span("stream::barrier", frag=0):
+        with obs_span("tpuft/stream/barrier", frag=0):
             averaged = work.wait()
         committed = self._manager.should_commit()
         self._manager.stream_resolved(0, committed)
@@ -577,7 +577,7 @@ class _Fragment:
         assert self._work is None, "fragment already has an allreduce in flight"
         self._stream_inflight = stream
         with obs_span(
-            "stream::submit" if stream else "diloco::prepare",
+            "tpuft/stream/submit" if stream else "tpuft/diloco/prepare",
             frag=self._index,
         ):
             if self._sharded():
@@ -642,7 +642,7 @@ class _Fragment:
         assert self._work is not None, "prepare_sync must run first"
         streamed = self._stream_inflight
         with obs_span(
-            "stream::barrier" if streamed else "diloco::perform",
+            "tpuft/stream/barrier" if streamed else "tpuft/diloco/perform",
             frag=self._index,
         ):
             result = self._work.wait()

@@ -39,8 +39,8 @@ import numpy as np
 from torchft_tpu import knobs
 from torchft_tpu.checkpointing._rwlock import RWLock
 from torchft_tpu.obs.flight import FlightEvent, FlightRecorder, flight_dir
+from torchft_tpu.obs import spans as obs_spans
 from torchft_tpu.obs.spans import span as obs_span
-from torchft_tpu.observability import QuorumTracer, traced
 from torchft_tpu.checkpointing.transport import CheckpointTransport
 from torchft_tpu.communicator import Communicator, ReduceOp
 from torchft_tpu.manager_server import ManagerClient, ManagerServer
@@ -197,9 +197,6 @@ class Manager:
 
         # state dict guard: reads (checkpoint serving) vs writes (train loop)
         self._state_dict_lock = RWLock(timeout=self._timeout)
-        # per-quorum profiler epochs (TORCHFT_TRACE_DIR; flight-recorder analog)
-        self._tracer = QuorumTracer()
-
         self._pending_state_dict: Optional[Dict[str, object]] = None
         self._healing = False
         self._errored: Optional[ExceptionWithTraceback] = None
@@ -304,6 +301,9 @@ class Manager:
 
             checkpoint_transport = HTTPTransport(timeout=self._timeout)
         self._checkpoint_transport: CheckpointTransport = checkpoint_transport
+        # the transport's serving threads work for this replica: their
+        # spans and HEAL_SERVE_END land in this replica's ring
+        self._checkpoint_transport.flight = self._flight
 
         self._own_store: Optional[StoreServer] = None
         self._manager_server: Optional[ManagerServer] = None
@@ -774,6 +774,9 @@ class Manager:
 
         self._errored = None
         self._healing = False
+        # the calling thread is this replica's train thread: its spans, and
+        # those of the helpers it starts, carry this replica's id and step
+        obs_spans.bind(self._flight)
         self._flight.set_context(step=self._step)
         self._flight.record(FlightEvent.QUORUM_START, step=self._step)
         # drop stale works from a step the caller abandoned without voting;
@@ -814,7 +817,6 @@ class Manager:
                 self._apply_pending_state_dict()
                 self._healing = False
 
-    @traced("torchft::manager::wait_quorum")
     def wait_quorum(self) -> None:
         """Block until the pending quorum completes; the communicator is in a
         healthy (re)configured state afterwards (``manager.py:617-627``)."""
@@ -823,7 +825,6 @@ class Manager:
         )
         self._quorum_future.result()
 
-    @traced("torchft::manager::_async_quorum")
     def _async_quorum(
         self, allow_heal: bool, shrink_only: bool, quorum_timeout: float
     ) -> None:
@@ -832,8 +833,10 @@ class Manager:
         # this to profiler spans — a dict is greppable in a kill report)
         timings: Dict[str, float] = {}
         self.last_quorum_timings = timings
-        t0 = time.monotonic()
-        with obs_span("manager::quorum_rpc", step=self._step):
+        obs_spans.bind(self._flight)  # the quorum thread works for this replica
+        with obs_span(
+            "tpuft/manager/quorum", step=self._step, into=timings, key="quorum_rpc_s"
+        ):
             quorum = self._client._quorum(
                 group_rank=self._group_rank,
                 step=self._step,
@@ -843,7 +846,6 @@ class Manager:
                 init_sync=self._init_sync,
                 commit_failures=self._commit_failures,
             )
-        timings["quorum_rpc_s"] = time.monotonic() - t0
         self._adopt_quorum(quorum, allow_heal, timings)
 
     def _adopt_quorum(
@@ -995,8 +997,6 @@ class Manager:
             self._logger.info(
                 f"reconfiguring for quorum_id={quorum_id} store={store_prefixed_addr}"
             )
-            # fresh profiler epoch per quorum (flight-recorder analog)
-            self._tracer.on_quorum_change(quorum_id)
             # the (quorum_id, step) pair stamped here is the correlation
             # anchor flight_merge aligns replicas' clocks on
             self._flight.set_context(step=max_step, quorum_id=quorum_id)
@@ -1007,11 +1007,14 @@ class Manager:
                 world=replica_world_size,
                 replica_rank=replica_rank,
             )
-            t_cfg = time.monotonic()
             try:
                 self._quorum_id = quorum_id
                 with obs_span(
-                    "manager::comm_configure", quorum_id=quorum_id
+                    "tpuft/manager/comm_configure",
+                    step=max_step,
+                    quorum_id=quorum_id,
+                    into=timings,
+                    key="configure_s",
                 ):
                     self._comm.configure(
                         store_prefixed_addr,
@@ -1029,7 +1032,6 @@ class Manager:
                 return
             finally:
                 self._comm_health_folding = False
-                timings["configure_s"] = time.monotonic() - t_cfg
             # lane layout of the fresh epoch (benches/operators read it from
             # last_quorum_timings next to the phase wall-times)
             fresh_lane_stats = (
@@ -1072,14 +1074,16 @@ class Manager:
                 )
                 if send_dsts:
                     self._logger.info(f"peers need recovery from us {send_dsts}")
-                    t_send = time.monotonic()
-                    self._flight.record(
-                        FlightEvent.HEAL_SEND_BEGIN,
+                    with obs_span(
+                        "tpuft/heal/snapshot",
                         step=max_step,
+                        begin=FlightEvent.HEAL_SEND_BEGIN,
+                        flight=FlightEvent.HEAL_SEND_END,
+                        into=timings,
+                        key="heal_send_s",
                         dst_ranks=list(send_dsts),
                         striped=i_am_striped_source,
-                    )
-                    with obs_span("manager::heal_send", step=max_step):
+                    ):
                         if i_am_striped_source:
                             self._checkpoint_transport.send_checkpoint_striped(
                                 dst_ranks=send_dsts,
@@ -1096,66 +1100,57 @@ class Manager:
                                 state_dict=self._manager_state_dict(),
                                 timeout=self._timeout,
                             )
-                    timings["heal_send_s"] = time.monotonic() - t_send
-                    self._flight.record(
-                        FlightEvent.HEAL_SEND_END,
-                        step=max_step,
-                        duration_s=round(timings["heal_send_s"], 4),
-                    )
 
                 if heal:
-                    t_recv = time.monotonic()
                     self._healing = True
-                    self._flight.record(
-                        FlightEvent.HEAL_RECV_BEGIN,
+                    with obs_span(
+                        "tpuft/heal/fetch",
                         step=max_step,
+                        begin=FlightEvent.HEAL_RECV_BEGIN,
+                        flight=FlightEvent.HEAL_RECV_END,
+                        into=timings,
+                        key="heal_recv_s",
                         sources=len(striped_sources) or 1,
-                    )
-                    if len(striped_sources) > 1:
-                        with obs_span("manager::heal_recv", step=max_step):
+                    ) as fetch_span:
+                        if len(striped_sources) > 1:
                             self._pending_state_dict = self._recv_striped_checkpoint(
-                                quorum.heal_sources(), max_step, timings
+                                quorum.heal_sources(), max_step
                             )
-                    else:
-                        self._logger.info(
-                            "healing required, fetching checkpoint metadata from "
-                            f"{quorum.recover_src_manager_address} max_step={max_step}"
-                        )
-                        primary_client = self._peer_client_factory(
-                            quorum.recover_src_manager_address
-                        )
-                        checkpoint_metadata = primary_client._checkpoint_metadata(
-                            self._group_rank, timeout=self._timeout
-                        )
-                        primary_client.close()
-                        recover_src_replica_rank = quorum.recover_src_replica_rank
-                        assert recover_src_replica_rank is not None, (
-                            "must have a recover rank when healing"
-                        )
-                        self._logger.info(
-                            f"fetching checkpoint from {recover_src_replica_rank=} "
-                            f"with {checkpoint_metadata=}"
-                        )
-                        # applied on the main thread at should_commit when safe
-                        self._pending_state_dict = (
-                            self._checkpoint_transport.recv_checkpoint(
-                                src_rank=recover_src_replica_rank,
-                                metadata=checkpoint_metadata,
-                                step=max_step,
-                                timeout=self._timeout,
+                        else:
+                            self._logger.info(
+                                "healing required, fetching checkpoint metadata from "
+                                f"{quorum.recover_src_manager_address} max_step={max_step}"
                             )
+                            primary_client = self._peer_client_factory(
+                                quorum.recover_src_manager_address
+                            )
+                            checkpoint_metadata = primary_client._checkpoint_metadata(
+                                self._group_rank, timeout=self._timeout
+                            )
+                            primary_client.close()
+                            recover_src_replica_rank = quorum.recover_src_replica_rank
+                            assert recover_src_replica_rank is not None, (
+                                "must have a recover rank when healing"
+                            )
+                            self._logger.info(
+                                f"fetching checkpoint from {recover_src_replica_rank=} "
+                                f"with {checkpoint_metadata=}"
+                            )
+                            # applied on the main thread at should_commit when safe
+                            self._pending_state_dict = (
+                                self._checkpoint_transport.recv_checkpoint(
+                                    src_rank=recover_src_replica_rank,
+                                    metadata=checkpoint_metadata,
+                                    step=max_step,
+                                    timeout=self._timeout,
+                                )
+                            )
+                        self.load_state_dict(
+                            cast(Dict[str, int], self._pending_state_dict["torchft"])
                         )
-                    self.load_state_dict(
-                        cast(Dict[str, int], self._pending_state_dict["torchft"])
-                    )
-                    self._step = max_step
-                    timings["heal_recv_s"] = time.monotonic() - t_recv
-                    self._flight.set_context(step=max_step)
-                    self._flight.record(
-                        FlightEvent.HEAL_RECV_END,
-                        step=max_step,
-                        duration_s=round(timings["heal_recv_s"], 4),
-                    )
+                        self._step = max_step
+                        self._flight.set_context(step=max_step)
+                        self._note_heal_metrics(timings, fetch_span)
             except Exception as e:  # noqa: BLE001
                 self._logger.exception(f"got exception in recovery: {e}")
                 self.report_error(e)
@@ -1166,7 +1161,6 @@ class Manager:
         self,
         sources: List,
         max_step: int,
-        timings: Dict[str, float],
     ) -> Dict[str, object]:
         """Striped multi-source heal: collect each source's transport
         metadata (tolerating unreachable managers — a dead source stays in
@@ -1197,21 +1191,28 @@ class Manager:
         state = self._checkpoint_transport.recv_checkpoint_striped(
             sources=src_list, step=max_step, timeout=self._timeout
         )
-        metrics = getattr(self._checkpoint_transport, "last_heal_metrics", None)
-        if metrics is not None:
-            from torchft_tpu.observability import log_heal
-
-            timings["heal_bytes"] = float(metrics.bytes_total)
-            timings["heal_bytes_per_sec"] = metrics.bytes_per_sec
-            timings["heal_num_sources"] = float(metrics.num_sources)
-            timings["heal_stolen_chunks"] = float(metrics.stolen_chunks)
-            log_heal(
-                metrics,
-                replica_id=self._replica_id,
-                rank=self._group_rank,
-                quorum_id=self._quorum_id,
-            )
         return cast(Dict[str, object], state)
+
+    def _note_heal_metrics(self, timings: Dict[str, float], fetch_span) -> None:
+        """What the transport counted of the fetch that just ended
+        (``last_heal_metrics``), onto the quorum round's timings, the
+        ``torchft_heals`` logger and the fetch span's HEAL_RECV_END."""
+        metrics = getattr(self._checkpoint_transport, "last_heal_metrics", None)
+        if metrics is None:
+            return
+        from torchft_tpu.observability import log_heal
+
+        timings["heal_bytes"] = float(metrics.bytes_total)
+        timings["heal_bytes_per_sec"] = metrics.bytes_per_sec
+        timings["heal_num_sources"] = float(metrics.num_sources)
+        timings["heal_stolen_chunks"] = float(metrics.stolen_chunks)
+        fetch_span.set(bytes=metrics.bytes_total, read_s=round(metrics.read_s, 6))
+        log_heal(
+            metrics,
+            replica_id=self._replica_id,
+            rank=self._group_rank,
+            quorum_id=self._quorum_id,
+        )
 
     def _apply_pending_state_dict(self) -> None:
         assert self._healing, "must be in healing state"
@@ -1225,11 +1226,13 @@ class Manager:
         self._logger.info("applying pending state dict")
         assert self._load_state_dict_fns, "user load_state_dict is not initialized"
         pending_user = cast(Dict[str, object], pending_state_dict["user"])
-        with self._state_dict_lock.w_lock():
-            for key, load_fn in self._load_state_dict_fns.items():
-                load_fn(pending_user[key])
-            self._pending_state_dict = None
-        self._flight.record(FlightEvent.HEAL_APPLY, step=self._step)
+        with obs_span(
+            "tpuft/heal/apply", step=self._step, flight=FlightEvent.HEAL_APPLY
+        ):
+            with self._state_dict_lock.w_lock():
+                for key, load_fn in self._load_state_dict_fns.items():
+                    load_fn(pending_user[key])
+                self._pending_state_dict = None
         self._logger.info("Loaded state dict.")
 
     # ------------------------------------------------------------------
@@ -1339,9 +1342,11 @@ class Manager:
             # AVG = SUM / runtime participant count — replica count is never
             # baked into compiled programs (SURVEY.md §7 hard part 1)
             def _normalize(value: object) -> object:
-                if isinstance(value, np.ndarray):
-                    return _div(value, num_participants)
-                return [_div(a, num_participants) for a in cast(list, value)]
+                # runs on the thread that completed the collective
+                with obs_span("tpuft/manager/normalize"):
+                    if isinstance(value, np.ndarray):
+                        return _div(value, num_participants)
+                    return [_div(a, num_participants) for a in cast(list, value)]
 
             wrapped = self.wrap_work(work.then(_normalize), data)
             if stream is not None:
@@ -1624,7 +1629,6 @@ class Manager:
     # commit
     # ------------------------------------------------------------------
 
-    @traced("torchft::manager::should_commit")
     def should_commit(self, timeout: Optional[float] = None) -> bool:
         """Vote on committing this step (``manager.py:855-943``)."""
         # the vote depends on this step's quorum results (participation
@@ -1644,12 +1648,13 @@ class Manager:
         except Exception as e:  # noqa: BLE001 — funnel, never raise
             self.report_error(e)
         # fence all in-flight collectives, then recovery, before voting
-        with obs_span("manager::fence", step=self._step):
+        with obs_span(
+            "tpuft/manager/fence", step=self._step, flight=FlightEvent.COMMIT_FENCE
+        ):
             self._fence_pending_works()
             if self._recovery_event is not None:
                 self._recovery_event.synchronize(timeout=self._timeout)
                 self._recovery_event = None
-        self._flight.record(FlightEvent.COMMIT_FENCE, step=self._step)
 
         if (err := self._comm.errored()) is not None:
             self.report_error(err)
@@ -1689,7 +1694,7 @@ class Manager:
         self._flight.record(
             FlightEvent.COMMIT_VOTE, step=self._step, local=local_should_commit
         )
-        with obs_span("manager::should_commit", step=self._step):
+        with obs_span("tpuft/manager/should_commit", step=self._step):
             should_commit = self._client.should_commit(
                 self._group_rank,
                 self._step,
@@ -1782,7 +1787,6 @@ class Manager:
     # ------------------------------------------------------------------
 
     def shutdown(self) -> None:
-        self._tracer.stop()  # flush the final quorum epoch's trace
         if flight_dir():
             # the final complete ring (atexit's analog for in-process
             # replicas — a thread-plane victim's dump survives its death)

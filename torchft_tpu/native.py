@@ -40,6 +40,7 @@ from torchft_tpu.communicator import (
 )
 from torchft_tpu.futures import TimerHandle, schedule_timeout
 from torchft_tpu.obs.flight import FlightEvent, FlightRecorder
+from torchft_tpu.obs import spans as obs_spans
 from torchft_tpu.obs.spans import span as obs_span
 from torchft_tpu.work import Work
 
@@ -909,6 +910,8 @@ class CppCommunicator(Communicator):
     # -- op machinery ------------------------------------------------------
 
     def _run_ops(self, ops: "queue.Queue", epoch: int) -> None:
+        # k: this op is the k-th of its step (the peer's k-th is its twin)
+        op_step, k = None, 0
         while True:
             item = ops.get()
             if item is None:
@@ -923,9 +926,13 @@ class CppCommunicator(Communicator):
                     epoch, f"op timed out after {timeout_s}s"
                 ),
             )
+            flight = self.flight
+            obs_spans.bind(flight)  # this thread works for the replica
+            step = flight.step if flight is not None else None
+            op_step, k = step, (k + 1 if step == op_step else 0)
             self._op_started()
             try:
-                with obs_span("comm::op", epoch=epoch, tier="cpp"):
+                with obs_span("tpuft/comm/op", epoch=epoch, k=k, tier="cpp"):
                     result = fn()
             except BaseException as e:  # noqa: BLE001
                 latched = False

@@ -6,9 +6,11 @@ Three pillars riding one event substrate (see ``docs/operations.md`` §17):
   stamped events keyed by ``(step, quorum_id, comm_epoch)``, dumped on
   comm-epoch poison, the Manager error funnel, SIGUSR2, and atexit; the
   native tier's C-side ring merges in via ``tpuft_comm_flight_drain``.
-- :mod:`.spans` — context-manager trace spans nested under the step,
-  exported as Chrome trace-event JSON; ``scripts/flight_merge.py`` aligns
-  multiple replicas into one Perfetto-loadable fleet timeline.
+- :mod:`.spans` — the one span API: every protocol stage is a
+  ``tpuft/<layer>/<stage>`` annotation on the jax profiler's clock, its
+  per-step and per-heal boundaries also events of the flight ring;
+  ``TORCHFT_FLIGHT_SPANS=1`` keeps them for a Chrome trace-event export
+  that ``scripts/flight_merge.py`` aligns into one fleet timeline.
 - :mod:`.metrics` — the central metric-name registry behind the
   Prometheus-text ``/metrics`` endpoints on the lighthouse (TTL-cached
   snapshot, zero new lock traffic) and every ManagerServer.

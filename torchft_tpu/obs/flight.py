@@ -77,8 +77,10 @@ class FlightEvent(enum.IntEnum):
     HEAL_SEND_BEGIN = 7
     HEAL_SEND_END = 8  # detail: dst_ranks, duration_s
     HEAL_RECV_BEGIN = 9
-    HEAL_RECV_END = 10  # detail: bytes, sources, duration_s
+    HEAL_RECV_END = 10  # detail: t0, duration_s, and from a one-source HTTP
+    # fetch read_s (seconds blocked reading the socket) and bytes
     HEAL_APPLY = 11  # pending state dict applied on the train thread
+    # (detail: t0, duration_s)
     # -- hot spares ----------------------------------------------------------
     SPARE_WARM = 12  # warm progress (detail: warm_step, lag)
     SPARE_PROMOTE = 13  # promotion (replica side AND lighthouse side)
@@ -103,6 +105,14 @@ class FlightEvent(enum.IntEnum):
     FRAG_SUBMIT = 26  # streamed fragment outer sync submitted (detail: frag)
     FRAG_COMMIT = 27  # streamed fragment delta applied on a committed vote
     FRAG_ABORT = 28  # streamed fragment sync discarded (failed vote / error)
+    # -- span boundaries (python only; written by obs.spans at a span's exit,
+    # each with t0 and duration_s) ------------------------------------------
+    DDP_SYNC = 29  # one replica-dimension round trip of a gradient pytree
+    # (detail: buckets, bytes, and the summed seconds of each stage:
+    # plan_s, d2h_s, pack_s, ring_wait_s, h2d_s)
+    HEAL_SERVE_END = 30  # one checkpoint response served to a healing peer
+    # (detail: bytes, d2h_s in device-to-host of leaves, write_s blocked
+    # writing the socket)
 
 
 # data-plane events the native tier may record; the ftlint checker requires
@@ -175,6 +185,12 @@ class FlightRecorder:
         # attachment, not ring occupancy (len() would otherwise leak into
         # truthiness and silently skip the first events)
         return True
+
+    @property
+    def step(self) -> int:
+        """The step the Manager last announced (``set_context``): what a
+        span on a helper thread is stamped with."""
+        return self._step
 
     def set_replica_id(self, replica_id: str) -> None:
         self.replica_id = replica_id

@@ -1,40 +1,66 @@
-"""Per-step trace spans with Chrome trace-event export.
+"""The program's one span API: named windows on the profiler's clock.
 
-A span is a named wall-clock window recorded into a process-global bounded
-buffer; nested calls on one thread render as a flame because Chrome's
-``"X"`` (complete) events nest by ``(tid, ts, dur)`` containment — no
-parent bookkeeping needed.  The instrumented protocol tree::
+``span(name, **attrs)`` is a context manager around one stage of the
+protocol.  While a profiler session is on, every span enters a
+``jax.profiler.TraceAnnotation`` and so lies in the SAME ``.xplane.pb`` as
+the device's operations, on one clock, with its attributes as the event's
+stats.  With no session on it makes no annotation and calls nothing of the
+profiler's native module: whether a session is on is read from jax's own
+Python record of it (what ``jax.profiler.start_trace`` and ``trace`` set),
+one attribute.  An annotation a span with none listening was measured on
+the v5e to go with stalled steps (seconds in which the whole process stands
+still: 6 of 16 runs against 0 of 24 without, on machines that show the
+short freezes at all; PERF.md section 6, PR 26), so a span that began
+before the session did is simply not in its trace, and neither is one
+inside a capture that a profiler SERVER took (jax keeps no record of
+those).  Names are ``tpuft/<layer>/<stage>``::
 
-    step
-    └─ quorum_rpc            (manager._async_quorum)
-       └─ comm_configure     (manager._adopt_quorum)
-    └─ comm_op               (communicator op thread, one per collective)
-       └─ lane_window        (striped exchange: one per lane part batch)
-    └─ outer_shard_chunk     (collectives.outer_sharded_sync pipeline)
-    └─ heal_send / heal_recv (checkpoint transfers)
+    tpuft/step/grad, tpuft/step/update            (HSDPTrainer.train_step)
+    tpuft/manager/quorum                           (quorum thread: the RPC)
+    └─ tpuft/manager/comm_configure
+       └─ tpuft/comm/rendezvous
+    tpuft/manager/fence, tpuft/manager/should_commit
+    tpuft/ddp/allreduce_pytree                     (train thread, then gather)
+    ├─ tpuft/ddp/plan, tpuft/ddp/d2h, tpuft/ddp/pack        (train thread)
+    ├─ tpuft/comm/op                               (op thread, one a collective)
+    │  └─ tpuft/comm/lane_window
+    └─ tpuft/ddp/ring_wait, tpuft/ddp/h2d          (gather thread)
+    tpuft/heal/snapshot, tpuft/heal/serve          (survivor)
+    tpuft/heal/fetch, tpuft/heal/apply             (new life)
+    tpuft/outer_shard/*, tpuft/stream/*            (DiLoCo / LocalSGD)
 
-Spans are OFF by default (``TORCHFT_FLIGHT_SPANS=1`` opts in; the bench's
-``obs_overhead_frac`` gate measures recorder+spans enabled at <= 1% step
-time).  When disabled, :func:`span` returns a shared no-op context manager
-— one truthiness check on the hot path.
+**Which replica, which step.**  Replica groups may be threads of one
+process, and helper threads work for one of them.  A thread says whom it
+works for with :func:`bind` (the replica's ``FlightRecorder``: it knows the
+replica's id and the step the Manager last announced); every span then
+carries ``r=`` and, unless the caller gave one, ``step=``.  A span on a
+thread nobody bound says ``r=""``.  A child names its parent by nesting on
+its own thread; across threads the spans of one round trip share ``r`` and
+``step``.  A span that crosses threads (:meth:`_Span.detach` on the thread
+that opened it, :meth:`_Span.attach` on the one that closes it) is two
+annotations of one name, ``r`` and ``step`` whose union is the span.
 
-Export: :func:`export_chrome_trace` writes ``{"traceEvents": [...]}`` JSON
-loadable in Perfetto / chrome://tracing; ``scripts/flight_merge.py`` merges
-several replicas' span files and flight dumps into one fleet timeline.
+**Boundaries also write the flight ring.**  ``span(..., flight=EVENT)``
+records ONE event into the bound recorder at exit, with ``t0``,
+``duration_s``, the attributes and whatever the body added with
+:meth:`_Span.set`; ``begin=EVENT`` records a marker at entry (what a dump
+shows of a stage that never returned).  Only per-step and per-heal
+boundaries take them; a per-bucket span never does.  ``into=dict, key=``
+stores the duration in seconds under ``key`` (``last_quorum_timings``).
 
-The buffer is process-global (thread-plane drills mix their replicas'
-spans onto distinct tids, which is exactly what a one-process fleet is);
-per-replica separation comes from one process per replica in production.
+``TORCHFT_FLIGHT_SPANS=1`` also keeps every span in a process-global
+bounded buffer on ``time.monotonic``; :func:`export_chrome_trace` writes it
+as Chrome trace-event JSON and ``scripts/flight_merge.py --spans`` merges
+several replicas' files with their flight dumps into one fleet timeline.
 """
 
 from __future__ import annotations
 
+import collections
 import json
 import threading
 import time
 from typing import Any, Dict, List, Optional
-
-import collections
 
 from torchft_tpu import knobs
 
@@ -44,9 +70,37 @@ SPANS_ENV = "TORCHFT_FLIGHT_SPANS"
 _enabled: Optional[bool] = None
 _spans: "collections.deque" = collections.deque(maxlen=8192)
 _lock = threading.Lock()
+_bound = threading.local()
+# jax.profiler.TraceAnnotation and jax's record of the session it started,
+# imported at the first span: the lighthouse and the launcher import this
+# package and never a backend
+_annotation_cls: Any = None
+_profile_state: Any = None
+
+
+def _load_profiler() -> None:
+    global _annotation_cls, _profile_state
+    from jax.profiler import TraceAnnotation
+
+    try:
+        from jax._src.profiler import _profile_state as state
+    except ImportError:  # a jax that keeps it elsewhere: ask the profiler
+        state = None
+    _profile_state = state
+    _annotation_cls = TraceAnnotation
+
+
+def _session_on() -> bool:
+    """Whether this process has a profiler session on."""
+    if _annotation_cls is None:
+        _load_profiler()
+    if _profile_state is None:
+        return _annotation_cls.is_enabled()
+    return _profile_state.profile_session is not None
 
 
 def spans_enabled() -> bool:
+    """Whether spans are also kept in the ``TORCHFT_FLIGHT_SPANS`` buffer."""
     global _enabled
     if _enabled is None:
         _enabled = knobs.get_bool(SPANS_ENV, False)
@@ -54,7 +108,7 @@ def spans_enabled() -> bool:
 
 
 def configure(enabled: Optional[bool], cap: Optional[int] = None) -> None:
-    """Pin span collection on/off for the process (``None`` re-reads the
+    """Pin the span buffer on/off for the process (``None`` re-reads the
     env on next use).  ``cap`` resizes the buffer (drops collected spans)."""
     global _enabled, _spans
     _enabled = enabled
@@ -68,59 +122,117 @@ def clear() -> None:
         _spans.clear()
 
 
-class _NullSpan:
-    __slots__ = ()
-
-    def __enter__(self) -> "_NullSpan":
-        return self
-
-    def __exit__(self, *exc: object) -> None:
-        return None
+def bind(recorder: Any) -> None:
+    """The calling thread works for ``recorder``'s replica from here on (a
+    ``FlightRecorder``, or None to unbind)."""
+    _bound.recorder = recorder
 
 
-_NULL = _NullSpan()
+def bound() -> Any:
+    """The recorder the calling thread was bound to, or None: what a thread
+    hands to a helper it starts."""
+    return getattr(_bound, "recorder", None)
 
 
 class _Span:
-    __slots__ = ("name", "attrs", "t0")
+    __slots__ = (
+        "name", "attrs", "t0", "duration_s", "_recorder", "_flight", "_into",
+        "_key", "_begin", "_annotation",
+    )
 
-    def __init__(self, name: str, attrs: Optional[Dict[str, Any]]) -> None:
+    def __init__(self, name, attrs, recorder, flight, begin, into, key) -> None:
         self.name = name
         self.attrs = attrs
         self.t0 = 0.0
+        self.duration_s = 0.0
+        self._recorder = recorder
+        self._flight = flight
+        self._into = into
+        self._key = key
+        self._begin = begin
+        self._annotation = None
 
     def __enter__(self) -> "_Span":
+        if self._begin is not None and self._recorder is not None:
+            self._recorder.record(self._begin, step=self.attrs.get("step"))
+        self.attach()
         self.t0 = time.monotonic()
         return self
 
+    def set(self, **attrs: Any) -> None:
+        """Facts the body learned (bytes, summed seconds of its stages): they
+        ride the exit's flight event and the span buffer, not the profiler's
+        annotation, which was written at entry."""
+        self.attrs.update(attrs)
+
+    def detach(self) -> None:
+        """The opening thread leaves; the span stays open."""
+        annotation, self._annotation = self._annotation, None
+        if annotation is not None:
+            annotation.__exit__(None, None, None)
+
+    def attach(self) -> None:
+        """The calling thread carries the span from here (and closes it)."""
+        # only while a profiler session is on (see the module docstring)
+        if _session_on():
+            self._annotation = _annotation_cls(self.name, **self.attrs)
+            self._annotation.__enter__()
+
     def __exit__(self, *exc: object) -> None:
-        t1 = time.monotonic()
-        _spans.append(  # deque append: GIL-atomic, no lock on the hot path
-            (self.name, self.t0, t1 - self.t0, threading.get_ident(), self.attrs)
-        )
+        self.duration_s = duration = time.monotonic() - self.t0
+        self.detach()
+        attrs = self.attrs
+        if _enabled:
+            _spans.append(  # deque append: GIL-atomic, no lock on the hot path
+                (self.name, self.t0, duration, threading.get_ident(), dict(attrs))
+            )
+        if self._into is not None:
+            self._into[self._key] = duration
+        if self._flight is not None and self._recorder is not None:
+            detail = {k: v for k, v in attrs.items() if k not in ("r", "step")}
+            self._recorder.record(
+                self._flight,
+                step=attrs.get("step"),
+                t0=round(self.t0, 6),
+                duration_s=round(duration, 6),
+                **detail,
+            )
 
 
-def span(name: str, **attrs: Any):
-    """Context manager recording one named wall-clock window.  Free (a
-    shared no-op object) when spans are disabled."""
-    if not spans_enabled():
-        return _NULL
-    return _Span(name, attrs or None)
+def span(
+    name: str,
+    flight: Any = None,
+    begin: Any = None,
+    into: Optional[Dict[str, float]] = None,
+    key: Optional[str] = None,
+    **attrs: Any,
+) -> _Span:
+    """Context manager around one named stage (see the module docstring)."""
+    recorder = getattr(_bound, "recorder", None)
+    if recorder is not None:
+        attrs["r"] = recorder.replica_id
+        if "step" not in attrs:
+            attrs["step"] = recorder.step
+    else:
+        attrs["r"] = ""
+    if _enabled is None:
+        spans_enabled()
+    return _Span(name, attrs, recorder, flight, begin, into, key)
 
 
 def snapshot() -> List[Dict[str, Any]]:
     """Collected spans as dicts, oldest first (non-destructive)."""
     out = []
     for name, t0, dur, tid, attrs in list(_spans):
-        rec: Dict[str, Any] = {
-            "name": name,
-            "t": round(t0, 6),
-            "dur": round(dur, 6),
-            "tid": tid,
-        }
-        if attrs:
-            rec["attrs"] = attrs
-        out.append(rec)
+        out.append(
+            {
+                "name": name,
+                "t": round(t0, 6),
+                "dur": round(dur, 6),
+                "tid": tid,
+                "attrs": attrs,
+            }
+        )
     return out
 
 
@@ -142,17 +254,17 @@ def export_chrome_trace(path: str, replica_id: str = "") -> int:
         )
     spans = snapshot()
     for rec in spans:
-        event = {
-            "name": rec["name"],
-            "ph": "X",
-            "ts": round(rec["t"] * 1e6, 1),
-            "dur": round(rec["dur"] * 1e6, 1),
-            "pid": pid,
-            "tid": rec["tid"],
-        }
-        if "attrs" in rec:
-            event["args"] = rec["attrs"]
-        events.append(event)
+        events.append(
+            {
+                "name": rec["name"],
+                "ph": "X",
+                "ts": round(rec["t"] * 1e6, 1),
+                "dur": round(rec["dur"] * 1e6, 1),
+                "pid": pid,
+                "tid": rec["tid"],
+                "args": rec["attrs"],
+            }
+        )
     with open(path, "w") as f:
         json.dump({"traceEvents": events, "displayTimeUnit": "ms"}, f)
     return len(spans)

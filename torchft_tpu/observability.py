@@ -1,4 +1,4 @@
-"""Observability: structured event logs + per-quorum profiler traces.
+"""Observability: structured event logs and heal metrics.
 
 Reference analogs:
 
@@ -8,21 +8,16 @@ Reference analogs:
   names; this module attaches exporters.  OTLP is used when the
   ``opentelemetry`` SDK is importable; otherwise events are emitted as JSON
   lines (console or ``TORCHFT_LOG_DIR`` files) — same schema, greppable.
-- ``torch.profiler.record_function`` spans on every protocol phase
-  (``manager.py:410`` etc.) → :func:`record_function` using jax's profiler
-  trace annotations.
-- Per-quorum NCCL flight-recorder dirs (``manager.py:815-824``) →
-  :class:`QuorumTracer`: with ``TORCHFT_TRACE_DIR`` set, each quorum epoch
-  gets its own jax profiler trace directory ``quorum_{id}/``, so the
-  post-mortem for a failed epoch is isolated exactly like an FR dump.
+- the reference's profiler spans on every protocol phase
+  (``manager.py:410`` etc.) → :func:`torchft_tpu.obs.spans.span`, the one
+  span API, on the jax profiler's clock.
 
-Everything is opt-in via env (``TORCHFT_USE_OTEL``, ``TORCHFT_LOG_DIR``,
-``TORCHFT_TRACE_DIR``); the default is zero overhead.
+Exporters are opt-in via env (``TORCHFT_USE_OTEL``, ``TORCHFT_LOG_DIR``); the
+default is zero overhead.
 """
 
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import json
 import logging
@@ -30,11 +25,9 @@ import os
 import sys
 import threading
 import time
-from typing import Iterator, Optional
 
 USE_OTEL_ENV = "TORCHFT_USE_OTEL"
 LOG_DIR_ENV = "TORCHFT_LOG_DIR"
-TRACE_DIR_ENV = "TORCHFT_TRACE_DIR"
 
 STRUCTURED_LOGGERS = (
     "torchft_quorums",
@@ -184,7 +177,9 @@ class HealMetrics:
 
     ``per_source_bytes`` is keyed by source id (replica rank or metadata
     URL); ``failed_sources`` lists sources that died or errored mid-heal;
-    ``stolen_chunks`` counts chunk reassignments to a surviving source."""
+    ``stolen_chunks`` counts chunk reassignments to a surviving source;
+    ``read_s`` is the share of ``duration_s`` spent blocked reading the
+    wire (one-source HTTP fetches count it; 0.0 where nobody did)."""
 
     step: int = 0
     num_sources: int = 1
@@ -193,6 +188,7 @@ class HealMetrics:
     per_source_bytes: dict = dataclasses.field(default_factory=dict)
     failed_sources: list = dataclasses.field(default_factory=list)
     stolen_chunks: int = 0
+    read_s: float = 0.0
 
     @property
     def bytes_per_sec(self) -> float:
@@ -227,88 +223,3 @@ def log_heal(
         quorum_id=quorum_id,
     )
     logging.getLogger("torchft_heals").info("", extra=extra)
-
-
-def traced(name: str):
-    """Decorator form of :func:`record_function` for whole protocol verbs."""
-
-    def _wrap(fn):
-        import functools
-
-        @functools.wraps(fn)
-        def _inner(*args, **kwargs):
-            with record_function(name):
-                return fn(*args, **kwargs)
-
-        return _inner
-
-    return _wrap
-
-
-@contextlib.contextmanager
-def record_function(name: str) -> Iterator[None]:
-    """Protocol-phase span (``torch.profiler.record_function`` analog): shows
-    up in jax profiler traces as a named annotation; free when no trace is
-    being captured."""
-    # resolve the annotation class BEFORE entering the body so an
-    # ImportError raised by the wrapped code is never swallowed here
-    try:
-        from jax.profiler import TraceAnnotation
-    except ImportError:  # pragma: no cover
-        TraceAnnotation = None
-    if TraceAnnotation is None:  # pragma: no cover
-        yield
-    else:
-        with TraceAnnotation(name):
-            yield
-
-
-class QuorumTracer:
-    """Per-quorum-epoch jax profiler traces (flight-recorder analog).
-
-    With ``TORCHFT_TRACE_DIR`` set, call ``on_quorum_change(quorum_id)`` from
-    the manager at each reconfiguration: the previous epoch's trace is closed
-    and a fresh one starts under ``{dir}/quorum_{id}``.
-    """
-
-    def __init__(self, base_dir: Optional[str] = None) -> None:
-        self._base_dir = base_dir or os.environ.get(TRACE_DIR_ENV)
-        self._active = False
-        self._lock = threading.Lock()
-
-    @property
-    def enabled(self) -> bool:
-        return bool(self._base_dir)
-
-    def on_quorum_change(self, quorum_id: int) -> None:
-        if not self.enabled:
-            return
-        import jax.profiler
-
-        with self._lock:
-            if self._active:
-                try:
-                    jax.profiler.stop_trace()
-                except RuntimeError:
-                    pass
-                self._active = False
-            path = os.path.join(self._base_dir, f"quorum_{quorum_id}")
-            os.makedirs(path, exist_ok=True)
-            try:
-                jax.profiler.start_trace(path)
-                self._active = True
-            except RuntimeError:
-                pass
-
-    def stop(self) -> None:
-        if not self.enabled:
-            return
-        import jax.profiler
-
-        with self._lock:
-            if self._active:
-                try:
-                    jax.profiler.stop_trace()
-                except RuntimeError:
-                    pass
-                self._active = False

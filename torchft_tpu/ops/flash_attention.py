@@ -172,6 +172,7 @@ def _fwd(
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(q, k, v)
 
 
@@ -322,6 +323,7 @@ def _bwd(
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         interpret=interpret,
+        name="flash_dq",
     )(q, k, v, lse, do, delta)
 
     # dk/dv: grid inner dim flattens (group member, q block) so the scratch
@@ -358,6 +360,7 @@ def _bwd(
             pltpu.VMEM((block_k, D), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_dkv",
     )(q, k, v, lse, do, delta)
     return dq, dk, dv
 

@@ -32,6 +32,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from torchft_tpu.ddp import ft_allreduce
 from torchft_tpu.manager import Manager
+from torchft_tpu.obs.spans import span as obs_span
 
 
 def fsdp_shardings(
@@ -257,15 +258,19 @@ class HSDPTrainer:
     def train_step(self, batch: Any) -> Tuple[float, bool]:
         """One fault-tolerant step; returns (loss, committed)."""
         self.manager.start_quorum()
-        loss, grads = self._grad_step(self.holder["params"], batch)
+        # the two dispatches, named so that a trace says which of them the
+        # device was waiting on
+        with obs_span("tpuft/step/grad"):
+            loss, grads = self._grad_step(self.holder["params"], batch)
         grads = ft_allreduce(
             self.manager, grads, should_quantize=self.quantize_outer
         )
         committed = self.manager.should_commit()
         if committed:
-            params, opt_state = self._update_step(
-                self.holder["params"], self.holder["opt_state"], grads
-            )
+            with obs_span("tpuft/step/update"):
+                params, opt_state = self._update_step(
+                    self.holder["params"], self.holder["opt_state"], grads
+                )
             self.holder["params"] = params
             self.holder["opt_state"] = opt_state
         return float(loss), committed
