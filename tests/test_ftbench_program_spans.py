@@ -1,5 +1,38 @@
 """Tier-1's view of ``ftbench/tests/test_ftbench_program_spans.py``: tier-1 collects
 ``tests/`` only, and the benchmark's own tests guard nothing unless it runs
-them (ROADMAP D3).  The tests live with the benchmark; this file imports them."""
+them (ROADMAP D3).  The tests live with the benchmark; this file imports them
+and adds what a later PR's reader needs (a file under ``ftbench/`` is the
+benchmark's, and only a ``benchmark`` issue may edit it: PERF.md section 7)."""
 
+import json
+import os
+
+import pytest
+
+from ftbench.tests import test_ftbench_program_spans as theirs
 from ftbench.tests.test_ftbench_program_spans import *  # noqa: F401,F403
+
+# PR 27: the division by the participant count, two spans of 40 ms a step
+LATER_READINGS = {"sync_normalize_ms": 80.0}
+
+
+@pytest.mark.parametrize("name", sorted(LATER_READINGS))
+def test_later_span_reader_on_synthetic_planes(run, name, monkeypatch):  # noqa: F405
+    monkeypatch.setitem(theirs.READINGS, name, LATER_READINGS[name])
+    theirs.test_span_reader_on_synthetic_planes(run, name, monkeypatch)
+
+
+def test_new_readers_are_the_eighteen_benchmark_json_lists():  # noqa: F811
+    """Theirs holds PR 26's eighteen to be the LAST entries of ``per_layer``;
+    a later PR appends, so here they are the eighteen before the later ones."""
+    with open(os.path.join(theirs.ROOT, "BENCHMARK.json")) as f:
+        per_layer = json.load(f)["per_layer"]
+    later = len(LATER_READINGS)
+    assert [m["name"] for m in per_layer[-later:]] == list(LATER_READINGS)
+    theirs_new = set(theirs.READINGS) | set(theirs.KILL_READINGS) | {"flash_fwd_ms", "flash_dq_ms", "flash_dkv_ms"}
+    assert len(theirs_new) == 18
+    assert {m["name"] for m in per_layer[-18 - later:-later]} == theirs_new
+    for entry in per_layer[-18 - later:]:
+        assert len(entry["workloads"]) == 1 and set(entry) == {
+            "name", "unit", "better", "source", "layer", "moves", "workloads",
+        }

@@ -17,7 +17,11 @@ from ftbench.tests.test_ftbench_rehearsal import (  # noqa: F401
 )
 
 _CASES = theirs.test_rehearsal_walks_the_cell.pytestmark[0].args[1]
-_NEW = {"mistral7b-ddp2-steady": set(READINGS), "mistral7b-ddp2-kill": set(KILL_READINGS)}
+# sync_normalize_ms is PR 27's reader of PR 26's span tpuft/manager/normalize
+_NEW = {
+    "mistral7b-ddp2-steady": set(READINGS) | {"sync_normalize_ms"},
+    "mistral7b-ddp2-kill": set(KILL_READINGS),
+}
 
 
 @pytest.mark.parametrize(

@@ -434,3 +434,90 @@ def test_allreduce_default_does_not_mutate_input() -> None:
     out = manager.allreduce(data).wait(timeout=5.0)
     np.testing.assert_array_equal(data, keep)  # input untouched
     np.testing.assert_array_equal(out, keep / 2)  # AVG over 2 participants
+
+
+def _div_input(dtype_name: str) -> np.ndarray:
+    """Every 16-bit pattern for the 16-bit floats (NaNs, infinities,
+    subnormals, both zeros); seeded values with edge cases for the rest."""
+    import ml_dtypes
+
+    dtype = np.dtype(getattr(ml_dtypes, dtype_name, dtype_name))
+    if dtype.itemsize == 2:
+        return np.arange(1 << 16, dtype=np.uint16).view(dtype)
+    rng = np.random.default_rng(27)
+    if np.issubdtype(dtype, np.integer):
+        info = np.iinfo(dtype)
+        edge = np.array([0, 1, -1, 256, -257, info.max, info.min + 1], dtype=dtype)
+        return np.concatenate([rng.integers(-(1 << 20), 1 << 20, 4096).astype(dtype), edge])
+    edge = np.array([0.0, -0.0, np.inf, -np.inf, np.nan, np.finfo(dtype).tiny / 2, np.finfo(dtype).max], dtype=dtype)
+    return np.concatenate([(rng.standard_normal(4096) * 1e3).astype(dtype), edge])
+
+
+@pytest.mark.parametrize("n", [1, 2, 3, 7, 257, 1000])
+@pytest.mark.parametrize("dtype_name", ["bfloat16", "float16", "float32", "float64", "int32", "int64"])
+def test_div_is_bit_equal_to_the_out_of_place_formula(dtype_name: str, n: int) -> None:
+    """``_div`` averages a reduced buffer with no array of the payload's
+    size beside it, and gives bit for bit what ``(a / n).astype(a.dtype)``
+    (integers ``a // n``) gave: no configuration can train differently."""
+    from torchft_tpu.manager import _div
+
+    a = _div_input(dtype_name)
+    keep = a.copy()
+    with np.errstate(all="ignore"):
+        want = a // n if np.issubdtype(a.dtype, np.integer) else (a / n).astype(a.dtype)
+        fresh = _div(a, n)
+        assert a.tobytes() == keep.tobytes()  # out-of-place: the input is untouched
+        mine = a.copy()
+        written = _div(mine, n, out=mine)
+    assert fresh.dtype == written.dtype == want.dtype == a.dtype
+    assert np.shares_memory(written, mine)
+    if n == 1:
+        # no pass, not even a copy: every number is the formula's (a NaN
+        # keeps the payload bits a trip through float32 would have quieted)
+        assert fresh is a and written is mine
+        with np.errstate(invalid="ignore"):
+            assert np.array_equal(a, want, equal_nan=a.dtype.kind != "i")
+    else:
+        assert not np.shares_memory(fresh, a)
+        assert fresh.tobytes() == want.tobytes()
+        assert written.tobytes() == want.tobytes()
+
+
+def test_allreduce_in_place_averages_into_the_bucket_itself() -> None:
+    """``in_place=True`` (the ddp bucket path): the average lands in the
+    reduced buffer, and nothing near the payload's size is allocated on the
+    way (the out-of-place formula peaked at three times the payload)."""
+    import tracemalloc
+
+    import ml_dtypes
+
+    client = StubClient()
+    client.quorum_results.append(_quorum_result())
+    manager = _make_manager(client)
+    manager.start_quorum()
+    manager.wait_quorum()
+    rng = np.random.default_rng(27)
+    bucket = rng.standard_normal(16 << 20, dtype=np.float32).astype(ml_dtypes.bfloat16)  # 32 MB
+    want = (bucket / 2).astype(bucket.dtype)
+    tracemalloc.start()  # the bucket and `want` were made before: not counted
+    try:
+        out = manager.allreduce(bucket, in_place=True).wait(timeout=30.0)
+        peak = tracemalloc.get_traced_memory()[1]
+    finally:
+        tracemalloc.stop()
+    assert peak < bucket.nbytes // 4, (peak, bucket.nbytes)
+    assert np.shares_memory(out, bucket)
+    assert out.tobytes() == want.tobytes()
+
+
+def test_allreduce_in_place_leaves_a_read_only_buffer_alone() -> None:
+    """A buffer the communicator let through read-only is not ours to write."""
+    client = StubClient()
+    client.quorum_results.append(_quorum_result())
+    manager = _make_manager(client)
+    manager.start_quorum()
+    data = np.full(8, 6.0, dtype=np.float32)
+    data.flags.writeable = False
+    out = manager.allreduce(data, in_place=True).wait(timeout=5.0)
+    np.testing.assert_array_equal(data, np.full(8, 6.0, dtype=np.float32))
+    np.testing.assert_array_equal(out, np.full(8, 3.0, dtype=np.float32))

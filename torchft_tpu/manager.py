@@ -1273,10 +1273,13 @@ class Manager:
         zeroed and the result is still divided by ``num_participants()``.
 
         ``in_place=True`` skips the communicator's full-payload defensive
-        copy by reducing directly in ``data``'s buffers — pass it ONLY for
+        copy by reducing directly in ``data``'s buffers, and the AVERAGE is
+        written into those same buffers too (the Work's value aliases them;
+        nothing of the payload's size is allocated) — pass it ONLY for
         buffers you built for this call and will not read afterwards (the
         ddp bucket path does); buffers that alias live state (LocalSGD's
-        host params) must keep the default.
+        host params) must keep the default, which leaves ``data`` untouched
+        and returns the average in one new array per buffer.
 
         ``stream``, when given, marks this as an ASYNC streamed fragment
         submit (the TORCHFT_STREAM_SYNC scheduler riding the legacy
@@ -1342,11 +1345,20 @@ class Manager:
             # AVG = SUM / runtime participant count — replica count is never
             # baked into compiled programs (SURVEY.md §7 hard part 1)
             def _normalize(value: object) -> object:
-                # runs on the thread that completed the collective
-                with obs_span("tpuft/manager/normalize"):
-                    if isinstance(value, np.ndarray):
-                        return _div(value, num_participants)
-                    return [_div(a, num_participants) for a in cast(list, value)]
+                # runs on the thread that completed the collective (the
+                # communicator's op thread: the next bucket's ring waits)
+                single = isinstance(value, np.ndarray)
+                arrays = [value] if single else cast(list, value)
+                # the caller's flag says whose the reduced buffers are (a
+                # read-only one the communicator let through is not ours)
+                outs = [a if in_place and a.flags.writeable else None for a in arrays]
+                with obs_span(
+                    "tpuft/manager/normalize",
+                    bytes=sum(int(a.nbytes) for a in arrays),
+                    in_place=int(all(o is not None for o in outs)),
+                ):
+                    out = [_div(a, num_participants, o) for a, o in zip(arrays, outs)]
+                return out[0] if single else out
 
             wrapped = self.wrap_work(work.then(_normalize), data)
             if stream is not None:
@@ -1840,15 +1852,32 @@ def _scale_contribution(
     return [_one(a) for a in data]
 
 
-def _div(a: np.ndarray, n: int) -> np.ndarray:
-    # Always out-of-place: the communicator may return the caller's own
-    # buffer aliased (DummyCommunicator passthrough), and mutating it would
-    # silently corrupt a retained gradient. Integer grads floor-divide;
-    # everything else (incl. extension float dtypes like bfloat16, which are
-    # NOT np.inexact subdtypes) true-divides.
+def _div(a: np.ndarray, n: int, out: Optional[np.ndarray] = None) -> np.ndarray:
+    """``a / n`` in ``a``'s dtype: the one place that averages a reduced
+    buffer.  ``out=a`` writes the average over the sum (the caller owns the
+    buffer); ``out=None`` leaves ``a`` untouched and returns one new array:
+    the communicator may return the caller's own buffer aliased
+    (DummyCommunicator passthrough), and mutating it would silently corrupt a
+    retained gradient.  ``n == 1`` is ``a`` itself, no pass.
+
+    Integer grads floor-divide.  Everything else (incl. extension float
+    dtypes like bfloat16, which are NOT np.inexact subdtypes) true-divides in
+    float32 (what is wider stays as wide) and rounds to nearest-even: bit for
+    bit ``(a / n).astype(a.dtype)``.  The divisor is a scalar of that
+    arithmetic, never of ``a``'s dtype (257 is no bfloat16), and numpy casts
+    block by block through its own small buffer, so nothing of the payload's
+    size is allocated: ``a / n`` made a float32 array of twice a bfloat16
+    payload and the cast a third, all on freshly mapped pages, which on the
+    v5e's host was the whole cost of this stage (267 MB/s against 2,670 in
+    place; PERF.md section 6, PR 27)."""
+    if n == 1:
+        return a
+    if out is None:
+        out = np.empty_like(a)
     if np.issubdtype(a.dtype, np.integer):
-        return a // n
-    return (a / n).astype(a.dtype)
+        return np.floor_divide(a, n, out=out)
+    divisor = np.result_type(a.dtype, np.float32).type(n)
+    return np.true_divide(a, divisor, out=out, casting="unsafe")
 
 
 class _ManagerLogger:
