@@ -285,3 +285,43 @@ def test_flash_ring_attention_matches_dense(monkeypatch) -> None:
             np.asarray(a), np.asarray(b), rtol=1e-3, atol=1e-3,
             err_msg=f"d{name}",
         )
+
+
+# -- v heads of another size than q's and k's (latent attention: 192 / 128) ----
+
+
+@pytest.mark.parametrize(
+    "H,KV,D,Dv,S,bq,bk",
+    [
+        (2, 2, 192, 128, 256, 128, 128),  # Ling-3.0-flash's MLA heads, several blocks
+        (4, 2, 48, 32, 256, 512, 512),  # the toy widths, grouped, one block
+        (2, 2, 32, 64, 256, 128, 256),  # v wider than q and k
+    ],
+)
+def test_value_heads_of_another_size(H, KV, D, Dv, S, bq, bk) -> None:
+    """Forward and the three gradients against plain attention.  The same
+    tolerances as for equal sizes above: float32's rounding through a
+    softmax over 256 keys; the output and dv have v's head size."""
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(3), 3)
+    q = jax.random.normal(kq, (2, S, H, D), jnp.float32)
+    k = jax.random.normal(kk, (2, S, KV, D), jnp.float32)
+    v = jax.random.normal(kv, (2, S, KV, Dv), jnp.float32)
+    flash = lambda q, k, v: flash_attention(  # noqa: E731
+        q, k, v, causal=True, block_q=bq, block_k=bk, interpret=True
+    )
+    out = flash(q, k, v)
+    assert out.shape == (2, S, H, Dv)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(_ref_attention(q, k, v)), rtol=2e-5, atol=2e-5)
+    g_flash = jax.grad(lambda *a: jnp.sum(jnp.sin(flash(*a))), argnums=(0, 1, 2))(q, k, v)
+    g_ref = jax.grad(lambda *a: jnp.sum(jnp.sin(_ref_attention(*a))), argnums=(0, 1, 2))(q, k, v)
+    for name, a, b in zip("qkv", g_flash, g_ref):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-4, err_msg=f"d{name}")
+
+
+def test_head_sizes_that_do_not_fit_are_refused() -> None:
+    q, k, v = _qkv(1, 256, 2, 2, 64)
+    with pytest.raises(ValueError):
+        flash_attention(q, k[..., :32], v, interpret=True)  # q and k differ
+    with pytest.raises(ValueError):
+        flash_attention(q, k, v[:, :128], interpret=True)  # v shorter than k

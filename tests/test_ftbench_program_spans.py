@@ -14,6 +14,12 @@ from ftbench.tests.test_ftbench_program_spans import *  # noqa: F401,F403
 
 # PR 27: the division by the participant count, two spans of 40 ms a step
 LATER_READINGS = {"sync_normalize_ms": 80.0}
+# PR 29: the readers of the cell ling3flash-ws1-seq8k, appended after it
+# (their own tests: ftbench/tests/test_ftbench_ling.py)
+LING_READERS = (
+    "kda_fwd_ms", "kda_bwd_ms", "kda_roofline", "mla_flash_ms", "mla_flash_roofline", "moe_gmm_ms",
+    "moe_gmm_roofline", "ling_step_mfu_pct", "moe_rows_here_per_step", "moe_load_max_over_mean",
+)
 
 
 @pytest.mark.parametrize("name", sorted(LATER_READINGS))
@@ -27,8 +33,8 @@ def test_new_readers_are_the_eighteen_benchmark_json_lists():  # noqa: F811
     a later PR appends, so here they are the eighteen before the later ones."""
     with open(os.path.join(theirs.ROOT, "BENCHMARK.json")) as f:
         per_layer = json.load(f)["per_layer"]
-    later = len(LATER_READINGS)
-    assert [m["name"] for m in per_layer[-later:]] == list(LATER_READINGS)
+    later = len(LATER_READINGS) + len(LING_READERS)
+    assert [m["name"] for m in per_layer[-later:]] == list(LATER_READINGS) + list(LING_READERS)
     theirs_new = set(theirs.READINGS) | set(theirs.KILL_READINGS) | {"flash_fwd_ms", "flash_dq_ms", "flash_dkv_ms"}
     assert len(theirs_new) == 18
     assert {m["name"] for m in per_layer[-18 - later:-later]} == theirs_new

@@ -113,6 +113,10 @@ class FlightEvent(enum.IntEnum):
     HEAL_SERVE_END = 30  # one checkpoint response served to a healing peer
     # (detail: bytes, d2h_s in device-to-host of leaves, write_s blocked
     # writing the socket)
+    # -- the model's own counters (python only) ------------------------------
+    MOE_ROUTE = 31  # one committed step of a model with routed experts
+    # (HSDPTrainer; detail, expert layer by expert layer: rows_here routed to
+    # the experts this chip holds, load_max and load_mean over them)
 
 
 # data-plane events the native tier may record; the ftlint checker requires

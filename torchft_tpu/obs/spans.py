@@ -29,6 +29,16 @@ those).  Names are ``tpuft/<layer>/<stage>``::
     tpuft/heal/fetch, tpuft/heal/apply             (new life)
     tpuft/outer_shard/*, tpuft/stream/*            (DiLoCo / LocalSGD)
 
+**Device operations with names of their own.**  A Mosaic kernel is named by
+its ``pallas_call``'s ``name=`` and is ``%<name>.N`` among a trace's device
+operations; these are the names the program gives out::
+
+    flash_fwd, flash_dq, flash_dkv     (ops/flash_attention.py)
+    kda_fwd, kda_bwd                   (ops/kda.py: the chunked delta rule)
+    ...gmm..., ...tgmm...              (parallel/moe.py RoutedExperts: jax's
+                                        megablox kernels, named after the
+                                        jitted functions around them)
+
 **Which replica, which step.**  Replica groups may be threads of one
 process, and helper threads work for one of them.  A thread says whom it
 works for with :func:`bind` (the replica's ``FlightRecorder``: it knows the

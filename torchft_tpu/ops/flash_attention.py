@@ -128,9 +128,11 @@ def _fwd(
     block_k: int,
     interpret: bool,
 ) -> Tuple[jax.Array, jax.Array]:
-    """q [B,H,Sq,D], k/v [B,KV,Sk,D] → (o [B,H,Sq,D], lse [B,H,Sq]).
-    Rectangular (Sq != Sk) is allowed when not causal."""
+    """q [B,H,Sq,D], k [B,KV,Sk,D], v [B,KV,Sk,Dv] → (o [B,H,Sq,Dv],
+    lse [B,H,Sq]).  Rectangular (Sq != Sk) is allowed when not causal; v's
+    head size may differ from q's and k's (latent attention: 192 and 128)."""
     B, H, S, D = q.shape
+    Dv = v.shape[3]
     KV = k.shape[1]
     Sk = k.shape[2]
     groups = H // KV
@@ -152,24 +154,24 @@ def _fwd(
                 (1, 1, block_k, D), lambda b, h, qi, ki: (b, h // groups, ki, 0)
             ),
             pl.BlockSpec(
-                (1, 1, block_k, D), lambda b, h, qi, ki: (b, h // groups, ki, 0)
+                (1, 1, block_k, Dv), lambda b, h, qi, ki: (b, h // groups, ki, 0)
             ),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, block_q, D), lambda b, h, qi, ki: (b, h, qi, 0)),
+            pl.BlockSpec((1, 1, block_q, Dv), lambda b, h, qi, ki: (b, h, qi, 0)),
             pl.BlockSpec(
                 (1, 1, block_q, _ROW_LANES),
                 lambda b, h, qi, ki: (b, h, qi, 0),
             ),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
+            jax.ShapeDtypeStruct((B, H, S, Dv), q.dtype),
             jax.ShapeDtypeStruct((B, H, S, _ROW_LANES), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, _LANES), jnp.float32),
-            pltpu.VMEM((block_q, D), jnp.float32),
+            pltpu.VMEM((block_q, Dv), jnp.float32),
         ],
         interpret=interpret,
         name="flash_fwd",
@@ -288,6 +290,7 @@ def _bwd(
     ds = p·(dp − (delta − dlse)) — the kernels are unchanged."""
     q, k, v, o, lse = residuals
     B, H, S, D = q.shape
+    Dv = v.shape[3]
     KV = k.shape[1]
     Sk = k.shape[2]
     groups = H // KV
@@ -314,9 +317,9 @@ def _bwd(
         in_specs=[
             pl.BlockSpec((1, 1, block_q, D), q_map),
             pl.BlockSpec((1, 1, block_k, D), kv_map),
-            pl.BlockSpec((1, 1, block_k, D), kv_map),
+            pl.BlockSpec((1, 1, block_k, Dv), kv_map),
             pl.BlockSpec((1, 1, block_q, _ROW_LANES), row_map),
-            pl.BlockSpec((1, 1, block_q, D), q_map),
+            pl.BlockSpec((1, 1, block_q, Dv), q_map),
             pl.BlockSpec((1, 1, block_q, _ROW_LANES), row_map),
         ],
         out_specs=pl.BlockSpec((1, 1, block_q, D), q_map),
@@ -342,14 +345,14 @@ def _bwd(
         in_specs=[
             pl.BlockSpec((1, 1, block_q, D), g_q_map),
             pl.BlockSpec((1, 1, block_k, D), g_kv_map),
-            pl.BlockSpec((1, 1, block_k, D), g_kv_map),
+            pl.BlockSpec((1, 1, block_k, Dv), g_kv_map),
             pl.BlockSpec((1, 1, block_q, _ROW_LANES), g_row_map),
-            pl.BlockSpec((1, 1, block_q, D), g_q_map),
+            pl.BlockSpec((1, 1, block_q, Dv), g_q_map),
             pl.BlockSpec((1, 1, block_q, _ROW_LANES), g_row_map),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, block_k, D), g_kv_map),
-            pl.BlockSpec((1, 1, block_k, D), g_kv_map),
+            pl.BlockSpec((1, 1, block_k, Dv), g_kv_map),
         ],
         out_shape=[
             jax.ShapeDtypeStruct(k.shape, k.dtype),
@@ -357,7 +360,7 @@ def _bwd(
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, D), jnp.float32),
-            pltpu.VMEM((block_k, D), jnp.float32),
+            pltpu.VMEM((block_k, Dv), jnp.float32),
         ],
         interpret=interpret,
         name="flash_dkv",
@@ -370,14 +373,20 @@ def _bwd(
 # ---------------------------------------------------------------------------
 
 
-def _validate(q, k, causal, sm_scale, block_q, block_k):
+def _validate(q, k, v, causal, sm_scale, block_q, block_k):
     """Shared shape/divisibility validation for the public wrappers
-    ([B, S, H, D] layout).  Returns the resolved (sm_scale, bq, bk)."""
+    ([B, S, H, D] layout).  Returns the resolved (sm_scale, bq, bk).  q and
+    k share a head size; v may have its own (the output has v's)."""
     B, S, H, D = q.shape
     KV = k.shape[2]
     Sk = k.shape[1]
     if H % KV:
         raise ValueError(f"GQA needs H % KV == 0, got H={H} KV={KV}")
+    if k.shape[3] != D or v.shape[:3] != k.shape[:3]:
+        raise ValueError(
+            f"q and k need one head size and v k's other dims, got "
+            f"q={q.shape} k={k.shape} v={v.shape}"
+        )
     if causal and Sk != S:
         raise ValueError(
             f"causal attention needs Sq == Sk, got Sq={S} Sk={Sk}"
@@ -454,7 +463,7 @@ def flash_attention_lse(
     K/V may carry a different sequence length than q (partial-block
     attention) when ``causal=False``."""
     sm_scale, block_q, block_k = _validate(
-        q, k, causal, sm_scale, block_q, block_k
+        q, k, v, causal, sm_scale, block_q, block_k
     )
     o, lse = _flash_hm_lse(
         q.transpose(0, 2, 1, 3),
@@ -487,7 +496,7 @@ def flash_attention(
     Llama dispatch falls back to the naive path otherwise).
     """
     sm_scale, block_q, block_k = _validate(
-        q, k, causal, sm_scale, block_q, block_k
+        q, k, v, causal, sm_scale, block_q, block_k
     )
 
     # kernel layout: heads-major so a (bq, D) block is contiguous in S,D

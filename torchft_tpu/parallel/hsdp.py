@@ -25,13 +25,16 @@ materialized on one host.
 
 from __future__ import annotations
 
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
+import jax.numpy as jnp
+import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from torchft_tpu.ddp import ft_allreduce
 from torchft_tpu.manager import Manager
+from torchft_tpu.obs.flight import FlightEvent
 from torchft_tpu.obs.spans import span as obs_span
 
 
@@ -68,15 +71,53 @@ def shard_init(model: Any, key: jax.Array, mesh: Mesh) -> Any:
         return jax.jit(model.init, out_shardings=params_sh)(key)
 
 
+def _state_mask(model: Any) -> Optional[List[bool]]:
+    """Which leaves of ``params`` (in flatten order) are state the optimizer
+    does not own, or None for a model that declares none (``Llama``).  A
+    model with such state (``models/ling_hybrid.py``: the routers' selection
+    biases) has ``state_mask()``, ``objective(params, batch) -> (scalar,
+    [signal of every state leaf])``, ``advance_state(state, signal)`` and
+    ``route_summary(signal)`` / ``route_stats(summary)``, the detail of a
+    committed step's flight event (a few numbers made on the device and read
+    with the loss)."""
+    if not hasattr(model, "state_mask"):
+        return None
+    return jax.tree_util.tree_leaves(model.state_mask())
+
+
+def _state_leaves(tree: Any, mask: List[bool]) -> List[Any]:
+    """The state leaves of a params-shaped ``tree``, in flatten order."""
+    return [x for x, is_state in zip(jax.tree_util.tree_leaves(tree), mask) if is_state]
+
+
+def _with_state(tree: Any, mask: List[bool], new: List[Any]) -> Any:
+    """``tree`` with its state leaves replaced by ``new``."""
+    leaves, treedef = jax.tree_util.tree_flatten(tree)
+    fresh = iter(new)
+    return jax.tree_util.tree_unflatten(
+        treedef, [next(fresh) if is_state else x for x, is_state in zip(leaves, mask)]
+    )
+
+
 def make_grad_step(
     model: Any, mesh: Mesh
 ) -> Callable[[Any, Any], Tuple[jax.Array, Any]]:
     """Compile ``(params, batch) → (loss, grads)`` with grads sharded like
-    params (the FSDP reduce-scatter happens inside via XLA SPMD)."""
+    params (the FSDP reduce-scatter happens inside via XLA SPMD).
+
+    For a model with state the optimizer does not own, the scalar is the
+    model's ``objective`` and each state leaf's slot in ``grads`` (its
+    gradient is stopped, so the slot is free) carries the step's signal for
+    that leaf: it passes the replica-dimension average with the gradients,
+    which keeps replicas bit-equal."""
     params_sh, batch_sh = fsdp_shardings(model, mesh)
+    mask = _state_mask(model)
 
     def _step(params: Any, batch: Any) -> Tuple[jax.Array, Any]:
-        return jax.value_and_grad(model.loss)(params, batch)
+        if mask is None:
+            return jax.value_and_grad(model.loss)(params, batch)
+        (loss, signal), grads = jax.value_and_grad(model.objective, has_aux=True)(params, batch)
+        return loss, _with_state(grads, mask, [s.astype(jnp.float32) for s in signal])
 
     with mesh:
         return jax.jit(
@@ -153,10 +194,20 @@ def make_update_step(
     import optax
 
     params_sh, _ = fsdp_shardings(model, mesh)
+    mask = _state_mask(model)
 
     def _update(params: Any, opt_state: Any, grads: Any) -> Tuple[Any, Any]:
+        if mask is not None:
+            # the state leaves' slots hold the averaged signal, not a
+            # gradient: the optimizer sees zeros there (its moments stay zero)
+            # and what it would do to those leaves (weight decay) is thrown away
+            state, signal = _state_leaves(params, mask), _state_leaves(grads, mask)
+            grads = _with_state(grads, mask, [jnp.zeros_like(x) for x in state])
         updates, opt_state = tx.update(grads, opt_state, params)
-        return optax.apply_updates(params, updates), opt_state
+        params = optax.apply_updates(params, updates)
+        if mask is not None:
+            params = _with_state(params, mask, model.advance_state(state, signal))
+        return params, opt_state
 
     with mesh:
         return jax.jit(_update, donate_argnums=(0, 1))
@@ -216,6 +267,13 @@ class HSDPTrainer:
         self.holder: Dict[str, Any] = {"params": params, "opt_state": opt_state}
         self._grad_step = make_grad_step(model, mesh)
         self._update_step = make_update_step(model, tx, mesh)
+        self._state_mask = _state_mask(model)
+        if self._state_mask is not None:
+            self._summary = jax.jit(
+                lambda loss, signal: jnp.concatenate(
+                    [loss.reshape(1).astype(jnp.float32), model.route_summary(signal).reshape(-1)]
+                )
+            )
 
         manager.register_state_dict_fn(
             "hsdp", self._load_state, self._save_state
@@ -262,9 +320,21 @@ class HSDPTrainer:
         # device was waiting on
         with obs_span("tpuft/step/grad"):
             loss, grads = self._grad_step(self.holder["params"], batch)
-        grads = ft_allreduce(
-            self.manager, grads, should_quantize=self.quantize_outer
-        )
+        mask = self._state_mask
+        if mask is not None:
+            # this replica's own signal, before the average
+            local_signal = _state_leaves(grads, mask)
+            summary = self._summary(loss, local_signal)
+        if mask is None or not self.quantize_outer:
+            grads = ft_allreduce(
+                self.manager, grads, should_quantize=self.quantize_outer
+            )
+        else:
+            # the signal crosses the wire by itself, unquantised: an 8-bit
+            # count could turn the sign its update takes
+            signal = ft_allreduce(self.manager, local_signal)
+            grads = _with_state(grads, mask, [jnp.zeros_like(x) for x in local_signal])
+            grads = _with_state(ft_allreduce(self.manager, grads, should_quantize=True), mask, signal)
         committed = self.manager.should_commit()
         if committed:
             with obs_span("tpuft/step/update"):
@@ -273,4 +343,11 @@ class HSDPTrainer:
                 )
             self.holder["params"] = params
             self.holder["opt_state"] = opt_state
-        return float(loss), committed
+        if mask is None:
+            return float(loss), committed
+        # ONE transfer a step, as without such state: the loss and the
+        # step's routing summary come to the host in one small array
+        host = np.asarray(summary)
+        if committed:
+            self.manager._flight.record(FlightEvent.MOE_ROUTE, **self.model.route_stats(host[1:]))
+        return float(host[0]), committed

@@ -170,3 +170,243 @@ class MoE:
             )
             out = fn(params, flat)
         return out.reshape(B, S, D)
+
+
+# ---------------------------------------------------------------------------
+# one chip's share of a wide expert layer (DeepSeek-V3-style routing)
+# ---------------------------------------------------------------------------
+
+
+@dataclass(frozen=True)
+class RoutedExpertsConfig:
+    dim: int
+    expert_hidden: int
+    num_experts: int  # the router's width: every expert of the layer
+    # the contiguous range of experts whose weights are HERE: (first, count)
+    experts_held: Tuple[int, int]
+    top_k: int
+    n_group: int
+    topk_group: int
+    routed_scaling_factor: float = 1.0
+    norm_topk_prob: bool = True
+    shared_hidden: int = 0  # width of the shared expert; 0: none
+    balance_loss_weight: float = 0.0  # sequence-wise, arXiv:2412.19437 eq. 17-20
+    dtype: Any = jnp.bfloat16
+
+
+def swiglu(gate: jax.Array, up: jax.Array, limit: float) -> jax.Array:
+    """``silu(gate) * up``; where ``limit`` is not 0 the gate is clamped
+    from above and the linear half on both sides (the reading of
+    ``expert_swiglu_limit_list`` taken: the clamp gpt-oss publishes)."""
+    if limit:
+        gate = jnp.minimum(gate, limit)
+        up = jnp.clip(up, -limit, limit)
+    return jax.nn.silu(gate) * up
+
+
+class RoutedExperts:
+    """An expert layer that is TOLD which experts it holds.
+
+    The router scores all ``num_experts`` in float32 (sigmoid), adds the
+    selection bias, keeps ``topk_group`` of the ``n_group`` groups by the sum
+    of each group's two best and then the ``top_k`` best experts inside
+    them; the weights are the UNBIASED scores of the chosen, normalised over
+    all ``top_k`` and scaled.  Nothing is dropped: the (token, choice) pairs
+    that fall on held experts are sorted by expert into a static buffer and
+    go through a grouped SwiGLU whose work follows the rows really routed
+    here (``megablox.gmm`` on the TPU: ``path`` "gmm"; ``lax.ragged_dot``
+    elsewhere).  What the absent experts would have added is left out; the
+    shared expert is added once.  On one chip there is no exchange, and no
+    code stands in for the absent chips.
+
+    The buffer has two sizes under one ``lax.cond``: four times the rows a
+    uniform router sends here, and, for the step in which more arrive, every
+    pair (``tokens * top_k``), so that no load is ever cut.  Rows go in by
+    a gather and come back by a scatter-add, both over the buffer's rows and
+    never over all ``tokens * top_k`` pairs.
+    """
+
+    def __init__(self, config: RoutedExpertsConfig) -> None:
+        self.config = config
+        if config.num_experts % config.n_group:
+            raise ValueError("num_experts must divide into n_group groups")
+        first, count = config.experts_held
+        if first < 0 or count < 1 or first + count > config.num_experts:
+            raise ValueError(f"experts_held {config.experts_held} outside 0..{config.num_experts}")
+        # set when the experts are traced: "gmm" or "ragged_dot"
+        self.path: Optional[str] = None
+
+    def init(self, key: jax.Array) -> Dict[str, Any]:
+        cfg = self.config
+        held = cfg.experts_held[1]
+        keys = jax.random.split(key, 7)
+
+        def normal(k, shape, fan_in, dtype=cfg.dtype):
+            return (jax.random.normal(k, shape, jnp.float32) / np.sqrt(fan_in)).astype(dtype)
+
+        params = {
+            "router": normal(keys[0], (cfg.dim, cfg.num_experts), cfg.dim, jnp.float32),
+            # state the optimizer does not own (``HSDPTrainer``): moved by
+            # the load, never by a gradient
+            "bias": jnp.zeros((cfg.num_experts,), jnp.float32),
+            "w_gate": normal(keys[1], (held, cfg.dim, cfg.expert_hidden), cfg.dim),
+            "w_up": normal(keys[2], (held, cfg.dim, cfg.expert_hidden), cfg.dim),
+            "w_down": normal(keys[3], (held, cfg.expert_hidden, cfg.dim), cfg.expert_hidden),
+        }
+        if cfg.shared_hidden:
+            params.update(
+                shared_gate=normal(keys[4], (cfg.dim, cfg.shared_hidden), cfg.dim),
+                shared_up=normal(keys[5], (cfg.dim, cfg.shared_hidden), cfg.dim),
+                shared_down=normal(keys[6], (cfg.shared_hidden, cfg.dim), cfg.shared_hidden),
+            )
+        return params
+
+    def param_specs(self) -> Dict[str, Any]:
+        """One chip's share: nothing here is divided further."""
+        cfg = self.config
+        specs = {
+            "router": P(None, None), "bias": P(None),
+            "w_gate": P(None, None, None), "w_up": P(None, None, None),
+            "w_down": P(None, None, None),
+        }
+        if cfg.shared_hidden:
+            specs.update(shared_gate=P(None, None), shared_up=P(None, None), shared_down=P(None, None))
+        return specs
+
+    # ------------------------------------------------------------------
+
+    def route(self, params: Dict[str, Any], x: jax.Array) -> Tuple[jax.Array, jax.Array, jax.Array]:
+        """x [T, D] → (chosen experts [T, k] int32, their weights [T, k]
+        float32, the unbiased scores [T, E] float32)."""
+        cfg = self.config
+        E, G = cfg.num_experts, cfg.n_group
+        logits = jnp.dot(
+            x.astype(jnp.float32), params["router"], precision=jax.lax.Precision.HIGHEST
+        )
+        scores = jax.nn.sigmoid(logits)
+        biased = scores + jax.lax.stop_gradient(params["bias"])
+        grouped = biased.reshape(-1, G, E // G)
+        group_score = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)  # [T, G]
+        _, keep = jax.lax.top_k(group_score, cfg.topk_group)
+        group_kept = jnp.sum(jax.nn.one_hot(keep, G, dtype=jnp.int32), axis=1) > 0  # [T, G]
+        masked = jnp.where(group_kept[:, :, None], grouped, -jnp.inf).reshape(-1, E)
+        _, chosen = jax.lax.top_k(masked, cfg.top_k)
+        weights = jnp.take_along_axis(scores, chosen, axis=-1)
+        if cfg.norm_topk_prob:
+            weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+        return chosen, weights * cfg.routed_scaling_factor, scores
+
+    def _grouped(self, lhs: jax.Array, rhs: jax.Array, sizes: jax.Array) -> jax.Array:
+        """Rows ``lhs`` [m, k], sorted by expert, each through its expert's
+        matrix of ``rhs`` [held, k, n]; rows past ``sum(sizes)`` are
+        undefined."""
+        from torchft_tpu.models.llama import Llama
+
+        if Llama._assumed_backend() == "tpu":
+            from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+            self.path = "gmm"
+            return gmm(lhs, rhs, sizes, preferred_element_type=lhs.dtype, tiling=(128, 256, 256))
+        self.path = "ragged_dot"
+        return jax.lax.ragged_dot(lhs, rhs, sizes)
+
+    def _through(
+        self, cap: int, limit: float, x: jax.Array, weights: jax.Array, w_gate: jax.Array,
+        w_up: jax.Array, w_down: jax.Array, order: jax.Array, sizes: jax.Array,
+    ) -> jax.Array:
+        """The held experts' part, [T, D] float32, through a buffer of
+        ``cap`` rows: the first ``cap`` of the (token, choice) pairs in
+        ``order`` (by held expert; those of absent experts last)."""
+        T, k = weights.shape
+        pair = order[:cap]
+        token = pair // k
+        routed = jnp.arange(cap) < jnp.sum(sizes)
+        # rows past the routed ones are masked on BOTH sides of the grouped
+        # products: a grouped kernel leaves them unwritten, in the backward
+        # pass too, and what lies there (a NaN, sooner or later) must reach
+        # neither the result nor x's gradient
+        xs = jnp.where(routed[:, None], jnp.take(x, token, axis=0), 0)
+        act = swiglu(self._grouped(xs, w_gate, sizes), self._grouped(xs, w_up, sizes), limit)
+        ys = self._grouped(act.astype(xs.dtype), w_down, sizes)
+        w_row = jnp.where(routed, jnp.take(weights.reshape(-1), pair), 0.0)
+        ys = jnp.where(routed[:, None], ys, 0).astype(jnp.float32) * w_row[:, None]
+        return jnp.zeros((T, x.shape[1]), jnp.float32).at[token].add(ys)
+
+    def _held_part(
+        self, params: Dict[str, Any], x: jax.Array, chosen: jax.Array, weights: jax.Array,
+        sizes: jax.Array, limit: float,
+    ) -> jax.Array:
+        """What the held experts add, [T, D] float32.
+
+        The buffer has two sizes under one ``lax.cond``; so that the
+        backward pass keeps nothing of the branch not taken (jax would make
+        BOTH branches' intermediates outputs of the forward ``cond``, the
+        full-size ones as zeros: 60 % of the layer's time on the chip,
+        PERF.md section 6, PR 29), the whole part is one ``custom_vjp`` that
+        keeps its inputs and runs forward again, inside the branch, when its
+        gradient is asked for."""
+        cfg = self.config
+        T, k = chosen.shape
+        first, held = cfg.experts_held
+        local = chosen - first
+        here = (local >= 0) & (local < held)
+        order = jnp.argsort(jnp.where(here, local, held).reshape(-1), stable=True)
+        full = T * k
+        usual = min(full, -(-4 * T * k * held // cfg.num_experts // 512) * 512)
+
+        def sized(f, sizes):
+            """``f(cap)`` at the smaller size that holds the step's rows."""
+            if usual == full:
+                return f(full)
+            return jax.lax.cond(jnp.sum(sizes) <= usual, lambda: f(usual), lambda: f(full))
+
+        @jax.custom_vjp
+        def part(x, weights, w_gate, w_up, w_down, order, sizes):
+            return sized(
+                lambda cap: self._through(cap, limit, x, weights, w_gate, w_up, w_down, order, sizes), sizes
+            )
+
+        def part_fwd(*operands):
+            return part(*operands), operands
+
+        def part_bwd(operands, g):
+            *floats, order, sizes = operands
+
+            def grads(cap):
+                through = lambda *a: self._through(cap, limit, *a, order, sizes)  # noqa: E731
+                return jax.vjp(through, *floats)[1](g)
+
+            return (*sized(grads, sizes), None, None)  # the two integer operands have no cotangent
+
+        part.defvjp(part_fwd, part_bwd)
+        return part(x, weights, params["w_gate"], params["w_up"], params["w_down"], order, sizes)
+
+    def apply(
+        self, params: Dict[str, Any], x: jax.Array,
+        swiglu_limit: float = 0.0, shared_swiglu_limit: float = 0.0,
+    ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+        """x [B, S, D] → (this chip's part of the layer [B, S, D], the
+        tokens every one of the ``num_experts`` was chosen by [E] float32,
+        the sequence-wise balance loss, weighted)."""
+        cfg = self.config
+        B, S, D = x.shape
+        flat = x.reshape(B * S, D)
+        chosen, weights, scores = self.route(params, flat)
+        picked = jax.nn.one_hot(chosen, cfg.num_experts, dtype=jnp.float32).sum(axis=1)  # [T, E]
+        load = jax.lax.stop_gradient(picked.sum(axis=0))
+        first, held = cfg.experts_held
+        sizes = load[first : first + held].astype(jnp.int32)
+        out = self._held_part(params, flat, chosen, weights, sizes, swiglu_limit)
+        if cfg.shared_hidden:
+            act = swiglu(flat @ params["shared_gate"], flat @ params["shared_up"], shared_swiglu_limit)
+            out = out + (act @ params["shared_down"]).astype(jnp.float32)
+        balance = jnp.zeros((), jnp.float32)
+        if cfg.balance_loss_weight:
+            # per sequence: f_i the share of choices that fell on expert i
+            # (times E / k), P_i the mean normalised score; sum_i f_i P_i
+            f = jax.lax.stop_gradient(picked.reshape(B, S, -1).mean(axis=1)) * (
+                cfg.num_experts / cfg.top_k
+            )
+            p = (scores / jnp.sum(scores, axis=-1, keepdims=True)).reshape(B, S, -1).mean(axis=1)
+            balance = cfg.balance_loss_weight * jnp.mean(jnp.sum(f * p, axis=-1))
+        return out.astype(x.dtype).reshape(B, S, D), load, balance
