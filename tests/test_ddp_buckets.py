@@ -1,0 +1,464 @@
+"""The host buckets of ``ddp.allreduce_pytree`` last from step to step
+(ISSUE 30).  Five rules, each with a test that fails when it is broken:
+
+1. a set of buckets is handed out again only after a round trip that ended
+   without error and whose restored leaves are ready;
+2. nothing returned to the caller aliases a kept bucket;
+3. the store is the Manager's: a new life starts cold;
+4. it is bounded;
+5. the values are bit for bit the parent's: ``_div(sum over replicas, n)``.
+
+Two harnesses: two thread replicas over the loopback ``TCPCommunicator``
+behind a real lighthouse, and one Manager on a stub client with a
+communicator the test holds, fails or lets through.
+"""
+
+import threading
+import tracemalloc
+from concurrent.futures import Future, ThreadPoolExecutor
+from typing import Any, Callable, Dict, List, Optional
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from torchft_tpu import ddp
+from torchft_tpu.communicator import DummyCommunicator, TCPCommunicator
+from torchft_tpu.ddp import BUCKET_CAP_MB_ENV, allreduce_pytree
+from torchft_tpu.lighthouse import LighthouseServer
+from torchft_tpu.manager import Manager, _div
+from torchft_tpu.work import Work
+
+from tests.test_manager import MemoryTransport, StubClient, _quorum_result
+
+
+def _gathers_done() -> None:
+    """The gather threads give a set back AFTER the composite's future is
+    set: a test that looks at the store waits for them."""
+    for t in threading.enumerate():
+        if t.name == "tpuft_ddp_gather":
+            t.join(timeout=10.0)
+            assert not t.is_alive()
+
+
+def _record_handed(manager: Manager) -> List[np.ndarray]:
+    """Every buffer this Manager's ``allreduce`` is handed, kept alive (so
+    that shared memory means the SAME buffer and never a reused address)."""
+    handed: List[np.ndarray] = []
+    inner = manager.allreduce
+
+    def _allreduce(data: Any, *args: Any, **kwargs: Any) -> Work:
+        handed.append(data)
+        return inner(data, *args, **kwargs)
+
+    manager.allreduce = _allreduce  # type: ignore[method-assign]
+    return handed
+
+
+def _syncs(manager: Manager) -> List[Dict[str, Any]]:
+    return [e for e in manager._flight.snapshot() if e["name"] == "DDP_SYNC"]
+
+
+def _bits(x: Any) -> bytes:
+    return np.asarray(x).tobytes()
+
+
+# ----------------------------------------------------------------------
+# two thread replicas over the loopback communicator
+# ----------------------------------------------------------------------
+
+
+class _Pair:
+    def __init__(self, lighthouse_addr: str) -> None:
+        self.managers: List[Manager] = []
+        for r in range(2):
+            state = {"w": np.zeros(3, np.float32)}
+            self.managers.append(
+                Manager(
+                    comm=TCPCommunicator(timeout_s=10.0),
+                    load_state_dict=state.update,
+                    state_dict=lambda state=state: dict(state),
+                    min_replica_size=2,
+                    replica_id=f"bucket_replica_{r}",
+                    lighthouse_addr=lighthouse_addr,
+                    timeout=10.0,
+                    quorum_timeout=10.0,
+                    connect_timeout=10.0,
+                )
+            )
+        self.handed = [_record_handed(m) for m in self.managers]
+        # who took part in the last step (the replica that heals in a life's
+        # first step sends zeros)
+        self.participating = [True, True]
+        self._pool = ThreadPoolExecutor(max_workers=2)
+
+    def step(self, trees: List[Any]) -> List[Any]:
+        """One committed step on both replicas: the averaged trees."""
+
+        def _one(r: int) -> Any:
+            manager = self.managers[r]
+            manager.start_quorum()
+            out = allreduce_pytree(manager, trees[r]).wait(timeout=30.0)
+            self.participating[r] = manager.is_participating()
+            assert manager.should_commit()
+            return out
+
+        futures = [self._pool.submit(_one, r) for r in range(2)]
+        outs = [f.result(timeout=60.0) for f in futures]
+        _gathers_done()
+        return outs
+
+    def shutdown(self) -> None:
+        self._pool.shutdown(wait=False)
+        for m in self.managers:
+            m.shutdown()
+
+
+@pytest.fixture()
+def lighthouse_addr():
+    server = LighthouseServer(
+        bind="127.0.0.1:0",
+        min_replicas=2,
+        join_timeout_ms=100,
+        quorum_tick_ms=20,
+        heartbeat_timeout_ms=5000,
+    )
+    yield server.address()
+    server.shutdown()
+
+
+@pytest.fixture()
+def pair(lighthouse_addr, monkeypatch):
+    # 2 KB a bucket: the float32 leaves below split into several
+    monkeypatch.setenv(BUCKET_CAP_MB_ENV, str(2048 / (1 << 20)))
+    p = _Pair(lighthouse_addr)
+    yield p
+    p.shutdown()
+
+
+def _tree(rng: np.random.Generator, wide: int = 300) -> Dict[str, Any]:
+    """jax and numpy leaves of two dtypes, values that differ every call."""
+    f32 = lambda *shape: rng.standard_normal(shape).astype(np.float32)  # noqa: E731
+    return {
+        "embed": jnp.asarray(f32(wide, 4)),
+        "layers": [jnp.asarray(f32(16, 16)), jnp.asarray(f32(7))],
+        "half": jnp.asarray(f32(33, 5)).astype(jnp.bfloat16),
+        "host": f32(41, 3),
+    }
+
+
+def _expected(trees: List[Any], participating: Optional[List[bool]] = None) -> Any:
+    """The parent's formula: the ring's sum in the leaf's dtype (a replica
+    that does not participate sends zeros), averaged by ``_div``."""
+    participating = participating or [True] * len(trees)
+    flat = [jax.tree_util.tree_leaves(t) for t in trees]
+    out = []
+    for leaves in zip(*flat):
+        arrays = [
+            np.asarray(l) if p else np.zeros_like(np.asarray(l))
+            for l, p in zip(leaves, participating)
+        ]
+        total = arrays[0]
+        for a in arrays[1:]:
+            total = total + a
+        out.append(_div(total, len(trees)))
+    return out
+
+
+STEPS = 6
+CHANGES_AT = 3
+
+
+@pytest.mark.parametrize("case", ["same_tree", "cap_flipped", "leaf_reshaped", "non_participating_step"])
+def test_six_steps_are_bit_equal_to_the_parents_formula(pair, monkeypatch, case) -> None:
+    rngs = [np.random.default_rng(10 + r) for r in range(2)]
+    for step in range(STEPS):
+        wide = 300
+        if step >= CHANGES_AT and case == "cap_flipped":
+            monkeypatch.setenv(BUCKET_CAP_MB_ENV, str(1024 / (1 << 20)))
+        if step >= CHANGES_AT and case == "leaf_reshaped":
+            wide = 200
+        if step == CHANGES_AT and case == "non_participating_step":
+            monkeypatch.setattr(pair.managers[1], "is_participating", lambda: False)
+        elif case == "non_participating_step":
+            monkeypatch.undo()
+            monkeypatch.setenv(BUCKET_CAP_MB_ENV, str(2048 / (1 << 20)))
+        trees = [_tree(rng, wide) for rng in rngs]
+        outs = pair.step(trees)
+        want = _expected(trees, pair.participating)
+        assert pair.participating == [True, step != 0 and (step, case) != (CHANGES_AT, "non_participating_step")]
+        for r, out in enumerate(outs):
+            got = jax.tree_util.tree_leaves(out)
+            for leaf, g, w in zip(jax.tree_util.tree_leaves(trees[r]), got, want):
+                assert type(g) is type(leaf) or isinstance(g, type(leaf))
+                assert np.asarray(g).dtype == w.dtype and np.asarray(g).shape == w.shape
+                assert _bits(g) == _bits(w), (case, step, r)
+    # the steps after the change were filled in kept memory again
+    for m in pair.managers:
+        syncs = _syncs(m)
+        assert len(syncs) == STEPS
+        assert syncs[-1]["warm_buckets"] == syncs[-1]["buckets"] > 1
+
+
+def test_from_the_second_step_the_buckets_are_the_first_steps_memory(pair) -> None:
+    rngs = [np.random.default_rng(20 + r) for r in range(2)]
+    for _ in range(3):
+        pair.step([_tree(rng) for rng in rngs])
+    for m, handed in zip(pair.managers, pair.handed):
+        syncs = _syncs(m)
+        n = syncs[0]["buckets"]
+        assert n > 2 and len(handed) == 3 * n
+        # DDP_SYNC carries the counter beside ``buckets``: 0, then all
+        assert [e["warm_buckets"] for e in syncs] == [0, n, n]
+        for b in range(n):
+            assert np.shares_memory(handed[b], handed[n + b])
+            assert np.shares_memory(handed[b], handed[2 * n + b])
+        # one set a signature is all this traffic ever holds
+        assert m._host_buckets.kept_bytes() == sum(a.nbytes for a in handed[:n])
+
+
+def test_a_second_manager_starts_cold(lighthouse_addr, monkeypatch) -> None:
+    """Rule 3: the store lives and dies with its Manager; one at module level
+    would hand a new life the dead life's warm pages."""
+    monkeypatch.setenv(BUCKET_CAP_MB_ENV, str(2048 / (1 << 20)))
+    first_handed: List[np.ndarray] = []
+    for life in range(2):
+        p = _Pair(lighthouse_addr)
+        try:
+            rngs = [np.random.default_rng(30 + r) for r in range(2)]
+            for _ in range(2):
+                p.step([_tree(rng) for rng in rngs])
+            for m, handed in zip(p.managers, p.handed):
+                assert [e["warm_buckets"] for e in _syncs(m)] == [0, _syncs(m)[0]["buckets"]]
+                assert not any(np.shares_memory(a, b) for a in handed for b in first_handed)
+                assert m._host_buckets is not None
+            if life == 0:
+                first_handed = [a for handed in p.handed for a in handed]
+        finally:
+            p.shutdown()
+        # ``shutdown()`` lets the store go
+        assert all(m._host_buckets is None for m in p.managers)
+    assert not [n for n in vars(ddp) if isinstance(getattr(ddp, n), ddp._BucketStore)]
+
+
+def test_results_of_step_k_are_unchanged_after_step_k_plus_one(pair) -> None:
+    """Rule 2, both leaf kinds: a numpy leaf that was a view of its bucket, or
+    a ``jax.Array`` that the CPU backend made over the bucket's memory without
+    a copy, would read step k+1's values after step k+1."""
+    rngs = [np.random.default_rng(40 + r) for r in range(2)]
+    kept_outs, kept_bits = [], []
+    for _ in range(3):
+        outs = pair.step([_tree(rng) for rng in rngs])
+        kept_outs.append(outs)
+        kept_bits.append([[_bits(l) for l in jax.tree_util.tree_leaves(o)] for o in outs])
+    for outs, bits in zip(kept_outs, kept_bits):
+        for out, want in zip(outs, bits):
+            leaves = jax.tree_util.tree_leaves(out)
+            assert [_bits(l) for l in leaves] == want
+            for handed in pair.handed:
+                for leaf in leaves:
+                    if isinstance(leaf, np.ndarray):
+                        assert not any(np.shares_memory(leaf, a) for a in handed)
+
+
+# ----------------------------------------------------------------------
+# one Manager, a communicator the test holds
+# ----------------------------------------------------------------------
+
+
+class _HeldComm(DummyCommunicator):
+    """Passthrough whose works end when, and how, the test says."""
+
+    def __init__(self) -> None:
+        super().__init__()
+        self.hold = False
+        self.fail_with: Optional[Callable[[], BaseException]] = None
+        self.held: List["Future[Any]"] = []
+        self.buffers: List[np.ndarray] = []
+
+    def allreduce(self, buffers, op=None, in_place=False) -> Work:  # type: ignore[override]
+        fut: "Future[Any]" = Future()
+        if self.fail_with is not None:
+            fut.set_exception(self.fail_with())
+        elif self.hold:
+            self.held.append(fut)
+            self.buffers.append(buffers)
+        else:
+            fut.set_result(buffers)
+        return Work(fut)
+
+    def release(self) -> None:
+        for fut, buffers in zip(self.held, self.buffers):
+            fut.set_result(buffers)
+        self.held, self.buffers = [], []
+
+
+class _Solo:
+    def __init__(self, steps: int = 16) -> None:
+        self.comm = _HeldComm()
+        self.client = StubClient()
+        self.client.quorum_results.extend(_quorum_result() for _ in range(steps))
+        self.manager = Manager(
+            comm=self.comm,
+            load_state_dict=None,
+            state_dict=None,
+            min_replica_size=1,
+            checkpoint_transport=MemoryTransport(),
+            _manager_client=self.client,
+            rank=0,
+            world_size=1,
+        )
+        self.handed = _record_handed(self.manager)
+
+    def step(self, tree: Any, **kwargs: Any) -> Any:
+        self.manager.start_quorum()
+        out = allreduce_pytree(self.manager, tree, **kwargs).wait(timeout=10.0)
+        self.manager.should_commit()
+        _gathers_done()
+        return out
+
+
+@pytest.fixture()
+def solo():
+    s = _Solo()
+    yield s
+    s.manager.shutdown()
+
+
+def _failure(kind: str) -> Callable[[], BaseException]:
+    return {
+        "raised": lambda: RuntimeError("peer closed the connection mid-ring"),
+        "timed_out": lambda: TimeoutError("allreduce timed out after 60 s"),
+    }[kind]
+
+
+@pytest.mark.parametrize("kind", ["raised", "timed_out", "errored_before_submit"])
+def test_a_failed_round_trip_never_gives_its_buckets_back(solo, kind) -> None:
+    """Rule 1: an op thread that is still receiving writes into memory nobody
+    reuses."""
+    tree = {"a": np.arange(64, dtype=np.float32), "b": jnp.ones((8, 8), jnp.bfloat16)}
+    solo.step(tree)
+    n = len(solo.handed)
+    solo.step(tree)  # warm: the first step's buckets
+    assert all(np.shares_memory(solo.handed[b], solo.handed[n + b]) for b in range(n))
+
+    if kind == "errored_before_submit":
+        # the first bucket's ring fails, the second is never submitted to one
+        inner, calls = solo.comm.allreduce, []
+
+        def _first_fails(buffers, *args, **kwargs):
+            calls.append(buffers)
+            if len(calls) == 1:
+                fut: "Future[Any]" = Future()
+                fut.set_exception(RuntimeError("ring broke"))
+                return Work(fut)
+            return inner(buffers, *args, **kwargs)
+
+        solo.comm.allreduce = _first_fails  # type: ignore[method-assign]
+    else:
+        solo.comm.fail_with = _failure(kind)
+    out = solo.step(tree)  # the round trip that fails, in the kept buckets
+    assert solo.manager.errored() is not None
+    np.testing.assert_array_equal(out["a"], tree["a"])  # the input rides through
+    poisoned = solo.handed[2 * n : 3 * n]
+    assert _syncs(solo.manager)[-1]["warm_buckets"] == n
+    if kind == "errored_before_submit":
+        del solo.comm.allreduce
+    solo.comm.fail_with = None
+
+    solo.step(tree)  # the step after: fresh memory, cold
+    after = solo.handed[3 * n : 4 * n]
+    assert len(after) == n
+    assert not any(np.shares_memory(a, p) for a in after for p in poisoned)
+    assert _syncs(solo.manager)[-1]["warm_buckets"] == 0
+    solo.step(tree)  # and kept again from there
+    assert all(np.shares_memory(a, b) for a, b in zip(after, solo.handed[4 * n : 5 * n]))
+    assert _syncs(solo.manager)[-1]["warm_buckets"] == n
+
+
+def test_a_set_that_is_still_out_is_not_handed_out_again(solo) -> None:
+    """Rule 1 again, and the streamed case: two fragments of one signature in
+    flight hold different memory; both sets are kept afterwards."""
+    tree = {"a": np.arange(64, dtype=np.float32)}
+    manager = solo.manager
+    manager.start_quorum()
+    solo.comm.hold = True
+    works = [allreduce_pytree(manager, tree, stream=frag) for frag in range(2)]
+    first, second = solo.handed
+    assert not np.shares_memory(first, second)
+    solo.comm.release()
+    for frag, w in enumerate(works):
+        np.testing.assert_array_equal(w.wait(timeout=10.0)["a"], tree["a"] / 2)
+        manager.stream_resolved(frag, True)
+    _gathers_done()
+    assert manager._host_buckets.kept_bytes() == 2 * first.nbytes
+    # the next two in flight are both warm, each in a set of its own
+    works = [allreduce_pytree(manager, tree, stream=frag) for frag in range(2)]
+    third, fourth = solo.handed[2:]
+    assert not np.shares_memory(third, fourth)
+    assert all(any(np.shares_memory(x, y) for y in (first, second)) for x in (third, fourth))
+    solo.comm.release()
+    for w in works:
+        w.wait(timeout=10.0)
+    assert [e["warm_buckets"] for e in _syncs(manager)] == [0, 0, 1, 1]
+
+
+def test_a_warm_call_allocates_nothing_of_the_payloads_size(solo) -> None:
+    """What the train thread pays: with kept buckets the call makes no array
+    of the payload's size (the parent made one a bucket, every page of it
+    touched for the first time)."""
+    tree = {
+        "w": np.ones(1 << 20, dtype=np.float32),
+        "v": np.ones(1 << 20, dtype=np.float32),
+    }
+    payload = 2 * (4 << 20)
+    solo.step(tree)
+    solo.manager.start_quorum()
+    solo.comm.hold = True  # the restore (which copies numpy leaves out) waits
+    tracemalloc.start()
+    try:
+        work = allreduce_pytree(solo.manager, tree)
+        peak = tracemalloc.get_traced_memory()[1]
+    finally:
+        tracemalloc.stop()
+    assert len(solo.handed) == 2 and solo.handed[-1].nbytes == payload
+    assert peak < payload / 4, peak
+    solo.comm.release()
+    out = work.wait(timeout=10.0)
+    np.testing.assert_array_equal(out["w"], np.full(1 << 20, 0.5, np.float32))
+
+
+def test_the_store_is_bounded(solo) -> None:
+    """Rule 4: signatures that stop coming are dropped after a fixed number
+    of others, and a signature keeps a fixed number of sets."""
+    store_bytes = []
+    for n in range(3 * ddp._KEPT_SIGNATURES):
+        solo.step({"a": np.ones(1000 + n, dtype=np.float32)})
+        store = solo.manager._host_buckets
+        assert len(store._plans) <= ddp._KEPT_SIGNATURES
+        store_bytes.append(store.kept_bytes())
+    assert max(store_bytes) <= ddp._KEPT_SIGNATURES * 4 * (1000 + 3 * ddp._KEPT_SIGNATURES)
+    # one in, one out: the newest, and the one that came _KEPT_SIGNATURES ago
+    assert store_bytes[-1] == store_bytes[-2] + 4 * ddp._KEPT_SIGNATURES
+    # more fragments of one signature in flight than a signature keeps sets
+    solo.manager.start_quorum()
+    solo.comm.hold = True
+    tree = {"a": np.ones(500, dtype=np.float32)}
+    works = [allreduce_pytree(solo.manager, tree, stream=f) for f in range(ddp._KEPT_SETS + 2)]
+    solo.comm.release()
+    for w in works:
+        w.wait(timeout=10.0)
+    _gathers_done()
+    (plan,) = [p for p in store._plans.values() if p.nbytes == 2000]
+    assert len(plan.free) == ddp._KEPT_SETS
+
+
+def test_no_knob() -> None:
+    import inspect
+
+    assert list(inspect.signature(allreduce_pytree).parameters) == [
+        "manager", "tree", "should_quantize", "stream",
+    ]
+    source = inspect.getsource(ddp)
+    assert source.count("os.environ") == 1  # the bucket cap, as before

@@ -694,6 +694,13 @@ class TestSpansAcrossAFleet:
             assert all(v >= 0.0 for v in stages)
             assert sum(stages) <= e["duration_s"] + 1e-3
             assert e["t0"] + e["duration_s"] == pytest.approx(e["t"], abs=1e-3)
+        # the buckets filled in memory kept from the step before: none in a
+        # life's first round trip (nor after the one the kill broke), then all
+        warm = [e["warm_buckets"] for e in syncs]
+        assert warm[0] == 0 and set(warm) == {0, 3}
+        assert warm.count(3) >= len(warm) - 8
+        new_life = [e for e in drill.managers[1][1]._flight.snapshot() if e["name"] == "DDP_SYNC"]
+        assert new_life[0]["warm_buckets"] == 0 and new_life[-1]["warm_buckets"] == 3
 
     def test_into_fills_last_quorum_timings_with_todays_keys(self, drill):
         # every round stamps the RPC; a reconfiguring round the configure;

@@ -17,16 +17,49 @@ from ftbench.tests.test_ftbench_rehearsal import (  # noqa: F401
 )
 
 _CASES = theirs.test_rehearsal_walks_the_cell.pytestmark[0].args[1]
-# sync_normalize_ms is PR 27's reader of PR 26's span tpuft/manager/normalize
+# sync_normalize_ms is PR 27's reader of PR 26's span tpuft/manager/normalize,
+# bucket_warm_pct PR 30's of DDP_SYNC's warm_buckets
 _NEW = {
-    "mistral7b-ddp2-steady": set(READINGS) | {"sync_normalize_ms"},
+    "mistral7b-ddp2-steady": set(READINGS) | {"sync_normalize_ms", "bucket_warm_pct"},
     "mistral7b-ddp2-kill": set(KILL_READINGS),
 }
+
+
+def _what_it_waited_on(cell, flight_dir):
+    """For the log of a walk that failed: the steps that were voted down or
+    took long (replica, life, step, committed, seconds), from the run's
+    series file, and the errors its Managers funnelled, from the flight
+    rings they dump under ``TORCHFT_FLIGHT_DIR``."""
+    import glob
+    import json
+    import os
+
+    said = []
+    series = glob.glob(os.path.join(theirs.ROOT, "ftbench", "out", f"{cell}-*.json"))
+    if series:
+        with open(max(series, key=os.path.getmtime)) as f:
+            steps = json.load(f)["series"]
+        said += [
+            ("step", r["replica"], r.get("life"), r["step"], r["committed"], round(r["wall_s"], 3))
+            for r in steps if not r["committed"] or r["wall_s"] > 20.0
+        ]
+    for path in sorted(glob.glob(os.path.join(str(flight_dir), "*.jsonl"))):
+        with open(path) as f:
+            for line in f:
+                if '"ERROR"' in line:
+                    said.append((os.path.basename(path), line.strip()[:400]))
+    return "\n".join(map(repr, dict.fromkeys(said)))
 
 
 @pytest.mark.parametrize(
     "cell,trace,devices,expects",
     [(c, t, d, e | _NEW[c] if t else e) for c, t, d, e in _CASES],
 )
-def test_rehearsal_walks_the_cell(cell, trace, devices, expects):
-    theirs.test_rehearsal_walks_the_cell(cell, trace, devices, expects)
+def test_rehearsal_walks_the_cell(cell, trace, devices, expects, tmp_path, monkeypatch):
+    # the walk's subprocess inherits this: every error a Manager funnels
+    # dumps its flight ring there, and a failure below says what it was
+    monkeypatch.setenv("TORCHFT_FLIGHT_DIR", str(tmp_path))
+    try:
+        theirs.test_rehearsal_walks_the_cell(cell, trace, devices, expects)
+    except AssertionError as e:
+        raise AssertionError(f"{e}\nwhat the walk waited on:\n{_what_it_waited_on(cell, tmp_path)}") from e

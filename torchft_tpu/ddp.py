@@ -11,11 +11,13 @@ shardings.  Compiled programs never see the replica count (SURVEY.md §7).
 
 from __future__ import annotations
 
+import collections
+import dataclasses
 import functools
 import os
 import threading
 from concurrent.futures import Future
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, Hashable, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -97,52 +99,213 @@ def _assemble_sharded(
     return jax.make_array_from_single_device_arrays(shape, sharding, per_device)
 
 
-def _host_contribution(leaf: Any) -> Tuple[np.ndarray, Any]:
-    """This host's flat (1-D) contribution to the replica-dim average, plus
-    a ``restore(avg_flat) -> leaf`` function.
+# A Manager keeps the plan and the host buckets of this many tree signatures
+# (the one that came longest ago goes first), and of each this many sets that
+# nobody holds.  DDP sends one tree a step; a model with state the optimizer
+# does not own two under ``quantize_outer``; streamed LocalSGD one a fragment.
+_KEPT_SIGNATURES = 4
+_KEPT_SETS = 2
 
-    Fully-addressable leaves ship whole.  For multi-host arrays (a replica
-    group spanning hosts, the v5p reality) each host ships only its UNIQUE
-    addressable shards: host h of every replica group addresses the same
-    logical region (identical mesh + shardings across groups), so
+
+@dataclasses.dataclass(frozen=True)
+class _Slot:
+    """Where one leaf lies in its bucket, and what brings it back.
+
+    A fully addressable (or non-jax) leaf ships whole.  For multi-host arrays
+    (a replica group spanning hosts, the v5p reality) each host ships only its
+    UNIQUE addressable shards: host h of every replica group addresses the
+    same logical region (identical mesh + shardings across groups), so
     shard-local averaging over the per-``group_rank`` DCN ring is exact —
-    same math, sharded bytes.  Restore rebuilds the global array from
-    per-device buffers without ever materializing it unsharded.
+    same math, sharded bytes.  ``segments`` then says where each shard lies
+    inside the leaf's part of the bucket, and the restore rebuilds the global
+    array from per-device buffers without ever materializing it unsharded.
     """
-    if not isinstance(leaf, jax.Array) or leaf.is_fully_addressable:
-        arr = np.asarray(leaf)
-        shape, is_jax = arr.shape, isinstance(leaf, jax.Array)
-        sharding = leaf.sharding if is_jax else None
 
-        def _restore_full(avg_flat: np.ndarray) -> Any:
-            host_val = avg_flat.reshape(shape)
-            if is_jax:
-                return jax.device_put(host_val, sharding)
-            return host_val
+    index: int  # the leaf's place among the tree's leaves
+    offset: int  # in elements, from the bucket's start
+    size: int
+    shape: Tuple[int, ...]
+    dtype: Any
+    sharding: Any  # None: not a jax.Array, comes back as numpy
+    # a ``device_put`` there may ALIAS aligned host memory (the CPU backend's
+    # zero copy), so what is put from a kept bucket is copied first
+    host_backed: bool
+    segments: Optional[Dict[Tuple, Tuple[int, int, tuple]]]  # shard key -> (offset, size, shape)
 
-        return arr.reshape(-1), _restore_full
 
-    shards = list(leaf.addressable_shards)
-    unique = _unique_local_shards(leaf)
-    segments: List[np.ndarray] = []
-    offsets: Dict[Tuple, Tuple[int, int, tuple]] = {}
-    off = 0
-    for k, s in unique.items():
-        data = np.asarray(s.data)
-        offsets[k] = (off, data.size, data.shape)
-        segments.append(data.reshape(-1))
-        off += data.size
-    flat = np.concatenate(segments) if segments else np.empty(0, leaf.dtype)
-    shape, sharding, dtype = leaf.shape, leaf.sharding, leaf.dtype
+@dataclasses.dataclass
+class _Bucket:
+    dtype: Any
+    size: int  # elements
+    slots: List[_Slot]
 
-    def _restore_sharded(avg_flat: np.ndarray) -> Any:
-        def _lookup(key: Tuple, _s: Any) -> np.ndarray:
-            o, n, shp = offsets[key]
-            return avg_flat[o : o + n].reshape(shp)
 
-        return _assemble_sharded(shape, sharding, dtype, shards, _lookup)
+@dataclasses.dataclass
+class _Plan:
+    """One tree signature's buckets, and the sets of flat host buffers (one a
+    bucket) that were filled before and that nothing reads or writes now."""
 
-    return flat, _restore_sharded
+    buckets: List[_Bucket]
+    nbytes: int  # what crosses the wire a round trip
+    free: List[List[np.ndarray]] = dataclasses.field(default_factory=list)
+
+
+class _BucketStore:
+    """The plans and host buckets one Manager keeps from step to step.
+
+    A bucket made with ``np.empty`` every step pays the first touch of every
+    page every step: on the v5e's host a copy into fresh pages runs at
+    0.6-0.95 GB/s against 11-20 GB/s into pages written before (PERF.md
+    section 6, PR 27 and PR 30).  So a tree whose signature comes again is
+    packed into the buffers of the step before.  A set is out from
+    :meth:`take` until :meth:`give_back`, which only a round trip that ended
+    without error calls, after its restored leaves are ready: a ring that
+    failed or never ended may still write into its buffers, and they are
+    never handed out again.  Lives as long as its Manager (a new life starts
+    cold, as a restarted process does).
+    """
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        self._plans: "collections.OrderedDict[Hashable, _Plan]" = collections.OrderedDict()
+
+    def plan(self, signature: Hashable, leaves: List[Any], bucket_cap: int) -> _Plan:
+        with self._lock:
+            plan = self._plans.get(signature)
+            if plan is not None:
+                self._plans.move_to_end(signature)
+                return plan
+        plan = _make_plan(leaves, bucket_cap)
+        with self._lock:
+            self._plans[signature] = plan
+            while len(self._plans) > _KEPT_SIGNATURES:
+                self._plans.popitem(last=False)
+        return plan
+
+    def take(self, plan: _Plan) -> Optional[List[np.ndarray]]:
+        with self._lock:
+            return plan.free.pop() if plan.free else None
+
+    def give_back(self, plan: _Plan, buffers: List[np.ndarray]) -> None:
+        with self._lock:
+            if len(plan.free) < _KEPT_SETS:
+                plan.free.append(buffers)
+
+    def kept_bytes(self) -> int:
+        with self._lock:
+            return sum(
+                int(b.nbytes) for p in self._plans.values() for bufs in p.free for b in bufs
+            )
+
+
+def _bucket_store(manager: Manager) -> _BucketStore:
+    store = manager._host_buckets
+    if store is None:
+        store = manager._host_buckets = _BucketStore()
+    return store
+
+
+def _array_like(leaf: Any) -> Any:
+    """``leaf`` if it says its own dtype and size, else as numpy sees it (a
+    Python scalar)."""
+    if hasattr(leaf, "dtype") and hasattr(leaf, "nbytes"):
+        return leaf
+    return np.asarray(leaf)
+
+
+def _leaf_signature(leaf: Any) -> Hashable:
+    if isinstance(leaf, jax.Array):
+        return (leaf.shape, leaf.dtype.name, leaf.sharding)
+    leaf = _array_like(leaf)
+    return (tuple(leaf.shape), leaf.dtype.name, None)
+
+
+def _make_plan(leaves: List[Any], bucket_cap: int) -> _Plan:
+    """Bucket by dtype (each dtype needs its own ring), then split large
+    buckets at ``bucket_cap`` bytes; each is submitted as its own collective:
+    the op thread rings bucket k while the train thread fetches and fills
+    bucket k+1 — transfer/communication pipelining, the reference's
+    bucket_cap_mb (``local_sgd.py:28,477-566``) in jax form."""
+    order: Dict[str, List[int]] = {}
+    described: List[Tuple[Any, int, Tuple[int, ...], Any]] = []
+    for i, leaf in enumerate(leaves):
+        segments = None
+        if isinstance(leaf, jax.Array) and not leaf.is_fully_addressable:
+            # bucket by what actually crosses the wire: this host's unique
+            # shard bytes (identical on twin hosts, so bucket boundaries —
+            # and therefore ring frame sizes — stay uniform)
+            dtype, shape = leaf.dtype, leaf.shape
+            segments, size = {}, 0
+            for key, s in _unique_local_shards(leaf).items():
+                segments[key] = (size, int(s.data.size), tuple(s.data.shape))
+                size += int(s.data.size)
+        else:
+            leaf = _array_like(leaf)
+            dtype, shape, size = leaf.dtype, tuple(leaf.shape), int(leaf.size)
+        described.append((np.dtype(dtype), size, shape, segments))
+        order.setdefault(dtype.name, []).append(i)
+
+    buckets: List[_Bucket] = []
+    for idxs in order.values():
+        dtype = described[idxs[0]][0]
+        bucket = _Bucket(dtype, 0, [])
+        for i in idxs:
+            _dtype, size, shape, segments = described[i]
+            if bucket.slots and (bucket.size + size) * dtype.itemsize > bucket_cap:
+                buckets.append(bucket)
+                bucket = _Bucket(dtype, 0, [])
+            leaf = leaves[i]
+            sharding = leaf.sharding if isinstance(leaf, jax.Array) else None
+            bucket.slots.append(
+                _Slot(
+                    index=i,
+                    offset=bucket.size,
+                    size=size,
+                    shape=shape,
+                    dtype=dtype,
+                    sharding=sharding,
+                    host_backed=sharding is not None
+                    and any(d.platform == "cpu" for d in sharding.device_set),
+                    segments=segments,
+                )
+            )
+            bucket.size += size
+        buckets.append(bucket)
+    return _Plan(
+        buckets=buckets,
+        nbytes=sum(b.size * b.dtype.itemsize for b in buckets),
+    )
+
+
+def _to_host(leaf: Any, slot: _Slot) -> List[np.ndarray]:
+    """This host's contribution of one leaf, flat, in the order of its place
+    in the bucket (waits for the leaf's asynchronous copy)."""
+    if slot.segments is None:
+        return [np.asarray(leaf).reshape(-1)]
+    shards = _unique_local_shards(leaf)
+    return [np.asarray(shards[key].data).reshape(-1) for key in slot.segments]
+
+
+def _restore(leaf: Any, slot: _Slot, avg_flat: np.ndarray, aliased: bool) -> Any:
+    """The averaged leaf in ``leaf``'s type and layout.  ``aliased``: the
+    average lies in a kept bucket, which the next step overwrites, so nothing
+    that is returned may share its memory."""
+    if slot.sharding is None:
+        host_val = avg_flat.reshape(slot.shape)
+        return host_val.copy() if aliased else host_val
+    copy = aliased and slot.host_backed
+    if slot.segments is None:
+        host_val = avg_flat.reshape(slot.shape)
+        return jax.device_put(host_val.copy() if copy else host_val, slot.sharding)
+
+    def _lookup(key: Tuple, _s: Any) -> np.ndarray:
+        o, n, shp = slot.segments[key]
+        block = avg_flat[o : o + n].reshape(shp)
+        return block.copy() if copy else block
+
+    return _assemble_sharded(
+        slot.shape, slot.sharding, slot.dtype, leaf.addressable_shards, _lookup
+    )
 
 
 def allreduce_pytree(
@@ -157,6 +320,13 @@ def allreduce_pytree(
     types restored (jax leaves come back as device arrays with their
     original sharding).  Error swallowing and participation zeroing happen
     inside ``manager.allreduce``.
+
+    The leaves are packed into flat host buckets, a ring each.  ``manager``
+    keeps the plan and the buckets of a tree whose signature comes again
+    (tree structure, each leaf's shape, dtype and sharding, the bucket cap)
+    for its life (:class:`_BucketStore`): host memory of the size of the
+    gradients it averages (this host's share), written every step and
+    allocated once.  The Work's value never aliases them.
 
     ``stream``, when given, marks this as an ASYNC streamed fragment submit
     (the TORCHFT_STREAM_SYNC LocalSGD scheduler): exactly one work — the
@@ -193,7 +363,6 @@ def allreduce_pytree(
         # and quantizing host-side.
         return _allreduce_pytree_device_quantized(manager, leaves, treedef)
 
-    original = list(leaves)
     # The round trip as ONE span, opened here on the train thread and closed
     # by the gather thread when the composite work is done; its stages are
     # child spans on three threads (this one, the communicator's op thread,
@@ -205,80 +374,49 @@ def allreduce_pytree(
     sync_span.__enter__()
     stage_s = {"plan_s": 0.0, "d2h_s": 0.0, "pack_s": 0.0, "ring_wait_s": 0.0, "h2d_s": 0.0}
 
-    def _plan() -> Tuple[List[List[int]], List[int]]:
-        # Kick off every device→host transfer asynchronously up front so DMA
-        # overlaps the bucket assembly and the first ring.
-        for leaf in leaves:
-            if isinstance(leaf, jax.Array):
-                leaf.copy_to_host_async()
-
-        # Bucket by dtype (each dtype needs its own ring), then split large
-        # buckets at ``bucket_cap`` bytes and submit each as its own collective:
-        # the op thread rings bucket k while we fetch/assemble bucket k+1 —
-        # transfer/communication pipelining, the reference's bucket_cap_mb
-        # (``local_sgd.py:28,477-566``) in jax form.
-        bucket_cap = _bucket_cap_bytes()
-        order: Dict[str, List[int]] = {}
-        leaf_bytes: List[int] = []
-        for i, leaf in enumerate(leaves):
-            if isinstance(leaf, jax.Array) and not leaf.is_fully_addressable:
-                # bucket by what actually crosses the wire: this host's unique
-                # shard bytes (identical on twin hosts, so bucket boundaries —
-                # and therefore ring frame sizes — stay uniform)
-                dtype_name = leaf.dtype.name
-                nbytes = sum(
-                    int(s.data.nbytes) for s in _unique_local_shards(leaf).values()
-                )
-            elif hasattr(leaf, "dtype") and hasattr(leaf, "nbytes"):
-                dtype_name, nbytes = leaf.dtype.name, int(leaf.nbytes)
-            else:
-                arr = np.asarray(leaf)
-                dtype_name, nbytes = arr.dtype.name, int(arr.nbytes)
-            leaf_bytes.append(nbytes)
-            order.setdefault(dtype_name, []).append(i)
-
-        groups: List[List[int]] = []
-        for _dtype_name, idxs in order.items():
-            group: List[int] = []
-            group_bytes = 0
-            for i in idxs:
-                if group and group_bytes + leaf_bytes[i] > bucket_cap:
-                    groups.append(group)
-                    group, group_bytes = [], 0
-                group.append(i)
-                group_bytes += leaf_bytes[i]
-            if group:
-                groups.append(group)
-        return groups, leaf_bytes
-
+    store = _bucket_store(manager)
     works: List[Work] = []
-    bucket_layouts: List[List[Tuple[int, int, int, tuple]]] = []
+    buffers: List[np.ndarray] = []
+    kept: Optional[List[np.ndarray]] = None
     try:
         with obs_span("tpuft/ddp/plan") as stage:
-            groups, leaf_bytes = _plan()
+            # Kick off every device→host transfer asynchronously up front so
+            # DMA overlaps the bucket assembly and the first ring.
+            for leaf in leaves:
+                if isinstance(leaf, jax.Array):
+                    leaf.copy_to_host_async()
+            bucket_cap = _bucket_cap_bytes()
+            plan = store.plan(
+                (treedef, bucket_cap, tuple(_leaf_signature(l) for l in leaves)),
+                leaves,
+                bucket_cap,
+            )
         stage_s["plan_s"] = stage.duration_s
-        for bucket, group in enumerate(groups):
+        for b, bucket in enumerate(plan.buckets):
             # waits async copies; sharded leaves contribute local shards only
-            with obs_span("tpuft/ddp/d2h", bucket=bucket) as stage:
-                contribs = [_host_contribution(leaves[i]) for i in group]
+            with obs_span("tpuft/ddp/d2h", bucket=b) as stage:
+                hosts = [_to_host(leaves[slot.index], slot) for slot in bucket.slots]
             stage_s["d2h_s"] += stage.duration_s
-            with obs_span("tpuft/ddp/pack", bucket=bucket) as stage:
-                total = sum(c[0].size for c in contribs)
-                flat = np.empty(total, dtype=contribs[0][0].dtype)
-                layout = []
-                off = 0
-                for i, (arr, restore) in zip(group, contribs):
-                    n = arr.size
-                    flat[off : off + n] = arr
-                    layout.append((i, off, n, restore))
-                    off += n
+            with obs_span("tpuft/ddp/pack", bucket=b) as stage:
+                if b == 0:
+                    # as late as can be: the set of the step before comes
+                    # back when its leaves are on the device again, and by
+                    # now this step's first leaves have come the other way
+                    kept = store.take(plan)
+                flat = np.empty(bucket.size, dtype=bucket.dtype) if kept is None else kept[b]
+                for slot, parts in zip(bucket.slots, hosts):
+                    off = slot.offset
+                    for arr in parts:
+                        flat[off : off + arr.size] = arr
+                        off += arr.size
             stage_s["pack_s"] += stage.duration_s
+            buffers.append(flat)
             # submit immediately: this bucket's ring overlaps the next
-            # bucket's fetch/assembly; in_place — the bucket is ours and
-            # discarded after the restore, so the ring reduces straight into
-            # it (no defensive copy; on this host class that copy costs as
-            # much as half the ring itself)
-            with obs_span("tpuft/ddp/submit", bucket=bucket):
+            # bucket's fetch/assembly; in_place — the bucket is ours until
+            # the restore is done, so the ring reduces straight into it (no
+            # defensive copy; on this host class that copy costs as much as
+            # half the ring itself)
+            with obs_span("tpuft/ddp/submit", bucket=b):
                 works.append(
                     manager.allreduce(
                         flat,
@@ -287,40 +425,59 @@ def allreduce_pytree(
                         register_pending=stream is None,
                     )
                 )
-            bucket_layouts.append(layout)
     except BaseException:
         sync_span.__exit__()
         raise
 
-    def _gather() -> Any:
-        out = list(original)
-        for bucket, (work, layout) in enumerate(zip(works, bucket_layouts)):
-            with obs_span("tpuft/ddp/ring_wait", bucket=bucket) as stage:
+    def _gather() -> List[Any]:
+        out = list(leaves)
+        for b, (work, bucket) in enumerate(zip(works, plan.buckets)):
+            with obs_span("tpuft/ddp/ring_wait", bucket=b) as stage:
                 flat = work.wait()
             stage_s["ring_wait_s"] += stage.duration_s
-            with obs_span("tpuft/ddp/h2d", bucket=bucket) as stage:
-                for i, off, n, restore in layout:
-                    out[i] = restore(flat[off : off + n])
+            with obs_span("tpuft/ddp/h2d", bucket=b) as stage:
+                aliased = np.may_share_memory(flat, buffers[b])
+                for slot in bucket.slots:
+                    avg = flat[slot.offset : slot.offset + slot.size]
+                    out[slot.index] = _restore(leaves[slot.index], slot, avg, aliased)
             stage_s["h2d_s"] += stage.duration_s
-        return jax.tree_util.tree_unflatten(treedef, out)
+        return out
 
     fut: "Future[Any]" = Future()
 
     def _finish() -> None:
+        nonlocal leaves
         obs_spans.bind(recorder)
         sync_span.attach()
+        restored: Optional[List[Any]] = None
         try:
-            value = _gather()
+            restored = _gather()
         except Exception as e:  # noqa: BLE001 — funnel, never raise
             manager.report_error(e)
-            value = jax.tree_util.tree_unflatten(treedef, original)
         sync_span.set(
             buckets=len(works),
-            bytes=sum(leaf_bytes),
+            warm_buckets=0 if kept is None else len(works),
+            bytes=plan.nbytes,
             **{k: round(v, 6) for k, v in stage_s.items()},
         )
         sync_span.__exit__()
+        value = jax.tree_util.tree_unflatten(treedef, leaves if restored is None else restored)
+        # this thread lives on below: the gradients as they came are the
+        # caller's to free (a copy of them on the device beside the average)
+        leaves = []
         fut.set_result(value)
+        # The buckets go back to the store only now, off the train thread's
+        # path, and only from a round trip in which every ring ended without
+        # error (one that failed or timed out may still be receiving into its
+        # bucket) and whose restored leaves are ready (``device_put`` reads
+        # the bucket until then).
+        if restored is None or any(w.swallowed is not None for w in works):
+            return
+        try:
+            jax.block_until_ready([x for x in restored if isinstance(x, jax.Array)])
+        except Exception:  # noqa: BLE001 — the next round trip will say so
+            return
+        store.give_back(plan, buffers)
 
     sync_span.detach()  # the gather thread carries the span from here
     threading.Thread(

@@ -206,6 +206,10 @@ class Manager:
         # allreduce_prequantized; fenced at should_commit (the analog of the
         # reference's accelerator-stream synchronize, ``manager.py:888-893``)
         self._pending_works: List[Work] = []
+        # ``ddp.allreduce_pytree``'s plans and host buckets (its
+        # ``_BucketStore``), made at its first round trip and kept for this
+        # Manager's life: a new life starts with none
+        self._host_buckets: Optional[Any] = None
         self._pending_works_lock = threading.Lock()
         # streamed fragment syncs (TORCHFT_STREAM_SYNC): per-fragment Works
         # submitted out-of-band of _pending_works so a round's vote never
@@ -731,18 +735,20 @@ class Manager:
         ``default`` (``manager.py:522-558``)."""
 
         fut: concurrent.futures.Future = concurrent.futures.Future()
+        out = Work(fut)
 
         def _chain(f: concurrent.futures.Future) -> None:
             err = f.exception()
             if err is not None:
                 if isinstance(err, Exception):
                     self.report_error(err)
+                out.swallowed = err
                 fut.set_result(default)
             else:
                 fut.set_result(f.result())
 
         work.future().add_done_callback(_chain)
-        return Work(fut)
+        return out
 
     # ------------------------------------------------------------------
     # quorum
@@ -1293,15 +1299,18 @@ class Manager:
         is what rides the stream-fence registry).
         """
 
-        def _failed_fast(w: Work) -> Work:
+        def _failed_fast(err: BaseException) -> Work:
+            # the input rides through in place of the average ``err`` cost;
             # a fail-fast streamed submit still registers (and stamps
             # FRAG_SUBMIT): the caller's barrier will stream_resolved the
             # fragment, and a FRAG_ABORT must always have a paired submit
             # on the flight timeline
+            w = DummyWork(data)
+            w.swallowed = err
             return w if stream is None else self.stream_submitted(stream, w)
 
-        if self.errored():
-            return _failed_fast(DummyWork(data))
+        if (err := self.errored()) is not None:
+            return _failed_fast(err)
 
         # a failed quorum funnels like any collective error: the input rides
         # through unchanged and the vote discards the step — errors must
@@ -1310,7 +1319,7 @@ class Manager:
             self.wait_quorum()
         except Exception as e:  # noqa: BLE001
             self.report_error(e)
-            return _failed_fast(DummyWork(data))
+            return _failed_fast(e)
         num_participants = self.num_participants()
 
         if not self.is_participating():
@@ -1369,7 +1378,7 @@ class Manager:
         except Exception as e:  # noqa: BLE001
             self._logger.exception(f"got exception in all reduce -- skipping remaining: {e}")
             self.report_error(e)
-            return _failed_fast(DummyWork(data))
+            return _failed_fast(e)
 
     def allreduce_prequantized(
         self,
@@ -1820,6 +1829,7 @@ class Manager:
         if self._own_store is not None:
             self._own_store.shutdown()
         self._comm.shutdown()
+        self._host_buckets = None
 
     # test-friendly logger attribute (mocked-client path sets it lazily)
     @property

@@ -24,6 +24,12 @@ class Work:
     callbacks (``torchft/manager.py:1256-1307``) minus stream bookkeeping.
     """
 
+    # The error this work's value stands in for, where a caller's error
+    # funnel swallowed one (``Manager.wrap_work`` hands the default through
+    # and the vote discards the step): the collective may not have let go of
+    # its buffers.  Set before the future is, so whoever waited may read it.
+    swallowed: Optional[BaseException] = None
+
     def __init__(self, future: "Future[Any]") -> None:
         self._future = future
 
