@@ -19,7 +19,7 @@ at a set of profiles including unshaped loopback as the control.  The
 quantized ring must BEAT the f32 ring at the constrained profiles — that is
 the claim that justifies its existence — while on unshaped loopback it may
 lose (host quantize cycles the fat link never repays; exactly why the
-DiLoCo quant gate is measurement-driven, ``bench.py``).
+DiLoCo quantized wire is opt-in and set from this measurement).
 
 Throughput keys are suffixed ``_GBps`` (gigaBYTES/s) — deliberately NOT
 ``gbps``, so they cannot be misread 8x against the profiles' Gbit/s link

@@ -1,7 +1,13 @@
 """Public API docstring presence (reference analog:
 ``coordination_test.py:15`` asserts the coordination surface is documented)."""
 
+import fnmatch
+import functools
 import inspect
+import os
+import re
+
+import pytest
 
 import torchft_tpu
 
@@ -66,3 +72,57 @@ def test_native_stub_covers_public_surface() -> None:
         if fname not in stub_names:
             missing.append(fname)
     assert not missing, f"native.pyi missing: {missing}"
+
+
+_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+_DOCUMENTS = (
+    "README.md",
+    "docs/operations.md",
+    "docs/analysis.md",
+    "docs/assumptions.md",
+    "docs/striped_heal.md",
+    "docs/SCALE_REHEARSAL.md",
+    ".claude/skills/verify/SKILL.md",
+)
+_FILE_SUFFIXES = (".py", ".md", ".json", ".jsonl", ".sh", ".h", ".cc", ".yaml", ".toml", ".pyi")
+# what building, running and the chip tool leave in a checkout
+_NOT_SOURCE = {
+    ".git", "__pycache__", ".jax_cache", ".pytest_cache", ".hypothesis",
+    "chiprun_out", "_checkout", "_parent", "_chip", "_v1", "_v2", "_v3", "out",
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _checkout_files() -> list:
+    files = []
+    for folder, dirs, names in os.walk(_ROOT):
+        dirs[:] = [d for d in dirs if d not in _NOT_SOURCE]
+        files += [os.path.relpath(os.path.join(folder, n), _ROOT) for n in names]
+    return files
+
+
+@pytest.mark.parametrize("document", _DOCUMENTS)
+def test_documents_name_files_that_exist(document: str) -> None:
+    """Every file a document names in backticks is a file of the checkout:
+    as written, or under some directory of it (``ling_hybrid.py`` for
+    ``torchft_tpu/models/ling_hybrid.py``; ``*`` matches as in a shell).  A
+    trailing ``:line`` is cut.  Not judged: what starts with ``/`` (a path
+    outside the checkout, a URL's path), what holds a ``<placeholder>`` or a
+    ``{field}`` (a name made at run time), and fenced blocks."""
+    files = _checkout_files()
+    with open(os.path.join(_ROOT, document), encoding="utf-8") as f:
+        text = re.sub(r"^```.*?^```", "", f.read(), flags=re.S | re.M)
+    missing = set()
+    for span in re.findall(r"`([^`]+)`", text):
+        for word in span.split():
+            word = re.sub(r":\d+(-\d+)?$", "", word.rstrip(".,;:)"))
+            if not word.endswith(_FILE_SUFFIXES) or word.startswith("/"):
+                continue
+            if any(c in word for c in "<>{}"):
+                continue
+            if not any(
+                fnmatch.fnmatchcase(f, word) or fnmatch.fnmatchcase(f, "*/" + word)
+                for f in files
+            ):
+                missing.add(word)
+    assert not missing, f"{document} names files the checkout does not have: {sorted(missing)}"

@@ -233,3 +233,53 @@ def test_baby_kill_recovers(store) -> None:
     out = comm.allreduce(np.full(4, 2.0, dtype=np.float32)).wait(timeout=10.0)
     np.testing.assert_allclose(out, np.full(4, 2.0))
     comm.shutdown()
+
+
+def test_baby_stale_listener_spares_the_next_childs_futures(store) -> None:
+    """The listener of a child that ``abort()`` replaced wakes on EOF
+    whenever the host lets it, possibly after the next ``configure()`` has
+    submitted to the NEW child.  It must fail nothing then: ``_futures`` is
+    one dictionary for every generation of child."""
+    import multiprocessing as mp
+    from concurrent.futures import Future
+
+    def pending(comm):
+        """A future of the live child whose command is not sent yet."""
+        fut: Future = Future()
+        with comm._lock:
+            op_id = comm._next_op
+            comm._next_op += 1
+            comm._futures[op_id] = fut
+        return op_id, fut
+
+    def death_path(comm, proc) -> None:
+        """``proc``'s listener, now, on a pipe that reads EOF at once."""
+        r, w = mp.Pipe(duplex=False)
+        w.close()
+        comm._listen(MonitoredPipe(r), proc)
+
+    comm = BabyCommunicator(timeout_s=30.0)
+    try:
+        comm.configure(
+            f"127.0.0.1:{store.port}/gen1", replica_id="r", rank=0, world_size=1
+        )
+        first = comm._proc
+        comm.configure(
+            f"127.0.0.1:{store.port}/gen2", replica_id="r", rank=0, world_size=1
+        )
+        assert comm._proc is not first and comm._proc.is_alive()
+
+        op_id, fut = pending(comm)
+        death_path(comm, first)
+        assert not fut.done()
+        assert comm._futures.get(op_id) is fut
+        assert comm.errored() is None
+        comm._cmd.send((op_id, "barrier", {}))
+        fut.result(timeout=30.0)
+
+        # the LIVE child's own death path still fails what is pending on it
+        _, orphan = pending(comm)
+        death_path(comm, comm._proc)
+        assert isinstance(orphan.exception(timeout=0), CommunicatorAborted)
+    finally:
+        comm.shutdown()

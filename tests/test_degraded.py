@@ -761,58 +761,6 @@ class TestDeviceLossChaos:
         assert "TORCHFT_CHAOS_DEVICE_LOSS" not in _Spec.env
 
 
-class TestBenchDegradedPhase:
-    def test_phase_extracts_headline_keys(self, monkeypatch) -> None:
-        """bench._run_degraded_phase must surface the two headline keys
-        (degraded_step_time_ratio / wound_to_swap_s) from the drills and
-        pin the wan_1g profile for the duration."""
-        import bench as bench_mod
-        from torchft_tpu import drill as drill_mod
-
-        seen = {}
-
-        def fake_drill(mode, num_replicas, steps):
-            import os as _os
-
-            seen[mode] = _os.environ.get("TORCHFT_NET_EMU")
-            if mode == "device_loss":
-                return {
-                    "degraded_step_time_ratio": 1.07,
-                    "capacity_observed": 0.75,
-                    "quorum_reconfigs": 0,
-                    "converged": True,
-                }
-            return {
-                "wound_to_swap_s": 0.4,
-                "swaps_total": 1,
-                "quorum_reconfigs": 1,
-            }
-
-        monkeypatch.setattr(drill_mod, "gray_failure_drill", fake_drill)
-        out = bench_mod._run_degraded_phase()
-        assert seen == {
-            "device_loss": "wan_1g",
-            "device_loss_swap": "wan_1g",
-        }
-        assert out["degraded_step_time_ratio"] == 1.07
-        assert out["wound_to_swap_s"] == 0.4
-        assert out["swaps_total"] == 1
-
-    def test_phase_records_failures_instead_of_raising(
-        self, monkeypatch
-    ) -> None:
-        import bench as bench_mod
-        from torchft_tpu import drill as drill_mod
-
-        def boom(**_kw):
-            raise RuntimeError("drill exploded")
-
-        monkeypatch.setattr(drill_mod, "gray_failure_drill", boom)
-        out = bench_mod._run_degraded_phase()
-        assert "drill exploded" in out["device_loss_error"]
-        assert "drill exploded" in out["swap_error"]
-
-
 class TestDeviceLossDrills:
     """The ISSUE-13 acceptance drills.  Loopback variants run in tier-1;
     CI reruns this module under TORCHFT_NET_EMU=wan_1g."""

@@ -1,0 +1,229 @@
+"""The package's layering, held by its import graph.
+
+Every arrow between two modules of ``torchft_tpu`` points down this table or
+stays inside a row.  Imports are read with ``ast`` over each whole file, so a
+lazy import inside a function counts like one at the top, and nothing is
+imported.  What ``ast`` cannot see: the string maps behind the lazy
+``__getattr__`` of the package ``__init__`` files (each sits in the row of
+the highest module it names), and a module reached through ``sys.path``
+under a bare name (``drill.py`` imports ``scripts/flight_merge.py`` that
+way: ROADMAP D10).
+
+The rows are ``PERF.md`` section 3's layers, bottom to top, with the shared
+plumbing under them.  A name ending in ``.*`` is a package with everything
+in it.  A new module needs a row here; a module that has to import upward is
+in the wrong row, or the code it wants is.
+"""
+
+from __future__ import annotations
+
+import ast
+import functools
+import os
+from typing import Dict, Iterator, List, Tuple
+
+import pytest
+
+PACKAGE = "torchft_tpu"
+_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+# the bottom layer first: a module imports from its own row and from rows
+# listed before it, never from a row listed after it (a higher layer)
+ROWS: List[Tuple[str, Tuple[str, ...]]] = [
+    ("options", ("knobs",)),
+    (
+        "wire-records-serialization",
+        (
+            "wire",
+            "futures",
+            "work",
+            "obs.*",
+            "observability",
+            "checkpointing._rwlock",
+            "checkpointing.serialization",
+            "checkpointing.transport",
+            "utils.*",
+        ),
+    ),
+    ("store-kernels-data", ("store", "ops.*", "data")),
+    (
+        # the model code: kernels under it, no Manager, no communicator
+        "compiled-step-models",
+        (
+            "parallel.mesh",
+            "parallel.ring_attention",
+            "parallel.moe",
+            "parallel.pipeline",
+            "models.*",
+        ),
+    ),
+    (
+        "host-data-plane",
+        (
+            "communicator",
+            "native",
+            "quantization",
+            "coord",
+            "coord.aggregator",
+            "multiprocessing",
+        ),
+    ),
+    (
+        "collectives-lighthouse-heal-transports",
+        (
+            "collectives",
+            "lighthouse",
+            "checkpointing",
+            "checkpointing.http_transport",
+            "checkpointing.comm_transport",
+            "baby",
+            "parameter_server",
+        ),
+    ),
+    ("control-plane-servers", ("manager_server", "tier", "coord.scale")),
+    ("manager", ("manager",)),
+    ("device-host-boundary", ("ddp", "optim")),
+    (
+        # hsdp and degraded import each other: one row, no exception list
+        "replica-algorithms-trainers",
+        (
+            "local_sgd",
+            "spare",
+            "parallel",
+            "parallel.hsdp",
+            "parallel.degraded",
+            "parallel.rehearsal",
+        ),
+    ),
+    (
+        "entry-points-drills-lint",
+        (
+            "",  # the package's own __init__: a lazy facade over everything
+            "drill",
+            "chaos",
+            "launcher",
+            "punisher",
+            "scheduler",
+            "coordination",
+            "analysis.*",
+        ),
+    ),
+]
+
+# what lives beside the package and is never imported from inside it
+OUTSIDE = (
+    "ftbench",
+    "tests",
+    "examples",
+    "scripts",
+    "benchmarks",
+    "bench",
+    "chip_smoke",
+    "__graft_entry__",
+)
+
+
+@functools.lru_cache(maxsize=None)
+def _modules() -> Dict[str, str]:
+    """Module name relative to the package ('' is its ``__init__``) -> path."""
+    out: Dict[str, str] = {}
+    top = os.path.join(_ROOT, PACKAGE)
+    for folder, _dirs, files in os.walk(top):
+        for name in files:
+            if not name.endswith(".py"):
+                continue
+            path = os.path.join(folder, name)
+            parts = os.path.relpath(path, top)[: -len(".py")].split(os.sep)
+            if parts[-1] == "__init__":
+                parts = parts[:-1]
+            out[".".join(parts)] = path
+    return out
+
+
+def _imports(module: str, path: str) -> Iterator[Tuple[str, str, int]]:
+    """(absolute module named, attribute or '', line) for every import."""
+    with open(path, encoding="utf-8") as f:
+        tree = ast.parse(f.read(), filename=path)
+    here = [PACKAGE] + ([p for p in module.split(".") if p])
+    if not path.endswith("__init__.py"):
+        here = here[:-1]
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Import):
+            for alias in node.names:
+                yield alias.name, "", node.lineno
+        elif isinstance(node, ast.ImportFrom):
+            base = node.module or ""
+            if node.level:
+                up = here[: len(here) - (node.level - 1)]
+                base = ".".join(up + ([node.module] if node.module else []))
+            for alias in node.names:
+                yield base, alias.name, node.lineno
+
+
+def _matches(module: str, pattern: str) -> bool:
+    if pattern.endswith(".*"):
+        return module == pattern[:-2] or module.startswith(pattern[:-1])
+    return module == pattern
+
+
+def _rows_of(module: str) -> List[int]:
+    return [i for i, (_n, pats) in enumerate(ROWS) if any(_matches(module, p) for p in pats)]
+
+
+@functools.lru_cache(maxsize=None)
+def _inner_edges() -> List[Tuple[str, int, str]]:
+    """(importing module, line, imported module), both inside the package."""
+    modules = _modules()
+    edges = []
+    for module, path in sorted(modules.items()):
+        for base, attr, line in _imports(module, path):
+            if base.split(".")[0] != PACKAGE:
+                continue
+            rel = base[len(PACKAGE) :].lstrip(".")
+            target = f"{rel}.{attr}".lstrip(".") if attr else rel
+            if target not in modules:
+                target = rel  # a name taken from a module, not a module
+            while target not in modules:  # e.g. ``import torchft_tpu.x.y`` of a name
+                target = target.rpartition(".")[0]
+            if target != module:
+                edges.append((module, line, target))
+    return edges
+
+
+def _label(module: str) -> str:
+    return f"{PACKAGE}.{module}" if module else PACKAGE
+
+
+@pytest.mark.parametrize("row", range(len(ROWS)), ids=[name for name, _ in ROWS])
+def test_no_module_of_a_row_imports_from_a_higher_row(row: int) -> None:
+    modules = _modules()
+    mine = [m for m in modules if _rows_of(m) == [row]]
+    assert mine, f"row {ROWS[row][0]!r} holds no module of the package: {ROWS[row][1]}"
+    upward = []
+    for module, line, target in _inner_edges():
+        if module not in mine:
+            continue
+        rows = _rows_of(target)
+        if rows and max(rows) > row:
+            upward.append(
+                f"{_label(module)}:{line} imports {_label(target)} "
+                f"(row {ROWS[row][0]!r} -> row {ROWS[max(rows)][0]!r})"
+            )
+    assert not upward, "upward imports:\n" + "\n".join(dict.fromkeys(upward))
+
+
+def test_the_packages_edge() -> None:
+    """Every module has exactly one row, and nothing inside the package
+    imports what lives beside it."""
+    modules = _modules()
+    misplaced = {
+        _label(m): [ROWS[i][0] for i in _rows_of(m)] for m in modules if len(_rows_of(m)) != 1
+    }
+    assert not misplaced, f"modules in no row or in several (add one to ROWS): {misplaced}"
+    outward = [
+        f"{_label(module)}:{line} imports {base}"
+        for module, path in sorted(modules.items())
+        for base, _attr, line in _imports(module, path)
+        if base.split(".")[0] in OUTSIDE
+    ]
+    assert not outward, "the package imports what lives beside it:\n" + "\n".join(outward)

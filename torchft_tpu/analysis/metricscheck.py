@@ -34,7 +34,7 @@ CHECKER = "metrics-registry"
 
 _REGISTRY_REL = os.path.join("torchft_tpu", "obs", "metrics.py")
 _DOC_REL = os.path.join("docs", "operations.md")
-_SCAN_ROOTS = ("torchft_tpu", "bench.py", "scripts", "benchmarks", "examples")
+_SCAN_ROOTS = ("torchft_tpu", "scripts", "benchmarks", "examples")
 
 _DECL_RE = re.compile(r'_m\(\s*\n?\s*"(?P<name>[^"]+)",\s*"(?P<kind>[^"]+)"')
 _NAME_RE = re.compile(r"^[a-z_:][a-z0-9_:]*$")

@@ -346,10 +346,10 @@ class BabyCommunicator(Communicator):
                 # quiet between steps indefinitely
                 if proc.is_alive():
                     continue
-                self._fail_all("baby communicator child died")
+                self._fail_all(proc, "baby communicator child died")
                 return
             except (EOFError, OSError):
-                self._fail_all("baby communicator child died")
+                self._fail_all(proc, "baby communicator child died")
                 return
             if op_id == -1:
                 # child init failure: surface the real cause everywhere
@@ -358,12 +358,7 @@ class BabyCommunicator(Communicator):
                     if isinstance(result, Exception)
                     else RuntimeError(str(result))
                 )
-                # first-error-wins must be atomic: the caller thread resets
-                # _errored at epoch boundaries, so an unlocked `x = x or e`
-                # here could resurrect a cleared error or drop this one
-                with self._lock:
-                    self._errored = self._errored or err
-                self._fail_all(str(err))
+                self._fail_all(proc, str(err), err)
                 return
             with self._lock:
                 fut = self._futures.pop(op_id, None)
@@ -376,8 +371,22 @@ class BabyCommunicator(Communicator):
             else:
                 fut.set_result(result)
 
-    def _fail_all(self, reason: str) -> None:
+    def _fail_all(
+        self, proc, reason: str, err: Optional[Exception] = None
+    ) -> None:
+        """Fail what is pending on ``proc``, the caller's own child.  A
+        listener that wakes on the EOF of a child ``abort()`` has replaced
+        fails nothing: ``abort()`` failed that child's futures itself, and
+        ``_futures`` (one dictionary for every generation of child) now
+        holds the NEXT child's, its ``configure`` first."""
         with self._lock:
+            if proc is not self._proc:
+                return
+            if err is not None:
+                # first-error-wins must be atomic: the caller thread resets
+                # _errored at epoch boundaries, so an unlocked `x = x or e`
+                # here could resurrect a cleared error or drop this one
+                self._errored = self._errored or err
             futures = list(self._futures.values())
             self._futures.clear()
         for fut in futures:

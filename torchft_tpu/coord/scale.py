@@ -28,8 +28,7 @@ Run it directly::
     python -m torchft_tpu.coord.scale --replicas 500 --aggregators 2
 
 The CI smoke runs ~200 replicas under a hard time budget
-(tests/test_coord.py); the 500–1000 sweep is the ``slow``-marked variant
-and the bench phase (bench.py ``coord``).
+(tests/test_coord.py); the 500–1000 sweep is the ``slow``-marked variant.
 """
 
 from __future__ import annotations

@@ -46,7 +46,7 @@ class Knob:
     type: str  # "str" | "int" | "float" | "bool"
     default: str  # human-rendered default (may be "auto", "unset", ...)
     doc: str
-    scope: str = "runtime"  # "runtime" | "bench" | "launcher"
+    scope: str = "runtime"  # "runtime" | "launcher"
 
 
 REGISTRY: Dict[str, Knob] = {}
@@ -214,95 +214,6 @@ _k("TPUFT_GROUP_WORLD_SIZE", "int", "1",
    "Total replica groups in the job (set by the launcher/scheduler)", "launcher")
 _k("TPUFT_STANDBY_GATE", "str", "unset",
    "Gate file a standby blocks on before starting (hot-standby launch path)", "launcher")
-# --- bench harness (bench.py / scripts) -------------------------------------
-_k("TPUFT_BENCH_PLATFORM", "str", "auto",
-   "Force the bench backend (cpu | tpu)", "bench")
-_k("TPUFT_BENCH_WORKER_PLATFORM", "str", "inherit",
-   "Backend for bench fleet worker processes", "bench")
-_k("TPUFT_BENCH_MODE", "str", "ddp",
-   "Bench training mode (ddp | localsgd | diloco)", "bench")
-_k("TPUFT_BENCH_OUT", "str", "<repo>/bench_out.json",
-   "Bench artifact output path", "bench")
-_k("TPUFT_BENCH_EVENTS_DIR", "str", "unset",
-   "Directory fleet workers write lifecycle events to", "bench")
-_k("TPUFT_BENCH_STEPS", "int", "8 cpu / 30 tpu",
-   "Phase-A measured steps", "bench")
-_k("TPUFT_BENCH_TARGET_STEPS", "int", "derived",
-   "Fleet worker step target (set for workers by the parent)", "bench")
-_k("TPUFT_BENCH_DIM", "int", "256 cpu / 2048 tpu",
-   "Bench model hidden dim", "bench")
-_k("TPUFT_BENCH_LAYERS", "int", "4 cpu / 16 tpu",
-   "Bench model layer count", "bench")
-_k("TPUFT_BENCH_SEQ", "int", "256 cpu / 2048 tpu",
-   "Bench sequence length", "bench")
-_k("TPUFT_BENCH_BATCH", "int", "4 cpu / 8 tpu",
-   "Bench per-step batch size", "bench")
-_k("TPUFT_BENCH_HEAD_DIM", "int", "64 cpu / 128 tpu",
-   "Bench attention head dim", "bench")
-_k("TPUFT_BENCH_REMAT", "bool", "0 cpu / 1 tpu",
-   "Enable remat in the bench model", "bench")
-_k("TPUFT_BENCH_REMAT_MODE", "str", "unset",
-   "Remat policy override for the bench model", "bench")
-_k("TPUFT_BENCH_REPLICAS", "int", "3",
-   "Fleet phase replica-group count", "bench")
-_k("TPUFT_BENCH_STANDBY", "int", "1",
-   "Hot standbys kept during the fleet phase", "bench")
-_k("TPUFT_BENCH_ALL_STANDBY", "bool", "0",
-   "Relaunch every killed replica as a standby", "bench")
-_k("TPUFT_BENCH_FLEET_STEPS", "int", "48 cpu / 100 tpu",
-   "Fleet phase step count", "bench")
-_k("TPUFT_BENCH_FLEET_DIM", "int", "256",
-   "Fleet phase model hidden dim", "bench")
-_k("TPUFT_BENCH_FLEET_LAYERS", "int", "4",
-   "Fleet phase model layer count", "bench")
-_k("TPUFT_BENCH_FLEET_SEQ", "int", "256 cpu / 512 tpu",
-   "Fleet phase sequence length", "bench")
-_k("TPUFT_BENCH_FLEET_BATCH", "int", "4 cpu / 8 tpu",
-   "Fleet phase batch size", "bench")
-_k("TPUFT_BENCH_KILL_EVERY", "int", "14 cpu / 25 tpu",
-   "Fleet phase: kill one replica every N steps", "bench")
-_k("TPUFT_BENCH_JOIN_MS", "float", "1000",
-   "Fleet phase relaunch join pause (ms)", "bench")
-_k("TPUFT_BENCH_HEAL_TRANSPORT", "str", "comm",
-   "Heal transport for the fleet phase (comm | http)", "bench")
-_k("TPUFT_BENCH_DILOCO_STEPS", "int", "48 cpu / 96 tpu",
-   "DiLoCo phase step count", "bench")
-_k("TPUFT_BENCH_DILOCO_SYNC", "int", "8",
-   "DiLoCo outer-sync cadence (steps)", "bench")
-_k("TPUFT_BENCH_DILOCO_DELAY", "int", "2",
-   "DiLoCo delayed-apply depth", "bench")
-_k("TPUFT_BENCH_DILOCO_FRAGMENTS", "int", "2",
-   "DiLoCo streaming fragment count", "bench")
-_k("TPUFT_BENCH_DILOCO_KILLS", "int", "3",
-   "DiLoCo chaos-leg kill count", "bench")
-_k("TPUFT_BENCH_DILOCO_QUANT", "str", "auto",
-   "DiLoCo quantized-wire legs: auto | 0 | 1", "bench")
-_k("TPUFT_BENCH_DILOCO_QUANT_WIRE", "bool", "0",
-   "Worker-side flag: quantize the outer-sync wire", "bench")
-_k("TPUFT_BENCH_SKIP_FLEET", "bool", "0",
-   "Skip the fleet (kill/heal) bench phase", "bench")
-_k("TPUFT_BENCH_SKIP_DILOCO", "bool", "0",
-   "Skip the DiLoCo bench phase", "bench")
-_k("TPUFT_BENCH_SKIP_SPARE", "bool", "0",
-   "Skip the hot-spare promotion bench phase", "bench")
-_k("TPUFT_BENCH_SKIP_COORD", "bool", "0",
-   "Skip the coordination-plane scale phase", "bench")
-_k("TPUFT_BENCH_SKIP_DEGRADED", "bool", "0",
-   "Skip the degraded-mode (device-loss) bench phase", "bench")
-_k("TPUFT_BENCH_SKIP_STREAM", "bool", "0",
-   "Skip the streamed-outer-sync DiLoCo bench leg (diloco_faultfree_streaming)", "bench")
-_k("TPUFT_BENCH_SKIP_OBS", "bool", "0",
-   "Skip the observability-overhead bench phase", "bench")
-_k("TPUFT_BENCH_OBS_STEPS", "int", "40",
-   "Measured steps per leg of the observability-overhead phase", "bench")
-_k("TPUFT_BENCH_COORD_REPLICAS", "int", "120 cpu / 500 tpu",
-   "Simulated replicas driven by the coordination scale phase", "bench")
-_k("TPUFT_BENCH_TOTAL_BUDGET_S", "float", "2100",
-   "Soft wall-clock budget for the whole bench run", "bench")
-_k("TPUFT_BENCH_HARD_DEADLINE_S", "float", "budget+420",
-   "Hard watchdog: emit a partial artifact and exit 0 at this age", "bench")
-_k("TPUFT_PEAK_TFLOPS", "float", "auto",
-   "Override the per-chip peak TFLOP/s used for MFU math", "bench")
 
 
 def _parse_error(name: str, raw: str, expected: str) -> ValueError:

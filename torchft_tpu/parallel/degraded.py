@@ -13,7 +13,7 @@ data-shard rescale, the lighthouse's wound→swap→evict ladder — lives in
    ``m <= n_surviving`` (most devices first); when a model is given each
    candidate is dry-run through the existing :mod:`rehearsal` layer
    (divisibility + sharding-aware HBM fit, optional abstract-mesh
-   lowering — the MULTICHIP_r05 machinery) and the first plan that
+   lowering) and the first plan that
    rehearses clean wins.  The plan's ``capacity`` fraction
    (``devices_used / original_devices``) is exactly what the Manager
    advertises on the wire-v5 capacity tail.

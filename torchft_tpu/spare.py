@@ -3,8 +3,8 @@
 PHOENIX (PAPERS.md) shows hot-swap recovery can be near-zero overhead when
 standby state is kept continuously warm; the 100k-GPU HSDP report makes the
 fleet-scale case: spare capacity that is already caught up turns a failure
-from a 6–12 s heal-in (BENCH_r03/r04 ``heal_breakdown``) into a membership
-edit.  This module is the SPARE side of that design:
+from a cold heal-in of seconds (``resume_s`` and ``heal_ms`` in the cell
+``mistral7b-ddp2-kill``, ``PERF_LEDGER.jsonl``) into a membership edit.  This module is the SPARE side of that design:
 
 - :class:`WarmChunkStore` — warm channel (b): a per-chunk, crc-watermarked
   cache of an active peer's serialized state dict, filled at idle priority
