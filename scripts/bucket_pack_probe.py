@@ -13,12 +13,28 @@ configuration, fresh device arrays every round), bucketed as
 Prints one JSON line a mode: seconds a round (thread 0), each bucket's wait
 for its leaves and each bucket's pack, medians over the rounds after the
 first.  ``chiprun -- python3 scripts/bucket_pack_probe.py``.
+
+``--ordered`` (PERF.md section 6, PR 32) reads instead what ORDER and WINDOW
+the transfers should be started in so that the ring runs beside them: each
+thread has a stand-in for the communicator's op thread (copies of each
+landed bucket and an in-place division, ``--ring-ms-per-mb`` a megabyte in
+all: the ledger's ``comm_op_ms`` + ``sync_normalize_ms`` over 973 MB), packs
+into kept buffers, and starts a bucket's ``copy_to_host_async`` only
+``window`` buckets ahead of the one it waits for (``all``: every leaf up
+front, as the program did before PR 32).  ``--configs`` is a list of
+``window:order``; the orders are ``tree`` (as the tree lists them), ``asc``,
+``desc`` (by bytes) and ``flow`` (the smallest first, then by falling size).
+One JSON line a config: the round (start to the last bucket's stand-in
+done), the transfer stretch (start to the last bucket landed), when the
+first bucket was handed over, and the share of the stand-in's seconds that
+lie before the last landing.
 """
 
 from __future__ import annotations
 
 import argparse
 import json
+import queue
 import statistics
 import sys
 import threading
@@ -31,8 +47,137 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
+def ordered(args, trees, bump, groups) -> None:
+    """The ``--ordered`` mode: see the module's docstring."""
+    from torchft_tpu import ddp
+
+    by_size = lambda sign: lambda sizes: sorted(range(len(sizes)), key=lambda b: (sign * sizes[b], b))  # noqa: E731
+    orders = {
+        "tree": lambda sizes: list(range(len(sizes))),
+        "asc": by_size(1),
+        "desc": by_size(-1),
+        "flow": ddp._pipeline_order,  # the one the program keeps
+    }
+    leaves0 = jax.tree_util.tree_leaves(trees[0])
+    sizes = [sum(leaves0[i].nbytes for i in g) for g in groups]
+    kept = [
+        [np.zeros(sum(leaves0[i].size for i in g), leaves0[g[0]].dtype) for g in groups]
+        for _ in range(args.threads)
+    ]
+    scratch = [np.zeros(max(k.size for k in ks), ks[0].dtype) for ks in kept]
+    med = lambda xs: round(statistics.median(xs) * 1e3, 1)  # noqa: E731
+
+    def stand_in(t: int, jobs: "queue.Queue", done: list) -> None:
+        while True:
+            job = jobs.get()
+            if job is None:
+                return
+            b, flat = job
+            t0 = time.perf_counter()
+            want = args.ring_ms_per_mb * 1e-3 * flat.nbytes / 1e6
+            if want <= 0.0:  # no stand-in: the transfer with nothing beside it
+                done.append((b, t0, t0))
+                continue
+            view = scratch[t][: flat.size]
+            view[:] = flat
+            flat[:] = view
+            np.true_divide(flat, 2, out=flat, casting="unsafe")
+            while time.perf_counter() - t0 < want:  # the ring's other passes
+                view[:] = flat
+            done.append((b, t0, time.perf_counter()))
+
+    for config in args.configs.split(","):
+        window_s, order_name = config.split(":")
+        order = orders[order_name](sizes)
+        window = len(order) if window_s == "all" else int(window_s)
+        out = [[] for _ in range(args.threads)]
+        barrier = threading.Barrier(args.threads)
+
+        def run(t: int) -> None:
+            tree = trees[t]
+            for _ in range(args.rounds):
+                tree = bump(tree)
+                jax.block_until_ready(tree)
+                leaves = jax.tree_util.tree_leaves(tree)
+                jobs: "queue.Queue" = queue.Queue()
+                done: list = []
+                op = threading.Thread(target=stand_in, args=(t, jobs, done))
+                op.start()
+                barrier.wait()
+                t0 = time.perf_counter()
+                asked, waits, lands, hands = 0, [], [], []
+                for at, b in enumerate(order):
+                    while asked < min(at + window, len(order)):
+                        for i in groups[order[asked]]:
+                            leaves[i].copy_to_host_async()
+                        asked += 1
+                    t1 = time.perf_counter()
+                    arrs = [np.asarray(leaves[i]).reshape(-1) for i in groups[b]]
+                    t2 = time.perf_counter()
+                    waits.append(t2 - t1)
+                    lands.append(t2 - t0)
+                    flat, off = kept[t][b], 0
+                    for a in arrs:
+                        flat[off : off + a.size] = a
+                        off += a.size
+                    jobs.put((b, flat))
+                    hands.append(time.perf_counter() - t0)
+                jobs.put(None)
+                op.join()
+                last_land = t0 + lands[-1]
+                busy = sum(e - s for _, s, e in done)
+                beside = sum(max(0.0, min(e, last_land) - s) for _, s, e in done)
+                out[t].append(
+                    dict(
+                        round=done[-1][2] - t0,
+                        stretch=lands[-1],
+                        first_hand=hands[0],
+                        waits=waits,
+                        lands=lands,
+                        op=busy,
+                        beside=beside / busy if busy else 0.0,
+                    )
+                )
+            trees[t] = tree
+
+        threads = [threading.Thread(target=run, args=(t,)) for t in range(args.threads)]
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join()
+        rounds = out[0][1:]
+        print(
+            json.dumps(
+                {
+                    "window": window_s,
+                    "order": order_name,
+                    "buckets_mb": [round(sizes[b] / 1e6, 1) for b in order],
+                    "round_ms": med([r["round"] for r in rounds]),
+                    "round_ms_all": [round(r["round"] * 1e3, 1) for r in out[0]],
+                    "round_ms_thread1": med([r["round"] for r in out[-1][1:]]),
+                    "transfer_stretch_ms": med([r["stretch"] for r in rounds]),
+                    "first_hand_ms": med([r["first_hand"] for r in rounds]),
+                    "stand_in_ms": med([r["op"] for r in rounds]),
+                    "stand_in_beside_transfer_pct": round(
+                        100 * statistics.median(r["beside"] for r in rounds), 1
+                    ),
+                    "d2h_wait_ms_in_order": [med([r["waits"][k] for r in rounds]) for k in range(len(order))],
+                    "lands_ms_in_order": [med([r["lands"][k] for r in rounds]) for k in range(len(order))],
+                }
+            ),
+            flush=True,
+        )
+
+
 def main() -> None:
     ap = argparse.ArgumentParser()
+    ap.add_argument("--ordered", action="store_true")
+    ap.add_argument(
+        "--configs",
+        default="all:tree,2:asc,2:desc,2:flow,1:flow,3:flow,all:flow,all:tree",
+        help="--ordered: window:order, ...; window a count of buckets or 'all'",
+    )
+    ap.add_argument("--ring-ms-per-mb", type=float, default=0.97)
     ap.add_argument("--workload", default="mistral7b-ddp2-steady")
     ap.add_argument("--rounds", type=int, default=6)
     ap.add_argument("--threads", type=int, default=2)
@@ -58,6 +203,9 @@ def main() -> None:
     leaves0 = jax.tree_util.tree_leaves(trees[0])
     plan = ddp._make_plan(leaves0, ddp._bucket_cap_bytes())  # the cell's own buckets
     groups = [[slot.index for slot in bucket.slots] for bucket in plan.buckets]
+    # as the tree lists them (a dtype's buckets together), whatever order the plan keeps
+    dtypes = [l.dtype.name for l in leaves0]
+    groups.sort(key=lambda g: (dtypes.index(dtypes[g[0]]), g[0]))
     mbytes = sum(l.nbytes for l in leaves0) / 1e6
     print(
         "buckets",
@@ -66,6 +214,9 @@ def main() -> None:
         round(mbytes, 2),
         file=sys.stderr,
     )
+    if args.ordered:
+        ordered(args, trees, bump, groups)
+        return
 
     for mode in ("d2h", "fresh", "kept", "fresh", "kept", "d2h"):
         out = [[] for _ in range(args.threads)]

@@ -1,5 +1,6 @@
 """The host buckets of ``ddp.allreduce_pytree`` last from step to step
-(ISSUE 30).  Five rules, each with a test that fails when it is broken:
+(ISSUE 30) and cross to the host in an order, a few at a time (ISSUE 32: the
+last section).  Five rules, each with a test that fails when it is broken:
 
 1. a set of buckets is handed out again only after a round trip that ended
    without error and whose restored leaves are ready;
@@ -170,11 +171,14 @@ STEPS = 6
 CHANGES_AT = 3
 
 
-@pytest.mark.parametrize("case", ["same_tree", "cap_flipped", "leaf_reshaped", "non_participating_step"])
+@pytest.mark.parametrize(
+    "case", ["same_tree", "cap_flipped", "leaf_reshaped", "non_participating_step", "buckets_differ_30_times"]
+)
 def test_six_steps_are_bit_equal_to_the_parents_formula(pair, monkeypatch, case) -> None:
     rngs = [np.random.default_rng(10 + r) for r in range(2)]
     for step in range(STEPS):
-        wide = 300
+        # 48,000 bytes of ``embed`` beside buckets of 1,544 and 330
+        wide = 3000 if case == "buckets_differ_30_times" else 300
         if step >= CHANGES_AT and case == "cap_flipped":
             monkeypatch.setenv(BUCKET_CAP_MB_ENV, str(1024 / (1 << 20)))
         if step >= CHANGES_AT and case == "leaf_reshaped":
@@ -199,6 +203,12 @@ def test_six_steps_are_bit_equal_to_the_parents_formula(pair, monkeypatch, case)
         syncs = _syncs(m)
         assert len(syncs) == STEPS
         assert syncs[-1]["warm_buckets"] == syncs[-1]["buckets"] > 1
+        if case == "buckets_differ_30_times":
+            # the rings ran in the plan's order, not the tree's: the smallest
+            # bucket, then by falling size
+            handed = pair.handed[pair.managers.index(m)]
+            sizes = [a.nbytes for a in handed[-syncs[-1]["buckets"]:]]
+            assert sizes == [330, 48000, 1544]
 
 
 def test_from_the_second_step_the_buckets_are_the_first_steps_memory(pair) -> None:
@@ -462,3 +472,157 @@ def test_no_knob() -> None:
     ]
     source = inspect.getsource(ddp)
     assert source.count("os.environ") == 1  # the bucket cap, as before
+    # the window and the order (ISSUE 32): a constant and a function of the
+    # buckets' sizes, not an argument, an environment variable or a knob
+    assert type(ddp._D2H_AHEAD) is int and ddp._D2H_AHEAD >= 1
+    assert list(inspect.signature(ddp._pipeline_order).parameters) == ["nbytes"]
+    assert list(inspect.signature(ddp._make_plan).parameters) == ["leaves", "bucket_cap"]
+    assert "knobs" not in source and "getenv" not in source
+    # the loop that started every leaf's copy at once is gone, not switched off
+    assert source.count("copy_to_host_async()") == 1
+
+
+# ----------------------------------------------------------------------
+# the order the buckets cross in, and how far ahead their copies start
+# ----------------------------------------------------------------------
+
+
+class _Shape:
+    """What ``_make_plan`` reads of a leaf that is no ``jax.Array``."""
+
+    def __init__(self, shape, dtype) -> None:
+        self.shape, self.dtype = tuple(shape), np.dtype(dtype)
+        self.size = int(np.prod(self.shape, dtype=np.int64))
+        self.nbytes = self.size * self.dtype.itemsize
+
+
+def _cell_trees(name: str) -> List[Any]:
+    """The trees a trainer of the benchmark's cell sends through
+    ``allreduce_pytree`` in a step, as shapes (``jax.eval_shape``): the
+    gradients (a state leaf's slot carries its float32 signal) and, for a
+    model with state the optimizer does not own under ``quantize_outer``,
+    the signal by itself."""
+    from ftbench import spec
+
+    cell = spec.load_cell(name)
+    model = cell.architecture.model(cell.config)
+    shapes = jax.tree_util.tree_leaves(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    mask = jax.tree_util.tree_leaves(model.state_mask()) if hasattr(model, "state_mask") else [False] * len(shapes)
+    grads = [_Shape(s.shape, np.float32 if is_state else s.dtype) for s, is_state in zip(shapes, mask)]
+    return [grads] + ([[g for g, is_state in zip(grads, mask) if is_state]] if any(mask) else [])
+
+
+@pytest.mark.parametrize(
+    "cell,which", [("mistral7b-ddp2-steady", 0), ("ling3flash-ws1-seq8k", 0), ("ling3flash-ws1-seq8k", 1)],
+    ids=["mistral_gradients", "ling_gradients", "ling_signal"],
+)
+def test_two_managers_derive_one_order_from_the_bucket_sizes_alone(cell, which) -> None:
+    leaves = _cell_trees(cell)[which]
+    cap = ddp._bucket_cap_bytes()
+    plans = [ddp._BucketStore().plan(("signature",), leaves, cap) for _ in range(2)]
+    assert plans[0] is not plans[1]
+    layouts = [[[slot.index for slot in b.slots] for b in p.buckets] for p in plans]
+    assert layouts[0] == layouts[1]
+    # every leaf in exactly one bucket, a bucket's leaves in the tree's order
+    assert sorted(i for group in layouts[0] for i in group) == list(range(len(leaves)))
+    assert all(group == sorted(group) for group in layouts[0])
+    sizes = [b.size * b.dtype.itemsize for b in plans[0].buckets]
+    assert sum(sizes) == plans[0].nbytes == sum(l.nbytes for l in leaves)
+    # the smallest first, then by falling size; ties by place in the tree
+    if len(sizes) > 1:
+        assert sizes[0] == min(sizes) and sizes[1:] == sorted(sizes[1:], reverse=True)
+    first = [group[0] for group in layouts[0]]
+    for a, b in zip(range(1, len(sizes)), range(2, len(sizes))):
+        assert sizes[a] > sizes[b] or first[a] < first[b]
+    # a function of the sizes alone: the same sizes from anywhere, the same order
+    in_tree = sorted(range(len(sizes)), key=lambda b: first[b])
+    order = ddp._pipeline_order([sizes[b] for b in in_tree])
+    assert [in_tree[b] for b in order] == list(range(len(sizes)))
+    if cell.startswith("mistral") :
+        assert [round(n / 1e6, 1) for n in sizes] == [0.0, 268.4, 268.4, 117.4, 117.4, 117.4, 33.6, 33.6, 8.4, 8.4]
+
+
+def test_the_order_of_sizes() -> None:
+    assert ddp._pipeline_order([]) == []
+    assert ddp._pipeline_order([7]) == [0]
+    assert ddp._pipeline_order([5, 5, 5]) == [2, 0, 1]
+    assert ddp._pipeline_order([268, 117, 117, 117, 8, 34, 34, 8, 268, 1]) == [9, 0, 8, 1, 2, 3, 5, 6, 4, 7]
+
+
+@pytest.fixture()
+def asked(monkeypatch):
+    """Every ``copy_to_host_async`` of a jax array, in order: the array's id."""
+    calls: List[int] = []
+    array_type = type(jnp.zeros(1))
+    inner = array_type.copy_to_host_async
+
+    def _recorded(self: Any) -> None:
+        calls.append(id(self))
+        inner(self)
+
+    monkeypatch.setattr(array_type, "copy_to_host_async", _recorded)
+    return calls
+
+
+def test_copies_start_a_window_ahead_of_the_ring(solo, asked, monkeypatch) -> None:
+    """At the submit of the bucket in place b of the plan, the leaves of the
+    buckets in places up to b + W - 1 have been asked for, none of a later
+    one; by the end every jax leaf exactly once and no numpy leaf at all."""
+    monkeypatch.setenv(BUCKET_CAP_MB_ENV, str(2048 / (1 << 20)))
+    rng = np.random.default_rng(50)
+    tree = {f"w{k}": jnp.asarray(rng.standard_normal(100 * (k + 1)).astype(np.float32)) for k in range(9)}
+    tree["host"] = rng.standard_normal(700).astype(np.float32)
+    leaves = jax.tree_util.tree_leaves(tree)
+    seen: List[List[int]] = []
+    inner = solo.manager.allreduce
+
+    def _allreduce(data: Any, *args: Any, **kwargs: Any) -> Work:
+        seen.append(list(asked))
+        return inner(data, *args, **kwargs)
+
+    solo.manager.allreduce = _allreduce  # type: ignore[method-assign]
+    for _ in range(2):  # a cold round trip and a warm one
+        del asked[:], seen[:]
+        out = solo.step(tree)
+        (plan,) = solo.manager._host_buckets._plans.values()
+        ids = [[id(leaves[s.index]) for s in b.slots if s.sharding is not None] for b in plan.buckets]
+        n = len(plan.buckets)
+        assert n >= 6 and len(seen) == n
+        for b in range(n):
+            upto = min(b + ddp._D2H_AHEAD, n)
+            assert seen[b] == [i for group in ids[:upto] for i in group], b
+        jax_leaves = [id(l) for l in leaves if isinstance(l, jax.Array)]
+        assert sorted(asked) == sorted(jax_leaves) and len(set(asked)) == len(asked)
+        for name, leaf in tree.items():
+            np.testing.assert_array_equal(np.asarray(out[name]), np.asarray(leaf) / 2)
+
+
+@pytest.mark.parametrize("kind", ["one_jax_bucket", "numpy_leaves", "fewer_buckets_than_the_window"])
+def test_trees_the_window_does_not_reach_go_through_unchanged(solo, asked, kind) -> None:
+    """One bucket, no jax leaf, or no more buckets than the window: what the
+    parent did (every copy started before the first wait)."""
+    rng = np.random.default_rng(60)
+    f32 = lambda n: rng.standard_normal(n).astype(np.float32)  # noqa: E731
+    tree = {
+        "one_jax_bucket": {"a": jnp.asarray(f32(64)), "b": jnp.asarray(f32(8))},
+        "numpy_leaves": {"a": f32(64), "b": f32(8).astype(np.float64), "c": 3.0},
+        "fewer_buckets_than_the_window": {"a": jnp.asarray(f32(64)), "b": jnp.asarray(f32(8)).astype(jnp.bfloat16)},
+    }[kind]
+    seen: List[int] = []
+    inner = solo.manager.allreduce
+
+    def _allreduce(data: Any, *args: Any, **kwargs: Any) -> Work:
+        seen.append(len(asked))
+        return inner(data, *args, **kwargs)
+
+    solo.manager.allreduce = _allreduce  # type: ignore[method-assign]
+    out = solo.step(tree)
+    n_jax = sum(isinstance(l, jax.Array) for l in jax.tree_util.tree_leaves(tree))
+    buckets = {"one_jax_bucket": 1, "numpy_leaves": 2, "fewer_buckets_than_the_window": 2}[kind]
+    assert buckets <= ddp._D2H_AHEAD and seen == [n_jax] * buckets and len(asked) == n_jax
+    for name, leaf in tree.items():
+        got = out[name]
+        assert isinstance(got, jax.Array) == isinstance(leaf, jax.Array)
+        want = _div(np.asarray(leaf) + np.zeros_like(np.asarray(leaf)), 2)
+        assert _bits(got) == _bits(want)
+    assert _syncs(solo.manager)[-1]["first_submit_s"] > 0.0

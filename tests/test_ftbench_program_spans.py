@@ -22,6 +22,8 @@ LING_READERS = (
 )
 # PR 30: the share of a step's buckets filled in kept memory, from DDP_SYNC
 BUCKET_READERS = ("bucket_warm_pct",)
+# PR 32: whether the ring runs beside the gradients' transfer to the host
+ORDER_READERS = ("sync_first_submit_ms", "ring_beside_d2h_pct")
 
 
 @pytest.mark.parametrize("name", sorted(LATER_READINGS))
@@ -35,9 +37,9 @@ def test_new_readers_are_the_eighteen_benchmark_json_lists():  # noqa: F811
     a later PR appends, so here they are the eighteen before the later ones."""
     with open(os.path.join(theirs.ROOT, "BENCHMARK.json")) as f:
         per_layer = json.load(f)["per_layer"]
-    later = len(LATER_READINGS) + len(LING_READERS) + len(BUCKET_READERS)
+    later = len(LATER_READINGS) + len(LING_READERS) + len(BUCKET_READERS) + len(ORDER_READERS)
     assert [m["name"] for m in per_layer[-later:]] == (
-        list(LATER_READINGS) + list(LING_READERS) + list(BUCKET_READERS)
+        list(LATER_READINGS) + list(LING_READERS) + list(BUCKET_READERS) + list(ORDER_READERS)
     )
     theirs_new = set(theirs.READINGS) | set(theirs.KILL_READINGS) | {"flash_fwd_ms", "flash_dq_ms", "flash_dkv_ms"}
     assert len(theirs_new) == 18
@@ -74,3 +76,92 @@ def test_bucket_warm_pct_on_synthetic_flight_events(events, expects):
     assert read(dict(window=window, flight=[events, [_sync(12.0, 0)]])) == expects
     assert read(dict(window=window, flight=None)) is None
     assert read(dict(window=[[], []], flight=[events, []])) is None
+
+
+def _round_trip(step, at, buckets):
+    """One round trip of replica 0 as ``program_spans.from_profile`` gives
+    it (seconds): ``buckets`` as (megabytes, when its leaves have landed in
+    ms from the round trip's start); a bucket is packed in 2 ms and
+    submitted, the op thread rings (2/3) and divides (1/3) them in order,
+    1 ms a megabyte."""
+    spans = []
+
+    def s(thread, name, start, end, **stats):
+        spans.append(dict(stats, name=name, start=at + start / 1e3, end=at + end / 1e3,
+                          line=("/host:CPU", thread), r=theirs.R0, step=step))
+
+    now, op_free = 10.0, 0.0
+    s(0, "tpuft/ddp/plan", 0.0, now)
+    for b, (mbytes, lands) in enumerate(buckets):
+        s(0, "tpuft/ddp/d2h", now, max(now + 0.1, lands), bucket=b)
+        now = max(now + 0.1, lands)
+        s(0, "tpuft/ddp/pack", now, now + 2.0, bucket=b)
+        s(0, "tpuft/ddp/submit", now + 2.0, now + 2.5, bucket=b)
+        now += 2.5
+        begins = max(now, op_free)
+        op_free = begins + mbytes
+        s(1, "tpuft/comm/op", begins, begins + 2 * (op_free - begins) / 3, k=b)
+        s(1, "tpuft/manager/normalize", begins + 2 * (op_free - begins) / 3, op_free)
+    s(0, program_spans.SYNC, 0.0, now)  # the train thread's piece
+    s(2, program_spans.SYNC, now, op_free + 40.0)  # the gather thread's
+    s(2, "tpuft/ddp/h2d", op_free, op_free + 40.0, bucket=len(buckets) - 1)
+    return spans
+
+
+# the parent's shape (every copy started at once: the ten buckets land
+# together, 1,390 ms in, embed first in line) and the change's (a small
+# bucket first, then by falling size, each landing when its bytes are across)
+MB = [268.0, 117.0, 117.0, 117.0, 8.0, 34.0, 34.0, 8.0, 268.0, 0.02]
+PARENT_SHAPE = [(mb, 1390.0) for mb in MB]
+FLOW = [0.02, 268.0, 268.0, 117.0, 117.0, 117.0, 34.0, 34.0, 8.0, 8.0]
+CHANGE_SHAPE = [(mb, 10.0 + 1.42 * sum(FLOW[: k + 1])) for k, mb in enumerate(FLOW)]
+
+
+@pytest.mark.parametrize(
+    "shape,first_submit_ms,beside_between",
+    [
+        # the rings begin when the first bucket is packed, 23 ms before the
+        # train thread has walked through the other nine that lie there
+        (PARENT_SHAPE, 1392.0, (0.0, 3.0)),
+        # the op thread's 971 ms but the 88 ms it is behind at the last
+        # landing: from the last 117 MB bucket on, 201 ms of ring against
+        # 119 ms of transfer
+        (CHANGE_SHAPE, 12.1, (90.0, 92.0)),
+    ],
+    ids=["parent", "change"],
+)
+def test_order_readers_on_synthetic_round_trips(shape, first_submit_ms, beside_between, monkeypatch):
+    spans = sorted(
+        _round_trip(5, 1.0, shape) + _round_trip(6, 4.0, shape),
+        key=lambda s: s["start"],
+    )
+    monkeypatch.setattr(program_spans, "load", lambda bench_dir=None: spans)
+    sources = dict(trace=None, replicas=2)
+    first = spec.load_metric("sync_first_submit_ms", theirs.BENCH_DIR).read  # noqa: F405
+    beside = spec.load_metric("ring_beside_d2h_pct", theirs.BENCH_DIR).read  # noqa: F405
+    assert first(sources) == pytest.approx(first_submit_ms, abs=1e-6)
+    # by hand: everything the op thread did before the last landing
+    trip = [s for s in spans if s["step"] == 5]
+    landed = max(s["end"] for s in trip if s["name"] == "tpuft/ddp/d2h")
+    ops = [s for s in trip if s["name"] in ("tpuft/comm/op", "tpuft/manager/normalize")]
+    total = sum(s["end"] - s["start"] for s in ops)
+    assert total == pytest.approx(0.97102, abs=1e-5)
+    beside_pct = 100 * sum(max(0.0, min(s["end"], landed) - s["start"]) for s in ops) / total
+    assert beside_between[0] < beside_pct < beside_between[1]
+    assert beside(sources) == pytest.approx(beside_pct, abs=1e-6)
+    # a program without spans, and one whose round trips hold no such stage
+    monkeypatch.setattr(program_spans, "load", lambda bench_dir=None: [])
+    assert first(sources) is None and beside(sources) is None
+    bare = [s for s in spans if s["name"] in (program_spans.SYNC, "tpuft/ddp/plan")]
+    monkeypatch.setattr(program_spans, "load", lambda bench_dir=None: bare)
+    assert first(sources) is None and beside(sources) is None
+
+
+def test_order_readers_on_the_synthetic_planes(run, monkeypatch):  # noqa: F405
+    """Theirs is a two-bucket round trip in which the first ring runs beside
+    the second bucket's transfer: submit 220 ms in; of the op thread's 220 ms
+    the first ring's 60 and 32 of its division lie before the last landing."""
+    monkeypatch.setattr(program_spans, "load", lambda bench_dir=None: run["spans"])
+    for sources in (run["sources"], dict(run["sources"], trace=None)):
+        assert spec.load_metric("sync_first_submit_ms", theirs.BENCH_DIR).read(sources) == pytest.approx(220.0, abs=1e-6)  # noqa: F405
+        assert spec.load_metric("ring_beside_d2h_pct", theirs.BENCH_DIR).read(sources) == pytest.approx(100 * 92 / 220, abs=1e-6)  # noqa: F405

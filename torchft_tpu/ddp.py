@@ -3,10 +3,19 @@
 The reference hooks torch DDP's bucket reducer into ``manager.allreduce``
 (``torchft/ddp.py:31-78``).  JAX has no module/buckets: gradients are a
 pytree produced by ``jax.grad`` inside a compiled step.  The replica-dim
-average runs host-side — leaves are fetched to host, flattened into one
-contiguous buffer per dtype (the bucketization DDP gets from its reducer),
+average runs host-side — leaves are fetched to host, flattened into
+contiguous buckets per dtype (the bucketization DDP gets from its reducer),
 ring-allreduced over DCN/TCP, and pushed back to device with the original
 shardings.  Compiled programs never see the replica count (SURVEY.md §7).
+
+The buckets cross to the host in an order made once per tree signature
+(:func:`_pipeline_order`: the smallest first, then by falling size) and a
+few at a time (``_D2H_AHEAD``): a bucket's ring is submitted as soon as it
+has landed and runs on the communicator's op thread while the next buckets
+still cross, so transfer and ring are two stages of a pipeline and not two
+stretches in a row.  The order shapes the collective sequence, so like the
+bucket cap it must agree across replicas; it follows from the tree
+signature and the cap alone.
 """
 
 from __future__ import annotations
@@ -105,6 +114,15 @@ def _assemble_sharded(
 # does not own two under ``quantize_outer``; streamed LocalSGD one a fragment.
 _KEPT_SIGNATURES = 4
 _KEPT_SETS = 2
+# A bucket's device-to-host copies are started only while the train thread
+# waits for a bucket at most this many places before it (itself counted): the
+# one it waits for and the next.  With every leaf's copy started at once the
+# runtime lands them all together and the rings wait for the whole gradient;
+# one at a time, a transfer by itself runs at two thirds of the speed of two
+# side by side; with three the first large bucket lands later than with two
+# and the op thread's whole work follows that landing (PERF.md section 6,
+# PR 32: the readings that chose 2).
+_D2H_AHEAD = 2
 
 
 @dataclasses.dataclass(frozen=True)
@@ -142,8 +160,10 @@ class _Bucket:
 
 @dataclasses.dataclass
 class _Plan:
-    """One tree signature's buckets, and the sets of flat host buffers (one a
-    bucket) that were filled before and that nothing reads or writes now."""
+    """One tree signature's buckets in the order they cross (see
+    :func:`_pipeline_order`), and the sets of flat host buffers (one a bucket,
+    in that order) that were filled before and that nothing reads or writes
+    now."""
 
     buckets: List[_Bucket]
     nbytes: int  # what crosses the wire a round trip
@@ -220,12 +240,33 @@ def _leaf_signature(leaf: Any) -> Hashable:
     return (tuple(leaf.shape), leaf.dtype.name, None)
 
 
+def _pipeline_order(nbytes: List[int]) -> List[int]:
+    """The order in which buckets of these sizes cross to the host and are
+    rung: a pure function of the sizes (ties by place in the tree), so every
+    replica with the same tree and cap derives the same one.
+
+    Transfer and ring are the two stages of a flow shop whose times both
+    follow the bytes.  The transfer is the slower a byte, so the whole takes
+    the transfers' sum plus the ring of the LAST bucket to land: buckets go
+    by falling size, and the tail is the smallest one's ring (by rising size
+    the tail is the largest one's).  The smallest of all goes first instead:
+    it costs the transfer nothing, its copy waits for the gradient program
+    in the large one's stead, and the op thread rings it, both replicas
+    meeting in a ring, while the first large one crosses."""
+    falling = sorted(range(len(nbytes)), key=lambda b: (-nbytes[b], b))
+    return falling[-1:] + falling[:-1]
+
+
 def _make_plan(leaves: List[Any], bucket_cap: int) -> _Plan:
     """Bucket by dtype (each dtype needs its own ring), then split large
-    buckets at ``bucket_cap`` bytes; each is submitted as its own collective:
-    the op thread rings bucket k while the train thread fetches and fills
-    bucket k+1 — transfer/communication pipelining, the reference's
-    bucket_cap_mb (``local_sgd.py:28,477-566``) in jax form."""
+    buckets at ``bucket_cap`` bytes, and put the buckets in
+    :func:`_pipeline_order`.  Each is submitted as its own collective as soon
+    as its leaves have landed and are packed, while the copies of the next
+    ``_D2H_AHEAD - 1`` are under way and the later ones not yet started: the
+    op thread rings bucket k while bucket k+1 crosses — transfer /
+    communication pipelining, the reference's bucket_cap_mb
+    (``local_sgd.py:28,477-566``) in jax form.  The order, like the cap, shapes
+    the collective sequence and follows from the tree signature alone."""
     order: Dict[str, List[int]] = {}
     described: List[Tuple[Any, int, Tuple[int, ...], Any]] = []
     for i, leaf in enumerate(leaves):
@@ -271,10 +312,21 @@ def _make_plan(leaves: List[Any], bucket_cap: int) -> _Plan:
             )
             bucket.size += size
         buckets.append(bucket)
+    order = _pipeline_order([b.size * b.dtype.itemsize for b in buckets])
+    buckets = [buckets[b] for b in order]
     return _Plan(
         buckets=buckets,
         nbytes=sum(b.size * b.dtype.itemsize for b in buckets),
     )
+
+
+def _start_copies(leaves: List[Any], bucket: _Bucket) -> None:
+    """Start the device-to-host copies of one bucket's leaves (on a leaf that
+    is not fully addressable: of its addressable shards, which are what
+    :func:`_to_host` reads)."""
+    for slot in bucket.slots:
+        if slot.sharding is not None:
+            leaves[slot.index].copy_to_host_async()
 
 
 def _to_host(leaf: Any, slot: _Slot) -> List[np.ndarray]:
@@ -372,7 +424,10 @@ def allreduce_pytree(
     obs_spans.bind(recorder)  # the caller is this replica's train thread
     sync_span = obs_span("tpuft/ddp/allreduce_pytree", flight=FlightEvent.DDP_SYNC)
     sync_span.__enter__()
-    stage_s = {"plan_s": 0.0, "d2h_s": 0.0, "pack_s": 0.0, "ring_wait_s": 0.0, "h2d_s": 0.0}
+    stage_s = {
+        "plan_s": 0.0, "d2h_s": 0.0, "pack_s": 0.0, "ring_wait_s": 0.0, "h2d_s": 0.0,
+        "first_submit_s": 0.0,  # from the round trip's start to the first bucket's submit
+    }
 
     store = _bucket_store(manager)
     works: List[Work] = []
@@ -380,11 +435,6 @@ def allreduce_pytree(
     kept: Optional[List[np.ndarray]] = None
     try:
         with obs_span("tpuft/ddp/plan") as stage:
-            # Kick off every device→host transfer asynchronously up front so
-            # DMA overlaps the bucket assembly and the first ring.
-            for leaf in leaves:
-                if isinstance(leaf, jax.Array):
-                    leaf.copy_to_host_async()
             bucket_cap = _bucket_cap_bytes()
             plan = store.plan(
                 (treedef, bucket_cap, tuple(_leaf_signature(l) for l in leaves)),
@@ -392,16 +442,24 @@ def allreduce_pytree(
                 bucket_cap,
             )
         stage_s["plan_s"] = stage.duration_s
+        asked = 0  # buckets whose copies to the host have been started
         for b, bucket in enumerate(plan.buckets):
-            # waits async copies; sharded leaves contribute local shards only
             with obs_span("tpuft/ddp/d2h", bucket=b) as stage:
+                # this bucket's copies and the next's are under way, no later
+                # one's: they land in the plan's order, and bucket b's ring
+                # runs on the op thread while buckets b+1 .. still cross
+                while asked < min(b + _D2H_AHEAD, len(plan.buckets)):
+                    _start_copies(leaves, plan.buckets[asked])
+                    asked += 1
+                # waits for the copies; sharded leaves contribute local shards only
                 hosts = [_to_host(leaves[slot.index], slot) for slot in bucket.slots]
             stage_s["d2h_s"] += stage.duration_s
             with obs_span("tpuft/ddp/pack", bucket=b) as stage:
                 if b == 0:
                     # as late as can be: the set of the step before comes
-                    # back when its leaves are on the device again, and by
-                    # now this step's first leaves have come the other way
+                    # back when its restored leaves are ready on the device,
+                    # and that step's vote and update, this step's quorum and
+                    # gradient program and the first bucket's copy lie between
                     kept = store.take(plan)
                 flat = np.empty(bucket.size, dtype=bucket.dtype) if kept is None else kept[b]
                 for slot, parts in zip(bucket.slots, hosts):
@@ -416,7 +474,9 @@ def allreduce_pytree(
             # the restore is done, so the ring reduces straight into it (no
             # defensive copy; on this host class that copy costs as much as
             # half the ring itself)
-            with obs_span("tpuft/ddp/submit", bucket=b):
+            with obs_span("tpuft/ddp/submit", bucket=b) as stage:
+                if b == 0:
+                    stage_s["first_submit_s"] = stage.t0 - sync_span.t0
                 works.append(
                     manager.allreduce(
                         flat,

@@ -460,9 +460,9 @@ class LocalSGD:
         (``local_sgd.py:129-172``).
 
         Routed through ``ddp.allreduce_pytree``'s bucketed pipeline — the
-        same path DiLoCo fragments ride: device→host copies start
-        asynchronously up front (``copy_to_host_async``) and overlap bucket
-        assembly, each bucket's ring runs while the next bucket stages, and
+        same path DiLoCo fragments ride: a bucket's device→host copies start
+        asynchronously (``copy_to_host_async``) a bucket ahead of the one
+        being waited for, each bucket's ring runs while the next ones cross, and
         the rings reduce ``in_place`` in the staging buffers (the live
         params are never aliased).  The old path shipped the whole model as
         one blocking collective with synchronous host copies."""
