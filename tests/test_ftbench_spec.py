@@ -24,6 +24,12 @@ PUBLISHED_WIDTHS = {
         head_dim=128, kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
         num_experts_per_tok=8,
     ),
+    "https://huggingface.co/Kwai-Keye/Keye-VL-2.0-30B-A3B/blob/main/config.json": dict(
+        hidden_size=2048, intermediate_size=6144, moe_intermediate_size=768, num_attention_heads=32,
+        num_key_value_heads=4, head_dim=128, num_experts_per_tok=8,
+        sa_config=dict(indexer_head_dim=64, indexer_num_heads=16, indexer_num_kv_heads=1, kv_chunk_size=512,
+                       q_chunk_size=512, topk=2048),
+    ),
 }
 
 

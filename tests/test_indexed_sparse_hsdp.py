@@ -1,0 +1,191 @@
+"""``IndexedSparseMoE`` under ``HSDPTrainer`` and a Manager: a model that
+reports its step (loads, the index's loss, keys a query) WITHOUT state the
+optimizer does not own.  Steps commit, the summary rides the loss's one
+transfer into MOE_ROUTE, replicas stay bit-equal through a kill and a live
+heal.  Toy widths, float32, the CPU's devices."""
+
+import threading
+from typing import Any, Dict, List
+
+import jax
+import numpy as np
+import optax
+
+from torchft_tpu import tier as tier_mod
+from torchft_tpu.communicator import DummyCommunicator
+from torchft_tpu.manager import Manager
+from torchft_tpu.models.indexed_sparse_moe import SUMMARY_FIELDS, IndexedSparseMoE, indexed_sparse_debug
+from torchft_tpu.parallel import hsdp
+from torchft_tpu.parallel.hsdp import HSDPTrainer, fsdp_shardings, make_grad_step
+from torchft_tpu.parallel.mesh import make_mesh
+
+from tests.test_manager import MemoryTransport, StubClient, _quorum_result
+
+SEQ = 32  # twice the toy index's 16 keys
+
+
+def _batch(model, mesh, seed, rows=1):
+    tokens = np.random.default_rng(seed).integers(0, model.config.vocab_size, (rows, SEQ)).astype(np.int32)
+    batch_sh = fsdp_shardings(model, mesh)[1]
+    return tuple(jax.device_put(b, sh) for b, sh in zip((tokens, np.roll(tokens, -1, axis=1)), batch_sh))
+
+
+def _stub_trainer(steps: int):
+    client = StubClient()
+    client.quorum_results.extend(_quorum_result() for _ in range(steps))
+    manager = Manager(
+        comm=DummyCommunicator(), load_state_dict=None, state_dict=None, min_replica_size=1,
+        checkpoint_transport=MemoryTransport(), _manager_client=client, rank=0, world_size=1,
+    )
+    model = IndexedSparseMoE(indexed_sparse_debug())
+    mesh = make_mesh(fsdp=1, devices=jax.devices()[:1])
+    trainer = HSDPTrainer(model, optax.adamw(1e-3), mesh, manager, key=jax.random.PRNGKey(0))
+    return model, mesh, manager, trainer
+
+
+def _routes(manager) -> List[Dict[str, Any]]:
+    return [e for e in manager._flight.snapshot() if e["name"] == "MOE_ROUTE"]
+
+
+def test_a_model_without_optimizer_free_state_still_reports_its_step():
+    from torchft_tpu.models.ling_hybrid import LingHybrid, ling_debug
+    from torchft_tpu.models.llama import Llama, llama_debug
+
+    model, mesh, manager, trainer = _stub_trainer(2)
+    # what the trainer asks of the three models: Llama nothing, Ling both, this one the report alone
+    assert not hsdp._reports(Llama(llama_debug())) and hsdp._state_mask(Llama(llama_debug())) is None
+    assert hsdp._reports(LingHybrid(ling_debug())) and hsdp._state_mask(LingHybrid(ling_debug())) is not None
+    assert hsdp._reports(model) and trainer._state_mask is None
+    batch = _batch(model, mesh, 1)
+    before = jax.tree_util.tree_map(np.asarray, trainer.holder["params"])
+    report, grads = make_grad_step(model, mesh)(trainer.holder["params"], batch)
+    layers = model.config.n_layers
+    assert report.shape == (1 + len(SUMMARY_FIELDS) * layers,)  # the loss and the summary, ONE array
+    assert jax.tree_util.tree_structure(grads) == jax.tree_util.tree_structure(before)
+    loss, committed = trainer.train_step(batch)
+    assert committed and loss == float(report[0])
+    assert loss > float(model.loss(before, batch))  # the objective: the index's and the balance loss on top
+    events = _routes(manager)
+    assert len(events) == 1
+    stats = model.summary_stats(np.asarray(report[1:]))
+    for name in SUMMARY_FIELDS:
+        assert events[0][name] == stats[name] and len(events[0][name]) == layers
+    assert events[0]["keys_per_query"] == [(16 * 17 / 2 + 16 * 16) / SEQ] * layers
+    assert all(kl > 0 for kl in events[0]["index_kl"])
+    assert all(rows > 0 for rows in events[0]["rows_here"])
+    # every leaf moved, the index's by its own loss
+    after = jax.tree_util.tree_map(np.asarray, trainer.holder["params"])
+    for (path, a), b in zip(jax.tree_util.tree_flatten_with_path(before)[0], jax.tree_util.tree_leaves(after)):
+        assert np.abs(a - b).max() > 0, jax.tree_util.keystr(path)
+
+
+def test_uncommitted_step_changes_nothing_and_records_nothing():
+    model, mesh, manager, trainer = _stub_trainer(2)
+    batch = _batch(model, mesh, 2)
+    before = jax.tree_util.tree_map(np.asarray, trainer.holder)
+    manager.should_commit = lambda *a, **k: False  # the fleet votes the step down
+    loss, committed = trainer.train_step(batch)
+    assert not committed and np.isfinite(loss)
+    for a, b in zip(jax.tree_util.tree_leaves(before), jax.tree_util.tree_leaves(trainer.holder)):
+        np.testing.assert_array_equal(a, np.asarray(b))
+    assert not _routes(manager)
+
+
+TOTAL, KILL_AT, QUANTIZED_FROM = 8, 4, 2
+
+
+class _Killed(Exception):
+    pass
+
+
+def test_two_replicas_commit_agree_bit_for_bit_and_heal_a_killed_one():
+    """Two replica groups as threads, a lighthouse, real Managers, a batch
+    each.  Steps 2 and 3 run the int8 wire.  Replica 1 dies at step 4, comes
+    back with other weights and heals from the survivor."""
+    devices = jax.devices()[:2]
+    tier = tier_mod.default_tier()
+    lighthouse = tier_mod.make_lighthouse(
+        bind="127.0.0.1:0", min_replicas=1, join_timeout_ms=200, quorum_tick_ms=20,
+        heartbeat_timeout_ms=2000, tier=tier,
+    )
+    managers: List[Manager] = []
+    errors: List[BaseException] = []
+    seen: List[Dict[int, str]] = [{}, {}]  # replica -> fleet step -> digest of its parameters
+    routes: List[int] = [0, 0]
+    rejoined = threading.Event()
+
+    def digest(params) -> str:
+        import hashlib
+
+        h = hashlib.sha256()
+        for leaf in jax.tree_util.tree_leaves(params):
+            h.update(np.asarray(leaf).tobytes())
+        return h.hexdigest()
+
+    def replica(idx: int) -> None:
+        mesh = make_mesh(fsdp=1, devices=[devices[idx]])
+        model = IndexedSparseMoE(indexed_sparse_debug())
+        batch = _batch(model, mesh, 100 + idx)
+        life = 0
+        while True:
+            manager = Manager(
+                comm=tier_mod.make_communicator(timeout_s=30.0, tier=tier),
+                load_state_dict=None, state_dict=None, min_replica_size=1,
+                timeout=30.0, quorum_timeout=30.0, connect_timeout=30.0,
+                replica_id=f"indexed_{idx}", lighthouse_addr=lighthouse.local_address(),
+                server_cls=tier_mod.manager_server_cls(tier),
+            )
+            managers.append(manager)
+            trainer = HSDPTrainer(
+                model, optax.adamw(1e-3), mesh, manager, key=jax.random.PRNGKey(10 * life + 1)
+            )
+            if life:
+                rejoined.set()
+            try:
+                stalled = 0
+                while (step := manager.current_step()) < TOTAL:
+                    if life == 0 and idx == 1 and step >= KILL_AT:
+                        raise _Killed()
+                    if idx == 0 and step == KILL_AT + 1:
+                        assert rejoined.wait(timeout=60.0), "the killed replica never came back"
+                    trainer.quantize_outer = QUANTIZED_FROM <= step < KILL_AT
+                    loss, committed = trainer.train_step(batch)
+                    assert np.isfinite(loss)
+                    stalled = 0 if committed else stalled + 1
+                    assert committed or (step >= KILL_AT and stalled < 3), manager.errored()
+                    if committed and manager.num_participants() == 2:
+                        seen[idx][manager.current_step()] = digest(trainer.holder["params"])
+                routes[idx] += len(_routes(manager))
+                return
+            except _Killed:
+                life += 1
+                routes[idx] += len(_routes(manager))
+                manager.shutdown()
+                managers.remove(manager)
+
+    def guarded(idx: int) -> None:
+        try:
+            with jax.default_device(devices[idx]):
+                replica(idx)
+        except BaseException as e:  # noqa: BLE001 — raised again below
+            errors.append(e)
+
+    threads = [threading.Thread(target=guarded, args=(i,), daemon=True) for i in range(2)]
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=300.0)
+        assert not errors, errors
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        for m in managers:
+            m.shutdown()
+        lighthouse.shutdown()
+    shared = sorted(set(seen[0]) & set(seen[1]))
+    # steps with both in the quorum: before the kill, and after the heal
+    assert any(s <= KILL_AT for s in shared) and any(s > KILL_AT + 1 for s in shared), shared
+    for step in shared:
+        assert seen[0][step] == seen[1][step], f"step {step}"
+    assert len(set(seen[0].values())) == len(seen[0])  # and the parameters moved every step
+    assert routes[0] >= TOTAL - 1 and routes[1] >= KILL_AT  # an event a committed step, each life

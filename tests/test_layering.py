@@ -227,3 +227,17 @@ def test_the_packages_edge() -> None:
         if base.split(".")[0] in OUTSIDE
     ]
     assert not outward, "the package imports what lives beside it:\n" + "\n".join(outward)
+
+
+@pytest.mark.parametrize(
+    "module,row,may_import",
+    [
+        ("ops.indexed_attention", "store-kernels-data", set()),
+        ("models.indexed_sparse_moe", "compiled-step-models", {"ops.indexed_attention", "parallel.moe", "models.llama"}),
+    ],
+)
+def test_indexed_attention_is_model_code_over_kernels(module: str, row: str, may_import: set) -> None:
+    """PR 33's two modules: the kernels in the kernels' row, the model in
+    the models', importing kernels and model code and nothing of the Manager."""
+    assert [ROWS[i][0] for i in _rows_of(module)] == [row]
+    assert {target for importer, _line, target in _inner_edges() if importer == module} == may_import

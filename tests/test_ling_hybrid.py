@@ -92,7 +92,7 @@ def test_logits_loss_and_every_gradient_agree_with_the_reference(path, reference
         want_loss = float(jnp.mean(want["nll"]) + 0.3 * jnp.mean(want["mtp_nll"]))
         assert float(jax.jit(model.loss)(params, batch)) == pytest.approx(want_loss, abs=1e-5)
         assert 0.3 * float(jnp.mean(want["mtp_nll"])) > 1.0  # the MTP term is there to be missed
-        (objective, signal), grads = jax.jit(jax.value_and_grad(model.objective, has_aux=True))(params, batch)
+        (objective, (signal, _)), grads = jax.jit(jax.value_and_grad(model.objective, has_aux=True))(params, batch)
         assert float(objective) == pytest.approx(float(want_objective), abs=1e-5)
         assert float(want["balance"]) > 1e-4
     # the signal: every router's load, in the order of the state leaves
@@ -143,5 +143,5 @@ def test_bias_goes_up_for_the_idle_and_down_for_the_busy():
     new = model.advance_state(bias, load)
     np.testing.assert_allclose(np.asarray(new[0][0]), 1e-3 * np.sign(7.5 - np.arange(16.0)))
     assert float(new[1][0]) == pytest.approx(-1e-3) and float(new[1][1]) == pytest.approx(1e-3)
-    stats = model.route_stats(np.asarray(model.route_summary(load)))
+    stats = model.summary_stats(np.asarray(model.route_summary(load)))
     assert stats["rows_here"] == [22.0, 22.0, 12.0] and stats["load_max"] == [7.0, 7.0, 3.0]

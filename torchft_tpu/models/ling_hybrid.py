@@ -27,8 +27,9 @@ that no gradient moves: after a committed step it goes up by
 down for one that saw more (DeepSeek-V3's balancing without an auxiliary
 loss).  ``HSDPTrainer`` asks three things of a model with such state:
 ``state_mask()`` (which leaves), ``objective(params, batch)`` (the scalar it
-differentiates and, for every such leaf, the step's signal: here the tokens
-each expert was chosen by) and ``advance_state(state, signal)``.  The signal
+differentiates, for every such leaf the step's signal, here the tokens each
+expert was chosen by, and the step's summary for the flight recorder) and
+``advance_state(state, signal)``.  The signal
 rides the leaf's own slot of the gradient tree through the replica-dimension
 average, so replicas stay bit-equal.
 
@@ -44,7 +45,6 @@ from __future__ import annotations
 
 import functools
 import logging
-import os
 from dataclasses import dataclass, replace
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -316,7 +316,7 @@ class LingHybrid:
         return jnp.stack([here.sum(axis=1), here.max(axis=1), here.mean(axis=1)], axis=1)
 
     @staticmethod
-    def route_stats(summary: np.ndarray) -> Dict[str, List[float]]:
+    def summary_stats(summary: np.ndarray) -> Dict[str, List[float]]:
         """:meth:`route_summary` on the host, as the flight event's detail."""
         rows, largest, mean = np.asarray(summary, np.float64).reshape(-1, 3).T
         return dict(rows_here=rows.tolist(), load_max=largest.tolist(), load_mean=mean.tolist())
@@ -326,25 +326,13 @@ class LingHybrid:
     # ------------------------------------------------------------------
 
     def _kernel_refusal(self, seq: int) -> Optional[str]:
-        """Why the Mosaic kernels do NOT apply, or None when they do.
-        ``TORCHFT_FLASH`` = 1 forces them (interpret mode off the TPU), 0
-        kills them, unset: on a TPU, one chip a group."""
-        env = os.environ.get("TORCHFT_FLASH", "")
-        if env == "0":
-            return "TORCHFT_FLASH=0"
+        """Why the Mosaic kernels do NOT apply, or None when they do."""
         block_q, block_k = Llama._flash_blocks(seq)
         chunk = min(KDA_CHUNK, seq)
+        shape_refusal = None
         if seq < 32 or seq % 8 or seq % block_q or seq % block_k or seq % chunk or chunk % min(32, chunk):
-            return f"seq={seq} does not divide into the blocks ({block_q}, {block_k}) and chunks of {chunk}"
-        if env == "1":
-            return None
-        backend = Llama._assumed_backend()
-        if backend != "tpu":
-            return f"backend is {backend}, not tpu"
-        mesh_size = 1 if self.mesh is None else int(np.prod(list(self.mesh.shape.values())))
-        if mesh_size > 1:
-            return f"a group of {mesh_size} chips: the kernels are one chip's"
-        return None
+            shape_refusal = f"seq={seq} does not divide into the blocks ({block_q}, {block_k}) and chunks of {chunk}"
+        return Llama._one_chip_refusal(shape_refusal, self.mesh)
 
     def _record_path(self, path: str) -> None:
         if path != self.attention_path:
@@ -504,12 +492,13 @@ class LingHybrid:
 
     def objective(
         self, params: Dict[str, Any], batch: Tuple[jax.Array, jax.Array]
-    ) -> Tuple[jax.Array, List[jax.Array]]:
+    ) -> Tuple[jax.Array, Tuple[List[jax.Array], jax.Array]]:
         """What a training step differentiates (``loss`` and the routers'
-        balance loss) and, for every leaf of ``state_mask``, the step's
-        signal: the tokens each expert was chosen by."""
+        balance loss), for every leaf of ``state_mask`` the step's signal
+        (the tokens each expert was chosen by) and the step's summary
+        (:meth:`route_summary` of this replica's own signal)."""
         loss, balance, signal = self._losses(params, batch)
-        return loss + balance, signal
+        return loss + balance, (signal, self.route_summary(signal))
 
     def num_params(self) -> int:
         return sum(int(np.prod(s.shape)) for s in jax.tree_util.tree_leaves(self._shapes))

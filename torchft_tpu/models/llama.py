@@ -261,6 +261,28 @@ class Llama:
             min(seq, _env("TORCHFT_FLASH_BLOCK_K")),
         )
 
+    @staticmethod
+    def _one_chip_refusal(shape_refusal: Optional[str], mesh: Optional[Any]) -> Optional[str]:
+        """Why kernels that are one chip's do NOT apply, or None when they
+        do, for the models whose groups are one chip (``shape_refusal``:
+        what the model's own kernels say of the sequence length).
+        ``TORCHFT_FLASH`` = 1 forces them (interpret mode off the TPU), 0
+        kills them, unset: on a TPU, one chip a group."""
+        env = os.environ.get("TORCHFT_FLASH", "")
+        if env == "0":
+            return "TORCHFT_FLASH=0"
+        if shape_refusal:
+            return shape_refusal
+        if env == "1":
+            return None
+        backend = Llama._assumed_backend()
+        if backend != "tpu":
+            return f"backend is {backend}, not tpu"
+        mesh_size = 1 if mesh is None else int(np.prod(list(mesh.shape.values())))
+        if mesh_size > 1:
+            return f"a group of {mesh_size} chips: the kernels are one chip's"
+        return None
+
     def _flash_refusal(self, seq: int) -> Optional[str]:
         """Why the fused Pallas kernel (``ops/flash_attention.py``) does NOT
         apply at this sequence length, or None when it does: TPU backend (or

@@ -114,9 +114,11 @@ class FlightEvent(enum.IntEnum):
     # (detail: bytes, d2h_s in device-to-host of leaves, write_s blocked
     # writing the socket)
     # -- the model's own counters (python only) ------------------------------
-    MOE_ROUTE = 31  # one committed step of a model with routed experts
-    # (HSDPTrainer; detail, expert layer by expert layer: rows_here routed to
-    # the experts this chip holds, load_max and load_mean over them)
+    MOE_ROUTE = 31  # one committed step of a model that reports its step
+    # (HSDPTrainer, from the model's own summary; detail, expert layer by
+    # expert layer: rows_here routed to the experts this chip holds, load_max
+    # and load_mean over them; with a learned index also index_kl and
+    # keys_per_query)
 
 
 # data-plane events the native tier may record; the ftlint checker requires

@@ -1,0 +1,715 @@
+"""Attention over keys that a learned index picks (Pallas, TPU), forward and
+backward, with the index's own loss.
+
+The mechanism is DeepSeek-V3.2's sparse attention: a light index scores every
+earlier position for every query, ``I[t, s] = sum_j w[t, j] * relu(qI[t, j] .
+kI[s])`` over ``J`` small heads and ONE key head, the ``topk`` best positions
+``s <= t`` of a row are its key set ``S_t`` (all of them while ``t < topk``;
+ties go to the lower ``s``), attention runs over ``S_t`` alone, and the index
+learns from ``L_I = sum_t KL(p[t, .] || softmax_{S_t} I[t, .])`` where ``p``
+is the head-mean of the attention's own softmax over ``S_t`` and carries no
+gradient.  Nothing here differentiates the selection.
+
+**The form.**  A key set is held as bits: ``mask[b, w, t, j]`` has bit ``i``
+set where row ``t`` picked position ``(32 w + i) * block_k + j``, so the tile
+of key block ``ki`` is ``(mask[b, ki // 32, rows, :] >> (ki % 32)) & 1``, an
+elementwise read with no shuffle, and 32 MB hold 16,384 rows of 16,384
+positions.  The kernels walk the causal blocks as a flash kernel does and
+apply that tile; eight query heads of a KV head are stacked into one
+``[8 * block_q, D]`` operand, so a key block and a mask tile are read once
+for the group.  No ``[S, S]`` array is made: the index's float32 scores exist
+for one chunk of ``chunk`` query rows at a time.
+
+Six ``pallas_call`` names, which the benchmark's readers find in a trace:
+
+- ``dsa_index``: the scores of one chunk of rows against every causal key
+  block, float32, ``[nk, chunk, block_k]``;
+- ``dsa_select``: the exact ``min(t + 1, topk)`` best of every row by
+  bisection on the scores' bit patterns (32 passes for the value, then
+  ``log2 S`` for the cut among equal scores), the bits, the row's
+  ``logsumexp`` of its picked scores and its number of keys;
+- ``dsa_attn_fwd``, ``dsa_attn_dq``, ``dsa_attn_dkv``: the attention;
+- ``dsa_probs``: ``L_I``, a row at a time, from the attention's ``lse``,
+  and in the same pass its gradient to ``qI``, ``kI`` and ``w`` (the
+  backward pass scales it by the loss's cotangent).
+
+:func:`indexed_attention_plain` is the same mathematics in ``jax.numpy`` with
+dense ``[S, S]`` arrays: the path off the TPU and the tests' oracle.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import NamedTuple, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+_NEG_INF = -1e30
+_LANES = 128
+_ROW_LANES = 8  # rowwise outputs carry a trailing 8-lane dim (ops/flash_attention.py)
+_INT_MIN = np.int32(-(2**31))
+_VMEM_LIMIT = 100 * 1024 * 1024
+
+
+class Blocks(NamedTuple):
+    """Tile sizes: ``q`` query rows a head in the attention and ``L_I``
+    kernels, ``k`` keys a block (and the width of a mask tile), ``chunk``
+    query rows whose index scores exist at a time, ``s`` of them in one
+    step of the selection."""
+
+    q: int = 128
+    k: int = 512
+    chunk: int = 512
+    s: int = 64
+
+    def fit(self, seq: int) -> "Blocks":
+        k = min(self.k, seq)
+        chunk = min(self.chunk, seq)
+        return Blocks(min(self.q, seq), k, chunk, min(self.s, chunk))
+
+    def refusal(self, seq: int) -> str:
+        """Why ``seq`` does not divide into these blocks, or ''."""
+        b = self.fit(seq)
+        if seq % b.q or seq % b.k or seq % b.chunk or b.chunk % b.s or b.q % 8 or b.s % 8:
+            return f"seq={seq} does not divide into blocks {tuple(b)}"
+        return ""
+
+
+def _params(*semantics: str) -> pltpu.CompilerParams:
+    return pltpu.CompilerParams(dimension_semantics=semantics, vmem_limit_bytes=_VMEM_LIMIT)
+
+
+def _dot_t(a: jax.Array, b: jax.Array) -> jax.Array:
+    """``a @ b.T`` with float32 accumulation."""
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+
+
+def _dot_0(a: jax.Array, b: jax.Array) -> jax.Array:
+    """``a.T @ b`` with float32 accumulation."""
+    return jax.lax.dot_general(a, b, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+
+
+def _row_lanes(x: jax.Array) -> jax.Array:
+    """A value a row as the kernels take it: ``[..., 8]``, the lanes alike."""
+    return jnp.broadcast_to(x[..., None], x.shape + (_ROW_LANES,))
+
+
+def _tile_bits(mask_ref, ki: jax.Array) -> jax.Array:
+    """The picked positions of key block ``ki`` as a boolean ``[bq, bk]``."""
+    return ((mask_ref[0, 0] >> (ki % 32)) & 1) != 0
+
+
+# ---------------------------------------------------------------------------
+# the index: scores of one chunk of rows, and the selection
+# ---------------------------------------------------------------------------
+
+
+def _index_scores(q_ref, w_ref, k, heads: int) -> jax.Array:
+    """``sum_j w[:, j] * relu(qI[:, j] @ k.T)``, float32 ``[rows, bk]``."""
+    acc = None
+    for j in range(heads):
+        term = w_ref[0, j][:, :1] * jnp.maximum(_dot_t(q_ref[0, j], k), 0.0)
+        acc = term if acc is None else acc + term
+    return acc
+
+
+def _index_kernel(c_ref, q_ref, w_ref, k_ref, out_ref, *, heads, chunk, block_k):
+    ki = pl.program_id(1)
+    live = ki * block_k <= c_ref[0] * chunk + chunk - 1
+
+    @pl.when(live)
+    def _():
+        out_ref[0, 0] = _index_scores(q_ref, w_ref, k_ref[0], heads)
+
+
+def _select_kernel(
+    c_ref, s_ref, mask_ref, stat_ref, key_scr, *, topk, chunk, block_s, block_k, words, pos_bits
+):
+    row0 = c_ref[0] * chunk + pl.program_id(1) * block_s
+    n_live = (row0 + block_s - 1) // block_k + 1
+    shape = (block_s, block_k)
+    rows = row0 + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    col0 = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    want = jnp.minimum(rows[:, :1] + 1, topk)  # keys this row picks
+
+    def total(per_block) -> jax.Array:
+        """``sum`` over the live blocks and their columns of an int32 tile."""
+        acc = jax.lax.fori_loop(
+            0, n_live, lambda j, a: a + per_block(j), jnp.zeros(shape, jnp.int32)
+        )
+        return jnp.sum(acc, axis=1, keepdims=True)
+
+    # float32 order as signed-integer order; positions after the row's own
+    # sort below every score.  -0.0 counts as 0.0.
+    def to_keys(j, best):
+        s = s_ref[0, j]
+        s = jnp.where(s == 0.0, 0.0, s)
+        bits = jax.lax.bitcast_convert_type(s, jnp.int32)
+        key = bits ^ ((bits >> 31) & jnp.int32(0x7FFFFFFF))
+        seen = j * block_k + col0 <= rows
+        key_scr[j] = jnp.where(seen, key, _INT_MIN)
+        return jnp.maximum(best, jnp.where(seen, s, _NEG_INF))
+
+    best = jax.lax.fori_loop(0, n_live, to_keys, jnp.full(shape, _NEG_INF, jnp.float32))
+    best = jnp.max(best, axis=1, keepdims=True)
+
+    # the want-th largest key, bit by bit from the top, in unsigned order
+    def value_bit(i, prefix):
+        cand = prefix | (jnp.int32(1) << (31 - i))
+        signed = cand ^ _INT_MIN
+        n = total(lambda j: (key_scr[j] >= signed).astype(jnp.int32))
+        return jnp.where(n >= want, cand, prefix)
+
+    tau = jax.lax.fori_loop(0, 32, value_bit, jnp.zeros((block_s, 1), jnp.int32)) ^ _INT_MIN
+    need = want - total(lambda j: (key_scr[j] > tau).astype(jnp.int32))
+
+    # among the keys equal to tau the lowest positions: the largest p with
+    # fewer than ``need`` equal keys before it is the last one taken
+    def position_bit(i, p):
+        cand = p | (jnp.int32(1) << (pos_bits - 1 - i))
+        n = total(lambda j: ((key_scr[j] == tau) & (j * block_k + col0 < cand)).astype(jnp.int32))
+        return jnp.where(n < need, cand, p)
+
+    cut = jax.lax.fori_loop(0, pos_bits, position_bit, jnp.zeros((block_s, 1), jnp.int32))
+
+    for word in range(words):
+        mask_ref[0, word] = jnp.zeros(shape, jnp.int32)
+
+    def emit(j, carry):
+        denom, count = carry
+        key = key_scr[j]
+        picked = (key > tau) | ((key == tau) & (j * block_k + col0 <= cut))
+        mask_ref[0, j // 32] = mask_ref[0, j // 32] | (picked.astype(jnp.int32) << (j % 32))
+        denom = denom + jnp.where(picked, jnp.exp(s_ref[0, j] - best), 0.0)
+        return denom, count + picked.astype(jnp.int32)
+
+    denom, count = jax.lax.fori_loop(
+        0, n_live, emit, (jnp.zeros(shape, jnp.float32), jnp.zeros(shape, jnp.int32))
+    )
+    lse = best + jnp.log(jnp.sum(denom, axis=1, keepdims=True))
+    count = jnp.sum(count, axis=1, keepdims=True).astype(jnp.float32)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (block_s, _ROW_LANES), 1)
+    stat_ref[0] = jnp.where(lane == 0, lse, count)
+
+
+def select_keys(
+    q_index: jax.Array, k_index: jax.Array, w: jax.Array, *, topk: int, blocks: Blocks = Blocks(),
+    interpret: bool = False,
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """The key sets.  ``q_index`` [B, S, J, DI], ``k_index`` [B, S, DI],
+    ``w`` [B, S, J] float32 → (``mask`` [B, words, S, block_k] int32, the
+    ``logsumexp`` of every row's picked scores [B, S] float32, its number of
+    keys [B, S] float32).  Not differentiable: the operands' gradients stop
+    here."""
+    q_index, k_index, w = jax.lax.stop_gradient((q_index, k_index, w))
+    B, S, J, DI = q_index.shape
+    blocks = blocks.fit(S)
+    bk, C, bs = blocks.k, blocks.chunk, blocks.s
+    nk = S // bk
+    words = -(-nk // 32)
+    qh = q_index.transpose(0, 2, 1, 3)  # [B, J, S, DI]
+    wh = _row_lanes(w.astype(jnp.float32).transpose(0, 2, 1))  # [B, J, S, 8]
+
+    def last_live(c):
+        return (c[0] * C + C - 1) // bk
+
+    index = pl.pallas_call(
+        functools.partial(_index_kernel, heads=J, chunk=C, block_k=bk),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, nk),
+            in_specs=[
+                pl.BlockSpec((1, J, C, DI), lambda b, ki, c: (b, 0, 0, 0)),
+                pl.BlockSpec((1, J, C, _ROW_LANES), lambda b, ki, c: (b, 0, 0, 0)),
+                # a block past the chunk's last row is never read: stay on the last live one
+                pl.BlockSpec((1, bk, DI), lambda b, ki, c: (b, jnp.minimum(ki, last_live(c)), 0)),
+            ],
+            out_specs=pl.BlockSpec((1, 1, C, bk), lambda b, ki, c: (b, ki, 0, 0)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((B, nk, C, bk), jnp.float32),
+        compiler_params=_params("parallel", "arbitrary"),
+        interpret=interpret,
+        name="dsa_index",
+    )
+    select = pl.pallas_call(
+        functools.partial(
+            _select_kernel, topk=topk, chunk=C, block_s=bs, block_k=bk, words=words,
+            pos_bits=max(1, int(S - 1).bit_length()),
+        ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, C // bs),
+            in_specs=[pl.BlockSpec((1, nk, bs, bk), lambda b, i, c: (b, 0, i, 0))],
+            out_specs=[
+                pl.BlockSpec((1, words, bs, bk), lambda b, i, c: (b, 0, i, 0)),
+                pl.BlockSpec((1, bs, _ROW_LANES), lambda b, i, c: (b, i, 0)),
+            ],
+            scratch_shapes=[pltpu.VMEM((nk, bs, bk), jnp.int32)],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((B, words, C, bk), jnp.int32),
+            jax.ShapeDtypeStruct((B, C, _ROW_LANES), jnp.float32),
+        ],
+        compiler_params=_params("parallel", "arbitrary"),
+        interpret=interpret,
+        name="dsa_select",
+    )
+
+    def one_chunk(c):
+        at = jnp.reshape(c, (1,)).astype(jnp.int32)
+        rows = lambda a: jax.lax.dynamic_slice_in_dim(a, c * C, C, axis=2)  # noqa: E731
+        scores = index(at, rows(qh), rows(wh), k_index)
+        return select(at, scores)
+
+    mask, stat = jax.lax.map(one_chunk, jnp.arange(S // C, dtype=jnp.int32))
+    # [S // C, B, words, C, bk] → [B, words, S, bk]
+    mask = mask.transpose(1, 2, 0, 3, 4).reshape(B, words, S, bk)
+    stat = stat.transpose(1, 0, 2, 3).reshape(B, S, _ROW_LANES)
+    return mask, stat[..., 0], stat[..., 1]
+
+
+# ---------------------------------------------------------------------------
+# attention over the picked keys
+# ---------------------------------------------------------------------------
+
+
+def _masked_scores(q, k, picked, sm_scale, group):
+    """``[group * bq, bk]`` scores of a stacked group of query heads, with
+    the positions a row did not pick at ``_NEG_INF``."""
+    s = _dot_t(q, k) * sm_scale
+    bq, bk = picked.shape
+    s = jnp.where(picked[None], s.reshape(group, bq, bk), _NEG_INF)
+    return s.reshape(group * bq, bk)
+
+
+def _attn_fwd_kernel(
+    q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
+    *, sm_scale, group, block_q, block_k, num_k_blocks,
+):
+    qi, ki = pl.program_id(2), pl.program_id(3)
+    D = q_ref.shape[-1]
+
+    @pl.when(ki == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    @pl.when(ki * block_k <= qi * block_q + block_q - 1)
+    def _accumulate():
+        q = q_ref[0].reshape(group * block_q, D)
+        v = v_ref[0, 0]
+        s = _masked_scores(q, k_ref[0, 0], _tile_bits(mask_ref, ki), sm_scale, group)
+        # a row that picked nothing in the blocks so far keeps m at _NEG_INF
+        # and adds exp(0) here; the first picked key's correction,
+        # exp(_NEG_INF - m), wipes that to exactly 0
+        m_prev = m_scr[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        correction = jnp.exp(m_prev - m_new)
+        l_new = l_scr[:, :1] * correction + jnp.sum(p, axis=1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * correction + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        )
+        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
+        l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
+
+    @pl.when(ki == num_k_blocks - 1)
+    def _finalize():
+        l = l_scr[:, :1]
+        o_ref[0] = (acc_scr[...] / l).reshape(group, block_q, D).astype(o_ref.dtype)
+        lse = m_scr[:, :1] + jnp.log(l)
+        lse_ref[0] = jnp.broadcast_to(lse, (group * block_q, _ROW_LANES)).reshape(
+            group, block_q, _ROW_LANES
+        )
+
+
+def _p_and_ds(q, k, v, do, lse, delta, picked, sm_scale, group):
+    s = _masked_scores(q, k, picked, sm_scale, group)
+    p = jnp.exp(s - lse)
+    dp = _dot_t(do, v)
+    return p, p * (dp - delta) * sm_scale
+
+
+def _attn_dq_kernel(
+    q_ref, k_ref, v_ref, mask_ref, lse_ref, do_ref, delta_ref, dq_ref, dq_scr,
+    *, sm_scale, group, block_q, block_k, num_k_blocks,
+):
+    qi, ki = pl.program_id(2), pl.program_id(3)
+    D = q_ref.shape[-1]
+    rows = group * block_q
+
+    @pl.when(ki == 0)
+    def _init():
+        dq_scr[...] = jnp.zeros_like(dq_scr)
+
+    @pl.when(ki * block_k <= qi * block_q + block_q - 1)
+    def _accumulate():
+        k = k_ref[0, 0]
+        _, ds = _p_and_ds(
+            q_ref[0].reshape(rows, D), k, v_ref[0, 0], do_ref[0].reshape(rows, D),
+            lse_ref[0].reshape(rows, _ROW_LANES)[:, :1], delta_ref[0].reshape(rows, _ROW_LANES)[:, :1],
+            _tile_bits(mask_ref, ki), sm_scale, group,
+        )
+        dq_scr[...] += jax.lax.dot_general(
+            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        )
+
+    @pl.when(ki == num_k_blocks - 1)
+    def _finalize():
+        dq_ref[0] = dq_scr[...].reshape(group, block_q, D).astype(dq_ref.dtype)
+
+
+def _attn_dkv_kernel(
+    q_ref, k_ref, v_ref, mask_ref, lse_ref, do_ref, delta_ref, dk_ref, dv_ref, dk_scr, dv_scr,
+    *, sm_scale, group, block_q, block_k, num_q_blocks,
+):
+    ki, qi = pl.program_id(2), pl.program_id(3)
+    D = q_ref.shape[-1]
+    rows = group * block_q
+
+    @pl.when(qi == 0)
+    def _init():
+        dk_scr[...] = jnp.zeros_like(dk_scr)
+        dv_scr[...] = jnp.zeros_like(dv_scr)
+
+    @pl.when(qi * block_q + block_q - 1 >= ki * block_k)
+    def _accumulate():
+        q, do = q_ref[0].reshape(rows, D), do_ref[0].reshape(rows, D)
+        p, ds = _p_and_ds(
+            q, k_ref[0, 0], v_ref[0, 0], do,
+            lse_ref[0].reshape(rows, _ROW_LANES)[:, :1], delta_ref[0].reshape(rows, _ROW_LANES)[:, :1],
+            _tile_bits(mask_ref, ki), sm_scale, group,
+        )
+        # contracting the stacked rows sums the whole group of query heads
+        dv_scr[...] += _dot_0(p.astype(do.dtype), do)
+        dk_scr[...] += _dot_0(ds.astype(q.dtype), q)
+
+    @pl.when(qi == num_q_blocks - 1)
+    def _finalize():
+        dk_ref[0, 0] = dk_scr[...].astype(dk_ref.dtype)
+        dv_ref[0, 0] = dv_scr[...].astype(dv_ref.dtype)
+
+
+def _attn_specs(group, bq, bk, D):
+    """Block specs of the q-major kernels' grid ``(B, KV, nq, nk)``.  A key
+    block wholly after the query block is never read: the index maps stay
+    on the last live one, so nothing is fetched for it."""
+    last = lambda qi: (qi * bq + bq - 1) // bk  # noqa: E731
+    q_spec = pl.BlockSpec((1, group, bq, D), lambda b, h, qi, ki: (b, h, qi, 0))
+    kv_spec = pl.BlockSpec((1, 1, bk, D), lambda b, h, qi, ki: (b, h, jnp.minimum(ki, last(qi)), 0))
+    mask_spec = pl.BlockSpec(
+        (1, 1, bq, bk), lambda b, h, qi, ki: (b, jnp.minimum(ki, last(qi)) // 32, qi, 0)
+    )
+    row_spec = pl.BlockSpec((1, group, bq, _ROW_LANES), lambda b, h, qi, ki: (b, h, qi, 0))
+    return q_spec, kv_spec, mask_spec, row_spec
+
+
+def _attn_fwd(q, k, v, mask, sm_scale, blocks, interpret):
+    """q [B, H, S, D], k and v [B, KV, S, D] → (o [B, H, S, D], lse
+    [B, H, S, 8])."""
+    B, H, S, D = q.shape
+    KV = k.shape[1]
+    group = H // KV
+    bq, bk = blocks.q, blocks.k
+    nq, nk = S // bq, S // bk
+    q_spec, kv_spec, mask_spec, row_spec = _attn_specs(group, bq, bk, D)
+    return pl.pallas_call(
+        functools.partial(
+            _attn_fwd_kernel, sm_scale=sm_scale, group=group, block_q=bq, block_k=bk, num_k_blocks=nk
+        ),
+        grid=(B, KV, nq, nk),
+        in_specs=[q_spec, kv_spec, kv_spec, mask_spec],
+        out_specs=[q_spec, row_spec],
+        out_shape=[
+            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct((B, H, S, _ROW_LANES), jnp.float32),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((group * bq, _LANES), jnp.float32),
+            pltpu.VMEM((group * bq, _LANES), jnp.float32),
+            pltpu.VMEM((group * bq, D), jnp.float32),
+        ],
+        compiler_params=_params("parallel", "parallel", "parallel", "arbitrary"),
+        interpret=interpret,
+        name="dsa_attn_fwd",
+    )(q, k, v, mask)
+
+
+def _attn_bwd(q, k, v, mask, o, lse, do, sm_scale, blocks, interpret):
+    B, H, S, D = q.shape
+    KV = k.shape[1]
+    group = H // KV
+    bq, bk = blocks.q, blocks.k
+    nq, nk = S // bq, S // bk
+    delta = jnp.broadcast_to(
+        jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1, keepdims=True),
+        (B, H, S, _ROW_LANES),
+    )
+    q_spec, kv_spec, mask_spec, row_spec = _attn_specs(group, bq, bk, D)
+    dq = pl.pallas_call(
+        functools.partial(
+            _attn_dq_kernel, sm_scale=sm_scale, group=group, block_q=bq, block_k=bk, num_k_blocks=nk
+        ),
+        grid=(B, KV, nq, nk),
+        in_specs=[q_spec, kv_spec, kv_spec, mask_spec, row_spec, q_spec, row_spec],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        scratch_shapes=[pltpu.VMEM((group * bq, D), jnp.float32)],
+        compiler_params=_params("parallel", "parallel", "parallel", "arbitrary"),
+        interpret=interpret,
+        name="dsa_attn_dq",
+    )(q, k, v, mask, lse, do, delta)
+
+    # k-major: a query block wholly before the key block is never read
+    first = lambda ki: (ki * bk) // bq  # noqa: E731
+    at = lambda ki, qi: jnp.maximum(qi, first(ki))  # noqa: E731
+    gq_spec = pl.BlockSpec((1, group, bq, D), lambda b, h, ki, qi: (b, h, at(ki, qi), 0))
+    gkv_spec = pl.BlockSpec((1, 1, bk, D), lambda b, h, ki, qi: (b, h, ki, 0))
+    gmask_spec = pl.BlockSpec((1, 1, bq, bk), lambda b, h, ki, qi: (b, ki // 32, at(ki, qi), 0))
+    grow_spec = pl.BlockSpec((1, group, bq, _ROW_LANES), lambda b, h, ki, qi: (b, h, at(ki, qi), 0))
+    dk, dv = pl.pallas_call(
+        functools.partial(
+            _attn_dkv_kernel, sm_scale=sm_scale, group=group, block_q=bq, block_k=bk, num_q_blocks=nq
+        ),
+        grid=(B, KV, nk, nq),
+        in_specs=[gq_spec, gkv_spec, gkv_spec, gmask_spec, grow_spec, gq_spec, grow_spec],
+        out_specs=[gkv_spec, gkv_spec],
+        out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype), jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32), pltpu.VMEM((bk, D), jnp.float32)],
+        compiler_params=_params("parallel", "parallel", "parallel", "arbitrary"),
+        interpret=interpret,
+        name="dsa_attn_dkv",
+    )(q, k, v, mask, lse, do, delta)
+    return dq, dk, dv
+
+
+# ---------------------------------------------------------------------------
+# the index's loss
+# ---------------------------------------------------------------------------
+
+
+def _probs_kernel(
+    q_ref, k_ref, lse_ref, mask_ref, qi_ref, w_ref, ki_ref, stat_ref, kl_ref, dq_ref, dw_ref, dk_ref,
+    kl_scr, dq_scr, dw_scr, *, sm_scale, kv_heads, group, heads, block_q, block_k, num_k_blocks,
+):
+    qi, ki = pl.program_id(1), pl.program_id(2)
+    D = q_ref.shape[-1]
+    rows = group * block_q
+
+    @pl.when(ki == 0)
+    def _init():
+        kl_scr[...] = jnp.zeros_like(kl_scr)
+        dq_scr[...] = jnp.zeros_like(dq_scr)
+        dw_scr[...] = jnp.zeros_like(dw_scr)
+
+    @pl.when((qi == 0) & (ki == 0))
+    def _init_keys():
+        dk_ref[...] = jnp.zeros_like(dk_ref)
+
+    @pl.when(ki * block_k <= qi * block_q + block_q - 1)
+    def _accumulate():
+        picked = _tile_bits(mask_ref, ki)
+        # the head-mean of the attention's softmax over the picked keys
+        p = jnp.zeros((block_q, block_k), jnp.float32)
+        for g in range(kv_heads):
+            heads_of = slice(g * group, (g + 1) * group)
+            s = _dot_t(q_ref[0, heads_of].reshape(rows, D), k_ref[0, g]) * sm_scale
+            lse = lse_ref[0, heads_of].reshape(rows, _ROW_LANES)[:, :1]
+            p = p + jnp.sum(jnp.exp(s - lse).reshape(group, block_q, block_k), axis=0)
+        p = jnp.where(picked, p * (1.0 / (kv_heads * group)), 0.0)
+        k_index = ki_ref[0]
+        log_q = _index_scores(qi_ref, w_ref, k_index, heads) - stat_ref[0][:, :1]
+        kl = jnp.where(picked, p * (jnp.log(jnp.maximum(p, 1e-37)) - log_q), 0.0)
+        kl_scr[...] += jnp.broadcast_to(jnp.sum(kl, axis=1, keepdims=True), kl_scr.shape)
+        # d L_I / d score = softmax_S(I) - p; through the relu to each head
+        d_score = jnp.where(picked, jnp.exp(log_q) - p, 0.0)
+        for j in range(heads):
+            q_j = qi_ref[0, j]
+            dots = _dot_t(q_j, k_index)
+            dw_scr[j] += jnp.broadcast_to(
+                jnp.sum(d_score * jnp.maximum(dots, 0.0), axis=1, keepdims=True), dw_scr.shape[1:]
+            )
+            d_dots = jnp.where(dots > 0.0, d_score * w_ref[0, j][:, :1], 0.0).astype(q_j.dtype)
+            dq_scr[j] += jax.lax.dot_general(
+                d_dots, k_index, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            )
+            dk_ref[0, ki] += _dot_0(d_dots, q_j)
+
+    @pl.when(ki == num_k_blocks - 1)
+    def _finalize():
+        kl_ref[0] = kl_scr[:, :_ROW_LANES]
+        dq_ref[0] = dq_scr[...]
+        dw_ref[0] = dw_scr[:, :, :_ROW_LANES]
+
+
+def _index_loss(q, k, lse, mask, q_index, w, k_index, lse_index, sm_scale, blocks, interpret):
+    """``sum_s p log(p / softmax_S(I))`` of every row, [B, S] float32, and
+    its gradient to ``q_index`` [B, J, S, DI], ``w`` [B, J, S] and
+    ``k_index`` [B, S, DI], all float32, in the one pass (the gradient
+    needs no cotangent but a scalar's, and the tile's probabilities are
+    the expensive part of both).  q [B, H, S, D], k [B, KV, S, D], lse
+    [B, H, S, 8], q_index [B, J, S, DI], w [B, J, S, 8], k_index
+    [B, S, DI], lse_index [B, S, 8]."""
+    B, H, S, D = q.shape
+    KV = k.shape[1]
+    J, DI = q_index.shape[1], q_index.shape[3]
+    bq, bk = blocks.q, blocks.k
+    nq, nk = S // bq, S // bk
+    last = lambda qi: (qi * bq + bq - 1) // bk  # noqa: E731
+    key_at = lambda qi, ki: jnp.minimum(ki, last(qi))  # noqa: E731
+    rows = lambda *lead: pl.BlockSpec(  # noqa: E731
+        (1, *lead, bq, _ROW_LANES), lambda b, qi, ki: (b,) + (0,) * len(lead) + (qi, 0)
+    )
+    index_q_spec = pl.BlockSpec((1, J, bq, DI), lambda b, qi, ki: (b, 0, qi, 0))
+    kl, dq, dw, dk = pl.pallas_call(
+        functools.partial(
+            _probs_kernel, sm_scale=sm_scale, kv_heads=KV, group=H // KV, heads=J, block_q=bq,
+            block_k=bk, num_k_blocks=nk,
+        ),
+        grid=(B, nq, nk),
+        in_specs=[
+            pl.BlockSpec((1, H, bq, D), lambda b, qi, ki: (b, 0, qi, 0)),
+            pl.BlockSpec((1, KV, bk, D), lambda b, qi, ki: (b, 0, key_at(qi, ki), 0)),
+            rows(H),
+            pl.BlockSpec((1, 1, bq, bk), lambda b, qi, ki: (b, key_at(qi, ki) // 32, qi, 0)),
+            index_q_spec,
+            rows(J),
+            pl.BlockSpec((1, bk, DI), lambda b, qi, ki: (b, key_at(qi, ki), 0)),
+            rows(),
+        ],
+        out_specs=[
+            rows(),
+            index_q_spec,
+            rows(J),
+            # every query block adds to every earlier key block: the whole
+            # array stays in fast memory for a batch row
+            pl.BlockSpec((1, nk, bk, DI), lambda b, qi, ki: (b, 0, 0, 0)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((B, S, _ROW_LANES), jnp.float32),
+            jax.ShapeDtypeStruct((B, J, S, DI), jnp.float32),
+            jax.ShapeDtypeStruct((B, J, S, _ROW_LANES), jnp.float32),
+            jax.ShapeDtypeStruct((B, nk, bk, DI), jnp.float32),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((bq, _LANES), jnp.float32),
+            pltpu.VMEM((J, bq, DI), jnp.float32),
+            pltpu.VMEM((J, bq, _LANES), jnp.float32),
+        ],
+        compiler_params=_params("parallel", "arbitrary", "arbitrary"),
+        interpret=interpret,
+        name="dsa_probs",
+    )(q, k, lse, mask, q_index, w, k_index, lse_index)
+    return kl[..., 0], dq, dw[..., 0], dk.reshape(B, S, DI)
+
+
+# ---------------------------------------------------------------------------
+# public entry
+# ---------------------------------------------------------------------------
+
+
+def _heads_major(q, k, v, q_index, w):
+    t = lambda a: a.transpose(0, 2, 1, 3)  # noqa: E731
+    return t(q), t(k), t(v), t(q_index), _row_lanes(w.astype(jnp.float32).transpose(0, 2, 1))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9, 10))
+def _attend(q, k, v, q_index, k_index, w, mask, lse_index, sm_scale, blocks, interpret):
+    return _attend_fwd(q, k, v, q_index, k_index, w, mask, lse_index, sm_scale, blocks, interpret)[0]
+
+
+def _attend_fwd(q, k, v, q_index, k_index, w, mask, lse_index, sm_scale, blocks, interpret):
+    qh, kh, vh, qih, wh = _heads_major(q, k, v, q_index, w)
+    o, lse = _attn_fwd(qh, kh, vh, mask, sm_scale, blocks, interpret)
+    kl, d_qi, d_w, d_ki = _index_loss(
+        qh, kh, lse, mask, qih, wh, k_index, _row_lanes(lse_index), sm_scale, blocks, interpret
+    )
+    like = tuple(jnp.zeros((0,), a.dtype) for a in (q_index, k_index, w))  # the cotangents' dtypes
+    kept = (qh, kh, vh, mask, o, lse, d_qi, d_w, d_ki, like)
+    return (o.transpose(0, 2, 1, 3), jnp.sum(kl)), kept
+
+
+def _attend_bwd(sm_scale, blocks, interpret, kept, cotangents):
+    qh, kh, vh, mask, o, lse, d_qi, d_w, d_ki, like = kept
+    qi_dtype, ki_dtype, w_dtype = (a.dtype for a in like)
+    do, g = cotangents
+    dq, dk, dv = _attn_bwd(
+        qh, kh, vh, mask, o, lse, do.transpose(0, 2, 1, 3).astype(o.dtype), sm_scale, blocks, interpret
+    )
+    t = lambda a: a.transpose(0, 2, 1, 3)  # noqa: E731
+    g = g.astype(jnp.float32)
+    return (
+        t(dq), t(dk), t(dv),
+        (g * t(d_qi)).astype(qi_dtype), (g * d_ki).astype(ki_dtype),
+        (g * d_w.transpose(0, 2, 1)).astype(w_dtype),
+        None, jnp.zeros_like(lse[:, 0, :, 0]),
+    )
+
+
+_attend.defvjp(_attend_fwd, _attend_bwd)
+
+
+def indexed_attention(
+    q: jax.Array, k: jax.Array, v: jax.Array, q_index: jax.Array, k_index: jax.Array, w: jax.Array,
+    mask: jax.Array, lse_index: jax.Array, *, blocks: Blocks = Blocks(), interpret: bool = False,
+) -> Tuple[jax.Array, jax.Array]:
+    """Attention over the key sets of :func:`select_keys` and the index's
+    loss.  q [B, S, H, D], k and v [B, S, KV, D], ``q_index`` [B, S, J, DI],
+    ``k_index`` [B, S, DI], ``w`` [B, S, J] → (o [B, S, H, D], ``L_I``
+    summed over the rows, float32).
+
+    ``o``'s gradient reaches q, k and v alone; ``L_I``'s reaches the
+    index's three operands alone (the attention's probabilities are its
+    target and carry none)."""
+    blocks = blocks.fit(q.shape[1])
+    sm_scale = 1.0 / float(np.sqrt(q.shape[-1]))
+    return _attend(
+        q, k, v, q_index, k_index, w, mask, jax.lax.stop_gradient(lse_index), sm_scale, blocks, interpret
+    )
+
+
+# ---------------------------------------------------------------------------
+# the same in plain jax.numpy (dense [S, S] arrays)
+# ---------------------------------------------------------------------------
+
+
+def picked_plain(q_index: jax.Array, k_index: jax.Array, w: jax.Array, topk: int) -> Tuple[jax.Array, jax.Array]:
+    """(the index's scores [B, S, S] float32, the key sets as booleans)."""
+    S = q_index.shape[1]
+    dots = jnp.einsum("btjd,bsd->btjs", q_index, k_index, preferred_element_type=jnp.float32)
+    scores = jnp.einsum("btj,btjs->bts", w.astype(jnp.float32), jax.nn.relu(dots))
+    causal = jnp.tril(jnp.ones((S, S), bool))
+    k = min(topk, S)
+    best, at = jax.lax.top_k(jnp.where(causal, scores, -jnp.inf), k)  # ties: the lower position first
+    rows = jnp.arange(S)[None, :, None]
+    picked = jnp.zeros(scores.shape, bool).at[jnp.arange(scores.shape[0])[:, None, None], rows, at].max(
+        best > -jnp.inf
+    )
+    return scores, picked
+
+
+def indexed_attention_plain(
+    q: jax.Array, k: jax.Array, v: jax.Array, q_index: jax.Array, k_index: jax.Array, w: jax.Array,
+    *, topk: int,
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """(o, ``L_I`` summed over the rows, keys a row [B, S]) as
+    :func:`select_keys` and :func:`indexed_attention` give them."""
+    B, S, H, D = q.shape
+    group = H // k.shape[2]
+    # the picking itself carries no gradient (positions and booleans); the
+    # scores do, to the index's loss below
+    scores, picked = picked_plain(q_index, k_index, w, topk)
+    logits = jnp.einsum(
+        "bthd,bshd->bhts", q, jnp.repeat(k, group, axis=2), preferred_element_type=jnp.float32
+    ) / np.sqrt(D)
+    probs = jax.nn.softmax(jnp.where(picked[:, None], logits, _NEG_INF), axis=-1)
+    o = jnp.einsum("bhts,bshd->bthd", probs.astype(v.dtype), jnp.repeat(v, group, axis=2))
+    log_q = jax.nn.log_softmax(jnp.where(picked, scores, _NEG_INF), axis=-1)
+    target = jax.lax.stop_gradient(jnp.mean(probs, axis=1))
+    kl = jnp.where(picked, target * (jnp.log(jnp.maximum(target, 1e-37)) - log_q), 0.0)
+    return o, jnp.sum(kl), jnp.sum(picked, axis=-1).astype(jnp.float32)

@@ -71,15 +71,26 @@ def shard_init(model: Any, key: jax.Array, mesh: Mesh) -> Any:
         return jax.jit(model.init, out_shardings=params_sh)(key)
 
 
+def _reports(model: Any) -> bool:
+    """Whether the model gives its own account of a step.  Such a model has
+    ``objective(params, batch) -> (scalar, (signal, summary))``: the scalar
+    a training step differentiates, the step's signal for every leaf of
+    ``state_mask()`` (an empty list for a model without one) and
+    ``summary``, a few float32 numbers made on the device inside the
+    compiled gradient step (the experts' loads layer by layer, the index's
+    loss: whatever ``summary_stats(summary)`` turns into the detail of a
+    committed step's MOE_ROUTE flight event).  They come to the host WITH the
+    loss, in one small array.  ``Llama`` has none of this and keeps
+    ``loss``."""
+    return hasattr(model, "objective")
+
+
 def _state_mask(model: Any) -> Optional[List[bool]]:
     """Which leaves of ``params`` (in flatten order) are state the optimizer
-    does not own, or None for a model that declares none (``Llama``).  A
-    model with such state (``models/ling_hybrid.py``: the routers' selection
-    biases) has ``state_mask()``, ``objective(params, batch) -> (scalar,
-    [signal of every state leaf])``, ``advance_state(state, signal)`` and
-    ``route_summary(signal)`` / ``route_stats(summary)``, the detail of a
-    committed step's flight event (a few numbers made on the device and read
-    with the loss)."""
+    does not own, or None for a model that declares none.  A model with such
+    state (``models/ling_hybrid.py``: the routers' selection biases) has
+    ``state_mask()`` and ``advance_state(state, signal)`` and reports
+    (:func:`_reports`): its ``objective`` gives the signal."""
     if not hasattr(model, "state_mask"):
         return None
     return jax.tree_util.tree_leaves(model.state_mask())
@@ -105,8 +116,10 @@ def make_grad_step(
     """Compile ``(params, batch) → (loss, grads)`` with grads sharded like
     params (the FSDP reduce-scatter happens inside via XLA SPMD).
 
-    For a model with state the optimizer does not own, the scalar is the
-    model's ``objective`` and each state leaf's slot in ``grads`` (its
+    For a model that reports (:func:`_reports`) the first result is a
+    float32 vector: the model's ``objective`` and then its ``summary``, so
+    that both reach the host in one transfer.  Where it has state the
+    optimizer does not own, each state leaf's slot in ``grads`` (its
     gradient is stopped, so the slot is free) carries the step's signal for
     that leaf: it passes the replica-dimension average with the gradients,
     which keeps replicas bit-equal."""
@@ -114,10 +127,13 @@ def make_grad_step(
     mask = _state_mask(model)
 
     def _step(params: Any, batch: Any) -> Tuple[jax.Array, Any]:
-        if mask is None:
+        if not _reports(model):
             return jax.value_and_grad(model.loss)(params, batch)
-        (loss, signal), grads = jax.value_and_grad(model.objective, has_aux=True)(params, batch)
-        return loss, _with_state(grads, mask, [s.astype(jnp.float32) for s in signal])
+        (loss, (signal, summary)), grads = jax.value_and_grad(model.objective, has_aux=True)(params, batch)
+        if mask is not None:
+            grads = _with_state(grads, mask, [s.astype(jnp.float32) for s in signal])
+        report = jnp.concatenate([loss.reshape(1), summary.reshape(-1)]).astype(jnp.float32)
+        return report, grads
 
     with mesh:
         return jax.jit(
@@ -268,12 +284,6 @@ class HSDPTrainer:
         self._grad_step = make_grad_step(model, mesh)
         self._update_step = make_update_step(model, tx, mesh)
         self._state_mask = _state_mask(model)
-        if self._state_mask is not None:
-            self._summary = jax.jit(
-                lambda loss, signal: jnp.concatenate(
-                    [loss.reshape(1).astype(jnp.float32), model.route_summary(signal).reshape(-1)]
-                )
-            )
 
         manager.register_state_dict_fn(
             "hsdp", self._load_state, self._save_state
@@ -321,10 +331,6 @@ class HSDPTrainer:
         with obs_span("tpuft/step/grad"):
             loss, grads = self._grad_step(self.holder["params"], batch)
         mask = self._state_mask
-        if mask is not None:
-            # this replica's own signal, before the average
-            local_signal = _state_leaves(grads, mask)
-            summary = self._summary(loss, local_signal)
         if mask is None or not self.quantize_outer:
             grads = ft_allreduce(
                 self.manager, grads, should_quantize=self.quantize_outer
@@ -332,8 +338,8 @@ class HSDPTrainer:
         else:
             # the signal crosses the wire by itself, unquantised: an 8-bit
             # count could turn the sign its update takes
-            signal = ft_allreduce(self.manager, local_signal)
-            grads = _with_state(grads, mask, [jnp.zeros_like(x) for x in local_signal])
+            signal = ft_allreduce(self.manager, _state_leaves(grads, mask))
+            grads = _with_state(grads, mask, [jnp.zeros_like(x) for x in signal])
             grads = _with_state(ft_allreduce(self.manager, grads, should_quantize=True), mask, signal)
         committed = self.manager.should_commit()
         if committed:
@@ -343,11 +349,12 @@ class HSDPTrainer:
                 )
             self.holder["params"] = params
             self.holder["opt_state"] = opt_state
-        if mask is None:
+        if not _reports(self.model):
             return float(loss), committed
-        # ONE transfer a step, as without such state: the loss and the
-        # step's routing summary come to the host in one small array
-        host = np.asarray(summary)
+        # ONE transfer a step, as for a model that reports nothing: the loss
+        # and this replica's own summary of the step (made before the
+        # average) come to the host in one small array
+        host = np.asarray(loss)
         if committed:
-            self.manager._flight.record(FlightEvent.MOE_ROUTE, **self.model.route_stats(host[1:]))
+            self.manager._flight.record(FlightEvent.MOE_ROUTE, **self.model.summary_stats(host[1:]))
         return float(host[0]), committed

@@ -185,8 +185,12 @@ class RoutedExpertsConfig:
     # the contiguous range of experts whose weights are HERE: (first, count)
     experts_held: Tuple[int, int]
     top_k: int
-    n_group: int
-    topk_group: int
+    n_group: int = 1  # 1: no groups, the top_k best of all experts
+    topk_group: int = 1
+    score_func: str = "sigmoid"  # or "softmax": over all experts, float32
+    # a selection bias that no gradient moves (``HSDPTrainer``: state the
+    # optimizer does not own); False: no such leaf, the scores alone choose
+    selection_bias: bool = True
     routed_scaling_factor: float = 1.0
     norm_topk_prob: bool = True
     shared_hidden: int = 0  # width of the shared expert; 0: none
@@ -207,11 +211,12 @@ def swiglu(gate: jax.Array, up: jax.Array, limit: float) -> jax.Array:
 class RoutedExperts:
     """An expert layer that is TOLD which experts it holds.
 
-    The router scores all ``num_experts`` in float32 (sigmoid), adds the
-    selection bias, keeps ``topk_group`` of the ``n_group`` groups by the sum
-    of each group's two best and then the ``top_k`` best experts inside
-    them; the weights are the UNBIASED scores of the chosen, normalised over
-    all ``top_k`` and scaled.  Nothing is dropped: the (token, choice) pairs
+    The router scores all ``num_experts`` in float32 (``score_func``:
+    sigmoid, or a softmax over all of them), adds the selection bias if it
+    has one, keeps ``topk_group`` of the ``n_group`` groups by the sum of
+    each group's two best (one group: no such step) and then the ``top_k``
+    best experts inside them; the weights are the UNBIASED scores of the
+    chosen, normalised over all ``top_k`` and scaled.  Nothing is dropped: the (token, choice) pairs
     that fall on held experts are sorted by expert into a static buffer and
     go through a grouped SwiGLU whose work follows the rows really routed
     here (``megablox.gmm`` on the TPU: ``path`` "gmm"; ``lax.ragged_dot``
@@ -230,6 +235,8 @@ class RoutedExperts:
         self.config = config
         if config.num_experts % config.n_group:
             raise ValueError("num_experts must divide into n_group groups")
+        if config.score_func not in ("sigmoid", "softmax"):
+            raise ValueError(f"score_func {config.score_func!r} is neither sigmoid nor softmax")
         first, count = config.experts_held
         if first < 0 or count < 1 or first + count > config.num_experts:
             raise ValueError(f"experts_held {config.experts_held} outside 0..{config.num_experts}")
@@ -246,13 +253,14 @@ class RoutedExperts:
 
         params = {
             "router": normal(keys[0], (cfg.dim, cfg.num_experts), cfg.dim, jnp.float32),
-            # state the optimizer does not own (``HSDPTrainer``): moved by
-            # the load, never by a gradient
-            "bias": jnp.zeros((cfg.num_experts,), jnp.float32),
             "w_gate": normal(keys[1], (held, cfg.dim, cfg.expert_hidden), cfg.dim),
             "w_up": normal(keys[2], (held, cfg.dim, cfg.expert_hidden), cfg.dim),
             "w_down": normal(keys[3], (held, cfg.expert_hidden, cfg.dim), cfg.expert_hidden),
         }
+        if cfg.selection_bias:
+            # state the optimizer does not own (``HSDPTrainer``): moved by
+            # the load, never by a gradient
+            params["bias"] = jnp.zeros((cfg.num_experts,), jnp.float32)
         if cfg.shared_hidden:
             params.update(
                 shared_gate=normal(keys[4], (cfg.dim, cfg.shared_hidden), cfg.dim),
@@ -265,10 +273,12 @@ class RoutedExperts:
         """One chip's share: nothing here is divided further."""
         cfg = self.config
         specs = {
-            "router": P(None, None), "bias": P(None),
+            "router": P(None, None),
             "w_gate": P(None, None, None), "w_up": P(None, None, None),
             "w_down": P(None, None, None),
         }
+        if cfg.selection_bias:
+            specs["bias"] = P(None)
         if cfg.shared_hidden:
             specs.update(shared_gate=P(None, None), shared_up=P(None, None), shared_down=P(None, None))
         return specs
@@ -283,14 +293,15 @@ class RoutedExperts:
         logits = jnp.dot(
             x.astype(jnp.float32), params["router"], precision=jax.lax.Precision.HIGHEST
         )
-        scores = jax.nn.sigmoid(logits)
-        biased = scores + jax.lax.stop_gradient(params["bias"])
-        grouped = biased.reshape(-1, G, E // G)
-        group_score = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)  # [T, G]
-        _, keep = jax.lax.top_k(group_score, cfg.topk_group)
-        group_kept = jnp.sum(jax.nn.one_hot(keep, G, dtype=jnp.int32), axis=1) > 0  # [T, G]
-        masked = jnp.where(group_kept[:, :, None], grouped, -jnp.inf).reshape(-1, E)
-        _, chosen = jax.lax.top_k(masked, cfg.top_k)
+        scores = jax.nn.softmax(logits, axis=-1) if cfg.score_func == "softmax" else jax.nn.sigmoid(logits)
+        biased = scores + jax.lax.stop_gradient(params["bias"]) if cfg.selection_bias else scores
+        if G > 1:
+            grouped = biased.reshape(-1, G, E // G)
+            group_score = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)  # [T, G]
+            _, keep = jax.lax.top_k(group_score, cfg.topk_group)
+            group_kept = jnp.sum(jax.nn.one_hot(keep, G, dtype=jnp.int32), axis=1) > 0  # [T, G]
+            biased = jnp.where(group_kept[:, :, None], grouped, -jnp.inf).reshape(-1, E)
+        _, chosen = jax.lax.top_k(biased, cfg.top_k)
         weights = jnp.take_along_axis(scores, chosen, axis=-1)
         if cfg.norm_topk_prob:
             weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
@@ -387,11 +398,16 @@ class RoutedExperts:
     ) -> Tuple[jax.Array, jax.Array, jax.Array]:
         """x [B, S, D] → (this chip's part of the layer [B, S, D], the
         tokens every one of the ``num_experts`` was chosen by [E] float32,
-        the sequence-wise balance loss, weighted)."""
+        the sequence-wise balance loss, weighted).  The router reads x as it
+        is given and the experts read it in the matrices' dtype: a caller
+        whose residual stream is float32 hands over float32, so that the
+        choice of experts does not turn on bfloat16's rounding of x, and
+        gets its part back in float32."""
         cfg = self.config
         B, S, D = x.shape
         flat = x.reshape(B * S, D)
         chosen, weights, scores = self.route(params, flat)
+        flat = flat.astype(cfg.dtype)
         picked = jax.nn.one_hot(chosen, cfg.num_experts, dtype=jnp.float32).sum(axis=1)  # [T, E]
         load = jax.lax.stop_gradient(picked.sum(axis=0))
         first, held = cfg.experts_held
