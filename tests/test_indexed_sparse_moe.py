@@ -12,6 +12,7 @@ import numpy as np
 import pytest
 
 from ftbench.architectures import indexed_sparse_moe_reference as ref
+from torchft_tpu.models import indexed_sparse_moe
 from torchft_tpu.models.indexed_sparse_moe import IndexedSparseMoE, indexed_sparse_debug
 from torchft_tpu.parallel.moe import RoutedExperts, RoutedExpertsConfig
 
@@ -211,3 +212,88 @@ def test_a_bfloat16_model_keeps_a_float32_stream_and_routes_on_it():
     picked, _, _ = layer.route(wl, h.reshape(-1, 32))
     np.testing.assert_array_equal(load, np.bincount(np.asarray(picked).ravel(), minlength=16))
     assert not np.array_equal(load, rounded_load)  # among 4,096 tokens some choice turns on the rounding
+
+
+# ---------------------------------------------------------------------------
+# what a rematerialised layer keeps (PR 34)
+# ---------------------------------------------------------------------------
+
+KERNEL_NAMES = ("dsa_index", "dsa_select", "dsa_attn_fwd", "dsa_attn_dq", "dsa_attn_dkv", "dsa_probs")
+
+
+def _kernel_calls(monkeypatch):
+    """How often each ``pallas_call`` name stands in the jaxpr of ``jax.grad``
+    of the model's objective on the kernels' path (``interpret``), the
+    sub-jaxprs of ``scan``, ``checkpoint`` and ``custom_vjp`` included.  The
+    layers are one ``scan`` each way, so a count is a count a layer body,
+    forward and backward bodies together."""
+    monkeypatch.setenv("TORCHFT_FLASH", "1")
+    model = IndexedSparseMoE(indexed_sparse_debug())
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    batch = _batch(model, 5)
+    jaxpr = jax.make_jaxpr(jax.grad(lambda p: model.objective(p, batch)[0]))(shapes)
+    assert model.attention_path == "dsa"
+    counts = dict.fromkeys(KERNEL_NAMES, 0)
+
+    def walk(j):
+        for eqn in j.eqns:
+            if eqn.primitive.name == "pallas_call":
+                counts[eqn.params["name"]] += 1
+            for value in eqn.params.values():
+                for inner in value if isinstance(value, (list, tuple)) else (value,):
+                    inner = getattr(inner, "jaxpr", inner)  # a ClosedJaxpr's
+                    if hasattr(inner, "eqns"):
+                        walk(inner)
+
+    walk(jaxpr.jaxpr)
+    return counts
+
+
+@pytest.fixture(scope="module")
+def kernel_calls():
+    with pytest.MonkeyPatch.context() as patch:
+        return _kernel_calls(patch)
+
+
+@pytest.mark.parametrize("name", KERNEL_NAMES)
+def test_every_kernel_runs_once_a_layer(name, kernel_calls):
+    """The backward pass's copy of a layer's forward holds neither
+    ``dsa_attn_fwd`` nor ``dsa_probs``: their outputs are among what the
+    layer keeps.  (On the parent of PR 34, which kept the selection alone,
+    those two count 2.)  Counted in the jaxpr: what is dead there is not
+    lowered."""
+    assert kernel_calls[name] == 1
+
+
+def test_a_layer_that_keeps_the_selection_alone_runs_two_kernels_twice(monkeypatch):
+    """The count above sees a rematerialised kernel where there is one."""
+    monkeypatch.setattr(indexed_sparse_moe, "KEPT_NAMES", ())
+    assert _kernel_calls(monkeypatch) == dict.fromkeys(KERNEL_NAMES, 1) | {"dsa_attn_fwd": 2, "dsa_probs": 2}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
+def test_keeping_the_kernels_outputs_moves_no_bit(dtype, monkeypatch):
+    """The objective, the summary and every gradient leaf are, to the bit,
+    those of the same model keeping the selection alone: what is kept is
+    what would have been computed again."""
+    monkeypatch.setenv("TORCHFT_FLASH", "1")
+    model = IndexedSparseMoE(indexed_sparse_debug(dtype=dtype))
+    w = model.init(jax.random.PRNGKey(3))
+    batch = _batch(model, 11)
+
+    def step():
+        # a function of its own, so that nothing traced before is found again
+        return jax.jit(jax.value_and_grad(lambda p: model.objective(p, batch), has_aux=True))(w)
+
+    (objective, (_, summary)), grads = step()
+    monkeypatch.setattr(indexed_sparse_moe, "KEPT_NAMES", ())
+    (want_objective, (_, want_summary)), want_grads = step()
+    assert model.attention_path == "dsa"
+    np.testing.assert_array_equal(objective, want_objective)
+    np.testing.assert_array_equal(summary, want_summary)
+    got, wanted = _leaves(grads), _leaves(want_grads)
+    assert got.keys() == wanted.keys()
+    for name in got:
+        assert got[name].dtype == wanted[name].dtype
+        np.testing.assert_array_equal(got[name], wanted[name], err_msg=name)
+        assert float(jnp.max(jnp.abs(got[name].astype(jnp.float32)))) > 0, name

@@ -21,8 +21,13 @@ built for is Keye-VL-2.0-30B-A3B's language model, ``model_type``
   renormalised; no groups, no selection bias, no shared expert.
 
 Layers are stacked and run under one ``lax.scan``, each rematerialised in
-the backward pass; the key sets (bits, 32 MB a layer at 16,384 positions)
-are the one thing kept, so the selection runs once a step.
+the backward pass but for what costs most to make again for its bytes: the
+key sets (``SELECTION_NAMES``: bits, 32 MB a layer at 16,384 positions) and
+what the attention kernel and the index's loss made of them (``KEPT_NAMES``
+of ``ops/indexed_attention.py``: the attention's output and row statistics,
+``L_I``'s finished gradient; 212 MB a layer at 16,384 positions and 32 query
+heads, linear in both).  So each of the six kernels runs once a layer and
+step; the projections, norms, rope and experts around them run again.
 
 ``attention_path`` is ``"dsa"`` only if every layer took the Mosaic kernels
 and the experts the grouped kernel; off the TPU the same mathematics runs as
@@ -56,13 +61,14 @@ from jax.sharding import PartitionSpec as P
 
 from torchft_tpu.models.llama import Llama
 from torchft_tpu.ops.indexed_attention import (
-    Blocks, indexed_attention, indexed_attention_plain, select_keys,
+    KEPT_NAMES, Blocks, indexed_attention, indexed_attention_plain, select_keys,
 )
 from torchft_tpu.parallel.moe import RoutedExperts, RoutedExpertsConfig
 
 logger = logging.getLogger(__name__)
 
 KERNEL_PATH = "dsa"
+SELECTION_NAMES = ("dsa_mask", "dsa_lse", "dsa_keys")
 SUMMARY_FIELDS = ("rows_here", "load_max", "load_mean", "index_kl", "keys_per_query")
 
 
@@ -270,10 +276,11 @@ class IndexedSparseMoE:
             mask, lse_index, keys = select_keys(
                 q_index, k_index, weight, topk=cfg.index_topk, blocks=cfg.blocks, interpret=interpret
             )
-            # the one thing a rematerialised layer keeps: the selection runs once a step
-            mask = checkpoint_name(mask, "dsa_mask")
-            lse_index = checkpoint_name(lse_index, "dsa_lse")
-            keys = checkpoint_name(keys, "dsa_keys")
+            # a rematerialised layer keeps the selection (named here) and what
+            # ``indexed_attention`` makes of it (named there, ``KEPT_NAMES``)
+            mask, lse_index, keys = (
+                checkpoint_name(a, n) for a, n in zip((mask, lse_index, keys), SELECTION_NAMES)
+            )
             o, kl = indexed_attention(
                 q, k, v, q_index, k_index, weight, mask, lse_index, blocks=cfg.blocks, interpret=interpret
             )
@@ -321,7 +328,7 @@ class IndexedSparseMoE:
 
         body = jax.checkpoint(
             body,
-            policy=jax.checkpoint_policies.save_only_these_names("dsa_mask", "dsa_lse", "dsa_keys"),
+            policy=jax.checkpoint_policies.save_only_these_names(*SELECTION_NAMES, *KEPT_NAMES),
             prevent_cse=False,
         )
         x, per_layer = jax.lax.scan(body, x, params["layers"])

@@ -33,6 +33,15 @@ Six ``pallas_call`` names, which the benchmark's readers find in a trace:
   and in the same pass its gradient to ``qI``, ``kI`` and ``w`` (the
   backward pass scales it by the loss's cotangent).
 
+**What the backward pass is handed.**  The forward rule's residuals are the
+attention's operands, the bits, and five values that carry a
+``checkpoint_name`` (``KEPT_NAMES``): ``o``, its ``lse`` a row (one lane of
+the kernel's eight: stacked over a model's layers the eight would each pad
+to 128), and ``L_I``'s gradient to ``qI``, ``w`` and ``kI`` in float32, as
+``dsa_probs`` left it.  A caller that rematerialises its layers lists those
+names in its policy and ``dsa_attn_fwd`` and ``dsa_probs`` run once; one that
+lists none computes both again in its backward pass, to the same bits.
+
 :func:`indexed_attention_plain` is the same mathematics in ``jax.numpy`` with
 dense ``[S, S]`` arrays: the path off the TPU and the tests' oracle.
 """
@@ -45,6 +54,7 @@ from typing import NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -53,6 +63,8 @@ _LANES = 128
 _ROW_LANES = 8  # rowwise outputs carry a trailing 8-lane dim (ops/flash_attention.py)
 _INT_MIN = np.int32(-(2**31))
 _VMEM_LIMIT = 100 * 1024 * 1024
+# the forward rule's residuals that a rematerialising caller's policy may keep
+KEPT_NAMES = ("dsa_o", "dsa_attn_lse", "dsa_d_qi", "dsa_d_w", "dsa_d_ki")
 
 
 class Blocks(NamedTuple):
@@ -629,6 +641,11 @@ def _attend_fwd(q, k, v, q_index, k_index, w, mask, lse_index, sm_scale, blocks,
     kl, d_qi, d_w, d_ki = _index_loss(
         qh, kh, lse, mask, qih, wh, k_index, _row_lanes(lse_index), sm_scale, blocks, interpret
     )
+    # the named values ARE the residuals: a policy that keeps the names leaves
+    # the backward pass no use for either kernel above
+    o, lse, d_qi, d_w, d_ki = (
+        checkpoint_name(a, n) for a, n in zip((o, lse[..., 0], d_qi, d_w, d_ki), KEPT_NAMES)
+    )
     like = tuple(jnp.zeros((0,), a.dtype) for a in (q_index, k_index, w))  # the cotangents' dtypes
     kept = (qh, kh, vh, mask, o, lse, d_qi, d_w, d_ki, like)
     return (o.transpose(0, 2, 1, 3), jnp.sum(kl)), kept
@@ -639,7 +656,8 @@ def _attend_bwd(sm_scale, blocks, interpret, kept, cotangents):
     qi_dtype, ki_dtype, w_dtype = (a.dtype for a in like)
     do, g = cotangents
     dq, dk, dv = _attn_bwd(
-        qh, kh, vh, mask, o, lse, do.transpose(0, 2, 1, 3).astype(o.dtype), sm_scale, blocks, interpret
+        qh, kh, vh, mask, o, _row_lanes(lse), do.transpose(0, 2, 1, 3).astype(o.dtype), sm_scale, blocks,
+        interpret,
     )
     t = lambda a: a.transpose(0, 2, 1, 3)  # noqa: E731
     g = g.astype(jnp.float32)
@@ -647,7 +665,7 @@ def _attend_bwd(sm_scale, blocks, interpret, kept, cotangents):
         t(dq), t(dk), t(dv),
         (g * t(d_qi)).astype(qi_dtype), (g * d_ki).astype(ki_dtype),
         (g * d_w.transpose(0, 2, 1)).astype(w_dtype),
-        None, jnp.zeros_like(lse[:, 0, :, 0]),
+        None, jnp.zeros_like(lse[:, 0]),
     )
 
 
