@@ -31,6 +31,12 @@ INDEXED_READERS = (
     "dsa_attn_roofline", "dsa_moe_gmm_roofline", "dsa_step_mfu_pct", "dsa_keys_per_query",
 )
 EXPERT_CELLS = ("moe_gmm_ms", "moe_rows_here_per_step", "moe_load_max_over_mean")
+# PR 35: the readers of the cell nemotron3nano-ws1-seq16k (their own tests:
+# ftbench/tests/test_ftbench_ssm.py), which also joined the experts' three lists and flash's three
+SSM_READERS = (
+    "ssd_fwd_ms", "ssd_bwd_ms", "ssd_roofline", "ssm_flash_roofline", "ssm_moe_gmm_roofline", "ssm_step_mfu_pct",
+)
+FLASH_CELLS = ("flash_fwd_ms", "flash_dq_ms", "flash_dkv_ms")
 
 
 @pytest.mark.parametrize("name", sorted(LATER_READINGS))
@@ -44,14 +50,15 @@ def test_new_readers_are_the_eighteen_benchmark_json_lists():  # noqa: F811
     a later PR appends, so here they are the eighteen before the later ones."""
     with open(os.path.join(theirs.ROOT, "BENCHMARK.json")) as f:
         per_layer = json.load(f)["per_layer"]
-    appended = (LATER_READINGS, LING_READERS, BUCKET_READERS, ORDER_READERS, INDEXED_READERS)
+    appended = (LATER_READINGS, LING_READERS, BUCKET_READERS, ORDER_READERS, INDEXED_READERS, SSM_READERS)
     later = sum(map(len, appended))
     assert [m["name"] for m in per_layer[-later:]] == [name for group in appended for name in group]
     theirs_new = set(theirs.READINGS) | set(theirs.KILL_READINGS) | {"flash_fwd_ms", "flash_dq_ms", "flash_dkv_ms"}
     assert len(theirs_new) == 18
     assert {m["name"] for m in per_layer[-18 - later:-later]} == theirs_new
     for entry in per_layer[-18 - later:]:
-        assert len(entry["workloads"]) == (2 if entry["name"] in EXPERT_CELLS else 1) and set(entry) == {
+        cells = 3 if entry["name"] in EXPERT_CELLS else 2 if entry["name"] in FLASH_CELLS else 1
+        assert len(entry["workloads"]) == cells and set(entry) == {
             "name", "unit", "better", "source", "layer", "moves", "workloads",
         }
 

@@ -30,6 +30,12 @@ PUBLISHED_WIDTHS = {
         sa_config=dict(indexer_head_dim=64, indexer_num_heads=16, indexer_num_kv_heads=1, kv_chunk_size=512,
                        q_chunk_size=512, topk=2048),
     ),
+    "https://huggingface.co/nvidia/NVIDIA-Nemotron-3-Nano-30B-A3B-BF16/blob/main/config.json": dict(
+        hidden_size=2688, intermediate_size=1856, moe_intermediate_size=1856,
+        moe_shared_expert_intermediate_size=3712, num_attention_heads=32, num_key_value_heads=2, head_dim=128,
+        mamba_num_heads=64, mamba_head_dim=64, ssm_state_size=128, n_groups=8, conv_kernel=4, chunk_size=128,
+        expand=2, num_experts_per_tok=6,
+    ),
 }
 
 

@@ -241,3 +241,21 @@ def test_indexed_attention_is_model_code_over_kernels(module: str, row: str, may
     the models', importing kernels and model code and nothing of the Manager."""
     assert [ROWS[i][0] for i in _rows_of(module)] == [row]
     assert {target for importer, _line, target in _inner_edges() if importer == module} == may_import
+
+
+@pytest.mark.parametrize(
+    "module,row,may_import",
+    [
+        ("ops.ssd", "store-kernels-data", {"ops.kda"}),
+        (
+            "models.ssm_hybrid_moe", "compiled-step-models",
+            {"ops.ssd", "ops.flash_attention", "parallel.moe", "models.llama", "models.ling_hybrid"},
+        ),
+    ],
+)
+def test_the_state_space_scan_is_model_code_over_kernels(module: str, row: str, may_import: set) -> None:
+    """PR 35's two modules: the scan's kernels in the kernels' row (sharing
+    ``ops/kda.py``'s products), the model in the models', importing kernels
+    and model code and nothing of the Manager."""
+    assert [ROWS[i][0] for i in _rows_of(module)] == [row]
+    assert {target for importer, _line, target in _inner_edges() if importer == module} == may_import

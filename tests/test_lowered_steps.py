@@ -1,0 +1,79 @@
+"""The gradient steps of the models that share ``RoutedExperts`` and
+``flash_attention`` lower, at toy widths and on both paths (plain, and the
+kernels in interpret mode), to the bytes they lowered to (but for the
+counters jax appends to the names of private functions) before a fourth
+model's expert form and kept names went in (PR 35: the digests under
+``tests/fixtures/lowered_steps.json`` were written by THIS file run on the
+parent commit).  A later change that means to alter one of these programs
+writes the fixture anew and says so: ``python tests/test_lowered_steps.py
+--write``."""
+
+import hashlib
+import json
+import os
+import re
+import sys
+
+import pytest
+
+FIXTURE = os.path.join(os.path.dirname(os.path.abspath(__file__)), "fixtures", "lowered_steps.json")
+CASES = [(m, p) for m in ("ling_hybrid", "indexed_sparse_moe") for p in ("plain", "kernels")]
+
+
+def _model(name):
+    if name == "ling_hybrid":
+        from torchft_tpu.models.ling_hybrid import LingHybrid, ling_debug
+
+        return LingHybrid(ling_debug()), 128
+    from torchft_tpu.models.indexed_sparse_moe import IndexedSparseMoE, indexed_sparse_debug
+
+    return IndexedSparseMoE(indexed_sparse_debug()), 32
+
+
+def digest(name: str, path: str) -> str:
+    import jax
+    import numpy as np
+
+    from torchft_tpu.parallel.hsdp import make_grad_step
+    from torchft_tpu.parallel.mesh import make_mesh
+
+    before = os.environ.get("TORCHFT_FLASH")
+    os.environ["TORCHFT_FLASH"] = "1" if path == "kernels" else "0"
+    try:
+        model, seq = _model(name)
+        mesh = make_mesh(fsdp=1, devices=jax.devices()[:1])
+        params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+        tokens = jax.ShapeDtypeStruct((1, seq), np.int32)
+        text = make_grad_step(model, mesh).lower(params, (tokens, tokens)).as_text()
+        assert ("plain" in model.attention_path) == (path == "plain"), model.attention_path
+        # jax numbers its private functions (@silu_808) from one counter a
+        # process: the numbers say what else was traced, not what the program is
+        text = re.sub(r"@([A-Za-z_]\w*?)_\d+\b", r"@\1", text)
+        return hashlib.sha256(text.encode()).hexdigest()
+    finally:
+        if before is None:
+            del os.environ["TORCHFT_FLASH"]
+        else:
+            os.environ["TORCHFT_FLASH"] = before
+
+
+@pytest.mark.parametrize("name,path", CASES)
+def test_lowers_to_the_bytes_it_lowered_to(name, path):
+    with open(FIXTURE) as f:
+        want = json.load(f)
+    if want["jax"] != __import__("jax").__version__:
+        pytest.skip(f"the digests were written under jax {want['jax']}")
+    assert digest(name, path) == want["sha256"][f"{name}:{path}"], (
+        f"{name}'s gradient step ({path}) lowers to another program than the fixture's: if that is "
+        "meant, write the fixture anew (python tests/test_lowered_steps.py --write) and say so"
+    )
+
+
+if __name__ == "__main__" and "--write" in sys.argv:
+    import jax
+
+    out = {"jax": jax.__version__, "sha256": {f"{n}:{p}": digest(n, p) for n, p in CASES}}
+    target = sys.argv[sys.argv.index("--write") + 1] if len(sys.argv) > sys.argv.index("--write") + 1 else FIXTURE
+    with open(target, "w") as f:
+        json.dump(out, f, indent=1)
+    print(json.dumps(out, indent=1))

@@ -149,12 +149,14 @@ def _rope_interleaved(x: jax.Array, theta: float) -> jax.Array:
     return out.reshape(x.shape).astype(x.dtype)
 
 
-def _short_conv_silu(x: jax.Array, w: jax.Array) -> jax.Array:
-    """Causal depthwise convolution (the last tap is the current token's)
-    and SiLU.  x [B, S, C], w [K, C]."""
+def _short_conv_silu(x: jax.Array, w: jax.Array, bias: Optional[jax.Array] = None) -> jax.Array:
+    """Causal depthwise convolution (the last tap is the current token's),
+    a bias a channel where one is given, and SiLU.  x [B, S, C], w [K, C]."""
     K, S = w.shape[0], x.shape[1]
     padded = jnp.pad(x, ((0, 0), (K - 1, 0), (0, 0)))
     acc = sum(padded[:, j : j + S].astype(jnp.float32) * w[j].astype(jnp.float32) for j in range(K))
+    if bias is not None:
+        acc = acc + bias.astype(jnp.float32)
     return jax.nn.silu(acc).astype(x.dtype)
 
 

@@ -29,6 +29,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -408,8 +409,15 @@ def _flash_hm(q, k, v, sm_scale, causal, block_q, block_k, interpret):
     return o
 
 
+# the forward rule's residuals that a rematerialising caller's policy may
+# keep, so that ``flash_fwd`` runs once a step (a policy that lists neither,
+# as ``nothing_saveable``, is served as before)
+KEPT_NAMES = ("flash_o", "flash_lse")
+
+
 def _flash_hm_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret):
     o, lse = _fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret)
+    o, lse = (checkpoint_name(a, n) for a, n in zip((o, lse), KEPT_NAMES))
     return o, (q, k, v, o, lse)
 
 

@@ -22,7 +22,7 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 from functools import partial
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -195,6 +195,10 @@ class RoutedExpertsConfig:
     norm_topk_prob: bool = True
     shared_hidden: int = 0  # width of the shared expert; 0: none
     balance_loss_weight: float = 0.0  # sequence-wise, arXiv:2412.19437 eq. 17-20
+    # an expert's form, the routed experts' and the shared one's alike:
+    # "swiglu", three matrices, ``w_down (silu(w_gate x) * (w_up x))``, or
+    # "relu2", two and no gate, ``w_down relu(w_up x)^2``
+    expert_form: str = "swiglu"
     dtype: Any = jnp.bfloat16
 
 
@@ -208,6 +212,11 @@ def swiglu(gate: jax.Array, up: jax.Array, limit: float) -> jax.Array:
     return jax.nn.silu(gate) * up
 
 
+def relu2(up: jax.Array) -> jax.Array:
+    """``relu(up)^2``: the activation of an expert of two matrices."""
+    return jnp.square(jax.nn.relu(up))
+
+
 class RoutedExperts:
     """An expert layer that is TOLD which experts it holds.
 
@@ -218,8 +227,9 @@ class RoutedExperts:
     best experts inside them; the weights are the UNBIASED scores of the
     chosen, normalised over all ``top_k`` and scaled.  Nothing is dropped: the (token, choice) pairs
     that fall on held experts are sorted by expert into a static buffer and
-    go through a grouped SwiGLU whose work follows the rows really routed
-    here (``megablox.gmm`` on the TPU: ``path`` "gmm"; ``lax.ragged_dot``
+    go through the experts' grouped products (``expert_form``: a SwiGLU of
+    three matrices or a squared ReLU of two), whose work follows the rows
+    really routed here (``megablox.gmm`` on the TPU: ``path`` "gmm"; ``lax.ragged_dot``
     elsewhere).  What the absent experts would have added is left out; the
     shared expert is added once.  On one chip there is no exchange, and no
     code stands in for the absent chips.
@@ -237,11 +247,17 @@ class RoutedExperts:
             raise ValueError("num_experts must divide into n_group groups")
         if config.score_func not in ("sigmoid", "softmax"):
             raise ValueError(f"score_func {config.score_func!r} is neither sigmoid nor softmax")
+        if config.expert_form not in ("swiglu", "relu2"):
+            raise ValueError(f"expert_form {config.expert_form!r} is neither swiglu nor relu2")
         first, count = config.experts_held
         if first < 0 or count < 1 or first + count > config.num_experts:
             raise ValueError(f"experts_held {config.experts_held} outside 0..{config.num_experts}")
         # set when the experts are traced: "gmm" or "ragged_dot"
         self.path: Optional[str] = None
+        # an expert's matrices in the order they are applied, and the shared one's
+        gate = ("gate",) if config.expert_form == "swiglu" else ()
+        self.expert_leaves = tuple(f"w_{n}" for n in (*gate, "up", "down"))
+        self.shared_leaves = tuple(f"shared_{n}" for n in (*gate, "up", "down")) if config.shared_hidden else ()
 
     def init(self, key: jax.Array) -> Dict[str, Any]:
         cfg = self.config
@@ -267,20 +283,17 @@ class RoutedExperts:
                 shared_up=normal(keys[5], (cfg.dim, cfg.shared_hidden), cfg.dim),
                 shared_down=normal(keys[6], (cfg.shared_hidden, cfg.dim), cfg.shared_hidden),
             )
+        if cfg.expert_form == "relu2":  # no gate matrix, in either kind of expert
+            params = {k: v for k, v in params.items() if not k.endswith("_gate")}
         return params
 
     def param_specs(self) -> Dict[str, Any]:
         """One chip's share: nothing here is divided further."""
         cfg = self.config
-        specs = {
-            "router": P(None, None),
-            "w_gate": P(None, None, None), "w_up": P(None, None, None),
-            "w_down": P(None, None, None),
-        }
+        specs = {"router": P(None, None), **{name: P(None, None, None) for name in self.expert_leaves}}
         if cfg.selection_bias:
             specs["bias"] = P(None)
-        if cfg.shared_hidden:
-            specs.update(shared_gate=P(None, None), shared_up=P(None, None), shared_down=P(None, None))
+        specs.update({name: P(None, None) for name in self.shared_leaves})
         return specs
 
     # ------------------------------------------------------------------
@@ -321,13 +334,23 @@ class RoutedExperts:
         self.path = "ragged_dot"
         return jax.lax.ragged_dot(lhs, rhs, sizes)
 
+    def _activate(self, hidden: List[jax.Array], limit: float) -> jax.Array:
+        """An expert's hidden activation from its matrices' products, by
+        ``expert_form``: ``[gate, up]`` or ``[up]``."""
+        if self.config.expert_form == "swiglu":
+            return swiglu(*hidden, limit)
+        if limit:
+            raise ValueError("a SwiGLU clamp was given to experts that have no gate")
+        return relu2(*hidden)
+
     def _through(
-        self, cap: int, limit: float, x: jax.Array, weights: jax.Array, w_gate: jax.Array,
-        w_up: jax.Array, w_down: jax.Array, order: jax.Array, sizes: jax.Array,
+        self, cap: int, limit: float, x: jax.Array, weights: jax.Array, *rest: jax.Array
     ) -> jax.Array:
         """The held experts' part, [T, D] float32, through a buffer of
         ``cap`` rows: the first ``cap`` of the (token, choice) pairs in
-        ``order`` (by held expert; those of absent experts last)."""
+        ``order`` (by held expert; those of absent experts last).  ``rest``
+        is the experts' matrices (``expert_leaves``), ``order``, ``sizes``."""
+        *w_in, w_down, order, sizes = rest
         T, k = weights.shape
         pair = order[:cap]
         token = pair // k
@@ -337,7 +360,7 @@ class RoutedExperts:
         # pass too, and what lies there (a NaN, sooner or later) must reach
         # neither the result nor x's gradient
         xs = jnp.where(routed[:, None], jnp.take(x, token, axis=0), 0)
-        act = swiglu(self._grouped(xs, w_gate, sizes), self._grouped(xs, w_up, sizes), limit)
+        act = self._activate([self._grouped(xs, w, sizes) for w in w_in], limit)
         ys = self._grouped(act.astype(xs.dtype), w_down, sizes)
         w_row = jnp.where(routed, jnp.take(weights.reshape(-1), pair), 0.0)
         ys = jnp.where(routed[:, None], ys, 0).astype(jnp.float32) * w_row[:, None]
@@ -372,10 +395,8 @@ class RoutedExperts:
             return jax.lax.cond(jnp.sum(sizes) <= usual, lambda: f(usual), lambda: f(full))
 
         @jax.custom_vjp
-        def part(x, weights, w_gate, w_up, w_down, order, sizes):
-            return sized(
-                lambda cap: self._through(cap, limit, x, weights, w_gate, w_up, w_down, order, sizes), sizes
-            )
+        def part(*operands):  # x, weights, the experts' matrices, order, sizes
+            return sized(lambda cap: self._through(cap, limit, *operands), operands[-1])
 
         def part_fwd(*operands):
             return part(*operands), operands
@@ -390,7 +411,7 @@ class RoutedExperts:
             return (*sized(grads, sizes), None, None)  # the two integer operands have no cotangent
 
         part.defvjp(part_fwd, part_bwd)
-        return part(x, weights, params["w_gate"], params["w_up"], params["w_down"], order, sizes)
+        return part(x, weights, *(params[name] for name in self.expert_leaves), order, sizes)
 
     def apply(
         self, params: Dict[str, Any], x: jax.Array,
@@ -414,8 +435,9 @@ class RoutedExperts:
         sizes = load[first : first + held].astype(jnp.int32)
         out = self._held_part(params, flat, chosen, weights, sizes, swiglu_limit)
         if cfg.shared_hidden:
-            act = swiglu(flat @ params["shared_gate"], flat @ params["shared_up"], shared_swiglu_limit)
-            out = out + (act @ params["shared_down"]).astype(jnp.float32)
+            *w_in, w_down = (params[name] for name in self.shared_leaves)
+            act = self._activate([flat @ w for w in w_in], shared_swiglu_limit)
+            out = out + (act @ w_down).astype(jnp.float32)
         balance = jnp.zeros((), jnp.float32)
         if cfg.balance_loss_weight:
             # per sequence: f_i the share of choices that fell on expert i
