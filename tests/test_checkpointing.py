@@ -379,3 +379,301 @@ class TestStreamingPlan:
         np.testing.assert_array_equal(
             loads_pytree(buf.getvalue())["p"], np.arange(1000, dtype=np.float32)
         )
+
+
+# -- the send path brings the next leaves to the host while the last one is
+# -- being written (PytreePlan.host_leaves)
+
+
+def _reference_stream(plan) -> bytes:
+    """The serialized form as the plain loop wrote it before the leaves came
+    ahead: the header, then for every leaf its length and its bytes."""
+    import struct
+
+    from torchft_tpu.checkpointing.serialization import materialize_leaf
+
+    out = [plan.header]
+    for leaf, nbytes in zip(plan.leaves, plan.leaf_nbytes):
+        out += [struct.pack("<Q", nbytes), materialize_leaf(leaf).tobytes()]
+    return b"".join(out)
+
+
+def _plan_of(kind: str):
+    """A plan of five leaves of 1,000 to 3,000 bytes: numpy leaves, jax
+    leaves, or jax ``Shard``s (what a multi-host array's plan holds)."""
+    import jax
+
+    from torchft_tpu.checkpointing.serialization import plan_pytree
+
+    rng = np.random.default_rng(11)
+    arrays = [rng.normal(size=250 * (1 + i % 3)).astype(np.float32) for i in range(5)]
+    if kind == "numpy":
+        return plan_pytree({f"l{i}": a for i, a in enumerate(arrays)})
+    plan = plan_pytree({f"l{i}": jax.numpy.asarray(a) for i, a in enumerate(arrays)})
+    if kind == "shard":
+        plan.leaves = [leaf.addressable_shards[0] for leaf in plan.leaves]
+    return plan
+
+
+def _ranges_of(plan, which: str):
+    first = len(plan.header)  # where leaf 0's frame begins
+    second = first + 8 + plan.leaf_nbytes[0]
+    return {
+        "whole": [(0, plan.total_len)],
+        "cuts_leaves": [(0, second + 100), (second + 100, plan.total_len - 7), (plan.total_len - 7, plan.total_len)],
+        "inside_one_leaf": [(second + 8 + 40, second + 8 + 440)],
+        "header_only": [(0, first), (3, first - 2)],
+        # ends on a leaf's length and begins on the next one's: no payload
+        "lengths_only": [(first - 1, first + 8), (second + 2, second + 8)],
+    }[which]
+
+
+@pytest.mark.parametrize("which", ["whole", "cuts_leaves", "inside_one_leaf", "header_only", "lengths_only"])
+@pytest.mark.parametrize("kind", ["jax", "numpy", "shard"])
+def test_write_range_is_the_plain_loops_stream(kind, which) -> None:
+    plan = _plan_of(kind)
+    want = _reference_stream(plan)
+    assert len(want) == plan.total_len
+    if which == "whole":  # and what a durable save of the same arrays writes
+        from torchft_tpu.checkpointing.serialization import materialize_leaf
+
+        assert dumps_pytree({f"l{i}": materialize_leaf(l) for i, l in enumerate(plan.leaves)}) == want
+    for start, stop in _ranges_of(plan, which):
+        buf = io.BytesIO()
+        sent = plan.write_range(start, stop, buf)
+        assert buf.getvalue() == want[start:stop]
+        assert 0 <= sent.ahead_bytes <= stop - start and sent.d2h_s >= 0.0
+        if kind == "numpy" or which in ("inside_one_leaf", "header_only", "lengths_only"):
+            assert sent.ahead_bytes == 0  # nothing was, or could be, asked ahead
+
+
+class _RecordingLeaf:
+    """Stands for a device array: ``copy_to_host_async`` and the read of its
+    host value (``np.asarray``) are written into ``log``."""
+
+    def __init__(self, log, index: int, nbytes: int, hold_s: float = 0.0) -> None:
+        self._log, self._index, self._hold_s = log, index, hold_s
+        self._value = np.full(nbytes, index + 1, np.uint8)
+        self.dtype, self.shape = self._value.dtype, self._value.shape
+
+    def copy_to_host_async(self) -> None:
+        self._log.append(("ask", self._index))
+
+    def __array__(self, dtype=None, copy=None):
+        self._log.append(("host", self._index))
+        if self._hold_s:
+            import time
+
+            time.sleep(self._hold_s)
+        return self._value
+
+
+class _RecordingShard:
+    """A jax ``Shard``'s shape: the array is its ``.data``."""
+
+    def __init__(self, data) -> None:
+        self.data = data
+
+
+class _RecordingStream:
+    def __init__(self, log, fail_after=None) -> None:
+        self._log, self._fail_after, self.wrote = log, fail_after, 0
+        self.parts = []
+
+    def write(self, b) -> int:
+        if self._fail_after is not None and self.wrote + len(b) > self._fail_after:
+            raise ConnectionResetError("the peer hung up")
+        self.wrote += len(b)
+        self.parts.append(bytes(b))
+        self._log.append(("write", len(b)))
+        return len(b)
+
+
+def _recording_plan(sizes, log, numpy_at=(), shard_at=(), hold_s=0.0):
+    from torchft_tpu.checkpointing.serialization import PytreePlan
+
+    leaves = []
+    for i, n in enumerate(sizes):
+        if i in numpy_at:
+            leaves.append(np.full(n, i + 1, np.uint8))
+        elif i in shard_at:
+            leaves.append(_RecordingShard(_RecordingLeaf(log, i, n, hold_s)))
+        else:
+            leaves.append(_RecordingLeaf(log, i, n, hold_s))
+    header = b"HEADER--"
+    plan = PytreePlan(header=header, leaves=leaves, leaf_nbytes=list(sizes),
+                      total_len=len(header) + sum(8 + n for n in sizes))
+    want = _reference_stream(plan)
+    log.clear()  # the reference's own reads
+    return plan, want
+
+
+def _frame_start(plan, index: int) -> int:
+    return len(plan.header) + sum(8 + n for n in plan.leaf_nbytes[:index])
+
+
+SIZES = [300, 100, 100, 500, 100, 100, 100, 400]
+
+
+@pytest.mark.parametrize(
+    "ahead_leaves,ahead_bytes",
+    [(1, 1 << 20), (2, 1 << 20), (4, 1 << 20), (len(SIZES), 1 << 20), (4, 250), (2, 50)],
+    ids=["one", "two", "four", "all", "four_within_250_bytes", "bytes_under_a_leaf"],
+)
+@pytest.mark.parametrize("first,last", [(0, 7), (2, 6)], ids=["whole", "leaves_2_to_6"])
+def test_next_leaves_are_asked_before_the_last_is_written(ahead_leaves, ahead_bytes, first, last, monkeypatch) -> None:
+    from torchft_tpu.checkpointing import serialization
+
+    monkeypatch.setattr(serialization, "_D2H_AHEAD_LEAVES", ahead_leaves)
+    monkeypatch.setattr(serialization, "_D2H_AHEAD_BYTES", ahead_bytes)
+    log = []
+    plan, want = _recording_plan(SIZES, log, shard_at=(3, 4))
+    # from inside leaf ``first`` to inside leaf ``last``
+    start, stop = _frame_start(plan, first) + 8 + 10, _frame_start(plan, last) + 8 + 10
+    stream = _RecordingStream(log)
+    sent = plan.write_range(start, stop, stream)
+    assert b"".join(stream.parts) == want[start:stop]
+
+    wanted = list(range(first, last + 1))
+    asks = [i for what, i in log if what == "ask"]
+    assert asks == wanted  # each once, in the stream's order, none outside the range
+    assert [i for what, i in log if what == "host"] == wanted
+    at = {entry: n for n, entry in enumerate(log) if entry[0] != "write"}
+    payload_writes = [n for n, entry in enumerate(log) if entry[0] == "write" and entry[1] > 8]
+    for k, i in enumerate(wanted[:-1]):
+        # leaf i+1 is under way BEFORE leaf i is waited for, let alone written
+        assert at[("ask", i + 1)] < at[("host", i)] < payload_writes[k]
+    # never further ahead than the window, in leaves and in bytes (the next
+    # one always): at every ask, count from the leaf the send waits for next
+    for n, (what, j) in enumerate(log):
+        if what != "ask":
+            continue
+        waits_for = first + sum(1 for w, _ in log[:n] if w == "host")
+        assert j - waits_for <= ahead_leaves
+        assert j <= waits_for + 1 or sum(SIZES[waits_for + 1 : j + 1]) <= ahead_bytes
+    # the first leaf's transfer was not under way when the send came to it
+    written = [min(stop, _frame_start(plan, i) + 8 + SIZES[i]) - max(start, _frame_start(plan, i) + 8) for i in wanted]
+    assert sent.ahead_bytes == sum(written[1:])
+
+
+@pytest.mark.parametrize(
+    "sizes,numpy_at,start_in,stop_in,asks",
+    [
+        # a range inside one leaf (a striped healer's request): nothing ahead
+        ([400, 400, 400], (), (1, 50), (1, 350), []),
+        # a plan of one leaf does what np.asarray does
+        ([400], (), (0, 0), (0, 400), []),
+        # numpy leaves have no transfer; the device leaves among them do
+        ([100, 100, 100, 100], (1, 3), (0, 0), (3, 100), [0, 2]),
+        ([100, 100, 100], (0, 1, 2), (0, 0), (2, 100), []),
+        # the range ends on leaf 1's last byte: leaf 2 is not touched
+        ([100, 100, 100], (), (0, 0), (1, 100), [0, 1]),
+        # it ends inside leaf 2's LENGTH: its payload is not wanted either
+        ([100, 100, 100], (), (0, 0), (2, -4), [0, 1]),
+    ],
+    ids=["inside_one_leaf", "one_leaf_plan", "numpy_between", "all_numpy", "ends_on_a_leaf", "ends_in_a_length"],
+)
+def test_what_is_asked_follows_what_the_range_needs(sizes, numpy_at, start_in, stop_in, asks) -> None:
+    log = []
+    plan, want = _recording_plan(sizes, log, numpy_at=numpy_at)
+    start = _frame_start(plan, start_in[0]) + 8 + start_in[1]
+    stop = _frame_start(plan, stop_in[0]) + 8 + stop_in[1]
+    stream = _RecordingStream(log)
+    plan.write_range(start, stop, stream)
+    assert b"".join(stream.parts) == want[start:stop]
+    assert [i for what, i in log if what == "ask"] == asks
+    touched = [i for i in range(len(sizes)) if i not in numpy_at
+               and max(start, _frame_start(plan, i) + 8) < min(stop, _frame_start(plan, i) + 8 + sizes[i])]
+    assert [i for what, i in log if what == "host"] == touched
+
+
+@pytest.mark.parametrize("fail_after", [20, 450, 700], ids=["in_leaf_0", "in_leaf_1", "in_leaf_2"])
+def test_a_writer_that_raises_leaves_the_plan_servable(fail_after) -> None:
+    log = []
+    plan, want = _recording_plan([300, 200, 300, 100], log)
+    with pytest.raises(ConnectionResetError):
+        plan.write_range(0, plan.total_len, _RecordingStream(log, fail_after=fail_after))
+    # the next request gets every byte, and no leaf's transfer is asked twice
+    buf = io.BytesIO()
+    sent = plan.write_range(0, plan.total_len, buf)
+    assert buf.getvalue() == want
+    asks = [i for what, i in log if what == "ask"]
+    assert sorted(asks) == [0, 1, 2, 3]
+    # whatever the first request had asked for counts as brought ahead
+    assert sent.ahead_bytes >= 200 + 300 + 100
+
+
+def _join_all(threads) -> None:
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=60.0)
+    assert not any(t.is_alive() for t in threads)
+
+
+def test_two_handlers_over_one_leaf_bring_it_to_the_host_once() -> None:
+    log = []
+    plan, want = _recording_plan([100, 4000, 100], log, hold_s=0.2)
+    inside = _frame_start(plan, 1) + 8
+    ranges = [(inside + 10, inside + 2000), (inside + 2000, inside + 3990)]
+    outs = [io.BytesIO() for _ in ranges]
+    barrier = threading.Barrier(len(ranges))
+
+    def handler(n: int) -> None:
+        barrier.wait(timeout=30.0)
+        plan.write_range(*ranges[n], outs[n])
+
+    _join_all([threading.Thread(target=handler, args=(n,)) for n in range(len(ranges))])
+    assert [out.getvalue() for out in outs] == [want[a:b] for a, b in ranges]
+    assert log.count(("host", 1)) == 1 and not [e for e in log if e[0] == "ask"]
+
+
+def test_many_handlers_never_ask_for_a_leaf_twice() -> None:
+    """More handlers than cores over one staged plan, whole and partial
+    ranges mixed: every response is the stream's bytes and no leaf's
+    transfer is started a second time."""
+    import sys
+
+    log = []
+    sizes = [64, 900, 32, 900, 900, 16, 700, 64]
+    plan, want = _recording_plan(sizes, log, numpy_at=(2,), shard_at=(4,))
+    rng = np.random.default_rng(3)
+    jobs = [(0, plan.total_len)] * 6 + [
+        tuple(sorted(int(x) for x in rng.integers(0, plan.total_len + 1, 2))) for _ in range(26)
+    ]
+    outs = [io.BytesIO() for _ in jobs]
+    barrier = threading.Barrier(len(jobs))
+
+    def handler(n: int) -> None:
+        barrier.wait(timeout=30.0)
+        plan.write_range(*jobs[n], outs[n])
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        _join_all([threading.Thread(target=handler, args=(n,)) for n in range(len(jobs))])
+    finally:
+        sys.setswitchinterval(interval)
+    assert [out.getvalue() for out in outs] == [want[a:b] for a, b in jobs]
+    asks = [i for what, i in log if what == "ask"]
+    assert len(asks) == len(set(asks)) and 2 not in asks
+
+
+def test_durable_save_rides_the_same_send_path(tmp_path) -> None:
+    """``save_pytree`` (what ``utils/checkpoint.py`` writes to disk) has no
+    loop of its own: a file of it loads back, and its leaves come ahead."""
+    log = []
+    plan, _ = _recording_plan([300, 200, 100], log)
+    from torchft_tpu.checkpointing import serialization
+
+    path = tmp_path / "state.tftc"
+    state = {"a": jnp.arange(300, dtype=jnp.float32), "b": np.arange(7), "c": jnp.ones((3, 5), jnp.bfloat16)}
+    with open(path, "wb") as f:
+        save_pytree(state, f)
+    with open(path, "rb") as f:
+        back = load_pytree(f)
+    assert path.stat().st_size == serialization.plan_pytree(state).total_len
+    for k in state:
+        np.testing.assert_array_equal(np.asarray(state[k]), back[k])
+    with open(path, "wb") as f:
+        assert plan.write_range(0, plan.total_len, f).ahead_bytes == 300

