@@ -41,6 +41,12 @@ FLASH_CELLS = ("flash_fwd_ms", "flash_dq_ms", "flash_dkv_ms")
 # PR 36: the share of a heal's bytes whose transfer to the host was under way
 # before the survivor's handler came to them, from HEAL_SERVE_END's ahead_bytes
 AHEAD_READERS = ("heal_serve_ahead_pct",)
+# PR 37: the compiled step shared out by the scopes the program names its parts
+# with (their own tests: tests/test_ftbench_device_scopes.py)
+SCOPE_READERS = (
+    "xla_mixer_proj_ms", "xla_mixer_glue_ms", "xla_ffn_ms", "xla_stream_ms", "xla_head_ms", "moe_route_ms",
+    "moe_dispatch_ms", "xla_layer_scan_ms", "optimizer_ms", "step_remat_ms", "xla_unscoped_ms",
+)
 
 
 @pytest.mark.parametrize("name", sorted(LATER_READINGS))
@@ -54,7 +60,10 @@ def test_new_readers_are_the_eighteen_benchmark_json_lists():  # noqa: F811
     a later PR appends, so here they are the eighteen before the later ones."""
     with open(os.path.join(theirs.ROOT, "BENCHMARK.json")) as f:
         per_layer = json.load(f)["per_layer"]
-    appended = (LATER_READINGS, LING_READERS, BUCKET_READERS, ORDER_READERS, INDEXED_READERS, SSM_READERS, AHEAD_READERS)
+    appended = (
+        LATER_READINGS, LING_READERS, BUCKET_READERS, ORDER_READERS, INDEXED_READERS, SSM_READERS, AHEAD_READERS,
+        SCOPE_READERS,
+    )
     later = sum(map(len, appended))
     assert [m["name"] for m in per_layer[-later:]] == [name for group in appended for name in group]
     theirs_new = set(theirs.READINGS) | set(theirs.KILL_READINGS) | {"flash_fwd_ms", "flash_dq_ms", "flash_dkv_ms"}
@@ -62,9 +71,28 @@ def test_new_readers_are_the_eighteen_benchmark_json_lists():  # noqa: F811
     assert {m["name"] for m in per_layer[-18 - later:-later]} == theirs_new
     for entry in per_layer[-18 - later:]:
         cells = 3 if entry["name"] in EXPERT_CELLS else 2 if entry["name"] in FLASH_CELLS else 1
+        if entry["name"] in SCOPE_READERS:  # the four one-replica cells, or the three that have the part
+            cells = 3 if entry["name"] in ("xla_ffn_ms", "moe_route_ms", "moe_dispatch_ms") else 4
         assert len(entry["workloads"]) == cells and set(entry) == {
             "name", "unit", "better", "source", "layer", "moves", "workloads",
         }
+
+
+@pytest.mark.parametrize(
+    "cell,new", theirs.test_rehearsal_would_report_the_program_span_metrics.pytestmark[0].args[1]
+)
+def test_rehearsal_would_report_the_program_span_metrics(cell, new, tmp_path, monkeypatch):  # noqa: F811
+    """Theirs, and of the same walk: a CPU's trace has no device plane, so
+    PR 37's readers of the scopes (``ftbench/device_scopes.py``) find nothing
+    to read in it and say so; the walk does not fail on them."""
+    from ftbench import device_scopes
+
+    seen = {}
+    rehearse = theirs._rehearse
+    monkeypatch.setattr(theirs, "_rehearse", lambda *a, **k: seen.setdefault("reported", rehearse(*a, **k)))
+    theirs.test_rehearsal_would_report_the_program_span_metrics(cell, new, tmp_path)
+    assert not seen["reported"] & set(SCOPE_READERS)
+    assert device_scopes.load(str(tmp_path / "ftbench")) == {}
 
 
 def _sync(t, warm=None, buckets=10, name="DDP_SYNC"):

@@ -233,7 +233,7 @@ def test_the_packages_edge() -> None:
     "module,row,may_import",
     [
         ("ops.indexed_attention", "store-kernels-data", set()),
-        ("models.indexed_sparse_moe", "compiled-step-models", {"ops.indexed_attention", "parallel.moe", "models.llama"}),
+        ("models.indexed_sparse_moe", "compiled-step-models", {"ops.indexed_attention", "parallel.moe", "models.llama", "obs.spans"}),
     ],
 )
 def test_indexed_attention_is_model_code_over_kernels(module: str, row: str, may_import: set) -> None:
@@ -249,7 +249,7 @@ def test_indexed_attention_is_model_code_over_kernels(module: str, row: str, may
         ("ops.ssd", "store-kernels-data", {"ops.kda"}),
         (
             "models.ssm_hybrid_moe", "compiled-step-models",
-            {"ops.ssd", "ops.flash_attention", "parallel.moe", "models.llama", "models.ling_hybrid"},
+            {"ops.ssd", "ops.flash_attention", "parallel.moe", "models.llama", "models.ling_hybrid", "obs.spans"},
         ),
     ],
 )

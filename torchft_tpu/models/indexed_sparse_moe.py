@@ -59,7 +59,8 @@ import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
-from torchft_tpu.models.llama import Llama
+from torchft_tpu.models.llama import Llama, _proj
+from torchft_tpu.obs.spans import part
 from torchft_tpu.ops.indexed_attention import (
     KEPT_NAMES, Blocks, indexed_attention, indexed_attention_plain, select_keys,
 )
@@ -243,6 +244,7 @@ class IndexedSparseMoE:
         """Why the Mosaic kernels do NOT apply, or None when they do."""
         return Llama._one_chip_refusal(self.config.blocks.refusal(seq), self.mesh)
 
+    @part("mixer_glue")
     def _index(
         self, h: jax.Array, ix: Dict[str, Any], positions: jax.Array
     ) -> Tuple[jax.Array, jax.Array, jax.Array]:
@@ -254,10 +256,11 @@ class IndexedSparseMoE:
         J, DI = cfg.index_heads, cfg.index_head_dim
         hs = jax.lax.stop_gradient(h)
         rope = lambda x: _mrope(x, positions, self.index_sections, cfg.rope_theta)  # noqa: E731
-        q_index = rope((hs @ ix["wq"]).reshape(B, S, J, DI))
-        k_index = rope(_unit_rms(hs @ ix["wk"], cfg.norm_eps))
-        return q_index, k_index, (hs @ ix["ww"]).astype(jnp.float32) * float((J * DI) ** -0.5)
+        q_index = rope(_proj(hs, ix["wq"]).reshape(B, S, J, DI))
+        k_index = rope(_unit_rms(_proj(hs, ix["wk"]), cfg.norm_eps))
+        return q_index, k_index, _proj(hs, ix["ww"]).astype(jnp.float32) * float((J * DI) ** -0.5)
 
+    @part("mixer_glue")
     def _attention(
         self, h: jax.Array, w: Dict[str, Any], positions: jax.Array, kernels: bool
     ) -> Tuple[jax.Array, jax.Array, jax.Array]:
@@ -267,9 +270,9 @@ class IndexedSparseMoE:
         H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
         a = w["attn"]
         rope = lambda x: _mrope(x, positions, cfg.mrope_section, cfg.rope_theta)  # noqa: E731
-        q = rope(Llama._rms_norm((h @ a["wq"]).reshape(B, S, H, hd), a["q_norm"], cfg.norm_eps))
-        k = rope(Llama._rms_norm((h @ a["wk"]).reshape(B, S, KV, hd), a["k_norm"], cfg.norm_eps))
-        v = (h @ a["wv"]).reshape(B, S, KV, hd)
+        q = rope(Llama._rms_norm(_proj(h, a["wq"]).reshape(B, S, H, hd), a["q_norm"], cfg.norm_eps))
+        k = rope(Llama._rms_norm(_proj(h, a["wk"]).reshape(B, S, KV, hd), a["k_norm"], cfg.norm_eps))
+        v = _proj(h, a["wv"]).reshape(B, S, KV, hd)
         q_index, k_index, weight = self._index(h, w["index"], positions)
         if kernels:
             interpret = Llama._assumed_backend() != "tpu"
@@ -286,7 +289,7 @@ class IndexedSparseMoE:
             )
         else:
             o, kl, keys = indexed_attention_plain(q, k, v, q_index, k_index, weight, topk=cfg.index_topk)
-        return o.reshape(B, S, H * hd) @ a["wo"], kl / (B * S), jnp.mean(keys)
+        return _proj(o.reshape(B, S, H * hd), a["wo"]), kl / (B * S), jnp.mean(keys)
 
     def _normed(self, x: jax.Array, weight: jax.Array) -> jax.Array:
         """What a layer reads of the float32 residual stream: its RMS norm,
@@ -297,12 +300,17 @@ class IndexedSparseMoE:
         self, x: jax.Array, w: Dict[str, Any], positions: jax.Array, kernels: bool
     ) -> Tuple[jax.Array, Tuple[jax.Array, ...]]:
         cfg = self.config
-        mixed, kl, keys = self._attention(self._normed(x, w["attn_norm"]), w, positions, kernels)
-        x = x + mixed
-        # the router reads the float32 norm itself: which 8 of 128 experts a
-        # token takes is a step function of it
-        out, load, balance = self.moe.apply(w["ffn"], Llama._rms_norm(x, w["mlp_norm"], cfg.norm_eps))
-        return x + out, (load, balance, kl, keys)
+        with part("stream"):
+            h = self._normed(x, w["attn_norm"])
+        mixed, kl, keys = self._attention(h, w, positions, kernels)
+        with part("stream"):
+            x = x + mixed
+            # the router reads the float32 norm itself: which 8 of 128 experts a
+            # token takes is a step function of it
+            h = Llama._rms_norm(x, w["mlp_norm"], cfg.norm_eps)
+        out, load, balance = self.moe.apply(w["ffn"], h)
+        with part("stream"):
+            return x + out, (load, balance, kl, keys)
 
     def _trunk(self, params: Dict[str, Any], batch: Tuple[Any, ...]) -> Tuple[jax.Array, Tuple[jax.Array, ...]]:
         """batch → (the residual stream after the last layer, a layer's
@@ -318,10 +326,11 @@ class IndexedSparseMoE:
         # twenty layer outputs of 0.03-0.08, and in bfloat16 each sum's
         # rounding, not the products', was the forward pass's distance from
         # the float32 reference (PERF.md section 6, PR 33)
-        x = params["embed"][tokens].astype(jnp.float32)
-        if len(batch) > 3:
-            embeds, given = batch[3], batch[4]
-            x = jnp.where(given[..., None], embeds.astype(jnp.float32), x)
+        with part("embed"):
+            x = params["embed"][tokens].astype(jnp.float32)
+            if len(batch) > 3:
+                embeds, given = batch[3], batch[4]
+                x = jnp.where(given[..., None], embeds.astype(jnp.float32), x)
 
         def body(carry, w):
             return self._block(carry, w, positions, kernels)
@@ -331,7 +340,8 @@ class IndexedSparseMoE:
             policy=jax.checkpoint_policies.save_only_these_names(*SELECTION_NAMES, *KEPT_NAMES),
             prevent_cse=False,
         )
-        x, per_layer = jax.lax.scan(body, x, params["layers"])
+        with part("layers"):
+            x, per_layer = jax.lax.scan(body, x, params["layers"])
         if kernels and self.moe.path not in (None, "gmm") and Llama._assumed_backend() == "tpu":
             refusal, kernels = f"the experts took {self.moe.path}", False
         path = KERNEL_PATH if kernels else f"plain: {refusal}"
@@ -340,6 +350,7 @@ class IndexedSparseMoE:
         self.attention_path = path
         return x, per_layer
 
+    @part("head")
     def _logits(self, params: Dict[str, Any], x: jax.Array) -> jax.Array:
         x = self._normed(x, params["final_norm"])
         # the products' float32 sums as they are: a logit is never rounded to the model's dtype
@@ -353,9 +364,10 @@ class IndexedSparseMoE:
 
     def _losses(self, params: Dict[str, Any], batch: Tuple[Any, ...]) -> Tuple[jax.Array, Tuple[jax.Array, ...]]:
         x, per_layer = self._trunk(params, batch)
-        logp = jax.nn.log_softmax(self._logits(params, x), axis=-1)
-        nll = -jnp.take_along_axis(logp, batch[1][..., None], axis=-1)[..., 0]
-        return jnp.mean(nll), per_layer
+        with part("head"):
+            logp = jax.nn.log_softmax(self._logits(params, x), axis=-1)
+            nll = -jnp.take_along_axis(logp, batch[1][..., None], axis=-1)[..., 0]
+            return jnp.mean(nll), per_layer
 
     def loss(self, params: Dict[str, Any], batch: Tuple[Any, ...]) -> jax.Array:
         """Mean next-token cross-entropy."""
@@ -369,8 +381,9 @@ class IndexedSparseMoE:
         is the optimizer's to leave alone) and the step's summary."""
         cfg = self.config
         loss, (load, balance, kl, keys) = self._losses(params, batch)
-        total = loss + cfg.index_loss_weight * jnp.sum(kl) + jnp.sum(balance)
-        return total, ([], self.step_summary(load, kl, keys))
+        with part("head"):
+            total = loss + cfg.index_loss_weight * jnp.sum(kl) + jnp.sum(balance)
+            return total, ([], self.step_summary(load, kl, keys))
 
     def step_summary(self, load: jax.Array, kl: jax.Array, keys: jax.Array) -> jax.Array:
         """Of this replica's step, on the device: ``[layers, 5]`` in the

@@ -53,7 +53,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from torchft_tpu.models.llama import Llama
+from torchft_tpu.models.llama import Llama, _proj
+from torchft_tpu.obs.spans import part
 from torchft_tpu.parallel.moe import RoutedExperts, RoutedExpertsConfig, swiglu
 
 logger = logging.getLogger(__name__)
@@ -341,6 +342,7 @@ class LingHybrid:
             logger.info("attention path: %s", path)
         self.attention_path = path
 
+    @part("mixer_glue")
     def _kda(self, h: jax.Array, w: Dict[str, jax.Array], kernels: bool) -> jax.Array:
         from torchft_tpu.ops.kda import kda_chunked, kda_chunked_plain
 
@@ -348,15 +350,15 @@ class LingHybrid:
         B, S, _ = h.shape
         H = cfg.n_heads
         heads = lambda a: a.reshape(B, S, H, -1)  # noqa: E731
-        q = _unit(heads(_short_conv_silu(h @ w["wq"], w["conv_q"])))
-        k = _unit(heads(_short_conv_silu(h @ w["wk"], w["conv_k"])))
-        v = heads(_short_conv_silu(h @ w["wv"], w["conv_v"]))
+        q = _unit(heads(_short_conv_silu(_proj(h, w["wq"]), w["conv_q"])))
+        k = _unit(heads(_short_conv_silu(_proj(h, w["wk"]), w["conv_k"])))
+        v = heads(_short_conv_silu(_proj(h, w["wv"]), w["conv_v"]))
         # the log of the decay, for every channel, in [lower_bound, 0]
         g = cfg.kda_lower_bound * jax.nn.sigmoid(
             jnp.exp(w["a_log"])[None, None, :, None]
-            * heads((h @ w["w_g"]).astype(jnp.float32) + w["dt_bias"])
+            * heads(_proj(h, w["w_g"]).astype(jnp.float32) + w["dt_bias"])
         )
-        beta = jax.nn.sigmoid((h @ w["w_beta"]).astype(jnp.float32))
+        beta = jax.nn.sigmoid(_proj(h, w["w_beta"]).astype(jnp.float32))
         if kernels:
             o = kda_chunked(
                 q, k, v, g, beta, chunk=KDA_CHUNK, interpret=Llama._assumed_backend() != "tpu"
@@ -364,19 +366,20 @@ class LingHybrid:
         else:
             o = kda_chunked_plain(q, k, v, g, beta, chunk=KDA_CHUNK)
         o = Llama._rms_norm(o, w["o_norm"], cfg.norm_eps)
-        o = o * jax.nn.sigmoid((h @ w["w_gate"]).astype(jnp.float32))[..., None].astype(o.dtype)
-        return o.reshape(B, S, -1) @ w["wo"]
+        o = o * jax.nn.sigmoid(_proj(h, w["w_gate"]).astype(jnp.float32))[..., None].astype(o.dtype)
+        return _proj(o.reshape(B, S, -1), w["wo"])
 
+    @part("mixer_glue")
     def _mla(self, h: jax.Array, w: Dict[str, jax.Array], kernels: bool) -> jax.Array:
         cfg = self.config
         B, S, _ = h.shape
         H, nope, rot = cfg.n_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
-        q = (h @ w["wq"]).reshape(B, S, H, nope + rot)
+        q = _proj(h, w["wq"]).reshape(B, S, H, nope + rot)
         q = jnp.concatenate([q[..., :nope], _rope_interleaved(q[..., nope:], cfg.rope_theta)], axis=-1)
-        kv_a = h @ w["w_kv_a"]
+        kv_a = _proj(h, w["w_kv_a"])
         latent = Llama._rms_norm(kv_a[..., : cfg.kv_lora_rank], w["kv_norm"], cfg.norm_eps)
         k_rot = _rope_interleaved(kv_a[..., cfg.kv_lora_rank :], cfg.rope_theta)
-        kv = (latent @ w["w_kv_b"]).reshape(B, S, H, nope + cfg.v_head_dim)
+        kv = _proj(latent, w["w_kv_b"]).reshape(B, S, H, nope + cfg.v_head_dim)
         k = jnp.concatenate(
             [kv[..., :nope], jnp.broadcast_to(k_rot[:, :, None, :], (B, S, H, rot))], axis=-1
         )
@@ -393,8 +396,8 @@ class LingHybrid:
             scores = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) / np.sqrt(nope + rot)
             scores = jnp.where(jnp.tril(jnp.ones((S, S), bool))[None, None], scores, -1e30)
             o = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(scores, axis=-1).astype(q.dtype), v)
-        o = o * jax.nn.sigmoid((h @ w["w_gate"]).astype(jnp.float32))[..., None].astype(o.dtype)
-        return o.reshape(B, S, -1) @ w["wo"]
+        o = o * jax.nn.sigmoid(_proj(h, w["w_gate"]).astype(jnp.float32))[..., None].astype(o.dtype)
+        return _proj(o.reshape(B, S, -1), w["wo"])
 
     def _block(
         self, x: jax.Array, w: Dict[str, Any], kind: Tuple[str, str, float, float], kernels: bool
@@ -403,14 +406,21 @@ class LingHybrid:
         balance loss)``."""
         cfg = self.config
         mixer = self._mla if kind[0] == "mla" else self._kda
-        x = x + mixer(Llama._rms_norm(x, w["attn_norm"], cfg.norm_eps), w["mixer"], kernels)
-        h = Llama._rms_norm(x, w["mlp_norm"], cfg.norm_eps)
+        with part("stream"):
+            h = Llama._rms_norm(x, w["attn_norm"], cfg.norm_eps)
+        mixed = mixer(h, w["mixer"], kernels)
+        with part("stream"):
+            x = x + mixed
+            h = Llama._rms_norm(x, w["mlp_norm"], cfg.norm_eps)
         if kind[1] == "dense":
             f = w["ffn"]
-            out = swiglu(h @ f["w_gate"], h @ f["w_up"], 0.0) @ f["w_down"]
-            return x + out, jnp.zeros((cfg.num_experts,), jnp.float32), jnp.zeros((), jnp.float32)
+            with part("ffn"):
+                out = swiglu(h @ f["w_gate"], h @ f["w_up"], 0.0) @ f["w_down"]
+            with part("stream"):
+                return x + out, jnp.zeros((cfg.num_experts,), jnp.float32), jnp.zeros((), jnp.float32)
         out, load, balance = self.moe.apply(w["ffn"], h, kind[2], kind[3])
-        return x + out, load, balance
+        with part("stream"):
+            return x + out, load, balance
 
     def _trunk(
         self, params: Dict[str, Any], tokens: jax.Array
@@ -421,7 +431,8 @@ class LingHybrid:
         cfg = self.config
         refusal = self._kernel_refusal(tokens.shape[1])
         kernels = refusal is None
-        x = params["embed"][tokens].astype(cfg.dtype)
+        with part("embed"):
+            x = params["embed"][tokens].astype(cfg.dtype)
         loads, balance = [], jnp.zeros((), jnp.float32)
         for (kind, _depth), stacked in zip(cfg.groups(), params["groups"]):
 
@@ -433,14 +444,17 @@ class LingHybrid:
             body = jax.checkpoint(
                 body, policy=jax.checkpoint_policies.nothing_saveable, prevent_cse=False
             )
-            x, (load, bal) = jax.lax.scan(body, x, stacked)
+            with part("layers"):
+                x, (load, bal) = jax.lax.scan(body, x, stacked)
             loads.append(load)
-            balance = balance + jnp.sum(bal)
+            with part("experts_route"):
+                balance = balance + jnp.sum(bal)
         if kernels and self.moe.path not in (None, "gmm") and Llama._assumed_backend() == "tpu":
             refusal, kernels = f"the experts took {self.moe.path}", False
         self._record_path(KERNEL_PATH if kernels else f"plain: {refusal}")
         return x, loads, balance, kernels
 
+    @part("head")
     def _logits(self, params: Dict[str, Any], x: jax.Array, norm: jax.Array) -> jax.Array:
         x = Llama._rms_norm(x, norm, self.config.norm_eps)
         return (x @ params["lm_head"]).astype(jnp.float32)
@@ -451,6 +465,7 @@ class LingHybrid:
         return self._logits(params, x, params["final_norm"])
 
     @staticmethod
+    @part("head")
     def _mean_nll(logits: jax.Array, targets: jax.Array) -> jax.Array:
         logp = jax.nn.log_softmax(logits, axis=-1)
         return jnp.mean(-jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0])
@@ -469,22 +484,23 @@ class LingHybrid:
             load for (kind, _), load in zip(cfg.groups(), loads) if kind[1] == "moe"
         ]
         if cfg.n_mtp:
-            m = params["mtp"]
-            z = jnp.concatenate(
-                [
-                    Llama._rms_norm(params["embed"][targets].astype(cfg.dtype), m["enorm"], cfg.norm_eps),
-                    Llama._rms_norm(x, m["hnorm"], cfg.norm_eps),
-                ],
-                axis=-1,
-            ) @ m["proj"]
-            z, load, bal = jax.checkpoint(
-                lambda z, w: self._block(z, w, ("mla", "moe", 0.0, 0.0), kernels),
-                policy=jax.checkpoint_policies.nothing_saveable, prevent_cse=False,
-            )(z, m["layer"])
-            mtp = self._mean_nll(self._logits(params, z, m["final_norm"]), jnp.roll(targets, -1, axis=1))
-            loss = loss + cfg.mtp_loss_weight * mtp
-            balance = balance + bal
-            signal.append(load)
+            with part("head"):  # the module's own layer names its parts itself
+                m = params["mtp"]
+                z = jnp.concatenate(
+                    [
+                        Llama._rms_norm(params["embed"][targets].astype(cfg.dtype), m["enorm"], cfg.norm_eps),
+                        Llama._rms_norm(x, m["hnorm"], cfg.norm_eps),
+                    ],
+                    axis=-1,
+                ) @ m["proj"]
+                z, load, bal = jax.checkpoint(
+                    lambda z, w: self._block(z, w, ("mla", "moe", 0.0, 0.0), kernels),
+                    policy=jax.checkpoint_policies.nothing_saveable, prevent_cse=False,
+                )(z, m["layer"])
+                mtp = self._mean_nll(self._logits(params, z, m["final_norm"]), jnp.roll(targets, -1, axis=1))
+                loss = loss + cfg.mtp_loss_weight * mtp
+                balance = balance + bal
+                signal.append(load)
         return loss, balance, signal
 
     def loss(self, params: Dict[str, Any], batch: Tuple[jax.Array, jax.Array]) -> jax.Array:
@@ -500,7 +516,8 @@ class LingHybrid:
         (the tokens each expert was chosen by) and the step's summary
         (:meth:`route_summary` of this replica's own signal)."""
         loss, balance, signal = self._losses(params, batch)
-        return loss + balance, (signal, self.route_summary(signal))
+        with part("head"):
+            return loss + balance, (signal, self.route_summary(signal))
 
     def num_params(self) -> int:
         return sum(int(np.prod(s.shape)) for s in jax.tree_util.tree_leaves(self._shapes))

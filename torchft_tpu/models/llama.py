@@ -33,7 +33,16 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from torchft_tpu.obs.spans import part
+
 logger = logging.getLogger(__name__)
+
+
+@part("mixer_proj")
+def _proj(x: jax.Array, w: jax.Array) -> jax.Array:
+    """A product into or out of a mixer, named so inside the mixer's glue
+    (``obs/spans.py``: the innermost scope is the operation's part)."""
+    return x @ w
 
 
 @dataclass(frozen=True)
@@ -436,23 +445,35 @@ class Llama:
         cos, sin = rope
         B, S, _ = x.shape
         hd = cfg.head_dim
-        h = self._rms_norm(x, layer_params["attn_norm"], cfg.norm_eps)
-        q = (h @ layer_params["wq"]).reshape(B, S, cfg.n_heads, hd)
-        k = (h @ layer_params["wk"]).reshape(B, S, cfg.n_kv_heads, hd)
-        v = (h @ layer_params["wv"]).reshape(B, S, cfg.n_kv_heads, hd)
-        q = self._apply_rope(q, cos, sin)
-        k = self._apply_rope(k, cos, sin)
-        attn = self._attention(q, k, v, positions)
-        return x + attn.reshape(B, S, cfg.n_heads * hd) @ layer_params["wo"]
+        # the parts of the compiled step (``obs/spans.py``): the innermost
+        # scope on an operation's path says which part made it
+        with part("stream"):
+            h = self._rms_norm(x, layer_params["attn_norm"], cfg.norm_eps)
+        with part("mixer_proj"):
+            q = (h @ layer_params["wq"]).reshape(B, S, cfg.n_heads, hd)
+            k = (h @ layer_params["wk"]).reshape(B, S, cfg.n_kv_heads, hd)
+            v = (h @ layer_params["wv"]).reshape(B, S, cfg.n_kv_heads, hd)
+        with part("mixer_glue"):
+            q = self._apply_rope(q, cos, sin)
+            k = self._apply_rope(k, cos, sin)
+            attn = self._attention(q, k, v, positions)
+        with part("mixer_proj"):
+            out = attn.reshape(B, S, cfg.n_heads * hd) @ layer_params["wo"]
+        with part("stream"):
+            return x + out
 
     def _ffn_block(
         self, x: jax.Array, layer_params: Dict[str, jax.Array]
     ) -> jax.Array:
         cfg = self.config
-        h = self._rms_norm(x, layer_params["mlp_norm"], cfg.norm_eps)
-        gate = jax.nn.silu(h @ layer_params["w_gate"])
-        up = h @ layer_params["w_up"]
-        return x + (gate * up) @ layer_params["w_down"]
+        with part("stream"):
+            h = self._rms_norm(x, layer_params["mlp_norm"], cfg.norm_eps)
+        with part("ffn"):
+            gate = jax.nn.silu(h @ layer_params["w_gate"])
+            up = h @ layer_params["w_up"]
+            out = (gate * up) @ layer_params["w_down"]
+        with part("stream"):
+            return x + out
 
     def _layer(
         self, x: jax.Array, layer_params: Dict[str, jax.Array], rope, positions
@@ -476,12 +497,14 @@ class Llama:
         """tokens [B, S] → logits [B, S, vocab] (fp32)."""
         cfg = self.config
         B, S = tokens.shape
-        x = params["embed"][tokens].astype(cfg.dtype)
+        with part("embed"):
+            x = params["embed"][tokens].astype(cfg.dtype)
 
         # Shapes under jit are GLOBAL even when the sequence dim is sharded
         # over sp — only the ring-attention shard_map body sees local blocks.
-        positions = jnp.broadcast_to(jnp.arange(S)[None, :], (B, S))
-        rope = self._rope(positions)
+        with part("mixer_glue"):
+            positions = jnp.broadcast_to(jnp.arange(S)[None, :], (B, S))
+            rope = self._rope(positions)
 
         def scan_body(carry, layer_params):
             return self._layer(carry, layer_params, rope, positions), None
@@ -497,9 +520,11 @@ class Llama:
                 prevent_cse=False,
             )
 
-        x, _ = jax.lax.scan(scan_body, x, params["layers"])
-        x = self._rms_norm(x, params["final_norm"], cfg.norm_eps)
-        return (x @ params["lm_head"]).astype(jnp.float32)
+        with part("layers"):
+            x, _ = jax.lax.scan(scan_body, x, params["layers"])
+        with part("head"):
+            x = self._rms_norm(x, params["final_norm"], cfg.norm_eps)
+            return (x @ params["lm_head"]).astype(jnp.float32)
 
     def loss(
         self, params: Dict[str, Any], batch: Tuple[jax.Array, jax.Array]
@@ -507,9 +532,10 @@ class Llama:
         """Mean next-token cross-entropy; batch = (tokens, targets)."""
         tokens, targets = batch
         logits = self.apply(params, tokens)
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-        return jnp.mean(nll)
+        with part("head"):
+            logp = jax.nn.log_softmax(logits, axis=-1)
+            nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
+            return jnp.mean(nll)
 
     def _attn_params_per_layer(self) -> int:
         cfg = self.config

@@ -68,7 +68,8 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from torchft_tpu.models.ling_hybrid import LingHybrid, _short_conv_silu
-from torchft_tpu.models.llama import Llama
+from torchft_tpu.models.llama import Llama, _proj
+from torchft_tpu.obs.spans import part
 from torchft_tpu.ops import flash_attention as flash
 from torchft_tpu.ops import ssd
 from torchft_tpu.parallel.moe import RoutedExperts, RoutedExpertsConfig
@@ -272,11 +273,12 @@ class SsmHybridMoE:
             shape_refusal = f"seq={seq} does not divide into the blocks ({block_q}, {block_k}) and chunks of {chunk}"
         return Llama._one_chip_refusal(shape_refusal, self.mesh)
 
+    @part("mixer_glue")
     def _ssm(self, h: jax.Array, w: Dict[str, jax.Array], kernels: bool) -> jax.Array:
         cfg = self.config
         B, S, _ = h.shape
         H, inner, GN = cfg.ssm_heads, cfg.ssm_inner, cfg.ssm_groups * cfg.ssm_state
-        zxr = h @ w["w_in"]
+        zxr = _proj(h, w["w_in"])
         z, xbc, r = jnp.split(zxr, [inner, inner + cfg.ssm_conv_width], axis=-1)
         xbc = _short_conv_silu(xbc, w["conv"], w["conv_bias"])
         x, Bm, Cm = jnp.split(xbc, [inner, inner + GN], axis=-1)
@@ -290,15 +292,16 @@ class SsmHybridMoE:
         # gate, then the norm over each group's channels
         y = y.reshape(B, S, inner).astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
         y = Llama._rms_norm(y.reshape(B, S, cfg.ssm_groups, -1), 1.0, cfg.norm_eps).reshape(B, S, inner)
-        return (y * w["o_norm"]).astype(h.dtype) @ w["w_out"]
+        return _proj((y * w["o_norm"]).astype(h.dtype), w["w_out"])
 
+    @part("mixer_glue")
     def _attention(self, h: jax.Array, w: Dict[str, jax.Array], kernels: bool) -> jax.Array:
         cfg = self.config
         B, S, _ = h.shape
         H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-        q = (h @ w["wq"]).reshape(B, S, H, hd)
-        k = (h @ w["wk"]).reshape(B, S, KV, hd)
-        v = (h @ w["wv"]).reshape(B, S, KV, hd)
+        q = _proj(h, w["wq"]).reshape(B, S, H, hd)
+        k = _proj(h, w["wk"]).reshape(B, S, KV, hd)
+        v = _proj(h, w["wv"]).reshape(B, S, KV, hd)
         if kernels:
             block_q, block_k = Llama._flash_blocks(S)
             o = flash.flash_attention(
@@ -310,7 +313,7 @@ class SsmHybridMoE:
             scores = jnp.einsum("bqgrd,bkgd->bgrqk", grouped, k).astype(jnp.float32) / np.sqrt(hd)
             scores = jnp.where(jnp.tril(jnp.ones((S, S), bool)), scores, -1e30)
             o = jnp.einsum("bgrqk,bkgd->bqgrd", jax.nn.softmax(scores, axis=-1).astype(q.dtype), v)
-        return o.reshape(B, S, H * hd) @ w["wo"]
+        return _proj(o.reshape(B, S, H * hd), w["wo"])
 
     def _block(
         self, x: jax.Array, w: Dict[str, Any], kind: str, kernels: bool
@@ -318,14 +321,19 @@ class SsmHybridMoE:
         """One residual layer: ``(x, load [E] (zeros but for an expert
         layer), balance loss)``."""
         cfg = self.config
-        h = Llama._rms_norm(x, w["norm"], cfg.norm_eps)
+        with part("stream"):
+            h = Llama._rms_norm(x, w["norm"], cfg.norm_eps)
         if kind == "experts":
             # the router reads the float32 norm itself
             out, load, balance = self.moe.apply(w["ffn"], h)
-            return x + out.astype(x.dtype), load, balance
+            with part("stream"):
+                return x + out.astype(x.dtype), load, balance
         mixer = self._ssm if kind == "ssm" else self._attention
-        out = mixer(h.astype(cfg.dtype), w, kernels)
-        return x + out.astype(x.dtype), jnp.zeros((cfg.num_experts,), jnp.float32), jnp.zeros((), jnp.float32)
+        with part("stream"):
+            h = h.astype(cfg.dtype)
+        out = mixer(h, w, kernels)
+        with part("stream"):
+            return x + out.astype(x.dtype), jnp.zeros((cfg.num_experts,), jnp.float32), jnp.zeros((), jnp.float32)
 
     def _trunk(self, params: Dict[str, Any], tokens: jax.Array) -> Tuple[jax.Array, List[jax.Array], jax.Array]:
         """tokens [B, S] → (the residual stream after the last layer, the
@@ -334,7 +342,8 @@ class SsmHybridMoE:
         cfg = self.config
         refusal = self._kernel_refusal(tokens.shape[1])
         kernels = refusal is None
-        x = params["embed"][tokens].astype(jnp.float32)  # the residual stream
+        with part("embed"):
+            x = params["embed"][tokens].astype(jnp.float32)  # the residual stream
         loads, balance = [], jnp.zeros((), jnp.float32)
         # kept through a layer's rematerialisation: flash's output and row
         # statistics (151 MB at 16,384 positions).  NOT ``ssd.KEPT_NAMES``: at
@@ -353,10 +362,12 @@ class SsmHybridMoE:
             # the first one stays on (``prevent_cse``): a scan of ONE layer
             # is no loop once XLA has simplified it, and merged they keep
             # every intermediate alive, 3.2 GB a state-space layer
-            x, (load, bal) = jax.lax.scan(jax.checkpoint(body, policy=policy), x, stacked)
+            with part("layers"):
+                x, (load, bal) = jax.lax.scan(jax.checkpoint(body, policy=policy), x, stacked)
             if kind == "experts":
                 loads.append(load)
-                balance = balance + jnp.sum(bal)
+                with part("experts_route"):
+                    balance = balance + jnp.sum(bal)
         if kernels and self.moe.path not in (None, "gmm") and Llama._assumed_backend() == "tpu":
             refusal, kernels = f"the experts took {self.moe.path}", False
         path = KERNEL_PATH if kernels else f"plain: {refusal}"
@@ -365,6 +376,7 @@ class SsmHybridMoE:
         self.attention_path = path
         return x, loads, balance
 
+    @part("head")
     def _logits(self, params: Dict[str, Any], x: jax.Array) -> jax.Array:
         x = Llama._rms_norm(x, params["final_norm"], self.config.norm_eps).astype(self.config.dtype)
         # the products' float32 sums as they are: a logit is never rounded to the model's dtype
@@ -393,4 +405,5 @@ class SsmHybridMoE:
         (the tokens each expert was chosen by) and the step's summary
         (``route_summary`` of this replica's own signal)."""
         loss, balance, signal = self._losses(params, batch)
-        return loss + balance, (signal, self.route_summary(signal))
+        with part("head"):
+            return loss + balance, (signal, self.route_summary(signal))

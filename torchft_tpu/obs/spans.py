@@ -39,6 +39,53 @@ operations; these are the names the program gives out::
                                         megablox kernels, named after the
                                         jitted functions around them)
 
+**The parts of the compiled step.**  A device operation that XLA makes has
+no name of ours, only the path of ``jax.named_scope``s it was traced under
+(``op_name`` in the compiled program, ``tf_op`` in a trace's event metadata).
+:class:`part` opens one of these ten scopes (``with part("ffn"):`` around
+some lines, ``@part("ffn")`` on a function that is one part whole), ONE
+vocabulary for every architecture (:data:`DEVICE_PARTS`); the innermost one
+on an operation's path is its part::
+
+    tpuft.embed             the embedding lookup (its scatter-add gradient
+                            follows by the path)
+    tpuft.stream            a layer's norm of the stream, the residual add,
+                            the stream's casts (float32 <-> bfloat16)
+    tpuft.mixer_proj        the products into and out of a mixer: q/k/v/o,
+                            MLA's low-rank pairs, KDA's and Mamba-2's w_in /
+                            w_out, the index's projections
+    tpuft.mixer_glue        everything of a mixer between its projections and
+                            its kernel (rope, q/k norms, the short convolution,
+                            softplus and decays, gates, the gated norm, splits,
+                            reshapes and their layout copies), and the plain
+                            path where no kernel runs
+    tpuft.ffn               dense MLPs: SwiGLU, a dense layer, the shared expert
+    tpuft.experts_route     router product, scores, groups, top-k, the load
+                            count, the balance loss
+    tpuft.experts_dispatch  RoutedExperts but for the grouped products: argsort,
+                            gather, masks, activation, weights, scatter-add
+    tpuft.head              final norm, logits, the losses, the step summary
+    tpuft.layers            the lax.scan over a run of layers, around the
+                            body's own parts: what is left under it alone is
+                            the loop's machinery, the slices of the stacked
+                            weights and the writes of their stacked gradients
+    tpuft.optimizer         the whole update step (optax, apply_updates,
+                            advance_state)
+
+The same paths group xprof's and TensorBoard's op profile by part, with no
+tool of ours.  In a path ``jvp(`` without ``transpose(`` is the forward pass,
+``transpose(`` the backward pass, and ``rematted_computation`` the forward
+pass run again inside the backward pass (``jax.checkpoint``).  A scope is
+metadata on the lowered operations: it costs nothing at run time, changes
+nothing of the lowered program's text or the compile cache's key, and there
+is no switch for it.  The other side of that: a persistent compile cache
+hands out an executable with the paths of the process that COMPILED it, so a
+program compiled before a scope was written shows the old paths until its
+text changes or the cache is emptied.  A Mosaic kernel keeps the name above
+(the TPU compiler names a custom call after the path component that encloses
+its ``pallas_call``, which a scope AROUND the call does not change).
+``ftbench/device_scopes.py`` shares out a trace's device time by them.
+
 **Which replica, which step.**  Replica groups may be threads of one
 process, and helper threads work for one of them.  A thread says whom it
 works for with :func:`bind` (the replica's ``FlightRecorder``: it knows the
@@ -67,6 +114,7 @@ several replicas' files with their flight dumps into one fleet timeline.
 from __future__ import annotations
 
 import collections
+import contextlib
 import json
 import threading
 import time
@@ -75,6 +123,13 @@ from typing import Any, Dict, List, Optional
 from torchft_tpu import knobs
 
 SPANS_ENV = "TORCHFT_FLIGHT_SPANS"
+
+# the parts of the compiled step (the module docstring says what lies under each)
+DEVICE_PARTS = (
+    "embed", "stream", "mixer_proj", "mixer_glue", "ffn", "experts_route",
+    "experts_dispatch", "head", "layers", "optimizer",
+)
+PART_PREFIX = "tpuft."
 
 # None = resolve from env on first use; configure() pins it for the process
 _enabled: Optional[bool] = None
@@ -207,6 +262,32 @@ class _Span:
                 duration_s=round(duration, 6),
                 **detail,
             )
+
+
+class part(contextlib.ContextDecorator):
+    """``jax.named_scope("tpuft.<name>")`` around the tracing of one part of
+    the compiled step, as ``with part("ffn"):`` around some lines or as
+    ``@part("ffn")`` on a function that is one part whole; ``name`` is one of
+    :data:`DEVICE_PARTS`."""
+
+    def __init__(self, name: str) -> None:
+        if name not in DEVICE_PARTS:
+            raise ValueError(f"{name!r} is no part of the compiled step: {DEVICE_PARTS}")
+        self.name = name
+
+    def _recreate_cm(self) -> "part":
+        # a scope of its own for every call of a decorated function: replica
+        # groups are threads, and two may trace the same function at once
+        return part(self.name)
+
+    def __enter__(self) -> Any:
+        import jax
+
+        self._scope = jax.named_scope(PART_PREFIX + self.name)
+        return self._scope.__enter__()
+
+    def __exit__(self, *exc: Any) -> Any:
+        return self._scope.__exit__(*exc)
 
 
 def span(

@@ -35,7 +35,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from torchft_tpu.ddp import ft_allreduce
 from torchft_tpu.manager import Manager
 from torchft_tpu.obs.flight import FlightEvent
-from torchft_tpu.obs.spans import span as obs_span
+from torchft_tpu.obs.spans import part, span as obs_span
 
 
 def fsdp_shardings(
@@ -130,9 +130,10 @@ def make_grad_step(
         if not _reports(model):
             return jax.value_and_grad(model.loss)(params, batch)
         (loss, (signal, summary)), grads = jax.value_and_grad(model.objective, has_aux=True)(params, batch)
-        if mask is not None:
-            grads = _with_state(grads, mask, [s.astype(jnp.float32) for s in signal])
-        report = jnp.concatenate([loss.reshape(1), summary.reshape(-1)]).astype(jnp.float32)
+        with part("head"):  # the step's account of itself, beside the losses
+            if mask is not None:
+                grads = _with_state(grads, mask, [s.astype(jnp.float32) for s in signal])
+            report = jnp.concatenate([loss.reshape(1), summary.reshape(-1)]).astype(jnp.float32)
         return report, grads
 
     with mesh:
@@ -212,6 +213,7 @@ def make_update_step(
     params_sh, _ = fsdp_shardings(model, mesh)
     mask = _state_mask(model)
 
+    @part("optimizer")  # the whole program is one part (``obs/spans.py``)
     def _update(params: Any, opt_state: Any, grads: Any) -> Tuple[Any, Any]:
         if mask is not None:
             # the state leaves' slots hold the averaged signal, not a

@@ -29,6 +29,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
+# ``part`` is the name of ``_held_part``'s own function below
+from torchft_tpu.obs.spans import part as device_part
 
 
 @dataclass(frozen=True)
@@ -298,6 +300,7 @@ class RoutedExperts:
 
     # ------------------------------------------------------------------
 
+    @device_part("experts_route")
     def route(self, params: Dict[str, Any], x: jax.Array) -> Tuple[jax.Array, jax.Array, jax.Array]:
         """x [T, D] → (chosen experts [T, k] int32, their weights [T, k]
         float32, the unbiased scores [T, E] float32)."""
@@ -366,6 +369,8 @@ class RoutedExperts:
         ys = jnp.where(routed[:, None], ys, 0).astype(jnp.float32) * w_row[:, None]
         return jnp.zeros((T, x.shape[1]), jnp.float32).at[token].add(ys)
 
+    # the whole of it is the dispatch; the grouped products inside keep their kernels' names
+    @device_part("experts_dispatch")
     def _held_part(
         self, params: Dict[str, Any], x: jax.Array, chosen: jax.Array, weights: jax.Array,
         sizes: jax.Array, limit: float,
@@ -426,25 +431,31 @@ class RoutedExperts:
         gets its part back in float32."""
         cfg = self.config
         B, S, D = x.shape
-        flat = x.reshape(B * S, D)
+        with device_part("stream"):
+            flat = x.reshape(B * S, D)
         chosen, weights, scores = self.route(params, flat)
-        flat = flat.astype(cfg.dtype)
-        picked = jax.nn.one_hot(chosen, cfg.num_experts, dtype=jnp.float32).sum(axis=1)  # [T, E]
-        load = jax.lax.stop_gradient(picked.sum(axis=0))
-        first, held = cfg.experts_held
-        sizes = load[first : first + held].astype(jnp.int32)
+        with device_part("stream"):
+            flat = flat.astype(cfg.dtype)
+        with device_part("experts_route"):
+            picked = jax.nn.one_hot(chosen, cfg.num_experts, dtype=jnp.float32).sum(axis=1)  # [T, E]
+            load = jax.lax.stop_gradient(picked.sum(axis=0))
+            first, held = cfg.experts_held
+            sizes = load[first : first + held].astype(jnp.int32)
         out = self._held_part(params, flat, chosen, weights, sizes, swiglu_limit)
         if cfg.shared_hidden:
-            *w_in, w_down = (params[name] for name in self.shared_leaves)
-            act = self._activate([flat @ w for w in w_in], shared_swiglu_limit)
-            out = out + (act @ w_down).astype(jnp.float32)
+            with device_part("ffn"):
+                *w_in, w_down = (params[name] for name in self.shared_leaves)
+                act = self._activate([flat @ w for w in w_in], shared_swiglu_limit)
+                out = out + (act @ w_down).astype(jnp.float32)
         balance = jnp.zeros((), jnp.float32)
         if cfg.balance_loss_weight:
             # per sequence: f_i the share of choices that fell on expert i
             # (times E / k), P_i the mean normalised score; sum_i f_i P_i
-            f = jax.lax.stop_gradient(picked.reshape(B, S, -1).mean(axis=1)) * (
-                cfg.num_experts / cfg.top_k
-            )
-            p = (scores / jnp.sum(scores, axis=-1, keepdims=True)).reshape(B, S, -1).mean(axis=1)
-            balance = cfg.balance_loss_weight * jnp.mean(jnp.sum(f * p, axis=-1))
-        return out.astype(x.dtype).reshape(B, S, D), load, balance
+            with device_part("experts_route"):
+                f = jax.lax.stop_gradient(picked.reshape(B, S, -1).mean(axis=1)) * (
+                    cfg.num_experts / cfg.top_k
+                )
+                p = (scores / jnp.sum(scores, axis=-1, keepdims=True)).reshape(B, S, -1).mean(axis=1)
+                balance = cfg.balance_loss_weight * jnp.mean(jnp.sum(f * p, axis=-1))
+        with device_part("stream"):
+            return out.astype(x.dtype).reshape(B, S, D), load, balance
