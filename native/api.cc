@@ -138,12 +138,17 @@ int tpuft_comm_allreduce(void* h, void* data, uint64_t nbytes, int32_t dtype,
 // caller buffers (all holding whole elements of `dtype`) treated as one
 // logical payload — frames leave and land via sendmsg/recvmsg straight
 // against these buffers, no staging concatenation on either side.
+// `divisor` (0 = none, OP_SUM alone): the buffers come back holding
+// SUM / divisor, divided inside the ring by each chunk's owner (comm.h
+// average_buffer), and not the sum.  `group` is the dtype group's index
+// within the caller's allreduce (its tag window).
 int tpuft_comm_allreduce_iov(void* h, void* const* bufs, const uint64_t* lens,
-                             uint64_t n, int32_t dtype, int32_t op) {
+                             uint64_t n, int32_t dtype, int32_t op,
+                             uint64_t divisor, uint64_t group) {
   auto* comm = static_cast<tpuft::Communicator*>(h);
   return guarded([&] {
     comm->allreduce_iov(bufs, lens, n, static_cast<tpuft::DType>(dtype),
-                        static_cast<tpuft::RedOp>(op));
+                        static_cast<tpuft::RedOp>(op), divisor, group);
   });
 }
 

@@ -24,6 +24,7 @@
 #include <array>
 #include <atomic>
 #include <cctype>
+#include <cmath>
 #include <chrono>
 #include <condition_variable>
 #include <cstring>
@@ -145,6 +146,73 @@ inline void reduce_buffer(void* acc, const void* in, size_t nbytes, DType dt,
 struct CommError : std::runtime_error {
   using std::runtime_error::runtime_error;
 };
+
+// --- the average -------------------------------------------------------------
+//
+// SUM / n, bit for bit what communicator._div makes of a ring's sum: the sum
+// rounded to the buffer's dtype as the ring leaves it, then a TRUE division
+// (never a product with a reciprocal: 3 and 5 are no powers of two) in
+// float32 for bfloat16 and float32, in float64 for float64, rounded to
+// nearest even; integers FLOOR-divide (C++'s `/` truncates towards zero).
+// A bfloat16 NaN comes out as the one NaN of its sign, as ml_dtypes' cast
+// makes it (f32_to_bf16 would keep the payload).
+
+inline uint16_t bf16_quotient(float sum, float n) {
+  float q = sum / n;
+  if (q != q) return std::signbit(q) ? 0xFFC0 : 0x7FC0;
+  return f32_to_bf16(q);
+}
+
+template <typename T, typename Q>
+inline void average_typed(void* buf, size_t n, Q quot) {
+  T* a = static_cast<T*>(buf);
+  for (size_t i = 0; i < n; ++i) a[i] = quot(a[i]);
+}
+
+template <typename T>
+inline void average_ints(void* buf, size_t n, int64_t d) {
+  average_typed<T>(buf, n, [d](T sum) {
+    int64_t s = static_cast<int64_t>(sum);
+    return static_cast<T>(s / d - (s % d < 0));  // d > 0: the floor
+  });
+}
+
+// buf = buf / divisor, in one pass
+inline void average_buffer(void* buf, size_t nbytes, DType dt,
+                           uint64_t divisor) {
+  switch (dt) {
+    case DT_F32: {
+      float d = static_cast<float>(divisor);
+      average_typed<float>(buf, nbytes / 4, [d](float sum) { return sum / d; });
+      break;
+    }
+    case DT_F64: {
+      double d = static_cast<double>(divisor);
+      average_typed<double>(buf, nbytes / 8,
+                            [d](double sum) { return sum / d; });
+      break;
+    }
+    case DT_I32:
+      average_ints<int32_t>(buf, nbytes / 4, divisor);
+      break;
+    case DT_I64:
+      average_ints<int64_t>(buf, nbytes / 8, divisor);
+      break;
+    case DT_I8:
+      average_ints<int8_t>(buf, nbytes, divisor);
+      break;
+    case DT_U8:
+      average_ints<uint8_t>(buf, nbytes, divisor);
+      break;
+    case DT_BF16: {
+      float d = static_cast<float>(divisor);
+      average_typed<uint16_t>(buf, nbytes / 2, [d](uint16_t sum) {
+        return bf16_quotient(bf16_to_f32(sum), d);
+      });
+      break;
+    }
+  }
+}
 
 // --- network emulation (mirror of communicator._NetEmu) ---------------------
 //
@@ -746,6 +814,19 @@ constexpr uint64_t kLaneHelloFlag = uint64_t(1) << 63;
 // window; mixed-tier meshes now pin this).
 constexpr uint64_t kRingReduceTagBase = 30000;
 
+// An allreduce that hands back the AVERAGE (a divisor: the owner of a chunk
+// divides it between the two phases) frames BOTH phases in a window of its
+// own — mirror of wire.RING_AVG_TAG_BASE.  A ring in which one rank divides
+// and its peer expects sums would hand every rank sums for some chunks and
+// averages for others, silently: so a peer that sums (or predates the
+// divisor) meets a tag mismatch and the op fails on both.
+constexpr uint64_t kRingAvgTagBase = 100000;
+
+// A multi-dtype allreduce is one ring a dtype: group i frames at i x this
+// stride — mirror of wire.RING_BUFFER_TAG_STRIDE (this tier framed every
+// group at 0, and a mixed-tier ring failed at its second dtype).
+constexpr uint64_t kRingBufferTagStride = 10000;
+
 // Flight-recorder event ids, mirror of the data-plane block of
 // obs/flight.py FlightEvent (the ftlint native-mirror checker pins every
 // kFlight* value against the Python enum).  The native tier records its
@@ -1169,11 +1250,14 @@ class Communicator {
 
   // -- collectives (synchronous; caller provides an op thread) -------------
 
-  // In-place ring allreduce over a contiguous buffer.
-  void allreduce(void* data, size_t nbytes, DType dt, RedOp op) {
+  // In-place ring allreduce over a contiguous buffer.  `divisor` (0 = none;
+  // OP_SUM alone): the buffer comes back holding SUM / divisor — what
+  // average_buffer makes of the sum — and not the sum.
+  void allreduce(void* data, size_t nbytes, DType dt, RedOp op,
+                 uint64_t divisor = 0) {
     ScatterView view(data, nbytes);
     IoPtr io = io_snapshot();
-    allreduce_ring_io(io, view, dt, op, full_ring(io->world));
+    allreduce_ring_io(io, view, dt, op, full_ring(io->world), divisor);
   }
 
   // In-place ring allreduce over MANY caller buffers treated as one
@@ -1182,11 +1266,14 @@ class Communicator {
   // straight into it; the payload is never assembled in a staging copy.
   // Every buffer must hold whole elements of `dt` (the Python binding
   // groups arrays by dtype), so chunk math never splits an element.
+  // `group`: which of a call's dtype groups this is (its tag window).
   void allreduce_iov(void* const* bufs, const uint64_t* lens, size_t n,
-                     DType dt, RedOp op) {
+                     DType dt, RedOp op, uint64_t divisor = 0,
+                     uint64_t group = 0) {
     ScatterView view(bufs, lens, n);
     IoPtr io = io_snapshot();
-    allreduce_ring_io(io, view, dt, op, full_ring(io->world));
+    allreduce_ring_io(io, view, dt, op, full_ring(io->world), divisor,
+                      group * kRingBufferTagStride);
   }
 
   // Ring allreduce over a RANK SUBSET (global ranks in ring order) — the
@@ -1206,9 +1293,20 @@ class Communicator {
   }
 
   void allreduce_ring_io(IoPtr io, ScatterView& view, DType dt, RedOp op,
-                         const std::vector<int64_t>& ring) {
-    if (ring.size() <= 1) return;
+                         const std::vector<int64_t>& ring,
+                         uint64_t divisor = 0, uint64_t tag_base = 0) {
+    if (divisor && op != OP_SUM)
+      throw CommError("an allreduce's divisor goes with OP_SUM alone");
+    if (divisor == 1) divisor = 0;  // the sum is the average: no pass
     size_t esz = dtype_size(dt);
+    auto average = [&](size_t off, size_t len) {
+      for (const struct iovec& seg : view.slice(off, len))
+        average_buffer(seg.iov_base, seg.iov_len, dt, divisor);
+    };
+    if (ring.size() <= 1) {
+      if (divisor) average(0, view.size());
+      return;
+    }
     auto deadline = deadline_in(timeout_s_);
     auto bounds = ring_bounds(view.size() / esz, ring.size());
 
@@ -1220,10 +1318,20 @@ class Communicator {
     // but chunk indices landed rotated by one against a Python peer — a
     // silent cross-tier corruption the constant-fill interop test never
     // saw (mixed-tier bit-identity tests now pin this).
+    //
+    // With a divisor the owner of a chunk divides it between the phases (one
+    // pass over 1/ws of the payload, done once a ring and not once a rank),
+    // so the allgather phase carries averages.  The Python tier divides at
+    // the same point (_ring_allreduce): mixed tiers ride one ring.
+    if (divisor) tag_base += kRingAvgTagBase;
     ring_reduce_phase(io, view, bounds, esz, dt, op, /*shift=*/-1, deadline,
-                      ring, /*tag_base=*/0);
+                      ring, tag_base);
+    if (divisor) {
+      size_t own = static_cast<size_t>(ring_pos(ring, io->rank));
+      average(bounds[own] * esz, (bounds[own + 1] - bounds[own]) * esz);
+    }
     ring_allgather_phase(io, view, bounds, esz, /*shift=*/-1, deadline, ring,
-                         /*tag_base=*/0);
+                         tag_base);
   }
 
   // reduce-scatter: `data` is reduced in place ring-wise; this rank's chunk

@@ -123,6 +123,33 @@ void phase_a_rank(Communicator* comm, int rank, const std::string& store_addr) {
       }
     }
 
+    // allreduce with a divisor: the owner of a chunk divides it between the
+    // phases, the allgather carries averages (send workers reading what the
+    // op thread has just written); float32 and, over two scattered segments,
+    // bfloat16 (3 is no power of two: a reciprocal would round otherwise)
+    std::fill(buf.begin(), buf.end(), static_cast<float>(rank + 1));
+    comm->allreduce(buf.data(), buf.size() * 4, DT_F32, OP_SUM, /*divisor=*/3);
+    for (size_t i = 0; i < buf.size(); ++i) {
+      if (buf[i] != want_sum / 3.0f) {
+        fail("phase A averaging allreduce corrupt at " + std::to_string(i));
+        break;
+      }
+    }
+    std::vector<uint16_t> half_a(kReduceFloats / 2 + 1, f32_to_bf16(rank + 1.0f));
+    std::vector<uint16_t> half_b(kReduceFloats / 3, f32_to_bf16(rank + 1.0f));
+    void* segs[2] = {half_a.data(), half_b.data()};
+    uint64_t lens[2] = {half_a.size() * 2, half_b.size() * 2};
+    comm->allreduce_iov(segs, lens, 2, DT_BF16, OP_SUM, /*divisor=*/3,
+                        /*group=*/1);
+    const uint16_t want_avg = f32_to_bf16(bf16_to_f32(f32_to_bf16(want_sum)) / 3.0f);
+    for (const auto* half : {&half_a, &half_b})
+      for (size_t i = 0; i < half->size(); ++i) {
+        if ((*half)[i] != want_avg) {
+          fail("phase A averaging bf16 allreduce corrupt at " + std::to_string(i));
+          break;
+        }
+      }
+
     // reduce_scatter: own chunk fully reduced
     std::fill(buf.begin(), buf.end(), static_cast<float>(rank + 1));
     std::vector<float> own(buf.size() / kWorld + kWorld);

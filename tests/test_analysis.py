@@ -1147,13 +1147,19 @@ class TestNativeMirror:
         findings = nativemirror.check_comm_header("// empty\n", "native/comm.h")
         assert any(f.symbol == "kMaxIovSegs" for f in findings)
 
-    def test_drifted_ring_reduce_tag_base_flagged(self):
-        text = "constexpr uint64_t kRingReduceTagBase = 40000;\n"
+    @pytest.mark.parametrize(
+        "const", ["kRingReduceTagBase", "kRingAvgTagBase", "kRingBufferTagStride"]
+    )
+    def test_drifted_ring_reduce_tag_base_flagged(self, const):
+        """The rings' tag windows (PR 40 added the averaging ring's and the
+        stride between a call's dtype groups): drifted, and missing."""
+        text = f"constexpr uint64_t {const} = 40000;\n"
         findings = nativemirror.check_comm_header(text, "native/comm.h")
         assert any(
-            f.symbol == "kRingReduceTagBase" and "40000" in f.message
-            for f in findings
+            f.symbol == const and "40000" in f.message for f in findings
         )
+        findings = nativemirror.check_comm_header("// empty\n", "native/comm.h")
+        assert any(f.symbol == const and "not found" in f.message for f in findings)
 
     def test_missing_pacer_knob_flagged(self):
         # references three of the four _NetEmu knobs: the missing one fires

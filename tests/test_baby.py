@@ -145,6 +145,43 @@ def test_baby_contract_parity_across_size_threshold(store) -> None:
             assert facts[f"{label}_input_kept"], (rank, label, facts)
 
 
+@pytest.mark.parametrize("in_place", [False, True], ids=["out_of_place", "in_place"])
+def test_baby_allreduce_divisor_is_the_childs_ring(store, in_place) -> None:
+    """A divisor crosses the pipe: the child's ring averages (PR 40), bit for
+    bit ``_div`` of the sum, on both sides of the size threshold; out of
+    place the caller's buffer is untouched."""
+    from torchft_tpu.communicator import _div
+
+    def _grad(rank: int, n: int) -> np.ndarray:
+        return (np.random.default_rng([rank, n]).standard_normal(n) * 100).astype(np.float32)
+
+    sizes = (257, 256 * 1024 + 1)
+
+    def _one(rank: int):
+        comm = BabyCommunicator(timeout_s=30.0)
+        comm.configure(
+            f"127.0.0.1:{store.port}/avg{int(in_place)}", replica_id=f"r{rank}", rank=rank, world_size=2
+        )
+        try:
+            outs = []
+            for n in sizes:
+                mine = _grad(rank, n)
+                out = comm.allreduce(mine, ReduceOp.SUM, in_place=in_place, divisor=3).wait(timeout=30.0)
+                assert np.shares_memory(out, mine) == in_place
+                assert in_place or mine.tobytes() == _grad(rank, n).tobytes()
+                outs.append(out)
+            comm.barrier().wait(timeout=30.0)
+            return outs
+        finally:
+            comm.shutdown()
+
+    with ThreadPoolExecutor(max_workers=2) as pool:
+        results = list(pool.map(_one, range(2)))
+    for outs in results:
+        for n, out in zip(sizes, outs):
+            assert out.tobytes() == _div(_grad(0, n) + _grad(1, n), 3).tobytes()
+
+
 def test_baby_send_bytes_non_contiguous(store) -> None:
     """Strided ndarrays must ship (the direct tiers accept them)."""
 

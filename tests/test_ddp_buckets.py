@@ -25,10 +25,10 @@ import numpy as np
 import pytest
 
 from torchft_tpu import ddp
-from torchft_tpu.communicator import DummyCommunicator, TCPCommunicator
+from torchft_tpu.communicator import DummyCommunicator, TCPCommunicator, _div
 from torchft_tpu.ddp import BUCKET_CAP_MB_ENV, allreduce_pytree
 from torchft_tpu.lighthouse import LighthouseServer
-from torchft_tpu.manager import Manager, _div
+from torchft_tpu.manager import Manager
 from torchft_tpu.work import Work
 
 from tests.test_manager import MemoryTransport, StubClient, _quorum_result
@@ -287,11 +287,14 @@ class _HeldComm(DummyCommunicator):
         self.held: List["Future[Any]"] = []
         self.buffers: List[np.ndarray] = []
 
-    def allreduce(self, buffers, op=None, in_place=False) -> Work:  # type: ignore[override]
+    def allreduce(self, buffers, op=None, in_place=False, divisor=None) -> Work:  # type: ignore[override]
         fut: "Future[Any]" = Future()
         if self.fail_with is not None:
             fut.set_exception(self.fail_with())
-        elif self.hold:
+            return Work(fut)
+        # the passthrough's own average (PR 40: the communicator divides)
+        buffers = super().allreduce(buffers, in_place=in_place, divisor=divisor).wait()
+        if self.hold:
             self.held.append(fut)
             self.buffers.append(buffers)
         else:

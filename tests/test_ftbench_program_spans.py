@@ -47,6 +47,9 @@ SCOPE_READERS = (
     "xla_mixer_proj_ms", "xla_mixer_glue_ms", "xla_ffn_ms", "xla_stream_ms", "xla_head_ms", "moe_route_ms",
     "moe_dispatch_ms", "xla_layer_scan_ms", "optimizer_ms", "step_remat_ms", "xla_unscoped_ms",
 )
+# PR 40: the share of a step's collectives whose average the ring made itself
+# (the span tpuft/manager/normalize carries in_ring=1)
+IN_RING_READERS = ("normalize_in_ring_pct",)
 
 
 @pytest.mark.parametrize("name", sorted(LATER_READINGS))
@@ -62,7 +65,7 @@ def test_new_readers_are_the_eighteen_benchmark_json_lists():  # noqa: F811
         per_layer = json.load(f)["per_layer"]
     appended = (
         LATER_READINGS, LING_READERS, BUCKET_READERS, ORDER_READERS, INDEXED_READERS, SSM_READERS, AHEAD_READERS,
-        SCOPE_READERS,
+        SCOPE_READERS, IN_RING_READERS,
     )
     later = sum(map(len, appended))
     assert [m["name"] for m in per_layer[-later:]] == [name for group in appended for name in group]
@@ -169,6 +172,82 @@ def test_heal_serve_ahead_pct_is_the_kill_cells_alone():
         (entry,) = [m for m in json.load(f)["per_layer"] if m["name"] == "heal_serve_ahead_pct"]
     assert entry["workloads"] == ["mistral7b-ddp2-kill"]
     meta = spec.load_metric("heal_serve_ahead_pct", theirs.BENCH_DIR).META  # noqa: F405
+    assert {k: entry[k] for k in meta} == meta and entry["better"] == "higher"
+
+
+def _with_in_ring(spans, flags):
+    """``spans`` with ``in_ring`` set on replica 0's ``tpuft/manager/normalize``
+    spans in turn (None: the span carries no such attribute, the parent's)."""
+    out, flags = [], list(flags)
+    for s in spans:
+        s = dict(s)
+        if s["name"] == "tpuft/manager/normalize" and s["r"] == theirs.R0:
+            flag = flags.pop(0)
+            if flag is not None:
+                s["in_ring"] = flag
+        out.append(s)
+    assert not flags
+    return out
+
+
+@pytest.mark.parametrize(
+    "flags,expects",
+    [
+        # every collective of both traced steps was averaged by its ring
+        ([1, 1, 1, 1], 100.0),
+        # a program from before PR 40: the callback divides every sum
+        ([None] * 4, 0.0),
+        # the quantized ring hands back sums: one collective in four
+        ([1, 0, 1, 1], 75.0),
+        ([0, 0, 0, 0], 0.0),
+        # as the profiler hands a TraceAnnotation's argument back
+        (["1", "1", "0", "0"], 50.0),
+    ],
+    ids=["all_in_ring", "parent", "one_quantized", "none", "strings"],
+)
+def test_normalize_in_ring_pct_on_the_synthetic_planes(run, flags, expects, monkeypatch):  # noqa: F405
+    read = spec.load_metric("normalize_in_ring_pct", theirs.BENCH_DIR).read  # noqa: F405
+    spans = _with_in_ring(run["spans"], flags)
+    monkeypatch.setattr(program_spans, "load", lambda bench_dir=None: spans)
+    for sources in (run["sources"], dict(run["sources"], trace=None)):
+        assert read(sources) == pytest.approx(expects)
+    # sync_normalize_ms beside it reads the same spans' seconds, whatever they carry
+    assert spec.load_metric("sync_normalize_ms", theirs.BENCH_DIR).read(run["sources"]) == pytest.approx(80.0)  # noqa: F405
+    # a run with no such span (a one-replica cell), and one with no span at all
+    bare = [s for s in spans if s["name"] != "tpuft/manager/normalize"]
+    monkeypatch.setattr(program_spans, "load", lambda bench_dir=None: bare)
+    assert read(run["sources"]) is None
+    monkeypatch.setattr(program_spans, "load", lambda bench_dir=None: [])
+    assert read(run["sources"]) is None
+
+
+def test_normalize_in_ring_pct_reads_the_attribute_out_of_a_profile():
+    """Through the protobuf: an integer argument of the annotation comes back
+    as the span's ``in_ring``; the other replica's spans do not count."""
+    from jax.profiler import ProfileData
+
+    spans = []
+    for step, at in ((5, 0.0), (6, theirs.STEP_MS)):
+        for thread, name, start, dur, stats in theirs._step_spans(step, at):
+            if name == "tpuft/manager/normalize":
+                stats = dict(stats, in_ring=1, bytes=1 << 20)
+            spans.append((thread, name, start, dur, stats))
+        spans.append(("op1", "tpuft/manager/normalize", at + 520, 1, dict(r=theirs.R1, step=step, in_ring=0)))
+    profile = ProfileData.from_text_proto(theirs._text_proto(spans, [("fusion.1", 0, 40)], [("jit__step(1)", 0, 40)]))
+    read_back = program_spans.from_profile(profile)
+    mine = [s for s in read_back if s["name"] == "tpuft/manager/normalize" and s["r"] == theirs.R0]
+    assert len(mine) == 4 and all(s["in_ring"] == 1 for s in mine)
+    import unittest.mock as mock
+
+    with mock.patch.object(program_spans, "load", lambda bench_dir=None: read_back):
+        assert spec.load_metric("normalize_in_ring_pct", theirs.BENCH_DIR).read(dict(trace=None)) == 100.0  # noqa: F405
+
+
+def test_normalize_in_ring_pct_is_the_steady_two_replica_cell_alone():
+    with open(os.path.join(theirs.ROOT, "BENCHMARK.json")) as f:
+        (entry,) = [m for m in json.load(f)["per_layer"] if m["name"] == "normalize_in_ring_pct"]
+    assert entry["workloads"] == ["mistral7b-ddp2-steady"]
+    meta = spec.load_metric("normalize_in_ring_pct", theirs.BENCH_DIR).META  # noqa: F405
     assert {k: entry[k] for k in meta} == meta and entry["better"] == "higher"
 
 

@@ -20,9 +20,10 @@ _CASES = theirs.test_rehearsal_walks_the_cell.pytestmark[0].args[1]
 # sync_normalize_ms is PR 27's reader of PR 26's span tpuft/manager/normalize,
 # bucket_warm_pct PR 30's of DDP_SYNC's warm_buckets, heal_serve_ahead_pct
 # PR 36's of HEAL_SERVE_END's ahead_bytes (a rehearsed kill run heals jax
-# leaves, so the counter is there and the share is reported)
+# leaves, so the counter is there and the share is reported),
+# normalize_in_ring_pct PR 40's of the normalize span's in_ring
 _NEW = {
-    "mistral7b-ddp2-steady": set(READINGS) | {"sync_normalize_ms", "bucket_warm_pct"},
+    "mistral7b-ddp2-steady": set(READINGS) | {"sync_normalize_ms", "bucket_warm_pct", "normalize_in_ring_pct"},
     "mistral7b-ddp2-kill": set(KILL_READINGS) | {"heal_serve_ahead_pct"},
 }
 

@@ -11,11 +11,13 @@ from ftbench.tests import test_ftbench_ssm as theirs
 from ftbench.tests.test_ftbench_ssm import *  # noqa: F401,F403
 
 # PR 36 appended its reader after PR 35's six, PR 37 the eleven that share
-# out the compiled step by its named parts (``ftbench/device_scopes.py``)
+# out the compiled step by its named parts (``ftbench/device_scopes.py``),
+# PR 40 the share of collectives the ring averaged itself
 LATER_READERS = (
     "heal_serve_ahead_pct",
     "xla_mixer_proj_ms", "xla_mixer_glue_ms", "xla_ffn_ms", "xla_stream_ms", "xla_head_ms", "moe_route_ms",
     "moe_dispatch_ms", "xla_layer_scan_ms", "optimizer_ms", "step_remat_ms", "xla_unscoped_ms",
+    "normalize_in_ring_pct",
 )
 
 

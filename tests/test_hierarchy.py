@@ -222,6 +222,44 @@ class TestHierarchicalCollectives:
         for b, g in zip(base, got):
             np.testing.assert_array_equal(np.asarray(b), np.asarray(g))
 
+    @pytest.mark.parametrize(
+        "hosts",
+        [["hostA", "hostA", "hostB", "hostB"], ["hostA", "hostA", "hostA"], ["hostA", "hostB", "hostB"]],
+        ids=["2x2", "one_host_forced", "1+2"],
+    )
+    @pytest.mark.parametrize("dtype", ["bfloat16", "float32", "int32"])
+    def test_allreduce_divisor_divides_in_the_leader_ring(self, store, hosts, dtype) -> None:
+        """With a divisor the LEADERS' ring hands back the average (a lone
+        leader divides what the shared memory reduced), the fan-out carries
+        it to the members: bit for bit ``_div`` of the same topology's sum,
+        the same on every rank (PR 40)."""
+        import ml_dtypes
+
+        from torchft_tpu.communicator import _div
+
+        dt = np.dtype(ml_dtypes.bfloat16 if dtype == "bfloat16" else dtype)
+        rng = np.random.default_rng(40)
+        inputs = [(rng.normal(size=100_003) * 50).astype(dt) for _ in hosts]
+        # a member that shuts down latches the abort into its host's shared
+        # segment, where the leader may still wait for another member's ack
+        all_done = threading.Barrier(len(hosts))
+
+        def _fn(comm, rank):
+            summed = comm.allreduce(inputs[rank].copy(), ReduceOp.SUM).wait(timeout=30.0)
+            mine = inputs[rank].copy()
+            avg = comm.allreduce(mine, ReduceOp.SUM, in_place=True, divisor=3).wait(timeout=30.0)
+            kept = inputs[rank].copy()
+            out = comm.allreduce(kept, ReduceOp.SUM, divisor=3).wait(timeout=30.0)
+            all_done.wait(timeout=30.0)
+            assert kept.tobytes() == inputs[rank].tobytes() and np.shares_memory(avg, mine)
+            return np.asarray(summed), np.asarray(avg), np.asarray(out)
+
+        results = _run_ranks(store, hosts, _fn, prefix=f"avg_{dtype}_{len(hosts)}_{hosts[1]}", hier="1")
+        want = _div(results[0][0], 3).tobytes()
+        for summed, avg, out in results:
+            assert summed.tobytes() == results[0][0].tobytes()
+            assert avg.tobytes() == want and out.tobytes() == want
+
     def test_allgather_and_reduce_scatter(self, store) -> None:
         n = 70_001
         rng = np.random.default_rng(13)

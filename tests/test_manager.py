@@ -315,7 +315,7 @@ class TestAllreduce:
         from torchft_tpu.work import Work
 
         class SlowFailingCommunicator(DummyCommunicator):
-            def allreduce(self, buffers, op=None, in_place=False):  # type: ignore[override]
+            def allreduce(self, buffers, op=None, in_place=False, divisor=None):  # type: ignore[override]
                 fut: Future = Future()
 
                 def _later() -> None:
@@ -344,12 +344,13 @@ class TestAllreduce:
         from torchft_tpu.work import Work
 
         class SlowCommunicator(DummyCommunicator):
-            def allreduce(self, buffers, op=None, in_place=False):  # type: ignore[override]
+            def allreduce(self, buffers, op=None, in_place=False, divisor=None):  # type: ignore[override]
                 fut: Future = Future()
+                averaged = super().allreduce(buffers, divisor=divisor).wait()
 
                 def _later() -> None:
                     _time.sleep(0.3)
-                    fut.set_result(buffers)
+                    fut.set_result(averaged)
 
                 _threading.Thread(target=_later, daemon=True).start()
                 return Work(fut)
@@ -459,7 +460,7 @@ def test_div_is_bit_equal_to_the_out_of_place_formula(dtype_name: str, n: int) -
     """``_div`` averages a reduced buffer with no array of the payload's
     size beside it, and gives bit for bit what ``(a / n).astype(a.dtype)``
     (integers ``a // n``) gave: no configuration can train differently."""
-    from torchft_tpu.manager import _div
+    from torchft_tpu.communicator import _div
 
     a = _div_input(dtype_name)
     keep = a.copy()

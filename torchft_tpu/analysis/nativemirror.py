@@ -18,6 +18,8 @@ every shared constant against its live Python counterpart:
 - ``kMinStripeBytes``       == ``communicator._MIN_STRIPE_BYTES``
 - ``kMaxAutoLanes``         == ``communicator._MAX_AUTO_LANES``
 - ``kRingReduceTagBase``    == ``wire.RING_REDUCE_TAG_BASE``
+- ``kRingAvgTagBase``       == ``wire.RING_AVG_TAG_BASE`` (the averaging ring)
+- ``kRingBufferTagStride``  == ``wire.RING_BUFFER_TAG_STRIDE``
 - ``kMaxIovSegs``           == ``native._MAX_IOV_SEGS`` (the scatter-gather
   framing's per-syscall segment batch, mirrored in the ctypes binding)
 - pacer knob names: ``comm.h`` must reference every ``TORCHFT_NET_*`` env
@@ -256,31 +258,38 @@ def check_comm_header(text: str, rel: str = _COMM_H) -> List[Finding]:
             )
         )
 
-    # explicit reduce_scatter tag window — a drift here frames the ring at
-    # the wrong tags against a Python peer (silent cross-tier corruption)
-    m = re.search(r"kRingReduceTagBase\s*=\s*(\d+)", text)
+    # the rings' tag windows (the explicit reduce_scatter's, the averaging
+    # ring's, the stride between a call's dtype groups) — a drift here frames
+    # a ring at the wrong tags against a Python peer: a failed op at best, at
+    # worst (two windows that meet) a ring that mixes sums and averages
     from torchft_tpu import wire as pywire
 
-    if not m:
-        findings.append(
-            _finding(
-                rel,
-                1,
-                "kRingReduceTagBase",
-                "kRingReduceTagBase not found in comm.h — the native "
-                "reduce_scatter no longer mirrors wire.RING_REDUCE_TAG_BASE",
+    for const, py_name in (
+        ("kRingReduceTagBase", "RING_REDUCE_TAG_BASE"),
+        ("kRingAvgTagBase", "RING_AVG_TAG_BASE"),
+        ("kRingBufferTagStride", "RING_BUFFER_TAG_STRIDE"),
+    ):
+        m = re.search(const + r"\s*=\s*(\d+)", text)
+        if not m:
+            findings.append(
+                _finding(
+                    rel,
+                    1,
+                    const,
+                    f"{const} not found in comm.h — the native rings no "
+                    f"longer mirror wire.{py_name}",
+                )
             )
-        )
-    elif int(m.group(1)) != pywire.RING_REDUCE_TAG_BASE:
-        findings.append(
-            _finding(
-                rel,
-                _line_of(text, r"kRingReduceTagBase"),
-                "kRingReduceTagBase",
-                f"native kRingReduceTagBase = {m.group(1)} but Python "
-                f"wire.RING_REDUCE_TAG_BASE = {pywire.RING_REDUCE_TAG_BASE}",
+        elif int(m.group(1)) != getattr(pywire, py_name):
+            findings.append(
+                _finding(
+                    rel,
+                    _line_of(text, const),
+                    const,
+                    f"native {const} = {m.group(1)} but Python "
+                    f"wire.{py_name} = {getattr(pywire, py_name)}",
+                )
             )
-        )
 
     # iovec segment batch: mirrored in the ctypes binding (_MAX_IOV_SEGS)
     from torchft_tpu import native as pynative

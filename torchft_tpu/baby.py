@@ -146,7 +146,7 @@ def _worker_main(cmd_pipe, out_pipe, backend: str, timeout_s: float) -> None:
                 views = _views(shm.buf, args["metas"])
                 if op == "allreduce_shm":
                     got = comm.allreduce(
-                        views, args["op"], in_place=True
+                        views, args["op"], in_place=True, divisor=args["divisor"]
                     ).wait(timeout=timeout_s)
                 else:
                     got = comm.broadcast(views, args["root"]).wait(
@@ -175,9 +175,9 @@ def _worker_main(cmd_pipe, out_pipe, backend: str, timeout_s: float) -> None:
                     "meta": (shard.dtype.str, tuple(shard.shape), 0),
                 }
             elif op == "allreduce":
-                result = comm.allreduce(args["buffers"], args["op"]).wait(
-                    timeout=timeout_s
-                )
+                result = comm.allreduce(
+                    args["buffers"], args["op"], divisor=args["divisor"]
+                ).wait(timeout=timeout_s)
             elif op == "broadcast":
                 result = comm.broadcast(args["buffers"], args["root"]).wait(
                     timeout=timeout_s
@@ -557,16 +557,18 @@ class BabyCommunicator(Communicator):
         buffers: Buffers,
         op: ReduceOp = ReduceOp.SUM,
         in_place: bool = False,
+        divisor: Optional[int] = None,
     ) -> Work:
+        # the child's ring divides (it reduces the arena's copy, or its own)
         arrays, single = self._as_list(buffers)
         if sum(a.nbytes for a in arrays) >= _SHM_MIN:
             return self._shm_arrays_op(
-                "allreduce_shm", arrays, dict(op=op), in_place, single
+                "allreduce_shm", arrays, dict(op=op, divisor=divisor), in_place, single
             )
         # small payloads: the pickle copy is cheaper than an arena trip.
         # in_place must mean the same thing at every size: land the
         # pickled results back in the caller's buffers
-        work = self._submit("allreduce", dict(buffers=buffers, op=op))
+        work = self._submit("allreduce", dict(buffers=buffers, op=op, divisor=divisor))
         if not in_place:
             return work
 

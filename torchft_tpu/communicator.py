@@ -80,8 +80,10 @@ Buffers = Union[np.ndarray, Sequence[np.ndarray]]
 
 
 class ReduceOp(Enum):
-    """Reduction for collectives; AVG divides the SUM by the communicator
-    world size (the Manager instead divides by live participants)."""
+    """Reduction for collectives; AVG is SUM with the communicator's world
+    size for a divisor (the Manager passes ``divisor=`` its live
+    participants instead: a healing or spare replica rides the ring with
+    zeros and is not counted)."""
 
     SUM = "sum"
     AVG = "avg"
@@ -93,6 +95,54 @@ def _bytes_view(arr: np.ndarray) -> memoryview:
     """Writable raw-byte view of a contiguous array; extension dtypes like
     bfloat16 reject memoryview.cast, so reinterpret through uint8 instead."""
     return memoryview(arr.reshape(-1).view(np.uint8))
+
+
+def _div(a: np.ndarray, n: int, out: Optional[np.ndarray] = None) -> np.ndarray:
+    """``a / n`` in ``a``'s dtype: the one place in Python that averages a
+    reduced buffer (``native/comm.h`` ``average_buffer`` is its twin, bit for
+    bit).  ``out=a`` writes the average over the sum (the caller owns the
+    buffer); ``out=None`` leaves ``a`` untouched and returns one new array:
+    a communicator may return the caller's own buffer aliased
+    (DummyCommunicator passthrough), and mutating it would silently corrupt a
+    retained gradient.  ``n == 1`` is ``a`` itself, no pass.
+
+    Integer grads floor-divide.  Everything else (incl. extension float
+    dtypes like bfloat16, which are NOT np.inexact subdtypes) true-divides in
+    float32 (what is wider stays as wide) and rounds to nearest-even: bit for
+    bit ``(a / n).astype(a.dtype)``.  The divisor is a scalar of that
+    arithmetic, never of ``a``'s dtype (257 is no bfloat16), and numpy casts
+    block by block through its own small buffer, so nothing of the payload's
+    size is allocated: ``a / n`` made a float32 array of twice a bfloat16
+    payload and the cast a third, all on freshly mapped pages, which on the
+    v5e's host was the whole cost of this stage (267 MB/s against 2,670 in
+    place; PERF.md section 6, PR 27)."""
+    if n == 1:
+        return a
+    if out is None:
+        out = np.empty_like(a)
+    if np.issubdtype(a.dtype, np.integer):
+        return np.floor_divide(a, n, out=out)
+    divisor = np.result_type(a.dtype, np.float32).type(n)
+    return np.true_divide(a, divisor, out=out, casting="unsafe")
+
+
+def _sum_divisor(
+    op: ReduceOp, divisor: Optional[int], world_size: int
+) -> Tuple[ReduceOp, Optional[int]]:
+    """What an allreduce's ``op`` and ``divisor`` ask of the ring: (the
+    reduction, the divisor or None).  AVG is SUM over the world size; a
+    divisor goes with SUM alone; 1 divides nothing."""
+    if op == ReduceOp.AVG:
+        if divisor is not None:
+            raise ValueError("ReduceOp.AVG divides by the world size: pass SUM with a divisor")
+        op, divisor = ReduceOp.SUM, world_size
+    if divisor is None:
+        return op, None
+    if op != ReduceOp.SUM:
+        raise ValueError(f"a divisor goes with ReduceOp.SUM, not {op}")
+    if divisor < 1:
+        raise ValueError(f"an allreduce's divisor is a count of replicas, not {divisor}")
+    return op, (None if divisor == 1 else int(divisor))
 
 
 def _reduce_into(op: ReduceOp, acc: np.ndarray, incoming: np.ndarray) -> None:
@@ -144,6 +194,7 @@ class Communicator(ABC):
         buffers: Buffers,
         op: ReduceOp = ReduceOp.SUM,
         in_place: bool = False,
+        divisor: Optional[int] = None,
     ) -> Work:
         """Reduce ``buffers`` across ranks; the Work's value is the reduced
         list of arrays (AVG divides by world size).
@@ -152,7 +203,18 @@ class Communicator(ABC):
         (contiguous, writable) buffers and return them aliased — c10d
         allreduce semantics, skipping a full-payload copy.  Only pass it for
         buffers you own and will not reuse (on error the contents are
-        unspecified; the step is voted down anyway)."""
+        unspecified; the step is voted down anyway).
+
+        ``divisor`` (with SUM): the Work's value is ``SUM / divisor``, bit
+        for bit :func:`_div` of the sum as the ring rounds it to the
+        buffers' dtype.  A tier with a ring divides INSIDE it (the rank that
+        owns a chunk at the end of the reduce phase divides it before the
+        allgather phase sends it round: no pass over the payload afterwards,
+        and each rank divides 1/N of it); every rank of a ring must pass the
+        same divisor, and a ring with one frames itself apart
+        (``wire.RING_AVG_TAG_BASE``), so a peer that expects sums fails the
+        op.  A tier with no ring of its own divides its result out of place
+        and never writes into a buffer it was handed aliased."""
 
     @abstractmethod
     def broadcast(self, buffers: Buffers, root: int = 0) -> Work:
@@ -3061,13 +3123,16 @@ class TCPCommunicator(Communicator):
         buffers: Buffers,
         op: ReduceOp = ReduceOp.SUM,
         in_place: bool = False,
+        divisor: Optional[int] = None,
     ) -> Work:
         arrays = self._as_list(buffers)
         single = isinstance(buffers, np.ndarray)
 
         def _make(ctx: "_CommCtx") -> Callable[[], object]:
+            ring_op, n = _sum_divisor(op, divisor, ctx.world_size)
+
             def _run() -> object:
-                out = _allreduce_sync(ctx, arrays, op, in_place=in_place)
+                out = _allreduce_sync(ctx, arrays, ring_op, in_place=in_place, divisor=n)
                 return out[0] if single else out
 
             return _run
@@ -3423,6 +3488,7 @@ class _LeaderComm(Communicator):
         buffers: Buffers,
         op: ReduceOp = ReduceOp.SUM,
         in_place: bool = False,
+        divisor: Optional[int] = None,
     ) -> Work:
         raise NotImplementedError("leader view carries alltoall/allgather only")
 
@@ -3450,7 +3516,10 @@ def _allreduce_sync(
     arrays: List[np.ndarray],
     op: ReduceOp,
     in_place: bool = False,
+    divisor: Optional[int] = None,
 ) -> List[np.ndarray]:
+    """``op`` and ``divisor`` as :func:`_sum_divisor` hands them out (no
+    AVG; a divisor of 2 or more, or None)."""
     ws = ctx.world_size
     out = [
         a
@@ -3480,27 +3549,26 @@ def _allreduce_sync(
                 reduce_flat(
                     ctx, flat, op,
                     tag_base=ring_idx * wire_tags.RING_BUFFER_TAG_STRIDE,
+                    divisor=divisor,
                 )
                 out[idxs[0]] = flat.reshape(out[idxs[0]].shape)
                 continue
             flat = np.concatenate([out[i].reshape(-1) for i in idxs])
             reduce_flat(
-                    ctx, flat, op,
-                    tag_base=ring_idx * wire_tags.RING_BUFFER_TAG_STRIDE,
-                )
+                ctx, flat, op,
+                tag_base=ring_idx * wire_tags.RING_BUFFER_TAG_STRIDE,
+                divisor=divisor,
+            )
             offset = 0
             for i in idxs:
                 n = out[i].size
                 out[i] = flat[offset : offset + n].reshape(out[i].shape)
                 offset += n
-    if op == ReduceOp.AVG:
+    elif divisor is not None:
+        # a ring of one has no phase to divide in: one pass over our own
+        # copies (or the buffers the caller gave up with in_place)
         for a in out:
-            if np.issubdtype(a.dtype, np.integer):
-                a //= ws
-            else:
-                # bfloat16/fp8 are not np.inexact subdtypes; true-divide all
-                # non-integer dtypes in place
-                np.divide(a, ws, out=a)
+            _div(a, divisor, out=a)
     return out
 
 
@@ -3578,6 +3646,7 @@ def _ring_allreduce(
     op: ReduceOp,
     tag_base: int = 0,
     ring: Optional[List[int]] = None,
+    divisor: Optional[int] = None,
 ) -> None:
     """In-place bandwidth-optimal ring allreduce.
 
@@ -3588,12 +3657,22 @@ def _ring_allreduce(
     order is fixed by the chunk schedule alone, so lane count never changes
     the bits.  ``ring`` restricts to a rank subset (the hierarchical leader
     ring); the default is the byte-for-byte legacy flat ring.
+
+    With a ``divisor`` ``flat`` comes back as the AVERAGE: the position that
+    owns a chunk after the reduce phase divides it (:func:`_div`) before the
+    allgather phase sends it round, as ``native/comm.h`` does at the same
+    point (mixed tiers ride one ring), and both phases are framed in the
+    averaging ring's own tag window.
     """
     if ring is None:
         ring = list(range(ctx.world_size))
     ws = len(ring)
     if ws == 1:
+        if divisor is not None:
+            _div(flat, divisor, out=flat)
         return
+    if divisor is not None:
+        tag_base += wire_tags.RING_AVG_TAG_BASE
     mesh = ctx.mesh
     assert mesh is not None
     pos = ring.index(ctx.rank)
@@ -3601,7 +3680,9 @@ def _ring_allreduce(
     left = ring[(pos - 1) % ws]
     deadline = ctx.deadline()
 
-    _ring_reduce_scatter(ctx, flat, op, tag_base, ring=ring)
+    own = _ring_reduce_scatter(ctx, flat, op, tag_base, ring=ring)
+    if divisor is not None:
+        _div(own, divisor, out=own)
     bounds = _ring_bounds(flat.size, ws)
 
     def chunk(i: int) -> np.ndarray:
@@ -3620,7 +3701,11 @@ def _ring_allreduce(
 
 
 def _hier_allreduce(
-    ctx: _CommCtx, flat: np.ndarray, op: ReduceOp, tag_base: int = 0
+    ctx: _CommCtx,
+    flat: np.ndarray,
+    op: ReduceOp,
+    tag_base: int = 0,
+    divisor: Optional[int] = None,
 ) -> None:
     """Two-level in-place allreduce over the discovered host topology:
     intra-host shared-memory reduce (fixed ascending-rank order) → striped
@@ -3628,14 +3713,15 @@ def _hier_allreduce(
     broadcast.  Each byte crosses the DCN once per HOST instead of once per
     replica; results are deterministic (fixed reduction order) and
     bit-identical across lane counts at a fixed topology, though not
-    bit-identical to the flat ring (different reduction ORDER — allclose)."""
+    bit-identical to the flat ring (different reduction ORDER — allclose).
+    A ``divisor`` is the leader ring's: the fan-out carries averages."""
     mesh = ctx.mesh
     assert mesh is not None and mesh.topo is not None
     topo = mesh.topo
     deadline = ctx.deadline()
     mesh.shm_reduce(flat, op, deadline)
-    if topo.is_leader and len(topo.leader_ring) > 1:
-        _ring_allreduce(ctx, flat, op, tag_base, ring=topo.leader_ring)
+    if topo.is_leader:
+        _ring_allreduce(ctx, flat, op, tag_base, ring=topo.leader_ring, divisor=divisor)
     mesh.shm_bcast(flat, deadline)
 
 
@@ -3791,8 +3877,23 @@ class DummyCommunicator(Communicator):
         buffers: Buffers,
         op: ReduceOp = ReduceOp.SUM,
         in_place: bool = False,
+        divisor: Optional[int] = None,
     ) -> Work:
-        return DummyWork(buffers)
+        # the passthrough hands back the caller's own buffers (the "sum" is
+        # one contribution, so AVG divides nothing): an average is made out
+        # of place unless the caller gave the buffer up (in_place) and it
+        # can be written
+        _, n = _sum_divisor(op, divisor, 1)
+        if n is None:
+            return DummyWork(buffers)
+
+        def _avg(b: object) -> np.ndarray:
+            a = np.asarray(b)
+            return _div(a, n, a if in_place and a.flags.writeable else None)
+
+        if isinstance(buffers, np.ndarray):
+            return DummyWork(_avg(buffers))
+        return DummyWork([_avg(b) for b in buffers])
 
     def broadcast(self, buffers: Buffers, root: int = 0) -> Work:
         return DummyWork(buffers)
@@ -3871,8 +3972,11 @@ class FakeCommunicatorWrapper(Communicator):
         buffers: Buffers,
         op: ReduceOp = ReduceOp.SUM,
         in_place: bool = False,
+        divisor: Optional[int] = None,
     ) -> Work:
-        return self._wrap(self._comm.allreduce(buffers, op, in_place=in_place))
+        return self._wrap(
+            self._comm.allreduce(buffers, op, in_place=in_place, divisor=divisor)
+        )
 
     def broadcast(self, buffers: Buffers, root: int = 0) -> Work:
         return self._wrap(self._comm.broadcast(buffers, root))
@@ -3960,7 +4064,10 @@ class ManagedCommunicator(Communicator):
         buffers: Buffers,
         op: ReduceOp = ReduceOp.SUM,
         in_place: bool = False,
+        divisor: Optional[int] = None,
     ) -> Work:
+        if divisor is not None:
+            raise ValueError("the Manager averages over its participants: no divisor")
         return self._manager.allreduce(buffers)
 
     def broadcast(self, buffers: Buffers, root: int = 0) -> Work:

@@ -42,7 +42,7 @@ from torchft_tpu.obs.flight import FlightEvent, FlightRecorder, flight_dir
 from torchft_tpu.obs import spans as obs_spans
 from torchft_tpu.obs.spans import span as obs_span
 from torchft_tpu.checkpointing.transport import CheckpointTransport
-from torchft_tpu.communicator import Communicator, ReduceOp
+from torchft_tpu.communicator import Communicator, ReduceOp, _div
 from torchft_tpu.manager_server import ManagerClient, ManagerServer
 from torchft_tpu.store import StoreClient, StoreServer
 from torchft_tpu.work import DummyWork, Event, Work
@@ -1276,7 +1276,9 @@ class Manager:
         Returns a Work whose value is the averaged array(s).  If an error was
         already recorded this step the input is returned unchanged; if this
         replica is not participating (healing/spare) its contribution is
-        zeroed and the result is still divided by ``num_participants()``.
+        zeroed and the result is still divided by ``num_participants()``:
+        the communicator is handed that count as its ``divisor`` and its ring
+        returns the average (the quantized ring returns sums, divided here).
 
         ``in_place=True`` skips the communicator's full-payload defensive
         copy by reducing directly in ``data``'s buffers, and the AVERAGE is
@@ -1341,6 +1343,14 @@ class Manager:
             data = _scale_contribution(data, scale)
 
         try:
+            # AVG = SUM / runtime participant count — replica count is never
+            # baked into compiled programs (SURVEY.md §7 hard part 1).  The
+            # count is not the ring's world size (a healing or spare replica
+            # rides the ring with zeros and is not counted), so it goes to
+            # the communicator as the divisor: the owner of a chunk divides
+            # it inside the ring, and the value that comes back is the
+            # average.  The quantized ring still hands back sums.
+            in_ring = not should_quantize
             if should_quantize:
                 from torchft_tpu.collectives import allreduce_quantized
                 from torchft_tpu.quantization import quant_kind
@@ -1349,24 +1359,28 @@ class Manager:
                 # fp8 e4m3 (the reference's format) via TORCHFT_QUANT_KIND
                 work = allreduce_quantized(self._comm, data, kind=quant_kind())
             else:
-                work = self._comm.allreduce(data, ReduceOp.SUM, in_place=in_place)
+                work = self._comm.allreduce(
+                    data, ReduceOp.SUM, in_place=in_place, divisor=num_participants
+                )
 
-            # AVG = SUM / runtime participant count — replica count is never
-            # baked into compiled programs (SURVEY.md §7 hard part 1)
             def _normalize(value: object) -> object:
                 # runs on the thread that completed the collective (the
                 # communicator's op thread: the next bucket's ring waits)
                 single = isinstance(value, np.ndarray)
                 arrays = [value] if single else cast(list, value)
-                # the caller's flag says whose the reduced buffers are (a
-                # read-only one the communicator let through is not ours)
-                outs = [a if in_place and a.flags.writeable else None for a in arrays]
                 with obs_span(
                     "tpuft/manager/normalize",
                     bytes=sum(int(a.nbytes) for a in arrays),
-                    in_place=int(all(o is not None for o in outs)),
+                    in_ring=int(in_ring),
                 ):
-                    out = [_div(a, num_participants, o) for a, o in zip(arrays, outs)]
+                    if in_ring:
+                        return value
+                    # the caller's flag says whose the reduced buffers are
+                    # (a read-only one the communicator let through is not ours)
+                    out = [
+                        _div(a, num_participants, a if in_place and a.flags.writeable else None)
+                        for a in arrays
+                    ]
                 return out[0] if single else out
 
             wrapped = self.wrap_work(work.then(_normalize), data)
@@ -1860,34 +1874,6 @@ def _scale_contribution(
     if isinstance(data, np.ndarray):
         return _one(data)
     return [_one(a) for a in data]
-
-
-def _div(a: np.ndarray, n: int, out: Optional[np.ndarray] = None) -> np.ndarray:
-    """``a / n`` in ``a``'s dtype: the one place that averages a reduced
-    buffer.  ``out=a`` writes the average over the sum (the caller owns the
-    buffer); ``out=None`` leaves ``a`` untouched and returns one new array:
-    the communicator may return the caller's own buffer aliased
-    (DummyCommunicator passthrough), and mutating it would silently corrupt a
-    retained gradient.  ``n == 1`` is ``a`` itself, no pass.
-
-    Integer grads floor-divide.  Everything else (incl. extension float
-    dtypes like bfloat16, which are NOT np.inexact subdtypes) true-divides in
-    float32 (what is wider stays as wide) and rounds to nearest-even: bit for
-    bit ``(a / n).astype(a.dtype)``.  The divisor is a scalar of that
-    arithmetic, never of ``a``'s dtype (257 is no bfloat16), and numpy casts
-    block by block through its own small buffer, so nothing of the payload's
-    size is allocated: ``a / n`` made a float32 array of twice a bfloat16
-    payload and the cast a third, all on freshly mapped pages, which on the
-    v5e's host was the whole cost of this stage (267 MB/s against 2,670 in
-    place; PERF.md section 6, PR 27)."""
-    if n == 1:
-        return a
-    if out is None:
-        out = np.empty_like(a)
-    if np.issubdtype(a.dtype, np.integer):
-        return np.floor_divide(a, n, out=out)
-    divisor = np.result_type(a.dtype, np.float32).type(n)
-    return np.true_divide(a, divisor, out=out, casting="unsafe")
 
 
 class _ManagerLogger:

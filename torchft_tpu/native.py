@@ -37,6 +37,7 @@ from torchft_tpu.communicator import (
     CommunicatorAborted,
     CommunicatorError,
     ReduceOp,
+    _sum_divisor,
 )
 from torchft_tpu.futures import TimerHandle, schedule_timeout
 from torchft_tpu.obs.flight import FlightEvent, FlightRecorder
@@ -222,6 +223,8 @@ def _load() -> Optional[ctypes.CDLL]:
             ctypes.c_uint64,
             ctypes.c_int32,
             ctypes.c_int32,
+            ctypes.c_uint64,
+            ctypes.c_uint64,
         ]
         lib.tpuft_comm_alltoall_ptrs.argtypes = [
             ctypes.c_void_p,
@@ -985,10 +988,12 @@ class CppCommunicator(Communicator):
         buffers: Buffers,
         op: ReduceOp = ReduceOp.SUM,
         in_place: bool = False,
+        divisor: Optional[int] = None,
     ) -> Work:
         arrays = self._as_list(buffers)
         single = isinstance(buffers, np.ndarray)
-        ws = self._world_size
+        # the ring divides (comm.h average_buffer): AVG is SUM over the world
+        op, divisor = _sum_divisor(op, divisor, self._world_size)
 
         def _run() -> object:
             out: List[np.ndarray] = [None] * len(arrays)  # type: ignore[list-item]
@@ -1000,7 +1005,7 @@ class CppCommunicator(Communicator):
             by_dtype: Dict[str, List[int]] = {}
             for i, a in enumerate(arrays):
                 by_dtype.setdefault(a.dtype.name, []).append(i)
-            for dtype_name, idxs in by_dtype.items():
+            for group, (dtype_name, idxs) in enumerate(by_dtype.items()):
                 code = _DTYPE_CODES.get(dtype_name)
                 if code is None:
                     raise CommunicatorError(f"unsupported dtype {dtype_name}")
@@ -1024,25 +1029,19 @@ class CppCommunicator(Communicator):
                     out[i] = flat
                 total = sum(int(f.nbytes) for f in flats)
                 if total > 0:
-                    n = len(flats)
-                    ptrs = (ctypes.c_void_p * n)(
+                    ptrs = (ctypes.c_void_p * len(flats))(
                         *(_data_ptr(f) for f in flats)
                     )
-                    lens = (ctypes.c_uint64 * n)(
+                    lens = (ctypes.c_uint64 * len(flats))(
                         *(int(f.nbytes) for f in flats)
                     )
                     self._check(
                         self._lib.tpuft_comm_allreduce_iov(
-                            self._h, ptrs, lens, n, code, _OP_CODES[op]
+                            self._h, ptrs, lens, len(flats), code,
+                            _OP_CODES[op], divisor or 0, group,
                         ),
                         "allreduce",
                     )
-                if op == ReduceOp.AVG:
-                    for f in flats:
-                        if np.issubdtype(f.dtype, np.integer):
-                            f //= ws
-                        else:
-                            np.divide(f, ws, out=f)
                 for i in idxs:
                     out[i] = out[i].reshape(arrays[i].shape)
             return out[0] if single else out

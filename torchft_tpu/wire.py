@@ -236,6 +236,11 @@ HIER_HOST_BLOCK_TAG_OFFSET = 9000  # hier allgather host-block exchange
 
 # -- internal allocators ----------------------------------------------------
 RING_REDUCE_TAG_BASE = 30_000  # explicit reduce_scatter API calls
+RING_AVG_TAG_BASE = 100_000  # an allreduce with a divisor: BOTH phases of a
+#   ring whose chunks' owners divide between them are framed here (on top of
+#   the buffer stride), so a peer that expects sums (or predates the divisor)
+#   meets a tag mismatch and the op fails: never sums for some chunks and
+#   averages for others.  Mirrored by native/comm.h kRingAvgTagBase.
 RING_BUFFER_TAG_STRIDE = 10_000  # multi-buffer allreduce: buffer i at i*stride
 HEAL_TAG_BASE = 9000  # striped heal (comm_transport.py): base*1000 +
 HEAL_STEP_TAG_STRIDE = 10_000_000  # step*stride salting, p2p lane only
@@ -276,6 +281,7 @@ WIRE_TAG_OFFSETS = {
 }
 INTERNAL_TAG_BASES = {
     "RING_REDUCE": RING_REDUCE_TAG_BASE,
+    "RING_AVG": RING_AVG_TAG_BASE,
     "RING_BUFFER_STRIDE": RING_BUFFER_TAG_STRIDE,
     "HEAL": HEAL_TAG_BASE,
     "HEAL_STEP_STRIDE": HEAL_STEP_TAG_STRIDE,
