@@ -1,5 +1,5 @@
 """The parts of the compiled step (``torchft_tpu/obs/spans.py``,
-``DEVICE_PARTS``): every operation that costs device time in the four models'
+``DEVICE_PARTS``): every operation that costs device time in the five models'
 two step programs is traced under a ``tpuft.<part>`` scope, at toy widths and
 on both paths (plain, and the kernels in interpret mode).  The paths are read
 from the COMPILED text's ``op_name``s: XLA inlines every private function
@@ -15,7 +15,7 @@ import pytest
 
 from torchft_tpu.obs.spans import DEVICE_PARTS, PART_PREFIX, part
 
-MODELS = ("llama", "ling_hybrid", "indexed_sparse_moe", "ssm_hybrid_moe")
+MODELS = ("llama", "ling_hybrid", "indexed_sparse_moe", "ssm_hybrid_moe", "windowed_moe")
 CASES = [(m, p) for m in MODELS for p in ("plain", "kernels")]
 EVERY = set(DEVICE_PARTS)
 # Keye has no dense MLP and no shared expert; Mistral has no experts
@@ -24,6 +24,7 @@ USES = {
     "ling_hybrid": EVERY,
     "indexed_sparse_moe": EVERY - {"ffn"},
     "ssm_hybrid_moe": EVERY,
+    "windowed_moe": EVERY,
 }
 # what costs time on a device and is never fused away into a neighbour
 HELD = ("dot", "convolution", "gather", "scatter", "sort")
@@ -45,6 +46,10 @@ def _model(name):
         from torchft_tpu.models.indexed_sparse_moe import IndexedSparseMoE, indexed_sparse_debug
 
         return IndexedSparseMoE(indexed_sparse_debug()), 32
+    if name == "windowed_moe":
+        from torchft_tpu.models.windowed_moe import WindowedMoE, windowed_moe_debug
+
+        return WindowedMoE(windowed_moe_debug()), 128
     from torchft_tpu.models.ssm_hybrid_moe import SsmHybridMoE, ssm_hybrid_debug
 
     return SsmHybridMoE(ssm_hybrid_debug()), 128

@@ -325,3 +325,101 @@ def test_head_sizes_that_do_not_fit_are_refused() -> None:
         flash_attention(q, k[..., :32], v, interpret=True)  # q and k differ
     with pytest.raises(ValueError):
         flash_attention(q, k, v[:, :128], interpret=True)  # v shorter than k
+
+
+# ---------------------------------------------------------------------------
+# a sliding window: the kernels walk only the blocks the window touches
+# ---------------------------------------------------------------------------
+
+
+def _ref_windowed(q, k, v, window):
+    """Plain attention under an explicit mask: query ``i`` sees keys ``j``
+    with ``i - window < j <= i``."""
+    S, D = q.shape[1], q.shape[3]
+    groups = q.shape[2] // k.shape[2]
+    kf, vf = jnp.repeat(k, groups, axis=2), jnp.repeat(v, groups, axis=2)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, kf).astype(jnp.float32) / np.sqrt(D)
+    i, j = jnp.arange(S)[:, None], jnp.arange(S)[None, :]
+    s = jnp.where((j <= i) & (j > i - window), s, -1e30)
+    return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1).astype(q.dtype), vf)
+
+
+WINDOW_CASES = [
+    # S, H, KV, D, bq, bk, window
+    (512, 4, 2, 64, 128, 128, 256),  # a multiple of the block, under the sequence
+    (512, 4, 2, 64, 128, 128, 200),  # no multiple of the block
+    (512, 4, 2, 64, 128, 128, 1),  # the query's own position alone
+    (512, 4, 2, 64, 128, 128, 129),  # one position past a block
+    (512, 4, 2, 64, 128, 128, 512),  # at the sequence: causal
+    (512, 4, 2, 64, 128, 128, 700),  # over the sequence: causal
+    (512, 4, 2, 64, 64, 128, 150),  # row blocks smaller than key blocks
+    (512, 4, 2, 64, 256, 64, 100),  # key blocks smaller than row blocks
+    (256, 32, 4, 16, 64, 64, 96),  # 32 query heads over 4 KV heads
+]
+
+
+@pytest.mark.parametrize("S,H,KV,D,bq,bk,window", WINDOW_CASES)
+def test_window_forward_matches_masked_attention(S, H, KV, D, bq, bk, window) -> None:
+    q, k, v = _qkv(1, S, H, KV, D)
+    out = flash_attention(q, k, v, block_q=bq, block_k=bk, window=window, interpret=True)
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(_ref_windowed(q, k, v, window)), rtol=2e-5, atol=2e-5
+    )
+
+
+@pytest.mark.parametrize("wrt", ["dq", "dkv"])
+@pytest.mark.parametrize("S,H,KV,D,bq,bk,window", WINDOW_CASES)
+def test_window_backward_matches_masked_attention(S, H, KV, D, bq, bk, window, wrt) -> None:
+    q, k, v = _qkv(1, S, H, KV, D)
+    argnums = (0,) if wrt == "dq" else (1, 2)
+
+    def loss(attend):
+        return lambda q, k, v: jnp.sum(jnp.sin(attend(q, k, v)))
+
+    flash = lambda q, k, v: flash_attention(  # noqa: E731
+        q, k, v, block_q=bq, block_k=bk, window=window, interpret=True
+    )
+    got = jax.grad(loss(flash), argnums=argnums)(q, k, v)
+    want = jax.grad(loss(lambda q, k, v: _ref_windowed(q, k, v, window)), argnums=argnums)(q, k, v)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-4)
+
+
+def _pallas_calls(fn, *args):
+    """(name, grid) of every ``pallas_call`` in ``fn``'s gradient program."""
+    found = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                found.append((eqn.params["name"], tuple(eqn.params["grid_mapping"].grid)))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(jax.grad(lambda *a: jnp.sum(fn(*a)), argnums=(0, 1, 2)))(*args).jaxpr)
+    return dict(found)
+
+
+def test_window_walks_only_its_blocks_and_names_its_programs() -> None:
+    """The grid holds the window's blocks plus one and not the sequence's:
+    a window that masked a full walk would keep the full grid."""
+    q, k, v = _qkv(1, 2048, 8, 1, 16)
+    blocks = dict(block_q=128, block_k=128, interpret=True)
+    windowed = _pallas_calls(lambda q, k, v: flash_attention(q, k, v, window=256, **blocks), q, k, v)
+    assert windowed == {
+        "flash_win_fwd": (1, 8, 16, 3), "flash_win_dq": (1, 8, 16, 3), "flash_win_dkv": (1, 1, 16, 8 * 3),
+    }
+    full = _pallas_calls(lambda q, k, v: flash_attention(q, k, v, **blocks), q, k, v)
+    assert full == {"flash_fwd": (1, 8, 16, 16), "flash_dq": (1, 8, 16, 16), "flash_dkv": (1, 1, 16, 8 * 16)}
+    # a window that covers the sequence IS causal attention: the full layers' programs
+    assert _pallas_calls(lambda q, k, v: flash_attention(q, k, v, window=2048, **blocks), q, k, v) == full
+    # no multiple of a block: one more block at the far edge
+    odd = _pallas_calls(lambda q, k, v: flash_attention(q, k, v, window=258, **blocks), q, k, v)
+    assert odd["flash_win_fwd"] == (1, 8, 16, 4) and odd["flash_win_dkv"] == (1, 1, 16, 8 * 4)
+
+
+@pytest.mark.parametrize("window,causal", [(0, True), (-3, True), (2.5, True), (True, True), (64, False)])
+def test_window_validation(window, causal) -> None:
+    q, k, v = _qkv(1, 256, 4, 2, 64)
+    with pytest.raises(ValueError, match="window"):
+        flash_attention(q, k, v, causal=causal, window=window, interpret=True)

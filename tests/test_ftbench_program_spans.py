@@ -50,6 +50,12 @@ SCOPE_READERS = (
 # PR 40: the share of a step's collectives whose average the ring made itself
 # (the span tpuft/manager/normalize carries in_ring=1)
 IN_RING_READERS = ("normalize_in_ring_pct",)
+# PR 41: the readers of the cell trinitymini-ws1-seq16k (their own tests:
+# ftbench/tests/test_ftbench_swa.py), which also joined the experts' three lists, flash's three and the scopes' eleven
+SWA_READERS = (
+    "swa_flash_ms", "swa_flash_roofline", "swa_full_flash_roofline", "swa_window_over_full_pct",
+    "swa_moe_gmm_roofline", "swa_step_mfu_pct",
+)
 
 
 @pytest.mark.parametrize("name", sorted(LATER_READINGS))
@@ -65,7 +71,7 @@ def test_new_readers_are_the_eighteen_benchmark_json_lists():  # noqa: F811
         per_layer = json.load(f)["per_layer"]
     appended = (
         LATER_READINGS, LING_READERS, BUCKET_READERS, ORDER_READERS, INDEXED_READERS, SSM_READERS, AHEAD_READERS,
-        SCOPE_READERS, IN_RING_READERS,
+        SCOPE_READERS, IN_RING_READERS, SWA_READERS,
     )
     later = sum(map(len, appended))
     assert [m["name"] for m in per_layer[-later:]] == [name for group in appended for name in group]
@@ -73,9 +79,9 @@ def test_new_readers_are_the_eighteen_benchmark_json_lists():  # noqa: F811
     assert len(theirs_new) == 18
     assert {m["name"] for m in per_layer[-18 - later:-later]} == theirs_new
     for entry in per_layer[-18 - later:]:
-        cells = 3 if entry["name"] in EXPERT_CELLS else 2 if entry["name"] in FLASH_CELLS else 1
-        if entry["name"] in SCOPE_READERS:  # the four one-replica cells, or the three that have the part
-            cells = 3 if entry["name"] in ("xla_ffn_ms", "moe_route_ms", "moe_dispatch_ms") else 4
+        cells = 4 if entry["name"] in EXPERT_CELLS else 3 if entry["name"] in FLASH_CELLS else 1
+        if entry["name"] in SCOPE_READERS:  # the five one-replica cells, or the four that have the part
+            cells = 4 if entry["name"] in ("xla_ffn_ms", "moe_route_ms", "moe_dispatch_ms") else 5
         assert len(entry["workloads"]) == cells and set(entry) == {
             "name", "unit", "better", "source", "layer", "moves", "workloads",
         }

@@ -36,6 +36,10 @@ PUBLISHED_WIDTHS = {
         mamba_num_heads=64, mamba_head_dim=64, ssm_state_size=128, n_groups=8, conv_kernel=4, chunk_size=128,
         expand=2, num_experts_per_tok=6,
     ),
+    "https://huggingface.co/arcee-ai/Trinity-Mini/blob/main/config.json": dict(
+        hidden_size=2048, intermediate_size=6144, moe_intermediate_size=1024, num_attention_heads=32,
+        num_key_value_heads=4, head_dim=128, num_experts_per_tok=8, sliding_window=2048, num_shared_experts=1,
+    ),
 }
 
 

@@ -12,19 +12,25 @@ from ftbench.tests.test_ftbench_ssm import *  # noqa: F401,F403
 
 # PR 36 appended its reader after PR 35's six, PR 37 the eleven that share
 # out the compiled step by its named parts (``ftbench/device_scopes.py``),
-# PR 40 the share of collectives the ring averaged itself
+# PR 40 the share of collectives the ring averaged itself, PR 41 the six of
+# the cell ``trinitymini-ws1-seq16k``
 LATER_READERS = (
     "heal_serve_ahead_pct",
     "xla_mixer_proj_ms", "xla_mixer_glue_ms", "xla_ffn_ms", "xla_stream_ms", "xla_head_ms", "moe_route_ms",
     "moe_dispatch_ms", "xla_layer_scan_ms", "optimizer_ms", "step_remat_ms", "xla_unscoped_ms",
     "normalize_in_ring_pct",
+    "swa_flash_ms", "swa_flash_roofline", "swa_full_flash_roofline", "swa_window_over_full_pct",
+    "swa_moe_gmm_roofline", "swa_step_mfu_pct",
 )
+# PR 41 appended a configuration and a cell after PR 35's, and the cell's name to the lists PR 35's joined
+LATER_CELLS = ("trinitymini-ws1-seq16k",)
 
 
 def test_the_cell_and_the_lists_it_joined(monkeypatch):  # noqa: F811
     """Theirs holds PR 35's six readers to be the LAST entries of
-    ``per_layer``; a later PR appends, so here they are the six before the
-    later ones, and the later ones are the last."""
+    ``per_layer`` and its cell and configuration the last of theirs; a later
+    PR appends, so here they are the last before the later ones, and the
+    later ones are the last."""
     load = json.load
 
     def without_the_later_ones(f):
@@ -33,6 +39,14 @@ def test_the_cell_and_the_lists_it_joined(monkeypatch):  # noqa: F811
             later = bench["per_layer"][-len(LATER_READERS):]
             assert [m["name"] for m in later] == list(LATER_READERS)
             bench["per_layer"] = bench["per_layer"][: -len(LATER_READERS)]
+            assert [w["name"] for w in bench["workloads"][-len(LATER_CELLS):]] == list(LATER_CELLS)
+            configs = {w["config"] for w in bench["workloads"][-len(LATER_CELLS):]}
+            assert {c["name"] for c in bench["configs"][-len(configs):]} == configs
+            bench["workloads"] = bench["workloads"][: -len(LATER_CELLS)]
+            bench["configs"] = bench["configs"][: -len(configs)]
+            for metric in bench["end_to_end"] + bench["per_layer"]:
+                if "workloads" in metric:
+                    metric["workloads"] = [w for w in metric["workloads"] if w not in LATER_CELLS]
         return bench
 
     monkeypatch.setattr(theirs.json, "load", without_the_later_ones)

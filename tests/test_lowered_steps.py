@@ -4,7 +4,9 @@ kernels in interpret mode), to the bytes they lowered to (but for the
 counters jax appends to the names of private functions) before a fourth
 model's expert form and kept names went in (PR 35: the digests under
 ``tests/fixtures/lowered_steps.json`` were written by THIS file run on the
-parent commit).  A later change that means to alter one of these programs
+parent commit), and ``llama``'s and ``ssm_hybrid_moe``'s to what they lowered
+to before ``flash_attention`` took a window (PR 41: theirs written the same
+way, on PR 41's parent; ``windowed_moe``'s is its own first tree's).  A later change that means to alter one of these programs
 writes the fixture anew and says so: ``python tests/test_lowered_steps.py
 --write``."""
 
@@ -17,7 +19,8 @@ import sys
 import pytest
 
 FIXTURE = os.path.join(os.path.dirname(os.path.abspath(__file__)), "fixtures", "lowered_steps.json")
-CASES = [(m, p) for m in ("ling_hybrid", "indexed_sparse_moe") for p in ("plain", "kernels")]
+MODELS = ("ling_hybrid", "indexed_sparse_moe", "llama", "ssm_hybrid_moe", "windowed_moe")
+CASES = [(m, p) for m in MODELS for p in ("plain", "kernels")]
 
 
 def _model(name):
@@ -25,6 +28,18 @@ def _model(name):
         from torchft_tpu.models.ling_hybrid import LingHybrid, ling_debug
 
         return LingHybrid(ling_debug()), 128
+    if name == "llama":
+        from torchft_tpu.models.llama import Llama, llama_debug
+
+        return Llama(llama_debug()), 128
+    if name == "ssm_hybrid_moe":
+        from torchft_tpu.models.ssm_hybrid_moe import SsmHybridMoE, ssm_hybrid_debug
+
+        return SsmHybridMoE(ssm_hybrid_debug()), 64
+    if name == "windowed_moe":
+        from torchft_tpu.models.windowed_moe import WindowedMoE, windowed_moe_debug
+
+        return WindowedMoE(windowed_moe_debug()), 64
     from torchft_tpu.models.indexed_sparse_moe import IndexedSparseMoE, indexed_sparse_debug
 
     return IndexedSparseMoE(indexed_sparse_debug()), 32
@@ -45,7 +60,8 @@ def digest(name: str, path: str) -> str:
         params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
         tokens = jax.ShapeDtypeStruct((1, seq), np.int32)
         text = make_grad_step(model, mesh).lower(params, (tokens, tokens)).as_text()
-        assert ("plain" in model.attention_path) == (path == "plain"), model.attention_path
+        off_kernels = any(word in model.attention_path for word in ("plain", "naive"))
+        assert off_kernels == (path == "plain"), model.attention_path
         # jax numbers its private functions (@silu_808) from one counter a
         # process: the numbers say what else was traced, not what the program is
         text = re.sub(r"@([A-Za-z_]\w*?)_\d+\b", r"@\1", text)
@@ -72,8 +88,11 @@ def test_lowers_to_the_bytes_it_lowered_to(name, path):
 if __name__ == "__main__" and "--write" in sys.argv:
     import jax
 
-    out = {"jax": jax.__version__, "sha256": {f"{n}:{p}": digest(n, p) for n, p in CASES}}
-    target = sys.argv[sys.argv.index("--write") + 1] if len(sys.argv) > sys.argv.index("--write") + 1 else FIXTURE
-    with open(target, "w") as f:
+    # ``--only a,b`` writes those models' digests anew and keeps the others'
+    only = sys.argv[sys.argv.index("--only") + 1].split(",") if "--only" in sys.argv else MODELS
+    with open(FIXTURE) as f:
+        kept = json.load(f)["sha256"]
+    out = {"jax": jax.__version__, "sha256": {**kept, **{f"{n}:{p}": digest(n, p) for n, p in CASES if n in only}}}
+    with open(FIXTURE, "w") as f:
         json.dump(out, f, indent=1)
     print(json.dumps(out, indent=1))

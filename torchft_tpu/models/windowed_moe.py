@@ -1,0 +1,393 @@
+"""A decoder whose attention layers are of two kinds from a published list,
+a sliding window or every earlier position, over routed experts with a shared
+one; for training on one chip's share.
+
+The published configuration this was built for is Trinity-Mini's
+(``model_type`` ``afmoe``): ``layer_types`` names each layer's attention,
+``"sliding_attention"`` (a window of ``sliding_window`` positions, the
+query's own counted) or ``"full_attention"``, three of the first to one of
+the second.  The stream is ``E[token] * sqrt(dim)`` (``mup_enabled``), then a
+layer is
+
+- the mixer: ``a = RMSNorm(h)``; ``q, k, v, g = a Wq, a Wk, a Wv, a Wg``;
+  ``q`` and ``k`` through an RMSNorm over a head's channels (one weight, the
+  heads share it); on a WINDOWED layer rope over all of a head's channels,
+  the halves paired, on a FULL layer no position encoding at all; grouped-
+  query attention; ``h += RMSNorm((o * sigmoid(g)) Wo)``: the gate is a
+  channel's own, the second norm is on the branch, before the add;
+- the feed-forward part: ``m = RMSNorm(h)``; below ``num_dense_layers`` a
+  SwiGLU of ``dense_hidden``, else ``parallel/moe.py`` ``RoutedExperts``, told
+  which experts are here (sigmoid scores in float32, a selection bias, the
+  ``top_k`` best of one group, the chosen scores normalised and scaled by
+  ``route_scale``, one shared expert); ``h += RMSNorm(y)``, the fourth norm.
+
+A final norm, then the head; embedding and head are not tied.
+
+What is the model's and what a kernel's: projections, norms, rope and the
+gate are here, plain ``jax.numpy``; the attention is
+``ops/flash_attention.py``'s, whose ``window`` makes the kernels WALK only the
+key blocks a row block's window touches (``flash_win_fwd``, ``flash_win_dq``,
+``flash_win_dkv``; the full layers' are ``flash_fwd``, ``flash_dq``,
+``flash_dkv``), the experts' grouped products ``megablox.gmm``.
+``attention_path`` is ``"flash_win+flash"`` only if every layer took the flash
+kernels and every expert layer the grouped kernel; off the TPU the window is
+a mask over plain attention beside ``lax.ragged_dot`` and the path is named
+``"plain: <why>"``.
+
+Contiguous layers of one kind (attention kind, feed-forward kind) are stacked
+and run under one ``lax.scan``, as ``SsmHybridMoE``'s are.  A layer is
+rematerialised in the backward pass but for its float32 input and, on a FULL
+layer, what flash made (``ops/flash_attention.py``, ``KEPT_NAMES``: 134 + 17
+MB a layer at 16,384 positions and 32 heads of 128), so that ``flash_fwd``
+stands once a full layer in a step's program.  A windowed layer keeps nothing
+of the kind and ``flash_win_fwd`` runs twice: the walk makes it cheap, and
+kept on all of the published cut's eight layers the step does not fit a chip.
+
+The residual stream is float32 whatever the matrices' dtype, and the router
+reads its float32 norm: which 8 of 128 experts a token takes is a step
+function of what the router reads.
+
+**State the optimizer does not own**: every router's selection bias, moved
+after a committed step as ``LingHybrid``'s is (``state_mask``, ``objective``,
+``advance_state``; ``HSDPTrainer``).  There is no auxiliary loss: ``objective``
+IS ``loss``, with the step's signal and summary beside it.
+"""
+
+from __future__ import annotations
+
+import functools
+import itertools
+import logging
+from dataclasses import dataclass, replace
+from typing import Any, Dict, List, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.sharding import PartitionSpec as P
+
+from torchft_tpu.models.ling_hybrid import LingHybrid
+from torchft_tpu.models.llama import Llama, _proj
+from torchft_tpu.obs.spans import part
+from torchft_tpu.ops import flash_attention as flash
+from torchft_tpu.parallel.moe import RoutedExperts, RoutedExpertsConfig, swiglu
+
+logger = logging.getLogger(__name__)
+
+KERNEL_PATH = "flash_win+flash"
+# what the two norms ON a branch (after the mixer, after the feed-forward
+# part) start at: such a weight is a scale a channel on a residual branch, and
+# 0.1 is what LayerScale starts one at in networks of up to 18 layers
+# (arXiv:2103.17239).  At 1, with seeded weights, the norm blows the mixer's
+# small, slowly varying output (near-uniform attention averages some hundred
+# zero-mean values) up to the stream's own size, every router downstream
+# reads mostly that, and which experts a stretch of the sequence takes turns
+# on the seed: the busiest held expert then has 4-6 times the mean load where
+# it has 1.3-2.2 at 0.1 (PERF.md section 6, PR 41)
+BRANCH_NORM_INIT = 0.1
+ATTENTION_KINDS = ("sliding_attention", "full_attention")
+
+
+@dataclass(frozen=True)
+class WindowedMoEConfig:
+    vocab_size: int = 200_192
+    dim: int = 2048
+    layer_types: Tuple[str, ...] = (*ATTENTION_KINDS[:1] * 3, ATTENTION_KINDS[1]) * 8  # a layer each
+    sliding_window: int = 2048
+    n_heads: int = 32
+    n_kv_heads: int = 4
+    head_dim: int = 128
+    rope_theta: float = 10_000.0
+    num_dense_layers: int = 2
+    dense_hidden: int = 6144
+    num_experts: int = 128
+    experts_held: Tuple[int, int] = (0, 128)  # (first, count): this chip's share
+    top_k: int = 8
+    expert_hidden: int = 1024
+    shared_hidden: int = 1024
+    route_scale: float = 2.826
+    route_norm: bool = True
+    bias_update_rate: float = 1e-3
+    embed_scale: bool = True  # ``mup_enabled``: the embedding times sqrt(dim)
+    norm_eps: float = 1e-5
+    dtype: Any = jnp.bfloat16
+
+    def kinds(self) -> List[Tuple[str, str]]:
+        """(attention kind, ``"dense"`` or ``"moe"``) a layer."""
+        unknown = set(self.layer_types) - set(ATTENTION_KINDS)
+        if unknown or not self.layer_types:
+            raise ValueError(f"layer_types: a layer is one of {ATTENTION_KINDS}, not {sorted(unknown)}")
+        return [
+            (kind, "dense" if i < self.num_dense_layers else "moe")
+            for i, kind in enumerate(self.layer_types)
+        ]
+
+    def groups(self) -> List[Tuple[Tuple[str, str], int]]:
+        """Runs of contiguous layers of one kind: (kind, how many)."""
+        return [(kind, len(list(run))) for kind, run in itertools.groupby(self.kinds())]
+
+    @property
+    def n_layers(self) -> int:
+        return len(self.layer_types)
+
+
+def windowed_moe_debug(**over: Any) -> WindowedMoEConfig:
+    """Tiny widths on the published list's first eight layers (two whole
+    periods, one leading dense layer), the window shorter than the tests'
+    sequences."""
+    return replace(
+        WindowedMoEConfig(
+            vocab_size=512, dim=64, layer_types=WindowedMoEConfig.layer_types[:8], sliding_window=24,
+            n_heads=4, n_kv_heads=2, head_dim=16, num_dense_layers=1, dense_hidden=128, num_experts=16,
+            experts_held=(4, 4), top_k=4, expert_hidden=32, shared_hidden=32, dtype=jnp.float32,
+        ),
+        **over,
+    )
+
+
+def _rope_halves(x: jax.Array, theta: float) -> jax.Array:
+    """Rotary embedding over ALL of the last axis, channel ``i`` paired with
+    ``i + R / 2``; x [B, S, H, R], position = index, float32 arithmetic."""
+    S, half = x.shape[1], x.shape[-1] // 2
+    freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
+    angles = jnp.arange(S, dtype=jnp.float32)[None, :, None] * freqs  # [1, S, R / 2]
+    return Llama._apply_rope(x, jnp.cos(angles), jnp.sin(angles))
+
+
+class WindowedMoE:
+    def __init__(self, config: WindowedMoEConfig, mesh: Optional[Any] = None) -> None:
+        self.config = config
+        self.mesh = mesh
+        cfg = config
+        self.groups = cfg.groups()
+        if cfg.n_heads % cfg.n_kv_heads or cfg.head_dim % 2 or cfg.sliding_window < 1:
+            raise ValueError("query heads divide into KV heads, rope pairs a head's halves, a window holds the query")
+        self.moe = RoutedExperts(
+            RoutedExpertsConfig(
+                dim=cfg.dim, expert_hidden=cfg.expert_hidden, num_experts=cfg.num_experts,
+                experts_held=tuple(cfg.experts_held), top_k=cfg.top_k,
+                routed_scaling_factor=cfg.route_scale, norm_topk_prob=cfg.route_norm,
+                shared_hidden=cfg.shared_hidden, dtype=cfg.dtype,
+            )
+        )
+        # set when the layers are traced: KERNEL_PATH or "plain: <why>"
+        self.attention_path: Optional[str] = None
+
+    # ------------------------------------------------------------------
+    # params
+    # ------------------------------------------------------------------
+
+    def _init_layer(self, kind: Tuple[str, str], key: jax.Array) -> Dict[str, Any]:
+        cfg = self.config
+        D, hd = cfg.dim, cfg.head_dim
+        q, kv = cfg.n_heads * hd, cfg.n_kv_heads * hd
+        keys = jax.random.split(key, 9)
+
+        def normal(k, shape, fan_in):
+            return (jax.random.normal(k, shape, jnp.float32) / np.sqrt(fan_in)).astype(cfg.dtype)
+
+        if kind[1] == "dense":
+            F = cfg.dense_hidden
+            ffn = {
+                "w_gate": normal(keys[5], (D, F), D), "w_up": normal(keys[6], (D, F), D),
+                "w_down": normal(keys[7], (F, D), F),
+            }
+        else:
+            ffn = self.moe.init(keys[5])
+        ones = lambda n: jnp.ones((n,), jnp.float32)  # noqa: E731
+        return {
+            "norms": {
+                "mixer_in": ones(D), "mixer_out": BRANCH_NORM_INIT * ones(D),
+                "ffn_in": ones(D), "ffn_out": BRANCH_NORM_INIT * ones(D),
+            },
+            "wq": normal(keys[0], (D, q), D), "wk": normal(keys[1], (D, kv), D),
+            "wv": normal(keys[2], (D, kv), D), "wg": normal(keys[3], (D, q), D),
+            "wo": normal(keys[4], (q, D), q),
+            "q_norm": ones(hd), "k_norm": ones(hd),
+            "ffn": ffn,
+        }
+
+    def init(self, key: jax.Array) -> Dict[str, Any]:
+        cfg = self.config
+        k_embed, k_out, k_layers = jax.random.split(key, 3)
+
+        def normal(k, shape, std):
+            return (std * jax.random.normal(k, shape, jnp.float32)).astype(cfg.dtype)
+
+        return {
+            # rows of variance 1 / dim, so that the stream, the rows times
+            # sqrt(dim), starts at unit variance
+            "embed": normal(k_embed, (cfg.vocab_size, cfg.dim), cfg.dim ** -0.5 if cfg.embed_scale else 1.0),
+            "groups": [
+                jax.vmap(functools.partial(self._init_layer, kind))(
+                    jax.random.split(jax.random.fold_in(k_layers, n), depth)
+                )
+                for n, (kind, depth) in enumerate(self.groups)
+            ],
+            "final_norm": jnp.ones((cfg.dim,), jnp.float32),
+            "lm_head": normal(k_out, (cfg.dim, cfg.vocab_size), cfg.dim ** -0.5),
+        }
+
+    @functools.cached_property
+    def _shapes(self) -> Any:
+        """What ``init`` would make, as shapes (traced once a model)."""
+        return jax.eval_shape(self.init, jax.random.PRNGKey(0))
+
+    def param_specs(self) -> Dict[str, Any]:
+        """One chip's share of a larger job: every leaf whole on the group's
+        one chip (the ``fsdp`` axis of this model's meshes has size 1)."""
+        return jax.tree_util.tree_map(lambda s: P(*([None] * len(s.shape))), self._shapes)
+
+    def batch_specs(self) -> Tuple[Any, Any]:
+        spec = P(("dp", "fsdp"), None)
+        return spec, spec
+
+    def num_params(self) -> int:
+        return sum(int(np.prod(s.shape)) for s in jax.tree_util.tree_leaves(self._shapes))
+
+    # the routers' selection biases: state the optimizer does not own, as
+    # ``LingHybrid``'s (a leaf called "bias"; a signal a leaf, its last axis
+    # the router's width)
+    state_mask = LingHybrid.state_mask
+    advance_state = LingHybrid.advance_state
+    route_summary = LingHybrid.route_summary
+    summary_stats = staticmethod(LingHybrid.summary_stats)
+
+    # ------------------------------------------------------------------
+    # forward
+    # ------------------------------------------------------------------
+
+    def _kernel_refusal(self, seq: int) -> Optional[str]:
+        """Why the Mosaic kernels do NOT apply, or None when they do."""
+        block_q, block_k = Llama._flash_blocks(seq)
+        shape_refusal = None
+        if seq < 32 or seq % 8 or seq % block_q or seq % block_k:
+            shape_refusal = f"seq={seq} does not divide into the blocks ({block_q}, {block_k})"
+        return Llama._one_chip_refusal(shape_refusal, self.mesh)
+
+    @part("mixer_glue")
+    def _attention(self, h: jax.Array, w: Dict[str, jax.Array], windowed: bool, kernels: bool) -> jax.Array:
+        cfg = self.config
+        B, S, _ = h.shape
+        H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+        q = Llama._rms_norm(_proj(h, w["wq"]).reshape(B, S, H, hd), w["q_norm"], cfg.norm_eps)
+        k = Llama._rms_norm(_proj(h, w["wk"]).reshape(B, S, KV, hd), w["k_norm"], cfg.norm_eps)
+        v = _proj(h, w["wv"]).reshape(B, S, KV, hd)
+        gate = _proj(h, w["wg"])
+        window = None
+        if windowed:  # position is the windowed layers' alone: a full layer has none
+            q, k = _rope_halves(q, cfg.rope_theta), _rope_halves(k, cfg.rope_theta)
+            window = cfg.sliding_window
+        if kernels:
+            block_q, block_k = Llama._flash_blocks(S)
+            o = flash.flash_attention(
+                q, k, v, causal=True, block_q=block_q, block_k=block_k, window=window,
+                interpret=Llama._assumed_backend() != "tpu",
+            )
+        else:
+            grouped = q.reshape(B, S, KV, H // KV, hd)
+            scores = jnp.einsum("bqgrd,bkgd->bgrqk", grouped, k).astype(jnp.float32) / np.sqrt(hd)
+            i, j = jnp.arange(S)[:, None], jnp.arange(S)[None, :]
+            seen = (j <= i) if window is None else (j <= i) & (j > i - window)
+            scores = jnp.where(seen, scores, -1e30)
+            o = jnp.einsum("bgrqk,bkgd->bqgrd", jax.nn.softmax(scores, axis=-1).astype(q.dtype), v)
+        o = o.reshape(B, S, H * hd).astype(jnp.float32) * jax.nn.sigmoid(gate.astype(jnp.float32))
+        return _proj(o.astype(h.dtype), w["wo"])
+
+    def _block(
+        self, x: jax.Array, w: Dict[str, Any], kind: Tuple[str, str], kernels: bool
+    ) -> Tuple[jax.Array, jax.Array]:
+        """One layer: ``(x, load [E] (zeros for a dense layer))``."""
+        cfg = self.config
+        norm = lambda a, name: Llama._rms_norm(a.astype(jnp.float32), w["norms"][name], cfg.norm_eps)  # noqa: E731
+        with part("stream"):
+            h = norm(x, "mixer_in").astype(cfg.dtype)
+        mixed = self._attention(h, w, kind[0] == "sliding_attention", kernels)
+        with part("stream"):
+            x = x + norm(mixed, "mixer_out")
+            h = norm(x, "ffn_in")
+        if kind[1] == "dense":
+            f = w["ffn"]
+            with part("stream"):
+                h = h.astype(cfg.dtype)
+            with part("ffn"):
+                out = swiglu(h @ f["w_gate"], h @ f["w_up"], 0.0) @ f["w_down"]
+            load = jnp.zeros((cfg.num_experts,), jnp.float32)
+        else:
+            # the router reads the float32 norm itself
+            out, load, _ = self.moe.apply(w["ffn"], h)
+        with part("stream"):
+            return x + norm(out, "ffn_out"), load
+
+    def _trunk(self, params: Dict[str, Any], tokens: jax.Array) -> Tuple[jax.Array, List[jax.Array]]:
+        """tokens [B, S] → (the residual stream after the last layer, the
+        loads [depth, E] of every stacked run of expert layers in the
+        layers' order)."""
+        cfg = self.config
+        refusal = self._kernel_refusal(tokens.shape[1])
+        kernels = refusal is None
+        with part("embed"):
+            x = params["embed"][tokens].astype(jnp.float32)  # the residual stream
+            if cfg.embed_scale:
+                x = x * np.float32(np.sqrt(cfg.dim))
+        loads = []
+        for (kind, depth), stacked in zip(self.groups, params["groups"]):
+
+            def body(carry, w, kind=kind):
+                return self._block(carry, w, kind, kernels)
+
+            # kept through a layer's rematerialisation: its float32 input and,
+            # on a FULL layer, flash's output and row statistics (151 MB at
+            # 16,384 positions), so that the dear ``flash_fwd`` stands once in a
+            # step.  A WINDOWED layer keeps nothing of the kind and runs its
+            # forward kernel again, which the walk makes cheap: kept on all
+            # eight layers the gradient step needs 3.4 GB more than kept on
+            # none, by the compiler's count, and does not fit beside 8.9 GB
+            # of weights, gradients and moments (PERF.md section 6, PR 41)
+            keep = flash.KEPT_NAMES if kind[0] == "full_attention" else ()
+            policy = jax.checkpoint_policies.save_only_these_names(*keep)
+            # jax's guard against XLA merging the rematerialised forward with
+            # the first one stays on where a scan of ONE layer is no loop once
+            # XLA has simplified it (``models/ssm_hybrid_moe.py``); inside a
+            # real loop it is not needed and costs memory
+            with part("layers"):
+                x, load = jax.lax.scan(jax.checkpoint(body, policy=policy, prevent_cse=depth == 1), x, stacked)
+            if kind[1] == "moe":
+                loads.append(load)
+        if kernels and self.moe.path not in (None, "gmm") and Llama._assumed_backend() == "tpu":
+            refusal, kernels = f"the experts took {self.moe.path}", False
+        path = KERNEL_PATH if kernels else f"plain: {refusal}"
+        if path != self.attention_path:
+            logger.info("attention path: %s", path)
+        self.attention_path = path
+        return x, loads
+
+    @part("head")
+    def _logits(self, params: Dict[str, Any], x: jax.Array) -> jax.Array:
+        x = Llama._rms_norm(x, params["final_norm"], self.config.norm_eps).astype(self.config.dtype)
+        # the products' float32 sums as they are: a logit is never rounded to the model's dtype
+        return jnp.dot(x, params["lm_head"], preferred_element_type=jnp.float32)
+
+    def apply(self, params: Dict[str, Any], tokens: jax.Array) -> jax.Array:
+        """tokens [B, S] → logits [B, S, vocab] (fp32)."""
+        return self._logits(params, self._trunk(params, tokens)[0])
+
+    def loss(self, params: Dict[str, Any], batch: Tuple[jax.Array, jax.Array]) -> jax.Array:
+        """Mean next-token cross-entropy; batch = (tokens, targets)."""
+        return self.objective(params, batch)[0]
+
+    def objective(
+        self, params: Dict[str, Any], batch: Tuple[jax.Array, jax.Array]
+    ) -> Tuple[jax.Array, Tuple[List[jax.Array], jax.Array]]:
+        """What a training step differentiates (``loss``: the published
+        configuration balances by the bias alone), for every leaf of
+        ``state_mask`` the step's signal (the tokens each expert was chosen
+        by) and the step's summary (``route_summary`` of this replica's own
+        signal)."""
+        tokens, targets = batch
+        x, signal = self._trunk(params, tokens)
+        loss = LingHybrid._mean_nll(self._logits(params, x), targets)
+        with part("head"):
+            # a model of dense layers alone has no router to sum up
+            summary = self.route_summary(signal) if signal else jnp.zeros((0, 3), jnp.float32)
+            return loss, (signal, summary)

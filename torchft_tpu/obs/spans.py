@@ -34,6 +34,9 @@ its ``pallas_call``'s ``name=`` and is ``%<name>.N`` among a trace's device
 operations; these are the names the program gives out::
 
     flash_fwd, flash_dq, flash_dkv     (ops/flash_attention.py)
+    flash_win_fwd, flash_win_dq, flash_win_dkv
+                                       (the same with a window: the grid
+                                        holds the window's blocks alone)
     kda_fwd, kda_bwd                   (ops/kda.py: the chunked delta rule)
     ...gmm..., ...tgmm...              (parallel/moe.py RoutedExperts: jax's
                                         megablox kernels, named after the

@@ -17,8 +17,22 @@ costs ``groups``× K/V bandwidth).
 Backward is the standard two-kernel flash scheme over the saved
 logsumexp: ``dq`` accumulates over k-blocks; ``dk``/``dv`` accumulate over
 (q-head-in-group × q-block) so each kv-head's gradient sums its whole GQA
-group without materializing per-q-head copies.  Causally-dead blocks are
-skipped with ``pl.when`` in both directions.
+group without materializing per-q-head copies.  Without a window the grid
+walks every (row block, key block) pair and the causally-dead ones are
+skipped with ``pl.when`` in both directions: their blocks are still fetched.
+
+With a ``window`` (a sliding window: query ``i`` sees keys ``j`` with ``i -
+window < j <= i``, its own position counted) the grid does not hold the dead
+pairs at all.  Forward and ``dq`` walk, for a row block, only the key blocks
+its rows' windows touch (``_key_steps`` of them at most, ending at the
+diagonal block: the index map is offset by the row block), ``dkv`` walks for
+a key block only the row blocks that can see it (the mirror, starting at the
+diagonal), and the two edge blocks are masked inside.  At 16,384 positions,
+a window of 2,048 and blocks of 512 that is 5 key blocks a row block against
+16.5 on average.  These programs are named ``flash_win_fwd``,
+``flash_win_dq`` and ``flash_win_dkv``, so that a trace tells a windowed
+layer's kernels from a full layer's; a window that covers the sequence IS
+causal attention and takes the full layers' programs.
 """
 
 from __future__ import annotations
@@ -42,6 +56,61 @@ _ROW_LANES = 8
 
 
 # ---------------------------------------------------------------------------
+# which pairs are alive
+# ---------------------------------------------------------------------------
+
+
+def _masked(s, qi, ki, block_q, block_k, window):
+    """Scores [bq, bk] of row block ``qi`` against key block ``ki`` with the
+    dead pairs (a later key; with a window, one ``window`` or more back) at
+    ``_NEG_INF``."""
+    rows = qi * block_q + jax.lax.broadcasted_iota(
+        jnp.int32, (block_q, block_k), 0
+    )
+    cols = ki * block_k + jax.lax.broadcasted_iota(
+        jnp.int32, (block_q, block_k), 1
+    )
+    keep = rows >= cols
+    if window is not None:
+        keep = keep & (cols > rows - window)
+    return jnp.where(keep, s, _NEG_INF)
+
+
+def _key_steps(nq, nk, block_q, block_k, window):
+    """The most key blocks one row block's live pairs touch under a window:
+    from its first row's oldest key to the diagonal block."""
+    most = max(
+        (i * block_q + block_q - 1) // block_k - (i * block_q - window + 1) // block_k + 1
+        for i in range(nq)
+    )
+    return min(most, nk)
+
+
+def _row_steps(nq, nk, block_q, block_k, window):
+    """The mirror: the most row blocks that see one key block, from the
+    diagonal block to its last key's last reader."""
+    most = max(
+        (i * block_k + block_k + window - 2) // block_q - (i * block_k) // block_q + 1
+        for i in range(nk)
+    )
+    return min(most, nq)
+
+
+def _walked_k(qi, step, block_q, block_k, steps):
+    """The key block of row block ``qi``'s ``step``-th visit: the walk ENDS
+    at the diagonal block, so an early row block's first visits fall before
+    the sequence (negative: dead, and the index map holds them at 0)."""
+    return jax.lax.div(qi * block_q + block_q - 1, block_k) - (steps - 1) + step
+
+
+def _walked_q(ki, step, block_q, block_k):
+    """The row block of key block ``ki``'s ``step``-th visit: the walk STARTS
+    at the diagonal block, so a late key block's last visits fall past the
+    sequence (dead, and the index map holds them at the last block)."""
+    return jax.lax.div(ki * block_k, block_q) + step
+
+
+# ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
 
@@ -60,19 +129,27 @@ def _fwd_kernel(
     causal: bool,
     block_q: int,
     block_k: int,
-    num_k_blocks: int,
+    num_k_blocks: int,  # the grid's steps a row block: with a window, its walk
+    window: Optional[int] = None,
 ):
     qi = pl.program_id(2)
-    ki = pl.program_id(3)
+    step = pl.program_id(3)
 
-    @pl.when(ki == 0)
+    @pl.when(step == 0)
     def _init():
         m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    # with causality, k-blocks wholly above the diagonal are dead
-    live = (not causal) or (ki * block_k <= qi * block_q + block_q - 1)
+    if window is None:
+        ki = step
+        # with causality, k-blocks wholly above the diagonal are dead
+        live = (not causal) or (ki * block_k <= qi * block_q + block_q - 1)
+    else:
+        ki = _walked_k(qi, step, block_q, block_k, num_k_blocks)
+        # the walk ends at the diagonal; a block is dead before the sequence
+        # or where its last key is older than the first row's window
+        live = (ki >= 0) & ((ki + 1) * block_k + window - 2 >= qi * block_q)
 
     @pl.when(live)
     def _accumulate():
@@ -88,13 +165,7 @@ def _fwd_kernel(
             * sm_scale
         )  # [bq, bk] f32
         if causal:
-            rows = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0
-            )
-            cols = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1
-            )
-            s = jnp.where(rows >= cols, s, _NEG_INF)
+            s = _masked(s, qi, ki, block_q, block_k, window)
 
         m_prev = m_scr[:, :1]  # [bq, 1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -108,7 +179,7 @@ def _fwd_kernel(
         m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
         l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
 
-    @pl.when(ki == num_k_blocks - 1)
+    @pl.when(step == num_k_blocks - 1)
     def _finalize():
         m = m_scr[:, :1]
         l = l_scr[:, :1]
@@ -128,6 +199,7 @@ def _fwd(
     block_q: int,
     block_k: int,
     interpret: bool,
+    window: Optional[int] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """q [B,H,Sq,D], k [B,KV,Sk,D], v [B,KV,Sk,Dv] → (o [B,H,Sq,Dv],
     lse [B,H,Sq]).  Rectangular (Sq != Sk) is allowed when not causal; v's
@@ -138,25 +210,23 @@ def _fwd(
     Sk = k.shape[2]
     groups = H // KV
     nq, nk = S // block_q, Sk // block_k
+    kv_map, steps, names = _walk_of_keys(groups, nq, nk, block_q, block_k, window)
     kernel = functools.partial(
         _fwd_kernel,
         sm_scale=sm_scale,
         causal=causal,
         block_q=block_q,
         block_k=block_k,
-        num_k_blocks=nk,
+        num_k_blocks=steps,
+        window=window,
     )
     return pl.pallas_call(
         kernel,
-        grid=(B, H, nq, nk),
+        grid=(B, H, nq, steps),
         in_specs=[
             pl.BlockSpec((1, 1, block_q, D), lambda b, h, qi, ki: (b, h, qi, 0)),
-            pl.BlockSpec(
-                (1, 1, block_k, D), lambda b, h, qi, ki: (b, h // groups, ki, 0)
-            ),
-            pl.BlockSpec(
-                (1, 1, block_k, Dv), lambda b, h, qi, ki: (b, h // groups, ki, 0)
-            ),
+            pl.BlockSpec((1, 1, block_k, D), kv_map),
+            pl.BlockSpec((1, 1, block_k, Dv), kv_map),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, block_q, Dv), lambda b, h, qi, ki: (b, h, qi, 0)),
@@ -175,8 +245,26 @@ def _fwd(
             pltpu.VMEM((block_q, Dv), jnp.float32),
         ],
         interpret=interpret,
-        name="flash_fwd",
+        name=names[0],
     )(q, k, v)
+
+
+def _walk_of_keys(groups, nq, nk, block_q, block_k, window):
+    """For forward and ``dq``: (the key blocks' index map over the grid ``(b,
+    h, row block, step)``, the steps a row block, the programs' names)."""
+    if window is None:
+        return (
+            lambda b, h, qi, ki: (b, h // groups, ki, 0),
+            nk,
+            ("flash_fwd", "flash_dq"),
+        )
+    steps = _key_steps(nq, nk, block_q, block_k, window)
+
+    def kv_map(b, h, qi, step):
+        ki = _walked_k(qi, step, block_q, block_k, steps)
+        return (b, h // groups, jnp.maximum(ki, 0), 0)
+
+    return kv_map, steps, ("flash_win_fwd", "flash_win_dq")
 
 
 # ---------------------------------------------------------------------------
@@ -185,7 +273,8 @@ def _fwd(
 
 
 def _recompute_p_ds(
-    q, k, lse, do, v, delta, sm_scale, causal, qi, ki, block_q, block_k
+    q, k, lse, do, v, delta, sm_scale, causal, qi, ki, block_q, block_k,
+    window=None,
 ):
     """Shared backward math for one (q-block, k-block) pair: the normalized
     probabilities ``p`` and score-gradient ``ds`` (both [bq, bk], f32).
@@ -198,13 +287,7 @@ def _recompute_p_ds(
         * sm_scale
     )
     if causal:
-        rows = qi * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0
-        )
-        cols = ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1
-        )
-        s = jnp.where(rows >= cols, s, _NEG_INF)
+        s = _masked(s, qi, ki, block_q, block_k, window)
     p = jnp.exp(s - lse)  # normalized probabilities
     dp = jax.lax.dot_general(
         do, v, (((1,), (1,)), ((), ())),
@@ -216,30 +299,35 @@ def _recompute_p_ds(
 
 def _dq_kernel(
     q_ref, k_ref, v_ref, lse_ref, do_ref, delta_ref, dq_ref, dq_scr,
-    *, sm_scale, causal, block_q, block_k, num_k_blocks,
+    *, sm_scale, causal, block_q, block_k, num_k_blocks, window=None,
 ):
     qi = pl.program_id(2)
-    ki = pl.program_id(3)
+    step = pl.program_id(3)
 
-    @pl.when(ki == 0)
+    @pl.when(step == 0)
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    live = (not causal) or (ki * block_k <= qi * block_q + block_q - 1)
+    if window is None:
+        ki = step
+        live = (not causal) or (ki * block_k <= qi * block_q + block_q - 1)
+    else:  # the forward's walk
+        ki = _walked_k(qi, step, block_q, block_k, num_k_blocks)
+        live = (ki >= 0) & ((ki + 1) * block_k + window - 2 >= qi * block_q)
 
     @pl.when(live)
     def _accumulate():
         _, ds = _recompute_p_ds(
             q_ref[0, 0], k_ref[0, 0], lse_ref[0, 0][:, :1], do_ref[0, 0],
             v_ref[0, 0], delta_ref[0, 0][:, :1], sm_scale, causal, qi, ki,
-            block_q, block_k,
+            block_q, block_k, window,
         )
         dq_scr[...] += jax.lax.dot_general(
             ds.astype(k_ref.dtype), k_ref[0, 0], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
 
-    @pl.when(ki == num_k_blocks - 1)
+    @pl.when(step == num_k_blocks - 1)
     def _finalize():
         dq_ref[0, 0] = dq_scr[...].astype(dq_ref.dtype)
 
@@ -248,24 +336,34 @@ def _dkv_kernel(
     q_ref, k_ref, v_ref, lse_ref, do_ref, delta_ref, dk_ref, dv_ref,
     dk_scr, dv_scr,
     *, sm_scale, causal, block_q, block_k, num_q_blocks, inner_steps,
+    window=None, walk=None,
 ):
+    # ``walk``: with a window, the row blocks a group member visits
     ki = pl.program_id(2)
     inner = pl.program_id(3)  # flattened (g, qi): sums the whole GQA group
-    qi = inner % num_q_blocks
+    if window is None:
+        qi = inner % num_q_blocks
+    else:
+        qi = _walked_q(ki, inner % walk, block_q, block_k)
 
     @pl.when(inner == 0)
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    live = (not causal) or (qi * block_q + block_q - 1 >= ki * block_k)
+    if window is None:
+        live = (not causal) or (qi * block_q + block_q - 1 >= ki * block_k)
+    else:
+        # the walk starts at the diagonal; a block is dead past the sequence
+        # or where its first row is past the last key's window
+        live = (qi < num_q_blocks) & (qi * block_q <= (ki + 1) * block_k + window - 2)
 
     @pl.when(live)
     def _accumulate():
         p, ds = _recompute_p_ds(
             q_ref[0, 0], k_ref[0, 0], lse_ref[0, 0][:, :1], do_ref[0, 0],
             v_ref[0, 0], delta_ref[0, 0][:, :1], sm_scale, causal, qi, ki,
-            block_q, block_k,
+            block_q, block_k, window,
         )
         do = do_ref[0, 0]
         dv_scr[...] += jax.lax.dot_general(
@@ -284,7 +382,8 @@ def _dkv_kernel(
 
 
 def _bwd(
-    sm_scale, causal, block_q, block_k, interpret, residuals, do, dlse=None
+    sm_scale, causal, block_q, block_k, interpret, residuals, do, dlse=None,
+    window=None,
 ):
     """``dlse`` (optional, [B, H, S]): cotangent of the logsumexp output.
     Since ∂lse_i/∂s_ij = p_ij, it folds into the existing delta term:
@@ -307,14 +406,14 @@ def _bwd(
     delta = jnp.broadcast_to(delta_rows, (B, H, S, _ROW_LANES))
 
     q_map = lambda b, h, qi, ki: (b, h, qi, 0)
-    kv_map = lambda b, h, qi, ki: (b, h // groups, ki, 0)
+    kv_map, steps, names = _walk_of_keys(groups, nq, nk, block_q, block_k, window)
     row_map = lambda b, h, qi, ki: (b, h, qi, 0)
     dq = pl.pallas_call(
         functools.partial(
             _dq_kernel, sm_scale=sm_scale, causal=causal,
-            block_q=block_q, block_k=block_k, num_k_blocks=nk,
+            block_q=block_q, block_k=block_k, num_k_blocks=steps, window=window,
         ),
-        grid=(B, H, nq, nk),
+        grid=(B, H, nq, steps),
         in_specs=[
             pl.BlockSpec((1, 1, block_q, D), q_map),
             pl.BlockSpec((1, 1, block_k, D), kv_map),
@@ -327,20 +426,31 @@ def _bwd(
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         interpret=interpret,
-        name="flash_dq",
+        name=names[1],
     )(q, k, v, lse, do, delta)
 
     # dk/dv: grid inner dim flattens (group member, q block) so the scratch
     # accumulator sums the whole GQA group for this kv head
-    inner = groups * nq
-    g_q_map = lambda b, kv, ki, i: (b, kv * groups + i // nq, i % nq, 0)
-    g_row_map = lambda b, kv, ki, i: (b, kv * groups + i // nq, i % nq, 0)
+    if window is None:
+        walk = nq
+        g_q_map = lambda b, kv, ki, i: (b, kv * groups + i // nq, i % nq, 0)
+        g_row_map = lambda b, kv, ki, i: (b, kv * groups + i // nq, i % nq, 0)
+    else:
+        # the mirror of the forward's walk: the row blocks that see this key block
+        walk = _row_steps(nq, nk, block_q, block_k, window)
+
+        def g_q_map(b, kv, ki, i):
+            qi = _walked_q(ki, i % walk, block_q, block_k)
+            return (b, kv * groups + i // walk, jnp.minimum(qi, nq - 1), 0)
+
+        g_row_map = g_q_map
+    inner = groups * walk
     g_kv_map = lambda b, kv, ki, i: (b, kv, ki, 0)
     dk, dv = pl.pallas_call(
         functools.partial(
             _dkv_kernel, sm_scale=sm_scale, causal=causal,
             block_q=block_q, block_k=block_k, num_q_blocks=nq,
-            inner_steps=inner,
+            inner_steps=inner, window=window, walk=walk,
         ),
         grid=(B, KV, nk, inner),
         in_specs=[
@@ -364,7 +474,7 @@ def _bwd(
             pltpu.VMEM((block_k, Dv), jnp.float32),
         ],
         interpret=interpret,
-        name="flash_dkv",
+        name="flash_dkv" if window is None else "flash_win_dkv",
     )(q, k, v, lse, do, delta)
     return dq, dk, dv
 
@@ -374,10 +484,12 @@ def _bwd(
 # ---------------------------------------------------------------------------
 
 
-def _validate(q, k, v, causal, sm_scale, block_q, block_k):
+def _validate(q, k, v, causal, sm_scale, block_q, block_k, window=None):
     """Shared shape/divisibility validation for the public wrappers
     ([B, S, H, D] layout).  Returns the resolved (sm_scale, bq, bk).  q and
-    k share a head size; v may have its own (the output has v's)."""
+    k share a head size; v may have its own (the output has v's).  A
+    ``window`` is a whole number of positions, at least 1 (the query's own),
+    and means something under causality only."""
     B, S, H, D = q.shape
     KV = k.shape[2]
     Sk = k.shape[1]
@@ -390,22 +502,33 @@ def _validate(q, k, v, causal, sm_scale, block_q, block_k):
         )
     if causal and Sk != S:
         raise ValueError(
-            f"causal attention needs Sq == Sk, got Sq={S} Sk={Sk}"
+            f"causal attention (with or without a window) needs Sq == Sk, "
+            f"got Sq={S} Sk={Sk}"
+        )
+    if window is not None and (
+        not causal or isinstance(window, bool)
+        or not isinstance(window, (int, np.integer)) or window < 1
+    ):
+        raise ValueError(
+            f"a window is a static whole number of positions >= 1 (the "
+            f"query's own counts) over causal attention, got "
+            f"window={window!r} causal={causal}"
         )
     block_q = min(block_q, S)
     block_k = min(block_k, Sk)
     if S % block_q or Sk % block_k:
         raise ValueError(
-            f"Sq={S}/Sk={Sk} not divisible by blocks ({block_q},{block_k})"
+            f"Sq={S}/Sk={Sk} not divisible by blocks ({block_q},{block_k}); "
+            f"a window need not be (its edge blocks are masked inside)"
         )
     if sm_scale is None:
         sm_scale = 1.0 / float(np.sqrt(D))
     return float(sm_scale), block_q, block_k
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash_hm(q, k, v, sm_scale, causal, block_q, block_k, interpret):
-    o, _ = _fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash_hm(q, k, v, sm_scale, causal, block_q, block_k, interpret, window):
+    o, _ = _fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret, window)
     return o
 
 
@@ -415,14 +538,14 @@ def _flash_hm(q, k, v, sm_scale, causal, block_q, block_k, interpret):
 KEPT_NAMES = ("flash_o", "flash_lse")
 
 
-def _flash_hm_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret):
-    o, lse = _fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret)
+def _flash_hm_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret, window):
+    o, lse = _fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret, window)
     o, lse = (checkpoint_name(a, n) for a, n in zip((o, lse), KEPT_NAMES))
     return o, (q, k, v, o, lse)
 
 
-def _flash_hm_bwd(sm_scale, causal, block_q, block_k, interpret, res, do):
-    return _bwd(sm_scale, causal, block_q, block_k, interpret, res, do)
+def _flash_hm_bwd(sm_scale, causal, block_q, block_k, interpret, window, res, do):
+    return _bwd(sm_scale, causal, block_q, block_k, interpret, res, do, window=window)
 
 
 _flash_hm.defvjp(_flash_hm_fwd, _flash_hm_bwd)
@@ -496,16 +619,26 @@ def flash_attention(
     block_q: int = 512,
     block_k: int = 512,
     interpret: bool = False,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Fused differentiable attention in the model's native layout.
 
     q: [B, S, H, D]; k/v: [B, S, KV, D] with H % KV == 0 (GQA, un-repeated).
     Returns [B, S, H, D].  S must be divisible by the block sizes (the
     Llama dispatch falls back to the naive path otherwise).
+
+    ``window`` (static): query ``i`` sees keys ``j`` with ``i - window < j
+    <= i``.  ``None``, or a window that covers the sequence, is causal
+    attention over every earlier key (``flash_fwd``, ``flash_dq``,
+    ``flash_dkv``); a shorter one walks only the blocks the window touches
+    (``flash_win_fwd``, ``flash_win_dq``, ``flash_win_dkv``) and need be no
+    multiple of a block.
     """
     sm_scale, block_q, block_k = _validate(
-        q, k, v, causal, sm_scale, block_q, block_k
+        q, k, v, causal, sm_scale, block_q, block_k, window
     )
+    if window is not None and window >= q.shape[1]:
+        window = None
 
     # kernel layout: heads-major so a (bq, D) block is contiguous in S,D
     out = _flash_hm(
@@ -517,6 +650,7 @@ def flash_attention(
         block_q,
         block_k,
         interpret,
+        None if window is None else int(window),
     )
     return out.transpose(0, 2, 1, 3)
 
