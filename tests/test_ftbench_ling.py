@@ -10,6 +10,9 @@ PR that adds a cell may not edit that file, and PR 33's cell joined
 which read the trace and MOE_ROUTE alone.  The version below asks that Ling's
 cell is IN each list and is otherwise that test."""
 
+import pytest
+
+from ftbench.tests import test_ftbench_ling as theirs
 from ftbench.tests.test_ftbench_ling import *  # noqa: F401,F403
 from ftbench.tests.test_ftbench_ling import CELL, NEW_READERS, ROOT, json, os
 
@@ -26,3 +29,13 @@ def test_new_readers_list_this_cell_alone():  # noqa: F811 — replaces the impo
     entry = next(w for w in bench["workloads"] if w["name"] == CELL)
     assert entry["chips"] == 1 and len(entry["why"]) <= 200
     assert entry["config"] in [c["name"] for c in bench["configs"]]
+
+
+# PR 42: the traced walk also reports how full the experts' buffer is
+# (``moe_buffer_fill_pct``, from MOE_ROUTE's ``buffer_rows``)
+@pytest.mark.parametrize(
+    "trace,expects",
+    [(t, e | {"moe_buffer_fill_pct"} if t else e) for t, e in theirs.test_rehearsal_walks_the_cell.pytestmark[0].args[1]],
+)
+def test_rehearsal_walks_the_cell(trace, expects):  # noqa: F811
+    theirs.test_rehearsal_walks_the_cell(trace, expects)

@@ -56,6 +56,9 @@ SWA_READERS = (
     "swa_flash_ms", "swa_flash_roofline", "swa_full_flash_roofline", "swa_window_over_full_pct",
     "swa_moe_gmm_roofline", "swa_step_mfu_pct",
 )
+# PR 42: how full the experts' buffer is, from MOE_ROUTE's buffer_rows (its own
+# tests: tests/test_ftbench_buffer_fill.py); the four expert cells
+FILL_READERS = ("moe_buffer_fill_pct",)
 
 
 @pytest.mark.parametrize("name", sorted(LATER_READINGS))
@@ -71,7 +74,7 @@ def test_new_readers_are_the_eighteen_benchmark_json_lists():  # noqa: F811
         per_layer = json.load(f)["per_layer"]
     appended = (
         LATER_READINGS, LING_READERS, BUCKET_READERS, ORDER_READERS, INDEXED_READERS, SSM_READERS, AHEAD_READERS,
-        SCOPE_READERS, IN_RING_READERS, SWA_READERS,
+        SCOPE_READERS, IN_RING_READERS, SWA_READERS, FILL_READERS,
     )
     later = sum(map(len, appended))
     assert [m["name"] for m in per_layer[-later:]] == [name for group in appended for name in group]
@@ -79,7 +82,7 @@ def test_new_readers_are_the_eighteen_benchmark_json_lists():  # noqa: F811
     assert len(theirs_new) == 18
     assert {m["name"] for m in per_layer[-18 - later:-later]} == theirs_new
     for entry in per_layer[-18 - later:]:
-        cells = 4 if entry["name"] in EXPERT_CELLS else 3 if entry["name"] in FLASH_CELLS else 1
+        cells = 4 if entry["name"] in EXPERT_CELLS + FILL_READERS else 3 if entry["name"] in FLASH_CELLS else 1
         if entry["name"] in SCOPE_READERS:  # the five one-replica cells, or the four that have the part
             cells = 4 if entry["name"] in ("xla_ffn_ms", "moe_route_ms", "moe_dispatch_ms") else 5
         assert len(entry["workloads"]) == cells and set(entry) == {

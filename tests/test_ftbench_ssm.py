@@ -7,13 +7,15 @@ PERF.md section 7)."""
 
 import json
 
+import pytest
+
 from ftbench.tests import test_ftbench_ssm as theirs
 from ftbench.tests.test_ftbench_ssm import *  # noqa: F401,F403
 
 # PR 36 appended its reader after PR 35's six, PR 37 the eleven that share
 # out the compiled step by its named parts (``ftbench/device_scopes.py``),
 # PR 40 the share of collectives the ring averaged itself, PR 41 the six of
-# the cell ``trinitymini-ws1-seq16k``
+# the cell ``trinitymini-ws1-seq16k``, PR 42 how full the experts' buffer is
 LATER_READERS = (
     "heal_serve_ahead_pct",
     "xla_mixer_proj_ms", "xla_mixer_glue_ms", "xla_ffn_ms", "xla_stream_ms", "xla_head_ms", "moe_route_ms",
@@ -21,6 +23,7 @@ LATER_READERS = (
     "normalize_in_ring_pct",
     "swa_flash_ms", "swa_flash_roofline", "swa_full_flash_roofline", "swa_window_over_full_pct",
     "swa_moe_gmm_roofline", "swa_step_mfu_pct",
+    "moe_buffer_fill_pct",
 )
 # PR 41 appended a configuration and a cell after PR 35's, and the cell's name to the lists PR 35's joined
 LATER_CELLS = ("trinitymini-ws1-seq16k",)
@@ -51,3 +54,13 @@ def test_the_cell_and_the_lists_it_joined(monkeypatch):  # noqa: F811
 
     monkeypatch.setattr(theirs.json, "load", without_the_later_ones)
     theirs.test_the_cell_and_the_lists_it_joined()
+
+
+# PR 42: the traced walk also reports how full the experts' buffer is
+# (``moe_buffer_fill_pct``, from MOE_ROUTE's ``buffer_rows``)
+@pytest.mark.parametrize(
+    "trace,expects",
+    [(t, e | {"moe_buffer_fill_pct"} if t else e) for t, e in theirs.test_rehearsal_walks_the_cell.pytestmark[0].args[1]],
+)
+def test_rehearsal_walks_the_cell(trace, expects):  # noqa: F811
+    theirs.test_rehearsal_walks_the_cell(trace, expects)

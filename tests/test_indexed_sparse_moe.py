@@ -91,6 +91,7 @@ def test_forward_and_every_gradient_are_the_reference(model, params, path, kind)
     assert stats["keys_per_query"] == [(16 * 17 / 2 + 48 * 16) / 64] * 2
     first, held = model.config.experts_held
     np.testing.assert_array_equal(stats["rows_here"], np.asarray(want["loads"])[:, first : first + held].sum(axis=1))
+    assert stats["buffer_rows"] == [float(batch[0].size * model.config.top_k)] * 2  # toy: the buffer is every pair, one pass
     got, wanted = _leaves(grads), _leaves(want_grads)
     assert got.keys() == wanted.keys()
     for name in got:

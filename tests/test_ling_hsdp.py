@@ -76,6 +76,7 @@ def test_committed_step_moves_the_bias_by_the_load_and_nothing_else_does():
     # one flight event a committed step, layer by layer
     events = [e for e in manager._flight.snapshot() if e["name"] == "MOE_ROUTE"]
     assert len(events) == 1 and len(events[0]["rows_here"]) == 6  # 4 + 1 + 1 expert layers
+    assert events[0]["buffer_rows"] == [64.0 * 4] * 6  # toy: the buffer is every pair, one pass
     assert events[0]["rows_here"] == [float(x) for load in loads for x in load.reshape(-1, 16)[:, 4:8].sum(axis=1)]
 
 

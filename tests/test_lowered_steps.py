@@ -6,7 +6,11 @@ model's expert form and kept names went in (PR 35: the digests under
 ``tests/fixtures/lowered_steps.json`` were written by THIS file run on the
 parent commit), and ``llama``'s and ``ssm_hybrid_moe``'s to what they lowered
 to before ``flash_attention`` took a window (PR 41: theirs written the same
-way, on PR 41's parent; ``windowed_moe``'s is its own first tree's).  A later change that means to alter one of these programs
+way, on PR 41's parent; ``windowed_moe``'s is its own first tree's).  PR 42
+wrote the four expert models' eight digests anew (``--write --only``: their
+step's summary gained a column, ``buffer_rows``, and the experts' rows go
+through their buffer in a loop of passes); ``llama``'s two are PR 41's
+parent's still.  A later change that means to alter one of these programs
 writes the fixture anew and says so: ``python tests/test_lowered_steps.py
 --write``."""
 

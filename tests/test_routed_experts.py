@@ -11,6 +11,7 @@ products of 64 and 32 terms (read: 1e-6).
 """
 
 import dataclasses
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -18,7 +19,7 @@ import numpy as np
 import pytest
 
 from ftbench.architectures import ling_hybrid_reference as ref
-from torchft_tpu.parallel.moe import RoutedExperts, RoutedExpertsConfig
+from torchft_tpu.parallel.moe import RoutedExperts, RoutedExpertsConfig, buffer_passes, buffer_size
 
 TOL = 5e-6
 E, HELD = 16, 4
@@ -120,9 +121,8 @@ def test_the_shares_add_up_to_the_uncut_layer():
 
 def test_a_load_over_the_usual_buffer_drops_nothing():
     """Every token on the held experts (a router that knows only them):
-    the rows pass the buffer of four times the uniform load, the
-    ``lax.cond`` takes the full-size one, and the result is still the
-    reference's."""
+    the rows pass four times the uniform load, the buffer is filled as often
+    as they need, and the result is still the reference's."""
     layer = _layer(0, 4, num_experts=64, n_group=4, topk_group=1)
     params = layer.init(jax.random.PRNGKey(0))
     params["bias"] = jnp.zeros((64,)).at[:4].set(10.0)  # group 0's first four, always
@@ -133,6 +133,107 @@ def test_a_load_over_the_usual_buffer_drops_nothing():
         want, _, _ = ref.moe_layer(x, params, cfg, (0, 4))
     assert float(load[:4].sum()) == 512 * 4 > 4 * 512 * 4 * 4 / 64  # past the usual buffer
     assert float(jnp.max(jnp.abs(out - want))) < TOL
+
+
+# THE SIZED LAYER: 2,048 tokens, 4 of 32 experts held, 4 a token: a uniform
+# router sends 1,024 rows here, the buffer is 1,536 (1.25 times, rounded up to
+# 512) and every pair, 8,192, needs six passes through it
+SIZE = 1536
+
+
+def _sized_layer():
+    return _layer(0, 4, num_experts=32, n_group=4, topk_group=1)
+
+
+def _sized_case(layer, rows, seed=5):
+    """Params and x [2, 1024, 64] whose router sends exactly ``rows`` pairs
+    to the four held experts: feature 0 of x is +1 on ``rows / 4`` tokens
+    and -1 on the others, and it alone (16 on the held experts' columns)
+    outweighs the rest of the router, so a token takes all four held experts
+    or none."""
+    params = layer.init(jax.random.PRNGKey(0))
+    params["router"] = params["router"].at[0].set(0.0).at[0, :4].set(16.0)
+    x = _x(seed, B=2, S=1024)
+    sign = jnp.where(jax.random.permutation(jax.random.PRNGKey(seed), 2048) < rows // 4, 1.0, -1.0)
+    return params, x.at[:, :, 0].set(sign.reshape(2, 1024))
+
+
+def _passes_run(monkeypatch, layer):
+    """Where in ``order`` every pass the layer really RUNS from here on
+    began (a ``jax.debug.callback`` inside the loop's body)."""
+    began, through = [], layer._through
+
+    def recording(size, limit, at, *rest):
+        jax.debug.callback(lambda at: began.append(int(at)), at)
+        return through(size, limit, at, *rest)
+
+    monkeypatch.setattr(layer, "_through", recording)
+    return began
+
+
+@pytest.mark.parametrize(
+    "tokens, top_k, held, experts, want",
+    [
+        (2048, 4, 4, 32, SIZE),
+        (16384, 8, 16, 128, 20480),  # Trinity's and Keye's cells: a uniform router sends 16,384
+        (16384, 6, 16, 128, 15360),  # Nemotron's: 12,288
+        (8192, 8, 8, 256, 2560),  # Ling's: 2,048
+        (1024, 4, 4, 32, 1024),  # 640 rounds up to 512's next multiple
+        (128, 4, 4, 16, 512),  # toy: every pair, one pass whatever arrives
+        (16, 2, 16, 16, 32),  # every expert held: never over tokens * top_k
+    ],
+)
+def test_the_buffer_is_sized_from_the_uniform_load_and_never_over_every_pair(tokens, top_k, held, experts, want):
+    size = buffer_size(tokens, top_k, held, experts)
+    full, uniform = tokens * top_k, tokens * top_k * held / experts
+    assert size == want <= full and (size % 512 == 0 or size == full)
+    assert size == full or 1.25 * uniform <= size < 1.25 * uniform + 512
+    # the passes: one up to the buffer's edge, even for no rows, one more a row over it, and every pair fits
+    assert [int(buffer_passes(size, rows)) for rows in (0, 1, size, size + 1, 2 * size, 2 * size + 1)] == [1, 1, 1, 2, 2, 3]
+    assert int(buffer_passes(size, full)) == -(-full // size)
+    np.testing.assert_array_equal(buffer_passes(size, jnp.asarray([0.0, size, size + 1.0])), [1, 1, 2])
+
+
+@pytest.mark.parametrize(
+    "rows, passes",
+    [
+        (0, 1), (1024, 1), (1536, 1), (1540, 2), (3072, 2), (3076, 3), (4608, 3),
+        (8192, 6),  # every token on the held experts, as in the test above
+    ],
+)
+def test_every_number_of_passes_gives_the_references_output_and_gradients(monkeypatch, rows, passes):
+    """No load (one pass all the same), a load just under and just over the
+    edge of one pass, of two and of three, and every pair: the output and the gradients of x,
+    the router and every expert matrix are the reference's, the forward
+    pass and the backward pass walk the SAME passes, the fewest that hold
+    the rows, and the counter says how many buffer rows that was."""
+    layer = _sized_layer()
+    assert buffer_size(2048, 4, 4, 32) == SIZE
+    params, x = _sized_case(layer, rows)
+    cfg = dict(REF_CFG, topk_group=1)
+    began = _passes_run(monkeypatch, layer)
+
+    def loss(f, p, x):
+        out, load, balance = f(p, x)
+        return jnp.sum(out ** 2) + balance, (out, load)
+
+    with jax.default_matmul_precision("highest"):
+        (_, (out, load)), got = jax.jit(jax.value_and_grad(partial(loss, layer.apply), argnums=(0, 1), has_aux=True))(params, x)
+        (_, (want_out, _)), want = jax.value_and_grad(
+            partial(loss, lambda p, x: ref.moe_layer(x, p, cfg, (0, 4))), argnums=(0, 1), has_aux=True
+        )(params, x)
+        jax.effects_barrier()
+    assert float(load[:4].sum()) == rows
+    # forward, and in the backward pass each of them forward again and back
+    assert sorted(began) == sorted(2 * [i * SIZE for i in range(passes)])
+    assert float(layer.buffer_rows(2048, load[:4].sum())) == passes * SIZE
+    assert float(jnp.max(jnp.abs(out - want_out))) < TOL
+    for name in ("router", *layer.expert_leaves, *layer.shared_leaves):
+        scale = float(jnp.max(jnp.abs(want[0][name]))) + 1e-6
+        assert float(jnp.max(jnp.abs(got[0][name] - want[0][name]))) < 2e-5 * scale + 1e-6, name
+    assert float(jnp.max(jnp.abs(got[0]["bias"]))) == 0.0
+    scale = float(jnp.max(jnp.abs(want[1])))
+    assert float(jnp.max(jnp.abs(got[1] - want[1]))) < 2e-5 * scale + 1e-6
 
 
 def test_swiglu_clamp_where_a_layers_entry_is_not_zero():
@@ -165,15 +266,22 @@ def test_gradients_reach_router_and_experts_and_never_the_bias():
         assert float(jnp.max(jnp.abs(got[name] - want[name]))) < 2e-5 * scale + 1e-6, name
 
 
-def test_rows_a_grouped_kernel_leaves_unwritten_reach_nothing(monkeypatch):
+@pytest.mark.parametrize("rows", [None, 1136, 1936], ids=["every_pair", "one_pass", "two_passes"])
+def test_rows_a_grouped_kernel_leaves_unwritten_reach_nothing(monkeypatch, rows):
     """``megablox.gmm`` writes only the rows its groups cover, in the
     backward pass too; past them lies whatever the buffer held (on the chip
     a NaN after six steps: PERF.md section 6, PR 29).  Here the grouped
     product is made to leave NaN there, both ways: the result and every
-    gradient must be what they are without the poison."""
-    layer = _layer()
-    params = layer.init(jax.random.PRNGKey(0))
-    x = _x(8)
+    gradient must be what they are without the poison, in the toy layer
+    whose buffer is every pair and in the sized layer with 400 rows of its
+    only pass unwritten and with 1,136 of its second."""
+    if rows is None:
+        layer = _layer()
+        params, x = layer.init(jax.random.PRNGKey(0)), _x(8)
+    else:
+        layer = _sized_layer()
+        params, x = _sized_case(layer, rows, seed=8)
+    began = _passes_run(monkeypatch, layer)
 
     def loss(p, x):
         out, _, _ = layer.apply(p, x)
@@ -203,6 +311,8 @@ def test_rows_a_grouped_kernel_leaves_unwritten_reach_nothing(monkeypatch):
         poisoned.defvjp(fwd, bwd)
         monkeypatch.setattr(layer, "_grouped", poisoned)
         dirty = jax.grad(loss, argnums=(0, 1))(params, x)
+        jax.effects_barrier()
+    assert set(began) == ({0} if rows in (None, 1136) else {0, SIZE})
     for a, b in zip(jax.tree_util.tree_leaves(clean), jax.tree_util.tree_leaves(dirty)):
         assert bool(jnp.all(jnp.isfinite(b)))
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-6, atol=1e-6)

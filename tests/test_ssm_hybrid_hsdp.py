@@ -44,7 +44,7 @@ def test_a_committed_step_moves_every_router_s_bias_and_reports_its_routing():
     # a bias's slot of the gradient tree carries its router's load (a stacked run of one layer)
     loads = [x[0] for x in _biases(model, grads)]
     assert len(loads) == 4 and all(float(x.sum()) == 64 * 4 for x in loads)  # 64 tokens, 4 experts each
-    assert report.shape == (1 + 3 * 4,)  # the objective and the summary, ONE array
+    assert report.shape == (1 + 4 * 4,)  # the objective and the summary, ONE array
     loss, committed = trainer.train_step(batch)
     assert committed and loss == float(report[0])
     for b0, b1, load in zip(before, _biases(model, trainer.holder["params"]), loads):
@@ -53,6 +53,7 @@ def test_a_committed_step_moves_every_router_s_bias_and_reports_its_routing():
     assert len(events) == 1
     assert events[0]["rows_here"] == [float(load[4:8].sum()) for load in loads]
     assert events[0]["load_max"] == [float(load[4:8].max()) for load in loads]
+    assert events[0]["buffer_rows"] == [64.0 * 4] * 4  # toy: the buffer is every pair, one pass
 
 
 def test_two_replicas_stay_bit_equal_while_the_biases_move():

@@ -118,6 +118,7 @@ def test_logits_loss_and_every_gradient_agree_with_the_reference(case, path, ref
     first, held = cfg.experts_held
     stats = model.summary_stats(np.asarray(summary))
     assert stats["rows_here"] == [float(load[first : first + held].sum()) for load in want["loads"]]
+    assert stats["buffer_rows"] == [float(batch[0].size * cfg.top_k)] * len(want["loads"])  # toy: the buffer is every pair, one pass
     got, wanted = _leaves(grads), _leaves(want_grads)
     assert got.keys() == wanted.keys()
     for name in got:

@@ -47,7 +47,7 @@ def test_a_committed_step_moves_every_router_s_bias_and_reports_its_routing():
     assert [len(x) for x in runs] == [2, 1, 3, 1]
     loads = [load for run in runs for load in run]
     assert all(float(x.sum()) == 64 * 4 for x in loads)  # 64 tokens, 4 experts each
-    assert report.shape == (1 + 3 * 7,)  # the objective and the summary of seven routers, ONE array
+    assert report.shape == (1 + 4 * 7,)  # the objective and the summary of seven routers, ONE array
     loss, committed = trainer.train_step(batch)
     assert committed and loss == float(report[0])
     after = [b for run in _biases(model, trainer.holder["params"]) for b in run]
@@ -57,6 +57,7 @@ def test_a_committed_step_moves_every_router_s_bias_and_reports_its_routing():
     assert len(events) == 1
     assert events[0]["rows_here"] == [float(load[4:8].sum()) for load in loads]
     assert events[0]["load_max"] == [float(load[4:8].max()) for load in loads]
+    assert events[0]["buffer_rows"] == [64.0 * 4] * 7  # toy: the buffer is every pair, one pass
 
 
 def test_two_replicas_stay_bit_equal_while_the_biases_move():

@@ -70,7 +70,7 @@ logger = logging.getLogger(__name__)
 
 KERNEL_PATH = "dsa"
 SELECTION_NAMES = ("dsa_mask", "dsa_lse", "dsa_keys")
-SUMMARY_FIELDS = ("rows_here", "load_max", "load_mean", "index_kl", "keys_per_query")
+SUMMARY_FIELDS = ("rows_here", "load_max", "load_mean", "index_kl", "keys_per_query", "buffer_rows")
 
 
 @dataclass(frozen=True)
@@ -383,14 +383,19 @@ class IndexedSparseMoE:
         loss, (load, balance, kl, keys) = self._losses(params, batch)
         with part("head"):
             total = loss + cfg.index_loss_weight * jnp.sum(kl) + jnp.sum(balance)
-            return total, ([], self.step_summary(load, kl, keys))
+            return total, ([], self.step_summary(load, kl, keys, batch[0].size))
 
-    def step_summary(self, load: jax.Array, kl: jax.Array, keys: jax.Array) -> jax.Array:
-        """Of this replica's step, on the device: ``[layers, 5]`` in the
-        order of ``SUMMARY_FIELDS``."""
+    def step_summary(self, load: jax.Array, kl: jax.Array, keys: jax.Array, tokens: int) -> jax.Array:
+        """Of this replica's step of ``tokens`` tokens, on the device:
+        ``[layers, 6]`` in the order of ``SUMMARY_FIELDS`` (``buffer_rows``:
+        the rows of the experts' buffer a layer's pairs went through,
+        ``RoutedExperts.buffer_rows``)."""
         first, held = self.config.experts_held
         here = load[:, first : first + held]
-        return jnp.stack([here.sum(axis=1), here.max(axis=1), here.mean(axis=1), kl, keys], axis=1)
+        rows = here.sum(axis=1)
+        return jnp.stack(
+            [rows, here.max(axis=1), here.mean(axis=1), kl, keys, self.moe.buffer_rows(tokens, rows)], axis=1
+        )
 
     @staticmethod
     def summary_stats(summary: np.ndarray) -> Dict[str, List[float]]:

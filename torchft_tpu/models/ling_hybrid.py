@@ -61,6 +61,8 @@ logger = logging.getLogger(__name__)
 
 KERNEL_PATH = "kda+flash"
 KDA_CHUNK = 64  # tokens a chunk of the delta rule (ops/kda.py)
+# a step's summary, one row an expert layer (``route_summary``, ``summary_stats``)
+ROUTE_FIELDS = ("rows_here", "load_max", "load_mean", "buffer_rows")
 
 
 @dataclass(frozen=True)
@@ -309,20 +311,23 @@ class LingHybrid:
             for bias, load in zip(state, signal)
         ]
 
-    def route_summary(self, loads: List[jax.Array]) -> jax.Array:
-        """Of this replica's step, on the device: ``[expert layers, 3]``,
-        the rows routed to the held experts and their largest and mean
-        load, expert layer by expert layer (a stacked leaf is one row a
-        layer)."""
+    def route_summary(self, loads: List[jax.Array], tokens: int) -> jax.Array:
+        """Of this replica's step of ``tokens`` tokens, on the device:
+        ``[expert layers, 4]`` in the order of ``ROUTE_FIELDS``: the rows
+        routed to the held experts, their largest and mean load and the
+        rows of the experts' buffer they went through
+        (``RoutedExperts.buffer_rows``), expert layer by expert layer (a
+        stacked leaf is one row a layer)."""
         first, held = self.config.experts_held
         here = jnp.concatenate([x.reshape(-1, x.shape[-1]) for x in loads])[:, first : first + held]
-        return jnp.stack([here.sum(axis=1), here.max(axis=1), here.mean(axis=1)], axis=1)
+        rows = here.sum(axis=1)
+        return jnp.stack([rows, here.max(axis=1), here.mean(axis=1), self.moe.buffer_rows(tokens, rows)], axis=1)
 
     @staticmethod
     def summary_stats(summary: np.ndarray) -> Dict[str, List[float]]:
         """:meth:`route_summary` on the host, as the flight event's detail."""
-        rows, largest, mean = np.asarray(summary, np.float64).reshape(-1, 3).T
-        return dict(rows_here=rows.tolist(), load_max=largest.tolist(), load_mean=mean.tolist())
+        columns = np.asarray(summary, np.float64).reshape(-1, len(ROUTE_FIELDS)).T
+        return {name: column.tolist() for name, column in zip(ROUTE_FIELDS, columns)}
 
     # ------------------------------------------------------------------
     # forward
@@ -440,10 +445,15 @@ class LingHybrid:
                 y, load, bal = self._block(carry, w, kind, kernels)
                 return y, (load, bal)
 
-            # keep only the residual stream at layer boundaries
-            body = jax.checkpoint(
-                body, policy=jax.checkpoint_policies.nothing_saveable, prevent_cse=False
-            )
+            # keep only the residual stream at layer boundaries; a group of
+            # one layer is no loop and keeps what it made: the compiler
+            # merged its second forward with the first anyway (no barrier
+            # forbids it), until the experts' loop of passes stood in its
+            # way (PERF.md section 6, PR 42)
+            if _depth > 1:
+                body = jax.checkpoint(
+                    body, policy=jax.checkpoint_policies.nothing_saveable, prevent_cse=False
+                )
             with part("layers"):
                 x, (load, bal) = jax.lax.scan(body, x, stacked)
             loads.append(load)
@@ -517,7 +527,7 @@ class LingHybrid:
         (:meth:`route_summary` of this replica's own signal)."""
         loss, balance, signal = self._losses(params, batch)
         with part("head"):
-            return loss + balance, (signal, self.route_summary(signal))
+            return loss + balance, (signal, self.route_summary(signal, batch[0].size))
 
     def num_params(self) -> int:
         return sum(int(np.prod(s.shape)) for s in jax.tree_util.tree_leaves(self._shapes))

@@ -406,4 +406,4 @@ class SsmHybridMoE:
         (``route_summary`` of this replica's own signal)."""
         loss, balance, signal = self._losses(params, batch)
         with part("head"):
-            return loss + balance, (signal, self.route_summary(signal))
+            return loss + balance, (signal, self.route_summary(signal, batch[0].size))

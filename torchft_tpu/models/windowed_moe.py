@@ -66,7 +66,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from torchft_tpu.models.ling_hybrid import LingHybrid
+from torchft_tpu.models.ling_hybrid import ROUTE_FIELDS, LingHybrid
 from torchft_tpu.models.llama import Llama, _proj
 from torchft_tpu.obs.spans import part
 from torchft_tpu.ops import flash_attention as flash
@@ -389,5 +389,5 @@ class WindowedMoE:
         loss = LingHybrid._mean_nll(self._logits(params, x), targets)
         with part("head"):
             # a model of dense layers alone has no router to sum up
-            summary = self.route_summary(signal) if signal else jnp.zeros((0, 3), jnp.float32)
+            summary = self.route_summary(signal, tokens.size) if signal else jnp.zeros((0, len(ROUTE_FIELDS)), jnp.float32)
             return loss, (signal, summary)

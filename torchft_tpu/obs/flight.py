@@ -118,8 +118,9 @@ class FlightEvent(enum.IntEnum):
     MOE_ROUTE = 31  # one committed step of a model that reports its step
     # (HSDPTrainer, from the model's own summary; detail, expert layer by
     # expert layer: rows_here routed to the experts this chip holds, load_max
-    # and load_mean over them; with a learned index also index_kl and
-    # keys_per_query)
+    # and load_mean over them, buffer_rows the rows of the experts' buffer
+    # they were moved through (parallel/moe.py buffer_size, times the passes
+    # they needed); with a learned index also index_kl and keys_per_query)
 
 
 # data-plane events the native tier may record; the ftlint checker requires

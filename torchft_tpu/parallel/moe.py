@@ -219,6 +219,20 @@ def relu2(up: jax.Array) -> jax.Array:
     return jnp.square(jax.nn.relu(up))
 
 
+def buffer_size(tokens: int, top_k: int, held: int, num_experts: int) -> int:
+    """The static size of the held experts' buffer, in rows: 1.25 times what
+    a uniform router sends here (``tokens * top_k * held / num_experts``),
+    rounded up to 512 and never over every pair (``tokens * top_k``)."""
+    full = tokens * top_k
+    return min(full, -(-5 * full * held // (4 * num_experts * 512)) * 512)
+
+
+def buffer_passes(size: int, rows: jax.Array) -> jax.Array:
+    """How often a buffer of ``size`` rows is filled to take ``rows`` (a
+    count, or one a layer) through it, int32: once even for no rows."""
+    return jnp.maximum(1, (jnp.asarray(rows).astype(jnp.int32) + (size - 1)) // size)
+
+
 class RoutedExperts:
     """An expert layer that is TOLD which experts it holds.
 
@@ -236,11 +250,14 @@ class RoutedExperts:
     shared expert is added once.  On one chip there is no exchange, and no
     code stands in for the absent chips.
 
-    The buffer has two sizes under one ``lax.cond``: four times the rows a
-    uniform router sends here, and, for the step in which more arrive, every
-    pair (``tokens * top_k``), so that no load is ever cut.  Rows go in by
+    The buffer is small and the PASSES through it follow the load:
+    ``buffer_size`` rows (1.25 times what a uniform router sends here), filled
+    as often as the rows that arrived need (``buffer_passes``: once, as a
+    rule), one loop a layer and a step whose trip count the device reads, so
+    that no load is ever cut and ONE program serves every load.  Rows go in by
     a gather and come back by a scatter-add, both over the buffer's rows and
-    never over all ``tokens * top_k`` pairs.
+    never over all ``tokens * top_k`` pairs; the grouped products' work follows
+    the rows, not the buffer.
     """
 
     def __init__(self, config: RoutedExpertsConfig) -> None:
@@ -347,27 +364,32 @@ class RoutedExperts:
         return relu2(*hidden)
 
     def _through(
-        self, cap: int, limit: float, x: jax.Array, weights: jax.Array, *rest: jax.Array
+        self, size: int, limit: float, at: jax.Array, into: jax.Array,
+        x: jax.Array, weights: jax.Array, *rest: jax.Array,
     ) -> jax.Array:
-        """The held experts' part, [T, D] float32, through a buffer of
-        ``cap`` rows: the first ``cap`` of the (token, choice) pairs in
-        ``order`` (by held expert; those of absent experts last).  ``rest``
-        is the experts' matrices (``expert_leaves``), ``order``, ``sizes``."""
+        """``into`` [T, D] float32 and what ONE pass of the buffer adds of
+        the held experts' part: the ``size`` (token, choice) pairs from ``at``
+        on in ``order`` (by held expert; those of absent experts last).
+        ``rest`` is the experts' matrices (``expert_leaves``), ``order``
+        (padded to whole passes), ``sizes``."""
         *w_in, w_down, order, sizes = rest
-        T, k = weights.shape
-        pair = order[:cap]
+        k = weights.shape[1]
+        pair = jax.lax.dynamic_slice(order, (at,), (size,))
         token = pair // k
-        routed = jnp.arange(cap) < jnp.sum(sizes)
+        # this pass's share of every held expert's rows
+        ends = jnp.cumsum(sizes)
+        here = jnp.clip(ends, at, at + size) - jnp.clip(ends - sizes, at, at + size)
+        routed = at + jnp.arange(size) < ends[-1]
         # rows past the routed ones are masked on BOTH sides of the grouped
         # products: a grouped kernel leaves them unwritten, in the backward
         # pass too, and what lies there (a NaN, sooner or later) must reach
         # neither the result nor x's gradient
         xs = jnp.where(routed[:, None], jnp.take(x, token, axis=0), 0)
-        act = self._activate([self._grouped(xs, w, sizes) for w in w_in], limit)
-        ys = self._grouped(act.astype(xs.dtype), w_down, sizes)
+        act = self._activate([self._grouped(xs, w, here) for w in w_in], limit)
+        ys = self._grouped(act.astype(xs.dtype), w_down, here)
         w_row = jnp.where(routed, jnp.take(weights.reshape(-1), pair), 0.0)
         ys = jnp.where(routed[:, None], ys, 0).astype(jnp.float32) * w_row[:, None]
-        return jnp.zeros((T, x.shape[1]), jnp.float32).at[token].add(ys)
+        return into.at[token].add(ys)
 
     # the whole of it is the dispatch; the grouped products inside keep their kernels' names
     @device_part("experts_dispatch")
@@ -377,31 +399,38 @@ class RoutedExperts:
     ) -> jax.Array:
         """What the held experts add, [T, D] float32.
 
-        The buffer has two sizes under one ``lax.cond``; so that the
-        backward pass keeps nothing of the branch not taken (jax would make
-        BOTH branches' intermediates outputs of the forward ``cond``, the
-        full-size ones as zeros: 60 % of the layer's time on the chip,
-        PERF.md section 6, PR 29), the whole part is one ``custom_vjp`` that
-        keeps its inputs and runs forward again, inside the branch, when its
-        gradient is asked for."""
+        The rows go through a buffer of ``buffer_size`` rows in as many
+        passes as they need, one loop whose trip count is the step's own:
+        one program, whatever arrived.  A loop of that kind has no transpose,
+        and the backward pass should keep nothing of a pass but its inputs
+        (what jax keeps of a conditional's branches was 60 % of the layer's
+        time on the chip, PERF.md section 6, PR 29), so the whole part is
+        one ``custom_vjp`` that keeps its inputs and, when its gradient is
+        asked for, walks the same passes again, each forward and back, and
+        adds up what they give."""
         cfg = self.config
         T, k = chosen.shape
         first, held = cfg.experts_held
         local = chosen - first
         here = (local >= 0) & (local < held)
+        size = buffer_size(T, k, held, cfg.num_experts)
         order = jnp.argsort(jnp.where(here, local, held).reshape(-1), stable=True)
-        full = T * k
-        usual = min(full, -(-4 * T * k * held // cfg.num_experts // 512) * 512)
+        order = jnp.pad(order, (0, -(T * k) % size))  # whole passes: the last one's slice stays inside
 
-        def sized(f, sizes):
-            """``f(cap)`` at the smaller size that holds the step's rows."""
-            if usual == full:
-                return f(full)
-            return jax.lax.cond(jnp.sum(sizes) <= usual, lambda: f(usual), lambda: f(full))
+        def passes(body, start, sizes):
+            """``body(at, carry)`` once a pass the step's rows need; the
+            first stands outside the loop (it runs whatever arrived, as the
+            one buffer did), so that the usual step's program has no loop's
+            carry in it: carried from zeros, the backward pass's sums of the
+            experts' matrices' gradients cost 3 GB more by the compiler's
+            count, PERF.md section 6, PR 42."""
+            more = buffer_passes(size, jnp.sum(sizes))
+            return jax.lax.fori_loop(1, more, lambda i, c: body(i * size, c), body(0, start))
 
         @jax.custom_vjp
         def part(*operands):  # x, weights, the experts' matrices, order, sizes
-            return sized(lambda cap: self._through(cap, limit, *operands), operands[-1])
+            into = jnp.zeros((T, operands[0].shape[1]), jnp.float32)
+            return passes(lambda at, into: self._through(size, limit, at, into, *operands), into, operands[-1])
 
         def part_fwd(*operands):
             return part(*operands), operands
@@ -409,14 +438,23 @@ class RoutedExperts:
         def part_bwd(operands, g):
             *floats, order, sizes = operands
 
-            def grads(cap):
-                through = lambda *a: self._through(cap, limit, *a, order, sizes)  # noqa: E731
-                return jax.vjp(through, *floats)[1](g)
+            def grads(at, so_far):
+                through = lambda *a: self._through(size, limit, at, jnp.zeros_like(g), *a, order, sizes)  # noqa: E731
+                return tuple(a + b for a, b in zip(so_far, jax.vjp(through, *floats)[1](g)))
 
-            return (*sized(grads, sizes), None, None)  # the two integer operands have no cotangent
+            # the two integer operands have no cotangent
+            return (*passes(grads, tuple(jnp.zeros_like(f) for f in floats), sizes), None, None)
 
         part.defvjp(part_fwd, part_bwd)
         return part(x, weights, *(params[name] for name in self.expert_leaves), order, sizes)
+
+    def buffer_rows(self, tokens: int, rows: jax.Array) -> jax.Array:
+        """The buffer's rows that ``_held_part`` moves for ``rows`` routed
+        pairs (one an expert layer) in a step of ``tokens`` tokens, float32:
+        its size times its passes, the counter's side of ``buffer_size``."""
+        cfg = self.config
+        size = buffer_size(tokens, cfg.top_k, cfg.experts_held[1], cfg.num_experts)
+        return (size * buffer_passes(size, rows)).astype(jnp.float32)
 
     def apply(
         self, params: Dict[str, Any], x: jax.Array,
