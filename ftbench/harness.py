@@ -378,6 +378,14 @@ def run_cell(
     kill = traffic.get("kill")
     if kill and kill["victim"] == 0:
         raise ValueError("replica 0 leads the run and cannot be the victim")
+    if per_group > 1 and n_replicas > 1 and not layout["groups_share_chip"]:
+        # a program with collectives that JAX's persistent cache hands back
+        # HALTS a group whose chips are not the host's first ("Core halted
+        # unexpectedly ... enhanced-barrier"; compiled in the process it runs
+        # without fault: README.md, "On four chips"; PERF.md section 6, PR 43).
+        # Such a cell compiles its programs in every run, as set-up
+        jax.config.update("jax_enable_compilation_cache", False)
+        cache_dir = "off: a cached program halts a later group's chips"
 
     ctl = Control()
     ctl.marks["setup"] = {"jax_devices_s": t_devices - t_process}

@@ -47,22 +47,24 @@ def no_compile_cache():
 with open(os.path.join(ROOT, "BENCHMARK.json")) as _f:
     _BENCH = json.load(_f)
 # every configuration BENCHMARK.json has, so one that a later PR adds is
-# compiled for the chip here too, through its own architecture; and ``2x2``
-CONFIG_NAMES = [c["name"] for c in _BENCH["configs"]] + ["2x2"]
+# compiled for the chip here too, through its own architecture.  Since PR 43
+# ``mistral-7b-v0.3-2x2`` (two groups of two chips, ``fsdp`` 2, depth 2) is
+# one of them: the file that ships, where a pseudo-configuration stood
+CONFIG_NAMES = [c["name"] for c in _BENCH["configs"]]
 
 
 def _cell(config_name):
     """(architecture module, model, configuration, sequence length) through
-    the lookup a run takes: a configuration's cells, or ``2x2``: the
-    four-chip layout a later PR adds (PERF.md section 7), which is the
-    two-replica file at depth 2 in two groups of two chips."""
-    name = "mistral-7b-v0.3-2on1" if config_name == "2x2" else config_name
-    cells = [spec.load_cell(w["name"]) for w in _BENCH["workloads"] if w["config"] == name]
+    the lookup a run takes: a configuration's cells."""
+    cells = [spec.load_cell(w["name"]) for w in _BENCH["workloads"] if w["config"] == config_name]
     arch, config = cells[0].architecture, dict(cells[0].config)
-    if config_name == "2x2":
-        config.update(num_hidden_layers=2,
-                      layout=dict(chips_per_group=2, groups_share_chip=False, fsdp=2))
     return arch, arch.model(config), config, max(c.traffic["seq_len"] for c in cells)
+
+
+def test_a_group_of_several_chips_is_among_the_configurations():
+    """``test_step_compiles_for_v5e`` looks for collectives only where a group
+    has more than one chip: one configuration at least has such a group."""
+    assert any(spec.load_cell(w["name"]).config["layout"]["chips_per_group"] > 1 for w in _BENCH["workloads"])
 
 
 def _compile_step(topo, config_name, monkeypatch):

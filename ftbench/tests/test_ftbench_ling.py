@@ -164,15 +164,22 @@ def test_reader_finds_nothing_on_a_program_without_it(cell, name):
     assert read(dict(sources, trace=None)) is None
 
 
+ANY_EXPERT_CELL = ("moe_gmm_ms", "moe_rows_here_per_step", "moe_load_max_over_mean")
+
+
 def test_new_readers_list_this_cell_alone():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
     listed = {m["name"]: m for m in bench["per_layer"]}
     for name in NEW_READERS:
-        assert listed[name]["workloads"] == [CELL] and listed[name]["moves"] == "tokens_per_s_per_chip"
+        assert CELL in listed[name]["workloads"] and listed[name]["moves"] == "tokens_per_s_per_chip"
+        # the readers of the experts read the trace and MOE_ROUTE alone, so
+        # any expert cell may join them; Ling's kernels are Ling's
+        assert name in ANY_EXPERT_CELL or listed[name]["workloads"] == [CELL]
     entry = next(w for w in bench["workloads"] if w["name"] == CELL)
     assert entry["chips"] == 1 and len(entry["why"]) <= 200
-    assert sum(1 for w in bench["workloads"]) == 4 and len(bench["configs"]) == 3
+    # no count of cells or configurations: a later PR adds its own
+    assert entry["config"] in [c["name"] for c in bench["configs"]]
 
 
 @pytest.mark.parametrize(

@@ -186,6 +186,22 @@ READINGS = {
     "bucket_copy_ms": 200.0, "h2d_restore_ms": 72.0, "comm_op_ms": 140.0, "ring_peer_skew_ms": 35.0,
 }
 
+# PR 43, the four-chip cell ``mistral7b-hsdp2x2-steady``.  Tier-1's view of
+# this file (``tests/test_ftbench_program_spans.py``) holds the lists of these
+# thirteen readers to ONE cell, and a ``benchmark`` PR may edit nothing under
+# ``tests/``: each reads the four-chip cell under ``<name>.hsdp``, a file that
+# names the reader it is (README.md, "On four chips")
+HSDP_CELL = "mistral7b-hsdp2x2-steady"
+HSDP_TWINS = tuple(sorted(READINGS)) + (
+    "sync_normalize_ms", "bucket_warm_pct", "sync_first_submit_ms", "ring_beside_d2h_pct", "normalize_in_ring_pct",
+)
+# the lists the cell joined itself: those whose readers find something to read
+# on the CPU, and those that need a device plane or ``memory_stats``
+HSDP_JOINED_ON_THE_HOST = {
+    "quorum_ms.ddp", "commit_vote_ms.ddp", "grad_mbytes_per_step", "ring_ms", "ring_tx_mbytes_per_step",
+}
+HSDP_JOINED = HSDP_JOINED_ON_THE_HOST | {"step_device_ms.ddp", "sync_exposed_ms", "device_idle_pct.ddp", "peak_hbm_gb.ddp"}
+
 
 @pytest.mark.parametrize("name", sorted(READINGS))
 def test_span_reader_on_synthetic_planes(run, name, monkeypatch):
@@ -297,17 +313,24 @@ def test_new_readers_are_the_eighteen_benchmark_json_lists():
     # found by name: a later PR appends its own readers after them
     listed = {m["name"]: m for m in bench["per_layer"]}
     assert new <= set(listed) and len(listed) == len(bench["per_layer"]) >= 18
+    # each lists the cell it was written for (and whichever later cell has
+    # the part it reads: no count), under the contract's keys and no other
     for name in new:
-        assert len(listed[name]["workloads"]) == 1 and set(listed[name]) == {
+        cell = ("mistral7b-ddp2-steady" if name in READINGS else
+                "mistral7b-ddp2-kill" if name in KILL_READINGS else "mistral7b-ws1-steady")
+        assert cell in listed[name]["workloads"] and set(listed[name]) == {
             "name", "unit", "better", "source", "layer", "moves", "workloads",
         }
 
 
-def _rehearse(cell, root, devices=2):
+def _rehearse(cell, root):
     """A traced rehearsal in a copy of the benchmark under ``root`` (the
     trace lands in ITS ``ftbench/out``: another test's traced run may be
-    writing the repo's at this moment); the program comes from the repo."""
+    writing the repo's at this moment); the program comes from the repo.  On
+    as many virtual devices as the cell has chips, two at the least."""
     import shutil
+
+    devices = max(2, spec.load_cell(cell).chips)
 
     shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), root)
     shutil.copytree(BENCH_DIR, os.path.join(root, "ftbench"),
@@ -334,6 +357,9 @@ def _rehearse(cell, root, devices=2):
         ("mistral7b-ddp2-kill", set(KILL_READINGS)),
         # the flash kernels do not run on the CPU, and it has no device plane
         ("mistral7b-ws1-steady", set()),
+        # PR 43: two groups of two chips; the thirteen readers whose lists
+        # tier-1 holds to one cell read the same spans under ``<name>.hsdp``
+        (HSDP_CELL, {name + ".hsdp" for name in HSDP_TWINS} | HSDP_JOINED_ON_THE_HOST),
     ],
 )
 def test_rehearsal_would_report_the_program_span_metrics(cell, new, tmp_path):
@@ -342,7 +368,7 @@ def test_rehearsal_would_report_the_program_span_metrics(cell, new, tmp_path):
     reported = _rehearse(cell, str(tmp_path))
     assert new <= reported
     assert not reported & {"flash_fwd_ms", "flash_dq_ms", "flash_dkv_ms"}
-    if cell == "mistral7b-ddp2-steady":
+    if cell in ("mistral7b-ddp2-steady", HSDP_CELL):
         spans = program_spans.load(str(tmp_path / "ftbench"))
         mine = program_spans.of_replica(spans, 0)
         assert {s["name"] for s in mine} >= {
@@ -355,3 +381,227 @@ def test_rehearsal_would_report_the_program_span_metrics(cell, new, tmp_path):
         assert program_spans.of_replica(spans, 1)
         trips = program_spans.sync_round_trips(dict(trace=None), spans=spans)
         assert trips and all(0.0 <= unnamed <= whole for whole, unnamed in trips)
+
+
+# ----------------------------------------------------------------------
+# PR 43: the four-chip cell, its lists, its twins and its own reader
+# ----------------------------------------------------------------------
+
+
+def _listed():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    return bench, {m["name"]: m for m in bench["end_to_end"] + bench["per_layer"]}
+
+
+def test_the_four_chip_cell_and_the_lists_it_joined():
+    bench, listed = _listed()
+    entry = next(w for w in bench["workloads"] if w["name"] == HSDP_CELL)
+    assert entry == dict(entry, config="mistral-7b-v0.3-2x2", traffic="ddp2-steady", chips=4)
+    assert len(entry["why"]) <= 200 and "ICI" in entry["why"] and "bypasses" in entry["why"]
+    config = next(c for c in bench["configs"] if c["name"] == entry["config"])
+    assert config["reduced"] == ["num_hidden_layers"]
+    cell = spec.load_cell(HSDP_CELL)
+    assert cell.config["layout"] == dict(chips_per_group=2, groups_share_chip=False, fsdp=2)
+    assert cell.config["num_hidden_layers"] == 2 and cell.traffic["replicas"] == 2
+    # two groups of two chips are the cell's four
+    assert cell.traffic["replicas"] * cell.config["layout"]["chips_per_group"] == cell.chips == 4
+    # every width is the one-chip twin's, which is the published one
+    twin = spec.load_cell("mistral7b-ddp2-steady").config
+    assert {k: v for k, v in cell.config.items() if isinstance(v, (int, float)) and k != "num_hidden_layers"} == {
+        k: v for k, v in twin.items() if isinstance(v, (int, float)) and k != "num_hidden_layers"
+    }
+    assert HSDP_CELL in listed["ddp_tokens_per_s_per_chip"]["workloads"]
+    for name in HSDP_JOINED:
+        assert HSDP_CELL in listed[name]["workloads"], name
+    assert listed["ici_collective_ms"]["workloads"] == [HSDP_CELL]
+    for name, metric in listed.items():
+        cells = metric.get("workloads", [])
+        # nothing that reads the one-chip two-replica cell is lost to the four-chip
+        # one in silence: it lists both, or its twin lists the four-chip cell
+        if "mistral7b-ddp2-steady" in cells:
+            assert HSDP_CELL in cells or listed[name + ".hsdp"]["workloads"] == [HSDP_CELL], name
+        # what it reports moves the throughput of two replica groups, nothing else
+        if HSDP_CELL in cells and "moves" in metric:
+            assert metric["moves"] == "ddp_tokens_per_s_per_chip", name
+
+
+@pytest.mark.parametrize("name", HSDP_TWINS)
+def test_an_hsdp_twin_is_the_reader_it_names(name):
+    _, listed = _listed()
+    base, twin = spec.load_metric(name, BENCH_DIR), spec.load_metric(name + ".hsdp", BENCH_DIR)
+    # the SAME reader (every load executes a reader's file anew: by its code)
+    assert twin.read.__code__ == base.read.__code__ and twin.META == base.META
+    assert listed[name + ".hsdp"] == dict(listed[name], name=name + ".hsdp", workloads=[HSDP_CELL])
+
+
+def _device_op(name, start_us, dur_us, category="", tf_op="jit(_step)/jit(main)/x"):
+    from ftbench import device_scopes
+
+    return device_scopes.annotate(dict(
+        name=name, start_ps=start_us * 10**6, dur_ps=dur_us * 10**6, start=start_us * 1e-6, dur_s=dur_us * 1e-6,
+        tf_op=tf_op, category=category, source="",
+    ))
+
+
+def _two_steps(ops):
+    """``sources`` whose traced stretch is two steps of one second each, and
+    the planes ``device_scopes.load`` would hand out: ``ops`` on chip 0."""
+    steps = [dict(t_enter=0.0, t_exit=1.0, committed=True), dict(t_enter=1.0, t_exit=2.0, committed=True)]
+    sources = dict(trace=dict(traced_steps=[steps], offset=0.0, per_device={0: {}}), replicas=2, groups_share_chip=False)
+    return sources, {0: ops, 1: [_device_op("%all-gather-done.9", 0, 10**6)]}
+
+
+def test_ici_collective_ms_is_the_own_time_of_the_collectives_on_the_traced_chip(monkeypatch):
+    from ftbench import device_scopes
+
+    ops = [
+        # a step's loop lies over its body: own time is what is left of it
+        _device_op("%while.1", 0, 1000),
+        _device_op("%all-gather-start.1", 0, 5),  # asynchronous: the start is short,
+        # a product runs beside the exchange; the trace names an operation by its
+        # whole HLO line, and an OPERAND that is a collective makes it none,
+        _device_op("%fusion.7 = bf16[2048,4096]{1,0} fusion(bf16[8]{0} %all-gather-start.1)", 5, 300, "convolution fusion"),
+        _device_op("%all-gather-done.1", 305, 45),  # and the wait for it is what the exchange cost
+        _device_op("%reduce-scatter.3 = bf16[1024]{0} reduce-scatter(bf16[2048]{0} %fusion.7)", 400, 100),
+        # a collective by its category: the v5e's fusion of a product and its reduce-scatter
+        _device_op("%fusion.8 = bf16[2048,32768]{1,0} fusion(%p)", 500, 400, category="all-reduce-scatter fusion"),
+        _device_op("%collective-permute-done.2", 1_000_100, 50),
+        _device_op("%all-reduce.5", 3_000_000, 999),  # after the traced stretch
+    ]
+    sources, planes = _two_steps(ops)
+    monkeypatch.setattr(device_scopes, "load", lambda bench_dir=None: planes)
+    read = spec.load_metric("ici_collective_ms", BENCH_DIR).read
+    # (5 + 45 + 100 + 400 + 50) us over two steps, chip 0 alone
+    assert read(sources) == pytest.approx(0.3)
+    # a group of one chip has no exchange: nothing, never 0
+    monkeypatch.setattr(device_scopes, "load", lambda bench_dir=None: {0: [_device_op("%fusion.7", 5, 300)]})
+    device_scopes._CUT.clear()
+    assert read(sources) is None
+    # no device plane (the CPU), no traced stretch
+    monkeypatch.setattr(device_scopes, "load", lambda bench_dir=None: {})
+    assert read(sources) is None and read(dict(sources, trace=None)) is None
+
+
+# ----------------------------------------------------------------------
+# the sharded case of what PR 30, 32 and 40 tested on whole leaves: two groups
+# of ``fsdp`` 2 on four virtual devices through ``ddp.allreduce_pytree``
+# ----------------------------------------------------------------------
+
+
+@pytest.fixture()
+def two_groups_of_two():
+    """Two Managers behind one lighthouse, as the cell builds them (the
+    tier the machine has), each with the model at toy widths laid out over its
+    own two devices; the process's span buffer on and empty."""
+    import jax
+
+    from torchft_tpu import tier as tier_mod
+    from torchft_tpu.checkpointing.http_transport import HTTPTransport
+    from torchft_tpu.manager import Manager
+    from torchft_tpu.obs import spans as obs_spans
+    from torchft_tpu.parallel.hsdp import fsdp_shardings
+    from torchft_tpu.parallel.mesh import make_mesh
+
+    devices = jax.devices()
+    if len(devices) < 4:
+        pytest.skip(f"four devices are needed, jax has {len(devices)} (ftbench/tests/conftest.py asks for eight)")
+    tier = tier_mod.default_tier()
+    lighthouse = tier_mod.make_lighthouse(
+        bind="127.0.0.1:0", min_replicas=2, join_timeout_ms=100, quorum_tick_ms=20, heartbeat_timeout_ms=5000,
+        tier=tier,
+    )
+    cell = spec.load_cell(HSDP_CELL)
+    config = dict(cell.config, **cell.architecture.TOY["config"])
+    groups = []
+    for g in range(2):
+        mesh = make_mesh(fsdp=2, devices=devices[2 * g : 2 * g + 2])
+        model = cell.architecture.model(config)
+        state = {"w": g}
+        manager = Manager(
+            comm=tier_mod.make_communicator(timeout_s=20.0, tier=tier),
+            checkpoint_transport=HTTPTransport(timeout=20.0),
+            load_state_dict=state.update, state_dict=lambda state=state: dict(state),
+            min_replica_size=2, timeout=20.0, quorum_timeout=20.0, connect_timeout=20.0,
+            replica_id=f"hsdp_group_{g}", lighthouse_addr=lighthouse.local_address(),
+            server_cls=tier_mod.manager_server_cls(tier),
+        )
+        groups.append(dict(mesh=mesh, model=model, manager=manager, shardings=fsdp_shardings(model, mesh)[0]))
+    obs_spans.configure(True)
+    obs_spans.clear()
+    yield groups
+    obs_spans.configure(None)
+    obs_spans.clear()
+    for group in groups:
+        group["manager"].shutdown()
+    lighthouse.shutdown()
+
+
+def test_sharded_leaves_of_two_groups_of_fsdp_2_through_allreduce_pytree(two_groups_of_two, monkeypatch):
+    """Three committed steps.  From the second every bucket is filled in kept
+    memory (PR 30), every collective's average is the ring's own (PR 40), and
+    what comes back is, leaf by leaf, the plain mean of the two groups'
+    gradients, laid out over the group's two devices as it went in."""
+    import threading
+    from concurrent.futures import ThreadPoolExecutor
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from torchft_tpu import ddp
+    from torchft_tpu.obs import spans as obs_spans
+
+    # 16 KB a bucket: the toy model's leaves split into several buckets
+    monkeypatch.setenv(ddp.BUCKET_CAP_MB_ENV, str(16384 / (1 << 20)))
+    groups = two_groups_of_two
+
+    def gradients(g, step):
+        # the model's own tree in the model's own layout, other values a group and a step
+        with groups[g]["mesh"]:
+            return jax.jit(groups[g]["model"].init, out_shardings=groups[g]["shardings"])(
+                jax.random.PRNGKey(100 * step + g)
+            )
+
+    def one(g, tree):
+        manager = groups[g]["manager"]
+        with jax.default_device(groups[g]["mesh"].devices.flat[0]):
+            manager.start_quorum()
+            out = ddp.allreduce_pytree(manager, tree).wait(timeout=60.0)
+            took_part = manager.is_participating()
+            assert manager.should_commit()
+        return out, took_part
+
+    with ThreadPoolExecutor(max_workers=2) as pool:
+        for step in range(3):
+            trees = [gradients(g, step) for g in range(2)]
+            sharded = [x for x in jax.tree_util.tree_leaves(trees[0]) if len(x.sharding.device_set) == 2
+                       and not x.sharding.is_fully_replicated]
+            assert sharded, "no leaf of the model is sharded over the group's two devices"
+            done = [f.result(timeout=120.0) for f in [pool.submit(one, g, trees[g]) for g in range(2)]]
+            outs, took_part = [d[0] for d in done], [d[1] for d in done]
+            # as in the cell, the group that heals in its life's first step
+            # (``init_sync``) rides the ring with zeros; then both send theirs
+            assert all(took_part) or step == 0
+            for t in threading.enumerate():
+                if t.name == "tpuft_ddp_gather":
+                    t.join(timeout=10.0)
+            host = [[np.asarray(x) for x in jax.tree_util.tree_leaves(t)] for t in trees]
+            for g in range(2):
+                got = jax.tree_util.tree_leaves(outs[g])
+                assert len(got) == len(host[0])
+                for leaf, mine, *theirs in zip(got, jax.tree_util.tree_leaves(trees[g]), *host):
+                    sent = jnp.stack([x if took else jnp.zeros_like(x) for x, took in zip(theirs, took_part)])
+                    np.testing.assert_array_equal(np.asarray(leaf), np.asarray(jnp.mean(sent, axis=0)))
+                    assert leaf.sharding == mine.sharding and leaf.dtype == mine.dtype
+    for group in groups:
+        syncs = [e for e in group["manager"]._flight.snapshot() if e["name"] == "DDP_SYNC"]
+        buckets = syncs[0]["buckets"]
+        assert len(syncs) == 3 and buckets > 1
+        # a life's first round trip fills fresh memory, every later one the kept buckets
+        assert [e["warm_buckets"] for e in syncs] == [0, buckets, buckets]
+        assert [e["buckets"] for e in syncs] == [buckets] * 3
+    normalize = [s for s in obs_spans.snapshot() if s["name"] == "tpuft/manager/normalize"]
+    # one span a collective: two groups, three steps, ``buckets`` rings each
+    assert len(normalize) == 2 * 3 * buckets
+    assert all(s["attrs"]["in_ring"] == 1 for s in normalize)

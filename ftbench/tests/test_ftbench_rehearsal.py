@@ -70,51 +70,28 @@ def test_rehearsal_walks_the_cell(cell, trace, devices, expects):
     assert checks["token_rms"] < 1e-4
 
 
-def _root_with_the_four_chip_cell(tmp_path):
-    """What the PR that proves hsdp2x2 on the chip adds: a configuration
-    file (two groups of two chips, ``fsdp=2``, depth 2: PERF.md section 7),
-    two entries and the cell's name in the metrics' lists, and no edit to a
-    file under ftbench/."""
-    shutil.copytree(os.path.join(ROOT, "ftbench"), tmp_path / "ftbench",
-                    ignore=shutil.ignore_patterns("out", "__pycache__", "tests"))
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    with open(os.path.join(ROOT, "ftbench", "configs", "mistral-7b-v0.3-2on1.json")) as f:
-        config = json.load(f)
-    config.update(name="mistral-7b-v0.3-2x2", num_hidden_layers=2,
-                  layout=dict(chips_per_group=2, groups_share_chip=False, fsdp=2))
-    with open(tmp_path / "ftbench" / "configs" / "mistral-7b-v0.3-2x2.json", "w") as f:
-        json.dump(config, f)
-    entry = next(c for c in bench["configs"] if c["name"] == "mistral-7b-v0.3-2on1")
-    bench["configs"].append(dict(entry, name="mistral-7b-v0.3-2x2",
-                                 file="ftbench/configs/mistral-7b-v0.3-2x2.json"))
-    bench["workloads"].append(dict(name="mistral7b-hsdp2x2-steady", config="mistral-7b-v0.3-2x2",
-                                   traffic="ddp2-steady", chips=4, why="2 groups x 2 chips"))
-    for m in bench["end_to_end"] + bench["per_layer"]:
-        if "mistral7b-ddp2-steady" in m.get("workloads", []):
-            m["workloads"].append("mistral7b-hsdp2x2-steady")
-    with open(tmp_path / "BENCHMARK.json", "w") as f:
-        json.dump(bench, f)
-    return str(tmp_path)
+HSDP_CELL = "mistral7b-hsdp2x2-steady"
 
 
-def test_rehearsal_walks_the_four_chip_cell_a_later_pr_adds(tmp_path):
-    root = _root_with_the_four_chip_cell(tmp_path)
-    args = ["--workload", "mistral7b-hsdp2x2-steady", "--seed", "7", "--seconds", "2",
-            "--trace", "0", "--rehearse"]
-    # the program is not in that root: it comes from the repo
-    os.environ["PYTHONPATH"] = ROOT + os.pathsep + os.environ.get("PYTHONPATH", "")
-    try:
-        done = _run(args, cwd=root, devices=4)
-        short = _run(args, cwd=root, devices=2)
-    finally:
-        os.environ["PYTHONPATH"] = os.environ["PYTHONPATH"].split(os.pathsep, 1)[1]
+def test_rehearsal_walks_the_four_chip_cell_a_later_pr_adds():
+    """The cell PR 43 added, from the files that ship (before it: built in a
+    temporary root; the name is the one tier-1's view of this file imports):
+    two replica groups of two chips each walk on four virtual devices, and
+    do not lay out on two.  The traced walk of the cell is
+    ``test_ftbench_program_spans.py``'s, in a copy with an ``out/`` of its own."""
+    args = ["--workload", HSDP_CELL, "--seed", "7", "--seconds", "2", "--trace", "0", "--rehearse"]
+    done = _run(args, devices=4)
+    short = _run(args, devices=2)
     assert done.returncode == 0, done.stderr[-3000:]
-    last = _lines(done.stdout)[-1]
-    assert last["rehearsal"] is True and last["correct"] is True
+    lines = _lines(done.stdout)
+    last = lines[-1]
+    assert last["rehearsal"] is True and last["correct"] is True and last["failed"] == 0
     assert set(last["would_report"]) == {"ddp_tokens_per_s_per_chip", "setup_s"}
+    checks = next(l for l in lines if "checks" in l)
+    assert checks["checks"]["devices_as_laid_out"] and len(set(checks["digests"])) == 1
     # two groups of two chips do not fit two devices
     assert short.returncode == 1 and short.stdout.strip() == ""
+    assert "needs 4 chips" in short.stderr
 
 
 def test_no_chip_is_exit_1_and_no_result():

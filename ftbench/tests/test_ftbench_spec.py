@@ -14,6 +14,10 @@ from ftbench.tests.test_ftbench_rehearsal import _lines, _run
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+MISTRAL = "https://huggingface.co/mistralai/Mistral-7B-v0.3/blob/main/config.json"
+# what ``reduced`` may never name: a hidden, intermediate, latent, state or
+# projection size, a head size, an expansion factor, the experts a token
+WIDTH = re.compile(r"(_dim|_rank|intermediate_size|state_size|head_size)$|^(hidden_size|expand|num_experts_per_tok)$")
 
 
 def _bench():
@@ -61,10 +65,14 @@ def test_contract_limits():
             config = json.load(f)
         assert config["source"] == c["source"]
         assert sorted(config["reduced"]) == sorted(c["reduced"])
-        # no width is cut
-        assert (config["hidden_size"], config["intermediate_size"]) == (4096, 14336)
-        assert (config["num_attention_heads"], config["num_key_value_heads"]) == (32, 8)
+        # no width is cut: ``reduced`` names none (the contract's rule, by the
+        # key), so a second family's file needs no edit here; Mistral's own
+        # widths are held where the source is Mistral's
+        if c["source"] == MISTRAL:
+            assert (config["hidden_size"], config["intermediate_size"]) == (4096, 14336)
+            assert (config["num_attention_heads"], config["num_key_value_heads"]) == (32, 8)
         for key in c["reduced"]:
+            assert not WIDTH.search(key), (c["name"], key)
             assert config[key] < config["published"][key]
 
 
@@ -85,8 +93,8 @@ def test_a_cell_a_config_a_mix_and_a_metric_are_added_as_files(tmp_path):
     config = dict(config, name="mistral-7b-v0.3-1x1-deep", num_hidden_layers=5)
     with open(os.path.join(root, "ftbench", "configs", "mistral-7b-v0.3-1x1-deep.json"), "w") as f:
         json.dump(config, f)
-    traffic = dict(spec.load_cell("mistral7b-ws1-steady").traffic, name="ws1-seq8k", seq_len=8192)
-    with open(os.path.join(root, "ftbench", "traffic", "ws1-seq8k.json"), "w") as f:
+    traffic = dict(spec.load_cell("mistral7b-ws1-steady").traffic, name="ws1-seq4k", seq_len=4096)
+    with open(os.path.join(root, "ftbench", "traffic", "ws1-seq4k.json"), "w") as f:
         json.dump(traffic, f)
     with open(os.path.join(root, "ftbench", "layer_metrics", "step_wall_ms.py"), "w") as f:
         f.write(
@@ -102,22 +110,22 @@ def test_a_cell_a_config_a_mix_and_a_metric_are_added_as_files(tmp_path):
              reduced=["num_hidden_layers"], why="five layers")
     )
     bench["workloads"].append(
-        dict(name="mistral7b-ws1-seq8k", config="mistral-7b-v0.3-1x1-deep",
-             traffic="ws1-seq8k", chips=1, why="one sequence of 8,192")
+        dict(name="mistral7b-ws1-seq4k", config="mistral-7b-v0.3-1x1-deep",
+             traffic="ws1-seq4k", chips=1, why="one sequence of 4,096")
     )
     for m in bench["end_to_end"]:
         if m["name"] == "tokens_per_s_per_chip":
-            m["workloads"] = m["workloads"] + ["mistral7b-ws1-seq8k"]
+            m["workloads"] = m["workloads"] + ["mistral7b-ws1-seq4k"]
     bench["per_layer"].append(
         dict(name="step_wall_ms", unit="ms", better="lower", source="host_clock",
              layer="entry points", moves="tokens_per_s_per_chip",
-             workloads=["mistral7b-ws1-seq8k"])
+             workloads=["mistral7b-ws1-seq4k"])
     )
     with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
         json.dump(bench, f)
 
-    cell = spec.load_cell("mistral7b-ws1-seq8k", root=root)
-    assert cell.config["num_hidden_layers"] == 5 and cell.traffic["seq_len"] == 8192
+    cell = spec.load_cell("mistral7b-ws1-seq4k", root=root)
+    assert cell.config["num_hidden_layers"] == 5 and cell.traffic["seq_len"] == 4096
     assert [m["name"] for m in cell.per_layer] == ["step_wall_ms"]
     reader = spec.load_metric("step_wall_ms", cell.bench_dir).read
     steps = [dict(committed=True, t_enter=1.0, t_exit=1.25), dict(committed=True, t_enter=2.0, t_exit=2.35)]
@@ -387,3 +395,56 @@ def test_every_cell_carries_its_architecture(cell):
     assert not any("naive" in path for path in arch.KERNEL_PATHS)
     assert set(arch.TOY) == {"config", "seq_len"} and arch.TOY["seq_len"] >= 16
     assert arch.COARSE_RATIO_K >= 3.0
+
+
+# ----------------------------------------------------------------------
+# the tests that hold a cell to its lists hold what they mean, not a place or
+# a count: the next cell edits none of them (PR 43)
+# ----------------------------------------------------------------------
+
+LIST_TESTS = [
+    ("test_ftbench_ling", "test_new_readers_list_this_cell_alone"),
+    ("test_ftbench_indexed", "test_the_cell_and_the_lists_it_joined"),
+    ("test_ftbench_ssm", "test_the_cell_and_the_lists_it_joined"),
+    ("test_ftbench_swa", "test_the_cell_and_the_lists_it_joined"),
+    ("test_ftbench_program_spans", "test_new_readers_are_the_eighteen_benchmark_json_lists"),
+    ("test_ftbench_program_spans", "test_the_four_chip_cell_and_the_lists_it_joined"),
+]
+
+
+def _with_a_further_cell(bench):
+    """``bench`` as a later PR leaves it: a configuration and a cell after the
+    last, the cell's name at the end of every list that two cells or more share
+    (a reader of one cell alone is that cell's kernel), a reader of its own
+    after the last and, to move every place, one before the first."""
+    bench = json.loads(json.dumps(bench))
+    last = bench["configs"][-1]
+    bench["configs"].append(dict(last, name="a-further-configuration"))
+    bench["workloads"].append(dict(bench["workloads"][-1], name="a-further-cell", config="a-further-configuration"))
+    shared = [m for m in bench["end_to_end"] + bench["per_layer"]
+              if bench["workloads"][-2]["name"] in m.get("workloads", []) and len(m["workloads"]) > 1]
+    assert len(shared) > 10
+    for m in shared:
+        m["workloads"].append("a-further-cell")
+    own = dict(bench["per_layer"][-1], workloads=["a-further-cell"])
+    bench["per_layer"] = [dict(own, name="a_further_reader.first")] + bench["per_layer"] + [dict(own, name="a_further_reader")]
+    return bench
+
+
+@pytest.mark.parametrize("module,test", LIST_TESTS, ids=[f"{m[13:]}.{t}" for m, t in LIST_TESTS])
+def test_a_further_cell_fails_none_of_the_list_tests(module, test, monkeypatch):
+    import importlib
+
+    load = json.load
+
+    def further(f):
+        read = load(f)
+        return _with_a_further_cell(read) if isinstance(read, dict) and "per_layer" in read else read
+
+    theirs = importlib.import_module("ftbench.tests." + module)
+    # on the file as it is, then on the file a later PR would leave
+    getattr(theirs, test)()
+    monkeypatch.setattr(json, "load", further)
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        assert json.load(f)["workloads"][-1]["name"] == "a-further-cell"
+    getattr(theirs, test)()

@@ -208,11 +208,12 @@ def test_the_cell_and_the_lists_it_joined():
     entry = next(w for w in bench["workloads"] if w["name"] == CELL)
     assert entry == dict(entry, config="nemotron-3-nano-30b-a3b-ep8-1x1", traffic="ws1-seq16k", chips=1)
     assert len(entry["why"]) <= 200 and "heads whole" in entry["why"] and "an eighth" in entry["why"]
-    assert bench["workloads"][-1] == entry and bench["configs"][-1]["name"] == entry["config"]
+    # what the test means, never a place or a count: a later PR's cell,
+    # configuration or reader may stand anywhere and edits nothing here
+    assert entry["config"] in [c["name"] for c in bench["configs"]]
     listed = {m["name"]: m.get("workloads") for m in bench["end_to_end"] + bench["per_layer"]}
-    for name in JOINED:
-        assert listed[name][-1] == CELL, name
-    assert [m["name"] for m in bench["per_layer"][-len(NEW_READERS):]] == list(NEW_READERS)
+    for name in JOINED + NEW_READERS:
+        assert CELL in listed[name], name
     traffic = spec.load_cell(CELL).traffic
     assert (traffic["replicas"], traffic["seq_len"], traffic["sequences_per_chip"]) == (1, SEQ, 1)
     assert (traffic["warmup_steps"], traffic["trace_steps"], traffic["kill"], traffic["quantize_outer"]) == (5, 8, None, False)
