@@ -169,27 +169,153 @@ def ordered(args, trees, bump, groups) -> None:
         )
 
 
+def sharded(args, cell, model) -> None:
+    """The ``--sharded`` mode: see the module's docstring."""
+    from torchft_tpu import ddp
+    from torchft_tpu.parallel.hsdp import fsdp_shardings
+    from torchft_tpu.parallel.mesh import make_mesh
+
+    per_group = cell.config["layout"]["chips_per_group"]
+    devices = jax.devices()
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    bump = jax.jit(lambda t: jax.tree_util.tree_map(lambda x: x * 1.0009765625 + 1, t))
+    med = lambda xs: round(statistics.median(xs) * 1e3, 1)  # noqa: E731
+
+    def make(t: int):
+        mesh = make_mesh(fsdp=per_group, devices=devices[t * per_group : (t + 1) * per_group])
+        params_sh = fsdp_shardings(cell.architecture.model(cell.config), mesh)[0]
+        with mesh:
+            return jax.jit(
+                lambda key: jax.tree_util.tree_map(
+                    lambda s: jax.random.normal(key, s.shape, jnp.float32).astype(s.dtype), shapes
+                ),
+                out_shardings=params_sh,
+            )(jax.random.PRNGKey(t))
+
+    most = max(int(c.split(":")[0]) for c in args.configs.split(","))
+    trees = [make(t) for t in range(most)]
+    jax.block_until_ready(trees)
+    plans = [ddp._make_plan(jax.tree_util.tree_leaves(tree), ddp._bucket_cap_bytes()) for tree in trees]
+    print(
+        "buckets_mb", [round(b.size * b.dtype.itemsize / 1e6, 1) for b in plans[0].buckets],
+        "direct_mb", round(plans[0].direct_nbytes / 1e6, 1), "of", round(plans[0].nbytes / 1e6, 1),
+        file=sys.stderr,
+    )
+    for config in args.configs.split(","):
+        n_threads, path = config.split(":")
+        n_threads = int(n_threads)
+        out = [[] for _ in range(n_threads)]
+        barrier = threading.Barrier(n_threads)
+
+        def run(t: int) -> None:
+            tree, plan = trees[t], plans[t]
+            kept = [np.zeros(b.size, b.dtype) for b in plan.buckets]
+            for _ in range(args.rounds):
+                tree = bump(tree)
+                jax.block_until_ready(tree)
+                leaves = jax.tree_util.tree_leaves(tree)
+                barrier.wait()
+                t0 = time.perf_counter()
+                asked, waits, packs, by_shard = 0, [], [], []
+                for b, bucket in enumerate(plan.buckets):
+                    while asked < min(b + ddp._D2H_AHEAD, len(plan.buckets)):
+                        ddp._start_copies(leaves, plan.buckets[asked])
+                        asked += 1
+                    t1 = time.perf_counter()
+                    places: dict = {}
+                    hosts = []
+                    for slot in bucket.slots:
+                        leaf = leaves[slot.index]
+                        if path == "whole" or slot.direct is None:
+                            hosts.append([np.asarray(leaf).reshape(-1)])
+                            continue
+                        parts = []
+                        for place, shard in enumerate(ddp._unique_local_shards(leaf).values()):
+                            t3 = time.perf_counter()
+                            parts.append(np.asarray(shard.data))
+                            places[place] = places.get(place, 0.0) + time.perf_counter() - t3
+                        hosts.append(parts)
+                    t2 = time.perf_counter()
+                    flat = kept[b]
+                    for slot, parts in zip(bucket.slots, hosts):
+                        if path == "direct" and slot.direct is not None:
+                            whole = flat[slot.offset : slot.offset + slot.size].reshape(slot.shape)
+                            for index, block in zip(slot.direct, parts):
+                                whole[index] = block
+                        else:
+                            flat[slot.offset : slot.offset + slot.size] = parts[0]
+                    waits.append(t2 - t1)
+                    packs.append(time.perf_counter() - t2)
+                    by_shard.append([places.get(k, 0.0) for k in range(per_group)])
+                out[t].append((time.perf_counter() - t0, waits, packs, by_shard))
+            trees[t] = tree
+
+        threads = [threading.Thread(target=run, args=(t,)) for t in range(n_threads)]
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join()
+        rounds = out[0][1:]
+        n = len(plans[0].buckets)
+        print(
+            json.dumps(
+                {
+                    "threads": n_threads,
+                    "path": path,
+                    "mbytes": round(plans[0].nbytes / 1e6, 2),
+                    "round_ms": med([r[0] for r in rounds]),
+                    "round_ms_all": [round(r[0] * 1e3, 1) for r in out[0]],
+                    "round_ms_last_thread": med([r[0] for r in out[-1][1:]]),
+                    "d2h_wait_ms": med([sum(r[1]) for r in rounds]),
+                    "pack_ms": med([sum(r[2]) for r in rounds]),
+                    "d2h_wait_ms_by_bucket": [med([r[1][b] for r in rounds]) for b in range(n)],
+                    "pack_ms_by_bucket": [med([r[2][b] for r in rounds]) for b in range(n)],
+                    "wait_ms_by_shard": [
+                        [med([r[3][b][k] for r in rounds]) for k in range(per_group)] for b in range(n)
+                    ],
+                }
+            ),
+            flush=True,
+        )
+
+
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--ordered", action="store_true")
+    ap.add_argument("--sharded", action="store_true")
     ap.add_argument(
         "--configs",
-        default="all:tree,2:asc,2:desc,2:flow,1:flow,3:flow,all:flow,all:tree",
-        help="--ordered: window:order, ...; window a count of buckets or 'all'",
+        default=None,
+        help="--ordered: window:order, ...; window a count of buckets or 'all'.  "
+        "--sharded: threads:path, ...; path 'whole' or 'direct'",
     )
     ap.add_argument("--ring-ms-per-mb", type=float, default=0.97)
-    ap.add_argument("--workload", default="mistral7b-ddp2-steady")
+    ap.add_argument("--workload", default=None, help="mistral7b-ddp2-steady; --sharded: mistral7b-hsdp2x2-steady")
     ap.add_argument("--rounds", type=int, default=6)
     ap.add_argument("--threads", type=int, default=2)
+    ap.add_argument("--toy", action="store_true", help="the architecture's toy widths: a walk on the CPU")
     args = ap.parse_args()
+    if args.configs is None:
+        args.configs = (
+            "2:whole,2:direct,2:direct,2:whole,1:whole,1:direct"
+            if args.sharded
+            else "all:tree,2:asc,2:desc,2:flow,1:flow,3:flow,all:flow,all:tree"
+        )
+    if args.workload is None:
+        args.workload = "mistral7b-hsdp2x2-steady" if args.sharded else "mistral7b-ddp2-steady"
 
     from ftbench import spec
     from torchft_tpu import ddp
 
     cell = spec.load_cell(args.workload)
+    if args.toy:
+        cell.config.update(cell.architecture.TOY["config"])
     model = cell.architecture.model(cell.config)
-    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     print("device", jax.devices()[0].device_kind, file=sys.stderr)
+    if args.sharded:
+        sharded(args, cell, model)
+        return
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
 
     @jax.jit
     def make(key):

@@ -629,3 +629,141 @@ def test_trees_the_window_does_not_reach_go_through_unchanged(solo, asked, kind)
         want = _div(np.asarray(leaf) + np.zeros_like(np.asarray(leaf)), 2)
         assert _bits(got) == _bits(want)
     assert _syncs(solo.manager)[-1]["first_submit_s"] > 0.0
+
+
+# ----------------------------------------------------------------------
+# a group's sharded leaves go from each chip's shard into the bucket (ISSUE 44)
+# ----------------------------------------------------------------------
+
+LAYOUTS = {
+    # name: (shape, the axis laid over the group's two chips; None: see _put)
+    "axis_0": ((64, 6), 0),
+    "axis_1": ((6, 64), 1),
+    "axis_2_of_a_stacked_leaf": ((3, 4, 32), 2),
+    "replicated_over_the_group": ((40, 3), None),
+    "on_one_device": ((40, 3), None),
+    "numpy_leaf_and_python_scalar_beside": ((6, 64), 1),
+}
+
+
+def _put(host: np.ndarray, axis: Optional[int], devices: List[Any], one_device: bool = False) -> jax.Array:
+    """``host`` on ``devices``: on the first alone, or over all of them,
+    ``axis`` in equal shards (None: every one holds the whole)."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    if one_device or len(devices) == 1:
+        return jax.device_put(host, devices[0])
+    spec = P() if axis is None else P(*([None] * axis + ["fsdp"]))
+    return jax.device_put(host, NamedSharding(Mesh(np.array(devices), ("fsdp",)), spec))
+
+
+def _layout_trees(kind: str, dtype: Any, rng: np.random.Generator):
+    """(host values, the fsdp-2 group's tree on chips 0 and 1, the one-chip
+    group's tree on chip 2, the names of the leaves that go direct)."""
+    shape, axis = LAYOUTS[kind]
+    draw = lambda *s: rng.standard_normal(s).astype(np.float32).astype(dtype)  # noqa: E731
+    host: Dict[str, Any] = {"a_kind": draw(*shape), "b_axis_1": draw(10, 8), "c_one_device": draw(7)}
+    if kind.startswith("numpy_leaf"):
+        host["host"] = rng.standard_normal((5, 3)).astype(np.float32)
+        host["scalar"] = float(rng.standard_normal())
+    devices = jax.devices()
+    group, chip = devices[:2], devices[2:3]
+    axes = {"a_kind": axis, "b_axis_1": 1}
+    alone = {"c_one_device"} | ({"a_kind"} if kind == "on_one_device" else set())
+    trees = [
+        {
+            name: value if name in ("host", "scalar") else _put(value, axes.get(name), devs, one_device=name in alone)
+            for name, value in host.items()
+        }
+        for devs in (group, chip)
+    ]
+    direct = ["b_axis_1"] + (["a_kind"] if axis is not None else [])
+    return host, trees[0], trees[1], direct
+
+
+def _record_copies(manager: Manager) -> List[np.ndarray]:
+    """A COPY of every buffer this Manager's ``allreduce`` is handed, as it
+    was handed (the ring reduces in place)."""
+    copies: List[np.ndarray] = []
+    inner = manager.allreduce
+
+    def _allreduce(data: Any, *args: Any, **kwargs: Any) -> Work:
+        copies.append(np.array(data, copy=True))
+        return inner(data, *args, **kwargs)
+
+    manager.allreduce = _allreduce  # type: ignore[method-assign]
+    return copies
+
+
+@pytest.mark.parametrize("kept_set", [False, True], ids=["no_kept_set", "kept_set"])
+@pytest.mark.parametrize("cap", [64, 1 << 20], ids=["cap_below_a_leaf", "cap_above_the_tree"])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, np.float32], ids=["bfloat16", "float32"])
+@pytest.mark.parametrize("kind", sorted(LAYOUTS))
+def test_sharded_leaves_go_from_their_shards_into_the_bucket(
+    lighthouse_addr, monkeypatch, kind, dtype, cap, kept_set
+) -> None:
+    monkeypatch.setenv(BUCKET_CAP_MB_ENV, str(cap / (1 << 20)))
+    p = _Pair(lighthouse_addr)
+    try:
+        copies = [_record_copies(m) for m in p.managers]
+        # a life's first step heals replica 1, which then sends zeros: spend it
+        # on a tree of another signature
+        p.step([{"z": np.ones(3, np.float32)}] * 2)
+        rng = np.random.default_rng(70)
+        for step in range(2 if kept_set else 1):
+            host, sharded, whole, direct = _layout_trees(kind, dtype, rng)
+            for c in copies:
+                del c[:]
+            outs = p.step([sharded, whole])
+            assert p.participating == [True, True]
+            syncs = [_syncs(m)[-1] for m in p.managers]
+            n = syncs[0]["buckets"]
+            assert [e["warm_buckets"] for e in syncs] == [n if step else 0] * 2
+            assert n == (len(host) if cap == 64 else len({np.asarray(v).dtype for v in host.values()}))
+        # (a) the averages: the parent's formula on the HOST values, in both
+        # groups (c: the fsdp-2 group and the one-chip group averaged the same
+        # elements), bit for bit, each leaf back in its own type and layout
+        want = _expected([host, host])
+        for tree, out in zip((sharded, whole), outs):
+            for leaf, g, w in zip(*map(jax.tree_util.tree_leaves, (tree, out)), want):
+                assert isinstance(g, jax.Array) == isinstance(leaf, jax.Array)
+                assert not isinstance(leaf, jax.Array) or g.sharding == leaf.sharding
+                assert np.asarray(g).dtype == w.dtype and _bits(g) == _bits(w), kind
+        # (b) the wire: both groups handed the same bytes, and every leaf lies
+        # in them whole and row-major
+        assert [c.tobytes() for c in copies[0]] == [c.tobytes() for c in copies[1]]
+        wire = b"".join(c.tobytes() for c in copies[0])
+        for name, value in host.items():
+            assert np.asarray(value).tobytes() in wire, name
+        assert sum(c.nbytes for c in copies[0]) == syncs[0]["bytes"] == syncs[1]["bytes"]
+        # the counter: the bytes of the leaves that lie in shards, and of no other
+        assert syncs[0]["direct_bytes"] == sum(host[name].nbytes for name in direct) > 0
+        assert syncs[1]["direct_bytes"] == 0
+        # (d) no whole-leaf host value was made of a leaf that lies in shards
+        for name in direct:
+            assert sharded[name]._npy_value is None, name
+
+        # (a) again, against the parent's PATH: the same values through the
+        # same Managers with no leaf direct (a store made anew plans anew)
+        monkeypatch.setattr(ddp, "_direct_indices", lambda leaf: None)
+        for m in p.managers:
+            m._host_buckets = None
+        direct_copies = [c.tobytes() for c in copies[0]]
+        for c in copies:
+            del c[:]
+        again = [
+            {name: value if not isinstance(value, jax.Array) else jax.device_put(host[name], value.sharding)
+             for name, value in tree.items()}
+            for tree in (sharded, whole)
+        ]
+        parents = p.step(again)
+        assert _syncs(p.managers[0])[-1]["direct_bytes"] == 0
+        assert [c.tobytes() for c in copies[0]] == direct_copies
+        for out, parent in zip(outs, parents):
+            for g, w in zip(*map(jax.tree_util.tree_leaves, (out, parent))):
+                assert _bits(g) == _bits(w)
+        # and there the whole leaf WAS made on the host: (d) can fail
+        for name in direct:
+            assert again[0][name]._npy_value is not None, name
+    finally:
+        p.shutdown()

@@ -59,6 +59,9 @@ SWA_READERS = (
 # PR 42: how full the experts' buffer is, from MOE_ROUTE's buffer_rows (its own
 # tests: tests/test_ftbench_buffer_fill.py); the four expert cells
 FILL_READERS = ("moe_buffer_fill_pct",)
+# PR 44: the share of a step's gradient bytes written from each chip's shard
+# straight into a bucket, from DDP_SYNC's direct_bytes; the four-chip cell
+DIRECT_READERS = ("d2h_direct_pct.hsdp",)
 
 
 @pytest.mark.parametrize("name", sorted(LATER_READINGS))
@@ -74,7 +77,7 @@ def test_new_readers_are_the_eighteen_benchmark_json_lists():  # noqa: F811
         per_layer = json.load(f)["per_layer"]
     appended = (
         LATER_READINGS, LING_READERS, BUCKET_READERS, ORDER_READERS, INDEXED_READERS, SSM_READERS, AHEAD_READERS,
-        SCOPE_READERS, IN_RING_READERS, SWA_READERS, FILL_READERS,
+        SCOPE_READERS, IN_RING_READERS, SWA_READERS, FILL_READERS, DIRECT_READERS,
     )
     later = sum(map(len, appended))
     assert [m["name"] for m in per_layer[-later:]] == [name for group in appended for name in group]
@@ -105,6 +108,9 @@ def test_rehearsal_would_report_the_program_span_metrics(cell, new, tmp_path, mo
     theirs.test_rehearsal_would_report_the_program_span_metrics(cell, new, tmp_path)
     assert not seen["reported"] & set(SCOPE_READERS)
     assert device_scopes.load(str(tmp_path / "ftbench")) == {}
+    # PR 44: the cell whose groups lie over two (virtual) chips reports the
+    # share that went from the shards into the bucket, and no other cell does
+    assert (set(DIRECT_READERS) <= seen["reported"]) == (cell == theirs.HSDP_CELL)
 
 
 def _sync(t, warm=None, buckets=10, name="DDP_SYNC"):
@@ -133,6 +139,45 @@ def test_bucket_warm_pct_on_synthetic_flight_events(events, expects):
     assert read(dict(window=window, flight=[events, [_sync(12.0, 0)]])) == expects
     assert read(dict(window=window, flight=None)) is None
     assert read(dict(window=[[], []], flight=[events, []])) is None
+
+
+def _direct(t, direct=None, name="DDP_SYNC"):
+    event = dict(_sync(t, 10, name=name), bytes=1409384448)
+    return event if direct is None else dict(event, direct_bytes=direct)
+
+
+@pytest.mark.parametrize(
+    "events,expects",
+    [
+        # Mistral on two chips a group: all but the float32 norms lie in shards
+        ([_direct(11.0, 1409286144), _direct(12.0, 1409286144)], 100.0 * 1409286144 / 1409384448),
+        # a group on one chip has no shards to write
+        ([_direct(11.0, 0), _direct(12.0, 0)], 0.0),
+        # the parent's events carry no such counter: every leaf was made whole on the host
+        ([_direct(11.0), _direct(12.0)], 0.0),
+        # events of other names, and of steps outside the window, do not count
+        ([_direct(11.0, 7, name="MOE_ROUTE"), _direct(30.0, 0), _direct(12.0, 1409384448)], 100.0),
+        ([], None),
+    ],
+    ids=["sharded_group", "one_chip_group", "parent", "other_events", "no_events"],
+)
+def test_d2h_direct_pct_on_synthetic_flight_events(events, expects):
+    read = spec.load_metric("d2h_direct_pct.hsdp", theirs.BENCH_DIR).read  # noqa: F405
+    window = [[dict(t_enter=10.0, t_exit=11.5), dict(t_enter=11.5, t_exit=20.0)], []]
+    assert read(dict(window=window, flight=[events, [_direct(12.0, 5)]])) == expects
+    assert read(dict(window=window, flight=None)) is None
+    assert read(dict(window=[[], []], flight=[events, []])) is None
+
+
+def test_d2h_direct_pct_is_the_four_chip_cells_alone():
+    with open(os.path.join(theirs.ROOT, "BENCHMARK.json")) as f:
+        (entry,) = [m for m in json.load(f)["per_layer"] if m["name"] == "d2h_direct_pct.hsdp"]
+    assert entry["workloads"] == [theirs.HSDP_CELL] and entry["better"] == "higher"
+    meta = spec.load_metric("d2h_direct_pct.hsdp", theirs.BENCH_DIR).META  # noqa: F405
+    assert {k: entry[k] for k in meta} == meta
+    assert meta == dict(
+        source="program_counter", layer="device-host boundary", unit="%", moves="ddp_tokens_per_s_per_chip"
+    )
 
 
 def _served(sources, ahead):

@@ -16,6 +16,23 @@ still cross, so transfer and ring are two stages of a pipeline and not two
 stretches in a row.  The order shapes the collective sequence, so like the
 bucket cap it must agree across replicas; it follows from the tree
 signature and the cap alone.
+
+A leaf that lies in shards on several chips of THIS process (a replica
+group on one multi-chip host: fully addressable, more than one unique shard)
+never becomes a whole host array.  ``np.asarray`` of such a leaf lands every
+shard in a host array made anew and then writes each into a second array of
+the leaf's size made anew, strided: the whole gradient a second time onto
+pages nobody has touched (0.6-0.95 GB/s on the v5e's host, PERF.md section
+6, PR 44).  Here each unique shard's landed host value is written straight
+into its place in the leaf's part of the bucket (:func:`_direct_indices`,
+``_Slot.direct``), which is kept memory from the second step on.  The
+bucket's layout does not change by it: the leaf's part stays the WHOLE leaf
+in row-major order, so the wire, the ring's frames, :func:`_restore` and a
+peer group on another layout (one chip, a wounded group re-lowered onto
+fewer chips) see the bytes they saw.  Leaves on one device, replicated
+leaves and numpy leaves ship whole as before; a leaf that is not fully
+addressable ships this host's shards in shard-major ``segments``, right only
+between groups of identical layout.
 """
 
 from __future__ import annotations
@@ -89,6 +106,19 @@ def _unique_local_shards(leaf: Any) -> Dict[Tuple, Any]:
     return dict(sorted(unique.items()))
 
 
+def _direct_indices(leaf: Any) -> Optional[Tuple[Any, ...]]:
+    """Where each unique shard of ``leaf`` lies in the whole leaf (numpy
+    indices, in :func:`_unique_local_shards`' order), for a jax leaf whose
+    shards all live in this process on more than one of its devices; None for
+    every other leaf.  The unique shards of a fully addressable leaf tile it,
+    and they are the ones whose copies ``copy_to_host_async`` starts (the
+    first device an index, as jax's own ``_cached_index_calc`` picks)."""
+    if not (isinstance(leaf, jax.Array) and leaf.is_fully_addressable):
+        return None
+    shards = _unique_local_shards(leaf)
+    return tuple(s.index for s in shards.values()) if len(shards) > 1 else None
+
+
 def _assemble_sharded(
     shape: Tuple[int, ...],
     sharding: Any,
@@ -129,7 +159,12 @@ _D2H_AHEAD = 2
 class _Slot:
     """Where one leaf lies in its bucket, and what brings it back.
 
-    A fully addressable (or non-jax) leaf ships whole.  For multi-host arrays
+    A fully addressable (or non-jax) leaf ships whole: its part of the bucket
+    is the leaf in row-major order.  Where such a leaf lies in shards on
+    several local chips, ``direct`` says where each unique shard goes inside
+    that part, and the pack writes the shards there one by one (the same
+    bytes in the same places as the whole leaf's copy, without the whole
+    leaf on the host in between).  For multi-host arrays
     (a replica group spanning hosts, the v5p reality) each host ships only its
     UNIQUE addressable shards: host h of every replica group addresses the
     same logical region (identical mesh + shardings across groups), so
@@ -149,6 +184,7 @@ class _Slot:
     # zero copy), so what is put from a kept bucket is copied first
     host_backed: bool
     segments: Optional[Dict[Tuple, Tuple[int, int, tuple]]]  # shard key -> (offset, size, shape)
+    direct: Optional[Tuple[Any, ...]] = None  # see :func:`_direct_indices`
 
 
 @dataclasses.dataclass
@@ -167,6 +203,7 @@ class _Plan:
 
     buckets: List[_Bucket]
     nbytes: int  # what crosses the wire a round trip
+    direct_nbytes: int  # of them, written from shards straight into a bucket
     free: List[List[np.ndarray]] = dataclasses.field(default_factory=list)
 
 
@@ -308,6 +345,7 @@ def _make_plan(leaves: List[Any], bucket_cap: int) -> _Plan:
                     host_backed=sharding is not None
                     and any(d.platform == "cpu" for d in sharding.device_set),
                     segments=segments,
+                    direct=_direct_indices(leaf),
                 )
             )
             bucket.size += size
@@ -317,6 +355,9 @@ def _make_plan(leaves: List[Any], bucket_cap: int) -> _Plan:
     return _Plan(
         buckets=buckets,
         nbytes=sum(b.size * b.dtype.itemsize for b in buckets),
+        direct_nbytes=sum(
+            s.size * b.dtype.itemsize for b in buckets for s in b.slots if s.direct is not None
+        ),
     )
 
 
@@ -330,8 +371,15 @@ def _start_copies(leaves: List[Any], bucket: _Bucket) -> None:
 
 
 def _to_host(leaf: Any, slot: _Slot) -> List[np.ndarray]:
-    """This host's contribution of one leaf, flat, in the order of its place
-    in the bucket (waits for the leaf's asynchronous copy)."""
+    """This host's contribution of one leaf in the order of its place in the
+    bucket (waits for the leaf's asynchronous copy): flat, except for a
+    ``direct`` slot, whose unique shards come as they landed, one host array
+    each in ``slot.direct``'s order.  ``np.asarray`` of the leaf itself would
+    assemble them in a whole-leaf array made anew, the leaf's size in fresh
+    pages a second time; the pack writes them into the bucket instead, whose
+    part for this leaf is that same row-major whole."""
+    if slot.direct is not None:
+        return [np.asarray(s.data) for s in _unique_local_shards(leaf).values()]
     if slot.segments is None:
         return [np.asarray(leaf).reshape(-1)]
     shards = _unique_local_shards(leaf)
@@ -459,10 +507,22 @@ def allreduce_pytree(
                     # as late as can be: the set of the step before comes
                     # back when its restored leaves are ready on the device,
                     # and that step's vote and update, this step's quorum and
-                    # gradient program and the first bucket's copy lie between
+                    # gradient program and the first bucket's copy lie between.
+                    # A ``direct`` slot's shards need no set sooner either:
+                    # they wait above as landed host values and are written
+                    # here.  A take before the first wait would find the set
+                    # of the step before still out whenever its restores took
+                    # longer than the vote and two dispatches, pack that step
+                    # cold and hold a second set (``_KEPT_SETS`` is 2: the
+                    # tree's size in host memory once more) from then on.
                     kept = store.take(plan)
                 flat = np.empty(bucket.size, dtype=bucket.dtype) if kept is None else kept[b]
                 for slot, parts in zip(bucket.slots, hosts):
+                    if slot.direct is not None:
+                        whole = flat[slot.offset : slot.offset + slot.size].reshape(slot.shape)
+                        for index, block in zip(slot.direct, parts):
+                            whole[index] = block
+                        continue
                     off = slot.offset
                     for arr in parts:
                         flat[off : off + arr.size] = arr
@@ -518,6 +578,7 @@ def allreduce_pytree(
             buckets=len(works),
             warm_buckets=0 if kept is None else len(works),
             bytes=plan.nbytes,
+            direct_bytes=plan.direct_nbytes,
             **{k: round(v, 6) for k, v in stage_s.items()},
         )
         sync_span.__exit__()
