@@ -24,6 +24,14 @@
 //      settled epoch must complete at least one verified allreduce per
 //      rank.
 //
+//   P  (``stress_comm_* pieces [count] [MiB]``, in place of A and B) — a
+//      step of ``ddp.allreduce_pytree`` on a leaf over the bucket cap: two
+//      ranks, ONE bfloat16 buffer a rank, ``count`` pieces of it rung one
+//      after another in place through ``allreduce_iov`` with the divisor,
+//      while a second thread a rank (the train thread's pack) writes the
+//      pieces after the one in the ring into the same buffer; every piece
+//      verified.  89 pieces of 16 MiB are the four-chip cell's step.
+//
 // Runs at TORCHFT_RING_LANES=2 so the per-lane worker pool and the
 // lane-striped framing are engaged throughout; abort mid-striped-op is the
 // native tier's lane-failover story (every lane to the peer dies at once).
@@ -259,9 +267,54 @@ void phase_b_rank(Communicator* comm, int rank, BState* st) {
   }
 }
 
+// ---------------------------------------------------------------------------
+// Phase P: a leaf's pieces rung in place while the next ones are written
+// ---------------------------------------------------------------------------
+
+void phase_p_rank(Communicator* comm, int rank, const std::string& store_addr,
+                  size_t count, size_t piece_elems) {
+  comm->configure(store_addr + "/stress_p", rank, 2);
+  std::vector<uint16_t> leaf(count * piece_elems);
+  std::atomic<size_t> packed{0};  // pieces written and handed to the ring
+  // the train thread's pack: piece k holds rank + 1 + k % 5 in every element
+  std::thread pack([&] {
+    for (size_t k = 0; k < count; ++k) {
+      std::fill(leaf.begin() + k * piece_elems,
+                leaf.begin() + (k + 1) * piece_elems,
+                f32_to_bf16(static_cast<float>(rank + 1 + k % 5)));
+      packed.store(k + 1, std::memory_order_release);
+    }
+  });
+  for (size_t k = 0; k < count; ++k) {
+    while (packed.load(std::memory_order_acquire) <= k)
+      std::this_thread::yield();
+    void* seg = leaf.data() + k * piece_elems;
+    uint64_t len = piece_elems * 2;
+    try {
+      comm->allreduce_iov(&seg, &len, 1, DT_BF16, OP_SUM, /*divisor=*/2);
+    } catch (const std::exception& ex) {
+      fail("phase P piece " + std::to_string(k) + ": " + ex.what());
+      break;
+    }
+  }
+  pack.join();
+  for (size_t k = 0; k < count; ++k) {
+    // (1 + k%5) + (2 + k%5) = 3 + 2 (k%5), halved: x.5 is a bfloat16
+    const uint16_t want = f32_to_bf16(1.5f + static_cast<float>(k % 5));
+    const uint16_t* piece = leaf.data() + k * piece_elems;
+    for (size_t i = 0; i < piece_elems; ++i) {
+      if (piece[i] != want) {
+        fail("phase P piece " + std::to_string(k) + " corrupt at " +
+             std::to_string(i));
+        break;
+      }
+    }
+  }
+}
+
 }  // namespace
 
-int main() {
+int main(int argc, char** argv) {
   // two lanes: the per-lane worker pool and striped framing run throughout;
   // an abort mid-striped-op kills every lane to the peer at once (the
   // native tier's lane-failure story)
@@ -273,6 +326,20 @@ int main() {
   std::vector<std::unique_ptr<Communicator>> comms;
   for (int r = 0; r < kWorld; ++r)
     comms.push_back(std::make_unique<Communicator>(kOpTimeoutS));
+
+  if (argc > 1 && std::string(argv[1]) == "pieces") {
+    const size_t count = argc > 2 ? std::strtoul(argv[2], nullptr, 10) : 89;
+    const size_t mib = argc > 3 ? std::strtoul(argv[3], nullptr, 10) : 16;
+    std::vector<std::thread> ranks;
+    for (int r = 0; r < 2; ++r)
+      ranks.emplace_back(phase_p_rank, comms[r].get(), r, addr, count,
+                         (mib << 20) / 2);
+    for (auto& t : ranks) t.join();
+    comms.clear();
+    std::printf("stress_comm: phase P done (%zu pieces of %zu MiB x 2 ranks, "
+                "%d failure(s))\n", count, mib, g_failures.load());
+    return g_failures.load() != 0;
+  }
 
   // --- phase A ---------------------------------------------------------
   {

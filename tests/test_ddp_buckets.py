@@ -1,6 +1,8 @@
 """The host buckets of ``ddp.allreduce_pytree`` last from step to step
-(ISSUE 30) and cross to the host in an order, a few at a time (ISSUE 32: the
-last section).  Five rules, each with a test that fails when it is broken:
+(ISSUE 30) and cross to the host in an order, a window of bytes at a time
+(ISSUE 32, ISSUE 46), a leaf over the cap in pieces (ISSUE 46: the section
+after the order's).  Five rules, each with a test that fails when it is
+broken:
 
 1. a set of buckets is handed out again only after a round trip that ended
    without error and whose restored leaves are ready;
@@ -172,12 +174,20 @@ CHANGES_AT = 3
 
 
 @pytest.mark.parametrize(
-    "case", ["same_tree", "cap_flipped", "leaf_reshaped", "non_participating_step", "buckets_differ_30_times"]
+    "case",
+    [
+        "same_tree", "cap_flipped", "leaf_reshaped", "non_participating_step", "buckets_differ_30_times",
+        "cap_cuts_every_leaf", "cap_of_one_row", "cap_at_the_largest_leaf",
+    ],
 )
 def test_six_steps_are_bit_equal_to_the_parents_formula(pair, monkeypatch, case) -> None:
     rngs = [np.random.default_rng(10 + r) for r in range(2)]
+    # the fixture's 2,048 bytes cut ``embed`` (4,800; 48,000 where it is wide)
+    # alone; these cut inside the other jax leaves too, or inside none
+    cap = {"cap_cuts_every_leaf": 24, "cap_of_one_row": 16, "cap_at_the_largest_leaf": 4800}.get(case, 2048)
+    monkeypatch.setenv(BUCKET_CAP_MB_ENV, str(cap / (1 << 20)))
     for step in range(STEPS):
-        # 48,000 bytes of ``embed`` beside buckets of 1,544 and 330
+        # 48,000 bytes of ``embed`` in 24 pieces beside buckets of 1,544 and 330
         wide = 3000 if case == "buckets_differ_30_times" else 300
         if step >= CHANGES_AT and case == "cap_flipped":
             monkeypatch.setenv(BUCKET_CAP_MB_ENV, str(1024 / (1 << 20)))
@@ -188,6 +198,8 @@ def test_six_steps_are_bit_equal_to_the_parents_formula(pair, monkeypatch, case)
         elif case == "non_participating_step":
             monkeypatch.undo()
             monkeypatch.setenv(BUCKET_CAP_MB_ENV, str(2048 / (1 << 20)))
+        if step >= CHANGES_AT and case == "cap_flipped":
+            cap = 1024
         trees = [_tree(rng, wide) for rng in rngs]
         outs = pair.step(trees)
         want = _expected(trees, pair.participating)
@@ -203,12 +215,19 @@ def test_six_steps_are_bit_equal_to_the_parents_formula(pair, monkeypatch, case)
         syncs = _syncs(m)
         assert len(syncs) == STEPS
         assert syncs[-1]["warm_buckets"] == syncs[-1]["buckets"] > 1
+        handed = pair.handed[pair.managers.index(m)]
+        sizes = [a.nbytes for a in handed[-syncs[-1]["buckets"]:]]
+        # no ring carries more than the cap but where one element is more
+        # (``cap_cuts_every_leaf``: none is), a numpy leaf goes whole
+        assert max(sizes) <= max(cap, 41 * 3 * 4)
+        # the counter: the bytes of the jax leaves that are over the cap
+        over = [l.nbytes for l in jax.tree_util.tree_leaves(trees[0]) if isinstance(l, jax.Array) and l.nbytes > cap]
+        assert syncs[-1]["split_bytes"] == sum(over) <= syncs[-1]["bytes"] == sum(sizes)
+        assert (case == "cap_at_the_largest_leaf") == (not over)
         if case == "buckets_differ_30_times":
             # the rings ran in the plan's order, not the tree's: the smallest
-            # bucket, then by falling size
-            handed = pair.handed[pair.managers.index(m)]
-            sizes = [a.nbytes for a in handed[-syncs[-1]["buckets"]:]]
-            assert sizes == [330, 48000, 1544]
+            # bucket, then by falling size (the wide leaf's pieces side by side)
+            assert sizes == [330] + [2000] * 24 + [1544]
 
 
 def test_from_the_second_step_the_buckets_are_the_first_steps_memory(pair) -> None:
@@ -346,13 +365,19 @@ def _failure(kind: str) -> Callable[[], BaseException]:
     }[kind]
 
 
+@pytest.mark.parametrize("cut", [False, True], ids=["whole_leaves", "a_leaf_in_pieces"])
 @pytest.mark.parametrize("kind", ["raised", "timed_out", "errored_before_submit"])
-def test_a_failed_round_trip_never_gives_its_buckets_back(solo, kind) -> None:
+def test_a_failed_round_trip_never_gives_its_buckets_back(solo, monkeypatch, kind, cut) -> None:
     """Rule 1: an op thread that is still receiving writes into memory nobody
     reuses."""
+    if cut:
+        # 32 bytes: ``b`` crosses in four pieces, views of ONE kept buffer (a
+        # failed ring of any of them keeps the whole set out)
+        monkeypatch.setenv(BUCKET_CAP_MB_ENV, str(32 / (1 << 20)))
     tree = {"a": np.arange(64, dtype=np.float32), "b": jnp.ones((8, 8), jnp.bfloat16)}
     solo.step(tree)
     n = len(solo.handed)
+    assert n == (5 if cut else 2) and _syncs(solo.manager)[-1]["split_bytes"] == (128 if cut else 0)
     solo.step(tree)  # warm: the first step's buckets
     assert all(np.shares_memory(solo.handed[b], solo.handed[n + b]) for b in range(n))
 
@@ -476,8 +501,12 @@ def test_no_knob() -> None:
     source = inspect.getsource(ddp)
     assert source.count("os.environ") == 1  # the bucket cap, as before
     # the window and the order (ISSUE 32): a constant and a function of the
-    # buckets' sizes, not an argument, an environment variable or a knob
-    assert type(ddp._D2H_AHEAD) is int and ddp._D2H_AHEAD >= 1
+    # buckets' sizes, not an argument, an environment variable or a knob; the
+    # window counts bytes (ISSUE 46) and the count of buckets is gone, and a
+    # piece's size is the cap: no second one beside it
+    assert type(ddp._D2H_AHEAD_BYTES) is int and ddp._D2H_AHEAD_BYTES >= 1
+    assert not hasattr(ddp, "_D2H_AHEAD")
+    assert list(inspect.signature(ddp._pieces).parameters) == ["shape", "itemsize", "cap"]
     assert list(inspect.signature(ddp._pipeline_order).parameters) == ["nbytes"]
     assert list(inspect.signature(ddp._make_plan).parameters) == ["leaves", "bucket_cap"]
     assert "knobs" not in source and "getenv" not in source
@@ -516,8 +545,9 @@ def _cell_trees(name: str) -> List[Any]:
 
 
 @pytest.mark.parametrize(
-    "cell,which", [("mistral7b-ddp2-steady", 0), ("ling3flash-ws1-seq8k", 0), ("ling3flash-ws1-seq8k", 1)],
-    ids=["mistral_gradients", "ling_gradients", "ling_signal"],
+    "cell,which",
+    [("mistral7b-ddp2-steady", 0), ("mistral7b-hsdp2x2-steady", 0), ("ling3flash-ws1-seq8k", 0), ("ling3flash-ws1-seq8k", 1)],
+    ids=["mistral_gradients", "mistral_2x2_gradients", "ling_gradients", "ling_signal"],
 )
 def test_two_managers_derive_one_order_from_the_bucket_sizes_alone(cell, which) -> None:
     leaves = _cell_trees(cell)[which]
@@ -534,15 +564,35 @@ def test_two_managers_derive_one_order_from_the_bucket_sizes_alone(cell, which) 
     # the smallest first, then by falling size; ties by place in the tree
     if len(sizes) > 1:
         assert sizes[0] == min(sizes) and sizes[1:] == sorted(sizes[1:], reverse=True)
-    first = [group[0] for group in layouts[0]]
+    # ... ties by place in the tree's listing: a dtype's buckets together (a
+    # ring a dtype), the dtypes as they first come
+    dtypes = [l.dtype.name for l in leaves]
+    place = [(dtypes.index(b.dtype.name), group[0]) for b, group in zip(plans[0].buckets, layouts[0])]
     for a, b in zip(range(1, len(sizes)), range(2, len(sizes))):
-        assert sizes[a] > sizes[b] or first[a] < first[b]
+        assert sizes[a] > sizes[b] or place[a] < place[b]
     # a function of the sizes alone: the same sizes from anywhere, the same order
-    in_tree = sorted(range(len(sizes)), key=lambda b: first[b])
+    in_tree = sorted(range(len(sizes)), key=lambda b: place[b])
     order = ddp._pipeline_order([sizes[b] for b in in_tree])
     assert [in_tree[b] for b in order] == list(range(len(sizes)))
-    if cell.startswith("mistral") :
-        assert [round(n / 1e6, 1) for n in sizes] == [0.0, 268.4, 268.4, 117.4, 117.4, 117.4, 33.6, 33.6, 8.4, 8.4]
+    if cell.startswith("mistral"):
+        # these leaves are shapes and no ``jax.Array``s, so none is cut: the
+        # buffers of the cell's plan.  On the chip every one over the cap
+        # crosses in ``_pieces``' pieces: the buckets a step and DDP_SYNC's
+        # ``split_bytes`` of ``bytes`` (PERF.md section 6, PR 46)
+        want_mb, buckets, split, total = {
+            "mistral7b-ddp2-steady": (
+                [0.0, 268.4, 268.4, 117.4, 117.4, 117.4, 33.6, 33.6, 8.4, 8.4], 62, 956301312, 973127680,
+            ),
+            "mistral7b-hsdp2x2-steady": (
+                [0.1, 268.4, 268.4, 234.9, 234.9, 234.9, 67.1, 67.1, 16.8, 16.8], 89, 1375731712, 1409368064,
+            ),
+        }[cell]
+        assert [round(n / 1e6, 1) for n in sizes] == want_mb and cap == 16 << 20
+        over = [l for l in leaves if l.nbytes > cap]
+        pieces = [ddp._pieces(l.shape, l.dtype.itemsize, cap) for l in over]
+        assert len(sizes) - len(over) + sum(map(len, pieces)) == buckets
+        assert (sum(l.nbytes for l in over), plans[0].nbytes) == (split, total)
+        assert max(size for of_leaf in pieces for _, _, size in of_leaf) * 2 == cap
 
 
 def test_the_order_of_sizes() -> None:
@@ -552,35 +602,171 @@ def test_the_order_of_sizes() -> None:
     assert ddp._pipeline_order([268, 117, 117, 117, 8, 34, 34, 8, 268, 1]) == [9, 0, 8, 1, 2, 3, 5, 6, 4, 7]
 
 
+# ----------------------------------------------------------------------
+# a leaf over the cap crosses in pieces (ISSUE 46): the plan as a pure function
+# ----------------------------------------------------------------------
+
+
+@pytest.mark.parametrize(
+    "shape,itemsize,cap,count,largest",
+    [
+        ((32768, 4096), 2, 16 << 20, 16, 16 << 20),  # Mistral's embedding at the default cap: rows a:b
+        ((4096, 32768), 2, 16 << 20, 16, 16 << 20),  # its head
+        ((1, 4096, 14336), 2, 16 << 20, 8, 512 * 28672),  # ddp2-steady's stacked MLP matrices: [0, a:b, :]
+        ((2, 4096, 14336), 2, 16 << 20, 16, 512 * 28672),  # hsdp2x2-steady's: [i, a:b, :]
+        ((2, 14336, 4096), 2, 16 << 20, 14, 2048 * 8192),  # seven pieces a layer
+        ((2, 4096, 4096), 2, 32 << 20, 2, 32 << 20),  # its attention matrices at a cap of 32 MiB: [i, :, :]
+        ((1000, 7), 4, 100, 334, 84),  # a long leading axis, three rows a piece at most
+        ((3, 5, 11), 4, 64, 15, 44),  # a row of the middle axis fits, a row of the first does not
+        ((4, 300), 4, 256, 20, 240),  # a row of the LAST axis is over the cap: [i, a:b]
+        ((1, 1, 9), 8, 8, 9, 8),  # one element a piece
+        ((5,), 8, 4, 5, 8),  # an element over the cap goes alone, over it
+        ((129,), 1, 64, 3, 43),  # as equal as they can be: 43 each, not 64, 64, 1
+    ],
+)
+def test_pieces_tile_the_leaf_in_row_major_order(shape, itemsize, cap, count, largest) -> None:
+    pieces = ddp._pieces(shape, itemsize, cap)
+    assert len(pieces) == count and max(size for _, _, size in pieces) * itemsize == largest
+    total = int(np.prod(shape, dtype=np.int64))
+    numbered = np.arange(total).reshape(shape) if total < 100_000 else None  # the cells' leaves by arithmetic
+    at = 0
+    for index, start, size in pieces:
+        # a contiguous range, where the last one ended: fixed indices of the
+        # leading axes, then a slice of one axis
+        assert all(isinstance(i, int) for i in index[:-1]) and isinstance(index[-1], slice)
+        first = index[:-1] + (index[-1].start,) + (0,) * (len(shape) - len(index))
+        row = int(np.prod(shape[len(index):], dtype=np.int64))
+        assert start == at == np.ravel_multi_index(first, shape) and size == (index[-1].stop - index[-1].start) * row
+        if numbered is not None:
+            assert numbered[index].reshape(-1).tolist() == list(range(start, start + size))
+        assert size * itemsize <= max(cap, itemsize)
+        at += size
+    assert at == total
+    sizes = [size for _, _, size in pieces]
+    assert max(sizes) - min(sizes) <= int(np.prod(shape[len(pieces[0][0]):]))  # a row apart at most
+
+
+@pytest.mark.parametrize("axis", [0, 1, None], ids=["rows_on_two_chips", "columns_on_two_chips", "replicated"])
+@pytest.mark.parametrize("cap", [96, 500, 4096])
+def test_a_group_of_two_chips_and_a_group_of_one_derive_the_same_pieces(axis, cap) -> None:
+    """The pieces follow from the shape, the dtype and the cap; where a piece
+    lies on the chips is each process's own matter."""
+    rng = np.random.default_rng(80)
+    host = {
+        "big": rng.standard_normal((40, 12)).astype(np.float32),
+        "stack": rng.standard_normal((2, 8, 16)).astype(np.float32),
+        "small": rng.standard_normal(5).astype(np.float32),
+        "host": rng.standard_normal((30, 12)).astype(np.float32),
+    }
+    devices = jax.devices()
+    trees = [
+        {name: v if name == "host" else _put(v, None if name == "small" else axis, devs) for name, v in host.items()}
+        for devs in (devices[:2], devices[2:3])
+    ]
+    plans = [ddp._make_plan(jax.tree_util.tree_leaves(t), cap) for t in trees]
+    described = [
+        [(b.buffer, b.offset, b.size, b.last, b.piece and b.piece.shape, [s.index for s in b.slots]) for b in p.buckets]
+        for p in plans
+    ]
+    assert described[0] == described[1]
+    assert [(p.buffers, p.nbytes, p.split_nbytes) for p in plans][0] == (plans[1].buffers, plans[1].nbytes, plans[1].split_nbytes)
+    over = [v.nbytes for name, v in host.items() if name != "host" and v.nbytes > cap]
+    assert plans[0].split_nbytes == sum(over) and (cap == 4096) == (not over)
+    sizes = [b.nbytes for b in plans[0].buckets]
+    assert sizes[0] == min(sizes) and sizes[1:] == sorted(sizes[1:], reverse=True)
+    # no bucket over the cap but the numpy leaf's, which goes whole
+    assert sorted(sizes)[-2] <= cap < sizes[1] == host["host"].nbytes or cap == 4096
+    # a buffer's buckets tile it, and the last of them in the plan's order restores the leaf
+    for k, (_dtype, size) in enumerate(plans[0].buffers):
+        mine = [b for b in plans[0].buckets if b.buffer == k]
+        assert sorted((b.offset, b.offset + b.size) for b in mine)[0][0] == 0 and sum(b.size for b in mine) == size
+        assert [b.last for b in mine] == [False] * (len(mine) - 1) + [True]
+    # each process finds every element of a piece exactly once on its own chips
+    for plan, tree in zip(plans, trees):
+        leaves = jax.tree_util.tree_leaves(tree)
+        for b in plan.buckets:
+            if b.piece is None:
+                continue
+            shards = list(ddp._unique_local_shards(leaves[b.slots[0].index]).values())
+            got = np.full(b.piece.shape, np.nan, np.float32)
+            for src in b.piece.sources:
+                block = np.asarray(shards[src.place].data)[tuple(slice(s, s + n) for s, n in zip(src.starts, src.sizes))]
+                assert np.isnan(got[src.where]).all()
+                got[src.where] = block
+            want = np.asarray(leaves[b.slots[0].index]).reshape(-1)[b.offset : b.offset + b.size]
+            assert _bits(got) == _bits(want)
+
+
+def _parents_buckets(leaves: List[Any], cap: int) -> List[List[int]]:
+    """The plan of the commit before ISSUE 46: by dtype, cut between leaves
+    at the cap, in ``_pipeline_order``."""
+    by_dtype: Dict[str, List[int]] = {}
+    for i, leaf in enumerate(leaves):
+        by_dtype.setdefault(np.asarray(leaf).dtype.name if not hasattr(leaf, "dtype") else leaf.dtype.name, []).append(i)
+    groups: List[List[int]] = []
+    for idxs in by_dtype.values():
+        group: List[int] = []
+        for i in idxs:
+            if group and sum(leaves[j].nbytes for j in group) + leaves[i].nbytes > cap:
+                groups.append(group)
+                group = []
+            group.append(i)
+        groups.append(group)
+    order = ddp._pipeline_order([sum(leaves[j].nbytes for j in g) for g in groups])
+    return [groups[b] for b in order]
+
+
+@pytest.mark.parametrize("cap", [4800, 6000, 1 << 20], ids=["cap_at_the_largest_leaf", "cap_over_it", "one_bucket_a_dtype"])
+def test_a_tree_under_the_cap_derives_the_parents_plan(cap) -> None:
+    leaves = jax.tree_util.tree_leaves(_tree(np.random.default_rng(81)))
+    assert max(l.nbytes for l in leaves) == 4800
+    plan = ddp._make_plan(leaves, cap)
+    assert [[s.index for s in b.slots] for b in plan.buckets] == _parents_buckets(leaves, cap)
+    assert plan.split_nbytes == 0 and all(b.piece is None and b.last and b.offset == 0 for b in plan.buckets)
+    assert plan.buffers == [(b.dtype, b.size) for b in sorted(plan.buckets, key=lambda b: b.buffer)]
+    assert sorted(b.buffer for b in plan.buckets) == list(range(len(plan.buckets)))
+    for b in plan.buckets:
+        assert [s.offset for s in b.slots] == [sum(t.size for t in b.slots[:k]) for k in range(len(b.slots))]
+    # one byte less and the largest leaf, and it alone, crosses in pieces
+    cut = ddp._make_plan(leaves, 4799)
+    assert cut.split_nbytes == 4800 and sum(b.piece is not None for b in cut.buckets) == 2
+
+
 @pytest.fixture()
 def asked(monkeypatch):
-    """Every ``copy_to_host_async`` of a jax array, in order: the array's id."""
-    calls: List[int] = []
+    """Every ``copy_to_host_async`` of a jax array, in order: the array's id
+    and its bytes."""
+    calls: List[Any] = []
     array_type = type(jnp.zeros(1))
     inner = array_type.copy_to_host_async
 
     def _recorded(self: Any) -> None:
-        calls.append(id(self))
+        calls.append((id(self), self.nbytes))
         inner(self)
 
     monkeypatch.setattr(array_type, "copy_to_host_async", _recorded)
     return calls
 
 
-def test_copies_start_a_window_ahead_of_the_ring(solo, asked, monkeypatch) -> None:
-    """At the submit of the bucket in place b of the plan, the leaves of the
-    buckets in places up to b + W - 1 have been asked for, none of a later
-    one; by the end every jax leaf exactly once and no numpy leaf at all."""
+@pytest.mark.parametrize("window", [1, 3000, 4096, 1 << 30])
+def test_copies_start_a_window_of_bytes_ahead_of_the_ring(solo, asked, monkeypatch, window) -> None:
+    """At the submit of the bucket in place b of the plan the copies of a
+    prefix of the plan have been started: bucket b's own, and the next ones'
+    until the bytes from b on pass the window, none of a later one.  By the
+    end every jax leaf under the cap was asked for exactly once, every piece
+    of a leaf over it exactly once as a transfer of its own, and no numpy
+    leaf at all."""
     monkeypatch.setenv(BUCKET_CAP_MB_ENV, str(2048 / (1 << 20)))
+    monkeypatch.setattr(ddp, "_D2H_AHEAD_BYTES", window)
     rng = np.random.default_rng(50)
     tree = {f"w{k}": jnp.asarray(rng.standard_normal(100 * (k + 1)).astype(np.float32)) for k in range(9)}
     tree["host"] = rng.standard_normal(700).astype(np.float32)
     leaves = jax.tree_util.tree_leaves(tree)
-    seen: List[List[int]] = []
+    seen: List[int] = []
     inner = solo.manager.allreduce
 
     def _allreduce(data: Any, *args: Any, **kwargs: Any) -> Work:
-        seen.append(list(asked))
+        seen.append(len(asked))
         return inner(data, *args, **kwargs)
 
     solo.manager.allreduce = _allreduce  # type: ignore[method-assign]
@@ -588,28 +774,47 @@ def test_copies_start_a_window_ahead_of_the_ring(solo, asked, monkeypatch) -> No
         del asked[:], seen[:]
         out = solo.step(tree)
         (plan,) = solo.manager._host_buckets._plans.values()
-        ids = [[id(leaves[s.index]) for s in b.slots if s.sharding is not None] for b in plan.buckets]
         n = len(plan.buckets)
-        assert n >= 6 and len(seen) == n
+        # w5 .. w8 (2,400 to 3,600 bytes) cross in two pieces each, the numpy
+        # leaf of 2,800 bytes whole
+        assert n == len(seen) == 13 and sum(b.piece is not None for b in plan.buckets) == 8
+        assert max(b.nbytes for b in plan.buckets) == 2800
+        # what a bucket asks for: its jax leaves as they are, or its piece
+        asks = [
+            [(None, b.nbytes)] if b.piece is not None
+            else [(id(leaves[s.index]), leaves[s.index].nbytes) for s in b.slots if s.sharding is not None]
+            for b in plan.buckets
+        ]
+        crossing = [sum(nbytes for _, nbytes in ask) for ask in asks]
+        assert crossing == [b.crossing for b in plan.buckets] and crossing.count(0) == 1
+        started = 0
         for b in range(n):
-            upto = min(b + ddp._D2H_AHEAD, n)
-            assert seen[b] == [i for group in ids[:upto] for i in group], b
-        jax_leaves = [id(l) for l in leaves if isinstance(l, jax.Array)]
-        assert sorted(asked) == sorted(jax_leaves) and len(set(asked)) == len(asked)
+            assert seen[b] >= seen[max(b - 1, 0)]
+            started = next(k for k in range(n + 1) if sum(len(a) for a in asks[:k]) == seen[b])
+            assert started > b
+            assert sum(crossing[b:started]) >= window or started == n, (b, started)
+            assert sum(crossing[b : started - 1]) < window or started == b + 1, (b, started)
+        flat_asks = [a for ask in asks for a in ask]
+        assert len(asked) == len(flat_asks)
+        for (got_id, got_bytes), (want_id, want_bytes) in zip(asked, flat_asks):
+            assert got_bytes == want_bytes and want_id in (None, got_id)
+        whole = [i for i, _ in flat_asks if i is not None]
+        assert len(set(whole)) == len(whole) and id(tree["host"]) not in [i for i, _ in asked]
+        assert _syncs(solo.manager)[-1]["split_bytes"] == sum(tree[f"w{k}"].nbytes for k in range(5, 9))
         for name, leaf in tree.items():
             np.testing.assert_array_equal(np.asarray(out[name]), np.asarray(leaf) / 2)
 
 
-@pytest.mark.parametrize("kind", ["one_jax_bucket", "numpy_leaves", "fewer_buckets_than_the_window"])
+@pytest.mark.parametrize("kind", ["one_jax_bucket", "numpy_leaves", "fewer_bytes_than_the_window"])
 def test_trees_the_window_does_not_reach_go_through_unchanged(solo, asked, kind) -> None:
-    """One bucket, no jax leaf, or no more buckets than the window: what the
+    """One bucket, no jax leaf, or fewer bytes than the window: what the
     parent did (every copy started before the first wait)."""
     rng = np.random.default_rng(60)
     f32 = lambda n: rng.standard_normal(n).astype(np.float32)  # noqa: E731
     tree = {
         "one_jax_bucket": {"a": jnp.asarray(f32(64)), "b": jnp.asarray(f32(8))},
         "numpy_leaves": {"a": f32(64), "b": f32(8).astype(np.float64), "c": 3.0},
-        "fewer_buckets_than_the_window": {"a": jnp.asarray(f32(64)), "b": jnp.asarray(f32(8)).astype(jnp.bfloat16)},
+        "fewer_bytes_than_the_window": {"a": jnp.asarray(f32(64)), "b": jnp.asarray(f32(8)).astype(jnp.bfloat16)},
     }[kind]
     seen: List[int] = []
     inner = solo.manager.allreduce
@@ -621,14 +826,16 @@ def test_trees_the_window_does_not_reach_go_through_unchanged(solo, asked, kind)
     solo.manager.allreduce = _allreduce  # type: ignore[method-assign]
     out = solo.step(tree)
     n_jax = sum(isinstance(l, jax.Array) for l in jax.tree_util.tree_leaves(tree))
-    buckets = {"one_jax_bucket": 1, "numpy_leaves": 2, "fewer_buckets_than_the_window": 2}[kind]
-    assert buckets <= ddp._D2H_AHEAD and seen == [n_jax] * buckets and len(asked) == n_jax
+    buckets = {"one_jax_bucket": 1, "numpy_leaves": 2, "fewer_bytes_than_the_window": 2}[kind]
+    assert seen == [n_jax] * buckets and len(asked) == n_jax
+    sync = _syncs(solo.manager)[-1]
+    assert sync["bytes"] < ddp._D2H_AHEAD_BYTES and sync["split_bytes"] == 0
     for name, leaf in tree.items():
         got = out[name]
         assert isinstance(got, jax.Array) == isinstance(leaf, jax.Array)
         want = _div(np.asarray(leaf) + np.zeros_like(np.asarray(leaf)), 2)
         assert _bits(got) == _bits(want)
-    assert _syncs(solo.manager)[-1]["first_submit_s"] > 0.0
+    assert sync["first_submit_s"] > 0.0
 
 
 # ----------------------------------------------------------------------
@@ -695,13 +902,32 @@ def _record_copies(manager: Manager) -> List[np.ndarray]:
     return copies
 
 
+def _newest_plan(manager: Manager) -> Any:
+    return next(reversed(manager._host_buckets._plans.values()))
+
+
+def _buffers_of(plan: Any, copies: List[np.ndarray]) -> List[bytes]:
+    """The plan's host buffers as the rings were handed them: a bucket's own
+    bytes, or a leaf's pieces put together at their places."""
+    out = [np.zeros(size, dtype) for dtype, size in plan.buffers]
+    for bucket, copy in zip(plan.buckets, copies):
+        out[bucket.buffer][bucket.offset : bucket.offset + bucket.size] = copy
+    return [b.tobytes() for b in out]
+
+
 @pytest.mark.parametrize("kept_set", [False, True], ids=["no_kept_set", "kept_set"])
-@pytest.mark.parametrize("cap", [64, 1 << 20], ids=["cap_below_a_leaf", "cap_above_the_tree"])
+@pytest.mark.parametrize(
+    "cap", [64, 256, 1 << 20], ids=["cap_of_a_row_or_less", "cap_of_some_rows", "cap_above_the_tree"]
+)
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, np.float32], ids=["bfloat16", "float32"])
 @pytest.mark.parametrize("kind", sorted(LAYOUTS))
 def test_sharded_leaves_go_from_their_shards_into_the_bucket(
     lighthouse_addr, monkeypatch, kind, dtype, cap, kept_set
 ) -> None:
+    """The two small caps cut INSIDE the leaves (ISSUE 46): 64 bytes is a row
+    of ``axis_0`` or a part of a row of the others, so a piece lies in one
+    chip's shard or, cut along the sharded axis's rows, in both; 256 bytes is
+    some rows, and a piece of ``axis_0`` straddles the two shards."""
     monkeypatch.setenv(BUCKET_CAP_MB_ENV, str(cap / (1 << 20)))
     p = _Pair(lighthouse_addr)
     try:
@@ -719,7 +945,22 @@ def test_sharded_leaves_go_from_their_shards_into_the_bucket(
             syncs = [_syncs(m)[-1] for m in p.managers]
             n = syncs[0]["buckets"]
             assert [e["warm_buckets"] for e in syncs] == [n if step else 0] * 2
-            assert n == (len(host) if cap == 64 else len({np.asarray(v).dtype for v in host.values()}))
+            # a jax leaf over the cap is as many buckets as it has pieces; at
+            # 64 bytes every other leaf is a bucket by itself
+            over = {
+                name: ddp._pieces(value.shape, value.dtype.itemsize, cap)
+                for name, value in host.items()
+                if name not in ("host", "scalar") and value.nbytes > cap
+            }
+            if cap == 64:
+                assert set(over) == {"a_kind", "b_axis_1"}
+                assert n == len(host) - len(over) + sum(len(pieces) for pieces in over.values())
+            elif cap == 1 << 20:
+                assert not over and n == len({np.asarray(v).dtype for v in host.values()})
+            else:
+                assert "a_kind" in over or host["a_kind"].nbytes == 240
+            assert [e["split_bytes"] for e in syncs] == [sum(host[name].nbytes for name in over)] * 2
+            assert max(c.nbytes for c in copies[0]) <= cap
         # (a) the averages: the parent's formula on the HOST values, in both
         # groups (c: the fsdp-2 group and the one-chip group averaged the same
         # elements), bit for bit, each leaf back in its own type and layout
@@ -732,9 +973,21 @@ def test_sharded_leaves_go_from_their_shards_into_the_bucket(
         # (b) the wire: both groups handed the same bytes, and every leaf lies
         # in them whole and row-major
         assert [c.tobytes() for c in copies[0]] == [c.tobytes() for c in copies[1]]
-        wire = b"".join(c.tobytes() for c in copies[0])
+        plans = [_newest_plan(m) for m in p.managers]
+        wire = b"".join(_buffers_of(plans[0], copies[0]))
+        assert wire == b"".join(_buffers_of(plans[1], copies[1]))
         for name, value in host.items():
             assert np.asarray(value).tobytes() in wire, name
+        # both groups cut the wire at the same places, whatever lies where
+        places = [[(b.buffer, b.offset, b.size, b.piece and b.piece.shape) for b in plan.buckets] for plan in plans]
+        assert places[0] == places[1]
+        if direct and over:
+            # ... and found the pieces on their own chips: the group's in its
+            # two shards, the one-chip group's in one
+            sources = [[len(b.piece.sources) for b in plan.buckets if b.piece is not None] for plan in plans]
+            assert set(sources[1]) == {1} and set(sources[0]) <= {1, 2}
+            if (kind, cap, dtype) == ("axis_0", 256, np.float32):
+                assert {1, 2} == set(sources[0])  # rows 28:37 lie on both chips
         assert sum(c.nbytes for c in copies[0]) == syncs[0]["bytes"] == syncs[1]["bytes"]
         # the counter: the bytes of the leaves that lie in shards, and of no other
         assert syncs[0]["direct_bytes"] == sum(host[name].nbytes for name in direct) > 0
@@ -762,8 +1015,129 @@ def test_sharded_leaves_go_from_their_shards_into_the_bucket(
         for out, parent in zip(outs, parents):
             for g, w in zip(*map(jax.tree_util.tree_leaves, (out, parent))):
                 assert _bits(g) == _bits(w)
-        # and there the whole leaf WAS made on the host: (d) can fail
+        # and there the whole leaf WAS made on the host: (d) can fail (a leaf
+        # over the cap is whole on the host on neither path: its pieces are)
         for name in direct:
-            assert again[0][name]._npy_value is not None, name
+            assert (again[0][name]._npy_value is None) == (name in over), name
     finally:
         p.shutdown()
+
+
+# ----------------------------------------------------------------------
+# what is never cut, the round trip under stress, and the Manager's end (ISSUE 46)
+# ----------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("cap", [64, 256, 1000])
+def test_numpy_and_multi_host_leaves_stay_whole(solo, monkeypatch, cap) -> None:
+    """Only a fully addressable jax leaf over the cap is cut: a numpy leaf and
+    a leaf of a group that spans hosts (this host's shards in ``segments``)
+    are one bucket each, over the cap as they are, beside the pieces of the
+    leaf that is cut."""
+    from jax._src.array import ArrayImpl
+
+    rng = np.random.default_rng(86)
+    host = {name: rng.standard_normal((32, 8)).astype(np.float32) for name in ("cut", "multi", "numpy")}
+    tree = {
+        "cut": _put(host["cut"], 0, jax.devices()[:2]),
+        "multi": _put(host["multi"], 0, jax.devices()[:2]),
+        "numpy": host["numpy"],
+    }
+    spans_hosts = {id(tree["multi"])}
+    whole = ArrayImpl.is_fully_addressable
+    monkeypatch.setattr(
+        ArrayImpl, "is_fully_addressable", property(lambda self: id(self) not in spans_hosts and whole.fget(self))
+    )
+    assert not tree["multi"].is_fully_addressable and tree["cut"].is_fully_addressable
+    monkeypatch.setenv(BUCKET_CAP_MB_ENV, str(cap / (1 << 20)))
+    out = solo.step(tree)
+    plan = _newest_plan(solo.manager)
+    by_leaf = {name: [b for b in plan.buckets if b.slots[0].index == i] for i, name in enumerate(sorted(tree))}
+    assert [len(by_leaf[name]) for name in ("multi", "numpy")] == [1, 1]
+    assert by_leaf["multi"][0].slots[0].segments is not None and by_leaf["multi"][0].piece is None
+    assert by_leaf["multi"][0].nbytes == by_leaf["numpy"][0].nbytes == 1024 > cap
+    pieces = ddp._pieces((32, 8), 4, cap)
+    assert len(by_leaf["cut"]) == len(pieces) > 1 and all(b.piece is not None for b in by_leaf["cut"])
+    assert _syncs(solo.manager)[-1]["split_bytes"] == 1024 and plan.nbytes == 3 * 1024
+    # the stub quorum counts two participants: every leaf comes back halved, in its own type
+    for name, value in host.items():
+        assert _bits(out[name]) == _bits(_div(value, 2)), name
+    assert isinstance(out["numpy"], np.ndarray) and out["multi"].sharding == tree["multi"].sharding
+
+
+def test_two_hundred_round_trips_through_the_real_ring_with_cut_leaves(lighthouse_addr, monkeypatch) -> None:
+    """The path the cells run, at a size of seconds: two thread replicas, a
+    group of two chips with its leaves in shards and a group of one, leaves
+    over the cap rung piece by piece in place in views of one kept buffer
+    while the train thread packs the next ones.  Every step both replicas
+    hold the same bits, from the third on in kept memory, and at the end
+    every set that was made is back in its store."""
+    monkeypatch.setenv(BUCKET_CAP_MB_ENV, str(1024 / (1 << 20)))
+    monkeypatch.setattr(ddp, "_D2H_AHEAD_BYTES", 4096)
+    rng = np.random.default_rng(87)
+    devices = jax.devices()
+    p = _Pair(lighthouse_addr)
+    try:
+        cold = [0, 0]
+        for step in range(200):
+            host = {
+                "big": rng.standard_normal((64, 48)).astype(np.float32),  # twelve pieces
+                "stack": rng.standard_normal((2, 16, 64)).astype(np.float32).astype(jnp.bfloat16),  # four
+                "small": rng.standard_normal(9).astype(np.float32),
+                "host": rng.standard_normal((40, 8)).astype(np.float32),  # numpy: whole, over the cap
+            }
+            trees = [
+                {
+                    "big": _put(host["big"] + r, 0, devs),
+                    "stack": _put(host["stack"], 2, devs),
+                    "small": _put(host["small"] * (r + 1), None, devs),
+                    "host": host["host"] - r,
+                }
+                for r, devs in enumerate((devices[:2], devices[2:3]))
+            ]
+            outs = p.step(trees)
+            got = [[_bits(l) for l in jax.tree_util.tree_leaves(o)] for o in outs]
+            assert got[0] == got[1], step
+            if step % 25 == 0:
+                assert got[0] == [_bits(e) for e in _expected(trees, p.participating)], step
+            for r, m in enumerate(p.managers):
+                sync = _syncs(m)[-1]
+                assert sync["buckets"] == 19 and sync["split_bytes"] == 64 * 48 * 4 + 2 * 16 * 64 * 2
+                cold[r] += sync["warm_buckets"] == 0
+                assert sync["warm_buckets"] in (0, 19) and (step < 2 or sync["warm_buckets"] == 19), (step, r)
+        for r, m in enumerate(p.managers):
+            assert m.errored() is None
+            plan = _newest_plan(m)
+            assert 1 <= cold[r] == len(plan.free) <= ddp._KEPT_SETS
+            assert m._host_buckets.kept_bytes() == cold[r] * plan.nbytes
+    finally:
+        p.shutdown()
+
+
+@pytest.mark.parametrize("ends", [True, False], ids=["the_gather_ends", "the_gather_never_ends"])
+def test_a_manager_that_is_shut_down_waits_for_its_gather_threads(solo, ends) -> None:
+    """A round trip's gather thread is a daemon that lives on after its Work
+    is done: ``Manager.shutdown`` waits for it, so that the caller may drop
+    the runtime afterwards, and for no longer than the Manager's timeout."""
+    import time
+
+    def _alive() -> List[threading.Thread]:
+        return [t for t in threading.enumerate() if t.name == "tpuft_ddp_gather"]
+
+    solo.manager._timeout = 5.0 if ends else 0.3
+    solo.manager.start_quorum()
+    solo.comm.hold = True
+    work = allreduce_pytree(solo.manager, {"a": jnp.arange(64, dtype=jnp.float32)})
+    assert len(_alive()) == 1  # it waits for the ring
+    if ends:
+        threading.Timer(0.3, solo.comm.release).start()
+    t0 = time.monotonic()
+    solo.manager.shutdown()
+    waited = time.monotonic() - t0
+    if ends:
+        assert not _alive() and 0.25 <= waited < 4.0
+        np.testing.assert_array_equal(work.wait(timeout=1.0)["a"], np.arange(64, dtype=np.float32) / 2)
+    else:
+        assert len(_alive()) == 1 and 0.25 <= waited < 4.0
+        solo.comm.release()
+        _gathers_done()

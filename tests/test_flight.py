@@ -612,7 +612,7 @@ def drill():
         heartbeat_timeout_ms=1000,
     )
     saved_cap = os.environ.get("TORCHFT_BUCKET_CAP_MB")
-    os.environ["TORCHFT_BUCKET_CAP_MB"] = "0.25"  # a leaf a bucket
+    os.environ["TORCHFT_BUCKET_CAP_MB"] = "0.375"  # a leaf a bucket, none over the cap
     spans_mod.configure(True, cap=65536)
     spans_mod.clear()
     fleet = _Drill(lighthouse.local_address())

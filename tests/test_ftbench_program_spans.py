@@ -62,6 +62,11 @@ FILL_READERS = ("moe_buffer_fill_pct",)
 # PR 44: the share of a step's gradient bytes written from each chip's shard
 # straight into a bucket, from DDP_SYNC's direct_bytes; the four-chip cell
 DIRECT_READERS = ("d2h_direct_pct.hsdp",)
+# PR 46: the share of a step's gradient bytes that crossed as pieces of a leaf
+# over the bucket cap, from DDP_SYNC's split_bytes, and when the round trip's
+# second submit (the first large bucket's) begins; the two steady cells with
+# a replica dimension
+PIECE_READERS = ("d2h_split_pct", "sync_second_submit_ms")
 
 
 @pytest.mark.parametrize("name", sorted(LATER_READINGS))
@@ -77,7 +82,7 @@ def test_new_readers_are_the_eighteen_benchmark_json_lists():  # noqa: F811
         per_layer = json.load(f)["per_layer"]
     appended = (
         LATER_READINGS, LING_READERS, BUCKET_READERS, ORDER_READERS, INDEXED_READERS, SSM_READERS, AHEAD_READERS,
-        SCOPE_READERS, IN_RING_READERS, SWA_READERS, FILL_READERS, DIRECT_READERS,
+        SCOPE_READERS, IN_RING_READERS, SWA_READERS, FILL_READERS, DIRECT_READERS, PIECE_READERS,
     )
     later = sum(map(len, appended))
     assert [m["name"] for m in per_layer[-later:]] == [name for group in appended for name in group]
@@ -88,6 +93,9 @@ def test_new_readers_are_the_eighteen_benchmark_json_lists():  # noqa: F811
         cells = 4 if entry["name"] in EXPERT_CELLS + FILL_READERS else 3 if entry["name"] in FLASH_CELLS else 1
         if entry["name"] in SCOPE_READERS:  # the five one-replica cells, or the four that have the part
             cells = 4 if entry["name"] in ("xla_ffn_ms", "moe_route_ms", "moe_dispatch_ms") else 5
+        if entry["name"] in PIECE_READERS:
+            cells = 2
+            assert entry["workloads"] == ["mistral7b-ddp2-steady", theirs.HSDP_CELL]
         assert len(entry["workloads"]) == cells and set(entry) == {
             "name", "unit", "better", "source", "layer", "moves", "workloads",
         }
@@ -178,6 +186,78 @@ def test_d2h_direct_pct_is_the_four_chip_cells_alone():
     assert meta == dict(
         source="program_counter", layer="device-host boundary", unit="%", moves="ddp_tokens_per_s_per_chip"
     )
+
+
+def _split(t, split=None, total=973127680, name="DDP_SYNC"):
+    event = dict(name=name, t=t, bytes=total)
+    return event if split is None else dict(event, split_bytes=split)
+
+
+@pytest.mark.parametrize(
+    "events,expects",
+    [
+        # ddp2-steady at the cap of 32 MiB: embedding, head and the three stacked MLP matrices
+        ([_split(11.0, 889192448), _split(12.0, 889192448)], 100.0 * 889192448 / 973127680),
+        # hsdp2x2-steady: those and the two attention matrices of 67 MB
+        ([_split(11.0, 1375731712, 1409368064)], 100.0 * 1375731712 / 1409368064),
+        # a tree whose leaves all fit under the cap
+        ([_split(11.0, 0), _split(12.0, 0)], 0.0),
+        # the parent's events carry no such counter: a leaf over the cap was one bucket
+        ([_split(11.0), _split(12.0)], 0.0),
+        # events of other names, and of steps outside the window, do not count
+        ([_split(11.0, 7, name="MOE_ROUTE"), _split(30.0, 0), _split(12.0, 973127680)], 100.0),
+        ([], None),
+    ],
+    ids=["one_chip_groups", "two_chip_groups", "under_the_cap", "parent", "other_events", "no_events"],
+)
+def test_d2h_split_pct_on_synthetic_flight_events(events, expects):
+    read = spec.load_metric("d2h_split_pct", theirs.BENCH_DIR).read  # noqa: F405
+    window = [[dict(t_enter=10.0, t_exit=11.5), dict(t_enter=11.5, t_exit=20.0)], []]
+    assert read(dict(window=window, flight=[events, [_split(12.0, 5)]])) == expects
+    assert read(dict(window=window, flight=None)) is None
+    assert read(dict(window=[[], []], flight=[events, []])) is None
+
+
+@pytest.mark.parametrize(
+    "submits,expects",
+    [
+        # two round trips of replica 0: the second submit 0.25 and 0.35 s in
+        ({1: [0.09, 0.25, 0.4], 2: [0.1, 0.35]}, 300.0),
+        # a round trip with one submit (one bucket) has no second; the other counts
+        ({1: [0.09], 2: [0.1, 0.5, 0.6]}, 500.0),
+        ({1: [0.09], 2: []}, None),
+    ],
+    ids=["two_trips", "a_trip_of_one_bucket", "no_second_submit"],
+)
+def test_sync_second_submit_ms_on_synthetic_spans(submits, expects, monkeypatch):
+    from ftbench import program_spans
+
+    spans = []
+    for step, offsets in submits.items():
+        t0 = 10.0 * step
+        spans.append(dict(name=program_spans.SYNC, start=t0, end=t0 + 2.0, r=0, step=step))
+        spans += [dict(name="tpuft/ddp/submit", start=t0 + o, end=t0 + o + 0.001, r=0, step=step) for o in offsets]
+        # the other replica's submits lie inside the same seconds and do not count
+        spans.append(dict(name="tpuft/ddp/submit", start=t0 + 0.01, end=t0 + 0.02, r=1, step=step))
+    mine = [s for s in spans if s["r"] == 0]
+    monkeypatch.setattr(program_spans, "in_stretch", lambda sources, replica=0, spans=None: (mine, len(submits)))
+    read = spec.load_metric("sync_second_submit_ms", theirs.BENCH_DIR).read  # noqa: F405
+    got = read({})
+    assert got is None if expects is None else abs(got - expects) < 1e-6
+    monkeypatch.setattr(program_spans, "in_stretch", lambda sources, replica=0, spans=None: None)
+    assert read({}) is None
+
+
+@pytest.mark.parametrize("name,source,unit,better", [
+    ("d2h_split_pct", "program_counter", "%", "higher"), ("sync_second_submit_ms", "program_span", "ms", "lower"),
+])
+def test_the_piece_readers_are_their_entries_and_list_the_two_steady_cells(name, source, unit, better):
+    with open(os.path.join(theirs.ROOT, "BENCHMARK.json")) as f:
+        (entry,) = [m for m in json.load(f)["per_layer"] if m["name"] == name]
+    assert entry["workloads"] == ["mistral7b-ddp2-steady", theirs.HSDP_CELL] and entry["better"] == better
+    meta = spec.load_metric(name, theirs.BENCH_DIR).META  # noqa: F405
+    assert {k: entry[k] for k in meta} == meta
+    assert meta == dict(source=source, layer="device-host boundary", unit=unit, moves="ddp_tokens_per_s_per_chip")
 
 
 def _served(sources, ahead):

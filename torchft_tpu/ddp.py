@@ -10,12 +10,23 @@ shardings.  Compiled programs never see the replica count (SURVEY.md §7).
 
 The buckets cross to the host in an order made once per tree signature
 (:func:`_pipeline_order`: the smallest first, then by falling size) and a
-few at a time (``_D2H_AHEAD``): a bucket's ring is submitted as soon as it
-has landed and runs on the communicator's op thread while the next buckets
-still cross, so transfer and ring are two stages of a pipeline and not two
-stretches in a row.  The order shapes the collective sequence, so like the
-bucket cap it must agree across replicas; it follows from the tree
-signature and the cap alone.
+window of bytes at a time (``_D2H_AHEAD_BYTES``): a bucket's ring is
+submitted as soon as it has landed and runs on the communicator's op thread
+while the next buckets still cross, so transfer and ring are two stages of a
+pipeline and not two stretches in a row.  The order shapes the collective
+sequence, so like the bucket cap it must agree across replicas; it follows
+from the tree signature and the cap alone.
+
+The cap is also the most ONE transfer and ONE ring carry: a leaf over it
+crosses in pieces (:func:`_pieces`), contiguous ranges of its row-major
+order that follow from its shape, its dtype and the cap alone, each a
+bucket with a ring of its own.  A piece is sliced on the device that holds
+it (from each unique shard, its part of the piece) and lands as a transfer
+of its own, so the first ring starts after a cap's worth has landed and not
+after the largest leaf has, and the window holds many transfers where it
+held two.  The leaf's host memory stays one flat array whose parts the
+pieces are, and the leaf goes back to the device in one ``device_put``
+after the last of its rings.
 
 A leaf that lies in shards on several chips of THIS process (a replica
 group on one multi-chip host: fully addressable, more than one unique shard)
@@ -42,8 +53,9 @@ import dataclasses
 import functools
 import os
 import threading
+import time
 from concurrent.futures import Future
-from typing import Any, Dict, Hashable, List, Optional, Tuple
+from typing import Any, Dict, Hashable, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -61,6 +73,8 @@ from torchft_tpu.work import DummyWork, Work
 
 # Split gradient buckets at this size (reference: TORCHFT_USE_BUCKETIZATION /
 # bucket_cap_mb, ``local_sgd.py:28``); pipelines D2H transfer with the rings.
+# Between leaves, and inside a leaf that is over it (:func:`_pieces`): the
+# most one device-to-host transfer and one ring carry.
 # MUST be uniform across replicas: bucket boundaries shape the collective
 # sequence (mismatches fail fast via the ring's frame-size validation, like
 # the reference's frozen DDP bucket layout requirement, ``ddp.py:46-62``).
@@ -69,8 +83,15 @@ from torchft_tpu.work import DummyWork, Work
 # across replicas that agree on the env), while tests can flip the env to
 # exercise bucket boundaries without re-importing the module.  Malformed
 # values fall back to the default rather than raising into the train loop.
+# 16 and not the 32 of before PR 46: jax lands every transfer in a host
+# array made anew, and glibc serves a request of 32 MiB or more (its mmap
+# threshold's ceiling) from pages mapped anew, every one of them touched for
+# the first time, where a smaller one comes back from the arena that the step
+# before freed it to.  On the v5e's host 973 MB in pieces of 32 MiB land in
+# 880 ms, in pieces of 8 to 31 MiB in 220-300 ms, and 16 is the round number
+# with room on both sides (PERF.md section 6, PR 46: the readings).
 BUCKET_CAP_MB_ENV = "TORCHFT_BUCKET_CAP_MB"
-DEFAULT_BUCKET_CAP_MB = 32
+DEFAULT_BUCKET_CAP_MB = 16
 
 
 @functools.lru_cache(maxsize=None)
@@ -144,15 +165,23 @@ def _assemble_sharded(
 # does not own two under ``quantize_outer``; streamed LocalSGD one a fragment.
 _KEPT_SIGNATURES = 4
 _KEPT_SETS = 2
-# A bucket's device-to-host copies are started only while the train thread
-# waits for a bucket at most this many places before it (itself counted): the
-# one it waits for and the next.  With every leaf's copy started at once the
-# runtime lands them all together and the rings wait for the whole gradient;
-# one at a time, a transfer by itself runs at two thirds of the speed of two
-# side by side; with three the first large bucket lands later than with two
-# and the op thread's whole work follows that landing (PERF.md section 6,
-# PR 32: the readings that chose 2).
-_D2H_AHEAD = 2
+# The device-to-host copies that are under way hold at most this many bytes
+# past the bucket the train thread waits for: that bucket's, and the next
+# ones' until the sum passes the constant; no later bucket's copy is started.
+# With every leaf's copy started at once the runtime lands them all together
+# and the rings wait for the whole gradient; a transfer by itself runs at two
+# thirds of the speed of two side by side, and more side by side land faster
+# still (PERF.md section 6, PR 32).  Since a bucket is at most the cap (a
+# leaf over it crosses in pieces) the window holds several transfers and
+# still hands over its first after one cap's worth.  64 MiB and 128 MiB read
+# the same round trip wherever the ring is in it (1,246 against 1,228 ms on
+# four chips, 995 against 995 on one), 256 and 512 MiB hand the first large
+# bucket over 15 and 55 ms later and gain nothing on the whole (PERF.md
+# section 6, PR 46).  So it is the smaller: it is what a replica's slices of
+# pieces in flight may hold of its device's memory, beside one cap more, and
+# half as many transfers are in flight whose sources die as they land, with
+# a signal 11 on record that nobody has seen twice (PERF.md section 7 (bi)).
+_D2H_AHEAD_BYTES = 64 << 20
 
 
 @dataclasses.dataclass(frozen=True)
@@ -187,23 +216,60 @@ class _Slot:
     direct: Optional[Tuple[Any, ...]] = None  # see :func:`_direct_indices`
 
 
+class _Source(NamedTuple):
+    """The part of a piece that one unique shard holds."""
+
+    place: int  # the shard's, in :func:`_unique_local_shards`' order
+    starts: np.ndarray  # where the part starts in the shard (int32: :func:`_device_slice`'s operand)
+    sizes: Tuple[int, ...]  # its shape
+    where: Tuple[slice, ...]  # where it lies in the piece
+
+
+@dataclasses.dataclass(frozen=True)
+class _Piece:
+    """One bucket's part of a leaf over the cap: a contiguous range of the
+    leaf's row-major order (:func:`_pieces`), and where this process finds it
+    on its devices."""
+
+    shape: Tuple[int, ...]  # the leaf's rank: 1 on the fixed axes, the range, the rest whole
+    sources: Tuple[_Source, ...]
+
+
 @dataclasses.dataclass
 class _Bucket:
     dtype: Any
     size: int  # elements
     slots: List[_Slot]
+    buffer: int = 0  # which of the plan's host buffers holds it
+    offset: int = 0  # in elements, from that buffer's start
+    piece: Optional[_Piece] = None  # of ``slots[0]``'s leaf, whose buffer is the whole leaf
+    last: bool = True  # of its buffer's buckets in the plan's order: restores the slots
+
+    @property
+    def nbytes(self) -> int:
+        return self.size * self.dtype.itemsize
+
+    @property
+    def crossing(self) -> int:
+        """The bytes that come from a device: what the window counts."""
+        if self.piece is not None:
+            return self.nbytes
+        return sum(s.size for s in self.slots if s.sharding is not None) * self.dtype.itemsize
 
 
 @dataclasses.dataclass
 class _Plan:
     """One tree signature's buckets in the order they cross (see
-    :func:`_pipeline_order`), and the sets of flat host buffers (one a bucket,
-    in that order) that were filled before and that nothing reads or writes
+    :func:`_pipeline_order`), the flat host buffers they lie in (a bucket's
+    own, or for the pieces of a leaf over the cap the leaf's), and the sets
+    of such buffers that were filled before and that nothing reads or writes
     now."""
 
     buckets: List[_Bucket]
+    buffers: List[Tuple[Any, int]]  # dtype, elements
     nbytes: int  # what crosses the wire a round trip
     direct_nbytes: int  # of them, written from shards straight into a bucket
+    split_nbytes: int  # of them, crossed as pieces of a leaf over the cap
     free: List[List[np.ndarray]] = dataclasses.field(default_factory=list)
 
 
@@ -219,12 +285,15 @@ class _BucketStore:
     without error calls, after its restored leaves are ready: a ring that
     failed or never ended may still write into its buffers, and they are
     never handed out again.  Lives as long as its Manager (a new life starts
-    cold, as a restarted process does).
+    cold, as a restarted process does), and so do the round trips' gather
+    threads, which give the sets back: ``Manager.shutdown`` waits for them
+    (:meth:`join`).
     """
 
     def __init__(self) -> None:
         self._lock = threading.Lock()
         self._plans: "collections.OrderedDict[Hashable, _Plan]" = collections.OrderedDict()
+        self._gathers: List[threading.Thread] = []  # started and not seen ended
 
     def plan(self, signature: Hashable, leaves: List[Any], bucket_cap: int) -> _Plan:
         with self._lock:
@@ -247,6 +316,21 @@ class _BucketStore:
         with self._lock:
             if len(plan.free) < _KEPT_SETS:
                 plan.free.append(buffers)
+
+    def started(self, gather: threading.Thread) -> None:
+        with self._lock:
+            self._gathers = [t for t in self._gathers if t.is_alive()] + [gather]
+
+    def join(self, timeout: float) -> None:
+        """Wait, ``timeout`` seconds in all, for the gather threads that are
+        still at work.  One lives on after its Work is done (it waits for the
+        restored leaves and gives the set back) and is a daemon: past its
+        Manager's end the interpreter could take the runtime down under it."""
+        deadline = time.monotonic() + timeout
+        with self._lock:
+            gathers, self._gathers = self._gathers, []
+        for gather in gathers:
+            gather.join(max(0.0, deadline - time.monotonic()))
 
     def kept_bytes(self) -> int:
         with self._lock:
@@ -294,16 +378,68 @@ def _pipeline_order(nbytes: List[int]) -> List[int]:
     return falling[-1:] + falling[:-1]
 
 
+def _pieces(shape: Tuple[int, ...], itemsize: int, cap: int) -> List[Tuple[Tuple[Any, ...], int, int]]:
+    """A leaf of ``shape`` cut into contiguous ranges of its row-major order
+    of at most ``cap`` bytes each: ``(index, start, size)``, the numpy index
+    of the range in the leaf (fixed indices of the leading axes, then a slice
+    of ONE axis), where it starts and how many elements it holds.
+
+    A pure function of the shape, the element size and the cap, never of how
+    the leaf lies on any replica's chips: every replica cuts the wire at the
+    same places.  The cut axis is the first whose single index fits the cap
+    (rows ``a:b`` of a ``[rows, columns]`` matrix, ``[i, a:b, :]`` of a stack
+    of them); its ranges are as many as the cap asks for and as equal as
+    they can be, so a leaf's pieces sort side by side in
+    :func:`_pipeline_order`.  A piece is over the cap only where one element
+    is."""
+    inner = [int(np.prod(shape[k + 1 :], dtype=np.int64)) for k in range(len(shape))]
+    axis = next((k for k, n in enumerate(inner) if n * itemsize <= cap), len(shape) - 1)
+    rows, row = shape[axis], inner[axis]
+    count = -(-rows // max(1, cap // (row * itemsize)))
+    base, extra = divmod(rows, count)
+    out = []
+    for at, lead in enumerate(np.ndindex(*shape[:axis])):
+        a = 0
+        for p in range(count):
+            b = a + base + (p < extra)
+            out.append((lead + (slice(a, b),), (at * rows + a) * row, (b - a) * row))
+            a = b
+    return out
+
+
+def _piece_of(leaf: Any, shape: Tuple[int, ...], index: Tuple[Any, ...]) -> _Piece:
+    """The piece ``index`` of ``leaf`` (see :func:`_pieces`) as this process
+    finds it: each unique shard's intersection with it."""
+    lead, cut = index[:-1], index[-1]
+    box = [(i, i + 1) for i in lead] + [(cut.start, cut.stop)] + [(0, n) for n in shape[len(index) :]]
+    sources = []
+    for place, shard in enumerate(_unique_local_shards(leaf).values()):
+        spans = [s.indices(n)[:2] for s, n in zip(shard.index, shape)]
+        both = [(max(p0, s0), min(p1, s1)) for (p0, p1), (s0, s1) in zip(box, spans)]
+        if all(lo < hi for lo, hi in both):
+            sources.append(
+                _Source(
+                    place,
+                    np.asarray([lo - s0 for (lo, _), (s0, _) in zip(both, spans)], np.int32),
+                    tuple(hi - lo for lo, hi in both),
+                    tuple(slice(lo - p0, hi - p0) for (lo, hi), (p0, _) in zip(both, box)),
+                )
+            )
+    return _Piece(shape=tuple(p1 - p0 for p0, p1 in box), sources=tuple(sources))
+
+
 def _make_plan(leaves: List[Any], bucket_cap: int) -> _Plan:
     """Bucket by dtype (each dtype needs its own ring), then split large
-    buckets at ``bucket_cap`` bytes, and put the buckets in
-    :func:`_pipeline_order`.  Each is submitted as its own collective as soon
-    as its leaves have landed and are packed, while the copies of the next
-    ``_D2H_AHEAD - 1`` are under way and the later ones not yet started: the
-    op thread rings bucket k while bucket k+1 crosses — transfer /
-    communication pipelining, the reference's bucket_cap_mb
-    (``local_sgd.py:28,477-566``) in jax form.  The order, like the cap, shapes
-    the collective sequence and follows from the tree signature alone."""
+    buckets at ``bucket_cap`` bytes, between leaves and inside a leaf that is
+    over it (:func:`_pieces`; a numpy leaf and a leaf that is not fully
+    addressable stay whole), and put the buckets in :func:`_pipeline_order`.
+    Each is submitted as its own collective as soon as it has landed and is
+    packed, while the copies of the next ``_D2H_AHEAD_BYTES`` are under way
+    and the later ones not yet started: the op thread rings bucket k while
+    bucket k+1 crosses — transfer / communication pipelining, the
+    reference's bucket_cap_mb (``local_sgd.py:28,477-566``) in jax form.  The
+    order, like the cap, shapes the collective sequence and follows from the
+    tree signature alone."""
     order: Dict[str, List[int]] = {}
     described: List[Tuple[Any, int, Tuple[int, ...], Any]] = []
     for i, leaf in enumerate(leaves):
@@ -324,50 +460,94 @@ def _make_plan(leaves: List[Any], bucket_cap: int) -> _Plan:
         order.setdefault(dtype.name, []).append(i)
 
     buckets: List[_Bucket] = []
+    buffers: List[Tuple[Any, int]] = []
+    direct_nbytes = split_nbytes = 0
+
+    def _close(bucket: _Bucket) -> None:
+        bucket.buffer = len(buffers)
+        buffers.append((bucket.dtype, bucket.size))
+        buckets.append(bucket)
+
     for idxs in order.values():
         dtype = described[idxs[0]][0]
         bucket = _Bucket(dtype, 0, [])
         for i in idxs:
             _dtype, size, shape, segments = described[i]
-            if bucket.slots and (bucket.size + size) * dtype.itemsize > bucket_cap:
-                buckets.append(bucket)
-                bucket = _Bucket(dtype, 0, [])
             leaf = leaves[i]
             sharding = leaf.sharding if isinstance(leaf, jax.Array) else None
-            bucket.slots.append(
-                _Slot(
-                    index=i,
-                    offset=bucket.size,
-                    size=size,
-                    shape=shape,
-                    dtype=dtype,
-                    sharding=sharding,
-                    host_backed=sharding is not None
-                    and any(d.platform == "cpu" for d in sharding.device_set),
-                    segments=segments,
-                    direct=_direct_indices(leaf),
-                )
+            # one transfer and one ring carry the cap at most
+            split = bool(shape) and sharding is not None and segments is None and size * dtype.itemsize > bucket_cap
+            if bucket.slots and (split or (bucket.size + size) * dtype.itemsize > bucket_cap):
+                _close(bucket)
+                bucket = _Bucket(dtype, 0, [])
+            slot = _Slot(
+                index=i,
+                offset=bucket.size,
+                size=size,
+                shape=shape,
+                dtype=dtype,
+                sharding=sharding,
+                host_backed=sharding is not None
+                and any(d.platform == "cpu" for d in sharding.device_set),
+                segments=segments,
+                direct=_direct_indices(leaf),
             )
-            bucket.size += size
-        buckets.append(bucket)
-    order = _pipeline_order([b.size * b.dtype.itemsize for b in buckets])
-    buckets = [buckets[b] for b in order]
+            direct_nbytes += size * dtype.itemsize if slot.direct is not None else 0
+            if not split:
+                bucket.slots.append(slot)
+                bucket.size += size
+                continue
+            # the leaf's buffer is the whole leaf; its pieces are parts of it
+            split_nbytes += size * dtype.itemsize
+            for index, start, count in _pieces(shape, dtype.itemsize, bucket_cap):
+                buckets.append(
+                    _Bucket(
+                        dtype, count, [slot], buffer=len(buffers), offset=start,
+                        piece=_piece_of(leaf, shape, index),
+                    )
+                )
+            buffers.append((dtype, size))
+        if bucket.slots:
+            _close(bucket)
+    buckets = [buckets[b] for b in _pipeline_order([b.nbytes for b in buckets])]
+    ends = {bucket.buffer: at for at, bucket in enumerate(buckets)}
+    for at, bucket in enumerate(buckets):
+        bucket.last = ends[bucket.buffer] == at
     return _Plan(
         buckets=buckets,
-        nbytes=sum(b.size * b.dtype.itemsize for b in buckets),
-        direct_nbytes=sum(
-            s.size * b.dtype.itemsize for b in buckets for s in b.slots if s.direct is not None
-        ),
+        buffers=buffers,
+        nbytes=sum(b.nbytes for b in buckets),
+        direct_nbytes=direct_nbytes,
+        split_nbytes=split_nbytes,
     )
 
 
-def _start_copies(leaves: List[Any], bucket: _Bucket) -> None:
-    """Start the device-to-host copies of one bucket's leaves (on a leaf that
-    is not fully addressable: of its addressable shards, which are what
-    :func:`_to_host` reads)."""
-    for slot in bucket.slots:
-        if slot.sharding is not None:
-            leaves[slot.index].copy_to_host_async()
+@functools.partial(jax.jit, static_argnames=("sizes",))
+def _device_slice(block: jax.Array, starts: Any, sizes: Tuple[int, ...]) -> jax.Array:
+    """``block[starts : starts + sizes]`` on the ONE device that holds
+    ``block``; the start is an operand, so a leaf's pieces share a program."""
+    return jax.lax.dynamic_slice(block, [starts[k] for k in range(len(sizes))], sizes)
+
+
+def _start_copies(leaves: List[Any], bucket: _Bucket) -> List[Any]:
+    """Start the device-to-host copies of one bucket (on a leaf that is not
+    fully addressable: of its addressable shards, which are what
+    :func:`_to_host` reads).  A piece's parts are sliced here, each on the
+    device of the shard it lies in (a slice of the leaf itself would be a
+    program over the group's mesh), and returned: device memory beside the
+    gradients until they have landed."""
+    if bucket.piece is None:
+        arrays = [leaves[slot.index] for slot in bucket.slots if slot.sharding is not None]
+    else:
+        shards = [s.data for s in _unique_local_shards(leaves[bucket.slots[0].index]).values()]
+        arrays = [
+            shards[src.place] if src.sizes == shards[src.place].shape
+            else _device_slice(shards[src.place], src.starts, src.sizes)
+            for src in bucket.piece.sources
+        ]
+    for array in arrays:
+        array.copy_to_host_async()
+    return arrays
 
 
 def _to_host(leaf: Any, slot: _Slot) -> List[np.ndarray]:
@@ -384,6 +564,35 @@ def _to_host(leaf: Any, slot: _Slot) -> List[np.ndarray]:
         return [np.asarray(leaf).reshape(-1)]
     shards = _unique_local_shards(leaf)
     return [np.asarray(shards[key].data).reshape(-1) for key in slot.segments]
+
+
+def _land(leaves: List[Any], bucket: _Bucket, crossing: List[Any]) -> List[List[np.ndarray]]:
+    """Wait for one bucket's copies (``crossing``: what :func:`_start_copies`
+    returned for it): its host values, a list a slot, or the parts of its
+    piece.  Sharded leaves contribute local shards only."""
+    if bucket.piece is not None:
+        return [[np.asarray(array) for array in crossing]]
+    return [_to_host(leaves[slot.index], slot) for slot in bucket.slots]
+
+
+def _pack(bucket: _Bucket, flat: np.ndarray, hosts: List[List[np.ndarray]]) -> None:
+    """Write one bucket's landed host values (a list a slot, or the parts of
+    a piece) into ``flat``, its part of a host buffer."""
+    if bucket.piece is not None:
+        part = flat.reshape(bucket.piece.shape)
+        for src, block in zip(bucket.piece.sources, hosts[0]):
+            part[src.where] = block
+        return
+    for slot, parts in zip(bucket.slots, hosts):
+        if slot.direct is not None:
+            whole = flat[slot.offset : slot.offset + slot.size].reshape(slot.shape)
+            for index, block in zip(slot.direct, parts):
+                whole[index] = block
+            continue
+        off = slot.offset
+        for arr in parts:
+            flat[off : off + arr.size] = arr
+            off += arr.size
 
 
 def _restore(leaf: Any, slot: _Slot, avg_flat: np.ndarray, aliased: bool) -> Any:
@@ -421,12 +630,14 @@ def allreduce_pytree(
     original sharding).  Error swallowing and participation zeroing happen
     inside ``manager.allreduce``.
 
-    The leaves are packed into flat host buckets, a ring each.  ``manager``
-    keeps the plan and the buckets of a tree whose signature comes again
-    (tree structure, each leaf's shape, dtype and sharding, the bucket cap)
-    for its life (:class:`_BucketStore`): host memory of the size of the
-    gradients it averages (this host's share), written every step and
-    allocated once.  The Work's value never aliases them.
+    The leaves are packed into flat host buckets of the cap's size at most,
+    a ring each; a leaf over the cap is several of them, the parts of one
+    buffer (:func:`_pieces`).  ``manager`` keeps the plan and the buffers of
+    a tree whose signature comes again (tree structure, each leaf's shape,
+    dtype and sharding, the bucket cap) for its life (:class:`_BucketStore`):
+    host memory of the size of the gradients it averages (this host's share),
+    written every step and allocated once.  The Work's value never aliases
+    them.
 
     ``stream``, when given, marks this as an ASYNC streamed fragment submit
     (the TORCHFT_STREAM_SYNC LocalSGD scheduler): exactly one work — the
@@ -479,7 +690,8 @@ def allreduce_pytree(
 
     store = _bucket_store(manager)
     works: List[Work] = []
-    buffers: List[np.ndarray] = []
+    flats: List[np.ndarray] = []  # each bucket's part of its host buffer, in the plan's order
+    buffers: List[Any] = []  # the plan's host buffers: a kept set, or made as the buckets come
     kept: Optional[List[np.ndarray]] = None
     try:
         with obs_span("tpuft/ddp/plan") as stage:
@@ -491,16 +703,21 @@ def allreduce_pytree(
             )
         stage_s["plan_s"] = stage.duration_s
         asked = 0  # buckets whose copies to the host have been started
+        ahead = 0  # the bytes of them that have not landed
+        flying: Dict[int, List[Any]] = {}  # what crosses, by bucket: a piece's slices live here alone
         for b, bucket in enumerate(plan.buckets):
             with obs_span("tpuft/ddp/d2h", bucket=b) as stage:
-                # this bucket's copies and the next's are under way, no later
-                # one's: they land in the plan's order, and bucket b's ring
-                # runs on the op thread while buckets b+1 .. still cross
-                while asked < min(b + _D2H_AHEAD, len(plan.buckets)):
-                    _start_copies(leaves, plan.buckets[asked])
+                # this bucket's copies are under way and the next ones' until
+                # their bytes pass the window, no later one's: they land in
+                # the plan's order, and bucket b's ring runs on the op thread
+                # while buckets b+1 .. still cross
+                while asked < len(plan.buckets) and (asked <= b or ahead < _D2H_AHEAD_BYTES):
+                    flying[asked] = _start_copies(leaves, plan.buckets[asked])
+                    ahead += plan.buckets[asked].crossing
                     asked += 1
-                # waits for the copies; sharded leaves contribute local shards only
-                hosts = [_to_host(leaves[slot.index], slot) for slot in bucket.slots]
+                # waits for the copies; a piece's slices leave the device here
+                hosts = _land(leaves, bucket, flying.pop(b))
+                ahead -= bucket.crossing
             stage_s["d2h_s"] += stage.duration_s
             with obs_span("tpuft/ddp/pack", bucket=b) as stage:
                 if b == 0:
@@ -516,19 +733,14 @@ def allreduce_pytree(
                     # cold and hold a second set (``_KEPT_SETS`` is 2: the
                     # tree's size in host memory once more) from then on.
                     kept = store.take(plan)
-                flat = np.empty(bucket.size, dtype=bucket.dtype) if kept is None else kept[b]
-                for slot, parts in zip(bucket.slots, hosts):
-                    if slot.direct is not None:
-                        whole = flat[slot.offset : slot.offset + slot.size].reshape(slot.shape)
-                        for index, block in zip(slot.direct, parts):
-                            whole[index] = block
-                        continue
-                    off = slot.offset
-                    for arr in parts:
-                        flat[off : off + arr.size] = arr
-                        off += arr.size
+                    buffers = kept if kept is not None else [None] * len(plan.buffers)
+                if buffers[bucket.buffer] is None:
+                    dtype, size = plan.buffers[bucket.buffer]
+                    buffers[bucket.buffer] = np.empty(size, dtype=dtype)
+                flat = buffers[bucket.buffer][bucket.offset : bucket.offset + bucket.size]
+                _pack(bucket, flat, hosts)
             stage_s["pack_s"] += stage.duration_s
-            buffers.append(flat)
+            flats.append(flat)
             # submit immediately: this bucket's ring overlaps the next
             # bucket's fetch/assembly; in_place — the bucket is ours until
             # the restore is done, so the ring reduces straight into it (no
@@ -556,10 +768,17 @@ def allreduce_pytree(
                 flat = work.wait()
             stage_s["ring_wait_s"] += stage.duration_s
             with obs_span("tpuft/ddp/h2d", bucket=b) as stage:
-                aliased = np.may_share_memory(flat, buffers[b])
-                for slot in bucket.slots:
-                    avg = flat[slot.offset : slot.offset + slot.size]
-                    out[slot.index] = _restore(leaves[slot.index], slot, avg, aliased)
+                aliased = np.may_share_memory(flat, flats[b])
+                if bucket.piece is not None:
+                    # the leaf goes back whole, from its buffer, after the
+                    # last of its pieces' rings
+                    if not aliased:
+                        flats[b][:] = flat
+                    flat, aliased = buffers[bucket.buffer], True
+                if bucket.last:
+                    for slot in bucket.slots:
+                        avg = flat[slot.offset : slot.offset + slot.size]
+                        out[slot.index] = _restore(leaves[slot.index], slot, avg, aliased)
             stage_s["h2d_s"] += stage.duration_s
         return out
 
@@ -579,6 +798,7 @@ def allreduce_pytree(
             warm_buckets=0 if kept is None else len(works),
             bytes=plan.nbytes,
             direct_bytes=plan.direct_nbytes,
+            split_bytes=plan.split_nbytes,
             **{k: round(v, 6) for k, v in stage_s.items()},
         )
         sync_span.__exit__()
@@ -601,9 +821,9 @@ def allreduce_pytree(
         store.give_back(plan, buffers)
 
     sync_span.detach()  # the gather thread carries the span from here
-    threading.Thread(
-        target=_finish, name="tpuft_ddp_gather", daemon=True
-    ).start()
+    gather = threading.Thread(target=_finish, name="tpuft_ddp_gather", daemon=True)
+    gather.start()
+    store.started(gather)
     out = Work(fut)
     # fence the WHOLE pipeline (including restore/device_put) at commit, not
     # just the wire collectives — a restore failure after the vote would

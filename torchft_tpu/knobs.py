@@ -126,8 +126,9 @@ _k("TORCHFT_LANE_RETRIES", "int", "2",
    "In-epoch re-dial attempts for a reset lane before failover to surviving lanes")
 _k("TORCHFT_LANE_BACKOFF_MS", "float", "50",
    "Base backoff between in-epoch lane re-dials (jittered exponential)")
-_k("TORCHFT_BUCKET_CAP_MB", "float", "32",
-   "Gradient bucket split size for DDP allreduce (must be uniform across replicas)")
+_k("TORCHFT_BUCKET_CAP_MB", "float", "16",
+   "Gradient bucket split size for DDP allreduce, between leaves and inside a leaf over it: "
+   "the most one device-to-host transfer and one ring carry (must be uniform across replicas)")
 _k("TORCHFT_BABY_SHM_MIN", "int", "262144",
    "Minimum payload bytes routed via the baby-process shared-memory ring")
 # --- data plane: quantization ----------------------------------------------

@@ -1843,6 +1843,11 @@ class Manager:
         if self._own_store is not None:
             self._own_store.shutdown()
         self._comm.shutdown()
+        if self._host_buckets is not None:
+            # the rings are aborted: the gather thread of a round trip that
+            # was under way ends, and one that was giving its set back has
+            # ended, before the caller goes on to drop the runtime under it
+            self._host_buckets.join(self._timeout)
         self._host_buckets = None
 
     # test-friendly logger attribute (mocked-client path sets it lazily)
