@@ -416,8 +416,14 @@ class Pacer {
 // _stripe_floor) and be uniform across ranks (verified in the rendezvous
 // hello).  "auto" resolves exactly like the Python tier: enough lanes that
 // the aggregate cwnd-limited stream rate reaches the emulated link rate
-// (capped at kMaxAutoLanes), 1 when unshaped.
+// (capped at kMaxAutoLanes); kUnshapedAutoLanes where no link is emulated
+// (or the emulator names no per-stream cap): one stream there moves at one
+// core's copy rate, which is what a step's rings were paced by (PERF.md
+// section 6, PR 47).  One constant and not a reading of this host's cores:
+// every rank must resolve the same count.
 constexpr size_t kMaxAutoLanes = 4;  // mirror of communicator._MAX_AUTO_LANES
+constexpr size_t kUnshapedAutoLanes =
+    4;  // mirror of communicator._UNSHAPED_AUTO_LANES
 constexpr size_t kMinStripeBytes =
     size_t(64) << 10;  // mirror of communicator._MIN_STRIPE_BYTES
 
@@ -428,7 +434,7 @@ inline size_t ring_lanes_from_env(const Pacer* pacer) {
     return n >= 1 ? static_cast<size_t>(n) : 1;
   }
   if (!pacer || pacer->stream_bytes_per_s() <= 0 || pacer->bytes_per_s() <= 0)
-    return 1;
+    return kUnshapedAutoLanes;
   size_t link = static_cast<size_t>(pacer->bytes_per_s());
   size_t stream =
       std::max<size_t>(1, static_cast<size_t>(pacer->stream_bytes_per_s()));

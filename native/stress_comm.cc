@@ -31,10 +31,13 @@
 //      while a second thread a rank (the train thread's pack) writes the
 //      pieces after the one in the ring into the same buffer; every piece
 //      verified.  89 pieces of 16 MiB are the four-chip cell's step.
+//      Runs at what ``auto`` resolves to with no link emulated
+//      (kUnshapedAutoLanes, PR 47): the count a deployment's rings run at.
 //
-// Runs at TORCHFT_RING_LANES=2 so the per-lane worker pool and the
-// lane-striped framing are engaged throughout; abort mid-striped-op is the
-// native tier's lane-failover story (every lane to the peer dies at once).
+// Phases A and B run at TORCHFT_RING_LANES=2 so the per-lane worker pool
+// and the lane-striped framing are engaged throughout; abort
+// mid-striped-op is the native tier's lane-failover story (every lane to
+// the peer dies at once).
 //
 // Exit 0 on success.  Sanitizer findings fail the run via halt_on_error
 // (CI sets TSAN_OPTIONS / ASAN_OPTIONS / UBSAN_OPTIONS).
@@ -330,14 +333,23 @@ int main(int argc, char** argv) {
   if (argc > 1 && std::string(argv[1]) == "pieces") {
     const size_t count = argc > 2 ? std::strtoul(argv[2], nullptr, 10) : 89;
     const size_t mib = argc > 3 ? std::strtoul(argv[3], nullptr, 10) : 16;
+    // the shipped lane count: auto, with no link emulated
+    ::unsetenv("TORCHFT_RING_LANES");
+    ::unsetenv("TORCHFT_NET_EMU");
     std::vector<std::thread> ranks;
     for (int r = 0; r < 2; ++r)
       ranks.emplace_back(phase_p_rank, comms[r].get(), r, addr, count,
                          (mib << 20) / 2);
     for (auto& t : ranks) t.join();
+    uint64_t tx[64], rx[64], stalls[64];
+    const size_t lanes = comms[0]->lane_stats(tx, rx, stalls, 64);
+    if (lanes != kUnshapedAutoLanes)
+      fail("phase P ran at " + std::to_string(lanes) + " lanes, auto is " +
+           std::to_string(kUnshapedAutoLanes));
     comms.clear();
     std::printf("stress_comm: phase P done (%zu pieces of %zu MiB x 2 ranks, "
-                "%d failure(s))\n", count, mib, g_failures.load());
+                "%zu lanes, %d failure(s))\n", count, mib, lanes,
+                g_failures.load());
     return g_failures.load() != 0;
   }
 

@@ -1143,6 +1143,25 @@ class TestNativeMirror:
             f.symbol == "kMaxIovSegs" and "8" in f.message for f in findings
         )
 
+    @pytest.mark.parametrize(
+        "const,py_name",
+        [("kMaxAutoLanes", "_MAX_AUTO_LANES"), ("kUnshapedAutoLanes", "_UNSHAPED_AUTO_LANES")],
+    )
+    def test_drifted_auto_lane_count_flagged(self, const, py_name):
+        """What ``auto`` resolves to, under a profile and (PR 47) without:
+        a drift is named, and so is a header that lacks the constant."""
+        from torchft_tpu import communicator as pycomm
+
+        ours = getattr(pycomm, py_name)
+        text = f"constexpr size_t {const} =\n    {ours + 1};  // mirror\n"
+        findings = nativemirror.check_comm_header(text, "native/comm.h")
+        assert any(f.symbol == const and str(ours + 1) in f.message for f in findings)
+        text = f"constexpr size_t {const} = {ours};\n"
+        findings = nativemirror.check_comm_header(text, "native/comm.h")
+        assert not any(f.symbol == const for f in findings)
+        findings = nativemirror.check_comm_header("// empty\n", "native/comm.h")
+        assert any(f.symbol == const and "not found" in f.message for f in findings)
+
     def test_missing_iovec_cap_flagged(self):
         findings = nativemirror.check_comm_header("// empty\n", "native/comm.h")
         assert any(f.symbol == "kMaxIovSegs" for f in findings)

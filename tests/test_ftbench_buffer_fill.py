@@ -74,11 +74,11 @@ def test_a_program_that_records_no_buffer_rows_reads_nothing(cell_name):
 def test_the_reader_is_its_entry_and_lists_the_four_expert_cells():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    # the last entry of its own PR; PR 44 appended one reader of the four-chip cell after it, PR 46 two
-    assert [m["name"] for m in bench["per_layer"][-4:]] == [
-        "moe_buffer_fill_pct", "d2h_direct_pct.hsdp", "d2h_split_pct", "sync_second_submit_ms",
+    # the last entry of its own PR; PR 44 appended one reader of the four-chip cell after it, PR 46 two, PR 47 one
+    assert [m["name"] for m in bench["per_layer"][-5:]] == [
+        "moe_buffer_fill_pct", "d2h_direct_pct.hsdp", "d2h_split_pct", "sync_second_submit_ms", "ring_striped_pct",
     ]
-    entry = bench["per_layer"][-4]
+    entry = bench["per_layer"][-5]
     assert entry["better"] == "higher"
     meta = spec.load_metric("moe_buffer_fill_pct", BENCH_DIR).META
     assert meta == {k: entry[k] for k in ("source", "layer", "unit", "moves")}

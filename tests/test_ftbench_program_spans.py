@@ -67,6 +67,9 @@ DIRECT_READERS = ("d2h_direct_pct.hsdp",)
 # second submit (the first large bucket's) begins; the two steady cells with
 # a replica dimension
 PIECE_READERS = ("d2h_split_pct", "sync_second_submit_ms")
+# PR 47: the share of a round trip's ring bytes that crossed on lanes other
+# than lane 0, from DDP_SYNC's striped_bytes beside ring_bytes; the same two cells
+LANE_READERS = ("ring_striped_pct",)
 
 
 @pytest.mark.parametrize("name", sorted(LATER_READINGS))
@@ -82,7 +85,7 @@ def test_new_readers_are_the_eighteen_benchmark_json_lists():  # noqa: F811
         per_layer = json.load(f)["per_layer"]
     appended = (
         LATER_READINGS, LING_READERS, BUCKET_READERS, ORDER_READERS, INDEXED_READERS, SSM_READERS, AHEAD_READERS,
-        SCOPE_READERS, IN_RING_READERS, SWA_READERS, FILL_READERS, DIRECT_READERS, PIECE_READERS,
+        SCOPE_READERS, IN_RING_READERS, SWA_READERS, FILL_READERS, DIRECT_READERS, PIECE_READERS, LANE_READERS,
     )
     later = sum(map(len, appended))
     assert [m["name"] for m in per_layer[-later:]] == [name for group in appended for name in group]
@@ -93,7 +96,7 @@ def test_new_readers_are_the_eighteen_benchmark_json_lists():  # noqa: F811
         cells = 4 if entry["name"] in EXPERT_CELLS + FILL_READERS else 3 if entry["name"] in FLASH_CELLS else 1
         if entry["name"] in SCOPE_READERS:  # the five one-replica cells, or the four that have the part
             cells = 4 if entry["name"] in ("xla_ffn_ms", "moe_route_ms", "moe_dispatch_ms") else 5
-        if entry["name"] in PIECE_READERS:
+        if entry["name"] in PIECE_READERS + LANE_READERS:
             cells = 2
             assert entry["workloads"] == ["mistral7b-ddp2-steady", theirs.HSDP_CELL]
         assert len(entry["workloads"]) == cells and set(entry) == {
@@ -258,6 +261,49 @@ def test_the_piece_readers_are_their_entries_and_list_the_two_steady_cells(name,
     meta = spec.load_metric(name, theirs.BENCH_DIR).META  # noqa: F405
     assert {k: entry[k] for k in meta} == meta
     assert meta == dict(source=source, layer="device-host boundary", unit=unit, moves="ddp_tokens_per_s_per_chip")
+
+
+def _striped(t, striped=None, ring=973127680, total=973127680, name="DDP_SYNC"):
+    event = dict(name=name, t=t, bytes=total)
+    return event if striped is None else dict(event, striped_bytes=striped, ring_bytes=ring)
+
+
+@pytest.mark.parametrize(
+    "events,expects",
+    [
+        # the parent's events carry no such counter: its auto is one lane
+        ([_striped(11.0), _striped(12.0)], 0.0),
+        # four lanes, every frame of at least two floors: three parts of four off lane 0
+        ([_striped(11.0, 729845760), _striped(12.0, 729845760)], 75.0),
+        # an explicit TORCHFT_RING_LANES=1 on a program that counts: 0, not nothing
+        ([_striped(11.0, 0), _striped(12.0, 0)], 0.0),
+        # a ring of three sends 4/3 of the tree: the share is of what the rings sent
+        ([_striped(11.0, 973127680, ring=1297503574)], 100.0 * 973127680 / 1297503574),
+        # events of other names, and of steps outside the window, do not count
+        ([_striped(11.0, 7, name="MOE_ROUTE"), _striped(30.0, 0), _striped(12.0, 486563840)], 50.0),
+        ([], None),
+    ],
+    ids=["parent", "four_lanes", "pinned_to_one", "ring_of_three", "other_events", "no_events"],
+)
+def test_ring_striped_pct_on_synthetic_flight_events(events, expects):
+    read = spec.load_metric("ring_striped_pct", theirs.BENCH_DIR).read  # noqa: F405
+    window = [[dict(t_enter=10.0, t_exit=11.5), dict(t_enter=11.5, t_exit=20.0)], []]
+    got = read(dict(window=window, flight=[events, [_striped(12.0, 5)]]))
+    assert got is None if expects is None else got == pytest.approx(expects, abs=1e-9)
+    assert read(dict(window=window, flight=None)) is None
+    assert read(dict(window=[[], []], flight=[events, []])) is None
+
+
+def test_the_lane_reader_is_its_entry_and_lists_the_two_steady_cells():
+    with open(os.path.join(theirs.ROOT, "BENCHMARK.json")) as f:
+        per_layer = json.load(f)["per_layer"]
+    entry = per_layer[-1]
+    assert entry["name"] == "ring_striped_pct" and entry["better"] == "higher"
+    assert entry["workloads"] == ["mistral7b-ddp2-steady", theirs.HSDP_CELL]
+    meta = spec.load_metric("ring_striped_pct", theirs.BENCH_DIR).META  # noqa: F405
+    assert {k: entry[k] for k in meta} == meta
+    assert meta == dict(source="program_counter", layer="host data plane", unit="%", moves="ddp_tokens_per_s_per_chip")
+    assert entry["layer"] in {m["layer"] for m in per_layer[:-1]}
 
 
 def _served(sources, ahead):

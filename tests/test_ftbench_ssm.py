@@ -17,7 +17,7 @@ from ftbench.tests.test_ftbench_ssm import *  # noqa: F401,F403
 # PR 40 the share of collectives the ring averaged itself, PR 41 the six of
 # the cell ``trinitymini-ws1-seq16k``, PR 42 how full the experts' buffer is, PR 44 the share of the
 # four-chip cell's gradient bytes that go from the shards into the bucket, PR 46 the two of the pieces a leaf
-# over the bucket cap crosses in
+# over the bucket cap crosses in, PR 47 the share of the rings' bytes that crossed off lane 0
 LATER_READERS = (
     "heal_serve_ahead_pct",
     "xla_mixer_proj_ms", "xla_mixer_glue_ms", "xla_ffn_ms", "xla_stream_ms", "xla_head_ms", "moe_route_ms",
@@ -28,6 +28,7 @@ LATER_READERS = (
     "moe_buffer_fill_pct",
     "d2h_direct_pct.hsdp",
     "d2h_split_pct", "sync_second_submit_ms",
+    "ring_striped_pct",
 )
 # PR 41 appended a configuration and a cell after PR 35's, and the cell's name to the lists PR 35's joined
 LATER_CELLS = ("trinitymini-ws1-seq16k",)

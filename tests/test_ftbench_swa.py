@@ -14,9 +14,9 @@ from ftbench.tests.test_ftbench_swa import *  # noqa: F401,F403
 
 # PR 42 appended how full the experts' buffer is, which lists this cell too
 LATER_READERS = ("moe_buffer_fill_pct",)
-# PR 44 appended a reader of the four-chip cell and PR 46 two of the cells with
-# a replica dimension, which do not list this one
-AFTER_THOSE = ("d2h_direct_pct.hsdp", "d2h_split_pct", "sync_second_submit_ms")
+# PR 44 appended a reader of the four-chip cell, PR 46 two of the cells with a
+# replica dimension and PR 47 one more of those, which do not list this one
+AFTER_THOSE = ("d2h_direct_pct.hsdp", "d2h_split_pct", "sync_second_submit_ms", "ring_striped_pct")
 
 
 def test_the_cell_and_the_lists_it_joined(monkeypatch):  # noqa: F811

@@ -20,12 +20,16 @@ from torchft_tpu.communicator import (
     CommunicatorError,
     ReduceOp,
     TCPCommunicator,
+    _MAX_AUTO_LANES,
+    _UNSHAPED_AUTO_LANES,
     _lane_parts,
     _NetEmu,
     _ring_lanes,
     _stripe_floor,
 )
 from torchft_tpu.store import StoreServer
+
+from tests.test_allreduce_divisor import _run as _run_tiers, _tiers
 
 
 @pytest.fixture()
@@ -96,8 +100,17 @@ class TestLaneResolution:
         with pytest.raises(CommunicatorError, match=">= 1"):
             _ring_lanes(None)
 
-    def test_auto_is_single_lane_on_loopback(self, monkeypatch) -> None:
+    def test_auto_stripes_where_no_link_is_emulated(self, monkeypatch) -> None:
+        # a stream on loopback (or any fast link) moves at one core's copy
+        # rate, so auto without a profile is the constant, not 1
         monkeypatch.delenv("TORCHFT_RING_LANES", raising=False)
+        assert _ring_lanes(None) == _UNSHAPED_AUTO_LANES > 1
+        monkeypatch.setenv("TORCHFT_RING_LANES", "auto")
+        assert _ring_lanes(None) == _UNSHAPED_AUTO_LANES
+        # never more than auto could already pick on a shaped link
+        assert _UNSHAPED_AUTO_LANES <= _MAX_AUTO_LANES
+        # an explicit count wins, 1 included (the rolling-upgrade pin)
+        monkeypatch.setenv("TORCHFT_RING_LANES", "1")
         assert _ring_lanes(None) == 1
 
     def test_auto_scales_with_stream_gap(self, monkeypatch) -> None:
@@ -106,8 +119,12 @@ class TestLaneResolution:
         # covers ~1/5 of the link -> auto picks the lane cap
         emu = _NetEmu(gbps=1.0, rtt_ms=10.0)
         assert _ring_lanes(emu) == 4
-        # no RTT -> no per-stream cap -> striping buys nothing
-        assert _ring_lanes(_NetEmu(gbps=1.0, rtt_ms=0.0)) == 1
+        # a stream that covers the link needs no second one
+        assert _ring_lanes(_NetEmu(gbps=0.1, rtt_ms=10.0)) == 1
+        # no RTT -> the emulator names no per-stream cap -> the link is
+        # shaped but a stream is not: the unshaped constant (the lanes share
+        # the one link bucket, so the emulated rate holds)
+        assert _ring_lanes(_NetEmu(gbps=1.0, rtt_ms=0.0)) == _UNSHAPED_AUTO_LANES
 
     def test_adaptive_frame_floor(self, monkeypatch) -> None:
         monkeypatch.delenv("TORCHFT_RING_FRAME_KB", raising=False)
@@ -337,3 +354,102 @@ class TestAbortMidLane:
         assert len(second_round) == world_size - 1
         for res in second_round:
             np.testing.assert_allclose(res, np.full(4096, 3.0))
+
+
+# --- auto stripes where no link is emulated (PR 47) -------------------------
+
+
+@pytest.mark.parametrize("tier", ["cpp", "python", "mixed"])
+def test_pieces_with_divisor_bit_identical_at_one_lane_and_auto(store, tier, monkeypatch) -> None:
+    """What a step of the two-group cells rings: a bfloat16 leaf cut in
+    pieces that are views into one kept buffer, each rung in place with the
+    divisor, and one small leaf (under two stripe floors: lane 0 whole).
+    ``auto`` without a profile stripes it over the constant's lanes on both
+    tiers and on a mixed pair, and every piece comes back bit for bit what
+    one lane gives."""
+    import ml_dtypes
+
+    monkeypatch.delenv("TORCHFT_NET_EMU", raising=False)
+    monkeypatch.delenv("TORCHFT_RING_FRAME_KB", raising=False)
+    bf16 = np.dtype(ml_dtypes.bfloat16)
+    piece, pieces, small = 700_001, 3, 100  # a piece's half is 5 floors and some
+    rng = np.random.default_rng(47)
+    grads = [
+        (rng.standard_normal(piece * pieces + small) * 1e3).astype(bf16) for _ in range(2)
+    ]
+
+    def _fn(comm, rank):
+        flat = grads[rank].copy()
+        views = [flat[k * piece : (k + 1) * piece] for k in range(pieces)] + [flat[piece * pieces :]]
+        works = [comm.allreduce(v, ReduceOp.SUM, in_place=True, divisor=2) for v in views]
+        for v, w in zip(views, works):
+            got = w.wait(timeout=30.0)
+            if not np.may_share_memory(np.asarray(got), v):
+                v[:] = got
+        return flat, comm.lane_stats()
+
+    monkeypatch.setenv("TORCHFT_RING_LANES", "1")
+    base = _run_tiers(store, _tiers(tier, 2), _fn, f"pieces1_{tier}")
+    monkeypatch.delenv("TORCHFT_RING_LANES")
+    auto = _run_tiers(store, _tiers(tier, 2), _fn, f"piecesauto_{tier}")
+    for (want, one), (got, striped) in zip(base, auto):
+        assert one["lanes"] == 1 and striped["lanes"] == _UNSHAPED_AUTO_LANES
+        np.testing.assert_array_equal(got.view(np.uint16), want.view(np.uint16))
+        # the same bytes cross, now on every lane; the small leaf's 200
+        # bytes and nothing else of the payload stay whole on lane 0
+        tx = striped["lane_tx_bytes"]
+        # (the Python tier counts a sub-frame's 16-byte header with its payload)
+        assert 0 <= sum(tx) - sum(one["lane_tx_bytes"]) < 1024 and all(b > 0 for b in tx)
+        share = sum(tx[1:]) / sum(tx)
+        assert abs(share - (1 - 1 / _UNSHAPED_AUTO_LANES)) < 0.01
+    np.testing.assert_array_equal(auto[0][0].view(np.uint16), auto[1][0].view(np.uint16))
+
+
+@pytest.mark.parametrize("new_tier", ["cpp", "python"])
+def test_one_lane_peer_from_before_auto_striped_fails_loudly_and_the_pin_heals(
+    store, new_tier, monkeypatch
+) -> None:
+    """A version boundary: a peer from before PR 47 resolves ``auto`` to one
+    lane and speaks the legacy hello; against a new peer's ``auto`` the
+    rendezvous fails LOUDLY on both sides, and ``TORCHFT_RING_LANES=1`` on
+    the new side (here: in the process) rings with it."""
+    from torchft_tpu import native
+    from torchft_tpu.communicator import _TcpMesh
+
+    monkeypatch.delenv("TORCHFT_NET_EMU", raising=False)
+    monkeypatch.delenv("TORCHFT_RING_LANES", raising=False)
+    errors: List[BaseException] = []
+    made: List[object] = []
+
+    def _old(prefix: str) -> None:
+        try:  # lanes=1: what the parent's ``auto`` resolved to, the legacy hello
+            made.append(_TcpMesh(f"127.0.0.1:{store.port}/{prefix}", 0, 2, timeout_s=5.0, lanes=1))
+        except Exception as e:  # noqa: BLE001
+            errors.append(e)
+
+    def _new(prefix: str):
+        comm = native.CppCommunicator(timeout_s=5.0) if new_tier == "cpp" else TCPCommunicator(timeout_s=5.0)
+        try:
+            comm.configure(f"127.0.0.1:{store.port}/{prefix}", replica_id="new", rank=1, world_size=2)
+        except Exception as e:  # noqa: BLE001
+            errors.append(e)
+        return comm
+
+    old = threading.Thread(target=_old, args=(f"boundary_{new_tier}",))
+    old.start()
+    comm = _new(f"boundary_{new_tier}")
+    old.join(timeout=30.0)
+    for mesh in made:
+        mesh.abort()
+    comm.shutdown()
+    assert errors, "a one-lane peer against auto's lanes must fail the rendezvous"
+    assert any("mismatch" in str(e) and "lane" in str(e) for e in errors), errors
+
+    monkeypatch.setenv("TORCHFT_RING_LANES", "1")
+
+    def _ring(comm, rank):
+        assert comm.lane_stats()["lanes"] == 1
+        return np.asarray(comm.allreduce(np.full(1 << 16, rank + 1.0, np.float32), ReduceOp.SUM).wait(timeout=30.0))
+
+    for got in _run_tiers(store, ["python", new_tier], _ring, f"pinned_{new_tier}"):
+        np.testing.assert_array_equal(got, np.full(1 << 16, 3.0, np.float32))

@@ -17,6 +17,7 @@ every shared constant against its live Python counterpart:
   == ``communicator._STRIPE_ALIGN``
 - ``kMinStripeBytes``       == ``communicator._MIN_STRIPE_BYTES``
 - ``kMaxAutoLanes``         == ``communicator._MAX_AUTO_LANES``
+- ``kUnshapedAutoLanes``    == ``communicator._UNSHAPED_AUTO_LANES``
 - ``kRingReduceTagBase``    == ``wire.RING_REDUCE_TAG_BASE``
 - ``kRingAvgTagBase``       == ``wire.RING_AVG_TAG_BASE`` (the averaging ring)
 - ``kRingBufferTagStride``  == ``wire.RING_BUFFER_TAG_STRIDE``
@@ -246,17 +247,28 @@ def check_comm_header(text: str, rel: str = _COMM_H) -> List[Finding]:
                     f"_MIN_STRIPE_BYTES = {pycomm._MIN_STRIPE_BYTES}",
                 )
             )
-    m = re.search(r"kMaxAutoLanes\s*=\s*(\d+)", text)
-    if m and int(m.group(1)) != pycomm._MAX_AUTO_LANES:
-        findings.append(
-            _finding(
-                rel,
-                _line_of(text, r"kMaxAutoLanes"),
-                "kMaxAutoLanes",
-                f"native kMaxAutoLanes = {m.group(1)} but Python "
-                f"_MAX_AUTO_LANES = {pycomm._MAX_AUTO_LANES}",
+    # what ``auto`` resolves to: the cap under an emulated profile, and the
+    # count where no link is emulated — ranks of the two tiers that disagree
+    # fail every mixed rendezvous at the hello's lane count
+    for const, py_name in (
+        ("kMaxAutoLanes", "_MAX_AUTO_LANES"),
+        ("kUnshapedAutoLanes", "_UNSHAPED_AUTO_LANES"),
+    ):
+        m = re.search(const + r"\s*=\s*(\d+)", text)
+        if not m:
+            findings.append(
+                _finding(rel, 1, const, f"{const} not found in {rel}")
             )
-        )
+        elif int(m.group(1)) != getattr(pycomm, py_name):
+            findings.append(
+                _finding(
+                    rel,
+                    _line_of(text, const),
+                    const,
+                    f"native {const} = {m.group(1)} but Python "
+                    f"{py_name} = {getattr(pycomm, py_name)}",
+                )
+            )
 
     # the rings' tag windows (the explicit reduce_scatter's, the averaging
     # ring's, the stride between a call's dtype groups) — a drift here frames

@@ -646,14 +646,17 @@ def _net_emu_from_env() -> Optional["_NetEmu"]:
 # each ring chunk across L independent connections is the standard cure
 # (cf. PAPERS.md: HSDP-at-100k-GPUs / SPARe stripe inter-replica reduction
 # the same way).  MUST be uniform across replicas (verified loudly at
-# rendezvous); "auto"/unset derives it from the emulated link profile (1 on
-# plain loopback, where a single stream already saturates).
+# rendezvous); "auto"/unset derives it from the emulated link profile, and
+# is _UNSHAPED_AUTO_LANES where no link is emulated: a stream there moves at
+# one core's copy rate (PERF.md section 6, PR 47).  A constant and not a
+# reading of this host's cores, because every rank must resolve the same.
 RING_LANES_ENV = "TORCHFT_RING_LANES"
 # Floor for one striped sub-frame, in KiB.  Unset/auto picks the link's
 # RTT×bandwidth product (jumbo frames on DCN so the per-frame half-RTT gate
 # amortizes; 64 KiB on loopback).  Uniform across replicas, like the lanes.
 RING_FRAME_KB_ENV = "TORCHFT_RING_FRAME_KB"
 _MAX_AUTO_LANES = 4
+_UNSHAPED_AUTO_LANES = 4
 _MIN_STRIPE_BYTES = 64 << 10
 # sub-frame boundaries are 64-byte aligned so no element of any supported
 # dtype (itemsize a power of two <= 64) ever splits across lanes — the
@@ -697,9 +700,9 @@ def _ring_lanes(emu: Optional[_NetEmu]) -> int:
             raise CommunicatorError(f"{RING_LANES_ENV} must be >= 1")
         return lanes
     # auto: enough lanes that the aggregate stream rate reaches the link
-    # rate, capped; 1 when unshaped (loopback) or the stream cap is off
+    # rate, capped; the constant when unshaped or the stream cap is off
     if emu is None or emu.stream_bytes_per_s <= 0 or emu.bytes_per_s <= 0:
-        return 1
+        return _UNSHAPED_AUTO_LANES
     need = -(-int(emu.bytes_per_s) // max(1, int(emu.stream_bytes_per_s)))
     return max(1, min(_MAX_AUTO_LANES, need))
 

@@ -688,6 +688,7 @@ def allreduce_pytree(
         "first_submit_s": 0.0,  # from the round trip's start to the first bucket's submit
     }
 
+    tx_before = manager.ring_tx_bytes()
     store = _bucket_store(manager)
     works: List[Work] = []
     flats: List[np.ndarray] = []  # each bucket's part of its host buffer, in the plan's order
@@ -793,12 +794,19 @@ def allreduce_pytree(
             restored = _gather()
         except Exception as e:  # noqa: BLE001 — funnel, never raise
             manager.report_error(e)
+        # what this round trip's rings sent, and how much of it off lane 0
+        # (a reconfiguration in between starts the lanes' counts anew)
+        ring_bytes, striped_bytes = (
+            max(0, after - before) for after, before in zip(manager.ring_tx_bytes(), tx_before)
+        )
         sync_span.set(
             buckets=len(works),
             warm_buckets=0 if kept is None else len(works),
             bytes=plan.nbytes,
             direct_bytes=plan.direct_nbytes,
             split_bytes=plan.split_nbytes,
+            ring_bytes=ring_bytes,
+            striped_bytes=striped_bytes,
             **{k: round(v, 6) for k, v in stage_s.items()},
         )
         sync_span.__exit__()

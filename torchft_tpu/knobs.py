@@ -113,7 +113,7 @@ _k("TORCHFT_METRICS_TTL_S", "float", "0.5",
    "ManagerServer /metrics snapshot TTL: scrape storms rebuild the sample set at most once per TTL")
 # --- data plane: lanes / framing / topology ---------------------------------
 _k("TORCHFT_RING_LANES", "str", "auto",
-   "TCP lanes per peer for striped collectives (auto = profile-derived; must be uniform)")
+   "TCP lanes per peer for striped collectives (auto = 4 with no emulated link, else profile-derived; must be uniform)")
 _k("TORCHFT_RING_FRAME_KB", "str", "auto",
    "Stripe floor per lane frame in KiB (auto = RTT*BW-derived)")
 _k("TORCHFT_HIERARCHICAL", "str", "auto",

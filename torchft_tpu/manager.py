@@ -1262,6 +1262,16 @@ class Manager:
             and self._errored is None
         )
 
+    def ring_tx_bytes(self) -> Tuple[int, int]:
+        """Payload bytes the live epoch's communicator has sent so far: on
+        every lane, and on the lanes other than lane 0 (what striping moved
+        off the one stream; 0 at one lane).  ``ddp.allreduce_pytree``
+        differences it over a round trip for DDP_SYNC's ``ring_bytes`` and
+        ``striped_bytes``."""
+        stats_fn = getattr(self._comm, "lane_stats", None)
+        tx = (stats_fn() if callable(stats_fn) else {}).get("lane_tx_bytes") or []
+        return sum(tx), sum(tx[1:])
+
     def allreduce(
         self,
         data: Union[np.ndarray, List[np.ndarray]],
