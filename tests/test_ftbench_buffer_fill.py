@@ -3,19 +3,15 @@ made MOE_ROUTE flight events, in the four expert cells, and its entry in
 ``BENCHMARK.json``.  It sits here and not beside the cells' own tests because a
 file under ``ftbench/`` is the benchmark's and only a ``benchmark`` issue may
 edit it (PERF.md section 7); the made sources are those files' own.  That the
-traced walk of each cell would report it is held in the four wrappers
-(``test_rehearsal_walks_the_cell``).  No number here is a device's."""
-
-import json
-import os
+traced walk of each cell would report it is held by the cells' views
+(``tests/_ftbench_view.py``, ``walk_reports``).  No number here is a device's."""
 
 import pytest
 
 from ftbench import spec
 from ftbench.tests import test_ftbench_indexed, test_ftbench_ling, test_ftbench_ssm, test_ftbench_swa
+from tests._ftbench_view import BENCH_DIR, reader_entry
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-BENCH_DIR = os.path.join(ROOT, "ftbench")
 # a cell's own test file (its ``_trace_sources``: two steps of a window that
 # opens at 1.0) and its expert layers
 CELLS = {
@@ -72,18 +68,9 @@ def test_a_program_that_records_no_buffer_rows_reads_nothing(cell_name):
 
 
 def test_the_reader_is_its_entry_and_lists_the_four_expert_cells():
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    # the last entry of its own PR; PR 44 appended one reader of the four-chip cell after it, PR 46 two, PR 47 one
-    assert [m["name"] for m in bench["per_layer"][-5:]] == [
-        "moe_buffer_fill_pct", "d2h_direct_pct.hsdp", "d2h_split_pct", "sync_second_submit_ms", "ring_striped_pct",
-    ]
-    entry = bench["per_layer"][-5]
-    assert entry["better"] == "higher"
-    meta = spec.load_metric("moe_buffer_fill_pct", BENCH_DIR).META
-    assert meta == {k: entry[k] for k in ("source", "layer", "unit", "moves")}
-    assert meta == dict(source="program_counter", layer="experts", unit="%", moves="tokens_per_s_per_chip")
-    # the cells that have experts: those the dispatch's own time is read in
-    dispatch = next(m for m in bench["per_layer"] if m["name"] == "moe_dispatch_ms")
-    assert entry["workloads"] == dispatch["workloads"] and set(entry["workloads"]) == set(CELLS)
-    assert set(entry) == {"name", "unit", "better", "source", "layer", "moves", "workloads"}
+    entry = reader_entry(
+        "moe_buffer_fill_pct", cells=CELLS, better="higher",
+        source="program_counter", layer="experts", unit="%", moves="tokens_per_s_per_chip",
+    )
+    # only cells that have experts: the dispatch's own time is read in each of them
+    assert set(entry["workloads"]) <= set(reader_entry("moe_dispatch_ms")["workloads"])

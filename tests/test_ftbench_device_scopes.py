@@ -4,15 +4,13 @@ eleven metrics that share the compiled step out by the program's named parts
 (``torchft_tpu/obs/spans.py``, ``DEVICE_PARTS``); on synthetic planes whose
 numbers are known and on the small trace recorded on the chip."""
 
-import json
 import os
 
 import pytest
 
 from ftbench import device_scopes, sources as bench_sources, spec, trace_reduce
+from tests._ftbench_view import BENCH_DIR, bench, reader_entry
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-BENCH_DIR = os.path.join(ROOT, "ftbench")
 SMALL = os.path.join(BENCH_DIR, "tests", "data", "small.xplane.pb")
 
 LAYER = "jit(_step)/jvp(tpuft.layers)/while/body/closed_call"
@@ -277,21 +275,26 @@ def test_the_table_a_builder_wants_first(run, capsys, monkeypatch):
     assert device_scopes.main(["device_scopes", "a series file"]) == 1
 
 
+# the one-replica cells the readers were written for (PR 37, and PR 41's, which
+# has every part): the dense model has no experts, Keye's layers no dense FFN
+WRITTEN_FOR = (
+    "mistral7b-ws1-steady", "ling3flash-ws1-seq8k", "keye2-ws1-seq16k", "nemotron3nano-ws1-seq16k",
+    "trinitymini-ws1-seq16k",
+)
+WITHOUT_THE_PART = {
+    "xla_ffn_ms": ("keye2-ws1-seq16k",), "moe_route_ms": ("mistral7b-ws1-steady",),
+    "moe_dispatch_ms": ("mistral7b-ws1-steady",),
+}
+
+
 @pytest.mark.parametrize("name", sorted(EXPECTS))
 def test_the_entry_benchmark_json_lists(name):
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    (entry,) = [m for m in bench["per_layer"] if m["name"] == name]
-    meta = spec.load_metric(name, BENCH_DIR).META
-    assert {k: entry[k] for k in meta} == meta and entry["better"] == "lower" and entry["unit"] == "ms"
-    assert entry["layer"] == ("experts" if name.startswith("moe_") else "compiled step")
-    one_replica = [w["name"] for w in bench["workloads"] if "-ws1-" in w["name"]]
-    assert len(one_replica) == 5  # PR 41 appended trinitymini-ws1-seq16k, which has every part
-    want = {
-        "xla_ffn_ms": [c for c in one_replica if not c.startswith("keye2")],
-        "moe_route_ms": one_replica[1:], "moe_dispatch_ms": one_replica[1:],
-    }.get(name, one_replica)
-    assert entry["workloads"] == want
+    without = WITHOUT_THE_PART.get(name, ())
+    entry = reader_entry(
+        name, cells=[c for c in WRITTEN_FOR if c not in without], better="lower", unit="ms",
+        layer="experts" if name.startswith("moe_") else "compiled step",
+    )
+    assert not set(without) & set(entry["workloads"])
     # each of them reports the end-to-end metric the entry moves
-    (moved,) = [m for m in bench["end_to_end"] if m["name"] == entry["moves"]]
+    (moved,) = [m for m in bench()["end_to_end"] if m["name"] == entry["moves"]]
     assert set(entry["workloads"]) <= set(moved["workloads"])

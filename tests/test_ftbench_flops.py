@@ -1,5 +1,4 @@
-"""Tier-1's view of ``ftbench/tests/test_ftbench_flops.py``: tier-1 collects
-``tests/`` only, and the benchmark's own tests guard nothing unless it runs
-them (ROADMAP D3).  The tests live with the benchmark; this file imports them."""
+"""Tier-1's view of ``ftbench/tests/test_ftbench_flops.py``: the benchmark's
+tests, imported (``tests/_ftbench_view.py`` says why, and the rule a view keeps)."""
 
 from ftbench.tests.test_ftbench_flops import *  # noqa: F401,F403

@@ -1,75 +1,21 @@
-"""Tier-1's view of ``ftbench/tests/test_ftbench_program_spans.py``: tier-1 collects
-``tests/`` only, and the benchmark's own tests guard nothing unless it runs
-them (ROADMAP D3).  The tests live with the benchmark; this file imports them
-and adds what a later PR's reader needs (a file under ``ftbench/`` is the
-benchmark's, and only a ``benchmark`` issue may edit it: PERF.md section 7)."""
+"""Tier-1's view of ``ftbench/tests/test_ftbench_program_spans.py``: the
+benchmark's tests, imported (``tests/_ftbench_view.py`` says why, and the rule
+a view keeps), and the tests of the readers that later PRs wrote under
+``tests/`` (a file under ``ftbench/`` is the benchmark's, and only a
+``benchmark`` issue may edit it: PERF.md section 7)."""
 
 import copy
-import json
-import os
 
 import pytest
 
 from ftbench.tests import test_ftbench_program_spans as theirs
 from ftbench.tests.test_ftbench_program_spans import *  # noqa: F401,F403
+from tests._ftbench_view import bench, device_trace_readers, reader_entry, walk_reports
 
-# PR 27: the division by the participant count, two spans of 40 ms a step
+# PR 27's reader on theirs' synthetic planes: the division by the participant
+# count, two spans of 40 ms a step
 LATER_READINGS = {"sync_normalize_ms": 80.0}
-# PR 29: the readers of the cell ling3flash-ws1-seq8k, appended after it
-# (their own tests: ftbench/tests/test_ftbench_ling.py)
-LING_READERS = (
-    "kda_fwd_ms", "kda_bwd_ms", "kda_roofline", "mla_flash_ms", "mla_flash_roofline", "moe_gmm_ms",
-    "moe_gmm_roofline", "ling_step_mfu_pct", "moe_rows_here_per_step", "moe_load_max_over_mean",
-)
-# PR 30: the share of a step's buckets filled in kept memory, from DDP_SYNC
-BUCKET_READERS = ("bucket_warm_pct",)
-# PR 32: whether the ring runs beside the gradients' transfer to the host
-ORDER_READERS = ("sync_first_submit_ms", "ring_beside_d2h_pct")
-# PR 33: the readers of the cell keye2-ws1-seq16k (their own tests:
-# ftbench/tests/test_ftbench_indexed.py), which also joined three of Ling's lists
-INDEXED_READERS = (
-    "dsa_index_ms", "dsa_select_ms", "dsa_attn_ms", "dsa_probs_ms", "dsa_index_roofline",
-    "dsa_attn_roofline", "dsa_moe_gmm_roofline", "dsa_step_mfu_pct", "dsa_keys_per_query",
-)
-EXPERT_CELLS = ("moe_gmm_ms", "moe_rows_here_per_step", "moe_load_max_over_mean")
-# PR 35: the readers of the cell nemotron3nano-ws1-seq16k (their own tests:
-# ftbench/tests/test_ftbench_ssm.py), which also joined the experts' three lists and flash's three
-SSM_READERS = (
-    "ssd_fwd_ms", "ssd_bwd_ms", "ssd_roofline", "ssm_flash_roofline", "ssm_moe_gmm_roofline", "ssm_step_mfu_pct",
-)
-FLASH_CELLS = ("flash_fwd_ms", "flash_dq_ms", "flash_dkv_ms")
-# PR 36: the share of a heal's bytes whose transfer to the host was under way
-# before the survivor's handler came to them, from HEAL_SERVE_END's ahead_bytes
-AHEAD_READERS = ("heal_serve_ahead_pct",)
-# PR 37: the compiled step shared out by the scopes the program names its parts
-# with (their own tests: tests/test_ftbench_device_scopes.py)
-SCOPE_READERS = (
-    "xla_mixer_proj_ms", "xla_mixer_glue_ms", "xla_ffn_ms", "xla_stream_ms", "xla_head_ms", "moe_route_ms",
-    "moe_dispatch_ms", "xla_layer_scan_ms", "optimizer_ms", "step_remat_ms", "xla_unscoped_ms",
-)
-# PR 40: the share of a step's collectives whose average the ring made itself
-# (the span tpuft/manager/normalize carries in_ring=1)
-IN_RING_READERS = ("normalize_in_ring_pct",)
-# PR 41: the readers of the cell trinitymini-ws1-seq16k (their own tests:
-# ftbench/tests/test_ftbench_swa.py), which also joined the experts' three lists, flash's three and the scopes' eleven
-SWA_READERS = (
-    "swa_flash_ms", "swa_flash_roofline", "swa_full_flash_roofline", "swa_window_over_full_pct",
-    "swa_moe_gmm_roofline", "swa_step_mfu_pct",
-)
-# PR 42: how full the experts' buffer is, from MOE_ROUTE's buffer_rows (its own
-# tests: tests/test_ftbench_buffer_fill.py); the four expert cells
-FILL_READERS = ("moe_buffer_fill_pct",)
-# PR 44: the share of a step's gradient bytes written from each chip's shard
-# straight into a bucket, from DDP_SYNC's direct_bytes; the four-chip cell
-DIRECT_READERS = ("d2h_direct_pct.hsdp",)
-# PR 46: the share of a step's gradient bytes that crossed as pieces of a leaf
-# over the bucket cap, from DDP_SYNC's split_bytes, and when the round trip's
-# second submit (the first large bucket's) begins; the two steady cells with
-# a replica dimension
-PIECE_READERS = ("d2h_split_pct", "sync_second_submit_ms")
-# PR 47: the share of a round trip's ring bytes that crossed on lanes other
-# than lane 0, from DDP_SYNC's striped_bytes beside ring_bytes; the same two cells
-LANE_READERS = ("ring_striped_pct",)
+STEADY_CELLS = ("mistral7b-ddp2-steady", theirs.HSDP_CELL)
 
 
 @pytest.mark.parametrize("name", sorted(LATER_READINGS))
@@ -78,50 +24,21 @@ def test_later_span_reader_on_synthetic_planes(run, name, monkeypatch):  # noqa:
     theirs.test_span_reader_on_synthetic_planes(run, name, monkeypatch)
 
 
-def test_new_readers_are_the_eighteen_benchmark_json_lists():  # noqa: F811
-    """Theirs holds PR 26's eighteen to be the LAST entries of ``per_layer``;
-    a later PR appends, so here they are the eighteen before the later ones."""
-    with open(os.path.join(theirs.ROOT, "BENCHMARK.json")) as f:
-        per_layer = json.load(f)["per_layer"]
-    appended = (
-        LATER_READINGS, LING_READERS, BUCKET_READERS, ORDER_READERS, INDEXED_READERS, SSM_READERS, AHEAD_READERS,
-        SCOPE_READERS, IN_RING_READERS, SWA_READERS, FILL_READERS, DIRECT_READERS, PIECE_READERS, LANE_READERS,
-    )
-    later = sum(map(len, appended))
-    assert [m["name"] for m in per_layer[-later:]] == [name for group in appended for name in group]
-    theirs_new = set(theirs.READINGS) | set(theirs.KILL_READINGS) | {"flash_fwd_ms", "flash_dq_ms", "flash_dkv_ms"}
-    assert len(theirs_new) == 18
-    assert {m["name"] for m in per_layer[-18 - later:-later]} == theirs_new
-    for entry in per_layer[-18 - later:]:
-        cells = 4 if entry["name"] in EXPERT_CELLS + FILL_READERS else 3 if entry["name"] in FLASH_CELLS else 1
-        if entry["name"] in SCOPE_READERS:  # the five one-replica cells, or the four that have the part
-            cells = 4 if entry["name"] in ("xla_ffn_ms", "moe_route_ms", "moe_dispatch_ms") else 5
-        if entry["name"] in PIECE_READERS + LANE_READERS:
-            cells = 2
-            assert entry["workloads"] == ["mistral7b-ddp2-steady", theirs.HSDP_CELL]
-        assert len(entry["workloads"]) == cells and set(entry) == {
-            "name", "unit", "better", "source", "layer", "moves", "workloads",
-        }
-
-
 @pytest.mark.parametrize(
     "cell,new", theirs.test_rehearsal_would_report_the_program_span_metrics.pytestmark[0].args[1]
 )
 def test_rehearsal_would_report_the_program_span_metrics(cell, new, tmp_path, monkeypatch):  # noqa: F811
-    """Theirs, and of the same walk: a CPU's trace has no device plane, so
-    PR 37's readers of the scopes (``ftbench/device_scopes.py``) find nothing
-    to read in it and say so; the walk does not fail on them."""
+    """Theirs, and of the same walk: it reports every reader of today that
+    lists the cell and can be read on a CPU, and none of the device's trace
+    (no device plane: PR 37's readers of the scopes find nothing and say so)."""
     from ftbench import device_scopes
 
     seen = {}
     rehearse = theirs._rehearse
     monkeypatch.setattr(theirs, "_rehearse", lambda *a, **k: seen.setdefault("reported", rehearse(*a, **k)))
     theirs.test_rehearsal_would_report_the_program_span_metrics(cell, new, tmp_path)
-    assert not seen["reported"] & set(SCOPE_READERS)
+    assert walk_reports(cell) <= seen["reported"] and not seen["reported"] & device_trace_readers()
     assert device_scopes.load(str(tmp_path / "ftbench")) == {}
-    # PR 44: the cell whose groups lie over two (virtual) chips reports the
-    # share that went from the shards into the bucket, and no other cell does
-    assert (set(DIRECT_READERS) <= seen["reported"]) == (cell == theirs.HSDP_CELL)
 
 
 def _sync(t, warm=None, buckets=10, name="DDP_SYNC"):
@@ -180,14 +97,10 @@ def test_d2h_direct_pct_on_synthetic_flight_events(events, expects):
     assert read(dict(window=[[], []], flight=[events, []])) is None
 
 
-def test_d2h_direct_pct_is_the_four_chip_cells_alone():
-    with open(os.path.join(theirs.ROOT, "BENCHMARK.json")) as f:
-        (entry,) = [m for m in json.load(f)["per_layer"] if m["name"] == "d2h_direct_pct.hsdp"]
-    assert entry["workloads"] == [theirs.HSDP_CELL] and entry["better"] == "higher"
-    meta = spec.load_metric("d2h_direct_pct.hsdp", theirs.BENCH_DIR).META  # noqa: F405
-    assert {k: entry[k] for k in meta} == meta
-    assert meta == dict(
-        source="program_counter", layer="device-host boundary", unit="%", moves="ddp_tokens_per_s_per_chip"
+def test_d2h_direct_pct_is_its_entry_and_lists_the_four_chip_cell():
+    reader_entry(
+        "d2h_direct_pct.hsdp", cells=[theirs.HSDP_CELL], better="higher",
+        source="program_counter", layer="device-host boundary", unit="%", moves="ddp_tokens_per_s_per_chip",
     )
 
 
@@ -255,12 +168,10 @@ def test_sync_second_submit_ms_on_synthetic_spans(submits, expects, monkeypatch)
     ("d2h_split_pct", "program_counter", "%", "higher"), ("sync_second_submit_ms", "program_span", "ms", "lower"),
 ])
 def test_the_piece_readers_are_their_entries_and_list_the_two_steady_cells(name, source, unit, better):
-    with open(os.path.join(theirs.ROOT, "BENCHMARK.json")) as f:
-        (entry,) = [m for m in json.load(f)["per_layer"] if m["name"] == name]
-    assert entry["workloads"] == ["mistral7b-ddp2-steady", theirs.HSDP_CELL] and entry["better"] == better
-    meta = spec.load_metric(name, theirs.BENCH_DIR).META  # noqa: F405
-    assert {k: entry[k] for k in meta} == meta
-    assert meta == dict(source=source, layer="device-host boundary", unit=unit, moves="ddp_tokens_per_s_per_chip")
+    reader_entry(
+        name, cells=STEADY_CELLS, better=better,
+        source=source, layer="device-host boundary", unit=unit, moves="ddp_tokens_per_s_per_chip",
+    )
 
 
 def _striped(t, striped=None, ring=973127680, total=973127680, name="DDP_SYNC"):
@@ -295,15 +206,12 @@ def test_ring_striped_pct_on_synthetic_flight_events(events, expects):
 
 
 def test_the_lane_reader_is_its_entry_and_lists_the_two_steady_cells():
-    with open(os.path.join(theirs.ROOT, "BENCHMARK.json")) as f:
-        per_layer = json.load(f)["per_layer"]
-    entry = per_layer[-1]
-    assert entry["name"] == "ring_striped_pct" and entry["better"] == "higher"
-    assert entry["workloads"] == ["mistral7b-ddp2-steady", theirs.HSDP_CELL]
-    meta = spec.load_metric("ring_striped_pct", theirs.BENCH_DIR).META  # noqa: F405
-    assert {k: entry[k] for k in meta} == meta
-    assert meta == dict(source="program_counter", layer="host data plane", unit="%", moves="ddp_tokens_per_s_per_chip")
-    assert entry["layer"] in {m["layer"] for m in per_layer[:-1]}
+    entry = reader_entry(
+        "ring_striped_pct", cells=STEADY_CELLS, better="higher",
+        source="program_counter", layer="host data plane", unit="%", moves="ddp_tokens_per_s_per_chip",
+    )
+    # a layer that other readers name too, not one of its own
+    assert any(m["layer"] == entry["layer"] for m in bench()["per_layer"] if m["name"] != entry["name"])
 
 
 def _served(sources, ahead):
@@ -347,12 +255,8 @@ def test_heal_serve_ahead_pct_reads_nothing_from_a_run_without_the_field(sources
     assert spec.load_metric("heal_serve_ahead_pct", theirs.BENCH_DIR).read(sources) is None  # noqa: F405
 
 
-def test_heal_serve_ahead_pct_is_the_kill_cells_alone():
-    with open(os.path.join(theirs.ROOT, "BENCHMARK.json")) as f:
-        (entry,) = [m for m in json.load(f)["per_layer"] if m["name"] == "heal_serve_ahead_pct"]
-    assert entry["workloads"] == ["mistral7b-ddp2-kill"]
-    meta = spec.load_metric("heal_serve_ahead_pct", theirs.BENCH_DIR).META  # noqa: F405
-    assert {k: entry[k] for k in meta} == meta and entry["better"] == "higher"
+def test_heal_serve_ahead_pct_is_its_entry_and_lists_the_kill_cell():
+    reader_entry("heal_serve_ahead_pct", cells=["mistral7b-ddp2-kill"], better="higher")
 
 
 def _with_in_ring(spans, flags):
@@ -423,12 +327,8 @@ def test_normalize_in_ring_pct_reads_the_attribute_out_of_a_profile():
         assert spec.load_metric("normalize_in_ring_pct", theirs.BENCH_DIR).read(dict(trace=None)) == 100.0  # noqa: F405
 
 
-def test_normalize_in_ring_pct_is_the_steady_two_replica_cell_alone():
-    with open(os.path.join(theirs.ROOT, "BENCHMARK.json")) as f:
-        (entry,) = [m for m in json.load(f)["per_layer"] if m["name"] == "normalize_in_ring_pct"]
-    assert entry["workloads"] == ["mistral7b-ddp2-steady"]
-    meta = spec.load_metric("normalize_in_ring_pct", theirs.BENCH_DIR).META  # noqa: F405
-    assert {k: entry[k] for k in meta} == meta and entry["better"] == "higher"
+def test_normalize_in_ring_pct_is_its_entry_and_lists_the_steady_two_replica_cell():
+    reader_entry("normalize_in_ring_pct", cells=["mistral7b-ddp2-steady"], better="higher")
 
 
 def _round_trip(step, at, buckets):

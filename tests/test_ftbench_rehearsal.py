@@ -1,31 +1,21 @@
-"""Tier-1's view of ``ftbench/tests/test_ftbench_rehearsal.py`` (ROADMAP D3):
-the CPU walk-through of every cell.  ``test_rehearsal_walks_the_cell`` is
-taken as it is for the untraced runs; its two traced cases hold
-``would_report`` to the five names PR 23's readers gave with ``==``, and the
-program's spans now give the rehearsal more to report, so those two are run
-here against the sets of today (the file under ``ftbench/`` is the
-benchmark's, and only a ``benchmark`` issue may edit it: PERF.md section 7)."""
+"""Tier-1's view of ``ftbench/tests/test_ftbench_rehearsal.py``
+(``tests/_ftbench_view.py`` says what a view is): the CPU walk-through of every
+cell.  ``test_rehearsal_walks_the_cell`` is taken as it is for the untraced
+runs; a traced case holds there the five names PR 23's readers gave, and here,
+in the same walk, every reader of today that a CPU can give
+(``walk_reports``).  A walk that fails says what it waited on."""
 
 import pytest
 
 from ftbench.tests import test_ftbench_rehearsal as theirs
-from ftbench.tests.test_ftbench_program_spans import KILL_READINGS, READINGS
 from ftbench.tests.test_ftbench_rehearsal import (  # noqa: F401
     test_benchmark_alone_without_the_program_fails,
     test_no_chip_is_exit_1_and_no_result,
     test_rehearsal_walks_the_four_chip_cell_a_later_pr_adds,
 )
+from tests._ftbench_view import walk_reports
 
 _CASES = theirs.test_rehearsal_walks_the_cell.pytestmark[0].args[1]
-# sync_normalize_ms is PR 27's reader of PR 26's span tpuft/manager/normalize,
-# bucket_warm_pct PR 30's of DDP_SYNC's warm_buckets, heal_serve_ahead_pct
-# PR 36's of HEAL_SERVE_END's ahead_bytes (a rehearsed kill run heals jax
-# leaves, so the counter is there and the share is reported),
-# normalize_in_ring_pct PR 40's of the normalize span's in_ring
-_NEW = {
-    "mistral7b-ddp2-steady": set(READINGS) | {"sync_normalize_ms", "bucket_warm_pct", "normalize_in_ring_pct"},
-    "mistral7b-ddp2-kill": set(KILL_READINGS) | {"heal_serve_ahead_pct"},
-}
 
 
 def _what_it_waited_on(cell, flight_dir):
@@ -56,7 +46,7 @@ def _what_it_waited_on(cell, flight_dir):
 
 @pytest.mark.parametrize(
     "cell,trace,devices,expects",
-    [(c, t, d, e | _NEW[c] if t else e) for c, t, d, e in _CASES],
+    [(c, t, d, e | walk_reports(c) if t else e) for c, t, d, e in _CASES],
 )
 def test_rehearsal_walks_the_cell(cell, trace, devices, expects, tmp_path, monkeypatch):
     # the walk's subprocess inherits this: every error a Manager funnels
