@@ -45,6 +45,8 @@ def _qkv(B, S, H, KV, D, dtype=jnp.float32):
         (2, 256, 8, 1, 64, True),  # MQA
         (2, 256, 4, 2, 64, False),  # bidirectional
         (1, 1024, 2, 1, 64, True),  # multiple 512-blocks
+        (1, 2048, 8, 1, 16, True),  # four row blocks: dead, whole and edge blocks at once, a group of 8
+        (1, 2048, 16, 1, 8, True),  # the same at a group of 16
     ],
 )
 def test_forward_matches_reference(B, S, H, KV, D, causal) -> None:
@@ -63,6 +65,8 @@ def test_forward_matches_reference(B, S, H, KV, D, causal) -> None:
         (False, 256, 512, 512),
         (True, 512, 128, 256),  # multi-block dq/dkv accumulation + g_q_map
         (False, 512, 256, 128),
+        (True, 256, 64, 32),  # four row blocks, eight key blocks: whole, edge and dead blocks in one launch
+        (True, 256, 32, 64),  # and the other way round
     ],
 )
 def test_backward_matches_reference(causal, S, bq, bk) -> None:
@@ -291,14 +295,20 @@ def test_flash_ring_attention_matches_dense(monkeypatch) -> None:
 
 
 @pytest.mark.parametrize(
-    "H,KV,D,Dv,S,bq,bk",
+    "H,KV,D,Dv,S,bq,bk,window",
     [
-        (2, 2, 192, 128, 256, 128, 128),  # Ling-3.0-flash's MLA heads, several blocks
-        (4, 2, 48, 32, 256, 512, 512),  # the toy widths, grouped, one block
-        (2, 2, 32, 64, 256, 128, 256),  # v wider than q and k
+        (2, 2, 192, 128, 256, 128, 128, None),  # Ling-3.0-flash's MLA heads, several blocks
+        (4, 2, 48, 32, 256, 512, 512, None),  # the toy widths, grouped, one block
+        (2, 2, 32, 64, 256, 128, 256, None),  # v wider than q and k
+        # JoyAI's and Ling's heads over four row blocks and eight key blocks:
+        # dead, whole and edge blocks in every launch
+        (2, 2, 192, 128, 256, 64, 32, None),
+        (2, 2, 192, 128, 256, 32, 64, None),  # the blocks the other way round
+        (2, 2, 192, 128, 256, 64, 32, 128),  # a window that is a whole number of blocks
+        (2, 2, 192, 128, 256, 32, 64, 100),  # and one that is not
     ],
 )
-def test_value_heads_of_another_size(H, KV, D, Dv, S, bq, bk) -> None:
+def test_value_heads_of_another_size(H, KV, D, Dv, S, bq, bk, window) -> None:
     """Forward and the three gradients against plain attention.  The same
     tolerances as for equal sizes above: float32's rounding through a
     softmax over 256 keys; the output and dv have v's head size."""
@@ -307,13 +317,14 @@ def test_value_heads_of_another_size(H, KV, D, Dv, S, bq, bk) -> None:
     k = jax.random.normal(kk, (2, S, KV, D), jnp.float32)
     v = jax.random.normal(kv, (2, S, KV, Dv), jnp.float32)
     flash = lambda q, k, v: flash_attention(  # noqa: E731
-        q, k, v, causal=True, block_q=bq, block_k=bk, interpret=True
+        q, k, v, causal=True, block_q=bq, block_k=bk, window=window, interpret=True
     )
+    ref = _ref_attention if window is None else lambda q, k, v: _ref_windowed(q, k, v, window)  # noqa: E731
     out = flash(q, k, v)
     assert out.shape == (2, S, H, Dv)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(_ref_attention(q, k, v)), rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref(q, k, v)), rtol=2e-5, atol=2e-5)
     g_flash = jax.grad(lambda *a: jnp.sum(jnp.sin(flash(*a))), argnums=(0, 1, 2))(q, k, v)
-    g_ref = jax.grad(lambda *a: jnp.sum(jnp.sin(_ref_attention(*a))), argnums=(0, 1, 2))(q, k, v)
+    g_ref = jax.grad(lambda *a: jnp.sum(jnp.sin(ref(*a))), argnums=(0, 1, 2))(q, k, v)
     for name, a, b in zip("qkv", g_flash, g_ref):
         assert a.shape == b.shape
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-4, err_msg=f"d{name}")
@@ -344,47 +355,6 @@ def _ref_windowed(q, k, v, window):
     return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1).astype(q.dtype), vf)
 
 
-WINDOW_CASES = [
-    # S, H, KV, D, bq, bk, window
-    (512, 4, 2, 64, 128, 128, 256),  # a multiple of the block, under the sequence
-    (512, 4, 2, 64, 128, 128, 200),  # no multiple of the block
-    (512, 4, 2, 64, 128, 128, 1),  # the query's own position alone
-    (512, 4, 2, 64, 128, 128, 129),  # one position past a block
-    (512, 4, 2, 64, 128, 128, 512),  # at the sequence: causal
-    (512, 4, 2, 64, 128, 128, 700),  # over the sequence: causal
-    (512, 4, 2, 64, 64, 128, 150),  # row blocks smaller than key blocks
-    (512, 4, 2, 64, 256, 64, 100),  # key blocks smaller than row blocks
-    (256, 32, 4, 16, 64, 64, 96),  # 32 query heads over 4 KV heads
-]
-
-
-@pytest.mark.parametrize("S,H,KV,D,bq,bk,window", WINDOW_CASES)
-def test_window_forward_matches_masked_attention(S, H, KV, D, bq, bk, window) -> None:
-    q, k, v = _qkv(1, S, H, KV, D)
-    out = flash_attention(q, k, v, block_q=bq, block_k=bk, window=window, interpret=True)
-    np.testing.assert_allclose(
-        np.asarray(out), np.asarray(_ref_windowed(q, k, v, window)), rtol=2e-5, atol=2e-5
-    )
-
-
-@pytest.mark.parametrize("wrt", ["dq", "dkv"])
-@pytest.mark.parametrize("S,H,KV,D,bq,bk,window", WINDOW_CASES)
-def test_window_backward_matches_masked_attention(S, H, KV, D, bq, bk, window, wrt) -> None:
-    q, k, v = _qkv(1, S, H, KV, D)
-    argnums = (0,) if wrt == "dq" else (1, 2)
-
-    def loss(attend):
-        return lambda q, k, v: jnp.sum(jnp.sin(attend(q, k, v)))
-
-    flash = lambda q, k, v: flash_attention(  # noqa: E731
-        q, k, v, block_q=bq, block_k=bk, window=window, interpret=True
-    )
-    got = jax.grad(loss(flash), argnums=argnums)(q, k, v)
-    want = jax.grad(loss(lambda q, k, v: _ref_windowed(q, k, v, window)), argnums=argnums)(q, k, v)
-    for a, b in zip(got, want):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-4)
-
-
 def _pallas_calls(fn, *args):
     """(name, grid) of every ``pallas_call`` in ``fn``'s gradient program."""
     found = []
@@ -401,21 +371,22 @@ def _pallas_calls(fn, *args):
 
 
 def test_window_walks_only_its_blocks_and_names_its_programs() -> None:
-    """The grid holds the window's blocks plus one and not the sequence's:
-    a window that masked a full walk would keep the full grid."""
+    """A launch's grid holds exactly the pairs of blocks with a live pair of
+    positions, windowed or not: a walk that masked the dead ones would keep
+    the rectangle."""
     q, k, v = _qkv(1, 2048, 8, 1, 16)
     blocks = dict(block_q=128, block_k=128, interpret=True)
     windowed = _pallas_calls(lambda q, k, v: flash_attention(q, k, v, window=256, **blocks), q, k, v)
-    assert windowed == {
-        "flash_win_fwd": (1, 8, 16, 3), "flash_win_dq": (1, 8, 16, 3), "flash_win_dkv": (1, 1, 16, 8 * 3),
-    }
+    # three key blocks a row block, but one and two for the first two: nothing lies before the sequence
+    assert windowed == {"flash_win_fwd": (1, 8, 45), "flash_win_dq": (1, 8, 45), "flash_win_dkv": (1, 1, 8 * 45)}
     full = _pallas_calls(lambda q, k, v: flash_attention(q, k, v, **blocks), q, k, v)
-    assert full == {"flash_fwd": (1, 8, 16, 16), "flash_dq": (1, 8, 16, 16), "flash_dkv": (1, 1, 16, 8 * 16)}
+    live = 16 * 17 // 2  # nq (nq + 1) / 2 at equal blocks, of 256
+    assert full == {"flash_fwd": (1, 8, live), "flash_dq": (1, 8, live), "flash_dkv": (1, 1, 8 * live)}
     # a window that covers the sequence IS causal attention: the full layers' programs
     assert _pallas_calls(lambda q, k, v: flash_attention(q, k, v, window=2048, **blocks), q, k, v) == full
     # no multiple of a block: one more block at the far edge
     odd = _pallas_calls(lambda q, k, v: flash_attention(q, k, v, window=258, **blocks), q, k, v)
-    assert odd["flash_win_fwd"] == (1, 8, 16, 4) and odd["flash_win_dkv"] == (1, 1, 16, 8 * 4)
+    assert odd["flash_win_fwd"] == (1, 8, 45 + 13) and odd["flash_win_dkv"] == (1, 1, 8 * 58)
 
 
 @pytest.mark.parametrize("window,causal", [(0, True), (-3, True), (2.5, True), (True, True), (64, False)])

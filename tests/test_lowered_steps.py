@@ -9,8 +9,15 @@ to before ``flash_attention`` took a window (PR 41: theirs written the same
 way, on PR 41's parent; ``windowed_moe``'s is its own first tree's).  PR 42
 wrote the four expert models' eight digests anew (``--write --only``: their
 step's summary gained a column, ``buffer_rows``, and the experts' rows go
-through their buffer in a loop of passes); ``llama``'s two are PR 41's
-parent's still.  A later change that means to alter one of these programs
+through their buffer in a loop of passes); ``llama``'s two were PR 41's
+parent's still.  PR 50 wrote the ``kernels`` digests of the models that call
+flash anew (``--write --only ling_hybrid,llama,ssm_hybrid_moe,windowed_moe``:
+the kernels' grids hold the live blocks alone and read their walk from
+tables, ``ops/flash_attention.py``); the five ``plain`` digests and both of
+``indexed_sparse_moe`` did NOT change, which is the proof that the plain path
+and Keye's kernels were not touched.  (``latent_moe`` is not here: its digest
+came out another in a worker that had traced other files first.)  A later
+change that means to alter one of these programs
 writes the fixture anew and says so: ``python tests/test_lowered_steps.py
 --write``."""
 

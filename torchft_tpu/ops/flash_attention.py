@@ -17,28 +17,54 @@ costs ``groups``× K/V bandwidth).
 Backward is the standard two-kernel flash scheme over the saved
 logsumexp: ``dq`` accumulates over k-blocks; ``dk``/``dv`` accumulate over
 (q-head-in-group × q-block) so each kv-head's gradient sums its whole GQA
-group without materializing per-q-head copies.  Without a window the grid
-walks every (row block, key block) pair and the causally-dead ones are
-skipped with ``pl.when`` in both directions: their blocks are still fetched.
+group without materializing per-q-head copies.
 
-With a ``window`` (a sliding window: query ``i`` sees keys ``j`` with ``i -
-window < j <= i``, its own position counted) the grid does not hold the dead
-pairs at all.  Forward and ``dq`` walk, for a row block, only the key blocks
-its rows' windows touch (``_key_steps`` of them at most, ending at the
-diagonal block: the index map is offset by the row block), ``dkv`` walks for
-a key block only the row blocks that can see it (the mirror, starting at the
-diagonal), and the two edge blocks are masked inside.  At 16,384 positions,
-a window of 2,048 and blocks of 512 that is 5 key blocks a row block against
-16.5 on average.  These programs are named ``flash_win_fwd``,
-``flash_win_dq`` and ``flash_win_dkv``, so that a trace tells a windowed
-layer's kernels from a full layer's; a window that covers the sequence IS
-causal attention and takes the full layers' programs.
+**The walk.**  From static shapes alone (the sequence, the blocks, the
+window) every (row block, key block) pair of a causal launch is classified
+once (``_live_blocks``) as live (some pair of positions alive) or dead (a key
+block wholly above the diagonal, or wholly behind the window), and a dead
+block is NOT IN THE GRID.  The grid's last axis is the flattened list of the
+live pairs (``_walk``); which row block and key block a step works on comes
+from small int32 tables handed in by scalar prefetch
+(``pltpu.PrefetchScalarGridSpec``: the index maps read them), and a step's
+flags say whether it is the first or the last visit of the block whose output
+it accumulates (init, write).  Forward and ``dq`` walk, for a row block, the
+key blocks with a live pair in ascending order; ``dkv`` walks, for a key block
+and each member of its GQA group in turn, the row blocks that see it: the
+order of accumulation is the rectangle's, so every output is bit for bit what
+a walk of the whole rectangle gives (``scripts/flash_walk_probe.py`` holds the
+programs to their predecessors' on the chip).  No dead block is fetched and
+none costs a grid step.  Every live block runs ONE body, mask and all: a full
+layer is a window of the whole sequence.  At 16,384 positions and blocks of
+512 a full layer's launch walks 528 pairs a head of 1,024; under a window of
+2,048 it walks 150.  ``dkv``'s tables hold a step for every member of the
+group (``_TABLE_BYTES`` bounds them, with an error that says so).
+
+Without causality (ring attention's off-diagonal steps through
+``flash_attention_lse``) no block is dead and nothing needs a mask: the grid
+is the rectangle ``(rows, keys)`` with affine index maps and no table
+(``_where`` tells the two apart inside a kernel).
+
+A ``window`` is a sliding window: query ``i`` sees keys ``j`` with ``i - window
+< j <= i``, its own position counted.  A windowed layer's programs are named
+``flash_win_fwd``, ``flash_win_dq`` and ``flash_win_dkv``, so that a trace
+tells its kernels from a full layer's (``flash_fwd``, ``flash_dq``,
+``flash_dkv``); a window that covers the sequence IS causal attention and
+takes the full layers' programs.
+
+What the chip said (v5e, PR 50, PERF.md section 6): the dead steps were
+15-28 % of a full layer's launches at 32 x 32 blocks (each fetched its
+blocks).  The mask costs NOTHING that shows: a second, maskless body for the
+blocks wholly under the diagonal ran as fast as this one (the vector unit's
+spare slots take the mask), so there is none.  A step's table reads cost
+0.06-0.09 us, which a windowed layer, with few dead steps to lose, pays for
+with 2-4 % of its kernels' time.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -56,8 +82,133 @@ _ROW_LANES = 8
 
 
 # ---------------------------------------------------------------------------
-# which pairs are alive
+# the walk: which blocks a causal launch visits
 # ---------------------------------------------------------------------------
+
+_FIRST, _LAST = 1, 2  # a grid step's flags
+# the most a launch's tables may hold: they are SMEM operands, and ``dkv``'s
+# grow with the live pairs times the GQA group.  A v5e's SMEM is 1 MiB by the
+# compiler's count and a program keeps some 9 KB of its own there (a group of
+# 16 compiles at 44,032 positions and blocks of 512, 958 KB, and not at
+# 49,152); the cells' largest is 135 KB and runs at full pace (PERF.md
+# section 6, PR 50)
+_TABLE_BYTES = 960 << 10
+
+
+def _live_blocks(nq, nk, block_q, block_k, window) -> np.ndarray:
+    """``[nq, nk]``: whether a (row block, key block) pair holds a live pair
+    of positions.  Query ``i`` sees key ``j`` with ``i - window < j <= i``; a
+    full layer is a window of the whole sequence."""
+    first_row = np.arange(nq)[:, None] * block_q
+    last_row = first_row + block_q - 1
+    first_key = np.arange(nk)[None, :] * block_k
+    last_key = first_key + block_k - 1
+    reach = nq * block_q if window is None else window
+    return (last_row >= first_key) & (last_key > first_row - reach)
+
+
+class Walk(NamedTuple):
+    """One launch's grid steps in their order, a table entry a step: the row
+    block and the key block it works on, its flags (the first and the last
+    visit of the block whose output it accumulates) and, for ``dkv``, the
+    member of the GQA group whose rows it reads.  A dead block has no step."""
+
+    q: np.ndarray
+    k: np.ndarray
+    flags: np.ndarray
+    member: Optional[np.ndarray] = None
+
+    @property
+    def steps(self) -> int:
+        return len(self.flags)
+
+    @property
+    def tables(self) -> Tuple[jax.Array, ...]:
+        """The scalar-prefetch operands, in the kernels' order."""
+        held = (self.q, self.k, self.flags) + (() if self.member is None else (self.member,))
+        if 4 * self.steps * len(held) > _TABLE_BYTES:
+            raise ValueError(
+                f"a flash launch of {self.steps} grid steps a head needs {len(held)} int32 tables of "
+                f"that length in SMEM, over {_TABLE_BYTES} bytes: take larger blocks"
+                + ("" if self.member is None else " (dkv holds a step for every member of the GQA group)")
+            )
+        return tuple(jnp.asarray(a, jnp.int32) for a in held)
+
+
+def _walk(live: np.ndarray, groups: Optional[int] = None) -> Walk:
+    """The steps over ``live`` (``_live_blocks``).  Forward and ``dq``
+    (``groups`` None): for a row block, the key blocks with a live pair,
+    ascending.  ``dkv``: for a key block and each member of its GQA group in
+    turn, the row blocks that see it, ascending, so that one accumulator sums
+    the whole group."""
+    by_key = groups is not None
+    members = groups or 1
+    outer, inner, flags, member = [], [], [], []
+    for o, row in enumerate(live.T if by_key else live):
+        (seen,) = np.nonzero(row)
+        assert seen.size, f"block {o} has no live pair to write its output from"
+        run = np.tile(seen, members)
+        f = np.zeros_like(run)
+        f[0] |= _FIRST
+        f[-1] |= _LAST
+        outer.append(np.full_like(run, o))
+        inner.append(run)
+        flags.append(f)
+        member.append(np.repeat(np.arange(members), seen.size))
+    outer, inner, flags, member = (np.concatenate(a) for a in (outer, inner, flags, member))
+    if by_key:
+        return Walk(q=inner, k=outer, flags=flags, member=member)
+    return Walk(q=outer, k=inner, flags=flags)
+
+
+def _row_launch(nq, nk, block_q, block_k, groups, window, causal):
+    """Forward's and ``dq``'s launch over ``(b, h)``: (the grid's further
+    axes, the tables, the index map of a row block's operands, that of the key
+    blocks of its KV head).  Under causality the walk; without it no block is
+    dead and the grid is the rectangle."""
+    if not causal:
+        return (
+            (nq, nk), (),
+            lambda b, h, i, j: (b, h, i, 0),
+            lambda b, h, i, j: (b, h // groups, j, 0),
+        )
+    walk = _walk(_live_blocks(nq, nk, block_q, block_k, window))
+    return (
+        (walk.steps,), walk.tables,
+        lambda b, h, t, qt, kt, ft: (b, h, qt[t], 0),
+        lambda b, h, t, qt, kt, ft: (b, h // groups, kt[t], 0),
+    )
+
+
+def _key_launch(nq, nk, block_q, block_k, groups, window, causal):
+    """``dkv``'s launch over ``(b, kv)``, as ``_row_launch``: a key block's
+    steps run over (group member, row block that sees it)."""
+    if not causal:
+        return (
+            (nk, groups * nq), (),
+            lambda b, kv, j, i: (b, kv * groups + i // nq, i % nq, 0),
+            lambda b, kv, j, i: (b, kv, j, 0),
+        )
+    walk = _walk(_live_blocks(nq, nk, block_q, block_k, window), groups)
+    return (
+        (walk.steps,), walk.tables,
+        lambda b, kv, t, qt, kt, ft, mt: (b, kv * groups + mt[t], qt[t], 0),
+        lambda b, kv, t, qt, kt, ft, mt: (b, kv, kt[t], 0),
+    )
+
+
+def _where(tables):
+    """Where a kernel's grid step is: (row block, key block, whether it is the
+    first visit of the block whose output it accumulates, whether the last).
+    With the walk's ``tables`` all four are read from them; on the rectangle
+    (no causality) the accumulation runs over the innermost axis and nothing
+    asks for the blocks (there is no mask)."""
+    if not tables:
+        inner = pl.program_id(3)
+        return None, None, inner == 0, inner == pl.num_programs(3) - 1
+    step = pl.program_id(2)
+    flags = tables[2][step]
+    return tables[0][step], tables[1][step], (flags & _FIRST) != 0, (flags & _LAST) != 0
 
 
 def _masked(s, qi, ki, block_q, block_k, window):
@@ -76,110 +227,58 @@ def _masked(s, qi, ki, block_q, block_k, window):
     return jnp.where(keep, s, _NEG_INF)
 
 
-def _key_steps(nq, nk, block_q, block_k, window):
-    """The most key blocks one row block's live pairs touch under a window:
-    from its first row's oldest key to the diagonal block."""
-    most = max(
-        (i * block_q + block_q - 1) // block_k - (i * block_q - window + 1) // block_k + 1
-        for i in range(nq)
-    )
-    return min(most, nk)
-
-
-def _row_steps(nq, nk, block_q, block_k, window):
-    """The mirror: the most row blocks that see one key block, from the
-    diagonal block to its last key's last reader."""
-    most = max(
-        (i * block_k + block_k + window - 2) // block_q - (i * block_k) // block_q + 1
-        for i in range(nk)
-    )
-    return min(most, nq)
-
-
-def _walked_k(qi, step, block_q, block_k, steps):
-    """The key block of row block ``qi``'s ``step``-th visit: the walk ENDS
-    at the diagonal block, so an early row block's first visits fall before
-    the sequence (negative: dead, and the index map holds them at 0)."""
-    return jax.lax.div(qi * block_q + block_q - 1, block_k) - (steps - 1) + step
-
-
-def _walked_q(ki, step, block_q, block_k):
-    """The row block of key block ``ki``'s ``step``-th visit: the walk STARTS
-    at the diagonal block, so a late key block's last visits fall past the
-    sequence (dead, and the index map holds them at the last block)."""
-    return jax.lax.div(ki * block_k, block_q) + step
-
-
 # ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
 
 
-def _fwd_kernel(
-    q_ref,  # [1, 1, bq, D]
-    k_ref,  # [1, 1, bk, D]
-    v_ref,  # [1, 1, bk, D]
-    o_ref,  # [1, 1, bq, D]
-    lse_ref,  # [1, 1, bq, _ROW_LANES]
-    m_scr,  # VMEM [bq, _LANES] f32: running row max
-    l_scr,  # VMEM [bq, _LANES] f32: running denominator
-    acc_scr,  # VMEM [bq, D] f32: running (unnormalized) output
-    *,
-    sm_scale: float,
-    causal: bool,
-    block_q: int,
-    block_k: int,
-    num_k_blocks: int,  # the grid's steps a row block: with a window, its walk
-    window: Optional[int] = None,
-):
-    qi = pl.program_id(2)
-    step = pl.program_id(3)
+def _fwd_kernel(*refs, sm_scale: float, causal: bool, block_q: int, block_k: int, window: Optional[int]):
+    (
+        *tables,  # SMEM [steps] int32: the walk (none without causality)
+        q_ref,  # [1, 1, bq, D]
+        k_ref,  # [1, 1, bk, D]
+        v_ref,  # [1, 1, bk, D]
+        o_ref,  # [1, 1, bq, D]
+        lse_ref,  # [1, 1, bq, _ROW_LANES]
+        m_scr,  # VMEM [bq, _LANES] f32: running row max
+        l_scr,  # VMEM [bq, _LANES] f32: running denominator
+        acc_scr,  # VMEM [bq, D] f32: running (unnormalized) output
+    ) = refs
+    qi, ki, first, last = _where(tables)
 
-    @pl.when(step == 0)
+    @pl.when(first)
     def _init():
         m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    if window is None:
-        ki = step
-        # with causality, k-blocks wholly above the diagonal are dead
-        live = (not causal) or (ki * block_k <= qi * block_q + block_q - 1)
-    else:
-        ki = _walked_k(qi, step, block_q, block_k, num_k_blocks)
-        # the walk ends at the diagonal; a block is dead before the sequence
-        # or where its last key is older than the first row's window
-        live = (ki >= 0) & ((ki + 1) * block_k + window - 2 >= qi * block_q)
+    q = q_ref[0, 0]  # [bq, D]
+    k = k_ref[0, 0]  # [bk, D]
+    v = v_ref[0, 0]
 
-    @pl.when(live)
-    def _accumulate():
-        q = q_ref[0, 0]  # [bq, D]
-        k = k_ref[0, 0]  # [bk, D]
-        v = v_ref[0, 0]
-
-        s = (
-            jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            * sm_scale
-        )  # [bq, bk] f32
-        if causal:
-            s = _masked(s, qi, ki, block_q, block_k, window)
-
-        m_prev = m_scr[:, :1]  # [bq, 1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)  # [bq, bk]
-        correction = jnp.exp(m_prev - m_new)  # [bq, 1]
-        l_new = l_scr[:, :1] * correction + jnp.sum(p, axis=1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * correction + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+    s = (
+        jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
+        * sm_scale
+    )  # [bq, bk] f32
+    if causal:
+        s = _masked(s, qi, ki, block_q, block_k, window)
 
-    @pl.when(step == num_k_blocks - 1)
+    m_prev = m_scr[:, :1]  # [bq, 1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    p = jnp.exp(s - m_new)  # [bq, bk]
+    correction = jnp.exp(m_prev - m_new)  # [bq, 1]
+    l_new = l_scr[:, :1] * correction + jnp.sum(p, axis=1, keepdims=True)
+    acc_scr[...] = acc_scr[...] * correction + jax.lax.dot_general(
+        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
+    l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
+
+    @pl.when(last)
     def _finalize():
         m = m_scr[:, :1]
         l = l_scr[:, :1]
@@ -207,64 +306,44 @@ def _fwd(
     B, H, S, D = q.shape
     Dv = v.shape[3]
     KV = k.shape[1]
-    Sk = k.shape[2]
-    groups = H // KV
-    nq, nk = S // block_q, Sk // block_k
-    kv_map, steps, names = _walk_of_keys(groups, nq, nk, block_q, block_k, window)
+    steps, tables, q_map, kv_map = _row_launch(
+        S // block_q, k.shape[2] // block_k, block_q, block_k, H // KV, window, causal
+    )
     kernel = functools.partial(
         _fwd_kernel,
         sm_scale=sm_scale,
         causal=causal,
         block_q=block_q,
         block_k=block_k,
-        num_k_blocks=steps,
         window=window,
     )
     return pl.pallas_call(
         kernel,
-        grid=(B, H, nq, steps),
-        in_specs=[
-            pl.BlockSpec((1, 1, block_q, D), lambda b, h, qi, ki: (b, h, qi, 0)),
-            pl.BlockSpec((1, 1, block_k, D), kv_map),
-            pl.BlockSpec((1, 1, block_k, Dv), kv_map),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, block_q, Dv), lambda b, h, qi, ki: (b, h, qi, 0)),
-            pl.BlockSpec(
-                (1, 1, block_q, _ROW_LANES),
-                lambda b, h, qi, ki: (b, h, qi, 0),
-            ),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(tables),
+            grid=(B, H, *steps),
+            in_specs=[
+                pl.BlockSpec((1, 1, block_q, D), q_map),
+                pl.BlockSpec((1, 1, block_k, D), kv_map),
+                pl.BlockSpec((1, 1, block_k, Dv), kv_map),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, 1, block_q, Dv), q_map),
+                pl.BlockSpec((1, 1, block_q, _ROW_LANES), q_map),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block_q, _LANES), jnp.float32),
+                pltpu.VMEM((block_q, _LANES), jnp.float32),
+                pltpu.VMEM((block_q, Dv), jnp.float32),
+            ],
+        ),
         out_shape=[
             jax.ShapeDtypeStruct((B, H, S, Dv), q.dtype),
             jax.ShapeDtypeStruct((B, H, S, _ROW_LANES), jnp.float32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
-            pltpu.VMEM((block_q, Dv), jnp.float32),
-        ],
         interpret=interpret,
-        name=names[0],
-    )(q, k, v)
-
-
-def _walk_of_keys(groups, nq, nk, block_q, block_k, window):
-    """For forward and ``dq``: (the key blocks' index map over the grid ``(b,
-    h, row block, step)``, the steps a row block, the programs' names)."""
-    if window is None:
-        return (
-            lambda b, h, qi, ki: (b, h // groups, ki, 0),
-            nk,
-            ("flash_fwd", "flash_dq"),
-        )
-    steps = _key_steps(nq, nk, block_q, block_k, window)
-
-    def kv_map(b, h, qi, step):
-        ki = _walked_k(qi, step, block_q, block_k, steps)
-        return (b, h // groups, jnp.maximum(ki, 0), 0)
-
-    return kv_map, steps, ("flash_win_fwd", "flash_win_dq")
+        name="flash_fwd" if window is None else "flash_win_fwd",
+    )(*tables, q, k, v)
 
 
 # ---------------------------------------------------------------------------
@@ -273,8 +352,7 @@ def _walk_of_keys(groups, nq, nk, block_q, block_k, window):
 
 
 def _recompute_p_ds(
-    q, k, lse, do, v, delta, sm_scale, causal, qi, ki, block_q, block_k,
-    window=None,
+    q, k, lse, do, v, delta, sm_scale, causal, qi, ki, block_q, block_k, window,
 ):
     """Shared backward math for one (q-block, k-block) pair: the normalized
     probabilities ``p`` and score-gradient ``ds`` (both [bq, bk], f32).
@@ -297,85 +375,59 @@ def _recompute_p_ds(
     return p, ds
 
 
-def _dq_kernel(
-    q_ref, k_ref, v_ref, lse_ref, do_ref, delta_ref, dq_ref, dq_scr,
-    *, sm_scale, causal, block_q, block_k, num_k_blocks, window=None,
-):
-    qi = pl.program_id(2)
-    step = pl.program_id(3)
+def _dq_kernel(*refs, sm_scale, causal, block_q, block_k, window):
+    *tables, q_ref, k_ref, v_ref, lse_ref, do_ref, delta_ref, dq_ref, dq_scr = refs
+    qi, ki, first, last = _where(tables)  # the forward's walk
 
-    @pl.when(step == 0)
+    @pl.when(first)
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    if window is None:
-        ki = step
-        live = (not causal) or (ki * block_k <= qi * block_q + block_q - 1)
-    else:  # the forward's walk
-        ki = _walked_k(qi, step, block_q, block_k, num_k_blocks)
-        live = (ki >= 0) & ((ki + 1) * block_k + window - 2 >= qi * block_q)
+    _, ds = _recompute_p_ds(
+        q_ref[0, 0], k_ref[0, 0], lse_ref[0, 0][:, :1], do_ref[0, 0],
+        v_ref[0, 0], delta_ref[0, 0][:, :1], sm_scale, causal, qi, ki,
+        block_q, block_k, window,
+    )
+    dq_scr[...] += jax.lax.dot_general(
+        ds.astype(k_ref.dtype), k_ref[0, 0], (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
 
-    @pl.when(live)
-    def _accumulate():
-        _, ds = _recompute_p_ds(
-            q_ref[0, 0], k_ref[0, 0], lse_ref[0, 0][:, :1], do_ref[0, 0],
-            v_ref[0, 0], delta_ref[0, 0][:, :1], sm_scale, causal, qi, ki,
-            block_q, block_k, window,
-        )
-        dq_scr[...] += jax.lax.dot_general(
-            ds.astype(k_ref.dtype), k_ref[0, 0], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-
-    @pl.when(step == num_k_blocks - 1)
+    @pl.when(last)
     def _finalize():
         dq_ref[0, 0] = dq_scr[...].astype(dq_ref.dtype)
 
 
-def _dkv_kernel(
-    q_ref, k_ref, v_ref, lse_ref, do_ref, delta_ref, dk_ref, dv_ref,
-    dk_scr, dv_scr,
-    *, sm_scale, causal, block_q, block_k, num_q_blocks, inner_steps,
-    window=None, walk=None,
-):
-    # ``walk``: with a window, the row blocks a group member visits
-    ki = pl.program_id(2)
-    inner = pl.program_id(3)  # flattened (g, qi): sums the whole GQA group
-    if window is None:
-        qi = inner % num_q_blocks
-    else:
-        qi = _walked_q(ki, inner % walk, block_q, block_k)
+def _dkv_kernel(*refs, sm_scale, causal, block_q, block_k, window):
+    (
+        *tables, q_ref, k_ref, v_ref, lse_ref, do_ref, delta_ref, dk_ref, dv_ref,
+        dk_scr, dv_scr,
+    ) = refs
+    # a key block's steps run over (group member, row block that sees it):
+    # the accumulators sum the whole GQA group
+    qi, ki, first, last = _where(tables)
 
-    @pl.when(inner == 0)
+    @pl.when(first)
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    if window is None:
-        live = (not causal) or (qi * block_q + block_q - 1 >= ki * block_k)
-    else:
-        # the walk starts at the diagonal; a block is dead past the sequence
-        # or where its first row is past the last key's window
-        live = (qi < num_q_blocks) & (qi * block_q <= (ki + 1) * block_k + window - 2)
+    p, ds = _recompute_p_ds(
+        q_ref[0, 0], k_ref[0, 0], lse_ref[0, 0][:, :1], do_ref[0, 0],
+        v_ref[0, 0], delta_ref[0, 0][:, :1], sm_scale, causal, qi, ki,
+        block_q, block_k, window,
+    )
+    do = do_ref[0, 0]
+    dv_scr[...] += jax.lax.dot_general(
+        p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )  # p^T @ do: [bk, D]
+    dk_scr[...] += jax.lax.dot_general(
+        ds.astype(q_ref.dtype), q_ref[0, 0], (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )  # ds^T @ q: [bk, D]
 
-    @pl.when(live)
-    def _accumulate():
-        p, ds = _recompute_p_ds(
-            q_ref[0, 0], k_ref[0, 0], lse_ref[0, 0][:, :1], do_ref[0, 0],
-            v_ref[0, 0], delta_ref[0, 0][:, :1], sm_scale, causal, qi, ki,
-            block_q, block_k, window,
-        )
-        do = do_ref[0, 0]
-        dv_scr[...] += jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # p^T @ do: [bk, D]
-        dk_scr[...] += jax.lax.dot_general(
-            ds.astype(q_ref.dtype), q_ref[0, 0], (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # ds^T @ q: [bk, D]
-
-    @pl.when(inner == inner_steps - 1)
+    @pl.when(last)
     def _finalize():
         dk_ref[0, 0] = dk_scr[...].astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_scr[...].astype(dv_ref.dtype)
@@ -392,9 +444,8 @@ def _bwd(
     B, H, S, D = q.shape
     Dv = v.shape[3]
     KV = k.shape[1]
-    Sk = k.shape[2]
-    groups = H // KV
-    nq, nk = S // block_q, Sk // block_k
+    blocks = (S // block_q, k.shape[2] // block_k, block_q, block_k, H // KV, window, causal)
+    static = dict(sm_scale=sm_scale, causal=causal, block_q=block_q, block_k=block_k, window=window)
 
     delta_rows = jnp.sum(
         do.astype(jnp.float32) * o.astype(jnp.float32),
@@ -405,77 +456,58 @@ def _bwd(
         delta_rows = delta_rows - dlse[..., None].astype(jnp.float32)
     delta = jnp.broadcast_to(delta_rows, (B, H, S, _ROW_LANES))
 
-    q_map = lambda b, h, qi, ki: (b, h, qi, 0)
-    kv_map, steps, names = _walk_of_keys(groups, nq, nk, block_q, block_k, window)
-    row_map = lambda b, h, qi, ki: (b, h, qi, 0)
+    steps, tables, q_map, kv_map = _row_launch(*blocks)
     dq = pl.pallas_call(
-        functools.partial(
-            _dq_kernel, sm_scale=sm_scale, causal=causal,
-            block_q=block_q, block_k=block_k, num_k_blocks=steps, window=window,
+        functools.partial(_dq_kernel, **static),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(tables),
+            grid=(B, H, *steps),
+            in_specs=[
+                pl.BlockSpec((1, 1, block_q, D), q_map),
+                pl.BlockSpec((1, 1, block_k, D), kv_map),
+                pl.BlockSpec((1, 1, block_k, Dv), kv_map),
+                pl.BlockSpec((1, 1, block_q, _ROW_LANES), q_map),
+                pl.BlockSpec((1, 1, block_q, Dv), q_map),
+                pl.BlockSpec((1, 1, block_q, _ROW_LANES), q_map),
+            ],
+            out_specs=pl.BlockSpec((1, 1, block_q, D), q_map),
+            scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         ),
-        grid=(B, H, nq, steps),
-        in_specs=[
-            pl.BlockSpec((1, 1, block_q, D), q_map),
-            pl.BlockSpec((1, 1, block_k, D), kv_map),
-            pl.BlockSpec((1, 1, block_k, Dv), kv_map),
-            pl.BlockSpec((1, 1, block_q, _ROW_LANES), row_map),
-            pl.BlockSpec((1, 1, block_q, Dv), q_map),
-            pl.BlockSpec((1, 1, block_q, _ROW_LANES), row_map),
-        ],
-        out_specs=pl.BlockSpec((1, 1, block_q, D), q_map),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         interpret=interpret,
-        name=names[1],
-    )(q, k, v, lse, do, delta)
+        name="flash_dq" if window is None else "flash_win_dq",
+    )(*tables, q, k, v, lse, do, delta)
 
-    # dk/dv: grid inner dim flattens (group member, q block) so the scratch
-    # accumulator sums the whole GQA group for this kv head
-    if window is None:
-        walk = nq
-        g_q_map = lambda b, kv, ki, i: (b, kv * groups + i // nq, i % nq, 0)
-        g_row_map = lambda b, kv, ki, i: (b, kv * groups + i // nq, i % nq, 0)
-    else:
-        # the mirror of the forward's walk: the row blocks that see this key block
-        walk = _row_steps(nq, nk, block_q, block_k, window)
-
-        def g_q_map(b, kv, ki, i):
-            qi = _walked_q(ki, i % walk, block_q, block_k)
-            return (b, kv * groups + i // walk, jnp.minimum(qi, nq - 1), 0)
-
-        g_row_map = g_q_map
-    inner = groups * walk
-    g_kv_map = lambda b, kv, ki, i: (b, kv, ki, 0)
+    steps, tables, g_q_map, g_kv_map = _key_launch(*blocks)
     dk, dv = pl.pallas_call(
-        functools.partial(
-            _dkv_kernel, sm_scale=sm_scale, causal=causal,
-            block_q=block_q, block_k=block_k, num_q_blocks=nq,
-            inner_steps=inner, window=window, walk=walk,
+        functools.partial(_dkv_kernel, **static),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(tables),
+            grid=(B, KV, *steps),
+            in_specs=[
+                pl.BlockSpec((1, 1, block_q, D), g_q_map),
+                pl.BlockSpec((1, 1, block_k, D), g_kv_map),
+                pl.BlockSpec((1, 1, block_k, Dv), g_kv_map),
+                pl.BlockSpec((1, 1, block_q, _ROW_LANES), g_q_map),
+                pl.BlockSpec((1, 1, block_q, Dv), g_q_map),
+                pl.BlockSpec((1, 1, block_q, _ROW_LANES), g_q_map),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, 1, block_k, D), g_kv_map),
+                pl.BlockSpec((1, 1, block_k, Dv), g_kv_map),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block_k, D), jnp.float32),
+                pltpu.VMEM((block_k, Dv), jnp.float32),
+            ],
         ),
-        grid=(B, KV, nk, inner),
-        in_specs=[
-            pl.BlockSpec((1, 1, block_q, D), g_q_map),
-            pl.BlockSpec((1, 1, block_k, D), g_kv_map),
-            pl.BlockSpec((1, 1, block_k, Dv), g_kv_map),
-            pl.BlockSpec((1, 1, block_q, _ROW_LANES), g_row_map),
-            pl.BlockSpec((1, 1, block_q, Dv), g_q_map),
-            pl.BlockSpec((1, 1, block_q, _ROW_LANES), g_row_map),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, block_k, D), g_kv_map),
-            pl.BlockSpec((1, 1, block_k, Dv), g_kv_map),
-        ],
         out_shape=[
             jax.ShapeDtypeStruct(k.shape, k.dtype),
             jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((block_k, D), jnp.float32),
-            pltpu.VMEM((block_k, Dv), jnp.float32),
-        ],
         interpret=interpret,
         name="flash_dkv" if window is None else "flash_win_dkv",
-    )(q, k, v, lse, do, delta)
+    )(*tables, q, k, v, lse, do, delta)
     return dq, dk, dv
 
 
