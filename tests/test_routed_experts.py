@@ -11,6 +11,8 @@ products of 64 and 32 terms (read: 1e-6).
 """
 
 import dataclasses
+import json
+import os
 from functools import partial
 
 import jax
@@ -19,7 +21,8 @@ import numpy as np
 import pytest
 
 from ftbench.architectures import ling_hybrid_reference as ref
-from torchft_tpu.parallel.moe import RoutedExperts, RoutedExpertsConfig, buffer_passes, buffer_size
+from torchft_tpu.parallel import moe
+from torchft_tpu.parallel.moe import RoutedExperts, RoutedExpertsConfig, buffer_passes, buffer_size, grouped_tiles
 
 TOL = 5e-6
 E, HELD = 16, 4
@@ -316,3 +319,114 @@ def test_rows_a_grouped_kernel_leaves_unwritten_reach_nothing(monkeypatch, rows)
     for a, b in zip(jax.tree_util.tree_leaves(clean), jax.tree_util.tree_leaves(dirty)):
         assert bool(jnp.all(jnp.isfinite(b)))
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-6, atol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# the grouped products' tiles (PR 51)
+# ---------------------------------------------------------------------------
+
+# the five expert cells' configurations, by file under ftbench/configs/
+EXPERT_CONFIGS = (
+    "ling-3.0-flash-ep32-1x1", "keye-vl-2.0-30b-a3b-ep8-1x1", "nemotron-3-nano-30b-a3b-ep8-1x1",
+    "trinity-mini-ep8-1x1", "joyai-llm-flash-ep16-1x1",
+)
+# a layer's calls: the kind ``grouped_tiles`` is asked for, and whether k and n
+# are (dim, hidden) as for ``w_up`` / ``w_gate`` or (hidden, dim) as for ``w_down``
+CALLS = {
+    "fwd.in": ("gmm", False), "fwd.out": ("gmm", True), "bwd.in": ("gmm_t", True), "bwd.out": ("gmm_t", False),
+    "tgmm.in": ("tgmm", False), "tgmm.out": ("tgmm", True),
+}
+
+
+def _cell_shapes(config_name):
+    """``(m, dim, hidden, held)`` of the cell that runs ``config_name``: the
+    buffer ``_held_part`` makes for its traffic's tokens, and its widths."""
+    from tests._ftbench_view import BENCH_DIR, bench
+
+    def read(folder, name):
+        with open(os.path.join(BENCH_DIR, folder, name + ".json")) as f:
+            return json.load(f)
+
+    (cell,) = [w for w in bench()["workloads"] if w["config"] == config_name]
+    cfg, traffic = read("configs", config_name), read("traffic", cell["traffic"])
+    tokens = traffic["seq_len"] * traffic["sequences_per_chip"]
+    held = cfg["experts_held"][1]
+    m = buffer_size(tokens, cfg["num_experts_per_tok"], held, cfg["router_experts"])
+    return m, cfg["hidden_size"], cfg["moe_intermediate_size"], held
+
+
+def _call_tiles(config_name, call):
+    m, dim, hidden, held = _cell_shapes(config_name)
+    kind, swapped = CALLS[call]
+    k, n = (hidden, dim) if swapped else (dim, hidden)
+    return kind, m, k, n, grouped_tiles(kind, m, k, n, held)
+
+
+@pytest.mark.parametrize("call", CALLS)
+@pytest.mark.parametrize("config_name", EXPERT_CONFIGS)
+def test_tiles_follow_the_cells_shapes(config_name, call):
+    """Every call of every expert cell gets tiles the kernels take: the row
+    tile divides the buffer, a tile is a multiple of the (8, 128) layout or
+    the whole dimension, the reckoned scoped memory is under the limit, and
+    none is PR 29's constant (0.4-0.5 us a grid step for 0.085 us of
+    product: ISSUE 51)."""
+    kind, m, k, n, (tm, tk, tn) = _call_tiles(config_name, call)
+    assert m % tm == 0 and tm % 8 == 0
+    assert (tk % 128 == 0 or tk == k) and (tn % 128 == 0 or tn == n)
+    assert tk <= k and tn <= n
+    assert moe.grouped_vmem(kind, tm, tk, tn) <= moe.SCOPED_VMEM
+    assert (tm, tk, tn) != (128, 256, 256)
+    assert 2 * tm * tk * tn / 197e12 > 1e-6  # a grid step holds over a microsecond of the chip's product
+
+
+@pytest.mark.parametrize("call", CALLS)
+def test_an_expert_of_a_hundred_rows_gets_the_smallest_row_tile(call):
+    """Ling's buffer holds 128 rows an expert under a uniform router (the
+    ledger reads 108): a row tile is visited once for each expert with rows
+    in it, so anything over 128 rows is waste there."""
+    m, _, _, held = _cell_shapes("ling-3.0-flash-ep32-1x1")
+    assert 4 * m // (5 * held) == 128
+    assert _call_tiles("ling-3.0-flash-ep32-1x1", call)[-1][0] == 128
+
+
+# toy shapes that hold what the cells hold: a width that is no multiple of 128
+# (Nemotron's 1,856), an expert with no rows, a 128-row tile that spans three
+# experts (rows 0-40, 40-70, 70-120), rows past sum(sizes) in the buffer
+TOY_SIZES = (40, 0, 30, 50, 200)
+TOY_M = 512
+
+
+@pytest.mark.parametrize("vmem_kb", [None, 400], ids=["whole_widths", "several_k_tiles"])
+@pytest.mark.parametrize("k, n", [(384, 200), (200, 384)], ids=["in", "out"])
+def test_grouped_product_agrees_with_ragged_dot_both_ways(monkeypatch, k, n, vmem_kb):
+    """``megablox``'s kernels in interpret mode at the tiles ``grouped_tiles``
+    gives, the value and both gradients against ``lax.ragged_dot``'s; with
+    the scoped memory squeezed the same shapes go through several k tiles
+    (the float32 accumulator's round trips; at k 200 ``megablox``'s mask of
+    the k remainder)."""
+    if vmem_kb:
+        monkeypatch.setattr(moe, "SCOPED_VMEM", vmem_kb * 1024)
+    held = len(TOY_SIZES)
+    tiles = {kind: grouped_tiles(kind, TOY_M, *kn, held) for kind, kn in (("gmm", (k, n)), ("gmm_t", (n, k)), ("tgmm", (k, n)))}
+    assert all(moe.grouped_vmem(kind, *t) <= moe.SCOPED_VMEM for kind, t in tiles.items())
+    assert (tiles["gmm"][1] < k) == bool(vmem_kb)  # k in several tiles only where it has to be
+    ka, kb, kg = jax.random.split(jax.random.PRNGKey(3), 3)
+    lhs = jax.random.normal(ka, (TOY_M, k), jnp.float32)
+    rhs = jax.random.normal(kb, (held, k, n), jnp.float32) / np.sqrt(k)
+    weight = jax.random.normal(kg, (TOY_M, n), jnp.float32)
+    sizes = jnp.asarray(TOY_SIZES, jnp.int32)
+    routed = (jnp.arange(TOY_M) < sum(TOY_SIZES))[:, None]
+
+    def loss(product):
+        # rows past the routed ones are undefined on both paths: masked, as ``_through`` masks them
+        return lambda a, b: jnp.sum(jnp.where(routed, product(a, b), 0.0) * weight)
+
+    with jax.default_matmul_precision("highest"):
+        got = moe.grouped_product(lhs, rhs, sizes, True)
+        want = jax.lax.ragged_dot(lhs, rhs, sizes)
+        d_got = jax.grad(loss(lambda a, b: moe.grouped_product(a, b, sizes, True)), argnums=(0, 1))(lhs, rhs)
+        d_want = jax.grad(loss(lambda a, b: jax.lax.ragged_dot(a, b, sizes)), argnums=(0, 1))(lhs, rhs)
+    np.testing.assert_allclose(np.where(routed, got, 0), np.where(routed, want, 0), rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(np.where(routed, d_got[0], 0), np.where(routed, d_want[0], 0), rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(d_got[1], d_want[1], rtol=1e-5, atol=1e-4)
+    assert float(jnp.max(jnp.abs(d_want[1][1]))) == 0.0  # the expert with no rows

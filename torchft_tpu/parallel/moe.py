@@ -227,6 +227,119 @@ def buffer_size(tokens: int, top_k: int, held: int, num_experts: int) -> int:
     return min(full, -(-5 * full * held // (4 * num_experts * 512)) * 512)
 
 
+# What a kernel may hold in fast memory where its ``pallas_call`` names no
+# limit, as ``megablox``'s does not: the compiler's default on a v5e.
+SCOPED_VMEM = 16 * 2**20
+
+
+def grouped_vmem(kind: str, tm: int, tk: int, tn: int) -> int:
+    """The scoped memory one grouped product is reckoned to need at tiles
+    ``(tm, tk, tn)``, in bytes of bfloat16 operands: two buffers of each of
+    its three blocks, a block's last dimension rounded up to the 128 lanes,
+    and the float32 accumulator, which is the result's block ([tm, tn] of a
+    ``gmm``, [tk, tn] of a ``tgmm``); beside them what the body makes: a
+    ``gmm``'s product before it is added, a ``tgmm``'s first block turned.
+    Alone, a kernel compiles at the blocks and the accumulator to the byte;
+    inside a step's program the compiler asked the turned block as well
+    (16.34 MiB where the blocks and the accumulator are 15.38), and at row
+    tiles of 512 more for a ``gmm`` (PERF.md section 6, PR 51)."""
+    lanes = lambda d: -(-d // 128) * 128  # noqa: E731
+    if kind == "tgmm":  # blocks [tm, tk] and [tm, tn] in, [tk, tn] out
+        return 4 * (tm * lanes(tk) + tm * lanes(tn) + tk * lanes(tn)) + 4 * tk * lanes(tn) + 2 * tm * lanes(tk)
+    matrix = tk * lanes(tn) if kind == "gmm" else tn * lanes(tk)  # stored [n, k] where it is transposed
+    return 4 * (tm * lanes(tk) + matrix + tm * lanes(tn)) + 8 * tm * lanes(tn)
+
+
+def _pieces(d: int) -> List[int]:
+    """What a width may be cut into: itself, its equal parts that are
+    multiples of 128 (2,688 in thirds of 896; 1,856 has none), and the
+    multiples of 128 under it whose last tile is partly empty."""
+    equal = [d // p for p in range(1, d // 128 + 1) if d % (128 * p) == 0]
+    return sorted({d, *equal, *(t for t in (1024, 512, 256, 128) if t < d)}, reverse=True)
+
+
+def grouped_tiles(kind: str, m: int, k: int, n: int, held: int) -> Tuple[int, int, int]:
+    """The tiles ``(tm, tk, tn)`` of one grouped product, from its shapes:
+    ``kind`` "gmm" (rows [m, k] through [held, k, n]), "gmm_t" (the same with
+    the matrices stored [held, n, k]) or "tgmm" ([m, k] and [m, n] into
+    [held, k, n]: the rows are contracted).  A grid step costs a third of a
+    microsecond whatever it holds (PR 29's constant ``(128, 256, 256)`` held
+    0.085 us of product), so a row tile should cross k and n in as few steps
+    as ``grouped_vmem`` admits.  Of the tiles that fit, in this order:
+
+    - a ``gmm``'s k in whole tiles (a last tile partly past k is masked in
+      float32 on every visit);
+    - the fewest grid steps a row tile, ``tk`` and ``tn`` from ``_pieces``;
+      then the least width computed in vain (a last tile partly past the edge);
+    - the larger row tile.  A row tile is visited once for EACH expert with
+      rows in it, ``rows / tm + held`` visits of ``tm`` rows' work, so it
+      follows the rows an expert gets from a uniform router, ``m / 1.25 /
+      held`` (``buffer_size``): 256 where that is 256 or more (512 lost to it
+      in every call), and 128 below that or where 256 would cost a step more;
+    - for a ``gmm`` the fewest k tiles (each more is a round trip of the
+      accumulator), for a ``tgmm`` the squarer result block.
+
+    One answer a call, made when the program is traced; no table of models
+    and no option (the sweep it reproduces: ``scripts/gmm_tile_probe.py``,
+    PERF.md section 6, PR 51)."""
+    uniform = 4 * m // (5 * held)
+    # a buffer that no row tile divides (a toy's) is one tile
+    row_tiles = [tm for tm in (256, 128) if tm <= max(uniform, 128) and m % tm == 0] or [m]
+    tiles = lambda d, t: -(-d // t)  # noqa: E731
+
+    def cost(tile):
+        tm, tk, tn = tile
+        steps, computed = tiles(k, tk) * tiles(n, tn), tiles(k, tk) * tk * tiles(n, tn) * tn
+        ragged_k = kind != "tgmm" and k % tk != 0
+        return (ragged_k, steps, computed, -tm, -min(tk, tn) if kind == "tgmm" else tiles(k, tk))
+
+    fit = [
+        (tm, tk, tn) for tm in row_tiles for tk in _pieces(k) for tn in _pieces(n)
+        if grouped_vmem(kind, tm, tk, tn) <= SCOPED_VMEM
+    ]
+    if not fit:
+        raise ValueError(f"no tiles of a {kind} [{m}, {k}] x [{k}, {n}] fit {SCOPED_VMEM} bytes of scoped memory")
+    return min(fit, key=cost)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(3,))
+def grouped_product(lhs: jax.Array, rhs: jax.Array, sizes: jax.Array, interpret: bool = False) -> jax.Array:
+    """Rows ``lhs`` [m, k], sorted by group, each through its group's matrix
+    of ``rhs`` [held, k, n], by ``megablox``'s kernels at the tiles
+    ``grouped_tiles`` reads off each call's shapes: the forward ``gmm``, the
+    backward ``gmm`` to the rows (the matrices transposed: k and n swap) and
+    the ``tgmm`` to the matrices (the rows are the contracted dimension there)
+    are asked for separately, which ``megablox``'s own ``custom_vjp`` cannot
+    do: it hands all three ONE tiling, and its forward and its ``tgmm`` even
+    the same ``(m, k, n)``.  Rows past ``sum(sizes)`` are left unwritten, in
+    the gradient to the rows too."""
+    from jax.experimental.pallas.ops.tpu.megablox.ops import backend
+
+    (m, k), (held, _, n) = lhs.shape, rhs.shape
+    return backend.gmm(lhs, rhs, sizes, lhs.dtype, grouped_tiles("gmm", m, k, n, held), interpret=interpret)
+
+
+def _grouped_product_fwd(lhs, rhs, sizes, interpret):
+    return grouped_product(lhs, rhs, sizes, interpret), (lhs, rhs, sizes)
+
+
+def _grouped_product_bwd(interpret, kept, grad):
+    from jax.experimental.pallas.ops.tpu.megablox.ops import backend
+
+    lhs, rhs, sizes = kept
+    (m, k), (held, _, n) = lhs.shape, rhs.shape
+    to_rows = backend.gmm(
+        grad, rhs, sizes, lhs.dtype, grouped_tiles("gmm_t", m, n, k, held), transpose_rhs=True, interpret=interpret
+    )
+    to_matrices = backend.tgmm(
+        lhs.swapaxes(0, 1), grad, sizes, rhs.dtype, grouped_tiles("tgmm", m, k, n, held), interpret=interpret
+    )
+    return to_rows, to_matrices, None  # the sizes are integers
+
+
+grouped_product.defvjp(_grouped_product_fwd, _grouped_product_bwd)
+
+
 def buffer_passes(size: int, rows: jax.Array) -> jax.Array:
     """How often a buffer of ``size`` rows is filled to take ``rows`` (a
     count, or one a layer) through it, int32: once even for no rows."""
@@ -347,10 +460,8 @@ class RoutedExperts:
         from torchft_tpu.models.llama import Llama
 
         if Llama._assumed_backend() == "tpu":
-            from jax.experimental.pallas.ops.tpu.megablox import gmm
-
             self.path = "gmm"
-            return gmm(lhs, rhs, sizes, preferred_element_type=lhs.dtype, tiling=(128, 256, 256))
+            return grouped_product(lhs, rhs, sizes)
         self.path = "ragged_dot"
         return jax.lax.ragged_dot(lhs, rhs, sizes)
 
