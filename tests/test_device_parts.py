@@ -1,5 +1,5 @@
 """The parts of the compiled step (``torchft_tpu/obs/spans.py``,
-``DEVICE_PARTS``): every operation that costs device time in the six models'
+``DEVICE_PARTS``): every operation that costs device time in the seven models'
 two step programs is traced under a ``tpuft.<part>`` scope, at toy widths and
 on both paths (plain, and the kernels in interpret mode).  The paths are read
 from the COMPILED text's ``op_name``s: XLA inlines every private function
@@ -15,19 +15,21 @@ import pytest
 
 from torchft_tpu.obs.spans import DEVICE_PARTS, PART_PREFIX, part
 
-MODELS = ("llama", "ling_hybrid", "indexed_sparse_moe", "ssm_hybrid_moe", "windowed_moe", "latent_moe")
+MODELS = ("llama", "ling_hybrid", "indexed_sparse_moe", "ssm_hybrid_moe", "windowed_moe", "latent_moe", "eva")
 CASES = [(m, p) for m in MODELS for p in ("plain", "kernels")]
 EVERY = set(DEVICE_PARTS)
 # Keye has no dense MLP and no shared expert; Mistral has no experts; the
 # prediction module's own work is ``mtp``, and JoyAI's is the one model here
-# that runs the module (Ling's toy preset builds none)
+# that runs the module (Ling's toy preset builds none); EvaByte's is the one
+# mixer that pools
 USES = {
-    "llama": EVERY - {"experts_route", "experts_dispatch", "mtp"},
-    "ling_hybrid": EVERY - {"mtp"},
-    "indexed_sparse_moe": EVERY - {"ffn", "mtp"},
-    "ssm_hybrid_moe": EVERY - {"mtp"},
-    "windowed_moe": EVERY - {"mtp"},
-    "latent_moe": EVERY,
+    "llama": EVERY - {"experts_route", "experts_dispatch", "mtp", "mixer_pool"},
+    "ling_hybrid": EVERY - {"mtp", "mixer_pool"},
+    "indexed_sparse_moe": EVERY - {"ffn", "mtp", "mixer_pool"},
+    "ssm_hybrid_moe": EVERY - {"mtp", "mixer_pool"},
+    "windowed_moe": EVERY - {"mtp", "mixer_pool"},
+    "latent_moe": EVERY - {"mixer_pool"},
+    "eva": EVERY - {"experts_route", "experts_dispatch", "mtp"},
 }
 # what costs time on a device and is never fused away into a neighbour
 HELD = ("dot", "convolution", "gather", "scatter", "sort")
@@ -57,6 +59,10 @@ def _model(name):
         from torchft_tpu.models.windowed_moe import WindowedMoE, windowed_moe_debug
 
         return WindowedMoE(windowed_moe_debug()), 128
+    if name == "eva":
+        from torchft_tpu.models.eva import Eva, eva_debug
+
+        return Eva(eva_debug()), 128
     from torchft_tpu.models.ssm_hybrid_moe import SsmHybridMoE, ssm_hybrid_debug
 
     return SsmHybridMoE(ssm_hybrid_debug()), 128
@@ -135,8 +141,42 @@ def test_every_costly_operation_has_a_part(name, path):
     assert every and {innermost(p) for p in every} == {"optimizer"}
 
 
+@pytest.mark.parametrize("path", ["plain", "kernels"])
+def test_the_poolings_operations_are_under_their_part_and_nothing_elses_is(path):
+    """``tpuft.mixer_pool`` is ``Eva._pool``, whole and alone.  The function
+    compiled BY ITSELF, forward and backward, has every operation under the
+    part; in the gradient step the part is entered from the mixer's glue
+    alone, forward, rematerialised and backward, and holds no primitive that
+    the function by itself does not (rope's angles, a projection, the joining
+    of the two key sources stay the glue's and the projections')."""
+    import jax
+    import jax.numpy as jnp
+
+    model, _ = _model("eva")
+    cfg = model.config
+    k = jax.ShapeDtypeStruct((1, 64, cfg.n_heads, cfg.head_dim), jnp.float32)
+    vector = jax.ShapeDtypeStruct((cfg.n_heads, cfg.head_dim), jnp.float32)
+    total = lambda *a: sum(jnp.sum(x) for x in model._pool(*a))  # noqa: E731
+    alone = []
+    for program in (jax.jit(model._pool), jax.jit(jax.grad(total, argnums=(0, 1, 2, 3)))):
+        every, _ = _paths(program.lower(k, k, vector, vector).compile().as_text())
+        alone += [p for p in every if "tpuft." in p or p.count("/") > 1]
+    assert len(alone) >= 10 and all(innermost(p) == "mixer_pool" for p in alone), [p for p in alone if innermost(p) != "mixer_pool"][:5]
+    tail = lambda p: p.rstrip(":").rsplit("/", 1)[-1]  # noqa: E731
+    its_own = {tail(p) for p in alone}
+    every, _ = _paths(_compiled_steps("eva", path)[0])
+    under = [p for p in every if innermost(p) == "mixer_pool"]
+    assert under and all("tpuft.mixer_glue/tpuft.mixer_pool/" in p for p in under)
+    assert {tail(p) for p in under} <= its_own, sorted({tail(p) for p in under} - its_own)
+    assert not its_own & {"cos", "sin", "dot_general", "concatenate", "pallas_call"}
+    for which in ("jvp(", "transpose(", "rematted_computation"):
+        assert any(which in p for p in under), which
+    # rope is there, and the glue's
+    assert any(tail(p) in ("cos", "sin") and innermost(p) == "mixer_glue" for p in every)
+
+
 def test_the_vocabulary_is_closed():
-    assert len(DEVICE_PARTS) == len(set(DEVICE_PARTS)) == 11
+    assert len(DEVICE_PARTS) == len(set(DEVICE_PARTS)) == 12
     with pytest.raises(ValueError, match="nonsense"):
         part("nonsense")
     for name in DEVICE_PARTS:
@@ -153,6 +193,8 @@ def test_the_vocabulary_is_closed():
         # the module's layer names its own parts; the module's own work is its part
         ("jit(_step)/jvp(tpuft.mtp)/tpuft.stream/add", "stream"),
         ("jit(_step)/transpose(jvp(tpuft.mtp))/dot_general", "mtp"),
+        # the pooling inside the glue is the pooling's
+        ("jit(_step)/jvp(tpuft.layers)/while/body/checkpoint/tpuft.mixer_glue/tpuft.mixer_pool/reduce_sum", "mixer_pool"),
         ("jit(_step)/concatenate", None),
         ("", None),
         (None, None),

@@ -301,3 +301,23 @@ def test_latent_attention_and_the_prediction_module_are_defined_once_under_both_
     code and nothing of the Manager."""
     assert [ROWS[i][0] for i in _rows_of(module)] == [row]
     assert {target for importer, _line, target in _inner_edges() if importer == module} == may_import
+
+
+@pytest.mark.parametrize(
+    "module,row,may_import",
+    [
+        ("ops.flash_attention", "store-kernels-data", set()),
+        (
+            "models.eva", "compiled-step-models",
+            {"ops.flash_attention", "parallel.moe", "models.llama", "models.latent", "obs.spans"},
+        ),
+    ],
+)
+def test_chunk_summary_attention_is_model_code_over_the_flash_kernels(module: str, row: str, may_import: set) -> None:
+    """PR 52's module and the entry it made the flash kernels take (two key
+    sources under one softmax, a second rule of liveness in the one walk): the
+    kernels in the kernels' row, importing nothing of the package; the model
+    in the models', calling ``Llama``'s projections, rope and norm, the shared
+    SwiGLU and cross-entropy, and nothing of the Manager."""
+    assert [ROWS[i][0] for i in _rows_of(module)] == [row]
+    assert {target for importer, _line, target in _inner_edges() if importer == module} == may_import

@@ -16,7 +16,10 @@ the kernels' grids hold the live blocks alone and read their walk from
 tables, ``ops/flash_attention.py``); the five ``plain`` digests and both of
 ``indexed_sparse_moe`` did NOT change, which is the proof that the plain path
 and Keye's kernels were not touched.  (``latent_moe`` is not here: its digest
-came out another in a worker that had traced other files first.)  A later
+came out another in a worker that had traced other files first.)  PR 52 ADDED
+``eva``'s two (``--write --only eva``: ``flash_attention`` took a second rule
+of liveness, two key sources under one softmax, and ``DEVICE_PARTS`` a
+twelfth part) and changed none of the ten.  A later
 change that means to alter one of these programs
 writes the fixture anew and says so: ``python tests/test_lowered_steps.py
 --write``."""
@@ -30,7 +33,7 @@ import sys
 import pytest
 
 FIXTURE = os.path.join(os.path.dirname(os.path.abspath(__file__)), "fixtures", "lowered_steps.json")
-MODELS = ("ling_hybrid", "indexed_sparse_moe", "llama", "ssm_hybrid_moe", "windowed_moe")
+MODELS = ("ling_hybrid", "indexed_sparse_moe", "llama", "ssm_hybrid_moe", "windowed_moe", "eva")
 CASES = [(m, p) for m in MODELS for p in ("plain", "kernels")]
 
 
@@ -51,6 +54,10 @@ def _model(name):
         from torchft_tpu.models.windowed_moe import WindowedMoE, windowed_moe_debug
 
         return WindowedMoE(windowed_moe_debug()), 64
+    if name == "eva":
+        from torchft_tpu.models.eva import Eva, eva_debug
+
+        return Eva(eva_debug()), 64
     from torchft_tpu.models.indexed_sparse_moe import IndexedSparseMoE, indexed_sparse_debug
 
     return IndexedSparseMoE(indexed_sparse_debug()), 32

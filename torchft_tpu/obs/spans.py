@@ -37,6 +37,9 @@ operations; these are the names the program gives out::
     flash_win_fwd, flash_win_dq, flash_win_dkv
                                        (the same with a window: the grid
                                         holds the window's blocks alone)
+    eva_fwd, eva_dq, eva_dkv           (the same over two key sources under
+                                        one softmax, ``eva_attention``: chunk
+                                        summaries and a window's tokens)
     kda_fwd, kda_bwd                   (ops/kda.py: the chunked delta rule)
     ...gmm..., ...tgmm...              (parallel/moe.py RoutedExperts: jax's
                                         megablox kernels, named after the
@@ -45,7 +48,7 @@ operations; these are the names the program gives out::
 **The parts of the compiled step.**  A device operation that XLA makes has
 no name of ours, only the path of ``jax.named_scope``s it was traced under
 (``op_name`` in the compiled program, ``tf_op`` in a trace's event metadata).
-:class:`part` opens one of these eleven scopes (``with part("ffn"):`` around
+:class:`part` opens one of these twelve scopes (``with part("ffn"):`` around
 some lines, ``@part("ffn")`` on a function that is one part whole), ONE
 vocabulary for every architecture (:data:`DEVICE_PARTS`); the innermost one
 on an operation's path is its part::
@@ -62,6 +65,12 @@ on an operation's path is its part::
                             softplus and decays, gates, the gated norm, splits,
                             reshapes and their layout copies), and the plain
                             path where no kernel runs
+    tpuft.mixer_pool        a mixer's pooling of keys and values into chunk
+                            summaries (``models/eva.py``): the scores against
+                            the learned vector, the softmax over a chunk, the
+                            two weighted sums, the learned offset, and their
+                            backward pass; told from the glue so that the
+                            mechanism's XLA half has a time of its own
     tpuft.ffn               dense MLPs: SwiGLU, a dense layer, the shared expert
     tpuft.experts_route     router product, scores, groups, top-k, the load
                             count, the balance loss
@@ -135,7 +144,7 @@ SPANS_ENV = "TORCHFT_FLIGHT_SPANS"
 
 # the parts of the compiled step (the module docstring says what lies under each)
 DEVICE_PARTS = (
-    "embed", "stream", "mixer_proj", "mixer_glue", "ffn", "experts_route",
+    "embed", "stream", "mixer_proj", "mixer_glue", "mixer_pool", "ffn", "experts_route",
     "experts_dispatch", "head", "mtp", "layers", "optimizer",
 )
 PART_PREFIX = "tpuft."

@@ -52,6 +52,24 @@ tells its kernels from a full layer's (``flash_fwd``, ``flash_dq``,
 ``flash_dkv``); a window that covers the sequence IS causal attention and
 takes the full layers' programs.
 
+**Two key sources under one softmax** (``eva_attention``; EvaByte's chunked
+attention, arXiv:2302.04542).  The key axis holds a sequence's chunk SUMMARIES
+first (a pooled key and value a chunk of ``chunk`` positions) and its TOKENS
+after them; query ``i`` of window ``w(i) = i // window`` sees the summaries of
+every EARLIER window and the tokens of its own window up to itself, and ONE
+running maximum and ONE denominator run over both.  That is a second rule of
+liveness (``Pooled``) beside the sliding window, taken by the same
+``_live_blocks``, ``_walk``, ``_masked`` and the same three bodies: a row block
+walks its live summary blocks and then its live token blocks, ``dkv`` walks the
+key blocks of both kinds, and the gradient of the key axis is cut back into
+``dk~, dv~`` and ``dk, dv`` where the two were joined.  The blocks divide a
+window, so a block lies in ONE window and is of ONE kind; a token block's mask
+is the diagonal's and a summary block's a bound a row block.  The programs are
+named ``eva_fwd``, ``eva_dq`` and ``eva_dkv``.  Key blocks are 512 for both
+kinds: a window's 128 summaries alone would make a block that is always whole
+or dead, but a step of 512 x 128 holds 0.17 us of product under the 0.4-0.5 us
+a grid step costs (PERF.md section 6, PR 51), and the mask costs nothing.
+
 What the chip said (v5e, PR 50, PERF.md section 6): the dead steps were
 15-28 % of a full layer's launches at 32 x 32 blocks (each fetched its
 blocks).  The mask costs NOTHING that shows: a second, maskless body for the
@@ -95,14 +113,38 @@ _FIRST, _LAST = 1, 2  # a grid step's flags
 _TABLE_BYTES = 960 << 10
 
 
+class Pooled(NamedTuple):
+    """The rule of liveness of two key sources (given where a ``window`` is):
+    the key axis holds ``summaries`` pooled keys first, ``per_window`` of them
+    a window of ``window`` positions, and the tokens after them.  Query ``i``
+    sees summary ``c`` with ``c // per_window < i // window`` and token ``j``
+    with ``j // window == i // window`` and ``j <= i``.  Both kinds of block
+    divide ``window`` and ``summaries``, so a row block lies in one window and
+    a key block is of one kind and, if of tokens, in one window."""
+
+    window: int
+    per_window: int
+    summaries: int
+
+
 def _live_blocks(nq, nk, block_q, block_k, window) -> np.ndarray:
     """``[nq, nk]``: whether a (row block, key block) pair holds a live pair
     of positions.  Query ``i`` sees key ``j`` with ``i - window < j <= i``; a
-    full layer is a window of the whole sequence."""
+    full layer is a window of the whole sequence.  Under ``Pooled`` a summary
+    block is live to the row blocks of a later window than its first
+    summary's, a token block to those of its own window from the diagonal on."""
     first_row = np.arange(nq)[:, None] * block_q
     last_row = first_row + block_q - 1
     first_key = np.arange(nk)[None, :] * block_k
     last_key = first_key + block_k - 1
+    if isinstance(window, Pooled):
+        rows_window = first_row // window.window
+        first_token = first_key - window.summaries
+        return np.where(
+            first_token < 0,
+            first_key < window.per_window * rows_window,
+            (first_token // window.window == rows_window) & (first_token <= last_row),
+        )
     reach = nq * block_q if window is None else window
     return (last_row >= first_key) & (last_key > first_row - reach)
 
@@ -161,6 +203,13 @@ def _walk(live: np.ndarray, groups: Optional[int] = None) -> Walk:
     return Walk(q=outer, k=inner, flags=flags)
 
 
+def _name(window, kernel: str) -> str:
+    """What a launch is called in a trace: by its rule of liveness."""
+    if isinstance(window, Pooled):
+        return "eva_" + kernel
+    return ("flash_" if window is None else "flash_win_") + kernel
+
+
 def _row_launch(nq, nk, block_q, block_k, groups, window, causal):
     """Forward's and ``dq``'s launch over ``(b, h)``: (the grid's further
     axes, the tables, the index map of a row block's operands, that of the key
@@ -189,7 +238,12 @@ def _key_launch(nq, nk, block_q, block_k, groups, window, causal):
             lambda b, kv, j, i: (b, kv * groups + i // nq, i % nq, 0),
             lambda b, kv, j, i: (b, kv, j, 0),
         )
-    walk = _walk(_live_blocks(nq, nk, block_q, block_k, window), groups)
+    live = _live_blocks(nq, nk, block_q, block_k, window)
+    if isinstance(window, Pooled):
+        # a key block no row sees (the LAST window's summaries where they fill
+        # a block, the padding) has its zeros written by one visit, all masked
+        live[-1] |= ~live.any(axis=0)
+    walk = _walk(live, groups)
     return (
         (walk.steps,), walk.tables,
         lambda b, kv, t, qt, kt, ft, mt: (b, kv * groups + mt[t], qt[t], 0),
@@ -214,13 +268,19 @@ def _where(tables):
 def _masked(s, qi, ki, block_q, block_k, window):
     """Scores [bq, bk] of row block ``qi`` against key block ``ki`` with the
     dead pairs (a later key; with a window, one ``window`` or more back) at
-    ``_NEG_INF``."""
+    ``_NEG_INF``.  Under ``Pooled`` a key block is of one kind: of a summary
+    block the row block's window sees the summaries before its own, of a token
+    block (its own window's, by liveness) a row sees up to itself."""
     rows = qi * block_q + jax.lax.broadcasted_iota(
         jnp.int32, (block_q, block_k), 0
     )
     cols = ki * block_k + jax.lax.broadcasted_iota(
         jnp.int32, (block_q, block_k), 1
     )
+    if isinstance(window, Pooled):
+        seen = (qi * block_q) // window.window * window.per_window
+        last = jnp.where(ki * block_k < window.summaries, seen - 1, rows + window.summaries)
+        return jnp.where(cols <= last, s, _NEG_INF)
     keep = rows >= cols
     if window is not None:
         keep = keep & (cols > rows - window)
@@ -342,7 +402,7 @@ def _fwd(
             jax.ShapeDtypeStruct((B, H, S, _ROW_LANES), jnp.float32),
         ],
         interpret=interpret,
-        name="flash_fwd" if window is None else "flash_win_fwd",
+        name=_name(window, "fwd"),
     )(*tables, q, k, v)
 
 
@@ -475,7 +535,7 @@ def _bwd(
         ),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
-        name="flash_dq" if window is None else "flash_win_dq",
+        name=_name(window, "dq"),
     )(*tables, q, k, v, lse, do, delta)
 
     steps, tables, g_q_map, g_kv_map = _key_launch(*blocks)
@@ -506,7 +566,7 @@ def _bwd(
             jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
         interpret=interpret,
-        name="flash_dkv" if window is None else "flash_win_dkv",
+        name=_name(window, "dkv"),
     )(*tables, q, k, v, lse, do, delta)
     return dq, dk, dv
 
@@ -581,6 +641,32 @@ def _flash_hm_bwd(sm_scale, causal, block_q, block_k, interpret, window, res, do
 
 
 _flash_hm.defvjp(_flash_hm_fwd, _flash_hm_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _pooled_hm(q, k, v, sm_scale, block_q, block_k, interpret, rule):
+    """``_flash_hm`` under a ``Pooled`` rule, whose kept row statistics are
+    ONE number a row: the kernels' ``[B, H, S, 8]`` float32 lies padded to 128
+    lanes in HBM (537 MB a layer at 32 heads and 32,768 positions, 2.1 GB kept
+    over four layers), ``[B, H, S]`` is 4 MB and is spread again where the
+    backward kernels read it."""
+    o, _ = _fwd(q, k, v, sm_scale, True, block_q, block_k, interpret, rule)
+    return o
+
+
+def _pooled_hm_fwd(q, k, v, sm_scale, block_q, block_k, interpret, rule):
+    o, lse = _fwd(q, k, v, sm_scale, True, block_q, block_k, interpret, rule)
+    o, lse = (checkpoint_name(a, n) for a, n in zip((o, lse[..., 0]), KEPT_NAMES))
+    return o, (q, k, v, o, lse)
+
+
+def _pooled_hm_bwd(sm_scale, block_q, block_k, interpret, rule, res, do):
+    *rest, lse = res
+    lse = jnp.broadcast_to(lse[..., None], (*lse.shape, _ROW_LANES))
+    return _bwd(sm_scale, True, block_q, block_k, interpret, (*rest, lse), do, window=rule)
+
+
+_pooled_hm.defvjp(_pooled_hm_fwd, _pooled_hm_bwd)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
@@ -683,6 +769,66 @@ def flash_attention(
         block_k,
         interpret,
         None if window is None else int(window),
+    )
+    return out.transpose(0, 2, 1, 3)
+
+
+def eva_attention(
+    q: jax.Array,
+    k: jax.Array,
+    v: jax.Array,
+    k_pooled: jax.Array,
+    v_pooled: jax.Array,
+    *,
+    window: int,
+    sm_scale: Optional[float] = None,
+    block_q: int = 512,
+    block_k: int = 512,
+    interpret: bool = False,
+) -> jax.Array:
+    """Attention over two key sources under ONE softmax (the module
+    docstring, ``Pooled``): q [B, S, H, D]; the tokens' k, v [B, S, KV, D];
+    the chunk summaries ``k_pooled``, ``v_pooled`` [B, S // chunk, KV, D], a
+    pooled key and value a chunk, ``window // chunk`` of them a window.
+    Query ``i`` sees the tokens ``j <= i`` of its own window ``i // window``
+    and the summaries of every earlier window.  Returns [B, S, H, D];
+    differentiable in all five (``eva_fwd``, ``eva_dq``, ``eva_dkv``: the key
+    axis' gradient is cut back into the summaries' and the tokens').
+
+    ``window`` divides the sequence and the blocks divide ``window``.  The
+    summaries are padded with dead keys to a whole number of key blocks.  A
+    window that covers the sequence sees no summary and IS causal attention
+    (``flash_fwd``, ``flash_dq``, ``flash_dkv``): the summaries then take no
+    gradient."""
+    B, S, H, D = q.shape
+    chunks = k_pooled.shape[1]
+    if isinstance(window, bool) or not isinstance(window, (int, np.integer)) or window < 1:
+        raise ValueError(f"a window is a static whole number of positions >= 1, got {window!r}")
+    if window >= S:
+        return flash_attention(
+            q, k, v, causal=True, sm_scale=sm_scale, block_q=block_q, block_k=block_k, interpret=interpret
+        )
+    block_q, block_k = min(block_q, window), min(block_k, window)
+    if S % window or window % block_q or window % block_k or chunks % (S // window):
+        raise ValueError(
+            f"S={S} holds whole windows of {window}, a window whole blocks ({block_q}, {block_k}) and "
+            f"the {chunks} summaries as many a window"
+        )
+    if k.shape != v.shape or k_pooled.shape != v_pooled.shape or k_pooled.shape[2:] != k.shape[2:] or H % k.shape[2]:
+        raise ValueError(
+            f"tokens and summaries share KV heads and a head size, got q={q.shape} k={k.shape} v={v.shape} "
+            f"k_pooled={k_pooled.shape} v_pooled={v_pooled.shape}"
+        )
+    padding = -chunks % block_k
+    rule = Pooled(int(window), chunks // (S // window), chunks + padding)
+
+    def key_axis(pooled, tokens):  # heads-major: the summaries, their padding, the tokens
+        pooled = jnp.pad(pooled, ((0, 0), (0, padding), (0, 0), (0, 0)))
+        return jnp.concatenate([pooled, tokens], axis=1).transpose(0, 2, 1, 3)
+
+    out = _pooled_hm(
+        q.transpose(0, 2, 1, 3), key_axis(k_pooled, k), key_axis(v_pooled, v),
+        float(1.0 / np.sqrt(D) if sm_scale is None else sm_scale), block_q, block_k, interpret, rule,
     )
     return out.transpose(0, 2, 1, 3)
 
