@@ -1,17 +1,26 @@
 """On the chip: the kernels of ``ops/indexed_attention.py`` against the plain
 path at a length the dense arrays fit, and each kernel's time at the cell's
 length.  ``chiprun -- python3 scripts/indexed_attention_probe.py``; the last
-line is ``PROBE {...}``."""
+line is ``PROBE {...}``.
+
+``--parent _parent`` (a checkout of another commit, ``git archive``) times
+that copy's ``dsa_attn_fwd``, ``dsa_attn_dq``, ``dsa_attn_dkv`` and
+``dsa_probs`` beside the tree's on the same operands, in turn (parent, tree,
+tree, parent), and says whether every output is EQUAL bit for bit (PR 53:
+the launches walk their live blocks alone).  ``--compile-only`` compiles the
+tree's four for a described v5e without one (``JAX_PLATFORMS=cpu``)."""
 
 from __future__ import annotations
 
 import argparse
+import importlib.util
 import json
 import os
 import sys
 import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
@@ -33,10 +42,10 @@ def operands(seq, seed, dtype, heads=32, kv=4, dim=128, index_heads=16, index_di
     )
 
 
-def kernels(topk):
+def kernels(topk, interpret=False):
     def f(q, k, v, qi, ki, w):
-        mask, lse_i, n = ia.select_keys(qi, ki, w, topk=topk)
-        o, kl = ia.indexed_attention(q, k, v, qi, ki, w, mask, lse_i)
+        mask, lse_i, n = ia.select_keys(qi, ki, w, topk=topk, interpret=interpret)
+        o, kl = ia.indexed_attention(q, k, v, qi, ki, w, mask, lse_i, interpret=interpret)
         return jnp.sum(o.astype(jnp.float32) ** 2) + kl, (o, kl, jnp.mean(n))
 
     return f
@@ -50,6 +59,59 @@ def plain(topk):
     return f
 
 
+def _load(checkout):
+    """``ops/indexed_attention.py`` of another checkout, as a module."""
+    spec = importlib.util.spec_from_file_location(
+        "indexed_attention_parent", os.path.join(checkout, "torchft_tpu", "ops", "indexed_attention.py")
+    )
+    module = importlib.util.module_from_spec(spec)
+    sys.modules[spec.name] = module
+    spec.loader.exec_module(module)
+    return module
+
+
+def launches(module, seq, dim, interpret=False):
+    """The attention's three launches and ``dsa_probs`` of ``module`` as
+    jitted programs over heads-major operands (an output that is not returned
+    takes its launch with it)."""
+    blocks = module.Blocks().fit(seq)
+    scale = 1.0 / float(np.sqrt(dim))
+    bwd = lambda *a: module._attn_bwd(*a, scale, blocks, interpret)  # noqa: E731
+    return dict(
+        attn_fwd=jax.jit(lambda *a: module._attn_fwd(*a, scale, blocks, interpret)),
+        attn_dq=jax.jit(lambda *a: bwd(*a)[0]),
+        attn_dkv=jax.jit(lambda *a: bwd(*a)[1:]),
+        probs=jax.jit(lambda *a: module._index_loss(*a, scale, blocks, interpret)),
+    )
+
+
+def compile_only(seq, heads=32, kv=4, dim=128, index_heads=16, index_dim=64):
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    chip = SingleDeviceSharding(topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2").devices[0])
+    blocks = ia.Blocks().fit(seq)
+    a = lambda dtype, *dims: jax.ShapeDtypeStruct(dims, dtype, sharding=chip)  # noqa: E731
+    bf, f32 = jnp.bfloat16, jnp.float32
+    q, kv_, lse = a(bf, 1, heads, seq, dim), a(bf, 1, kv, seq, dim), a(f32, 1, heads, seq, ia._ROW_LANES)
+    mask = a(jnp.int32, 1, -(-(seq // blocks.k) // 32), seq, blocks.k)
+    bwd = (q, kv_, kv_, mask, q, lse, q)
+    args = dict(
+        attn_fwd=(q, kv_, kv_, mask), attn_dq=bwd, attn_dkv=bwd,
+        probs=(
+            q, kv_, lse, mask, a(bf, 1, index_heads, seq, index_dim), a(f32, 1, index_heads, seq, ia._ROW_LANES),
+            a(bf, 1, seq, index_dim), a(f32, 1, seq, ia._ROW_LANES),
+        ),
+    )
+    steps = ia._steps(seq, blocks).steps
+    report = dict(steps=steps, dkv_steps=ia._steps(seq, blocks, by_key=True).steps, table_bytes=4 * 3 * steps)
+    for name, fn in launches(ia, seq, dim).items():
+        t0 = time.perf_counter()
+        fn.lower(*args[name]).compile()
+        report[name + "_compile_s"] = round(time.perf_counter() - t0, 1)
+    print("PROBE " + json.dumps(report))
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--check-seq", type=int, default=4096)
@@ -57,14 +119,22 @@ def main():
     ap.add_argument("--seq", type=int, default=16384)
     ap.add_argument("--topk", type=int, default=2048)
     ap.add_argument("--rounds", type=int, default=3)
+    ap.add_argument("--parent", default="", help="a checkout whose launches are timed beside the tree's")
+    ap.add_argument("--compile-only", action="store_true")
+    ap.add_argument("--toy", action="store_true", help="on the CPU in interpret mode, 1,024 positions")
     args = ap.parse_args()
+    if args.compile_only:
+        return compile_only(args.seq)
+    toy = args.toy
+    if toy:
+        args.check_seq, args.check_topk, args.seq, args.topk, args.rounds = 512, 64, 1024, 128, 1
     out = dict(device=jax.devices()[0].device_kind)
 
     # exactness at float32-scored bfloat16 operands: the same picks, and the
     # outputs and gradients to the plain path's rounding
     ops = operands(args.check_seq, 1, jnp.bfloat16)
     grad = lambda f: jax.jit(jax.value_and_grad(f, argnums=tuple(range(6)), has_aux=True))  # noqa: E731
-    (_, (o1, kl1, n1)), g1 = grad(kernels(args.check_topk))(*ops)
+    (_, (o1, kl1, n1)), g1 = grad(kernels(args.check_topk, toy))(*ops)
     (_, (o2, kl2, n2)), g2 = grad(plain(args.check_topk))(*ops)
     rel = lambda a, b: float(  # noqa: E731
         jnp.linalg.norm(a.astype(jnp.float32) - b.astype(jnp.float32)) / (jnp.linalg.norm(b.astype(jnp.float32)) + 1e-30)
@@ -90,21 +160,32 @@ def main():
         print(name, timed[name], flush=True)
         return r
 
-    mask, lse_i, n = clock("select_keys_ms", lambda qi, ki, w: ia.select_keys(qi, ki, w, topk=args.topk), qi, ki, w)
+    mask, lse_i, n = clock("select_keys_ms", lambda qi, ki, w: ia.select_keys(qi, ki, w, topk=args.topk, interpret=toy), qi, ki, w)
     out["keys_per_query"] = float(jnp.mean(n))
-    blocks = ia.Blocks().fit(args.seq)
     qh, kh, vh, qih, wh = ia._heads_major(q, k, v, qi, w)
-    scale = 1.0 / float(np.sqrt(q.shape[-1]))
-    o, lse = clock("attn_fwd_ms", lambda *a: ia._attn_fwd(*a, scale, blocks, False), qh, kh, vh, mask)
-    clock("attn_bwd_ms", lambda *a: ia._attn_bwd(*a, scale, blocks, False), qh, kh, vh, mask, o, lse, o)
-    clock(
-        "probs_ms", lambda *a: ia._index_loss(*a, scale, blocks, False),
-        qh, kh, lse, mask, qih, wh, ki, ia._row_lanes(lse_i),
-    )
-    clock("whole_grad_ms", jax.grad(lambda *a: kernels(args.topk)(*a)[0], argnums=tuple(range(6))), *ops)
+    sides = [("", ia)]
+    if args.parent:
+        parent = ("parent_", _load(args.parent))
+        sides = [parent, sides[0], ("again_", ia), ("parent_again_", parent[1])]
+    got = {}
+    for prefix, module in sides:
+        run = launches(module, args.seq, q.shape[-1], toy)
+        o, lse = clock(prefix + "attn_fwd_ms", run["attn_fwd"], qh, kh, vh, mask)
+        bwd = (qh, kh, vh, mask, o, lse, o)
+        dq = clock(prefix + "attn_dq_ms", run["attn_dq"], *bwd)
+        dk, dv = clock(prefix + "attn_dkv_ms", run["attn_dkv"], *bwd)
+        loss = clock(prefix + "probs_ms", run["probs"], qh, kh, lse, mask, qih, wh, ki, ia._row_lanes(lse_i))
+        got[prefix] = (o, lse, dq, dk, dv, *loss)
+    if args.parent:
+        names = "o lse dq dk dv kl d_qi d_w d_ki".split()
+        out["equal_to_parent"] = {
+            n: bool(jnp.array_equal(a, b)) for n, a, b in zip(names, got[""], got["parent_"], strict=True)
+        }
+        print("equal_to_parent", out["equal_to_parent"], flush=True)
+    clock("whole_grad_ms", jax.grad(lambda *a: kernels(args.topk, toy)(*a)[0], argnums=tuple(range(6))), *ops)
     from torchft_tpu.ops.flash_attention import flash_attention
 
-    clock("dense_flash_fwd_ms", lambda q, k, v: flash_attention(q, k, v, causal=True), q, k, v)
+    clock("dense_flash_fwd_ms", lambda q, k, v: flash_attention(q, k, v, causal=True, interpret=toy), q, k, v)
     out["ms"] = timed
     print("PROBE " + json.dumps(out))
 

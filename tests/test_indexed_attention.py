@@ -1,13 +1,17 @@
 """``ops/indexed_attention.py``: the Mosaic kernels in interpret mode against
 the plain path (dense ``[S, S]`` arrays, ``lax.top_k`` on the full row) and
-against dense causal attention where every key is picked.  Float32, seeded
-operands, the CPU."""
+against dense causal attention where every key is picked; the launches'
+grids against the live (row block, key block) pairs, and their outputs, bit
+for bit, against the rectangular launches they were before PR 53
+(``tests/_indexed_rectangle.py``).  Float32, seeded operands, the CPU."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from tests import _indexed_rectangle as rectangle
+from tests.test_flash_attention import _pallas_calls
 from torchft_tpu.ops import indexed_attention as ia
 
 CASES = {
@@ -18,6 +22,10 @@ CASES = {
     "eight_heads_a_kv_head": dict(S=64, topk=16, H=8, KV=1, B=1, blocks=ia.Blocks(16, 16, 32, 16)),
     "tied_scores": dict(S=64, topk=16, H=4, KV=2, B=2, blocks=ia.Blocks(16, 16, 32, 16), ties=True),
     "more_than_32_key_blocks": dict(S=272, topk=24, H=2, KV=1, B=1, blocks=ia.Blocks(16, 8, 136, 8)),
+    # the cell's ratio: four row blocks a key block, a row block sees qi // 4 + 1 key blocks
+    "four_row_blocks_a_key_block": dict(S=256, topk=32, H=4, KV=2, B=1, blocks=ia.Blocks(16, 64, 64, 16)),
+    "shorter_than_a_key_block": dict(S=16, topk=8, H=4, KV=2, B=2, blocks=ia.Blocks(16, 32, 32, 16)),  # one live pair
+    "a_group_of_one": dict(S=64, topk=16, H=2, KV=2, B=1, blocks=ia.Blocks(16, 32, 32, 16)),
 }
 
 
@@ -77,6 +85,79 @@ def test_kernels_are_the_plain_path(case):
     for name, a, b in zip("q k v q_index k_index w".split(), got, want):
         np.testing.assert_allclose(a, b, atol=2e-5, err_msg=name)
         assert float(jnp.max(jnp.abs(b))) > 1e-3, name  # a gradient that is there
+
+
+# the cases that differ in the walk's shape (three more differ in the scores alone)
+@pytest.mark.parametrize("case", sorted(set(CASES) - {"under_topk", "four_times_topk", "tied_scores"}))
+def test_walked_launches_are_the_rectangles_bit_for_bit(case):
+    """What the four launches give over their live pairs is what the parent's
+    gave over the whole rectangle, every bit: the order of accumulation inside
+    a row block and inside a key block is the rectangle's."""
+    cfg = CASES[case]
+    (q, k, v, qi, ki, w), do = _operands(**cfg)
+    blocks = cfg["blocks"].fit(cfg["S"])
+    if case == "shorter_than_a_key_block":
+        assert ia._steps(cfg["S"], blocks).steps == ia._steps(cfg["S"], blocks, by_key=True).steps == 1
+    mask, lse_index, _ = ia.select_keys(qi, ki, w, topk=cfg["topk"], blocks=blocks, interpret=True)
+    qh, kh, vh, qih, wh = ia._heads_major(q, k, v, qi, w)
+    scale = 1.0 / float(np.sqrt(q.shape[-1]))
+
+    def launches(module):
+        o, lse = module._attn_fwd(qh, kh, vh, mask, scale, blocks, True)
+        grads = module._attn_bwd(qh, kh, vh, mask, o, lse, do.transpose(0, 2, 1, 3), scale, blocks, True)
+        loss = module._index_loss(qh, kh, lse, mask, qih, wh, ki, ia._row_lanes(lse_index), scale, blocks, True)
+        return (o, lse, *grads, *loss)
+
+    names = "o lse dq dk dv kl d_q_index d_w d_k_index".split()
+    run = jax.jit(launches, static_argnums=0)
+    for name, got, want in zip(names, run(ia), run(rectangle), strict=True):
+        if name in names[:5]:
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want), err_msg=name)
+        else:
+            # L_I's four are EQUAL on the chip (scripts/indexed_attention_probe.py --parent).  HERE the
+            # interpreter's body is compiled by XLA for the CPU, bare in the walk and under a ``cond`` in
+            # the rectangle, and the two programs differ in the last bit of a few elements (4 of 64 ``kl``
+            # in a_group_of_one, ``d_w`` in one more case)
+            np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-6, atol=1e-7, err_msg=name)
+        assert np.isfinite(np.asarray(got)).all() and float(jnp.max(jnp.abs(got))) > 0, name
+
+
+def _live_pairs(S, bq, bk):
+    """Pairs of blocks with a key at or before a row, counted position by position."""
+    first_key, last_row = np.arange(S // bk)[None, :] * bk, np.arange(S // bq)[:, None] * bq + bq - 1
+    return int(np.count_nonzero(first_key <= last_row))
+
+
+@pytest.mark.parametrize(
+    "S,bq,bk,live",
+    [
+        (2048, 128, 512, 40),
+        (4096, 128, 512, 144),
+        (1024, 128, 128, 36),
+        (16384, 128, 512, 2112),  # the cell: 8,448 steps a launch of 4 KV heads where the rectangle held 16,384
+        (256, 128, 512, 2),  # shorter than a key block: two row blocks see the one
+    ],
+)
+def test_launches_walk_only_their_live_blocks(S, bq, bk, live):
+    """The real ``pallas_call`` grids of the attention's three launches and of
+    ``L_I``'s hold exactly the live pairs: a launch that skipped the dead ones'
+    work (``pl.when``) would keep the rectangle."""
+    blocks = ia.Blocks(bq, bk, 512, 64).fit(S)
+    assert live == _live_pairs(S, blocks.q, blocks.k)
+    B, H, KV, D, J, DI = 1, 32, 4, 16, 2, 8
+    a = lambda *dims, dtype=jnp.float32: jax.ShapeDtypeStruct(dims, dtype)  # noqa: E731
+    mask = a(B, -(-(S // blocks.k) // 32), S, blocks.k, dtype=jnp.int32)
+
+    def f(q, k, v, qi, ki, w, mask, lse_index):
+        o, kl = ia.indexed_attention(q, k, v, qi, ki, w, mask, lse_index, blocks=blocks, interpret=True)
+        return jnp.sum(o) + kl
+
+    grids = _pallas_calls(f, a(B, S, H, D), a(B, S, KV, D), a(B, S, KV, D), a(B, S, J, DI), a(B, S, DI), a(B, S, J), mask, a(B, S))
+    steps = (B, KV, live)
+    assert grids == {"dsa_attn_fwd": steps, "dsa_probs": (B, live), "dsa_attn_dq": steps, "dsa_attn_dkv": steps}
+    assert live * 2 > (S // blocks.q) * (S // blocks.k)  # the rectangle's other pairs, nearly half, are gone
+    tables = ia._steps(S, blocks).tables + ia._steps(S, blocks, by_key=True).tables
+    assert [len(t) for t in tables] == [live] * 6  # 12 bytes a step a launch
 
 
 def test_under_topk_is_dense_causal_attention():

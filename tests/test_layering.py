@@ -232,7 +232,7 @@ def test_the_packages_edge() -> None:
 @pytest.mark.parametrize(
     "module,row,may_import",
     [
-        ("ops.indexed_attention", "store-kernels-data", set()),
+        ("ops.indexed_attention", "store-kernels-data", {"ops.flash_attention"}),  # PR 53: the walk over live blocks
         ("models.indexed_sparse_moe", "compiled-step-models", {"ops.indexed_attention", "parallel.moe", "models.llama", "obs.spans"}),
     ],
 )

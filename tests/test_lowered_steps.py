@@ -19,10 +19,13 @@ and Keye's kernels were not touched.  (``latent_moe`` is not here: its digest
 came out another in a worker that had traced other files first.)  PR 52 ADDED
 ``eva``'s two (``--write --only eva``: ``flash_attention`` took a second rule
 of liveness, two key sources under one softmax, and ``DEVICE_PARTS`` a
-twelfth part) and changed none of the ten.  A later
-change that means to alter one of these programs
-writes the fixture anew and says so: ``python tests/test_lowered_steps.py
---write``."""
+twelfth part) and changed none of the ten.  PR 53 wrote ONE anew
+(``--write --only indexed_sparse_moe``, whose ``plain`` digest came out the
+same: Keye's ``dsa_attn_*`` and ``dsa_probs`` launches walk their live blocks
+from ``flash_attention``'s tables) and no other changed: the flash models'
+steps are what they were.  A later change that means to alter one of these
+programs writes the fixture anew and says so: ``python
+tests/test_lowered_steps.py --write``."""
 
 import hashlib
 import json

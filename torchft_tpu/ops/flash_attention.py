@@ -251,16 +251,17 @@ def _key_launch(nq, nk, block_q, block_k, groups, window, causal):
     )
 
 
-def _where(tables):
+def _where(tables, axis=2):
     """Where a kernel's grid step is: (row block, key block, whether it is the
     first visit of the block whose output it accumulates, whether the last).
-    With the walk's ``tables`` all four are read from them; on the rectangle
-    (no causality) the accumulation runs over the innermost axis and nothing
-    asks for the blocks (there is no mask)."""
+    With the walk's ``tables`` all four are read from them, at the step of the
+    grid's ``axis``; on the rectangle (no causality) the accumulation runs
+    over the innermost axis and nothing asks for the blocks (there is no
+    mask)."""
     if not tables:
         inner = pl.program_id(3)
         return None, None, inner == 0, inner == pl.num_programs(3) - 1
-    step = pl.program_id(2)
+    step = pl.program_id(axis)
     flags = tables[2][step]
     return tables[0][step], tables[1][step], (flags & _FIRST) != 0, (flags & _LAST) != 0
 

@@ -14,8 +14,18 @@ gradient.  Nothing here differentiates the selection.
 set where row ``t`` picked position ``(32 w + i) * block_k + j``, so the tile
 of key block ``ki`` is ``(mask[b, ki // 32, rows, :] >> (ki % 32)) & 1``, an
 elementwise read with no shuffle, and 32 MB hold 16,384 rows of 16,384
-positions.  The kernels walk the causal blocks as a flash kernel does and
-apply that tile; eight query heads of a KV head are stacked into one
+positions.  The attention's three launches and ``L_I``'s walk the LIVE
+(row block, key block) pairs alone, those with a key at or before a row, and
+apply that tile: their grids are ``(B, KV, steps)`` (``L_I``'s ``(B, steps)``)
+over the steps that ``ops/flash_attention.py``'s ``_live_blocks`` and
+``_walk`` list from the static shapes, a step's row block, key block and
+first-visit and last-visit flags come from int32 tables by scalar prefetch,
+and a dead pair is no grid step (``_steps``; at 16,384 positions and blocks
+of 128 x 512 a launch's 4 KV heads take 8,448 steps where the rectangle held
+16,384, from 25 KB of tables).  Forward, ``dq`` and ``L_I`` take a row
+block's key blocks ascending, ``dkv`` a key block's row blocks ascending: the
+rectangle's order of accumulation, so every output is bit for bit the
+rectangle's.  Eight query heads of a KV head are stacked into one
 ``[8 * block_q, D]`` operand, so a key block and a mask tile are read once
 for the group.  No ``[S, S]`` array is made: the index's float32 scores exist
 for one chunk of ``chunk`` query rows at a time.
@@ -57,6 +67,8 @@ import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from torchft_tpu.ops.flash_attention import Walk, _live_blocks, _walk, _where
 
 _NEG_INF = -1e30
 _LANES = 128
@@ -113,6 +125,16 @@ def _row_lanes(x: jax.Array) -> jax.Array:
 def _tile_bits(mask_ref, ki: jax.Array) -> jax.Array:
     """The picked positions of key block ``ki`` as a boolean ``[bq, bk]``."""
     return ((mask_ref[0, 0] >> (ki % 32)) & 1) != 0
+
+
+def _steps(seq: int, blocks: "Blocks", by_key: bool = False) -> Walk:
+    """The grid steps of a launch over ``seq`` positions, the live (row block,
+    key block) pairs alone (``ops/flash_attention.py``, the walk): a row
+    block's key blocks ascending (forward, ``dq``, ``L_I``) or, ``by_key``, a
+    key block's row blocks ascending (``dkv``; the group's heads are stacked
+    in a step, so its members take no step of their own and no table)."""
+    live = _live_blocks(seq // blocks.q, seq // blocks.k, blocks.q, blocks.k, None)
+    return _walk(live, 1)._replace(member=None) if by_key else _walk(live)
 
 
 # ---------------------------------------------------------------------------
@@ -298,39 +320,35 @@ def _masked_scores(q, k, picked, sm_scale, group):
     return s.reshape(group * bq, bk)
 
 
-def _attn_fwd_kernel(
-    q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
-    *, sm_scale, group, block_q, block_k, num_k_blocks,
-):
-    qi, ki = pl.program_id(2), pl.program_id(3)
+def _attn_fwd_kernel(*refs, sm_scale, group, block_q):
+    *tables, q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr = refs
+    _, ki, first, last = _where(tables)
     D = q_ref.shape[-1]
 
-    @pl.when(ki == 0)
+    @pl.when(first)
     def _init():
         m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    @pl.when(ki * block_k <= qi * block_q + block_q - 1)
-    def _accumulate():
-        q = q_ref[0].reshape(group * block_q, D)
-        v = v_ref[0, 0]
-        s = _masked_scores(q, k_ref[0, 0], _tile_bits(mask_ref, ki), sm_scale, group)
-        # a row that picked nothing in the blocks so far keeps m at _NEG_INF
-        # and adds exp(0) here; the first picked key's correction,
-        # exp(_NEG_INF - m), wipes that to exactly 0
-        m_prev = m_scr[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        correction = jnp.exp(m_prev - m_new)
-        l_new = l_scr[:, :1] * correction + jnp.sum(p, axis=1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * correction + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
+    q = q_ref[0].reshape(group * block_q, D)
+    v = v_ref[0, 0]
+    s = _masked_scores(q, k_ref[0, 0], _tile_bits(mask_ref, ki), sm_scale, group)
+    # a row that picked nothing in the blocks so far keeps m at _NEG_INF
+    # and adds exp(0) here; the first picked key's correction,
+    # exp(_NEG_INF - m), wipes that to exactly 0
+    m_prev = m_scr[:, :1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    correction = jnp.exp(m_prev - m_new)
+    l_new = l_scr[:, :1] * correction + jnp.sum(p, axis=1, keepdims=True)
+    acc_scr[...] = acc_scr[...] * correction + jax.lax.dot_general(
+        p.astype(v.dtype), v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+    )
+    m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
+    l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
 
-    @pl.when(ki == num_k_blocks - 1)
+    @pl.when(last)
     def _finalize():
         l = l_scr[:, :1]
         o_ref[0] = (acc_scr[...] / l).reshape(group, block_q, D).astype(o_ref.dtype)
@@ -347,77 +365,67 @@ def _p_and_ds(q, k, v, do, lse, delta, picked, sm_scale, group):
     return p, p * (dp - delta) * sm_scale
 
 
-def _attn_dq_kernel(
-    q_ref, k_ref, v_ref, mask_ref, lse_ref, do_ref, delta_ref, dq_ref, dq_scr,
-    *, sm_scale, group, block_q, block_k, num_k_blocks,
-):
-    qi, ki = pl.program_id(2), pl.program_id(3)
+def _attn_dq_kernel(*refs, sm_scale, group, block_q):
+    *tables, q_ref, k_ref, v_ref, mask_ref, lse_ref, do_ref, delta_ref, dq_ref, dq_scr = refs
+    _, ki, first, last = _where(tables)
     D = q_ref.shape[-1]
     rows = group * block_q
 
-    @pl.when(ki == 0)
+    @pl.when(first)
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    @pl.when(ki * block_k <= qi * block_q + block_q - 1)
-    def _accumulate():
-        k = k_ref[0, 0]
-        _, ds = _p_and_ds(
-            q_ref[0].reshape(rows, D), k, v_ref[0, 0], do_ref[0].reshape(rows, D),
-            lse_ref[0].reshape(rows, _ROW_LANES)[:, :1], delta_ref[0].reshape(rows, _ROW_LANES)[:, :1],
-            _tile_bits(mask_ref, ki), sm_scale, group,
-        )
-        dq_scr[...] += jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
+    k = k_ref[0, 0]
+    _, ds = _p_and_ds(
+        q_ref[0].reshape(rows, D), k, v_ref[0, 0], do_ref[0].reshape(rows, D),
+        lse_ref[0].reshape(rows, _ROW_LANES)[:, :1], delta_ref[0].reshape(rows, _ROW_LANES)[:, :1],
+        _tile_bits(mask_ref, ki), sm_scale, group,
+    )
+    dq_scr[...] += jax.lax.dot_general(
+        ds.astype(k.dtype), k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+    )
 
-    @pl.when(ki == num_k_blocks - 1)
+    @pl.when(last)
     def _finalize():
         dq_ref[0] = dq_scr[...].reshape(group, block_q, D).astype(dq_ref.dtype)
 
 
-def _attn_dkv_kernel(
-    q_ref, k_ref, v_ref, mask_ref, lse_ref, do_ref, delta_ref, dk_ref, dv_ref, dk_scr, dv_scr,
-    *, sm_scale, group, block_q, block_k, num_q_blocks,
-):
-    ki, qi = pl.program_id(2), pl.program_id(3)
+def _attn_dkv_kernel(*refs, sm_scale, group, block_q):
+    *tables, q_ref, k_ref, v_ref, mask_ref, lse_ref, do_ref, delta_ref, dk_ref, dv_ref, dk_scr, dv_scr = refs
+    _, ki, first, last = _where(tables)
     D = q_ref.shape[-1]
     rows = group * block_q
 
-    @pl.when(qi == 0)
+    @pl.when(first)
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    @pl.when(qi * block_q + block_q - 1 >= ki * block_k)
-    def _accumulate():
-        q, do = q_ref[0].reshape(rows, D), do_ref[0].reshape(rows, D)
-        p, ds = _p_and_ds(
-            q, k_ref[0, 0], v_ref[0, 0], do,
-            lse_ref[0].reshape(rows, _ROW_LANES)[:, :1], delta_ref[0].reshape(rows, _ROW_LANES)[:, :1],
-            _tile_bits(mask_ref, ki), sm_scale, group,
-        )
-        # contracting the stacked rows sums the whole group of query heads
-        dv_scr[...] += _dot_0(p.astype(do.dtype), do)
-        dk_scr[...] += _dot_0(ds.astype(q.dtype), q)
+    q, do = q_ref[0].reshape(rows, D), do_ref[0].reshape(rows, D)
+    p, ds = _p_and_ds(
+        q, k_ref[0, 0], v_ref[0, 0], do,
+        lse_ref[0].reshape(rows, _ROW_LANES)[:, :1], delta_ref[0].reshape(rows, _ROW_LANES)[:, :1],
+        _tile_bits(mask_ref, ki), sm_scale, group,
+    )
+    # contracting the stacked rows sums the whole group of query heads
+    dv_scr[...] += _dot_0(p.astype(do.dtype), do)
+    dk_scr[...] += _dot_0(ds.astype(q.dtype), q)
 
-    @pl.when(qi == num_q_blocks - 1)
+    @pl.when(last)
     def _finalize():
         dk_ref[0, 0] = dk_scr[...].astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_scr[...].astype(dv_ref.dtype)
 
 
 def _attn_specs(group, bq, bk, D):
-    """Block specs of the q-major kernels' grid ``(B, KV, nq, nk)``.  A key
-    block wholly after the query block is never read: the index maps stay
-    on the last live one, so nothing is fetched for it."""
-    last = lambda qi: (qi * bq + bq - 1) // bk  # noqa: E731
-    q_spec = pl.BlockSpec((1, group, bq, D), lambda b, h, qi, ki: (b, h, qi, 0))
-    kv_spec = pl.BlockSpec((1, 1, bk, D), lambda b, h, qi, ki: (b, h, jnp.minimum(ki, last(qi)), 0))
-    mask_spec = pl.BlockSpec(
-        (1, 1, bq, bk), lambda b, h, qi, ki: (b, jnp.minimum(ki, last(qi)) // 32, qi, 0)
-    )
-    row_spec = pl.BlockSpec((1, group, bq, _ROW_LANES), lambda b, h, qi, ki: (b, h, qi, 0))
+    """Block specs of the attention launches' grid ``(B, KV, steps)``: a
+    step's row block and key block are what the walk's tables say
+    (``_steps``; scalar prefetch), whichever of the two it walks by, so a
+    dead pair has no step and nothing of it is fetched."""
+    q_spec = pl.BlockSpec((1, group, bq, D), lambda b, h, t, qt, kt, ft: (b, h, qt[t], 0))
+    kv_spec = pl.BlockSpec((1, 1, bk, D), lambda b, h, t, qt, kt, ft: (b, h, kt[t], 0))
+    mask_spec = pl.BlockSpec((1, 1, bq, bk), lambda b, h, t, qt, kt, ft: (b, kt[t] // 32, qt[t], 0))
+    row_spec = pl.BlockSpec((1, group, bq, _ROW_LANES), lambda b, h, t, qt, kt, ft: (b, h, qt[t], 0))
     return q_spec, kv_spec, mask_spec, row_spec
 
 
@@ -428,28 +436,29 @@ def _attn_fwd(q, k, v, mask, sm_scale, blocks, interpret):
     KV = k.shape[1]
     group = H // KV
     bq, bk = blocks.q, blocks.k
-    nq, nk = S // bq, S // bk
+    walk = _steps(S, blocks)
     q_spec, kv_spec, mask_spec, row_spec = _attn_specs(group, bq, bk, D)
     return pl.pallas_call(
-        functools.partial(
-            _attn_fwd_kernel, sm_scale=sm_scale, group=group, block_q=bq, block_k=bk, num_k_blocks=nk
+        functools.partial(_attn_fwd_kernel, sm_scale=sm_scale, group=group, block_q=bq),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(walk.tables),
+            grid=(B, KV, walk.steps),
+            in_specs=[q_spec, kv_spec, kv_spec, mask_spec],
+            out_specs=[q_spec, row_spec],
+            scratch_shapes=[
+                pltpu.VMEM((group * bq, _LANES), jnp.float32),
+                pltpu.VMEM((group * bq, _LANES), jnp.float32),
+                pltpu.VMEM((group * bq, D), jnp.float32),
+            ],
         ),
-        grid=(B, KV, nq, nk),
-        in_specs=[q_spec, kv_spec, kv_spec, mask_spec],
-        out_specs=[q_spec, row_spec],
         out_shape=[
             jax.ShapeDtypeStruct(q.shape, q.dtype),
             jax.ShapeDtypeStruct((B, H, S, _ROW_LANES), jnp.float32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((group * bq, _LANES), jnp.float32),
-            pltpu.VMEM((group * bq, _LANES), jnp.float32),
-            pltpu.VMEM((group * bq, D), jnp.float32),
-        ],
-        compiler_params=_params("parallel", "parallel", "parallel", "arbitrary"),
+        compiler_params=_params("parallel", "parallel", "arbitrary"),
         interpret=interpret,
         name="dsa_attn_fwd",
-    )(q, k, v, mask)
+    )(*walk.tables, q, k, v, mask)
 
 
 def _attn_bwd(q, k, v, mask, o, lse, do, sm_scale, blocks, interpret):
@@ -457,46 +466,42 @@ def _attn_bwd(q, k, v, mask, o, lse, do, sm_scale, blocks, interpret):
     KV = k.shape[1]
     group = H // KV
     bq, bk = blocks.q, blocks.k
-    nq, nk = S // bq, S // bk
     delta = jnp.broadcast_to(
         jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1, keepdims=True),
         (B, H, S, _ROW_LANES),
     )
+    by_row, by_key = _steps(S, blocks), _steps(S, blocks, by_key=True)
     q_spec, kv_spec, mask_spec, row_spec = _attn_specs(group, bq, bk, D)
+    in_specs = [q_spec, kv_spec, kv_spec, mask_spec, row_spec, q_spec, row_spec]
+    static = dict(sm_scale=sm_scale, group=group, block_q=bq)
     dq = pl.pallas_call(
-        functools.partial(
-            _attn_dq_kernel, sm_scale=sm_scale, group=group, block_q=bq, block_k=bk, num_k_blocks=nk
+        functools.partial(_attn_dq_kernel, **static),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(by_row.tables),
+            grid=(B, KV, by_row.steps),
+            in_specs=in_specs,
+            out_specs=q_spec,
+            scratch_shapes=[pltpu.VMEM((group * bq, D), jnp.float32)],
         ),
-        grid=(B, KV, nq, nk),
-        in_specs=[q_spec, kv_spec, kv_spec, mask_spec, row_spec, q_spec, row_spec],
-        out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        scratch_shapes=[pltpu.VMEM((group * bq, D), jnp.float32)],
-        compiler_params=_params("parallel", "parallel", "parallel", "arbitrary"),
+        compiler_params=_params("parallel", "parallel", "arbitrary"),
         interpret=interpret,
         name="dsa_attn_dq",
-    )(q, k, v, mask, lse, do, delta)
-
-    # k-major: a query block wholly before the key block is never read
-    first = lambda ki: (ki * bk) // bq  # noqa: E731
-    at = lambda ki, qi: jnp.maximum(qi, first(ki))  # noqa: E731
-    gq_spec = pl.BlockSpec((1, group, bq, D), lambda b, h, ki, qi: (b, h, at(ki, qi), 0))
-    gkv_spec = pl.BlockSpec((1, 1, bk, D), lambda b, h, ki, qi: (b, h, ki, 0))
-    gmask_spec = pl.BlockSpec((1, 1, bq, bk), lambda b, h, ki, qi: (b, ki // 32, at(ki, qi), 0))
-    grow_spec = pl.BlockSpec((1, group, bq, _ROW_LANES), lambda b, h, ki, qi: (b, h, at(ki, qi), 0))
+    )(*by_row.tables, q, k, v, mask, lse, do, delta)
     dk, dv = pl.pallas_call(
-        functools.partial(
-            _attn_dkv_kernel, sm_scale=sm_scale, group=group, block_q=bq, block_k=bk, num_q_blocks=nq
+        functools.partial(_attn_dkv_kernel, **static),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(by_key.tables),
+            grid=(B, KV, by_key.steps),
+            in_specs=in_specs,
+            out_specs=[kv_spec, kv_spec],
+            scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32), pltpu.VMEM((bk, D), jnp.float32)],
         ),
-        grid=(B, KV, nk, nq),
-        in_specs=[gq_spec, gkv_spec, gkv_spec, gmask_spec, grow_spec, gq_spec, grow_spec],
-        out_specs=[gkv_spec, gkv_spec],
         out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype), jax.ShapeDtypeStruct(v.shape, v.dtype)],
-        scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32), pltpu.VMEM((bk, D), jnp.float32)],
-        compiler_params=_params("parallel", "parallel", "parallel", "arbitrary"),
+        compiler_params=_params("parallel", "parallel", "arbitrary"),
         interpret=interpret,
         name="dsa_attn_dkv",
-    )(q, k, v, mask, lse, do, delta)
+    )(*by_key.tables, q, k, v, mask, lse, do, delta)
     return dq, dk, dv
 
 
@@ -505,54 +510,53 @@ def _attn_bwd(q, k, v, mask, o, lse, do, sm_scale, blocks, interpret):
 # ---------------------------------------------------------------------------
 
 
-def _probs_kernel(
-    q_ref, k_ref, lse_ref, mask_ref, qi_ref, w_ref, ki_ref, stat_ref, kl_ref, dq_ref, dw_ref, dk_ref,
-    kl_scr, dq_scr, dw_scr, *, sm_scale, kv_heads, group, heads, block_q, block_k, num_k_blocks,
-):
-    qi, ki = pl.program_id(1), pl.program_id(2)
+def _probs_kernel(*refs, sm_scale, kv_heads, group, heads, block_q, block_k):
+    (
+        *tables, q_ref, k_ref, lse_ref, mask_ref, qi_ref, w_ref, ki_ref, stat_ref, kl_ref, dq_ref, dw_ref, dk_ref,
+        kl_scr, dq_scr, dw_scr,
+    ) = refs
+    _, ki, first, last = _where(tables, axis=1)
     D = q_ref.shape[-1]
     rows = group * block_q
 
-    @pl.when(ki == 0)
+    @pl.when(first)
     def _init():
         kl_scr[...] = jnp.zeros_like(kl_scr)
         dq_scr[...] = jnp.zeros_like(dq_scr)
         dw_scr[...] = jnp.zeros_like(dw_scr)
 
-    @pl.when((qi == 0) & (ki == 0))
+    @pl.when(pl.program_id(1) == 0)
     def _init_keys():
         dk_ref[...] = jnp.zeros_like(dk_ref)
 
-    @pl.when(ki * block_k <= qi * block_q + block_q - 1)
-    def _accumulate():
-        picked = _tile_bits(mask_ref, ki)
-        # the head-mean of the attention's softmax over the picked keys
-        p = jnp.zeros((block_q, block_k), jnp.float32)
-        for g in range(kv_heads):
-            heads_of = slice(g * group, (g + 1) * group)
-            s = _dot_t(q_ref[0, heads_of].reshape(rows, D), k_ref[0, g]) * sm_scale
-            lse = lse_ref[0, heads_of].reshape(rows, _ROW_LANES)[:, :1]
-            p = p + jnp.sum(jnp.exp(s - lse).reshape(group, block_q, block_k), axis=0)
-        p = jnp.where(picked, p * (1.0 / (kv_heads * group)), 0.0)
-        k_index = ki_ref[0]
-        log_q = _index_scores(qi_ref, w_ref, k_index, heads) - stat_ref[0][:, :1]
-        kl = jnp.where(picked, p * (jnp.log(jnp.maximum(p, 1e-37)) - log_q), 0.0)
-        kl_scr[...] += jnp.broadcast_to(jnp.sum(kl, axis=1, keepdims=True), kl_scr.shape)
-        # d L_I / d score = softmax_S(I) - p; through the relu to each head
-        d_score = jnp.where(picked, jnp.exp(log_q) - p, 0.0)
-        for j in range(heads):
-            q_j = qi_ref[0, j]
-            dots = _dot_t(q_j, k_index)
-            dw_scr[j] += jnp.broadcast_to(
-                jnp.sum(d_score * jnp.maximum(dots, 0.0), axis=1, keepdims=True), dw_scr.shape[1:]
-            )
-            d_dots = jnp.where(dots > 0.0, d_score * w_ref[0, j][:, :1], 0.0).astype(q_j.dtype)
-            dq_scr[j] += jax.lax.dot_general(
-                d_dots, k_index, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-            )
-            dk_ref[0, ki] += _dot_0(d_dots, q_j)
+    picked = _tile_bits(mask_ref, ki)
+    # the head-mean of the attention's softmax over the picked keys
+    p = jnp.zeros((block_q, block_k), jnp.float32)
+    for g in range(kv_heads):
+        heads_of = slice(g * group, (g + 1) * group)
+        s = _dot_t(q_ref[0, heads_of].reshape(rows, D), k_ref[0, g]) * sm_scale
+        lse = lse_ref[0, heads_of].reshape(rows, _ROW_LANES)[:, :1]
+        p = p + jnp.sum(jnp.exp(s - lse).reshape(group, block_q, block_k), axis=0)
+    p = jnp.where(picked, p * (1.0 / (kv_heads * group)), 0.0)
+    k_index = ki_ref[0]
+    log_q = _index_scores(qi_ref, w_ref, k_index, heads) - stat_ref[0][:, :1]
+    kl = jnp.where(picked, p * (jnp.log(jnp.maximum(p, 1e-37)) - log_q), 0.0)
+    kl_scr[...] += jnp.broadcast_to(jnp.sum(kl, axis=1, keepdims=True), kl_scr.shape)
+    # d L_I / d score = softmax_S(I) - p; through the relu to each head
+    d_score = jnp.where(picked, jnp.exp(log_q) - p, 0.0)
+    for j in range(heads):
+        q_j = qi_ref[0, j]
+        dots = _dot_t(q_j, k_index)
+        dw_scr[j] += jnp.broadcast_to(
+            jnp.sum(d_score * jnp.maximum(dots, 0.0), axis=1, keepdims=True), dw_scr.shape[1:]
+        )
+        d_dots = jnp.where(dots > 0.0, d_score * w_ref[0, j][:, :1], 0.0).astype(q_j.dtype)
+        dq_scr[j] += jax.lax.dot_general(
+            d_dots, k_index, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        )
+        dk_ref[0, ki] += _dot_0(d_dots, q_j)
 
-    @pl.when(ki == num_k_blocks - 1)
+    @pl.when(last)
     def _finalize():
         kl_ref[0] = kl_scr[:, :_ROW_LANES]
         dq_ref[0] = dq_scr[...]
@@ -571,52 +575,53 @@ def _index_loss(q, k, lse, mask, q_index, w, k_index, lse_index, sm_scale, block
     KV = k.shape[1]
     J, DI = q_index.shape[1], q_index.shape[3]
     bq, bk = blocks.q, blocks.k
-    nq, nk = S // bq, S // bk
-    last = lambda qi: (qi * bq + bq - 1) // bk  # noqa: E731
-    key_at = lambda qi, ki: jnp.minimum(ki, last(qi))  # noqa: E731
+    nk = S // bk
+    walk = _steps(S, blocks)  # the forward's
     rows = lambda *lead: pl.BlockSpec(  # noqa: E731
-        (1, *lead, bq, _ROW_LANES), lambda b, qi, ki: (b,) + (0,) * len(lead) + (qi, 0)
+        (1, *lead, bq, _ROW_LANES), lambda b, t, qt, kt, ft: (b,) + (0,) * len(lead) + (qt[t], 0)
     )
-    index_q_spec = pl.BlockSpec((1, J, bq, DI), lambda b, qi, ki: (b, 0, qi, 0))
+    index_q_spec = pl.BlockSpec((1, J, bq, DI), lambda b, t, qt, kt, ft: (b, 0, qt[t], 0))
     kl, dq, dw, dk = pl.pallas_call(
         functools.partial(
-            _probs_kernel, sm_scale=sm_scale, kv_heads=KV, group=H // KV, heads=J, block_q=bq,
-            block_k=bk, num_k_blocks=nk,
+            _probs_kernel, sm_scale=sm_scale, kv_heads=KV, group=H // KV, heads=J, block_q=bq, block_k=bk
         ),
-        grid=(B, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, H, bq, D), lambda b, qi, ki: (b, 0, qi, 0)),
-            pl.BlockSpec((1, KV, bk, D), lambda b, qi, ki: (b, 0, key_at(qi, ki), 0)),
-            rows(H),
-            pl.BlockSpec((1, 1, bq, bk), lambda b, qi, ki: (b, key_at(qi, ki) // 32, qi, 0)),
-            index_q_spec,
-            rows(J),
-            pl.BlockSpec((1, bk, DI), lambda b, qi, ki: (b, key_at(qi, ki), 0)),
-            rows(),
-        ],
-        out_specs=[
-            rows(),
-            index_q_spec,
-            rows(J),
-            # every query block adds to every earlier key block: the whole
-            # array stays in fast memory for a batch row
-            pl.BlockSpec((1, nk, bk, DI), lambda b, qi, ki: (b, 0, 0, 0)),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(walk.tables),
+            grid=(B, walk.steps),
+            in_specs=[
+                pl.BlockSpec((1, H, bq, D), lambda b, t, qt, kt, ft: (b, 0, qt[t], 0)),
+                pl.BlockSpec((1, KV, bk, D), lambda b, t, qt, kt, ft: (b, 0, kt[t], 0)),
+                rows(H),
+                pl.BlockSpec((1, 1, bq, bk), lambda b, t, qt, kt, ft: (b, kt[t] // 32, qt[t], 0)),
+                index_q_spec,
+                rows(J),
+                pl.BlockSpec((1, bk, DI), lambda b, t, qt, kt, ft: (b, kt[t], 0)),
+                rows(),
+            ],
+            out_specs=[
+                rows(),
+                index_q_spec,
+                rows(J),
+                # every query block adds to every earlier key block: the whole
+                # array stays in fast memory for a batch row
+                pl.BlockSpec((1, nk, bk, DI), lambda b, t, qt, kt, ft: (b, 0, 0, 0)),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((bq, _LANES), jnp.float32),
+                pltpu.VMEM((J, bq, DI), jnp.float32),
+                pltpu.VMEM((J, bq, _LANES), jnp.float32),
+            ],
+        ),
         out_shape=[
             jax.ShapeDtypeStruct((B, S, _ROW_LANES), jnp.float32),
             jax.ShapeDtypeStruct((B, J, S, DI), jnp.float32),
             jax.ShapeDtypeStruct((B, J, S, _ROW_LANES), jnp.float32),
             jax.ShapeDtypeStruct((B, nk, bk, DI), jnp.float32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((bq, _LANES), jnp.float32),
-            pltpu.VMEM((J, bq, DI), jnp.float32),
-            pltpu.VMEM((J, bq, _LANES), jnp.float32),
-        ],
-        compiler_params=_params("parallel", "arbitrary", "arbitrary"),
+        compiler_params=_params("parallel", "arbitrary"),
         interpret=interpret,
         name="dsa_probs",
-    )(q, k, lse, mask, q_index, w, k_index, lse_index)
+    )(*walk.tables, q, k, lse, mask, q_index, w, k_index, lse_index)
     return kl[..., 0], dq, dw[..., 0], dk.reshape(B, S, DI)
 
 
