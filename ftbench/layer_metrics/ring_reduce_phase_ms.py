@@ -1,0 +1,13 @@
+"""Host data plane, milliseconds a step: the op thread's wall time in the
+rings' reduce-scatter phase (``ring_reduce_phase``: a send on the tx workers
+beside a receive that adds as it lands), summed over a step's rings.  With
+``ring_average_ms`` and ``ring_gather_phase_ms`` it is ``comm_op_ms`` less the
+binding's own time an op.  DDP_SYNC's ``ring_reduce_s`` (``_ring.py`` says
+where it is counted and which events are read); None on a program whose events
+carry no such field."""
+
+from ftbench.layer_metrics._ring import META, field_ms
+
+
+def read(sources):
+    return field_ms(sources, "ring_reduce_s")

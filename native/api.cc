@@ -215,14 +215,20 @@ int tpuft_comm_allgather(void* h, const void* in, void* out,
 }
 
 // per-lane counters of the current epoch (tx/rx payload bytes, stall
-// events) — the native half of the tier-agnostic lane_stats() surface.
-// Returns the lane count; fills up to `cap` entries per array.
+// events, and the nanoseconds a lane spent in recv, in the reduce's add and
+// in send) with the op thread's four (`ring_ns`: reduce phase, division,
+// allgather phase, tail) — the native half of the tier-agnostic
+// lane_stats() surface.  Returns the lane count; fills up to `cap` entries
+// per array.
 uint64_t tpuft_comm_lane_stats(void* h, uint64_t* tx, uint64_t* rx,
-                               uint64_t* stalls, uint64_t cap,
-                               uint64_t* stripe_floor) {
+                               uint64_t* stalls, uint64_t* rx_ns,
+                               uint64_t* add_ns, uint64_t* tx_ns,
+                               uint64_t cap, uint64_t* stripe_floor,
+                               uint64_t* ring_ns) {
   auto* comm = static_cast<tpuft::Communicator*>(h);
   *stripe_floor = comm->stripe_floor();
-  return comm->lane_stats(tx, rx, stalls, cap);
+  uint64_t* lane_ns[3] = {rx_ns, add_ns, tx_ns};
+  return comm->lane_stats(tx, rx, stalls, cap, lane_ns, ring_ns);
 }
 
 // consume-drain of the C-side flight-recorder ring (fixed slots recording

@@ -5,6 +5,9 @@
 //                             the one-chip two-group cell's) rung with the
 //                             divisor whole and in pieces of 64, 16 and 4
 //                             MiB, at the lanes TORCHFT_RING_LANES names
+// Both print, beside the wall time, where the ring says its time went
+// (comm.h EpochIO's seven counters of nanoseconds): the terms a traced
+// two-group cell reports as ring_rx_ms ... ring_tail_ms.
 #include <sys/wait.h>
 #include <unistd.h>
 
@@ -17,6 +20,50 @@
 #include "store.h"
 
 using namespace tpuft;
+
+// The epoch's seven time counters in milliseconds, a lane's three as the
+// MEAN over the lanes that sent bytes (the rule ddp.allreduce_pytree puts
+// on DDP_SYNC); differenced over a stretch of rings they say where it went.
+struct RingTimes {
+  double ms[7] = {0, 0, 0, 0, 0, 0, 0};  // rx add tx | reduce average gather tail
+  uint64_t tx_bytes[64] = {0};
+  uint64_t lane_ns[3][64] = {{0}};
+  uint64_t ring_ns[4] = {0, 0, 0, 0};
+  size_t lanes = 0;
+
+  static RingTimes read(const Communicator& comm) {
+    RingTimes t;
+    uint64_t rx[64], stalls[64];
+    uint64_t* lane_ns[3] = {t.lane_ns[0], t.lane_ns[1], t.lane_ns[2]};
+    t.lanes = std::min<size_t>(
+        64, comm.lane_stats(t.tx_bytes, rx, stalls, 64, lane_ns, t.ring_ns));
+    return t;
+  }
+  // the stretch from `before` to this reading
+  RingTimes since(const RingTimes& before) const {
+    RingTimes d;
+    size_t moved = 0;
+    for (size_t i = 0; i < lanes; ++i) {
+      if (tx_bytes[i] == before.tx_bytes[i]) continue;
+      ++moved;
+      for (int k = 0; k < 3; ++k)
+        d.ms[k] += (lane_ns[k][i] - before.lane_ns[k][i]) / 1e6;
+    }
+    for (int k = 0; k < 3; ++k) d.ms[k] /= moved ? moved : 1;
+    for (int k = 0; k < 4; ++k)
+      d.ms[3 + k] = (ring_ns[k] - before.ring_ns[k]) / 1e6;
+    d.lanes = moved;
+    return d;
+  }
+  void print(int rank, const char* what, double wall_s) const {
+    std::printf("rank %d %s: wall %.1f ms = reduce %.1f + average %.1f + "
+                "gather %.1f (+ %.1f outside the phases); of the phases a "
+                "lane (mean of %zu) rx %.1f, add %.1f, tx %.1f; tail %.1f\n",
+                rank, what, wall_s * 1e3, ms[3], ms[4], ms[5],
+                wall_s * 1e3 - ms[3] - ms[4] - ms[5], lanes, ms[0], ms[1],
+                ms[2], ms[6]);
+  }
+};
 
 static void run_rank(const std::string& store_addr, int rank) {
   Communicator comm(60.0);
@@ -68,13 +115,18 @@ static void run_rank(const std::string& store_addr, int rank) {
         .count();
   };
   double best[2] = {1e9, 1e9};
+  RingTimes best_times;  // of the averaging ring's best round
   for (int round = 0; round <= kRounds; ++round) {
-    double got[2] = {timed(0), timed(2)};
+    double sum_s = timed(0);
+    RingTimes before = RingTimes::read(comm);
+    double got[2] = {sum_s, timed(2)};
     if (round == 0) continue;  // warm
+    if (got[1] < best[1]) best_times = RingTimes::read(comm).since(before);
     for (int k = 0; k < 2; ++k) best[k] = std::min(best[k], got[k]);
   }
   std::printf("rank %d ring 256MB bf16: sum %.3fs, average %.3fs (+%.0f ms)\n",
               rank, best[0], best[1], (best[1] - best[0]) * 1e3);
+  best_times.print(rank, "ring 256MB bf16 average", best[1]);
   std::fflush(stdout);  // the forked rank leaves by _exit
 }
 
@@ -85,16 +137,17 @@ static void run_rank(const std::string& store_addr, int rank) {
 static void run_pieces(const std::string& store_addr, int rank, size_t mb) {
   Communicator comm(60.0);
   comm.configure(store_addr + "/pieces", rank, 2);
-  uint64_t tx[64], rx[64], stalls[64];
-  const size_t lanes = comm.lane_stats(tx, rx, stalls, 64);
+  const size_t lanes = comm.lanes();
   const size_t elems = mb * 500000;  // bfloat16
   std::vector<uint16_t> grad(elems, f32_to_bf16(1.0f));  // the average of ones
   for (size_t mib : {0, 64, 16, 4}) {
     const size_t piece = mib ? (mib << 20) / 2 : elems;
     size_t rings = 0;
     double dt = 0;
+    RingTimes before;
     for (int pass = 0; pass < 2; ++pass) {
       rings = 0;
+      before = RingTimes::read(comm);
       auto t0 = std::chrono::steady_clock::now();
       for (size_t off = 0; off < elems; off += piece, ++rings)
         comm.allreduce(grad.data() + off, std::min(piece, elems - off) * 2,
@@ -105,6 +158,7 @@ static void run_pieces(const std::string& store_addr, int rank, size_t mb) {
     std::printf("rank %d lanes %zu: %zu MB as %zu ring(s) of %zu MiB: %.3fs, "
                 "%.2f ms a ring\n", rank, lanes, mb, rings, mib, dt,
                 dt * 1e3 / rings);
+    RingTimes::read(comm).since(before).print(rank, "the pass", dt);
   }
   std::fflush(stdout);
 }

@@ -853,6 +853,28 @@ struct FlightSlot {
   int64_t b = 0;
 };
 
+// Adds the steady_clock nanoseconds of its own lifetime to a counter
+// (steady_clock is CLOCK_MONOTONIC, Python's time.monotonic() on Linux, so
+// what the op thread counts lies on the clock of the span tpuft/comm/op).
+struct NsTimer {
+  using TimePoint = std::chrono::steady_clock::time_point;
+  std::atomic<uint64_t>* to;
+  TimePoint t0 = std::chrono::steady_clock::now();
+  explicit NsTimer(std::atomic<uint64_t>* counter) : to(counter) {}
+  NsTimer(const NsTimer&) = delete;
+  NsTimer& operator=(const NsTimer&) = delete;
+  static uint64_t between(TimePoint a, TimePoint b) {
+    return static_cast<uint64_t>(
+        std::chrono::duration_cast<std::chrono::nanoseconds>(b - a).count());
+  }
+  static uint64_t since(TimePoint t) {
+    return between(t, std::chrono::steady_clock::now());
+  }
+  ~NsTimer() {
+    if (to) to->fetch_add(since(t0), std::memory_order_relaxed);
+  }
+};
+
 // Per-epoch IO state: the pacer, the per-lane counters, and the lane
 // config they index.  Ops snapshot ONE shared_ptr at entry — configure()
 // swaps in a fresh instance while a superseded op thread may still be
@@ -874,11 +896,34 @@ struct EpochIO {
   // denials / kernel would-block), names mirroring _TcpMesh lane_tx_bytes
   // / lane_rx_bytes / lane_stalls
   std::unique_ptr<std::atomic<uint64_t>[]> tx, rx, stalls;
+  // where the ring's time goes, nanoseconds over the epoch, always counted
+  // (two clock reads a 4 MiB quantum and a frame).  A lane: its thread
+  // inside ::recv of a striped frame's header and payload (waiting for the
+  // peer AND the kernel's copy out of the socket: one syscall, not told
+  // apart), inside the reduce's add, and its sender inside sendmsg and the
+  // pacing.  The op thread: its wall time in the ring's reduce-scatter
+  // phase, in the owner's division between the phases, in the allgather
+  // phase, and, of a phase's steps, from its own part of the receive
+  // returning to the other lanes' parts and its own send having landed
+  // (the tail).  Lanes run beside each other, so a lane's seconds are a
+  // share of the phases' and the tail lies inside them.  Only frames on
+  // the TCP lanes count under a lane; a leg another transport carries lies
+  // in the phases alone.
+  std::unique_ptr<std::atomic<uint64_t>[]> rx_ns, add_ns, tx_ns;
+  std::atomic<uint64_t> reduce_ns{0}, average_ns{0}, gather_ns{0}, tail_ns{0};
 
   void alloc_counters() {
     tx.reset(new std::atomic<uint64_t>[lanes]());
     rx.reset(new std::atomic<uint64_t>[lanes]());
     stalls.reset(new std::atomic<uint64_t>[lanes]());
+    rx_ns.reset(new std::atomic<uint64_t>[lanes]());
+    add_ns.reset(new std::atomic<uint64_t>[lanes]());
+    tx_ns.reset(new std::atomic<uint64_t>[lanes]());
+  }
+  // the lane's counter of one kind, or none (an epoch without counters)
+  std::atomic<uint64_t>* lane_ns(
+      const std::unique_ptr<std::atomic<uint64_t>[]>& of, size_t lane) const {
+    return of && lane < lanes ? &of[lane] : nullptr;
   }
   void stall(size_t lane) {
     if (stalls && lane < lanes)
@@ -1241,16 +1286,31 @@ class Communicator {
   // moved + stall events: pacer denials / kernel would-block), the same
   // counters TCPCommunicator.lane_stats() exports — surfaced through
   // native.py so manager.last_quorum_timings is tier-agnostic.  Returns
-  // the lane count; fills up to `cap` entries per array.
-  size_t lane_stats(uint64_t* tx, uint64_t* rx, uint64_t* stalls,
-                    size_t cap) const {
+  // the lane count; fills up to `cap` entries per array.  The same snapshot
+  // hands out where the epoch's time went (EpochIO has what each one is):
+  // `lane_ns`, three arrays of `cap` (a lane's nanoseconds in recv, in the
+  // reduce's add, in send), and `ring_ns`, four (the op thread's in the
+  // reduce phase, the division, the allgather phase, the tail).  Who wants
+  // the lane count alone asks lanes().
+  size_t lane_stats(uint64_t* tx, uint64_t* rx, uint64_t* stalls, size_t cap,
+                    uint64_t* const* lane_ns, uint64_t* ring_ns) const {
     IoPtr io = io_snapshot();
     if (!io->tx) return 0;
+    auto read = [](const std::atomic<uint64_t>& c) {
+      return c.load(std::memory_order_relaxed);
+    };
     for (size_t i = 0; i < std::min(io->lanes, cap); ++i) {
-      tx[i] = io->tx[i].load(std::memory_order_relaxed);
-      rx[i] = io->rx[i].load(std::memory_order_relaxed);
-      stalls[i] = io->stalls[i].load(std::memory_order_relaxed);
+      tx[i] = read(io->tx[i]);
+      rx[i] = read(io->rx[i]);
+      stalls[i] = read(io->stalls[i]);
+      lane_ns[0][i] = read(io->rx_ns[i]);
+      lane_ns[1][i] = read(io->add_ns[i]);
+      lane_ns[2][i] = read(io->tx_ns[i]);
     }
+    ring_ns[0] = read(io->reduce_ns);
+    ring_ns[1] = read(io->average_ns);
+    ring_ns[2] = read(io->gather_ns);
+    ring_ns[3] = read(io->tail_ns);
     return io->lanes;
   }
 
@@ -1306,6 +1366,7 @@ class Communicator {
     if (divisor == 1) divisor = 0;  // the sum is the average: no pass
     size_t esz = dtype_size(dt);
     auto average = [&](size_t off, size_t len) {
+      NsTimer timed(&io->average_ns);
       for (const struct iovec& seg : view.slice(off, len))
         average_buffer(seg.iov_base, seg.iov_len, dt, divisor);
     };
@@ -1542,6 +1603,7 @@ class Communicator {
   void send_framed_iov(EpochIO& io, int fd, int64_t peer, uint64_t tag,
                        std::vector<struct iovec> payload, size_t nbytes,
                        TimePoint deadline, size_t lane) {
+    NsTimer timed(io.lane_ns(io.tx_ns, lane));
     io.gate();
     uint64_t hdr[2] = {nbytes, tag};
     payload.insert(payload.begin(), {hdr, sizeof(hdr)});
@@ -1629,13 +1691,16 @@ class Communicator {
   // sees exactly one reduction per step: results are bit-identical to a
   // single lane.
 
+  //
+  // Returns when the calling thread's OWN part returned: what the caller
+  // waits from then on is the other lanes (and, in a ring's step, its send).
   template <typename PartFn>
-  void run_lane_parts(int64_t peer, int dir,
-                      const std::vector<std::pair<size_t, size_t>>& parts,
-                      PartFn fn) {
+  TimePoint run_lane_parts(int64_t peer, int dir,
+                           const std::vector<std::pair<size_t, size_t>>& parts,
+                           PartFn fn) {
     if (parts.size() == 1) {
       fn(0, parts[0].first, parts[0].second);
-      return;
+      return now();
     }
     auto pool = pool_snapshot();
     auto latch = std::make_shared<OpLatch>();
@@ -1658,9 +1723,11 @@ class Communicator {
     } catch (const std::exception& ex) {
       err0 = ex.what();
     }
+    TimePoint own_done = now();
     std::string err = latch->wait_quiet();
     if (!err0.empty()) throw CommError(err0);
     if (!err.empty()) throw CommError(err);
+    return own_done;
   }
 
   // striped send of view[off, off+nbytes) to peer, synchronous
@@ -1695,6 +1762,9 @@ class Communicator {
       const ScatterView& view, size_t off,
       const std::vector<std::pair<size_t, size_t>>& parts,
       TimePoint deadline) {
+    // one thread is every lane's sender here: a lane's send time runs from
+    // the gate to its last byte leaving
+    TimePoint began = now();
     io.gate();  // one gate arms every lane, like the Python loop
     struct LaneTx {
       int fd = -1;
@@ -1760,7 +1830,11 @@ class Communicator {
         io.add_tx(lt->lane, s2 - hdr_part);
         lt->cursor.advance(s2);
         progressed = true;
-        if (lt->cursor.remaining() == 0) --live;
+        if (lt->cursor.remaining() == 0) {
+          --live;
+          if (auto* c = io.lane_ns(io.tx_ns, lt->lane))
+            c->fetch_add(NsTimer::since(began), std::memory_order_relaxed);
+        }
       }
       if (!progressed && live > 0)
         std::this_thread::sleep_for(std::chrono::microseconds(200));
@@ -1815,21 +1889,21 @@ class Communicator {
     return latch;
   }
 
-  void recv_striped(EpochIO& io, const std::vector<int>& fds, int64_t peer,
-                    uint64_t tag, ScatterView& view, size_t off,
-                    size_t nbytes, TimePoint deadline) {
-    run_lane_parts(peer, LanePool::kRx, io.lane_parts(nbytes),
+  TimePoint recv_striped(EpochIO& io, const std::vector<int>& fds,
+                         int64_t peer, uint64_t tag, ScatterView& view,
+                         size_t off, size_t nbytes, TimePoint deadline) {
+    return run_lane_parts(peer, LanePool::kRx, io.lane_parts(nbytes),
                    [&](size_t lane, size_t s, size_t e) {
                      recv_framed_iov(io, fds[lane], peer, tag, view, off + s,
                                      e - s, deadline, lane);
                    });
   }
 
-  void recv_striped_reduce(EpochIO& io, const std::vector<int>& fds,
-                           int64_t peer, uint64_t tag, ScatterView& view,
-                           size_t off, size_t nbytes, DType dt, RedOp op,
-                           TimePoint deadline,
-                           std::vector<std::vector<uint8_t>>& scratches) {
+  TimePoint recv_striped_reduce(EpochIO& io, const std::vector<int>& fds,
+                                int64_t peer, uint64_t tag, ScatterView& view,
+                                size_t off, size_t nbytes, DType dt, RedOp op,
+                                TimePoint deadline,
+                                std::vector<std::vector<uint8_t>>& scratches) {
     auto parts = io.lane_parts(nbytes);
     // per-lane scratch from the caller's pool (grown once, reused across
     // ring steps): the quantum-pipelined reduce runs concurrently on every
@@ -1841,7 +1915,7 @@ class Communicator {
           64;
       if (scratches[i].size() < want) scratches[i].resize(want);
     }
-    run_lane_parts(peer, LanePool::kRx, parts,
+    return run_lane_parts(peer, LanePool::kRx, parts,
                    [&](size_t lane, size_t s, size_t e) {
                      recv_framed_reduce(io, fds[lane], peer, tag, view,
                                         off + s, e - s,
@@ -1883,6 +1957,7 @@ class Communicator {
                          DType dt, RedOp op, int64_t shift,
                          TimePoint deadline, const std::vector<int64_t>& ring,
                          uint64_t tag_base) {
+    NsTimer timed(&io->reduce_ns);
     int64_t ws = static_cast<int64_t>(ring.size());
     int64_t pos = ring_pos(ring, io->rank);
     int64_t right = ring[(pos + 1) % ws];
@@ -1905,15 +1980,19 @@ class Communicator {
           send_striped_async(io, right_fds, right, tag_base + 1000 + step,
                              view, chunk_off(send_idx), chunk_bytes(send_idx),
                              deadline);
+      TimePoint own_done;
       try {
-        recv_striped_reduce(*io, left_fds, left, tag_base + 1000 + step, view,
-                            chunk_off(recv_idx), chunk_bytes(recv_idx), dt, op,
-                            deadline, scratches);
+        own_done = recv_striped_reduce(
+            *io, left_fds, left, tag_base + 1000 + step, view,
+            chunk_off(recv_idx), chunk_bytes(recv_idx), dt, op, deadline,
+            scratches);
       } catch (...) {
         send_latch->wait_quiet();
         throw;
       }
       send_latch->wait();
+      io->tail_ns.fetch_add(NsTimer::since(own_done),
+                            std::memory_order_relaxed);
     }
   }
 
@@ -1925,6 +2004,7 @@ class Communicator {
                             int64_t shift, TimePoint deadline,
                             const std::vector<int64_t>& ring,
                             uint64_t tag_base) {
+    NsTimer timed(&io->gather_ns);
     int64_t ws = static_cast<int64_t>(ring.size());
     int64_t pos = ring_pos(ring, io->rank);
     int64_t right = ring[(pos + 1) % ws];
@@ -1946,15 +2026,18 @@ class Communicator {
           send_striped_async(io, right_fds, right, tag_base + 2000 + step,
                              view, chunk_off(send_idx), chunk_bytes(send_idx),
                              deadline);
+      TimePoint own_done;
       try {
-        recv_striped(*io, left_fds, left, tag_base + 2000 + step, view,
-                     chunk_off(recv_idx),
-                     chunk_bytes(recv_idx), deadline);
+        own_done = recv_striped(*io, left_fds, left, tag_base + 2000 + step,
+                                view, chunk_off(recv_idx),
+                                chunk_bytes(recv_idx), deadline);
       } catch (...) {
         send_latch->wait_quiet();
         throw;
       }
       send_latch->wait();
+      io->tail_ns.fetch_add(NsTimer::since(own_done),
+                            std::memory_order_relaxed);
     }
   }
 
@@ -1966,6 +2049,17 @@ class Communicator {
                           uint8_t* scratch, DType dt, RedOp op,
                           TimePoint deadline, size_t lane) {
     static constexpr size_t kQuantum = size_t(4) << 20;
+    std::atomic<uint64_t>* rx_ns = io.lane_ns(io.rx_ns, lane);
+    std::atomic<uint64_t>* add_ns = io.lane_ns(io.add_ns, lane);
+    // recv (the header's with the first quantum's), add, recv, add, ...:
+    // one clock read where the thread passes from the one to the other
+    TimePoint mark = now();
+    auto lap = [&mark](std::atomic<uint64_t>* to) {
+      TimePoint t = now();
+      if (to)
+        to->fetch_add(NsTimer::between(mark, t), std::memory_order_relaxed);
+      mark = t;
+    };
     uint64_t hdr[2];
     recv_loop(io, fd, peer, hdr, 16, deadline, lane, /*count=*/false);
     if (hdr[1] != tag)
@@ -1978,15 +2072,19 @@ class Communicator {
     while (off < nbytes) {
       size_t take = std::min(quantum, nbytes - off);
       recv_loop(io, fd, peer, scratch, take, deadline, lane);
+      lap(rx_ns);
       view.reduce_in(dst_off + off, scratch, take, dt, op);
+      lap(add_ns);
       off += take;
     }
+    if (nbytes == 0) lap(rx_ns);  // an empty frame is its header
   }
 
   // recv one frame straight into the view's segments (zero staging copy)
   void recv_framed_iov(EpochIO& io, int fd, int64_t peer, uint64_t tag,
                        ScatterView& view, size_t dst_off, size_t nbytes,
                        TimePoint deadline, size_t lane) {
+    NsTimer timed(io.lane_ns(io.rx_ns, lane));
     uint64_t hdr[2];
     recv_loop(io, fd, peer, hdr, 16, deadline, lane, /*count=*/false);
     if (hdr[1] != tag)

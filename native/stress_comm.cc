@@ -341,8 +341,7 @@ int main(int argc, char** argv) {
       ranks.emplace_back(phase_p_rank, comms[r].get(), r, addr, count,
                          (mib << 20) / 2);
     for (auto& t : ranks) t.join();
-    uint64_t tx[64], rx[64], stalls[64];
-    const size_t lanes = comms[0]->lane_stats(tx, rx, stalls, 64);
+    const size_t lanes = comms[0]->lanes();
     if (lanes != kUnshapedAutoLanes)
       fail("phase P ran at " + std::to_string(lanes) + " lanes, auto is " +
            std::to_string(kUnshapedAutoLanes));

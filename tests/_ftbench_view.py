@@ -84,11 +84,13 @@ def cell_walk(theirs):
 def compile_cases(*config_names):
     """The two cases of ``ftbench/tests/test_ftbench_compile.py`` for these
     configurations, under the ids they have there.  A case compiles a cell's
-    whole step for a described v5e, one to three minutes, and tier-1 hands a
-    FILE to one worker: a configuration has a file of its own
-    (``tests/test_ftbench_compile_<configuration>.py``), so that the next one
-    adds to the run's sum and not to its longest pole.  The file imports the
-    fixtures ``topo`` and ``no_compile_cache`` beside this."""
+    whole step for a described v5e, one to three minutes: a configuration
+    has a file of its own (``tests/test_ftbench_compile_<configuration>.py``)
+    and ``tests/conftest.py`` puts the step cases first in the collection and
+    the forward checks a quarter in, two runs from the start, so that the
+    next configuration adds to the run's sum and not to its longest pole.
+    The file imports the fixtures ``topo`` and ``no_compile_cache`` beside
+    this."""
     from ftbench.tests import test_ftbench_compile as theirs
 
     @pytest.mark.parametrize("config_name", config_names)

@@ -1242,6 +1242,20 @@ class TestNativeMirror:
         assert "lane_stats.lane_stalls" in symbols
         assert "lane_stats.lanes" not in symbols
 
+    def test_binding_is_held_to_the_ring_s_seven_keys_of_seconds(self):
+        """The checker lists the keys itself (it reads text and imports no
+        runtime module at load); the list is the Python tier's constant, and
+        a binding that lacks one of the seven is named."""
+        from torchft_tpu.communicator import RING_TIME_KEYS
+
+        assert len(RING_TIME_KEYS) == 7
+        assert set(RING_TIME_KEYS) <= set(nativemirror._LANE_STAT_KEYS)
+        text = "_MAX_IOV_SEGS = 64\n" + " ".join(
+            f'"{k}"' for k in nativemirror._LANE_STAT_KEYS if k != "ring_tail_s"
+        )
+        symbols = {f.symbol for f in nativemirror.check_binding(text, "torchft_tpu/native.py")}
+        assert {s for s in symbols if s.startswith("lane_stats.")} == {"lane_stats.ring_tail_s"}
+
     def test_binding_missing_iov_constant_flagged(self):
         findings = nativemirror.check_binding(
             '"lanes" "stripe_floor_bytes" "lane_tx_bytes" '

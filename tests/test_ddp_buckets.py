@@ -247,6 +247,98 @@ def test_from_the_second_step_the_buckets_are_the_first_steps_memory(pair) -> No
         assert m._host_buckets.kept_bytes() == sum(a.nbytes for a in handed[:n])
 
 
+def test_a_round_trip_reads_the_rings_counters_twice_and_says_where_its_time_went(pair) -> None:
+    """``Manager.ring_counters()`` once before a round trip's first submit and
+    once after its last ring, a ``lane_stats()`` call each and no other on the
+    round trip's threads; DDP_SYNC carries the differences."""
+    logs: List[List[str]] = []
+    for m in pair.managers:
+        log: List[str] = []
+        logs.append(log)
+        reading = threading.local()
+        counters, allreduce, lane_stats = m.ring_counters, m.allreduce, m._comm.lane_stats
+
+        def _lane_stats(log=log, reading=reading, inner=lane_stats):
+            if getattr(reading, "on", False):  # (the heartbeat reads it too, on its own thread)
+                log.append("lane_stats")
+            return inner()
+
+        def _counters(log=log, reading=reading, inner=counters):
+            log.append("counters")
+            reading.on = True
+            try:
+                return inner()
+            finally:
+                reading.on = False
+
+        def _allreduce(*args, log=log, inner=allreduce, **kwargs):
+            log.append("submit")
+            return inner(*args, **kwargs)
+
+        m._comm.lane_stats, m.ring_counters, m.allreduce = _lane_stats, _counters, _allreduce
+    rngs = [np.random.default_rng(40 + r) for r in range(2)]
+    for _ in range(3):
+        pair.step([_tree(rng) for rng in rngs])
+    _gathers_done()
+    for m, log in zip(pair.managers, logs):
+        syncs = _syncs(m)
+        n = syncs[0]["buckets"]
+        # (the second reading is the gather thread's, after its last ``work.wait()``)
+        assert log == 3 * (["counters", "lane_stats"] + ["submit"] * n + ["counters", "lane_stats"])
+        # a life's first round trip may begin before its quorum is adopted:
+        # the epoch changes under it and it records none; the next two do
+        for e in syncs[1:]:
+            assert e["ring_bytes"] > 0
+            phases = e["ring_reduce_s"] + e["ring_average_s"] + e["ring_gather_s"]
+            assert e["ring_average_s"] > 0.0 and 0.0 < phases <= e["duration_s"] + 1e-3
+            assert 0.0 <= e["ring_tail_s"] <= phases + 1e-5
+            assert all(e[k] >= 0.0 for k in ("ring_rx_s", "ring_add_s", "ring_tx_s"))
+
+
+@pytest.mark.parametrize(
+    "before,after,expects",
+    [
+        # two lanes of four sent bytes: a lane's seconds are the mean over those two
+        (
+            dict(epoch=3, lane_tx_bytes=[10, 10, 5, 5], lane_rx_s=[1.0, 1.0, 1.0, 1.0], lane_add_s=[0.5] * 4,
+                 lane_tx_s=[0.0] * 4, ring_reduce_s=2.0, ring_average_s=0.1, ring_gather_s=1.0, ring_tail_s=0.2),
+            dict(epoch=3, lane_tx_bytes=[110, 60, 5, 5], lane_rx_s=[1.4, 1.2, 9.0, 1.0], lane_add_s=[0.7, 0.6, 0.5, 0.5],
+                 lane_tx_s=[0.2, 0.4, 0.0, 0.0], ring_reduce_s=2.5, ring_average_s=0.15, ring_gather_s=1.25, ring_tail_s=0.3),
+            dict(ring_bytes=150, striped_bytes=50, ring_rx_s=0.3, ring_add_s=0.15, ring_tx_s=0.3,
+                 ring_reduce_s=0.5, ring_average_s=0.05, ring_gather_s=0.25, ring_tail_s=0.1),
+        ),
+        # the epoch changed under the round trip: the counts began anew, none is recorded
+        (
+            dict(epoch=3, lane_tx_bytes=[10], lane_rx_s=[1.0], lane_add_s=[1.0], lane_tx_s=[1.0],
+                 ring_reduce_s=2.0, ring_average_s=0.1, ring_gather_s=1.0, ring_tail_s=0.2),
+            dict(epoch=4, lane_tx_bytes=[90], lane_rx_s=[3.0], lane_add_s=[3.0], lane_tx_s=[3.0],
+                 ring_reduce_s=4.0, ring_average_s=0.3, ring_gather_s=3.0, ring_tail_s=0.4),
+            dict(ring_bytes=0, striped_bytes=0),
+        ),
+        # a communicator that counts bytes and no time (a tier of its own): bytes alone
+        (dict(epoch=1, lane_tx_bytes=[1, 2]), dict(epoch=1, lane_tx_bytes=[4, 8]), dict(ring_bytes=9, striped_bytes=6)),
+        # no lanes at all (one member, a stand-in)
+        (dict(epoch=1), dict(epoch=1), dict(ring_bytes=0, striped_bytes=0)),
+        # time counted and no byte sent: zeros of a lane, the op thread's as they are
+        (
+            dict(epoch=1, lane_tx_bytes=[7], lane_rx_s=[1.0], lane_add_s=[1.0], lane_tx_s=[1.0],
+                 ring_reduce_s=0.0, ring_average_s=0.0, ring_gather_s=0.0, ring_tail_s=0.0),
+            dict(epoch=1, lane_tx_bytes=[7], lane_rx_s=[1.0], lane_add_s=[1.0], lane_tx_s=[1.0],
+                 ring_reduce_s=0.0, ring_average_s=0.25, ring_gather_s=0.0, ring_tail_s=0.0),
+            dict(ring_bytes=0, striped_bytes=0, ring_rx_s=0.0, ring_add_s=0.0, ring_tx_s=0.0,
+                 ring_reduce_s=0.0, ring_average_s=0.25, ring_gather_s=0.0, ring_tail_s=0.0),
+        ),
+    ],
+    ids=["two_lanes_of_four", "epoch_changed", "bytes_alone", "no_lanes", "no_byte_sent"],
+)
+def test_ring_account_differences_two_readings(before, after, expects) -> None:
+    from torchft_tpu.ddp import _ring_account
+
+    got = _ring_account(before, after)
+    assert set(got) == set(expects)
+    assert all(got[k] == pytest.approx(expects[k], abs=1e-9) for k in expects)
+
+
 def test_a_second_manager_starts_cold(lighthouse_addr, monkeypatch) -> None:
     """Rule 3: the store lives and dies with its Manager; one at module level
     would hand a new life the dead life's warm pages."""

@@ -631,22 +631,12 @@ def drill():
             os.environ["TORCHFT_BUCKET_CAP_MB"] = saved_cap
 
 
-def _union(intervals):
-    total, reach = 0.0, float("-inf")
-    for a, b in sorted(intervals):
-        if b > reach:
-            total += b - max(a, reach)
-            reach = b
-    return total
-
-
 class TestSpansAcrossAFleet:
     def test_ddp_children_share_the_parents_step_and_replica_and_tile_it(self, drill):
         ddp = [s for s in drill.spans if s["name"].startswith(("tpuft/ddp/", "tpuft/comm/op"))]
         parents = [s for s in ddp if s["name"] == "tpuft/ddp/allreduce_pytree"]
         # every two-member step made one round trip a replica
         assert len(parents) >= 2 * (drill.STEPS - drill.KILL_AT - 8)
-        covered = []
         for p in parents:
             r, step = p["attrs"]["r"], p["attrs"]["step"]
             assert r.startswith("drill_") and step >= 0
@@ -665,15 +655,44 @@ class TestSpansAcrossAFleet:
             for stage in ("d2h", "pack", "ring_wait", "h2d"):
                 assert sum(s["name"] == f"tpuft/ddp/{stage}" for s in kids) == 3
             assert len({s["tid"] for s in kids}) >= 3  # train, op and gather threads
-            union = _union(
-                (max(s["t"], t0), min(s["t"] + s["dur"], t1)) for s in kids
-            )
-            covered.append(union / p["dur"])
-        covered.sort()
-        # the children tile the parent: 90 % or more (a loaded CI host delays a
-        # thread's start now and then, so the median is held, and the worst loosely)
-        assert covered[len(covered) // 2] >= 0.9, covered[:5]
-        assert covered[0] >= 0.5, covered[:5]
+            # The children tile the parent.  Held by ORDER on the one clock
+            # and not by the share of the parent's wall time they cover: on a
+            # loaded host a thread starts late now and then and the share of
+            # one round trip fell to 0.29 (the 0.5 it was held to failed four
+            # of five full runs), while the order cannot break.
+            def of(name, bucket=None):
+                found = sorted(
+                    (s for s in kids if s["name"] == name
+                     and (bucket is None or s["attrs"]["bucket"] == bucket)),
+                    key=lambda s: s["t"],
+                )
+                assert found, (step, name, bucket)
+                return found
+
+            def chained(spans):  # one thread, each over before the next begins
+                assert len({s["tid"] for s in spans}) == 1, [s["name"] for s in spans]
+                for a, b in zip(spans, spans[1:]):
+                    assert a["t"] + a["dur"] <= b["t"] + 1e-6, (step, a["name"], b["name"])
+
+            # the train thread: the plan, then a bucket's wait, pack and submit
+            chained(of("tpuft/ddp/plan") + [
+                of(f"tpuft/ddp/{stage}", b)[0] for b in range(3) for stage in ("d2h", "pack", "submit")
+            ])
+            # the gather thread: a bucket's ring, then its way back
+            gather = [of(f"tpuft/ddp/{stage}", b)[0] for b in range(3) for stage in ("ring_wait", "h2d")]
+            chained(gather)
+            # the op thread: a ring begins in its bucket's submit or after it
+            # and is over when the wait for it is
+            ops = of("tpuft/comm/op")
+            chained(ops)
+            for op in ops:
+                if op["attrs"]["k"] < 3:
+                    b = op["attrs"]["k"]
+                    assert of("tpuft/ddp/submit", b)[0]["t"] <= op["t"] + 1e-6
+                    wait = of("tpuft/ddp/ring_wait", b)[0]
+                    assert op["t"] + op["dur"] <= wait["t"] + wait["dur"] + 1e-6
+            # and the parent ends with its last child
+            assert gather[-1]["t"] + gather[-1]["dur"] <= t1 + 1e-6
 
     def test_comm_ops_count_from_zero_in_every_step(self, drill):
         ops = [s for s in drill.spans if s["name"] == "tpuft/comm/op"]
@@ -693,7 +712,29 @@ class TestSpansAcrossAFleet:
             stages = [e[k] for k in ("plan_s", "d2h_s", "pack_s", "ring_wait_s", "h2d_s")]
             assert all(v >= 0.0 for v in stages)
             assert sum(stages) <= e["duration_s"] + 1e-3
-            assert e["t0"] + e["duration_s"] == pytest.approx(e["t"], abs=1e-3)
+            # the event is written when the span has ended: after it, and at
+            # once (a loaded host put 2.3 ms between the two, where a
+            # millisecond was held and failed; 50 ms still means "at once")
+            assert e["t0"] + e["duration_s"] <= e["t"] + 1e-5 < e["t0"] + e["duration_s"] + 0.05
+        # where the round trip's rings say their time went: the seven seconds
+        # of ``ddp._ring_account``, all or (a reconfiguration under the round
+        # trip, or a ring of one) none; the op thread's three phases lie in
+        # the round trip, the lanes' seconds and the tail in the phases
+        seven = (
+            "ring_rx_s", "ring_add_s", "ring_tx_s",
+            "ring_reduce_s", "ring_average_s", "ring_gather_s", "ring_tail_s",
+        )
+        timed = [e for e in syncs if "ring_reduce_s" in e]
+        assert len(timed) >= len(syncs) - 8
+        for e in syncs:
+            if e not in timed:
+                assert not any(k in e for k in seven) and e["ring_bytes"] == 0
+                continue
+            assert all(e[k] >= 0.0 for k in seven), e
+            phases = e["ring_reduce_s"] + e["ring_average_s"] + e["ring_gather_s"]
+            assert 0.0 < phases <= e["duration_s"] + 1e-3
+            assert e["ring_tail_s"] <= phases + 1e-5 and e["ring_add_s"] <= phases + 1e-5
+        assert sum(e["ring_average_s"] > 0.0 for e in timed) >= len(timed) - 8
         # the buckets filled in memory kept from the step before: none in a
         # life's first round trip (nor after the one the kill broke), then all
         warm = [e["warm_buckets"] for e in syncs]
@@ -742,9 +783,16 @@ class TestSpansAcrossAFleet:
         """Three leaves of one size: the first is fetched when the handler
         comes to it, the other two were under way while the one before them
         was written (``PytreePlan.host_leaves``)."""
-        survivor = drill.managers[0][0]._flight.snapshot()
-        served = [e for e in survivor if e["name"] == "HEAL_SERVE_END"]
-        assert len(served) >= 2  # the other replica's init_sync, then the heal
+        # the survivor served the heal, and before it one of the two first
+        # lives served the other's init_sync: whichever came to the first
+        # quorum first (the survivor as a rule; on a loaded host the other)
+        served = [
+            e
+            for first_life in (drill.managers[0][0], drill.managers[1][0])
+            for e in first_life._flight.snapshot()
+            if e["name"] == "HEAL_SERVE_END"
+        ]
+        assert len(served) >= 2
         for e in served:
             assert {"bytes", "d2h_s", "write_s", "ahead_bytes", "part", "duration_s"} <= set(e)
             assert e["ahead_bytes"] == 2 * 4 * drill.LEAF < e["bytes"]

@@ -20,10 +20,8 @@ from ftbench.tests.test_ftbench_compile import (  # noqa: F401
 from tests._ftbench_view import compile_cases
 
 CASES = ("test_step_compiles_for_v5e", "test_forward_check_compiles_for_v5e")
-_OWN_FILES = [
-    importlib.import_module("tests." + os.path.basename(path)[:-3])
-    for path in sorted(glob.glob(os.path.join(os.path.dirname(os.path.abspath(__file__)), "test_ftbench_compile_*.py")))
-]
+_OWN_PATHS = sorted(glob.glob(os.path.join(os.path.dirname(os.path.abspath(__file__)), "test_ftbench_compile_*.py")))
+_OWN_FILES = [importlib.import_module("tests." + os.path.basename(path)[:-3]) for path in _OWN_PATHS]
 # the configurations each case is parametrised over in the files of their own
 _IN_A_FILE = {
     case: [name for module in _OWN_FILES if hasattr(module, case) for name in getattr(module, case).pytestmark[0].args[1]]
@@ -37,3 +35,31 @@ if REST:  # an empty parametrisation would show as two skips in every run
 def test_every_configuration_is_compiled_exactly_once_across_the_files():
     for case in CASES:
         assert sorted(_IN_A_FILE[case] + REST) == sorted(theirs.CONFIG_NAMES), case
+
+
+def test_the_collection_hands_the_compile_cases_out_as_two_runs_from_the_start():
+    """``--dist load`` gives a worker consecutive tests: ``tests/conftest.py``
+    puts the step cases first and the forward checks a quarter in, and leaves
+    every other test in its order."""
+    from types import SimpleNamespace
+
+    from tests import conftest
+
+    names = (
+        [f"tests/test_a.py::{i}" for i in range(200)]
+        + [f"tests/{os.path.basename(path)}::{case}[x]" for path in _OWN_PATHS for case in CASES]
+        + ["tests/test_ftbench_compile.py::rest"]
+        + [f"tests/test_z.py::{i}" for i in range(1500)]
+    )
+    items = [SimpleNamespace(fspath=n.split("::")[0], name=n.split("::")[1], id=n) for n in names]
+    conftest.pytest_collection_modifyitems(None, items)
+    placed = [item.id for item in items]
+    configs, quarter = len(_OWN_PATHS), len(names) // 4
+    assert all("_compile_" in n and CASES[0] in n for n in placed[:configs])
+    later = placed[configs + quarter : 2 * configs + quarter]
+    assert all("_compile_" in n and CASES[1] in n for n in later)
+    assert [n for n in placed if "_compile_" not in n] == [n for n in names if "_compile_" not in n]
+    # a selection of a few tests keeps them all
+    few = items[:3]
+    conftest.pytest_collection_modifyitems(None, few)
+    assert len(few) == 3

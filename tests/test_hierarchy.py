@@ -316,6 +316,12 @@ class TestHierarchicalCollectives:
                 # the whole point: members never touch the DCN
                 assert sum(st["lane_tx_bytes"]) == 0
                 assert st["shm_tx_bytes"] > 0
+            # where the time went: the shared-memory legs are no lane's, they
+            # lie in the phase they belong to (``lane_stats``' docstring), so
+            # a member, which rings nothing, still says how long each took
+            assert st["ring_reduce_s"] > 0.0 and st["ring_gather_s"] > 0.0
+            moved = sum(st["lane_rx_s"]) + sum(st["lane_tx_s"]) + st["ring_tail_s"]
+            assert moved > 0.0 if st["topo_is_leader"] else moved == 0.0
 
 
 class TestQuantizedOncePerHost:

@@ -731,6 +731,63 @@ class TestNativeLaneStats:
         assert comm.lane_stats() == {}
         comm.shutdown()
 
+    @pytest.mark.parametrize(
+        "cpp_ranks", [{0, 1}, set(), {1}], ids=["cpp", "python", "mixed"]
+    )
+    def test_the_ring_says_where_its_time_went(
+        self, cpp_store, monkeypatch, cpp_ranks
+    ) -> None:
+        """``lane_stats()``'s seven counters of seconds (``RING_TIME_KEYS``),
+        the same in both tiers and across a mixed pair: there, monotone,
+        the op thread's three phases inside the wall time of the calls, the
+        add counted by a reduce alone and the division by a divisor alone."""
+        from torchft_tpu.communicator import RING_TIME_KEYS
+
+        monkeypatch.setenv("TORCHFT_RING_LANES", "2")
+        n = 400_000  # 1.6 MB: a ring's half stripes across both lanes
+
+        def _fn(comm, rank):
+            data = np.ones(n, dtype=np.float32) * (rank + 1)
+            seen = [comm.lane_stats()]
+            comm.allgather(np.arange(4096, dtype=np.float32)).wait(timeout=30.0)
+            seen.append(comm.lane_stats())
+            t0 = time.monotonic()
+            comm.allreduce(data, ReduceOp.SUM).wait(timeout=30.0)
+            seen.append(comm.lane_stats())
+            out = comm.allreduce(data, ReduceOp.SUM, divisor=2).wait(timeout=30.0)
+            wall = time.monotonic() - t0
+            seen.append(comm.lane_stats())
+            np.testing.assert_array_equal(np.asarray(out), np.full(n, 1.5, np.float32))
+            return seen, wall
+
+        for seen, wall in _run_mixed_ranks(cpp_store, 2, cpp_ranks, _fn, "times"):
+            fresh, gathered, summed, averaged = seen
+            phases = ("ring_reduce_s", "ring_average_s", "ring_gather_s")
+            for stats in seen:
+                assert set(RING_TIME_KEYS) <= set(stats)
+                assert all(len(stats[k]) == 2 for k in RING_TIME_KEYS[:3])
+            # every counter is cumulative over the epoch
+            for before, after in zip(seen, seen[1:]):
+                for key in RING_TIME_KEYS:
+                    a, b = np.atleast_1d(before[key]), np.atleast_1d(after[key])
+                    assert (b >= a).all() and (a >= 0.0).all(), key
+            # an allgather is no ring and adds nothing
+            assert sum(gathered["lane_add_s"]) == 0.0
+            assert all(gathered[k] == 0.0 for k in phases + ("ring_tail_s",))
+            assert sum(gathered["lane_rx_s"]) > sum(fresh["lane_rx_s"])
+            assert sum(gathered["lane_tx_s"]) > sum(fresh["lane_tx_s"])
+            # a ring that sums: both phases, the add, a tail, no division
+            assert summed["ring_reduce_s"] > 0.0 and summed["ring_gather_s"] > 0.0
+            assert all(v > 0.0 for v in summed["lane_add_s"])
+            assert summed["ring_tail_s"] > 0.0 and summed["ring_average_s"] == 0.0
+            # a ring that averages: the owner's division between the phases
+            assert averaged["ring_average_s"] > 0.0
+            # the op thread's phases lie in the calls, the tail in the phases
+            assert sum(averaged[k] for k in phases) <= wall
+            assert averaged["ring_tail_s"] <= (
+                averaged["ring_reduce_s"] + averaged["ring_gather_s"]
+            )
+
 
 class TestNativePacerParity:
     def test_auto_lane_and_floor_parity_under_emulation(

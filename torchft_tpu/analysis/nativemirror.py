@@ -29,8 +29,11 @@ every shared constant against its live Python counterpart:
   name-for-name and value-for-value in both directions
 - per-lane counter names: ``comm.h`` must define the ``lane_tx_bytes`` /
   ``lane_rx_bytes`` / ``lane_stalls`` counters and ``native.py`` must
-  export the same ``lane_stats()`` keys the Python tier does, so
-  ``manager.last_quorum_timings`` stays tier-agnostic
+  export the same ``lane_stats()`` keys the Python tier does (the three of
+  bytes and stalls, and the seven of seconds: ``lane_rx_s``, ``lane_add_s``,
+  ``lane_tx_s``, ``ring_reduce_s``, ``ring_average_s``, ``ring_gather_s``,
+  ``ring_tail_s``), so ``manager.last_quorum_timings`` and DDP_SYNC stay
+  tier-agnostic
 - flight-recorder event ids: every ``kFlight<Name> = N`` constant in
   ``comm.h`` must match ``obs.flight.FlightEvent.<NAME>`` (CamelCase →
   UPPER_SNAKE) value-for-value, the C ring must exist
@@ -73,6 +76,15 @@ _LANE_STAT_KEYS = (
     "lane_tx_bytes",
     "lane_rx_bytes",
     "lane_stalls",
+    # where the epoch's time went (seconds; ``communicator.RING_TIME_KEYS``,
+    # native: comm.h EpochIO's counters of nanoseconds)
+    "lane_rx_s",
+    "lane_add_s",
+    "lane_tx_s",
+    "ring_reduce_s",
+    "ring_average_s",
+    "ring_gather_s",
+    "ring_tail_s",
 )
 
 

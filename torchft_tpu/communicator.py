@@ -156,6 +156,21 @@ def _reduce_into(op: ReduceOp, acc: np.ndarray, incoming: np.ndarray) -> None:
         raise ValueError(f"unsupported reduce op {op}")
 
 
+# ``lane_stats()``'s seconds, the same in every tier that has lanes: a lane's
+# in recv, in the reduce's add and in send (lists), then the op thread's in a
+# ring's reduce-scatter phase, the division between the phases, the
+# allgather phase and the steps' tails (``TCPCommunicator.lane_stats``)
+RING_TIME_KEYS = (
+    "lane_rx_s",
+    "lane_add_s",
+    "lane_tx_s",
+    "ring_reduce_s",
+    "ring_average_s",
+    "ring_gather_s",
+    "ring_tail_s",
+)
+
+
 class CommunicatorError(RuntimeError):
     pass
 
@@ -295,8 +310,8 @@ class Communicator(ABC):
 
     def lane_stats(self) -> Dict[str, object]:
         """Per-lane data-plane counters of the current epoch (lane count,
-        stripe floor, bytes, stall events); empty for tiers without lane
-        striping or before configure."""
+        stripe floor, bytes, stall events, seconds by where they went);
+        empty for tiers without lane striping or before configure."""
         return {}
 
     def hier_topology(self) -> Optional[Dict[str, object]]:
@@ -1085,6 +1100,18 @@ class _TcpMesh:
         self.lane_tx_bytes = [0] * self.lanes
         self.lane_rx_bytes = [0] * self.lanes
         self.lane_stalls = [0] * self.lanes
+        # where the epoch's time goes, seconds, always counted: a lane's
+        # inside recv, inside the reduce's add and inside send (``exchange``),
+        # the op thread's in a ring's two phases, in the division between
+        # them and in a step's tail (``_ring_reduce_scatter``,
+        # ``_ring_allreduce``) — ``lane_stats`` says what each one is
+        self.lane_rx_s = [0.0] * self.lanes
+        self.lane_add_s = [0.0] * self.lanes
+        self.lane_tx_s = [0.0] * self.lanes
+        self.ring_reduce_s = 0.0
+        self.ring_average_s = 0.0
+        self.ring_gather_s = 0.0
+        self.ring_tail_s = 0.0
         # gray-failure machinery: fault program (env or runtime-armed),
         # in-epoch lane recovery knobs + counters, per-(peer, lane)
         # completed-sub-frame sequence counters the reconnect/failover
@@ -1673,8 +1700,14 @@ class _TcpMesh:
         recvs: Sequence[Tuple],
         deadline: float,
         lane: Optional[int] = None,
-    ) -> None:
+    ) -> Optional[float]:
         """Concurrently push ``sends`` and drain ``recvs``.
+
+        Returns the ``time.monotonic()`` at which the first part of the
+        first receive was whole and reduced (the part the native tier's op
+        thread receives itself, ``run_lane_parts``), None without receives:
+        from then on the loop serves the other lanes and its own sends, what
+        a ring's step counts as its tail.
 
         ``sends`` entries are ``(peer_rank, tag, payload_view)``; ``recvs``
         entries additionally accept an optional 4th element — an
@@ -1719,6 +1752,8 @@ class _TcpMesh:
         # plus the live buffer list carrying sub-frames strictly in order
         ctx = _ExchangeCtx()
         send_q, recv_q = ctx.send_q, ctx.recv_q
+        own_done: Optional[float] = None
+        clock = time.monotonic
         for peer, tag, view in sends:
             for ln, start, stop in _parts(len(view)):
                 header = _HDR.pack(stop - start, tag)
@@ -1738,6 +1773,9 @@ class _TcpMesh:
                         "start": start,
                         "stop": stop,
                         "on_part": on_part,
+                        # the first receive's first part: the native tier's
+                        # op thread takes that one itself
+                        "first": entry is recvs[0] and start == 0,
                     }
                 )
 
@@ -1856,7 +1894,9 @@ class _TcpMesh:
                                 self.lane_stalls[ln] += 1
                                 break
                             chunk = chunk[:allowed]
+                        t_io = clock()
                         sent = sock.send(chunk)
+                        self.lane_tx_s[ln] += clock() - t_io
                         if emu is not None:
                             emu.consume(sent, stream=key)
                         self.lane_tx_bytes[ln] += sent
@@ -1907,7 +1947,9 @@ class _TcpMesh:
                             key, {"hdr": bytearray(), "off": 0, "exp": None}
                         )
                         if len(st["hdr"]) < _HDR.size:
+                            t_io = clock()
                             chunk = sock.recv(_HDR.size - len(st["hdr"]))
+                            self.lane_rx_s[ln] += clock() - t_io
                             if not chunk:
                                 raise PeerGoneError(
                                     f"connection to rank {peer} closed"
@@ -1948,7 +1990,9 @@ class _TcpMesh:
                                         )
                                     st["exp"] = exp
                         elif st["off"] < len(st["exp"]["view"]):
+                            t_io = clock()
                             n = sock.recv_into(st["exp"]["view"][st["off"] :])
+                            self.lane_rx_s[ln] += clock() - t_io
                             if n == 0:
                                 raise PeerGoneError(
                                     f"connection to rank {peer} closed"
@@ -1980,7 +2024,11 @@ class _TcpMesh:
                                     self._rx_seq.get(key, 0) + 1
                                 )
                                 if exp["on_part"] is not None:
+                                    t_io = clock()
                                     exp["on_part"](exp["start"], exp["stop"])
+                                    self.lane_add_s[ln] += clock() - t_io
+                                if own_done is None and exp["first"]:
+                                    own_done = clock()
                 except BlockingIOError:
                     pass
                 except (OSError, PeerGoneError) as e:
@@ -2002,6 +2050,7 @@ class _TcpMesh:
                 # socket writable but the pacer denied bytes — select would
                 # return immediately and spin the op thread hot
                 time.sleep(0.0005)
+        return own_done
 
     # -- gray-failure recovery internals -------------------------------------
 
@@ -2894,7 +2943,25 @@ class TCPCommunicator(Communicator):
         payload bytes sent/received per lane, stall events (pacer denials /
         kernel would-block) per lane, and the gray-failure counters
         (in-epoch lane reconnects/failovers, injected faults).  Empty when
-        unconfigured or single-member."""
+        unconfigured or single-member.
+
+        Where the epoch's time went, in seconds since its configure, under
+        the native tier's names (``CppCommunicator.lane_stats`` says what
+        each one is there) and taken at the same points, always on.  A
+        lane: ``lane_rx_s`` inside ``recv`` of a frame's header and payload,
+        ``lane_add_s`` inside the reduce's add, ``lane_tx_s`` inside
+        ``send``.  This tier's sockets do not block and ONE select loop
+        serves every lane, so a lane's seconds here are the kernel's copies
+        alone and the wait for the peer lies in ``select``, under no lane
+        (the native tier's threads wait inside ``::recv``).  The op thread:
+        ``ring_reduce_s`` its wall time in a ring's reduce-scatter phase,
+        ``ring_average_s`` in the owner's division between the phases,
+        ``ring_gather_s`` in the allgather phase, ``ring_tail_s``, of the
+        phases' steps, from the first lane's part of the receive being
+        whole and reduced to the step's end.  Where another transport
+        carries a leg of a ring (the hierarchical topology's shared-memory
+        reduce before the leaders' ring and broadcast after it), its time
+        lies in that phase's counter and in no lane's."""
         mesh = self._mesh
         if mesh is None:
             return {}
@@ -2904,6 +2971,13 @@ class TCPCommunicator(Communicator):
             "lane_tx_bytes": list(mesh.lane_tx_bytes),
             "lane_rx_bytes": list(mesh.lane_rx_bytes),
             "lane_stalls": list(mesh.lane_stalls),
+            "lane_rx_s": list(mesh.lane_rx_s),
+            "lane_add_s": list(mesh.lane_add_s),
+            "lane_tx_s": list(mesh.lane_tx_s),
+            "ring_reduce_s": mesh.ring_reduce_s,
+            "ring_average_s": mesh.ring_average_s,
+            "ring_gather_s": mesh.ring_gather_s,
+            "ring_tail_s": mesh.ring_tail_s,
             "lane_reconnects": mesh.lane_reconnects,
             "lane_failovers": mesh.lane_failovers,
             "faults_injected": mesh.faults_injected,
@@ -3618,6 +3692,7 @@ def _ring_reduce_scatter(
 
     scratch = np.empty(bounds[1], dtype=flat.dtype)
     itemsize = flat.dtype.itemsize
+    began = time.monotonic()
     for step in range(ws - 1):
         send_idx = (pos - step - 1) % ws
         recv_idx = (pos - step - 2) % ws
@@ -3635,11 +3710,14 @@ def _ring_reduce_scatter(
             lo, hi = start // itemsize, stop // itemsize
             _reduce_into(op, _dst[lo:hi], _src[lo:hi])
 
-        mesh.exchange(
+        own_done = mesh.exchange(
             [(right, tag_base + 1000 + step, _bytes_view(send_chunk))],
             [(left, tag_base + 1000 + step, _bytes_view(recv_buf), _reduce_part)],
             deadline,
         )
+        if own_done is not None:  # (a failover may re-route the part)
+            mesh.ring_tail_s += time.monotonic() - own_done
+    mesh.ring_reduce_s += time.monotonic() - began
     return chunk(pos)
 
 
@@ -3670,13 +3748,16 @@ def _ring_allreduce(
     if ring is None:
         ring = list(range(ctx.world_size))
     ws = len(ring)
+    mesh = ctx.mesh
     if ws == 1:
         if divisor is not None:
+            began = time.monotonic()
             _div(flat, divisor, out=flat)
+            if mesh is not None:
+                mesh.ring_average_s += time.monotonic() - began
         return
     if divisor is not None:
         tag_base += wire_tags.RING_AVG_TAG_BASE
-    mesh = ctx.mesh
     assert mesh is not None
     pos = ring.index(ctx.rank)
     right = ring[(pos + 1) % ws]
@@ -3685,7 +3766,9 @@ def _ring_allreduce(
 
     own = _ring_reduce_scatter(ctx, flat, op, tag_base, ring=ring)
     if divisor is not None:
+        began = time.monotonic()
         _div(own, divisor, out=own)
+        mesh.ring_average_s += time.monotonic() - began
     bounds = _ring_bounds(flat.size, ws)
 
     def chunk(i: int) -> np.ndarray:
@@ -3693,14 +3776,18 @@ def _ring_allreduce(
         return flat[bounds[i] : bounds[i + 1]]
 
     # allgather phase: ring position p starts owning reduced chunk p
+    began = time.monotonic()
     for step in range(ws - 1):
         send_idx = (pos - step) % ws
         recv_idx = (pos - step - 1) % ws
-        mesh.exchange(
+        own_done = mesh.exchange(
             [(right, tag_base + 2000 + step, _bytes_view(chunk(send_idx)))],
             [(left, tag_base + 2000 + step, _bytes_view(chunk(recv_idx)))],
             deadline,
         )
+        if own_done is not None:  # (a failover may re-route the part)
+            mesh.ring_tail_s += time.monotonic() - own_done
+    mesh.ring_gather_s += time.monotonic() - began
 
 
 def _hier_allreduce(
@@ -3722,10 +3809,16 @@ def _hier_allreduce(
     assert mesh is not None and mesh.topo is not None
     topo = mesh.topo
     deadline = ctx.deadline()
+    # the shared-memory legs are no lane's: their time lies in the phase
+    # they belong to (``lane_stats``)
+    began = time.monotonic()
     mesh.shm_reduce(flat, op, deadline)
+    mesh.ring_reduce_s += time.monotonic() - began
     if topo.is_leader:
         _ring_allreduce(ctx, flat, op, tag_base, ring=topo.leader_ring, divisor=divisor)
+    began = time.monotonic()
     mesh.shm_bcast(flat, deadline)
+    mesh.ring_gather_s += time.monotonic() - began
 
 
 def _hier_allgather_sync(

@@ -65,6 +65,7 @@ from torchft_tpu.checkpointing.serialization import (
     ShardedHostArray,
     shard_key as _shard_key,
 )
+from torchft_tpu.communicator import RING_TIME_KEYS
 from torchft_tpu.manager import Manager
 from torchft_tpu.obs import spans as obs_spans
 from torchft_tpu.obs.flight import FlightEvent
@@ -617,6 +618,40 @@ def _restore(leaf: Any, slot: _Slot, avg_flat: np.ndarray, aliased: bool) -> Any
     )
 
 
+def _ring_account(before: Dict[str, Any], after: Dict[str, Any]) -> Dict[str, Any]:
+    """What a round trip's rings sent and where their time went: the
+    difference of two ``Manager.ring_counters()`` readings, as DDP_SYNC
+    carries it.  ``ring_bytes`` on every lane and ``striped_bytes`` on the
+    lanes other than lane 0 (what striping moved off the one stream).
+    ``ring_rx_s``, ``ring_add_s``, ``ring_tx_s``: a lane's seconds in recv, in
+    the reduce's add and in send, the MEAN over the lanes that sent bytes in
+    the round trip (lanes run beside each other on equal parts, so the mean
+    is a lane's share of the wall); ``ring_reduce_s``, ``ring_average_s``,
+    ``ring_gather_s``, ``ring_tail_s``: the op thread's in the two phases, the
+    division between them and the steps' tails.  A reconfiguration in between
+    starts the counts anew: such a round trip records no bytes and no
+    seconds, and a communicator that counts no time records none."""
+    account: Dict[str, Any] = {"ring_bytes": 0, "striped_bytes": 0}
+    if before["epoch"] != after["epoch"]:
+        return account
+    sent = [
+        max(0, b - a)
+        for a, b in zip(before.get("lane_tx_bytes") or [], after.get("lane_tx_bytes") or [])
+    ]
+    account.update(ring_bytes=sum(sent), striped_bytes=sum(sent[1:]))
+    if not all(k in before and k in after for k in RING_TIME_KEYS):
+        return account
+    busy = [lane for lane, n in enumerate(sent) if n]
+    for key in RING_TIME_KEYS:
+        a, b = before[key], after[key]
+        if key.startswith("lane_"):  # lane_rx_s -> ring_rx_s
+            spent = sum(b[lane] - a[lane] for lane in busy) / len(busy) if busy else 0.0
+            account[f"ring_{key[len('lane_'):]}"] = round(spent, 6)
+        else:
+            account[key] = round(b - a, 6)
+    return account
+
+
 def allreduce_pytree(
     manager: Manager,
     tree: Any,
@@ -688,7 +723,7 @@ def allreduce_pytree(
         "first_submit_s": 0.0,  # from the round trip's start to the first bucket's submit
     }
 
-    tx_before = manager.ring_tx_bytes()
+    ring_before = manager.ring_counters()
     store = _bucket_store(manager)
     works: List[Work] = []
     flats: List[np.ndarray] = []  # each bucket's part of its host buffer, in the plan's order
@@ -794,19 +829,13 @@ def allreduce_pytree(
             restored = _gather()
         except Exception as e:  # noqa: BLE001 — funnel, never raise
             manager.report_error(e)
-        # what this round trip's rings sent, and how much of it off lane 0
-        # (a reconfiguration in between starts the lanes' counts anew)
-        ring_bytes, striped_bytes = (
-            max(0, after - before) for after, before in zip(manager.ring_tx_bytes(), tx_before)
-        )
         sync_span.set(
             buckets=len(works),
             warm_buckets=0 if kept is None else len(works),
             bytes=plan.nbytes,
             direct_bytes=plan.direct_nbytes,
             split_bytes=plan.split_nbytes,
-            ring_bytes=ring_bytes,
-            striped_bytes=striped_bytes,
+            **_ring_account(ring_before, manager.ring_counters()),
             **{k: round(v, 6) for k, v in stage_s.items()},
         )
         sync_span.__exit__()

@@ -42,7 +42,7 @@ from torchft_tpu.obs.flight import FlightEvent, FlightRecorder, flight_dir
 from torchft_tpu.obs import spans as obs_spans
 from torchft_tpu.obs.spans import span as obs_span
 from torchft_tpu.checkpointing.transport import CheckpointTransport
-from torchft_tpu.communicator import Communicator, ReduceOp, _div
+from torchft_tpu.communicator import RING_TIME_KEYS, Communicator, ReduceOp, _div
 from torchft_tpu.manager_server import ManagerClient, ManagerServer
 from torchft_tpu.store import StoreClient, StoreServer
 from torchft_tpu.work import DummyWork, Event, Work
@@ -952,6 +952,13 @@ class Manager:
                     comm_injected_faults=prev_lane_stats.get(
                         "faults_injected", 0
                     ),
+                    # where the outgoing epoch's ring time went
+                    # (comm_lane_rx_s ... comm_ring_tail_s)
+                    **{
+                        f"comm_{k}": prev_lane_stats[k]
+                        for k in RING_TIME_KEYS
+                        if k in prev_lane_stats
+                    },
                 )
                 # fold the OUTGOING epoch's counters into the job-lifetime
                 # base the heartbeat health summary reports from; from here
@@ -1262,15 +1269,24 @@ class Manager:
             and self._errored is None
         )
 
-    def ring_tx_bytes(self) -> Tuple[int, int]:
-        """Payload bytes the live epoch's communicator has sent so far: on
-        every lane, and on the lanes other than lane 0 (what striping moved
-        off the one stream; 0 at one lane).  ``ddp.allreduce_pytree``
-        differences it over a round trip for DDP_SYNC's ``ring_bytes`` and
-        ``striped_bytes``."""
+    def ring_counters(self) -> Dict[str, Any]:
+        """What the live epoch's communicator has counted so far, in ONE
+        ``lane_stats()`` call: ``lane_tx_bytes`` (payload bytes sent a lane)
+        and the seven ``RING_TIME_KEYS`` (seconds a lane spent in recv, in
+        the reduce's add and in send; the op thread's in the ring's two
+        phases, the division between them and the tail), with ``epoch``, the
+        quorum the counts belong to (a reconfiguration starts them anew).
+        ``ddp.allreduce_pytree`` reads it before a round trip's first submit
+        and after its last ring and puts the differences on DDP_SYNC.  A
+        communicator without lanes (or of one member) gives the epoch
+        alone."""
         stats_fn = getattr(self._comm, "lane_stats", None)
-        tx = (stats_fn() if callable(stats_fn) else {}).get("lane_tx_bytes") or []
-        return sum(tx), sum(tx[1:])
+        stats = (stats_fn() if callable(stats_fn) else {}) or {}
+        counters: Dict[str, Any] = {"epoch": self._quorum_id}
+        counters.update(
+            (k, stats[k]) for k in ("lane_tx_bytes", *RING_TIME_KEYS) if k in stats
+        )
+        return counters
 
     def allreduce(
         self,

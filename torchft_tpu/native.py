@@ -236,11 +236,10 @@ def _load() -> Optional[ctypes.CDLL]:
         lib.tpuft_comm_lane_stats.restype = ctypes.c_uint64
         lib.tpuft_comm_lane_stats.argtypes = [
             ctypes.c_void_p,
-            ctypes.POINTER(ctypes.c_uint64),
-            ctypes.POINTER(ctypes.c_uint64),
-            ctypes.POINTER(ctypes.c_uint64),
+            *[ctypes.POINTER(ctypes.c_uint64)] * 6,  # tx rx stalls | rx add tx ns
             ctypes.c_uint64,
-            ctypes.POINTER(ctypes.c_uint64),
+            ctypes.POINTER(ctypes.c_uint64),  # stripe floor
+            ctypes.POINTER(ctypes.c_uint64),  # the op thread's four, ns
         ]
         lib.tpuft_comm_reduce_scatter.argtypes = [
             ctypes.c_void_p,
@@ -843,29 +842,52 @@ class CppCommunicator(Communicator):
         Python tier additionally exports (reconnects/failovers/injected
         faults) report 0 — the native tier has no fault injection or
         in-epoch lane recovery yet.  Empty when unconfigured or
-        single-member."""
+        single-member.
+
+        Where the epoch's time went, in seconds since its configure,
+        counted inside ``native/comm.h`` (``EpochIO``; always on).  A lane:
+        ``lane_rx_s`` its thread inside ``::recv`` of a striped frame's
+        header and payload (waiting for the peer AND the kernel's copy out
+        of the socket, not told apart), ``lane_add_s`` inside the reduce's
+        add, ``lane_tx_s`` its sender inside ``sendmsg`` and the pacing.
+        The op thread, on the clock of ``tpuft/comm/op``:
+        ``ring_reduce_s`` its wall time in the ring's reduce-scatter
+        phase, ``ring_average_s`` in the owner's division between the
+        phases, ``ring_gather_s`` in the allgather phase, and
+        ``ring_tail_s``, of the phases' steps, from its own part of a
+        receive returning to the other lanes' parts and its own send having
+        landed.  Lanes run beside each other, so a lane's seconds are a
+        share of the phases' and the tail lies inside them."""
         with self._lock:
             if self._h is None or self._world_size <= 1:
                 return {}
             cap = 64
-            tx = (ctypes.c_uint64 * cap)()
-            rx = (ctypes.c_uint64 * cap)()
-            stalls = (ctypes.c_uint64 * cap)()
+            # tx rx stalls, then a lane's nanoseconds in recv, add, send
+            lane = [(ctypes.c_uint64 * cap)() for _ in range(6)]
+            ring_ns = (ctypes.c_uint64 * 4)()
             floor = ctypes.c_uint64()
             lanes = int(
                 self._lib.tpuft_comm_lane_stats(
-                    self._h, tx, rx, stalls, cap, ctypes.byref(floor)
+                    self._h, *lane, cap, ctypes.byref(floor), ring_ns
                 )
             )
         if lanes <= 0:
             return {}
         n = min(lanes, cap)
+        tx, rx, stalls, rx_ns, add_ns, tx_ns = ([int(c[i]) for i in range(n)] for c in lane)
         return {
             "lanes": lanes,
             "stripe_floor_bytes": int(floor.value),
-            "lane_tx_bytes": [int(tx[i]) for i in range(n)],
-            "lane_rx_bytes": [int(rx[i]) for i in range(n)],
-            "lane_stalls": [int(stalls[i]) for i in range(n)],
+            "lane_tx_bytes": tx,
+            "lane_rx_bytes": rx,
+            "lane_stalls": stalls,
+            "lane_rx_s": [v / 1e9 for v in rx_ns],
+            "lane_add_s": [v / 1e9 for v in add_ns],
+            "lane_tx_s": [v / 1e9 for v in tx_ns],
+            "ring_reduce_s": ring_ns[0] / 1e9,
+            "ring_average_s": ring_ns[1] / 1e9,
+            "ring_gather_s": ring_ns[2] / 1e9,
+            "ring_tail_s": ring_ns[3] / 1e9,
             "lane_reconnects": 0,
             "lane_failovers": 0,
             "faults_injected": 0,

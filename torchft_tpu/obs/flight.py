@@ -108,8 +108,10 @@ class FlightEvent(enum.IntEnum):
     # -- span boundaries (python only; written by obs.spans at a span's exit,
     # each with t0 and duration_s) ------------------------------------------
     DDP_SYNC = 29  # one replica-dimension round trip of a gradient pytree
-    # (detail: buckets, bytes, and the summed seconds of each stage:
-    # plan_s, d2h_s, pack_s, ring_wait_s, h2d_s)
+    # (detail: buckets, bytes, the summed seconds of each stage: plan_s,
+    # d2h_s, pack_s, ring_wait_s, h2d_s, and where its rings say their time
+    # went: ring_rx_s, ring_add_s, ring_tx_s a lane, ring_reduce_s,
+    # ring_average_s, ring_gather_s, ring_tail_s on the op thread)
     HEAL_SERVE_END = 30  # one checkpoint response served to a healing peer
     # (detail: bytes, d2h_s blocked waiting for leaves to reach the host,
     # write_s blocked writing the socket, ahead_bytes of the leaves whose

@@ -22,12 +22,19 @@ those).  Names are ``tpuft/<layer>/<stage>``::
     tpuft/manager/fence, tpuft/manager/should_commit
     tpuft/ddp/allreduce_pytree                     (train thread, then gather)
     ├─ tpuft/ddp/plan, tpuft/ddp/d2h, tpuft/ddp/pack        (train thread)
+    ├─ tpuft/ddp/submit                            (train thread, one a bucket)
     ├─ tpuft/comm/op                               (op thread, one a collective)
     │  └─ tpuft/comm/lane_window
     └─ tpuft/ddp/ring_wait, tpuft/ddp/h2d          (gather thread)
     tpuft/heal/snapshot, tpuft/heal/serve          (survivor)
     tpuft/heal/fetch, tpuft/heal/apply             (new life)
     tpuft/outer_shard/*, tpuft/stream/*            (DiLoCo / LocalSGD)
+
+Beneath ``tpuft/comm/op`` there is no span: a ring's 4 MiB quanta are too many
+for one each.  The communicator counts its own seconds there, always
+(``Communicator.lane_stats()``: a lane's in recv, in the reduce's add and in
+send, the op thread's in the ring's two phases, the division between them and
+the tail), and DDP_SYNC carries a round trip's differences.
 
 **Device operations with names of their own.**  A Mosaic kernel is named by
 its ``pallas_call``'s ``name=`` and is ``%<name>.N`` among a trace's device

@@ -53,6 +53,16 @@ _ATTR_KEYS = (
     "comm_lane_tx_bytes",
     "comm_lane_rx_bytes",
     "comm_lane_stalls",
+    # where the outgoing epoch's ring time went (torchft_quorums; seconds,
+    # Communicator.lane_stats(): a lane's in recv, add and send, the op
+    # thread's in the two phases, the division and the tail)
+    "comm_lane_rx_s",
+    "comm_lane_add_s",
+    "comm_lane_tx_s",
+    "comm_ring_reduce_s",
+    "comm_ring_average_s",
+    "comm_ring_gather_s",
+    "comm_ring_tail_s",
     # gray-failure counters (torchft_quorums; in-epoch lane recovery +
     # fault injection of the outgoing epoch)
     "comm_lane_reconnects",
