@@ -18,10 +18,15 @@ runs every such test on the file a later PR would leave.
 
 import json
 import os
+import shutil
+import subprocess
+import sys
 
 import pytest
 
 from ftbench import spec
+
+from tests import _once
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH_DIR = os.path.join(ROOT, "ftbench")
@@ -68,6 +73,38 @@ def device_trace_readers():
     return {m["name"] for m in bench()["per_layer"] if m["source"] == "device_trace"}
 
 
+def traced_walk(cell):
+    """(root, the ended process) of the ONE traced CPU walk of ``cell`` that
+    a run of the tests makes (``tests/_once.py``): ``--rehearse --trace 1`` in
+    a copy of the benchmark under ``root`` (what
+    ``ftbench/tests/test_ftbench_program_spans.py`` ``_rehearse`` does: the
+    trace lands in ITS ``ftbench/out`` and no other traced walk clears it),
+    the program from the repo, on as many virtual devices as the cell has
+    chips, two at the least.  ``mistral7b-ddp2-steady`` and
+    ``mistral7b-ddp2-kill`` were walked traced by two files each, 20 and 56 s
+    a walk (PR 55).  The Managers' flight rings are dumped under
+    ``root/flight``."""
+    root = _once.directory / f"traced-walk-{cell}"
+
+    def walk():
+        root.mkdir()
+        shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), root)
+        shutil.copytree(BENCH_DIR, root / "ftbench", ignore=shutil.ignore_patterns("out", "__pycache__", "tests"))
+        devices = max(2, spec.load_cell(cell).chips)
+        env = dict(
+            os.environ, JAX_PLATFORMS="cpu", XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}",
+            PYTHONPATH=ROOT + os.pathsep + os.environ.get("PYTHONPATH", ""), TORCHFT_FLIGHT_DIR=str(root / "flight"),
+        )
+        env.pop("JAX_COMPILATION_CACHE_DIR", None)
+        return subprocess.run(
+            [sys.executable, os.path.join("ftbench", "run.py"), "--workload", cell, "--seed", "3000000023",
+             "--seconds", "2", "--trace", "1", "--rehearse"],
+            cwd=root, env=env, capture_output=True, text=True, timeout=600,
+        )
+
+    return root, _once.once_a_run(f"traced-walk-{cell}", walk)
+
+
 def cell_walk(theirs):
     """``theirs.test_rehearsal_walks_the_cell`` (a cell's own test file under
     ``ftbench/tests/``), one walk a case: its traced case holds the readers of
@@ -84,19 +121,26 @@ def cell_walk(theirs):
 def compile_cases(*config_names):
     """The two cases of ``ftbench/tests/test_ftbench_compile.py`` for these
     configurations, under the ids they have there.  A case compiles a cell's
-    whole step for a described v5e, one to three minutes: a configuration
-    has a file of its own (``tests/test_ftbench_compile_<configuration>.py``)
-    and ``tests/conftest.py`` puts the step cases first in the collection and
-    the forward checks a quarter in, two runs from the start, so that the
-    next configuration adds to the run's sum and not to its longest pole.
-    The file imports the fixtures ``topo`` and ``no_compile_cache`` beside
-    this."""
+    whole step for a described v5e, one to three minutes and three cores
+    wide: a configuration has a file of its own
+    (``tests/test_ftbench_compile_<configuration>.py``) and
+    ``tests/conftest.py`` puts the step cases first in the collection, so
+    that the next configuration adds to the run's sum and not to its longest
+    pole.  The step case stays in tier-1: it compiles the forward pass with
+    every kernel inside the gradient step and holds its memory.  The forward
+    check is ``slow`` since PR 55 (40-110 s a case, 760 s the nine): what it
+    adds is the harness's own after-window programs, which only a
+    ``benchmark`` PR may edit and every run of every cell on the chip
+    executes; ``-m slow -k forward_check`` runs the nine by hand
+    (``.claude/skills/verify/SKILL.md`` says when).  The file imports the
+    fixtures ``topo`` and ``no_compile_cache`` beside this."""
     from ftbench.tests import test_ftbench_compile as theirs
 
     @pytest.mark.parametrize("config_name", config_names)
     def test_step_compiles_for_v5e(topo, no_compile_cache, monkeypatch, config_name):
         theirs.test_step_compiles_for_v5e(topo, no_compile_cache, monkeypatch, config_name)
 
+    @pytest.mark.slow
     @pytest.mark.parametrize("config_name", config_names)
     def test_forward_check_compiles_for_v5e(topo, no_compile_cache, monkeypatch, config_name):
         theirs.test_forward_check_compiles_for_v5e(topo, no_compile_cache, monkeypatch, config_name)

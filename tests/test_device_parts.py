@@ -7,13 +7,14 @@ there, so a path is whole (the lowered module's locations are relative to the
 function an operation stands in)."""
 
 import collections
-import dataclasses
-import os
+import functools
 import re
 
 import pytest
 
 from torchft_tpu.obs.spans import DEVICE_PARTS, PART_PREFIX, part
+
+from tests._toys import toy, lowered_grad_step
 
 MODELS = ("llama", "ling_hybrid", "indexed_sparse_moe", "ssm_hybrid_moe", "windowed_moe", "latent_moe", "eva")
 CASES = [(m, p) for m in MODELS for p in ("plain", "kernels")]
@@ -38,36 +39,6 @@ _OP_NAME = re.compile(r'op_name="([^"]*)"')
 _PART = re.compile(re.escape(PART_PREFIX) + r"(\w+)")
 
 
-def _model(name):
-    if name == "llama":
-        from torchft_tpu.models.llama import Llama, llama_debug
-
-        return Llama(dataclasses.replace(llama_debug(), remat=True)), 128
-    if name == "ling_hybrid":
-        from torchft_tpu.models.ling_hybrid import LingHybrid, ling_debug
-
-        return LingHybrid(ling_debug()), 128
-    if name == "indexed_sparse_moe":
-        from torchft_tpu.models.indexed_sparse_moe import IndexedSparseMoE, indexed_sparse_debug
-
-        return IndexedSparseMoE(indexed_sparse_debug()), 32
-    if name == "latent_moe":
-        from torchft_tpu.models.latent_moe import LatentMoE, latent_moe_debug
-
-        return LatentMoE(latent_moe_debug()), 128
-    if name == "windowed_moe":
-        from torchft_tpu.models.windowed_moe import WindowedMoE, windowed_moe_debug
-
-        return WindowedMoE(windowed_moe_debug()), 128
-    if name == "eva":
-        from torchft_tpu.models.eva import Eva, eva_debug
-
-        return Eva(eva_debug()), 128
-    from torchft_tpu.models.ssm_hybrid_moe import SsmHybridMoE, ssm_hybrid_debug
-
-    return SsmHybridMoE(ssm_hybrid_debug()), 128
-
-
 def innermost(path):
     """The part of an operation from its path: the last ``tpuft.<name>``."""
     found = _PART.findall(path or "")
@@ -89,31 +60,21 @@ def _paths(text):
     return every, held
 
 
+@functools.lru_cache(maxsize=None)
 def _compiled_steps(name, path):
+    """The two step programs' compiled text; the gradient step is lowered
+    once a process for this file and ``test_lowered_steps.py`` (``_toys``),
+    so at that file's sequence lengths; ``llama`` rematerialised is this
+    file's own."""
     import jax
-    import numpy as np
     import optax
 
-    from torchft_tpu.parallel.hsdp import make_grad_step, make_update_step
-    from torchft_tpu.parallel.mesh import make_mesh
+    from torchft_tpu.parallel.hsdp import make_update_step
 
-    before = os.environ.get("TORCHFT_FLASH")
-    os.environ["TORCHFT_FLASH"] = "1" if path == "kernels" else "0"
-    try:
-        model, seq = _model(name)
-        mesh = make_mesh(fsdp=1, devices=jax.devices()[:1])
-        params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
-        tokens = jax.ShapeDtypeStruct((1, seq), np.int32)
-        grad = make_grad_step(model, mesh).lower(params, (tokens, tokens)).compile().as_text()
-        assert ("naive" in model.attention_path or "plain" in model.attention_path) == (path == "plain")
-        tx = optax.adamw(1e-3)
-        update = make_update_step(model, tx, mesh).lower(params, jax.eval_shape(tx.init, params), params)
-        return grad, update.compile().as_text()
-    finally:
-        if before is None:
-            del os.environ["TORCHFT_FLASH"]
-        else:
-            os.environ["TORCHFT_FLASH"] = before
+    model, mesh, params, lowered = lowered_grad_step("llama_remat" if name == "llama" else name, path)
+    tx = optax.adamw(1e-3)
+    update = make_update_step(model, tx, mesh).lower(params, jax.eval_shape(tx.init, params), params)
+    return lowered.compile().as_text(), update.compile().as_text()
 
 
 @pytest.mark.parametrize("name,path", CASES)
@@ -152,7 +113,7 @@ def test_the_poolings_operations_are_under_their_part_and_nothing_elses_is(path)
     import jax
     import jax.numpy as jnp
 
-    model, _ = _model("eva")
+    model, _ = toy("eva")
     cfg = model.config
     k = jax.ShapeDtypeStruct((1, 64, cfg.n_heads, cfg.head_dim), jnp.float32)
     vector = jax.ShapeDtypeStruct((cfg.n_heads, cfg.head_dim), jnp.float32)

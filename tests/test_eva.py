@@ -15,6 +15,7 @@ on the losses (the harness's own tie) and 1e-3 of a leaf's largest gradient
 (+1e-6).  bfloat16 matrices read 0.29 to 0.36 on the logits and ``mu`` left
 out 0.58: both fail a thousand times over."""
 
+import functools
 import zlib
 
 import jax
@@ -26,6 +27,9 @@ from ftbench.architectures import eva_reference as ref
 from torchft_tpu.models.eva import KERNEL_PATH, Eva, EvaConfig, eva_debug
 from torchft_tpu.models.llama import Llama
 from torchft_tpu.ops import flash_attention as flash
+
+from tests._once import once_a_run
+from tests._toys import gradients_jaxpr, on_path
 
 SEQ = 64
 CASES = {
@@ -44,10 +48,12 @@ def reference_config(c: EvaConfig) -> dict:
     )
 
 
-def _setup(seq=SEQ, **over):
-    cfg = eva_debug(**over)
-    model = Eva(cfg)
-    params = model.init(jax.random.PRNGKey(0))
+@functools.lru_cache(maxsize=None)
+def _params(**over):
+    """The toy's parameters, made once a run of the tests (``init`` runs
+    operation by operation: seconds of small compiles in every process that
+    makes them)."""
+    model = Eva(eva_debug(**over))
 
     def stir(path, p):
         """The norms' ``g`` starts at 0: a gradient is only tested where the
@@ -57,30 +63,49 @@ def _setup(seq=SEQ, **over):
         noise = jax.random.normal(jax.random.fold_in(jax.random.PRNGKey(3), zlib.crc32(name.encode()) % 997), p.shape)
         return p + 0.1 * noise if name.endswith("_norm") else p
 
-    params = jax.tree_util.tree_map_with_path(stir, params)
-    tokens = np.random.default_rng(0).integers(0, cfg.vocab_size, (2, seq)).astype(np.int32)
-    return cfg, model, params, (jnp.asarray(tokens), jnp.asarray(np.roll(tokens, -1, axis=1)))
+    return once_a_run(
+        f"eva-params-{sorted(over.items())}", lambda: jax.jit(lambda key: jax.tree_util.tree_map_with_path(stir, model.init(key)))(jax.random.PRNGKey(0))
+    )
 
 
-@pytest.fixture(scope="module")
-def reference_side():
+def _setup(**over):
+    """(config, a model of its own, the parameters, a batch): the model is
+    the caller's alone, since what it traces depends on ``TORCHFT_FLASH``."""
+    cfg = eva_debug(**over)
+    tokens = np.random.default_rng(0).integers(0, cfg.vocab_size, (2, SEQ)).astype(np.int32)
+    return cfg, Eva(cfg), _params(**over), (jnp.asarray(tokens), jnp.asarray(np.roll(tokens, -1, axis=1)))
+
+
+@functools.lru_cache(maxsize=None)
+def reference_side(case):
     """The reference's logits, losses and gradients of a case, computed once
-    for both of the program's paths."""
-    made = {}
+    a run for both of the program's paths."""
+    cfg, _, params, batch = _setup(**CASES[case])
+    rc = reference_config(cfg)
 
-    def side(case):
-        if case not in made:
-            cfg, _, params, batch = _setup(**CASES[case])
-            rc = reference_config(cfg)
-            made[case] = dict(
-                logits=jax.jit(lambda p: ref.slice_logits(p, batch[0], rc))(params),
-                means=jax.jit(lambda p: ref.slice_means(p, batch, rc))(params),
-                objective=jax.jit(jax.value_and_grad(lambda p: ref.objective(p, batch, rc)))(params),
-                token_nll=jax.jit(lambda p: ref.token_nll(p, *batch, rc))(params),
-            )
-        return made[case]
+    def make():
+        return dict(
+            logits=jax.jit(lambda p: ref.slice_logits(p, batch[0], rc))(params),
+            means=jax.jit(lambda p: ref.slice_means(p, batch, rc))(params),
+            objective=jax.jit(jax.value_and_grad(lambda p: ref.objective(p, batch, rc)))(params),
+            token_nll=jax.jit(lambda p: ref.token_nll(p, *batch, rc))(params),
+        )
 
-    return side
+    return once_a_run(f"eva-reference-{case}", make)
+
+
+@functools.lru_cache(maxsize=None)
+def programs_side(case, path):
+    """(model, every slice's logits, ``apply``'s logits, ``loss``,
+    ((objective, (signal, summary)), gradients)) of a case on ``path``: ONE
+    program, computed once a process for the two tests that read it."""
+    _, model, params, batch = _setup(**CASES[case])
+
+    def every(p, b):
+        return model.apply_all(p, b[0]), model.apply(p, b[0]), model.loss(p, b), jax.value_and_grad(model.objective, has_aux=True)(p, b)
+
+    with on_path(path):
+        return (model, *jax.jit(every)(params, batch))
 
 
 @pytest.fixture(params=["plain", "kernels"])
@@ -94,19 +119,18 @@ def _leaves(tree):
 
 
 @pytest.mark.parametrize("case", list(CASES))
-def test_every_slices_logits_and_both_losses_agree_with_the_reference(case, path, reference_side):
-    cfg, model, params, batch = _setup(**CASES[case])
+def test_every_slices_logits_and_both_losses_agree_with_the_reference(case, path):
+    cfg, _, _, batch = _setup(**CASES[case])
     want = reference_side(case)
-    logits = jax.jit(model.apply_all)(params, batch[0])
+    model, logits, first_slice, loss, ((objective, (signal, summary)), _) = programs_side(case, path)
     assert model.attention_path == (KERNEL_PATH if path == "kernels" else "plain: TORCHFT_FLASH=0")
     assert logits.shape == (2, SEQ, cfg.n_pred_heads, cfg.vocab_size) and logits.dtype == jnp.float32
     np.testing.assert_allclose(logits, want["logits"], atol=5e-5)
     # ``apply`` is slice 0, and ``loss`` the mean of its cross-entropy: the tie the benchmark holds
-    np.testing.assert_allclose(jax.jit(model.apply)(params, batch[0]), want["logits"][:, :, 0], atol=5e-5)
-    loss = float(jax.jit(model.loss)(params, batch))
+    np.testing.assert_allclose(first_slice, want["logits"][:, :, 0], atol=5e-5)
+    loss = float(loss)
     assert loss == pytest.approx(float(want["means"][0]), abs=2e-5)
     assert loss == pytest.approx(float(jnp.mean(want["token_nll"])), abs=2e-5)
-    objective, (signal, summary) = jax.jit(model.objective)(params, batch)
     assert float(objective) == pytest.approx(float(want["objective"][0]), abs=2e-5)
     assert float(objective) == pytest.approx(float(jnp.mean(want["means"])), abs=2e-5)
     # no state the optimizer does not own; the summary is the further slices' mean alone
@@ -120,10 +144,9 @@ def test_every_slices_logits_and_both_losses_agree_with_the_reference(case, path
 
 
 @pytest.mark.parametrize("case", list(CASES))
-def test_every_leafs_gradient_agrees_with_the_reference(case, path, reference_side):
-    cfg, model, params, batch = _setup(**CASES[case])
+def test_every_leafs_gradient_agrees_with_the_reference(case, path):
     _, want_grads = reference_side(case)["objective"]
-    grads = jax.jit(jax.grad(lambda p: model.objective(p, batch)[0]))(params)
+    (_, grads) = programs_side(case, path)[4]
     got, wanted = _leaves(grads), _leaves(want_grads)
     assert got.keys() == wanted.keys()
     # the pooling's two learned vectors take gradient from the summaries' keys alone
@@ -189,15 +212,23 @@ def test_no_output_before_a_changed_byte_moves(where, path):
     query sees a summary of its own window.  Every slice's logits before
     byte ``j`` stay bit for bit; those at ``j`` move, and so do those of the
     NEXT window, which see ``j`` through its chunk's summary alone."""
-    cfg, model, params, (tokens, _) = _setup()
+    cfg, _, params, (tokens, _) = _setup()
     j = BOUNDARIES[where]
     other = tokens.at[:, j].set((tokens[:, j] + 1) % cfg.vocab_size)
-    run = jax.jit(model.apply_all)
+    run = _apply_all(path)
     base, changed = run(params, tokens), run(params, other)
     np.testing.assert_array_equal(changed[:, :j], base[:, :j])
     assert float(jnp.min(jnp.max(jnp.abs(changed[:, j] - base[:, j]), axis=(1, 2)))) > 1e-4
     if j < 32:  # the second window's rows see byte j through a summary
         assert float(jnp.min(jnp.max(jnp.abs(changed[:, 32:] - base[:, 32:]), axis=(2, 3)))) > 1e-7
+
+
+@functools.lru_cache(maxsize=None)
+def _apply_all(path):
+    """The toy's ``apply_all`` compiled on ``path``, once for the six bytes."""
+    _, model, params, (tokens, _) = _setup()
+    with on_path(path):
+        return jax.jit(model.apply_all).lower(params, tokens).compile()
 
 
 def test_the_pooling_is_a_softmax_over_a_chunk_and_mu_is_added_after():
@@ -251,14 +282,19 @@ def test_a_bfloat16_model_keeps_a_float32_stream_and_float32_logits(monkeypatch)
 
 
 @pytest.mark.parametrize("kernel,count", [("eva_fwd", 1), ("eva_dq", 1), ("eva_dkv", 1), ("flash_fwd", 0)])
-def test_what_a_rematerialised_layer_keeps_and_what_it_runs_again(kernel, count, monkeypatch):
+def test_what_a_rematerialised_layer_keeps_and_what_it_runs_again(kernel, count):
     """The layers are one scan, whose body is traced once, rematerialised
     but for what the forward kernel made (``flash.KEPT_NAMES``): a second
     ``eva_fwd`` in the body would read 2."""
-    monkeypatch.setenv("TORCHFT_FLASH", "1")
-    _, model, params, batch = _setup()
-    text = str(jax.make_jaxpr(jax.grad(lambda p: model.objective(p, batch)[0]))(params))
+    text = _gradients_jaxpr()
     assert text.count(f"name={kernel}\n") + text.count(f"name={kernel} ") == count, kernel
+
+
+@functools.lru_cache(maxsize=None)
+def _gradients_jaxpr():
+    """Traced once for the four kernels' counts."""
+    _, model, params, batch = _setup()
+    return gradients_jaxpr(model, params, batch)
 
 
 # ----------------------------------------------------------------------

@@ -13,7 +13,6 @@ from typing import Any, Dict, List
 
 import jax
 import numpy as np
-import optax
 import pytest
 
 from torchft_tpu import tier as tier_mod
@@ -21,11 +20,15 @@ from torchft_tpu.communicator import DummyCommunicator
 from torchft_tpu.manager import Manager
 from torchft_tpu.models.eva import Eva, eva_debug
 from torchft_tpu.parallel import hsdp
-from torchft_tpu.parallel.hsdp import HSDPTrainer, make_grad_step
 from torchft_tpu.parallel.mesh import make_mesh
 
 from tests.test_ling_hsdp import _batch
+from tests._toys import replica_group, trainer as group_trainer
 from tests.test_manager import MemoryTransport, StubClient, _quorum_result
+
+
+def toy():
+    return Eva(eva_debug())
 
 TOTAL = 4
 
@@ -41,12 +44,11 @@ def committed_step():
         comm=DummyCommunicator(), load_state_dict=None, state_dict=None, min_replica_size=1,
         checkpoint_transport=MemoryTransport(), _manager_client=client, rank=0, world_size=1,
     )
-    model = Eva(eva_debug())
-    mesh = make_mesh(fsdp=1, devices=jax.devices()[:1])
-    trainer = HSDPTrainer(model, optax.adamw(1e-3), mesh, manager, key=jax.random.PRNGKey(0))
+    model, mesh, grad_step = replica_group(toy, 0)
+    trainer = group_trainer(toy, 0, manager, jax.random.PRNGKey(0), learning_rate=1e-3)
     batch = _batch(model, mesh, 1)
     before = jax.tree_util.tree_map(np.asarray, trainer.holder["params"])
-    report, _ = make_grad_step(model, mesh)(trainer.holder["params"], batch)
+    report, _ = grad_step(trainer.holder["params"], batch)
     result = trainer.train_step(batch)
     after = jax.tree_util.tree_map(np.asarray, trainer.holder["params"])
     events = [e for e in manager._flight.snapshot() if e["name"] == "MOE_ROUTE"]
@@ -104,8 +106,7 @@ def test_two_replicas_agree_bit_for_bit_through_every_commit():
         return h.hexdigest()
 
     def replica(idx: int) -> None:
-        mesh = make_mesh(fsdp=1, devices=[devices[idx]])
-        model = Eva(eva_debug())
+        model, mesh, _ = replica_group(toy, idx)
         batch = _batch(model, mesh, 100 + idx)  # a batch each: equal leaves REQUIRE the averaged gradient
         manager = Manager(
             comm=tier_mod.make_communicator(timeout_s=30.0, tier=tier),
@@ -115,7 +116,7 @@ def test_two_replicas_agree_bit_for_bit_through_every_commit():
             server_cls=tier_mod.manager_server_cls(tier),
         )
         managers.append(manager)
-        trainer = HSDPTrainer(model, optax.adamw(1e-3), mesh, manager, key=jax.random.PRNGKey(1))
+        trainer = group_trainer(toy, idx, manager, jax.random.PRNGKey(1), learning_rate=1e-3)
         while manager.current_step() < TOTAL:
             trainer.quantize_outer = manager.current_step() == 2  # one step on the int8 wire
             loss, committed = trainer.train_step(batch)

@@ -10,7 +10,8 @@ import pytest
 
 from ftbench.tests import test_ftbench_program_spans as theirs
 from ftbench.tests.test_ftbench_program_spans import *  # noqa: F401,F403
-from tests._ftbench_view import bench, device_trace_readers, reader_entry, walk_reports
+from ftbench.tests.test_ftbench_rehearsal import _lines
+from tests._ftbench_view import bench, device_trace_readers, reader_entry, traced_walk, walk_reports
 
 # PR 27's reader on theirs' synthetic planes: the division by the participant
 # count, two spans of 40 ms a step
@@ -27,18 +28,27 @@ def test_later_span_reader_on_synthetic_planes(run, name, monkeypatch):  # noqa:
 @pytest.mark.parametrize(
     "cell,new", theirs.test_rehearsal_would_report_the_program_span_metrics.pytestmark[0].args[1]
 )
-def test_rehearsal_would_report_the_program_span_metrics(cell, new, tmp_path, monkeypatch):  # noqa: F811
-    """Theirs, and of the same walk: it reports every reader of today that
-    lists the cell and can be read on a CPU, and none of the device's trace
-    (no device plane: PR 37's readers of the scopes find nothing and say so)."""
+def test_rehearsal_would_report_the_program_span_metrics(cell, new, monkeypatch):  # noqa: F811
+    """Theirs, on the cell's one traced walk (``traced_walk``, which is their
+    ``_rehearse`` kept for every test that reads it), and of the same walk:
+    it reports every reader of today that lists the cell and can be read on a
+    CPU, and none of the device's trace (no device plane: PR 37's readers of
+    the scopes find nothing and say so)."""
     from ftbench import device_scopes
 
-    seen = {}
-    rehearse = theirs._rehearse
-    monkeypatch.setattr(theirs, "_rehearse", lambda *a, **k: seen.setdefault("reported", rehearse(*a, **k)))
-    theirs.test_rehearsal_would_report_the_program_span_metrics(cell, new, tmp_path)
-    assert walk_reports(cell) <= seen["reported"] and not seen["reported"] & device_trace_readers()
-    assert device_scopes.load(str(tmp_path / "ftbench")) == {}
+    root, done = traced_walk(cell)
+
+    def rehearsed(cell, root):  # what ``_rehearse`` holds of its walk
+        assert done.returncode == 0, done.stderr[-3000:]
+        last = _lines(done.stdout)[-1]
+        assert last["rehearsal"] is True and last["correct"] is True
+        return set(last["would_report"])
+
+    monkeypatch.setattr(theirs, "_rehearse", rehearsed)
+    theirs.test_rehearsal_would_report_the_program_span_metrics(cell, new, root)
+    reported = rehearsed(cell, root)
+    assert walk_reports(cell) <= reported and not reported & device_trace_readers()
+    assert device_scopes.load(str(root / "ftbench")) == {}
 
 
 def _sync(t, warm=None, buckets=10, name="DDP_SYNC"):

@@ -98,7 +98,8 @@ def test_walked_launches_are_the_rectangles_bit_for_bit(case):
     blocks = cfg["blocks"].fit(cfg["S"])
     if case == "shorter_than_a_key_block":
         assert ia._steps(cfg["S"], blocks).steps == ia._steps(cfg["S"], blocks, by_key=True).steps == 1
-    mask, lse_index, _ = ia.select_keys(qi, ki, w, topk=cfg["topk"], blocks=blocks, interpret=True)
+    # one program, as each launch below: run operation by operation the interpreter's loop is seconds of small compiles
+    mask, lse_index, _ = jax.jit(lambda *a: ia.select_keys(*a, topk=cfg["topk"], blocks=blocks, interpret=True))(qi, ki, w)
     qh, kh, vh, qih, wh = ia._heads_major(q, k, v, qi, w)
     scale = 1.0 / float(np.sqrt(q.shape[-1]))
 
@@ -163,8 +164,13 @@ def test_launches_walk_only_their_live_blocks(S, bq, bk, live):
 def test_under_topk_is_dense_causal_attention():
     cfg = CASES["under_topk"]
     (q, k, v, qi, ki, w), _ = _operands(**cfg)
-    mask, lse_index, _ = ia.select_keys(qi, ki, w, topk=cfg["topk"], blocks=cfg["blocks"], interpret=True)
-    o, _ = ia.indexed_attention(q, k, v, qi, ki, w, mask, lse_index, blocks=cfg["blocks"], interpret=True)
+
+    @jax.jit  # one program: run operation by operation the interpreter's loops are 15 s of small compiles
+    def kernels(q, k, v, qi, ki, w):
+        mask, lse_index, _ = ia.select_keys(qi, ki, w, topk=cfg["topk"], blocks=cfg["blocks"], interpret=True)
+        return ia.indexed_attention(q, k, v, qi, ki, w, mask, lse_index, blocks=cfg["blocks"], interpret=True)
+
+    o, _ = kernels(q, k, v, qi, ki, w)
     S, group = cfg["S"], cfg["H"] // cfg["KV"]
     logits = jnp.einsum("bthd,bshd->bhts", q, jnp.repeat(k, group, axis=2)) / np.sqrt(q.shape[-1])
     probs = jax.nn.softmax(jnp.where(jnp.tril(jnp.ones((S, S), bool)), logits, -jnp.inf), axis=-1)
@@ -182,7 +188,7 @@ def test_each_loss_reaches_its_own_operands_and_no_other(path):
     def grads(weight, with_output):
         seed = do if with_output else jnp.zeros_like(do)
         f = _kernels(cfg["topk"], cfg["blocks"], seed, weight) if path == "kernels" else _plain(cfg["topk"], seed, weight)
-        return jax.grad(lambda *a: f(*a)[0], argnums=tuple(range(6)))(*ops)
+        return jax.jit(jax.grad(lambda *a: f(*a)[0], argnums=tuple(range(6))))(*ops)  # one program, not one an operation
 
     of_output, of_index_loss = grads(0.0, True), grads(1.0, False)
     for g in of_output[3:] + of_index_loss[:3]:

@@ -9,17 +9,20 @@ from typing import Any, Dict, List
 
 import jax
 import numpy as np
-import optax
 
 from torchft_tpu import tier as tier_mod
 from torchft_tpu.communicator import DummyCommunicator
 from torchft_tpu.manager import Manager
 from torchft_tpu.models.indexed_sparse_moe import SUMMARY_FIELDS, IndexedSparseMoE, indexed_sparse_debug
 from torchft_tpu.parallel import hsdp
-from torchft_tpu.parallel.hsdp import HSDPTrainer, fsdp_shardings, make_grad_step
-from torchft_tpu.parallel.mesh import make_mesh
+from torchft_tpu.parallel.hsdp import fsdp_shardings
 
+from tests._toys import replica_group, trainer as group_trainer
 from tests.test_manager import MemoryTransport, StubClient, _quorum_result
+
+
+def toy():
+    return IndexedSparseMoE(indexed_sparse_debug())
 
 SEQ = 32  # twice the toy index's 16 keys
 
@@ -37,9 +40,8 @@ def _stub_trainer(steps: int):
         comm=DummyCommunicator(), load_state_dict=None, state_dict=None, min_replica_size=1,
         checkpoint_transport=MemoryTransport(), _manager_client=client, rank=0, world_size=1,
     )
-    model = IndexedSparseMoE(indexed_sparse_debug())
-    mesh = make_mesh(fsdp=1, devices=jax.devices()[:1])
-    trainer = HSDPTrainer(model, optax.adamw(1e-3), mesh, manager, key=jax.random.PRNGKey(0))
+    model, mesh, _ = replica_group(toy, 0)
+    trainer = group_trainer(toy, 0, manager, jax.random.PRNGKey(0), learning_rate=1e-3)
     return model, mesh, manager, trainer
 
 
@@ -58,7 +60,7 @@ def test_a_model_without_optimizer_free_state_still_reports_its_step():
     assert hsdp._reports(model) and trainer._state_mask is None
     batch = _batch(model, mesh, 1)
     before = jax.tree_util.tree_map(np.asarray, trainer.holder["params"])
-    report, grads = make_grad_step(model, mesh)(trainer.holder["params"], batch)
+    report, grads = replica_group(toy, 0)[2](trainer.holder["params"], batch)
     layers = model.config.n_layers
     assert report.shape == (1 + len(SUMMARY_FIELDS) * layers,)  # the loss and the summary, ONE array
     assert jax.tree_util.tree_structure(grads) == jax.tree_util.tree_structure(before)
@@ -123,8 +125,7 @@ def test_two_replicas_commit_agree_bit_for_bit_and_heal_a_killed_one():
         return h.hexdigest()
 
     def replica(idx: int) -> None:
-        mesh = make_mesh(fsdp=1, devices=[devices[idx]])
-        model = IndexedSparseMoE(indexed_sparse_debug())
+        model, mesh, _ = replica_group(toy, idx)
         batch = _batch(model, mesh, 100 + idx)
         life = 0
         while True:
@@ -136,9 +137,7 @@ def test_two_replicas_commit_agree_bit_for_bit_and_heal_a_killed_one():
                 server_cls=tier_mod.manager_server_cls(tier),
             )
             managers.append(manager)
-            trainer = HSDPTrainer(
-                model, optax.adamw(1e-3), mesh, manager, key=jax.random.PRNGKey(10 * life + 1)
-            )
+            trainer = group_trainer(toy, idx, manager, jax.random.PRNGKey(10 * life + 1), learning_rate=1e-3)
             if life:
                 rejoined.set()
             try:

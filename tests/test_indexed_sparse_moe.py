@@ -6,6 +6,8 @@ tower would hand over, the routing options of ``RoutedExperts`` and the sum
 of the experts' shares.  Float32, seeded weights, the CPU; the kernels in
 interpret mode where a case says so."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -15,6 +17,8 @@ from ftbench.architectures import indexed_sparse_moe_reference as ref
 from torchft_tpu.models import indexed_sparse_moe
 from torchft_tpu.models.indexed_sparse_moe import IndexedSparseMoE, indexed_sparse_debug
 from torchft_tpu.parallel.moe import RoutedExperts, RoutedExpertsConfig
+
+from tests._once import once_a_run
 
 SEQ = 64  # four times the toy index's 16 keys
 
@@ -57,9 +61,35 @@ def model():
     return IndexedSparseMoE(indexed_sparse_debug())
 
 
+@functools.lru_cache(maxsize=None)
+def _params():
+    """Made once a run of the tests, by one program (``init`` run operation
+    by operation is seconds of small compiles in every process that does)."""
+    return once_a_run("indexed_sparse_moe-params", lambda: jax.jit(IndexedSparseMoE(indexed_sparse_debug()).init)(jax.random.PRNGKey(3)))
+
+
 @pytest.fixture(scope="module")
-def params(model):
-    return model.init(jax.random.PRNGKey(3))
+def params():
+    return _params()
+
+
+KINDS = {"text": {}, "streams": dict(streams=True), "tower": dict(tower=True)}
+
+
+@functools.lru_cache(maxsize=None)
+def reference_side(kind):
+    """The reference's forward pass, objective and gradients of a kind of
+    batch, which depend on no path: computed once a run (un-jitted, they took
+    40 s a case in each of a kind's two cases)."""
+    model, params = IndexedSparseMoE(indexed_sparse_debug()), _params()
+    batch = _batch(model, 5, **KINDS[kind])
+    rc = reference_config(model.config)
+
+    def make():
+        # ONE program: two compiled the forward pass twice
+        return jax.jit(lambda p: (ref.forward(p, batch[0], batch[1], rc, *batch[2:], logits=True), *jax.value_and_grad(lambda p: ref.loss(p, batch, rc))(p)))(params)
+
+    return once_a_run(f"indexed_sparse_moe-reference-{kind}", make)
 
 
 @pytest.fixture(params=["plain", "kernels"])
@@ -72,17 +102,19 @@ def _leaves(tree):
     return {jax.tree_util.keystr(p): x for p, x in jax.tree_util.tree_flatten_with_path(tree)[0]}
 
 
-@pytest.mark.parametrize("kind", ["text", "streams", "tower"])
-def test_forward_and_every_gradient_are_the_reference(model, params, path, kind):
-    batch = _batch(model, 5, streams=kind == "streams", tower=kind == "tower")
-    rc = reference_config(model.config)
-    want = ref.forward(params, batch[0], batch[1], rc, *batch[2:], logits=True)
-    logits = jax.jit(model.apply)(params, batch[0], *batch[2:])
+@pytest.mark.parametrize("kind", list(KINDS))
+def test_forward_and_every_gradient_are_the_reference(params, path, kind):
+    model = IndexedSparseMoE(indexed_sparse_debug())  # this case's own: what it traces depends on the path
+    batch = _batch(model, 5, **KINDS[kind])
+    want, want_objective, want_grads = reference_side(kind)
+
+    def every(p, b):  # ONE program: three compiled the forward pass three times
+        return model.apply(p, b[0], *b[2:]), model.loss(p, b), jax.value_and_grad(model.objective, has_aux=True)(p, b)
+
+    logits, loss, ((objective, (signal, summary)), grads) = jax.jit(every)(params, batch)
     assert model.attention_path == ("dsa" if path == "kernels" else "plain: TORCHFT_FLASH=0")
     np.testing.assert_allclose(logits, want["logits"], atol=2e-4)
-    assert float(jax.jit(model.loss)(params, batch)) == pytest.approx(float(jnp.mean(want["nll"])), abs=2e-5)
-    (objective, (signal, summary)), grads = jax.jit(jax.value_and_grad(model.objective, has_aux=True))(params, batch)
-    want_objective, want_grads = jax.value_and_grad(lambda p: ref.loss(p, batch, rc))(params)
+    assert float(loss) == pytest.approx(float(jnp.mean(want["nll"])), abs=2e-5)
     assert float(objective) == pytest.approx(float(want_objective), abs=5e-5)
     assert signal == []
     stats = model.summary_stats(np.asarray(summary))
@@ -107,9 +139,10 @@ def test_position_streams_that_differ_change_the_result(model, params, monkeypat
     assert float(jnp.max(jnp.abs(a[:, 10:] - b[:, 10:]))) > 1e-3
 
 
-def test_the_two_losses_reach_their_own_leaves_and_exactly_no_other(model, params, path):
+def test_the_two_losses_reach_their_own_leaves_and_exactly_no_other(params, path):
     """``L_LM``'s gradient on the index's leaves and ``L_I``'s gradient on
     every other leaf are exactly zero, in the program and in the reference."""
+    model = IndexedSparseMoE(indexed_sparse_debug())  # this case's own: what it traces depends on the path
     batch = _batch(model, 7)
     rc = reference_config(model.config)
     sides = {
@@ -117,9 +150,10 @@ def test_the_two_losses_reach_their_own_leaves_and_exactly_no_other(model, param
             jax.jit(jax.grad(model.loss))(params, batch),
             jax.jit(jax.grad(lambda p: jnp.sum(model._losses(p, batch)[1][2])))(params),  # L_I, layer by layer
         ),
-        "reference": (
-            jax.grad(lambda p: ref.losses(p, batch, rc)[0])(params),
-            jax.grad(lambda p: ref.losses(p, batch, rc)[1])(params),
+        # the reference's two, which depend on no path: one program each, once a run
+        "reference": once_a_run(
+            "indexed_sparse_moe-reference-two-losses",
+            lambda: tuple(jax.jit(jax.grad(lambda p, i=i: ref.losses(p, batch, rc)[i]))(params) for i in (0, 1)),
         ),
     }
     for side, (of_lm, of_index) in sides.items():
@@ -159,8 +193,9 @@ def test_softmax_routing_without_groups_or_bias_is_the_reference(norm_topk_prob)
     np.testing.assert_allclose(scores.sum(axis=-1), 1.0, atol=1e-6)  # a softmax over all 16
     np.testing.assert_array_equal(np.sort(picked, axis=-1), np.argwhere(np.asarray(chosen))[:, 1].reshape(-1, 4))
     np.testing.assert_allclose(np.sort(gates, axis=-1), np.sort(np.asarray(weights), axis=-1)[:, -4:], atol=1e-6)
-    g = jax.grad(lambda w: jnp.sum(layer.apply(w, x)[0] ** 2) + layer.apply(w, x)[2])(w)
-    g_want = jax.grad(lambda w: jnp.sum(ref.moe_layer(x, w, rc, (4, 8))[0] ** 2) + ref.moe_layer(x, w, rc, (4, 8))[2])(w)
+    # each side's gradient is one program: run operation by operation the two took 15 s of small compiles
+    g = jax.jit(jax.grad(lambda w: jnp.sum(layer.apply(w, x)[0] ** 2) + layer.apply(w, x)[2]))(w)
+    g_want = jax.jit(jax.grad(lambda w: jnp.sum(ref.moe_layer(x, w, rc, (4, 8))[0] ** 2) + ref.moe_layer(x, w, rc, (4, 8))[2]))(w)
     for name in w:
         np.testing.assert_allclose(g[name], g_want[name], atol=3e-5, err_msg=name)
 

@@ -11,6 +11,8 @@ one (read here: at most 7e-6).  A lower precision anywhere (bfloat16
 operands) reads 1e-2 and fails.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -38,22 +40,38 @@ def _inputs(seed, B=2, S=128, H=2, dk=32, dv=32, decay="drawn"):
     return tuple(jnp.asarray(a, jnp.float32) for a in (q, k, v, g, beta))
 
 
-def _weighted(f, weight):
-    return lambda *a: jnp.sum(f(*a) * weight)
+SCALE = 32 ** -0.5  # of ``_inputs``' keys
+
+
+@functools.lru_cache(maxsize=None)
+def run(form, chunk=None, grad=False):
+    """The rule in one ``form`` (the reference's ``recurrence``, the
+    ``kernels`` in interpret mode, the ``plain`` chunk algebra), or the
+    gradients of its output summed under a weight, as ONE program at full
+    precision that the cases of a chunk share: run operation by operation
+    each case compiled its own few hundred."""
+    f = {
+        "recurrence": lambda *a: kda_recurrence(*a, SCALE),
+        "kernels": lambda *a: kda.kda_chunked(*a, chunk=chunk, interpret=True),
+        "plain": lambda *a: kda.kda_chunked_plain(*a, chunk=chunk),
+    }[form]
+    program = jax.jit(jax.grad(lambda weight, *a: jnp.sum(f(*a) * weight), argnums=range(1, 6)) if grad else f)
+
+    def at_full_precision(*a):
+        with jax.default_matmul_precision("highest"):
+            return program(*a)
+
+    return at_full_precision
 
 
 @pytest.mark.parametrize("decay", ["drawn", "bound"])
 @pytest.mark.parametrize("chunk", [32, 64])
 def test_chunked_kernels_agree_with_the_recurrence(chunk, decay):
     args = _inputs(11, decay=decay)
-    scale = args[0].shape[-1] ** -0.5
+    assert args[0].shape[-1] ** -0.5 == SCALE
     weight = jnp.asarray(np.random.default_rng(5).standard_normal(args[2].shape), jnp.float32)
-    with jax.default_matmul_precision("highest"):
-        want = kda_recurrence(*args, scale)
-        want_grads = jax.grad(_weighted(lambda *a: kda_recurrence(*a, scale), weight), argnums=range(5))(*args)
-        run = lambda *a: kda.kda_chunked(*a, chunk=chunk, interpret=True)  # noqa: E731
-        got = run(*args)
-        got_grads = jax.grad(_weighted(run, weight), argnums=range(5))(*args)
+    want, want_grads = run("recurrence")(*args), run("recurrence", grad=True)(weight, *args)
+    got, got_grads = run("kernels", chunk)(*args), run("kernels", chunk, grad=True)(weight, *args)
     assert float(jnp.max(jnp.abs(got - want))) < TOL
     for name, a, b in zip("qkvgb", got_grads, want_grads):
         assert float(jnp.max(jnp.abs(a - b))) < TOL, name
@@ -66,15 +84,9 @@ def test_plain_chunk_algebra_is_the_kernels(chunk):
     backward against jax's own."""
     args = _inputs(12)
     weight = jnp.asarray(np.random.default_rng(6).standard_normal(args[2].shape), jnp.float32)
-    with jax.default_matmul_precision("highest"):
-        kernels = lambda *a: kda.kda_chunked(*a, chunk=chunk, interpret=True)  # noqa: E731
-        plain = lambda *a: kda.kda_chunked_plain(*a, chunk=chunk)  # noqa: E731
-        assert float(jnp.max(jnp.abs(kernels(*args) - plain(*args)))) < 1e-6
-        for a, b in zip(
-            jax.grad(_weighted(kernels, weight), argnums=range(5))(*args),
-            jax.grad(_weighted(plain, weight), argnums=range(5))(*args),
-        ):
-            assert float(jnp.max(jnp.abs(a - b))) < 1e-6
+    assert float(jnp.max(jnp.abs(run("kernels", chunk)(*args) - run("plain", chunk)(*args)))) < 1e-6
+    for a, b in zip(run("kernels", chunk, grad=True)(weight, *args), run("plain", chunk, grad=True)(weight, *args)):
+        assert float(jnp.max(jnp.abs(a - b))) < 1e-6
 
 
 def test_bfloat16_operands_stay_near_the_recurrence():
@@ -83,9 +95,7 @@ def test_bfloat16_operands_stay_near_the_recurrence():
     bfloat16's own rounding (2^-8) through a few products; the state in
     bfloat16 would read ten times that."""
     args = _inputs(13)
-    scale = args[0].shape[-1] ** -0.5
-    with jax.default_matmul_precision("highest"):
-        want = kda_recurrence(*args, scale)
+    want = run("recurrence")(*args)
     q, k, v, g, beta = args
     got = kda.kda_chunked(
         q.astype(jnp.bfloat16), k.astype(jnp.bfloat16), v.astype(jnp.bfloat16), g, beta,

@@ -22,6 +22,7 @@ differs above 1e-1, a dropped term (a latent's norm, rope, the shared expert,
 the module's loss, a wrapped last position) at least 1e-3 on the objective:
 all fail."""
 
+import functools
 import zlib
 
 import jax
@@ -32,6 +33,9 @@ import pytest
 from ftbench.architectures import latent_moe_reference as ref
 from torchft_tpu.models.latent_moe import KERNEL_PATH, LatentMoE, LatentMoEConfig, latent_moe_debug
 from torchft_tpu.parallel.moe import RoutedExperts, RoutedExpertsConfig
+
+from tests._once import once_a_run
+from tests._toys import gradients_jaxpr, program_side
 
 SEQ = 64
 MTP_WEIGHT = 0.3  # large enough that a dropped or wrapped module shows in the objective
@@ -49,10 +53,12 @@ def reference_config(c: LatentMoEConfig) -> dict:
     )
 
 
-def _setup(**over):
-    cfg = latent_moe_debug(**{"mtp_loss_weight": MTP_WEIGHT, **over})
-    model = LatentMoE(cfg)
-    params = model.init(jax.random.PRNGKey(0))
+@functools.lru_cache(maxsize=None)
+def _params(**over):
+    """The toy's parameters, made once a run of the tests (``init`` runs
+    operation by operation, 10-20 s of small compiles in every process that
+    makes them)."""
+    model = LatentMoE(latent_moe_debug(**{"mtp_loss_weight": MTP_WEIGHT, **over}))
 
     def stir(path, p, is_state):
         """What ``init`` leaves at a constant gets values of its own: a bias
@@ -65,19 +71,40 @@ def _setup(**over):
             return 0.05 * noise
         return p + 0.1 * noise if names[-1].endswith("norm") else p
 
-    params = jax.tree_util.tree_map_with_path(stir, params, model.state_mask())
+    def make():  # ONE program: ``init`` run operation by operation is 10-20 s of small compiles
+        return jax.jit(lambda key: jax.tree_util.tree_map_with_path(stir, model.init(key), model.state_mask()))(jax.random.PRNGKey(0))
+
+    return once_a_run(f"latent_moe-params-{sorted(over.items())}", make)
+
+
+def _setup(**over):
+    """(config, a model of its own, the parameters, a batch): the model is
+    the caller's alone, since what it traces depends on ``TORCHFT_FLASH``."""
+    cfg = latent_moe_debug(**{"mtp_loss_weight": MTP_WEIGHT, **over})
     tokens = np.random.default_rng(0).integers(0, cfg.vocab_size, (2, SEQ)).astype(np.int32)
-    return cfg, model, params, (jnp.asarray(tokens), jnp.asarray(np.roll(tokens, -1, axis=1)))
+    return cfg, LatentMoE(cfg), _params(**over), (jnp.asarray(tokens), jnp.asarray(np.roll(tokens, -1, axis=1)))
 
 
 @pytest.fixture(scope="module")
 def reference_side():
-    """The reference's losses, logits and gradients, computed once for both
-    of the program's paths."""
+    """The reference's losses, logits and gradients, computed once a run for
+    both of the program's paths."""
     cfg, _, params, batch = _setup()
     rc = reference_config(cfg)
-    want = jax.jit(lambda p: ref.forward(p, *batch, rc, logits=True))(params)
-    return (want, *jax.jit(jax.value_and_grad(lambda p: ref.loss(p, batch, rc)))(params))
+
+    def make():
+        # ONE program: two compiled the forward pass twice
+        return jax.jit(lambda p: (ref.forward(p, *batch, rc, logits=True), *jax.value_and_grad(lambda p: ref.loss(p, batch, rc))(p)))(params)
+
+    return once_a_run("latent_moe-reference", make)
+
+
+@functools.lru_cache(maxsize=None)
+def programs_side(path):
+    """(model, logits, loss, ((objective, (signal, summary)), gradients)) of
+    the toy on ``path``, computed once a process."""
+    _, model, params, batch = _setup()
+    return (model, *program_side(model, params, batch, path))
 
 
 @pytest.fixture(params=["plain", "kernels"])
@@ -102,24 +129,24 @@ def test_every_positions_cross_entropy_and_the_tie_of_loss_to_apply(path, refere
     ``loss`` IS its mean with the module ON: the tie ``ftbench/harness.py``
     holds (``loss_tie`` <= 2e-5), which a loss that added the module's term
     would break by 0.3 x 6.2."""
-    cfg, model, params, batch = _setup()
+    cfg, _, params, batch = _setup()
     want = reference_side[0]
-    logits = jax.jit(model.apply)(params, batch[0])
+    model, logits, loss, _ = programs_side(path)
     assert model.attention_path == (KERNEL_PATH if path == "kernels" else "plain: TORCHFT_FLASH=0")
     assert logits.dtype == jnp.float32
     np.testing.assert_allclose(logits, want["logits"], atol=3e-4)
     nll = _token_nll(logits, batch[1])
     np.testing.assert_allclose(nll, want["nll"], atol=5e-5)
-    loss = float(jax.jit(model.loss)(params, batch))
+    loss = float(loss)
     assert cfg.n_mtp == 1 and "mtp" in params
     assert loss == pytest.approx(float(jnp.mean(nll)), abs=2e-6)
     assert loss == pytest.approx(float(jnp.mean(want["nll"])), abs=2e-5)
 
 
 def test_objective_module_balance_loads_and_every_gradient_agree_with_the_reference(path, reference_side):
-    cfg, model, params, batch = _setup()
+    cfg, _, params, batch = _setup()
     want, want_total, want_grads = reference_side
-    (objective, (signal, summary)), grads = jax.jit(jax.value_and_grad(model.objective, has_aux=True))(params, batch)
+    model, _, loss, ((objective, (signal, summary)), grads) = programs_side(path)
     assert model.attention_path == (KERNEL_PATH if path == "kernels" else "plain: TORCHFT_FLASH=0")
     # the objective: the cross-entropy, the module at its weight over positions 0..S-2, the balance loss
     mtp = float(jnp.mean(want["mtp_nll"]))
@@ -127,7 +154,7 @@ def test_objective_module_balance_loads_and_every_gradient_agree_with_the_refere
     assert float(want["balance"]) > 1e-4
     assert float(want_total) == pytest.approx(float(jnp.mean(want["nll"])) + MTP_WEIGHT * mtp + float(want["balance"]), abs=1e-6)
     assert float(objective) == pytest.approx(float(want_total), abs=2e-5)
-    assert float(objective) - float(jax.jit(model.loss)(params, batch)) == pytest.approx(
+    assert float(objective) - float(loss) == pytest.approx(
         MTP_WEIGHT * mtp + float(want["balance"]), abs=2e-5
     )
     # the signal: every router's load, in the order of the state leaves, the module's last
@@ -177,7 +204,7 @@ def test_the_modules_loss_of_every_position_and_the_last_position_left_out(monke
     assert nll.shape == (2, SEQ)
     np.testing.assert_allclose(nll[:, :-1], kept, atol=1e-6)
     np.testing.assert_array_equal(load, want["loads"][-1])
-    _, (_, summary) = model.objective(params, batch)
+    (_, (_, summary)), _ = programs_side("plain")[3]  # ``objective``, as the step's program has it
     reported = model.summary_stats(np.asarray(summary))["mtp_nll"]
     assert reported == pytest.approx(float(jnp.mean(nll[:, :-1])), abs=1e-6)
     assert abs(float(jnp.mean(nll)) - reported) > 1e-3  # the wrapped mean is another number
@@ -188,10 +215,10 @@ def test_a_model_without_the_module_has_no_such_leaves_and_no_such_field(monkeyp
     cfg, model, params, batch = _setup(n_mtp=0)
     assert "mtp" not in params
     rc = reference_config(cfg)
-    objective, (signal, summary) = model.objective(params, batch)
-    want = ref.forward(params, *batch, rc)
+    objective, (signal, summary) = jax.jit(model.objective)(params, batch)
+    want = jax.jit(lambda p: ref.forward(p, *batch, rc))(params)
     assert want["mtp_nll"] is None and len(want["loads"]) == 3
-    assert float(objective) == pytest.approx(float(ref.loss(params, batch, rc)), abs=2e-5)
+    assert float(objective) == pytest.approx(float(jax.jit(lambda p: ref.loss(p, batch, rc))(params)), abs=2e-5)
     assert [np.asarray(s).shape for s in signal] == [(3, 16)]
     assert sorted(model.summary_stats(np.asarray(summary))) == ["buffer_rows", "load_max", "load_mean", "rows_here"]
     with pytest.raises(ValueError, match="one prediction module at most"):
@@ -279,23 +306,28 @@ def test_a_bfloat16_model_keeps_a_float32_stream_routes_on_it_and_never_rounds_a
     monkeypatch.setattr(model.moe, "apply", lambda w, x, *a: seen.append(x.dtype) or real(w, x, *a))
     monkeypatch.setattr(model, "_block", lambda x, *a: streams.append(x.dtype) or block(x, *a))
     tokens = jnp.zeros((1, SEQ), jnp.int32)
-    model.objective(params, (tokens, tokens))
+    jax.jit(model.objective)(params, (tokens, tokens))
     # a stacked run is traced once: the dense layer, the run of experts, the module's layer
     assert streams == [jnp.float32] * 3 and seen == [jnp.float32] * 2
-    logits = model.apply(params, tokens)
+    logits = jax.jit(model.apply)(params, tokens)
     assert logits.dtype == jnp.float32
     # a float32 sum of bfloat16 products holds more than bfloat16's eight bits
     assert float(jnp.max(jnp.abs(logits - logits.astype(jnp.bfloat16).astype(jnp.float32)))) > 0
 
 
 @pytest.mark.parametrize("kernel,count", [("flash_fwd", 5), ("flash_dq", 3), ("flash_dkv", 3)])
-def test_what_a_rematerialised_layer_keeps_and_what_it_runs_again(kernel, count, monkeypatch):
+def test_what_a_rematerialised_layer_keeps_and_what_it_runs_again(kernel, count):
     """Every layer is rematerialised, the module's too.  The stacked run of
     expert layers keeps what flash made (``flash.KEPT_NAMES``) and its
     ``flash_fwd`` stands once; the two single layers (the dense one, the
     module's) keep nothing of the kind and theirs stands twice: 1 + 2 + 2
     traced bodies' worth; the backward kernels once a body."""
-    monkeypatch.setenv("TORCHFT_FLASH", "1")
-    cfg, model, params, batch = _setup()
-    text = str(jax.make_jaxpr(jax.grad(lambda p: model.objective(p, batch)[0]))(params))
+    text = _gradients_jaxpr()
     assert text.count(f"name={kernel}\n") + text.count(f"name={kernel} ") == count, kernel
+
+
+@functools.lru_cache(maxsize=None)
+def _gradients_jaxpr():
+    """Traced once for the three kernels' counts."""
+    _, model, params, batch = _setup()
+    return gradients_jaxpr(model, params, batch)

@@ -16,12 +16,16 @@ from torchft_tpu import tier as tier_mod
 from torchft_tpu.communicator import DummyCommunicator
 from torchft_tpu.manager import Manager
 from torchft_tpu.models.ling_hybrid import LingHybrid, ling_debug
-from torchft_tpu.parallel.hsdp import HSDPTrainer, fsdp_shardings, make_grad_step, make_update_step
-from torchft_tpu.parallel.mesh import make_mesh
+from torchft_tpu.parallel.hsdp import fsdp_shardings, make_update_step
 
+from tests._toys import replica_group, trainer as group_trainer
 from tests.test_manager import MemoryTransport, StubClient, _quorum_result
 
 RATE = 1e-3
+
+
+def ling():
+    return LingHybrid(ling_debug())
 
 
 def _biases(model: LingHybrid, params: Any) -> List[np.ndarray]:
@@ -42,10 +46,9 @@ def _stub_trainer(steps: int):
         comm=DummyCommunicator(), load_state_dict=None, state_dict=None, min_replica_size=1,
         checkpoint_transport=MemoryTransport(), _manager_client=client, rank=0, world_size=1,
     )
-    model = LingHybrid(ling_debug())
-    mesh = make_mesh(fsdp=1, devices=jax.devices()[:1])
+    model, mesh, _ = replica_group(ling, 0)
     # weight decay large enough to see, were the optimizer let near a bias
-    trainer = HSDPTrainer(model, optax.adamw(1e-3, weight_decay=0.5), mesh, manager, key=jax.random.PRNGKey(0))
+    trainer = group_trainer(ling, 0, manager, jax.random.PRNGKey(0), learning_rate=1e-3, weight_decay=0.5)
     return model, mesh, manager, trainer
 
 
@@ -58,7 +61,7 @@ def test_committed_step_moves_the_bias_by_the_load_and_nothing_else_does():
     trainer.holder["params"] = start
     before = _biases(model, start)
     batch = _batch(model, mesh, 1)
-    loss, grads = make_grad_step(model, mesh)(start, batch)
+    loss, grads = replica_group(ling, 0)[2](start, batch)
     loads = _biases(model, grads)  # the bias's slot of the gradient tree carries the load
     assert all(float(x.sum(axis=-1).min()) == 64 * 4 for x in loads)  # 64 tokens, 4 experts each
     loss, committed = trainer.train_step(batch)
@@ -98,13 +101,12 @@ def test_update_step_keeps_its_signature_and_llama_its_path():
     from torchft_tpu.models.llama import Llama, llama_debug
     from torchft_tpu.parallel import hsdp
 
-    mesh = make_mesh(fsdp=1, devices=jax.devices()[:1])
     assert hsdp._state_mask(Llama(llama_debug())) is None
-    model = LingHybrid(ling_debug())
+    model, mesh, grad_step = replica_group(ling, 0)  # ``make_grad_step(model, mesh)``, as every test here has it
     tx = optax.adamw(3e-4)
     params = hsdp.shard_init(model, jax.random.PRNGKey(0), mesh)
     batch = _batch(model, mesh, 3)
-    loss, grads = make_grad_step(model, mesh)(params, batch)
+    loss, grads = grad_step(params, batch)
     assert jax.tree_util.tree_structure(grads) == jax.tree_util.tree_structure(params)
     new, opt_state = make_update_step(model, tx, mesh)(params, tx.init(params), grads)
     assert jax.tree_util.tree_structure(new) == jax.tree_util.tree_structure(grads)
@@ -135,8 +137,7 @@ def test_two_replicas_agree_bit_for_bit_through_a_kill_and_a_live_heal():
     rejoined = threading.Event()
 
     def replica(idx: int) -> None:
-        mesh = make_mesh(fsdp=1, devices=[devices[idx]])
-        model = LingHybrid(ling_debug())
+        model, mesh, _ = replica_group(ling, idx)
         batch = _batch(model, mesh, 100 + idx)
         life = 0
         while True:
@@ -148,9 +149,9 @@ def test_two_replicas_agree_bit_for_bit_through_a_kill_and_a_live_heal():
                 server_cls=tier_mod.manager_server_cls(tier),
             )
             managers.append(manager)
-            trainer = HSDPTrainer(
-                model, optax.adamw(1e-3), mesh, manager, key=jax.random.PRNGKey(10 * life + 1)
-            )
+            # the new life finds the step's programs compiled (``tests/_toys.py``): the
+            # survivor's ring does not wait out its 30 s while they compile (D13 (b))
+            trainer = group_trainer(ling, idx, manager, jax.random.PRNGKey(10 * life + 1), learning_rate=1e-3)
             if life:
                 rejoined.set()
             try:

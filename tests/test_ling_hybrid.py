@@ -1,14 +1,16 @@
 """``models/ling_hybrid.py`` against the plain float32 reference
 (``ftbench/architectures/ling_hybrid_reference.py``) at toy size on the
-CPU: 7 layers in the published pattern (dense+KDA, 4 KDA expert layers,
-MLA, KDA), the multi-token-prediction module at weight 0.3, a selection
-bias that is not zero.
+CPU: 6 layers, ONE period of the published pattern (dense+KDA, 4 KDA expert
+layers, MLA: every kind of layer, each run compiled once; the preset's
+seventh layer, a KDA expert layer again, is a fourth run to compile and no
+kind the six lack), the multi-token-prediction module at weight 0.3, a
+selection bias that is not zero.
 
 Tolerances, with their reasons.  Both sides are float32 with matrix products
 at ``highest``; they differ in the ORDER of float32 additions: the chunked
 delta rule against the per-token recurrence, sorted rows against masked
-experts, a scan over stacked layers against a loop.  Through 7 layers
-(unit-length q and k, RMS norms, decays down to exp(-5) a token) that read
+experts, a scan over stacked layers against a loop.  Through the preset's 7
+layers (unit-length q and k, RMS norms, decays down to exp(-5) a token) that read
 3e-4 on logits of up to 5 (6e-5 of their size) and 1e-4 of a leaf's largest
 gradient, 2.5e-3 for ``a_log`` whose whole gradient is 1e-2: limits of 1e-3
 absolute on logits, 1e-5 on the losses and 5e-3 of a leaf's largest
@@ -17,6 +19,8 @@ experts that differs reads above 1e-1, a dropped term (the shared expert,
 a gate, the MTP loss) at least 1e-2: all fail.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -24,6 +28,9 @@ import pytest
 
 from ftbench.architectures import ling_hybrid_reference as ref
 from torchft_tpu.models.ling_hybrid import KERNEL_PATH, LingHybrid, LingHybridConfig, ling_debug
+
+from tests._once import once_a_run
+from tests._toys import program_side
 
 
 def reference_config(c: LingHybridConfig) -> dict:
@@ -44,29 +51,47 @@ def reference_config(c: LingHybridConfig) -> dict:
     )
 
 
-def _setup(**over):
-    cfg = ling_debug(n_mtp=1, mtp_loss_weight=0.3, **over)
-    model = LingHybrid(cfg)
-    params = model.init(jax.random.PRNGKey(0))
-    # a selection bias that is not zero: at init it is, and then it routes nothing
-    params = jax.tree_util.tree_map(
-        lambda p, is_state: 0.05 * jax.random.normal(jax.random.PRNGKey(3), p.shape) if is_state else p,
-        params, model.state_mask(),
-    )
+@functools.lru_cache(maxsize=None)
+def _params():
+    """The toy's parameters, made once a run of the tests (``init`` runs
+    operation by operation, 15 s of small compiles in every process that
+    makes them, and again inside another matmul precision)."""
+    model = LingHybrid(ling_debug(n_layers=6, n_mtp=1, mtp_loss_weight=0.3))
+
+    def stirred(key):
+        # a selection bias that is not zero: at init it is, and then it routes nothing
+        return jax.tree_util.tree_map(
+            lambda p, is_state: 0.05 * jax.random.normal(jax.random.PRNGKey(3), p.shape) if is_state else p,
+            model.init(key), model.state_mask(),
+        )
+
+    def make():  # ONE program: ``init`` run operation by operation is 15 s of small compiles
+        return jax.jit(stirred)(jax.random.PRNGKey(0))
+
+    return once_a_run("ling_hybrid-params", make)
+
+
+def _setup():
+    """(config, a model of its own, the parameters, a batch): the model is
+    the caller's alone, since what it traces depends on ``TORCHFT_FLASH``."""
+    cfg = ling_debug(n_layers=6, n_mtp=1, mtp_loss_weight=0.3)
     tokens = np.random.default_rng(0).integers(0, cfg.vocab_size, (1, 64)).astype(np.int32)
-    return cfg, model, params, (tokens, np.roll(tokens, -1, axis=1))
+    return cfg, LingHybrid(cfg), _params(), (tokens, np.roll(tokens, -1, axis=1))
 
 
 @pytest.fixture(scope="module")
 def reference_side():
-    """The reference's logits, losses and gradients, computed once for both
-    of the program's paths."""
+    """The reference's logits, losses and gradients, computed once a run for
+    both of the program's paths."""
     cfg, _, params, batch = _setup()
     rc = reference_config(cfg)
-    with jax.default_matmul_precision("highest"):
-        want = jax.jit(lambda p: ref.forward(p, *batch, rc, logits=True))(params)
-        want_objective, want_grads = jax.jit(jax.value_and_grad(lambda p: ref.loss(p, batch, rc)))(params)
-    return want, want_objective, want_grads
+
+    def make():
+        with jax.default_matmul_precision("highest"):
+            # ONE program: two compiled the forward pass twice
+            return jax.jit(lambda p: (ref.forward(p, *batch, rc, logits=True), *jax.value_and_grad(lambda p: ref.loss(p, batch, rc))(p)))(params)
+
+    return once_a_run("ling_hybrid-reference", make)
 
 
 @pytest.fixture(params=["plain", "kernels"])
@@ -80,19 +105,39 @@ def path(request, monkeypatch):
     return request.param
 
 
+# The toy and the reference's side are made once a run, 20 s each on a loaded
+# worker: each by a test of its own, so that the comparison below, which
+# compiles the program, is not three costs in one test.
+
+
+def test_every_selection_bias_of_the_toy_routes():
+    _, model, params, _ = _setup()
+    mask = jax.tree_util.tree_leaves(model.state_mask())
+    biases = [p for p, is_state in zip(jax.tree_util.tree_leaves(params), mask) if is_state]
+    assert len(biases) == 3  # a stacked run of expert layers a leaf (KDA's four, MLA's one), and the MTP module's layer
+    assert all(np.abs(np.asarray(b)).reshape(-1, b.shape[-1]).max(axis=-1).min() > 0 for b in biases)
+
+
+def test_the_references_every_term_is_there_to_be_missed(reference_side):
+    want, want_objective, want_grads = reference_side
+    nll, mtp_nll = float(np.mean(want["nll"])), float(np.mean(want["mtp_nll"]))
+    assert 0.3 * mtp_nll > 1.0 and float(want["balance"]) > 1e-4
+    assert float(want_objective) > nll + 0.3 * mtp_nll  # and the balance loss
+    assert all(np.isfinite(np.asarray(g)).all() for g in jax.tree_util.tree_leaves(want_grads))
+
+
 def test_logits_loss_and_every_gradient_agree_with_the_reference(path, reference_side):
     cfg, model, params, batch = _setup()
     want, want_objective, want_grads = reference_side
     with jax.default_matmul_precision("highest"):
-        logits = jax.jit(model.apply)(params, batch[0])
+        logits, loss, ((objective, (signal, _)), grads) = program_side(model, params, batch, path)
         assert (model.attention_path == KERNEL_PATH) == (path == "kernels")
         assert path == "kernels" or model.attention_path.startswith("plain: ")
         assert float(jnp.max(jnp.abs(logits - want["logits"]))) < 1e-3
         # the loss: cross-entropy and the MTP term at 0.3; the objective adds the balance loss
         want_loss = float(jnp.mean(want["nll"]) + 0.3 * jnp.mean(want["mtp_nll"]))
-        assert float(jax.jit(model.loss)(params, batch)) == pytest.approx(want_loss, abs=1e-5)
+        assert float(loss) == pytest.approx(want_loss, abs=1e-5)
         assert 0.3 * float(jnp.mean(want["mtp_nll"])) > 1.0  # the MTP term is there to be missed
-        (objective, (signal, _)), grads = jax.jit(jax.value_and_grad(model.objective, has_aux=True))(params, batch)
         assert float(objective) == pytest.approx(float(want_objective), abs=1e-5)
         assert float(want["balance"]) > 1e-4
     # the signal: every router's load, in the order of the state leaves

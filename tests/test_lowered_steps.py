@@ -40,58 +40,14 @@ MODELS = ("ling_hybrid", "indexed_sparse_moe", "llama", "ssm_hybrid_moe", "windo
 CASES = [(m, p) for m in MODELS for p in ("plain", "kernels")]
 
 
-def _model(name):
-    if name == "ling_hybrid":
-        from torchft_tpu.models.ling_hybrid import LingHybrid, ling_debug
-
-        return LingHybrid(ling_debug()), 128
-    if name == "llama":
-        from torchft_tpu.models.llama import Llama, llama_debug
-
-        return Llama(llama_debug()), 128
-    if name == "ssm_hybrid_moe":
-        from torchft_tpu.models.ssm_hybrid_moe import SsmHybridMoE, ssm_hybrid_debug
-
-        return SsmHybridMoE(ssm_hybrid_debug()), 64
-    if name == "windowed_moe":
-        from torchft_tpu.models.windowed_moe import WindowedMoE, windowed_moe_debug
-
-        return WindowedMoE(windowed_moe_debug()), 64
-    if name == "eva":
-        from torchft_tpu.models.eva import Eva, eva_debug
-
-        return Eva(eva_debug()), 64
-    from torchft_tpu.models.indexed_sparse_moe import IndexedSparseMoE, indexed_sparse_debug
-
-    return IndexedSparseMoE(indexed_sparse_debug()), 32
-
-
 def digest(name: str, path: str) -> str:
-    import jax
-    import numpy as np
+    from tests._toys import lowered_grad_step  # traces once for this file and ``test_device_parts.py``
 
-    from torchft_tpu.parallel.hsdp import make_grad_step
-    from torchft_tpu.parallel.mesh import make_mesh
-
-    before = os.environ.get("TORCHFT_FLASH")
-    os.environ["TORCHFT_FLASH"] = "1" if path == "kernels" else "0"
-    try:
-        model, seq = _model(name)
-        mesh = make_mesh(fsdp=1, devices=jax.devices()[:1])
-        params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
-        tokens = jax.ShapeDtypeStruct((1, seq), np.int32)
-        text = make_grad_step(model, mesh).lower(params, (tokens, tokens)).as_text()
-        off_kernels = any(word in model.attention_path for word in ("plain", "naive"))
-        assert off_kernels == (path == "plain"), model.attention_path
-        # jax numbers its private functions (@silu_808) from one counter a
-        # process: the numbers say what else was traced, not what the program is
-        text = re.sub(r"@([A-Za-z_]\w*?)_\d+\b", r"@\1", text)
-        return hashlib.sha256(text.encode()).hexdigest()
-    finally:
-        if before is None:
-            del os.environ["TORCHFT_FLASH"]
-        else:
-            os.environ["TORCHFT_FLASH"] = before
+    text = lowered_grad_step(name, path)[3].as_text()
+    # jax numbers its private functions (@silu_808) from one counter a
+    # process: the numbers say what else was traced, not what the program is
+    text = re.sub(r"@([A-Za-z_]\w*?)_\d+\b", r"@\1", text)
+    return hashlib.sha256(text.encode()).hexdigest()
 
 
 @pytest.mark.parametrize("name,path", CASES)

@@ -11,6 +11,7 @@ products of 64 and 32 terms (read: 1e-6).
 """
 
 import dataclasses
+import functools
 import json
 import os
 from functools import partial
@@ -197,6 +198,20 @@ def test_the_buffer_is_sized_from_the_uniform_load_and_never_over_every_pair(tok
     np.testing.assert_array_equal(buffer_passes(size, jnp.asarray([0.0, size, size + 1.0])), [1, 1, 2])
 
 
+def _sized_loss(f, p, x):
+    out, load, balance = f(p, x)
+    return jnp.sum(out ** 2) + balance, (out, load)
+
+
+@functools.lru_cache(maxsize=None)
+def _sized_reference():
+    """The reference's side of the sized layer's cases as ONE program, traced
+    by the first case (inside its matmul precision) and found by the others:
+    run operation by operation it was 10 s and more of each case."""
+    cfg = dict(REF_CFG, topk_group=1)
+    return jax.jit(jax.value_and_grad(partial(_sized_loss, lambda p, x: ref.moe_layer(x, p, cfg, (0, 4))), argnums=(0, 1), has_aux=True))
+
+
 @pytest.mark.parametrize(
     "rows, passes",
     [
@@ -213,18 +228,12 @@ def test_every_number_of_passes_gives_the_references_output_and_gradients(monkey
     layer = _sized_layer()
     assert buffer_size(2048, 4, 4, 32) == SIZE
     params, x = _sized_case(layer, rows)
-    cfg = dict(REF_CFG, topk_group=1)
     began = _passes_run(monkeypatch, layer)
-
-    def loss(f, p, x):
-        out, load, balance = f(p, x)
-        return jnp.sum(out ** 2) + balance, (out, load)
+    loss = _sized_loss
 
     with jax.default_matmul_precision("highest"):
         (_, (out, load)), got = jax.jit(jax.value_and_grad(partial(loss, layer.apply), argnums=(0, 1), has_aux=True))(params, x)
-        (_, (want_out, _)), want = jax.value_and_grad(
-            partial(loss, lambda p, x: ref.moe_layer(x, p, cfg, (0, 4))), argnums=(0, 1), has_aux=True
-        )(params, x)
+        (_, (want_out, _)), want = _sized_reference()(params, x)
         jax.effects_barrier()
     assert float(load[:4].sum()) == rows
     # forward, and in the backward pass each of them forward again and back
@@ -261,8 +270,9 @@ def test_gradients_reach_router_and_experts_and_never_the_bias():
         return jnp.sum(out ** 2) + balance
 
     with jax.default_matmul_precision("highest"):
-        got = jax.grad(lambda p: loss(p, lambda p: layer.apply(p, x)))(params)
-        want = jax.grad(lambda p: loss(p, lambda p: ref.moe_layer(x, p, REF_CFG, (4, HELD))))(params)
+        # one program a side: run operation by operation the two were 15 s of small compiles
+        got = jax.jit(jax.grad(lambda p: loss(p, lambda p: layer.apply(p, x))))(params)
+        want = jax.jit(jax.grad(lambda p: loss(p, lambda p: ref.moe_layer(x, p, REF_CFG, (4, HELD)))))(params)
     assert float(jnp.max(jnp.abs(got["bias"]))) == 0.0
     for name in params:
         scale = float(jnp.max(jnp.abs(want[name]))) + 1e-6
@@ -291,7 +301,7 @@ def test_rows_a_grouped_kernel_leaves_unwritten_reach_nothing(monkeypatch, rows)
         return jnp.sum(out ** 2)
 
     with jax.default_matmul_precision("highest"):
-        clean = jax.grad(loss, argnums=(0, 1))(params, x)
+        clean = jax.jit(jax.grad(loss, argnums=(0, 1)))(params, x)  # one program, not one an operation
 
         def past(rows, sizes):
             return (jnp.arange(rows) >= jnp.sum(sizes))[:, None]
@@ -313,7 +323,7 @@ def test_rows_a_grouped_kernel_leaves_unwritten_reach_nothing(monkeypatch, rows)
 
         poisoned.defvjp(fwd, bwd)
         monkeypatch.setattr(layer, "_grouped", poisoned)
-        dirty = jax.grad(loss, argnums=(0, 1))(params, x)
+        dirty = jax.jit(jax.grad(loss, argnums=(0, 1)))(params, x)  # traced anew: another ``_grouped``
         jax.effects_barrier()
     assert set(began) == ({0} if rows in (None, 1136) else {0, SIZE})
     for a, b in zip(jax.tree_util.tree_leaves(clean), jax.tree_util.tree_leaves(dirty)):

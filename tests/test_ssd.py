@@ -2,6 +2,8 @@
 and the plain chunk algebra) against the token-by-token recurrence, outputs
 and every gradient."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -35,17 +37,27 @@ def operands(seed, decay):
     return x, dt, A_log, Bm, Cm, D
 
 
-def run(path):
-    if path == "kernels":
-        return lambda *a: ssd_chunked(*a, chunk=CHUNK, interpret=True)
-    return lambda *a: ssd_chunked_plain(*a, chunk=CHUNK)
+@functools.lru_cache(maxsize=None)
+def run(path, grad=False):
+    """The scan on ``path`` (``recurrence``: the reference's), or the
+    gradients of its output summed under a weight, as ONE program that the
+    three decays share: their operands differ in values alone, and run
+    operation by operation each case compiled its own few hundred."""
+    f = {
+        "kernels": lambda *a: ssd_chunked(*a, chunk=CHUNK, interpret=True),
+        "plain": lambda *a: ssd_chunked_plain(*a, chunk=CHUNK),
+        "recurrence": recurrence,
+    }[path]
+    if grad:
+        return jax.jit(jax.grad(lambda weight, *a: jnp.sum(f(*a) * weight), argnums=range(1, 7)))
+    return jax.jit(f)
 
 
 @pytest.mark.parametrize("decay", ["slow", "fast", "mixed"])
 @pytest.mark.parametrize("path", ["kernels", "plain"])
 def test_outputs_match_the_recurrence(path, decay):
     args = operands(1, decay)
-    want = recurrence(*args)
+    want = run("recurrence")(*args)
     got = run(path)(*args)
     assert got.shape == (B, S, H, P) and got.dtype == jnp.float32
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
@@ -56,9 +68,8 @@ def test_outputs_match_the_recurrence(path, decay):
 def test_every_gradient_matches_the_recurrence(path, decay):
     args = operands(2, decay)
     weight = jax.random.normal(jax.random.PRNGKey(9), (B, S, H, P), jnp.float32)
-    loss = lambda f: (lambda *a: jnp.sum(f(*a) * weight))  # noqa: E731
-    want = jax.grad(loss(recurrence), argnums=range(6))(*args)
-    got = jax.grad(loss(run(path)), argnums=range(6))(*args)
+    want = run("recurrence", grad=True)(weight, *args)
+    got = run(path, grad=True)(weight, *args)
     for name, g, w in zip(NAMES, got, want):
         scale = float(jnp.max(jnp.abs(w))) + 1e-30
         np.testing.assert_allclose(g / scale, w / scale, rtol=1e-4, atol=2e-5, err_msg=f"d{name}")

@@ -11,18 +11,20 @@ from typing import Any, Dict, List
 
 import jax
 import numpy as np
-import optax
 
 from torchft_tpu import tier as tier_mod
 from torchft_tpu.communicator import DummyCommunicator
 from torchft_tpu.manager import Manager
 from torchft_tpu.models.ssm_hybrid_moe import SsmHybridMoE, ssm_hybrid_debug
 from torchft_tpu.parallel import hsdp
-from torchft_tpu.parallel.hsdp import HSDPTrainer, make_grad_step
-from torchft_tpu.parallel.mesh import make_mesh
 
 from tests.test_ling_hsdp import RATE, _batch, _biases
+from tests._toys import replica_group, trainer as group_trainer
 from tests.test_manager import MemoryTransport, StubClient, _quorum_result
+
+
+def toy():
+    return SsmHybridMoE(ssm_hybrid_debug())
 
 TOTAL = 5
 
@@ -34,13 +36,12 @@ def test_a_committed_step_moves_every_router_s_bias_and_reports_its_routing():
         comm=DummyCommunicator(), load_state_dict=None, state_dict=None, min_replica_size=1,
         checkpoint_transport=MemoryTransport(), _manager_client=client, rank=0, world_size=1,
     )
-    model = SsmHybridMoE(ssm_hybrid_debug())
+    model, mesh, grad_step = replica_group(toy, 0)
     assert hsdp._reports(model) and sum(jax.tree_util.tree_leaves(hsdp._state_mask(model))) == 4
-    mesh = make_mesh(fsdp=1, devices=jax.devices()[:1])
-    trainer = HSDPTrainer(model, optax.adamw(1e-3, weight_decay=0.5), mesh, manager, key=jax.random.PRNGKey(0))
+    trainer = group_trainer(toy, 0, manager, jax.random.PRNGKey(0), learning_rate=1e-3, weight_decay=0.5)
     batch = _batch(model, mesh, 1)
     before = _biases(model, trainer.holder["params"])
-    report, grads = make_grad_step(model, mesh)(trainer.holder["params"], batch)
+    report, grads = grad_step(trainer.holder["params"], batch)
     # a bias's slot of the gradient tree carries its router's load (a stacked run of one layer)
     loads = [x[0] for x in _biases(model, grads)]
     assert len(loads) == 4 and all(float(x.sum()) == 64 * 4 for x in loads)  # 64 tokens, 4 experts each
@@ -74,8 +75,7 @@ def test_two_replicas_stay_bit_equal_while_the_biases_move():
         return h.hexdigest()
 
     def replica(idx: int) -> None:
-        mesh = make_mesh(fsdp=1, devices=[devices[idx]])
-        model = SsmHybridMoE(ssm_hybrid_debug())
+        model, mesh, _ = replica_group(toy, idx)
         batch = _batch(model, mesh, 100 + idx)  # a batch each: equal biases REQUIRE the averaged load
         manager = Manager(
             comm=tier_mod.make_communicator(timeout_s=30.0, tier=tier),
@@ -85,7 +85,7 @@ def test_two_replicas_stay_bit_equal_while_the_biases_move():
             server_cls=tier_mod.manager_server_cls(tier),
         )
         managers.append(manager)
-        trainer = HSDPTrainer(model, optax.adamw(1e-3), mesh, manager, key=jax.random.PRNGKey(1))
+        trainer = group_trainer(toy, idx, manager, jax.random.PRNGKey(1), learning_rate=1e-3)
         while manager.current_step() < TOTAL:
             trainer.quantize_outer = manager.current_step() == 2  # one step on the int8 wire
             loss, committed = trainer.train_step(batch)

@@ -17,6 +17,8 @@ bfloat16 anywhere reads 1e-1 on the logits, a choice of experts that differs
 above 1e-1, a dropped term (the shared expert, ``D x``, the convolution's bias,
 the gate) at least 1e-2: all fail."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -25,6 +27,9 @@ import pytest
 from ftbench.architectures import ssm_hybrid_moe_reference as ref
 from torchft_tpu.models.ssm_hybrid_moe import KERNEL_PATH, SsmHybridMoE, SsmHybridMoEConfig, ssm_hybrid_debug
 from torchft_tpu.parallel.moe import RoutedExperts, RoutedExpertsConfig
+
+from tests._once import once_a_run
+from tests._toys import gradients_jaxpr, program_side
 
 SEQ = 64  # four chunks of the toy scan
 
@@ -41,10 +46,12 @@ def reference_config(c: SsmHybridMoEConfig) -> dict:
     )
 
 
-def _setup(**over):
-    cfg = ssm_hybrid_debug(**over)
-    model = SsmHybridMoE(cfg)
-    params = model.init(jax.random.PRNGKey(0))
+@functools.lru_cache(maxsize=None)
+def _params(**over):
+    """The toy's parameters, made once a run of the tests (``init`` runs
+    operation by operation, 10-20 s of small compiles in every process that
+    makes them)."""
+    model = SsmHybridMoE(ssm_hybrid_debug(**over))
 
     def stir(path, p, is_state):
         """What ``init`` leaves at a constant gets values of its own: a bias
@@ -56,20 +63,32 @@ def _setup(**over):
             return 0.05 * noise
         return p + 0.1 * noise if name in ("D", "o_norm", "norm") else p
 
-    params = jax.tree_util.tree_map_with_path(stir, params, model.state_mask())
+    def make():  # ONE program: ``init`` run operation by operation is 10-20 s of small compiles
+        return jax.jit(lambda key: jax.tree_util.tree_map_with_path(stir, model.init(key), model.state_mask()))(jax.random.PRNGKey(0))
+
+    return once_a_run(f"ssm_hybrid_moe-params-{sorted(over.items())}", make)
+
+
+def _setup(**over):
+    """(config, a model of its own, the parameters, a batch): the model is
+    the caller's alone, since what it traces depends on ``TORCHFT_FLASH``."""
+    cfg = ssm_hybrid_debug(**over)
     tokens = np.random.default_rng(0).integers(0, cfg.vocab_size, (2, SEQ)).astype(np.int32)
-    return cfg, model, params, (jnp.asarray(tokens), jnp.asarray(np.roll(tokens, -1, axis=1)))
+    return cfg, SsmHybridMoE(cfg), _params(**over), (jnp.asarray(tokens), jnp.asarray(np.roll(tokens, -1, axis=1)))
 
 
 @pytest.fixture(scope="module")
 def reference_side():
-    """The reference's logits, losses and gradients, computed once for both
-    of the program's paths."""
+    """The reference's logits, losses and gradients, computed once a run for
+    both of the program's paths."""
     cfg, _, params, batch = _setup()
     rc = reference_config(cfg)
-    want = jax.jit(lambda p: ref.forward(p, *batch, rc, logits=True))(params)
-    want_objective, want_grads = jax.jit(jax.value_and_grad(lambda p: ref.loss(p, batch, rc)))(params)
-    return want, want_objective, want_grads
+
+    def make():
+        # ONE program: two compiled the forward pass twice
+        return jax.jit(lambda p: (ref.forward(p, *batch, rc, logits=True), *jax.value_and_grad(lambda p: ref.loss(p, batch, rc))(p)))(params)
+
+    return once_a_run("ssm_hybrid_moe-reference", make)
 
 
 @pytest.fixture(params=["plain", "kernels"])
@@ -85,11 +104,10 @@ def _leaves(tree):
 def test_logits_loss_and_every_gradient_agree_with_the_reference(path, reference_side):
     cfg, model, params, batch = _setup()
     want, want_objective, want_grads = reference_side
-    logits = jax.jit(model.apply)(params, batch[0])
+    logits, loss, ((objective, (signal, summary)), grads) = program_side(model, params, batch, path)
     assert model.attention_path == (KERNEL_PATH if path == "kernels" else "plain: TORCHFT_FLASH=0")
     np.testing.assert_allclose(logits, want["logits"], atol=3e-4)
-    assert float(jax.jit(model.loss)(params, batch)) == pytest.approx(float(jnp.mean(want["nll"])), abs=2e-5)
-    (objective, (signal, summary)), grads = jax.jit(jax.value_and_grad(model.objective, has_aux=True))(params, batch)
+    assert float(loss) == pytest.approx(float(jnp.mean(want["nll"])), abs=2e-5)
     assert float(objective) == pytest.approx(float(want_objective), abs=2e-5)
     assert float(want["balance"]) > 0 and float(objective) > float(jnp.mean(want["nll"]))
     # the signal is every expert layer's load, in the layers' order
@@ -163,15 +181,16 @@ def test_squared_relu_experts_of_two_matrices_are_a_dense_loop_over_experts():
     w["bias"] = 0.05 * jax.random.normal(jax.random.PRNGKey(6), (16,))
     x = jax.random.normal(jax.random.PRNGKey(2), (2, 24, 32), jnp.float32)
     with jax.default_matmul_precision("highest"):
-        want_out, want_load, want_balance = ref.moe_layer(x, w, RC, (4, 8))
+        want_out, want_load, want_balance = jax.jit(lambda w: ref.moe_layer(x, w, RC, (4, 8)))(w)
     out, load, balance = jax.jit(layer.apply)(w, x)
     np.testing.assert_allclose(out, want_out, atol=2e-5)
     np.testing.assert_array_equal(load, want_load)
     assert float(balance) == pytest.approx(float(want_balance), rel=1e-5)
     objective = lambda f: (lambda w: jnp.sum(f(w)[0] ** 2) + f(w)[2])  # noqa: E731
-    g = jax.grad(objective(lambda w: layer.apply(w, x)))(w)
+    # each side's gradient is one program: run operation by operation the two took 15 s of small compiles
+    g = jax.jit(jax.grad(objective(lambda w: layer.apply(w, x))))(w)
     with jax.default_matmul_precision("highest"):
-        g_want = jax.grad(objective(lambda w: ref.moe_layer(x, w, RC, (4, 8))))(w)
+        g_want = jax.jit(jax.grad(objective(lambda w: ref.moe_layer(x, w, RC, (4, 8)))))(w)
     for name in w:
         scale = float(jnp.max(jnp.abs(g_want[name])))
         np.testing.assert_allclose(g[name], g_want[name], atol=1e-4 * scale + 1e-6, err_msg=name)
@@ -226,12 +245,17 @@ def test_a_bfloat16_model_keeps_a_float32_stream_and_routes_on_it(monkeypatch):
 @pytest.mark.parametrize(
     "kernel,count", [("ssd_fwd", 8), ("ssd_bwd", 4), ("flash_fwd", 1), ("flash_dq", 1), ("flash_dkv", 1)]
 )
-def test_what_a_rematerialised_layer_keeps_and_what_it_runs_again(kernel, count, monkeypatch):
+def test_what_a_rematerialised_layer_keeps_and_what_it_runs_again(kernel, count):
     """Every layer is rematerialised.  The attention layer keeps what flash
     made (``flash.KEPT_NAMES``): a second ``flash_fwd`` would read 2.  The four
     state-space layers keep nothing of the scan (no room at the published
     widths): ``ssd_fwd`` stands twice a layer."""
-    monkeypatch.setenv("TORCHFT_FLASH", "1")
-    cfg, model, params, batch = _setup()
-    text = str(jax.make_jaxpr(jax.grad(lambda p: model.objective(p, batch)[0]))(params))
+    text = _gradients_jaxpr()
     assert text.count(f"name={kernel}\n") + text.count(f"name={kernel} ") == count, kernel
+
+
+@functools.lru_cache(maxsize=None)
+def _gradients_jaxpr():
+    """Traced once for the five kernels' counts."""
+    _, model, params, batch = _setup()
+    return gradients_jaxpr(model, params, batch)

@@ -19,6 +19,7 @@ a choice of experts that differs above 1e-1, a dropped term (the gate, a
 norm, rope, the shared expert, a window one position off) at least 1e-2: all
 fail."""
 
+import functools
 import zlib
 
 import jax
@@ -29,6 +30,9 @@ import pytest
 from ftbench.architectures import windowed_moe_reference as ref
 from torchft_tpu.models.windowed_moe import KERNEL_PATH, WindowedMoE, WindowedMoEConfig, windowed_moe_debug
 from torchft_tpu.parallel.moe import RoutedExperts, RoutedExpertsConfig
+
+from tests._once import once_a_run
+from tests._toys import gradients_jaxpr, program_side
 
 SEQ = 64  # the toy window is 24: every row past the 24th sees fewer keys than causal attention gives it
 S, F = "sliding_attention", "full_attention"
@@ -52,10 +56,12 @@ def reference_config(c: WindowedMoEConfig) -> dict:
     )
 
 
-def _setup(**over):
-    cfg = windowed_moe_debug(**over)
-    model = WindowedMoE(cfg)
-    params = model.init(jax.random.PRNGKey(0))
+@functools.lru_cache(maxsize=None)
+def _params(**over):
+    """The toy's parameters, made once a run of the tests (``init`` runs
+    operation by operation, 10-20 s of small compiles in every process that
+    makes them)."""
+    model = WindowedMoE(windowed_moe_debug(**over))
 
     def stir(path, p, is_state):
         """What ``init`` leaves at a constant gets values of its own: a bias
@@ -68,26 +74,40 @@ def _setup(**over):
             return 0.05 * noise
         return p + 0.1 * noise if "norms" in names or names[-1].endswith("_norm") else p
 
-    params = jax.tree_util.tree_map_with_path(stir, params, model.state_mask())
+    def make():  # ONE program: ``init`` run operation by operation is 10-20 s of small compiles
+        return jax.jit(lambda key: jax.tree_util.tree_map_with_path(stir, model.init(key), model.state_mask()))(jax.random.PRNGKey(0))
+
+    return once_a_run(f"windowed_moe-params-{sorted(over.items())}", make)
+
+
+def _setup(**over):
+    """(config, a model of its own, the parameters, a batch): the model is
+    the caller's alone, since what it traces depends on ``TORCHFT_FLASH``."""
+    cfg = windowed_moe_debug(**over)
     tokens = np.random.default_rng(0).integers(0, cfg.vocab_size, (2, SEQ)).astype(np.int32)
-    return cfg, model, params, (jnp.asarray(tokens), jnp.asarray(np.roll(tokens, -1, axis=1)))
+    return cfg, WindowedMoE(cfg), _params(**over), (jnp.asarray(tokens), jnp.asarray(np.roll(tokens, -1, axis=1)))
 
 
-@pytest.fixture(scope="module")
-def reference_side():
-    """The reference's logits, loss and gradients of a case, computed once
-    for both of the program's paths."""
-    made = {}
+@functools.lru_cache(maxsize=None)
+def reference_side(case):
+    """The reference's logits, loss and gradients of a case, computed once a
+    run for both of the program's paths."""
+    cfg, _, params, batch = _setup(**LAYERS[case])
+    rc = reference_config(cfg)
 
-    def side(case):
-        if case not in made:
-            cfg, _, params, batch = _setup(**LAYERS[case])
-            rc = reference_config(cfg)
-            want = jax.jit(lambda p: ref.forward(p, *batch, rc, logits=True))(params)
-            made[case] = (want, *jax.jit(jax.value_and_grad(lambda p: ref.loss(p, batch, rc)))(params))
-        return made[case]
+    def make():
+        # ONE program: two compiled the forward pass twice
+        return jax.jit(lambda p: (ref.forward(p, *batch, rc, logits=True), *jax.value_and_grad(lambda p: ref.loss(p, batch, rc))(p)))(params)
 
-    return side
+    return once_a_run(f"windowed_moe-reference-{case}", make)
+
+
+@functools.lru_cache(maxsize=None)
+def programs_side(case, path):
+    """(model, logits, loss, ((objective, (signal, summary)), gradients)) of
+    a case on ``path``, computed once a process."""
+    _, model, params, batch = _setup(**LAYERS[case])
+    return (model, *program_side(model, params, batch, path))
 
 
 @pytest.fixture(params=["plain", "kernels"])
@@ -101,14 +121,13 @@ def _leaves(tree):
 
 
 @pytest.mark.parametrize("case", list(LAYERS))
-def test_logits_loss_and_every_gradient_agree_with_the_reference(case, path, reference_side):
-    cfg, model, params, batch = _setup(**LAYERS[case])
+def test_logits_loss_and_every_gradient_agree_with_the_reference(case, path):
+    cfg, _, _, batch = _setup(**LAYERS[case])
     want, want_loss, want_grads = reference_side(case)
-    logits = jax.jit(model.apply)(params, batch[0])
+    model, logits, loss, ((objective, (signal, summary)), grads) = programs_side(case, path)
     assert model.attention_path == (KERNEL_PATH if path == "kernels" else "plain: TORCHFT_FLASH=0")
     np.testing.assert_allclose(logits, want["logits"], atol=3e-4)
-    assert float(jax.jit(model.loss)(params, batch)) == pytest.approx(float(jnp.mean(want["nll"])), abs=2e-5)
-    (objective, (signal, summary)), grads = jax.jit(jax.value_and_grad(model.objective, has_aux=True))(params, batch)
+    assert float(loss) == pytest.approx(float(jnp.mean(want["nll"])), abs=2e-5)
     # there is no auxiliary loss: what a step differentiates IS the cross-entropy
     assert float(objective) == pytest.approx(float(want_loss), abs=2e-5)
     # the signal is every expert layer's load, in the layers' order, a stacked run a leaf
@@ -229,11 +248,11 @@ def test_the_gate_the_embeddings_scale_and_the_branch_norms_are_there(monkeypatc
 
 
 def test_the_bias_moves_against_the_load_and_nothing_else_does():
-    cfg, model, params, batch = _setup()
+    cfg, _, params, batch = _setup()
+    model, _, _, ((_, (signal, _)), _) = programs_side("the-cell's-eight", "plain")  # ``objective``, as the step's program has it
     mask = model.state_mask()
     state = [p for p, m in zip(jax.tree_util.tree_leaves(params), jax.tree_util.tree_leaves(mask)) if m]
     assert [s.shape for s in state] == [(2, 16), (1, 16), (3, 16), (1, 16)]  # a router a layer, stacked by run
-    _, (signal, _) = model.objective(params, batch)
     moved = model.advance_state(state, signal)
     for before, after, load in zip(state, moved, signal):
         want = before + cfg.bias_update_rate * np.sign(np.mean(load, axis=-1, keepdims=True) - load)
@@ -299,14 +318,19 @@ def test_a_bfloat16_model_keeps_a_float32_stream_and_routes_on_it(monkeypatch):
         ("flash_fwd", 2), ("flash_dq", 2), ("flash_dkv", 2),
     ],
 )
-def test_what_a_rematerialised_layer_keeps_and_what_it_runs_again(kernel, count, monkeypatch):
+def test_what_a_rematerialised_layer_keeps_and_what_it_runs_again(kernel, count):
     """Every layer is rematerialised.  A FULL layer keeps what flash made
     (``flash.KEPT_NAMES``): a second ``flash_fwd`` a run would read 4.  A
     WINDOWED layer keeps nothing of the kind (no room for all eight at the
     published widths) and ``flash_win_fwd`` stands twice a run.  The
     windowed layers' kernels are the ``flash_win_*`` ones, whose grid holds
     the window's blocks alone (``tests/test_flash_attention.py``)."""
-    monkeypatch.setenv("TORCHFT_FLASH", "1")
-    cfg, model, params, batch = _setup()
-    text = str(jax.make_jaxpr(jax.grad(lambda p: model.objective(p, batch)[0]))(params))
+    text = _gradients_jaxpr()
     assert text.count(f"name={kernel}\n") + text.count(f"name={kernel} ") == count, kernel
+
+
+@functools.lru_cache(maxsize=None)
+def _gradients_jaxpr():
+    """Traced once for the six kernels' counts."""
+    _, model, params, batch = _setup()
+    return gradients_jaxpr(model, params, batch)
