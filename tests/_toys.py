@@ -142,6 +142,10 @@ def toy(name):
         from torchft_tpu.models.eva import Eva, eva_debug
 
         return Eva(eva_debug()), 64
+    if name == "gated_delta_moe":
+        from torchft_tpu.models.gated_delta_moe import GatedDeltaMoE, gated_delta_debug
+
+        return GatedDeltaMoE(gated_delta_debug()), 64
     if name == "ssm_hybrid_moe":
         from torchft_tpu.models.ssm_hybrid_moe import SsmHybridMoE, ssm_hybrid_debug
 

@@ -16,7 +16,7 @@ from torchft_tpu.obs.spans import DEVICE_PARTS, PART_PREFIX, part
 
 from tests._toys import toy, lowered_grad_step
 
-MODELS = ("llama", "ling_hybrid", "indexed_sparse_moe", "ssm_hybrid_moe", "windowed_moe", "latent_moe", "eva")
+MODELS = ("llama", "ling_hybrid", "indexed_sparse_moe", "ssm_hybrid_moe", "windowed_moe", "latent_moe", "eva", "gated_delta_moe")
 CASES = [(m, p) for m in MODELS for p in ("plain", "kernels")]
 EVERY = set(DEVICE_PARTS)
 # Keye has no dense MLP and no shared expert; Mistral has no experts; the
@@ -31,6 +31,7 @@ USES = {
     "windowed_moe": EVERY - {"mtp", "mixer_pool"},
     "latent_moe": EVERY - {"mixer_pool"},
     "eva": EVERY - {"experts_route", "experts_dispatch", "mtp"},
+    "gated_delta_moe": EVERY - {"mtp", "mixer_pool"},
 }
 # what costs time on a device and is never fused away into a neighbour
 HELD = ("dot", "convolution", "gather", "scatter", "sort")

@@ -321,3 +321,26 @@ def test_chunk_summary_attention_is_model_code_over_the_flash_kernels(module: st
     SwiGLU and cross-entropy, and nothing of the Manager."""
     assert [ROWS[i][0] for i in _rows_of(module)] == [row]
     assert {target for importer, _line, target in _inner_edges() if importer == module} == may_import
+
+
+@pytest.mark.parametrize(
+    "module,row,may_import",
+    [
+        ("ops.gdn", "store-kernels-data", {"ops.kda"}),
+        (
+            "models.gated_delta_moe", "compiled-step-models",
+            {
+                "ops.gdn", "ops.flash_attention", "parallel.moe", "models.llama", "models.ling_hybrid",
+                "models.windowed_moe", "obs.spans",
+            },
+        ),
+    ],
+)
+def test_gated_delta_net_is_model_code_over_kernels(module: str, row: str, may_import: set) -> None:
+    """PR 56's two modules: the scalar-decay delta rule's kernels in the
+    kernels' row (sharing ``ops/kda.py``'s products and its triangular inverse
+    by import), the model in the models', calling ``LingHybrid``'s convolution
+    and unit length, ``WindowedMoE``'s rope, ``Llama``'s norm and projections,
+    and nothing of the Manager."""
+    assert [ROWS[i][0] for i in _rows_of(module)] == [row]
+    assert {target for importer, _line, target in _inner_edges() if importer == module} == may_import

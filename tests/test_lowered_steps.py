@@ -23,7 +23,10 @@ twelfth part) and changed none of the ten.  PR 53 wrote ONE anew
 (``--write --only indexed_sparse_moe``, whose ``plain`` digest came out the
 same: Keye's ``dsa_attn_*`` and ``dsa_probs`` launches walk their live blocks
 from ``flash_attention``'s tables) and no other changed: the flash models'
-steps are what they were.  A later change that means to alter one of these
+steps are what they were.  PR 56 ADDED ``gated_delta_moe``'s two (``--write
+--only gated_delta_moe``: ``RoutedExperts`` took a gate on the shared expert,
+``ops/gdn.py`` imports ``ops/kda.py``'s inverse) and changed none of the
+twelve: no other model's program moved.  A later change that means to alter one of these
 programs writes the fixture anew and says so: ``python
 tests/test_lowered_steps.py --write``."""
 
@@ -36,7 +39,7 @@ import sys
 import pytest
 
 FIXTURE = os.path.join(os.path.dirname(os.path.abspath(__file__)), "fixtures", "lowered_steps.json")
-MODELS = ("ling_hybrid", "indexed_sparse_moe", "llama", "ssm_hybrid_moe", "windowed_moe", "eva")
+MODELS = ("ling_hybrid", "indexed_sparse_moe", "llama", "ssm_hybrid_moe", "windowed_moe", "eva", "gated_delta_moe")
 CASES = [(m, p) for m in MODELS for p in ("plain", "kernels")]
 
 

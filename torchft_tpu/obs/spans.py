@@ -48,6 +48,9 @@ operations; these are the names the program gives out::
                                         one softmax, ``eva_attention``: chunk
                                         summaries and a window's tokens)
     kda_fwd, kda_bwd                   (ops/kda.py: the chunked delta rule)
+    gdn_fwd, gdn_bwd                   (ops/gdn.py: the same with one decay a
+                                        head, unbounded, and value heads that
+                                        share a key head)
     ...gmm..., ...tgmm...              (parallel/moe.py RoutedExperts: jax's
                                         megablox kernels, named after the
                                         jitted functions around them)
@@ -71,7 +74,11 @@ on an operation's path is its part::
                             its kernel (rope, q/k norms, the short convolution,
                             softplus and decays, gates, the gated norm, splits,
                             reshapes and their layout copies), and the plain
-                            path where no kernel runs
+                            path where no kernel runs; Gated DeltaNet's
+                            convolution over q, k and v at once, its unit q and
+                            k, ``softplus`` and the decay, the SiLU-gated head
+                            norm and the full layers' sigmoid gate are here
+                            (``models/gated_delta_moe.py``): no part is new
     tpuft.mixer_pool        a mixer's pooling of keys and values into chunk
                             summaries (``models/eva.py``): the scores against
                             the learned vector, the softmax over a chunk, the

@@ -196,6 +196,9 @@ class RoutedExpertsConfig:
     routed_scaling_factor: float = 1.0
     norm_topk_prob: bool = True
     shared_hidden: int = 0  # width of the shared expert; 0: none
+    # the shared expert behind a gate of its own, ``sigmoid(x . shared_sigmoid)``
+    # a token (Qwen3-Next); False: no such leaf, the shared expert added bare
+    gated_shared: bool = False
     balance_loss_weight: float = 0.0  # sequence-wise, arXiv:2412.19437 eq. 17-20
     # an expert's form, the routed experts' and the shared one's alike:
     # "swiglu", three matrices, ``w_down (silu(w_gate x) * (w_up x))``, or
@@ -360,7 +363,8 @@ class RoutedExperts:
     three matrices or a squared ReLU of two), whose work follows the rows
     really routed here (``megablox.gmm`` on the TPU: ``path`` "gmm"; ``lax.ragged_dot``
     elsewhere).  What the absent experts would have added is left out; the
-    shared expert is added once.  On one chip there is no exchange, and no
+    shared expert is added once, behind ``sigmoid(x . shared_sigmoid)`` a token
+    where ``gated_shared`` asks for that gate.  On one chip there is no exchange, and no
     code stands in for the absent chips.
 
     The buffer is small and the PASSES through it follow the load:
@@ -381,6 +385,8 @@ class RoutedExperts:
             raise ValueError(f"score_func {config.score_func!r} is neither sigmoid nor softmax")
         if config.expert_form not in ("swiglu", "relu2"):
             raise ValueError(f"expert_form {config.expert_form!r} is neither swiglu nor relu2")
+        if config.gated_shared and not config.shared_hidden:
+            raise ValueError("gated_shared gates a shared expert, and shared_hidden is 0")
         first, count = config.experts_held
         if first < 0 or count < 1 or first + count > config.num_experts:
             raise ValueError(f"experts_held {config.experts_held} outside 0..{config.num_experts}")
@@ -415,6 +421,9 @@ class RoutedExperts:
                 shared_up=normal(keys[5], (cfg.dim, cfg.shared_hidden), cfg.dim),
                 shared_down=normal(keys[6], (cfg.shared_hidden, cfg.dim), cfg.shared_hidden),
             )
+        if cfg.gated_shared:
+            # a key of its own: the seven above give every other leaf what they gave
+            params["shared_sigmoid"] = normal(jax.random.fold_in(key, 7), (cfg.dim,), cfg.dim, jnp.float32)
         if cfg.expert_form == "relu2":  # no gate matrix, in either kind of expert
             params = {k: v for k, v in params.items() if not k.endswith("_gate")}
         return params
@@ -426,6 +435,8 @@ class RoutedExperts:
         if cfg.selection_bias:
             specs["bias"] = P(None)
         specs.update({name: P(None, None) for name in self.shared_leaves})
+        if cfg.gated_shared:
+            specs["shared_sigmoid"] = P(None)
         return specs
 
     # ------------------------------------------------------------------
@@ -595,7 +606,14 @@ class RoutedExperts:
             with device_part("ffn"):
                 *w_in, w_down = (params[name] for name in self.shared_leaves)
                 act = self._activate([flat @ w for w in w_in], shared_swiglu_limit)
-                out = out + (act @ w_down).astype(jnp.float32)
+                shared = (act @ w_down).astype(jnp.float32)
+                if cfg.gated_shared:  # the gate reads x as the router does: as it is given, in float32
+                    gate = jnp.dot(
+                        x.reshape(B * S, D).astype(jnp.float32), params["shared_sigmoid"],
+                        precision=jax.lax.Precision.HIGHEST,
+                    )
+                    shared = shared * jax.nn.sigmoid(gate)[:, None]
+                out = out + shared
         balance = jnp.zeros((), jnp.float32)
         if cfg.balance_loss_weight:
             # per sequence: f_i the share of choices that fell on expert i
