@@ -140,8 +140,8 @@ int tpuft_comm_allreduce(void* h, void* data, uint64_t nbytes, int32_t dtype,
 // against these buffers, no staging concatenation on either side.
 // `divisor` (0 = none, OP_SUM alone): the buffers come back holding
 // SUM / divisor, divided inside the ring by each chunk's owner (comm.h
-// average_buffer), and not the sum.  `group` is the dtype group's index
-// within the caller's allreduce (its tag window).
+// reduce_buffer with the divisor), and not the sum.  `group` is the dtype
+// group's index within the caller's allreduce (its tag window).
 int tpuft_comm_allreduce_iov(void* h, void* const* bufs, const uint64_t* lens,
                              uint64_t n, int32_t dtype, int32_t op,
                              uint64_t divisor, uint64_t group) {

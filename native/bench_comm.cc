@@ -1,13 +1,18 @@
 // Standalone throughput benchmark for the native communicator (no Python):
 //   ./bench_comm            — forks store + 2 ranks, 256MB p2p + ring,
 //                             and the averaging ring beside the summing one
+//                             (the difference: what the division costs the
+//                             last reduce step's add on the lanes, no pass)
 //   ./bench_comm pieces [MB] — a step's gradient bytes (973 MB of bfloat16,
 //                             the one-chip two-group cell's) rung with the
 //                             divisor whole and in pieces of 64, 16 and 4
 //                             MiB, at the lanes TORCHFT_RING_LANES names
 // Both print, beside the wall time, where the ring says its time went
 // (comm.h EpochIO's seven counters of nanoseconds): the terms a traced
-// two-group cell reports as ring_rx_ms ... ring_tail_ms.
+// two-group cell reports as ring_rx_ms ... ring_tail_ms.  `average` is the
+// stand-alone division pass, which a ring of two never takes: it reads 0.0
+// and the division lies in a lane's `add` (the before/after of moving it
+// there is `pieces` from the two commits' binaries).
 #include <sys/wait.h>
 #include <unistd.h>
 
@@ -99,9 +104,10 @@ static void run_rank(const std::string& store_addr, int rank) {
               buf.size() * 4.0 / dt / 1e9);
 
   // The ring that hands back the average beside the one that hands back the
-  // sum, 256MB of bfloat16 (a gradient bucket): what the owner's one pass
-  // over its half costs between the phases.  Best of kRounds each,
-  // interleaved.
+  // sum, 256MB of bfloat16 (a gradient bucket): what the division adds to
+  // the last reduce step's add (a true division an element of the owned
+  // half, on the lanes' threads beside their recv), no pass of its own.
+  // Best of kRounds each, interleaved.
   constexpr int kRounds = 5;
   const size_t M = 128ull << 20;  // elements
   std::vector<uint16_t> grad(M);
@@ -124,7 +130,8 @@ static void run_rank(const std::string& store_addr, int rank) {
     if (got[1] < best[1]) best_times = RingTimes::read(comm).since(before);
     for (int k = 0; k < 2; ++k) best[k] = std::min(best[k], got[k]);
   }
-  std::printf("rank %d ring 256MB bf16: sum %.3fs, average %.3fs (+%.0f ms)\n",
+  std::printf("rank %d ring 256MB bf16: sum %.3fs, average %.3fs (%+.0f ms: "
+              "the division inside the add)\n",
               rank, best[0], best[1], (best[1] - best[0]) * 1e3);
   best_times.print(rank, "ring 256MB bf16 average", best[1]);
   std::fflush(stdout);  // the forked rank leaves by _exit

@@ -35,6 +35,7 @@
 #include <mutex>
 #include <string>
 #include <thread>
+#include <type_traits>
 #include <vector>
 
 #include "store.h"
@@ -84,6 +85,83 @@ inline uint16_t f32_to_bf16(float f) {
   return static_cast<uint16_t>((bits + rounding) >> 16);
 }
 
+// --- the average -------------------------------------------------------------
+//
+// SUM / n, bit for bit what communicator._div makes of a ring's sum: the sum
+// rounded to the buffer's dtype as the ring leaves it, then a TRUE division
+// (never a product with a reciprocal: 3 and 5 are no powers of two) in
+// float32 for bfloat16 and float32, in float64 for float64, rounded to
+// nearest even; integers FLOOR-divide (C++'s `/` truncates towards zero).
+// A bfloat16 NaN comes out as the one NaN of its sign, as ml_dtypes' cast
+// makes it (f32_to_bf16 would keep the payload).
+//
+// One functor a dtype, `sum` the ring's add of two elements and `()` the
+// quotient of a sum: average_buffer's pass (a ring of one member) and
+// reduce_buffer's add with a divisor (the last reduce step of any other ring)
+// both call it, so the two cannot drift.
+
+inline uint16_t bf16_quotient(float sum, float n) {
+  float q = sum / n;
+  uint32_t bits;
+  std::memcpy(&bits, &q, 4);
+  // a select and no branch: the loops around it vectorise
+  uint16_t nan = static_cast<uint16_t>(0x7FC0 | (bits >> 16 & 0x8000));
+  return q != q ? nan : f32_to_bf16(q);
+}
+
+template <typename T>
+struct Quotient {
+  using Elem = T;
+  using Wide = std::conditional_t<std::is_floating_point<T>::value, T, int64_t>;
+  Wide d;
+  explicit Quotient(uint64_t divisor) : d(static_cast<Wide>(divisor)) {}
+  static T sum(T a, T b) { return static_cast<T>(a + b); }
+  T operator()(T sum) const {
+    if constexpr (std::is_floating_point<T>::value) {
+      return sum / d;
+    } else {
+      Wide s = sum;
+      return static_cast<T>(s / d - (s % d < 0));  // d > 0: the floor
+    }
+  }
+};
+
+struct Bf16Quotient {
+  using Elem = uint16_t;
+  float d;
+  explicit Bf16Quotient(uint64_t divisor) : d(static_cast<float>(divisor)) {}
+  static uint16_t sum(uint16_t a, uint16_t b) {
+    return f32_to_bf16(bf16_to_f32(a) + bf16_to_f32(b));
+  }
+  uint16_t operator()(uint16_t sum) const {
+    return bf16_quotient(bf16_to_f32(sum), d);
+  }
+};
+
+// f(the dtype's functor)
+template <typename F>
+inline void with_quotient(DType dt, uint64_t divisor, F f) {
+  switch (dt) {
+    case DT_F32: return f(Quotient<float>(divisor));
+    case DT_F64: return f(Quotient<double>(divisor));
+    case DT_I32: return f(Quotient<int32_t>(divisor));
+    case DT_I64: return f(Quotient<int64_t>(divisor));
+    case DT_I8: return f(Quotient<int8_t>(divisor));
+    case DT_U8: return f(Quotient<uint8_t>(divisor));
+    case DT_BF16: return f(Bf16Quotient(divisor));
+  }
+}
+
+// buf = buf / divisor, in one pass
+inline void average_buffer(void* buf, size_t nbytes, DType dt,
+                           uint64_t divisor) {
+  with_quotient(dt, divisor, [&](auto quot) {
+    using T = typename decltype(quot)::Elem;
+    T* a = static_cast<T*>(buf);
+    for (size_t i = 0, n = nbytes / sizeof(T); i < n; ++i) a[i] = quot(a[i]);
+  });
+}
+
 template <typename T>
 inline void reduce_typed(T* acc, const T* in, size_t n, RedOp op) {
   switch (op) {
@@ -99,8 +177,21 @@ inline void reduce_typed(T* acc, const T* in, size_t n, RedOp op) {
   }
 }
 
+// acc ?= in.  With a divisor (OP_SUM alone: the ring's add that completes a
+// sum) acc = (acc + in) / divisor, element for element what average_buffer
+// makes of the plain add's result, in the one pass.
 inline void reduce_buffer(void* acc, const void* in, size_t nbytes, DType dt,
-                          RedOp op) {
+                          RedOp op, uint64_t divisor = 0) {
+  if (divisor) {
+    with_quotient(dt, divisor, [&](auto quot) {
+      using T = typename decltype(quot)::Elem;
+      T* a = static_cast<T*>(acc);
+      const T* b = static_cast<const T*>(in);
+      for (size_t i = 0, n = nbytes / sizeof(T); i < n; ++i)
+        a[i] = quot(quot.sum(a[i], b[i]));
+    });
+    return;
+  }
   switch (dt) {
     case DT_F32:
       reduce_typed(static_cast<float*>(acc), static_cast<const float*>(in),
@@ -146,73 +237,6 @@ inline void reduce_buffer(void* acc, const void* in, size_t nbytes, DType dt,
 struct CommError : std::runtime_error {
   using std::runtime_error::runtime_error;
 };
-
-// --- the average -------------------------------------------------------------
-//
-// SUM / n, bit for bit what communicator._div makes of a ring's sum: the sum
-// rounded to the buffer's dtype as the ring leaves it, then a TRUE division
-// (never a product with a reciprocal: 3 and 5 are no powers of two) in
-// float32 for bfloat16 and float32, in float64 for float64, rounded to
-// nearest even; integers FLOOR-divide (C++'s `/` truncates towards zero).
-// A bfloat16 NaN comes out as the one NaN of its sign, as ml_dtypes' cast
-// makes it (f32_to_bf16 would keep the payload).
-
-inline uint16_t bf16_quotient(float sum, float n) {
-  float q = sum / n;
-  if (q != q) return std::signbit(q) ? 0xFFC0 : 0x7FC0;
-  return f32_to_bf16(q);
-}
-
-template <typename T, typename Q>
-inline void average_typed(void* buf, size_t n, Q quot) {
-  T* a = static_cast<T*>(buf);
-  for (size_t i = 0; i < n; ++i) a[i] = quot(a[i]);
-}
-
-template <typename T>
-inline void average_ints(void* buf, size_t n, int64_t d) {
-  average_typed<T>(buf, n, [d](T sum) {
-    int64_t s = static_cast<int64_t>(sum);
-    return static_cast<T>(s / d - (s % d < 0));  // d > 0: the floor
-  });
-}
-
-// buf = buf / divisor, in one pass
-inline void average_buffer(void* buf, size_t nbytes, DType dt,
-                           uint64_t divisor) {
-  switch (dt) {
-    case DT_F32: {
-      float d = static_cast<float>(divisor);
-      average_typed<float>(buf, nbytes / 4, [d](float sum) { return sum / d; });
-      break;
-    }
-    case DT_F64: {
-      double d = static_cast<double>(divisor);
-      average_typed<double>(buf, nbytes / 8,
-                            [d](double sum) { return sum / d; });
-      break;
-    }
-    case DT_I32:
-      average_ints<int32_t>(buf, nbytes / 4, divisor);
-      break;
-    case DT_I64:
-      average_ints<int64_t>(buf, nbytes / 8, divisor);
-      break;
-    case DT_I8:
-      average_ints<int8_t>(buf, nbytes, divisor);
-      break;
-    case DT_U8:
-      average_ints<uint8_t>(buf, nbytes, divisor);
-      break;
-    case DT_BF16: {
-      float d = static_cast<float>(divisor);
-      average_typed<uint16_t>(buf, nbytes / 2, [d](uint16_t sum) {
-        return bf16_quotient(bf16_to_f32(sum), d);
-      });
-      break;
-    }
-  }
-}
 
 // --- network emulation (mirror of communicator._NetEmu) ---------------------
 //
@@ -580,15 +604,16 @@ class ScatterView {
     return nullptr;
   }
 
-  // acc[off : off+len] ?= src, segment crossings handled (boundaries are
-  // element-aligned by construction)
-  void reduce_in(size_t off, const void* src, size_t len, DType dt, RedOp op) {
+  // acc[off : off+len] ?= src (reduce_buffer; `divisor` is its), segment
+  // crossings handled (boundaries are element-aligned by construction)
+  void reduce_in(size_t off, const void* src, size_t len, DType dt, RedOp op,
+                 uint64_t divisor = 0) {
     const uint8_t* s = static_cast<const uint8_t*>(src);
     size_t i = seg_at(off);
     while (len > 0) {
       size_t seg_off = off - starts_[i];
       size_t take = std::min(segs_[i].second - seg_off, len);
-      reduce_buffer(segs_[i].first + seg_off, s, take, dt, op);
+      reduce_buffer(segs_[i].first + seg_off, s, take, dt, op, divisor);
       s += take;
       off += take;
       len -= take;
@@ -821,7 +846,7 @@ constexpr uint64_t kLaneHelloFlag = uint64_t(1) << 63;
 constexpr uint64_t kRingReduceTagBase = 30000;
 
 // An allreduce that hands back the AVERAGE (a divisor: the owner of a chunk
-// divides it between the two phases) frames BOTH phases in a window of its
+// divides it before the allgather phase) frames BOTH phases in a window of its
 // own — mirror of wire.RING_AVG_TAG_BASE.  A ring in which one rank divides
 // and its peer expects sums would hand every rank sums for some chunks and
 // averages for others, silently: so a peer that sums (or predates the
@@ -902,13 +927,14 @@ struct EpochIO {
   // peer AND the kernel's copy out of the socket: one syscall, not told
   // apart), inside the reduce's add, and its sender inside sendmsg and the
   // pacing.  The op thread: its wall time in the ring's reduce-scatter
-  // phase, in the owner's division between the phases, in the allgather
-  // phase, and, of a phase's steps, from its own part of the receive
-  // returning to the other lanes' parts and its own send having landed
-  // (the tail).  Lanes run beside each other, so a lane's seconds are a
-  // share of the phases' and the tail lies inside them.  Only frames on
-  // the TCP lanes count under a lane; a leg another transport carries lies
-  // in the phases alone.
+  // phase, in the stand-alone division pass (rings of one member: every
+  // other ring divides in its last reduce step's add, on the lanes, and
+  // adds nothing here), in the allgather phase, and, of a phase's steps,
+  // from its own part of the receive returning to the other lanes' parts
+  // and its own send having landed (the tail).  Lanes run beside each
+  // other, so a lane's seconds are a share of the phases' and the tail lies
+  // inside them.  Only frames on the TCP lanes count under a lane; a leg
+  // another transport carries lies in the phases alone.
   std::unique_ptr<std::atomic<uint64_t>[]> rx_ns, add_ns, tx_ns;
   std::atomic<uint64_t> reduce_ns{0}, average_ns{0}, gather_ns{0}, tail_ns{0};
 
@@ -1363,15 +1389,14 @@ class Communicator {
                          uint64_t divisor = 0, uint64_t tag_base = 0) {
     if (divisor && op != OP_SUM)
       throw CommError("an allreduce's divisor goes with OP_SUM alone");
-    if (divisor == 1) divisor = 0;  // the sum is the average: no pass
+    if (divisor == 1) divisor = 0;  // the sum is the average: no division
     size_t esz = dtype_size(dt);
-    auto average = [&](size_t off, size_t len) {
-      NsTimer timed(&io->average_ns);
-      for (const struct iovec& seg : view.slice(off, len))
-        average_buffer(seg.iov_base, seg.iov_len, dt, divisor);
-    };
     if (ring.size() <= 1) {
-      if (divisor) average(0, view.size());
+      if (divisor) {  // no add to divide in: the stand-alone pass
+        NsTimer timed(&io->average_ns);
+        for (const struct iovec& seg : view.slice(0, view.size()))
+          average_buffer(seg.iov_base, seg.iov_len, dt, divisor);
+      }
       return;
     }
     auto deadline = deadline_in(timeout_s_);
@@ -1386,17 +1411,16 @@ class Communicator {
     // silent cross-tier corruption the constant-fill interop test never
     // saw (mixed-tier bit-identity tests now pin this).
     //
-    // With a divisor the owner of a chunk divides it between the phases (one
-    // pass over 1/ws of the payload, done once a ring and not once a rank),
-    // so the allgather phase carries averages.  The Python tier divides at
-    // the same point (_ring_allreduce): mixed tiers ride one ring.
+    // With a divisor the owner of a chunk divides it (1/ws of the payload,
+    // once a ring and not once a rank) in the add that completes its sum,
+    // the reduce phase's last step on the lanes' threads, so the allgather
+    // phase carries averages.  The Python tier divides the same sums in a
+    // pass of numpy's between the phases (_ring_allreduce): the tiers differ
+    // in HOW and not in WHAT, the bytes are the same and mixed tiers ride
+    // one ring.
     if (divisor) tag_base += kRingAvgTagBase;
     ring_reduce_phase(io, view, bounds, esz, dt, op, /*shift=*/-1, deadline,
-                      ring, tag_base);
-    if (divisor) {
-      size_t own = static_cast<size_t>(ring_pos(ring, io->rank));
-      average(bounds[own] * esz, (bounds[own + 1] - bounds[own]) * esz);
-    }
+                      ring, tag_base, divisor);
     ring_allgather_phase(io, view, bounds, esz, /*shift=*/-1, deadline, ring,
                          tag_base);
   }
@@ -1902,7 +1926,7 @@ class Communicator {
   TimePoint recv_striped_reduce(EpochIO& io, const std::vector<int>& fds,
                                 int64_t peer, uint64_t tag, ScatterView& view,
                                 size_t off, size_t nbytes, DType dt, RedOp op,
-                                TimePoint deadline,
+                                uint64_t divisor, TimePoint deadline,
                                 std::vector<std::vector<uint8_t>>& scratches) {
     auto parts = io.lane_parts(nbytes);
     // per-lane scratch from the caller's pool (grown once, reused across
@@ -1920,7 +1944,7 @@ class Communicator {
                      recv_framed_reduce(io, fds[lane], peer, tag, view,
                                         off + s, e - s,
                                         scratches[lane].data(), dt, op,
-                                        deadline, lane);
+                                        divisor, deadline, lane);
                    });
   }
 
@@ -1951,12 +1975,13 @@ class Communicator {
   // up owning the fully-reduced chunk (pos + 1 + s) mod ws.  The (memory-
   // bound) reduction rides under the wire via quantum-pipelined recv; the
   // send leg runs on the per-lane tx workers, the recv leg on the calling
-  // thread + rx workers.
+  // thread + rx workers.  With a `divisor` the LAST step's add, the one that
+  // completes the owned chunk's sum at any ring size, divides it too.
   void ring_reduce_phase(IoPtr io, ScatterView& view,
                          const std::vector<size_t>& bounds, size_t esz,
                          DType dt, RedOp op, int64_t shift,
                          TimePoint deadline, const std::vector<int64_t>& ring,
-                         uint64_t tag_base) {
+                         uint64_t tag_base, uint64_t divisor = 0) {
     NsTimer timed(&io->reduce_ns);
     int64_t ws = static_cast<int64_t>(ring.size());
     int64_t pos = ring_pos(ring, io->rank);
@@ -1984,8 +2009,8 @@ class Communicator {
       try {
         own_done = recv_striped_reduce(
             *io, left_fds, left, tag_base + 1000 + step, view,
-            chunk_off(recv_idx), chunk_bytes(recv_idx), dt, op, deadline,
-            scratches);
+            chunk_off(recv_idx), chunk_bytes(recv_idx), dt, op,
+            step == ws - 2 ? divisor : 0, deadline, scratches);
       } catch (...) {
         send_latch->wait_quiet();
         throw;
@@ -2047,7 +2072,7 @@ class Communicator {
   void recv_framed_reduce(EpochIO& io, int fd, int64_t peer, uint64_t tag,
                           ScatterView& view, size_t dst_off, size_t nbytes,
                           uint8_t* scratch, DType dt, RedOp op,
-                          TimePoint deadline, size_t lane) {
+                          uint64_t divisor, TimePoint deadline, size_t lane) {
     static constexpr size_t kQuantum = size_t(4) << 20;
     std::atomic<uint64_t>* rx_ns = io.lane_ns(io.rx_ns, lane);
     std::atomic<uint64_t>* add_ns = io.lane_ns(io.add_ns, lane);
@@ -2073,7 +2098,7 @@ class Communicator {
       size_t take = std::min(quantum, nbytes - off);
       recv_loop(io, fd, peer, scratch, take, deadline, lane);
       lap(rx_ns);
-      view.reduce_in(dst_off + off, scratch, take, dt, op);
+      view.reduce_in(dst_off + off, scratch, take, dt, op, divisor);
       lap(add_ns);
       off += take;
     }

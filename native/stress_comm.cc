@@ -134,10 +134,11 @@ void phase_a_rank(Communicator* comm, int rank, const std::string& store_addr) {
       }
     }
 
-    // allreduce with a divisor: the owner of a chunk divides it between the
-    // phases, the allgather carries averages (send workers reading what the
-    // op thread has just written); float32 and, over two scattered segments,
-    // bfloat16 (3 is no power of two: a reciprocal would round otherwise)
+    // allreduce with a divisor: the owner of a chunk divides it in the last
+    // reduce step's add, the allgather carries averages (send workers reading
+    // what the rx lanes have just written); float32 and, over two scattered
+    // segments, bfloat16 (3 is no power of two: a reciprocal would round
+    // otherwise)
     std::fill(buf.begin(), buf.end(), static_cast<float>(rank + 1));
     comm->allreduce(buf.data(), buf.size() * 4, DT_F32, OP_SUM, /*divisor=*/3);
     for (size_t i = 0; i < buf.size(); ++i) {

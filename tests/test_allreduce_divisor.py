@@ -2,15 +2,19 @@
 bit for bit ``_div(the ring's sum, n)``, whichever tier divides.
 
 The rank that owns a chunk at the end of the reduce phase divides it before
-the allgather phase sends it round: ``native/comm.h`` ``average_buffer``
-over the owned chunk, ``communicator._ring_allreduce`` with ``_div`` on the
-owned view.  One parametrised test holds the two to the same bits on
-every dtype the Manager averages, for divisors that are no power of two and
-one that is no bfloat16, in rings of one, two and three whose size does not
-divide the element count, on each tier and on a ring of one of each; the
-rest is the contract's edges: a passthrough never writes what it was handed,
-and a peer that expects sums fails the op and the vote."""
+the allgather phase sends it round: ``native/comm.h`` in the add of the
+reduce phase's last step (``reduce_buffer`` with the divisor; a ring of one
+member in ``average_buffer``'s pass), ``communicator._ring_allreduce`` with
+``_div`` on the owned view between the phases.  One parametrised test holds
+the two to the same bits on every dtype the Manager averages, for divisors
+that are no power of two and one that is no bfloat16, in rings of one, two
+and three whose size does not divide the element count and a native one of
+four (three reduce steps: a division in any but the last is off by a
+factor), on each tier and on a ring of one of each; the rest is the
+contract's edges: a passthrough never writes what it was handed, and a peer
+that expects sums fails the op and the vote."""
 
+import ctypes
 from typing import Any, Callable, List
 
 import ml_dtypes
@@ -106,6 +110,8 @@ def _tiers(tier: str, world: int) -> List[str]:
 
 
 RINGS = [(tier, world) for tier in ("cpp", "python", "mixed") for world in (1, 2, 3) if (tier, world) != ("mixed", 1)]
+# four native members: three reduce steps, of which the LAST alone divides
+RINGS.append(("cpp", 4))
 
 
 @pytest.mark.parametrize("n", [2, 3, 5, 257])
@@ -164,6 +170,68 @@ def test_divisor_on_a_striped_ring_of_many_quanta(store, tier: str, world: int, 
         return True
 
     assert all(_run(store, _tiers(tier, world), _ops, f"big_{tier}_{world}_{lanes}", timeout_s=60.0))
+
+
+@pytest.mark.parametrize("lanes", [1, 4])
+@pytest.mark.parametrize("tier,world", [("cpp", 2), ("cpp", 3), ("mixed", 2), ("mixed", 3)])
+def test_divisor_where_a_quantum_straddles_a_segment_boundary(store, tier: str, world: int, lanes: int, monkeypatch) -> None:
+    """Three float32 arrays of 6, 5.2 and 6.8 MB in one call, scattered
+    segments of one ring on the native tier: every owned chunk holds several
+    4 MiB quanta a lane at one lane, and at either lane count a boundary
+    between two arrays lies INSIDE a quantum of the last reduce step, so the
+    add that divides is cut in two there and each part must divide."""
+    monkeypatch.setenv("TORCHFT_RING_LANES", str(lanes))
+    monkeypatch.setenv("TORCHFT_RING_FRAME_KB", "64")
+
+    def _ops(comm, rank):
+        data = [_contribution(DTYPES["float32"], rank, n) for n in (1_500_001, 1_300_003, 1_700_001)]
+        with np.errstate(all="ignore"):
+            summed = comm.allreduce(_copy(data), ReduceOp.SUM).wait(timeout=60.0)
+            want = [_div(a, 3) for a in summed]
+        got = comm.allreduce(data, ReduceOp.SUM, in_place=True, divisor=3).wait(timeout=60.0)
+        for w, g in zip(want, got):
+            assert g.tobytes() == w.tobytes()
+        return True
+
+    assert all(_run(store, _tiers(tier, world), _ops, f"straddle_{tier}_{world}_{lanes}", timeout_s=60.0))
+
+
+def _ring_seconds(comm) -> dict:
+    """The op thread's seconds in the stand-alone division pass and all lanes'
+    in the reduce's add: ``lane_stats()``'s, and for a world of one member,
+    which it hands nothing, the native epoch's counters themselves."""
+    stats = comm.lane_stats()
+    if stats:
+        return {"ring_average_s": stats["ring_average_s"], "lane_add_s": sum(stats["lane_add_s"])}
+    cap = 64
+    lane = [(ctypes.c_uint64 * cap)() for _ in range(6)]
+    ring_ns, floor = (ctypes.c_uint64 * 4)(), ctypes.c_uint64()
+    comm._lib.tpuft_comm_lane_stats(comm._h, *lane, cap, ctypes.byref(floor), ring_ns)
+    return {"ring_average_s": ring_ns[1] / 1e9, "lane_add_s": sum(lane[4]) / 1e9}
+
+
+@pytest.mark.parametrize("world", [1, 2, 3])
+def test_the_native_ring_divides_in_its_add_and_a_ring_of_one_in_a_pass(store, world: int) -> None:
+    """The account that says where the division ran: a native ring of two or
+    three members that averages adds nothing to ``ring_average_s`` (the
+    stand-alone pass) and grows ``lane_add_s`` (the last reduce step's add
+    divides); a ring of ONE member has no add, and its pass is counted."""
+
+    def _ops(comm, rank):
+        data = _contribution(BF16, rank, 2_000_003)
+        comm.allreduce(data.copy(), ReduceOp.SUM).wait(timeout=30.0)
+        before = _ring_seconds(comm)
+        comm.allreduce(data, ReduceOp.SUM, in_place=True, divisor=3).wait(timeout=30.0)
+        after = _ring_seconds(comm)
+        if world == 1:
+            assert after["ring_average_s"] > before["ring_average_s"] == 0.0
+            assert after["lane_add_s"] == 0.0
+        else:
+            assert after["ring_average_s"] == before["ring_average_s"] == 0.0
+            assert after["lane_add_s"] > before["lane_add_s"] > 0.0
+        return True
+
+    assert all(_run(store, ["cpp"] * world, _ops, f"account_{world}"))
 
 
 @pytest.mark.parametrize("tier,world", RINGS)

@@ -740,7 +740,8 @@ class TestNativeLaneStats:
         """``lane_stats()``'s seven counters of seconds (``RING_TIME_KEYS``),
         the same in both tiers and across a mixed pair: there, monotone,
         the op thread's three phases inside the wall time of the calls, the
-        add counted by a reduce alone and the division by a divisor alone."""
+        add counted by a reduce alone and the division by a divisor alone: in
+        a pass of its own on the Python tier, inside the add on the native."""
         from torchft_tpu.communicator import RING_TIME_KEYS
 
         monkeypatch.setenv("TORCHFT_RING_LANES", "2")
@@ -760,7 +761,8 @@ class TestNativeLaneStats:
             np.testing.assert_array_equal(np.asarray(out), np.full(n, 1.5, np.float32))
             return seen, wall
 
-        for seen, wall in _run_mixed_ranks(cpp_store, 2, cpp_ranks, _fn, "times"):
+        ranks = _run_mixed_ranks(cpp_store, 2, cpp_ranks, _fn, "times")
+        for rank, (seen, wall) in enumerate(ranks):
             fresh, gathered, summed, averaged = seen
             phases = ("ring_reduce_s", "ring_average_s", "ring_gather_s")
             for stats in seen:
@@ -780,8 +782,11 @@ class TestNativeLaneStats:
             assert summed["ring_reduce_s"] > 0.0 and summed["ring_gather_s"] > 0.0
             assert all(v > 0.0 for v in summed["lane_add_s"])
             assert summed["ring_tail_s"] > 0.0 and summed["ring_average_s"] == 0.0
-            # a ring that averages: the owner's division between the phases
-            assert averaged["ring_average_s"] > 0.0
+            # a ring that averages: the owner's division, between the phases
+            # on the Python tier and in the last reduce step's add on the
+            # native one, whose stand-alone pass no ring of two takes
+            assert (averaged["ring_average_s"] == 0.0) == (rank in cpp_ranks)
+            assert all(a > s for a, s in zip(averaged["lane_add_s"], summed["lane_add_s"]))
             # the op thread's phases lie in the calls, the tail in the phases
             assert sum(averaged[k] for k in phases) <= wall
             assert averaged["ring_tail_s"] <= (

@@ -158,7 +158,8 @@ def _reduce_into(op: ReduceOp, acc: np.ndarray, incoming: np.ndarray) -> None:
 
 # ``lane_stats()``'s seconds, the same in every tier that has lanes: a lane's
 # in recv, in the reduce's add and in send (lists), then the op thread's in a
-# ring's reduce-scatter phase, the division between the phases, the
+# ring's reduce-scatter phase, the division's own pass (between the phases
+# here; the native tier's rings divide in their last add and count none), the
 # allgather phase and the steps' tails (``TCPCommunicator.lane_stats``)
 RING_TIME_KEYS = (
     "lane_rx_s",
@@ -2955,7 +2956,9 @@ class TCPCommunicator(Communicator):
         alone and the wait for the peer lies in ``select``, under no lane
         (the native tier's threads wait inside ``::recv``).  The op thread:
         ``ring_reduce_s`` its wall time in a ring's reduce-scatter phase,
-        ``ring_average_s`` in the owner's division between the phases,
+        ``ring_average_s`` in the owner's division between the phases (a
+        pass of numpy's; on the native tier the stand-alone division pass:
+        rings of one member, every other ring divides in its last add),
         ``ring_gather_s`` in the allgather phase, ``ring_tail_s``, of the
         phases' steps, from the first lane's part of the receive being
         whole and reduced to the step's end.  Where another transport

@@ -628,9 +628,12 @@ def _ring_account(before: Dict[str, Any], after: Dict[str, Any]) -> Dict[str, An
     the round trip (lanes run beside each other on equal parts, so the mean
     is a lane's share of the wall); ``ring_reduce_s``, ``ring_average_s``,
     ``ring_gather_s``, ``ring_tail_s``: the op thread's in the two phases, the
-    division between them and the steps' tails.  A reconfiguration in between
-    starts the counts anew: such a round trip records no bytes and no
-    seconds, and a communicator that counts no time records none."""
+    division's own pass (the Python tier's between them; on the native tier the
+    stand-alone division pass: rings of one member, so 0 here while
+    ``ring_add_s`` carries the division) and the steps' tails.  A
+    reconfiguration in between starts the counts anew: such a round trip
+    records no bytes and no seconds, and a communicator that counts no time
+    records none."""
     account: Dict[str, Any] = {"ring_bytes": 0, "striped_bytes": 0}
     if before["epoch"] != after["epoch"]:
         return account

@@ -852,8 +852,10 @@ class CppCommunicator(Communicator):
         add, ``lane_tx_s`` its sender inside ``sendmsg`` and the pacing.
         The op thread, on the clock of ``tpuft/comm/op``:
         ``ring_reduce_s`` its wall time in the ring's reduce-scatter
-        phase, ``ring_average_s`` in the owner's division between the
-        phases, ``ring_gather_s`` in the allgather phase, and
+        phase, ``ring_average_s`` in the stand-alone division pass (rings
+        of one member alone: every other ring divides in its last reduce
+        step's add, under ``lane_add_s``, and adds nothing here),
+        ``ring_gather_s`` in the allgather phase, and
         ``ring_tail_s``, of the phases' steps, from its own part of a
         receive returning to the other lanes' parts and its own send having
         landed.  Lanes run beside each other, so a lane's seconds are a
