@@ -222,7 +222,8 @@ class dsa_flops:
         """Operations of ``L_I`` and its gradient of one step, all layers,
         a picked pair: the head-mean needs QK^T again (``2 D`` a head), the
         index's scores once more forward and twice backward (the pass
-        runs twice a step, which is not credited)."""
+        runs once a step and layer since PR 34; the second run before it
+        was never credited)."""
         per_pair = 2.0 * s["head_dim"] * s["n_heads"] + 3 * 2.0 * s["index_heads"] * s["index_head_dim"]
         return s["n_layers"] * per_pair * dsa_flops.picked_pairs(s, seq) * rows
 

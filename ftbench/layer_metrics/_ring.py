@@ -1,9 +1,11 @@
-"""Shared by the seven readers of where the ring says its time goes (no metric
+"""Shared by the six readers of where the ring says its time goes (no metric
 itself: ``BENCHMARK.json`` names no ``_ring``).  The communicator counts
 seconds beneath ``tpuft/comm/op``, a lane's inside recv, inside the reduce's
-add and inside send, the op thread's in the ring's two phases, in the division
-between them and in the steps' tails (``native/comm.h`` ``EpochIO``,
-``lane_stats()``); ``Manager.ring_counters()`` is read before a round trip's
+add and inside send, the op thread's in the ring's two phases and in the
+steps' tails (``native/comm.h`` ``EpochIO``, ``lane_stats()``; also in a
+stand-alone division between the phases, which since PR 57 only a ring of one
+member takes: ``ring_average_s``, read by nobody);
+``Manager.ring_counters()`` is read before a round trip's
 first submit and after its last ring, and ``ddp.allreduce_pytree`` puts the
 differences on the span ``tpuft/ddp/allreduce_pytree`` and so on its flight
 event DDP_SYNC.  A reader takes replica (or group) 0's DDP_SYNC events of the

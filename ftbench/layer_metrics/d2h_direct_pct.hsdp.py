@@ -11,8 +11,10 @@ chip holds whole (Mistral's float32 norms), which have no shards to write.
 0 where the events carry no such counter (a program from before PR 44 makes
 every leaf whole on the host), None where there is no event in the window.
 
-Named ``.hsdp`` as the four-chip cell's other readers are (README.md, "On
-four chips"): only a group of several chips has sharded leaves to read."""
+It keeps the suffix ``.hsdp`` that PR 43 to 57 gave the four-chip cell's twins
+(README.md, "On four chips"): only a group of several chips has sharded leaves
+to read, so it has no namesake to fold into, and two tests under ``tests/``
+load it by this name."""
 
 META = dict(source="program_counter", layer="device-host boundary", unit="%", moves="ddp_tokens_per_s_per_chip")
 

@@ -2,8 +2,9 @@
 ``dsa_probs`` of ``ops/indexed_attention.py`` (the head-mean of the
 attention's softmax over the picked keys, from its ``lse``, against the
 index's softmax, with the gradient to the index's three operands in the same
-pass; twice a step, the second time to rematerialise the layer), by the name
-its ``pallas_call`` carries in the trace.  None on a program without it."""
+pass; once a step and layer since PR 34, twice before, the second time to
+rematerialise the layer), by the name its ``pallas_call`` carries in the
+trace: every such event there is.  None on a program without it."""
 
 META = dict(source="device_trace", layer="kernels", unit="ms", moves="tokens_per_s_per_chip")
 

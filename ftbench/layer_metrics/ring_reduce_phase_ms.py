@@ -1,8 +1,8 @@
 """Host data plane, milliseconds a step: the op thread's wall time in the
 rings' reduce-scatter phase (``ring_reduce_phase``: a send on the tx workers
-beside a receive that adds as it lands), summed over a step's rings.  With
-``ring_average_ms`` and ``ring_gather_phase_ms`` it is ``comm_op_ms`` less the
-binding's own time an op.  DDP_SYNC's ``ring_reduce_s`` (``_ring.py`` says
+beside a receive that adds as it lands, and in its last step divides),
+summed over a step's rings.  With ``ring_gather_phase_ms`` it is
+``comm_op_ms`` less the binding's own time an op.  DDP_SYNC's ``ring_reduce_s`` (``_ring.py`` says
 where it is counted and which events are read); None on a program whose events
 carry no such field."""
 

@@ -1,8 +1,10 @@
 """Host data plane: spans ``tpuft/manager/normalize`` (one a collective) on
-replica 0's communicator op thread: ``Manager.allreduce`` turning the ring's
-sum into the average, in the future's done-callback, so the next bucket's
-ring waits for it.  Summed over a step's collectives, mean over the traced
-steps."""
+replica 0's communicator op thread: ``Manager.allreduce``'s done-callback,
+which the next bucket's ring waits for.  Until PR 40 it turned the ring's sum
+into the average; since then the ring is handed the divisor and returns the
+average, and the callback divides only what still comes back as sums (the
+quantized ring, ``in_ring=0``): the callback's microseconds in both two-group
+cells.  Summed over a step's collectives, mean over the traced steps."""
 
 META = dict(source="program_span", layer="host data plane", unit="ms", moves="ddp_tokens_per_s_per_chip")
 

@@ -186,19 +186,26 @@ READINGS = {
     "bucket_copy_ms": 200.0, "h2d_restore_ms": 72.0, "comm_op_ms": 140.0, "ring_peer_skew_ms": 35.0,
 }
 
-# PR 43, the four-chip cell ``mistral7b-hsdp2x2-steady``.  Tier-1's view of
-# this file (``tests/test_ftbench_program_spans.py``) holds the lists of these
-# thirteen readers to ONE cell, and a ``benchmark`` PR may edit nothing under
-# ``tests/``: each reads the four-chip cell under ``<name>.hsdp``, a file that
-# names the reader it is (README.md, "On four chips")
+# PR 43, the four-chip cell ``mistral7b-hsdp2x2-steady``.  These thirteen
+# readers stand ONCE and list both two-group cells (PR 58; PR 43 to 57 the
+# four-chip cell read each under a twin ``<name>.hsdp``, because tier-1 then
+# held these lists to one cell): a reader reads replica or group 0 in whichever
+# cell it runs
 HSDP_CELL = "mistral7b-hsdp2x2-steady"
-HSDP_TWINS = tuple(sorted(READINGS)) + (
+TWO_GROUP_CELLS = ("mistral7b-ddp2-steady", HSDP_CELL)
+TWO_GROUP_READERS = tuple(sorted(READINGS)) + (
     "sync_normalize_ms", "bucket_warm_pct", "sync_first_submit_ms", "ring_beside_d2h_pct", "normalize_in_ring_pct",
 )
+# the one reader of the four-chip cell alone (a group of one chip has no shard
+# to write): it keeps the suffix, which two tests under ``tests/`` load it by
+HSDP_ONLY = "d2h_direct_pct.hsdp"
+# what PR 58 retired: the thirteen twins, and two readers that told nothing more
+# (README.md, "On four chips"; PERF.md section 6, PR 58)
+RETIRED = tuple(name + ".hsdp" for name in TWO_GROUP_READERS) + ("ring_ms", "ring_average_ms")
 # the lists the cell joined itself: those whose readers find something to read
 # on the CPU, and those that need a device plane or ``memory_stats``
 HSDP_JOINED_ON_THE_HOST = {
-    "quorum_ms.ddp", "commit_vote_ms.ddp", "grad_mbytes_per_step", "ring_ms", "ring_tx_mbytes_per_step",
+    "quorum_ms.ddp", "commit_vote_ms.ddp", "grad_mbytes_per_step", "ring_tx_mbytes_per_step",
 }
 HSDP_JOINED = HSDP_JOINED_ON_THE_HOST | {"step_device_ms.ddp", "sync_exposed_ms", "device_idle_pct.ddp", "peak_hbm_gb.ddp"}
 
@@ -357,9 +364,9 @@ def _rehearse(cell, root):
         ("mistral7b-ddp2-kill", set(KILL_READINGS)),
         # the flash kernels do not run on the CPU, and it has no device plane
         ("mistral7b-ws1-steady", set()),
-        # PR 43: two groups of two chips; the thirteen readers whose lists
-        # tier-1 holds to one cell read the same spans under ``<name>.hsdp``
-        (HSDP_CELL, {name + ".hsdp" for name in HSDP_TWINS} | HSDP_JOINED_ON_THE_HOST),
+        # PR 43: two groups of two chips; the thirteen readers read group 0's
+        # spans under their own names (PR 58), and the cell's own reader beside them
+        (HSDP_CELL, set(TWO_GROUP_READERS) | {HSDP_ONLY} | HSDP_JOINED_ON_THE_HOST),
     ],
 )
 def test_rehearsal_would_report_the_program_span_metrics(cell, new, tmp_path):
@@ -384,7 +391,7 @@ def test_rehearsal_would_report_the_program_span_metrics(cell, new, tmp_path):
 
 
 # ----------------------------------------------------------------------
-# PR 43: the four-chip cell, its lists, its twins and its own reader
+# PR 43: the four-chip cell, its lists and its own reader; PR 58: the twins folded
 # ----------------------------------------------------------------------
 
 
@@ -418,21 +425,74 @@ def test_the_four_chip_cell_and_the_lists_it_joined():
     for name, metric in listed.items():
         cells = metric.get("workloads", [])
         # nothing that reads the one-chip two-replica cell is lost to the four-chip
-        # one in silence: it lists both, or its twin lists the four-chip cell
+        # one in silence: it lists both
         if "mistral7b-ddp2-steady" in cells:
-            assert HSDP_CELL in cells or listed[name + ".hsdp"]["workloads"] == [HSDP_CELL], name
+            assert HSDP_CELL in cells, name
+        # and no twin comes back: one name keeps the suffix, excepted by name
+        assert not name.endswith(".hsdp") or name == HSDP_ONLY, name
         # what it reports moves the throughput of two replica groups, nothing else
         if HSDP_CELL in cells and "moves" in metric:
             assert metric["moves"] == "ddp_tokens_per_s_per_chip", name
 
 
-@pytest.mark.parametrize("name", HSDP_TWINS)
-def test_an_hsdp_twin_is_the_reader_it_names(name):
+@pytest.mark.parametrize("name", TWO_GROUP_READERS)
+def test_a_two_group_reader_stands_once_and_lists_both_cells(name):
     _, listed = _listed()
-    base, twin = spec.load_metric(name, BENCH_DIR), spec.load_metric(name + ".hsdp", BENCH_DIR)
-    # the SAME reader (every load executes a reader's file anew: by its code)
-    assert twin.read.__code__ == base.read.__code__ and twin.META == base.META
-    assert listed[name + ".hsdp"] == dict(listed[name], name=name + ".hsdp", workloads=[HSDP_CELL])
+    entry = listed[name]
+    assert set(TWO_GROUP_CELLS) <= set(entry["workloads"]) and entry["moves"] == "ddp_tokens_per_s_per_chip"
+    assert spec.load_metric(name, BENCH_DIR).META["moves"] == entry["moves"]
+    # under one name: no entry and no file of a twin
+    assert name + ".hsdp" not in listed
+    assert not os.path.exists(os.path.join(BENCH_DIR, "layer_metrics", name + ".hsdp.py"))
+
+
+# what the thirteen read off ``run``'s two traced steps: the eight of READINGS,
+# PR 27's, PR 32's two, and the two shares at the values the test below lays in
+TWO_GROUP_READINGS = dict(
+    READINGS, sync_normalize_ms=80.0, sync_first_submit_ms=220.0, ring_beside_d2h_pct=100.0 * 92 / 220,
+    bucket_warm_pct=100.0, normalize_in_ring_pct=100.0,
+)
+
+
+@pytest.mark.parametrize("name", TWO_GROUP_READERS)
+def test_a_two_group_reader_reads_group_0_beside_a_whole_group_1(run, name, monkeypatch):
+    """What the fold rests on: in the four-chip cell ONE process records both
+    groups' whole round trips (``run`` has group 1's collectives alone), each
+    group on chips of its own, and a reader still reads group 0's."""
+    zero = [dict(s, in_ring=1) if s["name"] == "tpuft/manager/normalize" else s
+            for s in program_spans.of_replica(run["spans"], 0)]
+    # group 1's round trip: group 0's spans 7 ms later and half as long again, on
+    # lines of their own, dividing in the callback (its collectives are there already)
+    one = [
+        dict(s, r=R1, start=s["start"] + 0.007, end=s["start"] + 0.007 + 1.5 * (s["end"] - s["start"]),
+             line=(s["line"][0], f"group 1, {s['line'][1]}"), in_ring=0)
+        for s in zero if s["name"] != "tpuft/comm/op"
+    ]
+    spans = sorted(zero + program_spans.of_replica(run["spans"], 1) + one, key=lambda s: s["start"])
+    monkeypatch.setattr(program_spans, "load", lambda bench_dir=None: spans)
+    trace = run["sources"]["trace"]
+    steps = trace["traced_steps"][0]
+    warm = [dict(name="DDP_SYNC", t=r["t_enter"] + 0.5, bytes=1409368064, buckets=89, warm_buckets=89) for r in steps]
+    cold = [dict(e, warm_buckets=0) for e in warm]
+    sources = dict(
+        run["sources"], groups_share_chip=False,
+        trace=dict(trace, per_device={chip: trace["per_device"][0] for chip in range(4)}),
+        window=[steps, steps], flight=[warm, cold],
+    )
+    read = spec.load_metric(name, BENCH_DIR).read
+    assert read(sources) == pytest.approx(TWO_GROUP_READINGS[name], abs=1e-6)
+
+
+@pytest.mark.parametrize("name", RETIRED)
+def test_a_retired_name_is_gone(name):
+    """No entry, no reader's file, nothing for ``spec.load_metric`` to load,
+    and the benchmark's own page does not name it."""
+    _, listed = _listed()
+    assert name not in listed
+    assert not os.path.exists(os.path.join(BENCH_DIR, "layer_metrics", name + ".py"))
+    assert spec.load_metric(name, BENCH_DIR) is None
+    with open(os.path.join(BENCH_DIR, "README.md")) as f:
+        assert f"`{name}`" not in f.read()
 
 
 def _device_op(name, start_us, dur_us, category="", tf_op="jit(_step)/jit(main)/x"):

@@ -33,7 +33,7 @@ def _lines(stdout):
         ("mistral7b-ddp2-steady", 0, 2, {"ddp_tokens_per_s_per_chip", "setup_s"}),
         ("mistral7b-ws1-steady", 0, 2, {"tokens_per_s_per_chip", "setup_s"}),
         ("mistral7b-ddp2-kill", 0, 2, {"resume_s", "setup_s"}),
-        ("mistral7b-ddp2-steady", 1, 2, {"quorum_ms.ddp", "commit_vote_ms.ddp", "ring_ms",
+        ("mistral7b-ddp2-steady", 1, 2, {"quorum_ms.ddp", "commit_vote_ms.ddp",
                                          "ring_tx_mbytes_per_step", "grad_mbytes_per_step"}),
         ("mistral7b-ddp2-kill", 1, 2, {"detect_ms", "heal_ms", "heal_mbytes", "rejoin_first_step_ms",
                                        "survivor_stall_s"}),

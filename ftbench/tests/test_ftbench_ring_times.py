@@ -1,5 +1,6 @@
-"""The seven readers of where the ring says its time goes (PR 54:
-``layer_metrics/_ring.py`` and ``ring_rx_ms`` ... ``ring_tail_ms``) on
+"""The six readers of where the ring says its time goes (PR 54:
+``layer_metrics/_ring.py`` and ``ring_rx_ms`` ... ``ring_tail_ms``; seven until
+PR 58 retired ``ring_average_ms``, whose pass no cell's ring takes since PR 57) on
 hand-made ``sources``: DDP_SYNC events that carry the fields, events that do
 not (what a parent's program writes), and none in the window; and each
 reader's entry in ``BENCHMARK.json``."""
@@ -21,7 +22,6 @@ READERS = {
     "ring_add_ms": ("ring_add_s", (0.100, 0.120)),
     "ring_tx_ms": ("ring_tx_s", (0.250, 0.270)),
     "ring_reduce_phase_ms": ("ring_reduce_s", (0.280, 0.300)),
-    "ring_average_ms": ("ring_average_s", (0.030, 0.034)),
     "ring_gather_phase_ms": ("ring_gather_s", (0.230, 0.250)),
     "ring_tail_ms": ("ring_tail_s", (0.040, 0.060)),
 }
@@ -58,7 +58,7 @@ def test_a_counting_programs_events_read_as_their_mean_in_ms(name):
 
 @pytest.mark.parametrize("name", sorted(READERS))
 def test_a_parents_events_read_as_nothing(name):
-    # the parent's DDP_SYNC carries bytes and stage seconds and none of the seven
+    # the parent's DDP_SYNC carries bytes and stage seconds and none of the fields
     events = [_sync(11.0, ring_bytes=973127680, striped_bytes=729845760, ring_wait_s=0.5), _sync(12.0)]
     assert _read(name, events) is None
 

@@ -49,6 +49,14 @@ def test_reader_file_says_what_benchmark_json_says(metric):
         assert module.META[key] == metric[key], (metric["name"], key)
 
 
+def test_every_reader_file_is_an_entry():
+    """The other way round: a reader's file that no entry names is read by
+    no run (a retired entry takes its file with it: PR 58)."""
+    files = {f[:-3] for f in os.listdir(os.path.join(ROOT, "ftbench", "layer_metrics"))
+             if f.endswith(".py") and not f.startswith("_")}
+    assert files == {m["name"] for m in BENCH["per_layer"]}
+
+
 def test_contract_limits():
     assert set(BENCH) == {"command", "paths", "run_seconds", "configs", "workloads", "end_to_end", "per_layer"}
     assert 1 <= BENCH["run_seconds"] <= 51
