@@ -146,6 +146,10 @@ def toy(name):
         from torchft_tpu.models.gated_delta_moe import GatedDeltaMoE, gated_delta_debug
 
         return GatedDeltaMoE(gated_delta_debug()), 64
+    if name == "looped":
+        from torchft_tpu.models.looped import Looped, looped_debug
+
+        return Looped(looped_debug()), 64
     if name == "ssm_hybrid_moe":
         from torchft_tpu.models.ssm_hybrid_moe import SsmHybridMoE, ssm_hybrid_debug
 

@@ -1,5 +1,5 @@
 """The parts of the compiled step (``torchft_tpu/obs/spans.py``,
-``DEVICE_PARTS``): every operation that costs device time in the seven models'
+``DEVICE_PARTS``): every operation that costs device time in the nine models'
 two step programs is traced under a ``tpuft.<part>`` scope, at toy widths and
 on both paths (plain, and the kernels in interpret mode).  The paths are read
 from the COMPILED text's ``op_name``s: XLA inlines every private function
@@ -16,22 +16,23 @@ from torchft_tpu.obs.spans import DEVICE_PARTS, PART_PREFIX, part
 
 from tests._toys import toy, lowered_grad_step
 
-MODELS = ("llama", "ling_hybrid", "indexed_sparse_moe", "ssm_hybrid_moe", "windowed_moe", "latent_moe", "eva", "gated_delta_moe")
+MODELS = ("llama", "ling_hybrid", "indexed_sparse_moe", "ssm_hybrid_moe", "windowed_moe", "latent_moe", "eva", "gated_delta_moe", "looped")
 CASES = [(m, p) for m in MODELS for p in ("plain", "kernels")]
 EVERY = set(DEVICE_PARTS)
 # Keye has no dense MLP and no shared expert; Mistral has no experts; the
 # prediction module's own work is ``mtp``, and JoyAI's is the one model here
 # that runs the module (Ling's toy preset builds none); EvaByte's is the one
-# mixer that pools
+# mixer that pools, and the looped model's the one exit gate
 USES = {
-    "llama": EVERY - {"experts_route", "experts_dispatch", "mtp", "mixer_pool"},
-    "ling_hybrid": EVERY - {"mtp", "mixer_pool"},
-    "indexed_sparse_moe": EVERY - {"ffn", "mtp", "mixer_pool"},
-    "ssm_hybrid_moe": EVERY - {"mtp", "mixer_pool"},
-    "windowed_moe": EVERY - {"mtp", "mixer_pool"},
-    "latent_moe": EVERY - {"mixer_pool"},
-    "eva": EVERY - {"experts_route", "experts_dispatch", "mtp"},
-    "gated_delta_moe": EVERY - {"mtp", "mixer_pool"},
+    "llama": EVERY - {"experts_route", "experts_dispatch", "mtp", "mixer_pool", "loop_gate"},
+    "ling_hybrid": EVERY - {"mtp", "mixer_pool", "loop_gate"},
+    "indexed_sparse_moe": EVERY - {"ffn", "mtp", "mixer_pool", "loop_gate"},
+    "ssm_hybrid_moe": EVERY - {"mtp", "mixer_pool", "loop_gate"},
+    "windowed_moe": EVERY - {"mtp", "mixer_pool", "loop_gate"},
+    "latent_moe": EVERY - {"mixer_pool", "loop_gate"},
+    "eva": EVERY - {"experts_route", "experts_dispatch", "mtp", "loop_gate"},
+    "gated_delta_moe": EVERY - {"mtp", "mixer_pool", "loop_gate"},
+    "looped": EVERY - {"experts_route", "experts_dispatch", "mtp", "mixer_pool"},
 }
 # what costs time on a device and is never fused away into a neighbour
 HELD = ("dot", "convolution", "gather", "scatter", "sort")
@@ -138,7 +139,7 @@ def test_the_poolings_operations_are_under_their_part_and_nothing_elses_is(path)
 
 
 def test_the_vocabulary_is_closed():
-    assert len(DEVICE_PARTS) == len(set(DEVICE_PARTS)) == 12
+    assert len(DEVICE_PARTS) == len(set(DEVICE_PARTS)) == 13
     with pytest.raises(ValueError, match="nonsense"):
         part("nonsense")
     for name in DEVICE_PARTS:
@@ -157,6 +158,9 @@ def test_the_vocabulary_is_closed():
         ("jit(_step)/transpose(jvp(tpuft.mtp))/dot_general", "mtp"),
         # the pooling inside the glue is the pooling's
         ("jit(_step)/jvp(tpuft.layers)/while/body/checkpoint/tpuft.mixer_glue/tpuft.mixer_pool/reduce_sum", "mixer_pool"),
+        # the exit gate and what it weighs are the gate's; a pass's head stays the head's
+        ("jit(_step)/transpose(jvp(tpuft.loop_gate))/mul", "loop_gate"),
+        ("jit(_step)/jvp(tpuft.head)/while/body/checkpoint/dot_general", "head"),
         ("jit(_step)/concatenate", None),
         ("", None),
         (None, None),

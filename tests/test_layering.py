@@ -344,3 +344,21 @@ def test_gated_delta_net_is_model_code_over_kernels(module: str, row: str, may_i
     and nothing of the Manager."""
     assert [ROWS[i][0] for i in _rows_of(module)] == [row]
     assert {target for importer, _line, target in _inner_edges() if importer == module} == may_import
+
+
+@pytest.mark.parametrize(
+    "module,row,may_import",
+    [
+        (
+            "models.looped", "compiled-step-models",
+            {"ops.flash_attention", "parallel.moe", "models.llama", "models.latent", "obs.spans"},
+        ),
+    ],
+)
+def test_the_looped_model_is_model_code_over_the_flash_kernels(module: str, row: str, may_import: set) -> None:
+    """PR 59's module: a stack of layers applied several times a step is model
+    code, calling ``Llama``'s norm, rope and refusals, the shared SwiGLU and
+    cross-entropy and the flash kernels as they stand, and nothing of the
+    Manager, ``ddp`` or the harness: the loop needed no edit outside it."""
+    assert [ROWS[i][0] for i in _rows_of(module)] == [row]
+    assert {target for importer, _line, target in _inner_edges() if importer == module} == may_import

@@ -26,7 +26,10 @@ from ``flash_attention``'s tables) and no other changed: the flash models'
 steps are what they were.  PR 56 ADDED ``gated_delta_moe``'s two (``--write
 --only gated_delta_moe``: ``RoutedExperts`` took a gate on the shared expert,
 ``ops/gdn.py`` imports ``ops/kda.py``'s inverse) and changed none of the
-twelve: no other model's program moved.  A later change that means to alter one of these
+twelve: no other model's program moved.  PR 59 ADDED ``looped``'s two (``--write
+--only looped``: ``DEVICE_PARTS`` took a thirteenth part, ``loop_gate``; no
+helper of ``Llama`` and no kernel was touched) and changed none of the
+fourteen.  A later change that means to alter one of these
 programs writes the fixture anew and says so: ``python
 tests/test_lowered_steps.py --write``."""
 
@@ -39,7 +42,7 @@ import sys
 import pytest
 
 FIXTURE = os.path.join(os.path.dirname(os.path.abspath(__file__)), "fixtures", "lowered_steps.json")
-MODELS = ("ling_hybrid", "indexed_sparse_moe", "llama", "ssm_hybrid_moe", "windowed_moe", "eva", "gated_delta_moe")
+MODELS = ("ling_hybrid", "indexed_sparse_moe", "llama", "ssm_hybrid_moe", "windowed_moe", "eva", "gated_delta_moe", "looped")
 CASES = [(m, p) for m in MODELS for p in ("plain", "kernels")]
 
 

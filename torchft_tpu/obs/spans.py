@@ -58,7 +58,7 @@ operations; these are the names the program gives out::
 **The parts of the compiled step.**  A device operation that XLA makes has
 no name of ours, only the path of ``jax.named_scope``s it was traced under
 (``op_name`` in the compiled program, ``tf_op`` in a trace's event metadata).
-:class:`part` opens one of these twelve scopes (``with part("ffn"):`` around
+:class:`part` opens one of these thirteen scopes (``with part("ffn"):`` around
 some lines, ``@part("ffn")`` on a function that is one part whole), ONE
 vocabulary for every architecture (:data:`DEVICE_PARTS`); the innermost one
 on an operation's path is its part::
@@ -97,6 +97,13 @@ on an operation's path is its part::
                             projection of the pair, its final norm, its logits
                             and its cross-entropy; its layer names its parts
                             (mixer_proj, experts_dispatch, ...) as any layer does
+    tpuft.loop_gate         a looped model's exit gate and what it weighs
+                            (``models/looped.py``): every pass's gate logit,
+                            ``softplus``, the exit distribution over the
+                            passes, the expected loss under it, its entropy
+                            and the step's summary of them, forward and
+                            backward; the heads' logits and cross-entropies
+                            stay ``head``'s
     tpuft.layers            the lax.scan over a run of layers, around the
                             body's own parts: what is left under it alone is
                             the loop's machinery, the slices of the stacked
@@ -159,7 +166,7 @@ SPANS_ENV = "TORCHFT_FLIGHT_SPANS"
 # the parts of the compiled step (the module docstring says what lies under each)
 DEVICE_PARTS = (
     "embed", "stream", "mixer_proj", "mixer_glue", "mixer_pool", "ffn", "experts_route",
-    "experts_dispatch", "head", "mtp", "layers", "optimizer",
+    "experts_dispatch", "head", "mtp", "loop_gate", "layers", "optimizer",
 )
 PART_PREFIX = "tpuft."
 
