@@ -214,11 +214,68 @@ int tpuft_comm_allgather(void* h, const void* in, void* out,
   return guarded([&] { comm->allgather(in, out, chunk_bytes, tag); });
 }
 
+// ---- a round trip's rings as one call (comm.h RingSession) ----
+//
+// open -> push x n (the train thread, as each piece is packed; bind it so
+// that the caller keeps its interpreter lock: it never blocks) -> run (the
+// op thread, ONE call for the round trip) / wait(k) (whoever needs piece k)
+// -> close (no further push; a must where a push may be missing) -> free
+// (after run and every wait have returned).
+
+void* tpuft_ring_session_open(uint64_t pieces, int32_t op, uint64_t divisor) {
+  return new tpuft::RingSession(pieces, static_cast<tpuft::RedOp>(op), divisor);
+}
+
+// 1: kept; 0: a no-op (the session failed, was closed or is full)
+int tpuft_ring_session_push(void* s, void* data, uint64_t nbytes,
+                            int32_t dtype) {
+  return static_cast<tpuft::RingSession*>(s)->push(
+      data, nbytes, static_cast<tpuft::DType>(dtype));
+}
+
+int tpuft_ring_session_run(void* h, void* s) {
+  auto* comm = static_cast<tpuft::Communicator*>(h);
+  return guarded(
+      [&] { comm->run_session(*static_cast<tpuft::RingSession*>(s)); });
+}
+
+// 0: piece k is rung; -1: it failed or never will be (tpuft_last_error says
+// why); 1: `timeout_s` (< 0: none) passed first
+int tpuft_ring_session_wait(void* s, uint64_t k, double timeout_s) {
+  std::string why;
+  auto got = static_cast<tpuft::RingSession*>(s)->wait(k, timeout_s, &why);
+  if (got == tpuft::RingSession::kFailed) {
+    g_last_error = why;
+    return -1;
+  }
+  return got == tpuft::RingSession::kRung ? 0 : 1;
+}
+
+void tpuft_ring_session_close(void* s) {
+  static_cast<tpuft::RingSession*>(s)->close();
+}
+
+// the run failed outside the call or never began: wakes every waiter
+void tpuft_ring_session_fail(void* s, const char* why) {
+  static_cast<tpuft::RingSession*>(s)->fail(why);
+}
+
+// pieces rung so far, and the start and end of each one's ring in
+// steady_clock seconds (Python's time.monotonic()), up to `cap`
+uint64_t tpuft_ring_session_times(void* s, double* t0, double* t1,
+                                  uint64_t cap) {
+  return static_cast<tpuft::RingSession*>(s)->times(t0, t1, cap);
+}
+
+void tpuft_ring_session_free(void* s) {
+  delete static_cast<tpuft::RingSession*>(s);
+}
+
 // per-lane counters of the current epoch (tx/rx payload bytes, stall
 // events, and the nanoseconds a lane spent in recv, in the reduce's add and
-// in send) with the op thread's four (`ring_ns`: reduce phase, division,
-// allgather phase, tail) — the native half of the tier-agnostic
-// lane_stats() surface.  Returns the lane count; fills up to `cap` entries
+// in send) with the op thread's five (`ring_ns`: reduce phase, division,
+// allgather phase, tail, a session's wait for the next push) — the native
+// half of the tier-agnostic lane_stats() surface.  Returns the lane count; fills up to `cap` entries
 // per array.
 uint64_t tpuft_comm_lane_stats(void* h, uint64_t* tx, uint64_t* rx,
                                uint64_t* stalls, uint64_t* rx_ns,

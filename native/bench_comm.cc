@@ -6,10 +6,15 @@
 //   ./bench_comm pieces [MB] — a step's gradient bytes (973 MB of bfloat16,
 //                             the one-chip two-group cell's) rung with the
 //                             divisor whole and in pieces of 64, 16 and 4
-//                             MiB, at the lanes TORCHFT_RING_LANES names
+//                             MiB, at the lanes TORCHFT_RING_LANES names;
+//                             a second line a piece size rings the same
+//                             pieces through ONE session (run_session), all
+//                             pushed up front: the ring alone, with what a
+//                             ring pays once a piece paid once
 // Both print, beside the wall time, where the ring says its time went
-// (comm.h EpochIO's seven counters of nanoseconds): the terms a traced
-// two-group cell reports as ring_rx_ms ... ring_tail_ms.  `average` is the
+// (comm.h EpochIO's seven counters of nanoseconds, and a session's wait for
+// the next push): the terms a traced two-group cell reports as ring_rx_ms
+// ... ring_tail_ms.  `average` is the
 // stand-alone division pass, which a ring of two never takes: it reads 0.0
 // and the division lies in a lane's `add` (the before/after of moving it
 // there is `pieces` from the two commits' binaries).
@@ -30,10 +35,10 @@ using namespace tpuft;
 // MEAN over the lanes that sent bytes (the rule ddp.allreduce_pytree puts
 // on DDP_SYNC); differenced over a stretch of rings they say where it went.
 struct RingTimes {
-  double ms[7] = {0, 0, 0, 0, 0, 0, 0};  // rx add tx | reduce average gather tail
+  double ms[8] = {0, 0, 0, 0, 0, 0, 0, 0};  // rx add tx | reduce average gather tail wait_push
   uint64_t tx_bytes[64] = {0};
   uint64_t lane_ns[3][64] = {{0}};
-  uint64_t ring_ns[4] = {0, 0, 0, 0};
+  uint64_t ring_ns[5] = {0, 0, 0, 0, 0};
   size_t lanes = 0;
 
   static RingTimes read(const Communicator& comm) {
@@ -55,18 +60,19 @@ struct RingTimes {
         d.ms[k] += (lane_ns[k][i] - before.lane_ns[k][i]) / 1e6;
     }
     for (int k = 0; k < 3; ++k) d.ms[k] /= moved ? moved : 1;
-    for (int k = 0; k < 4; ++k)
+    for (int k = 0; k < 5; ++k)
       d.ms[3 + k] = (ring_ns[k] - before.ring_ns[k]) / 1e6;
     d.lanes = moved;
     return d;
   }
   void print(int rank, const char* what, double wall_s) const {
     std::printf("rank %d %s: wall %.1f ms = reduce %.1f + average %.1f + "
-                "gather %.1f (+ %.1f outside the phases); of the phases a "
-                "lane (mean of %zu) rx %.1f, add %.1f, tx %.1f; tail %.1f\n",
+                "gather %.1f (+ %.1f outside the phases, ring_wait_push %.1f "
+                "of it); of the phases a lane (mean of %zu) rx %.1f, add %.1f, "
+                "tx %.1f; tail %.1f\n",
                 rank, what, wall_s * 1e3, ms[3], ms[4], ms[5],
-                wall_s * 1e3 - ms[3] - ms[4] - ms[5], lanes, ms[0], ms[1],
-                ms[2], ms[6]);
+                wall_s * 1e3 - ms[3] - ms[4] - ms[5], ms[7], lanes, ms[0],
+                ms[1], ms[2], ms[6]);
   }
 };
 
@@ -166,6 +172,23 @@ static void run_pieces(const std::string& store_addr, int rank, size_t mb) {
                 "%.2f ms a ring\n", rank, lanes, mb, rings, mib, dt,
                 dt * 1e3 / rings);
     RingTimes::read(comm).since(before).print(rank, "the pass", dt);
+    // the same pieces as ONE call: a session with every piece pushed up
+    // front, so the op thread never waits and the line reads the ring alone
+    for (int pass = 0; pass < 2; ++pass) {
+      RingSession session(rings, OP_SUM, /*divisor=*/2);
+      for (size_t off = 0; off < elems; off += piece)
+        session.push(grad.data() + off, std::min(piece, elems - off) * 2,
+                     DT_BF16);
+      before = RingTimes::read(comm);
+      auto t0 = std::chrono::steady_clock::now();
+      comm.run_session(session);
+      dt = std::chrono::duration<double>(std::chrono::steady_clock::now() - t0)
+               .count();
+    }
+    std::printf("rank %d lanes %zu: the same %zu piece(s) through one "
+                "session: %.3fs, %.2f ms a piece\n", rank, lanes, rings, dt,
+                dt * 1e3 / rings);
+    RingTimes::read(comm).since(before).print(rank, "the session", dt);
   }
   std::fflush(stdout);
 }

@@ -17,6 +17,7 @@
 #pragma once
 
 #include <fcntl.h>
+#include <pthread.h>
 #include <sys/socket.h>
 #include <sys/uio.h>
 
@@ -671,7 +672,14 @@ class LanePool {
         if (it == workers_.end()) {
           it = workers_.emplace(key, std::make_shared<Worker>()).first;
           Worker* raw = it->second.get();
-          raw->th = std::thread([raw] { raw->run(); });
+          // named for whoever looks at the process's threads (top -H,
+          // scripts/ddp_sync_probe.py): tpuft-rx<lane>, tpuft-tx<lane>
+          std::string name =
+              (dir == kRx ? "tpuft-rx" : "tpuft-tx") + std::to_string(lane);
+          raw->th = std::thread([raw, name] {
+            pthread_setname_np(pthread_self(), name.c_str());
+            raw->run();
+          });
         }
         w = it->second;
       }
@@ -931,12 +939,16 @@ struct EpochIO {
   // other ring divides in its last reduce step's add, on the lanes, and
   // adds nothing here), in the allgather phase, and, of a phase's steps,
   // from its own part of the receive returning to the other lanes' parts
-  // and its own send having landed (the tail).  Lanes run beside each
+  // and its own send having landed (the tail); and, in a ring session,
+  // waiting for the next piece to be pushed (outside the phases).  Lanes run beside each
   // other, so a lane's seconds are a share of the phases' and the tail lies
   // inside them.  Only frames on the TCP lanes count under a lane; a leg
   // another transport carries lies in the phases alone.
   std::unique_ptr<std::atomic<uint64_t>[]> rx_ns, add_ns, tx_ns;
   std::atomic<uint64_t> reduce_ns{0}, average_ns{0}, gather_ns{0}, tail_ns{0};
+  // a ring session's op thread waiting for the train thread's next push
+  // (RingSession): between two pieces' rings, in NEITHER phase nor the tail
+  std::atomic<uint64_t> wait_push_ns{0};
 
   void alloc_counters() {
     tx.reset(new std::atomic<uint64_t>[lanes]());
@@ -989,6 +1001,106 @@ struct EpochIO {
 };
 
 using IoPtr = std::shared_ptr<EpochIO>;
+
+// A round trip's rings as ONE call (Communicator::run_session): the train
+// thread pushes each piece as it is packed, the op thread stays inside the
+// call from the first piece to the last and rings them one after another,
+// exactly as allreduce_iov would have rung each (same bounds, frames, tags,
+// lanes and order: a peer on the per-piece path rides the same rings), and
+// whoever needs piece k waits for "piece k is rung".  What a ring paid once
+// a piece (the fd lists, the scratch, the way back into the caller's
+// language and out again) is paid once a session.  The first error fails
+// its piece and every later one; later pushes are no-ops.
+struct RingSession {
+  struct Piece {
+    void* data = nullptr;
+    uint64_t nbytes = 0;
+    DType dt = DT_F32;
+    // steady_clock seconds (Python's time.monotonic()) around its ring
+    double t0 = 0.0, t1 = 0.0;
+  };
+  enum Wait { kRung = 0, kFailed = 1, kTimedOut = 2 };
+
+  RingSession(size_t pieces, RedOp red_op, uint64_t div)
+      : op(red_op), divisor(div), pieces_(pieces) {}
+
+  // Hand over the next piece.  Never blocks beyond the lock of a counter;
+  // false (and nothing kept) once the session failed, was closed or is full.
+  bool push(void* data, uint64_t nbytes, DType dt) {
+    std::lock_guard<std::mutex> lock(mu_);
+    if (closed_ || !err_.empty() || pushed_ == pieces_.size()) return false;
+    Piece& piece = pieces_[pushed_++];
+    piece.data = data;
+    piece.nbytes = nbytes;
+    piece.dt = dt;
+    cv_.notify_all();
+    return true;
+  }
+
+  // No further push: the run ends after the last piece pushed so far.
+  void close() {
+    std::lock_guard<std::mutex> lock(mu_);
+    closed_ = true;
+    cv_.notify_all();
+  }
+
+  // The session will ring nothing more (its run failed, or never began):
+  // wakes every waiter.  The first error stays.
+  void fail(const std::string& what) { end(what); }
+
+  // Wait for piece k's ring.  `timeout_s` < 0: no limit.
+  Wait wait(size_t k, double timeout_s, std::string* why) {
+    auto until = std::chrono::steady_clock::now() +
+                 std::chrono::duration_cast<std::chrono::steady_clock::duration>(
+                     std::chrono::duration<double>(std::max(0.0, timeout_s)));
+    std::unique_lock<std::mutex> lock(mu_);
+    while (true) {
+      if (done_ > k) return kRung;
+      if (!err_.empty() || ended_) {
+        *why = !err_.empty() ? err_ : "the session ended before this piece";
+        return kFailed;
+      }
+      if (timeout_s < 0) {
+        cv_.wait(lock);
+      } else if (cv_.wait_until(lock, until) == std::cv_status::timeout &&
+                 done_ <= k && err_.empty() && !ended_) {
+        return kTimedOut;
+      }
+    }
+  }
+
+  // Pieces rung so far; fills their start and end times (up to `cap`).
+  size_t times(double* t0, double* t1, size_t cap) {
+    std::lock_guard<std::mutex> lock(mu_);
+    for (size_t k = 0; k < std::min(done_, cap); ++k) {
+      t0[k] = pieces_[k].t0;
+      t1[k] = pieces_[k].t1;
+    }
+    return done_;
+  }
+
+  const RedOp op;
+  const uint64_t divisor;
+
+ private:
+  friend class Communicator;
+  // nothing more will be rung; `what` (if any, and the first) is why
+  void end(const std::string& what) {
+    std::lock_guard<std::mutex> lock(mu_);
+    if (err_.empty()) err_ = what;
+    closed_ = true;
+    ended_ = true;
+    cv_.notify_all();
+  }
+
+  std::mutex mu_;
+  std::condition_variable cv_;
+  std::vector<Piece> pieces_;  // sized at open: a piece never moves
+  size_t pushed_ = 0, done_ = 0;
+  bool closed_ = false;  // no further push
+  bool ended_ = false;   // the run returned (or never will run)
+  std::string err_;      // the first error: piece `done_` and every later one
+};
 
 class Communicator {
  public:
@@ -1208,6 +1320,7 @@ class Communicator {
     // abort() to supersede, so a bare flag write would log boot noise)
     if (!aborted_.exchange(true) && flight_epochs_.load() > 0)
       flight_record(kFlightCommAbort, 0, 0);
+    abort_gen_.fetch_add(1);  // wakes a session that waits for a push
     std::lock_guard<std::mutex> lock(state_mu_);
     for (auto& [peer, fds] : peers_)
       for (int fd : fds) ::shutdown(fd, SHUT_RDWR);
@@ -1315,9 +1428,10 @@ class Communicator {
   // the lane count; fills up to `cap` entries per array.  The same snapshot
   // hands out where the epoch's time went (EpochIO has what each one is):
   // `lane_ns`, three arrays of `cap` (a lane's nanoseconds in recv, in the
-  // reduce's add, in send), and `ring_ns`, four (the op thread's in the
-  // reduce phase, the division, the allgather phase, the tail).  Who wants
-  // the lane count alone asks lanes().
+  // reduce's add, in send), and `ring_ns`, five (the op thread's in the
+  // reduce phase, the division, the allgather phase, the tail, and a
+  // session's wait for the next push).  Who wants the lane count alone asks
+  // lanes().
   size_t lane_stats(uint64_t* tx, uint64_t* rx, uint64_t* stalls, size_t cap,
                     uint64_t* const* lane_ns, uint64_t* ring_ns) const {
     IoPtr io = io_snapshot();
@@ -1337,6 +1451,7 @@ class Communicator {
     ring_ns[1] = read(io->average_ns);
     ring_ns[2] = read(io->gather_ns);
     ring_ns[3] = read(io->tail_ns);
+    ring_ns[4] = read(io->wait_push_ns);
     return io->lanes;
   }
 
@@ -1389,40 +1504,54 @@ class Communicator {
                          uint64_t divisor = 0, uint64_t tag_base = 0) {
     if (divisor && op != OP_SUM)
       throw CommError("an allreduce's divisor goes with OP_SUM alone");
-    if (divisor == 1) divisor = 0;  // the sum is the average: no division
-    size_t esz = dtype_size(dt);
-    if (ring.size() <= 1) {
-      if (divisor) {  // no add to divide in: the stand-alone pass
-        NsTimer timed(&io->average_ns);
-        for (const struct iovec& seg : view.slice(0, view.size()))
-          average_buffer(seg.iov_base, seg.iov_len, dt, divisor);
-      }
-      return;
-    }
-    auto deadline = deadline_in(timeout_s_);
-    auto bounds = ring_bounds(view.size() / esz, ring.size());
+    RingCtx ctx = ring_ctx(std::move(io), ring);
+    allreduce_ring_ctx(ctx, view, dt, op, divisor, tag_base);
+  }
 
-    // shift -1 on BOTH phases: the Python tier's schedule (ring position p
-    // ends the reduce phase owning chunk p, the conventional contract —
-    // communicator._ring_reduce_scatter sends pos-step-1 / recvs
-    // pos-step-2, then allgather sends pos-step / recvs pos-step-1).  The
-    // round-1 build ran the textbook shift-0 schedule here: correct alone,
-    // but chunk indices landed rotated by one against a Python peer — a
-    // silent cross-tier corruption the constant-fill interop test never
-    // saw (mixed-tier bit-identity tests now pin this).
-    //
-    // With a divisor the owner of a chunk divides it (1/ws of the payload,
-    // once a ring and not once a rank) in the add that completes its sum,
-    // the reduce phase's last step on the lanes' threads, so the allgather
-    // phase carries averages.  The Python tier divides the same sums in a
-    // pass of numpy's between the phases (_ring_allreduce): the tiers differ
-    // in HOW and not in WHAT, the bytes are the same and mixed tiers ride
-    // one ring.
-    if (divisor) tag_base += kRingAvgTagBase;
-    ring_reduce_phase(io, view, bounds, esz, dt, op, /*shift=*/-1, deadline,
-                      ring, tag_base, divisor);
-    ring_allgather_phase(io, view, bounds, esz, /*shift=*/-1, deadline, ring,
-                         tag_base);
+  // The op thread's ONE call of a round trip (RingSession says what it is):
+  // rings the session's pieces in the order they were pushed, each exactly
+  // as allreduce_iov(&data, &nbytes, 1, dt, op, divisor, group 0) would,
+  // and no piece's ring starts before its predecessor's has ended.  The fd
+  // lists, the scratch and the decoding live for the call; a piece's
+  // deadline runs from when it is both pushed and at the head, so a slow
+  // landing never reads as a ring that hangs.  The wait for the next push
+  // is counted by itself (EpochIO::wait_push_ns) and ends on abort().
+  // Throws the first error, after the session has heard it.
+  void run_session(RingSession& s) {
+    try {
+      if (s.divisor && s.op != OP_SUM)
+        throw CommError("an allreduce's divisor goes with OP_SUM alone");
+      const uint64_t gen = abort_gen_.load();
+      IoPtr io = io_snapshot();
+      RingCtx ctx = ring_ctx(io, full_ring(io->world));
+      for (size_t k = 0; k < s.pieces_.size(); ++k) {
+        RingSession::Piece piece;
+        {
+          NsTimer timed(&io->wait_push_ns);
+          std::unique_lock<std::mutex> lock(s.mu_);
+          while (s.pushed_ <= k && !s.closed_) {
+            s.cv_.wait_for(lock, std::chrono::milliseconds(20));
+            if (aborted_ || abort_gen_.load() != gen)
+              throw CommError("communicator aborted");
+          }
+          if (s.pushed_ <= k) break;  // closed: the unpushed never start
+          piece = s.pieces_[k];
+        }
+        ScatterView view(piece.data, piece.nbytes);
+        double t0 = steady_seconds();
+        allreduce_ring_ctx(ctx, view, piece.dt, s.op, s.divisor, 0);
+        double t1 = steady_seconds();
+        std::lock_guard<std::mutex> lock(s.mu_);
+        s.pieces_[k].t0 = t0;
+        s.pieces_[k].t1 = t1;
+        s.done_ = k + 1;
+        s.cv_.notify_all();
+      }
+    } catch (const std::exception& e) {
+      s.fail(e.what());
+      throw;
+    }
+    s.end("");
   }
 
   // reduce-scatter: `data` is reduced in place ring-wise; this rank's chunk
@@ -1444,8 +1573,9 @@ class Communicator {
       ScatterView view(data, nbytes);
       // shift -1: rank ends owning chunk `rank` (conventional contract);
       // the explicit-API tag window keeps these frames clear of allreduce
-      ring_reduce_phase(io, view, bounds, esz, dt, op, /*shift=*/-1, deadline,
-                        full_ring(ws), kRingReduceTagBase);
+      RingCtx ctx = ring_ctx(io, full_ring(ws));
+      ring_reduce_phase(ctx, view, bounds, esz, dt, op, /*shift=*/-1, deadline,
+                        kRingReduceTagBase);
     }
     std::memcpy(out, bytes + own_off, own_bytes);
     return own_bytes;
@@ -1581,6 +1711,75 @@ class Communicator {
   TimePoint deadline_in(double seconds) const {
     return now() + std::chrono::duration_cast<std::chrono::steady_clock::duration>(
                        std::chrono::duration<double>(seconds));
+  }
+
+  static double steady_seconds() {
+    return std::chrono::duration<double>(now().time_since_epoch()).count();
+  }
+
+  // What a ring needs of its epoch beyond the bytes, made once a call (a
+  // session: once a round trip) and kept from piece to piece: where this
+  // rank stands in the ring, its neighbours' fd lists and the lanes'
+  // scratch (grown once to the size rule of recv_striped_reduce, reused).
+  struct RingCtx {
+    IoPtr io;
+    std::vector<int64_t> ring;
+    int64_t ws = 1, pos = 0, right = 0, left = 0;
+    std::vector<int> right_fds, left_fds;
+    std::vector<std::vector<uint8_t>> scratches;
+  };
+
+  RingCtx ring_ctx(IoPtr io, const std::vector<int64_t>& ring) {
+    RingCtx ctx;
+    ctx.io = std::move(io);
+    ctx.ring = ring;
+    ctx.ws = static_cast<int64_t>(ring.size());
+    if (ctx.ws <= 1) return ctx;  // nobody to reach
+    ctx.pos = ring_pos(ring, ctx.io->rank);
+    ctx.right = ring[(ctx.pos + 1) % ctx.ws];
+    ctx.left = ring[(ctx.pos - 1 + ctx.ws) % ctx.ws];
+    ctx.right_fds = peer_fds(ctx.right);
+    ctx.left_fds = peer_fds(ctx.left);
+    return ctx;
+  }
+
+  // One ring over `ctx`'s members: the body of every allreduce.
+  void allreduce_ring_ctx(RingCtx& ctx, ScatterView& view, DType dt, RedOp op,
+                          uint64_t divisor, uint64_t tag_base) {
+    if (divisor == 1) divisor = 0;  // the sum is the average: no division
+    size_t esz = dtype_size(dt);
+    if (ctx.ws <= 1) {
+      if (divisor) {  // no add to divide in: the stand-alone pass
+        NsTimer timed(&ctx.io->average_ns);
+        for (const struct iovec& seg : view.slice(0, view.size()))
+          average_buffer(seg.iov_base, seg.iov_len, dt, divisor);
+      }
+      return;
+    }
+    auto deadline = deadline_in(timeout_s_);
+    auto bounds = ring_bounds(view.size() / esz, ctx.ring.size());
+
+    // shift -1 on BOTH phases: the Python tier's schedule (ring position p
+    // ends the reduce phase owning chunk p, the conventional contract —
+    // communicator._ring_reduce_scatter sends pos-step-1 / recvs
+    // pos-step-2, then allgather sends pos-step / recvs pos-step-1).  The
+    // round-1 build ran the textbook shift-0 schedule here: correct alone,
+    // but chunk indices landed rotated by one against a Python peer — a
+    // silent cross-tier corruption the constant-fill interop test never
+    // saw (mixed-tier bit-identity tests now pin this).
+    //
+    // With a divisor the owner of a chunk divides it (1/ws of the payload,
+    // once a ring and not once a rank) in the add that completes its sum,
+    // the reduce phase's last step on the lanes' threads, so the allgather
+    // phase carries averages.  The Python tier divides the same sums in a
+    // pass of numpy's between the phases (_ring_allreduce): the tiers differ
+    // in HOW and not in WHAT, the bytes are the same and mixed tiers ride
+    // one ring.
+    if (divisor) tag_base += kRingAvgTagBase;
+    ring_reduce_phase(ctx, view, bounds, esz, dt, op, /*shift=*/-1, deadline,
+                      tag_base, divisor);
+    ring_allgather_phase(ctx, view, bounds, esz, /*shift=*/-1, deadline,
+                         tag_base);
   }
 
   std::vector<int> peer_fds(int64_t peer) {
@@ -1970,23 +2169,21 @@ class Communicator {
     return it - ring.begin();
   }
 
-  // ring reduce phase: ws-1 duplex steps over `ring` (global ranks in ring
-  // order; ws = ring.size()); with shift s, this rank's ring POSITION ends
-  // up owning the fully-reduced chunk (pos + 1 + s) mod ws.  The (memory-
-  // bound) reduction rides under the wire via quantum-pipelined recv; the
-  // send leg runs on the per-lane tx workers, the recv leg on the calling
-  // thread + rx workers.  With a `divisor` the LAST step's add, the one that
-  // completes the owned chunk's sum at any ring size, divides it too.
-  void ring_reduce_phase(IoPtr io, ScatterView& view,
+  // ring reduce phase: ws-1 duplex steps over `ctx.ring` (global ranks in
+  // ring order; ws = ring.size()); with shift s, this rank's ring POSITION
+  // ends up owning the fully-reduced chunk (pos + 1 + s) mod ws.  The
+  // (memory-bound) reduction rides under the wire via quantum-pipelined
+  // recv; the send leg runs on the per-lane tx workers, the recv leg on the
+  // calling thread + rx workers.  With a `divisor` the LAST step's add, the
+  // one that completes the owned chunk's sum at any ring size, divides it too.
+  void ring_reduce_phase(RingCtx& ctx, ScatterView& view,
                          const std::vector<size_t>& bounds, size_t esz,
                          DType dt, RedOp op, int64_t shift,
-                         TimePoint deadline, const std::vector<int64_t>& ring,
-                         uint64_t tag_base, uint64_t divisor = 0) {
+                         TimePoint deadline, uint64_t tag_base,
+                         uint64_t divisor = 0) {
+    const IoPtr& io = ctx.io;
     NsTimer timed(&io->reduce_ns);
-    int64_t ws = static_cast<int64_t>(ring.size());
-    int64_t pos = ring_pos(ring, io->rank);
-    int64_t right = ring[(pos + 1) % ws];
-    int64_t left = ring[(pos - 1 + ws) % ws];
+    const int64_t ws = ctx.ws, pos = ctx.pos;
     auto chunk_off = [&](int64_t i) {
       i = ((i % ws) + ws) % ws;
       return bounds[i] * esz;
@@ -1995,22 +2192,18 @@ class Communicator {
       i = ((i % ws) + ws) % ws;
       return (bounds[i + 1] - bounds[i]) * esz;
     };
-    std::vector<int> right_fds = peer_fds(right);
-    std::vector<int> left_fds = peer_fds(left);
-    std::vector<std::vector<uint8_t>> scratches;  // grown once, reused/step
     for (int64_t step = 0; step < ws - 1; ++step) {
       int64_t send_idx = pos - step + shift;
       int64_t recv_idx = pos - step - 1 + shift;
-      auto send_latch =
-          send_striped_async(io, right_fds, right, tag_base + 1000 + step,
-                             view, chunk_off(send_idx), chunk_bytes(send_idx),
-                             deadline);
+      auto send_latch = send_striped_async(
+          io, ctx.right_fds, ctx.right, tag_base + 1000 + step, view,
+          chunk_off(send_idx), chunk_bytes(send_idx), deadline);
       TimePoint own_done;
       try {
         own_done = recv_striped_reduce(
-            *io, left_fds, left, tag_base + 1000 + step, view,
+            *io, ctx.left_fds, ctx.left, tag_base + 1000 + step, view,
             chunk_off(recv_idx), chunk_bytes(recv_idx), dt, op,
-            step == ws - 2 ? divisor : 0, deadline, scratches);
+            step == ws - 2 ? divisor : 0, deadline, ctx.scratches);
       } catch (...) {
         send_latch->wait_quiet();
         throw;
@@ -2022,18 +2215,15 @@ class Communicator {
   }
 
   // ring allgather phase: ws-1 duplex steps circulating the fully-reduced
-  // chunks over `ring`; with shift s, this rank's ring position starts
+  // chunks over `ctx.ring`; with shift s, this rank's ring position starts
   // owning chunk (pos + 1 + s) mod ws.
-  void ring_allgather_phase(IoPtr io, ScatterView& view,
+  void ring_allgather_phase(RingCtx& ctx, ScatterView& view,
                             const std::vector<size_t>& bounds, size_t esz,
                             int64_t shift, TimePoint deadline,
-                            const std::vector<int64_t>& ring,
                             uint64_t tag_base) {
+    const IoPtr& io = ctx.io;
     NsTimer timed(&io->gather_ns);
-    int64_t ws = static_cast<int64_t>(ring.size());
-    int64_t pos = ring_pos(ring, io->rank);
-    int64_t right = ring[(pos + 1) % ws];
-    int64_t left = ring[(pos - 1 + ws) % ws];
+    const int64_t ws = ctx.ws, pos = ctx.pos;
     auto chunk_off = [&](int64_t i) {
       i = ((i % ws) + ws) % ws;
       return bounds[i] * esz;
@@ -2042,20 +2232,18 @@ class Communicator {
       i = ((i % ws) + ws) % ws;
       return (bounds[i + 1] - bounds[i]) * esz;
     };
-    std::vector<int> right_fds = peer_fds(right);
-    std::vector<int> left_fds = peer_fds(left);
     for (int64_t step = 0; step < ws - 1; ++step) {
       int64_t send_idx = pos + 1 + shift - step;
       int64_t recv_idx = pos + shift - step;
-      auto send_latch =
-          send_striped_async(io, right_fds, right, tag_base + 2000 + step,
-                             view, chunk_off(send_idx), chunk_bytes(send_idx),
-                             deadline);
+      auto send_latch = send_striped_async(
+          io, ctx.right_fds, ctx.right, tag_base + 2000 + step, view,
+          chunk_off(send_idx), chunk_bytes(send_idx), deadline);
       TimePoint own_done;
       try {
-        own_done = recv_striped(*io, left_fds, left, tag_base + 2000 + step,
-                                view, chunk_off(recv_idx),
-                                chunk_bytes(recv_idx), deadline);
+        own_done = recv_striped(*io, ctx.left_fds, ctx.left,
+                                tag_base + 2000 + step, view,
+                                chunk_off(recv_idx), chunk_bytes(recv_idx),
+                                deadline);
       } catch (...) {
         send_latch->wait_quiet();
         throw;
@@ -2207,6 +2395,9 @@ class Communicator {
   std::atomic<size_t> lanes_{1};
   std::atomic<size_t> stripe_floor_{kMinStripeBytes};
   std::atomic<bool> aborted_{false};
+  // bumped by every abort() (configure() un-latches aborted_ again, so a
+  // session that waits across both would miss the flag alone)
+  std::atomic<uint64_t> abort_gen_{0};
   // guards peers_/graveyard_/pool_/io_ STRUCTURE only — never held across
   // IO; ops snapshot the fds/pool/io they need at entry (fds stay open
   // until destruction, so a snapshot can never dangle; superseded pools

@@ -33,6 +33,18 @@
 //      verified.  89 pieces of 16 MiB are the four-chip cell's step.
 //      Runs at what ``auto`` resolves to with no link emulated
 //      (kUnshapedAutoLanes, PR 47): the count a deployment's rings run at.
+//      Rank 0 rings its pieces through ONE ring session (``run_session``:
+//      the pack thread pushes each piece as it has written it, a third
+//      thread waits for "piece k is rung" and verifies it at once), rank 1
+//      piece by piece through ``allreduce_iov``: the two ride the same rings.
+//
+//   C  ring sessions under abort + epoch-swap churn (after B) — every rank's
+//      op thread inside ``run_session`` while a pusher hands pieces over
+//      with naps between them and a waiter waits for each; the controller
+//      aborts every communicator mid-session (some ranks wait for a push,
+//      some are mid-ring), every run and every wait must RETURN, a piece
+//      that reports rung must verify, and after a re-rendezvous a clean
+//      session of the same shape must ring every piece.
 //
 // Phases A and B run at TORCHFT_RING_LANES=2 so the per-lane worker pool
 // and the lane-striped framing are engaged throughout; abort
@@ -289,21 +301,8 @@ void phase_p_rank(Communicator* comm, int rank, const std::string& store_addr,
       packed.store(k + 1, std::memory_order_release);
     }
   });
-  for (size_t k = 0; k < count; ++k) {
-    while (packed.load(std::memory_order_acquire) <= k)
-      std::this_thread::yield();
-    void* seg = leaf.data() + k * piece_elems;
-    uint64_t len = piece_elems * 2;
-    try {
-      comm->allreduce_iov(&seg, &len, 1, DT_BF16, OP_SUM, /*divisor=*/2);
-    } catch (const std::exception& ex) {
-      fail("phase P piece " + std::to_string(k) + ": " + ex.what());
-      break;
-    }
-  }
-  pack.join();
-  for (size_t k = 0; k < count; ++k) {
-    // (1 + k%5) + (2 + k%5) = 3 + 2 (k%5), halved: x.5 is a bfloat16
+  // (1 + k%5) + (2 + k%5) = 3 + 2 (k%5), halved: x.5 is a bfloat16
+  auto verify = [&](size_t k) {
     const uint16_t want = f32_to_bf16(1.5f + static_cast<float>(k % 5));
     const uint16_t* piece = leaf.data() + k * piece_elems;
     for (size_t i = 0; i < piece_elems; ++i) {
@@ -313,7 +312,112 @@ void phase_p_rank(Communicator* comm, int rank, const std::string& store_addr,
         break;
       }
     }
+  };
+  if (rank == 0) {
+    // the session's three threads: this one is the op thread
+    RingSession session(count, OP_SUM, /*divisor=*/2);
+    std::thread push([&] {
+      for (size_t k = 0; k < count; ++k) {
+        while (packed.load(std::memory_order_acquire) <= k)
+          std::this_thread::yield();
+        if (!session.push(leaf.data() + k * piece_elems, piece_elems * 2,
+                          DT_BF16))
+          break;  // the session failed: the run says why
+      }
+    });
+    std::thread gather([&] {
+      for (size_t k = 0; k < count; ++k) {
+        std::string why;
+        if (session.wait(k, 120.0, &why) != RingSession::kRung) {
+          fail("phase P session piece " + std::to_string(k) + ": " + why);
+          break;
+        }
+        verify(k);  // while the later pieces are still written and rung
+      }
+    });
+    try {
+      comm->run_session(session);
+    } catch (const std::exception& ex) {
+      fail(std::string("phase P session: ") + ex.what());
+    }
+    push.join();
+    gather.join();
+  } else {
+    for (size_t k = 0; k < count; ++k) {
+      while (packed.load(std::memory_order_acquire) <= k)
+        std::this_thread::yield();
+      void* seg = leaf.data() + k * piece_elems;
+      uint64_t len = piece_elems * 2;
+      try {
+        comm->allreduce_iov(&seg, &len, 1, DT_BF16, OP_SUM, /*divisor=*/2);
+      } catch (const std::exception& ex) {
+        fail("phase P piece " + std::to_string(k) + ": " + ex.what());
+        break;
+      }
+    }
   }
+  pack.join();
+  for (size_t k = 0; k < count; ++k) verify(k);
+}
+
+// ---------------------------------------------------------------------------
+// Phase C: ring sessions with abort() fired into them
+// ---------------------------------------------------------------------------
+
+constexpr size_t kSessionPieces = 12;
+std::atomic<size_t> g_session_rung{0};  // pieces reported rung, every rank's
+constexpr size_t kSessionFloats = 96 << 10;  // 384 KiB: two lanes engage
+
+// One session on this rank: a pusher (naps between pieces, so the run waits
+// for pushes), a waiter, and this thread as the op thread.  Returns how many
+// pieces reported rung (each verified).  `expect_all`: a failure is a fault.
+size_t phase_c_session(Communicator* comm, int rank, bool expect_all) {
+  std::vector<std::vector<float>> pieces(
+      kSessionPieces, std::vector<float>(kSessionFloats));
+  RingSession session(kSessionPieces, OP_SUM, /*divisor=*/kWorld);
+  std::thread push([&] {
+    for (size_t k = 0; k < kSessionPieces; ++k) {
+      // a piece a rank and a place: (rank + 1) * (k + 1)
+      std::fill(pieces[k].begin(), pieces[k].end(),
+                static_cast<float>((rank + 1) * (k + 1)));
+      std::this_thread::sleep_for(std::chrono::microseconds(300 * (rank + 1)));
+      if (!session.push(pieces[k].data(), kSessionFloats * 4, DT_F32)) break;
+    }
+    session.close();  // a push may be missing: the run must not wait for it
+  });
+  size_t rung = 0;
+  std::thread gather([&] {
+    for (size_t k = 0; k < kSessionPieces; ++k) {
+      std::string why;
+      auto got = session.wait(k, 60.0, &why);
+      if (got == RingSession::kTimedOut) {
+        fail("phase C wait for piece " + std::to_string(k) + " never returned");
+        return;
+      }
+      if (got != RingSession::kRung) {
+        if (expect_all)
+          fail("phase C clean session piece " + std::to_string(k) + ": " + why);
+        return;
+      }
+      const float want =
+          static_cast<float>(expected_sum(kWorld)) * (k + 1) / kWorld;
+      for (float v : pieces[k])
+        if (v != want) {
+          fail("phase C piece " + std::to_string(k) + " reported rung, corrupt");
+          break;
+        }
+      ++rung;
+      g_session_rung.fetch_add(1);
+    }
+  });
+  try {
+    comm->run_session(session);
+  } catch (const std::exception& ex) {
+    if (expect_all) fail(std::string("phase C clean session: ") + ex.what());
+  }
+  push.join();
+  gather.join();
+  return rung;
 }
 
 }  // namespace
@@ -412,6 +516,51 @@ int main(int argc, char** argv) {
     std::printf("stress_comm: phase B done (%d/%d epochs verified)\n",
                 verified_epochs, kPhaseBEpochs);
     check(verified_epochs == kPhaseBEpochs, "phase B epochs missed");
+  }
+
+  // --- phase C ---------------------------------------------------------
+  {
+    constexpr int kRounds = 4;
+    size_t rung_under_abort = 0;
+    for (int e = 1; e <= kRounds; ++e) {
+      std::vector<std::thread> cfg;
+      for (int r = 0; r < kWorld; ++r)
+        cfg.emplace_back([&, r] {
+          try {
+            comms[r]->configure(addr + "/stress_c_" + std::to_string(e), r,
+                                kWorld);
+          } catch (const std::exception& ex) {
+            fail("phase C configure rank " + std::to_string(r) + ": " +
+                 ex.what());
+          }
+        });
+      for (auto& t : cfg) t.join();
+      // a clean session first: every piece of every rank rung and verified
+      std::vector<size_t> rung(kWorld, 0);
+      std::vector<std::thread> ranks;
+      for (int r = 0; r < kWorld; ++r)
+        ranks.emplace_back(
+            [&, r] { rung[r] = phase_c_session(comms[r].get(), r, true); });
+      for (auto& t : ranks) t.join();
+      for (int r = 0; r < kWorld; ++r)
+        check(rung[r] == kSessionPieces, "phase C clean session stopped short");
+      // then one with abort() fired into it, a piece later every round
+      ranks.clear();
+      g_session_rung.store(0);
+      for (int r = 0; r < kWorld; ++r)
+        ranks.emplace_back(
+            [&, r] { rung[r] = phase_c_session(comms[r].get(), r, false); });
+      auto until = std::chrono::steady_clock::now() + std::chrono::seconds(30);
+      while (g_session_rung.load() < size_t(kWorld) * e &&
+             std::chrono::steady_clock::now() < until)
+        std::this_thread::sleep_for(std::chrono::microseconds(100));
+      for (auto& c : comms) c->abort();
+      for (auto& t : ranks) t.join();
+      for (int r = 0; r < kWorld; ++r) rung_under_abort += rung[r];
+    }
+    std::printf("stress_comm: phase C done (%d rounds; %zu of %zu pieces rung "
+                "before the aborts)\n", kRounds, rung_under_abort,
+                size_t(kRounds) * kWorld * kSessionPieces);
   }
 
   comms.clear();

@@ -59,7 +59,21 @@ NOT_ON_A_CPU_WALK = {
     "peak_hbm_gb": "the CPU's devices have no memory_stats",
     "peak_hbm_gb.ddp": "the CPU's devices have no memory_stats",
     "sync_second_submit_ms": "the toy tree is one bucket: its round trip has one submit",
+    **dict.fromkeys(
+        ("comm_op_ms", "ring_peer_skew_ms", "ring_beside_d2h_pct"),
+        "a round trip's rings are ONE call of the op thread (PR 60): no tpuft/comm/op in a trace, on any host",
+    ),
+    **dict.fromkeys(
+        ("sync_normalize_ms", "normalize_in_ring_pct"),
+        "no done-callback runs a bucket where the rings are a session (PR 60): no tpuft/manager/normalize",
+    ),
 }
+# the five of them that fell silent with PR 60's ring session, in every cell
+# that lists them (PERF.md section 3): ftbench/tests/ still expects them of a
+# walk, which only a `benchmark` PR may correct (PERF.md section 7 (cc))
+SILENT_IN_A_SESSION = frozenset(
+    ("comm_op_ms", "ring_peer_skew_ms", "ring_beside_d2h_pct", "sync_normalize_ms", "normalize_in_ring_pct")
+)
 
 
 def walk_reports(cell):

@@ -443,3 +443,47 @@ class TestNetEmu:
             os.environ.pop("TORCHFT_NET_GBPS", None)
             os.environ.pop("TORCHFT_NET_RTT_MS", None)
         assert results == [2.0, 2.0]
+
+
+# ----------------------------------------------------------------------
+# the per-call path stays where no ring session is offered (PR 60)
+# ----------------------------------------------------------------------
+
+
+@pytest.mark.parametrize(
+    "comm",
+    [TCPCommunicator(timeout_s=5.0), DummyCommunicator(), FakeCommunicatorWrapper(DummyCommunicator())],
+    ids=["tcp", "dummy", "wrapper"],
+)
+def test_no_tier_but_the_native_one_offers_a_ring_session(comm) -> None:
+    """The choice between the session and a ring an op is by what the
+    communicator offers: no class of this file does, and none is told to by a
+    knob, an environment variable or an argument."""
+    import inspect
+
+    from torchft_tpu import communicator, knobs
+
+    assert not hasattr(comm, "ring_session")
+    assert "ring_session" not in inspect.getsource(communicator)
+    assert "ring_session" not in inspect.getsource(knobs) and "RING_SESSION" not in inspect.getsource(knobs)
+    comm.shutdown()
+
+
+def test_the_python_tier_counts_its_ring_calls_and_no_wait_for_a_push(store) -> None:
+    """``lane_stats()`` carries the native tier's two new keys with this
+    tier's meaning: a call a ring, and an op thread that never waits for a
+    push (every buffer is an op of its own)."""
+
+    def _fn(comm, rank):
+        before = comm.lane_stats()
+        for _ in range(3):
+            comm.allreduce(np.ones(1000, np.float32), ReduceOp.SUM, divisor=2).wait(timeout=30.0)
+        # two dtypes: a ring each
+        comm.allreduce(
+            [np.ones(10, np.float32), np.ones(10, np.float64)], ReduceOp.SUM
+        ).wait(timeout=30.0)
+        return before, comm.lane_stats()
+
+    for before, after in _run_ranks(store, 2, _fn):
+        assert before["ring_calls"] == 0 and after["ring_calls"] == 5
+        assert before["ring_wait_push_s"] == after["ring_wait_push_s"] == 0.0

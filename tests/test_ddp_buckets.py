@@ -17,6 +17,7 @@ communicator the test holds, fails or lets through.
 """
 
 import threading
+import time
 import tracemalloc
 from concurrent.futures import Future, ThreadPoolExecutor
 from typing import Any, Callable, Dict, List, Optional
@@ -73,13 +74,13 @@ def _bits(x: Any) -> bytes:
 
 
 class _Pair:
-    def __init__(self, lighthouse_addr: str) -> None:
+    def __init__(self, lighthouse_addr: str, comm: Callable[[], Any] = lambda: TCPCommunicator(timeout_s=10.0)) -> None:
         self.managers: List[Manager] = []
         for r in range(2):
             state = {"w": np.zeros(3, np.float32)}
             self.managers.append(
                 Manager(
-                    comm=TCPCommunicator(timeout_s=10.0),
+                    comm=comm(),
                     load_state_dict=state.update,
                     state_dict=lambda state=state: dict(state),
                     min_replica_size=2,
@@ -1233,3 +1234,263 @@ def test_a_manager_that_is_shut_down_waits_for_its_gather_threads(solo, ends) ->
         assert len(_alive()) == 1 and 0.25 <= waited < 4.0
         solo.comm.release()
         _gathers_done()
+
+
+# ----------------------------------------------------------------------
+# the native tier: a round trip's rings are ONE call of the op thread (PR 60)
+# ----------------------------------------------------------------------
+
+
+@pytest.fixture()
+def native_pair(lighthouse_addr, monkeypatch):
+    from torchft_tpu import native
+
+    if not native.available():
+        pytest.skip("native runtime unavailable")
+    monkeypatch.setenv(BUCKET_CAP_MB_ENV, str(2048 / (1 << 20)))
+    p = _Pair(lighthouse_addr, comm=lambda: native.CppCommunicator(timeout_s=10.0))
+    yield p
+    p.shutdown()
+
+
+@pytest.mark.parametrize("case", ["same_tree", "non_participating_step", "cap_cuts_every_leaf", "buckets_differ_30_times"])
+def test_the_session_s_steps_are_bit_equal_to_the_parents_formula(native_pair, monkeypatch, case) -> None:
+    """What ``test_six_steps_are_bit_equal_to_the_parents_formula`` holds the
+    per-call path to, through the session: the same bits, ONE ring call a
+    round trip, no ``Manager.allreduce`` at all, the buckets kept and given
+    back as before."""
+    pair = native_pair
+    rngs = [np.random.default_rng(10 + r) for r in range(2)]
+    cap = 24 if case == "cap_cuts_every_leaf" else 2048
+    monkeypatch.setenv(BUCKET_CAP_MB_ENV, str(cap / (1 << 20)))
+    for step in range(STEPS):
+        if step == CHANGES_AT and case == "non_participating_step":
+            monkeypatch.setattr(pair.managers[1], "is_participating", lambda: False)
+        elif case == "non_participating_step":
+            monkeypatch.undo()  # (the fixture's too)
+            monkeypatch.setenv(BUCKET_CAP_MB_ENV, str(2048 / (1 << 20)))
+        trees = [_tree(rng, 3000 if case == "buckets_differ_30_times" else 300) for rng in rngs]
+        outs = pair.step(trees)
+        want = _expected(trees, pair.participating)
+        assert pair.participating == [True, step != 0 and (step, case) != (CHANGES_AT, "non_participating_step")]
+        for r, out in enumerate(outs):
+            for g, w in zip(jax.tree_util.tree_leaves(out), want):
+                assert np.asarray(g).dtype == w.dtype and _bits(g) == _bits(w), (case, step, r)
+    for r, m in enumerate(pair.managers):
+        assert m.errored() is None and pair.handed[r] == []  # no ring was an op of its own
+        syncs = _syncs(m)
+        assert len(syncs) == STEPS
+        # (a life's first round trip may begin before its quorum is adopted
+        # and records no counters; the later ones do)
+        for e in syncs[2:]:
+            assert e["ring_calls"] == 1 and e["buckets"] > 1 and e["ring_bytes"] > 0
+            assert e["ring_wait_push_s"] >= 0.0
+            phases = e["ring_reduce_s"] + e["ring_average_s"] + e["ring_gather_s"]
+            assert phases + e["ring_wait_push_s"] <= e["duration_s"] + 1e-3
+        assert syncs[-1]["warm_buckets"] == syncs[-1]["buckets"]
+        assert len(_newest_plan(m).free) >= 1  # the sets came back
+
+
+def test_the_session_s_spans_are_the_per_call_path_s(native_pair) -> None:
+    """One ``tpuft/comm/op`` a bucket with ``k`` rising from 0 in every
+    step, as the op thread opened them when each ring was an op, inside ONE
+    live ``tpuft/comm/session``; no ``tpuft/manager/normalize`` (the ring
+    has divided and no callback runs a bucket: nothing to time)."""
+    from torchft_tpu.obs import spans as obs_spans
+
+    obs_spans.configure(True, cap=1 << 15)
+    try:
+        rngs = [np.random.default_rng(60 + r) for r in range(2)]
+        for _ in range(3):
+            native_pair.step([_tree(rng) for rng in rngs])
+        spans = obs_spans.snapshot()
+    finally:
+        obs_spans.configure(None)
+        obs_spans.clear()
+    for m in native_pair.managers:
+        sync = _syncs(m)[-1]
+        mine = [s for s in spans if s["attrs"].get("r") == m._flight.replica_id and s["attrs"].get("step") == sync["step"]]
+        ops = sorted((s for s in mine if s["name"] == "tpuft/comm/op"), key=lambda s: s["t"])
+        assert [s["attrs"]["k"] for s in ops] == list(range(sync["buckets"]))
+        (live,) = [s for s in mine if s["name"] == "tpuft/comm/session"]
+        assert live["attrs"]["pieces"] == sync["buckets"]
+        assert all(live["t"] <= s["t"] and s["t"] + s["dur"] <= live["t"] + live["dur"] + 1e-6 for s in ops)
+        assert not [s for s in mine if s["name"] == "tpuft/manager/normalize"]
+        # a bucket's ring begins after its pack has ended, and its wait ends after its ring has
+        packs = {s["attrs"]["bucket"]: s for s in mine if s["name"] == "tpuft/ddp/pack"}
+        waits = {s["attrs"]["bucket"]: s for s in mine if s["name"] == "tpuft/ddp/ring_wait"}
+        for b, op in enumerate(ops):
+            assert packs[b]["t"] + packs[b]["dur"] <= op["t"] + 1e-4
+            assert op["t"] + op["dur"] <= waits[b]["t"] + waits[b]["dur"] + 1e-4
+
+
+def test_a_streamed_round_trip_registers_the_composite_alone(native_pair) -> None:
+    """``stream=``: the one work in the stream-fence registry is the
+    composite; the session's pieces register nowhere."""
+    rngs = [np.random.default_rng(70 + r) for r in range(2)]
+    trees = [_tree(rng) for rng in rngs]
+
+    def _one(r: int) -> Any:
+        m = native_pair.managers[r]
+        m.start_quorum()
+        work = allreduce_pytree(m, trees[r], stream=3)
+        with m._pending_works_lock:
+            assert m._pending_works == [] and list(m._stream_pending) == [3]
+            assert m._stream_pending[3][0] is work
+        out = work.wait(timeout=30.0)
+        assert m.stream_unresolved() == []
+        assert m.should_commit()
+        m.stream_resolved(3, True)
+        return out
+
+    outs = [f.result(timeout=60.0) for f in [native_pair._pool.submit(_one, r) for r in range(2)]]
+    _gathers_done()
+    want = _expected(trees, [True, False])  # replica 1 heals in its life's first step
+    for out in outs:
+        assert [_bits(g) for g in jax.tree_util.tree_leaves(out)] == [_bits(w) for w in want]
+    assert all(pair_handed == [] for pair_handed in native_pair.handed)
+
+
+def test_a_session_that_fails_keeps_its_buckets_and_is_heard_once(native_pair, monkeypatch) -> None:
+    """A piece fails mid round trip (replica 0's communicator is aborted
+    under its third push): that piece and every later one fail on both
+    replicas, ``report_error`` hears ONE error a replica, the vote discards
+    the step, the set is never handed out again, and the next step serves in
+    fresh memory through a session again."""
+    pair = native_pair
+    rngs = [np.random.default_rng(80 + r) for r in range(2)]
+    trees = [_tree(rng, 3000) for rng in rngs]  # 26 buckets
+    for _ in range(2):
+        pair.step(trees)
+    plans = [_newest_plan(m) for m in pair.managers]
+    kept = [[id(b) for bufs in plan.free for b in bufs] for plan in plans]
+    assert all(kept)
+    heard: List[List[BaseException]] = [[], []]
+    for r, m in enumerate(pair.managers):
+        inner = m.report_error
+        monkeypatch.setattr(m, "report_error", lambda e, inner=inner, r=r: (heard[r].append(e), inner(e))[1])
+    open_session, pushes = pair.managers[0].ring_session, []
+    opened: List[Any] = [None, None]
+    other = pair.managers[1].ring_session
+    monkeypatch.setattr(
+        pair.managers[1], "ring_session", lambda pieces: opened.__setitem__(1, other(pieces)) or opened[1]
+    )
+
+    def _aborting(pieces: int):
+        session = opened[0] = open_session(pieces)
+        push = session.push
+
+        def _push(flat):
+            pushes.append(flat)
+            if len(pushes) == 3:
+                pair.managers[0]._comm.abort("injected under the third push")
+            push(flat)
+
+        session.push = _push
+        return session
+
+    monkeypatch.setattr(pair.managers[0], "ring_session", _aborting)
+
+    def _one(r: int):
+        m = pair.managers[r]
+        m.start_quorum()
+        out = allreduce_pytree(m, trees[r]).wait(timeout=30.0)
+        return out, m.should_commit()
+
+    results = [f.result(timeout=60.0) for f in [pair._pool.submit(_one, r) for r in range(2)]]
+    _gathers_done()
+    monkeypatch.setattr(pair.managers[0], "ring_session", open_session)
+    monkeypatch.setattr(pair.managers[1], "ring_session", other)
+    for r, (out, committed) in enumerate(results):
+        # the session's error, whichever way it came (the run's, a wait's, or
+        # the abort's where the run had not begun), was reported ONCE by the
+        # session; ``should_commit`` reports what the communicator latched
+        # besides, as it always did (the same object where the run latched it)
+        mine = opened[r].swallowed
+        assert not committed and mine is not None and 1 <= len(heard[r]) <= 2, heard[r]
+        assert sum(e is mine for e in heard[r]) in (1, len(heard[r])) and any(e is mine for e in heard[r])
+        assert _syncs(pair.managers[r])[-1]["warm_buckets"] > 0
+        # the failed round trip's set did not come back
+        assert [id(b) for bufs in plans[r].free for b in bufs] == kept[r][: len(kept[r]) - len(plans[r].buffers)]
+    # the next steps: a new quorum, fresh memory, the session again
+    outs = pair.step(trees)
+    want = _expected(trees, pair.participating)
+    for out in outs:
+        assert [_bits(g) for g in jax.tree_util.tree_leaves(out)] == [_bits(w) for w in want]
+    for m in pair.managers:
+        assert _syncs(m)[-1]["warm_buckets"] == 0 and m.errored() is None
+
+
+def test_a_train_thread_that_raises_mid_round_trip_closes_the_session(native_pair, monkeypatch) -> None:
+    """The pack of the fourth bucket raises on both replicas: the exception
+    is the caller's, the session is closed in the same breath (the buckets
+    not pushed never start, as rings never submitted), the op thread leaves
+    it, and the next step serves."""
+    pair = native_pair
+    rngs = [np.random.default_rng(90 + r) for r in range(2)]
+    trees = [_tree(rng) for rng in rngs]
+    pair.step(trees)
+    inner = ddp._pack
+
+    def _raising(bucket, flat, hosts):
+        if bucket is _newest_plan_of[threading.get_ident()].buckets[3]:
+            raise RuntimeError("the pack broke")
+        return inner(bucket, flat, hosts)
+
+    _newest_plan_of: Dict[int, Any] = {}
+    monkeypatch.setattr(ddp, "_pack", _raising)
+
+    def _one(r: int) -> None:
+        m = pair.managers[r]
+        _newest_plan_of[threading.get_ident()] = _newest_plan(m)
+        m.start_quorum()
+        with pytest.raises(RuntimeError, match="the pack broke"):
+            allreduce_pytree(m, trees[r])
+        deadline = time.monotonic() + 10.0
+        while m._comm.busy() and time.monotonic() < deadline:
+            time.sleep(0.01)
+        assert not m._comm.busy()  # the op thread is out of the session
+        m.report_error(RuntimeError("the caller funnels what it caught"))
+        assert not m.should_commit()
+
+    for f in [pair._pool.submit(_one, r) for r in range(2)]:
+        f.result(timeout=60.0)
+    monkeypatch.setattr(ddp, "_pack", inner)
+    outs = pair.step(trees)
+    want = _expected(trees, pair.participating)
+    for out in outs:
+        assert [_bits(g) for g in jax.tree_util.tree_leaves(out)] == [_bits(w) for w in want]
+
+
+def test_the_per_call_path_serves_what_the_session_does_not(native_pair, monkeypatch) -> None:
+    """``should_quantize`` and an error already recorded keep the per-call
+    path on the native tier too; the choice is the call's and the
+    communicator's, and ``Manager.ring_session`` takes no argument but the
+    count."""
+    import inspect
+
+    assert list(inspect.signature(Manager.ring_session).parameters) == ["self", "pieces"]
+    pair = native_pair
+    rngs = [np.random.default_rng(95 + r) for r in range(2)]
+    trees = [{"w": jnp.asarray(rng.standard_normal((64, 32)).astype(np.float32))} for rng in rngs]
+    opened = []
+    for m in pair.managers:
+        inner = m.ring_session
+        monkeypatch.setattr(m, "ring_session", lambda pieces, inner=inner: opened.append(pieces) or inner(pieces))
+
+    def _quantized(r: int) -> Any:
+        m = pair.managers[r]
+        m.start_quorum()
+        # (numpy leaves: the bucketed path with the int8 wire, not the device quantizer)
+        out = allreduce_pytree(m, jax.tree_util.tree_map(np.asarray, trees[r]), should_quantize=True).wait(timeout=30.0)
+        assert m.should_commit()
+        return out
+
+    for f in [pair._pool.submit(_quantized, r) for r in range(2)]:
+        f.result(timeout=60.0)
+    _gathers_done()
+    assert opened == [] and all(len(h) >= 1 for h in pair.handed)
+    # an error recorded before the round trip: no session is opened over it
+    m = pair.managers[0]
+    m.report_error(RuntimeError("earlier in the step"))
+    assert Manager.ring_session(m, 4) is None

@@ -11,7 +11,9 @@ import pytest
 from ftbench.tests import test_ftbench_program_spans as theirs
 from ftbench.tests.test_ftbench_program_spans import *  # noqa: F401,F403
 from ftbench.tests.test_ftbench_rehearsal import _lines
-from tests._ftbench_view import bench, device_trace_readers, reader_entry, traced_walk, walk_reports
+from tests._ftbench_view import (
+    SILENT_IN_A_SESSION, bench, device_trace_readers, reader_entry, traced_walk, walk_reports,
+)
 
 # PR 27's reader on theirs' synthetic planes: the division by the participant
 # count, two spans of 40 ms a step
@@ -33,7 +35,10 @@ def test_rehearsal_would_report_the_program_span_metrics(cell, new, monkeypatch)
     ``_rehearse`` kept for every test that reads it), and of the same walk:
     it reports every reader of today that lists the cell and can be read on a
     CPU, and none of the device's trace (no device plane: PR 37's readers of
-    the scopes find nothing and say so)."""
+    the scopes find nothing and say so).  Theirs still lists the five readers
+    that fell silent with PR 60's ring session; the walk must NOT report them
+    (a reader that found a ``tpuft/comm/op`` in a trace again would say the
+    op thread left its one call)."""
     from ftbench import device_scopes
 
     root, done = traced_walk(cell)
@@ -45,10 +50,42 @@ def test_rehearsal_would_report_the_program_span_metrics(cell, new, monkeypatch)
         return set(last["would_report"])
 
     monkeypatch.setattr(theirs, "_rehearse", rehearsed)
-    theirs.test_rehearsal_would_report_the_program_span_metrics(cell, new, root)
     reported = rehearsed(cell, root)
+    if cell in STEADY_CELLS:
+        # theirs, line for line, but for the two spans a session's round trip
+        # does not have and the one it has in their place
+        assert new - SILENT_IN_A_SESSION <= reported and not reported & SILENT_IN_A_SESSION
+        assert not reported & {"flash_fwd_ms", "flash_dq_ms", "flash_dkv_ms"}
+        spans = theirs.program_spans.load(str(root / "ftbench"))
+        mine = theirs.program_spans.of_replica(spans, 0)
+        names = {s["name"] for s in mine}
+        assert names >= {
+            "tpuft/step/grad", "tpuft/step/update", "tpuft/manager/quorum", "tpuft/manager/fence",
+            "tpuft/manager/should_commit", "tpuft/comm/session",
+            "tpuft/ddp/allreduce_pytree", "tpuft/ddp/plan", "tpuft/ddp/d2h", "tpuft/ddp/pack",
+            "tpuft/ddp/submit", "tpuft/ddp/ring_wait", "tpuft/ddp/h2d",
+        }
+        assert not names & {"tpuft/comm/op", "tpuft/manager/normalize"}
+        assert all(isinstance(s.get("step"), int) for s in mine)
+        assert theirs.program_spans.of_replica(spans, 1)
+        trips = theirs.program_spans.sync_round_trips(dict(trace=None), spans=spans)
+        assert trips and all(0.0 <= unnamed <= whole for whole, unnamed in trips)
+    else:
+        theirs.test_rehearsal_would_report_the_program_span_metrics(cell, new, root)
     assert walk_reports(cell) <= reported and not reported & device_trace_readers()
     assert device_scopes.load(str(root / "ftbench")) == {}
+
+
+def test_sharded_leaves_of_two_groups_of_fsdp_2_through_allreduce_pytree(two_groups_of_two, monkeypatch):  # noqa: F405,F811
+    """Theirs counts one ``tpuft/manager/normalize`` a collective, which is the
+    PER-CALL path's (PR 40): held there, a ring an op, as the Python tier and a
+    quantized call still run it.  Through the session the same sharded leaves
+    are the four-chip cell's on the chip, and ``tests/test_ddp_buckets.py``
+    holds the session's bits and spans."""
+    from torchft_tpu.manager import Manager
+
+    monkeypatch.setattr(Manager, "ring_session", lambda self, pieces: None)
+    theirs.test_sharded_leaves_of_two_groups_of_fsdp_2_through_allreduce_pytree(two_groups_of_two, monkeypatch)
 
 
 def _sync(t, warm=None, buckets=10, name="DDP_SYNC"):

@@ -1002,3 +1002,324 @@ def test_cross_implementation_rendezvous() -> None:
         np.testing.assert_allclose(results[1], np.full(64, 3.0))
     finally:
         store.shutdown()
+
+
+# ----------------------------------------------------------------------
+# a round trip's rings as ONE native call (comm.h RingSession)
+# ----------------------------------------------------------------------
+
+
+def _pieces(rank: int, dtype, sizes) -> List[np.ndarray]:
+    """One flat array a piece, values that differ by rank and place."""
+    import ml_dtypes  # noqa: F401 — numpy learns bfloat16
+
+    rng = np.random.default_rng(700 + rank)
+    return [rng.standard_normal(n).astype(np.float32).astype(dtype) for n in sizes]
+
+
+def _ring_each(comm, pieces: List[np.ndarray], divisor) -> None:
+    """The per-piece path: a ring an op, in place."""
+    for a in pieces:
+        comm.allreduce(a, ReduceOp.SUM, in_place=True, divisor=divisor).wait(timeout=30.0)
+
+
+def _ring_session(comm, pieces: List[np.ndarray], divisor, nap: float = 0.0) -> native.RingSession:
+    """The same pieces through one session, each waited for as the gather
+    thread would."""
+    session = comm.ring_session(len(pieces), divisor)
+    assert session is not None
+    for a in pieces:
+        if nap:
+            time.sleep(nap)
+        assert session.push(a)
+    for k in range(len(pieces)):
+        session.wait(k, timeout=30.0)
+    session.work.wait(timeout=30.0)
+    return session
+
+
+# unequal sizes: one element, one under two stripe floors (it rides lane 0
+# whole), one that stripes over every lane, one that no ring size divides
+SESSION_SIZES = (1, 40_000, 300_007, 65_536, 13)
+
+
+class TestRingSession:
+    @pytest.mark.parametrize("divided", [False, True], ids=["sum", "average"])
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    @pytest.mark.parametrize("world_size", [2, 3, 4])
+    def test_the_session_s_bytes_are_the_per_piece_ring_s(
+        self, cpp_store, monkeypatch, world_size, dtype, divided
+    ) -> None:
+        monkeypatch.setenv("TORCHFT_RING_LANES", "4")
+        monkeypatch.setenv("TORCHFT_RING_FRAME_KB", "64")
+        divisor = world_size - 1 if divided and world_size > 2 else (2 if divided else None)
+
+        def _fn(through):
+            def _one(comm, rank):
+                pieces = _pieces(rank, dtype, SESSION_SIZES)
+                through(comm, pieces, divisor)
+                return [a.tobytes() for a in pieces], comm.lane_stats()
+            return _one
+
+        # (a rendezvous of its own each: the store keeps the first one's keys)
+        everyone = set(range(world_size))
+        each = _run_mixed_ranks(cpp_store, world_size, everyone, _fn(_ring_each), "each")
+        once = _run_mixed_ranks(cpp_store, world_size, everyone, _fn(_ring_session), "once")
+        for rank in range(world_size):
+            assert once[rank][0] == each[rank][0], rank
+            assert once[rank][0] == once[0][0]  # and every rank holds the same
+            # ONE native ring call, and the same bytes on the same lanes
+            assert once[rank][1]["ring_calls"] == 1 and each[rank][1]["ring_calls"] == len(SESSION_SIZES)
+            assert once[rank][1]["lane_tx_bytes"] == each[rank][1]["lane_tx_bytes"]
+
+    @pytest.mark.parametrize("lanes", [1, 4])
+    def test_a_session_peer_rides_with_a_per_piece_peer_and_a_python_peer(
+        self, cpp_store, monkeypatch, lanes
+    ) -> None:
+        """Rank 0 inside a session, rank 1 on the native per-piece path, rank
+        2 on the Python tier: one ring, the bits of an all-Python mesh."""
+        import ml_dtypes  # noqa: F401
+
+        monkeypatch.setenv("TORCHFT_RING_LANES", str(lanes))
+        monkeypatch.setenv("TORCHFT_RING_FRAME_KB", "64")
+
+        def _fn(comm, rank):
+            pieces = _pieces(rank, "bfloat16", SESSION_SIZES)
+            if rank == 0 and isinstance(comm, native.CppCommunicator):
+                _ring_session(comm, pieces, 3, nap=0.002)
+            else:
+                _ring_each(comm, pieces, 3)
+            return [a.tobytes() for a in pieces]
+
+        mixed = _run_mixed_ranks(cpp_store, 3, {0, 1}, _fn, f"sess_mix_{lanes}")
+        ref = _run_mixed_ranks(cpp_store, 3, set(), _fn, f"sess_ref_{lanes}")
+        assert mixed == ref
+
+    def test_pushes_that_arrive_late_are_waited_for_outside_the_phases(self, cpp_store, monkeypatch) -> None:
+        monkeypatch.setenv("TORCHFT_RING_LANES", "2")
+        nap, sizes = 0.03, (50_000,) * 6
+
+        def _fn(comm, rank):
+            before = comm.lane_stats()
+            t0 = time.monotonic()
+            session = _ring_session(comm, _pieces(rank, "float32", sizes), 2, nap=nap)
+            wall = time.monotonic() - t0
+            return before, comm.lane_stats(), wall, session.times()
+
+        for before, after, wall, times in _run_ranks(cpp_store, 2, _fn):
+            assert before["ring_wait_push_s"] == 0.0
+            waited = after["ring_wait_push_s"]
+            phases = sum(after[k] - before[k] for k in ("ring_reduce_s", "ring_average_s", "ring_gather_s"))
+            rings = sum(t1 - t0 for t0, t1 in times)
+            # the op thread waited about a nap a piece, and that wait lies in
+            # neither phase nor the tail: the three add up inside the wall
+            assert waited > 0.5 * nap * len(sizes)
+            assert phases <= rings + 1e-4 and after["ring_tail_s"] - before["ring_tail_s"] <= phases
+            assert waited + rings <= wall + 1e-3
+            # a piece's ring never starts before its predecessor's has ended
+            assert all(a1 <= b0 for (_, a1), (b0, _) in zip(times, times[1:]))
+            assert len(times) == len(sizes) and all(t0 < t1 for t0, t1 in times)
+
+    def test_close_with_pieces_unpushed(self, cpp_store) -> None:
+        def _fn(comm, rank):
+            pieces = _pieces(rank, "float32", (1000, 2000))
+            session = comm.ring_session(5, 2)
+            for a in pieces:
+                assert session.push(a)
+            session.close()
+            assert not session.push(np.zeros(8, np.float32))  # a no-op now
+            session.work.wait(timeout=30.0)  # the run ended without an error
+            session.wait(1, timeout=5.0)
+            with pytest.raises(native.CommunicatorError, match="ended before"):
+                session.wait(2, timeout=5.0)
+            # the communicator is whole: the next op runs
+            out = comm.allreduce(np.ones(4, np.float32), ReduceOp.SUM).wait(timeout=30.0)
+            return [a.tobytes() for a in pieces], out.tolist()
+
+        got = _run_ranks(cpp_store, 2, _fn)
+        assert got[0] == got[1] and got[0][1] == [2.0] * 4
+
+    @pytest.mark.parametrize("where", ["run_waits_for_a_push", "wait_waits_for_a_piece"])
+    @pytest.mark.parametrize("what", ["abort", "peer_death", "timeout"])
+    def test_a_failure_wakes_the_run_and_every_wait(self, cpp_store, what, where) -> None:
+        """Rank 0's session meets the failure: while its run waits for a push
+        that never comes (its peer is mid-ring on a piece it never pushed)
+        or while piece 0 is in its ring and the peer never pushes its own.
+        The first error fails that piece and every later one, later pushes
+        are no-ops, and the next epoch serves."""
+        pushes = where == "wait_waits_for_a_piece"
+        barrier = threading.Barrier(2)
+        seen: dict = {}
+
+        def _fn(rank: int) -> None:
+            comm = native.CppCommunicator(timeout_s=1.5 if what == "timeout" else 20.0)
+            comm.configure(f"127.0.0.1:{cpp_store.port}/fail_{what}_{where}", f"r{rank}", rank, 2)
+            session = comm.ring_session(3, 2)
+            assert session is not None
+            # who pushes: in the one case rank 0 alone (its ring waits for a
+            # peer that never comes), in the other rank 1 alone (rank 0's run
+            # waits for a push while its peer's ring waits for it)
+            if pushes == (rank == 0):
+                assert session.push(np.ones(100_000, np.float32))
+            barrier.wait()
+            if rank == 1:
+                if what == "peer_death":
+                    time.sleep(0.2)
+                    comm.shutdown()
+                elif what == "abort":
+                    time.sleep(5.0)
+                    comm.shutdown()
+                else:
+                    with pytest.raises(Exception):
+                        session.wait(0, timeout=20.0)
+                    comm.shutdown()
+                return
+            if what == "abort":
+                threading.Timer(0.2, comm.abort, args=("injected",)).start()
+            t0 = time.monotonic()
+            if what == "timeout" and not pushes:
+                # nobody is late but the train thread: a run that waits for a
+                # push has no deadline (a slow landing is no hanging ring);
+                # the waiter's own limit passes and the close lets the run go
+                with pytest.raises(TimeoutError):
+                    session.wait(0, timeout=0.3)
+                session.close()
+                assert session.work.exception(timeout=10.0) is None
+                with pytest.raises(native.CommunicatorError, match="ended before"):
+                    session.wait(0, timeout=5.0)
+            else:
+                if what == "peer_death" and not pushes:
+                    # a run that waits for a push reads no socket: the death
+                    # is the next ring's to find, as on the per-piece path
+                    with pytest.raises(TimeoutError):
+                        session.wait(0, timeout=1.0)
+                    assert session.push(np.ones(100_000, np.float32))
+                with pytest.raises(native.CommunicatorError):
+                    session.wait(0, timeout=15.0)
+                assert session.work.exception(timeout=10.0) is not None
+                with pytest.raises(native.CommunicatorError):
+                    session.wait(2, timeout=5.0)  # and every later one
+                assert comm.errored() is not None
+            seen["took"] = time.monotonic() - t0
+            assert not session.push(np.ones(8, np.float32))  # a no-op
+            # a new epoch serves
+            comm.configure(f"127.0.0.1:{cpp_store.port}/fail_{what}_{where}_b", "r0", 0, 1)
+            seen["next"] = comm.allreduce(np.full(4, 2.0, np.float32)).wait(timeout=10.0).tolist()
+            comm.shutdown()
+
+        threads = [threading.Thread(target=_fn, args=(r,)) for r in range(2)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60.0)
+            assert not t.is_alive()
+        assert seen["next"] == [2.0] * 4 and seen["took"] < 10.0
+
+    def test_no_session_where_there_is_no_ring_or_no_op_thread(self, cpp_store) -> None:
+        comm = native.CppCommunicator(timeout_s=5.0)
+        assert comm.ring_session(3, 2) is None  # not configured: one member
+        comm.configure(f"127.0.0.1:{cpp_store.port}/solo", "r0", 0, 1)
+        assert comm.ring_session(3, 2) is None  # a ring of one
+        comm.shutdown()
+
+    @pytest.mark.parametrize(
+        "looks,aborts",
+        [
+            ([(0, 1), (0, 1)], 1),  # pushed, at the head, and a whole timeout later still not rung
+            ([(0, 0), (0, 0), (0, 0)], 0),  # nothing pushed: a slow landing is no ring that hangs
+            ([(0, 0), (0, 1), (1, 1)], 0),  # pushed after the last look, rung by the next
+            ([(0, 0), (0, 1), (0, 1)], 1),  # pushed after a look: it has had a whole timeout at the next but one
+            ([(1, 2), (2, 3), (3, 4)], 0),  # the pieces move
+            ([(3, 3), (3, 3)], 0),  # everything pushed is rung: the call waits for a push or a close
+        ],
+    )
+    def test_the_session_s_watch_aborts_a_piece_that_outlives_its_deadline_alone(self, monkeypatch, looks, aborts) -> None:
+        """The op watchdog's stand-in over a session's one call looks at the
+        pieces' progress once a timeout: only a piece that was pushed and at
+        the head at the LAST look and is still not rung is a hang (C's own
+        deadline is then overdue)."""
+        timers = []
+
+        class _Handle:
+            def cancel(self):
+                pass
+
+        monkeypatch.setattr(native, "schedule_timeout", lambda delay, fn: timers.append(fn) or _Handle())
+
+        class _Session:
+            pushed = 0
+
+            def rung(self):
+                return self._rung
+
+        session, heard = _Session(), []
+        watch = native._SessionWatch(session, 5.0, heard.append)
+        for rung, pushed in looks:
+            session._rung, session.pushed = rung, pushed
+            if timers:
+                timers.pop()()
+        assert len(heard) == aborts and (not heard or "piece 0" in heard[0])
+        assert len(timers) == (0 if aborts else 1)  # armed anew until it aborts
+        watch.cancel()
+        if timers:
+            timers.pop()()  # a look after the call returned does nothing
+        assert len(heard) == aborts and not timers
+
+    def test_one_comm_op_span_a_piece_with_rising_k(self, cpp_store) -> None:
+        """The op thread is inside one call (``tpuft/comm/session``); the
+        pieces' spans are told when it returns, from the times C kept, in the
+        span buffer alone (a profiler's trace: the test below)."""
+        from torchft_tpu.obs import spans as obs_spans
+
+        obs_spans.configure(True)
+        obs_spans.clear()
+        try:
+            sizes = (1000, 5000, 70_000, 9)
+
+            def _fn(comm, rank):
+                _ring_session(comm, _pieces(rank, "float32", sizes), 2)
+                _ring_each(comm, _pieces(rank, "float32", (64,)), 2)
+                return comm._op_thread.ident
+
+            idents = _run_ranks(cpp_store, 2, _fn)
+            for ident in idents:
+                mine = [s for s in obs_spans.snapshot() if s["tid"] == ident]
+                ops = [s for s in mine if s["name"] == "tpuft/comm/op"]
+                (live,) = [s for s in mine if s["name"] == "tpuft/comm/session"]
+                # the session's four pieces, then the op after it: k goes on
+                first = live["attrs"]["k"]  # (1 where no recorder tells the op thread the step)
+                assert [s["attrs"]["k"] for s in ops] == [first + i for i in range(5)]
+                assert live["attrs"]["pieces"] == len(sizes)
+                assert all(s["attrs"]["tier"] == "cpp" for s in ops)
+                inside = ops[:4]
+                assert all(live["t"] <= s["t"] and s["t"] + s["dur"] <= live["t"] + live["dur"] + 1e-6 for s in inside)
+                assert all(a["t"] + a["dur"] <= b["t"] + 1e-9 for a, b in zip(inside, inside[1:]))
+        finally:
+            obs_spans.configure(None)
+            obs_spans.clear()
+
+    def test_a_profiler_s_trace_shows_the_session_and_none_of_its_pieces(self, cpp_store, tmp_path) -> None:
+        """A span that has already ended cannot be annotated: the trace has
+        the op thread's one live ``tpuft/comm/session`` a rank and no
+        ``tpuft/comm/op`` (whoever reads those from a trace finds none where
+        the session ran)."""
+        import glob
+
+        import jax
+        from jax.profiler import ProfileData
+
+        sizes = (1000, 5000, 70_000)
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            _run_ranks(cpp_store, 2, lambda comm, rank: _ring_session(comm, _pieces(rank, "float32", sizes), 2))
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb"))
+        names = [
+            ev.name
+            for plane in ProfileData.from_file(path).planes
+            for line in plane.lines
+            for ev in line.events
+            if ev.name.startswith("tpuft/comm/")
+        ]
+        assert names.count("tpuft/comm/session") == 2 and "tpuft/comm/op" not in names

@@ -85,6 +85,10 @@ _LANE_STAT_KEYS = (
     "ring_average_s",
     "ring_gather_s",
     "ring_tail_s",
+    # a ring session's wait for the next push (0.0 on the Python tier, which
+    # has no session) and the ring calls the op thread made
+    "ring_wait_push_s",
+    "ring_calls",
 )
 
 

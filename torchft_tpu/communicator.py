@@ -1113,6 +1113,7 @@ class _TcpMesh:
         self.ring_average_s = 0.0
         self.ring_gather_s = 0.0
         self.ring_tail_s = 0.0
+        self.ring_calls = 0  # rings of more than one member the op thread ran
         # gray-failure machinery: fault program (env or runtime-armed),
         # in-epoch lane recovery knobs + counters, per-(peer, lane)
         # completed-sub-frame sequence counters the reconnect/failover
@@ -2964,7 +2965,11 @@ class TCPCommunicator(Communicator):
         whole and reduced to the step's end.  Where another transport
         carries a leg of a ring (the hierarchical topology's shared-memory
         reduce before the leaders' ring and broadcast after it), its time
-        lies in that phase's counter and in no lane's."""
+        lies in that phase's counter and in no lane's.  ``ring_calls``: the
+        rings this epoch's op thread ran (one an ``allreduce``'s dtype group);
+        ``ring_wait_push_s``: 0.0, always (the native tier's ring session
+        counts there how long its op thread waited for the next buffer; this
+        tier rings every buffer as an op of its own)."""
         mesh = self._mesh
         if mesh is None:
             return {}
@@ -2981,6 +2986,10 @@ class TCPCommunicator(Communicator):
             "ring_average_s": mesh.ring_average_s,
             "ring_gather_s": mesh.ring_gather_s,
             "ring_tail_s": mesh.ring_tail_s,
+            # this tier has no ring session: its op thread never waits for
+            # a push, and every ring is a call of its own
+            "ring_wait_push_s": 0.0,
+            "ring_calls": mesh.ring_calls,
             "lane_reconnects": mesh.lane_reconnects,
             "lane_failovers": mesh.lane_failovers,
             "faults_injected": mesh.faults_injected,
@@ -3762,6 +3771,7 @@ def _ring_allreduce(
     if divisor is not None:
         tag_base += wire_tags.RING_AVG_TAG_BASE
     assert mesh is not None
+    mesh.ring_calls += 1
     pos = ring.index(ctx.rank)
     right = ring[(pos + 1) % ws]
     left = ring[(pos - 1) % ws]

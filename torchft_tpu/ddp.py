@@ -633,7 +633,11 @@ def _ring_account(before: Dict[str, Any], after: Dict[str, Any]) -> Dict[str, An
     ``ring_add_s`` carries the division) and the steps' tails.  A
     reconfiguration in between starts the counts anew: such a round trip
     records no bytes and no seconds, and a communicator that counts no time
-    records none."""
+    records none.  ``ring_calls``: the ring calls the op thread made for the
+    round trip (one a bucket on the per-call path, ONE where the rings are a
+    session), and ``ring_wait_push_s``: a session's op thread waiting for
+    the train thread's next bucket, outside both phases; each where the
+    communicator counts it."""
     account: Dict[str, Any] = {"ring_bytes": 0, "striped_bytes": 0}
     if before["epoch"] != after["epoch"]:
         return account
@@ -642,6 +646,10 @@ def _ring_account(before: Dict[str, Any], after: Dict[str, Any]) -> Dict[str, An
         for a, b in zip(before.get("lane_tx_bytes") or [], after.get("lane_tx_bytes") or [])
     ]
     account.update(ring_bytes=sum(sent), striped_bytes=sum(sent[1:]))
+    if "ring_calls" in before and "ring_calls" in after:
+        account["ring_calls"] = after["ring_calls"] - before["ring_calls"]
+    if "ring_wait_push_s" in before and "ring_wait_push_s" in after:
+        account["ring_wait_push_s"] = round(after["ring_wait_push_s"] - before["ring_wait_push_s"], 6)
     if not all(k in before and k in after for k in RING_TIME_KEYS):
         return account
     busy = [lane for lane, n in enumerate(sent) if n]
@@ -728,7 +736,8 @@ def allreduce_pytree(
 
     ring_before = manager.ring_counters()
     store = _bucket_store(manager)
-    works: List[Work] = []
+    works: List[Work] = []  # a ring each, where the rings are calls of their own
+    session = None  # the round trip's rings as ONE call of the op thread, where the tier has it
     flats: List[np.ndarray] = []  # each bucket's part of its host buffer, in the plan's order
     buffers: List[Any] = []  # the plan's host buffers: a kept set, or made as the buckets come
     kept: Optional[List[np.ndarray]] = None
@@ -741,6 +750,11 @@ def allreduce_pytree(
                 bucket_cap,
             )
         stage_s["plan_s"] = stage.duration_s
+        if not should_quantize:
+            # by what the communicator offers and this call asks, never by a
+            # knob: the op thread enters the session here and rings each
+            # bucket as it is pushed, where it took them one op at a time
+            session = manager.ring_session(len(plan.buckets))
         asked = 0  # buckets whose copies to the host have been started
         ahead = 0  # the bytes of them that have not landed
         flying: Dict[int, List[Any]] = {}  # what crosses, by bucket: a piece's slices live here alone
@@ -788,6 +802,9 @@ def allreduce_pytree(
             with obs_span("tpuft/ddp/submit", bucket=b) as stage:
                 if b == 0:
                     stage_s["first_submit_s"] = stage.t0 - sync_span.t0
+                if session is not None:
+                    session.push(flat)
+                    continue
                 works.append(
                     manager.allreduce(
                         flat,
@@ -797,14 +814,20 @@ def allreduce_pytree(
                     )
                 )
     except BaseException:
+        if session is not None:
+            session.close()  # the buckets not pushed never start, as rings never submitted
         sync_span.__exit__()
         raise
 
     def _gather() -> List[Any]:
         out = list(leaves)
-        for b, (work, bucket) in enumerate(zip(works, plan.buckets)):
+        for b, bucket in enumerate(plan.buckets):
             with obs_span("tpuft/ddp/ring_wait", bucket=b) as stage:
-                flat = work.wait()
+                if session is not None:
+                    session.wait(b)  # rung in place; a failed one rides through as it is
+                    flat = flats[b]
+                else:
+                    flat = works[b].wait()
             stage_s["ring_wait_s"] += stage.duration_s
             with obs_span("tpuft/ddp/h2d", bucket=b) as stage:
                 aliased = np.may_share_memory(flat, flats[b])
@@ -833,8 +856,8 @@ def allreduce_pytree(
         except Exception as e:  # noqa: BLE001 — funnel, never raise
             manager.report_error(e)
         sync_span.set(
-            buckets=len(works),
-            warm_buckets=0 if kept is None else len(works),
+            buckets=len(flats),
+            warm_buckets=0 if kept is None else len(flats),
             bytes=plan.nbytes,
             direct_bytes=plan.direct_nbytes,
             split_bytes=plan.split_nbytes,
@@ -852,7 +875,10 @@ def allreduce_pytree(
         # error (one that failed or timed out may still be receiving into its
         # bucket) and whose restored leaves are ready (``device_put`` reads
         # the bucket until then).
-        if restored is None or any(w.swallowed is not None for w in works):
+        failed = session.swallowed if session is not None else next(
+            (w.swallowed for w in works if w.swallowed is not None), None
+        )
+        if restored is None or failed is not None:
             return
         try:
             jax.block_until_ready([x for x in restored if isinstance(x, jax.Array)])

@@ -1274,7 +1274,10 @@ class Manager:
         ``lane_stats()`` call: ``lane_tx_bytes`` (payload bytes sent a lane)
         and the seven ``RING_TIME_KEYS`` (seconds a lane spent in recv, in
         the reduce's add and in send; the op thread's in the ring's two
-        phases, the division between them and the tail), with ``epoch``, the
+        phases, the division between them and the tail), where the tier
+        counts them ``ring_wait_push_s`` (a ring session's op thread waiting
+        for the next buffer) and ``ring_calls`` (the ring calls the op thread
+        made), with ``epoch``, the
         quorum the counts belong to (a reconfiguration starts them anew).
         ``ddp.allreduce_pytree`` reads it before a round trip's first submit
         and after its last ring and puts the differences on DDP_SYNC.  A
@@ -1284,7 +1287,9 @@ class Manager:
         stats = (stats_fn() if callable(stats_fn) else {}) or {}
         counters: Dict[str, Any] = {"epoch": self._quorum_id}
         counters.update(
-            (k, stats[k]) for k in ("lane_tx_bytes", *RING_TIME_KEYS) if k in stats
+            (k, stats[k])
+            for k in ("lane_tx_bytes", *RING_TIME_KEYS, "ring_wait_push_s", "ring_calls")
+            if k in stats
         )
         return counters
 
@@ -1419,6 +1424,48 @@ class Manager:
             self._logger.exception(f"got exception in all reduce -- skipping remaining: {e}")
             self.report_error(e)
             return _failed_fast(e)
+
+    def ring_session(self, pieces: int) -> Optional["ManagedRingSession"]:
+        """:meth:`allreduce`'s contract over ``pieces`` buffers of ONE round
+        trip whose rings the communicator runs as one call (a native
+        ``RingSession``): ``push(flat)`` where ``allreduce(flat,
+        in_place=True, register_pending=False)`` was called, ``wait(k)``
+        where its Work was waited for.  None where the per-call path must
+        serve: the communicator offers no session (the Python tier, every
+        wrapper) or has no ring to stay inside (one member), an error is
+        already recorded or the quorum fails (the per-call path's fail-fast
+        says so, once a buffer as before).  The caller owns the one work that
+        covers the round trip and registers it (``_register_pending`` or the
+        stream fence): the pieces register nowhere.
+
+        The session's run holds the communicator's op thread from here to
+        the round trip's last piece (or :meth:`ManagedRingSession.close`):
+        another op submitted meanwhile waits behind it, where it waited
+        behind one bucket's ring."""
+        open_session = getattr(self._comm, "ring_session", None)
+        if not callable(open_session) or self.errored() is not None:
+            return None
+        try:
+            self.wait_quorum()
+        except Exception as e:  # noqa: BLE001 — funnel, never raise
+            self.report_error(e)
+            return None
+        try:
+            # AVG = SUM / runtime participant count, divided inside the ring
+            # by each chunk's owner (see :meth:`allreduce`)
+            session = open_session(pieces, divisor=self.num_participants())
+        except Exception as e:  # noqa: BLE001
+            self._logger.exception(f"got exception opening a ring session -- the per-call path serves: {e}")
+            return None
+        if session is None:
+            return None
+        participating = self.is_participating()
+        return ManagedRingSession(
+            self,
+            session,
+            zeros=not participating,
+            scale=self._capacity_weight_scale() if participating else None,
+        )
 
     def allreduce_prequantized(
         self,
@@ -1905,6 +1952,65 @@ def _scale_contribution(
     if isinstance(data, np.ndarray):
         return _one(data)
     return [_one(a) for a in data]
+
+
+class ManagedRingSession:
+    """What :meth:`Manager.allreduce` does around one ring, around a round
+    trip's rings in one native session (:meth:`Manager.ring_session`): a
+    replica that does not participate contributes zeros, a degraded fleet's
+    capacity weight scales the contribution, the ring divides by the
+    participants, and an error never reaches the train loop: the first one
+    fails its piece and every later one, ``report_error`` hears it once and
+    the vote discards the step."""
+
+    def __init__(self, manager: Manager, session: Any, zeros: bool, scale: Optional[float]) -> None:
+        self._manager = manager
+        self._session = session
+        self._zeros = zeros
+        self._scale = scale
+        self._lock = threading.Lock()
+        self.swallowed: Optional[BaseException] = None
+        session.work.future().add_done_callback(self._ended)
+
+    def push(self, flat: np.ndarray) -> None:
+        """Hand over the next buffer: ours until the round trip is over, and
+        the average is written into it (``allreduce(in_place=True)``'s
+        terms).  A no-op once the session has failed."""
+        if self._zeros:
+            flat.fill(0)  # (the per-call path rings a zero buffer in its stead)
+        elif self._scale is not None:
+            flat[...] = _scale_contribution(flat, self._scale)
+        self._session.push(flat)
+
+    def wait(self, k: int) -> bool:
+        """True when buffer ``k`` holds the average; False when its ring
+        failed or never ran (the buffer is whatever the ring left of it, as
+        a failed ``allreduce``'s input rides through)."""
+        try:
+            self._session.wait(k)
+            return True
+        except Exception as e:  # noqa: BLE001 — funnel, never raise
+            self._swallow(e)
+            return False
+
+    def close(self) -> None:
+        """No further push: the op thread leaves the session after the
+        buffers pushed so far.  A must where a push may be missing."""
+        self._session.close()
+
+    def _swallow(self, err: BaseException) -> None:
+        with self._lock:
+            first, self.swallowed = self.swallowed is None, self.swallowed or err
+        if first and isinstance(err, Exception):
+            self._manager.report_error(err)
+
+    def _ended(self, fut: "concurrent.futures.Future") -> None:
+        # on the thread that completed the run (the communicator's op
+        # thread).  The ring has divided: nothing is left to normalize, and
+        # no ``tpuft/manager/normalize`` span says otherwise
+        err = fut.exception()
+        if err is not None:
+            self._swallow(err)
 
 
 class _ManagerLogger:

@@ -43,7 +43,7 @@ from torchft_tpu.futures import TimerHandle, schedule_timeout
 from torchft_tpu.obs.flight import FlightEvent, FlightRecorder
 from torchft_tpu.obs import spans as obs_spans
 from torchft_tpu.obs.spans import span as obs_span
-from torchft_tpu.work import Work
+from torchft_tpu.work import DummyWork, Work, failed_work
 
 logger = logging.getLogger(__name__)
 
@@ -239,8 +239,36 @@ def _load() -> Optional[ctypes.CDLL]:
             *[ctypes.POINTER(ctypes.c_uint64)] * 6,  # tx rx stalls | rx add tx ns
             ctypes.c_uint64,
             ctypes.POINTER(ctypes.c_uint64),  # stripe floor
-            ctypes.POINTER(ctypes.c_uint64),  # the op thread's four, ns
+            ctypes.POINTER(ctypes.c_uint64),  # the op thread's five, ns
         ]
+        # a round trip's rings as one call (comm.h RingSession)
+        lib.tpuft_ring_session_open.restype = ctypes.c_void_p
+        lib.tpuft_ring_session_open.argtypes = [
+            ctypes.c_uint64, ctypes.c_int32, ctypes.c_uint64,
+        ]
+        lib.tpuft_ring_session_run.argtypes = [ctypes.c_void_p, ctypes.c_void_p]
+        lib.tpuft_ring_session_wait.argtypes = [
+            ctypes.c_void_p, ctypes.c_uint64, ctypes.c_double,
+        ]
+        lib.tpuft_ring_session_close.argtypes = [ctypes.c_void_p]
+        lib.tpuft_ring_session_fail.argtypes = [ctypes.c_void_p, ctypes.c_char_p]
+        lib.tpuft_ring_session_times.restype = ctypes.c_uint64
+        lib.tpuft_ring_session_times.argtypes = [
+            ctypes.c_void_p,
+            ctypes.POINTER(ctypes.c_double),
+            ctypes.POINTER(ctypes.c_double),
+            ctypes.c_uint64,
+        ]
+        lib.tpuft_ring_session_free.argtypes = [ctypes.c_void_p]
+        # push through a handle that KEEPS the interpreter lock: the call is
+        # a counter under a mutex and a notify, and a CDLL call would give the
+        # lock up and wait for it again on the train thread, once a piece
+        # (what one ring call cost the op thread, PERF.md section 6, PR 54)
+        push = ctypes.PyDLL(lib_path).tpuft_ring_session_push
+        push.argtypes = [
+            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_uint64, ctypes.c_int32,
+        ]
+        lib.ring_session_push_holding_lock = push
         lib.tpuft_comm_reduce_scatter.argtypes = [
             ctypes.c_void_p,
             ctypes.c_void_p,
@@ -630,6 +658,111 @@ class CppManagerServer:
 # ---------------------------------------------------------------------------
 
 
+class RingSession:
+    """A round trip's rings as ONE native call (``native/comm.h``
+    ``RingSession``; :meth:`CppCommunicator.ring_session` opens it and puts
+    its run on the op thread).  The caller pushes each flat, contiguous,
+    writable array as it is ready, in the order every replica agrees on;
+    piece k is rung in place exactly as ``allreduce(flat, SUM, in_place=True,
+    divisor=)`` would ring it, after piece k-1 and never beside it; whoever
+    needs piece k waits for it.  The first error fails its piece and every
+    later one, and later pushes are no-ops.  A caller that may stop short of
+    ``pieces`` pushes calls :meth:`close` (the op thread stays in the call
+    until then)."""
+
+    def __init__(self, lib: ctypes.CDLL, pieces: int, divisor: int) -> None:
+        self._lib = lib
+        self.pieces = pieces
+        self._s = lib.tpuft_ring_session_open(pieces, _OP_CODES[ReduceOp.SUM], divisor)
+        self._push = lib.ring_session_push_holding_lock
+        self._kept: List[np.ndarray] = []  # what C holds pointers into, until the run ends
+        self.pushed = 0
+        self.work: Optional[Work] = None  # the op thread's run
+
+    def push(self, flat: np.ndarray) -> bool:
+        """Hand over the next piece; False where the session no longer takes
+        one (it failed, was closed, or is full).  Neither blocks nor gives up
+        the interpreter lock."""
+        code = _DTYPE_CODES.get(flat.dtype.name)
+        if code is None or not (flat.flags.c_contiguous and flat.flags.writeable):
+            raise CommunicatorError(
+                f"a session's piece is a contiguous writable array of a ring's dtype, not {flat.dtype.name}"
+            )
+        self._kept.append(flat)
+        self.pushed += 1
+        return bool(self._push(self._s, flat.ctypes.data, flat.nbytes, code))
+
+    def wait(self, k: int, timeout: Optional[float] = None) -> None:
+        """Return when piece ``k`` is rung (its array holds the result);
+        raise what failed it, or ``TimeoutError``."""
+        rc = self._lib.tpuft_ring_session_wait(
+            self._s, k, -1.0 if timeout is None else timeout
+        )
+        if rc < 0:
+            raise CommunicatorError(f"ring piece {k} failed: {_last_error(self._lib)}")
+        if rc > 0:
+            raise TimeoutError(f"ring piece {k} not rung after {timeout}s")
+
+    def close(self) -> None:
+        """No further push: the run ends after the pieces pushed so far."""
+        self._lib.tpuft_ring_session_close(self._s)
+
+    def fail(self, why: str) -> None:
+        """The run failed or will never begin: every waiter wakes."""
+        self._lib.tpuft_ring_session_fail(self._s, why.encode())
+
+    def rung(self) -> int:
+        """How many pieces have been rung."""
+        return int(self._lib.tpuft_ring_session_times(self._s, None, None, 0))
+
+    def times(self) -> List[Tuple[float, float]]:
+        """(start, end) of each rung piece's ring on ``time.monotonic``."""
+        t0 = (ctypes.c_double * self.pieces)()
+        t1 = (ctypes.c_double * self.pieces)()
+        n = int(self._lib.tpuft_ring_session_times(self._s, t0, t1, self.pieces))
+        return [(t0[k], t1[k]) for k in range(min(n, self.pieces))]
+
+    def __del__(self) -> None:
+        # the run's closure and every waiter hold this object: nobody is
+        # inside the C session any more
+        if self._s:
+            self._lib.tpuft_ring_session_free(self._s)
+            self._s = None
+
+
+
+class _SessionWatch:
+    """The op watchdog's stand-in over a session's ONE call.  The C side
+    gives every piece its own deadline, from when it is both pushed and at
+    the head, so a slow landing never reads as a ring that hangs; this looks
+    once a timeout and aborts the epoch only where a piece that was pushed
+    and at the head at the LAST look is still not rung: C's deadline is then
+    overdue, the call hangs where no deadline is checked, and the abort wakes
+    it as it wakes an op.  ``cancel()`` when the call returns."""
+
+    def __init__(self, session: RingSession, timeout_s: float, abort: Callable[[str], None]) -> None:
+        self._session = session
+        self._timeout_s = timeout_s
+        self._abort = abort
+        self._seen = (-1, 0)  # pieces rung and pushed at the last look
+        self._cancelled = False
+        self._handle = schedule_timeout(timeout_s, self._look)
+
+    def _look(self) -> None:
+        if self._cancelled:
+            return
+        now = (self._session.rung(), self._session.pushed)
+        if now[0] == self._seen[0] and self._seen[1] > now[0]:
+            self._abort(f"a session's piece {now[0]} was not rung {self._timeout_s}s after it was pushed and at the head")
+            return
+        self._seen = now
+        self._handle = schedule_timeout(self._timeout_s, self._look)
+
+    def cancel(self) -> None:
+        self._cancelled = True
+        self._handle.cancel()
+
+
 class CppCommunicator(Communicator):
     """Data-plane communicator backed by the C++ runtime.
 
@@ -658,6 +791,9 @@ class CppCommunicator(Communicator):
         # TCPCommunicator._inflight_ops)
         self._inflight_ops = 0
         self._inflight_lock = threading.Lock()
+        # native ring calls made (``tpuft_comm_allreduce_iov`` and a
+        # session's run): written on the op thread alone, never reset
+        self._ring_calls = 0
         # flight recorder attachment point (set by the owning Manager):
         # epoch lifecycle records Python-side, and the C-side fixed-slot
         # ring drains into every dump via tpuft_comm_flight_drain
@@ -678,12 +814,13 @@ class CppCommunicator(Communicator):
         global_ranks: Sequence[int] = (),
     ) -> None:
         with self._lock:
-            self._teardown_locked("superseded by reconfigure")
+            drained = self._teardown_locked("superseded by reconfigure")
             self._epoch += 1
             epoch = self._epoch
             self._errored = None
             self._rank = rank
             self._world_size = world_size
+        self._fail_drained(drained, "superseded by reconfigure")
         # the C configure blocks on rendezvous; run outside the lock
         rc = self._lib.tpuft_comm_configure(
             self._h, store_addr.encode(), rank, world_size
@@ -728,7 +865,9 @@ class CppCommunicator(Communicator):
             quorum_id,
         )
 
-    def _teardown_locked(self, reason: str) -> None:
+    def _teardown_locked(self, reason: str) -> List[Future]:
+        """Returns the futures of the ops that never began: the caller fails
+        them (:meth:`_fail_drained`) once it has let go of the lock."""
         # No join here: the op thread's error path takes self._lock, so
         # joining under the lock would deadlock.  The in-flight C op
         # observes the abort and errors out; the C layer parks superseded
@@ -736,24 +875,35 @@ class CppCommunicator(Communicator):
         # never touch a recycled fd.
         if self._h:
             self._lib.tpuft_comm_abort(self._h)  # unblocks in-flight op
+        drained: List[Future] = []
         try:
             while True:
                 item = self._ops.get_nowait()
                 if item is not None:
-                    item[1].set_exception(CommunicatorAborted(reason))
+                    drained.append(item[1])
         except queue.Empty:
             pass
         if self._op_thread is not None:
             self._ops.put(None)
             self._op_thread = None
+        return drained
+
+    @staticmethod
+    def _fail_drained(drained: List[Future], reason: str) -> None:
+        # outside self._lock: a future's callbacks run here, and one of them
+        # may come back for the lock (a ring session's error funnel dumps the
+        # flight ring, whose drain of the C side takes it)
+        for fut in drained:
+            fut.set_exception(CommunicatorAborted(reason))
 
     def abort(self, reason: str = "aborted") -> None:
         with self._lock:
             newly_poisoned = self._errored is None
             if self._errored is None:
                 self._errored = CommunicatorAborted(reason)
-            self._teardown_locked(reason)
+            drained = self._teardown_locked(reason)
             self._epoch += 1
+        self._fail_drained(drained, reason)
         self._flight_poison(reason, newly_poisoned)
         logger.warning("cpp communicator aborted: %s", reason)
 
@@ -778,8 +928,9 @@ class CppCommunicator(Communicator):
                 newly_poisoned = self._errored is None
                 if self._errored is None:
                     self._errored = CommunicatorAborted(reason)
-                self._teardown_locked(reason)
+                drained = self._teardown_locked(reason)
                 self._epoch += 1
+            self._fail_drained(drained, reason)
             self._flight_poison(reason, newly_poisoned)
             logger.warning("cpp communicator aborted: %s", reason)
 
@@ -791,10 +942,11 @@ class CppCommunicator(Communicator):
     def shutdown(self) -> None:
         with self._lock:
             thread = self._op_thread
-            self._teardown_locked("shutdown")
+            drained = self._teardown_locked("shutdown")
             if self._errored is None:
                 self._errored = CommunicatorAborted("shutdown")
             self._epoch += 1
+        self._fail_drained(drained, "shutdown")
         # join OUTSIDE the lock (the op thread's error path takes it); the C
         # object must not be freed while an op thread is inside a C call
         if thread is not None:
@@ -859,14 +1011,19 @@ class CppCommunicator(Communicator):
         ``ring_tail_s``, of the phases' steps, from its own part of a
         receive returning to the other lanes' parts and its own send having
         landed.  Lanes run beside each other, so a lane's seconds are a
-        share of the phases' and the tail lies inside them."""
+        share of the phases' and the tail lies inside them.
+        ``ring_wait_push_s``: a ring session's op thread waiting for the
+        next piece to be pushed, between two rings and in neither phase;
+        ``ring_calls``: the native ring calls the op thread has made in this
+        communicator's life (one an ``allreduce``'s dtype group, one a
+        session)."""
         with self._lock:
             if self._h is None or self._world_size <= 1:
                 return {}
             cap = 64
             # tx rx stalls, then a lane's nanoseconds in recv, add, send
             lane = [(ctypes.c_uint64 * cap)() for _ in range(6)]
-            ring_ns = (ctypes.c_uint64 * 4)()
+            ring_ns = (ctypes.c_uint64 * 5)()
             floor = ctypes.c_uint64()
             lanes = int(
                 self._lib.tpuft_comm_lane_stats(
@@ -890,6 +1047,8 @@ class CppCommunicator(Communicator):
             "ring_average_s": ring_ns[1] / 1e9,
             "ring_gather_s": ring_ns[2] / 1e9,
             "ring_tail_s": ring_ns[3] / 1e9,
+            "ring_wait_push_s": ring_ns[4] / 1e9,
+            "ring_calls": self._ring_calls,
             "lane_reconnects": 0,
             "lane_failovers": 0,
             "faults_injected": 0,
@@ -937,7 +1096,8 @@ class CppCommunicator(Communicator):
     # -- op machinery ------------------------------------------------------
 
     def _run_ops(self, ops: "queue.Queue", epoch: int) -> None:
-        # k: this op is the k-th of its step (the peer's k-th is its twin)
+        # k: this op is the k-th of its step (the peer's k-th is its twin);
+        # a session's pieces count as the ops they stand for
         op_step, k = None, 0
         while True:
             item = ops.get()
@@ -946,8 +1106,14 @@ class CppCommunicator(Communicator):
             fn, fut = item
             if not fut.set_running_or_notify_cancel():
                 continue
+            session: Optional[RingSession] = getattr(fn, "session", None)
             timeout_s = self._timeout_s
-            handle: TimerHandle = schedule_timeout(
+            # a session's pieces each have the C side's deadline: one
+            # deadline over the whole call would read a slow landing as a
+            # ring that hangs, so the watch looks at the pieces' progress
+            handle = _SessionWatch(
+                session, timeout_s, lambda why: self._abort_if_epoch(epoch, why)
+            ) if session is not None else schedule_timeout(
                 timeout_s,
                 lambda: self._abort_if_epoch(
                     epoch, f"op timed out after {timeout_s}s"
@@ -959,8 +1125,15 @@ class CppCommunicator(Communicator):
             op_step, k = step, (k + 1 if step == op_step else 0)
             self._op_started()
             try:
-                with obs_span("tpuft/comm/op", epoch=epoch, k=k, tier="cpp"):
-                    result = fn()
+                if session is None:
+                    with obs_span("tpuft/comm/op", epoch=epoch, k=k, tier="cpp"):
+                        result = fn()
+                else:
+                    try:
+                        with obs_span("tpuft/comm/session", epoch=epoch, k=k, pieces=session.pieces, tier="cpp"):
+                            result = fn()
+                    finally:
+                        k += self._emit_pieces(session, epoch, k, step) - 1
             except BaseException as e:  # noqa: BLE001
                 latched = False
                 with self._lock:
@@ -977,6 +1150,18 @@ class CppCommunicator(Communicator):
             finally:
                 self._op_finished()
                 handle.cancel()
+
+    @staticmethod
+    def _emit_pieces(session: RingSession, epoch: int, k: int, step: object) -> int:
+        """One ``tpuft/comm/op`` span a rung piece, from the start and end
+        the session kept in C (the op thread was inside ONE call and could
+        open none): the spans a collective each has on the per-call path,
+        ``k`` rising from the session's place in the step.  Returns how many
+        ops the session stands for (one at least)."""
+        if obs_spans.spans_enabled():
+            for i, (t0, t1) in enumerate(session.times()):
+                obs_spans.emit("tpuft/comm/op", t0, t1, epoch=epoch, k=k + i, step=step, tier="cpp")
+        return max(1, session.pieces)
 
     def _submit(self, fn: Callable[[], object]) -> Work:
         with self._lock:
@@ -1059,6 +1244,7 @@ class CppCommunicator(Communicator):
                     lens = (ctypes.c_uint64 * len(flats))(
                         *(int(f.nbytes) for f in flats)
                     )
+                    self._ring_calls += 1
                     self._check(
                         self._lib.tpuft_comm_allreduce_iov(
                             self._h, ptrs, lens, len(flats), code,
@@ -1071,6 +1257,52 @@ class CppCommunicator(Communicator):
             return out[0] if single else out
 
         return self._submit(_run)
+
+    def ring_session(self, pieces: int, divisor: Optional[int] = None) -> Optional[RingSession]:
+        """Open a :class:`RingSession` of ``pieces`` SUM rings (``divisor``:
+        each comes back as SUM / divisor, as :meth:`allreduce`'s) and put its
+        ONE native call on the op thread, behind the ops submitted before.
+        None where there is no ring to stay inside (one member) or the
+        communicator takes no op now (errored, not configured): the caller's
+        per-call path says why, as it always did.
+
+        Inside a session a group's lanes are runnable from the round trip's
+        first bucket to its last; the per-call path's way back into Python
+        between two rings was also a pause in which everything else on the
+        host caught up.  A host with cores to spare (two groups on 30) rings
+        as fast without it and ends the round trip 5 % sooner; where the
+        groups' threads crowd the host (both groups of a benchmark cell in
+        ONE process on 13 cores, the loopback as their wire) the round trip
+        alone read 6-16 % longer (PERF.md section 6, PR 60: the readings, what
+        was tried against it, and why the cause is not known)."""
+        if self._world_size <= 1 or pieces < 1:
+            return None
+        session = RingSession(self._lib, pieces, divisor or 0)
+
+        def _run() -> object:
+            self._ring_calls += 1
+            self._check(self._lib.tpuft_ring_session_run(self._h, session._s), "ring session")
+            return None
+
+        _run.session = session  # type: ignore[attr-defined]
+        work = self._submit(_run)
+        if work.done() and work.exception() is not None:
+            return None
+        session.work = work
+
+        def _ended(fut: Future) -> None:
+            # a run that failed in C has told the session; one that never
+            # began (the queue was drained by a teardown) tells it here
+            err = fut.exception()
+            if err is not None:
+                session.fail(str(err))
+            # C reads the buffers no more, and the session lets go of the
+            # future that holds this callback (no cycle for the collector)
+            session._kept.clear()
+            session.work = DummyWork() if err is None else failed_work(err)
+
+        work.future().add_done_callback(_ended)
+        return session
 
     def reduce_scatter(
         self, data: np.ndarray, op: ReduceOp = ReduceOp.SUM

@@ -30,6 +30,16 @@ those).  Names are ``tpuft/<layer>/<stage>``::
     tpuft/heal/fetch, tpuft/heal/apply             (new life)
     tpuft/outer_shard/*, tpuft/stream/*            (DiLoCo / LocalSGD)
 
+On the native tier a round trip's rings are ONE call of the op thread
+(``tpuft/comm/session``, ``pieces=``), which can open no span while it is
+inside; the session keeps each piece's start and end in C on
+``time.monotonic``'s clock and the binding hands them to :func:`emit` when the
+call returns: one ``tpuft/comm/op`` a piece with rising ``k``, in the
+``TORCHFT_FLIGHT_SPANS`` buffer.  A profiler's trace shows the session's one
+span and no ``tpuft/comm/op`` under it (a span that has already ended cannot
+be annotated), so whoever reads ``tpuft/comm/op`` from a trace finds none
+where the session ran; DDP_SYNC's ring counters say what the rings took.
+
 Beneath ``tpuft/comm/op`` there is no span: a ring's 4 MiB quanta are too many
 for one each.  The communicator counts its own seconds there, always
 (``Communicator.lane_stats()``: a lane's in recv, in the reduce's add and in
@@ -348,6 +358,21 @@ def span(
     if _enabled is None:
         spans_enabled()
     return _Span(name, attrs, recorder, flight, begin, into, key)
+
+
+def emit(name: str, t0: float, t1: float, **attrs: Any) -> None:
+    """A span that has already ended, ``t0`` to ``t1`` on ``time.monotonic``:
+    what a thread measured where it could open none (inside one native
+    call).  Carries ``r`` and ``step`` as :func:`span` does and goes to the
+    ``TORCHFT_FLIGHT_SPANS`` buffer alone: a profiler's trace takes only
+    annotations that are open while it listens."""
+    if not spans_enabled():
+        return
+    recorder = getattr(_bound, "recorder", None)
+    attrs["r"] = recorder.replica_id if recorder is not None else ""
+    if attrs.get("step") is None and recorder is not None:
+        attrs["step"] = recorder.step
+    _spans.append((name, t0, t1 - t0, threading.get_ident(), attrs))
 
 
 def snapshot() -> List[Dict[str, Any]]:
