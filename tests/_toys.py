@@ -10,12 +10,17 @@ called through, so what is shared here is the wrapper.
 import contextlib
 import dataclasses
 import functools
+import hashlib
+import importlib
+import threading
 
 import jax
 import numpy as np
 import optax
 import pytest
 
+from torchft_tpu import tier as tier_mod
+from torchft_tpu.manager import Manager
 from torchft_tpu.parallel.hsdp import HSDPTrainer, fsdp_shardings, make_grad_step, make_update_step
 from torchft_tpu.parallel.mesh import make_mesh
 
@@ -112,49 +117,174 @@ def trainer(make_model, idx, manager, key, **adamw):
     return made
 
 
+# -- two replica groups under real Managers (the ``test_<model>_hsdp.py`` files) --
+
+# How long a replica waits for the other to stand (its Manager made and
+# heartbeating, its trainer built): both first lives before either's first
+# quorum, and the killed replica's new life before the survivor's next one.
+# Nothing is compiled in it (``trainer``; a first life's ``replica_group``
+# traces the toy's ``init`` for its shapes, under a second), so what it
+# covers is a dead life's ``Manager.shutdown()`` and a Manager's servers,
+# a few seconds beside busy workers
+STANDING_WAIT_S = 60.0
+
+
+class _Killed(Exception):
+    pass
+
+
+def digest(params) -> str:
+    """One hash over every leaf's bytes."""
+    h = hashlib.sha256()
+    for leaf in jax.tree_util.tree_leaves(params):
+        h.update(np.asarray(leaf).tobytes())
+    return h.hexdigest()
+
+
+def two_replica_walk(make_model, make_batch, total, kill_at=None, quantized=(), record=None):
+    """Two replica groups as threads on the CPU's first two devices, a
+    lighthouse, a real Manager a life, ``total`` fleet steps.  Each replica
+    has a batch of its own (``make_batch(model, mesh, seed)``), so equal
+    leaves REQUIRE what crossed the replica dimension to have been averaged.
+    The steps in ``quantized`` run the int8 wire.
+
+    Neither asks for its first quorum before BOTH stand: with a lighthouse
+    that lets one replica go on alone (the walk with a kill needs that), a
+    replica whose Manager came first walked every step to ``kill_at + 1`` by
+    itself in half a second and parked there, heartbeating, and the other,
+    one of two heartbeating replicas and so no majority, timed out of its
+    first quorum (``tests/test_gated_delta_hsdp.py`` in the driver's run, PR
+    61: the two start-ups are 0.7 s each and which is longer turned on what
+    else the process had imported).
+
+    Without ``kill_at`` both are in every quorum and every step commits.
+    With it replica 1 dies once the fleet is at step ``kill_at``, comes back
+    with other weights and heals from the survivor, which waits for the new
+    life to stand before it asks for the quorum after the kill; steps alone
+    may stall, twice at most.  A replica that fails wakes the one waiting
+    for it, so that the failure is reported and not the wait's.
+
+    Asserted here: both finish; the digests of every leaf agree at every
+    step the two shared; the parameters moved every step; the shared steps
+    are all of them (no kill) or lie on both sides of the kill.  Returns
+    ``(the shared steps, [replica -> {fleet step -> record(model, manager,
+    trainer)}])``: what a file checks of its own, read after a shared step
+    committed."""
+    devices = jax.devices()[:2]
+    tier = tier_mod.default_tier()
+    everyone = 1 if kill_at is not None else 2
+    lighthouse = tier_mod.make_lighthouse(
+        bind="127.0.0.1:0", min_replicas=everyone, join_timeout_ms=200, quorum_tick_ms=20,
+        heartbeat_timeout_ms=2000, tier=tier,
+    )
+    name = make_model.__module__.rpartition(".")[2]
+    managers, errors = [], []
+    seen, recorded = [{}, {}], [{}, {}]  # replica -> fleet step -> digest of every leaf / the file's record
+    standing, rejoined = threading.Barrier(2), threading.Event()
+
+    def replica(idx):
+        model, mesh, _ = replica_group(make_model, idx)
+        batch = make_batch(model, mesh, 100 + idx)
+        life = 0
+        while True:
+            manager = Manager(
+                comm=tier_mod.make_communicator(timeout_s=30.0, tier=tier),
+                load_state_dict=None, state_dict=None, min_replica_size=everyone,
+                timeout=30.0, quorum_timeout=30.0, connect_timeout=30.0,
+                replica_id=f"{name}_{idx}", lighthouse_addr=lighthouse.local_address(),
+                server_cls=tier_mod.manager_server_cls(tier),
+            )
+            managers.append(manager)
+            # the new life finds the step's programs compiled (``trainer``): the
+            # survivor's ring does not wait out its 30 s while they compile (D13 (b))
+            made = trainer(make_model, idx, manager, jax.random.PRNGKey(10 * life + 1), learning_rate=1e-3)
+            if life:
+                rejoined.set()
+            else:
+                standing.wait(timeout=STANDING_WAIT_S)
+            try:
+                stalled = 0
+                while (step := manager.current_step()) < total:
+                    if kill_at is not None and life == 0 and idx == 1 and step >= kill_at:
+                        raise _Killed()
+                    if kill_at is not None and idx == 0 and step == kill_at + 1:
+                        assert rejoined.wait(timeout=STANDING_WAIT_S), "the killed replica never came back"
+                    made.quantize_outer = step in quantized
+                    loss, committed = made.train_step(batch)
+                    assert np.isfinite(loss)
+                    stalled = 0 if committed else stalled + 1
+                    assert committed or (kill_at is not None and step >= kill_at and stalled < 3), manager.errored()
+                    if committed and manager.num_participants() == 2:
+                        seen[idx][manager.current_step()] = digest(made.holder["params"])
+                        if record is not None:
+                            recorded[idx][manager.current_step()] = record(model, manager, made)
+                return
+            except _Killed:
+                life += 1
+                manager.shutdown()
+                managers.remove(manager)
+
+    def guarded(idx):
+        try:
+            with jax.default_device(devices[idx]):
+                replica(idx)
+        except BaseException as e:  # noqa: BLE001 — raised again below
+            errors.append(e)
+            standing.abort()
+            rejoined.set()
+
+    threads = [threading.Thread(target=guarded, args=(i,), daemon=True) for i in range(2)]
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=300.0)
+        assert not errors, errors
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        for m in managers:
+            m.shutdown()
+        lighthouse.shutdown()
+    shared = sorted(set(seen[0]) & set(seen[1]))
+    if kill_at is None:
+        assert sorted(seen[0]) == sorted(seen[1]) == list(range(1, total + 1)), (seen[0].keys(), seen[1].keys())
+    else:
+        # steps with both in the quorum: before the kill, and after the heal
+        assert any(s <= kill_at for s in shared) and any(s > kill_at + 1 for s in shared), shared
+    for step in shared:
+        assert seen[0][step] == seen[1][step], f"step {step}"
+    assert len({seen[0][step] for step in shared}) == len(shared)  # the parameters moved every step
+    return shared, recorded
+
+
 # -- a model's gradient step, lowered (``test_device_parts.py``, ``test_lowered_steps.py``) --
 
 
+# name -> (module of ``torchft_tpu.models``, class, its debug configuration, the
+# sequence length the digests of ``tests/fixtures/lowered_steps.json`` were
+# written at): the row a new model adds
+TOYS = {
+    "llama": ("llama", "Llama", "llama_debug", 128),
+    "ling_hybrid": ("ling_hybrid", "LingHybrid", "ling_debug", 128),
+    "indexed_sparse_moe": ("indexed_sparse_moe", "IndexedSparseMoE", "indexed_sparse_debug", 32),
+    "latent_moe": ("latent_moe", "LatentMoE", "latent_moe_debug", 64),
+    "windowed_moe": ("windowed_moe", "WindowedMoE", "windowed_moe_debug", 64),
+    "eva": ("eva", "Eva", "eva_debug", 64),
+    "gated_delta_moe": ("gated_delta_moe", "GatedDeltaMoE", "gated_delta_debug", 64),
+    "looped": ("looped", "Looped", "looped_debug", 64),
+    "ssm_hybrid_moe": ("ssm_hybrid_moe", "SsmHybridMoE", "ssm_hybrid_debug", 64),
+}
+
+
 def toy(name):
-    """(model, sequence length): the sequence is the one the digests of
-    ``tests/fixtures/lowered_steps.json`` were written at."""
-    if name in ("llama", "llama_remat"):
-        from torchft_tpu.models.llama import Llama, llama_debug
-
-        return Llama(dataclasses.replace(llama_debug(), remat=name == "llama_remat")), 128
-    if name == "ling_hybrid":
-        from torchft_tpu.models.ling_hybrid import LingHybrid, ling_debug
-
-        return LingHybrid(ling_debug()), 128
-    if name == "indexed_sparse_moe":
-        from torchft_tpu.models.indexed_sparse_moe import IndexedSparseMoE, indexed_sparse_debug
-
-        return IndexedSparseMoE(indexed_sparse_debug()), 32
-    if name == "latent_moe":
-        from torchft_tpu.models.latent_moe import LatentMoE, latent_moe_debug
-
-        return LatentMoE(latent_moe_debug()), 64
-    if name == "windowed_moe":
-        from torchft_tpu.models.windowed_moe import WindowedMoE, windowed_moe_debug
-
-        return WindowedMoE(windowed_moe_debug()), 64
-    if name == "eva":
-        from torchft_tpu.models.eva import Eva, eva_debug
-
-        return Eva(eva_debug()), 64
-    if name == "gated_delta_moe":
-        from torchft_tpu.models.gated_delta_moe import GatedDeltaMoE, gated_delta_debug
-
-        return GatedDeltaMoE(gated_delta_debug()), 64
-    if name == "looped":
-        from torchft_tpu.models.looped import Looped, looped_debug
-
-        return Looped(looped_debug()), 64
-    if name == "ssm_hybrid_moe":
-        from torchft_tpu.models.ssm_hybrid_moe import SsmHybridMoE, ssm_hybrid_debug
-
-        return SsmHybridMoE(ssm_hybrid_debug()), 64
-    raise KeyError(name)
+    """(model, sequence length) of ``TOYS``; ``llama_remat`` is ``llama``
+    with every layer rematerialised."""
+    module, cls, debug, seq = TOYS["llama" if name == "llama_remat" else name]
+    module = importlib.import_module(f"torchft_tpu.models.{module}")
+    config = getattr(module, debug)()
+    if name == "llama_remat":
+        config = dataclasses.replace(config, remat=True)
+    return getattr(module, cls)(config), seq
 
 
 @functools.lru_cache(maxsize=None)

@@ -4,20 +4,18 @@ optimizer does not own.  Steps commit, the summary rides the loss's one
 transfer into MOE_ROUTE, replicas stay bit-equal through a kill and a live
 heal.  Toy widths, float32, the CPU's devices."""
 
-import threading
 from typing import Any, Dict, List
 
 import jax
 import numpy as np
 
-from torchft_tpu import tier as tier_mod
 from torchft_tpu.communicator import DummyCommunicator
 from torchft_tpu.manager import Manager
 from torchft_tpu.models.indexed_sparse_moe import SUMMARY_FIELDS, IndexedSparseMoE, indexed_sparse_debug
 from torchft_tpu.parallel import hsdp
 from torchft_tpu.parallel.hsdp import fsdp_shardings
 
-from tests._toys import replica_group, trainer as group_trainer
+from tests._toys import replica_group, trainer as group_trainer, two_replica_walk
 from tests.test_manager import MemoryTransport, StubClient, _quorum_result
 
 
@@ -96,95 +94,14 @@ def test_uncommitted_step_changes_nothing_and_records_nothing():
 TOTAL, KILL_AT, QUANTIZED_FROM = 8, 4, 2
 
 
-class _Killed(Exception):
-    pass
-
-
 def test_two_replicas_commit_agree_bit_for_bit_and_heal_a_killed_one():
     """Two replica groups as threads, a lighthouse, real Managers, a batch
-    each.  Steps 2 and 3 run the int8 wire.  Replica 1 dies at step 4, comes
-    back with other weights and heals from the survivor."""
-    devices = jax.devices()[:2]
-    tier = tier_mod.default_tier()
-    lighthouse = tier_mod.make_lighthouse(
-        bind="127.0.0.1:0", min_replicas=1, join_timeout_ms=200, quorum_tick_ms=20,
-        heartbeat_timeout_ms=2000, tier=tier,
+    each (``tests/_toys.py`` ``two_replica_walk``).  Steps 2 and 3 run the
+    int8 wire.  Replica 1 dies at step 4, comes back with other weights and
+    heals from the survivor."""
+    shared, routes = two_replica_walk(
+        toy, _batch, TOTAL, kill_at=KILL_AT, quantized=range(QUANTIZED_FROM, KILL_AT),
+        record=lambda model, manager, trainer: len(_routes(manager)),
     )
-    managers: List[Manager] = []
-    errors: List[BaseException] = []
-    seen: List[Dict[int, str]] = [{}, {}]  # replica -> fleet step -> digest of its parameters
-    routes: List[int] = [0, 0]
-    rejoined = threading.Event()
-
-    def digest(params) -> str:
-        import hashlib
-
-        h = hashlib.sha256()
-        for leaf in jax.tree_util.tree_leaves(params):
-            h.update(np.asarray(leaf).tobytes())
-        return h.hexdigest()
-
-    def replica(idx: int) -> None:
-        model, mesh, _ = replica_group(toy, idx)
-        batch = _batch(model, mesh, 100 + idx)
-        life = 0
-        while True:
-            manager = Manager(
-                comm=tier_mod.make_communicator(timeout_s=30.0, tier=tier),
-                load_state_dict=None, state_dict=None, min_replica_size=1,
-                timeout=30.0, quorum_timeout=30.0, connect_timeout=30.0,
-                replica_id=f"indexed_{idx}", lighthouse_addr=lighthouse.local_address(),
-                server_cls=tier_mod.manager_server_cls(tier),
-            )
-            managers.append(manager)
-            trainer = group_trainer(toy, idx, manager, jax.random.PRNGKey(10 * life + 1), learning_rate=1e-3)
-            if life:
-                rejoined.set()
-            try:
-                stalled = 0
-                while (step := manager.current_step()) < TOTAL:
-                    if life == 0 and idx == 1 and step >= KILL_AT:
-                        raise _Killed()
-                    if idx == 0 and step == KILL_AT + 1:
-                        assert rejoined.wait(timeout=60.0), "the killed replica never came back"
-                    trainer.quantize_outer = QUANTIZED_FROM <= step < KILL_AT
-                    loss, committed = trainer.train_step(batch)
-                    assert np.isfinite(loss)
-                    stalled = 0 if committed else stalled + 1
-                    assert committed or (step >= KILL_AT and stalled < 3), manager.errored()
-                    if committed and manager.num_participants() == 2:
-                        seen[idx][manager.current_step()] = digest(trainer.holder["params"])
-                routes[idx] += len(_routes(manager))
-                return
-            except _Killed:
-                life += 1
-                routes[idx] += len(_routes(manager))
-                manager.shutdown()
-                managers.remove(manager)
-
-    def guarded(idx: int) -> None:
-        try:
-            with jax.default_device(devices[idx]):
-                replica(idx)
-        except BaseException as e:  # noqa: BLE001 — raised again below
-            errors.append(e)
-
-    threads = [threading.Thread(target=guarded, args=(i,), daemon=True) for i in range(2)]
-    try:
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join(timeout=300.0)
-        assert not errors, errors
-        assert not any(t.is_alive() for t in threads)
-    finally:
-        for m in managers:
-            m.shutdown()
-        lighthouse.shutdown()
-    shared = sorted(set(seen[0]) & set(seen[1]))
-    # steps with both in the quorum: before the kill, and after the heal
-    assert any(s <= KILL_AT for s in shared) and any(s > KILL_AT + 1 for s in shared), shared
-    for step in shared:
-        assert seen[0][step] == seen[1][step], f"step {step}"
-    assert len(set(seen[0].values())) == len(seen[0])  # and the parameters moved every step
-    assert routes[0] >= TOTAL - 1 and routes[1] >= KILL_AT  # an event a committed step, each life
+    # an event a committed step of a life: the survivor's every step, the killed one's first life
+    assert routes[0][shared[-1]] >= TOTAL - 1 and routes[1][KILL_AT] >= KILL_AT

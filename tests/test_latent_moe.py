@@ -197,7 +197,7 @@ def test_the_modules_loss_of_every_position_and_the_last_position_left_out(monke
     # the shared definition gives every position, the last one's label wrapped around
     x, _, _, kernels = model._trunk(params, batch[0])
     nll, load, _ = mtp_token_nll(
-        params["mtp"], params["embed"], x, batch[1], layer=model._layer("moe", kernels, in_a_loop=False),
+        params["mtp"], params["embed"], x, batch[1], layer=lambda z, w: model._block(z, w, "moe", kernels),
         head_nll=lambda z, norm, labels: model._token_nll(params["lm_head"], z, norm, labels),
         norm_eps=cfg.norm_eps, dtype=cfg.dtype,
     )

@@ -6,22 +6,18 @@ load and reports its routing and the module's loss; two replica groups as
 threads, each with a batch of its own, stay bit-equal in every leaf while the
 biases move.  Toy widths, float32, the CPU's devices."""
 
-import hashlib
-import threading
-from typing import Any, Dict, List
 
 import jax
 import numpy as np
 import pytest
 
-from torchft_tpu import tier as tier_mod
 from torchft_tpu.communicator import DummyCommunicator
 from torchft_tpu.manager import Manager
 from torchft_tpu.models.latent_moe import LatentMoE, latent_moe_debug
 from torchft_tpu.parallel import hsdp
 
 from tests.test_ling_hsdp import RATE, _batch, _biases
-from tests._toys import replica_group, trainer as group_trainer
+from tests._toys import replica_group, trainer as group_trainer, two_replica_walk
 from tests.test_manager import MemoryTransport, StubClient, _quorum_result
 
 
@@ -68,67 +64,11 @@ def test_a_committed_step_moves_every_router_s_bias_and_reports_the_modules_loss
 
 
 def test_two_replicas_stay_bit_equal_while_the_biases_move_the_modules_too():
-    devices = jax.devices()[:2]
-    tier = tier_mod.default_tier()
-    lighthouse = tier_mod.make_lighthouse(
-        bind="127.0.0.1:0", min_replicas=2, join_timeout_ms=200, quorum_tick_ms=20,
-        heartbeat_timeout_ms=2000, tier=tier,
+    _, seen = two_replica_walk(
+        toy, _batch, TOTAL, quantized=(2,),  # a batch each: equal biases REQUIRE the averaged load; one step on the int8 wire
+        record=lambda model, manager, trainer: _biases(model, trainer.holder["params"]),
     )
-    managers: List[Manager] = []
-    errors: List[BaseException] = []
-    seen: List[Dict[int, Any]] = [{}, {}]  # replica -> fleet step -> (digest of every leaf, biases)
-
-    def digest(params) -> str:
-        h = hashlib.sha256()
-        for leaf in jax.tree_util.tree_leaves(params):
-            h.update(np.asarray(leaf).tobytes())
-        return h.hexdigest()
-
-    def replica(idx: int) -> None:
-        model, mesh, _ = replica_group(toy, idx)
-        batch = _batch(model, mesh, 100 + idx)  # a batch each: equal biases REQUIRE the averaged load
-        manager = Manager(
-            comm=tier_mod.make_communicator(timeout_s=30.0, tier=tier),
-            load_state_dict=None, state_dict=None, min_replica_size=2,
-            timeout=30.0, quorum_timeout=30.0, connect_timeout=30.0,
-            replica_id=f"latent_{idx}", lighthouse_addr=lighthouse.local_address(),
-            server_cls=tier_mod.manager_server_cls(tier),
-        )
-        managers.append(manager)
-        trainer = group_trainer(toy, idx, manager, jax.random.PRNGKey(1), learning_rate=1e-3)
-        while manager.current_step() < TOTAL:
-            trainer.quantize_outer = manager.current_step() == 2  # one step on the int8 wire
-            loss, committed = trainer.train_step(batch)
-            assert np.isfinite(loss) and committed, manager.errored()
-            assert manager.num_participants() == 2
-            params = trainer.holder["params"]
-            seen[idx][manager.current_step()] = (digest(params), _biases(model, params))
-
-    def guarded(idx: int) -> None:
-        try:
-            with jax.default_device(devices[idx]):
-                replica(idx)
-        except BaseException as e:  # noqa: BLE001 — raised again below
-            errors.append(e)
-
-    threads = [threading.Thread(target=guarded, args=(i,), daemon=True) for i in range(2)]
-    try:
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join(timeout=300.0)
-        assert not errors, errors
-        assert not any(t.is_alive() for t in threads)
-    finally:
-        for m in managers:
-            m.shutdown()
-        lighthouse.shutdown()
-    assert sorted(seen[0]) == sorted(seen[1]) == list(range(1, TOTAL + 1))
-    for step in seen[0]:
-        assert seen[0][step][0] == seen[1][step][0], f"step {step}"
-    digests = [seen[0][step][0] for step in sorted(seen[0])]
-    assert len(set(digests)) == TOTAL  # the parameters moved every step
-    last = seen[0][TOTAL][1]
+    last = seen[0][TOTAL]
     # the trunk's run of routers and the module's router: both moved, by whole steps of the rate
     assert [b.shape for b in last] == [(3, 16), (16,)] and all(np.abs(b).max() > 0 for b in last)
     assert all(np.abs(b).max() <= RATE * TOTAL * (1 + 1e-5) for b in last)

@@ -3,8 +3,7 @@ optimizer does not own.  It moves only on a committed step, by the step's
 per-expert load averaged over the replicas; replicas stay bit-equal; a
 healed life has the survivor's.  Toy widths, float32, the CPU's devices."""
 
-import threading
-from typing import Any, Dict, List
+from typing import Any, List
 
 import jax
 import jax.numpy as jnp
@@ -12,13 +11,12 @@ import numpy as np
 import optax
 import pytest
 
-from torchft_tpu import tier as tier_mod
 from torchft_tpu.communicator import DummyCommunicator
 from torchft_tpu.manager import Manager
 from torchft_tpu.models.ling_hybrid import LingHybrid, ling_debug
 from torchft_tpu.parallel.hsdp import fsdp_shardings, make_update_step
 
-from tests._toys import replica_group, trainer as group_trainer
+from tests._toys import replica_group, trainer as group_trainer, two_replica_walk
 from tests.test_manager import MemoryTransport, StubClient, _quorum_result
 
 RATE = 1e-3
@@ -115,87 +113,17 @@ def test_update_step_keeps_its_signature_and_llama_its_path():
 TOTAL, KILL_AT, QUANTIZED_FROM = 9, 5, 3
 
 
-class _Killed(Exception):
-    pass
-
-
 def test_two_replicas_agree_bit_for_bit_through_a_kill_and_a_live_heal():
-    """Two replica groups as threads, a lighthouse, real Managers.  Each
-    has a batch of its own, so equal biases REQUIRE the loads to have
-    passed the replica-dimension average.  Steps 3 and 4 run the int8 wire
-    (the loads cross it unquantised).  Replica 1 dies at step 5, comes back
-    with other weights and a zero bias, and heals from the survivor."""
-    devices = jax.devices()[:2]
-    tier = tier_mod.default_tier()
-    lighthouse = tier_mod.make_lighthouse(
-        bind="127.0.0.1:0", min_replicas=1, join_timeout_ms=200, quorum_tick_ms=20,
-        heartbeat_timeout_ms=2000, tier=tier,
+    """Two replica groups as threads, a lighthouse, real Managers
+    (``tests/_toys.py`` ``two_replica_walk``).  Each has a batch of its own,
+    so equal biases REQUIRE the loads to have passed the replica-dimension
+    average.  Steps 3 and 4 run the int8 wire (the loads cross it
+    unquantised).  Replica 1 dies at step 5, comes back with other weights
+    and a zero bias, and heals from the survivor."""
+    shared, seen = two_replica_walk(
+        ling, _batch, TOTAL, kill_at=KILL_AT, quantized=range(QUANTIZED_FROM, KILL_AT),
+        record=lambda model, manager, trainer: _biases(model, trainer.holder["params"]),
     )
-    managers: List[Manager] = []
-    errors: List[BaseException] = []
-    seen: List[Dict[int, List[np.ndarray]]] = [{}, {}]  # replica -> fleet step -> biases
-    rejoined = threading.Event()
-
-    def replica(idx: int) -> None:
-        model, mesh, _ = replica_group(ling, idx)
-        batch = _batch(model, mesh, 100 + idx)
-        life = 0
-        while True:
-            manager = Manager(
-                comm=tier_mod.make_communicator(timeout_s=30.0, tier=tier),
-                load_state_dict=None, state_dict=None, min_replica_size=1,
-                timeout=30.0, quorum_timeout=30.0, connect_timeout=30.0,
-                replica_id=f"ling_{idx}", lighthouse_addr=lighthouse.local_address(),
-                server_cls=tier_mod.manager_server_cls(tier),
-            )
-            managers.append(manager)
-            # the new life finds the step's programs compiled (``tests/_toys.py``): the
-            # survivor's ring does not wait out its 30 s while they compile (D13 (b))
-            trainer = group_trainer(ling, idx, manager, jax.random.PRNGKey(10 * life + 1), learning_rate=1e-3)
-            if life:
-                rejoined.set()
-            try:
-                stalled = 0
-                while (step := manager.current_step()) < TOTAL:
-                    if life == 0 and idx == 1 and step >= KILL_AT:
-                        raise _Killed()
-                    if idx == 0 and step == KILL_AT + 1:
-                        assert rejoined.wait(timeout=60.0), "the killed replica never came back"
-                    trainer.quantize_outer = QUANTIZED_FROM <= step < KILL_AT
-                    loss, committed = trainer.train_step(batch)
-                    assert np.isfinite(loss)
-                    stalled = 0 if committed else stalled + 1
-                    assert committed or (step >= KILL_AT and stalled < 3), manager.errored()
-                    if committed and manager.num_participants() == 2:
-                        seen[idx][manager.current_step()] = _biases(model, trainer.holder["params"])
-                return
-            except _Killed:
-                life += 1
-                manager.shutdown()
-                managers.remove(manager)
-
-    def guarded(idx: int) -> None:
-        try:
-            with jax.default_device(devices[idx]):
-                replica(idx)
-        except BaseException as e:  # noqa: BLE001 — raised again below
-            errors.append(e)
-
-    threads = [threading.Thread(target=guarded, args=(i,), daemon=True) for i in range(2)]
-    try:
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join(timeout=300.0)
-        assert not errors, errors
-        assert not any(t.is_alive() for t in threads)
-    finally:
-        for m in managers:
-            m.shutdown()
-        lighthouse.shutdown()
-    shared = sorted(set(seen[0]) & set(seen[1]))
-    # steps with both in the quorum: before the kill, and after the heal
-    assert any(s <= KILL_AT for s in shared) and any(s > KILL_AT + 1 for s in shared), shared
     for step in shared:
         for a, b in zip(seen[0][step], seen[1][step]):
             np.testing.assert_array_equal(a, b, err_msg=f"step {step}")

@@ -188,9 +188,9 @@ def test_bias_goes_up_for_the_idle_and_down_for_the_busy():
     new = model.advance_state(bias, load)
     np.testing.assert_allclose(np.asarray(new[0][0]), 1e-3 * np.sign(7.5 - np.arange(16.0)))
     assert float(new[1][0]) == pytest.approx(-1e-3) and float(new[1][1]) == pytest.approx(1e-3)
-    stats = model.summary_stats(np.asarray(model.route_summary(load, 64)))
+    stats = model.summary_stats(np.asarray(model.moe.route_summary(load, 64)))
     assert stats["rows_here"] == [22.0, 22.0, 12.0] and stats["load_max"] == [7.0, 7.0, 3.0]
     assert stats["buffer_rows"] == [256.0] * 3  # 64 tokens, 4 each: the buffer is every pair, one pass
     # 2,048 tokens: a uniform router sends 2,048 rows here, the buffer is 2,560: two passes, two, one
-    stats = model.summary_stats(np.asarray(model.route_summary([200 * x for x in load], 2048)))
+    stats = model.summary_stats(np.asarray(model.moe.route_summary([200 * x for x in load], 2048)))
     assert stats["rows_here"] == [4400.0, 4400.0, 2400.0] and stats["buffer_rows"] == [5120.0, 5120.0, 2560.0]

@@ -8,21 +8,16 @@ share, over the plain wire through a kill and a live heal and over the int8
 wire; the 1-element leaf goes through ``ddp.allreduce_pytree``'s bucket plan
 and the heal as any other.  Toy widths, float32, the CPU's devices."""
 
-import hashlib
-import threading
-from typing import Dict, List
-
 import jax
 import numpy as np
 import pytest
 
-from torchft_tpu import tier as tier_mod
 from torchft_tpu.communicator import DummyCommunicator
 from torchft_tpu.manager import Manager
 from torchft_tpu.models.looped import Looped, looped_debug
 from torchft_tpu.parallel import hsdp
 
-from tests._toys import replica_group, trainer as group_trainer
+from tests._toys import replica_group, trainer as group_trainer, two_replica_walk
 from tests.test_ling_hsdp import _batch
 from tests.test_manager import MemoryTransport, StubClient, _quorum_result
 
@@ -90,107 +85,25 @@ def test_a_committed_step_moves_every_leaf(leaf, committed_step):
     assert leaf != "b" or moved.shape == (1,)
 
 
-class _Killed(Exception):
-    pass
-
-
 @pytest.mark.parametrize("quantize,total,kill_at", [(False, 8, 4), (True, 4, None)], ids=["plain-wire-kill-heal", "int8-wire"])
 def test_two_replicas_commit_agree_bit_for_bit_and_heal_a_killed_one(quantize, total, kill_at):
-    """Two replica groups as threads, a lighthouse, real Managers.  Each has a
-    batch of its own, so equal leaves REQUIRE the averaged gradient: the
-    gate's vector and its 1-element bias cross ``ddp.allreduce_pytree``'s
-    bucket plan beside leaves ten thousand times their size (over the int8
-    wire in the second case, ``should_quantize=True``).  On the plain wire
-    replica 1 dies at step 4, comes back with other weights, and heals from
-    the survivor, the 1-element leaf with the rest."""
-    devices = jax.devices()[:2]
-    tier = tier_mod.default_tier()
-    lighthouse = tier_mod.make_lighthouse(
-        bind="127.0.0.1:0", min_replicas=2 if kill_at is None else 1, join_timeout_ms=200, quorum_tick_ms=20,
-        heartbeat_timeout_ms=2000, tier=tier,
+    """Two replica groups as threads, a lighthouse, real Managers
+    (``tests/_toys.py`` ``two_replica_walk``).  Each has a batch of its own,
+    so equal leaves REQUIRE the averaged gradient: the gate's vector and its
+    1-element bias cross ``ddp.allreduce_pytree``'s bucket plan beside leaves
+    ten thousand times their size (over the int8 wire in the second case,
+    ``should_quantize=True``).  On the plain wire replica 1 dies at step 4,
+    comes back with other weights, and heals from the survivor, the 1-element
+    leaf with the rest."""
+
+    def gate_bias(model, manager, trainer):
+        event = [e for e in manager._flight.snapshot() if e["name"] == "MOE_ROUTE"][-1]
+        assert sorted(k for k in event if k in ("pass_nll", "exit_p", "exit_entropy")) == ["exit_entropy", "exit_p", "pass_nll"]
+        return float(trainer.holder["params"]["gate"]["b"][0])
+
+    shared, biases = two_replica_walk(
+        toy, _batch, total, kill_at=kill_at, quantized=range(total) if quantize else (), record=gate_bias
     )
-    managers: List[Manager] = []
-    errors: List[BaseException] = []
-    seen: List[Dict[int, str]] = [{}, {}]  # replica -> fleet step -> digest of every leaf
-    biases: List[Dict[int, float]] = [{}, {}]
-    rejoined = threading.Event()
-
-    def digest(params) -> str:
-        h = hashlib.sha256()
-        for leaf in jax.tree_util.tree_leaves(params):
-            h.update(np.asarray(leaf).tobytes())
-        return h.hexdigest()
-
-    def replica(idx: int) -> None:
-        model, mesh, _ = replica_group(toy, idx)
-        batch = _batch(model, mesh, 100 + idx)
-        life = 0
-        while True:
-            manager = Manager(
-                comm=tier_mod.make_communicator(timeout_s=30.0, tier=tier),
-                load_state_dict=None, state_dict=None, min_replica_size=1,
-                timeout=30.0, quorum_timeout=30.0, connect_timeout=30.0,
-                replica_id=f"loop_{idx}", lighthouse_addr=lighthouse.local_address(),
-                server_cls=tier_mod.manager_server_cls(tier),
-            )
-            managers.append(manager)
-            # the new life finds the step's programs compiled (``tests/_toys.py``)
-            trainer = group_trainer(toy, idx, manager, jax.random.PRNGKey(10 * life + 1), learning_rate=1e-3)
-            trainer.quantize_outer = quantize
-            if life:
-                rejoined.set()
-            try:
-                stalled = 0
-                while (step := manager.current_step()) < total:
-                    if kill_at is not None and life == 0 and idx == 1 and step >= kill_at:
-                        raise _Killed()
-                    if kill_at is not None and idx == 0 and step == kill_at + 1:
-                        # 120 s: beside five busy workers the dead life's shutdown and the new
-                        # one's Manager have taken over the 60 s its siblings allow (D13 (c))
-                        assert rejoined.wait(timeout=120.0), "the killed replica never came back"
-                    loss, committed = trainer.train_step(batch)
-                    assert np.isfinite(loss)
-                    stalled = 0 if committed else stalled + 1
-                    assert committed or (kill_at is not None and step >= kill_at and stalled < 3), manager.errored()
-                    if committed and manager.num_participants() == 2:
-                        seen[idx][manager.current_step()] = digest(trainer.holder["params"])
-                        biases[idx][manager.current_step()] = float(trainer.holder["params"]["gate"]["b"][0])
-                        event = [e for e in manager._flight.snapshot() if e["name"] == "MOE_ROUTE"][-1]
-                        assert sorted(k for k in event if k in ("pass_nll", "exit_p", "exit_entropy")) == ["exit_entropy", "exit_p", "pass_nll"]
-                return
-            except _Killed:
-                life += 1
-                manager.shutdown()
-                managers.remove(manager)
-
-    def guarded(idx: int) -> None:
-        try:
-            with jax.default_device(devices[idx]):
-                replica(idx)
-        except BaseException as e:  # noqa: BLE001 — raised again below
-            errors.append(e)
-
-    threads = [threading.Thread(target=guarded, args=(i,), daemon=True) for i in range(2)]
-    try:
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join(timeout=300.0)
-        assert not errors, errors
-        assert not any(t.is_alive() for t in threads)
-    finally:
-        for m in managers:
-            m.shutdown()
-        lighthouse.shutdown()
-    shared = sorted(set(seen[0]) & set(seen[1]))
-    if kill_at is None:
-        assert len(shared) >= total - 1, shared
-    else:
-        # steps with both in the quorum: before the kill, and after the heal
-        assert any(s <= kill_at for s in shared) and any(s > kill_at + 1 for s in shared), shared
-    for step in shared:
-        assert seen[0][step] == seen[1][step], f"step {step}"
-    assert len({seen[0][step] for step in shared}) == len(shared)  # the parameters moved every step
     # the 1-element leaf itself: equal on both replicas, moved from its start of 0 by the averaged gradient
     assert all(biases[0][step] == biases[1][step] != 0.0 for step in shared)
     assert len({biases[0][step] for step in shared}) == len(shared)

@@ -57,22 +57,17 @@ made again in the backward pass and never kept.
 from __future__ import annotations
 
 import functools
-import logging
 from dataclasses import dataclass, replace
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
 
-from torchft_tpu.models.latent import token_nll
-from torchft_tpu.models.llama import Llama, _proj
+from torchft_tpu.models import decoder
 from torchft_tpu.obs.spans import part
 from torchft_tpu.ops import flash_attention as flash
 from torchft_tpu.parallel.moe import swiglu
-
-logger = logging.getLogger(__name__)
 
 KERNEL_PATH = "flash"
 
@@ -159,20 +154,16 @@ class Eva:
 
     @functools.cached_property
     def _shapes(self) -> Any:
-        """What ``init`` would make, as shapes (traced once a model)."""
-        return jax.eval_shape(self.init, jax.random.PRNGKey(0))
+        return decoder.shapes(self.init)
 
     def param_specs(self) -> Dict[str, Any]:
-        """One chip's share of a larger job: every leaf whole on the group's
-        one chip (the ``fsdp`` axis of this model's meshes has size 1)."""
-        return jax.tree_util.tree_map(lambda s: P(*([None] * len(s.shape))), self._shapes)
+        return decoder.one_chip_param_specs(self._shapes)
 
     def batch_specs(self) -> Tuple[Any, Any]:
-        spec = P(("dp", "fsdp"), None)
-        return spec, spec
+        return decoder.batch_specs()
 
     def num_params(self) -> int:
-        return sum(int(np.prod(s.shape)) for s in jax.tree_util.tree_leaves(self._shapes))
+        return decoder.num_params(self._shapes)
 
     @staticmethod
     def summary_stats(summary: np.ndarray) -> Dict[str, Any]:
@@ -189,16 +180,16 @@ class Eva:
     def _kernel_refusal(self, seq: int) -> Optional[str]:
         """Why the Mosaic kernels do NOT apply, or None when they do."""
         span = min(seq, self.config.window_size)  # a window that covers the sequence: causal attention
-        block_q, block_k = Llama._flash_blocks(span)
+        block_q, block_k = decoder.flash_blocks(span)
         shape_refusal = None
         if seq < 32 or seq % 8 or seq % span or span % block_q or span % block_k:
             shape_refusal = f"seq={seq} does not divide into windows of {span} of whole blocks ({block_q}, {block_k})"
-        return Llama._one_chip_refusal(shape_refusal, self.mesh)
+        return decoder.one_chip_refusal(shape_refusal, self.mesh)
 
     def _normed(self, x: jax.Array, g: jax.Array) -> jax.Array:
         """What a layer reads of the float32 residual stream: its RMS norm
         under the weight ``1 + g``, in the matrices' dtype."""
-        return Llama._rms_norm(x, 1.0 + g, self.config.norm_eps).astype(self.config.dtype)
+        return decoder.rms_norm(x, 1.0 + g, self.config.norm_eps).astype(self.config.dtype)
 
     @part("mixer_pool")
     def _pool(self, k: jax.Array, v: jax.Array, phi: jax.Array, mu: jax.Array) -> Tuple[jax.Array, jax.Array]:
@@ -218,15 +209,15 @@ class Eva:
         cfg = self.config
         B, S, _ = h.shape
         H, hd, W, C = cfg.n_heads, cfg.head_dim, cfg.window_size, cfg.chunk_size
-        q = Llama._apply_rope(_proj(h, w["wq"]).reshape(B, S, H, hd), *rope)
-        k = Llama._apply_rope(_proj(h, w["wk"]).reshape(B, S, H, hd), *rope)  # rope BEFORE pooling
-        v = _proj(h, w["wv"]).reshape(B, S, H, hd)
+        q = decoder.apply_rope(decoder.proj(h, w["wq"]).reshape(B, S, H, hd), *rope)
+        k = decoder.apply_rope(decoder.proj(h, w["wk"]).reshape(B, S, H, hd), *rope)  # rope BEFORE pooling
+        v = decoder.proj(h, w["wv"]).reshape(B, S, H, hd)
         k_pooled, v_pooled = self._pool(k, v, w["phi"], w["mu"])
         if kernels:
-            block_q, block_k = Llama._flash_blocks(min(S, W))
+            block_q, block_k = decoder.flash_blocks(min(S, W))
             o = flash.eva_attention(
                 q, k, v, k_pooled, v_pooled, window=W, block_q=block_q, block_k=block_k,
-                interpret=Llama._assumed_backend() != "tpu",
+                interpret=decoder.assumed_backend() != "tpu",
             )
         else:
             i, j, c = jnp.arange(S)[:, None], jnp.arange(S)[None, :], jnp.arange(S // C)[None, :]
@@ -235,7 +226,7 @@ class Eva:
             scores = jnp.einsum("bqhd,bkhd->bhqk", q, keys).astype(jnp.float32) * hd ** -0.5
             probs = jax.nn.softmax(jnp.where(seen, scores, -1e30), axis=-1).astype(q.dtype)
             o = jnp.einsum("bhqk,bkhd->bqhd", probs, values)
-        return _proj(o.reshape(B, S, H * hd), w["wo"])
+        return decoder.proj(o.reshape(B, S, H * hd), w["wo"])
 
     def _block(self, x: jax.Array, w: Dict[str, Any], rope: Tuple[jax.Array, jax.Array], kernels: bool) -> jax.Array:
         """One residual block on the float32 stream."""
@@ -261,27 +252,15 @@ class Eva:
         with part("embed"):
             x = params["embed"][tokens].astype(jnp.float32)  # the residual stream
         with part("mixer_glue"):
-            half = cfg.head_dim // 2
-            freqs = 1.0 / (cfg.rope_theta ** (jnp.arange(half, dtype=jnp.float32) / half))
-            angles = jnp.arange(S, dtype=jnp.float32)[None, :, None] * freqs  # [1, S, hd / 2]
-            rope = jnp.cos(angles), jnp.sin(angles)
+            rope = decoder.rope_table(S, cfg.head_dim, cfg.rope_theta)
         # kept through a layer's rematerialisation: its float32 input and the
         # forward kernel's output and row statistics, so that ``eva_fwd``
-        # stands once a layer in a step.  jax's guard against XLA merging the
-        # rematerialised forward with the first one stays on where a scan of
-        # ONE layer is no loop once XLA has simplified it
-        # (``models/ssm_hybrid_moe.py``)
-        layer = jax.checkpoint(
-            lambda carry, w: (self._block(carry, w, rope, kernels), None),
-            policy=jax.checkpoint_policies.save_only_these_names(*flash.KEPT_NAMES),
-            prevent_cse=cfg.n_layers == 1,
+        # stands once a layer in a step
+        x, _ = decoder.scan_run(
+            lambda carry, w: (self._block(carry, w, rope, kernels), None), x, params["layers"], cfg.n_layers,
+            keep=flash.KEPT_NAMES,
         )
-        with part("layers"):
-            x, _ = jax.lax.scan(layer, x, params["layers"])
-        path = KERNEL_PATH if kernels else f"plain: {refusal}"
-        if path != self.attention_path:
-            logger.info("attention path: %s", path)
-        self.attention_path = path
+        decoder.kernel_path(self, KERNEL_PATH, refusal)
         return x
 
     def _head(self, params: Dict[str, Any], x: jax.Array, slices: int) -> jax.Array:
@@ -290,7 +269,7 @@ class Eva:
         never rounded to the model's dtype."""
         cfg = self.config
         head = params["lm_head"][:, : slices * cfg.vocab_size]
-        logits = jnp.dot(self._normed(x, params["final_norm"]), head, preferred_element_type=jnp.float32)
+        logits = decoder.head_logits(x, 1.0 + params["final_norm"], head, cfg.norm_eps, cfg.dtype)
         return logits.reshape(*x.shape[:2], slices, cfg.vocab_size)
 
     def apply_all(self, params: Dict[str, Any], tokens: jax.Array) -> jax.Array:
@@ -317,7 +296,7 @@ class Eva:
         ahead = jnp.arange(slices)
         labels = jnp.stack([jnp.roll(targets, -m, axis=1) for m in range(slices)], axis=-1)  # [B, S, slices]
         has_label = jnp.arange(S)[:, None] + ahead < S  # [S, slices]
-        nll = token_nll(self._head(params, x, slices), labels)
+        nll = decoder.token_nll(self._head(params, x, slices), labels)
         return jnp.sum(jnp.where(has_label, nll, 0.0), axis=(0, 1)) / (targets.shape[0] * (S - ahead))
 
     def loss(self, params: Dict[str, Any], batch: Tuple[jax.Array, jax.Array]) -> jax.Array:

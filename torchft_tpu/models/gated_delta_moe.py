@@ -63,24 +63,18 @@ describes none.
 from __future__ import annotations
 
 import functools
-import itertools
-import logging
 from dataclasses import dataclass, replace
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
 
-from torchft_tpu.models.ling_hybrid import ROUTE_FIELDS, LingHybrid, _short_conv_silu, _unit
-from torchft_tpu.models.llama import Llama, _proj
-from torchft_tpu.models.windowed_moe import _rope_halves
+from torchft_tpu.models import decoder
 from torchft_tpu.obs.spans import part
 from torchft_tpu.ops import flash_attention as flash
-from torchft_tpu.parallel.moe import RoutedExperts, RoutedExpertsConfig
-
-logger = logging.getLogger(__name__)
+from torchft_tpu.parallel import moe
+from torchft_tpu.parallel.moe import ROUTE_FIELDS, RoutedExperts, RoutedExpertsConfig
 
 KERNEL_PATH = "gdn+flash"
 GDN_CHUNK = 64  # tokens a chunk of the delta rule (ops/gdn.py)
@@ -124,7 +118,7 @@ class GatedDeltaMoEConfig:
 
     def groups(self) -> List[Tuple[str, int]]:
         """Runs of contiguous layers of one kind: (kind, how many)."""
-        return [(kind, len(list(run))) for kind, run in itertools.groupby(self.kinds())]
+        return decoder.runs(self.kinds())
 
 
 def gated_delta_debug(**over: Any) -> GatedDeltaMoEConfig:
@@ -174,8 +168,7 @@ class GatedDeltaMoE:
         D = cfg.dim
         keys = jax.random.split(key, 5)
 
-        def normal(k, shape, fan_in):
-            return (jax.random.normal(k, shape, jnp.float32) / np.sqrt(fan_in)).astype(cfg.dtype)
+        normal = functools.partial(decoder.seeded, dtype=cfg.dtype)
 
         if kind == "full":
             q, kv = cfg.n_heads * cfg.head_dim, cfg.n_kv_heads * cfg.head_dim
@@ -220,12 +213,7 @@ class GatedDeltaMoE:
         return {
             # rows of unit variance, as ``IndexedSparseMoE``'s and for its reason
             "embed": jax.random.normal(k_embed, (cfg.vocab_size, cfg.dim), jnp.float32).astype(cfg.dtype),
-            "groups": [
-                jax.vmap(functools.partial(self._init_layer, kind))(
-                    jax.random.split(jax.random.fold_in(k_layers, n), depth)
-                )
-                for n, (kind, depth) in enumerate(self.groups)
-            ],
+            "groups": decoder.init_runs(self._init_layer, k_layers, self.groups),
             "final_norm": jnp.zeros((cfg.dim,), jnp.float32),
             "lm_head": (
                 jax.random.normal(k_out, (cfg.dim, cfg.vocab_size), jnp.float32) / np.sqrt(cfg.dim)
@@ -234,20 +222,16 @@ class GatedDeltaMoE:
 
     @functools.cached_property
     def _shapes(self) -> Any:
-        """What ``init`` would make, as shapes (traced once a model)."""
-        return jax.eval_shape(self.init, jax.random.PRNGKey(0))
+        return decoder.shapes(self.init)
 
     def param_specs(self) -> Dict[str, Any]:
-        """One chip's share of a larger job: every leaf whole on the group's
-        one chip (the ``fsdp`` axis of this model's meshes has size 1)."""
-        return jax.tree_util.tree_map(lambda s: P(*([None] * len(s.shape))), self._shapes)
+        return decoder.one_chip_param_specs(self._shapes)
 
     def batch_specs(self) -> Tuple[Any, Any]:
-        spec = P(("dp", "fsdp"), None)
-        return spec, spec
+        return decoder.batch_specs()
 
     def num_params(self) -> int:
-        return sum(int(np.prod(s.shape)) for s in jax.tree_util.tree_leaves(self._shapes))
+        return decoder.num_params(self._shapes)
 
     # ------------------------------------------------------------------
     # forward
@@ -255,16 +239,11 @@ class GatedDeltaMoE:
 
     def _kernel_refusal(self, seq: int) -> Optional[str]:
         """Why the Mosaic kernels do NOT apply, or None when they do."""
-        block_q, block_k = Llama._flash_blocks(seq)
-        chunk = min(GDN_CHUNK, seq)
-        shape_refusal = None
-        if seq < 32 or seq % 8 or seq % block_q or seq % block_k or seq % chunk:
-            shape_refusal = f"seq={seq} does not divide into the blocks ({block_q}, {block_k}) and chunks of {chunk}"
-        return Llama._one_chip_refusal(shape_refusal, self.mesh)
+        return decoder.kernel_refusal(seq, self.mesh, chunk=GDN_CHUNK)
 
     def _normed(self, x: jax.Array, w: jax.Array) -> jax.Array:
         """The RMS norm under the weight ``1 + w``, in x's dtype."""
-        return Llama._rms_norm(x, 1.0 + w, self.config.norm_eps)
+        return decoder.rms_norm(x, 1.0 + w, self.config.norm_eps)
 
     @part("mixer_glue")
     def _delta_net(self, h: jax.Array, w: Dict[str, jax.Array], kernels: bool) -> Tuple[jax.Array, jax.Array]:
@@ -275,41 +254,41 @@ class GatedDeltaMoE:
         B, S, _ = h.shape
         Hk, Hv = cfg.linear_key_heads, cfg.linear_value_heads
         keyed, valued = Hk * cfg.linear_key_head_dim, Hv * cfg.linear_value_head_dim
-        qkvz = _proj(h, w["w_qkvz"])
-        ba = _proj(h, w["w_ba"]).astype(jnp.float32)
-        qkv = _short_conv_silu(qkvz[..., : 2 * keyed + valued], w["conv"])
-        q = _unit(qkv[..., :keyed].reshape(B, S, Hk, -1))
-        k = _unit(qkv[..., keyed : 2 * keyed].reshape(B, S, Hk, -1))
+        qkvz = decoder.proj(h, w["w_qkvz"])
+        ba = decoder.proj(h, w["w_ba"]).astype(jnp.float32)
+        qkv = decoder.short_conv_silu(qkvz[..., : 2 * keyed + valued], w["conv"])
+        q = decoder.unit(qkv[..., :keyed].reshape(B, S, Hk, -1))
+        k = decoder.unit(qkv[..., keyed : 2 * keyed].reshape(B, S, Hk, -1))
         v = qkv[..., 2 * keyed :].reshape(B, S, Hv, -1)
         z = qkvz[..., 2 * keyed + valued :].reshape(B, S, Hv, -1)
         beta = jax.nn.sigmoid(ba[..., :Hv])
         # the log of the decay: one number a value head and token, unbounded below
         g = -jnp.exp(w["a_log"]) * jax.nn.softplus(ba[..., Hv:] + w["dt_bias"])
         if kernels:
-            o = gdn_chunked(q, k, v, g, beta, chunk=GDN_CHUNK, interpret=Llama._assumed_backend() != "tpu")
+            o = gdn_chunked(q, k, v, g, beta, chunk=GDN_CHUNK, interpret=decoder.assumed_backend() != "tpu")
         else:
             o = gdn_chunked_plain(q, k, v, g, beta, chunk=GDN_CHUNK)
         # norm first, then the gate
-        o = Llama._rms_norm(o, w["o_norm"], cfg.norm_eps).astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
-        return _proj(o.astype(h.dtype).reshape(B, S, valued), w["wo"]), jax.lax.stop_gradient(jnp.min(g))
+        o = decoder.rms_norm(o, w["o_norm"], cfg.norm_eps).astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+        return decoder.proj(o.astype(h.dtype).reshape(B, S, valued), w["wo"]), jax.lax.stop_gradient(jnp.min(g))
 
     @part("mixer_glue")
     def _attention(self, h: jax.Array, w: Dict[str, jax.Array], kernels: bool) -> jax.Array:
         cfg = self.config
         B, S, _ = h.shape
         H, KV, hd, rot = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, cfg.rotary_dim
-        q_gate = _proj(h, w["wq"])
+        q_gate = decoder.proj(h, w["wq"])
         q = self._normed(q_gate[..., : H * hd].reshape(B, S, H, hd), w["q_norm"])
-        k = self._normed(_proj(h, w["wk"]).reshape(B, S, KV, hd), w["k_norm"])
-        v = _proj(h, w["wv"]).reshape(B, S, KV, hd)
+        k = self._normed(decoder.proj(h, w["wk"]).reshape(B, S, KV, hd), w["k_norm"])
+        v = decoder.proj(h, w["wv"]).reshape(B, S, KV, hd)
         gate = q_gate[..., H * hd :]
-        turn = lambda a: jnp.concatenate([_rope_halves(a[..., :rot], cfg.rope_theta), a[..., rot:]], axis=-1)  # noqa: E731
+        turn = lambda a: jnp.concatenate([decoder.rope_halves(a[..., :rot], cfg.rope_theta), a[..., rot:]], axis=-1)  # noqa: E731
         q, k = turn(q), turn(k)
         if kernels:
-            block_q, block_k = Llama._flash_blocks(S)
+            block_q, block_k = decoder.flash_blocks(S)
             o = flash.flash_attention(
                 q, k, v, causal=True, block_q=block_q, block_k=block_k,
-                interpret=Llama._assumed_backend() != "tpu",
+                interpret=decoder.assumed_backend() != "tpu",
             )
         else:
             grouped = q.reshape(B, S, KV, H // KV, hd)
@@ -317,7 +296,7 @@ class GatedDeltaMoE:
             scores = jnp.where(jnp.arange(S)[None, :] <= jnp.arange(S)[:, None], scores, -1e30)
             o = jnp.einsum("bgrqk,bkgd->bqgrd", jax.nn.softmax(scores, axis=-1).astype(q.dtype), v)
         o = o.reshape(B, S, H * hd).astype(jnp.float32) * jax.nn.sigmoid(gate.astype(jnp.float32))
-        return _proj(o.astype(h.dtype), w["wo"])
+        return decoder.proj(o.astype(h.dtype), w["wo"])
 
     def _block(
         self, x: jax.Array, w: Dict[str, Any], kind: str, kernels: bool
@@ -341,39 +320,27 @@ class GatedDeltaMoE:
     def _trunk(self, params: Dict[str, Any], tokens: jax.Array) -> Tuple[jax.Array, Tuple[jax.Array, ...]]:
         """tokens [B, S] → (the residual stream after the last layer, a
         layer's (loads [L, E], balance loss [L], decay_min [L]))."""
-        cfg = self.config
         refusal = self._kernel_refusal(tokens.shape[1])
         kernels = refusal is None
         with part("embed"):
             x = params["embed"][tokens].astype(jnp.float32)  # the residual stream
         per_group = []
         for (kind, depth), stacked in zip(self.groups, params["groups"]):
-
-            def body(carry, w, kind=kind):
-                return self._block(carry, w, kind, kernels)
-
             # kept through a layer's rematerialisation: its float32 input and,
             # on a FULL layer, flash's output and row statistics, so that the
-            # dear ``flash_fwd`` stands once in a step (``WindowedMoE`` does the
-            # same, and says why ``prevent_cse`` stays on for a run of one)
-            keep = flash.KEPT_NAMES if kind == "full" else ()
-            policy = jax.checkpoint_policies.save_only_these_names(*keep)
-            with part("layers"):
-                x, per_layer = jax.lax.scan(jax.checkpoint(body, policy=policy, prevent_cse=depth == 1), x, stacked)
+            # dear ``flash_fwd`` stands once in a step (as ``WindowedMoE``'s)
+            x, per_layer = decoder.scan_run(
+                lambda carry, w, kind=kind: self._block(carry, w, kind, kernels), x, stacked, depth,
+                keep=flash.KEPT_NAMES if kind == "full" else (),
+            )
             per_group.append(per_layer)
-        if kernels and self.moe.path not in (None, "gmm") and Llama._assumed_backend() == "tpu":
-            refusal, kernels = f"the experts took {self.moe.path}", False
-        path = KERNEL_PATH if kernels else f"plain: {refusal}"
-        if path != self.attention_path:
-            logger.info("attention path: %s", path)
-        self.attention_path = path
+        decoder.kernel_path(self, KERNEL_PATH, refusal, self.moe.path)
         return x, tuple(jnp.concatenate(field) for field in zip(*per_group))
 
     @part("head")
     def _logits(self, params: Dict[str, Any], x: jax.Array) -> jax.Array:
-        x = self._normed(x, params["final_norm"]).astype(self.config.dtype)
-        # the products' float32 sums as they are: a logit is never rounded to the model's dtype
-        return jnp.dot(x, params["lm_head"], preferred_element_type=jnp.float32)
+        cfg = self.config
+        return decoder.head_logits(x, 1.0 + params["final_norm"], params["lm_head"], cfg.norm_eps, cfg.dtype)
 
     def apply(self, params: Dict[str, Any], tokens: jax.Array) -> jax.Array:
         """tokens [B, S] → logits [B, S, vocab] (fp32)."""
@@ -382,7 +349,7 @@ class GatedDeltaMoE:
     def _losses(self, params: Dict[str, Any], batch: Tuple[jax.Array, jax.Array]) -> Tuple[jax.Array, Tuple[jax.Array, ...]]:
         tokens, targets = batch
         x, per_layer = self._trunk(params, tokens)
-        return LingHybrid._mean_nll(self._logits(params, x), targets), per_layer
+        return decoder.mean_nll(self._logits(params, x), targets), per_layer
 
     def loss(self, params: Dict[str, Any], batch: Tuple[jax.Array, jax.Array]) -> jax.Array:
         """Mean next-token cross-entropy; batch = (tokens, targets)."""
@@ -398,16 +365,12 @@ class GatedDeltaMoE:
         with part("head"):
             return loss + jnp.sum(balance), ([], self.step_summary(load, decay_min, batch[0].size))
 
-    # a layer's rows on the held experts, their largest and mean load, the buffer's rows
-    route_summary = LingHybrid.route_summary
-
     def step_summary(self, load: jax.Array, decay_min: jax.Array, tokens: int) -> jax.Array:
         """Of this replica's step of ``tokens`` tokens, on the device:
-        ``[layers, 5]`` in the order of ``SUMMARY_FIELDS``."""
-        return jnp.concatenate([self.route_summary([load], tokens), decay_min[:, None]], axis=1)
+        ``[layers, 5]`` in the order of ``SUMMARY_FIELDS``: a layer's rows on
+        the held experts, their largest and mean load, the buffer's rows
+        (``RoutedExperts.route_summary``) and ``decay_min``."""
+        return jnp.concatenate([self.moe.route_summary([load], tokens), decay_min[:, None]], axis=1)
 
-    @staticmethod
-    def summary_stats(summary: np.ndarray) -> Dict[str, List[float]]:
-        """:meth:`step_summary` on the host, as the flight event's detail."""
-        columns = np.asarray(summary, np.float64).reshape(-1, len(SUMMARY_FIELDS)).T
-        return {name: column.tolist() for name, column in zip(SUMMARY_FIELDS, columns)}
+    # :meth:`step_summary` on the host, as the flight event's detail
+    summary_stats = staticmethod(functools.partial(moe.summary_stats, fields=SUMMARY_FIELDS))

@@ -49,7 +49,6 @@ where ``embeds [B, S, D]`` stand in for the token embeddings wherever
 from __future__ import annotations
 
 import functools
-import logging
 from dataclasses import dataclass, replace
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -57,16 +56,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.ad_checkpoint import checkpoint_name
-from jax.sharding import PartitionSpec as P
 
-from torchft_tpu.models.llama import Llama, _proj
+from torchft_tpu.models import decoder
 from torchft_tpu.obs.spans import part
 from torchft_tpu.ops.indexed_attention import (
     KEPT_NAMES, Blocks, indexed_attention, indexed_attention_plain, select_keys,
 )
+from torchft_tpu.parallel import moe
 from torchft_tpu.parallel.moe import RoutedExperts, RoutedExpertsConfig
-
-logger = logging.getLogger(__name__)
 
 KERNEL_PATH = "dsa"
 SELECTION_NAMES = ("dsa_mask", "dsa_lse", "dsa_keys")
@@ -170,8 +167,7 @@ class IndexedSparseMoE:
         J, DI = cfg.index_heads, cfg.index_head_dim
         keys = jax.random.split(key, 8)
 
-        def normal(k, shape, fan_in):
-            return (jax.random.normal(k, shape, jnp.float32) / np.sqrt(fan_in)).astype(cfg.dtype)
+        normal = functools.partial(decoder.seeded, dtype=cfg.dtype)
 
         return {
             "attn_norm": jnp.ones((D,), jnp.float32),
@@ -201,40 +197,33 @@ class IndexedSparseMoE:
     def init(self, key: jax.Array) -> Dict[str, Any]:
         cfg = self.config
         k_embed, k_out, k_layers = jax.random.split(key, 3)
-
-        def normal(k, shape, std):
-            return (std * jax.random.normal(k, shape, jnp.float32)).astype(cfg.dtype)
-
+        # rows of unit variance, so that a token's own embedding leads
+        # the residual stream it enters.  With rows of 1 / sqrt(dim) the
+        # first layers' attention output, a near-uniform mean over 2,048
+        # values and so nearly the SAME vector for every token, was 40 %
+        # of what the first routers saw: every token then preferred the
+        # same few experts (the busiest held expert at 12 times the
+        # mean, PERF.md section 6, PR 33), and a step's work followed the seed
+        embed, lm_head = decoder.embed_and_head(k_embed, k_out, cfg.vocab_size, cfg.dim, cfg.dtype)
         return {
-            # rows of unit variance, so that a token's own embedding leads
-            # the residual stream it enters.  With rows of 1 / sqrt(dim) the
-            # first layers' attention output, a near-uniform mean over 2,048
-            # values and so nearly the SAME vector for every token, was 40 %
-            # of what the first routers saw: every token then preferred the
-            # same few experts (the busiest held expert at 12 times the
-            # mean, PERF.md section 6, PR 33), and a step's work followed the seed
-            "embed": normal(k_embed, (cfg.vocab_size, cfg.dim), 1.0),
+            "embed": embed,
             "layers": jax.vmap(self._init_layer)(jax.random.split(k_layers, cfg.n_layers)),
             "final_norm": jnp.ones((cfg.dim,), jnp.float32),
-            "lm_head": normal(k_out, (cfg.dim, cfg.vocab_size), cfg.dim ** -0.5),
+            "lm_head": lm_head,
         }
 
     @functools.cached_property
     def _shapes(self) -> Any:
-        """What ``init`` would make, as shapes (traced once a model)."""
-        return jax.eval_shape(self.init, jax.random.PRNGKey(0))
+        return decoder.shapes(self.init)
 
     def param_specs(self) -> Dict[str, Any]:
-        """One chip's share of a larger job: every leaf whole on the group's
-        one chip (the ``fsdp`` axis of this model's meshes has size 1)."""
-        return jax.tree_util.tree_map(lambda s: P(*([None] * len(s.shape))), self._shapes)
+        return decoder.one_chip_param_specs(self._shapes)
 
     def batch_specs(self) -> Tuple[Any, Any]:
-        spec = P(("dp", "fsdp"), None)
-        return spec, spec
+        return decoder.batch_specs()
 
     def num_params(self) -> int:
-        return sum(int(np.prod(s.shape)) for s in jax.tree_util.tree_leaves(self._shapes))
+        return decoder.num_params(self._shapes)
 
     # ------------------------------------------------------------------
     # forward
@@ -242,7 +231,7 @@ class IndexedSparseMoE:
 
     def _kernel_refusal(self, seq: int) -> Optional[str]:
         """Why the Mosaic kernels do NOT apply, or None when they do."""
-        return Llama._one_chip_refusal(self.config.blocks.refusal(seq), self.mesh)
+        return decoder.one_chip_refusal(self.config.blocks.refusal(seq), self.mesh)
 
     @part("mixer_glue")
     def _index(
@@ -256,9 +245,9 @@ class IndexedSparseMoE:
         J, DI = cfg.index_heads, cfg.index_head_dim
         hs = jax.lax.stop_gradient(h)
         rope = lambda x: _mrope(x, positions, self.index_sections, cfg.rope_theta)  # noqa: E731
-        q_index = rope(_proj(hs, ix["wq"]).reshape(B, S, J, DI))
-        k_index = rope(_unit_rms(_proj(hs, ix["wk"]), cfg.norm_eps))
-        return q_index, k_index, _proj(hs, ix["ww"]).astype(jnp.float32) * float((J * DI) ** -0.5)
+        q_index = rope(decoder.proj(hs, ix["wq"]).reshape(B, S, J, DI))
+        k_index = rope(_unit_rms(decoder.proj(hs, ix["wk"]), cfg.norm_eps))
+        return q_index, k_index, decoder.proj(hs, ix["ww"]).astype(jnp.float32) * float((J * DI) ** -0.5)
 
     @part("mixer_glue")
     def _attention(
@@ -270,12 +259,12 @@ class IndexedSparseMoE:
         H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
         a = w["attn"]
         rope = lambda x: _mrope(x, positions, cfg.mrope_section, cfg.rope_theta)  # noqa: E731
-        q = rope(Llama._rms_norm(_proj(h, a["wq"]).reshape(B, S, H, hd), a["q_norm"], cfg.norm_eps))
-        k = rope(Llama._rms_norm(_proj(h, a["wk"]).reshape(B, S, KV, hd), a["k_norm"], cfg.norm_eps))
-        v = _proj(h, a["wv"]).reshape(B, S, KV, hd)
+        q = rope(decoder.rms_norm(decoder.proj(h, a["wq"]).reshape(B, S, H, hd), a["q_norm"], cfg.norm_eps))
+        k = rope(decoder.rms_norm(decoder.proj(h, a["wk"]).reshape(B, S, KV, hd), a["k_norm"], cfg.norm_eps))
+        v = decoder.proj(h, a["wv"]).reshape(B, S, KV, hd)
         q_index, k_index, weight = self._index(h, w["index"], positions)
         if kernels:
-            interpret = Llama._assumed_backend() != "tpu"
+            interpret = decoder.assumed_backend() != "tpu"
             mask, lse_index, keys = select_keys(
                 q_index, k_index, weight, topk=cfg.index_topk, blocks=cfg.blocks, interpret=interpret
             )
@@ -289,12 +278,12 @@ class IndexedSparseMoE:
             )
         else:
             o, kl, keys = indexed_attention_plain(q, k, v, q_index, k_index, weight, topk=cfg.index_topk)
-        return _proj(o.reshape(B, S, H * hd), a["wo"]), kl / (B * S), jnp.mean(keys)
+        return decoder.proj(o.reshape(B, S, H * hd), a["wo"]), kl / (B * S), jnp.mean(keys)
 
     def _normed(self, x: jax.Array, weight: jax.Array) -> jax.Array:
         """What a layer reads of the float32 residual stream: its RMS norm,
         in the matrices' dtype."""
-        return Llama._rms_norm(x, weight, self.config.norm_eps).astype(self.config.dtype)
+        return decoder.rms_norm(x, weight, self.config.norm_eps).astype(self.config.dtype)
 
     def _block(
         self, x: jax.Array, w: Dict[str, Any], positions: jax.Array, kernels: bool
@@ -307,7 +296,7 @@ class IndexedSparseMoE:
             x = x + mixed
             # the router reads the float32 norm itself: which 8 of 128 experts a
             # token takes is a step function of it
-            h = Llama._rms_norm(x, w["mlp_norm"], cfg.norm_eps)
+            h = decoder.rms_norm(x, w["mlp_norm"], cfg.norm_eps)
         out, load, balance = self.moe.apply(w["ffn"], h)
         with part("stream"):
             return x + out, (load, balance, kl, keys)
@@ -332,29 +321,20 @@ class IndexedSparseMoE:
                 embeds, given = batch[3], batch[4]
                 x = jnp.where(given[..., None], embeds.astype(jnp.float32), x)
 
-        def body(carry, w):
-            return self._block(carry, w, positions, kernels)
-
-        body = jax.checkpoint(
-            body,
-            policy=jax.checkpoint_policies.save_only_these_names(*SELECTION_NAMES, *KEPT_NAMES),
-            prevent_cse=False,
+        # a rematerialised layer keeps the selection and what the kernels
+        # make of it; ``prevent_cse`` off whatever the depth, the constant
+        # this model has always passed
+        x, per_layer = decoder.scan_run(
+            lambda carry, w: self._block(carry, w, positions, kernels), x, params["layers"], cfg.n_layers,
+            keep=(*SELECTION_NAMES, *KEPT_NAMES), prevent_cse=False,
         )
-        with part("layers"):
-            x, per_layer = jax.lax.scan(body, x, params["layers"])
-        if kernels and self.moe.path not in (None, "gmm") and Llama._assumed_backend() == "tpu":
-            refusal, kernels = f"the experts took {self.moe.path}", False
-        path = KERNEL_PATH if kernels else f"plain: {refusal}"
-        if path != self.attention_path:
-            logger.info("attention path: %s", path)
-        self.attention_path = path
+        decoder.kernel_path(self, KERNEL_PATH, refusal, self.moe.path)
         return x, per_layer
 
     @part("head")
     def _logits(self, params: Dict[str, Any], x: jax.Array) -> jax.Array:
-        x = self._normed(x, params["final_norm"])
-        # the products' float32 sums as they are: a logit is never rounded to the model's dtype
-        return jnp.dot(x, params["lm_head"], preferred_element_type=jnp.float32)
+        cfg = self.config
+        return decoder.head_logits(x, params["final_norm"], params["lm_head"], cfg.norm_eps, cfg.dtype)
 
     def apply(self, params: Dict[str, Any], tokens: jax.Array, *more: Any) -> jax.Array:
         """tokens [B, S] (and what a batch may hold after its targets) →
@@ -364,10 +344,7 @@ class IndexedSparseMoE:
 
     def _losses(self, params: Dict[str, Any], batch: Tuple[Any, ...]) -> Tuple[jax.Array, Tuple[jax.Array, ...]]:
         x, per_layer = self._trunk(params, batch)
-        with part("head"):
-            logp = jax.nn.log_softmax(self._logits(params, x), axis=-1)
-            nll = -jnp.take_along_axis(logp, batch[1][..., None], axis=-1)[..., 0]
-            return jnp.mean(nll), per_layer
+        return decoder.mean_nll(self._logits(params, x), batch[1]), per_layer
 
     def loss(self, params: Dict[str, Any], batch: Tuple[Any, ...]) -> jax.Array:
         """Mean next-token cross-entropy."""
@@ -397,8 +374,5 @@ class IndexedSparseMoE:
             [rows, here.max(axis=1), here.mean(axis=1), kl, keys, self.moe.buffer_rows(tokens, rows)], axis=1
         )
 
-    @staticmethod
-    def summary_stats(summary: np.ndarray) -> Dict[str, List[float]]:
-        """:meth:`step_summary` on the host, as the flight event's detail."""
-        columns = np.asarray(summary, np.float64).reshape(-1, len(SUMMARY_FIELDS)).T
-        return {name: column.tolist() for name, column in zip(SUMMARY_FIELDS, columns)}
+    # :meth:`step_summary` on the host, as the flight event's detail
+    summary_stats = staticmethod(functools.partial(moe.summary_stats, fields=SUMMARY_FIELDS))

@@ -29,6 +29,7 @@ its parts as any layer does.
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, Optional, Sequence, Tuple
 
@@ -36,7 +37,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from torchft_tpu.models.llama import Llama, _proj
+from torchft_tpu.models import decoder
 from torchft_tpu.obs.spans import part
 
 
@@ -78,8 +79,7 @@ class LatentAttention:
         D, H = self.dim, self.n_heads
         qk = self.qk_nope_head_dim + self.qk_rope_head_dim
 
-        def normal(k, shape, fan_in):
-            return (jax.random.normal(k, shape, jnp.float32) / np.sqrt(fan_in)).astype(self.dtype)
+        normal = functools.partial(decoder.seeded, dtype=self.dtype)
 
         if self.q_lora_rank is None:
             w = {"wq": normal(keys[0], (D, H * qk), D)}
@@ -108,15 +108,15 @@ class LatentAttention:
         B, S, _ = h.shape
         H, nope, rot = self.n_heads, self.qk_nope_head_dim, self.qk_rope_head_dim
         if self.q_lora_rank is None:
-            q = _proj(h, w["wq"])
+            q = decoder.proj(h, w["wq"])
         else:
-            q = _proj(Llama._rms_norm(_proj(h, w["w_q_a"]), w["q_norm"], self.norm_eps), w["w_q_b"])
+            q = decoder.proj(decoder.rms_norm(decoder.proj(h, w["w_q_a"]), w["q_norm"], self.norm_eps), w["w_q_b"])
         q = q.reshape(B, S, H, nope + rot)
         q = jnp.concatenate([q[..., :nope], rope_interleaved(q[..., nope:], self.rope_theta)], axis=-1)
-        kv_a = _proj(h, w["w_kv_a"])
-        latent = Llama._rms_norm(kv_a[..., : self.kv_lora_rank], w["kv_norm"], self.norm_eps)
+        kv_a = decoder.proj(h, w["w_kv_a"])
+        latent = decoder.rms_norm(kv_a[..., : self.kv_lora_rank], w["kv_norm"], self.norm_eps)
         k_rot = rope_interleaved(kv_a[..., self.kv_lora_rank :], self.rope_theta)
-        kv = _proj(latent, w["w_kv_b"]).reshape(B, S, H, nope + self.v_head_dim)
+        kv = decoder.proj(latent, w["w_kv_b"]).reshape(B, S, H, nope + self.v_head_dim)
         k = jnp.concatenate(
             [kv[..., :nope], jnp.broadcast_to(k_rot[:, :, None, :], (B, S, H, rot))], axis=-1
         )
@@ -124,24 +124,18 @@ class LatentAttention:
         if kernels:
             from torchft_tpu.ops.flash_attention import flash_attention
 
-            block_q, block_k = Llama._flash_blocks(S)
+            block_q, block_k = decoder.flash_blocks(S)
             o = flash_attention(
                 q, k, v, causal=True, block_q=block_q, block_k=block_k,
-                interpret=Llama._assumed_backend() != "tpu",
+                interpret=decoder.assumed_backend() != "tpu",
             )
         else:
             scores = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) / np.sqrt(nope + rot)
             scores = jnp.where(jnp.tril(jnp.ones((S, S), bool))[None, None], scores, -1e30)
             o = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(scores, axis=-1).astype(q.dtype), v)
         if self.head_gate:
-            o = o * jax.nn.sigmoid(_proj(h, w["w_gate"]).astype(jnp.float32))[..., None].astype(o.dtype)
-        return _proj(o.reshape(B, S, -1), w["wo"])
-
-
-def token_nll(logits: jax.Array, labels: jax.Array) -> jax.Array:
-    """The cross-entropy of ``labels`` [B, S] under ``logits`` [B, S, V]."""
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    return -jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
+            o = o * jax.nn.sigmoid(decoder.proj(h, w["w_gate"]).astype(jnp.float32))[..., None].astype(o.dtype)
+        return decoder.proj(o.reshape(B, S, -1), w["wo"])
 
 
 def mtp_init(dim: int, k_proj: jax.Array, layer: Dict[str, Any], dtype: Any) -> Dict[str, Any]:
@@ -149,7 +143,7 @@ def mtp_init(dim: int, k_proj: jax.Array, layer: Dict[str, Any], dtype: Any) -> 
     return {
         "enorm": jnp.ones((dim,), jnp.float32),
         "hnorm": jnp.ones((dim,), jnp.float32),
-        "proj": (jax.random.normal(k_proj, (2 * dim, dim), jnp.float32) / np.sqrt(2 * dim)).astype(dtype),
+        "proj": decoder.seeded(k_proj, (2 * dim, dim), 2 * dim, dtype),
         "layer": layer,
         "final_norm": jnp.ones((dim,), jnp.float32),
     }
@@ -175,8 +169,8 @@ def mtp_token_nll(
     with part("mtp"):
         pair = jnp.concatenate(
             [
-                Llama._rms_norm(embed[targets].astype(x.dtype), m["enorm"], norm_eps).astype(dtype),
-                Llama._rms_norm(x, m["hnorm"], norm_eps).astype(dtype),
+                decoder.rms_norm(embed[targets].astype(x.dtype), m["enorm"], norm_eps).astype(dtype),
+                decoder.rms_norm(x, m["hnorm"], norm_eps).astype(dtype),
             ],
             axis=-1,
         )

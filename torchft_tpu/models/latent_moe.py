@@ -37,9 +37,9 @@ step differentiates, is ``loss + mtp_loss_weight * mtp + balance`` (the
 routers' sequence-wise balance loss, the module's router among them).
 
 **State the optimizer does not own**: every router's selection bias, the
-module's too, moved after a committed step as ``LingHybrid``'s is
+module's too, moved after a committed step by ``parallel/moe.py``'s rule
 (``state_mask``, ``objective``, ``advance_state``; ``HSDPTrainer``).  The
-step's summary is ``LingHybrid``'s rows, one an expert layer (the module's
+step's summary is ``RoutedExperts.route_summary``'s rows, one an expert layer (the module's
 last), and then ONE number more, ``mtp_nll``: the module's mean loss of the
 step, which ``summary_stats`` hands the MOE_ROUTE flight event beside the
 routing fields.
@@ -64,24 +64,19 @@ the grouped kernel; off the TPU the plain path, named ``"plain: <why>"``.
 from __future__ import annotations
 
 import functools
-import itertools
-import logging
 from dataclasses import dataclass, replace
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
 
-from torchft_tpu.models.latent import LatentAttention, mtp_init, mtp_token_nll, token_nll
-from torchft_tpu.models.ling_hybrid import ROUTE_FIELDS, LingHybrid
-from torchft_tpu.models.llama import Llama
+from torchft_tpu.models import decoder
+from torchft_tpu.models.latent import LatentAttention, mtp_init, mtp_token_nll
 from torchft_tpu.obs.spans import part
 from torchft_tpu.ops import flash_attention as flash
+from torchft_tpu.parallel import moe
 from torchft_tpu.parallel.moe import RoutedExperts, RoutedExpertsConfig, swiglu
-
-logger = logging.getLogger(__name__)
 
 KERNEL_PATH = "flash"
 
@@ -117,8 +112,7 @@ class LatentMoEConfig:
     def groups(self) -> List[Tuple[str, int]]:
         """Runs of contiguous layers of one feed-forward kind: (``"dense"``
         or ``"moe"``, how many)."""
-        kinds = ["dense" if i < self.first_k_dense else "moe" for i in range(self.n_layers)]
-        return [(kind, len(list(run))) for kind, run in itertools.groupby(kinds)]
+        return decoder.runs("dense" if i < self.first_k_dense else "moe" for i in range(self.n_layers))
 
 
 def latent_moe_debug(**over: Any) -> LatentMoEConfig:
@@ -170,12 +164,7 @@ class LatentMoE:
         cfg = self.config
         k_mixer, k_ffn = jax.random.split(key)
         if kind == "dense":
-            D, F = cfg.dim, cfg.dense_hidden
-            ks = jax.random.split(k_ffn, 3)
-            normal = lambda k, shape: (  # noqa: E731
-                jax.random.normal(k, shape, jnp.float32) / np.sqrt(shape[0])
-            ).astype(cfg.dtype)
-            ffn = {"w_gate": normal(ks[0], (D, F)), "w_up": normal(ks[1], (D, F)), "w_down": normal(ks[2], (F, D))}
+            ffn = decoder.dense_ffn_init(jax.random.split(k_ffn, 3), cfg.dim, cfg.dense_hidden, cfg.dtype)
         else:
             ffn = self.moe.init(k_ffn)
         return {
@@ -188,24 +177,12 @@ class LatentMoE:
     def init(self, key: jax.Array) -> Dict[str, Any]:
         cfg = self.config
         k_embed, k_out, k_layers, k_mtp = jax.random.split(key, 4)
-
-        def normal(k, shape, std):
-            return (std * jax.random.normal(k, shape, jnp.float32)).astype(cfg.dtype)
-
+        embed, lm_head = decoder.embed_and_head(k_embed, k_out, cfg.vocab_size, cfg.dim, cfg.dtype)
         params = {
-            # rows of unit variance, so that a token's own embedding leads the
-            # residual stream it enters and the first routers read the token,
-            # not what every attention output has in common (PERF.md section
-            # 6, PR 33)
-            "embed": normal(k_embed, (cfg.vocab_size, cfg.dim), 1.0),
-            "groups": [
-                jax.vmap(functools.partial(self._init_layer, kind))(
-                    jax.random.split(jax.random.fold_in(k_layers, n), depth)
-                )
-                for n, (kind, depth) in enumerate(self.groups)
-            ],
+            "embed": embed,
+            "groups": decoder.init_runs(self._init_layer, k_layers, self.groups),
             "final_norm": jnp.ones((cfg.dim,), jnp.float32),
-            "lm_head": normal(k_out, (cfg.dim, cfg.vocab_size), cfg.dim ** -0.5),
+            "lm_head": lm_head,
         }
         if cfg.n_mtp:
             k_proj, k_layer = jax.random.split(k_mtp)
@@ -214,36 +191,32 @@ class LatentMoE:
 
     @functools.cached_property
     def _shapes(self) -> Any:
-        """What ``init`` would make, as shapes (traced once a model)."""
-        return jax.eval_shape(self.init, jax.random.PRNGKey(0))
+        return decoder.shapes(self.init)
 
     def param_specs(self) -> Dict[str, Any]:
-        """One chip's share of a larger job: every leaf whole on the group's
-        one chip (the ``fsdp`` axis of this model's meshes has size 1)."""
-        return jax.tree_util.tree_map(lambda s: P(*([None] * len(s.shape))), self._shapes)
+        return decoder.one_chip_param_specs(self._shapes)
 
     def batch_specs(self) -> Tuple[Any, Any]:
-        spec = P(("dp", "fsdp"), None)
-        return spec, spec
+        return decoder.batch_specs()
 
     def num_params(self) -> int:
-        return sum(int(np.prod(s.shape)) for s in jax.tree_util.tree_leaves(self._shapes))
+        return decoder.num_params(self._shapes)
 
-    # the routers' selection biases, the module's among them: state the
-    # optimizer does not own, as ``LingHybrid``'s (a leaf called "bias"; a
-    # signal a leaf, its last axis the router's width)
-    state_mask = LingHybrid.state_mask
-    advance_state = LingHybrid.advance_state
-    route_summary = LingHybrid.route_summary
+    # the routers' selection biases, the module's among them: state the optimizer does not own (``parallel/moe.py``)
+    def state_mask(self) -> Any:
+        return moe.state_mask(self.param_specs())
+
+    def advance_state(self, state: List[jax.Array], signal: List[jax.Array]) -> List[jax.Array]:
+        return moe.advance_state(self.config.bias_update_rate, state, signal)
 
     def summary_stats(self, summary: np.ndarray) -> Dict[str, Any]:
         """``objective``'s summary on the host, as the flight event's detail:
-        ``LingHybrid``'s fields, a list an expert layer, and with the module
+        ``parallel/moe.py``'s fields, a list an expert layer, and with the module
         ``mtp_nll``, its mean loss of the step."""
         flat = np.asarray(summary, np.float64).reshape(-1)
         if not self.config.n_mtp:
-            return LingHybrid.summary_stats(flat)
-        return dict(LingHybrid.summary_stats(flat[:-1]), mtp_nll=float(flat[-1]))
+            return moe.summary_stats(flat)
+        return dict(moe.summary_stats(flat[:-1]), mtp_nll=float(flat[-1]))
 
     # ------------------------------------------------------------------
     # forward
@@ -251,16 +224,12 @@ class LatentMoE:
 
     def _kernel_refusal(self, seq: int) -> Optional[str]:
         """Why the Mosaic kernels do NOT apply, or None when they do."""
-        block_q, block_k = Llama._flash_blocks(seq)
-        shape_refusal = None
-        if seq < 32 or seq % 8 or seq % block_q or seq % block_k:
-            shape_refusal = f"seq={seq} does not divide into the blocks ({block_q}, {block_k})"
-        return Llama._one_chip_refusal(shape_refusal, self.mesh)
+        return decoder.kernel_refusal(seq, self.mesh)
 
     def _normed(self, x: jax.Array, weight: jax.Array) -> jax.Array:
         """What a layer reads of the float32 residual stream: its RMS norm,
         in the matrices' dtype."""
-        return Llama._rms_norm(x, weight, self.config.norm_eps).astype(self.config.dtype)
+        return decoder.rms_norm(x, weight, self.config.norm_eps).astype(self.config.dtype)
 
     def _block(
         self, x: jax.Array, w: Dict[str, Any], kind: str, kernels: bool
@@ -275,7 +244,7 @@ class LatentMoE:
             x = x + mixed
             # a router reads the float32 norm itself: which 8 of 256 experts a
             # token takes is a step function of it
-            h = Llama._rms_norm(x, w["mlp_norm"], cfg.norm_eps)
+            h = decoder.rms_norm(x, w["mlp_norm"], cfg.norm_eps)
         if kind == "dense":
             f = w["ffn"]
             with part("stream"):
@@ -287,20 +256,6 @@ class LatentMoE:
         out, load, balance = self.moe.apply(w["ffn"], h)
         with part("stream"):
             return x + out, load, balance
-
-    def _layer(self, kind: str, kernels: bool, in_a_loop: bool) -> Any:
-        """``(x, w) -> (x, load, balance)`` of one layer, rematerialised in
-        the backward pass from its float32 input and, in a stacked run's
-        layers, from what flash made (the module docstring says why there
-        and not everywhere).  jax's guard against XLA merging the second
-        forward with the first stays on where the layer is no loop's body
-        (``models/ssm_hybrid_moe.py``); inside a real loop it is not needed
-        and costs memory."""
-        keep = flash.KEPT_NAMES if in_a_loop else ()
-        return jax.checkpoint(
-            lambda x, w: self._block(x, w, kind, kernels),
-            policy=jax.checkpoint_policies.save_only_these_names(*keep), prevent_cse=not in_a_loop,
-        )
 
     def _trunk(
         self, params: Dict[str, Any], tokens: jax.Array
@@ -314,30 +269,26 @@ class LatentMoE:
             x = params["embed"][tokens].astype(jnp.float32)  # the residual stream
         loads, balance = [], jnp.zeros((), jnp.float32)
         for (kind, depth), stacked in zip(self.groups, params["groups"]):
-            layer = self._layer(kind, kernels, in_a_loop=depth > 1)
 
-            def body(carry, w, layer=layer):
-                y, load, bal = layer(carry, w)
+            def body(carry, w, kind=kind):
+                y, load, bal = self._block(carry, w, kind, kernels)
                 return y, (load, bal)
 
-            with part("layers"):
-                x, (load, bal) = jax.lax.scan(body, x, stacked)
+            # a layer is rematerialised from its float32 input and, in a
+            # stacked run's layers, from what flash made (the module
+            # docstring says why there and not everywhere)
+            x, (load, bal) = decoder.scan_run(body, x, stacked, depth, keep=flash.KEPT_NAMES if depth > 1 else ())
             if kind == "moe":
                 loads.append(load)
                 with part("experts_route"):
                     balance = balance + jnp.sum(bal)
-        if kernels and self.moe.path not in (None, "gmm") and Llama._assumed_backend() == "tpu":
-            refusal, kernels = f"the experts took {self.moe.path}", False
-        path = KERNEL_PATH if kernels else f"plain: {refusal}"
-        if path != self.attention_path:
-            logger.info("attention path: %s", path)
-        self.attention_path = path
+        kernels = decoder.kernel_path(self, KERNEL_PATH, refusal, self.moe.path)
         return x, loads, balance, kernels
 
     def _head(self, head: jax.Array, x: jax.Array, norm: jax.Array) -> jax.Array:
         """A norm and the head; the products' float32 sums as they are: a
         logit is never rounded to the model's dtype."""
-        return jnp.dot(self._normed(x, norm), head, preferred_element_type=jnp.float32)
+        return decoder.head_logits(x, norm, head, self.config.norm_eps, self.config.dtype)
 
     def apply(self, params: Dict[str, Any], tokens: jax.Array) -> jax.Array:
         """tokens [B, S] → logits [B, S, vocab] (fp32)."""
@@ -350,7 +301,7 @@ class LatentMoE:
         """The cross-entropy of ``labels`` at every position [B, S] through a
         norm and the head, under whatever part the caller stands in; the
         logits are made again in the backward pass, never kept."""
-        return token_nll(self._head(head, x, norm), labels)
+        return decoder.token_nll(self._head(head, x, norm), labels)
 
     def _module(
         self, params: Dict[str, Any], x: jax.Array, targets: jax.Array, kernels: bool
@@ -361,7 +312,7 @@ class LatentMoE:
         left out, not wrapped."""
         nll, load, balance = mtp_token_nll(
             params["mtp"], params["embed"], x, targets,
-            layer=self._layer("moe", kernels, in_a_loop=False),
+            layer=decoder.remat(lambda z, w: self._block(z, w, "moe", kernels), depth=1),
             head_nll=functools.partial(self._token_nll, params["lm_head"]),
             norm_eps=self.config.norm_eps, dtype=self.config.dtype,
         )
@@ -390,7 +341,7 @@ class LatentMoE:
         its weight, the routers' balance loss), for every leaf of
         ``state_mask`` the step's signal (the tokens each expert was chosen
         by; the module's router last) and the step's summary
-        (:meth:`route_summary` of this replica's own signal, then the
+        (``RoutedExperts.route_summary`` of this replica's own signal, then the
         module's mean loss)."""
         cfg = self.config
         tokens, targets = batch
@@ -406,5 +357,5 @@ class LatentMoE:
             signal, more = [*signal, load], [mtp.reshape(1)]
         with part("head"):
             # a model of dense layers alone has no router to sum up
-            routes = self.route_summary(signal, tokens.size) if signal else jnp.zeros((0, len(ROUTE_FIELDS)))
+            routes = self.moe.route_summary(signal, tokens.size) if signal else jnp.zeros((0, len(moe.ROUTE_FIELDS)))
             return total, (signal, jnp.concatenate([routes.reshape(-1), *more]).astype(jnp.float32))

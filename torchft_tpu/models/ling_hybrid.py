@@ -45,26 +45,20 @@ built for ``n_mtp`` > 0.  The MLA mixer is ``models/latent.py``'s too.
 from __future__ import annotations
 
 import functools
-import logging
 from dataclasses import dataclass, replace
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
-from jax.sharding import PartitionSpec as P
 
-from torchft_tpu.models.latent import LatentAttention, mtp_init, mtp_token_nll, token_nll
-from torchft_tpu.models.llama import Llama, _proj
+from torchft_tpu.models import decoder
+from torchft_tpu.models.latent import LatentAttention, mtp_init, mtp_token_nll
 from torchft_tpu.obs.spans import part
+from torchft_tpu.parallel import moe
 from torchft_tpu.parallel.moe import RoutedExperts, RoutedExpertsConfig, swiglu
-
-logger = logging.getLogger(__name__)
 
 KERNEL_PATH = "kda+flash"
 KDA_CHUNK = 64  # tokens a chunk of the delta rule (ops/kda.py)
-# a step's summary, one row an expert layer (``route_summary``, ``summary_stats``)
-ROUTE_FIELDS = ("rows_here", "load_max", "load_mean", "buffer_rows")
 
 
 @dataclass(frozen=True)
@@ -118,13 +112,7 @@ class LingHybridConfig:
 
     def groups(self) -> List[Tuple[Tuple[str, str, float, float], int]]:
         """Runs of contiguous layers of one kind: (kind, how many)."""
-        out: List[Tuple[Any, int]] = []
-        for kind in self.kinds():
-            if out and out[-1][0] == kind:
-                out[-1] = (kind, out[-1][1] + 1)
-            else:
-                out.append((kind, 1))
-        return out
+        return decoder.runs(self.kinds())
 
 
 def ling_debug(**over: Any) -> LingHybridConfig:
@@ -138,22 +126,6 @@ def ling_debug(**over: Any) -> LingHybridConfig:
         ),
         **over,
     )
-
-
-def _short_conv_silu(x: jax.Array, w: jax.Array, bias: Optional[jax.Array] = None) -> jax.Array:
-    """Causal depthwise convolution (the last tap is the current token's),
-    a bias a channel where one is given, and SiLU.  x [B, S, C], w [K, C]."""
-    K, S = w.shape[0], x.shape[1]
-    padded = jnp.pad(x, ((0, 0), (K - 1, 0), (0, 0)))
-    acc = sum(padded[:, j : j + S].astype(jnp.float32) * w[j].astype(jnp.float32) for j in range(K))
-    if bias is not None:
-        acc = acc + bias.astype(jnp.float32)
-    return jax.nn.silu(acc).astype(x.dtype)
-
-
-def _unit(x: jax.Array) -> jax.Array:
-    x32 = x.astype(jnp.float32)
-    return (x32 * jax.lax.rsqrt(jnp.sum(x32 * x32, axis=-1, keepdims=True) + 1e-6)).astype(x.dtype)
 
 
 class LingHybrid:
@@ -190,8 +162,7 @@ class LingHybrid:
         D, H = cfg.dim, cfg.n_heads
         keys = jax.random.split(key, 10)
 
-        def normal(k, shape, fan_in):
-            return (jax.random.normal(k, shape, jnp.float32) / np.sqrt(fan_in)).astype(cfg.dtype)
+        normal = functools.partial(decoder.seeded, dtype=cfg.dtype)
 
         if kind == "mla":
             return self.mla.init(keys)
@@ -217,13 +188,7 @@ class LingHybrid:
         cfg = self.config
         k_mixer, k_ffn = jax.random.split(key)
         if kind[1] == "dense":
-            ks = jax.random.split(k_ffn, 3)
-            shape_in, shape_out = (cfg.dim, cfg.dense_hidden), (cfg.dense_hidden, cfg.dim)
-            scale = lambda k, shape: (  # noqa: E731
-                jax.random.normal(k, shape, jnp.float32) / np.sqrt(shape[0])
-            ).astype(cfg.dtype)
-            ffn = {"w_gate": scale(ks[0], shape_in), "w_up": scale(ks[1], shape_in),
-                   "w_down": scale(ks[2], shape_out)}
+            ffn = decoder.dense_ffn_init(jax.random.split(k_ffn, 3), cfg.dim, cfg.dense_hidden, cfg.dtype)
         else:
             ffn = self.moe.init(k_ffn)
         return {
@@ -236,17 +201,10 @@ class LingHybrid:
     def init(self, key: jax.Array) -> Dict[str, Any]:
         cfg = self.config
         k_embed, k_out, k_layers, k_mtp = jax.random.split(key, 4)
-        groups = []
-        for n, (kind, depth) in enumerate(cfg.groups()):
-            keys = jax.random.split(jax.random.fold_in(k_layers, n), depth)
-            groups.append(jax.vmap(lambda k, kind=kind: self._init_layer(kind, k))(keys))
-
-        def table(k, shape):
-            return (jax.random.normal(k, shape, jnp.float32) / np.sqrt(cfg.dim)).astype(cfg.dtype)
-
+        table = functools.partial(decoder.seeded, fan_in=cfg.dim, dtype=cfg.dtype)
         params = {
             "embed": table(k_embed, (cfg.vocab_size, cfg.dim)),
-            "groups": groups,
+            "groups": decoder.init_runs(self._init_layer, k_layers, cfg.groups()),
             "final_norm": jnp.ones((cfg.dim,), jnp.float32),
             "lm_head": table(k_out, (cfg.dim, cfg.vocab_size)),
         }
@@ -255,55 +213,27 @@ class LingHybrid:
             params["mtp"] = mtp_init(cfg.dim, k_proj, self._init_layer(("mla", "moe", 0.0, 0.0), k_layer), cfg.dtype)
         return params
 
-    def param_specs(self) -> Dict[str, Any]:
-        """One chip's share of a larger job: every leaf whole on the group's
-        one chip (the ``fsdp`` axis of this model's meshes has size 1)."""
-        return jax.tree_util.tree_map(lambda s: P(*([None] * len(s.shape))), self._shapes)
-
     @functools.cached_property
     def _shapes(self) -> Any:
-        """What ``init`` would make, as shapes (traced once a model)."""
-        return jax.eval_shape(self.init, jax.random.PRNGKey(0))
+        return decoder.shapes(self.init)
+
+    def param_specs(self) -> Dict[str, Any]:
+        return decoder.one_chip_param_specs(self._shapes)
 
     def batch_specs(self) -> Tuple[Any, Any]:
-        spec = P(("dp", "fsdp"), None)
-        return spec, spec
+        return decoder.batch_specs()
 
+    def num_params(self) -> int:
+        return decoder.num_params(self._shapes)
+
+    # the routers' selection biases: state the optimizer does not own (``parallel/moe.py``)
     def state_mask(self) -> Any:
-        """True for the leaves the optimizer does not own: the routers'
-        selection biases."""
-        return jax.tree_util.tree_map_with_path(
-            lambda path, _: getattr(path[-1], "key", None) == "bias",
-            self.param_specs(),
-            is_leaf=lambda x: isinstance(x, P),
-        )
+        return moe.state_mask(self.param_specs())
 
     def advance_state(self, state: List[jax.Array], signal: List[jax.Array]) -> List[jax.Array]:
-        """``bias += rate * sign(mean(load) - load)``, a router at a time
-        (the last axis is the router's width)."""
-        rate = self.config.bias_update_rate
-        return [
-            bias + rate * jnp.sign(jnp.mean(load, axis=-1, keepdims=True) - load)
-            for bias, load in zip(state, signal)
-        ]
+        return moe.advance_state(self.config.bias_update_rate, state, signal)
 
-    def route_summary(self, loads: List[jax.Array], tokens: int) -> jax.Array:
-        """Of this replica's step of ``tokens`` tokens, on the device:
-        ``[expert layers, 4]`` in the order of ``ROUTE_FIELDS``: the rows
-        routed to the held experts, their largest and mean load and the
-        rows of the experts' buffer they went through
-        (``RoutedExperts.buffer_rows``), expert layer by expert layer (a
-        stacked leaf is one row a layer)."""
-        first, held = self.config.experts_held
-        here = jnp.concatenate([x.reshape(-1, x.shape[-1]) for x in loads])[:, first : first + held]
-        rows = here.sum(axis=1)
-        return jnp.stack([rows, here.max(axis=1), here.mean(axis=1), self.moe.buffer_rows(tokens, rows)], axis=1)
-
-    @staticmethod
-    def summary_stats(summary: np.ndarray) -> Dict[str, List[float]]:
-        """:meth:`route_summary` on the host, as the flight event's detail."""
-        columns = np.asarray(summary, np.float64).reshape(-1, len(ROUTE_FIELDS)).T
-        return {name: column.tolist() for name, column in zip(ROUTE_FIELDS, columns)}
+    summary_stats = staticmethod(moe.summary_stats)
 
     # ------------------------------------------------------------------
     # forward
@@ -311,17 +241,12 @@ class LingHybrid:
 
     def _kernel_refusal(self, seq: int) -> Optional[str]:
         """Why the Mosaic kernels do NOT apply, or None when they do."""
-        block_q, block_k = Llama._flash_blocks(seq)
+        block_q, block_k = decoder.flash_blocks(seq)
         chunk = min(KDA_CHUNK, seq)
         shape_refusal = None
         if seq < 32 or seq % 8 or seq % block_q or seq % block_k or seq % chunk or chunk % min(32, chunk):
             shape_refusal = f"seq={seq} does not divide into the blocks ({block_q}, {block_k}) and chunks of {chunk}"
-        return Llama._one_chip_refusal(shape_refusal, self.mesh)
-
-    def _record_path(self, path: str) -> None:
-        if path != self.attention_path:
-            logger.info("attention path: %s", path)
-        self.attention_path = path
+        return decoder.one_chip_refusal(shape_refusal, self.mesh)
 
     @part("mixer_glue")
     def _kda(self, h: jax.Array, w: Dict[str, jax.Array], kernels: bool) -> jax.Array:
@@ -331,24 +256,24 @@ class LingHybrid:
         B, S, _ = h.shape
         H = cfg.n_heads
         heads = lambda a: a.reshape(B, S, H, -1)  # noqa: E731
-        q = _unit(heads(_short_conv_silu(_proj(h, w["wq"]), w["conv_q"])))
-        k = _unit(heads(_short_conv_silu(_proj(h, w["wk"]), w["conv_k"])))
-        v = heads(_short_conv_silu(_proj(h, w["wv"]), w["conv_v"]))
+        q = decoder.unit(heads(decoder.short_conv_silu(decoder.proj(h, w["wq"]), w["conv_q"])))
+        k = decoder.unit(heads(decoder.short_conv_silu(decoder.proj(h, w["wk"]), w["conv_k"])))
+        v = heads(decoder.short_conv_silu(decoder.proj(h, w["wv"]), w["conv_v"]))
         # the log of the decay, for every channel, in [lower_bound, 0]
         g = cfg.kda_lower_bound * jax.nn.sigmoid(
             jnp.exp(w["a_log"])[None, None, :, None]
-            * heads(_proj(h, w["w_g"]).astype(jnp.float32) + w["dt_bias"])
+            * heads(decoder.proj(h, w["w_g"]).astype(jnp.float32) + w["dt_bias"])
         )
-        beta = jax.nn.sigmoid(_proj(h, w["w_beta"]).astype(jnp.float32))
+        beta = jax.nn.sigmoid(decoder.proj(h, w["w_beta"]).astype(jnp.float32))
         if kernels:
             o = kda_chunked(
-                q, k, v, g, beta, chunk=KDA_CHUNK, interpret=Llama._assumed_backend() != "tpu"
+                q, k, v, g, beta, chunk=KDA_CHUNK, interpret=decoder.assumed_backend() != "tpu"
             )
         else:
             o = kda_chunked_plain(q, k, v, g, beta, chunk=KDA_CHUNK)
-        o = Llama._rms_norm(o, w["o_norm"], cfg.norm_eps)
-        o = o * jax.nn.sigmoid(_proj(h, w["w_gate"]).astype(jnp.float32))[..., None].astype(o.dtype)
-        return _proj(o.reshape(B, S, -1), w["wo"])
+        o = decoder.rms_norm(o, w["o_norm"], cfg.norm_eps)
+        o = o * jax.nn.sigmoid(decoder.proj(h, w["w_gate"]).astype(jnp.float32))[..., None].astype(o.dtype)
+        return decoder.proj(o.reshape(B, S, -1), w["wo"])
 
     def _block(
         self, x: jax.Array, w: Dict[str, Any], kind: Tuple[str, str, float, float], kernels: bool
@@ -358,11 +283,11 @@ class LingHybrid:
         cfg = self.config
         mixer = self.mla.apply if kind[0] == "mla" else self._kda
         with part("stream"):
-            h = Llama._rms_norm(x, w["attn_norm"], cfg.norm_eps)
+            h = decoder.rms_norm(x, w["attn_norm"], cfg.norm_eps)
         mixed = mixer(h, w["mixer"], kernels)
         with part("stream"):
             x = x + mixed
-            h = Llama._rms_norm(x, w["mlp_norm"], cfg.norm_eps)
+            h = decoder.rms_norm(x, w["mlp_norm"], cfg.norm_eps)
         if kind[1] == "dense":
             f = w["ffn"]
             with part("ffn"):
@@ -385,7 +310,7 @@ class LingHybrid:
         with part("embed"):
             x = params["embed"][tokens].astype(cfg.dtype)
         loads, balance = [], jnp.zeros((), jnp.float32)
-        for (kind, _depth), stacked in zip(cfg.groups(), params["groups"]):
+        for (kind, depth), stacked in zip(cfg.groups(), params["groups"]):
 
             def body(carry, w, kind=kind):
                 y, load, bal = self._block(carry, w, kind, kernels)
@@ -396,23 +321,18 @@ class LingHybrid:
             # merged its second forward with the first anyway (no barrier
             # forbids it), until the experts' loop of passes stood in its
             # way (PERF.md section 6, PR 42)
-            if _depth > 1:
-                body = jax.checkpoint(
-                    body, policy=jax.checkpoint_policies.nothing_saveable, prevent_cse=False
-                )
-            with part("layers"):
-                x, (load, bal) = jax.lax.scan(body, x, stacked)
+            x, (load, bal) = decoder.scan_run(
+                body, x, stacked, depth, keep=() if depth > 1 else None, prevent_cse=False
+            )
             loads.append(load)
             with part("experts_route"):
                 balance = balance + jnp.sum(bal)
-        if kernels and self.moe.path not in (None, "gmm") and Llama._assumed_backend() == "tpu":
-            refusal, kernels = f"the experts took {self.moe.path}", False
-        self._record_path(KERNEL_PATH if kernels else f"plain: {refusal}")
+        kernels = decoder.kernel_path(self, KERNEL_PATH, refusal, self.moe.path)
         return x, loads, balance, kernels
 
     def _head(self, params: Dict[str, Any], x: jax.Array, norm: jax.Array) -> jax.Array:
         """A norm and the head, under whatever part the caller stands in."""
-        x = Llama._rms_norm(x, norm, self.config.norm_eps)
+        x = decoder.rms_norm(x, norm, self.config.norm_eps)
         return (x @ params["lm_head"]).astype(jnp.float32)
 
     @part("head")
@@ -424,12 +344,6 @@ class LingHybrid:
         x, _, _, _ = self._trunk(params, tokens)
         return self._logits(params, x, params["final_norm"])
 
-    @staticmethod
-    @part("head")
-    def _mean_nll(logits: jax.Array, targets: jax.Array) -> jax.Array:
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        return jnp.mean(-jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0])
-
     def _losses(
         self, params: Dict[str, Any], batch: Tuple[jax.Array, jax.Array]
     ) -> Tuple[jax.Array, jax.Array, List[jax.Array]]:
@@ -438,7 +352,7 @@ class LingHybrid:
         cfg = self.config
         tokens, targets = batch
         x, loads, balance, kernels = self._trunk(params, tokens)
-        loss = self._mean_nll(self._logits(params, x, params["final_norm"]), targets)
+        loss = decoder.mean_nll(self._logits(params, x, params["final_norm"]), targets)
         # the loads of the groups that have routers, in the groups' order
         signal = [
             load for (kind, _), load in zip(cfg.groups(), loads) if kind[1] == "moe"
@@ -448,11 +362,10 @@ class LingHybrid:
             # model's reference does (``models/latent.py``)
             nll, load, bal = mtp_token_nll(
                 params["mtp"], params["embed"], x, targets,
-                layer=jax.checkpoint(
-                    lambda z, w: self._block(z, w, ("mla", "moe", 0.0, 0.0), kernels),
-                    policy=jax.checkpoint_policies.nothing_saveable, prevent_cse=False,
+                layer=decoder.remat(
+                    lambda z, w: self._block(z, w, ("mla", "moe", 0.0, 0.0), kernels), depth=1, prevent_cse=False
                 ),
-                head_nll=lambda z, norm, labels: token_nll(self._head(params, z, norm), labels),
+                head_nll=lambda z, norm, labels: decoder.token_nll(self._head(params, z, norm), labels),
                 norm_eps=cfg.norm_eps, dtype=cfg.dtype,
             )
             with part("mtp"):
@@ -472,10 +385,7 @@ class LingHybrid:
         """What a training step differentiates (``loss`` and the routers'
         balance loss), for every leaf of ``state_mask`` the step's signal
         (the tokens each expert was chosen by) and the step's summary
-        (:meth:`route_summary` of this replica's own signal)."""
+        (``RoutedExperts.route_summary`` of this replica's own signal)."""
         loss, balance, signal = self._losses(params, batch)
         with part("head"):
-            return loss + balance, (signal, self.route_summary(signal, batch[0].size))
-
-    def num_params(self) -> int:
-        return sum(int(np.prod(s.shape)) for s in jax.tree_util.tree_leaves(self._shapes))
+            return loss + balance, (signal, self.moe.route_summary(signal, batch[0].size))

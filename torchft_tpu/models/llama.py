@@ -33,16 +33,10 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from torchft_tpu.models import decoder
 from torchft_tpu.obs.spans import part
 
 logger = logging.getLogger(__name__)
-
-
-@part("mixer_proj")
-def _proj(x: jax.Array, w: jax.Array) -> jax.Array:
-    """A product into or out of a mixer, named so inside the mixer's glue
-    (``obs/spans.py``: the innermost scope is the operation's part)."""
-    return x @ w
 
 
 @dataclass(frozen=True)
@@ -142,10 +136,7 @@ class Llama:
         cfg = self.config
         k_embed, k_layers, k_out = jax.random.split(key, 3)
 
-        def _norm(k, shape, fan_in):
-            return (
-                jax.random.normal(k, shape, dtype=jnp.float32) / np.sqrt(fan_in)
-            ).astype(cfg.dtype)
+        _norm = functools.partial(decoder.seeded, dtype=cfg.dtype)
 
         hd = cfg.head_dim
         L = cfg.n_layers
@@ -218,11 +209,12 @@ class Llama:
     # forward
     # ------------------------------------------------------------------
 
-    @staticmethod
-    def _rms_norm(x: jax.Array, weight: jax.Array, eps: float) -> jax.Array:
-        x32 = x.astype(jnp.float32)
-        rms = jnp.sqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
-        return ((x32 / rms) * weight).astype(x.dtype)
+    # what this model shares with every decoder (``models/decoder.py``),
+    # under the names its own program and its tests call
+    _rms_norm = staticmethod(decoder.rms_norm)
+    _apply_rope = staticmethod(decoder.apply_rope)
+    _assumed_backend = staticmethod(decoder.assumed_backend)
+    _flash_blocks = staticmethod(decoder.flash_blocks)
 
     def _rope(self, positions: jax.Array) -> Tuple[jax.Array, jax.Array]:
         cfg = self.config
@@ -232,65 +224,6 @@ class Llama:
         )
         angles = positions[:, :, None].astype(jnp.float32) * freqs  # [B,S,half]
         return jnp.cos(angles), jnp.sin(angles)
-
-    @staticmethod
-    def _apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
-        # x: [B, S, H, D]; rotate pairs (x1, x2) per RoPE
-        x1, x2 = jnp.split(x, 2, axis=-1)
-        c = cos[:, :, None, :]
-        s = sin[:, :, None, :]
-        return jnp.concatenate(
-            [x1 * c - x2 * s, x2 * c + x1 * s], axis=-1
-        ).astype(x.dtype)
-
-    @staticmethod
-    def _assumed_backend() -> str:
-        """The platform kernel dispatch plans for.  Normally the runtime
-        backend; ``TORCHFT_FLASH_PLATFORM`` overrides it so a device-free
-        host can trace the TPU program (``parallel/rehearsal.py`` lowers
-        the real Mosaic flash kernels for a pod without owning one)."""
-        return os.environ.get("TORCHFT_FLASH_PLATFORM") or jax.default_backend()
-
-    @staticmethod
-    def _flash_blocks(seq: int) -> Tuple[int, int]:
-        """(block_q, block_k) for the flash kernel: env-tunable (the bench
-        sweeps them when hunting MFU), clamped to the sequence length.
-        A malformed or non-positive override falls back to the 512 default
-        (the divisibility gate then decides flash vs naive)."""
-
-        def _env(name: str) -> int:
-            try:
-                v = int(os.environ.get(name, "512"))
-            except ValueError:
-                return 512
-            return v if v > 0 else 512
-
-        return (
-            min(seq, _env("TORCHFT_FLASH_BLOCK_Q")),
-            min(seq, _env("TORCHFT_FLASH_BLOCK_K")),
-        )
-
-    @staticmethod
-    def _one_chip_refusal(shape_refusal: Optional[str], mesh: Optional[Any]) -> Optional[str]:
-        """Why kernels that are one chip's do NOT apply, or None when they
-        do, for the models whose groups are one chip (``shape_refusal``:
-        what the model's own kernels say of the sequence length).
-        ``TORCHFT_FLASH`` = 1 forces them (interpret mode off the TPU), 0
-        kills them, unset: on a TPU, one chip a group."""
-        env = os.environ.get("TORCHFT_FLASH", "")
-        if env == "0":
-            return "TORCHFT_FLASH=0"
-        if shape_refusal:
-            return shape_refusal
-        if env == "1":
-            return None
-        backend = Llama._assumed_backend()
-        if backend != "tpu":
-            return f"backend is {backend}, not tpu"
-        mesh_size = 1 if mesh is None else int(np.prod(list(mesh.shape.values())))
-        if mesh_size > 1:
-            return f"a group of {mesh_size} chips: the kernels are one chip's"
-        return None
 
     def _flash_refusal(self, seq: int) -> Optional[str]:
         """Why the fused Pallas kernel (``ops/flash_attention.py``) does NOT
@@ -509,19 +442,12 @@ class Llama:
         def scan_body(carry, layer_params):
             return self._layer(carry, layer_params, rope, positions), None
 
-        if cfg.effective_remat_mode == "layer":
-            # keep only the residual stream at layer boundaries; each layer
-            # recomputes in the backward pass
-            # prevent_cse is unnecessary under lax.scan (per jax docs) and
-            # its optimization barriers cost step time
-            scan_body = jax.checkpoint(
-                scan_body,
-                policy=jax.checkpoint_policies.nothing_saveable,
-                prevent_cse=False,
-            )
-
-        with part("layers"):
-            x, _ = jax.lax.scan(scan_body, x, params["layers"])
+        # "layer" mode keeps only the residual stream at layer boundaries and
+        # each layer recomputes in the backward pass; prevent_cse is
+        # unnecessary under lax.scan (per jax docs) and its optimization
+        # barriers cost step time: off whatever the depth
+        keep = () if cfg.effective_remat_mode == "layer" else None
+        x, _ = decoder.scan_run(scan_body, x, params["layers"], cfg.n_layers, keep=keep, prevent_cse=False)
         with part("head"):
             x = self._rms_norm(x, params["final_norm"], cfg.norm_eps)
             return (x @ params["lm_head"]).astype(jnp.float32)
@@ -532,10 +458,7 @@ class Llama:
         """Mean next-token cross-entropy; batch = (tokens, targets)."""
         tokens, targets = batch
         logits = self.apply(params, tokens)
-        with part("head"):
-            logp = jax.nn.log_softmax(logits, axis=-1)
-            nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-            return jnp.mean(nll)
+        return decoder.mean_nll(logits, targets)
 
     def _attn_params_per_layer(self) -> int:
         cfg = self.config

@@ -95,7 +95,7 @@ class LlamaMoE(Llama):
 
         for layer in range(cfg.n_layers):
             lp = {k: v[layer] for k, v in params["layers"].items()}
-            # shared attention half (Llama._attn_block); only the FFN differs
+            # the attention half is the dense model's (``_attn_block``, inherited); only the FFN differs
             x = self._attn_block(x, lp, rope, positions)
             h = self._rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
             x = x + self.moe.apply(params["moe_layers"][layer], h).astype(cfg.dtype)

@@ -36,7 +36,7 @@ model under an adaptive exit loss) is NOT part of the step.  The model has no
 state the optimizer does not own.
 
 What is the model's and what a kernel's: projections, norms, rope, the gate
-and the objective are here, plain ``jax.numpy`` under ``Llama``'s helpers; the
+and the objective are here, plain ``jax.numpy`` over ``models/decoder.py``'s helpers; the
 attention is ``ops/flash_attention.py``'s (``flash_fwd``, ``flash_dq``,
 ``flash_dkv``), ``L x T`` calls of each a step.  ``attention_path`` is
 ``"flash"`` only if the kernels ran; off the TPU the layers take plain
@@ -58,22 +58,17 @@ whole pass is ever held.
 from __future__ import annotations
 
 import functools
-import logging
 from dataclasses import dataclass, replace
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
 
-from torchft_tpu.models.latent import token_nll
-from torchft_tpu.models.llama import Llama
+from torchft_tpu.models import decoder
 from torchft_tpu.obs.spans import part
 from torchft_tpu.ops import flash_attention as flash
 from torchft_tpu.parallel.moe import swiglu
-
-logger = logging.getLogger(__name__)
 
 KERNEL_PATH = "flash"
 
@@ -124,7 +119,7 @@ class Looped:
         keys = jax.random.split(key, 7)
 
         def normal(k, shape):
-            return (jax.random.normal(k, shape, jnp.float32) / np.sqrt(shape[0])).astype(cfg.dtype)
+            return decoder.seeded(k, shape, shape[0], cfg.dtype)
 
         ones = jnp.ones((D,), jnp.float32)
         return {
@@ -139,12 +134,13 @@ class Looped:
         cfg = self.config
         k_embed, k_out, k_gate, k_layers = jax.random.split(key, 4)
         scale = cfg.dim ** -0.5
+        embed, lm_head = decoder.embed_and_head(k_embed, k_out, cfg.vocab_size, cfg.dim, cfg.dtype, embed_std=scale)
         return {
-            "embed": (scale * jax.random.normal(k_embed, (cfg.vocab_size, cfg.dim), jnp.float32)).astype(cfg.dtype),
+            "embed": embed,
             # ONE stack: every pass reads these leaves
             "layers": jax.vmap(self._init_layer)(jax.random.split(k_layers, cfg.n_layers)),
             "final_norm": jnp.ones((cfg.dim,), jnp.float32),
-            "lm_head": (scale * jax.random.normal(k_out, (cfg.dim, cfg.vocab_size), jnp.float32)).astype(cfg.dtype),
+            "lm_head": lm_head,
             # float32 as the norms are: the gate's logit is of order one at
             # the seeded start (``x_t`` leaves a norm), its bias 0
             "gate": {"w": scale * jax.random.normal(k_gate, (cfg.dim,), jnp.float32), "b": jnp.zeros((1,), jnp.float32)},
@@ -152,20 +148,16 @@ class Looped:
 
     @functools.cached_property
     def _shapes(self) -> Any:
-        """What ``init`` would make, as shapes (traced once a model)."""
-        return jax.eval_shape(self.init, jax.random.PRNGKey(0))
+        return decoder.shapes(self.init)
 
     def param_specs(self) -> Dict[str, Any]:
-        """One chip's share of a larger job: every leaf whole on the group's
-        one chip (the ``fsdp`` axis of this model's meshes has size 1)."""
-        return jax.tree_util.tree_map(lambda s: P(*([None] * len(s.shape))), self._shapes)
+        return decoder.one_chip_param_specs(self._shapes)
 
     def batch_specs(self) -> Tuple[Any, Any]:
-        spec = P(("dp", "fsdp"), None)
-        return spec, spec
+        return decoder.batch_specs()
 
     def num_params(self) -> int:
-        return sum(int(np.prod(s.shape)) for s in jax.tree_util.tree_leaves(self._shapes))
+        return decoder.num_params(self._shapes)
 
     @staticmethod
     def summary_stats(summary: np.ndarray) -> Dict[str, Any]:
@@ -185,11 +177,7 @@ class Looped:
 
     def _kernel_refusal(self, seq: int) -> Optional[str]:
         """Why the Mosaic kernels do NOT apply, or None when they do."""
-        block_q, block_k = Llama._flash_blocks(seq)
-        shape_refusal = None
-        if seq < 32 or seq % 8 or seq % block_q or seq % block_k:
-            shape_refusal = f"seq={seq} does not divide into the blocks ({block_q}, {block_k})"
-        return Llama._one_chip_refusal(shape_refusal, self.mesh)
+        return decoder.kernel_refusal(seq, self.mesh)
 
     def _attention(self, h: jax.Array, w: Dict[str, jax.Array], rope: Tuple[jax.Array, jax.Array], kernels: bool) -> jax.Array:
         cfg = self.config
@@ -198,12 +186,12 @@ class Looped:
         with part("mixer_proj"):
             q, k, v = ((h @ w[name]).reshape(B, S, H, hd) for name in ("wq", "wk", "wv"))
         with part("mixer_glue"):
-            q, k = Llama._apply_rope(q, *rope), Llama._apply_rope(k, *rope)
+            q, k = decoder.apply_rope(q, *rope), decoder.apply_rope(k, *rope)
             if kernels:
-                block_q, block_k = Llama._flash_blocks(S)
+                block_q, block_k = decoder.flash_blocks(S)
                 o = flash.flash_attention(
                     q, k, v, causal=True, block_q=block_q, block_k=block_k,
-                    interpret=Llama._assumed_backend() != "tpu",
+                    interpret=decoder.assumed_backend() != "tpu",
                 )
             else:
                 scores = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * hd ** -0.5
@@ -214,7 +202,7 @@ class Looped:
 
     def _block(self, x: jax.Array, w: Dict[str, Any], rope: Tuple[jax.Array, jax.Array], kernels: bool) -> jax.Array:
         """One layer on the stream: both halves between two norms."""
-        norm = lambda a, name: Llama._rms_norm(a, w["norms"][name], self.config.norm_eps)  # noqa: E731
+        norm = lambda a, name: decoder.rms_norm(a, w["norms"][name], self.config.norm_eps)  # noqa: E731
         with part("stream"):
             h = norm(x, "mixer_in")
         mixed = self._attention(h, w, rope, kernels)
@@ -240,34 +228,22 @@ class Looped:
         with part("embed"):
             x = params["embed"][tokens].astype(cfg.dtype)
         with part("mixer_glue"):
-            half = cfg.head_dim // 2
-            freqs = 1.0 / (cfg.rope_theta ** (jnp.arange(half, dtype=jnp.float32) / half))
-            angles = jnp.arange(S, dtype=jnp.float32)[None, :, None] * freqs  # [1, S, hd / 2]
-            rope = jnp.cos(angles), jnp.sin(angles)  # the same positions in every pass
+            rope = decoder.rope_table(S, cfg.head_dim, cfg.rope_theta)  # the same positions in every pass
         # a layer keeps its input alone and runs again in the backward pass:
         # L x T inputs a step (2 GiB at eight layers, four passes and 16,384
-        # positions) beside the state.  jax's guard against XLA merging the
-        # rematerialised forward with the first one stays on where a scan of
-        # ONE layer is no loop once XLA has simplified it
-        # (``models/ssm_hybrid_moe.py``)
-        layer = jax.checkpoint(
-            lambda carry, w: (self._block(carry, w, rope, kernels), None),
-            policy=jax.checkpoint_policies.nothing_saveable,
-            prevent_cse=cfg.n_layers == 1,
-        )
+        # positions) beside the state.  The pass is the outer loop and the
+        # stack the inner one, so the two scans are written here
+        layer = decoder.remat(lambda carry, w: (self._block(carry, w, rope, kernels), None), cfg.n_layers)
 
         def one_pass(x, own_layers):
             h, _ = jax.lax.scan(layer, x, params["layers"] if own_layers is None else own_layers)
             with part("stream"):
-                x = Llama._rms_norm(h, params["final_norm"], cfg.norm_eps)
+                x = decoder.rms_norm(h, params["final_norm"], cfg.norm_eps)
             return x, x
 
         with part("layers"):
             _, x_all = jax.lax.scan(one_pass, x, layers_by_pass, length=cfg.n_passes)
-        path = KERNEL_PATH if kernels else f"plain: {refusal}"
-        if path != self.attention_path:
-            logger.info("attention path: %s", path)
-        self.attention_path = path
+        decoder.kernel_path(self, KERNEL_PATH, refusal)
         return x_all
 
     def _logits(self, params: Dict[str, Any], x: jax.Array) -> jax.Array:
@@ -287,7 +263,7 @@ class Looped:
 
         @functools.partial(jax.checkpoint, prevent_cse=False)
         def of_block(rows):
-            return token_nll(self._logits(params, rows[0]), rows[1])
+            return decoder.token_nll(self._logits(params, rows[0]), rows[1])
 
         return jax.lax.map(of_block, (x.reshape(-1, block, D), labels)).reshape(t, B, S)
 

@@ -49,7 +49,7 @@ reads its float32 norm: which 6 of 128 experts a token takes is a step
 function of what the router reads.
 
 **State the optimizer does not own**: every router's selection bias, moved
-after a committed step as ``LingHybrid``'s is (``state_mask``, ``objective``,
+after a committed step by ``parallel/moe.py``'s rule (``state_mask``, ``objective``,
 ``advance_state``; ``HSDPTrainer``).  ``loss`` is the next-token
 cross-entropy; ``objective`` adds the routers' sequence-wise balance loss.
 """
@@ -57,24 +57,19 @@ cross-entropy; ``objective`` adds the routers' sequence-wise balance loss.
 from __future__ import annotations
 
 import functools
-import itertools
-import logging
 from dataclasses import dataclass, replace
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
 
-from torchft_tpu.models.ling_hybrid import LingHybrid, _short_conv_silu
-from torchft_tpu.models.llama import Llama, _proj
+from torchft_tpu.models import decoder
 from torchft_tpu.obs.spans import part
 from torchft_tpu.ops import flash_attention as flash
 from torchft_tpu.ops import ssd
+from torchft_tpu.parallel import moe
 from torchft_tpu.parallel.moe import RoutedExperts, RoutedExpertsConfig
-
-logger = logging.getLogger(__name__)
 
 KERNEL_PATH = "ssd+flash"
 KINDS = {"M": "ssm", "*": "attention", "E": "experts"}
@@ -117,7 +112,7 @@ class SsmHybridMoEConfig:
 
     def groups(self) -> List[Tuple[str, int]]:
         """Runs of contiguous layers of one kind: (kind, how many)."""
-        return [(kind, len(list(run))) for kind, run in itertools.groupby(self.kinds())]
+        return decoder.runs(self.kinds())
 
     @property
     def n_layers(self) -> int:
@@ -177,8 +172,7 @@ class SsmHybridMoE:
         # (``rescale_prenorm_residual``, GPT-2's 1 / sqrt(2 layers))
         out_scale = 2 * cfg.n_layers
 
-        def normal(k, shape, fan_in):
-            return (jax.random.normal(k, shape, jnp.float32) / np.sqrt(fan_in)).astype(cfg.dtype)
+        normal = functools.partial(decoder.seeded, dtype=cfg.dtype)
 
         norm = jnp.ones((D,), jnp.float32)
         if kind == "experts":
@@ -217,48 +211,35 @@ class SsmHybridMoE:
     def init(self, key: jax.Array) -> Dict[str, Any]:
         cfg = self.config
         k_embed, k_out, k_layers = jax.random.split(key, 3)
-
-        def normal(k, shape, std):
-            return (std * jax.random.normal(k, shape, jnp.float32)).astype(cfg.dtype)
-
+        embed, lm_head = decoder.embed_and_head(k_embed, k_out, cfg.vocab_size, cfg.dim, cfg.dtype)
         return {
-            # rows of unit variance, so that a token's own embedding leads
-            # the stream its first routers read (PERF.md section 6, PR 33)
-            "embed": normal(k_embed, (cfg.vocab_size, cfg.dim), 1.0),
-            "groups": [
-                jax.vmap(functools.partial(self._init_layer, kind))(
-                    jax.random.split(jax.random.fold_in(k_layers, n), depth)
-                )
-                for n, (kind, depth) in enumerate(self.groups)
-            ],
+            "embed": embed,
+            "groups": decoder.init_runs(self._init_layer, k_layers, self.groups),
             "final_norm": jnp.ones((cfg.dim,), jnp.float32),
-            "lm_head": normal(k_out, (cfg.dim, cfg.vocab_size), cfg.dim ** -0.5),
+            "lm_head": lm_head,
         }
 
     @functools.cached_property
     def _shapes(self) -> Any:
-        """What ``init`` would make, as shapes (traced once a model)."""
-        return jax.eval_shape(self.init, jax.random.PRNGKey(0))
+        return decoder.shapes(self.init)
 
     def param_specs(self) -> Dict[str, Any]:
-        """One chip's share of a larger job: every leaf whole on the group's
-        one chip (the ``fsdp`` axis of this model's meshes has size 1)."""
-        return jax.tree_util.tree_map(lambda s: P(*([None] * len(s.shape))), self._shapes)
+        return decoder.one_chip_param_specs(self._shapes)
 
     def batch_specs(self) -> Tuple[Any, Any]:
-        spec = P(("dp", "fsdp"), None)
-        return spec, spec
+        return decoder.batch_specs()
 
     def num_params(self) -> int:
-        return sum(int(np.prod(s.shape)) for s in jax.tree_util.tree_leaves(self._shapes))
+        return decoder.num_params(self._shapes)
 
-    # the routers' selection biases: state the optimizer does not own, as
-    # ``LingHybrid``'s (a leaf called "bias"; a signal a leaf, its last axis
-    # the router's width)
-    state_mask = LingHybrid.state_mask
-    advance_state = LingHybrid.advance_state
-    route_summary = LingHybrid.route_summary
-    summary_stats = staticmethod(LingHybrid.summary_stats)
+    # the routers' selection biases: state the optimizer does not own (``parallel/moe.py``)
+    def state_mask(self) -> Any:
+        return moe.state_mask(self.param_specs())
+
+    def advance_state(self, state: List[jax.Array], signal: List[jax.Array]) -> List[jax.Array]:
+        return moe.advance_state(self.config.bias_update_rate, state, signal)
+
+    summary_stats = staticmethod(moe.summary_stats)
 
     # ------------------------------------------------------------------
     # forward
@@ -266,54 +247,49 @@ class SsmHybridMoE:
 
     def _kernel_refusal(self, seq: int) -> Optional[str]:
         """Why the Mosaic kernels do NOT apply, or None when they do."""
-        block_q, block_k = Llama._flash_blocks(seq)
-        chunk = min(self.config.chunk, seq)
-        shape_refusal = None
-        if seq < 32 or seq % 8 or seq % block_q or seq % block_k or seq % chunk:
-            shape_refusal = f"seq={seq} does not divide into the blocks ({block_q}, {block_k}) and chunks of {chunk}"
-        return Llama._one_chip_refusal(shape_refusal, self.mesh)
+        return decoder.kernel_refusal(seq, self.mesh, chunk=self.config.chunk)
 
     @part("mixer_glue")
     def _ssm(self, h: jax.Array, w: Dict[str, jax.Array], kernels: bool) -> jax.Array:
         cfg = self.config
         B, S, _ = h.shape
         H, inner, GN = cfg.ssm_heads, cfg.ssm_inner, cfg.ssm_groups * cfg.ssm_state
-        zxr = _proj(h, w["w_in"])
+        zxr = decoder.proj(h, w["w_in"])
         z, xbc, r = jnp.split(zxr, [inner, inner + cfg.ssm_conv_width], axis=-1)
-        xbc = _short_conv_silu(xbc, w["conv"], w["conv_bias"])
+        xbc = decoder.short_conv_silu(xbc, w["conv"], w["conv_bias"])
         x, Bm, Cm = jnp.split(xbc, [inner, inner + GN], axis=-1)
         groups = lambda a: a.reshape(B, S, cfg.ssm_groups, cfg.ssm_state)  # noqa: E731
         dt = jax.nn.softplus(r.astype(jnp.float32) + w["dt_bias"])
         operands = (x.reshape(B, S, H, cfg.ssm_head_dim), dt, w["A_log"], groups(Bm), groups(Cm), w["D"])
         if kernels:
-            y = ssd.ssd_chunked(*operands, chunk=cfg.chunk, interpret=Llama._assumed_backend() != "tpu")
+            y = ssd.ssd_chunked(*operands, chunk=cfg.chunk, interpret=decoder.assumed_backend() != "tpu")
         else:
             y = ssd.ssd_chunked_plain(*operands, chunk=cfg.chunk)
         # gate, then the norm over each group's channels
         y = y.reshape(B, S, inner).astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
-        y = Llama._rms_norm(y.reshape(B, S, cfg.ssm_groups, -1), 1.0, cfg.norm_eps).reshape(B, S, inner)
-        return _proj((y * w["o_norm"]).astype(h.dtype), w["w_out"])
+        y = decoder.rms_norm(y.reshape(B, S, cfg.ssm_groups, -1), 1.0, cfg.norm_eps).reshape(B, S, inner)
+        return decoder.proj((y * w["o_norm"]).astype(h.dtype), w["w_out"])
 
     @part("mixer_glue")
     def _attention(self, h: jax.Array, w: Dict[str, jax.Array], kernels: bool) -> jax.Array:
         cfg = self.config
         B, S, _ = h.shape
         H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-        q = _proj(h, w["wq"]).reshape(B, S, H, hd)
-        k = _proj(h, w["wk"]).reshape(B, S, KV, hd)
-        v = _proj(h, w["wv"]).reshape(B, S, KV, hd)
+        q = decoder.proj(h, w["wq"]).reshape(B, S, H, hd)
+        k = decoder.proj(h, w["wk"]).reshape(B, S, KV, hd)
+        v = decoder.proj(h, w["wv"]).reshape(B, S, KV, hd)
         if kernels:
-            block_q, block_k = Llama._flash_blocks(S)
+            block_q, block_k = decoder.flash_blocks(S)
             o = flash.flash_attention(
                 q, k, v, causal=True, block_q=block_q, block_k=block_k,
-                interpret=Llama._assumed_backend() != "tpu",
+                interpret=decoder.assumed_backend() != "tpu",
             )
         else:
             grouped = q.reshape(B, S, KV, H // KV, hd)
             scores = jnp.einsum("bqgrd,bkgd->bgrqk", grouped, k).astype(jnp.float32) / np.sqrt(hd)
             scores = jnp.where(jnp.tril(jnp.ones((S, S), bool)), scores, -1e30)
             o = jnp.einsum("bgrqk,bkgd->bqgrd", jax.nn.softmax(scores, axis=-1).astype(q.dtype), v)
-        return _proj(o.reshape(B, S, H * hd), w["wo"])
+        return decoder.proj(o.reshape(B, S, H * hd), w["wo"])
 
     def _block(
         self, x: jax.Array, w: Dict[str, Any], kind: str, kernels: bool
@@ -322,7 +298,7 @@ class SsmHybridMoE:
         layer), balance loss)``."""
         cfg = self.config
         with part("stream"):
-            h = Llama._rms_norm(x, w["norm"], cfg.norm_eps)
+            h = decoder.rms_norm(x, w["norm"], cfg.norm_eps)
         if kind == "experts":
             # the router reads the float32 norm itself
             out, load, balance = self.moe.apply(w["ffn"], h)
@@ -339,7 +315,6 @@ class SsmHybridMoE:
         """tokens [B, S] → (the residual stream after the last layer, the
         loads [depth, E] of every stacked run of expert layers in the
         layers' order, the summed balance loss)."""
-        cfg = self.config
         refusal = self._kernel_refusal(tokens.shape[1])
         kernels = refusal is None
         with part("embed"):
@@ -350,37 +325,31 @@ class SsmHybridMoE:
         # the published widths the scan's 402 MB a layer, four layers, beside
         # a float32 stream ask 16.2 GB of a chip that gives out 16.9 and the
         # step dies allocating (PERF.md section 6, PR 35), so ``ssd_fwd``
-        # (1.6 ms a layer in the cell's trace) runs again in the backward pass
-        policy = jax.checkpoint_policies.save_only_these_names(*flash.KEPT_NAMES)
-        for (kind, _depth), stacked in zip(self.groups, params["groups"]):
+        # (1.6 ms a layer in the cell's trace) runs again in the backward
+        # pass.  ONE policy object for every run, as this model's text was
+        # made (``decoder.remat``)
+        keep = jax.checkpoint_policies.save_only_these_names(*flash.KEPT_NAMES)
+        for (kind, depth), stacked in zip(self.groups, params["groups"]):
 
             def body(carry, w, kind=kind):
                 y, load, bal = self._block(carry, w, kind, kernels)
                 return y, (load, bal)
 
-            # jax's guard against XLA merging the rematerialised forward with
-            # the first one stays on (``prevent_cse``): a scan of ONE layer
-            # is no loop once XLA has simplified it, and merged they keep
-            # every intermediate alive, 3.2 GB a state-space layer
-            with part("layers"):
-                x, (load, bal) = jax.lax.scan(jax.checkpoint(body, policy=policy), x, stacked)
+            # every run of the published pattern is ONE layer, where jax's
+            # guard against XLA merging the two forwards stays on: it was
+            # measured here (``decoder.remat``)
+            x, (load, bal) = decoder.scan_run(body, x, stacked, depth, keep=keep)
             if kind == "experts":
                 loads.append(load)
                 with part("experts_route"):
                     balance = balance + jnp.sum(bal)
-        if kernels and self.moe.path not in (None, "gmm") and Llama._assumed_backend() == "tpu":
-            refusal, kernels = f"the experts took {self.moe.path}", False
-        path = KERNEL_PATH if kernels else f"plain: {refusal}"
-        if path != self.attention_path:
-            logger.info("attention path: %s", path)
-        self.attention_path = path
+        decoder.kernel_path(self, KERNEL_PATH, refusal, self.moe.path)
         return x, loads, balance
 
     @part("head")
     def _logits(self, params: Dict[str, Any], x: jax.Array) -> jax.Array:
-        x = Llama._rms_norm(x, params["final_norm"], self.config.norm_eps).astype(self.config.dtype)
-        # the products' float32 sums as they are: a logit is never rounded to the model's dtype
-        return jnp.dot(x, params["lm_head"], preferred_element_type=jnp.float32)
+        cfg = self.config
+        return decoder.head_logits(x, params["final_norm"], params["lm_head"], cfg.norm_eps, cfg.dtype)
 
     def apply(self, params: Dict[str, Any], tokens: jax.Array) -> jax.Array:
         """tokens [B, S] → logits [B, S, vocab] (fp32)."""
@@ -391,7 +360,7 @@ class SsmHybridMoE:
     ) -> Tuple[jax.Array, jax.Array, List[jax.Array]]:
         tokens, targets = batch
         x, loads, balance = self._trunk(params, tokens)
-        return LingHybrid._mean_nll(self._logits(params, x), targets), balance, loads
+        return decoder.mean_nll(self._logits(params, x), targets), balance, loads
 
     def loss(self, params: Dict[str, Any], batch: Tuple[jax.Array, jax.Array]) -> jax.Array:
         """Mean next-token cross-entropy; batch = (tokens, targets)."""
@@ -403,7 +372,7 @@ class SsmHybridMoE:
         """What a training step differentiates (``loss`` and the routers'
         balance loss), for every leaf of ``state_mask`` the step's signal
         (the tokens each expert was chosen by) and the step's summary
-        (``route_summary`` of this replica's own signal)."""
+        (``RoutedExperts.route_summary`` of this replica's own signal)."""
         loss, balance, signal = self._losses(params, batch)
         with part("head"):
-            return loss + balance, (signal, self.route_summary(signal, batch[0].size))
+            return loss + balance, (signal, self.moe.route_summary(signal, batch[0].size))

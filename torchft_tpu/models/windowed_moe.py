@@ -48,7 +48,7 @@ reads its float32 norm: which 8 of 128 experts a token takes is a step
 function of what the router reads.
 
 **State the optimizer does not own**: every router's selection bias, moved
-after a committed step as ``LingHybrid``'s is (``state_mask``, ``objective``,
+after a committed step by ``parallel/moe.py``'s rule (``state_mask``, ``objective``,
 ``advance_state``; ``HSDPTrainer``).  There is no auxiliary loss: ``objective``
 IS ``loss``, with the step's signal and summary beside it.
 """
@@ -56,23 +56,18 @@ IS ``loss``, with the step's signal and summary beside it.
 from __future__ import annotations
 
 import functools
-import itertools
-import logging
 from dataclasses import dataclass, replace
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
 
-from torchft_tpu.models.ling_hybrid import ROUTE_FIELDS, LingHybrid
-from torchft_tpu.models.llama import Llama, _proj
+from torchft_tpu.models import decoder
 from torchft_tpu.obs.spans import part
 from torchft_tpu.ops import flash_attention as flash
+from torchft_tpu.parallel import moe
 from torchft_tpu.parallel.moe import RoutedExperts, RoutedExpertsConfig, swiglu
-
-logger = logging.getLogger(__name__)
 
 KERNEL_PATH = "flash_win+flash"
 # what the two norms ON a branch (after the mixer, after the feed-forward
@@ -124,7 +119,7 @@ class WindowedMoEConfig:
 
     def groups(self) -> List[Tuple[Tuple[str, str], int]]:
         """Runs of contiguous layers of one kind: (kind, how many)."""
-        return [(kind, len(list(run))) for kind, run in itertools.groupby(self.kinds())]
+        return decoder.runs(self.kinds())
 
     @property
     def n_layers(self) -> int:
@@ -143,15 +138,6 @@ def windowed_moe_debug(**over: Any) -> WindowedMoEConfig:
         ),
         **over,
     )
-
-
-def _rope_halves(x: jax.Array, theta: float) -> jax.Array:
-    """Rotary embedding over ALL of the last axis, channel ``i`` paired with
-    ``i + R / 2``; x [B, S, H, R], position = index, float32 arithmetic."""
-    S, half = x.shape[1], x.shape[-1] // 2
-    freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
-    angles = jnp.arange(S, dtype=jnp.float32)[None, :, None] * freqs  # [1, S, R / 2]
-    return Llama._apply_rope(x, jnp.cos(angles), jnp.sin(angles))
 
 
 class WindowedMoE:
@@ -183,15 +169,10 @@ class WindowedMoE:
         q, kv = cfg.n_heads * hd, cfg.n_kv_heads * hd
         keys = jax.random.split(key, 9)
 
-        def normal(k, shape, fan_in):
-            return (jax.random.normal(k, shape, jnp.float32) / np.sqrt(fan_in)).astype(cfg.dtype)
+        normal = functools.partial(decoder.seeded, dtype=cfg.dtype)
 
         if kind[1] == "dense":
-            F = cfg.dense_hidden
-            ffn = {
-                "w_gate": normal(keys[5], (D, F), D), "w_up": normal(keys[6], (D, F), D),
-                "w_down": normal(keys[7], (F, D), F),
-            }
+            ffn = decoder.dense_ffn_init(keys[5:8], D, cfg.dense_hidden, cfg.dtype)
         else:
             ffn = self.moe.init(keys[5])
         ones = lambda n: jnp.ones((n,), jnp.float32)  # noqa: E731
@@ -210,48 +191,39 @@ class WindowedMoE:
     def init(self, key: jax.Array) -> Dict[str, Any]:
         cfg = self.config
         k_embed, k_out, k_layers = jax.random.split(key, 3)
-
-        def normal(k, shape, std):
-            return (std * jax.random.normal(k, shape, jnp.float32)).astype(cfg.dtype)
-
+        # rows of variance 1 / dim, so that the stream, the rows times
+        # sqrt(dim), starts at unit variance
+        embed, lm_head = decoder.embed_and_head(
+            k_embed, k_out, cfg.vocab_size, cfg.dim, cfg.dtype, embed_std=cfg.dim ** -0.5 if cfg.embed_scale else 1.0
+        )
         return {
-            # rows of variance 1 / dim, so that the stream, the rows times
-            # sqrt(dim), starts at unit variance
-            "embed": normal(k_embed, (cfg.vocab_size, cfg.dim), cfg.dim ** -0.5 if cfg.embed_scale else 1.0),
-            "groups": [
-                jax.vmap(functools.partial(self._init_layer, kind))(
-                    jax.random.split(jax.random.fold_in(k_layers, n), depth)
-                )
-                for n, (kind, depth) in enumerate(self.groups)
-            ],
+            "embed": embed,
+            "groups": decoder.init_runs(self._init_layer, k_layers, self.groups),
             "final_norm": jnp.ones((cfg.dim,), jnp.float32),
-            "lm_head": normal(k_out, (cfg.dim, cfg.vocab_size), cfg.dim ** -0.5),
+            "lm_head": lm_head,
         }
 
     @functools.cached_property
     def _shapes(self) -> Any:
-        """What ``init`` would make, as shapes (traced once a model)."""
-        return jax.eval_shape(self.init, jax.random.PRNGKey(0))
+        return decoder.shapes(self.init)
 
     def param_specs(self) -> Dict[str, Any]:
-        """One chip's share of a larger job: every leaf whole on the group's
-        one chip (the ``fsdp`` axis of this model's meshes has size 1)."""
-        return jax.tree_util.tree_map(lambda s: P(*([None] * len(s.shape))), self._shapes)
+        return decoder.one_chip_param_specs(self._shapes)
 
     def batch_specs(self) -> Tuple[Any, Any]:
-        spec = P(("dp", "fsdp"), None)
-        return spec, spec
+        return decoder.batch_specs()
 
     def num_params(self) -> int:
-        return sum(int(np.prod(s.shape)) for s in jax.tree_util.tree_leaves(self._shapes))
+        return decoder.num_params(self._shapes)
 
-    # the routers' selection biases: state the optimizer does not own, as
-    # ``LingHybrid``'s (a leaf called "bias"; a signal a leaf, its last axis
-    # the router's width)
-    state_mask = LingHybrid.state_mask
-    advance_state = LingHybrid.advance_state
-    route_summary = LingHybrid.route_summary
-    summary_stats = staticmethod(LingHybrid.summary_stats)
+    # the routers' selection biases: state the optimizer does not own (``parallel/moe.py``)
+    def state_mask(self) -> Any:
+        return moe.state_mask(self.param_specs())
+
+    def advance_state(self, state: List[jax.Array], signal: List[jax.Array]) -> List[jax.Array]:
+        return moe.advance_state(self.config.bias_update_rate, state, signal)
+
+    summary_stats = staticmethod(moe.summary_stats)
 
     # ------------------------------------------------------------------
     # forward
@@ -259,30 +231,26 @@ class WindowedMoE:
 
     def _kernel_refusal(self, seq: int) -> Optional[str]:
         """Why the Mosaic kernels do NOT apply, or None when they do."""
-        block_q, block_k = Llama._flash_blocks(seq)
-        shape_refusal = None
-        if seq < 32 or seq % 8 or seq % block_q or seq % block_k:
-            shape_refusal = f"seq={seq} does not divide into the blocks ({block_q}, {block_k})"
-        return Llama._one_chip_refusal(shape_refusal, self.mesh)
+        return decoder.kernel_refusal(seq, self.mesh)
 
     @part("mixer_glue")
     def _attention(self, h: jax.Array, w: Dict[str, jax.Array], windowed: bool, kernels: bool) -> jax.Array:
         cfg = self.config
         B, S, _ = h.shape
         H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-        q = Llama._rms_norm(_proj(h, w["wq"]).reshape(B, S, H, hd), w["q_norm"], cfg.norm_eps)
-        k = Llama._rms_norm(_proj(h, w["wk"]).reshape(B, S, KV, hd), w["k_norm"], cfg.norm_eps)
-        v = _proj(h, w["wv"]).reshape(B, S, KV, hd)
-        gate = _proj(h, w["wg"])
+        q = decoder.rms_norm(decoder.proj(h, w["wq"]).reshape(B, S, H, hd), w["q_norm"], cfg.norm_eps)
+        k = decoder.rms_norm(decoder.proj(h, w["wk"]).reshape(B, S, KV, hd), w["k_norm"], cfg.norm_eps)
+        v = decoder.proj(h, w["wv"]).reshape(B, S, KV, hd)
+        gate = decoder.proj(h, w["wg"])
         window = None
         if windowed:  # position is the windowed layers' alone: a full layer has none
-            q, k = _rope_halves(q, cfg.rope_theta), _rope_halves(k, cfg.rope_theta)
+            q, k = decoder.rope_halves(q, cfg.rope_theta), decoder.rope_halves(k, cfg.rope_theta)
             window = cfg.sliding_window
         if kernels:
-            block_q, block_k = Llama._flash_blocks(S)
+            block_q, block_k = decoder.flash_blocks(S)
             o = flash.flash_attention(
                 q, k, v, causal=True, block_q=block_q, block_k=block_k, window=window,
-                interpret=Llama._assumed_backend() != "tpu",
+                interpret=decoder.assumed_backend() != "tpu",
             )
         else:
             grouped = q.reshape(B, S, KV, H // KV, hd)
@@ -292,14 +260,14 @@ class WindowedMoE:
             scores = jnp.where(seen, scores, -1e30)
             o = jnp.einsum("bgrqk,bkgd->bqgrd", jax.nn.softmax(scores, axis=-1).astype(q.dtype), v)
         o = o.reshape(B, S, H * hd).astype(jnp.float32) * jax.nn.sigmoid(gate.astype(jnp.float32))
-        return _proj(o.astype(h.dtype), w["wo"])
+        return decoder.proj(o.astype(h.dtype), w["wo"])
 
     def _block(
         self, x: jax.Array, w: Dict[str, Any], kind: Tuple[str, str], kernels: bool
     ) -> Tuple[jax.Array, jax.Array]:
         """One layer: ``(x, load [E] (zeros for a dense layer))``."""
         cfg = self.config
-        norm = lambda a, name: Llama._rms_norm(a.astype(jnp.float32), w["norms"][name], cfg.norm_eps)  # noqa: E731
+        norm = lambda a, name: decoder.rms_norm(a.astype(jnp.float32), w["norms"][name], cfg.norm_eps)  # noqa: E731
         with part("stream"):
             h = norm(x, "mixer_in").astype(cfg.dtype)
         mixed = self._attention(h, w, kind[0] == "sliding_attention", kernels)
@@ -332,10 +300,6 @@ class WindowedMoE:
                 x = x * np.float32(np.sqrt(cfg.dim))
         loads = []
         for (kind, depth), stacked in zip(self.groups, params["groups"]):
-
-            def body(carry, w, kind=kind):
-                return self._block(carry, w, kind, kernels)
-
             # kept through a layer's rematerialisation: its float32 input and,
             # on a FULL layer, flash's output and row statistics (151 MB at
             # 16,384 positions), so that the dear ``flash_fwd`` stands once in a
@@ -344,29 +308,19 @@ class WindowedMoE:
             # eight layers the gradient step needs 3.4 GB more than kept on
             # none, by the compiler's count, and does not fit beside 8.9 GB
             # of weights, gradients and moments (PERF.md section 6, PR 41)
-            keep = flash.KEPT_NAMES if kind[0] == "full_attention" else ()
-            policy = jax.checkpoint_policies.save_only_these_names(*keep)
-            # jax's guard against XLA merging the rematerialised forward with
-            # the first one stays on where a scan of ONE layer is no loop once
-            # XLA has simplified it (``models/ssm_hybrid_moe.py``); inside a
-            # real loop it is not needed and costs memory
-            with part("layers"):
-                x, load = jax.lax.scan(jax.checkpoint(body, policy=policy, prevent_cse=depth == 1), x, stacked)
+            x, load = decoder.scan_run(
+                lambda carry, w, kind=kind: self._block(carry, w, kind, kernels), x, stacked, depth,
+                keep=flash.KEPT_NAMES if kind[0] == "full_attention" else (),
+            )
             if kind[1] == "moe":
                 loads.append(load)
-        if kernels and self.moe.path not in (None, "gmm") and Llama._assumed_backend() == "tpu":
-            refusal, kernels = f"the experts took {self.moe.path}", False
-        path = KERNEL_PATH if kernels else f"plain: {refusal}"
-        if path != self.attention_path:
-            logger.info("attention path: %s", path)
-        self.attention_path = path
+        decoder.kernel_path(self, KERNEL_PATH, refusal, self.moe.path)
         return x, loads
 
     @part("head")
     def _logits(self, params: Dict[str, Any], x: jax.Array) -> jax.Array:
-        x = Llama._rms_norm(x, params["final_norm"], self.config.norm_eps).astype(self.config.dtype)
-        # the products' float32 sums as they are: a logit is never rounded to the model's dtype
-        return jnp.dot(x, params["lm_head"], preferred_element_type=jnp.float32)
+        cfg = self.config
+        return decoder.head_logits(x, params["final_norm"], params["lm_head"], cfg.norm_eps, cfg.dtype)
 
     def apply(self, params: Dict[str, Any], tokens: jax.Array) -> jax.Array:
         """tokens [B, S] → logits [B, S, vocab] (fp32)."""
@@ -382,12 +336,12 @@ class WindowedMoE:
         """What a training step differentiates (``loss``: the published
         configuration balances by the bias alone), for every leaf of
         ``state_mask`` the step's signal (the tokens each expert was chosen
-        by) and the step's summary (``route_summary`` of this replica's own
+        by) and the step's summary (``RoutedExperts.route_summary`` of this replica's own
         signal)."""
         tokens, targets = batch
         x, signal = self._trunk(params, tokens)
-        loss = LingHybrid._mean_nll(self._logits(params, x), targets)
+        loss = decoder.mean_nll(self._logits(params, x), targets)
         with part("head"):
             # a model of dense layers alone has no router to sum up
-            summary = self.route_summary(signal, tokens.size) if signal else jnp.zeros((0, len(ROUTE_FIELDS)), jnp.float32)
+            summary = self.moe.route_summary(signal, tokens.size) if signal else jnp.zeros((0, len(moe.ROUTE_FIELDS)), jnp.float32)
             return loss, (signal, summary)

@@ -29,6 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
+from torchft_tpu.models.decoder import assumed_backend
 # ``part`` is the name of ``_held_part``'s own function below
 from torchft_tpu.obs.spans import part as device_part
 
@@ -349,6 +350,43 @@ def buffer_passes(size: int, rows: jax.Array) -> jax.Array:
     return jnp.maximum(1, (jnp.asarray(rows).astype(jnp.int32) + (size - 1)) // size)
 
 
+# a step's summary, one row an expert layer (``RoutedExperts.route_summary``, ``summary_stats``)
+ROUTE_FIELDS = ("rows_here", "load_max", "load_mean", "buffer_rows")
+
+
+def summary_stats(summary: np.ndarray, fields: Tuple[str, ...] = ROUTE_FIELDS) -> Dict[str, List[float]]:
+    """A step's summary of one row a layer (``RoutedExperts.route_summary``,
+    or a model's own rows of ``fields``) on the host, a list a field: the
+    flight event's detail."""
+    columns = np.asarray(summary, np.float64).reshape(-1, len(fields)).T
+    return {name: column.tolist() for name, column in zip(fields, columns)}
+
+
+def state_mask(param_specs: Any) -> Any:
+    """True for the leaves of a model's tree (``param_specs``: its
+    PartitionSpecs) that the optimizer does not own: the routers' selection
+    biases, a leaf called "bias" (``HSDPTrainer`` asks a model with such
+    state for this, for ``objective``'s signal a leaf and for
+    ``advance_state``)."""
+    return jax.tree_util.tree_map_with_path(
+        lambda path, _: getattr(path[-1], "key", None) == "bias",
+        param_specs,
+        is_leaf=lambda x: isinstance(x, P),
+    )
+
+
+def advance_state(rate: float, state: List[jax.Array], signal: List[jax.Array]) -> List[jax.Array]:
+    """``bias += rate * sign(mean(load) - load)``, a router at a time (the
+    last axis is the router's width): after a committed step a selection
+    bias goes up for an expert that saw fewer tokens than the mean and down
+    for one that saw more (DeepSeek-V3's balancing without an auxiliary
+    loss)."""
+    return [
+        bias + rate * jnp.sign(jnp.mean(load, axis=-1, keepdims=True) - load)
+        for bias, load in zip(state, signal)
+    ]
+
+
 class RoutedExperts:
     """An expert layer that is TOLD which experts it holds.
 
@@ -468,9 +506,7 @@ class RoutedExperts:
         """Rows ``lhs`` [m, k], sorted by expert, each through its expert's
         matrix of ``rhs`` [held, k, n]; rows past ``sum(sizes)`` are
         undefined."""
-        from torchft_tpu.models.llama import Llama
-
-        if Llama._assumed_backend() == "tpu":
+        if assumed_backend() == "tpu":
             self.path = "gmm"
             return grouped_product(lhs, rhs, sizes)
         self.path = "ragged_dot"
@@ -577,6 +613,18 @@ class RoutedExperts:
         cfg = self.config
         size = buffer_size(tokens, cfg.top_k, cfg.experts_held[1], cfg.num_experts)
         return (size * buffer_passes(size, rows)).astype(jnp.float32)
+
+    def route_summary(self, loads: List[jax.Array], tokens: int) -> jax.Array:
+        """Of this replica's step of ``tokens`` tokens, on the device:
+        ``[expert layers, 4]`` in the order of ``ROUTE_FIELDS``: the rows
+        routed to the held experts, their largest and mean load and the
+        rows of the experts' buffer they went through (``buffer_rows``),
+        expert layer by expert layer (``loads``: what ``apply`` counted, a
+        stacked leaf one row a layer)."""
+        first, held = self.config.experts_held
+        here = jnp.concatenate([x.reshape(-1, x.shape[-1]) for x in loads])[:, first : first + held]
+        rows = here.sum(axis=1)
+        return jnp.stack([rows, here.max(axis=1), here.mean(axis=1), self.buffer_rows(tokens, rows)], axis=1)
 
     def apply(
         self, params: Dict[str, Any], x: jax.Array,
