@@ -4,14 +4,16 @@ CPU CI runs the Pallas interpreter; the compiled path is checked on the chip
 by ``chip_smoke.py`` leg B (Mosaic custom call in the HLO, same reference).
 """
 
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.ad_checkpoint import print_saved_residuals
 
 from torchft_tpu.models.llama import Llama, LlamaConfig
-from torchft_tpu.ops.flash_attention import flash_attention
+from torchft_tpu.ops.flash_attention import KEPT_NAMES, eva_attention, flash_attention
 
 
 def _ref_attention(q, k, v, causal=True):
@@ -394,3 +396,62 @@ def test_window_validation(window, causal) -> None:
     q, k, v = _qkv(1, 256, 4, 2, 64)
     with pytest.raises(ValueError, match="window"):
         flash_attention(q, k, v, causal=causal, window=window, interpret=True)
+
+
+# ---------------------------------------------------------------------------
+# what the forward rule keeps for the backward pass
+# ---------------------------------------------------------------------------
+
+
+def _kept_case(kind):
+    """(a function of its operands, the operands, o's heads-major shape) of a
+    full layer, a window and two key sources, several blocks each."""
+    B, S, H, KV, D, Dv = 1, 128, 4, 2, 16, 8
+    kq, kk, kv, kp = jax.random.split(jax.random.PRNGKey(5), 4)
+    q = jax.random.normal(kq, (B, S, H, D), jnp.float32)
+    k = jax.random.normal(kk, (B, S, KV, D), jnp.float32)
+    blocks = dict(block_q=32, block_k=32, interpret=True)
+    if kind == "eva":
+        v = jax.random.normal(kv, (B, S, KV, D), jnp.float32)
+        pooled = jax.random.normal(kp, (2, B, S // 8, KV, D), jnp.float32)
+        return functools.partial(eva_attention, window=64, **blocks), (q, k, v, *pooled), (B, H, S, D)
+    v = jax.random.normal(kv, (B, S, KV, Dv), jnp.float32)
+    window = dict(full=None, window=40)[kind]
+    return functools.partial(flash_attention, window=window, **blocks), (q, k, v), (B, H, S, Dv)
+
+
+@pytest.mark.parametrize("kind", ["full", "window", "eva"])
+def test_what_the_forward_rule_keeps_is_o_and_one_number_a_row(kind, capsys) -> None:
+    """Under ``save_only_these_names(*KEPT_NAMES)`` a rematerialised caller
+    holds ``o`` [B, H, S, Dv] and the row statistics as ONE float32 a row,
+    [B, H, S]: the kernels' trailing 8 lanes, padded to 128 in HBM, are
+    spread again in the backward rule and never kept."""
+    fn, operands, o_shape = _kept_case(kind)
+    print_saved_residuals(
+        jax.checkpoint(fn, policy=jax.checkpoint_policies.save_only_these_names(*KEPT_NAMES)), *operands
+    )
+    # a line a residual, ``f32[1,4,128] named 'flash_lse' from <where>``; the operands are always kept
+    kept = [line.split()[0] for line in capsys.readouterr().out.splitlines() if " from the argument " not in line]
+    shape = lambda dims: "f32[" + ",".join(map(str, dims)) + "]"  # noqa: E731
+    assert sorted(kept) == sorted([shape(o_shape), shape(o_shape[:3])])
+
+
+@pytest.mark.parametrize("kind", ["full", "window", "eva"])
+def test_gradients_through_what_is_kept_are_those_of_a_second_forward_bit_for_bit(kind) -> None:
+    """The same ``lse`` values reach the same backward kernels whether the
+    caller kept them (one number a row) or ran ``flash_fwd`` again.  Operation
+    by operation and not one program: XLA fuses ``delta``'s row sums
+    differently around a kept ``o`` and a recomputed one, which reads one
+    float32 rounding in ``dq`` and ``dk`` on the CPU and is no property of
+    the rule."""
+    fn, operands, _ = _kept_case(kind)
+    policies = jax.checkpoint_policies
+
+    def gradients(policy):
+        layer = jax.checkpoint(fn, policy=policy)
+        return jax.grad(lambda *a: jnp.sum(jnp.sin(layer(*a))), argnums=tuple(range(len(operands))))(*operands)
+
+    kept, again = gradients(policies.save_only_these_names(*KEPT_NAMES)), gradients(policies.nothing_saveable)
+    for name, a, b in zip(("q", "k", "v", "k_pooled", "v_pooled"), kept, again):
+        assert float(jnp.max(jnp.abs(a))) > 0, name
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b), err_msg=f"d{name}")

@@ -27,7 +27,7 @@ from ftbench.architectures import looped_reference as ref
 from torchft_tpu.models.looped import KERNEL_PATH, Looped, LoopedConfig, looped_debug
 
 from tests._once import once_a_run
-from tests._toys import on_path
+from tests._toys import gradients_jaxpr, on_path
 
 SEQ = 64
 CASES = {
@@ -184,6 +184,22 @@ def test_a_stacked_leafs_gradient_is_the_sum_over_the_passes():
         for t in range(T):
             assert float(jnp.max(jnp.abs(parts[t]))) > 1e-3 * scale, (name, t)
             assert float(jnp.max(jnp.abs(parts[t] - whole))) > 1e-2 * scale, (name, t)
+
+
+@pytest.mark.parametrize("kernel", ["flash_fwd", "flash_dq", "flash_dkv"])
+def test_what_a_rematerialised_layer_keeps_and_what_it_runs_again(kernel):
+    """The layers are one scan inside the passes' scan, whose body is traced
+    once, rematerialised but for its input and what flash made
+    (``flash.KEPT_NAMES``): a second ``flash_fwd`` in the body would read 2."""
+    text = _gradients_jaxpr()
+    assert text.count(f"name={kernel}\n") + text.count(f"name={kernel} ") == 1, kernel
+
+
+@functools.lru_cache(maxsize=None)
+def _gradients_jaxpr():
+    """Traced once for the three kernels' counts."""
+    _, model, params, batch = _setup()
+    return gradients_jaxpr(model, params, batch)
 
 
 def test_the_last_passs_gate_draws_no_gradient_and_p_needs_no_sigmoid_of_it():

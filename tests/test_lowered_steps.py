@@ -29,7 +29,15 @@ steps are what they were.  PR 56 ADDED ``gated_delta_moe``'s two (``--write
 twelve: no other model's program moved.  PR 59 ADDED ``looped``'s two (``--write
 --only looped``: ``DEVICE_PARTS`` took a thirteenth part, ``loop_gate``; no
 helper of ``Llama`` and no kernel was touched) and changed none of the
-fourteen.  A later change that means to alter one of these
+fourteen.  PR 62 wrote the ``kernels`` digests of the models whose flash
+residual changed anew (``--write --only
+ling_hybrid,llama,ssm_hybrid_moe,windowed_moe,gated_delta_moe,looped``: the
+forward rule keeps its row statistics as ONE float32 a row, ``[B, H, S]``, and
+spreads them again in the backward rule; ``looped``'s layer keeps what flash
+made); the eight ``plain`` digests and both of ``indexed_sparse_moe`` did NOT
+change, nor ``eva``'s ``kernels`` one: its rule was already this one, and the
+``custom_vjp``'s Python name (``_pooled_hm`` then, ``_flash_hm`` now) is not
+in the lowered text.  A later change that means to alter one of these
 programs writes the fixture anew and says so: ``python
 tests/test_lowered_steps.py --write``."""
 

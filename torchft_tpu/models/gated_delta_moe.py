@@ -42,7 +42,7 @@ attention and ``lax.ragged_dot`` and the path is named ``"plain: <why>"``.
 
 Contiguous layers of one kind are stacked and run under one ``lax.scan``, a
 layer rematerialised in the backward pass but for its float32 input and, on a
-FULL layer, what flash made (``flash.KEPT_NAMES``: 134 + 8 MB a layer at 16,384
+FULL layer, what flash made (``flash.KEPT_NAMES``: 134 + 1 MB a layer at 16,384
 positions and 16 heads of 256), so that ``flash_fwd`` stands once a full layer
 in a step's program; a DeltaNet layer keeps nothing and ``gdn_fwd`` runs twice
 (the states its backward reads are 537 MB a layer).

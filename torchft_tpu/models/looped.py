@@ -48,8 +48,10 @@ over, never four copies: a leaf's gradient is the sum over its ``T`` uses, made
 inside the compiled step by the pass scan's transposition, which adds a
 pass's contribution to the running sum in the LEAF's dtype (bfloat16 at the
 published precision: three roundings a leaf, PERF.md section 6, PR 59).  A
-layer is rematerialised in the backward pass but for its input, ``L x T`` of
-them a step; the head and its cross-entropy run over blocks of ``head_block``
+layer is rematerialised in the backward pass but for its input and what flash
+made (``ops/flash_attention.py`` ``KEPT_NAMES``: ``o`` and one number a row),
+``L x T`` of each a step, so that ``flash_fwd`` stands once a layer
+application; the head and its cross-entropy run over blocks of ``head_block``
 positions of all passes at once, each block's logits made again in the
 backward pass and never kept, so that no ``[S, vocab]`` float32 array of a
 whole pass is ever held.
@@ -229,11 +231,15 @@ class Looped:
             x = params["embed"][tokens].astype(cfg.dtype)
         with part("mixer_glue"):
             rope = decoder.rope_table(S, cfg.head_dim, cfg.rope_theta)  # the same positions in every pass
-        # a layer keeps its input alone and runs again in the backward pass:
-        # L x T inputs a step (2 GiB at eight layers, four passes and 16,384
-        # positions) beside the state.  The pass is the outer loop and the
-        # stack the inner one, so the two scans are written here
-        layer = decoder.remat(lambda carry, w: (self._block(carry, w, rope, kernels), None), cfg.n_layers)
+        # a layer keeps its input and what flash made and runs the rest again
+        # in the backward pass: L x T inputs a step (2 GiB at eight layers,
+        # four passes and 16,384 positions) and as many of ``o`` and its row
+        # statistics (67 + 1 MB each, 2.2 GB) beside the state.  The pass is
+        # the outer loop and the stack the inner one, so the two scans are
+        # written here
+        layer = decoder.remat(
+            lambda carry, w: (self._block(carry, w, rope, kernels), None), cfg.n_layers, keep=flash.KEPT_NAMES
+        )
 
         def one_pass(x, own_layers):
             h, _ = jax.lax.scan(layer, x, params["layers"] if own_layers is None else own_layers)

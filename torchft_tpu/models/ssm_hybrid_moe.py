@@ -37,7 +37,7 @@ published pattern are of one kind, so there every run is one layer and
 ``params["groups"]`` holds a stack of one a layer.  A layer is
 rematerialised in the backward pass but for what flash made
 (``ops/flash_attention.py``, ``KEPT_NAMES``: its output and row statistics,
-134 + 17 MB a ``*`` layer at 16,384 positions and 32 heads of 128), so that
+134 + 2 MB a ``*`` layer at 16,384 positions and 32 heads of 128), so that
 ``flash_fwd`` stands once in a step's program.  ``ops/ssd.py`` names its
 output and chunk-start states the same way (134 + 268 MB an ``M`` layer at
 64 heads of 64 by 128); this model's policy does not list them, because at
@@ -321,7 +321,7 @@ class SsmHybridMoE:
             x = params["embed"][tokens].astype(jnp.float32)  # the residual stream
         loads, balance = [], jnp.zeros((), jnp.float32)
         # kept through a layer's rematerialisation: flash's output and row
-        # statistics (151 MB at 16,384 positions).  NOT ``ssd.KEPT_NAMES``: at
+        # statistics (136 MB at 16,384 positions).  NOT ``ssd.KEPT_NAMES``: at
         # the published widths the scan's 402 MB a layer, four layers, beside
         # a float32 stream ask 16.2 GB of a chip that gives out 16.9 and the
         # step dies allocating (PERF.md section 6, PR 35), so ``ssd_fwd``

@@ -37,11 +37,12 @@ a mask over plain attention beside ``lax.ragged_dot`` and the path is named
 Contiguous layers of one kind (attention kind, feed-forward kind) are stacked
 and run under one ``lax.scan``, as ``SsmHybridMoE``'s are.  A layer is
 rematerialised in the backward pass but for its float32 input and, on a FULL
-layer, what flash made (``ops/flash_attention.py``, ``KEPT_NAMES``: 134 + 17
-MB a layer at 16,384 positions and 32 heads of 128), so that ``flash_fwd``
-stands once a full layer in a step's program.  A windowed layer keeps nothing
-of the kind and ``flash_win_fwd`` runs twice: the walk makes it cheap, and
-kept on all of the published cut's eight layers the step does not fit a chip.
+layer, what flash made (``ops/flash_attention.py``, ``KEPT_NAMES``: ``o`` and
+one float32 a row, 134 + 2 MB a layer at 16,384 positions and 32 heads of
+128), so that ``flash_fwd`` stands once a full layer in a step's program.  A
+windowed layer keeps nothing of the kind and ``flash_win_fwd`` runs twice:
+the walk makes it cheap, and kept on all of the published cut's eight layers
+the step did not fit a chip while the row statistics were kept 128 lanes wide.
 
 The residual stream is float32 whatever the matrices' dtype, and the router
 reads its float32 norm: which 8 of 128 experts a token takes is a step
@@ -301,13 +302,13 @@ class WindowedMoE:
         loads = []
         for (kind, depth), stacked in zip(self.groups, params["groups"]):
             # kept through a layer's rematerialisation: its float32 input and,
-            # on a FULL layer, flash's output and row statistics (151 MB at
+            # on a FULL layer, flash's output and row statistics (136 MB at
             # 16,384 positions), so that the dear ``flash_fwd`` stands once in a
             # step.  A WINDOWED layer keeps nothing of the kind and runs its
             # forward kernel again, which the walk makes cheap: kept on all
-            # eight layers the gradient step needs 3.4 GB more than kept on
-            # none, by the compiler's count, and does not fit beside 8.9 GB
-            # of weights, gradients and moments (PERF.md section 6, PR 41)
+            # eight layers the first chip run died allocating (PERF.md section
+            # 6, PR 41), when the row statistics were 268 MB a layer; at one
+            # number a row that policy has not run (ROADMAP.md S14 (2))
             x, load = decoder.scan_run(
                 lambda carry, w, kind=kind: self._block(carry, w, kind, kernels), x, stacked, depth,
                 keep=flash.KEPT_NAMES if kind[0] == "full_attention" else (),

@@ -627,47 +627,26 @@ def _flash_hm(q, k, v, sm_scale, causal, block_q, block_k, interpret, window):
 
 # the forward rule's residuals that a rematerialising caller's policy may
 # keep, so that ``flash_fwd`` runs once a step (a policy that lists neither,
-# as ``nothing_saveable``, is served as before)
+# as ``nothing_saveable``, is served as before): ``o`` and ONE float32 a row.
+# The kernels' ``[B, H, S, 8]`` row statistics lie padded to 128 lanes in HBM
+# (134 MB a layer at 16 heads and 16,384 positions); ``[B, H, S]`` is 1 MB
+# and is spread again where the backward kernels read it
 KEPT_NAMES = ("flash_o", "flash_lse")
 
 
 def _flash_hm_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret, window):
     o, lse = _fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret, window)
-    o, lse = (checkpoint_name(a, n) for a, n in zip((o, lse), KEPT_NAMES))
-    return o, (q, k, v, o, lse)
-
-
-def _flash_hm_bwd(sm_scale, causal, block_q, block_k, interpret, window, res, do):
-    return _bwd(sm_scale, causal, block_q, block_k, interpret, res, do, window=window)
-
-
-_flash_hm.defvjp(_flash_hm_fwd, _flash_hm_bwd)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _pooled_hm(q, k, v, sm_scale, block_q, block_k, interpret, rule):
-    """``_flash_hm`` under a ``Pooled`` rule, whose kept row statistics are
-    ONE number a row: the kernels' ``[B, H, S, 8]`` float32 lies padded to 128
-    lanes in HBM (537 MB a layer at 32 heads and 32,768 positions, 2.1 GB kept
-    over four layers), ``[B, H, S]`` is 4 MB and is spread again where the
-    backward kernels read it."""
-    o, _ = _fwd(q, k, v, sm_scale, True, block_q, block_k, interpret, rule)
-    return o
-
-
-def _pooled_hm_fwd(q, k, v, sm_scale, block_q, block_k, interpret, rule):
-    o, lse = _fwd(q, k, v, sm_scale, True, block_q, block_k, interpret, rule)
     o, lse = (checkpoint_name(a, n) for a, n in zip((o, lse[..., 0]), KEPT_NAMES))
     return o, (q, k, v, o, lse)
 
 
-def _pooled_hm_bwd(sm_scale, block_q, block_k, interpret, rule, res, do):
+def _flash_hm_bwd(sm_scale, causal, block_q, block_k, interpret, window, res, do):
     *rest, lse = res
     lse = jnp.broadcast_to(lse[..., None], (*lse.shape, _ROW_LANES))
-    return _bwd(sm_scale, True, block_q, block_k, interpret, (*rest, lse), do, window=rule)
+    return _bwd(sm_scale, causal, block_q, block_k, interpret, (*rest, lse), do, window=window)
 
 
-_pooled_hm.defvjp(_pooled_hm_fwd, _pooled_hm_bwd)
+_flash_hm.defvjp(_flash_hm_fwd, _flash_hm_bwd)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
@@ -827,9 +806,9 @@ def eva_attention(
         pooled = jnp.pad(pooled, ((0, 0), (0, padding), (0, 0), (0, 0)))
         return jnp.concatenate([pooled, tokens], axis=1).transpose(0, 2, 1, 3)
 
-    out = _pooled_hm(
+    out = _flash_hm(
         q.transpose(0, 2, 1, 3), key_axis(k_pooled, k), key_axis(v_pooled, v),
-        float(1.0 / np.sqrt(D) if sm_scale is None else sm_scale), block_q, block_k, interpret, rule,
+        float(1.0 / np.sqrt(D) if sm_scale is None else sm_scale), True, block_q, block_k, interpret, rule,
     )
     return out.transpose(0, 2, 1, 3)
 
