@@ -1,5 +1,5 @@
 """The parts of the compiled step (``torchft_tpu/obs/spans.py``,
-``DEVICE_PARTS``): every operation that costs device time in the nine models'
+``DEVICE_PARTS``): every operation that costs device time in the ten models'
 two step programs is traced under a ``tpuft.<part>`` scope, at toy widths and
 on both paths (plain, and the kernels in interpret mode).  The paths are read
 from the COMPILED text's ``op_name``s: XLA inlines every private function
@@ -16,23 +16,25 @@ from torchft_tpu.obs.spans import DEVICE_PARTS, PART_PREFIX, part
 
 from tests._toys import toy, lowered_grad_step
 
-MODELS = ("llama", "ling_hybrid", "indexed_sparse_moe", "ssm_hybrid_moe", "windowed_moe", "latent_moe", "eva", "gated_delta_moe", "looped")
+MODELS = ("llama", "ling_hybrid", "indexed_sparse_moe", "ssm_hybrid_moe", "windowed_moe", "latent_moe", "eva", "gated_delta_moe", "looped", "sambay")
 CASES = [(m, p) for m in MODELS for p in ("plain", "kernels")]
 EVERY = set(DEVICE_PARTS)
 # Keye has no dense MLP and no shared expert; Mistral has no experts; the
 # prediction module's own work is ``mtp``, and JoyAI's is the one model here
 # that runs the module (Ling's toy preset builds none); EvaByte's is the one
-# mixer that pools, and the looped model's the one exit gate
+# mixer that pools, the looped model's the one exit gate, and SambaY's the one
+# model that subtracts two softmaxes
 USES = {
-    "llama": EVERY - {"experts_route", "experts_dispatch", "mtp", "mixer_pool", "loop_gate"},
-    "ling_hybrid": EVERY - {"mtp", "mixer_pool", "loop_gate"},
-    "indexed_sparse_moe": EVERY - {"ffn", "mtp", "mixer_pool", "loop_gate"},
-    "ssm_hybrid_moe": EVERY - {"mtp", "mixer_pool", "loop_gate"},
-    "windowed_moe": EVERY - {"mtp", "mixer_pool", "loop_gate"},
-    "latent_moe": EVERY - {"mixer_pool", "loop_gate"},
-    "eva": EVERY - {"experts_route", "experts_dispatch", "mtp", "loop_gate"},
-    "gated_delta_moe": EVERY - {"mtp", "mixer_pool", "loop_gate"},
-    "looped": EVERY - {"experts_route", "experts_dispatch", "mtp", "mixer_pool"},
+    "llama": EVERY - {"experts_route", "experts_dispatch", "mtp", "mixer_pool", "loop_gate", "mixer_diff"},
+    "ling_hybrid": EVERY - {"mtp", "mixer_pool", "loop_gate", "mixer_diff"},
+    "indexed_sparse_moe": EVERY - {"ffn", "mtp", "mixer_pool", "loop_gate", "mixer_diff"},
+    "ssm_hybrid_moe": EVERY - {"mtp", "mixer_pool", "loop_gate", "mixer_diff"},
+    "windowed_moe": EVERY - {"mtp", "mixer_pool", "loop_gate", "mixer_diff"},
+    "latent_moe": EVERY - {"mixer_pool", "loop_gate", "mixer_diff"},
+    "eva": EVERY - {"experts_route", "experts_dispatch", "mtp", "loop_gate", "mixer_diff"},
+    "gated_delta_moe": EVERY - {"mtp", "mixer_pool", "loop_gate", "mixer_diff"},
+    "looped": EVERY - {"experts_route", "experts_dispatch", "mtp", "mixer_pool", "mixer_diff"},
+    "sambay": EVERY - {"experts_route", "experts_dispatch", "mtp", "mixer_pool", "loop_gate"},
 }
 # what costs time on a device and is never fused away into a neighbour
 HELD = ("dot", "convolution", "gather", "scatter", "sort")
@@ -139,7 +141,7 @@ def test_the_poolings_operations_are_under_their_part_and_nothing_elses_is(path)
 
 
 def test_the_vocabulary_is_closed():
-    assert len(DEVICE_PARTS) == len(set(DEVICE_PARTS)) == 13
+    assert len(DEVICE_PARTS) == len(set(DEVICE_PARTS)) == 14
     with pytest.raises(ValueError, match="nonsense"):
         part("nonsense")
     for name in DEVICE_PARTS:
@@ -161,6 +163,8 @@ def test_the_vocabulary_is_closed():
         # the exit gate and what it weighs are the gate's; a pass's head stays the head's
         ("jit(_step)/transpose(jvp(tpuft.loop_gate))/mul", "loop_gate"),
         ("jit(_step)/jvp(tpuft.head)/while/body/checkpoint/dot_general", "head"),
+        # the two softmaxes' combination inside the glue is its own
+        ("jit(_step)/jvp(tpuft.layers)/while/body/checkpoint/tpuft.mixer_glue/tpuft.mixer_diff/sub", "mixer_diff"),
         ("jit(_step)/concatenate", None),
         ("", None),
         (None, None),

@@ -361,6 +361,26 @@ def test_the_looped_model_is_model_code_over_the_flash_kernels(module: str, row:
     assert {target for importer, _line, target in _inner_edges() if importer == module} == may_import
 
 
+@pytest.mark.parametrize(
+    "module,row,may_import",
+    [
+        ("ops.selscan", "store-kernels-data", {"ops.kda"}),
+        (
+            "models.sambay", "compiled-step-models",
+            {"ops.selscan", "ops.flash_attention", "parallel.moe", "models.decoder", "obs.spans"},
+        ),
+    ],
+)
+def test_the_decoder_hybrid_decoder_is_model_code_over_kernels(module: str, row: str, may_import: set) -> None:
+    """PR 63's two modules: the selective scan's kernels in the kernels' row
+    (sharing ``ops/kda.py``'s products by import), the model in the models',
+    calling ``models/decoder.py``'s norms, convolution, projections, refusals,
+    layer walk and blocked head, the shared SwiGLU and the flash kernels as
+    they stand, no sibling model and nothing of the Manager."""
+    assert [ROWS[i][0] for i in _rows_of(module)] == [row]
+    assert {target for importer, _line, target in _inner_edges() if importer == module} == may_import
+
+
 def test_no_model_imports_another_and_the_shared_shell_imports_none() -> None:
     """PR 61's seam, for every module of ``models/`` at once: what models
     share is ``models/decoder.py`` (the shell of a decoder, as functions) and

@@ -41,6 +41,14 @@ def rms_norm(x: jax.Array, weight: jax.Array, eps: float) -> jax.Array:
     return ((x32 / rms) * weight).astype(x.dtype)
 
 
+def layer_norm(x: jax.Array, weight: jax.Array, bias: jax.Array, eps: float) -> jax.Array:
+    """LayerNorm under ``weight`` and ``bias``, float32 statistics, in x's dtype."""
+    x32 = x.astype(jnp.float32)
+    centred = x32 - jnp.mean(x32, axis=-1, keepdims=True)
+    scale = jax.lax.rsqrt(jnp.mean(centred * centred, axis=-1, keepdims=True) + eps)
+    return (centred * scale * weight + bias).astype(x.dtype)
+
+
 def unit(x: jax.Array) -> jax.Array:
     """x at unit length over its last axis."""
     x32 = x.astype(jnp.float32)
@@ -304,6 +312,24 @@ def token_nll(logits: jax.Array, labels: jax.Array) -> jax.Array:
     """The cross-entropy of ``labels`` [B, S] under ``logits`` [B, S, V]."""
     logp = jax.nn.log_softmax(logits, axis=-1)
     return -jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
+
+
+def blocked_nll(logits_of: Callable[[jax.Array], jax.Array], x: jax.Array, targets: jax.Array, block: int) -> jax.Array:
+    """The cross-entropy of every position of ``x`` ``[..., S, D]`` under
+    ``logits_of(rows)``, float32 in x's leading shape, ``block`` positions at a
+    time (the whole sequence where a block does not divide it): a block's
+    logits are made again in the backward pass, never kept, and no ``[S,
+    vocab]`` is ever whole.  ``targets`` ``[B, S]`` serve every leading axis
+    before the batch's."""
+    *lead, S, D = x.shape
+    block = block if S % block == 0 else S
+    labels = jnp.broadcast_to(targets, (*lead, S)).reshape(-1, block)
+
+    @functools.partial(jax.checkpoint, prevent_cse=False)
+    def of_block(rows):
+        return token_nll(logits_of(rows[0]), rows[1])
+
+    return jax.lax.map(of_block, (x.reshape(-1, block, D), labels)).reshape(*lead, S)
 
 
 @part("head")

@@ -262,16 +262,7 @@ class Looped:
         D] holds, [t, B, S] float32, ``head_block`` positions at a time: a
         block's logits are made again in the backward pass, never kept, and
         no pass's ``[S, vocab]`` is ever whole."""
-        cfg = self.config
-        t, B, S, D = x.shape
-        block = cfg.head_block if S % cfg.head_block == 0 else S
-        labels = jnp.broadcast_to(targets, (t, B, S)).reshape(-1, block)
-
-        @functools.partial(jax.checkpoint, prevent_cse=False)
-        def of_block(rows):
-            return decoder.token_nll(self._logits(params, rows[0]), rows[1])
-
-        return jax.lax.map(of_block, (x.reshape(-1, block, D), labels)).reshape(t, B, S)
+        return decoder.blocked_nll(functools.partial(self._logits, params), x, targets, self.config.head_block)
 
     @part("loop_gate")
     def _exit_log_p(self, params: Dict[str, Any], x_all: jax.Array) -> jax.Array:

@@ -61,6 +61,10 @@ operations; these are the names the program gives out::
     gdn_fwd, gdn_bwd                   (ops/gdn.py: the same with one decay a
                                         head, unbounded, and value heads that
                                         share a key head)
+    selscan_fwd, selscan_bwd           (ops/selscan.py: a selective scan with a
+                                        decay for every (channel, state) pair,
+                                        the recurrence itself on the vector
+                                        unit with the state in VMEM)
     ...gmm..., ...tgmm...              (parallel/moe.py RoutedExperts: jax's
                                         megablox kernels, named after the
                                         jitted functions around them)
@@ -68,7 +72,7 @@ operations; these are the names the program gives out::
 **The parts of the compiled step.**  A device operation that XLA makes has
 no name of ours, only the path of ``jax.named_scope``s it was traced under
 (``op_name`` in the compiled program, ``tf_op`` in a trace's event metadata).
-:class:`part` opens one of these thirteen scopes (``with part("ffn"):`` around
+:class:`part` opens one of these fourteen scopes (``with part("ffn"):`` around
 some lines, ``@part("ffn")`` on a function that is one part whole), ONE
 vocabulary for every architecture (:data:`DEVICE_PARTS`); the innermost one
 on an operation's path is its part::
@@ -89,6 +93,13 @@ on an operation's path is its part::
                             k, ``softplus`` and the decay, the SiLU-gated head
                             norm and the full layers' sigmoid gate are here
                             (``models/gated_delta_moe.py``): no part is new
+    tpuft.mixer_diff        differential attention's combination of its two
+                            softmaxes (``models/sambay.py``): ``lambda`` from
+                            its four vectors, ``O1 - lambda O2``, the RMSNorm
+                            over a pair's channels and ``1 - lambda_0``,
+                            forward and backward; the projections stay
+                            ``mixer_proj``'s and the head shuffles around the
+                            launch ``mixer_glue``'s
     tpuft.mixer_pool        a mixer's pooling of keys and values into chunk
                             summaries (``models/eva.py``): the scores against
                             the learned vector, the softmax over a chunk, the
@@ -175,7 +186,7 @@ SPANS_ENV = "TORCHFT_FLIGHT_SPANS"
 
 # the parts of the compiled step (the module docstring says what lies under each)
 DEVICE_PARTS = (
-    "embed", "stream", "mixer_proj", "mixer_glue", "mixer_pool", "ffn", "experts_route",
+    "embed", "stream", "mixer_proj", "mixer_glue", "mixer_diff", "mixer_pool", "ffn", "experts_route",
     "experts_dispatch", "head", "mtp", "loop_gate", "layers", "optimizer",
 )
 PART_PREFIX = "tpuft."
