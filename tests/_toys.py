@@ -295,6 +295,12 @@ def lowered_grad_step(name, path):
     kernels in interpret mode), one row of one sequence: traced once a
     process for the file that reads the lowered text and the one that
     compiles it."""
+    # jax shares a private function between two places of the lowered text
+    # where its caches hand both the same jaxpr OBJECT (a rematerialised
+    # layer's partial evaluation is cached by the policy's identity, a library
+    # function's trace by its shapes), so what a worker traced before decides
+    # what is shared: the text is made from the caches as a new process has them
+    jax.clear_caches()
     with on_path(path):
         model, seq = toy(name)
         mesh = make_mesh(fsdp=1, devices=jax.devices()[:1])

@@ -13,6 +13,7 @@ import pytest
 from jax.ad_checkpoint import print_saved_residuals
 
 from torchft_tpu.models.llama import Llama, LlamaConfig
+from torchft_tpu.ops import flash_attention as fa
 from torchft_tpu.ops.flash_attention import KEPT_NAMES, eva_attention, flash_attention
 
 
@@ -455,3 +456,79 @@ def test_gradients_through_what_is_kept_are_those_of_a_second_forward_bit_for_bi
     for name, a, b in zip(("q", "k", "v", "k_pooled", "v_pooled"), kept, again):
         assert float(jnp.max(jnp.abs(a))) > 0, name
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b), err_msg=f"d{name}")
+
+
+# ---------------------------------------------------------------------------
+# the forward's keys-major body over every kind of launch that reaches ``_fwd``
+# ---------------------------------------------------------------------------
+
+
+def _dense_hm(q, k, v, seen, scale):
+    """Plain attention over heads-major operands under ``seen`` [Sq, Sk]
+    (None: every key): (o [B, H, Sq, Dv], lse [B, H, Sq])."""
+    groups = q.shape[1] // k.shape[1]
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, jnp.repeat(k, groups, axis=1)) * scale
+    if seen is not None:
+        s = jnp.where(seen, s, -1e30)
+    lse = jax.nn.logsumexp(s, axis=-1)
+    return jnp.einsum("bhqk,bhkd->bhqd", jnp.exp(s - lse[..., None]), jnp.repeat(v, groups, axis=1)), lse
+
+
+def _sees(Sq, Sk, rule, causal):
+    """[Sq, Sk]: which keys a query sees, position by position."""
+    if not causal:
+        return None
+    i, j = np.arange(Sq)[:, None], np.arange(Sk)[None, :]
+    if isinstance(rule, fa.Pooled):
+        token = j - rule.summaries
+        of_summaries = (j < rule.summaries) & (j // rule.per_window < i // rule.window)
+        return of_summaries | ((token >= 0) & (token // rule.window == i // rule.window) & (token <= i))
+    return (j <= i) & (j > i - (Sq if rule is None else rule))
+
+
+@pytest.mark.parametrize(
+    "H,KV,D,Dv,Sq,Sk,bq,bk,rule,causal",
+    [
+        pytest.param(2, 2, 32, 32, 256, 256, 64, 64, None, True, id="causal-whole-group-1"),
+        pytest.param(8, 1, 16, 16, 256, 256, 32, 64, 100, True, id="window-no-multiple-of-a-block-group-8"),
+        pytest.param(16, 1, 8, 8, 256, 256, 64, 128, None, True, id="causal-whole-group-16"),
+        # 32 summaries of 8 positions and their padding to a key block of 64, then the tokens
+        pytest.param(4, 2, 16, 16, 256, 320, 32, 64, fa.Pooled(64, 8, 64), True, id="pooled-two-key-sources"),
+        pytest.param(4, 2, 32, 16, 128, 256, 64, 32, None, False, id="no-causality-Sq-not-Sk"),
+        pytest.param(2, 2, 192, 128, 128, 128, 64, 32, None, True, id="latent-heads-192-128"),
+        pytest.param(4, 2, 64, 128, 128, 128, 32, 64, 72, True, id="differential-heads-64-128-window"),
+    ],
+)
+def test_keys_major_forward_and_its_rules_agree_with_plain_attention(H, KV, D, Dv, Sq, Sk, bq, bk, rule, causal) -> None:
+    """``_fwd``'s ``o`` and ``lse`` against plain attention, the row statistic
+    leaving as ``[B, H, S]`` (a row of the kernel's keys-major tile, not the
+    ``[B, H, S, 8]`` the backward kernels read), and the gradients of the
+    forward rule that takes them (``_flash_hm``; without causality
+    ``_flash_hm_lse``, whose ``lse`` takes a cotangent too) against jax's
+    through plain attention."""
+    kq, kk, kv, kd, kl = jax.random.split(jax.random.PRNGKey(11), 5)
+    q = jax.random.normal(kq, (1, H, Sq, D), jnp.float32)
+    k = jax.random.normal(kk, (1, KV, Sk, D), jnp.float32)
+    v = jax.random.normal(kv, (1, KV, Sk, Dv), jnp.float32)
+    do = jax.random.normal(kd, (1, H, Sq, Dv), jnp.float32)
+    dlse = jax.random.normal(kl, (1, H, Sq), jnp.float32)
+    scale = 1.0 / float(np.sqrt(D))
+    dense = functools.partial(_dense_hm, seen=_sees(Sq, Sk, rule, causal), scale=scale)
+
+    o, lse = jax.jit(lambda *a: fa._fwd(*a, scale, causal, bq, bk, True, rule))(q, k, v)
+    assert o.shape == (1, H, Sq, Dv) and lse.shape == (1, H, Sq) and lse.dtype == jnp.float32
+    want_o, want_lse = dense(q, k, v)
+    np.testing.assert_allclose(np.asarray(o), np.asarray(want_o), rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(want_lse), rtol=2e-5, atol=2e-5)
+
+    if causal:
+        rules = lambda *a: jnp.sum(fa._flash_hm(*a, scale, True, bq, bk, True, rule) * do)  # noqa: E731
+        plain = lambda *a: jnp.sum(dense(*a)[0] * do)  # noqa: E731
+    else:
+        both = lambda o, lse: jnp.sum(o * do) + jnp.sum(lse * dlse)  # noqa: E731
+        rules = lambda *a: both(*fa._flash_hm_lse(*a, scale, False, bq, bk, True))  # noqa: E731
+        plain = lambda *a: both(*dense(*a))  # noqa: E731
+    got = jax.jit(jax.grad(rules, argnums=(0, 1, 2)))(q, k, v)
+    want = jax.grad(plain, argnums=(0, 1, 2))(q, k, v)
+    for name, a, b in zip(("dq", "dk", "dv"), got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-4, err_msg=name)

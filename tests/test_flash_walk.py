@@ -91,7 +91,7 @@ def test_without_causality_the_grid_is_the_rectangle_and_holds_no_table(H, KV, S
 
     def run(q, k, v):
         o, lse = fa._fwd(q, k, v, 0.125, False, bq, bk, True)
-        return (o, lse[..., 0]), fa._bwd(0.125, False, bq, bk, True, (q, k, v, o, lse), do)
+        return (o, lse), fa._bwd(0.125, False, bq, bk, True, (q, k, v, o, fa._spread(lse)), do)
 
     launches = []
     jaxpr = jax.make_jaxpr(run)(q, k, v).jaxpr

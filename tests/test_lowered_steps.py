@@ -37,9 +37,20 @@ spreads them again in the backward rule; ``looped``'s layer keeps what flash
 made); the eight ``plain`` digests and both of ``indexed_sparse_moe`` did NOT
 change, nor ``eva``'s ``kernels`` one: its rule was already this one, and the
 ``custom_vjp``'s Python name (``_pooled_hm`` then, ``_flash_hm`` now) is not
-in the lowered text.  A later change that means to alter one of these
-programs writes the fixture anew and says so: ``python
-tests/test_lowered_steps.py --write``."""
+in the lowered text.  PR 64 wrote the ``kernels`` digests of the eight models
+that call flash anew (``--write --only
+ling_hybrid,llama,ssm_hybrid_moe,windowed_moe,eva,gated_delta_moe,looped,sambay``:
+the forward's score tile lies keys-major and its second output is ``[B, H,
+S]``, ``ops/flash_attention.py``); the nine ``plain`` digests and both of
+``indexed_sparse_moe`` did NOT change, which is the proof that the plain path
+and Keye's kernels were not touched.  Since PR 64 the text is made from jax's
+caches as a new process has them (``tests/_toys.py`` ``lowered_grad_step``
+clears them before it lowers): jax shares a private function between two
+places of the text where its caches hand both the same jaxpr object, so
+``ssm_hybrid_moe``'s two digests, whose runs share ONE policy object, came out
+another in a worker that had traced other files first.  A later change that
+means to alter one of these programs writes the fixture anew and says so:
+``python tests/test_lowered_steps.py --write``."""
 
 import hashlib
 import json
@@ -77,6 +88,9 @@ def test_lowers_to_the_bytes_it_lowered_to(name, path):
 
 
 if __name__ == "__main__" and "--write" in sys.argv:
+    sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    import tests.conftest  # noqa: F401  (the tests' environment: the CPU's eight devices)
+
     import jax
 
     # ``--only a,b`` writes those models' digests anew and keeps the others'

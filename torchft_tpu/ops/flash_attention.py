@@ -14,6 +14,24 @@ are fetched from kv-head ``h // groups`` directly, so grouped K/V are
 never repeated to full head count in HBM (the naive path's ``jnp.repeat``
 costs ``groups``× K/V bandwidth).
 
+**The forward's tile lies keys-major** (PR 64).  ``s^T = k q^T`` is
+``[bk, bq]``, the keys down the sublanes and the queries along the lanes, so
+the two reductions over the keys an online-softmax step makes (the running
+maximum, the denominator) are elementwise maxima and sums ACROSS the tile's
+vector registers and one fold of eight sublanes a column group, and no
+reduction along the lanes stands in the loop; ``m`` and ``l`` are rows of
+``[1, bq]`` float32 (four registers, where ``[bq, 128]`` broadcasts were
+written whole every step) and reach the tile by a sublane broadcast.  The
+accumulator lies the same way, ``[Dv, bq]`` (``v^T p^T``, the transposed-left
+product ``dkv`` makes), and is turned once a ROW BLOCK on its last visit;
+``lse`` leaves as it lies, a row: the forward's second output is ``[B, H, S]``
+float32 (block ``(1, 1, 1, bq)`` of ``[B, H, 1, S]``).  Same scores, same
+``exp``, same products and operand types as the rows-major body it replaced:
+the maximum is exact in any order, ``l`` is the same addends summed in another
+order (``scripts/flash_walk_probe.py`` prints the largest difference).  The
+backward kernels keep their rows-major tiles and read ``lse`` and ``delta`` as
+``[B, H, S, 8]`` columns (``_spread``).
+
 Backward is the standard two-kernel flash scheme over the saved
 logsumexp: ``dq`` accumulates over k-blocks; ``dk``/``dv`` accumulate over
 (q-head-in-group × q-block) so each kv-head's gradient sums its whole GQA
@@ -92,10 +110,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
-_LANES = 128  # accumulator minor dim (TPU lane width)
-# rowwise stats (lse, delta) carry a trailing 8-lane dim: Mosaic requires
-# the last block dim be 128-divisible OR equal to the full array dim, and a
-# [B,H,S]-shaped output tiled (1,1,bq) satisfies neither
+# the backward kernels' rowwise stats (lse, delta) carry a trailing 8-lane dim
+# and are read as [bq, 1] columns beside their rows-major tiles; the forward
+# writes its own as a row, block (1, 1, 1, bq) of [B, H, 1, S]
 _ROW_LANES = 8
 
 
@@ -266,18 +283,17 @@ def _where(tables, axis=2):
     return tables[0][step], tables[1][step], (flags & _FIRST) != 0, (flags & _LAST) != 0
 
 
-def _masked(s, qi, ki, block_q, block_k, window):
+def _masked(s, qi, ki, block_q, block_k, window, keys_major=False):
     """Scores [bq, bk] of row block ``qi`` against key block ``ki`` with the
     dead pairs (a later key; with a window, one ``window`` or more back) at
     ``_NEG_INF``.  Under ``Pooled`` a key block is of one kind: of a summary
     block the row block's window sees the summaries before its own, of a token
-    block (its own window's, by liveness) a row sees up to itself."""
-    rows = qi * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0
-    )
-    cols = ki * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1
-    )
+    block (its own window's, by liveness) a row sees up to itself.
+    ``keys_major``: the tile is the forward's, [bk, bq] with the keys down the
+    sublanes, and the same compares run over it."""
+    shape, of_rows, of_cols = ((block_k, block_q), 1, 0) if keys_major else ((block_q, block_k), 0, 1)
+    rows = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, shape, of_rows)
+    cols = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, shape, of_cols)
     if isinstance(window, Pooled):
         seen = (qi * block_q) // window.window * window.per_window
         last = jnp.where(ki * block_k < window.summaries, seen - 1, rows + window.summaries)
@@ -294,16 +310,19 @@ def _masked(s, qi, ki, block_q, block_k, window):
 
 
 def _fwd_kernel(*refs, sm_scale: float, causal: bool, block_q: int, block_k: int, window: Optional[int]):
+    """One live (row block, key block) pair of the online softmax, the tile
+    keys-major (the module docstring): every reduction over the keys runs
+    down the sublanes and across vector registers."""
     (
         *tables,  # SMEM [steps] int32: the walk (none without causality)
         q_ref,  # [1, 1, bq, D]
         k_ref,  # [1, 1, bk, D]
-        v_ref,  # [1, 1, bk, D]
-        o_ref,  # [1, 1, bq, D]
-        lse_ref,  # [1, 1, bq, _ROW_LANES]
-        m_scr,  # VMEM [bq, _LANES] f32: running row max
-        l_scr,  # VMEM [bq, _LANES] f32: running denominator
-        acc_scr,  # VMEM [bq, D] f32: running (unnormalized) output
+        v_ref,  # [1, 1, bk, Dv]
+        o_ref,  # [1, 1, bq, Dv]
+        lse_ref,  # [1, 1, 1, bq]
+        m_scr,  # VMEM [1, bq] f32: running row max
+        l_scr,  # VMEM [1, bq] f32: running denominator
+        acc_scr,  # VMEM [Dv, bq] f32: running (unnormalized) output, transposed
     ) = refs
     qi, ki, first, last = _where(tables)
 
@@ -319,35 +338,31 @@ def _fwd_kernel(*refs, sm_scale: float, causal: bool, block_q: int, block_k: int
 
     s = (
         jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
+            k, q, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
         * sm_scale
-    )  # [bq, bk] f32
+    )  # [bk, bq] f32
     if causal:
-        s = _masked(s, qi, ki, block_q, block_k, window)
+        s = _masked(s, qi, ki, block_q, block_k, window, keys_major=True)
 
-    m_prev = m_scr[:, :1]  # [bq, 1]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-    p = jnp.exp(s - m_new)  # [bq, bk]
-    correction = jnp.exp(m_prev - m_new)  # [bq, 1]
-    l_new = l_scr[:, :1] * correction + jnp.sum(p, axis=1, keepdims=True)
+    m_prev = m_scr[...]  # [1, bq]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
+    p = jnp.exp(s - m_new)  # [bk, bq]
+    correction = jnp.exp(m_prev - m_new)  # [1, bq]
+    l_scr[...] = l_scr[...] * correction + jnp.sum(p, axis=0, keepdims=True)
     acc_scr[...] = acc_scr[...] * correction + jax.lax.dot_general(
-        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        v, p.astype(v.dtype), (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
-    )
-    m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
-    l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
+    )  # v^T @ p: [Dv, bq]
+    m_scr[...] = m_new
 
     @pl.when(last)
     def _finalize():
-        m = m_scr[:, :1]
-        l = l_scr[:, :1]
+        l = l_scr[...]
         denom = jnp.where(l > 0.0, l, 1.0)  # fully-masked rows guard
-        o_ref[0, 0] = (acc_scr[...] / denom).astype(o_ref.dtype)
-        lse_ref[0, 0] = jnp.broadcast_to(
-            m + jnp.log(denom), (m.shape[0], _ROW_LANES)
-        )
+        o_ref[0, 0] = (acc_scr[...] / denom).T.astype(o_ref.dtype)
+        lse_ref[0, 0] = m_scr[...] + jnp.log(denom)
 
 
 def _fwd(
@@ -362,7 +377,7 @@ def _fwd(
     window: Optional[int] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """q [B,H,Sq,D], k [B,KV,Sk,D], v [B,KV,Sk,Dv] → (o [B,H,Sq,Dv],
-    lse [B,H,Sq]).  Rectangular (Sq != Sk) is allowed when not causal; v's
+    lse [B,H,Sq] f32).  Rectangular (Sq != Sk) is allowed when not causal; v's
     head size may differ from q's and k's (latent attention: 192 and 128)."""
     B, H, S, D = q.shape
     Dv = v.shape[3]
@@ -378,7 +393,12 @@ def _fwd(
         block_k=block_k,
         window=window,
     )
-    return pl.pallas_call(
+
+    def row_map(*at):  # a row block's statistics lie along the lanes of [B, H, 1, S]
+        b, h, i, _ = q_map(*at)
+        return b, h, 0, i
+
+    o, lse = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(tables),
@@ -390,21 +410,22 @@ def _fwd(
             ],
             out_specs=[
                 pl.BlockSpec((1, 1, block_q, Dv), q_map),
-                pl.BlockSpec((1, 1, block_q, _ROW_LANES), q_map),
+                pl.BlockSpec((1, 1, 1, block_q), row_map),
             ],
             scratch_shapes=[
-                pltpu.VMEM((block_q, _LANES), jnp.float32),
-                pltpu.VMEM((block_q, _LANES), jnp.float32),
-                pltpu.VMEM((block_q, Dv), jnp.float32),
+                pltpu.VMEM((1, block_q), jnp.float32),
+                pltpu.VMEM((1, block_q), jnp.float32),
+                pltpu.VMEM((Dv, block_q), jnp.float32),
             ],
         ),
         out_shape=[
             jax.ShapeDtypeStruct((B, H, S, Dv), q.dtype),
-            jax.ShapeDtypeStruct((B, H, S, _ROW_LANES), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, 1, S), jnp.float32),
         ],
         interpret=interpret,
         name=_name(window, "fwd"),
     )(*tables, q, k, v)
+    return o, lse.reshape(B, H, S)
 
 
 # ---------------------------------------------------------------------------
@@ -627,23 +648,27 @@ def _flash_hm(q, k, v, sm_scale, causal, block_q, block_k, interpret, window):
 
 # the forward rule's residuals that a rematerialising caller's policy may
 # keep, so that ``flash_fwd`` runs once a step (a policy that lists neither,
-# as ``nothing_saveable``, is served as before): ``o`` and ONE float32 a row.
-# The kernels' ``[B, H, S, 8]`` row statistics lie padded to 128 lanes in HBM
-# (134 MB a layer at 16 heads and 16,384 positions); ``[B, H, S]`` is 1 MB
-# and is spread again where the backward kernels read it
+# as ``nothing_saveable``, is served as before): ``o`` and ONE float32 a row,
+# ``[B, H, S]`` (1 MB at 16 heads and 16,384 positions), which is what the
+# forward kernel writes.  The BACKWARD kernels read ``[B, H, S, 8]``, padded
+# to 128 lanes in HBM (134 MB a layer there): the backward rules spread it
 KEPT_NAMES = ("flash_o", "flash_lse")
+
+
+def _spread(lse):
+    """A row statistic ``[B, H, S]`` as the backward kernels read it."""
+    return jnp.broadcast_to(lse[..., None], (*lse.shape, _ROW_LANES))
 
 
 def _flash_hm_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret, window):
     o, lse = _fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret, window)
-    o, lse = (checkpoint_name(a, n) for a, n in zip((o, lse[..., 0]), KEPT_NAMES))
+    o, lse = (checkpoint_name(a, n) for a, n in zip((o, lse), KEPT_NAMES))
     return o, (q, k, v, o, lse)
 
 
 def _flash_hm_bwd(sm_scale, causal, block_q, block_k, interpret, window, res, do):
     *rest, lse = res
-    lse = jnp.broadcast_to(lse[..., None], (*lse.shape, _ROW_LANES))
-    return _bwd(sm_scale, causal, block_q, block_k, interpret, (*rest, lse), do, window=window)
+    return _bwd(sm_scale, causal, block_q, block_k, interpret, (*rest, _spread(lse)), do, window=window)
 
 
 _flash_hm.defvjp(_flash_hm_fwd, _flash_hm_bwd)
@@ -653,19 +678,19 @@ _flash_hm.defvjp(_flash_hm_fwd, _flash_hm_bwd)
 def _flash_hm_lse(q, k, v, sm_scale, causal, block_q, block_k, interpret):
     """Heads-major flash returning (o, lse [B,H,S] f32) — for callers that
     merge partial attention results across blocks (ring attention)."""
-    o, lse4 = _fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret)
-    return o, lse4[..., 0]
+    return _fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret)
 
 
 def _flash_hm_lse_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret):
-    o, lse4 = _fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret)
-    return (o, lse4[..., 0]), (q, k, v, o, lse4)
+    o, lse = _fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret)
+    return (o, lse), (q, k, v, o, lse)
 
 
 def _flash_hm_lse_bwd(sm_scale, causal, block_q, block_k, interpret, res, cts):
     do, dlse = cts
+    *rest, lse = res
     return _bwd(
-        sm_scale, causal, block_q, block_k, interpret, res, do, dlse=dlse
+        sm_scale, causal, block_q, block_k, interpret, (*rest, _spread(lse)), do, dlse=dlse
     )
 
 
