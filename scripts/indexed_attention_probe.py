@@ -6,13 +6,22 @@ line is ``PROBE {...}``.
 ``--parent _parent`` (a checkout of another commit, ``git archive``) times
 that copy's ``dsa_attn_fwd``, ``dsa_attn_dq``, ``dsa_attn_dkv`` and
 ``dsa_probs`` beside the tree's on the same operands, in turn (parent, tree,
-tree, parent), and says whether every output is EQUAL bit for bit (PR 53:
-the launches walk their live blocks alone).  ``--compile-only`` compiles the
-tree's four for a described v5e without one (``JAX_PLATFORMS=cpu``)."""
+tree, parent).  The backward launches and ``dsa_probs`` of both are handed
+the TREE's ``o`` and ``lse``, and the report says whether ``dq``, ``dk``,
+``dv`` and ``L_I``'s four are EQUAL bit for bit; of ``o`` and ``lse`` it
+gives the LARGEST DIFFERENCE from the parent's (since PR 65 the forward's
+tile lies keys-major and sums a row's denominator in another order: ``o`` in
+steps of its own type, ``lse`` absolute and as ``[B, H, S]`` whichever way it
+left the kernel).  ``--variants name=path,...`` times the FORWARD of further
+copies of the module beside them (PR 65 read the parent's body without its
+two lane reductions so, and the bits turned inside the kernel).
+``--compile-only`` compiles the tree's four, and the variants' forwards, for a
+described v5e without one (``JAX_PLATFORMS=cpu``)."""
 
 from __future__ import annotations
 
 import argparse
+import functools
 import importlib.util
 import json
 import os
@@ -59,10 +68,12 @@ def plain(topk):
     return f
 
 
-def _load(checkout):
-    """``ops/indexed_attention.py`` of another checkout, as a module."""
+def _load(path):
+    """Another copy of the module (a checkout's, a variant's file)."""
+    if os.path.isdir(path):
+        path = os.path.join(path, "torchft_tpu", "ops", "indexed_attention.py")
     spec = importlib.util.spec_from_file_location(
-        "indexed_attention_parent", os.path.join(checkout, "torchft_tpu", "ops", "indexed_attention.py")
+        "indexed_attention_at_" + "".join(c if c.isalnum() else "_" for c in path), path
     )
     module = importlib.util.module_from_spec(spec)
     sys.modules[spec.name] = module
@@ -70,10 +81,33 @@ def _load(checkout):
     return module
 
 
+def _rows(lse):
+    """A forward's row statistic as ``[B, H, S]``, whichever way it left."""
+    return lse[..., 0] if lse.ndim == 4 else lse
+
+
+def _forward_differences(got, want):
+    """``o`` and ``lse`` of one forward against another's: the largest
+    absolute difference of each, and ``o``'s in steps of its type at the
+    other's value (over the entries that are not tiny beside the largest: a
+    value near 0 is a cancellation, and float32's last bit is many of ITS
+    steps)."""
+    (o, lse), (o_want, lse_want) = got, want
+    f32 = lambda a: np.asarray(a, np.float32)  # noqa: E731
+    a, b = f32(o), f32(o_want)
+    gap, large = np.abs(a - b), np.abs(b) > 1e-3 * np.abs(b).max()
+    step = 2.0 ** (np.floor(np.log2(np.abs(b[large]))) - jnp.finfo(o.dtype).nmant)
+    return dict(
+        o_abs=float(gap.max()), o_steps=float((gap[large] / step).max()),
+        lse_abs=float(np.abs(f32(_rows(lse)) - f32(_rows(lse_want))).max()),
+    )
+
+
+@functools.cache
 def launches(module, seq, dim, interpret=False):
     """The attention's three launches and ``dsa_probs`` of ``module`` as
     jitted programs over heads-major operands (an output that is not returned
-    takes its launch with it)."""
+    takes its launch with it); one set a module."""
     blocks = module.Blocks().fit(seq)
     scale = 1.0 / float(np.sqrt(dim))
     bwd = lambda *a: module._attn_bwd(*a, scale, blocks, interpret)  # noqa: E731
@@ -85,7 +119,7 @@ def launches(module, seq, dim, interpret=False):
     )
 
 
-def compile_only(seq, heads=32, kv=4, dim=128, index_heads=16, index_dim=64):
+def compile_only(seq, variants, heads=32, kv=4, dim=128, index_heads=16, index_dim=64):
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
@@ -109,6 +143,10 @@ def compile_only(seq, heads=32, kv=4, dim=128, index_heads=16, index_dim=64):
         t0 = time.perf_counter()
         fn.lower(*args[name]).compile()
         report[name + "_compile_s"] = round(time.perf_counter() - t0, 1)
+    for name, module in variants.items():
+        t0 = time.perf_counter()
+        launches(module, seq, dim)["attn_fwd"].lower(*args["attn_fwd"]).compile()
+        report[name + "_attn_fwd_compile_s"] = round(time.perf_counter() - t0, 1)
     print("PROBE " + json.dumps(report))
 
 
@@ -120,11 +158,13 @@ def main():
     ap.add_argument("--topk", type=int, default=2048)
     ap.add_argument("--rounds", type=int, default=3)
     ap.add_argument("--parent", default="", help="a checkout whose launches are timed beside the tree's")
+    ap.add_argument("--variants", default="", help="name=path,...: further copies of the module, their forward timed")
     ap.add_argument("--compile-only", action="store_true")
     ap.add_argument("--toy", action="store_true", help="on the CPU in interpret mode, 1,024 positions")
     args = ap.parse_args()
+    variants = {n: _load(p) for n, p in (v.split("=") for v in args.variants.split(",") if v)}
     if args.compile_only:
-        return compile_only(args.seq)
+        return compile_only(args.seq, variants)
     toy = args.toy
     if toy:
         args.check_seq, args.check_topk, args.seq, args.topk, args.rounds = 512, 64, 1024, 128, 1
@@ -167,21 +207,30 @@ def main():
     if args.parent:
         parent = ("parent_", _load(args.parent))
         sides = [parent, sides[0], ("again_", ia), ("parent_again_", parent[1])]
-    got = {}
+    # every side's backward launches and dsa_probs take the TREE's o and lse
+    o, lse = launches(ia, args.seq, q.shape[-1], toy)["attn_fwd"](qh, kh, vh, mask)
+    lanes = ia._row_lanes(lse)
+    bwd = (qh, kh, vh, mask, o, lanes, o)
+    forwards, got = {}, {}
     for prefix, module in sides:
         run = launches(module, args.seq, q.shape[-1], toy)
-        o, lse = clock(prefix + "attn_fwd_ms", run["attn_fwd"], qh, kh, vh, mask)
-        bwd = (qh, kh, vh, mask, o, lse, o)
+        forwards[prefix] = clock(prefix + "attn_fwd_ms", run["attn_fwd"], qh, kh, vh, mask)
         dq = clock(prefix + "attn_dq_ms", run["attn_dq"], *bwd)
         dk, dv = clock(prefix + "attn_dkv_ms", run["attn_dkv"], *bwd)
-        loss = clock(prefix + "probs_ms", run["probs"], qh, kh, lse, mask, qih, wh, ki, ia._row_lanes(lse_i))
-        got[prefix] = (o, lse, dq, dk, dv, *loss)
+        loss = clock(prefix + "probs_ms", run["probs"], qh, kh, lanes, mask, qih, wh, ki, ia._row_lanes(lse_i))
+        got[prefix] = (dq, dk, dv, *loss)
+    for name, module in variants.items():
+        forwards[name] = clock(name + "_attn_fwd_ms", launches(module, args.seq, q.shape[-1], toy)["attn_fwd"], qh, kh, vh, mask)
     if args.parent:
-        names = "o lse dq dk dv kl d_qi d_w d_ki".split()
+        names = "dq dk dv kl d_qi d_w d_ki".split()
         out["equal_to_parent"] = {
             n: bool(jnp.array_equal(a, b)) for n, a, b in zip(names, got[""], got["parent_"], strict=True)
         }
         print("equal_to_parent", out["equal_to_parent"], flush=True)
+    others = {n: f for n, f in forwards.items() if n and not n.startswith("again")}
+    if others:
+        out["forward_differs_by"] = {n.rstrip("_"): _forward_differences(forwards[""], f) for n, f in others.items()}
+        print("forward_differs_by", out["forward_differs_by"], flush=True)
     clock("whole_grad_ms", jax.grad(lambda *a: kernels(args.topk, toy)(*a)[0], argnums=tuple(range(6))), *ops)
     from torchft_tpu.ops.flash_attention import flash_attention
 

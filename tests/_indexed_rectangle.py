@@ -3,9 +3,15 @@
 whole RECTANGLE of (row block, key block) pairs, a dead pair a grid step that
 does nothing (``pl.when``) and fetches nothing (the index maps stay on the
 last live block).  Kept word for word (the bodies' shared pieces come from the
-package, which did not change them) as the oracle that
-``tests/test_indexed_attention.py`` holds the walked launches to, bit for
-bit; nothing of the package reads this file."""
+package, which did not change them) as the oracle of
+``tests/test_indexed_attention.py``.  What it still holds BIT FOR BIT: ``dq``,
+``dk``, ``dv`` and ``L_I``'s four (``kl`` and its three gradients), both
+modules handed the tree's ``o`` and ``lse``.  Its forward is the rows-major
+body the package had until PR 65 (the maximum and the sum along the lanes,
+``lse`` out as ``[B, H, S, 8]``): the tree's keys-major forward sums a row's
+denominator in another order, so ``o`` and ``lse`` are held to it at
+float32's rounding, not bit for bit.  Nothing of the package reads this
+file."""
 
 import functools
 

@@ -1,9 +1,11 @@
 """``ops/indexed_attention.py``: the Mosaic kernels in interpret mode against
 the plain path (dense ``[S, S]`` arrays, ``lax.top_k`` on the full row) and
 against dense causal attention where every key is picked; the launches'
-grids against the live (row block, key block) pairs, and their outputs, bit
-for bit, against the rectangular launches they were before PR 53
-(``tests/_indexed_rectangle.py``).  Float32, seeded operands, the CPU."""
+grids against the live (row block, key block) pairs, and their outputs
+against the rectangular launches they were before PR 53
+(``tests/_indexed_rectangle.py``): the backward launches' and ``L_I``'s bit for
+bit, the keys-major forward's (PR 65) to float32's rounding.  Float32, seeded
+operands, the CPU."""
 
 import jax
 import jax.numpy as jnp
@@ -90,9 +92,11 @@ def test_kernels_are_the_plain_path(case):
 # the cases that differ in the walk's shape (three more differ in the scores alone)
 @pytest.mark.parametrize("case", sorted(set(CASES) - {"under_topk", "four_times_topk", "tied_scores"}))
 def test_walked_launches_are_the_rectangles_bit_for_bit(case):
-    """What the four launches give over their live pairs is what the parent's
-    gave over the whole rectangle, every bit: the order of accumulation inside
-    a row block and inside a key block is the rectangle's."""
+    """What the backward launches and ``L_I``'s give over their live pairs is
+    what the parent's gave over the whole rectangle, every bit: the order of
+    accumulation inside a row block and inside a key block is the
+    rectangle's.  The forward's tile lies keys-major and its ``o`` and ``lse``
+    are the rectangle's to float32's rounding."""
     cfg = CASES[case]
     (q, k, v, qi, ki, w), do = _operands(**cfg)
     blocks = cfg["blocks"].fit(cfg["S"])
@@ -103,16 +107,26 @@ def test_walked_launches_are_the_rectangles_bit_for_bit(case):
     qh, kh, vh, qih, wh = ia._heads_major(q, k, v, qi, w)
     scale = 1.0 / float(np.sqrt(q.shape[-1]))
 
-    def launches(module):
-        o, lse = module._attn_fwd(qh, kh, vh, mask, scale, blocks, True)
-        grads = module._attn_bwd(qh, kh, vh, mask, o, lse, do.transpose(0, 2, 1, 3), scale, blocks, True)
-        loss = module._index_loss(qh, kh, lse, mask, qih, wh, ki, ia._row_lanes(lse_index), scale, blocks, True)
-        return (o, lse, *grads, *loss)
+    # the tree's forward is keys-major since PR 65: its ``o`` and ``lse`` are the rectangle's to float32's
+    # rounding of a denominator summed in another order, and BOTH modules' backward launches and ``L_I`` are
+    # handed the tree's two, so that what did not change is still held bit for bit
+    forward = jax.jit(lambda module: module._attn_fwd(qh, kh, vh, mask, scale, blocks, True), static_argnums=0)
+    (o, lse), (o_want, lse_want) = forward(ia), forward(rectangle)
+    assert lse.shape == o.shape[:3] and lse_want.shape == (*lse.shape, ia._ROW_LANES)
+    # read over the six cases (interpret mode, float32): o 6.0e-7 at most, lse 4.8e-7
+    np.testing.assert_allclose(np.asarray(o), np.asarray(o_want), rtol=0, atol=2e-6, err_msg="o")
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(lse_want[..., 0]), rtol=0, atol=2e-6, err_msg="lse")
 
-    names = "o lse dq dk dv kl d_q_index d_w d_k_index".split()
+    def launches(module):
+        lanes = ia._row_lanes(lse)
+        grads = module._attn_bwd(qh, kh, vh, mask, o, lanes, do.transpose(0, 2, 1, 3), scale, blocks, True)
+        loss = module._index_loss(qh, kh, lanes, mask, qih, wh, ki, ia._row_lanes(lse_index), scale, blocks, True)
+        return (*grads, *loss)
+
+    names = "dq dk dv kl d_q_index d_w d_k_index".split()
     run = jax.jit(launches, static_argnums=0)
     for name, got, want in zip(names, run(ia), run(rectangle), strict=True):
-        if name in names[:5]:
+        if name in names[:3]:
             np.testing.assert_array_equal(np.asarray(got), np.asarray(want), err_msg=name)
         else:
             # L_I's four are EQUAL on the chip (scripts/indexed_attention_probe.py --parent).  HERE the
@@ -121,6 +135,36 @@ def test_walked_launches_are_the_rectangles_bit_for_bit(case):
             # in a_group_of_one, ``d_w`` in one more case)
             np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-6, atol=1e-7, err_msg=name)
         assert np.isfinite(np.asarray(got)).all() and float(jnp.max(jnp.abs(got))) > 0, name
+
+
+def test_a_row_that_picks_nothing_in_its_first_key_blocks():
+    """Index scores that rise with the position: a row's key set is the
+    ``topk`` positions up to itself, so every key block before those is all
+    ``_NEG_INF`` to it.  The keys-major forward keeps such a row's ``m`` at
+    ``_NEG_INF`` and adds ``exp(0)`` a key there, and the first picked key's
+    correction wipes that to exactly 0: ``o`` and ``lse`` are the plain
+    path's."""
+    S, topk, H, KV, blocks = 128, 8, 8, 2, ia.Blocks(16, 16, 32, 16)
+    (q, k, v, qi, ki, w), _ = _operands(S=S, H=H, KV=KV, B=1)
+    qi, w = jnp.ones_like(qi), jnp.ones_like(w)
+    ki = jnp.broadcast_to((jnp.arange(1, S + 1, dtype=jnp.float32) / S)[None, :, None], ki.shape)
+
+    @jax.jit
+    def kernels(q, k, v, qi, ki, w):
+        mask, _, _ = ia.select_keys(qi, ki, w, topk=topk, blocks=blocks, interpret=True)
+        qh, kh, vh, _, _ = ia._heads_major(q, k, v, qi, w)
+        return mask, *ia._attn_fwd(qh, kh, vh, mask, 1.0 / float(np.sqrt(q.shape[-1])), blocks, True)
+
+    mask, o, lse = kernels(q, k, v, qi, ki, w)
+    rows, cols = np.arange(S)[:, None], np.arange(S)[None, :]
+    picked = (cols <= rows) & (cols > rows - topk)
+    np.testing.assert_array_equal(_dense_bits(mask, S, blocks.k)[0], picked)
+    assert not picked[3 * blocks.k :, : 2 * blocks.k].any()  # two whole key blocks and more of nothing, walked all the same
+    o_want, _, _ = ia.indexed_attention_plain(q, k, v, qi, ki, w, topk=topk)
+    np.testing.assert_allclose(o.transpose(0, 2, 1, 3), o_want, atol=3e-6)
+    logits = jnp.einsum("bthd,bshd->bhts", q, jnp.repeat(k, H // KV, axis=2)) / np.sqrt(q.shape[-1])
+    lse_want = jax.nn.logsumexp(jnp.where(picked, logits, -jnp.inf), axis=-1)
+    np.testing.assert_allclose(lse, lse_want, atol=1e-5)
 
 
 def _live_pairs(S, bq, bk):

@@ -48,7 +48,11 @@ caches as a new process has them (``tests/_toys.py`` ``lowered_grad_step``
 clears them before it lowers): jax shares a private function between two
 places of the text where its caches hand both the same jaxpr object, so
 ``ssm_hybrid_moe``'s two digests, whose runs share ONE policy object, came out
-another in a worker that had traced other files first.  A later change that
+another in a worker that had traced other files first.  PR 65 wrote ONE anew
+(``--write --only indexed_sparse_moe``, whose ``plain`` digest came out the
+same: Keye's ``dsa_attn_fwd`` takes the bits keys-major and its tile lies
+keys-major, its second output is ``[B, H, S]``, ``ops/indexed_attention.py``)
+and none of the other seventeen changed.  A later change that
 means to alter one of these programs writes the fixture anew and says so:
 ``python tests/test_lowered_steps.py --write``."""
 

@@ -24,11 +24,34 @@ and a dead pair is no grid step (``_steps``; at 16,384 positions and blocks
 of 128 x 512 a launch's 4 KV heads take 8,448 steps where the rectangle held
 16,384, from 25 KB of tables).  Forward, ``dq`` and ``L_I`` take a row
 block's key blocks ascending, ``dkv`` a key block's row blocks ascending: the
-rectangle's order of accumulation, so every output is bit for bit the
-rectangle's.  Eight query heads of a KV head are stacked into one
-``[8 * block_q, D]`` operand, so a key block and a mask tile are read once
-for the group.  No ``[S, S]`` array is made: the index's float32 scores exist
-for one chunk of ``chunk`` query rows at a time.
+rectangle's order of accumulation, so ``dq``, ``dk``, ``dv`` and ``L_I``'s
+four are bit for bit the rectangle's.  Eight query heads of a KV head are
+stacked into one ``[8 * block_q, D]`` operand, so a key block and a mask tile
+are read once for the group.  No ``[S, S]`` array is made: the index's
+float32 scores exist for one chunk of ``chunk`` query rows at a time.
+
+**The forward's tile lies keys-major** (PR 65, as ``ops/flash_attention.py``'s
+since PR 64).  ``s^T = k q^T`` is ``[block_k, 8 * block_q]``, the keys down the
+sublanes and the group's heads side by side along the lanes, so the running
+maximum and the denominator are elementwise maxima and sums ACROSS the tile's
+vector registers and one fold of eight sublanes a column group: no reduction
+along the lanes stands in the loop.  ``m`` and ``l`` are rows of
+``[1, 8 * block_q]`` float32 and reach the tile by a sublane broadcast; the
+accumulator lies the same way, ``[D, 8 * block_q]`` (``v^T p^T``, the
+transposed-left product ``dkv`` makes), and is turned once a ROW BLOCK on its
+last visit.  The bits arrive as every launch reads them, an int32 tile
+``[block_q, block_k]`` of the rows-major mask; the kernel shifts and masks it
+as the others do, TURNS it (64 registers through the transpose unit, beside
+the products) and applies the ``[block_k, block_q]`` tile to each head's
+columns.  (A keys-major copy of the mask made by XLA once a launch, read as
+``[block_k, block_q]`` tiles, was 1.2 ms a launch SLOWER on the chip: PERF.md
+section 6, PR 65.)  Same scores, same ``exp``, same products and operand
+types as the rows-major body it replaced: the maximum is exact in any order,
+``l`` is the same addends summed in another order, so ``o`` and ``lse`` are
+the rectangle's to float32's rounding of that sum
+(``scripts/indexed_attention_probe.py --parent`` prints the largest
+difference).  ``dsa_attn_dq``, ``dsa_attn_dkv`` and ``dsa_probs`` keep their
+rows-major tiles.
 
 Six ``pallas_call`` names, which the benchmark's readers find in a trace:
 
@@ -45,12 +68,14 @@ Six ``pallas_call`` names, which the benchmark's readers find in a trace:
 
 **What the backward pass is handed.**  The forward rule's residuals are the
 attention's operands, the bits, and five values that carry a
-``checkpoint_name`` (``KEPT_NAMES``): ``o``, its ``lse`` a row (one lane of
-the kernel's eight: stacked over a model's layers the eight would each pad
-to 128), and ``L_I``'s gradient to ``qI``, ``w`` and ``kI`` in float32, as
-``dsa_probs`` left it.  A caller that rematerialises its layers lists those
-names in its policy and ``dsa_attn_fwd`` and ``dsa_probs`` run once; one that
-lists none computes both again in its backward pass, to the same bits.
+``checkpoint_name`` (``KEPT_NAMES``): ``o``, its ``lse`` a row as the forward
+wrote it (``[B, H, S]`` float32, block ``(1, group, 1, block_q)`` of
+``[B, H, 1, S]``: ONE number a row; ``dsa_probs`` and the backward launches
+take theirs as ``[..., 8]`` columns, ``_row_lanes``), and ``L_I``'s gradient
+to ``qI``, ``w`` and ``kI`` in float32, as ``dsa_probs`` left it.  A caller
+that rematerialises its layers lists those names in its policy and
+``dsa_attn_fwd`` and ``dsa_probs`` run once; one that lists none computes
+both again in its backward pass, to the same bits.
 
 :func:`indexed_attention_plain` is the same mathematics in ``jax.numpy`` with
 dense ``[S, S]`` arrays: the path off the TPU and the tests' oracle.
@@ -122,9 +147,11 @@ def _row_lanes(x: jax.Array) -> jax.Array:
     return jnp.broadcast_to(x[..., None], x.shape + (_ROW_LANES,))
 
 
-def _tile_bits(mask_ref, ki: jax.Array) -> jax.Array:
-    """The picked positions of key block ``ki`` as a boolean ``[bq, bk]``."""
-    return ((mask_ref[0, 0] >> (ki % 32)) & 1) != 0
+def _tile_bits(mask_ref, ki: jax.Array, keys_major: bool = False) -> jax.Array:
+    """The picked positions of key block ``ki`` as a boolean ``[bq, bk]``;
+    ``keys_major``, the int32 tile is turned first and they are ``[bk, bq]``."""
+    bits = (mask_ref[0, 0] >> (ki % 32)) & 1
+    return (bits.T if keys_major else bits) != 0
 
 
 def _steps(seq: int, blocks: "Blocks", by_key: bool = False) -> Walk:
@@ -321,9 +348,14 @@ def _masked_scores(q, k, picked, sm_scale, group):
 
 
 def _attn_fwd_kernel(*refs, sm_scale, group, block_q):
+    """One live (row block, key block) pair of the online softmax over the
+    picked keys, the tile keys-major (the module docstring): the group's
+    heads lie side by side along the lanes, and every reduction over the keys
+    runs down the sublanes and across vector registers."""
     *tables, q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr = refs
     _, ki, first, last = _where(tables)
     D = q_ref.shape[-1]
+    heads = [slice(g * block_q, (g + 1) * block_q) for g in range(group)]
 
     @pl.when(first)
     def _init():
@@ -333,29 +365,27 @@ def _attn_fwd_kernel(*refs, sm_scale, group, block_q):
 
     q = q_ref[0].reshape(group * block_q, D)
     v = v_ref[0, 0]
-    s = _masked_scores(q, k_ref[0, 0], _tile_bits(mask_ref, ki), sm_scale, group)
+    s = _dot_t(k_ref[0, 0], q) * sm_scale  # [bk, group * bq]
+    picked = _tile_bits(mask_ref, ki, keys_major=True)  # [bk, bq]
+    s = jnp.concatenate([jnp.where(picked, s[:, h], _NEG_INF) for h in heads], axis=1)
     # a row that picked nothing in the blocks so far keeps m at _NEG_INF
     # and adds exp(0) here; the first picked key's correction,
     # exp(_NEG_INF - m), wipes that to exactly 0
-    m_prev = m_scr[:, :1]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    m_prev = m_scr[...]  # [1, group * bq]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
     p = jnp.exp(s - m_new)
     correction = jnp.exp(m_prev - m_new)
-    l_new = l_scr[:, :1] * correction + jnp.sum(p, axis=1, keepdims=True)
-    acc_scr[...] = acc_scr[...] * correction + jax.lax.dot_general(
-        p.astype(v.dtype), v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
-    l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
+    l_scr[...] = l_scr[...] * correction + jnp.sum(p, axis=0, keepdims=True)
+    acc_scr[...] = acc_scr[...] * correction + _dot_0(v, p.astype(v.dtype))  # v^T p^T: [D, group * bq]
+    m_scr[...] = m_new
 
     @pl.when(last)
     def _finalize():
-        l = l_scr[:, :1]
-        o_ref[0] = (acc_scr[...] / l).reshape(group, block_q, D).astype(o_ref.dtype)
-        lse = m_scr[:, :1] + jnp.log(l)
-        lse_ref[0] = jnp.broadcast_to(lse, (group * block_q, _ROW_LANES)).reshape(
-            group, block_q, _ROW_LANES
-        )
+        l = l_scr[...]
+        o_ref[0] = (acc_scr[...] / l).T.reshape(group, block_q, D).astype(o_ref.dtype)
+        lse = m_scr[...] + jnp.log(l)
+        for g, h in enumerate(heads):  # a head a row of [group, 1, bq]
+            lse_ref[0, g] = lse[:, h]
 
 
 def _p_and_ds(q, k, v, do, lse, delta, picked, sm_scale, group):
@@ -431,34 +461,39 @@ def _attn_specs(group, bq, bk, D):
 
 def _attn_fwd(q, k, v, mask, sm_scale, blocks, interpret):
     """q [B, H, S, D], k and v [B, KV, S, D] → (o [B, H, S, D], lse
-    [B, H, S, 8])."""
+    [B, H, S])."""
     B, H, S, D = q.shape
     KV = k.shape[1]
     group = H // KV
     bq, bk = blocks.q, blocks.k
     walk = _steps(S, blocks)
-    q_spec, kv_spec, mask_spec, row_spec = _attn_specs(group, bq, bk, D)
-    return pl.pallas_call(
+    q_spec, kv_spec, mask_spec, _ = _attn_specs(group, bq, bk, D)
+    o, lse = pl.pallas_call(
         functools.partial(_attn_fwd_kernel, sm_scale=sm_scale, group=group, block_q=bq),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(walk.tables),
             grid=(B, KV, walk.steps),
             in_specs=[q_spec, kv_spec, kv_spec, mask_spec],
-            out_specs=[q_spec, row_spec],
+            out_specs=[
+                q_spec,
+                # a row block's statistics lie along the lanes of [B, H, 1, S]
+                pl.BlockSpec((1, group, 1, bq), lambda b, h, t, qt, kt, ft: (b, h, 0, qt[t])),
+            ],
             scratch_shapes=[
-                pltpu.VMEM((group * bq, _LANES), jnp.float32),
-                pltpu.VMEM((group * bq, _LANES), jnp.float32),
-                pltpu.VMEM((group * bq, D), jnp.float32),
+                pltpu.VMEM((1, group * bq), jnp.float32),
+                pltpu.VMEM((1, group * bq), jnp.float32),
+                pltpu.VMEM((D, group * bq), jnp.float32),
             ],
         ),
         out_shape=[
             jax.ShapeDtypeStruct(q.shape, q.dtype),
-            jax.ShapeDtypeStruct((B, H, S, _ROW_LANES), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, 1, S), jnp.float32),
         ],
         compiler_params=_params("parallel", "parallel", "arbitrary"),
         interpret=interpret,
         name="dsa_attn_fwd",
     )(*walk.tables, q, k, v, mask)
+    return o, lse.reshape(B, H, S)
 
 
 def _attn_bwd(q, k, v, mask, o, lse, do, sm_scale, blocks, interpret):
@@ -644,12 +679,12 @@ def _attend_fwd(q, k, v, q_index, k_index, w, mask, lse_index, sm_scale, blocks,
     qh, kh, vh, qih, wh = _heads_major(q, k, v, q_index, w)
     o, lse = _attn_fwd(qh, kh, vh, mask, sm_scale, blocks, interpret)
     kl, d_qi, d_w, d_ki = _index_loss(
-        qh, kh, lse, mask, qih, wh, k_index, _row_lanes(lse_index), sm_scale, blocks, interpret
+        qh, kh, _row_lanes(lse), mask, qih, wh, k_index, _row_lanes(lse_index), sm_scale, blocks, interpret
     )
     # the named values ARE the residuals: a policy that keeps the names leaves
     # the backward pass no use for either kernel above
     o, lse, d_qi, d_w, d_ki = (
-        checkpoint_name(a, n) for a, n in zip((o, lse[..., 0], d_qi, d_w, d_ki), KEPT_NAMES)
+        checkpoint_name(a, n) for a, n in zip((o, lse, d_qi, d_w, d_ki), KEPT_NAMES)
     )
     like = tuple(jnp.zeros((0,), a.dtype) for a in (q_index, k_index, w))  # the cotangents' dtypes
     kept = (qh, kh, vh, mask, o, lse, d_qi, d_w, d_ki, like)
