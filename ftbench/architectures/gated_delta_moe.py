@@ -9,10 +9,10 @@ holds, a shared expert behind a sigmoid gate).
 The benchmark's adapter, never a second implementation: the model is the
 program's, the plain reference is ``gated_delta_moe_reference.py`` beside this
 file (it imports nothing of the program), and the counting of parameters,
-operations and bytes is ONE object, ``gdn_flops`` below, which the readers
-``gdn_roofline``, ``gdn_flash_roofline`` and ``gdn_step_mfu_pct`` call through
-``layer_metrics/_gdn.py`` (``gmm_step`` has no reader yet: ``BENCHMARK.json``'s
-list of per-layer metrics is at the contract's cap of 128, PERF.md section 7).
+operations and bytes is ONE object, ``gdn_flops`` below, ``flops`` at the end of the file, which
+``step_mfu_pct``, ``moe_gmm_roofline`` and ``flash_roofline`` find through the
+cell's architecture (``gmm_step`` has its reader since PR 66) and ``gdn_roofline``
+calls through ``layer_metrics/_gdn.py``.
 ``ftbench/README.md``, "An architecture", says what the harness asks of a file
 like this one.
 
@@ -242,3 +242,9 @@ class gdn_flops:
         gdn, _ = gdn_flops.gdn_step(s, 1.0, seq)
         full, _ = gdn_flops.flash_step(s, 1.0, seq)
         return 6.0 * gdn_flops.matmul_params_touched(s) + (gdn + full) / seq
+
+
+# the ONE name the folded readers find the class by (``step_mfu_pct``, and where
+# it has the method ``moe_gmm_roofline`` and ``flash_roofline``: ``sources["architecture"].flops``;
+# README.md, "An architecture")
+flops = gdn_flops

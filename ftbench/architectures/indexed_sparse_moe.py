@@ -7,9 +7,9 @@ language model, ``model_type`` ``KeyeVL2``: grouped-query attention over the
 The benchmark's adapter, never a second implementation: the model is the
 program's, the plain reference is ``indexed_sparse_moe_reference.py`` beside
 this file (it imports nothing of the program), and the counting of
-parameters, operations and bytes is ``dsa_flops`` below, which the readers
-``dsa_index_roofline``, ``dsa_attn_roofline``, ``dsa_moe_gmm_roofline`` and
-``dsa_step_mfu_pct`` call.  ``ftbench/README.md``, "An architecture", says
+parameters, operations and bytes is ``dsa_flops`` below, ``flops`` at the end of the file,
+which ``step_mfu_pct`` and ``moe_gmm_roofline`` find through the cell's
+architecture and ``dsa_index_roofline`` and ``dsa_attn_roofline`` call.  ``ftbench/README.md``, "An architecture", says
 what the harness asks of a file like this one.
 
 ``model.loss`` is the next-token cross-entropy, which is what
@@ -248,3 +248,9 @@ class dsa_flops:
         index, _ = dsa_flops.index_step(s, 1.0, seq)
         attn, _ = dsa_flops.attn_step(s, 1.0, seq)
         return 6.0 * dsa_flops.matmul_params_touched(s) + (index + attn + dsa_flops.index_loss_step(s, 1.0, seq)) / seq
+
+
+# the ONE name the folded readers find the class by (``step_mfu_pct``, and where
+# it has the method ``moe_gmm_roofline`` and ``flash_roofline``: ``sources["architecture"].flops``;
+# README.md, "An architecture")
+flops = dsa_flops

@@ -6,9 +6,9 @@ selection bias over the experts this chip holds, one shared expert).
 The benchmark's adapter, never a second implementation: the model is the
 program's, the plain reference is ``ling_hybrid_reference.py`` beside this
 file (it imports nothing of the program), and the counting of parameters,
-operations and bytes is ``ling_flops`` below, which the readers
-``kda_roofline``, ``mla_flash_roofline``, ``moe_gmm_roofline`` and
-``ling_step_mfu_pct`` call.  ``ftbench/README.md``, "An architecture", says
+operations and bytes is ``ling_flops`` below, ``flops`` at the end of the file,
+which ``step_mfu_pct``, ``moe_gmm_roofline`` and ``flash_roofline`` find through
+the cell's architecture and ``kda_roofline`` calls.  ``ftbench/README.md``, "An architecture", says
 what the harness asks of a file like this one.
 
 ``model.loss`` is the next-token cross-entropy, which is what
@@ -174,6 +174,11 @@ class ling_flops:
     of a peak made from it can only read low."""
 
     @staticmethod
+    def is_mine(s: Dict[str, Any]) -> bool:
+        """Whether a cell's shapes are this architecture's."""
+        return "n_kda" in (s or {})
+
+    @staticmethod
     def matmul_params_touched(s: Dict[str, Any]) -> float:
         """Matrix-product parameters ONE TOKEN passes through here: the
         mixers, the dense layer, routers and shared experts whole, the
@@ -222,6 +227,9 @@ class ling_flops:
         elements = rows * seq * h * ((2 * qk + 2 * dv) + (4 * qk + 4 * dv))
         return s["n_mla"] * flops, s["n_mla"] * float(elements * itemsize)
 
+    # the common name of the launches ``flash_fwd``/``_dq``/``_dkv``'s need (``flash_roofline``)
+    flash_step = mla_flash_step
+
     @staticmethod
     def gmm_step(s: Dict[str, Any], rows_here: float, itemsize: int = 2):
         """(operations, bytes) of the grouped products of one step, all
@@ -242,3 +250,9 @@ class ling_flops:
         kda, _ = ling_flops.kda_step(s, 1.0, seq)
         mla, _ = ling_flops.mla_flash_step(s, 1.0, seq)
         return 6.0 * ling_flops.matmul_params_touched(s) + (kda + mla) / seq
+
+
+# the ONE name the folded readers find the class by (``step_mfu_pct``, and where
+# it has the method ``moe_gmm_roofline`` and ``flash_roofline``: ``sources["architecture"].flops``;
+# README.md, "An architecture")
+flops = ling_flops

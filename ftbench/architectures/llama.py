@@ -13,7 +13,7 @@ from __future__ import annotations
 
 from typing import Any, Dict
 
-from ftbench import flops, reference
+from ftbench import flops as counting, reference
 
 # the values ``model.attention_path`` may have on the chip (the check
 # ``attention_flash``): a naive path fails the run
@@ -94,9 +94,36 @@ def token_nll(host_params: Any, tokens: Any, targets: Any, config: Dict[str, Any
 
 
 def num_params(config: Dict[str, Any]) -> int:
-    return flops.num_params(shapes(config))
+    return counting.num_params(shapes(config))
 
 
 def vocab(config: Dict[str, Any]) -> int:
     """The batch's token ids are drawn below it."""
     return config["vocab_size"]
+
+
+class llama_flops:
+    """``ftbench/flops.py``'s counting under the three names every
+    architecture's class has: what the folded readers call
+    (``sources["architecture"].flops``)."""
+
+    @staticmethod
+    def is_mine(s: Dict[str, Any]) -> bool:
+        """Whether a cell's shapes are this architecture's."""
+        return "rope_theta" in (s or {}) and "ffn_hidden" in s
+
+    @staticmethod
+    def train_flops_per_token(s: Dict[str, Any], seq: int) -> float:
+        return counting.train_flops_per_token(s, seq)
+
+    @staticmethod
+    def flash_step(s: Dict[str, Any], rows: float, seq: int):
+        """(operations, bytes) of the causal attention of one step on ONE chip's
+        ``rows`` sequences (a group of several chips shares its rows out)."""
+        return counting.flash_step_flops(s, rows, seq), counting.flash_step_bytes(s, rows, seq)
+
+
+# the ONE name the folded readers find the class by (``step_mfu_pct`` and
+# ``flash_roofline``; README.md, "An architecture"); ``ftbench/flops.py`` is
+# ``counting`` here
+flops = llama_flops

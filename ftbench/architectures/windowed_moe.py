@@ -9,9 +9,10 @@ selection bias over the SwiGLU experts this chip holds, one shared expert).
 The benchmark's adapter, never a second implementation: the model is the
 program's, the plain reference is ``windowed_moe_reference.py`` beside this
 file (it imports nothing of the program), and the counting of parameters,
-operations and bytes is ONE object, ``swa_flops`` below, which the readers
-``swa_flash_roofline``, ``swa_full_flash_roofline``, ``swa_moe_gmm_roofline``
-and ``swa_step_mfu_pct`` call through ``layer_metrics/_swa.py``.
+operations and bytes is ONE object, ``swa_flops`` below, ``flops`` at the end of the file, which
+``step_mfu_pct``, ``moe_gmm_roofline`` and ``flash_roofline`` (the FULL layers')
+find through the cell's architecture and ``swa_flash_roofline`` (the windowed
+layers') calls through ``layer_metrics/_swa.py``.
 ``ftbench/README.md``, "An architecture", says what the harness asks of a
 file like this one.
 
@@ -220,6 +221,10 @@ class swa_flops:
         """The same of the FULL layers: the causal half."""
         return swa_flops._flash(s, rows, seq, s["n_full"], None, itemsize)
 
+    # the common name of the launches ``flash_fwd``/``_dq``/``_dkv``'s need (``flash_roofline``;
+    # the windowed layers' ``flash_win_*`` are ``win_flash_step``'s and ``swa_flash_roofline``'s)
+    flash_step = full_flash_step
+
     @staticmethod
     def gmm_step(s: Dict[str, Any], rows_here: float, itemsize: int = 2):
         """(operations, bytes) of the grouped products of one step, all
@@ -240,3 +245,9 @@ class swa_flops:
         windowed, _ = swa_flops.win_flash_step(s, 1.0, seq)
         full, _ = swa_flops.full_flash_step(s, 1.0, seq)
         return 6.0 * swa_flops.matmul_params_touched(s) + (windowed + full) / seq
+
+
+# the ONE name the folded readers find the class by (``step_mfu_pct``, and where
+# it has the method ``moe_gmm_roofline`` and ``flash_roofline``: ``sources["architecture"].flops``;
+# README.md, "An architecture")
+flops = swa_flops

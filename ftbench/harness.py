@@ -797,6 +797,8 @@ class Fleet:
             chips=cell.chips,
             replicas=len(self.groups),
             groups_share_chip=self.config["layout"]["groups_share_chip"],
+            # the cell's architecture file: a folded reader counts with ITS ``flops``
+            architecture=self.arch,
             shapes=shapes,
             seq=self.seq,
             rows_per_replica=tokens_per_step // self.seq,

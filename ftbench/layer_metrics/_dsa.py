@@ -5,7 +5,7 @@ PR that brought them is."""
 
 # reading a trace by a kernel's name and MOE_ROUTE out of the window are any
 # architecture's: the helpers PR 29 brought
-from ftbench.layer_metrics._ling import GMM, kernel_s_per_step, route_events  # noqa: F401
+from ftbench.layer_metrics._ling import kernel_s_per_step, route_events  # noqa: F401
 
 
 def flops():

@@ -4,9 +4,8 @@ without the kernels or the architecture, as the parent of the PR that brought
 them is."""
 
 # reading a trace by a kernel's name and MOE_ROUTE out of the window are any
-# architecture's: the helpers PR 29 brought.  ``FLASH`` is the full layers'
-# three kernels, here at heads of 256
-from ftbench.layer_metrics._ling import FLASH, kernel_s_per_step, route_events  # noqa: F401
+# architecture's: the helpers PR 29 brought
+from ftbench.layer_metrics._ling import kernel_s_per_step, route_events  # noqa: F401
 
 # the two kernels of ``ops/gdn.py``
 GDN = r"^%?gdn_(fwd|bwd)\b"

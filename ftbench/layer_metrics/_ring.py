@@ -1,6 +1,8 @@
 """Shared by the six readers of where the ring says its time goes (no metric
 itself: ``BENCHMARK.json`` names no ``_ring``).  The communicator counts
-seconds beneath ``tpuft/comm/op``, a lane's inside recv, inside the reduce's
+seconds beneath ``tpuft/comm/session`` (a round trip's rings are ONE call of
+the op thread since PR 60; ``tpuft/comm/op`` a collective on the per-call
+path), where no span can be opened: a lane's inside recv, inside the reduce's
 add and inside send, the op thread's in the ring's two phases and in the
 steps' tails (``native/comm.h`` ``EpochIO``, ``lane_stats()``; also in a
 stand-alone division between the phases, which since PR 57 only a ring of one

@@ -3,9 +3,8 @@
 program without the kernels or the architecture, as the parent of the PR
 that brought them is."""
 
-# reading a trace by a kernel's name and MOE_ROUTE out of the window are any
-# architecture's: the helpers PR 29 brought
-from ftbench.layer_metrics._ling import FLASH, GMM, kernel_s_per_step, route_events  # noqa: F401
+# reading a trace by a kernel's name is any architecture's: the helper PR 29 brought
+from ftbench.layer_metrics._ling import kernel_s_per_step
 
 SSD = r"^%?ssd_(fwd|bwd)\b"
 

@@ -3,10 +3,10 @@
 program without the kernels or the architecture, as the parent of the PR
 that brought them is."""
 
-# reading a trace by a kernel's name and MOE_ROUTE out of the window are any
-# architecture's: the helpers PR 29 brought.  ``FLASH`` is the FULL layers'
-# three kernels: ``flash_fwd`` does not match ``flash_win_fwd``
-from ftbench.layer_metrics._ling import FLASH, GMM, kernel_s_per_step, route_events  # noqa: F401
+# reading a trace by a kernel's name is any architecture's: the helper PR 29
+# brought.  ``FLASH`` is the FULL layers' three kernels: ``flash_fwd`` does not
+# match ``flash_win_fwd``
+from ftbench.layer_metrics._ling import FLASH, kernel_s_per_step  # noqa: F401
 
 # the windowed layers' three kernels (``ops/flash_attention.py`` with a window)
 FLASH_WIN = r"^%?flash_win_(fwd|dq|dkv)\b"

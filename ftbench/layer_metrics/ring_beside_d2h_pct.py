@@ -6,7 +6,14 @@ end of that round trip's last ``tpuft/ddp/d2h`` span.  100 x the sum of the
 one over the sum of the other, over the round trips of the traced steps.
 About 0 where the buckets land together and the rings start only then; the
 nearer 100, the less of the ring stands in series with the transfer.  None
-where there is no such span."""
+where there is no such span.
+
+NO ENTRY of ``BENCHMARK.json`` names this file since PR 66, so no run loads
+it: where a round trip's rings are a session (PR 60, both two-group cells) no
+trace holds either span.  The file stays because tier-1's
+``tests/test_ftbench_program_spans.py`` loads it by name and a ``benchmark`` PR
+edits nothing there (``ftbench/tests/test_ftbench_spec.py``
+``FILES_WITHOUT_AN_ENTRY``; README.md, "What the benchmark has")."""
 
 META = dict(source="program_span", layer="host data plane", unit="%", moves="ddp_tokens_per_s_per_chip")
 

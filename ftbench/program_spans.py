@@ -5,7 +5,8 @@ Every stage of the program is a ``tpuft/<layer>/<stage>`` annotation on the
 profiler's clock, in the same ``.xplane.pb`` as the device's operations, and
 carries as stats the replica it works for (``r``), the step that caused it
 (``step``) and, on the communicator's op thread, which collective of the
-step it is (``k``).  ``trace_reduce.from_profile`` keeps names and times
+step it is (``k``; a round trip's ONE ``tpuft/comm/session`` carries the
+``k`` of its first piece and ``pieces``).  ``trace_reduce.from_profile`` keeps names and times
 only, so this file reads the host planes again, with their stats, once a
 process.  A program without such spans (a parent commit) gives an empty
 list and every reader of it returns None.
@@ -182,10 +183,14 @@ def sync_round_trips(
     return out
 
 
-def peer_skew_s(spans: Sequence[Span], name: str = "tpuft/comm/op") -> List[Tuple[Any, float]]:
-    """(step, summed over the step's collectives the distance between the
-    replicas' starts of the k-th one) for every step two replicas or more
-    have spans of."""
+def peer_skew_s(spans: Sequence[Span], name: str = "tpuft/comm/session") -> List[Tuple[Any, float]]:
+    """(step, summed over the step's spans called ``name`` the distance between
+    the replicas' starts of the k-th one) for every step two replicas or more
+    have spans of.  By default the ONE span a round trip's rings have since
+    PR 60 (``tpuft/comm/session``: how far apart the replicas enter the ring;
+    ``tpuft/comm/op``, a span a collective, is the per-call path's and in no
+    trace of a session).  No per-layer metric reads it since PR 66 retired
+    ``ring_peer_skew_ms``: it is a column of :func:`main`'s table."""
     starts: Dict[Tuple[Any, Any], Dict[str, float]] = {}
     for s in spans:
         if s["name"] == name and "k" in s and "step" in s:

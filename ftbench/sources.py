@@ -12,6 +12,12 @@
 - counters: ``peak_bytes``, ``grad_bytes_per_replica``, ``lane_tx`` (per
   replica, bytes sent at ``open`` and ``final``), ``open_step``,
   ``close_step``, ``final_step``;
+- ``architecture``: the module ``architectures/<name>.py`` the cell's
+  configuration names (``spec.load_architecture``).  Its ``flops`` is the
+  class that counts operations and bytes from ``shapes`` (``is_mine``,
+  ``train_flops_per_token`` and, where the architecture has them, ``gmm_step``
+  and ``flash_step``): ``step_mfu_pct``, ``moe_gmm_roofline`` and
+  ``flash_roofline`` are ONE reader each through it (:func:`arch_flops`);
 - shapes: ``shapes`` (what the cell's architecture file gives:
   ``architectures/<name>.py``, ``shapes(config)``), ``seq``,
   ``rows_per_replica``, ``tokens_per_step_per_replica``, ``chips``,
@@ -46,6 +52,18 @@ def split_for(name: str, moves: str) -> Tuple[Dict[str, str], Callable[[Dict[str
 
     base = spec.load_metric(name, os.path.dirname(os.path.abspath(__file__)))
     return dict(base.META, moves=moves), base.read
+
+
+def arch_flops(sources: Dict[str, Any], method: str) -> Optional[Callable[..., Any]]:
+    """``method`` of the ``flops`` class of the cell's architecture, or None
+    where the sources name no architecture or its class has no such method
+    (an architecture with no experts has no ``gmm_step``)."""
+    return getattr(getattr(sources.get("architecture"), "flops", None), method, None)
+
+
+def chips_per_group(sources: Dict[str, Any]) -> int:
+    """The chips ONE replica group's rows and tokens are shared out over."""
+    return 1 if sources["groups_share_chip"] else sources["chips"] // sources["replicas"]
 
 
 def all_steps(sources: Dict[str, Any]) -> List[Dict[str, Any]]:

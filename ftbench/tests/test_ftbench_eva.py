@@ -123,7 +123,7 @@ def _trace_sources(cell, ops, flight=None):
     return dict(
         trace=dict(per_device={0: dict(ops=ops)}, offset=0.0, traced_steps=[steps]),
         window=[steps], flight=[flight or []], replicas=1, groups_share_chip=False, chips=1,
-        shapes=cell.architecture.shapes(cell.config), seq=SEQ, rows_per_replica=1,
+        architecture=cell.architecture, shapes=cell.architecture.shapes(cell.config), seq=SEQ, rows_per_replica=1,
         tokens_per_step_per_replica=SEQ, device_kind="TPU v5 lite",
     )
 
@@ -149,8 +149,9 @@ def _made_trace(cell, further=True):
     return _trace_sources(cell, ops, [event(2.9, 6.05), event(4.9, 5.95), event(0.5, 99.0)])
 
 
-NEW_READERS = ("eva_flash_ms", "eva_flash_roofline", "xla_mixer_pool_ms", "eva_step_mfu_pct", "eva_multibyte_nll")
-JOINED = ("tokens_per_s_per_chip", "quorum_ms", "commit_vote_ms", "step_device_ms", "device_idle_pct", "peak_hbm_gb",
+# ``eva_step_mfu_pct`` was the fifth until PR 66: the cell is on ``step_mfu_pct``'s list
+NEW_READERS = ("eva_flash_ms", "eva_flash_roofline", "xla_mixer_pool_ms", "eva_multibyte_nll")
+JOINED = ("tokens_per_s_per_chip", "step_mfu_pct", "quorum_ms", "commit_vote_ms", "step_device_ms", "device_idle_pct", "peak_hbm_gb",
           "xla_mixer_proj_ms", "xla_mixer_glue_ms", "xla_ffn_ms", "xla_stream_ms", "xla_head_ms", "xla_layer_scan_ms",
           "optimizer_ms", "step_remat_ms", "xla_unscoped_ms")
 
@@ -163,13 +164,14 @@ def test_kernel_and_counter_readers_on_a_made_trace(cell):
     assert read("eva_flash_roofline") == pytest.approx(flops.roofline_pct(*count.flash_step(s, 1, SEQ), 0.300, "TPU v5 lite")["pct"])
     assert 0 < read("eva_flash_roofline") < 100
     busy = 1.2 + 0.300 + 0.001  # a step's operations, none overlapping
-    assert read("eva_step_mfu_pct") == pytest.approx(100 * SEQ / busy * count.train_flops_per_token(s, SEQ) / 197e12)
-    assert 0 < read("eva_step_mfu_pct") < 100
+    assert read("step_mfu_pct") == pytest.approx(100 * SEQ / busy * count.train_flops_per_token(s, SEQ) / 197e12)
+    assert 0 < read("step_mfu_pct") < 100
+    # no experts and no launch called ``flash_fwd``: the two other folded readers find nothing
+    assert read("moe_gmm_roofline") is None and read("flash_roofline") is None
     # the further slices' mean loss over the window's events (the one before the window is not in it)
     assert read("eva_multibyte_nll") == pytest.approx(6.0) and abs(read("eva_multibyte_nll") - math.log(320)) < 0.5
     # no other architecture's kernel reader matches these names, and theirs find nothing here
-    for theirs in ("flash_fwd_ms", "flash_dq_ms", "flash_dkv_ms", "swa_flash_ms", "moe_gmm_ms", "swa_step_mfu_pct",
-                   "latent_step_mfu_pct", "ssm_step_mfu_pct", "mtp_nll"):
+    for theirs in ("flash_fwd_ms", "flash_dq_ms", "flash_dkv_ms", "swa_flash_ms", "moe_gmm_ms", "mtp_nll"):
         assert read(theirs) is None, theirs
 
 
@@ -229,7 +231,7 @@ def test_reader_meta_is_its_entry_and_it_lists_this_cell_alone(name):
     meta = spec.load_metric(name, BENCH_DIR).META
     assert meta == {k: entry[k] for k in ("source", "layer", "unit", "moves")}
     assert entry["workloads"] == [CELL] and entry["moves"] == "tokens_per_s_per_chip"
-    assert entry["better"] == ("higher" if name in ("eva_flash_roofline", "eva_step_mfu_pct") else "lower")
+    assert entry["better"] == ("higher" if name == "eva_flash_roofline" else "lower")
     assert entry["layer"] == ("kernels" if name.startswith("eva_flash") else "compiled step")
     assert entry["source"] == ("program_counter" if name == "eva_multibyte_nll" else "device_trace")
 
@@ -251,8 +253,7 @@ def test_reader_finds_nothing_on_a_program_without_it(cell, name, monkeypatch):
         assert read(dict(sources, trace=None)) is None
         assert read(dict(sources, flight=[[]])) is None
     # this architecture's shapes over a trace without its kernels and events without the field
-    if name != "eva_step_mfu_pct":
-        assert read(_trace_sources(cell, ops, old_events)) is None
+    assert read(_trace_sources(cell, ops, old_events)) is None
     if name == "eva_multibyte_nll":
         assert read(_made_trace(cell, further=False)) is None
 
@@ -309,7 +310,7 @@ def test_rehearsal_walks_the_cell(trace, expects):
     last = lines[-1]
     assert last["rehearsal"] is True and last["correct"] is True and last["failed"] == 0
     assert (set(last["would_report"]) >= expects) if trace else (set(last["would_report"]) == expects)
-    assert not {"eva_flash_ms", "eva_flash_roofline", "eva_step_mfu_pct", "xla_mixer_pool_ms", "step_device_ms"} & set(last["would_report"])
+    assert not {"eva_flash_ms", "eva_flash_roofline", "step_mfu_pct", "xla_mixer_pool_ms", "step_device_ms"} & set(last["would_report"])
     checks = next(l for l in lines if "checks" in l)
     assert checks["reference_arm"] == "absolute" and checks["token_rms"] < 1e-4 and checks["loss_tie"] <= 2e-5
     assert checks["attention"][0].startswith("plain: ") and checks["params_M"] == pytest.approx(0.3492, abs=1e-3)

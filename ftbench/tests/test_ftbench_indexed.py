@@ -99,7 +99,7 @@ def _trace_sources(cell, ops, flight=None):
     return dict(
         trace=dict(per_device={0: dict(ops=ops)}, offset=0.0, traced_steps=[steps]),
         window=[steps], flight=[flight or []], replicas=1, groups_share_chip=False, chips=1,
-        shapes=cell.architecture.shapes(cell.config), seq=SEQ, rows_per_replica=1,
+        architecture=cell.architecture, shapes=cell.architecture.shapes(cell.config), seq=SEQ, rows_per_replica=1,
         tokens_per_step_per_replica=SEQ, device_kind="TPU v5 lite",
     )
 
@@ -142,7 +142,7 @@ def test_kernel_readers_on_a_made_trace(cell):
     for name, need, seconds in (
         ("dsa_index_roofline", count.index_step(s, 1, SEQ), 0.030),
         ("dsa_attn_roofline", count.attn_step(s, 1, SEQ), 1.050),
-        ("dsa_moe_gmm_roofline", count.gmm_step(s, 18432.0), 0.150),
+        ("moe_gmm_roofline", count.gmm_step(s, 18432.0), 0.150),
     ):
         assert read(name) == pytest.approx(flops.roofline_pct(*need, seconds, "TPU v5 lite")["pct"])
         assert 0 < read(name) < 100
@@ -151,17 +151,17 @@ def test_kernel_readers_on_a_made_trace(cell):
     assert read("moe_rows_here_per_step") == pytest.approx(layers * 18432.0)
     assert read("moe_load_max_over_mean") == pytest.approx(2.0)
     busy = 0.2 + 0.03 + 0.08 + 1.05 + 0.34 + 0.15 + 0.002  # a step's operations, none overlapping
-    assert read("dsa_step_mfu_pct") == pytest.approx(
+    assert read("step_mfu_pct") == pytest.approx(
         100 * SEQ / busy * count.train_flops_per_token(s, SEQ) / 197e12
     )
-    # the readers of another architecture's shapes find nothing here
-    assert read("moe_gmm_roofline") is None and read("ling_step_mfu_pct") is None
+    # no launch called ``flash_fwd`` and no ``flash_step``: the third folded reader finds nothing, nor another architecture's
+    assert read("flash_roofline") is None and read("kda_roofline") is None
 
 
 NEW_READERS = (
     "dsa_index_ms", "dsa_select_ms", "dsa_attn_ms", "dsa_probs_ms", "dsa_index_roofline",
-    "dsa_attn_roofline", "dsa_moe_gmm_roofline", "dsa_step_mfu_pct", "dsa_keys_per_query",
-)
+    "dsa_attn_roofline", "dsa_keys_per_query",
+)  # ``dsa_moe_gmm_roofline`` and ``dsa_step_mfu_pct`` were two more until PR 66: the cell is on the folded readers' lists
 
 
 @pytest.mark.parametrize("name", NEW_READERS)
@@ -196,7 +196,8 @@ def test_the_cell_and_the_lists_it_joined():
     assert len(entry["why"]) <= 200
     listed = {m["name"]: m.get("workloads") for m in bench["end_to_end"] + bench["per_layer"]}
     for name in ("tokens_per_s_per_chip", "quorum_ms", "commit_vote_ms", "step_device_ms", "device_idle_pct",
-                 "peak_hbm_gb", "moe_gmm_ms", "moe_rows_here_per_step", "moe_load_max_over_mean"):
+                 "peak_hbm_gb", "moe_gmm_ms", "moe_rows_here_per_step", "moe_load_max_over_mean",
+                 "step_mfu_pct", "moe_gmm_roofline"):
         assert CELL in listed[name], name
     traffic = spec.load_cell(CELL).traffic
     assert (traffic["replicas"], traffic["seq_len"], traffic["sequences_per_chip"]) == (1, SEQ, 1)

@@ -115,7 +115,7 @@ def _trace_sources(cell, ops, flight=None):
     return dict(
         trace=dict(per_device={0: dict(ops=ops)}, offset=0.0, traced_steps=[steps]),
         window=[steps], flight=[flight or []], replicas=1, groups_share_chip=False, chips=1,
-        shapes=cell.architecture.shapes(cell.config), seq=SEQ, rows_per_replica=1,
+        architecture=cell.architecture, shapes=cell.architecture.shapes(cell.config), seq=SEQ, rows_per_replica=1,
         tokens_per_step_per_replica=SEQ, device_kind="TPU v5 lite",
     )
 
@@ -145,8 +145,10 @@ def _made_trace(cell, looped=True):
     return _trace_sources(cell, ops, [event(3.9, 10.9, 1.15), event(6.9, 10.5, 1.05), event(0.5, 99.0, 9.0)])
 
 
-NEW_READERS = ("loop_step_mfu_pct", "loop_flash_roofline", "xla_loop_gate_ms", "loop_first_pass_nll", "loop_exit_entropy")
-JOINED = ("tokens_per_s_per_chip", "step_device_ms", "device_idle_pct", "peak_hbm_gb", "quorum_ms", "commit_vote_ms",
+# ``loop_step_mfu_pct`` and ``loop_flash_roofline`` were two more until PR 66: the cell is on the lists of
+# ``step_mfu_pct`` and ``flash_roofline``
+NEW_READERS = ("xla_loop_gate_ms", "loop_first_pass_nll", "loop_exit_entropy")
+JOINED = ("tokens_per_s_per_chip", "step_mfu_pct", "flash_roofline", "step_device_ms", "device_idle_pct", "peak_hbm_gb", "quorum_ms", "commit_vote_ms",
           "flash_fwd_ms", "flash_dq_ms", "flash_dkv_ms", "xla_mixer_proj_ms", "xla_mixer_glue_ms", "xla_ffn_ms",
           "xla_stream_ms", "xla_head_ms", "xla_layer_scan_ms", "optimizer_ms", "step_remat_ms", "xla_unscoped_ms")
 
@@ -159,18 +161,18 @@ def test_kernel_and_counter_readers_on_a_made_trace(cell):
     assert read("flash_dkv_ms") == pytest.approx(32 * 16.0)
     count, s = cell.architecture.looped_flops, sources["shapes"]
     kernels = 32 * 0.048
-    assert read("loop_flash_roofline") == pytest.approx(flops.roofline_pct(*count.flash_step(s, 1, SEQ), kernels, "TPU v5 lite")["pct"])
+    assert read("flash_roofline") == pytest.approx(flops.roofline_pct(*count.flash_step(s, 1, SEQ), kernels, "TPU v5 lite")["pct"])
     # recomputed work uncredited: the forward kernel run twice LOWERS the share, it adds no operation
-    assert 0 < read("loop_flash_roofline") < 100
+    assert 0 < read("flash_roofline") < 100
     busy = 1.3 + kernels + 0.001  # a step's operations, none overlapping
-    assert read("loop_step_mfu_pct") == pytest.approx(100 * SEQ / busy * count.train_flops_per_token(s, SEQ) / 197e12)
-    assert 0 < read("loop_step_mfu_pct") < 100
+    assert read("step_mfu_pct") == pytest.approx(100 * SEQ / busy * count.train_flops_per_token(s, SEQ) / 197e12)
+    assert 0 < read("step_mfu_pct") < 100
     # the window's events (the one before the window is not in it)
     assert read("loop_first_pass_nll") == pytest.approx(10.7) and abs(read("loop_first_pass_nll") - math.log(49152)) < 0.5
     assert read("loop_exit_entropy") == pytest.approx(1.1) and 0 < read("loop_exit_entropy") < math.log(4)
     # another architecture's counting finds nothing here
-    for theirs in ("swa_step_mfu_pct", "latent_step_mfu_pct", "ssm_step_mfu_pct", "eva_step_mfu_pct", "gdn_step_mfu_pct",
-                   "gdn_flash_roofline", "eva_multibyte_nll", "mtp_nll", "moe_gmm_ms"):
+    for theirs in ("moe_gmm_roofline", "swa_flash_roofline", "eva_flash_roofline", "gdn_roofline", "eva_multibyte_nll", "mtp_nll",
+                   "moe_gmm_ms"):
         assert read(theirs) is None, theirs
 
 
@@ -233,7 +235,7 @@ def test_reader_meta_is_its_entry_and_it_lists_this_cell_alone(name):
     assert set(entry) == {"name", "unit", "better", "source", "layer", "moves", "workloads"}
     assert entry["workloads"] == [CELL] and entry["moves"] == "tokens_per_s_per_chip"
     assert entry["better"] == ("lower" if name in ("xla_loop_gate_ms", "loop_first_pass_nll") else "higher")
-    assert entry["layer"] == ("kernels" if name == "loop_flash_roofline" else "compiled step")
+    assert entry["layer"] == "compiled step"
     assert entry["source"] == ("program_counter" if name in ("loop_first_pass_nll", "loop_exit_entropy") else "device_trace")
 
 
@@ -281,7 +283,7 @@ def test_the_cell_and_the_lists_it_joined():
     for name, cells in listed.items():
         if cells and CELL in cells:
             assert not name.startswith(("kda_", "mla_", "ling_", "dsa_", "ssd_", "ssm_", "swa_", "moe_", "latent_", "mtp_", "eva_", "gdn_")), name
-            assert name not in ("flash_roofline", "step_mfu_pct", "xla_mixer_pool_ms", "xla_mtp_ms"), name
+            assert name not in ("moe_gmm_roofline", "xla_mixer_pool_ms", "xla_mtp_ms"), name
             assert moved.get(name, "tokens_per_s_per_chip") == "tokens_per_s_per_chip", name
     traffic = spec.load_cell(CELL).traffic
     assert (traffic["replicas"], traffic["seq_len"], traffic["sequences_per_chip"]) == (1, SEQ, 1)
@@ -314,7 +316,7 @@ def test_rehearsal_walks_the_cell(trace, expects):
     last = lines[-1]
     assert last["rehearsal"] is True and last["correct"] is True and last["failed"] == 0
     assert (set(last["would_report"]) >= expects) if trace else (set(last["would_report"]) == expects)
-    assert not {"loop_step_mfu_pct", "loop_flash_roofline", "xla_loop_gate_ms", "flash_fwd_ms", "step_device_ms"} & set(last["would_report"])
+    assert not {"step_mfu_pct", "flash_roofline", "xla_loop_gate_ms", "flash_fwd_ms", "step_device_ms"} & set(last["would_report"])
     checks = next(l for l in lines if "checks" in l)
     assert checks["reference_arm"] == "absolute" and checks["token_rms"] < 1e-4 and checks["loss_tie"] <= 2e-5
     assert checks["attention"][0].startswith("plain: ") and checks["params_M"] == pytest.approx(0.3954, abs=1e-3)

@@ -155,11 +155,16 @@ def test_the_round_trip_is_tiled_and_what_is_left_has_no_name(run):
 
 
 def test_peer_skew_pairs_the_kth_collective_of_a_step(run):
-    skew = program_spans.peer_skew_s(run["spans"])
+    # ``run`` is the per-call path's round trip (a ``tpuft/comm/op`` a collective): by that name
+    skew = program_spans.peer_skew_s(run["spans"], "tpuft/comm/op")
     assert [step for step, _ in skew] == [5, 6]
     assert all(s == pytest.approx(0.035) for _, s in skew)
     only_one = program_spans.of_replica(run["spans"], 0)
-    assert program_spans.peer_skew_s(only_one) == []
+    assert program_spans.peer_skew_s(only_one, "tpuft/comm/op") == []
+    # by default the ONE span a round trip has since PR 60 (``python -m ftbench.program_spans``'s table)
+    assert program_spans.peer_skew_s(run["spans"]) == []
+    sessions = [dict(s, name="tpuft/comm/session") for s in run["spans"] if s["name"] == "tpuft/comm/op" and s["k"] == 0]
+    assert [round(skew, 6) for _, skew in program_spans.peer_skew_s(sessions)] == [0.010, 0.010]
 
 
 def test_idle_seconds_go_to_one_leaf_span_each(run):
@@ -181,27 +186,45 @@ def test_idle_seconds_go_to_one_leaf_span_each(run):
     assert program_spans.SYNC not in table and "tpuft/step/grad" not in table
 
 
+# ``comm_op_ms`` (140.0) and ``ring_peer_skew_ms`` (35.0) were two more until PR 66 retired them: they read
+# ``tpuft/comm/op``, which no trace holds since a round trip's rings are ONE call (PR 60)
 READINGS = {
     "sync_host_ms": 600.0, "sync_unnamed_ms": 13.0, "sync_plan_ms": 20.0, "d2h_wait_ms": 200.0,
-    "bucket_copy_ms": 200.0, "h2d_restore_ms": 72.0, "comm_op_ms": 140.0, "ring_peer_skew_ms": 35.0,
+    "bucket_copy_ms": 200.0, "h2d_restore_ms": 72.0,
 }
 
-# PR 43, the four-chip cell ``mistral7b-hsdp2x2-steady``.  These thirteen
-# readers stand ONCE and list both two-group cells (PR 58; PR 43 to 57 the
+# PR 43, the four-chip cell ``mistral7b-hsdp2x2-steady``.  These nine
+# readers (thirteen until PR 66) stand ONCE and list both two-group cells (PR 58; PR 43 to 57 the
 # four-chip cell read each under a twin ``<name>.hsdp``, because tier-1 then
 # held these lists to one cell): a reader reads replica or group 0 in whichever
 # cell it runs
 HSDP_CELL = "mistral7b-hsdp2x2-steady"
 TWO_GROUP_CELLS = ("mistral7b-ddp2-steady", HSDP_CELL)
-TWO_GROUP_READERS = tuple(sorted(READINGS)) + (
-    "sync_normalize_ms", "bucket_warm_pct", "sync_first_submit_ms", "ring_beside_d2h_pct", "normalize_in_ring_pct",
-)
+TWO_GROUP_READERS = tuple(sorted(READINGS)) + ("bucket_warm_pct", "sync_first_submit_ms", "normalize_in_ring_pct")
+# Two more read the per-call path's spans (``tpuft/manager/normalize``, ``tpuft/comm/op``), which no cell runs since
+# PR 60 and no trace of a session holds.  PR 66 took their ENTRIES out (the driver's check refuses an entry that
+# reads nothing in a cell it lists); their FILES stay, because tier-1's ``tests/test_ftbench_program_spans.py``
+# loads both by name and a ``benchmark`` PR edits nothing there (``test_ftbench_spec.FILES_WITHOUT_AN_ENTRY``)
+PER_CALL_READERS = ("sync_normalize_ms", "ring_beside_d2h_pct")
 # the one reader of the four-chip cell alone (a group of one chip has no shard
 # to write): it keeps the suffix, which two tests under ``tests/`` load it by
 HSDP_ONLY = "d2h_direct_pct.hsdp"
 # what PR 58 retired: the thirteen twins, and two readers that told nothing more
 # (README.md, "On four chips"; PERF.md section 6, PR 58)
-RETIRED = tuple(name + ".hsdp" for name in TWO_GROUP_READERS) + ("ring_ms", "ring_average_ms")
+RETIRED = tuple(name + ".hsdp" for name in TWO_GROUP_READERS + PER_CALL_READERS) + ("ring_ms", "ring_average_ms") + (
+    # PR 66: the two readers of ``tpuft/comm/op``, and the twins they once had
+    "comm_op_ms", "ring_peer_skew_ms", "comm_op_ms.hsdp", "ring_peer_skew_ms.hsdp",
+)
+# ``normalize_in_ring_pct`` counts a session's pieces (``tpuft/comm/session``, ``pieces=``) and so reads 100 in both
+# cells on the chip, but only inside a device trace's stretch: tier-1 holds its ENTRY to list ``mistral7b-ddp2-steady``
+# (``tests/test_ftbench_program_spans.py``) AND that a traced CPU walk of either cell does not report it
+# (``tests/_ftbench_view.py`` ``SILENT_IN_A_SESSION``), and a ``benchmark`` PR edits nothing there (PERF.md section 7)
+SILENT_ON_A_CPU_WALK = frozenset(("normalize_in_ring_pct",))
+# what a CPU walk at toy widths cannot give for a reason of its own
+NOT_ON_A_CPU_WALK = {
+    "peak_hbm_gb.ddp": "the CPU's devices have no memory_stats",
+    "sync_second_submit_ms": "the toy tree is one bucket: its round trip has one submit",
+}
 # the lists the cell joined itself: those whose readers find something to read
 # on the CPU, and those that need a device plane or ``memory_stats``
 HSDP_JOINED_ON_THE_HOST = {
@@ -315,8 +338,9 @@ def test_the_chips_small_trace_has_no_program_span():
 def test_new_readers_are_the_eighteen_benchmark_json_lists():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
+    # sixteen since PR 66 retired ``comm_op_ms`` and ``ring_peer_skew_ms`` (the name is the one tier-1 calls it by)
     new = set(READINGS) | set(KILL_READINGS) | {"flash_fwd_ms", "flash_dq_ms", "flash_dkv_ms"}
-    assert len(new) == 18
+    assert len(new) == 16
     # found by name: a later PR appends its own readers after them
     listed = {m["name"]: m for m in bench["per_layer"]}
     assert new <= set(listed) and len(listed) == len(bench["per_layer"]) >= 18
@@ -364,9 +388,9 @@ def _rehearse(cell, root):
         ("mistral7b-ddp2-kill", set(KILL_READINGS)),
         # the flash kernels do not run on the CPU, and it has no device plane
         ("mistral7b-ws1-steady", set()),
-        # PR 43: two groups of two chips; the thirteen readers read group 0's
+        # PR 43: two groups of two chips; the two-group readers read group 0's
         # spans under their own names (PR 58), and the cell's own reader beside them
-        (HSDP_CELL, set(TWO_GROUP_READERS) | {HSDP_ONLY} | HSDP_JOINED_ON_THE_HOST),
+        (HSDP_CELL, set(TWO_GROUP_READERS) - SILENT_ON_A_CPU_WALK - set(NOT_ON_A_CPU_WALK) | {HSDP_ONLY} | HSDP_JOINED_ON_THE_HOST),
     ],
 )
 def test_rehearsal_would_report_the_program_span_metrics(cell, new, tmp_path):
@@ -380,14 +404,30 @@ def test_rehearsal_would_report_the_program_span_metrics(cell, new, tmp_path):
         mine = program_spans.of_replica(spans, 0)
         assert {s["name"] for s in mine} >= {
             "tpuft/step/grad", "tpuft/step/update", "tpuft/manager/quorum", "tpuft/manager/fence",
-            "tpuft/manager/should_commit", "tpuft/manager/normalize", "tpuft/comm/op",
+            "tpuft/manager/should_commit", "tpuft/comm/session",
             "tpuft/ddp/allreduce_pytree", "tpuft/ddp/plan", "tpuft/ddp/d2h", "tpuft/ddp/pack",
             "tpuft/ddp/submit", "tpuft/ddp/ring_wait", "tpuft/ddp/h2d",
         }
+        # a round trip's rings are ONE call of the op thread (PR 60): a span that has ended cannot be annotated
+        assert not {s["name"] for s in mine} & {"tpuft/comm/op", "tpuft/manager/normalize"}
         assert all(isinstance(s.get("step"), int) for s in mine)
         assert program_spans.of_replica(spans, 1)
         trips = program_spans.sync_round_trips(dict(trace=None), spans=spans)
         assert trips and all(0.0 <= unnamed <= whole for whole, unnamed in trips)
+
+
+@pytest.mark.parametrize("cell", TWO_GROUP_CELLS)
+def test_no_host_reader_lists_a_two_group_cell_it_reads_nothing_in(cell, tmp_path):
+    """PR 66 (iii): of the readers that list the cell and do not read the
+    device's trace, a traced walk leaves out EXACTLY the two a CPU at toy widths
+    cannot give and the one that reads a session inside a device's stretch
+    alone; none else lists a cell in which it finds nothing (five did for five
+    checks running, and none of the five does now: PERF.md section 6, PR 66)."""
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        listed = {m["name"] for m in json.load(f)["per_layer"] if cell in m["workloads"] and m["source"] != "device_trace"}
+    assert SILENT_ON_A_CPU_WALK <= listed and not ({"comm_op_ms", "ring_peer_skew_ms"} | set(PER_CALL_READERS)) & listed
+    silent = listed - _rehearse(cell, str(tmp_path))
+    assert silent == (set(NOT_ON_A_CPU_WALK) | SILENT_ON_A_CPU_WALK) & listed, sorted(silent)
 
 
 # ----------------------------------------------------------------------
@@ -454,7 +494,7 @@ TWO_GROUP_READINGS = dict(
 )
 
 
-@pytest.mark.parametrize("name", TWO_GROUP_READERS)
+@pytest.mark.parametrize("name", TWO_GROUP_READERS + PER_CALL_READERS)
 def test_a_two_group_reader_reads_group_0_beside_a_whole_group_1(run, name, monkeypatch):
     """What the fold rests on: in the four-chip cell ONE process records both
     groups' whole round trips (``run`` has group 1's collectives alone), each
@@ -481,6 +521,66 @@ def test_a_two_group_reader_reads_group_0_beside_a_whole_group_1(run, name, monk
     )
     read = spec.load_metric(name, BENCH_DIR).read
     assert read(sources) == pytest.approx(TWO_GROUP_READINGS[name], abs=1e-6)
+
+
+def _sessions(run, pieces):
+    """``run``'s spans as a session leaves them: ONE ``tpuft/comm/session`` a
+    round trip where the op thread's first ``tpuft/comm/op`` stood, ``pieces``
+    on it as the profiler hands an annotation's argument back, and neither
+    ``tpuft/comm/op`` nor ``tpuft/manager/normalize``."""
+    out = []
+    for s in run["spans"]:
+        if s["name"] == "tpuft/comm/op" and s["k"] == 0:
+            out.append(dict(s, name="tpuft/comm/session", pieces=pieces))
+        elif s["name"] not in ("tpuft/comm/op", "tpuft/manager/normalize"):
+            out.append(s)
+    return out
+
+
+@pytest.mark.parametrize(
+    "pieces,per_call,expects",
+    [
+        # both two-group cells since PR 60: every bucket a piece of the round trip's one session
+        (62, [], 100.0),
+        # as the profiler may hand the argument back
+        ("62", [], 100.0),
+        # a quantized call beside the sessions (in_ring=0: its callback divided the sum): 124 of 125
+        (62, [0], 100.0 * 124 / 125),
+        # and one whose ring divided, on the per-call path
+        (2, [1, 0], 100.0 * 5 / 6),
+    ],
+    ids=["sessions", "strings", "one_quantized_call", "both_paths"],
+)
+def test_normalize_in_ring_pct_counts_a_sessions_pieces(run, pieces, per_call, expects, monkeypatch):
+    """PR 66: a session is opened with the divisor and in no other way
+    (``Manager.ring_session``), so each of its pieces is a collective whose
+    average the ring made; replica 1's sessions do not count."""
+    spans = _sessions(run, pieces)
+    assert len([s for s in spans if s["name"] == "tpuft/comm/session" and s["r"] == R0]) == 2
+    (at,) = {s["start"] for s in spans if s["name"] == "tpuft/comm/session" and s["r"] == R0 and s["step"] == 5}
+    spans += [
+        dict(name="tpuft/manager/normalize", start=at + 0.3 + 0.01 * i, end=at + 0.301 + 0.01 * i, r=R0, step=5,
+             line=("/host:CPU", "op0"), in_ring=flag)
+        for i, flag in enumerate(per_call)
+    ]
+    monkeypatch.setattr(program_spans, "load", lambda bench_dir=None: sorted(spans, key=lambda s: s["start"]))
+    read = spec.load_metric("normalize_in_ring_pct", BENCH_DIR).read
+    assert read(run["sources"]) == pytest.approx(expects)
+
+
+def test_normalize_in_ring_pct_reads_a_session_inside_a_devices_stretch_alone(run, monkeypatch):
+    """A trace with no device plane (the CPU rehearsal: ``trace`` is None) has
+    no stretch, and under a session the reader finds nothing there: what
+    tier-1's ``SILENT_IN_A_SESSION`` holds of a walk.  The per-call path's
+    spans read with and without one, as they did."""
+    spans = _sessions(run, 62)
+    monkeypatch.setattr(program_spans, "load", lambda bench_dir=None: spans)
+    read = spec.load_metric("normalize_in_ring_pct", BENCH_DIR).read
+    assert read(run["sources"]) == 100.0
+    assert read(dict(run["sources"], trace=None)) is None
+    flagged = [dict(s, in_ring=1) if s["name"] == "tpuft/manager/normalize" else s for s in run["spans"]]
+    monkeypatch.setattr(program_spans, "load", lambda bench_dir=None: flagged)
+    assert read(run["sources"]) == read(dict(run["sources"], trace=None)) == 100.0
 
 
 @pytest.mark.parametrize("name", RETIRED)
@@ -661,7 +761,16 @@ def test_sharded_leaves_of_two_groups_of_fsdp_2_through_allreduce_pytree(two_gro
         # a life's first round trip fills fresh memory, every later one the kept buckets
         assert [e["warm_buckets"] for e in syncs] == [0, buckets, buckets]
         assert [e["buckets"] for e in syncs] == [buckets] * 3
-    normalize = [s for s in obs_spans.snapshot() if s["name"] == "tpuft/manager/normalize"]
-    # one span a collective: two groups, three steps, ``buckets`` rings each
-    assert len(normalize) == 2 * 3 * buckets
-    assert all(s["attrs"]["in_ring"] == 1 for s in normalize)
+    spans = obs_spans.snapshot()
+    normalize = [s for s in spans if s["name"] == "tpuft/manager/normalize"]
+    sessions = [s for s in spans if s["name"] == "tpuft/comm/session"]
+    if sessions:
+        # a round trip's rings are ONE call of the op thread (PR 60): a session a group and step over the
+        # step's buckets, the ring divides inside it and no done-callback runs a bucket
+        assert len(sessions) == 2 * 3 and not normalize
+        assert all(s["attrs"]["pieces"] == buckets for s in sessions)
+    else:
+        # the per-call path (tier-1 holds it by taking ``Manager.ring_session`` away): one span a collective,
+        # two groups, three steps, ``buckets`` rings each
+        assert len(normalize) == 2 * 3 * buckets
+        assert all(s["attrs"]["in_ring"] == 1 for s in normalize)

@@ -107,7 +107,7 @@ def _trace_sources(cell, ops, flight=None):
     return dict(
         trace=dict(per_device={0: dict(ops=ops)}, offset=0.0, traced_steps=[steps]),
         window=[steps], flight=[flight or []], replicas=1, groups_share_chip=False, chips=1,
-        shapes=cell.architecture.shapes(cell.config), seq=SEQ, rows_per_replica=1,
+        architecture=cell.architecture, shapes=cell.architecture.shapes(cell.config), seq=SEQ, rows_per_replica=1,
         tokens_per_step_per_replica=SEQ, device_kind="TPU v5 lite",
     )
 
@@ -135,9 +135,11 @@ def _made_trace(cell, mine=True):
     return _trace_sources(cell, ops, [event(3.9, -1.6), event(6.9, -1.9), event(0.5, -99.0)])
 
 
-NEW_READERS = ("selscan_fwd_ms", "selscan_bwd_ms", "selscan_roofline", "sambay_flash_roofline", "sambay_step_mfu_pct", "selscan_decay_min",
+# ``sambay_step_mfu_pct`` was the eighth until PR 66: the cell is on ``step_mfu_pct``'s list (and on no list of
+# ``flash_roofline``: ``sambay_flash_roofline`` counts the six launches of a step, the windowed ones too)
+NEW_READERS = ("selscan_fwd_ms", "selscan_bwd_ms", "selscan_roofline", "sambay_flash_roofline", "selscan_decay_min",
                "xla_mixer_diff_ms")
-JOINED = ("tokens_per_s_per_chip", "step_device_ms", "device_idle_pct", "peak_hbm_gb", "quorum_ms", "commit_vote_ms",
+JOINED = ("tokens_per_s_per_chip", "step_mfu_pct", "step_device_ms", "device_idle_pct", "peak_hbm_gb", "quorum_ms", "commit_vote_ms",
           "flash_fwd_ms", "flash_dq_ms", "flash_dkv_ms", "xla_mixer_proj_ms", "xla_mixer_glue_ms", "xla_ffn_ms",
           "xla_stream_ms", "xla_head_ms", "xla_layer_scan_ms", "optimizer_ms", "step_remat_ms", "xla_unscoped_ms")
 
@@ -156,11 +158,10 @@ def test_kernel_and_counter_readers_on_a_made_trace(cell):
     assert read("sambay_flash_roofline") == pytest.approx(flops.roofline_pct(*count.flash_step(s, 1, SEQ), 0.188, "TPU v5 lite")["pct"])
     assert 0 < read("selscan_roofline") < 100 and 0 < read("sambay_flash_roofline") < 100
     busy = 1.0 + 0.120 + 0.188 + 0.001  # a step's operations, none overlapping
-    assert read("sambay_step_mfu_pct") == pytest.approx(100 * SEQ / busy * count.train_flops_per_token(s, SEQ) / 197e12)
-    assert 0 < read("sambay_step_mfu_pct") < 100
+    assert read("step_mfu_pct") == pytest.approx(100 * SEQ / busy * count.train_flops_per_token(s, SEQ) / 197e12)
+    assert 0 < read("step_mfu_pct") < 100
     assert read("selscan_decay_min") == -1.9  # the window's events: the one before the window is not in it
-    for theirs in ("swa_step_mfu_pct", "swa_flash_roofline", "ssm_step_mfu_pct", "ssd_fwd_ms", "gdn_roofline",
-                   "loop_step_mfu_pct", "loop_flash_roofline", "moe_gmm_ms"):
+    for theirs in ("swa_flash_roofline", "ssd_fwd_ms", "gdn_roofline", "moe_gmm_roofline", "moe_gmm_ms"):
         assert read(theirs) is None, theirs
 
 
@@ -203,7 +204,7 @@ def test_reader_meta_is_its_entry_and_it_lists_this_cell(name):
     assert set(entry) == {"name", "unit", "better", "source", "layer", "moves", "workloads"}
     assert CELL in entry["workloads"] and entry["moves"] == "tokens_per_s_per_chip"
     assert entry["better"] == ("lower" if name in ("selscan_fwd_ms", "selscan_bwd_ms", "selscan_decay_min", "xla_mixer_diff_ms") else "higher")
-    assert entry["layer"] == ("compiled step" if name in ("sambay_step_mfu_pct", "xla_mixer_diff_ms") else "kernels")
+    assert entry["layer"] == ("compiled step" if name == "xla_mixer_diff_ms" else "kernels")
     assert entry["source"] == ("program_counter" if name == "selscan_decay_min" else "device_trace")
 
 
@@ -248,7 +249,7 @@ def test_the_cell_and_the_lists_it_joined():
     for name, cells in listed.items():
         if cells and CELL in cells:
             assert not name.startswith(("kda_", "mla_", "ling_", "dsa_", "ssd_", "ssm_", "swa_", "moe_", "latent_", "mtp_", "eva_", "gdn_", "loop_")), name
-            assert name not in ("flash_roofline", "step_mfu_pct", "xla_mixer_pool_ms", "xla_mtp_ms", "xla_loop_gate_ms"), name
+            assert name not in ("flash_roofline", "moe_gmm_roofline", "xla_mixer_pool_ms", "xla_mtp_ms", "xla_loop_gate_ms"), name
             assert moved.get(name, "tokens_per_s_per_chip") == "tokens_per_s_per_chip", name
     traffic = spec.load_cell(CELL).traffic
     assert (traffic["replicas"], traffic["seq_len"], traffic["sequences_per_chip"]) == (1, SEQ, 1)
@@ -291,7 +292,7 @@ def test_rehearsal_walks_the_cell(trace, expects):
     last = lines[-1]
     assert last["rehearsal"] is True and last["correct"] is True and last["failed"] == 0
     assert (set(last["would_report"]) >= expects) if trace else (set(last["would_report"]) == expects)
-    assert not {"selscan_fwd_ms", "selscan_roofline", "sambay_step_mfu_pct", "flash_fwd_ms", "step_device_ms"} & set(last["would_report"])
+    assert not {"selscan_fwd_ms", "selscan_roofline", "step_mfu_pct", "flash_fwd_ms", "step_device_ms"} & set(last["would_report"])
     checks = next(l for l in lines if "checks" in l)
     assert checks["reference_arm"] == "absolute" and checks["token_rms"] < 1e-4 and checks["loss_tie"] <= 2e-5
     assert checks["attention"][0].startswith("plain: ") and checks["params_M"] == pytest.approx(0.3857, abs=1e-3)

@@ -49,12 +49,32 @@ def test_reader_file_says_what_benchmark_json_says(metric):
         assert module.META[key] == metric[key], (metric["name"], key)
 
 
+# Two readers of the per-call path's spans, which no cell runs since PR 60: their entries went with PR 66 (an
+# entry that reads nothing is refused) and their files stay, because tier-1's ``tests/test_ftbench_program_spans.py``
+# loads both by name and a ``benchmark`` PR edits nothing under ``tests/``.  With those cases the files go and
+# this set is empty again (PERF.md section 7 (cn))
+FILES_WITHOUT_AN_ENTRY = frozenset(("sync_normalize_ms", "ring_beside_d2h_pct"))
+
+
 def test_every_reader_file_is_an_entry():
     """The other way round: a reader's file that no entry names is read by
-    no run (a retired entry takes its file with it: PR 58)."""
+    no run (a retired entry takes its file with it: PR 58), but the two that
+    tier-1 loads by name."""
     files = {f[:-3] for f in os.listdir(os.path.join(ROOT, "ftbench", "layer_metrics"))
              if f.endswith(".py") and not f.startswith("_")}
-    assert files == {m["name"] for m in BENCH["per_layer"]}
+    entries = {m["name"] for m in BENCH["per_layer"]}
+    assert files - entries == FILES_WITHOUT_AN_ENTRY and entries <= files
+
+
+@pytest.mark.parametrize("name", sorted(FILES_WITHOUT_AN_ENTRY))
+def test_a_file_without_an_entry_is_read_by_no_cell(name):
+    """No entry, so no cell's ``per_layer`` holds it and no run loads it; the
+    file still loads and says where its number would come from."""
+    assert all(m["name"] != name for m in BENCH["end_to_end"] + BENCH["per_layer"])
+    assert all(m["name"] != name for cell in CELLS for m in spec.load_cell(cell).per_layer)
+    module = spec.load_metric(name, os.path.join(ROOT, "ftbench"))
+    assert set(module.META) == {"source", "layer", "unit", "moves"} and callable(module.read)
+    assert "NO ENTRY" in module.__doc__
 
 
 def test_contract_limits():

@@ -30,7 +30,7 @@ planes { id: 1 name: "/device:TPU:0"
     events { metadata_id: 4 offset_ps: 100000000000 duration_ps: 30000000000 }
     events { metadata_id: 5 offset_ps: 185000000000 duration_ps: 10000000000 } }
   event_metadata { key: 1 value { id: 1 name: "fusion.1" } }
-  event_metadata { key: 2 value { id: 2 name: "%closed_call.84 = bf16[1,32,2048,128] custom-call(bf16[1,32,2048,128] %p), custom_call_target=tpu_custom_call" } }
+  event_metadata { key: 2 value { id: 2 name: "%flash_fwd.84 = bf16[1,32,2048,128] custom-call(bf16[1,32,2048,128] %p), custom_call_target=tpu_custom_call" } }
   event_metadata { key: 3 value { id: 3 name: "fusion.9" } }
   event_metadata { key: 4 value { id: 4 name: "jit__step(123)" } }
   event_metadata { key: 5 value { id: 5 name: "jit__update(456)" } } }
@@ -88,9 +88,12 @@ def _sources(space, **more):
     of one replica, host clock = trace clock less 1 s."""
     steps = [dict(t_enter=0.0, t_exit=0.1, committed=True), dict(t_enter=0.1, t_exit=0.2, committed=True)]
     shapes = dict(dim=4096, n_layers=1, n_heads=32, n_kv_heads=8, ffn_hidden=14336, vocab_size=32768)
+    from ftbench import spec
+
+    llama = spec.load_architecture("llama", os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
     return dict(
         trace=dict(per_device=trace_reduce.summarize(space), offset=1.0, traced_steps=[steps]),
-        replicas=1, groups_share_chip=False, chips=1, shapes=shapes, seq=2048,
+        replicas=1, groups_share_chip=False, chips=1, architecture=llama, shapes=shapes, seq=2048,
         rows_per_replica=1, tokens_per_step_per_replica=2048, device_kind="TPU v5 lite", **more,
     )
 
