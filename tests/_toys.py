@@ -272,6 +272,7 @@ TOYS = {
     "eva": ("eva", "Eva", "eva_debug", 64),
     "gated_delta_moe": ("gated_delta_moe", "GatedDeltaMoE", "gated_delta_debug", 64),
     "looped": ("looped", "Looped", "looped_debug", 64),
+    "prerouted_moe": ("prerouted_moe", "PreroutedMoE", "prerouted_moe_debug", 64),
     "sambay": ("sambay", "SambaY", "sambay_debug", 64),
     "ssm_hybrid_moe": ("ssm_hybrid_moe", "SsmHybridMoE", "ssm_hybrid_debug", 64),
 }

@@ -1,5 +1,5 @@
 """The parts of the compiled step (``torchft_tpu/obs/spans.py``,
-``DEVICE_PARTS``): every operation that costs device time in the ten models'
+``DEVICE_PARTS``): every operation that costs device time in the eleven models'
 two step programs is traced under a ``tpuft.<part>`` scope, at toy widths and
 on both paths (plain, and the kernels in interpret mode).  The paths are read
 from the COMPILED text's ``op_name``s: XLA inlines every private function
@@ -16,14 +16,15 @@ from torchft_tpu.obs.spans import DEVICE_PARTS, PART_PREFIX, part
 
 from tests._toys import toy, lowered_grad_step
 
-MODELS = ("llama", "ling_hybrid", "indexed_sparse_moe", "ssm_hybrid_moe", "windowed_moe", "latent_moe", "eva", "gated_delta_moe", "looped", "sambay")
+MODELS = ("llama", "ling_hybrid", "indexed_sparse_moe", "ssm_hybrid_moe", "windowed_moe", "latent_moe", "eva", "gated_delta_moe", "looped", "sambay", "prerouted_moe")
 CASES = [(m, p) for m in MODELS for p in ("plain", "kernels")]
 EVERY = set(DEVICE_PARTS)
 # Keye has no dense MLP and no shared expert; Mistral has no experts; the
 # prediction module's own work is ``mtp``, and JoyAI's is the one model here
 # that runs the module (Ling's toy preset builds none); EvaByte's is the one
 # mixer that pools, the looped model's the one exit gate, and SambaY's the one
-# model that subtracts two softmaxes
+# model that subtracts two softmaxes; SmallThinker, like Keye, has no dense MLP
+# and no shared expert
 USES = {
     "llama": EVERY - {"experts_route", "experts_dispatch", "mtp", "mixer_pool", "loop_gate", "mixer_diff"},
     "ling_hybrid": EVERY - {"mtp", "mixer_pool", "loop_gate", "mixer_diff"},
@@ -35,6 +36,7 @@ USES = {
     "gated_delta_moe": EVERY - {"mtp", "mixer_pool", "loop_gate", "mixer_diff"},
     "looped": EVERY - {"experts_route", "experts_dispatch", "mtp", "mixer_pool", "mixer_diff"},
     "sambay": EVERY - {"experts_route", "experts_dispatch", "mtp", "mixer_pool", "loop_gate"},
+    "prerouted_moe": EVERY - {"ffn", "mtp", "mixer_pool", "loop_gate", "mixer_diff"},
 }
 # what costs time on a device and is never fused away into a neighbour
 HELD = ("dot", "convolution", "gather", "scatter", "sort")

@@ -269,11 +269,17 @@ def test_the_state_space_scan_is_model_code_over_kernels(module: str, row: str, 
             "models.windowed_moe", "compiled-step-models",
             {"ops.flash_attention", "parallel.moe", "models.decoder", "obs.spans"},
         ),
+        # PR 67's model: the same kernels at another window and a GQA group of seven, ``RoutedExperts`` told where
+        # its router reads; no sibling model
+        (
+            "models.prerouted_moe", "compiled-step-models",
+            {"ops.flash_attention", "parallel.moe", "models.decoder", "obs.spans"},
+        ),
     ],
 )
 def test_windowed_attention_is_model_code_over_the_flash_kernels(module: str, row: str, may_import: set) -> None:
     """PR 41's module and the kernels it made take a window: the kernels in
-    the kernels' row, importing nothing of the package; the model in the
+    the kernels' row, importing nothing of the package; the models in the
     models', importing kernels and model code and nothing of the Manager."""
     assert [ROWS[i][0] for i in _rows_of(module)] == [row]
     assert {target for importer, _line, target in _inner_edges() if importer == module} == may_import

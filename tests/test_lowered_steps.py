@@ -52,7 +52,11 @@ another in a worker that had traced other files first.  PR 65 wrote ONE anew
 (``--write --only indexed_sparse_moe``, whose ``plain`` digest came out the
 same: Keye's ``dsa_attn_fwd`` takes the bits keys-major and its tile lies
 keys-major, its second output is ``[B, H, S]``, ``ops/indexed_attention.py``)
-and none of the other seventeen changed.  A later change that
+and none of the other seventeen changed.  PR 67 ADDED
+``prerouted_moe``'s two (``--write --only prerouted_moe``: ``RoutedExperts.apply``
+took ``route_from`` and ``expert_form`` a third value, "reglu") and changed none
+of the eighteen: with ``route_from`` None the six expert models' programs are
+the parent's to the letter.  A later change that
 means to alter one of these programs writes the fixture anew and says so:
 ``python tests/test_lowered_steps.py --write``."""
 
@@ -65,7 +69,7 @@ import sys
 import pytest
 
 FIXTURE = os.path.join(os.path.dirname(os.path.abspath(__file__)), "fixtures", "lowered_steps.json")
-MODELS = ("ling_hybrid", "indexed_sparse_moe", "llama", "ssm_hybrid_moe", "windowed_moe", "eva", "gated_delta_moe", "looped", "sambay")
+MODELS = ("ling_hybrid", "indexed_sparse_moe", "llama", "ssm_hybrid_moe", "windowed_moe", "eva", "gated_delta_moe", "looped", "sambay", "prerouted_moe")
 CASES = [(m, p) for m in MODELS for p in ("plain", "kernels")]
 
 

@@ -202,8 +202,9 @@ class RoutedExpertsConfig:
     gated_shared: bool = False
     balance_loss_weight: float = 0.0  # sequence-wise, arXiv:2412.19437 eq. 17-20
     # an expert's form, the routed experts' and the shared one's alike:
-    # "swiglu", three matrices, ``w_down (silu(w_gate x) * (w_up x))``, or
-    # "relu2", two and no gate, ``w_down relu(w_up x)^2``
+    # "swiglu", three matrices, ``w_down (silu(w_gate x) * (w_up x))``,
+    # "reglu", the same three with a ReLU for the SiLU, ``w_down (relu(w_gate
+    # x) * (w_up x))``, or "relu2", two and no gate, ``w_down relu(w_up x)^2``
     expert_form: str = "swiglu"
     dtype: Any = jnp.bfloat16
 
@@ -216,6 +217,12 @@ def swiglu(gate: jax.Array, up: jax.Array, limit: float) -> jax.Array:
         gate = jnp.minimum(gate, limit)
         up = jnp.clip(up, -limit, limit)
     return jax.nn.silu(gate) * up
+
+
+def reglu(gate: jax.Array, up: jax.Array) -> jax.Array:
+    """``relu(gate) * up``: a gated expert whose gate is a ReLU, so that a
+    hidden unit is exactly off for most tokens (SmallThinker's sparse experts)."""
+    return jax.nn.relu(gate) * up
 
 
 def relu2(up: jax.Array) -> jax.Array:
@@ -397,8 +404,8 @@ class RoutedExperts:
     best experts inside them; the weights are the UNBIASED scores of the
     chosen, normalised over all ``top_k`` and scaled.  Nothing is dropped: the (token, choice) pairs
     that fall on held experts are sorted by expert into a static buffer and
-    go through the experts' grouped products (``expert_form``: a SwiGLU of
-    three matrices or a squared ReLU of two), whose work follows the rows
+    go through the experts' grouped products (``expert_form``: a SwiGLU or a
+    ReGLU of three matrices or a squared ReLU of two), whose work follows the rows
     really routed here (``megablox.gmm`` on the TPU: ``path`` "gmm"; ``lax.ragged_dot``
     elsewhere).  What the absent experts would have added is left out; the
     shared expert is added once, behind ``sigmoid(x . shared_sigmoid)`` a token
@@ -421,8 +428,8 @@ class RoutedExperts:
             raise ValueError("num_experts must divide into n_group groups")
         if config.score_func not in ("sigmoid", "softmax"):
             raise ValueError(f"score_func {config.score_func!r} is neither sigmoid nor softmax")
-        if config.expert_form not in ("swiglu", "relu2"):
-            raise ValueError(f"expert_form {config.expert_form!r} is neither swiglu nor relu2")
+        if config.expert_form not in ("swiglu", "reglu", "relu2"):
+            raise ValueError(f"expert_form {config.expert_form!r} is none of swiglu, reglu and relu2")
         if config.gated_shared and not config.shared_hidden:
             raise ValueError("gated_shared gates a shared expert, and shared_hidden is 0")
         first, count = config.experts_held
@@ -431,7 +438,7 @@ class RoutedExperts:
         # set when the experts are traced: "gmm" or "ragged_dot"
         self.path: Optional[str] = None
         # an expert's matrices in the order they are applied, and the shared one's
-        gate = ("gate",) if config.expert_form == "swiglu" else ()
+        gate = () if config.expert_form == "relu2" else ("gate",)
         self.expert_leaves = tuple(f"w_{n}" for n in (*gate, "up", "down"))
         self.shared_leaves = tuple(f"shared_{n}" for n in (*gate, "up", "down")) if config.shared_hidden else ()
 
@@ -515,11 +522,12 @@ class RoutedExperts:
     def _activate(self, hidden: List[jax.Array], limit: float) -> jax.Array:
         """An expert's hidden activation from its matrices' products, by
         ``expert_form``: ``[gate, up]`` or ``[up]``."""
-        if self.config.expert_form == "swiglu":
+        form = self.config.expert_form
+        if form == "swiglu":
             return swiglu(*hidden, limit)
         if limit:
-            raise ValueError("a SwiGLU clamp was given to experts that have no gate")
-        return relu2(*hidden)
+            raise ValueError("a SwiGLU clamp was given to experts " + ("whose gate is a ReLU" if form == "reglu" else "that have no gate"))
+        return reglu(*hidden) if form == "reglu" else relu2(*hidden)
 
     def _through(
         self, size: int, limit: float, at: jax.Array, into: jax.Array,
@@ -629,6 +637,7 @@ class RoutedExperts:
     def apply(
         self, params: Dict[str, Any], x: jax.Array,
         swiglu_limit: float = 0.0, shared_swiglu_limit: float = 0.0,
+        route_from: Optional[jax.Array] = None,
     ) -> Tuple[jax.Array, jax.Array, jax.Array]:
         """x [B, S, D] → (this chip's part of the layer [B, S, D], the
         tokens every one of the ``num_experts`` was chosen by [E] float32,
@@ -636,12 +645,16 @@ class RoutedExperts:
         is given and the experts read it in the matrices' dtype: a caller
         whose residual stream is float32 hands over float32, so that the
         choice of experts does not turn on bfloat16's rounding of x, and
-        gets its part back in float32."""
+        gets its part back in float32.  ``route_from`` [B, S, D]: what the
+        router reads where that is NOT what the experts read (a model whose
+        router stands before its attention and whose experts after it); the
+        gradient of the weights then reaches ``route_from`` and never x."""
         cfg = self.config
         B, S, D = x.shape
         with device_part("stream"):
             flat = x.reshape(B * S, D)
-        chosen, weights, scores = self.route(params, flat)
+            routed_on = flat if route_from is None else route_from.reshape(B * S, D)
+        chosen, weights, scores = self.route(params, routed_on)
         with device_part("stream"):
             flat = flat.astype(cfg.dtype)
         with device_part("experts_route"):
