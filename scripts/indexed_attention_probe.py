@@ -4,18 +4,22 @@ length.  ``chiprun -- python3 scripts/indexed_attention_probe.py``; the last
 line is ``PROBE {...}``.
 
 ``--parent _parent`` (a checkout of another commit, ``git archive``) times
-that copy's ``dsa_attn_fwd``, ``dsa_attn_dq``, ``dsa_attn_dkv`` and
-``dsa_probs`` beside the tree's on the same operands, in turn (parent, tree,
-tree, parent).  The backward launches and ``dsa_probs`` of both are handed
-the TREE's ``o`` and ``lse``, and the report says whether ``dq``, ``dk``,
-``dv`` and ``L_I``'s four are EQUAL bit for bit; of ``o`` and ``lse`` it
+that copy's ``dsa_attn_fwd``, its backward launches and ``dsa_probs`` beside
+the tree's on the same operands, in turn (parent, tree, tree, parent).  Since
+PR 68 the tree's backward is ONE launch (``attn_bwd_ms``; the trace knows it as
+``dsa_attn_dkv``) and a parent from before it has two (``parent_attn_dq_ms``,
+``parent_attn_dkv_ms``): the report gives each and, as ``us_a_step``, each
+over the launch's grid steps.  The backward launches and ``dsa_probs`` of both
+are handed the TREE's ``o`` and ``lse``, and the report says whether ``dq``,
+``dk``, ``dv`` and ``L_I``'s four are EQUAL bit for bit; of ``o`` and ``lse`` it
 gives the LARGEST DIFFERENCE from the parent's (since PR 65 the forward's
 tile lies keys-major and sums a row's denominator in another order: ``o`` in
 steps of its own type, ``lse`` absolute and as ``[B, H, S]`` whichever way it
-left the kernel).  ``--variants name=path,...`` times the FORWARD of further
-copies of the module beside them (PR 65 read the parent's body without its
-two lane reductions so, and the bits turned inside the kernel).
-``--compile-only`` compiles the tree's four, and the variants' forwards, for a
+left the kernel).  ``--variants name=path,...`` times the forward and the
+backward of further copies of the module beside them (PR 65 read the parent's
+forward without its two lane reductions so; PR 68 its one backward launch
+with a product or the resident accumulations taken out).
+``--compile-only`` compiles the tree's launches, and the variants', for a
 described v5e without one (``JAX_PLATFORMS=cpu``)."""
 
 from __future__ import annotations
@@ -105,16 +109,20 @@ def _forward_differences(got, want):
 
 @functools.cache
 def launches(module, seq, dim, interpret=False):
-    """The attention's three launches and ``dsa_probs`` of ``module`` as
-    jitted programs over heads-major operands (an output that is not returned
-    takes its launch with it); one set a module."""
+    """The attention's launches and ``dsa_probs`` of ``module`` as jitted
+    programs over heads-major operands; one set a module.  A module from
+    before PR 68 has two backward launches (an output that is not returned
+    takes its launch with it), the tree one."""
     blocks = module.Blocks().fit(seq)
     scale = 1.0 / float(np.sqrt(dim))
     bwd = lambda *a: module._attn_bwd(*a, scale, blocks, interpret)  # noqa: E731
+    if hasattr(module, "_attn_dq_kernel"):
+        backward = dict(attn_dq=jax.jit(lambda *a: bwd(*a)[:1]), attn_dkv=jax.jit(lambda *a: bwd(*a)[1:]))
+    else:
+        backward = dict(attn_bwd=jax.jit(bwd))
     return dict(
         attn_fwd=jax.jit(lambda *a: module._attn_fwd(*a, scale, blocks, interpret)),
-        attn_dq=jax.jit(lambda *a: bwd(*a)[0]),
-        attn_dkv=jax.jit(lambda *a: bwd(*a)[1:]),
+        **backward,
         probs=jax.jit(lambda *a: module._index_loss(*a, scale, blocks, interpret)),
     )
 
@@ -131,22 +139,21 @@ def compile_only(seq, variants, heads=32, kv=4, dim=128, index_heads=16, index_d
     mask = a(jnp.int32, 1, -(-(seq // blocks.k) // 32), seq, blocks.k)
     bwd = (q, kv_, kv_, mask, q, lse, q)
     args = dict(
-        attn_fwd=(q, kv_, kv_, mask), attn_dq=bwd, attn_dkv=bwd,
+        attn_fwd=(q, kv_, kv_, mask), attn_dq=bwd, attn_dkv=bwd, attn_bwd=bwd,
         probs=(
             q, kv_, lse, mask, a(bf, 1, index_heads, seq, index_dim), a(f32, 1, index_heads, seq, ia._ROW_LANES),
             a(bf, 1, seq, index_dim), a(f32, 1, seq, ia._ROW_LANES),
         ),
     )
     steps = ia._steps(seq, blocks).steps
-    report = dict(steps=steps, dkv_steps=ia._steps(seq, blocks, by_key=True).steps, table_bytes=4 * 3 * steps)
-    for name, fn in launches(ia, seq, dim).items():
-        t0 = time.perf_counter()
-        fn.lower(*args[name]).compile()
-        report[name + "_compile_s"] = round(time.perf_counter() - t0, 1)
-    for name, module in variants.items():
-        t0 = time.perf_counter()
-        launches(module, seq, dim)["attn_fwd"].lower(*args["attn_fwd"]).compile()
-        report[name + "_attn_fwd_compile_s"] = round(time.perf_counter() - t0, 1)
+    report = dict(steps=steps, table_bytes=4 * 3 * steps)
+    for prefix, module in [("", ia)] + [(name + "_", module) for name, module in variants.items()]:
+        for name, fn in launches(module, seq, dim).items():
+            if prefix and name == "probs":
+                continue
+            t0 = time.perf_counter()
+            fn.lower(*args[name]).compile()
+            report[prefix + name + "_compile_s"] = round(time.perf_counter() - t0, 1)
     print("PROBE " + json.dumps(report))
 
 
@@ -212,21 +219,32 @@ def main():
     lanes = ia._row_lanes(lse)
     bwd = (qh, kh, vh, mask, o, lanes, o)
     forwards, got = {}, {}
+
+    def attention(prefix, run):
+        """A module's forward (kept in ``forwards``) and backward launches, timed: (dq, dk, dv)."""
+        forwards[prefix] = clock(prefix + "attn_fwd_ms", run["attn_fwd"], qh, kh, vh, mask)
+        backward = [name for name in ("attn_dq", "attn_dkv", "attn_bwd") if name in run]
+        return tuple(g for name in backward for g in clock(prefix + name + "_ms", run[name], *bwd))
+
     for prefix, module in sides:
         run = launches(module, args.seq, q.shape[-1], toy)
-        forwards[prefix] = clock(prefix + "attn_fwd_ms", run["attn_fwd"], qh, kh, vh, mask)
-        dq = clock(prefix + "attn_dq_ms", run["attn_dq"], *bwd)
-        dk, dv = clock(prefix + "attn_dkv_ms", run["attn_dkv"], *bwd)
+        grads = attention(prefix, run)
         loss = clock(prefix + "probs_ms", run["probs"], qh, kh, lanes, mask, qih, wh, ki, ia._row_lanes(lse_i))
-        got[prefix] = (dq, dk, dv, *loss)
+        got[prefix] = (*grads, *loss)
     for name, module in variants.items():
-        forwards[name] = clock(name + "_attn_fwd_ms", launches(module, args.seq, q.shape[-1], toy)["attn_fwd"], qh, kh, vh, mask)
+        got[name + "_"] = attention(name + "_", launches(module, args.seq, q.shape[-1], toy))
     if args.parent:
         names = "dq dk dv kl d_qi d_w d_ki".split()
         out["equal_to_parent"] = {
             n: bool(jnp.array_equal(a, b)) for n, a, b in zip(names, got[""], got["parent_"], strict=True)
         }
         print("equal_to_parent", out["equal_to_parent"], flush=True)
+    if variants:
+        out["variants_equal_to_tree"] = {
+            name: {n: bool(jnp.array_equal(a, b)) for n, a, b in zip("dq dk dv".split(), got[name + "_"], got[""])}
+            for name in variants
+        }
+        print("variants_equal_to_tree", out["variants_equal_to_tree"], flush=True)
     others = {n: f for n, f in forwards.items() if n and not n.startswith("again")}
     if others:
         out["forward_differs_by"] = {n.rstrip("_"): _forward_differences(forwards[""], f) for n, f in others.items()}
@@ -236,6 +254,9 @@ def main():
 
     clock("dense_flash_fwd_ms", lambda q, k, v: flash_attention(q, k, v, causal=True, interpret=toy), q, k, v)
     out["ms"] = timed
+    # a launch's time over its grid steps (every attention launch walks the same live pairs of every KV head)
+    grid_steps = kh.shape[1] * ia._steps(args.seq, ia.Blocks().fit(args.seq)).steps
+    out["us_a_step"] = {n[:-3]: 1000.0 * ms / grid_steps for n, ms in timed.items() if "attn_" in n}
     print("PROBE " + json.dumps(out))
 
 

@@ -3,9 +3,9 @@ the plain path (dense ``[S, S]`` arrays, ``lax.top_k`` on the full row) and
 against dense causal attention where every key is picked; the launches'
 grids against the live (row block, key block) pairs, and their outputs
 against the rectangular launches they were before PR 53
-(``tests/_indexed_rectangle.py``): the backward launches' and ``L_I``'s bit for
-bit, the keys-major forward's (PR 65) to float32's rounding.  Float32, seeded
-operands, the CPU."""
+(``tests/_indexed_rectangle.py``): the backward's (ONE launch since PR 68,
+held to the rectangle's two) and ``L_I``'s bit for bit, the keys-major
+forward's (PR 65) to float32's rounding.  Float32, seeded operands, the CPU."""
 
 import jax
 import jax.numpy as jnp
@@ -101,7 +101,7 @@ def test_walked_launches_are_the_rectangles_bit_for_bit(case):
     (q, k, v, qi, ki, w), do = _operands(**cfg)
     blocks = cfg["blocks"].fit(cfg["S"])
     if case == "shorter_than_a_key_block":
-        assert ia._steps(cfg["S"], blocks).steps == ia._steps(cfg["S"], blocks, by_key=True).steps == 1
+        assert ia._steps(cfg["S"], blocks).steps == 1
     # one program, as each launch below: run operation by operation the interpreter's loop is seconds of small compiles
     mask, lse_index, _ = jax.jit(lambda *a: ia.select_keys(*a, topk=cfg["topk"], blocks=blocks, interpret=True))(qi, ki, w)
     qh, kh, vh, qih, wh = ia._heads_major(q, k, v, qi, w)
@@ -184,9 +184,11 @@ def _live_pairs(S, bq, bk):
     ],
 )
 def test_launches_walk_only_their_live_blocks(S, bq, bk, live):
-    """The real ``pallas_call`` grids of the attention's three launches and of
-    ``L_I``'s hold exactly the live pairs: a launch that skipped the dead ones'
-    work (``pl.when``) would keep the rectangle."""
+    """The real ``pallas_call`` grids of the attention's two launches (the
+    forward, and since PR 68 ONE backward launch, which the trace knows as
+    ``dsa_attn_dkv`` and which makes ``dq`` too) and of ``L_I``'s hold exactly
+    the live pairs: a launch that skipped the dead ones' work (``pl.when``)
+    would keep the rectangle."""
     blocks = ia.Blocks(bq, bk, 512, 64).fit(S)
     assert live == _live_pairs(S, blocks.q, blocks.k)
     B, H, KV, D, J, DI = 1, 32, 4, 16, 2, 8
@@ -199,10 +201,37 @@ def test_launches_walk_only_their_live_blocks(S, bq, bk, live):
 
     grids = _pallas_calls(f, a(B, S, H, D), a(B, S, KV, D), a(B, S, KV, D), a(B, S, J, DI), a(B, S, DI), a(B, S, J), mask, a(B, S))
     steps = (B, KV, live)
-    assert grids == {"dsa_attn_fwd": steps, "dsa_probs": (B, live), "dsa_attn_dq": steps, "dsa_attn_dkv": steps}
+    assert grids == {"dsa_attn_fwd": steps, "dsa_probs": (B, live), "dsa_attn_dkv": steps}
     assert live * 2 > (S // blocks.q) * (S // blocks.k)  # the rectangle's other pairs, nearly half, are gone
-    tables = ia._steps(S, blocks).tables + ia._steps(S, blocks, by_key=True).tables
-    assert [len(t) for t in tables] == [live] * 6  # 12 bytes a step a launch
+    assert [len(t) for t in ia._steps(S, blocks).tables] == [live] * 3  # one walk for every launch: 12 bytes a step
+
+
+def test_the_backward_is_one_launch_with_a_kv_heads_dk_and_dv_resident():
+    """``dq``, ``dk`` and ``dv`` leave ONE ``pallas_call``; its second and
+    third outputs are a KV head's whole ``dk`` and ``dv`` in float32, a block
+    whose index does not move with the step (it stays in fast memory while the
+    head's row blocks add to it, and is written once)."""
+    S, B, H, KV, D = 256, 2, 4, 2, 16
+    blocks = ia.Blocks(16, 64, 64, 16)
+    a = lambda *dims, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(dims, dtype)  # noqa: E731
+    q, kv, rows = a(B, H, S, D), a(B, KV, S, D), a(B, H, S, ia._ROW_LANES, dtype=jnp.float32)
+    mask = a(B, 1, S, blocks.k, dtype=jnp.int32)
+    jaxpr = jax.make_jaxpr(lambda *ops: ia._attn_bwd(*ops, 0.25, blocks, True))(q, kv, kv, mask, q, rows, q)
+    (call,) = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+    assert call.params["name"] == "dsa_attn_dkv"
+    mapping = call.params["grid_mapping"]
+    assert tuple(mapping.grid) == (B, KV, _live_pairs(S, blocks.q, blocks.k))
+    nk = S // blocks.k
+    dq_aval, *keys_avals = (v.aval for v in call.outvars)
+    assert dq_aval.dtype == jnp.bfloat16
+    for aval, out in zip(keys_avals, list(mapping.block_mappings_output)[1:], strict=True):
+        assert aval.shape == (B, KV, nk, blocks.k, D) and aval.dtype == jnp.float32
+        assert tuple(out.block_aval.shape) == (1, 1, nk, blocks.k, D)
+        # of the index map's operands (b, h, the step, three tables) the block's index reads b and h alone
+        index_map = out.index_map_jaxpr.jaxpr
+        assert not index_map.eqns and list(index_map.outvars[:2]) == list(index_map.invars[:2])
+        assert [getattr(v, "val", None) for v in index_map.outvars[2:]] == [0, 0, 0]
+    assert [o.dtype for o in jaxpr.out_avals] == [jnp.bfloat16] * 3  # rounded once, by XLA
 
 
 def test_under_topk_is_dense_causal_attention():
@@ -245,3 +274,9 @@ def test_blocks_refuse_a_length_they_do_not_divide():
     assert ia.Blocks().refusal(16384) == "" and ia.Blocks().refusal(2048) == ""
     assert "does not divide" in ia.Blocks().refusal(16384 + 128)
     assert ia.Blocks().fit(256) == ia.Blocks(128, 256, 256, 64)
+    # the backward launch keeps a KV head's dk and dv in fast memory: 32 MiB at the cell's shapes, 64 at twice
+    # its length, and past three quarters of the kernels' scoped memory the refusal gives the count
+    assert ia.Blocks().refusal(16384, 128) == ia.Blocks().refusal(32768, 128) == ia.Blocks().refusal(16384, 256) == ""
+    for seq, head_dim, mib in ((65536, 128, 128), (32768, 256, 128), (49152, 128, 96)):
+        refusal = ia.Blocks().refusal(seq, head_dim)
+        assert f"take {mib} MiB of fast memory in the backward launch, over 75 of the 100 MiB" in refusal, refusal

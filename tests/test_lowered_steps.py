@@ -56,7 +56,12 @@ and none of the other seventeen changed.  PR 67 ADDED
 ``prerouted_moe``'s two (``--write --only prerouted_moe``: ``RoutedExperts.apply``
 took ``route_from`` and ``expert_form`` a third value, "reglu") and changed none
 of the eighteen: with ``route_from`` None the six expert models' programs are
-the parent's to the letter.  A later change that
+the parent's to the letter.  PR 68 wrote ONE anew (``--write --only
+indexed_sparse_moe``, whose ``plain`` digest came out the same: Keye's
+attention over the picked keys takes its backward in one launch,
+``dsa_attn_dkv``, where it took ``dsa_attn_dq`` and ``dsa_attn_dkv``,
+``ops/indexed_attention.py``) and none of the other nineteen changed: the nine
+other models' steps are what they were.  A later change that
 means to alter one of these programs writes the fixture anew and says so:
 ``python tests/test_lowered_steps.py --write``."""
 

@@ -231,7 +231,7 @@ class IndexedSparseMoE:
 
     def _kernel_refusal(self, seq: int) -> Optional[str]:
         """Why the Mosaic kernels do NOT apply, or None when they do."""
-        return decoder.one_chip_refusal(self.config.blocks.refusal(seq), self.mesh)
+        return decoder.one_chip_refusal(self.config.blocks.refusal(seq, self.config.head_dim), self.mesh)
 
     @part("mixer_glue")
     def _index(

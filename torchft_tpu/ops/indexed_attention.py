@@ -14,7 +14,7 @@ gradient.  Nothing here differentiates the selection.
 set where row ``t`` picked position ``(32 w + i) * block_k + j``, so the tile
 of key block ``ki`` is ``(mask[b, ki // 32, rows, :] >> (ki % 32)) & 1``, an
 elementwise read with no shuffle, and 32 MB hold 16,384 rows of 16,384
-positions.  The attention's three launches and ``L_I``'s walk the LIVE
+positions.  The attention's two launches and ``L_I``'s walk the LIVE
 (row block, key block) pairs alone, those with a key at or before a row, and
 apply that tile: their grids are ``(B, KV, steps)`` (``L_I``'s ``(B, steps)``)
 over the steps that ``ops/flash_attention.py``'s ``_live_blocks`` and
@@ -22,11 +22,9 @@ over the steps that ``ops/flash_attention.py``'s ``_live_blocks`` and
 first-visit and last-visit flags come from int32 tables by scalar prefetch,
 and a dead pair is no grid step (``_steps``; at 16,384 positions and blocks
 of 128 x 512 a launch's 4 KV heads take 8,448 steps where the rectangle held
-16,384, from 25 KB of tables).  Forward, ``dq`` and ``L_I`` take a row
-block's key blocks ascending, ``dkv`` a key block's row blocks ascending: the
-rectangle's order of accumulation, so ``dq``, ``dk``, ``dv`` and ``L_I``'s
-four are bit for bit the rectangle's.  Eight query heads of a KV head are
-stacked into one ``[8 * block_q, D]`` operand, so a key block and a mask tile
+16,384, from 25 KB of tables).  Every launch takes a row block's key blocks
+ascending.  Eight query heads of a KV head are stacked into one
+``[8 * block_q, D]`` operand, so a key block and a mask tile
 are read once for the group.  No ``[S, S]`` array is made: the index's
 float32 scores exist for one chunk of ``chunk`` query rows at a time.
 
@@ -50,10 +48,32 @@ types as the rows-major body it replaced: the maximum is exact in any order,
 ``l`` is the same addends summed in another order, so ``o`` and ``lse`` are
 the rectangle's to float32's rounding of that sum
 (``scripts/indexed_attention_probe.py --parent`` prints the largest
-difference).  ``dsa_attn_dq``, ``dsa_attn_dkv`` and ``dsa_probs`` keep their
-rows-major tiles.
+difference).  The backward launch and ``dsa_probs`` keep their rows-major
+tiles.
 
-Six ``pallas_call`` names, which the benchmark's readers find in a trace:
+**The backward pass is ONE launch** (PR 68), named ``dsa_attn_dkv`` (the name
+the benchmark's readers already count as the backward; it makes ``dq`` too).
+A live pair's ``s``, ``p`` and ``ds`` are made once and feed all three
+gradients: five products a pair (``q k^T``, ``do v^T``, ``ds k``, ``p^T do``,
+``ds^T q``) where a ``dq`` launch and a ``dkv`` launch made seven, one
+``exp``, one read of the bits.  ``dq`` sums over a row block's key blocks,
+which the walk visits in a run: a scratch accumulator, flushed on the row
+block's last visit.  ``dk`` and ``dv`` sum over a key block's row blocks,
+which the walk does NOT visit in a run, so a KV head's WHOLE ``dk`` and
+``dv`` stay in fast memory for its steps as float32 output blocks
+``(1, 1, nk, block_k, D)``, zeroed at the head's first step, added to at the
+key block the table names (``dk_ref[0, 0, ki] += ...``, as ``dsa_probs``
+keeps a batch row's ``dk``) and written to HBM once, when the head changes;
+XLA rounds them to the operands' type.  At 16,384 positions and ``D`` 128
+that is 2 x 8 MB (32 MB double-buffered) of the 100 MiB the kernels may use;
+``Blocks.refusal`` refuses a length whose two would not fit.  The order of
+every sum is still the rectangle's: ``dq`` adds a row block's key blocks
+ascending, and a key block meets its row blocks ascending because the walk
+takes the row blocks ascending, which is the order the ``dkv`` launch took
+them in.  So ``dq``, ``dk``, ``dv`` and ``L_I``'s four are bit for bit the
+rectangle's.
+
+Five ``pallas_call`` names, which the benchmark's readers find in a trace:
 
 - ``dsa_index``: the scores of one chunk of rows against every causal key
   block, float32, ``[nk, chunk, block_k]``;
@@ -61,7 +81,7 @@ Six ``pallas_call`` names, which the benchmark's readers find in a trace:
   bisection on the scores' bit patterns (32 passes for the value, then
   ``log2 S`` for the cut among equal scores), the bits, the row's
   ``logsumexp`` of its picked scores and its number of keys;
-- ``dsa_attn_fwd``, ``dsa_attn_dq``, ``dsa_attn_dkv``: the attention;
+- ``dsa_attn_fwd``, ``dsa_attn_dkv``: the attention, forward and backward;
 - ``dsa_probs``: ``L_I``, a row at a time, from the attention's ``lse``,
   and in the same pass its gradient to ``qI``, ``kI`` and ``w`` (the
   backward pass scales it by the loss's cotangent).
@@ -70,7 +90,7 @@ Six ``pallas_call`` names, which the benchmark's readers find in a trace:
 attention's operands, the bits, and five values that carry a
 ``checkpoint_name`` (``KEPT_NAMES``): ``o``, its ``lse`` a row as the forward
 wrote it (``[B, H, S]`` float32, block ``(1, group, 1, block_q)`` of
-``[B, H, 1, S]``: ONE number a row; ``dsa_probs`` and the backward launches
+``[B, H, 1, S]``: ONE number a row; ``dsa_probs`` and the backward launch
 take theirs as ``[..., 8]`` columns, ``_row_lanes``), and ``L_I``'s gradient
 to ``qI``, ``w`` and ``kI`` in float32, as ``dsa_probs`` left it.  A caller
 that rematerialises its layers lists those names in its policy and
@@ -100,6 +120,9 @@ _LANES = 128
 _ROW_LANES = 8  # rowwise outputs carry a trailing 8-lane dim (ops/flash_attention.py)
 _INT_MIN = np.int32(-(2**31))
 _VMEM_LIMIT = 100 * 1024 * 1024
+# of which the backward launch's resident dk and dv may take three quarters (its tiles and
+# its other operands' buffers take the rest: 64 MiB of accumulators compile for a v5e)
+_ACCUMULATORS_LIMIT = _VMEM_LIMIT * 3 // 4
 # the forward rule's residuals that a rematerialising caller's policy may keep
 KEPT_NAMES = ("dsa_o", "dsa_attn_lse", "dsa_d_qi", "dsa_d_w", "dsa_d_ki")
 
@@ -120,11 +143,21 @@ class Blocks(NamedTuple):
         chunk = min(self.chunk, seq)
         return Blocks(min(self.q, seq), k, chunk, min(self.s, chunk))
 
-    def refusal(self, seq: int) -> str:
-        """Why ``seq`` does not divide into these blocks, or ''."""
+    def refusal(self, seq: int, head_dim: int = 128) -> str:
+        """Why the kernels do not take ``seq`` positions at heads of
+        ``head_dim``, or ''."""
         b = self.fit(seq)
         if seq % b.q or seq % b.k or seq % b.chunk or b.chunk % b.s or b.q % 8 or b.s % 8:
             return f"seq={seq} does not divide into blocks {tuple(b)}"
+        # the backward launch's dk and dv of a KV head: two float32 [seq, head_dim]
+        # output blocks, each held twice (Pallas double-buffers an output block)
+        held = 2 * 2 * seq * head_dim * 4
+        if held > _ACCUMULATORS_LIMIT:
+            return (
+                f"a KV head's dk and dv over seq={seq} at head_dim={head_dim} take {held >> 20} MiB of fast "
+                f"memory in the backward launch, over {_ACCUMULATORS_LIMIT >> 20} of the {_VMEM_LIMIT >> 20} MiB "
+                "it may use"
+            )
         return ""
 
 
@@ -154,14 +187,11 @@ def _tile_bits(mask_ref, ki: jax.Array, keys_major: bool = False) -> jax.Array:
     return (bits.T if keys_major else bits) != 0
 
 
-def _steps(seq: int, blocks: "Blocks", by_key: bool = False) -> Walk:
+def _steps(seq: int, blocks: "Blocks") -> Walk:
     """The grid steps of a launch over ``seq`` positions, the live (row block,
     key block) pairs alone (``ops/flash_attention.py``, the walk): a row
-    block's key blocks ascending (forward, ``dq``, ``L_I``) or, ``by_key``, a
-    key block's row blocks ascending (``dkv``; the group's heads are stacked
-    in a step, so its members take no step of their own and no table)."""
-    live = _live_blocks(seq // blocks.q, seq // blocks.k, blocks.q, blocks.k, None)
-    return _walk(live, 1)._replace(member=None) if by_key else _walk(live)
+    block's key blocks ascending, in every launch."""
+    return _walk(_live_blocks(seq // blocks.q, seq // blocks.k, blocks.q, blocks.k, None))
 
 
 # ---------------------------------------------------------------------------
@@ -395,8 +425,12 @@ def _p_and_ds(q, k, v, do, lse, delta, picked, sm_scale, group):
     return p, p * (dp - delta) * sm_scale
 
 
-def _attn_dq_kernel(*refs, sm_scale, group, block_q):
-    *tables, q_ref, k_ref, v_ref, mask_ref, lse_ref, do_ref, delta_ref, dq_ref, dq_scr = refs
+def _attn_bwd_kernel(*refs, sm_scale, group, block_q):
+    """One live (row block, key block) pair of the whole backward pass: ``p``
+    and ``ds`` once, ``dq`` summed in scratch over the row block's key blocks,
+    ``dk`` and ``dv`` into the KV head's whole float32 arrays, resident for a
+    ``(b, h)``, at the key block the table names."""
+    *tables, q_ref, k_ref, v_ref, mask_ref, lse_ref, do_ref, delta_ref, dq_ref, dk_ref, dv_ref, dq_scr = refs
     _, ki, first, last = _where(tables)
     D = q_ref.shape[-1]
     rows = group * block_q
@@ -405,46 +439,27 @@ def _attn_dq_kernel(*refs, sm_scale, group, block_q):
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    k = k_ref[0, 0]
-    _, ds = _p_and_ds(
-        q_ref[0].reshape(rows, D), k, v_ref[0, 0], do_ref[0].reshape(rows, D),
+    @pl.when(pl.program_id(2) == 0)
+    def _init_keys():
+        dk_ref[...] = jnp.zeros_like(dk_ref)
+        dv_ref[...] = jnp.zeros_like(dv_ref)
+
+    q, do, k = q_ref[0].reshape(rows, D), do_ref[0].reshape(rows, D), k_ref[0, 0]
+    p, ds = _p_and_ds(
+        q, k, v_ref[0, 0], do,
         lse_ref[0].reshape(rows, _ROW_LANES)[:, :1], delta_ref[0].reshape(rows, _ROW_LANES)[:, :1],
         _tile_bits(mask_ref, ki), sm_scale, group,
     )
     dq_scr[...] += jax.lax.dot_general(
         ds.astype(k.dtype), k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )
+    # contracting the stacked rows sums the whole group of query heads
+    dv_ref[0, 0, ki] += _dot_0(p.astype(do.dtype), do)
+    dk_ref[0, 0, ki] += _dot_0(ds.astype(q.dtype), q)
 
     @pl.when(last)
     def _finalize():
         dq_ref[0] = dq_scr[...].reshape(group, block_q, D).astype(dq_ref.dtype)
-
-
-def _attn_dkv_kernel(*refs, sm_scale, group, block_q):
-    *tables, q_ref, k_ref, v_ref, mask_ref, lse_ref, do_ref, delta_ref, dk_ref, dv_ref, dk_scr, dv_scr = refs
-    _, ki, first, last = _where(tables)
-    D = q_ref.shape[-1]
-    rows = group * block_q
-
-    @pl.when(first)
-    def _init():
-        dk_scr[...] = jnp.zeros_like(dk_scr)
-        dv_scr[...] = jnp.zeros_like(dv_scr)
-
-    q, do = q_ref[0].reshape(rows, D), do_ref[0].reshape(rows, D)
-    p, ds = _p_and_ds(
-        q, k_ref[0, 0], v_ref[0, 0], do,
-        lse_ref[0].reshape(rows, _ROW_LANES)[:, :1], delta_ref[0].reshape(rows, _ROW_LANES)[:, :1],
-        _tile_bits(mask_ref, ki), sm_scale, group,
-    )
-    # contracting the stacked rows sums the whole group of query heads
-    dv_scr[...] += _dot_0(p.astype(do.dtype), do)
-    dk_scr[...] += _dot_0(ds.astype(q.dtype), q)
-
-    @pl.when(last)
-    def _finalize():
-        dk_ref[0, 0] = dk_scr[...].astype(dk_ref.dtype)
-        dv_ref[0, 0] = dv_scr[...].astype(dv_ref.dtype)
 
 
 def _attn_specs(group, bq, bk, D):
@@ -497,47 +512,38 @@ def _attn_fwd(q, k, v, mask, sm_scale, blocks, interpret):
 
 
 def _attn_bwd(q, k, v, mask, o, lse, do, sm_scale, blocks, interpret):
+    """(dq, dk, dv) from ONE launch over the forward's walk (the module
+    docstring).  lse [B, H, S, 8]."""
     B, H, S, D = q.shape
     KV = k.shape[1]
     group = H // KV
     bq, bk = blocks.q, blocks.k
+    nk = S // bk
     delta = jnp.broadcast_to(
         jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1, keepdims=True),
         (B, H, S, _ROW_LANES),
     )
-    by_row, by_key = _steps(S, blocks), _steps(S, blocks, by_key=True)
+    walk = _steps(S, blocks)
     q_spec, kv_spec, mask_spec, row_spec = _attn_specs(group, bq, bk, D)
-    in_specs = [q_spec, kv_spec, kv_spec, mask_spec, row_spec, q_spec, row_spec]
-    static = dict(sm_scale=sm_scale, group=group, block_q=bq)
-    dq = pl.pallas_call(
-        functools.partial(_attn_dq_kernel, **static),
+    # every row block adds to every earlier key block: a KV head's whole dk and
+    # dv stay in fast memory for its steps, as dsa_probs' dk does for a batch row
+    keys_spec = pl.BlockSpec((1, 1, nk, bk, D), lambda b, h, t, qt, kt, ft: (b, h, 0, 0, 0))
+    keys_shape = jax.ShapeDtypeStruct((B, KV, nk, bk, D), jnp.float32)
+    dq, dk, dv = pl.pallas_call(
+        functools.partial(_attn_bwd_kernel, sm_scale=sm_scale, group=group, block_q=bq),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=len(by_row.tables),
-            grid=(B, KV, by_row.steps),
-            in_specs=in_specs,
-            out_specs=q_spec,
+            num_scalar_prefetch=len(walk.tables),
+            grid=(B, KV, walk.steps),
+            in_specs=[q_spec, kv_spec, kv_spec, mask_spec, row_spec, q_spec, row_spec],
+            out_specs=[q_spec, keys_spec, keys_spec],
             scratch_shapes=[pltpu.VMEM((group * bq, D), jnp.float32)],
         ),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype), keys_shape, keys_shape],
         compiler_params=_params("parallel", "parallel", "arbitrary"),
         interpret=interpret,
-        name="dsa_attn_dq",
-    )(*by_row.tables, q, k, v, mask, lse, do, delta)
-    dk, dv = pl.pallas_call(
-        functools.partial(_attn_dkv_kernel, **static),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=len(by_key.tables),
-            grid=(B, KV, by_key.steps),
-            in_specs=in_specs,
-            out_specs=[kv_spec, kv_spec],
-            scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32), pltpu.VMEM((bk, D), jnp.float32)],
-        ),
-        out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype), jax.ShapeDtypeStruct(v.shape, v.dtype)],
-        compiler_params=_params("parallel", "parallel", "arbitrary"),
-        interpret=interpret,
-        name="dsa_attn_dkv",
-    )(*by_key.tables, q, k, v, mask, lse, do, delta)
-    return dq, dk, dv
+        name="dsa_attn_dkv",  # the name the benchmark's readers count as the backward (it makes dq too)
+    )(*walk.tables, q, k, v, mask, lse, do, delta)
+    return dq, dk.reshape(k.shape).astype(k.dtype), dv.reshape(v.shape).astype(v.dtype)
 
 
 # ---------------------------------------------------------------------------
