@@ -20,7 +20,10 @@ directory = None
 def once_a_run(name, make):
     """``make()``, from whichever worker of this run got here first.
     ``name`` is the value's, among everything the run keeps: a file's own
-    name in it, and whatever the value depends on."""
+    name in it, and whatever the value depends on.  Outside a run of the
+    tests (a file's ``--write``) there is no such directory and ``make()`` it is."""
+    if directory is None:
+        return make()
     with open(directory / f"{name}.lock", "w") as lock:
         fcntl.flock(lock, fcntl.LOCK_EX)  # until the file is closed
         kept = directory / f"{name}.pickle"

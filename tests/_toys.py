@@ -257,7 +257,7 @@ def two_replica_walk(make_model, make_batch, total, kill_at=None, quantized=(), 
     return shared, recorded
 
 
-# -- a model's gradient step, lowered (``test_device_parts.py``, ``test_lowered_steps.py``) --
+# -- a model's gradient step, lowered and compiled (``test_device_parts.py``, ``test_lowered_steps.py``) --
 
 
 # name -> (module of ``torchft_tpu.models``, class, its debug configuration, the
@@ -275,6 +275,7 @@ TOYS = {
     "prerouted_moe": ("prerouted_moe", "PreroutedMoE", "prerouted_moe_debug", 64),
     "sambay": ("sambay", "SambaY", "sambay_debug", 64),
     "ssm_hybrid_moe": ("ssm_hybrid_moe", "SsmHybridMoE", "ssm_hybrid_debug", 64),
+    "ssm_hybrid_dense": ("ssm_hybrid_dense", "SsmHybridDense", "ssm_hybrid_dense_debug", 64),
 }
 
 
@@ -289,13 +290,10 @@ def toy(name):
     return getattr(module, cls)(config), seq
 
 
-@functools.lru_cache(maxsize=None)
-def lowered_grad_step(name, path):
+def _lowered_grad_step(name, path):
     """(model, mesh, the parameters' shapes, the gradient step LOWERED) of
     the toy ``name`` on ``path`` (``plain``, or ``kernels``: the Pallas
-    kernels in interpret mode), one row of one sequence: traced once a
-    process for the file that reads the lowered text and the one that
-    compiles it."""
+    kernels in interpret mode), one row of one sequence."""
     # jax shares a private function between two places of the lowered text
     # where its caches hand both the same jaxpr OBJECT (a rematerialised
     # layer's partial evaluation is cached by the policy's identity, a library
@@ -311,3 +309,28 @@ def lowered_grad_step(name, path):
         off_kernels = any(word in model.attention_path for word in ("plain", "naive"))
         assert off_kernels == (path == "plain"), model.attention_path
         return model, mesh, params, lowered
+
+
+@functools.lru_cache(maxsize=None)
+def step_texts(name, path):
+    """``dict(lowered, grad, update)``: the text the toy ``name``'s gradient
+    step LOWERS to on ``path`` and the texts its two step programs COMPILE to,
+    made once a RUN of the tests (``tests/_once.py``) for the file that reads
+    the lowered text (``test_lowered_steps.py``) and the one that reads the
+    compiled ones (``test_device_parts.py``).  ``--dist load`` hands the two
+    files' cases of one toy to different workers five times of six, and each
+    then traced the step for itself: 10-25 s a toy and path, the larger half
+    of either test (PR 69; ROADMAP.md D13)."""
+    from tests._once import once_a_run
+
+    def make():
+        model, mesh, params, lowered = _lowered_grad_step(name, path)
+
+        def update():
+            tx = optax.adamw(1e-3)
+            return make_update_step(model, tx, mesh).lower(params, jax.eval_shape(tx.init, params), params).compile().as_text()
+
+        # the update step knows nothing of the path: one compile a toy
+        return dict(lowered=lowered.as_text(), grad=lowered.compile().as_text(), update=once_a_run(f"update-text-{name}", update))
+
+    return once_a_run(f"step-texts-{name}-{path}", make)

@@ -57,6 +57,7 @@ LIST_TESTS = [
     ("test_ftbench_ssm", "test_the_cell_and_the_lists_it_joined"),
     ("test_ftbench_swa", "test_the_cell_and_the_lists_it_joined"),
     ("test_ftbench_prerouted", "test_the_cell_and_the_lists_it_joined"),
+    ("test_ftbench_ssmdense", "test_the_cell_and_the_lists_it_joined"),
     ("test_ftbench_program_spans", "test_new_readers_are_the_eighteen_benchmark_json_lists"),
     ("test_ftbench_program_spans", "test_the_four_chip_cell_and_the_lists_it_joined"),
     # the tests of the readers that were written under ``tests/``

@@ -251,12 +251,17 @@ def test_indexed_attention_is_model_code_over_kernels(module: str, row: str, may
             "models.ssm_hybrid_moe", "compiled-step-models",
             {"ops.ssd", "ops.flash_attention", "parallel.moe", "models.decoder", "obs.spans"},
         ),
+        (
+            "models.ssm_hybrid_dense", "compiled-step-models",
+            {"ops.ssd", "ops.flash_attention", "parallel.moe", "models.decoder", "obs.spans"},
+        ),
     ],
 )
 def test_the_state_space_scan_is_model_code_over_kernels(module: str, row: str, may_import: set) -> None:
     """PR 35's two modules: the scan's kernels in the kernels' row (sharing
     ``ops/kda.py``'s products), the model in the models', importing kernels
-    and model code and nothing of the Manager."""
+    and model code and nothing of the Manager; PR 69's model over the same
+    kernels (``parallel/moe.py`` for the shared SwiGLU alone) and no sibling."""
     assert [ROWS[i][0] for i in _rows_of(module)] == [row]
     assert {target for importer, _line, target in _inner_edges() if importer == module} == may_import
 

@@ -44,7 +44,7 @@ the forward's score tile lies keys-major and its second output is ``[B, H,
 S]``, ``ops/flash_attention.py``); the nine ``plain`` digests and both of
 ``indexed_sparse_moe`` did NOT change, which is the proof that the plain path
 and Keye's kernels were not touched.  Since PR 64 the text is made from jax's
-caches as a new process has them (``tests/_toys.py`` ``lowered_grad_step``
+caches as a new process has them (``tests/_toys.py`` ``_lowered_grad_step``
 clears them before it lowers): jax shares a private function between two
 places of the text where its caches hand both the same jaxpr object, so
 ``ssm_hybrid_moe``'s two digests, whose runs share ONE policy object, came out
@@ -61,7 +61,11 @@ indexed_sparse_moe``, whose ``plain`` digest came out the same: Keye's
 attention over the picked keys takes its backward in one launch,
 ``dsa_attn_dkv``, where it took ``dsa_attn_dq`` and ``dsa_attn_dkv``,
 ``ops/indexed_attention.py``) and none of the other nineteen changed: the nine
-other models' steps are what they were.  A later change that
+other models' steps are what they were.  PR 69 ADDED ``ssm_hybrid_dense``'s two
+(``--write --only ssm_hybrid_dense``: ``ops/ssd.py`` walks a group wider than
+``HEAD_BLOCK`` in head blocks, ``DEVICE_PARTS`` took ``mixer_conv`` and
+``mixer_gate``) and changed none of the twenty: a group of ONE block, which
+``ssm_hybrid_moe``'s toy and cell are, runs the program it ran, to the letter.  A later change that
 means to alter one of these programs writes the fixture anew and says so:
 ``python tests/test_lowered_steps.py --write``."""
 
@@ -74,14 +78,14 @@ import sys
 import pytest
 
 FIXTURE = os.path.join(os.path.dirname(os.path.abspath(__file__)), "fixtures", "lowered_steps.json")
-MODELS = ("ling_hybrid", "indexed_sparse_moe", "llama", "ssm_hybrid_moe", "windowed_moe", "eva", "gated_delta_moe", "looped", "sambay", "prerouted_moe")
+MODELS = ("ling_hybrid", "indexed_sparse_moe", "llama", "ssm_hybrid_moe", "windowed_moe", "eva", "gated_delta_moe", "looped", "sambay", "prerouted_moe", "ssm_hybrid_dense")
 CASES = [(m, p) for m in MODELS for p in ("plain", "kernels")]
 
 
 def digest(name: str, path: str) -> str:
-    from tests._toys import lowered_grad_step  # traces once for this file and ``test_device_parts.py``
+    from tests._toys import step_texts  # traced once a run for this file and ``test_device_parts.py``
 
-    text = lowered_grad_step(name, path)[3].as_text()
+    text = step_texts(name, path)["lowered"]
     # jax numbers its private functions (@silu_808) from one counter a
     # process: the numbers say what else was traced, not what the program is
     text = re.sub(r"@([A-Za-z_]\w*?)_\d+\b", r"@\1", text)

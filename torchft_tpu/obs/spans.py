@@ -72,7 +72,7 @@ operations; these are the names the program gives out::
 **The parts of the compiled step.**  A device operation that XLA makes has
 no name of ours, only the path of ``jax.named_scope``s it was traced under
 (``op_name`` in the compiled program, ``tf_op`` in a trace's event metadata).
-:class:`part` opens one of these fourteen scopes (``with part("ffn"):`` around
+:class:`part` opens one of these sixteen scopes (``with part("ffn"):`` around
 some lines, ``@part("ffn")`` on a function that is one part whole), ONE
 vocabulary for every architecture (:data:`DEVICE_PARTS`); the innermost one
 on an operation's path is its part::
@@ -93,6 +93,16 @@ on an operation's path is its part::
                             k, ``softplus`` and the decay, the SiLU-gated head
                             norm and the full layers' sigmoid gate are here
                             (``models/gated_delta_moe.py``): no part is new
+    tpuft.mixer_conv        a Mamba-2 mixer's short causal convolution over its
+                            ``X | B | C`` channels with the bias and the SiLU
+                            (``models/ssm_hybrid_dense.py``), forward and
+                            backward: told from the glue where the state-space
+                            mixer is the larger part of a step
+    tpuft.mixer_gate        the same mixer's ``y * silu(z)``, the RMSNorm over
+                            all its channels and the norm's weight, forward and
+                            backward; ``softplus``, ``dt * x``, the running sum
+                            of the log decay, the splits and the layout copies
+                            around the launch stay ``mixer_glue``'s
     tpuft.mixer_diff        differential attention's combination of its two
                             softmaxes (``models/sambay.py``): ``lambda`` from
                             its four vectors, ``O1 - lambda O2``, the RMSNorm
@@ -187,7 +197,7 @@ SPANS_ENV = "TORCHFT_FLIGHT_SPANS"
 # the parts of the compiled step (the module docstring says what lies under each)
 DEVICE_PARTS = (
     "embed", "stream", "mixer_proj", "mixer_glue", "mixer_diff", "mixer_pool", "ffn", "experts_route",
-    "experts_dispatch", "head", "mtp", "loop_gate", "layers", "optimizer",
+    "experts_dispatch", "head", "mtp", "loop_gate", "layers", "optimizer", "mixer_conv", "mixer_gate",
 )
 PART_PREFIX = "tpuft."
 

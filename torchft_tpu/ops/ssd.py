@@ -27,8 +27,15 @@ tests).
 Kernels: ``ssd_fwd`` walks a group's chunks in order with its heads' states
 in VMEM and keeps each chunk's starting state for the backward; ``ssd_bwd``
 walks them in reverse with the states' cotangent in VMEM and applies the
-hand-written transpose of the algebra above from the kept states.  What is
-elementwise in the tokens stays outside, in XLA, differentiated by jax:
+hand-written transpose of the algebra above from the kept states.  A grid
+step takes a BLOCK of a group's heads (``head_block``: ``HEAD_BLOCK`` of them,
+the body unrolls a loop over them); a wider group's blocks are
+the grid's innermost axis, under one chunk of ``B`` and ``C`` that stays where
+it is: ``C B^T`` is made by the chunk's first block and kept in VMEM, and what
+the group's heads SHARE of the cotangents (``dB``, ``dC`` and ``d(C B^T)``) is
+summed over the blocks in VMEM, in float32, and written by the last.  A group
+of one block runs the program it ran before there were blocks, to the letter.
+What is elementwise in the tokens stays outside, in XLA, differentiated by jax:
 ``dt * x``, the running sum of ``log a`` inside a chunk (so ``dt``, ``A_log``
 and ``D`` get their gradients there) and ``D x``.  The kernels read the
 running sum twice, once with the tokens along the lanes and once along the
@@ -46,6 +53,7 @@ a ``lax.scan``, differentiated by jax: what a model takes off the TPU.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -137,36 +145,60 @@ def _one_hot_lane(width, h):
     return (jax.lax.broadcasted_iota(jnp.int32, (1, width), 1) == h).astype(_F32)
 
 
-def _fwd_kernel(xd_ref, b_ref, c_ref, gr_ref, gc_ref, y_ref, h_ref, s_scr, *, heads):
-    @pl.when(pl.program_id(2) == 0)
+def _first_block(blocks):
+    """(Which of the group's head blocks a grid step works on, whether it is
+    the first step of the (batch, group)'s walk)."""
+    j = pl.program_id(3) if blocks > 1 else 0
+    first = pl.program_id(2) == 0
+    return j, first & (j == 0) if blocks > 1 else first
+
+
+def _chunk_cb(Bm, Cm, j, blocks, cb_scr=None):
+    """The group's ``C B^T`` of this chunk: made once, by the first head block."""
+    if blocks == 1:
+        return _group_cb(Bm, Cm)
+
+    @pl.when(j == 0)
+    def _make():
+        cb_scr[...] = _group_cb(Bm, Cm)
+
+    return cb_scr[...]
+
+
+def _fwd_kernel(xd_ref, b_ref, c_ref, gr_ref, gc_ref, y_ref, h_ref, s_scr, *shared, heads, blocks):
+    j, first = _first_block(blocks)
+
+    @pl.when(first)
     def _start():
         s_scr[...] = jnp.zeros_like(s_scr)
 
     Bm, Cm = b_ref[0, 0], c_ref[0, 0]
-    CB = _group_cb(Bm, Cm)
+    CB = _chunk_cb(Bm, Cm, j, blocks, *shared)
     mask = _causal(Bm.shape[0])
     g_cols = gc_ref[0, 0, 0]
     for h in range(heads):
-        S0 = s_scr[h]
+        S0 = s_scr[j * heads + h]
         h_ref[0, h, 0] = S0
         Y, S1 = _head_fwd(
             xd_ref[0, h], Bm, Cm, CB, mask, gr_ref[0, 0, h : h + 1, :], g_cols[:, h : h + 1], S0
         )
         y_ref[0, h] = Y.astype(y_ref.dtype)
-        s_scr[h] = S1
+        s_scr[j * heads + h] = S1
 
 
 def _bwd_kernel(
     xd_ref, b_ref, c_ref, gr_ref, gc_ref, h_ref, dy_ref,
-    dxd_ref, db_ref, dc_ref, dgr_ref, dgc_ref, ds_scr, *, heads,
+    dxd_ref, db_ref, dc_ref, dgr_ref, dgc_ref, ds_scr, *shared, heads, blocks,
 ):
-    @pl.when(pl.program_id(2) == 0)
+    j, first = _first_block(blocks)
+
+    @pl.when(first)
     def _start():
         ds_scr[...] = jnp.zeros_like(ds_scr)
 
     Bm, Cm = b_ref[0, 0], c_ref[0, 0]
     mm = Bm.dtype
-    CB = _group_cb(Bm, Cm)
+    CB = _chunk_cb(Bm, Cm, j, blocks, *shared[:1])
     mask = _causal(Bm.shape[0])
     g_cols = gc_ref[0, 0, 0]
     dCB = jnp.zeros_like(CB)
@@ -176,60 +208,105 @@ def _bwd_kernel(
     for h in range(heads):
         dxd, dCB_h, dB_h, dC_h, dg_row, dg_col, dS0 = _head_bwd(
             xd_ref[0, h], Bm, Cm, CB, mask, gr_ref[0, 0, h : h + 1, :], g_cols[:, h : h + 1],
-            h_ref[0, h, 0], dy_ref[0, h], ds_scr[h],
+            h_ref[0, h, 0], dy_ref[0, h], ds_scr[j * heads + h],
         )
         dxd_ref[0, h] = dxd.astype(dxd_ref.dtype)
         dgr_ref[0, 0, h : h + 1, :] = dg_row
         dg_cols = dg_cols + dg_col * _one_hot_lane(heads, h)
         dCB, dB, dC = dCB + dCB_h, dB + dB_h, dC + dC_h
-        ds_scr[h] = dS0
+        ds_scr[j * heads + h] = dS0
     dgc_ref[0, 0, 0] = dg_cols
-    db_ref[0, 0] = (dB + _dot(dCB, Cm, _TN, mm)).astype(db_ref.dtype)
-    dc_ref[0, 0] = (dC + _dot(dCB, Bm, _NN, mm)).astype(dc_ref.dtype)
+
+    def write(dCB, dB, dC):
+        db_ref[0, 0] = (dB + _dot(dCB, Cm, _TN, mm)).astype(db_ref.dtype)
+        dc_ref[0, 0] = (dC + _dot(dCB, Bm, _NN, mm)).astype(dc_ref.dtype)
+
+    if blocks == 1:
+        return write(dCB, dB, dC)
+    # what the group's heads share, summed over its blocks where it lies
+    sums = shared[1:]
+
+    @pl.when(j == 0)
+    def _set():
+        for ref, part in zip(sums, (dCB, dB, dC)):
+            ref[...] = part
+
+    @pl.when(j > 0)
+    def _add():
+        for ref, part in zip(sums, (dCB, dB, dC)):
+            ref[...] += part
+
+    @pl.when(j == blocks - 1)
+    def _write():
+        write(*(ref[...] for ref in sums))
 
 
-def _specs(heads, chunk, P, N, at):
-    """Block specs of a grid step (batch, group, chunk), ``at(c)`` the chunk
-    a step works on: the heads' rows, the group's rows, the running sum with
-    the tokens along the lanes and along the sublanes, the heads' states."""
+def _specs(heads, blocks, chunk, P, N, at):
+    """Block specs of a grid step (batch, group, chunk and, where a group is
+    ``blocks`` > 1 blocks of ``heads`` heads, the block), ``at(c)`` the chunk a
+    step works on: the block's rows, the group's rows, the running sum with
+    the tokens along the lanes and along the sublanes, the block's states.
+    The heads' arrays count the blocks of all groups along one axis."""
+
+    def block(g, j):
+        return g * blocks + j[0] if j else g
+
     return dict(
-        head=pl.BlockSpec((1, heads, chunk, P), lambda b, g, c: (b, g, at(c), 0)),
-        group=pl.BlockSpec((1, 1, chunk, N), lambda b, g, c: (b, g, at(c), 0)),
-        g_row=pl.BlockSpec((1, 1, heads, chunk), lambda b, g, c: (b, g, 0, at(c))),
-        g_col=pl.BlockSpec((1, 1, 1, chunk, heads), lambda b, g, c: (b, g, at(c), 0, 0)),
-        state=pl.BlockSpec((1, heads, 1, P, N), lambda b, g, c: (b, g, at(c), 0, 0)),
+        head=pl.BlockSpec((1, heads, chunk, P), lambda b, g, c, *j: (b, block(g, j), at(c), 0)),
+        group=pl.BlockSpec((1, 1, chunk, N), lambda b, g, c, *j: (b, g, at(c), 0)),
+        g_row=pl.BlockSpec((1, 1, heads, chunk), lambda b, g, c, *j: (b, block(g, j), 0, at(c))),
+        g_col=pl.BlockSpec((1, 1, 1, chunk, heads), lambda b, g, c, *j: (b, block(g, j), at(c), 0, 0)),
+        state=pl.BlockSpec((1, heads, 1, P, N), lambda b, g, c, *j: (b, block(g, j), at(c), 0, 0)),
     )
 
 
-_PARAMS = pltpu.CompilerParams(dimension_semantics=("parallel", "parallel", "arbitrary"))
+def _grid(B, G, nt, blocks):
+    """(grid, compiler parameters): the blocks of a group that has several
+    are the innermost axis, walked in order under one chunk."""
+    more = (blocks,) if blocks > 1 else ()
+    semantics = ("parallel", "parallel", "arbitrary") + ("arbitrary",) * len(more)
+    return (B, G, nt) + more, pltpu.CompilerParams(dimension_semantics=semantics)
 
 
 def _rows(g_cols):
-    """``[B, G, nt, C, heads]`` (tokens along the sublanes) to ``[B, G,
-    heads, S]`` (along the lanes)."""
+    """``[B, blocks, nt, C, heads]`` (tokens along the sublanes) to ``[B,
+    blocks, heads, S]`` (along the lanes)."""
     B, G, nt, C, heads = g_cols.shape
     return g_cols.transpose(0, 1, 4, 2, 3).reshape(B, G, heads, nt * C)
 
 
+def _shared_scratch(blocks, chunk, N, cotangents):
+    """What a group of several head blocks keeps in VMEM for a chunk: ``C
+    B^T`` and, in the backward kernel, the sums of ``d(C B^T)``, ``dB`` and
+    ``dC`` over the blocks, all float32."""
+    if blocks == 1:
+        return []
+    square, rows = pltpu.VMEM((chunk, chunk), _F32), pltpu.VMEM((chunk, N), _F32)
+    return [square, square, rows, rows] if cotangents else [square]
+
+
 def _fwd(xd, Bm, Cm, g_cols, interpret):
     """Heads-major ``xd [B, H, S, P]``, ``Bm, Cm [B, G, S, N]``, ``g_cols [B,
-    G, S/C, C, H/G]``; ``(y [B, H, S, P], h [B, H, S/C, P, N])`` out, ``h``
-    the state every chunk started from."""
+    H/b, S/C, C, b]`` for head blocks of ``b`` heads (a group is a whole
+    number of them); ``(y [B, H, S, P], h [B, H, S/C, P, N])`` out, ``h`` the
+    state every chunk started from."""
     B, H, S, P = xd.shape
     G, N = Bm.shape[1], Bm.shape[-1]
     nt, chunk, heads = g_cols.shape[2:]
-    spec = _specs(heads, chunk, P, N, lambda c: c)
+    blocks = H // (G * heads)
+    spec = _specs(heads, blocks, chunk, P, N, lambda c: c)
+    grid, params = _grid(B, G, nt, blocks)
     return pl.pallas_call(
-        functools.partial(_fwd_kernel, heads=heads),
-        grid=(B, G, nt),
+        functools.partial(_fwd_kernel, heads=heads, blocks=blocks),
+        grid=grid,
         in_specs=[spec["head"], spec["group"], spec["group"], spec["g_row"], spec["g_col"]],
         out_specs=[spec["head"], spec["state"]],
         out_shape=[
             jax.ShapeDtypeStruct((B, H, S, P), xd.dtype),
             jax.ShapeDtypeStruct((B, H, nt, P, N), _F32),
         ],
-        scratch_shapes=[pltpu.VMEM((heads, P, N), _F32)],
-        compiler_params=_PARAMS,
+        scratch_shapes=[pltpu.VMEM((blocks * heads, P, N), _F32), *_shared_scratch(blocks, chunk, N, False)],
+        compiler_params=params,
         interpret=interpret,
         name="ssd_fwd",
     )(xd, Bm, Cm, _rows(g_cols), g_cols)
@@ -239,25 +316,27 @@ def _bwd(xd, Bm, Cm, g_cols, h, dy, interpret):
     B, H, S, P = xd.shape
     G, N = Bm.shape[1], Bm.shape[-1]
     nt, chunk, heads = g_cols.shape[2:]
+    blocks = H // (G * heads)
     # the chunks in reverse: the states' cotangent flows from the last one
-    spec = _specs(heads, chunk, P, N, lambda c: nt - 1 - c)
+    spec = _specs(heads, blocks, chunk, P, N, lambda c: nt - 1 - c)
+    grid, params = _grid(B, G, nt, blocks)
     like = lambda x, dtype=None: jax.ShapeDtypeStruct(x.shape, dtype or x.dtype)  # noqa: E731
     g_rows = _rows(g_cols)
     dxd, dB, dC, dg_rows, dg_cols = pl.pallas_call(
-        functools.partial(_bwd_kernel, heads=heads),
-        grid=(B, G, nt),
+        functools.partial(_bwd_kernel, heads=heads, blocks=blocks),
+        grid=grid,
         in_specs=[
             spec["head"], spec["group"], spec["group"], spec["g_row"], spec["g_col"],
             spec["state"], spec["head"],
         ],
         out_specs=[spec["head"], spec["group"], spec["group"], spec["g_row"], spec["g_col"]],
         out_shape=[like(xd), like(Bm), like(Cm), like(g_rows, _F32), like(g_cols, _F32)],
-        scratch_shapes=[pltpu.VMEM((heads, P, N), _F32)],
-        compiler_params=_PARAMS,
+        scratch_shapes=[pltpu.VMEM((blocks * heads, P, N), _F32), *_shared_scratch(blocks, chunk, N, True)],
+        compiler_params=params,
         interpret=interpret,
         name="ssd_bwd",
     )(xd, Bm, Cm, g_rows, g_cols, h, dy)
-    dg = dg_cols + dg_rows.reshape(B, G, heads, nt, chunk).transpose(0, 1, 3, 4, 2)
+    dg = dg_cols + dg_rows.reshape(*g_cols.shape[:2], heads, nt, chunk).transpose(0, 1, 3, 4, 2)
     return dxd, dB, dC, dg
 
 
@@ -287,18 +366,36 @@ _ssd_hm.defvjp(_ssd_hm_fwd, _ssd_hm_bwd)
 # ---------------------------------------------------------------------------
 
 
-def _prepare(x, dt, A_log, Bm, Cm, chunk):
+# the most heads of a group that one grid step takes: the kernels' bodies
+# unroll a loop over them and their blocks are ``(heads, chunk, P)`` and
+# ``(heads, P, N)``
+HEAD_BLOCK = 8
+
+
+def head_block(heads: int, block: Optional[int] = None) -> int:
+    """How many of a group's ``heads`` a grid step of the kernels takes:
+    ``block`` if given, else ``HEAD_BLOCK`` where that divides the group and
+    the whole group where it does not."""
+    block = block or (HEAD_BLOCK if heads % HEAD_BLOCK == 0 else heads)
+    if heads % block or (block < heads and block % 8):
+        raise ValueError(f"a group of {heads} heads does not divide into blocks of {block} (a multiple of 8 where it is not the group)")
+    return block
+
+
+def _prepare(x, dt, A_log, Bm, Cm, chunk, block=None):
     """Heads-major ``dt * x``, ``B`` and ``C``, and the running sum of the
-    log decay inside each chunk ``[B, G, S/C, C, H/G]`` (float32)."""
+    log decay inside each chunk ``[B, H/b, S/C, C, b]`` (float32) for blocks
+    of ``b`` heads, a group's heads unless ``block`` says fewer."""
     B, S, H, _ = x.shape
     G = Bm.shape[2]
     if S % chunk or H % G:
         raise ValueError(f"S={S} not divisible by the chunk {chunk}, or {H} heads by {G} groups")
+    block = block or H // G
     hm = lambda a: a.transpose(0, 2, 1, 3)  # noqa: E731
     dt = dt.astype(_F32)
     xd = (x.astype(_F32) * dt[..., None]).astype(x.dtype)
     log_a = -dt * jnp.exp(A_log.astype(_F32))
-    g_cols = jnp.cumsum(log_a.reshape(B, S // chunk, chunk, G, H // G), axis=2).transpose(0, 3, 1, 2, 4)
+    g_cols = jnp.cumsum(log_a.reshape(B, S // chunk, chunk, H // block, block), axis=2).transpose(0, 3, 1, 2, 4)
     return hm(xd), hm(Bm), hm(Cm), g_cols
 
 
@@ -317,15 +414,18 @@ def ssd_chunked(
     D: jax.Array,
     *,
     chunk: int = 128,
+    block: Optional[int] = None,
     interpret: bool = False,
 ) -> jax.Array:
     """The scan over whole sequences from a zero state, by the chunked
     kernels.  ``x`` ``[B, S, H, P]``, ``dt`` ``[B, S, H]`` (positive: after its
     softplus), ``A_log`` and ``D`` ``[H]``, ``Bm`` and ``Cm`` ``[B, S, G, N]``
     with ``H`` a multiple of ``G``.  Returns ``[B, S, H, P]`` in ``x``'s
-    type.  ``S`` must be a multiple of ``chunk``."""
+    type.  ``S`` must be a multiple of ``chunk``; ``block`` is the heads of a
+    group that a grid step takes (:func:`head_block`)."""
     chunk = min(chunk, x.shape[1])
-    return _with_skip(_ssd_hm(*_prepare(x, dt, A_log, Bm, Cm, chunk), interpret), x, D)
+    block = head_block(x.shape[2] // Bm.shape[2], block)
+    return _with_skip(_ssd_hm(*_prepare(x, dt, A_log, Bm, Cm, chunk, block), interpret), x, D)
 
 
 def ssd_chunked_plain(
